@@ -28,7 +28,7 @@ every query carries an explicit ``now`` — so the exact same object runs
 inside the live master's tick loop AND inside the offline control-plane
 simulator, and replay verdicts stay byte-identical. The throughput
 signal it consumes is the same one the ``easydl_worker_mfu`` gauge and
-``bench.py --mesh-sweep`` report: one MFU definition
+``bench.py`` report: one MFU definition
 (:mod:`easydl_tpu.core.mfu`), three readers.
 """
 

@@ -9,11 +9,11 @@ surfaced live as the ``easydl_worker_mfu`` gauge) read THESE functions, so
 the number the Brain's mesh-shape policy sees and the number the bench
 artifact reports can never silently diverge.
 
-The denominator is no longer allowed to be quietly wrong on new hardware:
-an unknown ``device_kind`` used to fall back to v4's 275 TFLOP/s in
-silence — now the fallback logs a loud warning naming the assumed peak,
-and ``EASYDL_CHIP_PEAK_TFLOPS`` overrides the table outright (the knob
-for chips the table has never heard of, declared in utils/env.py).
+The denominator is never a guess: a ``device_kind`` the table does not
+know raises (a CPU has no peak to normalise by, and a new chip's peak must
+be stated, not assumed), and ``EASYDL_CHIP_PEAK_TFLOPS`` overrides the table
+outright (the knob for chips the table has never heard of, declared in
+utils/env.py).
 """
 
 from __future__ import annotations
@@ -36,19 +36,15 @@ PEAK_FLOPS: Dict[str, float] = {
     "v2": 45e12,
 }
 
-#: The fallback peak an unknown chip is assumed to have (v4) — always
-#: announced loudly, never silent.
-FALLBACK_PEAK = 275e12
-
 
 def peak_flops_per_chip(device_kind: str) -> float:
     """Peak dense FLOP/s for ``device_kind``.
 
     Resolution order: the ``EASYDL_CHIP_PEAK_TFLOPS`` knob (an explicit
     operator statement — wins even for known chips, e.g. to model an
-    fp8-rated peak), then the spec table, then the v4 fallback with a
-    WARNING naming the assumed number — a multi-chip MFU headline must
-    never be quietly normalised by the wrong denominator."""
+    fp8-rated peak), then the spec table. A kind neither knows raises
+    ``ValueError``: an MFU normalised by an assumed denominator is not a
+    measurement."""
     override = knob_raw("EASYDL_CHIP_PEAK_TFLOPS")
     if override:
         try:
@@ -61,12 +57,9 @@ def peak_flops_per_chip(device_kind: str) -> float:
     for key, val in PEAK_FLOPS.items():
         if key in kind:
             return val
-    log.warning(
-        "unknown device kind %r: assuming v4 peak %.0f TFLOP/s for the MFU "
-        "denominator — set EASYDL_CHIP_PEAK_TFLOPS to this chip's real peak "
-        "or the reported MFU is meaningless", device_kind,
-        FALLBACK_PEAK / 1e12)
-    return FALLBACK_PEAK
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {device_kind!r}: add it to "
+        "core/mfu.py PEAK_FLOPS or state it with EASYDL_CHIP_PEAK_TFLOPS")
 
 
 def model_flops_per_token(n_params: int, n_layers: int, d_model: int,

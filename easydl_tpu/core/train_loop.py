@@ -16,6 +16,9 @@ Design notes (TPU):
 - state is donated, so buffers are reused in place (HBM headroom).
 - flax ``Partitioned`` metadata boxes are kept in the state; logical-axis
   rules map them to mesh axes (see :mod:`easydl_tpu.core.sharding`).
+- every compiled function is traced under ``jax.set_mesh(mesh)``: GSPMD
+  cannot partition a Mosaic kernel, so ops that hold one (ops/attention.py)
+  read the context mesh and run the kernel per shard in ``jax.shard_map``.
 """
 
 from __future__ import annotations
@@ -51,6 +54,23 @@ def cast_floating(tree: Any, dtype: jnp.dtype) -> Any:
     return jax.tree.map(cast, tree)
 
 
+class _OnMesh:
+    """A jitted function that is called, and lowered, under
+    ``jax.set_mesh(mesh)`` — so code traced inside it can ask
+    ``jax.sharding.get_abstract_mesh()`` which mesh it is compiled for."""
+
+    def __init__(self, fn, mesh: Mesh):
+        self._fn, self._mesh = fn, mesh
+
+    def __call__(self, *args):
+        with jax.set_mesh(self._mesh):
+            return self._fn(*args)
+
+    def lower(self, *args):
+        with jax.set_mesh(self._mesh):
+            return self._fn.lower(*args)
+
+
 class TrainState(struct.PyTreeNode):
     step: jax.Array
     params: Any
@@ -66,11 +86,10 @@ class TrainState(struct.PyTreeNode):
 class TrainConfig:
     global_batch: int = 32
     grad_accum: int = 1
-    #: lax.scan unroll for the accumulation loop. The profiler trace
-    #: (scripts/bench_profile.py → PROFILE.json) showed the scan carry's
-    #: gradient adds as dynamic-update-slice fusions costing ~16% of the
-    #: step at accum 32; unrolling lets XLA fuse the carry update across
-    #: ``accum_unroll`` microbatches, cutting that HBM write traffic.
+    #: lax.scan unroll for the accumulation loop: unrolling lets XLA fuse
+    #: the scan carry's gradient adds across ``accum_unroll`` microbatches.
+    #: A hypothesis — the profile that priced it was retracted, and it has
+    #: not been measured on a chip (ROADMAP Speed 5).
     accum_unroll: int = 1
     compute_dtype: Any = jnp.bfloat16
     seed: int = 0
@@ -145,7 +164,7 @@ class Trainer:
         abstract, make, rng = self._abstract_state()
         shardings = self.state_shardings()
         t0 = time.perf_counter()
-        state = jax.jit(make, out_shardings=shardings)(rng)
+        state = _OnMesh(jax.jit(make, out_shardings=shardings), self.mesh)(rng)
         log.info(
             "initialised state on mesh [%s] in %.2fs (%d params)",
             ", ".join(f"{k}={v}" for k, v in self.mesh.shape.items() if v > 1) or "1 device",
@@ -245,12 +264,12 @@ class Trainer:
         shardings = self.state_shardings()
         batch_shd = shd.batch_sharding(self.mesh)
         replicated = NamedSharding(self.mesh, P())
-        return jax.jit(
+        return _OnMesh(jax.jit(
             train_step,
             in_shardings=(shardings, batch_shd),
             out_shardings=(shardings, replicated),
             donate_argnums=(0,) if self.config.donate_state else (),
-        )
+        ), self.mesh)
 
     @property
     def step_fn(self):
@@ -277,8 +296,8 @@ class Trainer:
             _, aux = eval_fn(cast_floating(state.params, compute_dtype), batch, state.rng)
             return aux
 
-        return jax.jit(
+        return _OnMesh(jax.jit(
             eval_step,
             in_shardings=(self.state_shardings(), shd.batch_sharding(self.mesh)),
             out_shardings=NamedSharding(self.mesh, P()),
-        )
+        ), self.mesh)

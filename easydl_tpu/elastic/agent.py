@@ -29,7 +29,7 @@ from easydl_tpu.utils.rpc import RpcClient
 from easydl_tpu.elastic import timeline
 from easydl_tpu.elastic.master import MASTER_SERVICE
 from easydl_tpu.obs.errors import count_swallowed
-from easydl_tpu.utils.env import knob_float, knob_raw
+from easydl_tpu.utils.env import default_platform, knob_float, knob_raw
 
 log = get_logger("elastic", "agent")
 
@@ -63,7 +63,7 @@ class Agent:
         workdir: str,
         slots: int = 1,
         host: str = "localhost",
-        platform: str = "cpu",
+        platform: Optional[str] = None,
         heartbeat_interval: float = 0.3,
         worker_argv: Optional[List[str]] = None,
         master_file: Optional[str] = None,
@@ -75,7 +75,11 @@ class Agent:
         self.workdir = workdir
         self.slots = slots
         self.host = host
-        self.platform = platform
+        # "cpu" forces the workers onto a `slots`-device CPU platform; any
+        # other value leaves them the host's accelerator. Unstated, it
+        # follows JAX_PLATFORMS like every other process here — an agent
+        # on a TPU VM must not quietly train on the CPU.
+        self.platform = platform or default_platform()
         self.heartbeat_interval = heartbeat_interval
         # When the trainer pod is replaced, the new master publishes a NEW
         # address into master_file; after master_refresh_s of failed
@@ -101,6 +105,9 @@ class Agent:
         self._preflight: Optional[tuple] = None
         self._preflight_count = 0
         self._preflight_failed_sig: Optional[tuple] = None
+        # The prepare this agent declined because its own live worker
+        # holds the accelerator (see _maybe_preflight).
+        self._preflight_declined_sig: Optional[tuple] = None
         self.worker_argv = worker_argv or [
             sys.executable, "-m", "easydl_tpu.elastic.worker"
         ]
@@ -116,6 +123,7 @@ class Agent:
         self._applied_key = (-1, "")  # (generation, coordinator) last spawned
         self._state = "idle"
         self._quiesce_sent = False
+        self._kill_sent = False
         self._preempting = threading.Event()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -150,7 +158,7 @@ class Agent:
             "wall time.", ("agent",))
         # One MFU definition, three readers (core/mfu.py): the worker
         # stamps "mfu" into its step records, this gauge surfaces it live,
-        # and bench.py --mesh-sweep reports the same formula — the Brain's
+        # and bench.py reports the same formula — the Brain's
         # mesh-shape policy and the bench artifact can never diverge.
         self._m_worker_mfu = reg.gauge(
             "easydl_worker_mfu", "Worker-reported model-FLOP utilisation "
@@ -652,6 +660,7 @@ class Agent:
             self._state = "idle"
         self._proc = None
         self._quiesce_sent = False
+        self._kill_sent = False
         self._exit0_deadline = None
 
     def _apply(self, directive: pb.Directive) -> None:
@@ -673,7 +682,13 @@ class Agent:
             # port). Re-applying a stale RUN while the master is unreachable
             # would respawn-loop against a dead coordinator.
             if self._applied_key != (m.generation, m.coordinator):
-                self._terminate_worker(graceful=False)
+                if self._kill_worker():
+                    # The old worker must be gone before the new one
+                    # starts — an accelerator belongs to one process — and
+                    # a process that held one takes seconds to die. The
+                    # master repeats this RUN until we apply it; spawn on
+                    # the heartbeat that finds the worker reaped.
+                    return
                 self._spawn(m)
         elif kind == pb.DirectiveKind.QUIESCE:
             if self._proc and self._proc.poll() is None and not self._quiesce_sent:
@@ -683,10 +698,7 @@ class Agent:
                 self._proc.send_signal(signal.SIGUSR1)
                 self._quiesce_sent = True
         elif kind == pb.DirectiveKind.KILL:
-            if self._proc and self._proc.poll() is None:
-                log.info("%s: killing worker", self.agent_id)
-                self._proc.kill()
-                self._proc.wait()
+            self._kill_worker()
         elif kind == pb.DirectiveKind.SHUTDOWN:
             self._terminate_worker(graceful=True)
             self._state = "shutdown"
@@ -717,6 +729,8 @@ class Agent:
         switch is in flight (a RUN consumes or kills it itself)."""
         prep = directive.prepare
         if not prep.world_size or self.agent_id not in prep.hosts:
+            if directive.kind != pb.DirectiveKind.RUN:  # _spawn reads it
+                self._preflight_declined_sig = None
             if (self._preflight is not None
                     and directive.kind == pb.DirectiveKind.NOOP
                     and not prep.world_size):
@@ -727,6 +741,24 @@ class Agent:
         sig = (prep.generation, prep.coordinator)
         if self._preflight_failed_sig == sig:
             return  # this preflight crashed once; don't crash-loop it
+        if self._preflight_declined_sig == sig:
+            return  # declined, and reported ready: this switch stays cold
+        if (self.platform != "cpu" and self._preflight is None
+                and self._proc is not None and self._proc.poll() is None):
+            # An accelerator belongs to one process at a time, and this
+            # host's is held by our own live worker: a preflight (it runs
+            # a real train step) would fail or hang at backend init. Don't
+            # spawn it — the switch will be cold — and report the prepare
+            # as ready (_preflight_ready), since there is nothing the
+            # master could wait for here.
+            self._preflight_declined_sig = sig
+            timeline.emit(self.timeline_path, "preflight_skipped",
+                          prep.generation, reason="device_held")
+            log.info("%s: no preflight for gen %d: this host's %s is held "
+                     "by the live worker (pid %d); the switch will be cold",
+                     self.agent_id, prep.generation, self.platform,
+                     self._proc.pid)
+            return
         if self._preflight is not None:
             if self._preflight[2] == sig:
                 if self._preflight[0].poll() is None:
@@ -774,7 +806,9 @@ class Agent:
         """Coordinator of the ready preflight ("" when none) — reported in
         heartbeats so the master knows when to start the drain."""
         if self._preflight is None:
-            return ""
+            # A declined preflight (device held) is as ready as it gets.
+            declined = self._preflight_declined_sig
+            return declined[1] if declined is not None else ""
         proc, go_file, sig, _ = self._preflight
         if proc.poll() is not None:
             return ""
@@ -940,10 +974,14 @@ class Agent:
             not preflight_hit
             and self.warm_start and self._warm and self._warm[0].poll() is None
         )
+        declined, self._preflight_declined_sig = (
+            self._preflight_declined_sig, None)
         timeline.emit(
             self.timeline_path, "spawn", m.generation,
             mode="preflight" if preflight_hit
             else ("warm" if warm_hit else "cold"),
+            **({"reason": "device_held"}
+               if declined is not None and not preflight_hit else {}),
         )
         if preflight_hit:
             proc, go_file, sig, log_file = self._preflight
@@ -992,11 +1030,27 @@ class Agent:
         self._warm_due = self.warm_start
         self._applied_key = (m.generation, m.coordinator)
         self._state = "running"
+        self._kill_sent = False
         log.info(
             "%s: %s rank %d/%d gen %d (pid %d)",
             self.agent_id, promoted, rank, m.world_size, m.generation,
             self._proc.pid,
         )
+
+    def _kill_worker(self) -> bool:
+        """SIGKILL the live worker WITHOUT waiting for it: True while it is
+        still dying (``_refresh_state`` reaps it). Waiting here would stop
+        the heartbeats for as long as the kernel takes to tear the process
+        down — ~5 s for one that held a TPU, which is the master's whole
+        loss timeout, so every switch read as a lost agent."""
+        if self._proc is None or self._proc.poll() is not None:
+            return False
+        if not self._kill_sent:
+            log.info("%s: killing worker (pid %d)", self.agent_id,
+                     self._proc.pid)
+            self._proc.kill()
+            self._kill_sent = True
+        return True
 
     def _terminate_worker(self, graceful: bool) -> None:
         if self._proc and self._proc.poll() is None:
@@ -1036,7 +1090,11 @@ def main() -> None:  # pragma: no cover - CLI entry
                         "trainer publishes the master)")
     p.add_argument("--workdir", required=True)
     p.add_argument("--slots", type=int, default=1)
-    p.add_argument("--platform", default="cpu")
+    p.add_argument("--platform", default=None,
+                   help="'cpu' forces workers onto a --slots-device CPU "
+                        "platform; anything else leaves them the host's "
+                        "accelerator (default: what JAX_PLATFORMS says, "
+                        "else tpu)")
     p.add_argument("--warm-start", action="store_true",
                    help="keep a jax-preimported standby worker per agent "
                         "(faster recovery/reshape at one idle process cost)")

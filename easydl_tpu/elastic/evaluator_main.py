@@ -62,12 +62,7 @@ def main() -> None:
         # instead of evaluating a model with missing parameters.
         raise SystemExit("evaluator does not support embedding='ps' jobs")
 
-    import jax  # noqa: F401  (backend init order matters)
-
-    from easydl_tpu.utils.env import pin_cpu_platform_if_requested
-
-    pin_cpu_platform_if_requested()
-
+    import jax
     import optax
 
     from easydl_tpu.core import MeshSpec, Trainer, TrainConfig, build_mesh

@@ -134,6 +134,11 @@ class Master:
     """Runs the rendezvous over gRPC + background lost-agent ticking +
     (optionally) the Brain plan-polling loop."""
 
+    #: seconds between ticks, and how far past that one may run before the
+    #: process is taken to have been paused (see _tick_loop)
+    TICK_S = 0.2
+    PAUSE_S = 1.0
+
     def __init__(
         self,
         job_name: str,
@@ -461,8 +466,21 @@ class Master:
     def _tick_loop(self) -> None:
         last_phase = None
         phase_since = time.monotonic()
+        last_tick = time.monotonic()
         while not self._stop.is_set():
+            # A loop that should turn every TICK_S and stood still for
+            # seconds means this whole process was not running: a stopped
+            # VM, or a host frozen while a TPU runtime starts (4-8 s at a
+            # time, measured on a v5e host without transparent hugepages,
+            # where it read as a lost agent at every worker start). The
+            # agents cannot be blamed for heartbeats nobody could receive.
+            now = time.monotonic()
+            paused, last_tick = now - last_tick - self.TICK_S, now
             with self._lock:
+                if paused > self.PAUSE_S:
+                    log.warning("master did not run for %.1fs; not counting "
+                                "it against the agents' heartbeats", paused)
+                    self.rendezvous.forgive_pause(paused)
                 self.rendezvous.tick()
                 self._maybe_evict_straggler()
                 self._maybe_mesh_reshape()
@@ -494,7 +512,7 @@ class Master:
                 # host changes) lands on disk within one tick.
                 self._persist_if_stale()
                 self._trace_maybe_close_switch(phase)
-            self._stop.wait(0.2)
+            self._stop.wait(self.TICK_S)
 
     # ---------------------------------------------------------------- tracing
     def _members_all_running(self) -> bool:

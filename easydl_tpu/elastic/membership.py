@@ -324,6 +324,15 @@ class Rendezvous:
         self._evaluate()
         return self.directive_for(agent_id)
 
+    def forgive_pause(self, seconds: float) -> None:
+        """The process that hosts this rendezvous did not run for
+        ``seconds`` (the caller measured its own loop standing still): no
+        heartbeat could have been received in that window, so its silence
+        says nothing about the agents — move every last-heard time forward
+        by it."""
+        for a in self.agents.values():
+            a.last_heartbeat += seconds
+
     def tick(self, now: Optional[float] = None) -> None:
         """Advance time: mark lost agents, re-evaluate."""
         now = now if now is not None else self._clock()

@@ -141,28 +141,16 @@ def run_worker(env: Dict[str, str]) -> int:
 
     import jax
 
-    from easydl_tpu.utils.env import pin_cpu_platform_if_requested
+    from easydl_tpu.utils.env import configure_compile_cache
 
-    pin_cpu_platform_if_requested()
     # Persistent compilation cache shared across generations: every
     # membership change rebuilds the trainer and re-jits, and without this
     # the recompile dominates recovery time (SURVEY.md §7 hard part 1).
-    # Thresholds at 0 so even fast test-scale compiles are cached.
-    # EASYDL_COMPILE_CACHE=off/0/none DISABLES it: on some kernels (this
-    # container's 4.4 era) deserializing a cache entry another process
-    # wrote segfaults XLA:CPU — the chaos harness runs drills with the
-    # cache off so every respawn pays a clean compile instead of SIGSEGV.
-    cache_dir = knob_str(
-        "EASYDL_COMPILE_CACHE", os.path.join(workdir, "jax_cache")
-    )
-    if cache_dir.strip().lower() not in ("", "off", "0", "none", "disabled"):
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except (AttributeError, KeyError, ValueError):
-            pass  # older jax without these knobs: best-effort
+    # One fixed directory for every generation and every job of this
+    # checkout (utils/env.py); EASYDL_COMPILE_CACHE=off disables it — the
+    # chaos harness runs drills that way so every respawn pays a clean
+    # compile.
+    configure_compile_cache()
     timeline.emit(tl_path, "jax_imported", generation, rank=rank)
     if world > 1:
         with tracing.start_span("dist_init", parent=root_span,
@@ -189,6 +177,8 @@ def run_worker(env: Dict[str, str]) -> int:
     log = get_logger("elastic", f"worker-r{rank}")
 
     devices = jax.device_count()
+    log.info("gen %d: device: %s (%s) x%d", generation,
+             jax.devices()[0].platform, jax.devices()[0].device_kind, devices)
     mesh_key = knob_raw("EASYDL_MESH", env=env)
     if mesh_key:
         # The master's mesh-shape policy decided this generation's
@@ -562,15 +552,18 @@ def run_worker(env: Dict[str, str]) -> int:
     # per-step record carries it when the model publishes a FLOP hint, the
     # agent bridges it to the easydl_worker_mfu gauge, and the Brain's
     # mesh-shape policy reads the throughput it normalises. Peak resolved
-    # once — unknown chips warn loudly here, at worker start, not once per
-    # step.
+    # once, at worker start — an unknown TPU kind raises here. Off the TPU
+    # there is no chip peak to normalise by, so no mfu is stamped (a CPU
+    # number must not travel under a device metric's name) unless the
+    # operator states a peak.
     from easydl_tpu.core.mfu import peak_flops_per_chip
 
     flops_per_sample = float(getattr(bundle, "flops_per_sample_hint", 0.0))
-    mfu_denom = (
-        devices * peak_flops_per_chip(jax.devices()[0].device_kind)
-        if flops_per_sample > 0 else 0.0
-    )
+    device = jax.devices()[0]
+    mfu_denom = 0.0
+    if flops_per_sample > 0 and (
+            device.platform == "tpu" or knob_raw("EASYDL_CHIP_PEAK_TFLOPS")):
+        mfu_denom = devices * peak_flops_per_chip(device.device_kind)
     mesh_key_out = mesh_spec.key()
 
     def append_metrics(step: int, loss: float, dt: float) -> None:
@@ -586,8 +579,8 @@ def run_worker(env: Dict[str, str]) -> int:
             "t": time.time(),
         }
         if mfu_denom > 0:
-            # 8 decimals, matching bench.py: CPU-smoke MFUs are ~1e-5 and
-            # a 6-decimal round quantizes the compile step to a flat 0.0
+            # 8 decimals, matching bench.py: the compile step's MFU is
+            # ~1e-5 and a 6-decimal round quantizes it to a flat 0.0
             rec["mfu"] = round(rate * flops_per_sample / mfu_denom, 8)
         with open(metrics_path, "a") as f:
             f.write(json.dumps(rec) + "\n")
@@ -723,9 +716,6 @@ def _warm_wait(warm_file: str) -> Dict[str, str]:
 
     import jax  # noqa: F401  (the import IS the work)
 
-    from easydl_tpu.utils.env import pin_cpu_platform_if_requested
-
-    pin_cpu_platform_if_requested()
     # Pre-import the rest of the training stack too: the RECOVERY.json
     # decomposition shows a multi-second "trainer build" phase after
     # promotion that is mostly first-touch module imports (optax, the

@@ -98,9 +98,11 @@ def main() -> None:
 
     import jax
 
-    from easydl_tpu.utils.env import pin_cpu_platform_if_requested
+    from easydl_tpu.utils.env import configure_compile_cache
+    from easydl_tpu.utils.profiling import CompileWatch, peak_device_bytes
 
-    pin_cpu_platform_if_requested()
+    cache_dir = configure_compile_cache()
+    compiles = CompileWatch()
 
     import optax
 
@@ -126,6 +128,9 @@ def main() -> None:
 
     pp = max(args.pp, 1)
     n_dev = jax.device_count()
+    log.info("device: %s (%s) x%d; compile cache: %s",
+             jax.devices()[0].platform, jax.devices()[0].device_kind, n_dev,
+             cache_dir or "off")
     if pp > 1 and (n_dev < pp or n_dev % pp):
         # fail here with the cause, not later with an empty/truncated mesh
         ap.error(f"--pp {pp} needs a device count divisible by it "
@@ -162,10 +167,15 @@ def main() -> None:
         ev.run(poll_interval_s=2.0, max_evals=args.eval_polls or None)
         return
 
-    state = trainer.init_state()
     if ckpt is not None and ckpt.latest_step() is not None:
+        # Restore INSTEAD of init, not on top of it: a second full state
+        # held through the restore is what overflows a chip the first one
+        # already fills.
         state = trainer.restore_from(ckpt)
         log.info("resumed from step %d", state.int_step)
+    else:
+        state = trainer.init_state()
+    first_step = state.int_step + 1
     source = None
     if args.data_dir:
         source = file_data(args, bundle)
@@ -208,9 +218,11 @@ def main() -> None:
             rec = recorder.end_step(step, float(metrics["loss"]))
             if profiler is not None:
                 profiler.maybe_stop(step - 1)
-            if step % 10 == 0 or step == args.steps:
+            if step % 10 == 0 or step in (first_step, args.steps):
                 log.info("step %d loss %.4f (%.1f samples/s)", step, rec.loss,
                          rec.samples_per_sec)
+            if step == first_step:
+                log.info("first step done: %s", json.dumps(compiles.summary()))
             if ckpt is not None and (step % args.ckpt_every == 0 or step == args.steps):
                 ckpt.save(step, state, metadata=(
                     {"data_state": source.state()} if source is not None
@@ -226,6 +238,9 @@ def main() -> None:
             profiler.close()
     if ckpt is not None:
         ckpt.wait()
+    peak = peak_device_bytes()
+    if peak is not None:
+        log.info("peak device memory: %d bytes", peak)
 
 
 if __name__ == "__main__":

@@ -3,6 +3,14 @@
 ``impl="auto"`` picks the Pallas flash kernel on TPU (large HBM win: the
 [B,H,S,S] score matrix never materialises) and the XLA reference path
 elsewhere; models call :func:`multihead_attention` and never care which runs.
+Which one ran is never a guess: the choice, and every drop from the kernel
+to the reference, is logged once per process with its reason.
+
+Under a mesh of more than one device the kernel runs per shard: Mosaic
+kernels cannot be partitioned by GSPMD, so :func:`multihead_attention`
+wraps the call in ``jax.shard_map`` over the context mesh (the one
+``Trainer`` enters with ``jax.set_mesh``) — batch over ``dp``/``fsdp``,
+heads over ``tp``.
 
 Shapes follow the [batch, seq, heads, head_dim] convention throughout (the
 layout XLA prefers for TPU attention: contraction dims innermost).
@@ -11,10 +19,21 @@ layout XLA prefers for TPU attention: contraction dims innermost).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from easydl_tpu.core.mesh_shapes import BATCH_AXES
+from easydl_tpu.ops.flash_attention import flash_attention
+from easydl_tpu.utils.logging import get_logger, log_once
+
+log = get_logger("ops", "attention")
+
+#: mesh axis the heads dimension is sharded over (core/sharding.py rules).
+HEAD_AXIS = "tp"
 
 
 def _reference_attention(
@@ -52,6 +71,41 @@ def _reference_attention(
     return out
 
 
+def _per_shard(fn, q: jax.Array):
+    """Wrap ``fn(q, k, v)`` in ``jax.shard_map`` over the context mesh when
+    that mesh spans more than one device, else return it unchanged.
+
+    Batch is split over the mesh's batch axes and heads over ``tp`` where
+    the sizes divide; an axis that does not divide (the batch-1 trace inside
+    ``model.init``) is left out of the specs, so those devices compute the
+    whole of it — still the kernel, still no GSPMD partitioning of it. Axes
+    that are already manual (a caller's own ``shard_map``: the pipeline,
+    Ulysses) are per shard already."""
+    mesh = jax.sharding.get_abstract_mesh()
+    free = [a for a in mesh.axis_names
+            if a not in mesh.manual_axes and mesh.shape[a] > 1]
+    if not free:
+        return fn
+    batch = tuple(a for a in BATCH_AXES if a in free)
+    heads = HEAD_AXIS if HEAD_AXIS in free else None
+    if batch and q.shape[0] % math.prod(mesh.shape[a] for a in batch):
+        if q.shape[0] > 1:
+            log_once(
+                log,
+                f"attention: batch {q.shape[0]} does not divide over mesh "
+                f"axes {batch}; every shard computes the whole batch")
+        batch = ()
+    if heads and q.shape[2] % mesh.shape[heads]:
+        log_once(
+            log,
+            f"attention: {q.shape[2]} heads do not divide over "
+            f"{heads}={mesh.shape[heads]}; every shard computes all heads")
+        heads = None
+    spec = P(batch or None, None, heads, None)
+    return jax.shard_map(fn, in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)
+
+
 @functools.partial(
     jax.named_call, name="multihead_attention"
 )
@@ -73,17 +127,24 @@ def multihead_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if impl == "auto":
-        on_tpu = jax.devices()[0].platform == "tpu"
-        impl = "flash" if on_tpu else "reference"
+        platform = jax.devices()[0].platform
+        impl = "flash" if platform == "tpu" else "reference"
+        if impl == "reference":
+            log_once(
+                log,
+                f"attention: XLA reference path (impl=auto on platform "
+                f"{platform!r}, the Pallas flash kernel needs a tpu)")
     if impl == "flash":
-        try:
-            from easydl_tpu.ops.flash_attention import flash_attention
-
+        if segment_ids is not None:
+            # flash_attention logs this drop itself; the reference path it
+            # takes partitions under GSPMD, so no per-shard wrap.
             return flash_attention(
                 q, k, v, causal=causal, scale=scale, segment_ids=segment_ids
             )
-        except ImportError:
-            impl = "reference"
+        kernel = functools.partial(flash_attention, causal=causal, scale=scale)
+        return _per_shard(kernel, q)(q, k, v)
+    if impl != "reference":
+        raise ValueError(f"unknown attention impl {impl!r}")
     return _reference_attention(
         q, k, v, causal=causal, scale=scale, segment_ids=segment_ids
     )

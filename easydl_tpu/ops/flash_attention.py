@@ -15,8 +15,9 @@ dq kernel loops K-blocks, a dk/dv kernel loops Q-blocks; the rowwise
 Public shapes: [batch, seq, heads, head_dim] (the models' layout); kernels
 run on a [batch·heads, seq, head_dim] view.
 
-On non-TPU backends the kernels run in interpreter mode so unit tests can
-check numerics against the XLA reference path without hardware.
+The kernels are compiled by Mosaic, which needs a TPU. ``interpret=True``
+runs them in the Pallas interpreter instead — something only a test passes,
+to check numerics against the XLA reference path without hardware.
 """
 
 from __future__ import annotations
@@ -28,11 +29,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from easydl_tpu.utils.logging import get_logger, log_once
+
+log = get_logger("ops", "flash_attention")
+
 NEG_INF = float(jnp.finfo(jnp.float32).min)
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pick_block(s: int, target: int) -> Optional[int]:
@@ -350,29 +351,35 @@ def flash_attention(
     segment_ids: Optional[jax.Array] = None,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] tensors.
 
-    Falls back to the XLA reference path when the kernel can't tile the
-    sequence lengths (no block divisor) or a segment mask is requested."""
+    Drops to the XLA reference path when the kernel can't tile the sequence
+    lengths (no block divisor) or a segment mask is requested; each such
+    drop is logged once with its reason."""
     b, s, h, d = q.shape
     s_k = k.shape[1]
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     bq = _pick_block(s, block_q)
     bk = _pick_block(s_k, block_k)
     if segment_ids is not None or bq is None or bk is None:
         from easydl_tpu.ops.attention import _reference_attention
 
+        why = ("segment mask requested" if segment_ids is not None else
+               f"lengths q={s} k={s_k} have no block divisor <= "
+               f"{block_q}/{block_k}")
+        log_once(log, f"flash attention: XLA reference path, not the "
+                      f"kernel: {why}")
         return _reference_attention(
-            q, k, v, causal=causal,
-            scale=scale if scale is not None else q.shape[-1] ** -0.5,
-            segment_ids=segment_ids,
+            q, k, v, causal=causal, scale=scale, segment_ids=segment_ids,
         )
     block_q, block_k = bq, bk
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        interpret = _interpret_default()
+    device = jax.devices()[0]
+    how = "INTERPRETED" if interpret else "compiled"
+    log_once(log, f"flash attention: {how} Pallas kernel on "
+                  f"{device.platform} ({device.device_kind})")
     # [B, S, H, d] -> [B*H, S, d]
     def to_bh(x, sl):
         return jnp.swapaxes(x, 1, 2).reshape(b * h, sl, d)

@@ -56,8 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from easydl_tpu.ops._compat import shard_map
-
 
 def pipeline_ticks(microbatches: int, pp: int) -> int:
     """Static trip count of the schedule's scan: ``m`` work ticks plus the
@@ -174,7 +172,7 @@ def pipeline_blocks(mesh: Mesh, apply_stage: Callable, stage_params: Any,
     stage_apply = jax.checkpoint(apply_stage) if remat else apply_stage
 
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_spec, batch_spec),
         out_specs=batch_spec,
         check_vma=False,

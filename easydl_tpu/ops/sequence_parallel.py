@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from easydl_tpu.ops._compat import shard_map
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -186,7 +184,7 @@ def make_sp_attention(
                     ulysses_attention, axis_name=axis, causal=is_causal,
                     scale=scale, impl=impl,
                 )
-            sharded_cache[is_causal] = shard_map(
+            sharded_cache[is_causal] = jax.shard_map(
                 lambda q, k, v: inner(q, k, v),
                 mesh=mesh,
                 in_specs=(spec, spec, spec),

@@ -40,6 +40,20 @@ def get_logger(component: str, role: Optional[str] = None) -> logging.Logger:
     return logging.getLogger(name)
 
 
+_logged_once: set = set()
+
+
+def log_once(logger: logging.Logger, message: str) -> None:
+    """Log ``message`` at INFO the first time this process says it.
+
+    For choices made at trace time (which attention path, which device):
+    a jitted function retraces, the statement stays one line, and a run's
+    log can be checked for it."""
+    if (logger.name, message) not in _logged_once:
+        _logged_once.add((logger.name, message))
+        logger.info("%s", message)
+
+
 class StepTimer:
     """Cheap wall-clock step timer used by the trainer's metrics loop."""
 

@@ -53,6 +53,51 @@ def step_annotation(name: str, step: Optional[int] = None):
     return jax.profiler.TraceAnnotation(name)
 
 
+class CompileWatch:
+    """What this process has spent compiling, from ``jax.monitoring``.
+
+    ``seconds`` sums every XLA compile *or* persistent-cache retrieval (the
+    backend-compile event wraps both), so the same counter read in a cold
+    process and in one that found the cache gives the cold and the warm
+    compile time; ``saved`` sums what the cache's answers would have cost to
+    compile (each entry records it); ``hits``/``misses`` count them.
+    Construct before the first compile."""
+
+    def __init__(self) -> None:
+        self.seconds = 0.0
+        self.saved = 0.0
+        self.hits = 0
+        self.misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += seconds
+        elif event == "/jax/compilation_cache/compile_time_saved_sec":
+            self.saved += seconds
+
+    def _event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def summary(self) -> dict:
+        return {"compile_s": round(self.seconds, 3),
+                "cache_saved_s": round(self.saved, 3),
+                "cache_hits": self.hits, "cache_misses": self.misses}
+
+
+def peak_device_bytes() -> Optional[int]:
+    """Largest ``peak_bytes_in_use`` over this process's devices, or None
+    where the backend keeps no memory statistics (the CPU)."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
+
+
 class StepProfiler:
     """Window-triggered tracing inside a training loop: skips compile/warmup
     steps and captures exactly ``num_steps`` steady-state steps."""

@@ -381,6 +381,9 @@ def test_preflight_crash_falls_back_to_plain_drain(workdir):
             "import os, sys\n"
             "if os.environ.get('EASYDL_GO_FILE'):\n"
             "    sys.exit(9)\n"
+            # a script's sys.path[0] is its own directory, not the cwd the
+            # package is imported from when it is not installed
+            "sys.path.insert(0, os.getcwd())\n"
             "from easydl_tpu.elastic.worker import main\n"
             "main()\n"
         )

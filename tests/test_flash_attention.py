@@ -164,9 +164,19 @@ def test_bf16_inputs():
     )
 
 
-def test_inside_jitted_train_step():
-    """Flash path composes with jit + grad in a real model step."""
+def test_inside_jitted_train_step(monkeypatch):
+    """Flash path composes with jit + grad in a real model step — on a
+    2-device mesh, so the kernel runs per shard inside ``jax.shard_map``."""
+    import functools
+
     import optax
+
+    from easydl_tpu.ops import attention
+
+    # No chip here: the test, not the program, asks for the interpreter.
+    monkeypatch.setattr(
+        attention, "flash_attention",
+        functools.partial(attention.flash_attention, interpret=True))
 
     from easydl_tpu.core.mesh import MeshSpec
     from easydl_tpu.core.train_loop import TrainConfig, Trainer

@@ -3,6 +3,8 @@
 
 import itertools
 
+import pytest
+
 from easydl_tpu.elastic.membership import AgentState, JobPhase, Rendezvous
 
 ports = itertools.count(9000)
@@ -102,6 +104,23 @@ def test_unplanned_member_loss():
     assert rdv.phase == JobPhase.STABLE and rdv.generation == gen + 1
     d = rdv.directive_for("a0")
     assert d.kind == "run" and d.world_size == 1 and d.hosts == ("a0",)
+
+
+@pytest.mark.parametrize("forgiven", [False, True])
+def test_silence_while_the_master_itself_was_paused_is_forgiven(forgiven):
+    """8 s without a heartbeat loses an agent (timeout 5 s) — unless the
+    master's own loop stood still for those 8 s (a frozen host: measured
+    on a v5e VM at every TPU runtime start) and says so."""
+    clock = {"t": 0.0}
+    rdv = mk(desired=1, heartbeat_timeout=5.0, clock=lambda: clock["t"])
+    gen = start_gen(rdv, ["a0"])
+    clock["t"] = 8.0
+    if forgiven:
+        rdv.forgive_pause(8.0)
+    rdv.tick()
+    lost = rdv.agents["a0"].state == AgentState.LOST
+    assert lost != forgiven
+    assert rdv.generation == gen  # (a lost sole member cannot re-form)
 
 
 def test_worker_crash_triggers_unplanned_reshape():
