@@ -1,0 +1,20 @@
+"""model: model FLOP/s utilisation — tokens per second of this run times the
+training FLOPs a token needs (lib/flops.py, recomputation not counted), over
+chips times the published bf16 peak (lib/peaks.py)."""
+
+from lib import flops, peaks
+
+
+def read(artifacts):
+    # Off the chip there is no peak to hold a rate against (and a TPU of a
+    # kind the table lacks is an error, raised below).
+    if "step_s" not in artifacts or artifacts["device"]["platform"] != "tpu":
+        return None
+    config = artifacts["config"]
+    per_token = flops.train_flops_per_token(
+        artifacts["n_params"], config["n_layer"], config["n_embd"],
+        config["kwargs"]["seq_len"])
+    rate = artifacts["steps"] * artifacts["tokens_per_step"] \
+        / artifacts["window_s"]
+    peak = peaks.peak(artifacts["device"]["kind"], "bf16_flops_per_s")
+    return 100.0 * rate * per_token / (artifacts["chips"] * peak)
