@@ -37,7 +37,7 @@ import re
 import threading
 import time
 import uuid
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -186,8 +186,21 @@ class CheckpointManager:
     """
 
     def __init__(self, directory: str, keep: int = 3, async_save: bool = True,
-                 storage: Optional[CheckpointStorage] = None):
+                 storage: Optional[CheckpointStorage] = None,
+                 on_event: Optional[Callable[..., None]] = None):
         self.directory = directory
+        #: ``on_event(name, **data)`` is told where a save stands:
+        #: ``ckpt_snapshot_done`` (the synchronous device-to-host copies
+        #: are over and the caller's step loop goes on: ``seconds`` they
+        #: took, ``waited_s`` spent before them on the save before this
+        #: one, ``bytes``, ``leaves``), ``ckpt_chunks_written`` (every chunk
+        #: of this process is in storage: ``seconds`` the writing took,
+        #: ``bytes``) and ``ckpt_committed`` (the marker is written:
+        #: ``seconds`` since ``save`` was entered). Each carries ``step``.
+        #: The last two come from the IO thread of an async save. The
+        #: elastic worker puts them on its timeline; a callback that raises
+        #: fails the save like any other error in it.
+        self._on_event = on_event
         self.keep = keep
         self.async_save = async_save
         self.storage = storage if storage is not None else get_storage(directory)
@@ -214,7 +227,9 @@ class CheckpointManager:
         NOT needed — the snapshot happens here, synchronously. In
         multi-process runs an async save defers its commit barrier: call
         :meth:`finalize` each step (all ranks together) to complete it."""
+        t_enter = time.perf_counter()
         self.wait()
+        waited_s = time.perf_counter() - t_enter
         storage = self.storage
         multiproc = jax.process_count() > 1
         # Skip if already committed (e.g. quiesce landing on a periodic-save
@@ -243,6 +258,7 @@ class CheckpointManager:
             cache_token = bytes(
                 np.asarray(multihost_utils.broadcast_one_to_all(raw))
             ).decode().strip()
+        t_snapshot = time.perf_counter()
         leaves = jax.tree_util.tree_flatten_with_path(state)[0]
         snapshot = []  # (leaf_idx, keystr, global_shape, dtype, [(bounds, np.ndarray)])
         for i, (path, leaf) in enumerate(leaves):
@@ -262,6 +278,12 @@ class CheckpointManager:
                      [(tuple(slice(0, d) for d in arr.shape), arr)])
                 )
 
+        snapshot_bytes = sum(data.nbytes for _, _, _, _, chunks in snapshot
+                             for _, data in chunks)
+        self._event("ckpt_snapshot_done", step=step,
+                    seconds=time.perf_counter() - t_snapshot,
+                    waited_s=waited_s, bytes=snapshot_bytes,
+                    leaves=len(snapshot))
         t0 = time.perf_counter()
         step_dir = f"step_{step:08d}"
         # POSIX: stage in a per-process tmp dir, commit by rename.
@@ -310,6 +332,9 @@ class CheckpointManager:
                 storage.write_bytes(
                     f"{write_dir}/manifest.json", json.dumps(manifest).encode()
                 )
+            self._event("ckpt_chunks_written", step=step,
+                        seconds=time.perf_counter() - t0,
+                        bytes=snapshot_bytes)
 
         def commit():
             # Contains the collective barriers — must run on the MAIN thread
@@ -357,6 +382,8 @@ class CheckpointManager:
                 storage.write_bytes(f"{step_dir}/{_COMMITTED}", str(step).encode())
             log.info("saved step %d in %.2fs -> %s/%s",
                      step, time.perf_counter() - t0, self.directory, step_dir)
+            self._event("ckpt_committed", step=step,
+                        seconds=time.perf_counter() - t_enter)
             self._gc()
             if self.cache is not None:
                 self.cache.gc()
@@ -380,6 +407,10 @@ class CheckpointManager:
         else:
             write_chunks()
             commit()
+
+    def _event(self, name: str, **data: Any) -> None:
+        if self._on_event is not None:
+            self._on_event(name, **data)
 
     def _uncommitted_debris(self, step_dir: str) -> bool:
         return (

@@ -131,6 +131,7 @@ class Trainer:
         self._state_shardings: Any = None
         self._step_fn = None
         self._abstract: Any = None
+        self._host_step = 0  # train_step calls so far (trace annotation)
 
     # ------------------------------------------------------------------ init
     def _abstract_state(self) -> TrainState:
@@ -199,8 +200,13 @@ class Trainer:
         loss_fn = self.loss_fn
         optimizer = self.optimizer
 
+        # The named scopes below are metadata on the compiled program's
+        # operations (their op_name path): the device trace's readers tell
+        # cast, accumulation, optimizer and gradient norm apart by them.
         def forward(params, batch, rng):
-            loss, aux = loss_fn(cast_floating(params, compute_dtype), batch, rng)
+            with jax.named_scope("cast_params"):
+                params = cast_floating(params, compute_dtype)
+            loss, aux = loss_fn(params, batch, rng)
             return loss.astype(jnp.float32), aux
 
         grad_fn = jax.value_and_grad(forward, has_aux=True)
@@ -220,11 +226,12 @@ class Trainer:
                 loss_sum, aux_sum, grad_sum = carry
                 mb, i = xs
                 loss, aux, grads = single(params, mb, jax.random.fold_in(rng, i))
-                return (
-                    loss_sum + loss,
-                    jax.tree.map(jnp.add, aux_sum, aux),
-                    jax.tree.map(jnp.add, grad_sum, grads),
-                ), None
+                with jax.named_scope("accumulate"):
+                    return (
+                        loss_sum + loss,
+                        jax.tree.map(jnp.add, aux_sum, aux),
+                        jax.tree.map(jnp.add, grad_sum, grads),
+                    ), None
 
             loss0, aux0, grads0 = single(
                 params, jax.tree.map(lambda x: x[0], microbatches), jax.random.fold_in(rng, 0)
@@ -235,11 +242,12 @@ class Trainer:
                 unroll=max(self.config.accum_unroll, 1),
             )
             scale = 1.0 / accum
-            return (
-                loss_sum * scale,
-                jax.tree.map(lambda a: a * scale, aux_sum),
-                jax.tree.map(lambda g: g * scale, grad_sum),
-            )
+            with jax.named_scope("accumulate"):
+                return (
+                    loss_sum * scale,
+                    jax.tree.map(lambda a: a * scale, aux_sum),
+                    jax.tree.map(lambda g: g * scale, grad_sum),
+                )
 
         def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, jax.Array]]:
             step_rng = jax.random.fold_in(state.rng, state.step)
@@ -247,13 +255,12 @@ class Trainer:
                 loss, aux, grads = accumulated(state.params, batch, step_rng)
             else:
                 loss, aux, grads = single(state.params, batch, step_rng)
-            updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
-            metrics = {
-                "loss": loss,
-                "grad_norm": optax.global_norm(grads),
-                **aux,
-            }
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("grad_norm"):
+                grad_norm = optax.global_norm(grads)
+            metrics = {"loss": loss, "grad_norm": grad_norm, **aux}
             new_state = state.replace(
                 step=state.step + 1,
                 params=new_params,
@@ -285,7 +292,18 @@ class Trainer:
         )
 
     def train_step(self, state: TrainState, host_batch: Any):
-        return self.step_fn(state, self.shard_batch(host_batch))
+        """One step from a host batch. The annotations cost nothing without
+        a profiler session; inside one they are host spans on the device
+        trace's own clock. The step number is this Trainer's count of calls
+        — never a fetch from the device."""
+        with jax.profiler.StepTraceAnnotation("train_step",
+                                              step_num=self._host_step):
+            with jax.profiler.TraceAnnotation("easydl/shard_batch"):
+                batch = self.shard_batch(host_batch)
+            with jax.profiler.TraceAnnotation("easydl/dispatch"):
+                out = self.step_fn(state, batch)
+        self._host_step += 1
+        return out
 
     # ------------------------------------------------------------------ eval
     def build_eval_step(self, eval_fn: LossFn):

@@ -187,6 +187,9 @@ class Agent:
             "buffered during the current/last master outage.", ("agent",))
         self._hb_times: Deque[float] = collections.deque(maxlen=20)
         self._tl_last: Optional[tuple] = None  # (phase, monotonic t)
+        # ((generation, coordinator), unix t) of the newest RUN directive
+        # when it was first seen; rides the spawn record as directive_t
+        self._run_seen: tuple = (None, 0.0)
         # The master's open generation-switch context (from directive-reply
         # trailing metadata): parents this agent's switch-leg spans and is
         # handed to spawned workers via EASYDL_TRACE_CONTEXT so worker
@@ -657,6 +660,11 @@ class Agent:
         else:
             if self._state == "running":
                 log.warning("%s: worker exited unexpectedly (code %s)", self.agent_id, code)
+            # kill -> here is the reaping (seconds for a process that held a
+            # TPU); here -> the next `spawn` is the report to the master,
+            # its decision and this agent's own work.
+            timeline.emit(self.timeline_path, "worker_crash",
+                          self._applied_key[0], code=code)
             self._state = "idle"
         self._proc = None
         self._quiesce_sent = False
@@ -682,6 +690,9 @@ class Agent:
             # port). Re-applying a stale RUN while the master is unreachable
             # would respawn-loop against a dead coordinator.
             if self._applied_key != (m.generation, m.coordinator):
+                if self._run_seen[0] != (m.generation, m.coordinator):
+                    self._run_seen = ((m.generation, m.coordinator),
+                                      time.time())
                 if self._kill_worker():
                     # The old worker must be gone before the new one
                     # starts — an accelerator belongs to one process — and
@@ -976,10 +987,14 @@ class Agent:
         )
         declined, self._preflight_declined_sig = (
             self._preflight_declined_sig, None)
+        # directive_t: when this generation's RUN was first seen — data on
+        # the spawn record, not a phase of its own (the legs above are
+        # measured between consecutive phases).
         timeline.emit(
             self.timeline_path, "spawn", m.generation,
             mode="preflight" if preflight_hit
             else ("warm" if warm_hit else "cold"),
+            directive_t=self._run_seen[1],
             **({"reason": "device_held"}
                if declined is not None and not preflight_hit else {}),
         )

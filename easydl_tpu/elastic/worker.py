@@ -162,6 +162,18 @@ def run_worker(env: Dict[str, str]) -> int:
                 process_id=rank,
             )
     timeline.emit(tl_path, "dist_init_done", generation, rank=rank)
+    # The first touch of the backend, with nothing else between the two
+    # boundaries: on a TPU host this is the runtime's start (and the host's
+    # standstill). What follows up to trainer_built is the training stack's
+    # imports, mesh, model and Trainer.
+    devices = jax.device_count()
+    timeline.emit(tl_path, "devices_ready", generation, rank=rank,
+                  devices=devices)
+    # Made before the Trainer so no compile escapes it; read at `restored`
+    # and at the first step's end (first_step_done carries the difference).
+    from easydl_tpu.utils.profiling import CompileWatch
+
+    compile_watch = CompileWatch()
     from jax.experimental import multihost_utils
 
     import optax
@@ -176,7 +188,6 @@ def run_worker(env: Dict[str, str]) -> int:
 
     log = get_logger("elastic", f"worker-r{rank}")
 
-    devices = jax.device_count()
     log.info("gen %d: device: %s (%s) x%d", generation,
              jax.devices()[0].platform, jax.devices()[0].device_kind, devices)
     mesh_key = knob_raw("EASYDL_MESH", env=env)
@@ -334,7 +345,15 @@ def run_worker(env: Dict[str, str]) -> int:
 
     # Async saves overlap chunk IO with training; the commit barrier runs on
     # this (main) thread via ckpt.finalize() at step boundaries below.
-    ckpt = CheckpointManager(os.path.join(workdir, "ckpt"), keep=3, async_save=True)
+    # The save's own phase boundaries go on the timeline (from the IO thread
+    # for the last two; emit opens, appends and closes, and never raises).
+    def _ckpt_event(name: str, **data: Any) -> None:
+        timeline.emit(tl_path, name, generation, rank=rank, **{
+            k: round(v, 3) if isinstance(v, float) else v
+            for k, v in data.items()})
+
+    ckpt = CheckpointManager(os.path.join(workdir, "ckpt"), keep=3,
+                             async_save=True, on_event=_ckpt_event)
 
     # Chaos hook flag, read once: the straggler injector below costs one
     # None-check per step when a spec is armed, nothing when not.
@@ -442,6 +461,7 @@ def run_worker(env: Dict[str, str]) -> int:
         start_step = 0
         log.info("gen %d: fresh init, world=%d (%d devices)", generation, world, devices)
     timeline.emit(tl_path, "restored", generation, rank=rank, step=start_step)
+    compiled_at_restore = compile_watch.totals()
     first_step_emitted = False
 
     total_steps = int(cfg.get("total_steps", 100))
@@ -666,9 +686,12 @@ def run_worker(env: Dict[str, str]) -> int:
                                 parent=root_span, step=step,
                                 loss=round(loss, 5))
         if not first_step_emitted:
-            # restored -> here = jit compile (or cache hit) + one step.
+            # restored -> here = jit compile (or cache hit) + one step;
+            # the compile counters since `restored` say which: tracing,
+            # lowering, the backend (cache fetch included), hits, misses.
             timeline.emit(tl_path, "first_step_done", generation,
-                          rank=rank, step=step, step_time_s=round(dt, 3))
+                          rank=rank, step=step, step_time_s=round(dt, 3),
+                          **compile_watch.since(compiled_at_restore))
             first_step_emitted = True
 
         # Auto cadence computes next_ckpt from values every rank shares

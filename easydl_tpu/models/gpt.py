@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Dict, Tuple
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -114,9 +115,10 @@ def make_gpt(
             # bf16×f32 dot_general promotes to an f32 matmul, which would
             # take the [B,chunk,V] matmul off the bf16 MXU path.
             head = jnp.asarray(head, dtype=hidden.dtype)
-            loss, _ = fused_softmax_xent(
-                hidden, head, batch["targets"], chunk_size=loss_chunk
-            )
+            with jax.named_scope("lm_head_loss"):
+                loss, _ = fused_softmax_xent(
+                    hidden, head, batch["targets"], chunk_size=loss_chunk
+                )
         else:
             out = model.apply(
                 {"params": params}, batch["inputs"],
@@ -124,7 +126,8 @@ def make_gpt(
             )
             logits = out[0] if mutable else out
             mut = out[1] if mutable else None
-            loss, _ = lm_loss(logits, batch["targets"])
+            with jax.named_scope("loss"):
+                loss, _ = lm_loss(logits, batch["targets"])
         return loss, mut
 
     def loss_fn(params, batch, rng):

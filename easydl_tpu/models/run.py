@@ -17,7 +17,6 @@ Data is synthetic per model bundle, so any config runs hermetically.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 
 
@@ -195,7 +194,7 @@ def main() -> None:
     recorder = MetricsRecorder(args.batch, world_size=dp)
     profiler = None
     if args.profile_dir:
-        from easydl_tpu.utils.profiling import StepProfiler, step_annotation
+        from easydl_tpu.utils.profiling import StepProfiler
 
         # Window relative to the (possibly resumed) first step, so the
         # recompile-after-restore step is skipped just like a cold start's.
@@ -207,13 +206,8 @@ def main() -> None:
             step = state.int_step
             if profiler is not None:
                 profiler.maybe_start(step)
-            annotation = (
-                step_annotation("train", step) if profiler is not None
-                else contextlib.nullcontext()
-            )
             recorder.start_step()
-            with annotation:
-                state, metrics = trainer.train_step(state, next(data))
+            state, metrics = trainer.train_step(state, next(data))
             step = state.int_step
             rec = recorder.end_step(step, float(metrics["loss"]))
             if profiler is not None:

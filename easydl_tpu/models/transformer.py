@@ -154,62 +154,68 @@ class Block(nn.Module):
         dt = jnp.dtype(cfg.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
-        h = _layernorm("ln_attn", dtype=dt)(x)
-        qkv_shape = (cfg.n_heads, cfg.head_dim)
-        q = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
-                   name="q", dtype=dt)(h)
-        k = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
-                   name="k", dtype=dt)(h)
-        v = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
-                   name="v", dtype=dt)(h)
-        q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
-        k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
-        v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
-        if cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
-            attn = cfg.attention_fn(q, k, v, causal=cfg.causal)
-        else:
-            attn = multihead_attention(
-                q, k, v, causal=cfg.causal, impl=cfg.attention_impl
-            )
-        attn = _dense(
-            cfg.d_model,
-            ("heads", "kv", "embed"),
-            ("embed",),
-            name="out",
-            init_scale=(2 * cfg.n_layers) ** -0.5,  # GPT-2 residual scaling
-            axis=(-2, -1),
-            dtype=dt,
-        )(attn)
-        if cfg.dropout and not deterministic:
-            attn = nn.Dropout(cfg.dropout, deterministic=False)(attn)
-        x = x + attn
-
-        h = _layernorm("ln_mlp", dtype=dt)(x)
-        aux = jnp.zeros((), jnp.float32)
-        if cfg.moe_experts:
-            from easydl_tpu.ops.moe import MoeMlp
-
-            h, aux = MoeMlp(
-                num_experts=cfg.moe_experts,
-                d_ff=cfg.d_ff,
-                k=cfg.moe_k,
-                capacity_factor=cfg.moe_capacity_factor,
-                out_init_scale=(2 * cfg.n_layers) ** -0.5,
-                dtype=cfg.dtype,
-                name="moe",
-            )(h)
-        else:
-            h = _dense(cfg.d_ff, ("embed", "mlp"), ("mlp",), name="up",
-                       dtype=dt)(h)
-            h = nn.gelu(h)
-            h = _dense(
-                cfg.d_model, ("mlp", "embed"), ("embed",), name="down",
-                init_scale=(2 * cfg.n_layers) ** -0.5,
+        # The two scopes put every operation of a block, residual adds,
+        # GELU and logical constraints included, under `attention` or `ffn`
+        # in the compiled program's op_name paths (read by the device
+        # trace's reducers); flax's module names sit inside them.
+        with jax.named_scope("attention"):
+            h = _layernorm("ln_attn", dtype=dt)(x)
+            qkv_shape = (cfg.n_heads, cfg.head_dim)
+            q = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
+                       name="q", dtype=dt)(h)
+            k = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
+                       name="k", dtype=dt)(h)
+            v = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
+                       name="v", dtype=dt)(h)
+            q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
+            k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
+            v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
+            if cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
+                attn = cfg.attention_fn(q, k, v, causal=cfg.causal)
+            else:
+                attn = multihead_attention(
+                    q, k, v, causal=cfg.causal, impl=cfg.attention_impl
+                )
+            attn = _dense(
+                cfg.d_model,
+                ("heads", "kv", "embed"),
+                ("embed",),
+                name="out",
+                init_scale=(2 * cfg.n_layers) ** -0.5,  # GPT-2 residual scaling
+                axis=(-2, -1),
                 dtype=dt,
-            )(h)
-        if cfg.dropout and not deterministic:
-            h = nn.Dropout(cfg.dropout, deterministic=False)(h)
-        x = x + h
+            )(attn)
+            if cfg.dropout and not deterministic:
+                attn = nn.Dropout(cfg.dropout, deterministic=False)(attn)
+            x = x + attn
+
+        aux = jnp.zeros((), jnp.float32)
+        with jax.named_scope("ffn"):
+            h = _layernorm("ln_mlp", dtype=dt)(x)
+            if cfg.moe_experts:
+                from easydl_tpu.ops.moe import MoeMlp
+
+                h, aux = MoeMlp(
+                    num_experts=cfg.moe_experts,
+                    d_ff=cfg.d_ff,
+                    k=cfg.moe_k,
+                    capacity_factor=cfg.moe_capacity_factor,
+                    out_init_scale=(2 * cfg.n_layers) ** -0.5,
+                    dtype=cfg.dtype,
+                    name="moe",
+                )(h)
+            else:
+                h = _dense(cfg.d_ff, ("embed", "mlp"), ("mlp",), name="up",
+                           dtype=dt)(h)
+                h = nn.gelu(h)
+                h = _dense(
+                    cfg.d_model, ("mlp", "embed"), ("embed",), name="down",
+                    init_scale=(2 * cfg.n_layers) ** -0.5,
+                    dtype=dt,
+                )(h)
+            if cfg.dropout and not deterministic:
+                h = nn.Dropout(cfg.dropout, deterministic=False)(h)
+            x = x + h
         return nn.with_logical_constraint(x, ("batch", "seq", "embed")), aux
 
 
@@ -330,10 +336,12 @@ class Transformer(nn.Module):
         x = _layernorm("ln_f", dtype=dt)(x)
         if return_hidden:
             return x
-        if cfg.tied_head:
-            logits = tok_emb.attend(x)
-        else:
-            logits = _dense(
-                cfg.vocab, ("embed", "vocab"), (), name="head", use_bias=False
-            )(x)
+        with jax.named_scope("lm_head"):
+            if cfg.tied_head:
+                logits = tok_emb.attend(x)
+            else:
+                logits = _dense(
+                    cfg.vocab, ("embed", "vocab"), (), name="head",
+                    use_bias=False,
+                )(x)
         return logits

@@ -146,6 +146,7 @@ def _fwd(q, k, v, *, causal: bool, scale: float, block_q: int, block_k: int, int
             jax.ShapeDtypeStruct((bh, s_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -279,6 +280,7 @@ def _bwd(
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -303,6 +305,7 @@ def _bwd(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

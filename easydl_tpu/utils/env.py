@@ -487,12 +487,25 @@ def configure_compile_cache() -> Optional[str]:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     # A Mosaic kernel is serialized into its program WITH its MLIR
     # locations, and by default a location holds the Python call stack that
-    # traced it. The same train step traced after an init (a fresh run, an
-    # elastic generation 1) and after a restore (a resumed run, every later
-    # generation) then differs in bytes the cache keys on, and the restart
-    # the cache exists for compiles cold (seen on the chip: 14.5 s twice).
-    # One frame per location does not depend on the caller.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # traced it (ten frames). The same train step traced after an init (a
+    # fresh run, an elastic generation 1) and after a restore (a resumed
+    # run, every later generation) then differs in bytes the cache keys on,
+    # and the restart the cache exists for compiles cold (seen on the chip:
+    # 14.5 s twice). One frame per location does not depend on the caller.
+    # (Not ``jax_include_full_tracebacks_in_locations=False``, which gives
+    # one frame too: jax then writes locations in a form from which XLA does
+    # not compose an operation's name stack through calls and loops — the
+    # compiled step's op_name was the bare primitive, and the passes and
+    # scopes the device trace is read by were gone.)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    # The key leaves the locations of a program's operations out unless told
+    # otherwise, and the name stack (named scopes, module names, a Pallas
+    # kernel's ``name=``) lives in them: a program found in the cache then
+    # wears the names of whichever version was compiled first. The device
+    # trace is read by those names, so they belong to the key. The price: an
+    # edit that moves a traced line compiles cold once, as a checkout at a
+    # new path already does.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return cache_dir
 
 

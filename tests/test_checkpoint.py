@@ -97,6 +97,42 @@ def test_async_save_and_retention(tmp_path, eight_devices):
     assert meta["step"] == 4 and len(meta["leaves"]) > 0
 
 
+@pytest.mark.parametrize("async_save", [False, True], ids=["sync", "async"])
+@pytest.mark.parametrize("spec", [MeshSpec(dp=8), MeshSpec(fsdp=4, tp=2)],
+                         ids=["replicated", "sharded"])
+def test_save_reports_its_phases(tmp_path, eight_devices, async_save, spec):
+    """``on_event``: snapshot done, chunks written, committed — in that
+    order, once a save, with the bytes of the state (each shard once,
+    however many devices hold a replica of it)."""
+    events = []
+    t, _ = make_trainer(spec)
+    state = t.init_state()
+    mgr = CheckpointManager(
+        str(tmp_path), async_save=async_save,
+        on_event=lambda name, **data: events.append((name, data)))
+    mgr.save(7, state)
+    # the copies to the host are over when save returns, whatever the mode
+    assert events[0][0] == "ckpt_snapshot_done"
+    mgr.wait()
+    assert [name for name, _ in events] == [
+        "ckpt_snapshot_done", "ckpt_chunks_written", "ckpt_committed"]
+    snapshot, written, committed = (data for _, data in events)
+    leaves = jax.tree.leaves(state)
+    size = sum(np.asarray(leaf).nbytes for leaf in leaves)
+    assert snapshot["bytes"] == written["bytes"] == size
+    assert snapshot["leaves"] == len(leaves)
+    assert {d["step"] for _, d in events} == {7}
+    assert snapshot["waited_s"] >= 0 and snapshot["seconds"] > 0
+    assert written["seconds"] > 0
+    # since save was entered: the snapshot and the writing lie inside it
+    assert committed["seconds"] >= snapshot["seconds"] + written["seconds"]
+    assert mgr.latest_step() == 7
+    # a save that finds its step committed reports nothing
+    mgr.save(7, state)
+    mgr.wait()
+    assert len(events) == 3
+
+
 def test_uncommitted_step_ignored(tmp_path, eight_devices):
     t1, _ = make_trainer(MeshSpec(dp=8))
     s1 = t1.init_state()

@@ -4,9 +4,8 @@ it rests on.
 - the phase functions at test size on the forced-CPU mesh — steered from
   here (a ``Size``, interpret mode, what the agent is told its host has),
   never by an option of the script;
-- on a machine without a chip, ``chip_smoke.py``, ``bench.py`` and
-  ``scripts/bench_profile.py`` exit non-zero, name the missing chip and
-  print no metric;
+- on a machine without a chip, ``chip_smoke.py`` and ``bench.py`` exit
+  non-zero, name the missing chip and print no metric;
 - the compile-cache resolver, the MFU denominator, and the agent's refusal
   to preflight onto a device its own worker holds.
 """
@@ -108,6 +107,24 @@ def test_elastic_phase_declines_preflight_on_a_held_device(tmp_path):
     assert [s["mode"] for s in r["spawns"]] == ["cold", "cold"]
     assert r["spawns"][-1]["reason"] == "device_held"
     assert r["recovered_to_step"] > r["killed_at_step"] > r["restored_step"]
+    # the recovery on the agent's and the workers' timeline: the reaped
+    # worker, then the spawn with the RUN's first sighting between the two;
+    # the resumed worker's boot with its devices and compile counters
+    from easydl_tpu.elastic.timeline import read
+
+    events = read(str(tmp_path / "elastic" / "timeline-a0.jsonl"))
+    crash, = [e for e in events if e["phase"] == "worker_crash"]
+    spawn = next(e for e in events
+                 if e["phase"] == "spawn" and e["gen"] == 2)
+    assert crash["gen"] == 1 and crash["code"] == -9
+    assert crash["t"] <= spawn["directive_t"] <= spawn["t"]
+    gen2 = [e["phase"] for e in events if e["gen"] == 2]
+    assert gen2.index("dist_init_done") < gen2.index("devices_ready") \
+        < gen2.index("trainer_built")
+    first = next(e for e in events
+                 if e["phase"] == "first_step_done" and e["gen"] == 2)
+    assert {"trace_s", "lower_s", "backend_s", "cache_retrieval_s",
+            "cache_hits", "cache_misses"} <= set(first)
 
 
 def _run(argv, cwd=REPO, **env):
@@ -116,8 +133,7 @@ def _run(argv, cwd=REPO, **env):
         timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu", **env))
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py",
-                                    "scripts/bench_profile.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
 def test_no_chip_no_metric(script):
     """On a CPU-only machine the measurement paths fail; they do not fall
     back. Non-zero exit, the missing chip named, no JSON line on stdout."""
@@ -141,7 +157,8 @@ def cache_config(monkeypatch):
     names = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
              "jax_persistent_cache_min_compile_time_secs",
              "jax_persistent_cache_min_entry_size_bytes",
-             "jax_include_full_tracebacks_in_locations")
+             "jax_traceback_in_locations_limit",
+             "jax_compilation_cache_include_metadata_in_key")
     before = {n: getattr(jax.config, n) for n in names}
     calls = {}
     real_update = jax.config.update
@@ -166,8 +183,11 @@ def test_compile_cache_resolver(case, cache_config, monkeypatch, tmp_path):
     else:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     got = env.configure_compile_cache()
-    if case != "off":  # see test_tpu_compile: cache keys of Mosaic programs
-        assert cache_config["jax_include_full_tracebacks_in_locations"] is False
+    if case != "off":
+        # see test_tpu_compile: cache keys of Mosaic programs ...
+        assert cache_config["jax_traceback_in_locations_limit"] == 1
+        # ... and test_step_scopes: the names the device trace is read by
+        assert cache_config["jax_compilation_cache_include_metadata_in_key"]
     if case == "env_set":  # jax reads it; the code sets no directory
         assert got == str(tmp_path)
         assert "jax_compilation_cache_dir" not in cache_config

@@ -103,6 +103,64 @@ def test_sharded_attention_compiles_per_shard(v5e_2x2, spec, per_device):
     assert "all-gather" not in compiled.as_text()
 
 
+@pytest.fixture(scope="module")
+def named_texts(v5e_2x2):
+    """Compiled text of forward + backward attention, on one described chip
+    and under ``shard_map`` on the described 2x2, compiled as the program's
+    entry points compile: one frame per location (``configure_compile_cache``
+    sets the same) — XLA then writes each operation's whole name stack."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache = jax.config.jax_enable_compilation_cache
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    compilation_cache.reset_cache()
+    try:
+        one = jax.ShapeDtypeStruct(
+            SHAPE, jnp.bfloat16, sharding=SingleDeviceSharding(v5e_2x2[0]))
+        alone = jax.jit(jax.grad(_loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=True)), argnums=(0, 1, 2))).lower(
+                one, one, one).compile().as_text()
+        mesh = build_mesh(MeshSpec(dp=4), devices=v5e_2x2)
+        x = jax.ShapeDtypeStruct(
+            SHAPE, jnp.bfloat16,
+            sharding=NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None)))
+        with jax.set_mesh(mesh):
+            sharded = jax.jit(jax.grad(_loss(lambda q, k, v:
+                multihead_attention(q, k, v, causal=True, impl="flash")),
+                argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+        compilation_cache.reset_cache()
+    return {"one_chip": alone, "shard_map": sharded}
+
+
+@pytest.mark.parametrize("where", ["one_chip", "shard_map"])
+@pytest.mark.parametrize("kernel,passes", [
+    ("flash_fwd", "jvp("), ("flash_bwd_dq", "transpose(jvp("),
+    ("flash_bwd_dkv", "transpose(jvp(")])
+def test_kernels_are_told_by_name_in_the_compiled_text(named_texts, where,
+                                                       kernel, passes):
+    """Each Mosaic call's own line names its kernel, on its path (op_name)
+    and as the instruction's name — no result type has to be looked at."""
+    lines = [line for line in named_texts[where].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(lines) == 3
+    mine = [line for line in lines
+            if f"{kernel}/pallas_call" in line or f"({kernel})" in line]
+    assert len(mine) == 1, lines
+    line = mine[0]
+    op_name = line.split('op_name="', 1)[1].split('"', 1)[0]
+    assert kernel in op_name and passes in op_name
+    assert kernel in line.split(" = ", 1)[0]       # the instruction's name
+    if where == "shard_map":
+        assert "shard_map" in op_name
+    others = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} - {kernel}
+    assert not any(o + "/" in op_name or f"({o})" in op_name for o in others)
+
+
 def test_bare_kernel_under_a_mesh_is_refused(v5e_2x2):
     """Why the wrap exists: GSPMD cannot partition a Mosaic kernel. If this
     ever compiles, ``ops/attention._per_shard`` can go."""
@@ -115,13 +173,14 @@ def test_bare_kernel_under_a_mesh_is_refused(v5e_2x2):
         jax.jit(fn).lower(x, x, x).compile()
 
 
-@pytest.mark.parametrize("full_tracebacks", [True, False])
-def test_mosaic_payload_and_the_python_call_stack(v5e_2x2, full_tracebacks):
+@pytest.mark.parametrize("frames", [10, 1])
+def test_mosaic_payload_and_the_python_call_stack(v5e_2x2, frames):
     """The bytes of a Mosaic kernel inside its program — which the
     persistent compile cache keys on — hold MLIR locations. With jax's
-    default they carry the Python call stack, so the same step traced from
-    two callers (a fresh run vs a resumed one) never shares a cache entry;
-    ``configure_compile_cache`` turns that off, and then they are equal."""
+    default they carry ten frames of the Python call stack, so the same step
+    traced from two callers (a fresh run vs a resumed one) never shares a
+    cache entry; ``configure_compile_cache`` cuts them to one frame, and
+    then they are equal."""
     import re
 
     x = jax.ShapeDtypeStruct(SHAPE, jnp.bfloat16,
@@ -135,12 +194,11 @@ def test_mosaic_payload_and_the_python_call_stack(v5e_2x2, full_tracebacks):
     def from_another_stack():
         return payload(lambda q, k, v: fn(q, k, v))
 
-    before = jax.config.jax_include_full_tracebacks_in_locations
-    jax.config.update("jax_include_full_tracebacks_in_locations",
-                      full_tracebacks)
+    before = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", frames)
     try:
         a, b = payload(fn), from_another_stack()
     finally:
-        jax.config.update("jax_include_full_tracebacks_in_locations", before)
+        jax.config.update("jax_traceback_in_locations_limit", before)
     assert len(a) == len(b) == 1
-    assert (a == b) != full_tracebacks
+    assert (a == b) == (frames == 1)
