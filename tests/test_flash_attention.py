@@ -4,6 +4,8 @@ use inside a jitted transformer step. Kernels run in Pallas interpreter mode
 on CPU (same code path the TPU compiles).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -164,11 +166,157 @@ def test_bf16_inputs():
     )
 
 
+def _loss(attend):
+    def loss(q, k, v):
+        out = attend(q, k, v).astype(jnp.float32)
+        return (out * jnp.cos(out)).sum()
+    return loss
+
+
+def _assert_grads_close(got, want, atol, rtol):
+    for g, w, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            atol=atol, rtol=rtol, err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("s_q,s_k", [(128, 128), (64, 128), (128, 64)],
+                         ids=["square", "sq<sk", "sq>sk"])
+@pytest.mark.parametrize("block_q,block_k", [(64, 32), (32, 64)])
+def test_causal_rectangular_blocks_forward_and_grads(block_q, block_k, s_q, s_k):
+    """block_q != block_k, both ways, square and with a non-zero offset
+    either way: the unmasked loop, the masked loop and the boundary between
+    them all run (8 block pairs: unrolled), and dead rows where s_q > s_k."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(7), 3)
+    b, h, d = 1, 2, 16
+    q = jax.random.normal(kq, (b, s_q, h, d))
+    k = jax.random.normal(kk, (b, s_k, h, d))
+    v = jax.random.normal(kv, (b, s_k, h, d))
+    flash = functools.partial(flash_attention, causal=True, block_q=block_q,
+                              block_k=block_k, interpret=True)
+    ref = functools.partial(_reference_attention, causal=True, scale=d**-0.5)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
+    _assert_grads_close(jax.grad(_loss(flash), argnums=(0, 1, 2))(q, k, v),
+                        jax.grad(_loss(ref), argnums=(0, 1, 2))(q, k, v),
+                        atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("s_q,s_k,block_q,block_k", [
+    (256, 256, 32, 32), (128, 256, 16, 64), (256, 128, 64, 16),
+    (32, 576, 32, 32)],
+    ids=["square", "sq<sk", "sq>sk", "one-q-block"])
+def test_looped_walk_matches_reference(s_q, s_k, block_q, block_k, causal):
+    """More block pairs than ``_UNROLL_PAIRS``: one Q-block (K-block) a grid
+    cell, block indices known only at run time, both loops ``fori_loop``s."""
+    from easydl_tpu.ops import flash_attention as fa
+
+    assert (s_q // block_q) * (s_k // block_k) > fa._UNROLL_PAIRS
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(8), 3)
+    b, h, d = 1, 2, 32
+    q = jax.random.normal(kq, (b, s_q, h, d))
+    k = jax.random.normal(kk, (b, s_k, h, d))
+    v = jax.random.normal(kv, (b, s_k, h, d))
+    flash = functools.partial(flash_attention, causal=causal, block_q=block_q,
+                              block_k=block_k, interpret=True)
+    ref = functools.partial(_reference_attention, causal=causal, scale=d**-0.5)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
+    _assert_grads_close(jax.grad(_loss(flash), argnums=(0, 1, 2))(q, k, v),
+                        jax.grad(_loss(ref), argnums=(0, 1, 2))(q, k, v),
+                        atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("against", ["bf16", "float32"])
+def test_bf16_grads(against, causal):
+    """bf16 in, bf16 operands to every matmul: gradients against the XLA
+    reference on the same bf16 inputs (which makes the same two roundings,
+    of the probabilities and of dS), and against it on float32 copies."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(9), b=1, s=128, h=2, d=32,
+                       dtype=jnp.bfloat16)
+    flash = functools.partial(flash_attention, causal=causal, block_q=64,
+                              block_k=32, interpret=True)
+    ref = functools.partial(_reference_attention, causal=causal, scale=32**-0.5)
+    got = jax.grad(_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    assert all(g.dtype == jnp.bfloat16 for g in got)
+    if against == "float32":
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    want = jax.grad(_loss(ref), argnums=(0, 1, 2))(q, k, v)
+    # bf16 keeps 8 bits: a few percent of the largest entry, as chip_smoke's
+    # KERNEL_GRAD_RTOL states for the real size
+    for g, w, name in zip(got, want, "qkv"):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= 3e-2 * np.abs(w).max(), f"d{name}"
+
+
+@pytest.mark.parametrize("bh,pairs,rows,dtype,heads", [
+    (128, 4, 6 * 1024, "bfloat16", 1),   # gpt2-medium: a head fills its cell
+    (100, 4, 6 * 1024, "bfloat16", 1),   # gpt2-xl's per shard
+    (384, 1, 6 * 128, "bfloat16", 4),    # BERT at 128: one pair a head
+    (384, 2, 6 * 256, "bfloat16", 2),
+    (6, 1, 6 * 512, "bfloat16", 3),      # what divides the batch·heads
+    (64, 1, 6 * 1024, "float32", 2),     # VMEM: [4, 1024, 128] float32 x 6
+], ids=["medium", "xl-shard", "bert-128", "two-pairs", "six-heads", "float32"])
+def test_batch_heads_a_grid_cell(bh, pairs, rows, dtype, heads):
+    from easydl_tpu.ops.flash_attention import _cell_heads
+
+    like = jax.ShapeDtypeStruct((bh, 1024, 64), jnp.dtype(dtype))
+    assert _cell_heads(bh, pairs, True, rows, like) == heads
+    assert _cell_heads(bh, pairs, False, rows, like) == 1
+
+
+def _kernel_dots(fn, *args):
+    """{kernel name: [(lhs dtype, rhs dtype) of every dot_general inside]}
+    for the ``pallas_call``s that ``fn(*args)`` traces to."""
+    found = {}
+
+    def subjaxprs(params):
+        for value in params.values():
+            for v in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield inner
+
+    def walk(jaxpr, kernel):
+        for eqn in jaxpr.eqns:
+            inside = kernel
+            if eqn.primitive.name == "pallas_call":
+                inside = eqn.params["name"]
+                found.setdefault(inside, [])
+            elif eqn.primitive.name == "dot_general" and kernel:
+                found[kernel].append(tuple(
+                    jnp.dtype(x.aval.dtype).name for x in eqn.invars))
+            for sub in subjaxprs(eqn.params):
+                walk(sub, inside)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return found
+
+
+@pytest.mark.parametrize("looped", [False, True], ids=["unrolled", "looped"])
+@pytest.mark.parametrize("dtype,other", [("bfloat16", "float32"),
+                                         ("float32", "bfloat16")])
+def test_matmul_operands_follow_the_input_dtype(dtype, other, looped):
+    """Walk the kernel jaxprs inside the three ``pallas_call``s: with bf16
+    inputs no ``dot_general`` takes a float32 operand, with float32 inputs
+    none takes a bf16 one."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(10), b=1, s=256 if looped else 64,
+                       h=1, d=32, dtype=jnp.dtype(dtype))
+    flash = functools.partial(flash_attention, causal=True, block_q=32,
+                              block_k=32, interpret=True)
+    dots = _kernel_dots(jax.grad(_loss(flash), argnums=(0, 1, 2)), q, k, v)
+    assert sorted(dots) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert [len(dots[n]) > 0 for n in sorted(dots)] == [True] * 3
+    for name, operands in dots.items():
+        assert all(pair == (dtype, dtype) for pair in operands), (name, operands)
+        assert not any(other in pair for pair in operands)
+
+
 def test_inside_jitted_train_step(monkeypatch):
     """Flash path composes with jit + grad in a real model step — on a
     2-device mesh, so the kernel runs per shard inside ``jax.shard_map``."""
-    import functools
-
     import optax
 
     from easydl_tpu.ops import attention

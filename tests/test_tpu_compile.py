@@ -81,6 +81,29 @@ def test_flash_compiles_on_one_v5e_device(v5e_2x2, backward, n_kernels):
     assert all("[128,1024," in c for c in calls), calls  # [B*H, S, ...]
 
 
+@pytest.mark.parametrize("shape,blocks", [
+    # [batch·heads, seq, head_dim] = [128, 1024, 64]: gpt2-medium's call;
+    # [100, 1024, 64]: gpt2-xl's per shard under fsdp=4
+    ((8, 1024, 16, 64), ((512, 512), (512, 512), (256, 256))),
+    ((4, 1024, 25, 64), ((512, 512), (512, 512), (256, 256))),
+], ids=["128x1024x64", "100x1024x64"])
+def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
+    """The (block_q, block_k) the forward, dq and dk/dv kernels choose for
+    the benchmark's two calls, bf16 causal — a later change to the choice
+    shows here — and that Mosaic takes the three kernels at those sizes."""
+    from easydl_tpu.ops.flash_attention import _choose_blocks
+
+    _, seq, _, _ = shape
+    assert _choose_blocks(seq, seq, True, None, None) == blocks
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e_2x2[0]))
+    fn = jax.grad(_loss(lambda q, k, v: flash_attention(q, k, v, causal=True)),
+                  argnums=(0, 1, 2))
+    calls = _mosaic_calls(jax.jit(fn).lower(x, x, x).compile())
+    assert len(calls) == 3, calls
+    assert all(f"[{shape[0] * shape[2]},1024," in c for c in calls), calls
+
+
 @pytest.mark.parametrize("spec,per_device", [
     (MeshSpec(dp=4), "[32,1024,"),          # 2 rows x 16 heads
     (MeshSpec(dp=2, tp=2), "[32,1024,"),    # 4 rows x 8 heads
