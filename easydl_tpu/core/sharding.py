@@ -30,6 +30,8 @@ DEFAULT_RULES: Tuple[Tuple[str, Any], ...] = (
     ("mlp", "tp"),              # FFN hidden dim
     ("heads", "tp"),            # attention heads
     ("kv", None),               # per-head dim: replicated
+    ("ssm_group", None),        # Mamba-2 B / C groups (fewer than tp): whole
+    ("ssm_state", None),        # Mamba-2 state dim: replicated
     ("qkv", "tp"),
     ("vocab", "tp"),
     ("seq", "sp"),              # sequence dim of activations

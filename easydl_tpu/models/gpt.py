@@ -8,6 +8,7 @@ to a multiple of 128 so the embedding/logits matmuls tile cleanly on the MXU.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Tuple
 
 import jax
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import optax
 
 from easydl_tpu.core.data import SyntheticTokens
+from easydl_tpu.core.mesh_shapes import BATCH_AXES
 from easydl_tpu.models.registry import ModelBundle, register_model
 from easydl_tpu.models.transformer import Transformer, TransformerConfig
 
@@ -41,6 +43,122 @@ def lm_loss(logits, targets, ignore_id: int = -1):
     return loss, denom
 
 
+#: The chunked fused head (ops/fused_xent.py) takes the place of full logits
+#: when ONE device's share of a microbatch's ``[B, S, V]`` float32 logits
+#: would pass this: an eighth of a v5e chip's 16 GB. The fused head computes
+#: every chunk's logits twice (its backward recomputes them) and was the
+#: slower side wherever both fit (ROADMAP Speed 5c), so it is chosen for the
+#: room, not the time: GPT-2 at 8 x 1024 x 50304 (1.5 GiB) keeps full
+#: logits, the Granite hybrid at 2 x 4096 x 100352 (3.1 GiB, beside 1.5 GiB
+#: of bf16 logits) does not fit them.
+FUSED_HEAD_LOGITS_BYTES = 2 * 1024 ** 3
+
+
+def fused_head_by_shape(batch: int, seq: int, vocab: int) -> bool:
+    """The rule above, for logits of ``[batch, seq, vocab]`` as the loss
+    function sees them under the context mesh (the one ``Trainer`` enters):
+    the batch is split over the mesh's batch axes where it divides."""
+    mesh = jax.sharding.get_abstract_mesh()
+    shards = math.prod(mesh.shape[a] for a in BATCH_AXES
+                       if a in mesh.axis_names)
+    if batch % shards:
+        shards = 1
+    return 4 * (batch // shards) * seq * vocab > FUSED_HEAD_LOGITS_BYTES
+
+
+def lm_bundle(cfg: TransformerConfig, name: str, *, fused_loss=None,
+              loss_chunk: int = 128, moe_aux_weight: float = 0.01
+              ) -> ModelBundle:
+    """The causal-LM bundle of one description of the stack: init, loss
+    (full logits, or the fused chunked head: ``fused_loss`` True / False
+    states it, None leaves it to :func:`fused_head_by_shape`), eval, data
+    and the hints."""
+    model = Transformer(cfg)
+    seq_len, vocab, n_layers = cfg.max_seq, cfg.vocab, cfg.n_layers
+
+    def init_fn(rng):
+        tokens = jnp.zeros((1, seq_len), jnp.int32)
+        return model.init(rng, tokens)["params"]
+
+    def _lm_loss_from(params, batch, mutable=False):
+        """LM loss via the fused chunked head or full logits.
+
+        The fused path asks the stack for hidden states and applies the tied
+        head chunk-by-chunk (ops/fused_xent.py) — the full [B,S,V] f32
+        logits buffer never exists.
+        """
+        mut = None
+        fused = fused_loss
+        if fused is None:
+            fused = fused_head_by_shape(*batch["inputs"].shape, vocab)
+        if fused and cfg.tied_head:
+            from easydl_tpu.ops.fused_xent import fused_softmax_xent
+
+            out = model.apply(
+                {"params": params}, batch["inputs"], return_hidden=True,
+                **({"mutable": ["intermediates"]} if mutable else {}),
+            )
+            hidden = out[0] if mutable else out
+            mut = out[1] if mutable else None
+            head = params["tok_emb"]["embedding"]
+            if hasattr(head, "unbox"):  # boxed (LogicallyPartitioned) params
+                head = head.unbox()
+            # Cast the stored-f32 param to the compute dtype — exactly what
+            # tok_emb.attend's dtype promotion does on the logits path. A
+            # bf16×f32 dot_general promotes to an f32 matmul, which would
+            # take the [B,chunk,V] matmul off the bf16 MXU path.
+            head = jnp.asarray(head, dtype=hidden.dtype)
+            with jax.named_scope("lm_head_loss"):
+                loss, _ = fused_softmax_xent(
+                    hidden, head, batch["targets"], chunk_size=loss_chunk,
+                    logit_scale=1.0 / cfg.logits_scaling,
+                )
+        else:
+            out = model.apply(
+                {"params": params}, batch["inputs"],
+                **({"mutable": ["intermediates"]} if mutable else {}),
+            )
+            logits = out[0] if mutable else out
+            mut = out[1] if mutable else None
+            with jax.named_scope("loss"):
+                loss, _ = lm_loss(logits, batch["targets"])
+        return loss, mut
+
+    def loss_fn(params, batch, rng):
+        if cfg.moe_experts:
+            loss, mut = _lm_loss_from(params, batch, mutable=True)
+            aux = jnp.sum(
+                jnp.asarray(mut["intermediates"]["moe_aux_loss"][0])
+            )
+            return loss + moe_aux_weight * aux, {
+                "perplexity": jnp.exp(loss),
+                "moe_balance": aux / max(n_layers, 1),
+            }
+        loss, _ = _lm_loss_from(params, batch)
+        return loss, {"perplexity": jnp.exp(loss)}
+
+    def eval_fn(params, batch, rng):
+        # Pure LM loss — no balance regularizer, so eval is comparable
+        # across dense/MoE configs and aux weights.
+        loss, _ = _lm_loss_from(params, batch)
+        return loss, {"perplexity": jnp.exp(loss)}
+
+    def make_data(global_batch: int, seed: int = 0):
+        return SyntheticTokens(global_batch, seq_len=seq_len, vocab=vocab, seed=seed)
+
+    return ModelBundle(
+        name=name,
+        init_fn=init_fn,
+        loss_fn=loss_fn,
+        make_data=make_data,
+        eval_fn=eval_fn,
+        param_count_hint=cfg.param_count,
+        # the description's own count: a layer without a score matrix adds
+        # no 12 d s (core/mfu.py's GPT formula is this for all-attention)
+        flops_per_sample_hint=cfg.train_flops_per_token(seq_len) * seq_len,
+    )
+
+
 @register_model("gpt")
 def make_gpt(
     size: str = "345m",
@@ -56,7 +174,7 @@ def make_gpt(
     moe_k: int = 2,
     moe_aux_weight: float = 0.01,
     moe_capacity_factor: float = 1.25,
-    fused_loss: bool = False,
+    fused_loss=None,
     loss_chunk: int = 128,
     pipeline_fn=None,
     pipeline_stages: int = 0,
@@ -83,87 +201,10 @@ def make_gpt(
         pipeline_fn=pipeline_fn,
         pipeline_stages=pipeline_stages,
     )
-    model = Transformer(cfg)
-
-    def init_fn(rng):
-        tokens = jnp.zeros((1, seq_len), jnp.int32)
-        return model.init(rng, tokens)["params"]
-
-    def _lm_loss_from(params, batch, mutable=False):
-        """LM loss via the fused chunked head (default) or full logits.
-
-        The fused path asks the stack for hidden states and applies the tied
-        head chunk-by-chunk (ops/fused_xent.py) — the full [B,S,V] f32
-        logits buffer never exists, which is what caps the microbatch (and
-        MFU) on the logits path (bench.py r2 evidence).
-        """
-        mut = None
-        if fused_loss and cfg.tied_head:
-            from easydl_tpu.ops.fused_xent import fused_softmax_xent
-
-            out = model.apply(
-                {"params": params}, batch["inputs"], return_hidden=True,
-                **({"mutable": ["intermediates"]} if mutable else {}),
-            )
-            hidden = out[0] if mutable else out
-            mut = out[1] if mutable else None
-            head = params["tok_emb"]["embedding"]
-            if hasattr(head, "unbox"):  # boxed (LogicallyPartitioned) params
-                head = head.unbox()
-            # Cast the stored-f32 param to the compute dtype — exactly what
-            # tok_emb.attend's dtype promotion does on the logits path. A
-            # bf16×f32 dot_general promotes to an f32 matmul, which would
-            # take the [B,chunk,V] matmul off the bf16 MXU path.
-            head = jnp.asarray(head, dtype=hidden.dtype)
-            with jax.named_scope("lm_head_loss"):
-                loss, _ = fused_softmax_xent(
-                    hidden, head, batch["targets"], chunk_size=loss_chunk
-                )
-        else:
-            out = model.apply(
-                {"params": params}, batch["inputs"],
-                **({"mutable": ["intermediates"]} if mutable else {}),
-            )
-            logits = out[0] if mutable else out
-            mut = out[1] if mutable else None
-            with jax.named_scope("loss"):
-                loss, _ = lm_loss(logits, batch["targets"])
-        return loss, mut
-
-    def loss_fn(params, batch, rng):
-        if moe_experts:
-            loss, mut = _lm_loss_from(params, batch, mutable=True)
-            aux = jnp.sum(
-                jnp.asarray(mut["intermediates"]["moe_aux_loss"][0])
-            )
-            return loss + moe_aux_weight * aux, {
-                "perplexity": jnp.exp(loss),
-                "moe_balance": aux / max(n_layers, 1),
-            }
-        loss, _ = _lm_loss_from(params, batch)
-        return loss, {"perplexity": jnp.exp(loss)}
-
-    def eval_fn(params, batch, rng):
-        # Pure LM loss — no balance regularizer, so eval is comparable
-        # across dense/MoE configs and aux weights.
-        loss, _ = _lm_loss_from(params, batch)
-        return loss, {"perplexity": jnp.exp(loss)}
-
-    def make_data(global_batch: int, seed: int = 0):
-        return SyntheticTokens(global_batch, seq_len=seq_len, vocab=vocab, seed=seed)
-
-    from easydl_tpu.core.mfu import model_flops_per_token
-
-    return ModelBundle(
-        name=f"gpt-{size}" + (f"-moe{moe_experts}" if moe_experts else ""),
-        init_fn=init_fn,
-        loss_fn=loss_fn,
-        make_data=make_data,
-        eval_fn=eval_fn,
-        param_count_hint=cfg.param_count,
-        flops_per_sample_hint=model_flops_per_token(
-            cfg.param_count, n_layers, d_model, seq_len) * seq_len,
-    )
+    return lm_bundle(
+        cfg, f"gpt-{size}" + (f"-moe{moe_experts}" if moe_experts else ""),
+        fused_loss=fused_loss, loss_chunk=loss_chunk,
+        moe_aux_weight=moe_aux_weight)
 
 
 @register_model("gpt_moe")

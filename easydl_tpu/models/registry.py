@@ -11,6 +11,8 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
 
 _REGISTRY: Dict[str, Callable[..., "ModelBundle"]] = {}
+#: modules under easydl_tpu.models that register factories on import
+_MODULES = ("mlp", "resnet", "bert", "gpt", "granite_hybrid", "deepfm")
 
 
 @dataclass
@@ -41,7 +43,7 @@ def get_model(name: str, **kwargs: Any) -> ModelBundle:
         # Import-on-demand so registering modules stay lazy.
         import importlib
 
-        for mod in ("mlp", "resnet", "bert", "gpt", "deepfm"):
+        for mod in _MODULES:
             try:
                 importlib.import_module(f"easydl_tpu.models.{mod}")
             except ImportError:
@@ -54,7 +56,7 @@ def get_model(name: str, **kwargs: Any) -> ModelBundle:
 def list_models() -> list:
     import importlib
 
-    for mod in ("mlp", "resnet", "bert", "gpt", "deepfm"):
+    for mod in _MODULES:
         try:
             importlib.import_module(f"easydl_tpu.models.{mod}")
         except ImportError:

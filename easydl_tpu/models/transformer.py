@@ -1,4 +1,10 @@
-"""Shared transformer stack for the LM families (GPT-2, BERT).
+"""One stack for the LM families, described by data: GPT-2 and BERT
+(LayerNorm, learned positions, multi-head attention, GELU, biases) and the
+Granite 4.0-H hybrid (RMSNorm, no positions, Mamba-2 mixers between
+grouped-query attention layers, SwiGLU, four scalar multipliers) are two
+descriptions of it (``TransformerConfig``). Per layer the description names
+a mixer (``attention`` | ``mamba2``) and an FFN (``gelu`` | ``swiglu``);
+consecutive layers of one kind are one ``nn.scan``.
 
 TPU-first choices:
 - every parameter carries logical axis names (``embed``/``heads``/``kv``/
@@ -21,13 +27,15 @@ hit the BASELINE configs 3-4 (BERT-base, GPT-2 345M).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, Optional
+from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from easydl_tpu.ops import multihead_attention
+from easydl_tpu.ops.ssd import (causal_conv1d, gated_rmsnorm,
+                                ssd_flops_per_token, ssd_scan)
 
 Init = nn.initializers.Initializer
 
@@ -57,10 +65,25 @@ def _dense(
     )
 
 
-def _layernorm(name, dtype=None):
-    # LayerNorm statistics always accumulate in f32 (flax does this when
-    # dtype is low-precision); only the output is cast to ``dtype``.
+def _norm(cfg, name, dtype=None):
+    """The description's norm (``layernorm`` with bias | ``rmsnorm``) over
+    the model width."""
+    # Statistics always accumulate in f32 (flax does this when dtype is
+    # low-precision); only the output is cast to ``dtype``.
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(
+            epsilon=cfg.norm_eps,
+            dtype=dtype,
+            scale_init=nn.with_logical_partitioning(
+                nn.initializers.ones_init(), ("embed",)
+            ),
+            name=name,
+        )
+    if cfg.norm != "layernorm":
+        raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
+                         f"{cfg.norm!r}")
     return nn.LayerNorm(
+        epsilon=cfg.norm_eps,
         use_bias=True,
         dtype=dtype,
         scale_init=nn.with_logical_partitioning(
@@ -71,6 +94,25 @@ def _layernorm(name, dtype=None):
         ),
         name=name,
     )
+
+
+#: one layer of the description: (mixer, ffn)
+Layer = Tuple[str, str]
+MIXERS = ("attention", "mamba2")
+FFNS = ("gelu", "swiglu")
+
+
+@dataclass(frozen=True)
+class SsmConfig:
+    """Widths of the Mamba-2 mixer (ops/ssd.py); the inner width is
+    ``n_heads * head_dim``."""
+
+    n_heads: int = 64
+    head_dim: int = 64
+    d_state: int = 128
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk: int = 256
 
 
 @dataclass(frozen=True)
@@ -113,6 +155,41 @@ class TransformerConfig:
     moe_experts: int = 0
     moe_k: int = 2
     moe_capacity_factor: float = 1.25
+    # ---- the description. The defaults below are GPT-2's (and BERT's).
+    #: per layer (mixer, ffn); None = ``n_layers`` x ("attention", "gelu").
+    #: When given, its length is the depth and ``n_layers`` must agree.
+    layers: Optional[Tuple[Layer, ...]] = None
+    norm: str = "layernorm"       # | "rmsnorm"
+    norm_eps: float = 1e-6
+    position: str = "learned"     # | "none": no table, no rotary
+    #: key/value heads (grouped-query attention); 0 = ``n_heads``
+    n_kv_heads: int = 0
+    bias: bool = True             # on the projections and the FFN
+    embedding_multiplier: float = 1.0
+    #: the softmax scale; None = ``head_dim ** -0.5``
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    #: logits are divided by this
+    logits_scaling: float = 1.0
+    #: widths of the ``mamba2`` mixers, where the description has any
+    ssm: Optional[SsmConfig] = None
+
+    def __post_init__(self):
+        if self.layers is not None and len(self.layers) != self.n_layers:
+            raise ValueError(f"{len(self.layers)} layers described, "
+                             f"n_layers={self.n_layers}")
+        for mixer, ffn in self.pattern:
+            if mixer not in MIXERS or ffn not in FFNS:
+                raise ValueError(f"unknown layer kind {(mixer, ffn)}; mixers "
+                                 f"{MIXERS}, FFNs {FFNS}")
+            if mixer == "mamba2" and self.ssm is None:
+                raise ValueError("a mamba2 layer needs ssm=SsmConfig(...)")
+        if self.position not in ("learned", "none"):
+            raise ValueError(f"position must be 'learned' or 'none', got "
+                             f"{self.position!r}")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"{self.n_heads} heads do not divide into "
+                             f"{self.kv_heads} key/value heads")
 
     @property
     def head_dim(self) -> int:
@@ -120,32 +197,212 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
     @property
-    def param_count(self) -> int:
-        if self.moe_experts:
-            ffn = (
-                self.moe_experts * 2 * self.d_model * self.d_ff  # expert FFNs
-                + self.d_model * self.moe_experts                # router
-            )
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def pattern(self) -> Tuple[Layer, ...]:
+        return self.layers or (("attention", "gelu"),) * self.n_layers
+
+    @property
+    def runs(self) -> Tuple[Tuple[Layer, int], ...]:
+        """The pattern as runs of equal layers: ``((mixer, ffn), count)``."""
+        out = []
+        for layer in self.pattern:
+            if out and out[-1][0] == layer:
+                out[-1][1] += 1
+            else:
+                out.append([layer, 1])
+        return tuple((layer, n) for layer, n in out)
+
+    def layer_params(self, layer: Layer) -> int:
+        """Parameters of one layer. Exact for the bias-free kinds; a layer
+        with biases counts ``4 * d_model`` for its biases and norms, as this
+        estimate always has."""
+        mixer, ffn = layer
+        d = self.d_model
+        if mixer == "attention":
+            n = 2 * d * d + 2 * d * self.kv_heads * self.head_dim
         else:
-            ffn = 2 * self.d_model * self.d_ff
-        per_block = (
-            4 * self.d_model * self.d_model      # qkv + out projections
-            + ffn
-            + 4 * self.d_model                   # biases-ish + 2 LN
-        )
-        emb = self.vocab * self.d_model + self.max_seq * self.d_model
+            m = self.ssm
+            inner, bc = m.n_heads * m.head_dim, m.n_groups * m.d_state
+            n = (d * (2 * inner + 2 * bc + m.n_heads)      # z, x, B, C, dt
+                 + (m.d_conv + 1) * (inner + 2 * bc)       # conv and bias
+                 + 3 * m.n_heads + inner + inner * d)      # dt_bias A D norm out
+        if ffn == "swiglu":
+            n += 3 * d * self.d_ff
+        elif self.moe_experts:
+            n += self.moe_experts * 2 * d * self.d_ff + d * self.moe_experts
+        else:
+            n += 2 * d * self.d_ff
+        return n + (4 * d if self.bias else 2 * d)  # biases-ish + 2 norms
+
+    @property
+    def param_count(self) -> int:
+        emb = self.vocab * self.d_model
+        if self.position == "learned":
+            emb += self.max_seq * self.d_model
+        if self.norm == "rmsnorm":
+            emb += self.d_model   # final norm (a LayerNorm's sits in the
+            #                       layers' "biases-ish" estimate)
         head = 0 if self.tied_head else self.vocab * self.d_model
-        return emb + self.n_layers * per_block + head
+        return emb + sum(self.layer_params(l) for l in self.pattern) + head
+
+    def train_flops_per_token(self, seq_len: int) -> float:
+        """Training FLOPs a token, forward and backward, recomputation not
+        counted: 6 per parameter for the matrix multiplications (PaLM
+        appendix B), ``12 * d_model * seq`` for each ATTENTION layer's
+        scores and weighted values, and three times the scan's forward
+        count for each Mamba-2 layer — not ``12 L d s`` for layers that
+        have no score matrix."""
+        n_attn = sum(1 for mixer, _ in self.pattern if mixer == "attention")
+        flops = 6.0 * self.param_count + 12.0 * n_attn * self.d_model * seq_len
+        if n_attn < len(self.pattern):
+            m = self.ssm
+            flops += 3.0 * (len(self.pattern) - n_attn) * ssd_flops_per_token(
+                m.n_heads, m.head_dim, m.d_state, m.n_groups, m.chunk)
+        return flops
+
+
+# The mixers and the FFN are functions of the block, not methods of it: flax
+# wraps a Module's methods in a named scope of their own (``blocks._ffn``),
+# which would put a new component into every operation's path.
+def _projection(block, features, kernel_axes, bias_axes, name,
+                residual=False, axis=-1):
+    cfg = block.cfg
+    return _dense(
+        features, kernel_axes, bias_axes, name=name, use_bias=cfg.bias,
+        # GPT-2 residual scaling on the projections that write the
+        # residual stream
+        init_scale=(2 * cfg.n_layers) ** -0.5 if residual else 1.0,
+        axis=axis, dtype=jnp.dtype(cfg.dtype))
+
+
+def _attention(block, h):
+    cfg = block.cfg
+    heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
+    q = _projection(block, (cfg.n_heads, cfg.head_dim), heads, kv, "q")(h)
+    k = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "k")(h)
+    v = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "v")(h)
+    q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
+    k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
+    v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
+    if cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
+        attn = cfg.attention_fn(q, k, v, causal=cfg.causal)
+    else:
+        attn = multihead_attention(
+            q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
+            scale=cfg.attention_multiplier,
+        )
+    return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                       ("embed",), "out", residual=True, axis=(-2, -1))(attn)
+
+
+def _mamba2(block, u):
+    """The Mamba-2 mixer on the normed input ``u``. The published fused
+    input projection ``[z, xBC, dt]`` is five projections here and the
+    depthwise convolution three — the same mathematics, column by
+    column — so that heads shard over ``tp`` and B, C stay whole."""
+    cfg, m = block.cfg, block.cfg.ssm
+    dt_ = jnp.dtype(cfg.dtype)
+    heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
+    group = ("embed", "ssm_group", "ssm_state")
+    z = _projection(block, (m.n_heads, m.head_dim), heads, kv, "in_z")(u)
+    x = _projection(block, (m.n_heads, m.head_dim), heads, kv, "in_x")(u)
+    B = _projection(block, (m.n_groups, m.d_state), group, group[1:],
+                    "in_B")(u)
+    C = _projection(block, (m.n_groups, m.d_state), group, group[1:],
+                    "in_C")(u)
+    dt = _projection(block, m.n_heads, ("embed", "heads"), ("heads",),
+                     "in_dt")(u)
+
+    def conv(name, a, axes):
+        # torch's Conv1d default: uniform in +-1/sqrt(fan_in), fan_in
+        # the taps of a depthwise filter
+        bound = m.d_conv ** -0.5
+
+        def init(key, shape, dtype=jnp.float32):
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        w = block.param(f"conv_{name}", nn.with_logical_partitioning(
+            init, (None,) + axes), (m.d_conv,) + a.shape[2:])
+        b = block.param(f"conv_{name}_bias", nn.with_logical_partitioning(
+            init, axes), a.shape[2:])
+        return nn.silu(causal_conv1d(a, w, b))
+
+    with jax.named_scope("conv1d"):
+        x = conv("x", x, kv)
+        B = conv("B", B, group[1:])
+        C = conv("C", C, group[1:])
+
+    def per_head(name, init):
+        return block.param(name, nn.with_logical_partitioning(
+            init, ("heads",)), (m.n_heads,))
+
+    # Mamba-2's own: dt from exp(U(log 1e-3, log 1e-1)) through the
+    # inverse of softplus, A from U(1, 16), D ones
+    def dt_bias_init(key, shape, dtype=jnp.float32):
+        dt0 = jnp.exp(jax.random.uniform(
+            key, shape, dtype, jnp.log(1e-3), jnp.log(1e-1)))
+        dt0 = jnp.maximum(dt0, 1e-4)
+        return dt0 + jnp.log(-jnp.expm1(-dt0))
+
+    def a_log_init(key, shape, dtype=jnp.float32):
+        return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+    dt_bias = per_head("dt_bias", dt_bias_init)
+    a_log = per_head("A_log", a_log_init)
+    skip = per_head("D", nn.initializers.ones_init())
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+    with jax.named_scope("ssd"):
+        y = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), B, C,
+                     skip, chunk=m.chunk)
+    gain = block.param("norm_gated", nn.with_logical_partitioning(
+        nn.initializers.ones_init(), kv), (m.n_heads, m.head_dim))
+    y = gated_rmsnorm(y, z, gain, cfg.norm_eps).astype(dt_)
+    return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                       ("embed",), "out", residual=True, axis=(-2, -1))(y)
+
+
+def _ffn(block, h):
+    cfg = block.cfg
+    aux = jnp.zeros((), jnp.float32)
+    if block.ffn == "swiglu":
+        gate = _projection(block, cfg.d_ff, ("embed", "mlp"), ("mlp",),
+                           "gate")(h)
+        up = _projection(block, cfg.d_ff, ("embed", "mlp"), ("mlp",), "up")(h)
+        h = nn.silu(gate) * up
+    elif cfg.moe_experts:
+        from easydl_tpu.ops.moe import MoeMlp
+
+        return MoeMlp(
+            num_experts=cfg.moe_experts,
+            d_ff=cfg.d_ff,
+            k=cfg.moe_k,
+            capacity_factor=cfg.moe_capacity_factor,
+            out_init_scale=(2 * cfg.n_layers) ** -0.5,
+            dtype=cfg.dtype,
+            name="moe",
+        )(h)
+    else:
+        h = nn.gelu(_projection(block, cfg.d_ff, ("embed", "mlp"), ("mlp",),
+                                "up")(h))
+    h = _projection(block, cfg.d_model, ("mlp", "embed"), ("embed",), "down",
+                    residual=True)(h)
+    return h, aux
 
 
 class Block(nn.Module):
-    """Pre-LN transformer block (attention + MLP).
+    """One pre-norm layer of the stack: a mixer and an FFN, each from the
+    norm to the residual add.
 
-    Returns ``(x, None)`` — the (carry, per-step-output) pair ``nn.scan``
+    Returns ``(x, aux)`` — the (carry, per-step-output) pair ``nn.scan``
     expects; standalone callers unpack the first element.
     """
 
     cfg: TransformerConfig
+    mixer: str = "attention"
+    ffn: str = "gelu"
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
@@ -154,69 +411,81 @@ class Block(nn.Module):
         dt = jnp.dtype(cfg.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
-        # The two scopes put every operation of a block, residual adds,
-        # GELU and logical constraints included, under `attention` or `ffn`
-        # in the compiled program's op_name paths (read by the device
-        # trace's reducers); flax's module names sit inside them.
-        with jax.named_scope("attention"):
-            h = _layernorm("ln_attn", dtype=dt)(x)
-            qkv_shape = (cfg.n_heads, cfg.head_dim)
-            q = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
-                       name="q", dtype=dt)(h)
-            k = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
-                       name="k", dtype=dt)(h)
-            v = _dense(qkv_shape, ("embed", "heads", "kv"), ("heads", "kv"),
-                       name="v", dtype=dt)(h)
-            q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
-            k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
-            v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
-            if cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
-                attn = cfg.attention_fn(q, k, v, causal=cfg.causal)
-            else:
-                attn = multihead_attention(
-                    q, k, v, causal=cfg.causal, impl=cfg.attention_impl
-                )
-            attn = _dense(
-                cfg.d_model,
-                ("heads", "kv", "embed"),
-                ("embed",),
-                name="out",
-                init_scale=(2 * cfg.n_layers) ** -0.5,  # GPT-2 residual scaling
-                axis=(-2, -1),
-                dtype=dt,
-            )(attn)
-            if cfg.dropout and not deterministic:
-                attn = nn.Dropout(cfg.dropout, deterministic=False)(attn)
-            x = x + attn
-
-        aux = jnp.zeros((), jnp.float32)
-        with jax.named_scope("ffn"):
-            h = _layernorm("ln_mlp", dtype=dt)(x)
-            if cfg.moe_experts:
-                from easydl_tpu.ops.moe import MoeMlp
-
-                h, aux = MoeMlp(
-                    num_experts=cfg.moe_experts,
-                    d_ff=cfg.d_ff,
-                    k=cfg.moe_k,
-                    capacity_factor=cfg.moe_capacity_factor,
-                    out_init_scale=(2 * cfg.n_layers) ** -0.5,
-                    dtype=cfg.dtype,
-                    name="moe",
-                )(h)
-            else:
-                h = _dense(cfg.d_ff, ("embed", "mlp"), ("mlp",), name="up",
-                           dtype=dt)(h)
-                h = nn.gelu(h)
-                h = _dense(
-                    cfg.d_model, ("mlp", "embed"), ("embed",), name="down",
-                    init_scale=(2 * cfg.n_layers) ** -0.5,
-                    dtype=dt,
-                )(h)
+        def residual(x, h):
             if cfg.dropout and not deterministic:
                 h = nn.Dropout(cfg.dropout, deterministic=False)(h)
-            x = x + h
+            if cfg.residual_multiplier != 1.0:
+                h = h * jnp.asarray(cfg.residual_multiplier, h.dtype)
+            return x + h
+
+        # The scopes put every operation of a layer, residual adds,
+        # activations and logical constraints included, under `attention`
+        # or `ssm` and under `ffn` in the compiled program's op_name paths
+        # (read by the device trace's reducers); flax's module names sit
+        # inside them.
+        if self.mixer == "attention":
+            with jax.named_scope("attention"):
+                x = residual(x, _attention(
+                    self, _norm(cfg, "ln_attn", dtype=dt)(x)))
+        else:
+            with jax.named_scope("ssm"):
+                x = residual(x, _mamba2(self, _norm(cfg, "ln_ssm", dtype=dt)(x)))
+        with jax.named_scope("ffn"):
+            h, aux = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x))
+            x = residual(x, h)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed")), aux
+
+
+def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic):
+    """The one run of the stack through ``cfg.pipeline_fn``'s GPipe
+    schedule, on the stacked params the plain path created."""
+    cfg = stack.cfg
+    if cfg.moe_experts:
+        raise NotImplementedError("MoE inside the pipeline")
+    if cfg.dropout and not deterministic:
+        # The stage apply below passes no rngs, so a non-
+        # deterministic dropout>0 apply would otherwise die with an
+        # opaque flax missing-'dropout'-rng error deep inside
+        # shard_map tracing. v1 pipeline scope is dropout-free at
+        # train time — say so. (Deterministic applies — eval,
+        # embedding extraction — need no rng and stay allowed.)
+        raise NotImplementedError(
+            f"dropout={cfg.dropout} with pipeline_fn: the pipeline "
+            "path applies stages without rngs (v1 trains "
+            "dropout-free; deterministic applies are fine)"
+        )
+    if cfg.n_layers % cfg.pipeline_stages:
+        raise ValueError(
+            f"n_layers={cfg.n_layers} not divisible by "
+            f"pipeline_stages={cfg.pipeline_stages}"
+        )
+    fn_stages = getattr(cfg.pipeline_fn, "stages", None)
+    if fn_stages is not None and fn_stages != cfg.pipeline_stages:
+        # A mismatch would otherwise surface as an opaque scan
+        # axis-size error deep inside shard_map tracing.
+        raise ValueError(
+            f"pipeline_stages={cfg.pipeline_stages} != the "
+            f"pipeline_fn's mesh pp size {fn_stages}"
+        )
+    # Apply the SAME stacked params through the GPipe schedule: a
+    # standalone scan of length n_layers/pp has an identical param
+    # tree structure, so each stage applies its [L/pp, ...] slice.
+    chunk = nn.scan(
+        block_cls, length=cfg.n_layers // cfg.pipeline_stages,
+        **scan_kwargs,
+    )(cfg, mixer, ffn)
+    stacked = nn.meta.unbox(stack.variables["params"]["blocks"])
+
+    def apply_stage(stage_params, h):
+        y, _ = chunk.apply({"params": stage_params}, h, deterministic)
+        return y
+
+    # block_remat tells the pipeline whether the blocks already
+    # carry nn.remat (then its own stage checkpoint would double
+    # the backward recompute)
+    x = cfg.pipeline_fn(apply_stage, stacked, x,
+                        block_remat=cfg.remat)
+    return x, jnp.zeros((cfg.n_layers,), jnp.float32)
 
 
 class Transformer(nn.Module):
@@ -245,15 +514,19 @@ class Transformer(nn.Module):
             ),
             name="tok_emb",
         )
-        pos_emb = self.param(
-            "pos_emb",
-            nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.01), ("seq", "embed")
-            ),
-            (cfg.max_seq, cfg.d_model),
-        )
         seq = tokens.shape[1]
-        x = tok_emb(tokens) + jnp.asarray(pos_emb, dt)[None, :seq]
+        x = tok_emb(tokens)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, dt)
+        if cfg.position == "learned":
+            pos_emb = self.param(
+                "pos_emb",
+                nn.with_logical_partitioning(
+                    nn.initializers.normal(stddev=0.01), ("seq", "embed")
+                ),
+                (cfg.max_seq, cfg.d_model),
+            )
+            x = x + jnp.asarray(pos_emb, dt)[None, :seq]
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
         block_cls = Block
@@ -268,72 +541,38 @@ class Transformer(nn.Module):
                 if cfg.remat_policy == "dots" else None
             )
             block_cls = nn.remat(Block, prevent_cse=False, policy=policy)
-        # One traced block, scanned over a stacked 'layers' param axis.
+        # One traced block a run of equal layers, scanned over a stacked
+        # 'layers' param axis: `blocks` where the whole stack is one run
+        # (GPT-2, BERT), `blocks_<i>` where the pattern has several.
         scan_kwargs = dict(
             variable_axes={"params": 0},
             split_rngs={"params": True, "dropout": True},
             in_axes=(nn.broadcast,),
             metadata_params={nn.PARTITION_NAME: "layers"},
         )
-        scanned = nn.scan(block_cls, length=cfg.n_layers,
-                          **scan_kwargs)(cfg, name="blocks")
-        if cfg.pipeline_fn is None or self.is_initializing():
-            # plain (or init) path: params are created here with the
-            # stacked [n_layers, ...] layout the pipeline also expects
-            x, layer_aux = scanned(x, deterministic)
-        else:
-            if cfg.moe_experts:
-                raise NotImplementedError("MoE inside the pipeline")
-            if cfg.dropout and not deterministic:
-                # The stage apply below passes no rngs, so a non-
-                # deterministic dropout>0 apply would otherwise die with an
-                # opaque flax missing-'dropout'-rng error deep inside
-                # shard_map tracing. v1 pipeline scope is dropout-free at
-                # train time — say so. (Deterministic applies — eval,
-                # embedding extraction — need no rng and stay allowed.)
-                raise NotImplementedError(
-                    f"dropout={cfg.dropout} with pipeline_fn: the pipeline "
-                    "path applies stages without rngs (v1 trains "
-                    "dropout-free; deterministic applies are fine)"
-                )
-            if cfg.n_layers % cfg.pipeline_stages:
-                raise ValueError(
-                    f"n_layers={cfg.n_layers} not divisible by "
-                    f"pipeline_stages={cfg.pipeline_stages}"
-                )
-            fn_stages = getattr(cfg.pipeline_fn, "stages", None)
-            if fn_stages is not None and fn_stages != cfg.pipeline_stages:
-                # A mismatch would otherwise surface as an opaque scan
-                # axis-size error deep inside shard_map tracing.
-                raise ValueError(
-                    f"pipeline_stages={cfg.pipeline_stages} != the "
-                    f"pipeline_fn's mesh pp size {fn_stages}"
-                )
-            # Apply the SAME stacked params through the GPipe schedule: a
-            # standalone scan of length n_layers/pp has an identical param
-            # tree structure, so each stage applies its [L/pp, ...] slice.
-            chunk = nn.scan(
-                block_cls, length=cfg.n_layers // cfg.pipeline_stages,
-                **scan_kwargs,
-            )(cfg)
-            stacked = nn.meta.unbox(self.variables["params"]["blocks"])
-
-            def apply_stage(stage_params, h):
-                y, _ = chunk.apply({"params": stage_params}, h, deterministic)
-                return y
-
-            # block_remat tells the pipeline whether the blocks already
-            # carry nn.remat (then its own stage checkpoint would double
-            # the backward recompute)
-            x = cfg.pipeline_fn(apply_stage, stacked, x,
-                                block_remat=cfg.remat)
-            layer_aux = jnp.zeros((cfg.n_layers,), jnp.float32)
+        runs = cfg.runs
+        if cfg.pipeline_fn is not None and len(runs) > 1:
+            raise NotImplementedError(
+                "pipeline_fn over a stack of more than one run of layers")
+        aux_losses = []
+        for i, ((mixer, ffn), count) in enumerate(runs):
+            scanned = nn.scan(block_cls, length=count, **scan_kwargs)(
+                cfg, mixer, ffn,
+                name="blocks" if len(runs) == 1 else f"blocks_{i}")
+            if cfg.pipeline_fn is None or self.is_initializing():
+                # plain (or init) path: params are created here with the
+                # stacked [n_layers, ...] layout the pipeline also expects
+                x, layer_aux = scanned(x, deterministic)
+            else:
+                x, layer_aux = _pipelined(
+                    self, block_cls, scan_kwargs, mixer, ffn, x, deterministic)
+            aux_losses.append(jnp.sum(layer_aux))
         # Per-layer MoE load-balance losses (zeros for dense blocks); read
         # back by MoE loss fns via mutable=["intermediates"] — a no-op sow
         # for plain apply() calls.
-        self.sow("intermediates", "moe_aux_loss", jnp.sum(layer_aux))
+        self.sow("intermediates", "moe_aux_loss", sum(aux_losses[1:], aux_losses[0]))
 
-        x = _layernorm("ln_f", dtype=dt)(x)
+        x = _norm(cfg, "ln_f", dtype=dt)(x)
         if return_hidden:
             return x
         with jax.named_scope("lm_head"):
@@ -344,4 +583,7 @@ class Transformer(nn.Module):
                     cfg.vocab, ("embed", "vocab"), (), name="head",
                     use_bias=False,
                 )(x)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / jnp.asarray(cfg.logits_scaling,
+                                              logits.dtype)
         return logits

@@ -14,6 +14,13 @@ heads over ``tp``.
 
 Shapes follow the [batch, seq, heads, head_dim] convention throughout (the
 layout XLA prefers for TPU attention: contraction dims innermost).
+
+Grouped-query attention: ``k`` and ``v`` may carry fewer heads than ``q``;
+query head ``h`` then reads key/value head ``h // (heads / kv_heads)``. Both
+paths repeat the shared heads to the query's count in front of the score
+product (per shard under a mesh, so only the un-repeated heads cross the
+wrap) and the repeat's transpose sums their gradients: the kernels see
+``heads`` equal operands and stay as they are.
 """
 
 from __future__ import annotations
@@ -71,12 +78,25 @@ def _reference_attention(
     return out
 
 
-def _per_shard(fn, q: jax.Array):
+def _repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
+    """``k, v`` with each head repeated up to ``q``'s head count."""
+    heads, kv_heads = q.shape[2], k.shape[2]
+    if heads == kv_heads:
+        return k, v
+    if heads % kv_heads or v.shape[2] != kv_heads:
+        raise ValueError(f"{heads} query heads over {kv_heads} / "
+                         f"{v.shape[2]} key / value heads")
+    rep = heads // kv_heads
+    return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+
+
+def _per_shard(fn, q: jax.Array, k: jax.Array):
     """Wrap ``fn(q, k, v)`` in ``jax.shard_map`` over the context mesh when
     that mesh spans more than one device, else return it unchanged.
 
     Batch is split over the mesh's batch axes and heads over ``tp`` where
-    the sizes divide; an axis that does not divide (the batch-1 trace inside
+    the sizes divide (the query's heads AND the key/value heads, which may
+    be fewer); an axis that does not divide (the batch-1 trace inside
     ``model.init``) is left out of the specs, so those devices compute the
     whole of it — still the kernel, still no GSPMD partitioning of it. Axes
     that are already manual (a caller's own ``shard_map``: the pipeline,
@@ -95,11 +115,13 @@ def _per_shard(fn, q: jax.Array):
                 f"attention: batch {q.shape[0]} does not divide over mesh "
                 f"axes {batch}; every shard computes the whole batch")
         batch = ()
-    if heads and q.shape[2] % mesh.shape[heads]:
+    if heads and (q.shape[2] % mesh.shape[heads]
+                  or k.shape[2] % mesh.shape[heads]):
         log_once(
             log,
-            f"attention: {q.shape[2]} heads do not divide over "
-            f"{heads}={mesh.shape[heads]}; every shard computes all heads")
+            f"attention: {q.shape[2]} / {k.shape[2]} heads do not divide "
+            f"over {heads}={mesh.shape[heads]}; every shard computes all "
+            f"heads")
         heads = None
     spec = P(batch or None, None, heads, None)
     return jax.shard_map(fn, in_specs=(spec, spec, spec), out_specs=spec,
@@ -139,12 +161,18 @@ def multihead_attention(
             # flash_attention logs this drop itself; the reference path it
             # takes partitions under GSPMD, so no per-shard wrap.
             return flash_attention(
-                q, k, v, causal=causal, scale=scale, segment_ids=segment_ids
+                q, *_repeat_kv(q, k, v), causal=causal, scale=scale,
+                segment_ids=segment_ids
             )
-        kernel = functools.partial(flash_attention, causal=causal, scale=scale)
-        return _per_shard(kernel, q)(q, k, v)
+
+        def kernel(q, k, v):
+            return flash_attention(q, *_repeat_kv(q, k, v), causal=causal,
+                                   scale=scale)
+
+        return _per_shard(kernel, q, k)(q, k, v)
     if impl != "reference":
         raise ValueError(f"unknown attention impl {impl!r}")
     return _reference_attention(
-        q, k, v, causal=causal, scale=scale, segment_ids=segment_ids
+        q, *_repeat_kv(q, k, v), causal=causal, scale=scale,
+        segment_ids=segment_ids
     )

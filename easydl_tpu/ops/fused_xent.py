@@ -34,6 +34,7 @@ def fused_softmax_xent(
     *,
     ignore_id: int = -1,
     chunk_size: int = 128,
+    logit_scale: float = 1.0,
 ):
     """Mean next-token cross-entropy from final hidden states.
 
@@ -46,6 +47,8 @@ def fused_softmax_xent(
         contribute nothing to loss or denominator.
       chunk_size: sequence positions per scan step. Peak memory is
         ``B · chunk_size · V`` f32; 128 ≈ 1/8 the naive peak at seq 1024.
+      logit_scale: the logits are ``logit_scale * hidden @ head^T`` (a
+        model's ``1 / logits_scaling``), applied to the f32 products.
 
     Returns:
       ``(loss, denom)`` — mean f32 loss over unmasked positions and the
@@ -77,6 +80,8 @@ def fused_softmax_xent(
             dimension_numbers=(((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if logit_scale != 1.0:
+            logits = logits * logit_scale
         lse = jax.nn.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, t_safe[..., None], axis=-1)[..., 0]
         total, count = carry

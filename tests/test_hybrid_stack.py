@@ -1,0 +1,561 @@
+"""The stack described by data (``models/transformer.py``): GPT-2 unchanged
+from the commit before it, the Granite 4.0-H description against the plain
+float32 reference (``benchmark/lib/reference_granite_hybrid.py``, the Mamba-2
+layers as the sequential recurrence), the scan and its neighbours
+(``ops/ssd.py``), grouped-query attention, the head chosen by shape, the
+hybrid's state sharded, saved and restored, and its names in the step.
+
+CPU, small sizes, seeded weights. Tolerances: float32 against float32 is the
+same mathematics in another order of summation (1e-5 relative); bf16 operands
+against the float32 recurrence carry bf16's 8 bits through a few products
+(2-3e-2 relative RMS at these sizes).
+"""
+
+from __future__ import annotations
+
+import copy
+import functools
+import importlib
+import json
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from easydl_tpu.core.checkpoint import CheckpointManager
+from easydl_tpu.core.mesh import MeshSpec, build_mesh
+from easydl_tpu.core.sharding import flatten_dict, unbox
+from easydl_tpu.core.train_loop import TrainConfig, Trainer
+from easydl_tpu.models import gpt as gpt_module
+from easydl_tpu.models.granite_hybrid import describe
+from easydl_tpu.models.registry import get_model, list_models
+from easydl_tpu.ops import attention as attention_module
+from easydl_tpu.ops.flash_attention import flash_attention
+from easydl_tpu.ops.fused_xent import fused_softmax_xent
+from easydl_tpu.ops.ssd import (causal_conv1d, gated_rmsnorm,
+                                ssd_flops_per_token, ssd_scan)
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+BENCH = os.path.join(ROOT, "benchmark")
+sys.path.insert(0, HERE)
+from gpt2_fingerprint import fingerprint, step_program_sha256  # noqa: E402
+
+
+def _bench_lib(name):
+    """A module of ``benchmark/lib`` (the package is not on tier-1's path)."""
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    return importlib.import_module(f"lib.{name}")
+
+
+ref = _bench_lib("reference_granite_hybrid")
+check_module = _bench_lib("check_granite_hybrid")
+TEST = dict(size="test", seq_len=32, vocab=256)
+
+
+def rel(a, r):
+    a, r = np.asarray(a, np.float64), np.asarray(r, np.float64)
+    return float(np.linalg.norm(a - r) / max(np.linalg.norm(r), 1e-30))
+
+
+# ------------------------------------------------------------------ GPT-2
+@pytest.fixture(scope="module")
+def gpt2_now_and_then():
+    with open(os.path.join(HERE, "goldens", "gpt2_stack.json")) as f:
+        return fingerprint(), json.load(f)
+
+
+def test_gpt2_parameter_tree_is_the_parents(gpt2_now_and_then):
+    now, then = gpt2_now_and_then
+    assert now["axes"] == then["axes"]
+    assert {k: v["shape"] for k, v in now["params"].items()} \
+        == {k: v["shape"] for k, v in then["params"].items()}
+
+
+@pytest.mark.parametrize("what", ["params", "grads"])
+def test_gpt2_values_are_the_parents(gpt2_now_and_then, what):
+    """Seeded initial values and every gradient leaf: sums and absolute sums
+    recorded on the parent commit (the same jax, the same CPU)."""
+    now, then = gpt2_now_and_then
+    assert sorted(now[what]) == sorted(then[what])
+    for key, want in then[what].items():
+        got = now[what][key]
+        assert got["sum"] == pytest.approx(want["sum"], rel=1e-5, abs=1e-7), key
+        assert got["abs"] == pytest.approx(want["abs"], rel=1e-5), key
+
+
+def test_gpt2_loss_is_the_parents(gpt2_now_and_then):
+    now, then = gpt2_now_and_then
+    assert now["loss"] == pytest.approx(then["loss"], rel=1e-6)
+
+
+def test_gpt2_step_program_is_the_parents_op_for_op(gpt2_now_and_then):
+    """The lowered bf16 / remat-dots / two-microbatch step at the test size:
+    the same StableHLO text as on the parent commit."""
+    assert step_program_sha256() == gpt2_now_and_then[1]["step_program_sha256"]
+
+
+def test_gpt2_hint_is_the_all_attention_formula():
+    from easydl_tpu.core.mfu import model_flops_per_token
+
+    bundle = get_model("gpt", size="345m")
+    assert bundle.flops_per_sample_hint == model_flops_per_token(
+        bundle.param_count_hint, 24, 1024, 1024) * 1024
+
+
+# -------------------------------------------------------------- ops/ssd.py
+def _scan_inputs(seed, b, s, h, p, g, n, dtype):
+    r = np.random.default_rng(seed)
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    x = f32(r.normal(size=(b, s, h, p))).astype(dtype)
+    dt = jax.nn.softplus(f32(r.normal(size=(b, s, h)) - 1.0))
+    A = -jnp.exp(f32(r.uniform(0, 2.7, size=(h,))))
+    B = f32(r.normal(size=(b, s, g, n))).astype(dtype)
+    C = f32(r.normal(size=(b, s, g, n))).astype(dtype)
+    return x, dt, A, B, C, f32(r.normal(size=(h,)))
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("seq", [64, 50, 12], ids=["multiple", "ragged",
+                                                   "under-a-chunk"])
+def test_chunked_scan_equals_the_recurrence(seq, dtype, tol):
+    """Forward and all six gradients; the recurrence is the reference's
+    ``lax.scan`` over positions, in float32 on the same (rounded) inputs."""
+    args = _scan_inputs(seq, 2, seq, 4, 8, 2, 16, jnp.dtype(dtype))
+    as32 = [a.astype(jnp.float32) for a in args]
+    with jax.default_matmul_precision("highest"):
+        y = ssd_scan(*args, chunk=16)
+        y_ref = ref.recurrence(*as32)
+        assert y.dtype == args[0].dtype and y.shape == args[0].shape
+        assert rel(y, y_ref) < tol
+        weights = jnp.asarray(np.random.default_rng(1).normal(size=y.shape),
+                              jnp.float32)
+        grads = jax.grad(lambda *a: (ssd_scan(*a, chunk=16) * weights).sum(),
+                         argnums=range(6))(*args)
+        grads_ref = jax.grad(lambda *a: (ref.recurrence(*a) * weights).sum(),
+                             argnums=range(6))(*as32)
+    for name, g, g_ref in zip("x dt A B C D".split(), grads, grads_ref):
+        assert rel(g, g_ref) < 2 * tol, name
+
+
+def test_scan_keeps_its_decay_sums_in_float32():
+    """bf16 inputs at the published chunk (256) with Mamba-2's own ranges of
+    dt (1e-3 to 1e-1) and A (-1 to -16): the cumulative log-decay reaches
+    hundreds, where bf16 resolves to 2 and the decay matrix is noise. With
+    the sums in float32 the result is off by the output's own bf16 rounding,
+    1.7e-3; with them in bf16 it was 1.3e-2 (tried while writing this)."""
+    r = np.random.default_rng(0)
+    seq, h, p, n = 512, 4, 16, 32
+    x = jnp.asarray(r.normal(size=(1, seq, h, p)), jnp.bfloat16)
+    dt0 = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), h))
+    dt = jax.nn.softplus(jnp.asarray(
+        r.normal(size=(1, seq, h)) * 0.5 + np.log(np.expm1(dt0)), jnp.float32))
+    A = -jnp.asarray(np.linspace(1, 16, h), jnp.float32)
+    B, C = (jnp.asarray(r.normal(size=(1, seq, 1, n)) / n ** 0.25,
+                        jnp.bfloat16) for _ in range(2))
+    args = (x, dt, A, B, C, jnp.ones((h,), jnp.float32))
+    y = ssd_scan(*args, chunk=256)
+    y_ref = ref.recurrence(*[a.astype(jnp.float32) for a in args])
+    assert rel(y, y_ref) < 4e-3
+
+
+def test_causal_conv1d_is_a_depthwise_causal_convolution():
+    r = np.random.default_rng(0)
+    x = r.normal(size=(2, 9, 3, 5)).astype(np.float32)
+    w = r.normal(size=(4, 3, 5)).astype(np.float32)
+    b = r.normal(size=(3, 5)).astype(np.float32)
+    want = np.zeros_like(x)
+    for t in range(9):
+        for k in range(4):
+            if t - 3 + k >= 0:
+                want[:, t] += w[k] * x[:, t - 3 + k]
+    want += b
+    np.testing.assert_allclose(causal_conv1d(jnp.asarray(x), jnp.asarray(w),
+                                             jnp.asarray(b)), want, atol=1e-5)
+
+
+def test_gated_rmsnorm_normalises_the_gated_product_over_all_channels():
+    r = np.random.default_rng(0)
+    y, z = r.normal(size=(2, 2, 5, 3, 4)).astype(np.float32)
+    w = r.normal(size=(3, 4)).astype(np.float32)
+    g = y * (z / (1 + np.exp(-z)))
+    want = g / np.sqrt((g ** 2).mean(axis=(-2, -1), keepdims=True) + 1e-5) * w
+    np.testing.assert_allclose(
+        gated_rmsnorm(jnp.asarray(y), jnp.asarray(z), jnp.asarray(w), 1e-5),
+        want, rtol=1e-5, atol=1e-6)
+
+
+def test_scan_flop_count_by_hand():
+    # chunk 256, 64 heads of 64, one group of 128: 2*256*128 + 2*256*64*64
+    # + 4*64*128*64
+    assert ssd_flops_per_token(64, 64, 128, 1, 256) \
+        == 65_536 + 2_097_152 + 2_097_152
+
+
+# ------------------------------------------------- grouped-query attention
+def _qkv(heads, kv_heads, seq=128, dtype=jnp.float32):
+    r = np.random.default_rng(0)
+    mk = lambda h: jnp.asarray(r.normal(size=(2, seq, h, 32)), dtype)  # noqa: E731
+    return mk(heads), mk(kv_heads), mk(kv_heads)
+
+
+@pytest.mark.parametrize("impl", ["reference", "flash"])
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 2), (4, 1)])
+def test_grouped_query_attention_reads_head_h_over_r(monkeypatch, impl, heads,
+                                                     kv_heads):
+    """Both paths (the kernel in interpret mode) against attention on
+    explicitly repeated key/value heads, forward and gradients; the shared
+    heads' gradients are the sums over their query heads."""
+    monkeypatch.setattr(attention_module, "flash_attention",
+                        functools.partial(flash_attention, interpret=True))
+    q, k, v = _qkv(heads, kv_heads)
+    rep = heads // kv_heads
+
+    def grouped(q, k, v):
+        return attention_module.multihead_attention(
+            q, k, v, causal=True, scale=0.2, impl=impl)
+
+    def repeated(q, k, v):
+        return attention_module._reference_attention(
+            q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+            causal=True, scale=0.2)
+
+    np.testing.assert_allclose(grouped(q, k, v), repeated(q, k, v), atol=2e-5)
+    weights = jnp.asarray(np.random.default_rng(1).normal(size=q.shape),
+                          jnp.float32)
+    got = jax.grad(lambda *a: (grouped(*a) * weights).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (repeated(*a) * weights).sum(),
+                    (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and rel(g, w) < 5e-4
+
+
+def test_heads_that_do_not_group_are_refused():
+    q, k, v = _qkv(4, 3)
+    with pytest.raises(ValueError, match="query heads"):
+        attention_module.multihead_attention(q, k, v, impl="reference")
+
+
+def test_per_shard_wrap_splits_heads_only_where_both_counts_divide(
+        monkeypatch, eight_devices):
+    """tp=4 over 8 query heads and 2 key/value heads: the query's divide,
+    the shared ones do not, so every shard computes all heads; under tp=2
+    both divide. Either way the result is the one-device one."""
+    monkeypatch.setattr(attention_module, "flash_attention",
+                        functools.partial(flash_attention, interpret=True))
+    q, k, v = _qkv(8, 2)
+    want = attention_module._reference_attention(
+        q, jnp.repeat(k, 4, axis=2), jnp.repeat(v, 4, axis=2), causal=True,
+        scale=0.2)
+    for spec in (MeshSpec(dp=2, tp=4), MeshSpec(dp=2, tp=2)):
+        mesh = build_mesh(spec, devices=eight_devices[:spec.size])
+        with jax.set_mesh(mesh):
+            got = jax.jit(functools.partial(
+                attention_module.multihead_attention, causal=True, scale=0.2,
+                impl="flash"))(q, k, v)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# ------------------------------------------------------ the head, by shape
+@pytest.mark.parametrize("scale", [1.0, 0.125])
+def test_fused_head_with_logit_scale_equals_full_logits(scale):
+    r = np.random.default_rng(0)
+    hidden = jnp.asarray(r.normal(size=(2, 40, 16)), jnp.float32)
+    head = jnp.asarray(r.normal(size=(64, 16)), jnp.float32)
+    targets = jnp.asarray(r.integers(0, 64, (2, 40)), jnp.int32)
+
+    def full(hidden, head):
+        return gpt_module.lm_loss(
+            jnp.einsum("bsd,vd->bsv", hidden, head) * scale, targets)[0]
+
+    def fused(hidden, head):
+        return fused_softmax_xent(hidden, head, targets, chunk_size=16,
+                                  logit_scale=scale)[0]
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(fused(hidden, head), full(hidden, head),
+                                   rtol=1e-6)
+        for g, w in zip(jax.grad(fused, (0, 1))(hidden, head),
+                        jax.grad(full, (0, 1))(hidden, head)):
+            assert rel(g, w) < 1e-5
+
+
+@pytest.mark.parametrize("shape,mesh,fused", [
+    ((8, 1024, 50304), None, False),      # gpt2-medium's microbatch: 1.5 GiB
+    ((2, 4096, 100352), None, True),      # the hybrid's: 3.1 GiB
+    ((16, 1024, 50304), "fsdp=4", False),  # gpt2-xl: 4 rows a chip
+    ((16, 1024, 50304), None, True),      # the same rows on one device
+    ((2, 1024, 100352), None, False),     # the check's gradient prefix
+])
+def test_head_is_chosen_by_one_devices_share_of_the_logits(
+        eight_devices, shape, mesh, fused):
+    if mesh is None:
+        assert gpt_module.fused_head_by_shape(*shape) is fused
+        return
+    spec = MeshSpec.parse(mesh)
+    with jax.set_mesh(build_mesh(spec, devices=eight_devices[:spec.size])):
+        assert gpt_module.fused_head_by_shape(*shape) is fused
+
+
+def test_hybrid_loss_is_the_same_through_either_head(monkeypatch):
+    bundle = get_model("granite_hybrid", **TEST)
+    params = unbox(bundle.init_fn(jax.random.PRNGKey(0)))
+    tokens = np.random.default_rng(0).integers(0, 256, (2, 33), np.int32)
+    batch = {"inputs": jnp.asarray(tokens[:, :-1]),
+             "targets": jnp.asarray(tokens[:, 1:])}
+    f = jax.value_and_grad(lambda p: bundle.loss_fn(p, batch, None)[0])
+    full, g_full = f(params)
+    monkeypatch.setattr(gpt_module, "FUSED_HEAD_LOGITS_BYTES", 0)
+    fused, g_fused = f(params)
+    assert float(fused) == pytest.approx(float(full), rel=1e-5)
+    for key, g in flatten_dict(g_fused).items():
+        assert rel(g, flatten_dict(g_full)[key]) < 1e-4, key
+
+
+# ---------------------------------------- the program against the reference
+def _config(dtype):
+    with open(os.path.join(BENCH, "configs", "granite-test.json")) as f:
+        config = copy.deepcopy(json.load(f))
+    config["kwargs"]["dtype"] = dtype
+    return config
+
+
+def _check(dtype, compute_dtype, tolerances=None, seed=0):
+    config = _config(dtype)
+    if tolerances:
+        config["check"]["tolerances"] = tolerances
+    bundle = get_model(config["factory"], **config["kwargs"])
+    trainer = Trainer(
+        init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+        optimizer=optax.adamw(1e-3),
+        config=TrainConfig(global_batch=2, compute_dtype=compute_dtype,
+                           seed=seed),
+        mesh=build_mesh(MeshSpec.parse("dp=1"), devices=jax.devices()[:1]))
+    return check_module.check(config, bundle, trainer, seed)
+
+
+TIGHT = {"loss_abs": 2e-5, "hidden_rel_rms": 2e-5, "grad_rel_rms_worst": 2e-4}
+
+
+@pytest.mark.parametrize("seed", [0, 7])
+def test_float32_hybrid_equals_the_reference_to_rounding(seed):
+    """Two Mamba-2 layers and one attention layer: loss, final hidden state
+    and EVERY gradient leaf (the worst is reported) of the chunked, split
+    program against the sequential, fused reference."""
+    result = _check("float32", jnp.float32, TIGHT, seed=seed)
+    assert result["ok"], result
+    assert result["errors"]["grad_rel_rms_all"] > 0  # it did compare
+
+
+def test_bf16_hybrid_sits_inside_the_files_tolerances():
+    result = _check("bfloat16", jnp.bfloat16)
+    assert result["ok"], result
+    assert result["errors"]["hidden_rel_rms"] > 1e-3  # bf16 is visible
+
+
+def test_a_lower_precision_than_stated_fails_the_hybrid_check():
+    result = _check("bfloat16", jnp.bfloat16, TIGHT)
+    assert not result["ok"], result
+
+
+def test_to_reference_rebuilds_the_published_fused_layout():
+    params = unbox(get_model("granite_hybrid", **TEST).init_fn(
+        jax.random.PRNGKey(0)))
+    plain = check_module.to_reference(
+        params, ["mamba", "mamba", "attention"])
+    mamba, attn = plain["layers"][1], plain["layers"][2]
+    # test size: 4 heads of 16 (inner 64), 2 groups of 16, d_model 64
+    assert mamba["in_proj"].shape == (64, 2 * 64 + 2 * 32 + 4)
+    assert mamba["conv_w"].shape == (4, 64 + 2 * 32)
+    assert mamba["conv_b"].shape == (128,)
+    assert mamba["out_proj"].shape == (64, 64)
+    assert mamba["w_in"].shape == (64, 256)
+    assert attn["wq"].shape == (64, 4, 16) and attn["wk"].shape == (64, 2, 16)
+    np.testing.assert_array_equal(
+        mamba["in_proj"][:, 64:128],
+        params["blocks_0"]["in_x"]["kernel"][1].reshape(64, 64))
+
+
+# ------------------------------------------------- counts, hints, registry
+def test_registry_builds_the_hybrid_like_any_model():
+    assert "granite_hybrid" in list_models()
+    bundle = get_model("granite_hybrid", **TEST)
+    assert bundle.name == "granite-4.0-h-test-3l"
+    data = bundle.make_data(4, seed=0)
+    batch = next(iter(data))
+    assert batch["inputs"].shape == (4, 32)
+
+
+@pytest.mark.parametrize("layers,millions", [
+    (("mamba",) * 5 + ("attention",), 647.259328),   # the benchmark's slice
+    (None, 3191.396096),                             # as published
+])
+def test_hybrid_parameter_count_is_exact(layers, millions):
+    cfg = describe(layer_types=layers)
+    assert cfg.param_count == round(millions * 1e6)
+    bundle = get_model("granite_hybrid", layer_types=layers)
+    shapes = jax.eval_shape(bundle.init_fn, jax.random.PRNGKey(0))
+    real = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(unbox(shapes)))
+    assert real == cfg.param_count == bundle.param_count_hint
+
+
+def test_hybrid_flops_hint_counts_no_scores_for_mamba_layers():
+    cfg = describe(layer_types=("mamba",) * 5 + ("attention",))
+    per_token = (6.0 * cfg.param_count + 12.0 * 1 * 2048 * 4096
+                 + 3.0 * 5 * ssd_flops_per_token(64, 64, 128, 1, 256))
+    bundle = get_model("granite_hybrid",
+                       layer_types=("mamba",) * 5 + ("attention",))
+    assert bundle.flops_per_sample_hint == per_token * 4096
+    # 32,768 tokens a step: the issue's 133 TFLOP
+    assert per_token * 32768 == pytest.approx(132.6e12, rel=2e-3)
+    # and not 6N + 12 L d s
+    assert per_token < 6.0 * cfg.param_count + 12.0 * 6 * 2048 * 4096
+
+
+def test_models_run_trains_the_hybrid_from_the_command_line(tmp_path):
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    proc = subprocess.run(
+        [sys.executable, "-m", "easydl_tpu.models.run", "--model",
+         "granite_hybrid", "--steps", "3", "--batch", "4", "--model-arg",
+         "size=test", "--model-arg", "seq_len=32", "--model-arg", "vocab=256",
+         "--ckpt-dir", str(tmp_path / "ckpt"), "--ckpt-every", "2"],
+        cwd=str(tmp_path), env=env, capture_output=True, text=True,
+        timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert (tmp_path / "ckpt").exists()
+
+
+def test_runs_group_equal_neighbours():
+    cfg = describe(size="test",
+                   layer_types=("mamba", "mamba", "attention", "mamba"))
+    assert cfg.runs == ((("mamba2", "swiglu"), 2), (("attention", "swiglu"), 1),
+                        (("mamba2", "swiglu"), 1))
+    tree = unbox(get_model(
+        "granite_hybrid", **TEST,
+        layer_types=("mamba", "mamba", "attention", "mamba")
+    ).init_fn(jax.random.PRNGKey(0)))
+    assert sorted(k for k in tree if k.startswith("blocks")) \
+        == ["blocks_0", "blocks_1", "blocks_2"]
+    assert "pos_emb" not in tree and "bias" not in tree["blocks_1"]["q"]
+
+
+@pytest.mark.parametrize("bad", [
+    dict(layers=(("attention", "gelu"),), n_layers=2),
+    dict(layers=(("mamba2", "swiglu"),), n_layers=1),          # no ssm widths
+    dict(layers=(("conv", "gelu"),), n_layers=1),
+    dict(position="rotary"),
+    dict(n_heads=4, n_kv_heads=3, d_model=64),
+])
+def test_a_description_that_does_not_hold_together_is_refused(bad):
+    from easydl_tpu.models.transformer import TransformerConfig
+
+    with pytest.raises(ValueError):
+        TransformerConfig(**bad)
+
+
+# ------------------------------------------ sharded, saved, restored, named
+def _hybrid_trainer(spec, devices, **kwargs):
+    bundle = get_model("granite_hybrid", **TEST, **kwargs)
+    return Trainer(
+        init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+        optimizer=optax.adamw(1e-3),
+        config=TrainConfig(global_batch=4, seed=3),
+        mesh=build_mesh(spec, devices=devices[:spec.size])), bundle
+
+
+@pytest.fixture(scope="module")
+def one_device_step(eight_devices):
+    trainer, bundle = _hybrid_trainer(MeshSpec(), eight_devices)
+    batch = next(iter(bundle.make_data(4, seed=5)))
+    state, metrics = trainer.train_step(trainer.init_state(), batch)
+    return batch, float(metrics["loss"]), jax.device_get(unbox(state.params))
+
+
+@pytest.mark.parametrize("mesh,sharded", [
+    ("fsdp=2", {"blocks_0/in_x/kernel": "fsdp", "tok_emb/embedding": "fsdp"}),
+    ("tp=2", {"blocks_0/in_x/kernel": "tp", "blocks_0/conv_x": "tp",
+              "blocks_0/A_log": "tp", "blocks_1/k/kernel": "tp",
+              "blocks_0/gate/kernel": "tp"}),
+    ("fsdp=2,tp=2", {"blocks_0/out/kernel": "tp"}),
+])
+def test_sharded_hybrid_steps_like_one_device(eight_devices, one_device_step,
+                                              mesh, sharded):
+    batch, loss, params = one_device_step
+    trainer, _ = _hybrid_trainer(MeshSpec.parse(mesh), eight_devices)
+    specs = flatten_dict(jax.tree.map(lambda s: str(s.spec),
+                                      trainer.state_shardings().params))
+    for key, axis in sharded.items():
+        assert axis in specs[key], (key, specs[key])
+    for whole in ("blocks_0/in_B/kernel", "blocks_0/conv_C"):
+        assert "tp" not in specs[whole], (whole, specs[whole])
+    state, metrics = trainer.train_step(trainer.init_state(), batch)
+    assert float(metrics["loss"]) == pytest.approx(loss, rel=1e-5)
+    got = flatten_dict(jax.device_get(unbox(state.params)))
+    for key, want in flatten_dict(params).items():
+        np.testing.assert_allclose(got[key], want, atol=2e-5, err_msg=key)
+
+
+def test_hybrid_state_survives_the_checkpoint_manager(tmp_path,
+                                                      eight_devices):
+    """Saved under fsdp=2, restored under tp=2: every leaf equal, and the
+    next step's loss too."""
+    t1, bundle = _hybrid_trainer(MeshSpec(fsdp=2), eight_devices)
+    batch = next(iter(bundle.make_data(4, seed=5)))
+    s1, _ = t1.train_step(t1.init_state(), batch)
+    mgr = CheckpointManager(str(tmp_path), async_save=False)
+    mgr.save(1, s1, metadata={"mesh": "fsdp=2"})
+    t2, _ = _hybrid_trainer(MeshSpec(tp=2), eight_devices)
+    abstract, _, _ = t2._abstract_state()
+    s2 = mgr.restore(1, abstract, t2.state_shardings())
+    for a, b in zip(jax.tree.leaves(unbox(s1.params)),
+                    jax.tree.leaves(unbox(s2.params))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _, m1 = t1.train_step(s1, batch)
+    _, m2 = t2.train_step(s2, batch)
+    assert float(m2["loss"]) == pytest.approx(float(m1["loss"]), rel=1e-5)
+
+
+@pytest.fixture(scope="module")
+def hybrid_step_paths():
+    bundle = get_model("granite_hybrid", **TEST, dtype="bfloat16", remat=True,
+                       remat_policy="full")
+    trainer = Trainer(
+        init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+        optimizer=optax.adamw(1e-3),
+        config=TrainConfig(global_batch=4, grad_accum=2),
+        mesh=build_mesh(MeshSpec(), devices=jax.devices()[:1]))
+    tokens = jax.ShapeDtypeStruct((4, 32), jnp.int32)
+    text = trainer.step_fn.lower(
+        trainer.abstract_state(), {"inputs": tokens, "targets": tokens}
+    ).as_text(debug_info=True)
+    return set(re.findall(r'loc\("([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", ["ssm", "ssm/ssd", "ssm/conv1d",
+                                   "attention", "ffn"])
+@pytest.mark.parametrize("where", ["forward", "backward", "recomputed"])
+def test_hybrid_scopes_in_every_pass(hybrid_step_paths, scope, where):
+    """The lowered module keeps a scanned block's body as a function of its
+    own, with paths relative to the call (tests/test_step_scopes.py joins
+    them): the forward's start at the run's name, the backward of a
+    rematerialised block under ``checkpoint/`` and its second forward under
+    ``checkpoint/rematted_computation/``."""
+    run = "blocks_1" if scope == "attention" else "blocks_0"
+    own = [p for p in hybrid_step_paths
+           if re.search(rf"(^|/){run}/{scope}(/|$)", p)]
+    if scope == "ffn":
+        own = [p for p in hybrid_step_paths if re.search(r"/ffn(/|$)", p)]
+    assert own, scope
+    prefix = {"forward": r"^blocks_\d/",
+              "backward": r"^checkpoint/blocks_\d/",
+              "recomputed": r"^checkpoint/rematted_computation/blocks_\d/"}
+    assert any(re.search(prefix[where], p) for p in own), own[:8]
