@@ -8,7 +8,6 @@ to a multiple of 128 so the embedding/logits matmuls tile cleanly on the MXU.
 
 from __future__ import annotations
 
-import math
 from typing import Dict, Tuple
 
 import jax
@@ -16,9 +15,10 @@ import jax.numpy as jnp
 import optax
 
 from easydl_tpu.core.data import SyntheticTokens
-from easydl_tpu.core.mesh_shapes import BATCH_AXES
 from easydl_tpu.models.registry import ModelBundle, register_model
 from easydl_tpu.models.transformer import Transformer, TransformerConfig
+from easydl_tpu.ops.fused_xent import fused_softmax_xent, local_batch
+from easydl_tpu.utils.logging import get_logger, log_once
 
 #: name -> (n_layers, d_model, n_heads)
 SIZES: Dict[str, Tuple[int, int, int]] = {
@@ -45,29 +45,29 @@ def lm_loss(logits, targets, ignore_id: int = -1):
 
 #: The chunked fused head (ops/fused_xent.py) takes the place of full logits
 #: when ONE device's share of a microbatch's ``[B, S, V]`` float32 logits
-#: would pass this: an eighth of a v5e chip's 16 GB. The fused head computes
-#: every chunk's logits twice (its backward recomputes them) and was the
-#: slower side wherever both fit (ROADMAP Speed 5c), so it is chosen for the
-#: room, not the time: GPT-2 at 8 x 1024 x 50304 (1.5 GiB) keeps full
-#: logits, the Granite hybrid at 2 x 4096 x 100352 (3.1 GiB, beside 1.5 GiB
-#: of bf16 logits) does not fit them.
+#: would pass this: an eighth of a v5e chip's 16 GB. It is chosen for the
+#: room: GPT-2 at 8 x 1024 x 50304 (1.5 GiB) keeps full logits, the Granite
+#: hybrid at 2 x 4096 x 100352 (3.1 GiB, beside 1.5 GiB of bf16 logits) does
+#: not fit them. The fused head forms the loss and both gradients in one
+#: pass over each chunk's logits; whether it also beats full logits where
+#: both fit has not been measured since it stopped recomputing them. Which
+#: head a shape gets is decided here; how the fused head cuts the sequence
+#: into chunks is its own matter (``fused_xent.chunk_positions``), unless
+#: ``loss_chunk`` names a chunk's positions.
 FUSED_HEAD_LOGITS_BYTES = 2 * 1024 ** 3
+
+log = get_logger("models", "gpt")
 
 
 def fused_head_by_shape(batch: int, seq: int, vocab: int) -> bool:
     """The rule above, for logits of ``[batch, seq, vocab]`` as the loss
     function sees them under the context mesh (the one ``Trainer`` enters):
     the batch is split over the mesh's batch axes where it divides."""
-    mesh = jax.sharding.get_abstract_mesh()
-    shards = math.prod(mesh.shape[a] for a in BATCH_AXES
-                       if a in mesh.axis_names)
-    if batch % shards:
-        shards = 1
-    return 4 * (batch // shards) * seq * vocab > FUSED_HEAD_LOGITS_BYTES
+    return 4 * local_batch(batch) * seq * vocab > FUSED_HEAD_LOGITS_BYTES
 
 
 def lm_bundle(cfg: TransformerConfig, name: str, *, fused_loss=None,
-              loss_chunk: int = 128, moe_aux_weight: float = 0.01
+              loss_chunk=None, moe_aux_weight: float = 0.01
               ) -> ModelBundle:
     """The causal-LM bundle of one description of the stack: init, loss
     (full logits, or the fused chunked head: ``fused_loss`` True / False
@@ -92,8 +92,6 @@ def lm_bundle(cfg: TransformerConfig, name: str, *, fused_loss=None,
         if fused is None:
             fused = fused_head_by_shape(*batch["inputs"].shape, vocab)
         if fused and cfg.tied_head:
-            from easydl_tpu.ops.fused_xent import fused_softmax_xent
-
             out = model.apply(
                 {"params": params}, batch["inputs"], return_hidden=True,
                 **({"mutable": ["intermediates"]} if mutable else {}),
@@ -114,6 +112,8 @@ def lm_bundle(cfg: TransformerConfig, name: str, *, fused_loss=None,
                     logit_scale=1.0 / cfg.logits_scaling,
                 )
         else:
+            log_once(log, f"lm head: full logits "
+                          f"{[*batch['inputs'].shape, vocab]} in float32")
             out = model.apply(
                 {"params": params}, batch["inputs"],
                 **({"mutable": ["intermediates"]} if mutable else {}),
@@ -175,7 +175,7 @@ def make_gpt(
     moe_aux_weight: float = 0.01,
     moe_capacity_factor: float = 1.25,
     fused_loss=None,
-    loss_chunk: int = 128,
+    loss_chunk=None,
     pipeline_fn=None,
     pipeline_stages: int = 0,
 ) -> ModelBundle:

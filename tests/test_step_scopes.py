@@ -122,6 +122,30 @@ def test_fused_head_and_loss_are_one_scope(step_paths):
     assert not any(re.search(r"\blm_head/|jvp\(loss\)", p) for p in paths)
 
 
+@pytest.mark.parametrize("grad_accum", [1, 2])
+def test_fused_head_runs_in_the_forward_pass_and_recomputes_nothing(
+        grad_accum):
+    """The one-pass head (a ``custom_vjp``): its three products a chunk are
+    the forward rule's and read as ``jvp(lm_head_loss)``; the backward rule
+    only scales the two finished gradients; nothing of it is recomputed.
+    Read from the COMPILED step's ``op_name``s, which XLA composes whole."""
+    trainer = _trainer(grad_accum, fused_loss=True, loss_chunk=16)
+    tokens = jax.ShapeDtypeStruct((BATCH, SEQ), jnp.int32)
+    text = trainer.step_fn.lower(
+        trainer.abstract_state(), {"inputs": tokens, "targets": tokens}
+    ).compile().as_text()
+    own = {p for p in re.findall(r'op_name="([^"]*)"', text)
+           if "lm_head_loss" in p}
+    products = [p for p in own if p.endswith("/dot_general")]
+    assert products and all(
+        re.search(r"/jvp\(lm_head_loss\)/while/body/", p) for p in products)
+    assert not any("rematted_computation" in p or "checkpoint" in p
+                   for p in own), own
+    backward = {p.rsplit("/", 1)[1] for p in own
+                if "transpose(jvp(lm_head_loss))" in p}
+    assert backward <= {"mul", "convert_element_type"}, backward
+
+
 @pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dkv"])
 def test_flash_kernels_carry_their_names(kernel):
