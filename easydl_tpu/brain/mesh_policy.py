@@ -27,9 +27,8 @@ Pure by design, same contract as ``brain/policy.py`` /
 every query carries an explicit ``now`` — so the exact same object runs
 inside the live master's tick loop AND inside the offline control-plane
 simulator, and replay verdicts stay byte-identical. The throughput
-signal it consumes is the same one the ``easydl_worker_mfu`` gauge and
-``bench.py`` report: one MFU definition
-(:mod:`easydl_tpu.core.mfu`), three readers.
+signal it consumes is the one the ``easydl_worker_mfu`` gauge reports:
+the program's one MFU definition (:mod:`easydl_tpu.core.mfu`).
 """
 
 from __future__ import annotations

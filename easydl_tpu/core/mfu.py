@@ -1,13 +1,15 @@
-"""Model-FLOP-utilisation: ONE definition shared by the bench and the
-live fleet.
+"""Model-FLOP-utilisation: the program's ONE definition, for the live
+fleet.
 
 MFU = achieved model FLOP/s / the chip's peak dense FLOP/s. The numerator
 uses the PaLM appendix-B accounting (:func:`model_flops_per_token`); the
-denominator comes from :func:`peak_flops_per_chip`. Both ``bench.py`` and
-the elastic worker (which stamps ``mfu`` into its step-metrics records,
-surfaced live as the ``easydl_worker_mfu`` gauge) read THESE functions, so
-the number the Brain's mesh-shape policy sees and the number the bench
-artifact reports can never silently diverge.
+denominator comes from :func:`peak_flops_per_chip`. The elastic worker
+stamps ``mfu`` into its step-metrics records with THESE functions, the
+agent surfaces it live as the ``easydl_worker_mfu`` gauge, and the Brain's
+mesh-shape policy reads the throughput it normalises. The benchmark has
+its own count and its own table of peaks under ``benchmark/lib/``, by
+design independent of the program: the instrument imports nothing it
+measures.
 
 The denominator is never a guess: a ``device_kind`` the table does not
 know raises (a CPU has no peak to normalise by, and a new chip's peak must

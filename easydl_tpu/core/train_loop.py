@@ -86,11 +86,6 @@ class TrainState(struct.PyTreeNode):
 class TrainConfig:
     global_batch: int = 32
     grad_accum: int = 1
-    #: lax.scan unroll for the accumulation loop: unrolling lets XLA fuse
-    #: the scan carry's gradient adds across ``accum_unroll`` microbatches.
-    #: A hypothesis — the profile that priced it was retracted, and it has
-    #: not been measured on a chip (ROADMAP Speed 5).
-    accum_unroll: int = 1
     compute_dtype: Any = jnp.bfloat16
     seed: int = 0
     rules: Sequence[Tuple[str, Any]] = field(default_factory=lambda: shd.DEFAULT_RULES)
@@ -239,7 +234,6 @@ class Trainer:
             rest = jax.tree.map(lambda x: x[1:], microbatches)
             (loss_sum, aux_sum, grad_sum), _ = jax.lax.scan(
                 body, (loss0, aux0, grads0), (rest, jnp.arange(1, accum)),
-                unroll=max(self.config.accum_unroll, 1),
             )
             scale = 1.0 / accum
             with jax.named_scope("accumulate"):

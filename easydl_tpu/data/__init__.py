@@ -21,4 +21,5 @@ from easydl_tpu.data.images import (  # noqa: F401
     import_image_folder,
     read_idx,
 )
+from easydl_tpu.data.source import open_dataset, restore_cursor  # noqa: F401
 from easydl_tpu.data.tokenizer import ByteBpeTokenizer  # noqa: F401

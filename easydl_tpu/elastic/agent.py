@@ -156,10 +156,9 @@ class Agent:
         self._m_worker_step_time = reg.gauge(
             "easydl_agent_worker_step_time_seconds", "Worker-reported step "
             "wall time.", ("agent",))
-        # One MFU definition, three readers (core/mfu.py): the worker
-        # stamps "mfu" into its step records, this gauge surfaces it live,
-        # and bench.py reports the same formula — the Brain's
-        # mesh-shape policy and the bench artifact can never diverge.
+        # One MFU definition (core/mfu.py): the worker stamps "mfu" into
+        # its step records, this gauge surfaces it live, and the Brain's
+        # mesh-shape policy reads the throughput it normalises.
         self._m_worker_mfu = reg.gauge(
             "easydl_worker_mfu", "Worker-reported model-FLOP utilisation "
             "(achieved model FLOP/s over n_chips x peak; 0 when the model "

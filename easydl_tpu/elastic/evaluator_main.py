@@ -25,7 +25,6 @@ import argparse
 import json
 import os
 import time
-from types import SimpleNamespace
 
 
 def main() -> None:
@@ -89,15 +88,15 @@ def main() -> None:
 
     val_fraction = float(cfg.get("val_fraction", 0.0))
     if cfg.get("data_dir"):
-        from easydl_tpu.models.run import file_data
+        from easydl_tpu.data import open_dataset
 
-        ns = SimpleNamespace(data_dir=cfg["data_dir"], batch=global_batch,
-                             seq_len=int(cfg.get("seq_len", 0)),
-                             val_fraction=val_fraction)
         # a real holdout when the job carved one; otherwise a different
-        # shuffle order than training (seed_offset=1)
-        data = iter(file_data(ns, bundle, seed_offset=1,
-                              split="val" if val_fraction else "train"))
+        # shuffle order than training (seed 1)
+        data = iter(open_dataset(
+            cfg["data_dir"], bundle, batch_size=global_batch,
+            seq_len=int(cfg.get("seq_len", 0)), seed=1,
+            split="val" if val_fraction else "train",
+            val_fraction=val_fraction))
     else:
         data = iter(bundle.make_data(global_batch, seed=1))
 

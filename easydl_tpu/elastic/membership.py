@@ -478,8 +478,8 @@ class Rendezvous:
         if self._mesh_reshape_pending:
             # Same membership, new mesh factorization: a PLANNED reshape
             # (members quiesce at a step boundary, restore resharded onto
-            # the new shape — checkpoint bit-parity across shapes is the
-            # MULTICHIP dry-run's standing proof).
+            # the new shape — checkpoint bit-parity across shapes is held
+            # by tests/test_mesh_shapes.py).
             return True, True, "mesh-shape"
         return False, True, "plan-change"
 

@@ -508,53 +508,27 @@ def run_worker(env: Dict[str, str]) -> int:
                                           30.0)),
         )
         if latest >= 0:
-            data_state = ckpt.metadata(latest).get("metadata", {}).get(
-                "data_state"
-            )
-            if data_state:
-                data_source.restore_state(data_state)
+            from easydl_tpu.data import restore_cursor
+
+            restore_cursor(data_source, ckpt, latest)
         log.info("gen %d: continuous feedback data from %s (rank %d/%d)",
                  generation, cfg["feedback_spools"], rank, world)
         data = iter(data_source)
     elif cfg.get("data_dir"):
-        from easydl_tpu.data import (
-            ArrayImageDataset,
-            ClickLogDataset,
-            TokenFileDataset,
-        )
+        from easydl_tpu.data import open_dataset, restore_cursor
 
         data_dir = cfg["data_dir"]
         # val_fraction carves the evaluator's holdout out of training here
         # too — otherwise elastic trainers would see 100% of the windows and
         # contaminate the "held-out" eval loss
-        val_fraction = float(cfg.get("val_fraction", 0.0))
-        if os.path.exists(os.path.join(data_dir, "images.npy")):
-            data_source = ArrayImageDataset(
-                data_dir, batch_size=per_process_batch, rank=rank,
-                world=world, split="train", val_fraction=val_fraction,
-            )
-        elif os.path.exists(os.path.join(data_dir, "sparse.npy")):
-            data_source = ClickLogDataset(
-                data_dir, batch_size=per_process_batch, rank=rank,
-                world=world, split="train", val_fraction=val_fraction,
-            )
-        else:
-            seq_len = int(cfg.get("seq_len", 0)) or getattr(
-                bundle.make_data(1), "seq_len", 0
-            )
-            data_source = TokenFileDataset(
-                data_dir, batch_size=per_process_batch, seq_len=seq_len,
-                rank=rank, world=world, split="train",
-                val_fraction=val_fraction,
-            )
+        data_source = open_dataset(
+            data_dir, bundle, batch_size=per_process_batch, rank=rank,
+            world=world, seq_len=int(cfg.get("seq_len", 0)), split="train",
+            val_fraction=float(cfg.get("val_fraction", 0.0)),
+        )
         if latest >= 0:
-            # resume the data cursor with the model; the state is
-            # world/batch-tagged so a reshaped generation rescales it
-            data_state = ckpt.metadata(latest).get("metadata", {}).get(
-                "data_state"
-            )
-            if data_state:
-                data_source.restore_state(data_state)
+            # resume the data cursor with the model
+            restore_cursor(data_source, ckpt, latest)
         log.info("gen %d: file data %s (%d batches/epoch, rank %d/%d)",
                  generation, data_dir, data_source.batches_per_epoch,
                  rank, world)
@@ -568,7 +542,7 @@ def run_worker(env: Dict[str, str]) -> int:
         return ({"data_state": data_source.state()}
                 if data_source is not None else None)
 
-    # Live MFU (core/mfu.py — the SAME definition bench.py reports): the
+    # Live MFU (core/mfu.py, the program's one definition): the
     # per-step record carries it when the model publishes a FLOP hint, the
     # agent bridges it to the easydl_worker_mfu gauge, and the Brain's
     # mesh-shape policy reads the throughput it normalises. Peak resolved
@@ -599,8 +573,8 @@ def run_worker(env: Dict[str, str]) -> int:
             "t": time.time(),
         }
         if mfu_denom > 0:
-            # 8 decimals, matching bench.py: the compile step's MFU is
-            # ~1e-5 and a 6-decimal round quantizes it to a flat 0.0
+            # 8 decimals: the compile step's MFU is ~1e-5 and a 6-decimal
+            # round quantizes it to a flat 0.0
             rec["mfu"] = round(rate * flops_per_sample / mfu_denom, 8)
         with open(metrics_path, "a") as f:
             f.write(json.dumps(rec) + "\n")

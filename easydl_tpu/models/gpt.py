@@ -51,9 +51,9 @@ def lm_loss(logits, targets, ignore_id: int = -1):
 #: not fit them. The fused head forms the loss and both gradients in one
 #: pass over each chunk's logits; whether it also beats full logits where
 #: both fit has not been measured since it stopped recomputing them. Which
-#: head a shape gets is decided here; how the fused head cuts the sequence
-#: into chunks is its own matter (``fused_xent.chunk_positions``), unless
-#: ``loss_chunk`` names a chunk's positions.
+#: head a shape gets is decided here and nowhere else; how the fused head
+#: cuts the sequence into chunks is its own matter
+#: (``fused_xent.chunk_positions``).
 FUSED_HEAD_LOGITS_BYTES = 2 * 1024 ** 3
 
 log = get_logger("models", "gpt")
@@ -66,13 +66,11 @@ def fused_head_by_shape(batch: int, seq: int, vocab: int) -> bool:
     return 4 * local_batch(batch) * seq * vocab > FUSED_HEAD_LOGITS_BYTES
 
 
-def lm_bundle(cfg: TransformerConfig, name: str, *, fused_loss=None,
-              loss_chunk=None, moe_aux_weight: float = 0.01
-              ) -> ModelBundle:
+def lm_bundle(cfg: TransformerConfig, name: str, *,
+              moe_aux_weight: float = 0.01) -> ModelBundle:
     """The causal-LM bundle of one description of the stack: init, loss
-    (full logits, or the fused chunked head: ``fused_loss`` True / False
-    states it, None leaves it to :func:`fused_head_by_shape`), eval, data
-    and the hints."""
+    (full logits, or the fused chunked head where
+    :func:`fused_head_by_shape` says so), eval, data and the hints."""
     model = Transformer(cfg)
     seq_len, vocab, n_layers = cfg.max_seq, cfg.vocab, cfg.n_layers
 
@@ -88,10 +86,8 @@ def lm_bundle(cfg: TransformerConfig, name: str, *, fused_loss=None,
         logits buffer never exists.
         """
         mut = None
-        fused = fused_loss
-        if fused is None:
-            fused = fused_head_by_shape(*batch["inputs"].shape, vocab)
-        if fused and cfg.tied_head:
+        if cfg.tied_head and fused_head_by_shape(*batch["inputs"].shape,
+                                                 vocab):
             out = model.apply(
                 {"params": params}, batch["inputs"], return_hidden=True,
                 **({"mutable": ["intermediates"]} if mutable else {}),
@@ -108,7 +104,7 @@ def lm_bundle(cfg: TransformerConfig, name: str, *, fused_loss=None,
             head = jnp.asarray(head, dtype=hidden.dtype)
             with jax.named_scope("lm_head_loss"):
                 loss, _ = fused_softmax_xent(
-                    hidden, head, batch["targets"], chunk_size=loss_chunk,
+                    hidden, head, batch["targets"],
                     logit_scale=1.0 / cfg.logits_scaling,
                 )
         else:
@@ -174,8 +170,6 @@ def make_gpt(
     moe_k: int = 2,
     moe_aux_weight: float = 0.01,
     moe_capacity_factor: float = 1.25,
-    fused_loss=None,
-    loss_chunk=None,
     pipeline_fn=None,
     pipeline_stages: int = 0,
 ) -> ModelBundle:
@@ -203,7 +197,6 @@ def make_gpt(
     )
     return lm_bundle(
         cfg, f"gpt-{size}" + (f"-moe{moe_experts}" if moe_experts else ""),
-        fused_loss=fused_loss, loss_chunk=loss_chunk,
         moe_aux_weight=moe_aux_weight)
 
 

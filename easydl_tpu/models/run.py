@@ -54,41 +54,18 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def file_data(args, bundle, rank: int = 0, world: int = 1,
-              batch: int = 0, seed_offset: int = 0, split: str = "train"):
-    """--data-dir -> a dataset matching the model's input contract.
+def file_data(args, bundle, seed_offset: int = 0, split: str = "train"):
+    """--data-dir -> a dataset matching the model's input contract
+    (``data/source.py open_dataset``; --seq-len overrides the model's own)."""
+    from easydl_tpu.data import open_dataset
 
-    seq_len comes from the bundle's own data stream (the model's actual
-    config) unless --seq-len overrides it — a hardcoded fallback would
-    silently train a long-context model on short windows."""
-    import os
-
-    from easydl_tpu.data import (
-        ArrayImageDataset,
-        ClickLogDataset,
-        TokenFileDataset,
-    )
-
-    batch = batch or args.batch
-    if os.path.exists(os.path.join(args.data_dir, "images.npy")):
-        return ArrayImageDataset(args.data_dir, batch_size=batch,
-                                 rank=rank, world=world, seed=seed_offset,
-                                 split=split,
-                                 val_fraction=args.val_fraction)
-    if os.path.exists(os.path.join(args.data_dir, "sparse.npy")):
-        return ClickLogDataset(args.data_dir, batch_size=batch,
-                               rank=rank, world=world, seed=seed_offset,
-                               split=split,
-                               val_fraction=args.val_fraction)
-    seq_len = args.seq_len or getattr(bundle.make_data(1), "seq_len", 0)
-    if not seq_len:
-        raise SystemExit(
-            f"cannot infer seq_len for model {bundle.name!r}; pass --seq-len"
-        )
-    return TokenFileDataset(args.data_dir, batch_size=batch,
-                            seq_len=seq_len, rank=rank, world=world,
-                            seed=seed_offset, split=split,
-                            val_fraction=args.val_fraction)
+    try:
+        return open_dataset(
+            args.data_dir, bundle, batch_size=args.batch,
+            seq_len=args.seq_len, seed=seed_offset, split=split,
+            val_fraction=args.val_fraction)
+    except ValueError as e:
+        raise SystemExit(f"{e} (--seq-len)") from e
 
 
 def main() -> None:
@@ -181,10 +158,10 @@ def main() -> None:
         if ckpt is not None and state.int_step > 0:
             # resume the data cursor alongside the model: without this a
             # restored run replays epoch 0 from the start
-            data_state = ckpt.metadata(state.int_step).get(
-                "metadata", {}).get("data_state")
+            from easydl_tpu.data import restore_cursor
+
+            data_state = restore_cursor(source, ckpt, state.int_step)
             if data_state:
-                source.restore_state(data_state)
                 log.info("data cursor resumed: %s", data_state)
         log.info("file-backed data: %s (%d batches/epoch)",
                  args.data_dir, source.batches_per_epoch)

@@ -3,8 +3,11 @@
 ``impl="auto"`` picks the Pallas flash kernel on TPU (large HBM win: the
 [B,H,S,S] score matrix never materialises) and the XLA reference path
 elsewhere; models call :func:`multihead_attention` and never care which runs.
-Which one ran is never a guess: the choice, and every drop from the kernel
-to the reference, is logged once per process with its reason.
+Which path runs is decided HERE and nowhere else (``ops/flash_attention.py``
+holds kernels only), in one order: the platform (``impl="auto"``), a segment
+mask, lengths the kernels can tile. Which one ran is never a guess: the
+choice, and every drop from the kernel to the reference, is logged once per
+process with its reason.
 
 Under a mesh of more than one device the kernel runs per shard: Mosaic
 kernels cannot be partitioned by GSPMD, so :func:`multihead_attention`
@@ -34,7 +37,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from easydl_tpu.core.mesh_shapes import BATCH_AXES
-from easydl_tpu.ops.flash_attention import flash_attention
+from easydl_tpu.ops.flash_attention import (
+    MAX_BLOCK,
+    choose_blocks,
+    flash_attention,
+)
 from easydl_tpu.utils.logging import get_logger, log_once
 
 log = get_logger("ops", "attention")
@@ -157,20 +164,23 @@ def multihead_attention(
                 f"attention: XLA reference path (impl=auto on platform "
                 f"{platform!r}, the Pallas flash kernel needs a tpu)")
     if impl == "flash":
+        why = None
         if segment_ids is not None:
-            # flash_attention logs this drop itself; the reference path it
-            # takes partitions under GSPMD, so no per-shard wrap.
-            return flash_attention(
-                q, *_repeat_kv(q, k, v), causal=causal, scale=scale,
-                segment_ids=segment_ids
-            )
+            why = "segment mask requested"
+        elif choose_blocks(q.shape[1], k.shape[1], causal) is None:
+            # the wrap below never splits the sequence: asked once, here
+            why = (f"lengths q={q.shape[1]} k={k.shape[1]} have no block "
+                   f"divisor <= {MAX_BLOCK}/{MAX_BLOCK}")
+        if why is None:
+            def kernel(q, k, v):
+                return flash_attention(q, *_repeat_kv(q, k, v), causal=causal,
+                                       scale=scale)
 
-        def kernel(q, k, v):
-            return flash_attention(q, *_repeat_kv(q, k, v), causal=causal,
-                                   scale=scale)
-
-        return _per_shard(kernel, q, k)(q, k, v)
-    if impl != "reference":
+            return _per_shard(kernel, q, k)(q, k, v)
+        # the reference path partitions under GSPMD: no per-shard wrap
+        log_once(log, f"flash attention: XLA reference path, not the "
+                      f"kernel: {why}")
+    elif impl != "reference":
         raise ValueError(f"unknown attention impl {impl!r}")
     return _reference_attention(
         q, *_repeat_kv(q, k, v), causal=causal, scale=scale,

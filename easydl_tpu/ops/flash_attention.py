@@ -89,6 +89,10 @@ _CELL_PAIRS = 4
 #: keeps two; float32 [4, 1024, 64] blocks of q, k, v, o did not fit).
 _CELL_BYTES = 6 << 20
 
+#: the largest block a kernel takes when the caller names none (the sweep
+#: in :func:`choose_blocks`)
+MAX_BLOCK = 512
+
 #: (block_q, block_k) of the forward, the dq and the dk/dv kernel.
 Blocks = Tuple[Tuple[int, int], Tuple[int, int], Tuple[int, int]]
 
@@ -100,8 +104,7 @@ _NN = (((1,), (0,)), ((), ()))
 
 def _pick_block(s: int, target: int) -> Optional[int]:
     """Largest block <= target that divides s, preferring multiples of 128
-    (MXU/lane tiling). None when s can't be tiled — caller falls back to the
-    reference path."""
+    (MXU/lane tiling). None when s can't be tiled."""
     b = min(target, s)
     if s % b == 0:
         return b
@@ -114,11 +117,13 @@ def _pick_block(s: int, target: int) -> Optional[int]:
     return None
 
 
-def _choose_blocks(s_q: int, s_k: int, causal: bool,
-                   block_q: Optional[int], block_k: Optional[int]) -> Optional[Blocks]:
+def choose_blocks(s_q: int, s_k: int, causal: bool,
+                  block_q: Optional[int] = None,
+                  block_k: Optional[int] = None) -> Optional[Blocks]:
     """Block sizes per kernel from what the call can see; a caller's
     ``block_q`` / ``block_k`` hold for all three. None when a length has no
-    block divisor.
+    block divisor: the kernels cannot tile it, and whoever chooses the
+    attention path (``ops/attention.py``) asks here before calling them.
 
     Swept on a v5e over {128, 256, 512, 1024}² at [128, 1024, 64] and
     [100, 1024, 64] bf16 causal (PERF.md section 6, PR 24): 512 x 512 is
@@ -130,7 +135,7 @@ def _choose_blocks(s_q: int, s_k: int, causal: bool,
         return (_pick_block(s_q, block_q or target),
                 _pick_block(s_k, block_k or target))
 
-    big, small = pick(512), pick(256)
+    big, small = pick(MAX_BLOCK), pick(MAX_BLOCK // 2)
     if None in big:
         return None
     dkv = big
@@ -500,36 +505,26 @@ def flash_attention(
     *,
     causal: bool = False,
     scale: Optional[float] = None,
-    segment_ids: Optional[jax.Array] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Flash attention over [batch, seq, heads, head_dim] tensors.
+    """Flash attention over [batch, seq, heads, head_dim] tensors: the
+    kernels' result, or ValueError where they cannot tile the lengths
+    (:func:`choose_blocks` is the question; this module holds no other
+    path).
 
     ``block_q`` / ``block_k``, when passed, hold for all three kernels; left
-    out, each kernel's are chosen from what the call shows
-    (:func:`_choose_blocks`).
-
-    Drops to the XLA reference path when the kernel can't tile the sequence
-    lengths (no block divisor) or a segment mask is requested; each such
-    drop is logged once with its reason."""
+    out, each kernel's are chosen from what the call shows."""
     b, s, h, d = q.shape
     s_k = k.shape[1]
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    blocks = _choose_blocks(s, s_k, causal, block_q, block_k)
-    if segment_ids is not None or blocks is None:
-        from easydl_tpu.ops.attention import _reference_attention
-
-        why = ("segment mask requested" if segment_ids is not None else
-               f"lengths q={s} k={s_k} have no block divisor <= "
-               f"{block_q or 512}/{block_k or 512}")
-        log_once(log, f"flash attention: XLA reference path, not the "
-                      f"kernel: {why}")
-        return _reference_attention(
-            q, k, v, causal=causal, scale=scale, segment_ids=segment_ids,
-        )
+    blocks = choose_blocks(s, s_k, causal, block_q, block_k)
+    if blocks is None:
+        raise ValueError(
+            f"flash attention: lengths q={s} k={s_k} have no block divisor "
+            f"<= {block_q or MAX_BLOCK}/{block_k or MAX_BLOCK}")
     device = jax.devices()[0]
     how = "INTERPRETED" if interpret else "compiled"
     chosen = ", ".join(

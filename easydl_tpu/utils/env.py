@@ -293,8 +293,6 @@ KNOB_DECLS = (
      "Marks the re-exec'd forced-CPU chaos_run child ('1')."),
     ("EASYDL_RECOVERY_CHILD", "str", "",
      "Marks the re-exec'd measure_recovery child ('1')."),
-    ("EASYDL_PIPEBENCH_CHILD", "str", "",
-     "Marks the re-exec'd bench_pipeline child ('1')."),
 )
 
 
@@ -468,7 +466,7 @@ def configure_compile_cache() -> Optional[str]:
     its directory, or None when ``EASYDL_COMPILE_CACHE=off`` disabled it.
 
     The one resolver every entry point that compiles uses (zoo runner,
-    elastic worker, bench.py, chip_smoke's children). Where
+    elastic worker, chip_smoke's children). Where
     ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads it, and no
     directory is set in code; otherwise the cache is
     :data:`COMPILE_CACHE_DIR`. Thresholds go to 0 so test-scale compiles

@@ -50,3 +50,24 @@ def eight_devices():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected >=8 forced CPU devices, got {len(devs)}"
     return devs[:8]
+
+
+@pytest.fixture
+def fused_head(monkeypatch):
+    """``fused_head(chunk_rows=None)``: from the call on, every shape gets
+    the fused chunked head (``models/gpt.FUSED_HEAD_LOGITS_BYTES`` -> 0) and,
+    where ``chunk_rows`` is named, chunks of that many rows a device
+    (``ops/fused_xent.CHUNK_ROWS``), so that a test-size model runs the head
+    the hybrid's cell runs, in several chunks. Both constants are read when
+    a loss is TRACED: build the full-logits side of a comparison before the
+    call. Which head a shape gets is no argument of the program; a test
+    steers it here."""
+    def steer(chunk_rows=None):
+        from easydl_tpu.models import gpt
+        from easydl_tpu.ops import fused_xent
+
+        monkeypatch.setattr(gpt, "FUSED_HEAD_LOGITS_BYTES", 0)
+        if chunk_rows is not None:
+            monkeypatch.setattr(fused_xent, "CHUNK_ROWS", chunk_rows)
+
+    return steer

@@ -48,33 +48,73 @@ def fingerprint(**extra):
             "grads": {k: leaf(v) for k, v in sorted(flat_g.items())}}
 
 
-def step_program_sha256():
-    """SHA-256 of the lowered train step (StableHLO text without locations)
-    of the test size in bf16 under remat ``dots`` with two microbatches —
-    the benchmark cells' program at a small size. Equal text is the same
-    program, op for op."""
+def _step_sha256(bundle, spec, devices):
+    """SHA-256 of ``bundle``'s lowered train step (StableHLO text without
+    locations) under ``spec`` on ``devices``: 8 sequences as two
+    microbatches, AdamW. Equal text is the same program, op for op."""
     import hashlib
 
     import jax
     import jax.numpy as jnp
     import optax
 
-    from easydl_tpu.core.mesh import MeshSpec, build_mesh
+    from easydl_tpu.core.mesh import build_mesh
     from easydl_tpu.core.train_loop import TrainConfig, Trainer
-    from easydl_tpu.models.registry import get_model
 
-    bundle = get_model("gpt", size="test", seq_len=64, vocab=1024,
-                       dtype="bfloat16", remat=True, remat_policy="dots")
     trainer = Trainer(
         init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
         optimizer=optax.adamw(1e-3),
         config=TrainConfig(global_batch=8, grad_accum=2),
-        mesh=build_mesh(MeshSpec(), devices=jax.devices()[:1]))
-    tokens = jax.ShapeDtypeStruct((8, 64), jnp.int32)
+        mesh=build_mesh(spec, devices=devices))
+    tokens = jax.ShapeDtypeStruct((8, SMALL["seq_len"]), jnp.int32)
     text = trainer.step_fn.lower(
         trainer.abstract_state(),
         {"inputs": tokens, "targets": tokens}).as_text()
     return hashlib.sha256(text.encode()).hexdigest()
+
+
+#: the benchmark cells' program at a small size: bf16, two microbatches
+SMALL = dict(size="test", seq_len=64, vocab=1024, dtype="bfloat16",
+             remat=True)
+
+
+def step_program_sha256():
+    """GPT-2 under remat ``dots`` on one device (full logits by the rule)."""
+    import jax
+
+    from easydl_tpu.core.mesh import MeshSpec
+    from easydl_tpu.models.registry import get_model
+
+    bundle = get_model("gpt", remat_policy="dots", **SMALL)
+    return _step_sha256(bundle, MeshSpec(), jax.devices()[:1])
+
+
+def hybrid_step_program_sha256():
+    """The hybrid's test description under remat ``full``. The caller
+    steers the head's two constants (``conftest.py fused_head``) so that
+    this size gets the fused head in several chunks, as the cell's does."""
+    import jax
+
+    from easydl_tpu.core.mesh import MeshSpec
+    from easydl_tpu.models.registry import get_model
+
+    bundle = get_model("granite_hybrid", remat_policy="full", **SMALL)
+    return _step_sha256(bundle, MeshSpec(), jax.devices()[:1])
+
+
+def gpt2_fsdp4_step_program_sha256():
+    """GPT-2 under ``MeshSpec(fsdp=4)`` on four of the forced host devices
+    with ``attention_impl="flash"``: the kernels called per shard. The
+    caller puts the kernels in interpret mode (``ops.attention``'s
+    ``flash_attention`` patched), as every whole-model CPU test does."""
+    import jax
+
+    from easydl_tpu.core.mesh import MeshSpec
+    from easydl_tpu.models.registry import get_model
+
+    bundle = get_model("gpt", remat_policy="dots", attention_impl="flash",
+                       **SMALL)
+    return _step_sha256(bundle, MeshSpec(fsdp=4), jax.devices()[:4])
 
 
 if __name__ == "__main__":

@@ -4,7 +4,7 @@ it rests on.
 - the phase functions at test size on the forced-CPU mesh — steered from
   here (a ``Size``, interpret mode, what the agent is told its host has),
   never by an option of the script;
-- on a machine without a chip, ``chip_smoke.py`` and ``bench.py`` exit
+- on a machine without a chip, ``chip_smoke.py`` and ``benchmark/run.py`` exit
   non-zero, name the missing chip and print no metric;
 - the compile-cache resolver, the MFU denominator, and the agent's refusal
   to preflight onto a device its own worker holds.
@@ -133,11 +133,15 @@ def _run(argv, cwd=REPO, **env):
         timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu", **env))
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
-def test_no_chip_no_metric(script):
+@pytest.mark.parametrize("argv", [
+    ["chip_smoke.py"],
+    ["benchmark/run.py", "--workload", "gpt2-medium.steady", "--seed", "1",
+     "--seconds", "50", "--trace", "0"],
+], ids=["chip_smoke.py", "benchmark-run.py"])
+def test_no_chip_no_metric(argv):
     """On a CPU-only machine the measurement paths fail; they do not fall
     back. Non-zero exit, the missing chip named, no JSON line on stdout."""
-    proc = _run([os.path.join(REPO, script)])
+    proc = _run([os.path.join(REPO, argv[0])] + argv[1:])
     assert proc.returncode != 0
     assert "tpu" in (proc.stderr + proc.stdout).lower()
     assert not [line for line in proc.stdout.splitlines()

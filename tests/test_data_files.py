@@ -147,6 +147,92 @@ def test_image_dataset_shapes_and_sharding(tmp_path):
     assert len(seen) == 64
 
 
+# ------------------------------------------- a directory -> its data source
+class _Bundle:
+    """What ``open_dataset`` reads of a model bundle."""
+    name = "stub"
+
+    def __init__(self, seq_len):
+        self._seq_len = seq_len
+
+    def make_data(self, batch):
+        from types import SimpleNamespace
+
+        return SimpleNamespace(seq_len=self._seq_len) if self._seq_len \
+            else object()
+
+
+def _make_dir(kind, path):
+    from easydl_tpu.data import encode_click_tsv
+
+    if kind == "images":
+        np.save(path / "images.npy", np.zeros((64, 8, 8, 1), np.uint8))
+        np.save(path / "labels.npy", np.arange(64) % 10)
+    elif kind == "clicks":
+        _write_click_tsv(str(path / "clicks.tsv"))
+        encode_click_tsv([str(path / "clicks.tsv")], str(path))
+    else:
+        write_token_shards(np.arange(8192), str(path))
+
+
+@pytest.mark.parametrize("kind,cls", [
+    ("images", "ArrayImageDataset"), ("clicks", "ClickLogDataset"),
+    ("tokens", "TokenFileDataset")])
+def test_open_dataset_probes_the_directory_and_restores_the_cursor(
+        tmp_path, kind, cls):
+    """The one probe the worker, the runner and the evaluator share: the
+    class each got from its own copy before, built with the caller's share
+    (rank, world, seed, split), and the cursor a checkpoint's metadata
+    carried."""
+    from easydl_tpu.data import open_dataset, restore_cursor
+
+    _make_dir(kind, tmp_path)
+    share = dict(batch_size=2, rank=1, world=2, seed=3, split="train",
+                 val_fraction=0.25)
+    ds = open_dataset(str(tmp_path), _Bundle(31), **share)
+    assert type(ds).__name__ == cls
+    assert (ds.batch_size, ds.rank, ds.world, ds.seed) == (2, 1, 2, 3)
+    if kind == "tokens":
+        assert ds.seq_len == 31  # the model's own ...
+        assert open_dataset(str(tmp_path), _Bundle(31), seq_len=15,
+                            **share).seq_len == 15  # ... unless stated
+        with pytest.raises(ValueError, match="cannot infer seq_len"):
+            open_dataset(str(tmp_path), _Bundle(0), **share)
+    # the worker's call: no seed, the whole of the defaults
+    plain = open_dataset(str(tmp_path), _Bundle(31), batch_size=2)
+    assert (plain.rank, plain.world, plain.seed) == (0, 1, 0)
+
+    it = iter(ds)
+    for _ in range(3):
+        next(it)
+    state = ds.state()
+
+    class Ckpt:
+        def metadata(self, step):
+            return {"metadata": {"data_state": state} if step == 7 else {}}
+
+    resumed = open_dataset(str(tmp_path), _Bundle(31), **share)
+    assert restore_cursor(resumed, Ckpt(), 6) is None  # none carried
+    assert restore_cursor(resumed, Ckpt(), 7) == state
+    a, b = next(iter(resumed)), next(it)
+    assert sorted(a) == sorted(b)
+    for key in a:
+        np.testing.assert_array_equal(a[key], b[key])
+
+
+def test_runner_turns_a_missing_seq_len_into_its_exit(tmp_path):
+    from types import SimpleNamespace
+
+    from easydl_tpu.models.run import file_data
+
+    _make_dir("tokens", tmp_path)
+    args = SimpleNamespace(data_dir=str(tmp_path), batch=2, seq_len=0,
+                           val_fraction=0.0)
+    with pytest.raises(SystemExit, match="--seq-len"):
+        file_data(args, _Bundle(0))
+    assert file_data(args, _Bundle(31), seed_offset=1).seed == 1
+
+
 # ------------------------------------------------------------- end-to-end
 
 def test_encode_cli_and_training_from_files(tmp_path, eight_devices):

@@ -1,7 +1,7 @@
 """The driver's dry-run entry point, run as the subprocess the driver runs.
 
-(``bench.py`` and ``chip_smoke.py`` on a machine without a chip are covered
-in tests/test_chip_smoke.py.)
+(``benchmark/run.py`` and ``chip_smoke.py`` on a machine without a chip are
+covered in tests/test_chip_smoke.py.)
 """
 
 from __future__ import annotations

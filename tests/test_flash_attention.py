@@ -4,15 +4,21 @@ use inside a jitted transformer step. Kernels run in Pallas interpreter mode
 on CPU (same code path the TPU compiles).
 """
 
+import ast
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from easydl_tpu.ops.attention import _reference_attention
-from easydl_tpu.ops.flash_attention import flash_attention
+from easydl_tpu.core.mesh import MeshSpec, build_mesh
+from easydl_tpu.ops import attention as attention_module
+from easydl_tpu.ops import flash_attention as flash_module
+from easydl_tpu.ops.attention import _reference_attention, multihead_attention
+from easydl_tpu.ops.flash_attention import choose_blocks, flash_attention
+from easydl_tpu.utils import logging as easydl_logging
 
 
 def rand_qkv(key, b=2, s=128, h=4, d=32, dtype=jnp.float32):
@@ -149,12 +155,98 @@ def test_causal_cross_length_sq_gt_sk_dead_rows():
 
 
 def test_untileable_length_falls_back_to_reference():
-    """Lengths with no usable block divisor (e.g. 72 with block 48 → none
-    ≥128-aligned) must not assert — the wrapper falls back to the XLA path."""
-    q, k, v = rand_qkv(jax.random.PRNGKey(5), s=72, d=16)
+    """Lengths with no usable block divisor (520: over a block of 512, not a
+    multiple of 128) must not assert — ``multihead_attention`` drops to the
+    XLA path."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(5), s=520, d=16)
     ref = _reference_attention(q, k, v, causal=True, scale=16**-0.5)
-    out = flash_attention(q, k, v, causal=True, block_q=48, block_k=48, interpret=True)
+    out = multihead_attention(q, k, v, causal=True, impl="flash")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def test_kernels_raise_on_lengths_they_cannot_tile():
+    """The kernel module has no other path: the question is a function, the
+    call a ValueError."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(5), s=72, d=16)
+    assert choose_blocks(72, 72, True, 48, 48) is None
+    assert choose_blocks(520, 520, True) is None
+    assert choose_blocks(72, 72, True) == ((72, 72),) * 3
+    with pytest.raises(ValueError, match="q=72 k=72 have no block divisor"):
+        flash_attention(q, k, v, causal=True, block_q=48, block_k=48,
+                        interpret=True)
+    with pytest.raises(TypeError, match="segment_ids"):
+        flash_attention(q, k, v, causal=True, interpret=True,
+                        segment_ids=jnp.zeros((2, 72), jnp.int32))
+
+
+def test_kernel_module_imports_nothing_from_the_module_that_chooses():
+    """``ops/attention.py`` imports the kernels; the kernels' module reaches
+    back up for nothing (read from its imports, at any depth)."""
+    with open(flash_module.__file__) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add(node.module or "")
+            imported |= {f"{node.module}.{a.name}" for a in node.names}
+    assert imported and not {m for m in imported if "attention" in m}, imported
+    names = {n.id for n in ast.walk(tree) if isinstance(n, ast.Name)}
+    assert "_reference_attention" not in names
+
+
+@pytest.fixture
+def attention_log(monkeypatch):
+    """Messages ``ops/attention.py`` logs during the test, with ``log_once``
+    forgetting what earlier tests of this process said."""
+    monkeypatch.setattr(easydl_logging, "_logged_once", set())
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda record: records.append(record.getMessage())
+    attention_module.log.addHandler(handler)
+    yield records
+    attention_module.log.removeHandler(handler)
+
+
+@pytest.mark.parametrize("mesh", [None, "dp=2,tp=2"], ids=["one-device", "mesh"])
+@pytest.mark.parametrize("case,reason", [
+    ("segment-mask", "segment mask requested"),
+    ("untileable", "lengths q=520 k=520 have no block divisor <= 512/512"),
+])
+def test_each_drop_from_the_kernel_is_logged_once_with_its_reason(
+        attention_log, monkeypatch, eight_devices, case, reason, mesh):
+    """``impl="flash"`` where the kernels cannot serve: the reference path's
+    result, one log line with the reason however often the call is traced,
+    and the kernels never called — under a mesh (no per-shard wrap around
+    the reference path) and without."""
+    def never(*a, **k):
+        raise AssertionError("the kernels were called")
+
+    monkeypatch.setattr(attention_module, "flash_attention", never)
+    s = 520 if case == "untileable" else 64
+    q, k, v = rand_qkv(jax.random.PRNGKey(7), b=4, s=s, d=16)
+    segments = None
+    if case == "segment-mask":
+        segments = jnp.asarray(np.arange(s) // 24, jnp.int32)[None].repeat(4, 0)
+    want = _reference_attention(q, k, v, causal=True, scale=0.25,
+                                segment_ids=segments)
+    call = jax.jit(functools.partial(multihead_attention, causal=True,
+                                     impl="flash"))
+    if mesh is None:
+        got = [call(q, k, v, segment_ids=segments) for _ in range(2)]
+    else:
+        spec = MeshSpec.parse(mesh)
+        with jax.set_mesh(build_mesh(spec, devices=eight_devices[:spec.size])):
+            got = [call(q, k, v, segment_ids=segments),
+                   jax.jit(lambda *a: call(*a, segment_ids=segments))(q, k, v)]
+            assert "shard_map" not in str(jax.make_jaxpr(
+                lambda *a: call(*a, segment_ids=segments))(q, k, v))
+    for out in got:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+    assert attention_log == [
+        f"flash attention: XLA reference path, not the kernel: {reason}"]
 
 
 def test_bf16_inputs():

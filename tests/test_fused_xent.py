@@ -75,32 +75,34 @@ def test_all_masked_is_finite():
     assert float(loss) == 0.0 and float(denom) == 1.0
 
 
-def test_gpt_bundle_fused_matches_logits_path(eight_devices):
-    """End-to-end through the model: the fused-loss bundle and the logits
-    bundle compute the same loss and the same gradients on the same params."""
-    kw = dict(size="test", seq_len=64, vocab=256)
-    fused = get_model("gpt", fused_loss=True, loss_chunk=16, **kw)
-    plain = get_model("gpt", fused_loss=False, **kw)
+def test_gpt_bundle_fused_matches_logits_path(eight_devices, fused_head):
+    """End-to-end through the model: the bundle with the fused head (4
+    chunks of 16 positions) and with full logits compute the same loss and
+    the same gradients on the same params."""
+    bundle = get_model("gpt", size="test", seq_len=64, vocab=256)
     rng = jax.random.PRNGKey(0)
-    params = fused.init_fn(rng)
-    batch = next(iter(plain.make_data(4, seed=3)))
+    params = bundle.init_fn(rng)
+    batch = next(iter(bundle.make_data(4, seed=3)))
 
-    lf, mf = fused.loss_fn(params, batch, rng)
-    lp, mp = plain.loss_fn(params, batch, rng)
+    lp, mp = bundle.loss_fn(params, batch, rng)
+    gp = jax.grad(lambda p: bundle.loss_fn(p, batch, rng)[0])(params)
+    fused_head(chunk_rows=64)
+    assert gpt_module.fused_head_by_shape(4, 64, 256)
+    lf, mf = bundle.loss_fn(params, batch, rng)
+    gf = jax.grad(lambda p: bundle.loss_fn(p, batch, rng)[0])(params)
     np.testing.assert_allclose(float(lf), float(lp), rtol=1e-6)
     np.testing.assert_allclose(float(mf["perplexity"]),
                                float(mp["perplexity"]), rtol=1e-6)
-
-    gf = jax.grad(lambda p: fused.loss_fn(p, batch, rng)[0])(params)
-    gp = jax.grad(lambda p: plain.loss_fn(p, batch, rng)[0])(params)
     for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gp)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-6)
 
 
-def test_gpt_moe_fused_loss_runs(eight_devices):
+def test_gpt_moe_fused_head_runs(eight_devices, fused_head):
     bundle = get_model("gpt", size="test", seq_len=32, vocab=128,
-                       moe_experts=4, fused_loss=True, loss_chunk=8)
+                       moe_experts=4)
+    fused_head(chunk_rows=32)  # 4 sequences: 8 positions a chunk
+    assert gpt_module.fused_head_by_shape(4, 32, 128)
     rng = jax.random.PRNGKey(1)
     params = bundle.init_fn(rng)
     batch = next(iter(bundle.make_data(4, seed=5)))
@@ -109,31 +111,63 @@ def test_gpt_moe_fused_loss_runs(eight_devices):
     assert "moe_balance" in metrics
 
 
+def _reject_cases():
+    from easydl_tpu.core.train_loop import TrainConfig
+    from easydl_tpu.models.gpt import lm_bundle
+    from easydl_tpu.models.granite_hybrid import describe
+
+    job = {"model": "gpt", "model_kwargs": {"size": "test", "seq_len": 32}}
+    return {
+        "make_gpt-fused_loss": lambda: get_model(
+            "gpt", size="test", fused_loss=True),
+        "make_gpt-loss_chunk": lambda: get_model(
+            "gpt", size="test", loss_chunk=16),
+        "lm_bundle-fused_loss": lambda: lm_bundle(
+            describe(size="test"), "x", fused_loss=False),
+        "lm_bundle-loss_chunk": lambda: lm_bundle(
+            describe(size="test"), "x", loss_chunk=16),
+        "TrainConfig-accum_unroll": lambda: TrainConfig(accum_unroll=2),
+        # as the worker, the runner and the evaluator build a job's model
+        "job-fused_loss": lambda: get_model(
+            job["model"], **job["model_kwargs"], fused_loss=True),
+        "job-hybrid-loss_chunk": lambda: get_model(
+            "granite_hybrid", size="test", loss_chunk=128),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_reject_cases()))
+def test_the_retired_switches_are_rejected_by_name(case):
+    """Which head, how many positions a chunk and the accumulation scan's
+    unroll are no arguments any more: the head is the shape rule's, the
+    chunk ``chunk_positions``'s. A job description that still carries one
+    fails where its model is built, with the argument named."""
+    with pytest.raises(TypeError, match=case.rsplit("-", 1)[1]):
+        _reject_cases()[case]()
+
+
 @pytest.mark.parametrize("moe_experts", [0, 4], ids=["dense", "moe4"])
 def test_bf16_bundle_with_the_head_chosen_by_shape_matches_full_logits(
-        monkeypatch, moe_experts):
-    """Through ``lm_bundle`` as a training cell reaches it: bf16, no
-    ``fused_loss``, no ``loss_chunk`` — the shape rule picks the one-pass
-    head and the chunk is sized in rows — against the full-logits bundle,
-    loss and every parameter's gradient."""
-    kw = dict(size="test", seq_len=512, vocab=256, dtype="bfloat16",
-              moe_experts=moe_experts)
-    by_shape = get_model("gpt", **kw)
-    plain = get_model("gpt", fused_loss=False, **kw)
+        monkeypatch, fused_head, moe_experts):
+    """Through ``lm_bundle`` as a training cell reaches it: bf16, the shape
+    rule picks the one-pass head and the chunk is sized in rows, the rule's
+    own — against the same bundle with full logits, loss and every
+    parameter's gradient."""
+    bundle = get_model("gpt", size="test", seq_len=512, vocab=256,
+                       dtype="bfloat16", moe_experts=moe_experts)
     rng = jax.random.PRNGKey(0)
-    params = plain.init_fn(rng)
-    batch = next(iter(plain.make_data(4, seed=3)))
+    params = bundle.init_fn(rng)
+    batch = next(iter(bundle.make_data(4, seed=3)))
     want, g_want = jax.value_and_grad(
-        lambda p: plain.loss_fn(p, batch, rng)[0])(params)
+        lambda p: bundle.loss_fn(p, batch, rng)[0])(params)
 
-    monkeypatch.setattr(gpt_module, "FUSED_HEAD_LOGITS_BYTES", 0)
+    fused_head()
     chunks = []
     monkeypatch.setattr(
         gpt_module, "fused_softmax_xent",
-        lambda *a, **k: chunks.append(k["chunk_size"])
+        lambda *a, **k: chunks.append(k.get("chunk_size"))
         or fused_softmax_xent(*a, **k))
     got, g_got = jax.value_and_grad(
-        lambda p: by_shape.loss_fn(p, batch, rng)[0])(params)
+        lambda p: bundle.loss_fn(p, batch, rng)[0])(params)
     assert chunks == [None]           # 4 x 512 rows: two chunks of 1,024
     assert chunk_positions(4, 512, 256) == 256
     np.testing.assert_allclose(float(got), float(want), rtol=2e-3)

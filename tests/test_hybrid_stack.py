@@ -44,7 +44,12 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, HERE)
-from gpt2_fingerprint import fingerprint, step_program_sha256  # noqa: E402
+from gpt2_fingerprint import (  # noqa: E402
+    fingerprint,
+    gpt2_fsdp4_step_program_sha256,
+    hybrid_step_program_sha256,
+    step_program_sha256,
+)
 
 
 def _bench_lib(name):
@@ -99,6 +104,38 @@ def test_gpt2_step_program_is_the_parents_op_for_op(gpt2_now_and_then):
     """The lowered bf16 / remat-dots / two-microbatch step at the test size:
     the same StableHLO text as on the parent commit."""
     assert step_program_sha256() == gpt2_now_and_then[1]["step_program_sha256"]
+
+
+@pytest.fixture(scope="module")
+def parents_step_programs():
+    """Hashes written on the parent of PR 27 (cb2ffa4: the last commit on
+    which a caller could state the head, its chunk and the accumulation
+    scan's unroll)."""
+    with open(os.path.join(HERE, "goldens", "step_programs.json")) as f:
+        return json.load(f)
+
+
+def test_hybrid_step_program_with_the_fused_head_is_the_parents_op_for_op(
+        fused_head, parents_step_programs):
+    """The hybrid's test description, bf16, remat ``full``, two
+    microbatches of 4 x 64, the fused head in 4 chunks of 64 rows: the
+    parent got it from ``lm_bundle``'s head switch set to True, this tree
+    from the shape rule with its constant lowered — the same StableHLO
+    text."""
+    fused_head(chunk_rows=64)
+    assert hybrid_step_program_sha256() \
+        == parents_step_programs["hybrid_step_program_sha256"]
+
+
+def test_gpt2_step_program_under_fsdp4_is_the_parents_op_for_op(
+        monkeypatch, eight_devices, parents_step_programs):
+    """GPT-2 at the test size under ``MeshSpec(fsdp=4)`` with the kernels
+    called per shard (interpreted): what ``_per_shard`` wraps, and the
+    choice in front of it, lower to the parent's text."""
+    monkeypatch.setattr(attention_module, "flash_attention",
+                        functools.partial(flash_attention, interpret=True))
+    assert gpt2_fsdp4_step_program_sha256() \
+        == parents_step_programs["gpt2_fsdp4_step_program_sha256"]
 
 
 def test_gpt2_hint_is_the_all_attention_formula():

@@ -414,9 +414,10 @@ def test_same_world_mesh_change_restores_params_bit_identically(
     """The live acceptance's core: a generation switch that keeps the
     world at 8 devices but changes the factorization (dp=8 ->
     dp=2,fsdp=2,tp=2) restores every param leaf bitwise-equal and
-    continues with the control's loss — the same proof the MULTICHIP
-    8->32 dry-run makes across world sizes, here across SHAPES (what the
-    mesh-shape policy's probes do on every reshape)."""
+    continues with the control's loss — the proof
+    ``__graft_entry__.dryrun_multichip``'s 8->32 leg makes across world
+    sizes, here across SHAPES (what the mesh-shape policy's probes do on
+    every reshape)."""
     import jax
     import optax
 

@@ -11,6 +11,7 @@ on the CPU, from the lowered step's own locations — the compiled program's
 
 from __future__ import annotations
 
+import functools
 import glob
 import os
 import re
@@ -29,10 +30,9 @@ from easydl_tpu.ops.flash_attention import flash_attention
 BATCH, SEQ, VOCAB = 8, 64, 1024
 
 
-def _trainer(grad_accum: int, **model_kwargs) -> Trainer:
+def _trainer(grad_accum: int) -> Trainer:
     bundle = get_model("gpt", size="test", seq_len=SEQ, vocab=VOCAB,
-                       dtype="bfloat16", remat=True, remat_policy="dots",
-                       **model_kwargs)
+                       dtype="bfloat16", remat=True, remat_policy="dots")
     return Trainer(
         init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
         optimizer=optax.adamw(1e-3),
@@ -40,25 +40,25 @@ def _trainer(grad_accum: int, **model_kwargs) -> Trainer:
         mesh=build_mesh(MeshSpec(), devices=jax.devices()[:1]))
 
 
+def _lowered(grad_accum: int):
+    trainer = _trainer(grad_accum)
+    tokens = jax.ShapeDtypeStruct((BATCH, SEQ), jnp.int32)
+    return trainer.step_fn.lower(
+        trainer.abstract_state(), {"inputs": tokens, "targets": tokens})
+
+
+def _paths(grad_accum: int):
+    """Name-stack paths in the lowered step, as jax writes them into the
+    operations' locations (the primitive's name at the end)."""
+    return set(re.findall(r'loc\("([^"]*)"',
+                          _lowered(grad_accum).as_text(debug_info=True)))
+
+
 @pytest.fixture(scope="module")
 def step_paths():
-    """``{grad_accum: name-stack paths in the lowered step}``, as jax writes
-    them into the operations' locations (the primitive's name at the end)."""
-    cache = {}
-
-    def paths(grad_accum: int, **model_kwargs):
-        key = (grad_accum, tuple(sorted(model_kwargs.items())))
-        if key not in cache:
-            trainer = _trainer(grad_accum, **model_kwargs)
-            tokens = jax.ShapeDtypeStruct((BATCH, SEQ), jnp.int32)
-            text = trainer.step_fn.lower(
-                trainer.abstract_state(),
-                {"inputs": tokens, "targets": tokens},
-            ).as_text(debug_info=True)
-            cache[key] = set(re.findall(r'loc\("([^"]*)"', text))
-        return cache[key]
-
-    return paths
+    """``grad_accum -> _paths`` of the step with the head the shape rule
+    gives this size (full logits), lowered once a module."""
+    return functools.cache(_paths)
 
 
 def _composed(paths):
@@ -116,24 +116,22 @@ def test_accumulate_scope_only_where_the_step_accumulates(step_paths,
         assert any(re.match(r"(.*/body/)?accumulate(/|$)", p) for p in own)
 
 
-def test_fused_head_and_loss_are_one_scope(step_paths):
-    paths = step_paths(1, fused_loss=True)
+def test_fused_head_and_loss_are_one_scope(fused_head):
+    fused_head()
+    paths = _paths(1)
     assert any("lm_head_loss" in p for p in paths)
     assert not any(re.search(r"\blm_head/|jvp\(loss\)", p) for p in paths)
 
 
 @pytest.mark.parametrize("grad_accum", [1, 2])
 def test_fused_head_runs_in_the_forward_pass_and_recomputes_nothing(
-        grad_accum):
+        fused_head, grad_accum):
     """The one-pass head (a ``custom_vjp``): its three products a chunk are
     the forward rule's and read as ``jvp(lm_head_loss)``; the backward rule
     only scales the two finished gradients; nothing of it is recomputed.
     Read from the COMPILED step's ``op_name``s, which XLA composes whole."""
-    trainer = _trainer(grad_accum, fused_loss=True, loss_chunk=16)
-    tokens = jax.ShapeDtypeStruct((BATCH, SEQ), jnp.int32)
-    text = trainer.step_fn.lower(
-        trainer.abstract_state(), {"inputs": tokens, "targets": tokens}
-    ).compile().as_text()
+    fused_head(chunk_rows=16 * BATCH // grad_accum)  # 16 positions a chunk
+    text = _lowered(grad_accum).compile().as_text()
     own = {p for p in re.findall(r'op_name="([^"]*)"', text)
            if "lm_head_loss" in p}
     products = [p for p in own if p.endswith("/dot_general")]

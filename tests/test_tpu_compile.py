@@ -91,10 +91,10 @@ def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
     """The (block_q, block_k) the forward, dq and dk/dv kernels choose for
     the benchmark's two calls, bf16 causal — a later change to the choice
     shows here — and that Mosaic takes the three kernels at those sizes."""
-    from easydl_tpu.ops.flash_attention import _choose_blocks
+    from easydl_tpu.ops.flash_attention import choose_blocks
 
     _, seq, _, _ = shape
-    assert _choose_blocks(seq, seq, True, None, None) == blocks
+    assert choose_blocks(seq, seq, True) == blocks
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e_2x2[0]))
     fn = jax.grad(_loss(lambda q, k, v: flash_attention(q, k, v, causal=True)),
