@@ -26,6 +26,7 @@ hit the BASELINE configs 3-4 (BERT-base, GPT-2 345M).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Callable, Optional, Tuple
 
@@ -40,6 +41,30 @@ from easydl_tpu.ops.ssd import (causal_conv1d, gated_rmsnorm,
 Init = nn.initializers.Initializer
 
 
+def _matrix_dot_general(lhs, rhs, dimension_numbers, precision=None,
+                        preferred_element_type=None):
+    """``lax.dot_general`` for an attention projection, whose weight carries
+    heads and head size as two dimensions (``[embed, heads, kv]`` in,
+    ``[heads, kv, embed]`` out), as ONE plain matrix product over their
+    merged dimension.
+    The same numbers; but the compiler lays a ``[batch, seq, heads·kv]``
+    product out row by row, the layout the flash kernels take and give,
+    where it gives a ``[batch, seq, heads, kv]`` product of 64-wide rows the
+    sequence as its minor dimension and a transposing copy on the way to
+    every kernel (PERF.md section 6, PR 28)."""
+    (lhs_c, rhs_c), batch = dimension_numbers
+    n = len(lhs_c)
+    assert batch == ((), ()) and tuple(rhs_c) == tuple(range(n)) and \
+        tuple(lhs_c) == tuple(range(lhs.ndim - n, lhs.ndim)), dimension_numbers
+    free = rhs.shape[n:]
+    out = jax.lax.dot_general(
+        lhs.reshape(lhs.shape[:lhs.ndim - n] + (-1,)),
+        rhs.reshape(math.prod(rhs.shape[:n]), math.prod(free)),
+        (((lhs.ndim - n,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=preferred_element_type)
+    return out.reshape(out.shape[:-1] + free)
+
+
 def _dense(
     features,
     kernel_axes,
@@ -49,12 +74,14 @@ def _dense(
     init_scale=1.0,
     axis=-1,
     dtype=None,
+    dot_general=None,
 ):
     return nn.DenseGeneral(
         features,
         axis=axis,
         use_bias=use_bias,
         dtype=dtype,  # compute dtype; params stay f32 (param_dtype default)
+        dot_general=dot_general,  # None: lax.dot_general
         kernel_init=nn.with_logical_partitioning(
             nn.initializers.normal(stddev=0.02 * init_scale), kernel_axes
         ),
@@ -268,22 +295,29 @@ class TransformerConfig:
 # wraps a Module's methods in a named scope of their own (``blocks._ffn``),
 # which would put a new component into every operation's path.
 def _projection(block, features, kernel_axes, bias_axes, name,
-                residual=False, axis=-1):
+                residual=False, axis=-1, dot_general=None):
     cfg = block.cfg
     return _dense(
         features, kernel_axes, bias_axes, name=name, use_bias=cfg.bias,
         # GPT-2 residual scaling on the projections that write the
         # residual stream
         init_scale=(2 * cfg.n_layers) ** -0.5 if residual else 1.0,
-        axis=axis, dtype=jnp.dtype(cfg.dtype))
+        axis=axis, dtype=jnp.dtype(cfg.dtype), dot_general=dot_general)
 
 
 def _attention(block, h):
     cfg = block.cfg
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
-    q = _projection(block, (cfg.n_heads, cfg.head_dim), heads, kv, "q")(h)
-    k = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "k")(h)
-    v = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "v")(h)
+    # the four products around the kernels as matrix products: the
+    # kernels' layout (the Mamba-2 mixer's same-shaped projections feed no
+    # kernel and measured SLOWER that way: PERF.md section 6, PR 28)
+    rows = _matrix_dot_general
+    q = _projection(block, (cfg.n_heads, cfg.head_dim), heads, kv, "q",
+                    dot_general=rows)(h)
+    k = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "k",
+                    dot_general=rows)(h)
+    v = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "v",
+                    dot_general=rows)(h)
     q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
     k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
     v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
@@ -295,7 +329,8 @@ def _attention(block, h):
             scale=cfg.attention_multiplier,
         )
     return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
-                       ("embed",), "out", residual=True, axis=(-2, -1))(attn)
+                       ("embed",), "out", residual=True, axis=(-2, -1),
+                       dot_general=rows)(attn)
 
 
 def _mamba2(block, u):

@@ -13,10 +13,15 @@ Under a mesh of more than one device the kernel runs per shard: Mosaic
 kernels cannot be partitioned by GSPMD, so :func:`multihead_attention`
 wraps the call in ``jax.shard_map`` over the context mesh (the one
 ``Trainer`` enters with ``jax.set_mesh``) — batch over ``dp``/``fsdp``,
-heads over ``tp``.
+heads over ``tp``. What crosses the wrap is the kernels' own view,
+``[batch, seq, heads·head_dim]`` (heads over ``tp`` are contiguous lane
+ranges of it): a four-dimensional array at that boundary keeps the
+compiler from seeing the projections' products and the kernels' operands
+as the same rows, and it puts a copy in front of every kernel.
 
-Shapes follow the [batch, seq, heads, head_dim] convention throughout (the
-layout XLA prefers for TPU attention: contraction dims innermost).
+Public shapes follow the [batch, seq, heads, head_dim] convention
+throughout; the kernels run on the ``[batch, seq, heads·head_dim]`` view of
+the same bytes (``ops/flash_attention.py``).
 
 Grouped-query attention: ``k`` and ``v`` may carry fewer heads than ``q``;
 query head ``h`` then reads key/value head ``h // (heads / kv_heads)``. Both
@@ -98,8 +103,10 @@ def _repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
 
 
 def _per_shard(fn, q: jax.Array, k: jax.Array):
-    """Wrap ``fn(q, k, v)`` in ``jax.shard_map`` over the context mesh when
-    that mesh spans more than one device, else return it unchanged.
+    """Wrap ``fn``, a function of q, k, v as ``[batch, seq, heads·head_dim]``
+    views, in ``jax.shard_map`` over the context mesh when that mesh spans
+    more than one device, else return it unchanged. ``q`` and ``k`` are the
+    ``[batch, seq, heads, head_dim]`` arrays, for their sizes.
 
     Batch is split over the mesh's batch axes and heads over ``tp`` where
     the sizes divide (the query's heads AND the key/value heads, which may
@@ -130,7 +137,7 @@ def _per_shard(fn, q: jax.Array, k: jax.Array):
             f"over {heads}={mesh.shape[heads]}; every shard computes all "
             f"heads")
         heads = None
-    spec = P(batch or None, None, heads, None)
+    spec = P(batch or None, None, heads)
     return jax.shard_map(fn, in_specs=(spec, spec, spec), out_specs=spec,
                          check_vma=False)
 
@@ -172,11 +179,19 @@ def multihead_attention(
             why = (f"lengths q={q.shape[1]} k={k.shape[1]} have no block "
                    f"divisor <= {MAX_BLOCK}/{MAX_BLOCK}")
         if why is None:
-            def kernel(q, k, v):
-                return flash_attention(q, *_repeat_kv(q, k, v), causal=causal,
-                                       scale=scale)
+            head_dim = q.shape[-1]
 
-            return _per_shard(kernel, q, k)(q, k, v)
+            def flat(x):
+                return x.reshape(*x.shape[:2], -1)
+
+            def kernel(q, k, v):
+                q, k, v = (x.reshape(*x.shape[:2], -1, head_dim)
+                           for x in (q, k, v))
+                return flat(flash_attention(q, *_repeat_kv(q, k, v),
+                                            causal=causal, scale=scale))
+
+            return _per_shard(kernel, q, k)(flat(q), flat(k), flat(v)
+                                            ).reshape(q.shape)
         # the reference path partitions under GSPMD: no per-shard wrap
         log_once(log, f"flash attention: XLA reference path, not the "
                       f"kernel: {why}")

@@ -10,7 +10,9 @@ lanes).
 Backward follows the standard flash decomposition: save per-row logsumexp
 ``lse`` from the forward; recompute P = exp(qkᵀ·scale − lse) blockwise; a
 dq kernel loops K-blocks, a dk/dv kernel loops Q-blocks; the rowwise
-``delta = Σ dO∘O`` term is a cheap XLA einsum outside the kernels.
+``delta = Σ dO∘O`` term is formed inside both from an ``O`` operand (a
+product and a turn on the XLU a Q-block, under the kernels' compute, where
+an XLA pass over dO and O stood in the open).
 
 What follows the input and what is float32, always. Every matmul takes its
 operands in the dtype q, k, v and dO arrive in (bf16 in, bf16 to the MXU;
@@ -33,23 +35,35 @@ per-query statistics (max, normaliser, ``lse``, ``delta``) are then rows of
 ``dSᵀ Q`` are plain products, and the forward and dq accumulate ``Oᵀ = Vᵀ
 Pᵀ`` / ``dQᵀ = Kᵀ dSᵀ`` as ``[head_dim, block_q]`` (transposed once, when a
 Q-block is finished; the ``[block_k, head_dim]`` blocks of V and K are
-transposed in the kernel, on the otherwise idle XLU). For that the backward's
-wrapper hands its kernels ``lse`` / ``delta`` as rows by Q-block: operands'
-layouts, not results'.
+transposed in the kernel, on the otherwise idle XLU). ``lse`` leaves the
+forward as a ``[.., seq, 1]`` column (the result type the benchmark's reader
+tells the forward by); the backward's unrolled cells turn it into rows
+themselves, the looped ones are handed rows by Q-block (``_lse_row``).
 
 Causal masking is bottom-right aligned (``offset = s_k − s_q``: query row
 r sees key columns ≤ r + offset, as the reference's ``tril(k=s_k−s_q)``).
 Each Q-block (K-block in dk/dv) walks its live block pairs in two loops:
 pairs wholly below the diagonal take no mask, pairs the diagonal crosses are
 masked. When the whole problem is at most ``_UNROLL_PAIRS`` block pairs, one
-grid cell takes a whole batch·head (several where a head is a pair or two,
-``_cell_heads``): every block index is then a Python number, dead pairs are
-never emitted and both loops unroll into straight-line code that the compiler
-schedules across pairs. Longer sequences take one Q-block (K-block) per grid
-cell and loop at run time.
+grid cell takes the whole sequence of its heads: every block index is then a
+Python number, dead pairs are never emitted and both loops unroll into
+straight-line code that the compiler schedules across pairs. Longer
+sequences take one Q-block (K-block) per grid cell and loop at run time.
 
-Public shapes: [batch, seq, heads, head_dim] (the models' layout); kernels
-run on a [batch·heads, seq, head_dim] view.
+Layout. Public shapes are the models' ``[batch, seq, heads, head_dim]``; the
+kernels take q, k, v, O, dO and give O, dq, dk, dv as ``[batch, seq,
+heads·head_dim]`` — the same bytes in the same order, and the layout a
+projection's matrix product leaves and takes, so no swap of axes, no copy
+and no lane padding stands between the two. A grid cell ``(batch row, lane
+block[, Q- or K-block])`` holds ``(rows, lanes)`` blocks of whole 128-lane
+tiles: two heads of 64 side by side (four of 32, one of 128; the whole
+width where that is narrower than a tile), each head a static lane slice
+inside the kernel, the results of a cell's heads joined and stored as whole
+rows. Short sequences widen the block (``_cell_heads``). A head count the
+tile does not divide (25 heads of 64: 13 lane blocks) leaves the last cell
+half outside the array: Pallas reads and writes only the part inside, and
+since every head is computed from its own slice alone, whatever the other
+half holds reaches no live head.
 
 The kernels are compiled by Mosaic, which needs a TPU. ``interpret=True``
 runs them in the Pallas interpreter instead — something only a test passes,
@@ -73,16 +87,16 @@ log = get_logger("ops", "flash_attention")
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
-#: a problem of at most this many block pairs is one grid cell per
-#: batch·head, unrolled (1024 x 1024 causal: 3 live pairs in blocks of 512,
-#: 10 in blocks of 256).
+#: a problem of at most this many block pairs a head is one grid cell per
+#: batch row and lane block, unrolled (1024 x 1024 causal: 3 live pairs in
+#: blocks of 512, 10 in blocks of 256).
 _UNROLL_PAIRS = 16
 
-#: block pairs an unrolled grid cell is filled up to with further
-#: batch·heads. Every unrolled pair costs a cached program about 0.1 s of
+#: block pairs an unrolled grid cell is filled up to with further lane
+#: tiles of heads. Every unrolled pair costs a cached program about 0.1 s of
 #: set-up each time it is traced and loaded (PERF.md section 6, PR 24: four
 #: heads a cell at 1024 x 1024 gave +1.2% tokens/s for +3.8 s), so only cells
-#: smaller than this are filled.
+#: smaller than this are widened.
 _CELL_PAIRS = 4
 
 #: what one grid cell's blocks may take of VMEM, one buffer each (Pallas
@@ -207,31 +221,94 @@ def _over_k_blocks(body, carry, q_start, *, block_q: int, block_k: int, n_k: int
 
 
 def _unrolled(n_q: int, n_k: int) -> bool:
-    """Whether a problem of ``n_q x n_k`` block pairs is one grid cell per
-    batch·head, walked in straight-line code (else one block per cell and
-    loops at run time)."""
+    """Whether a problem of ``n_q x n_k`` block pairs a head is one grid
+    cell per batch row and lane block, walked in straight-line code (else
+    one block per cell and loops at run time)."""
     return n_q * n_k <= _UNROLL_PAIRS
 
 
-def _cell_heads(bh: int, pairs: int, unroll: bool, rows: int, like) -> int:
-    """Batch·heads one grid cell takes. One, unless a head is so few block
-    pairs (short sequences: one pair at 128 or 512) that a cell of it is
-    mostly waiting — then as many as make ``_CELL_PAIRS`` pairs a cell and
-    keep its operands and results (``rows`` rows a head of ``like``'s width
-    and dtype, lane-padded as VMEM holds them) within ``_CELL_BYTES``:
-    independent chains of products for the compiler to interleave."""
-    if not unroll:
-        return 1
-    head_bytes = rows * -(-like.shape[-1] // 128) * 128 * like.dtype.itemsize
-    most = max(1, min(_CELL_PAIRS // pairs, _CELL_BYTES // head_bytes))
-    return max(g for g in range(1, most + 1) if bh % g == 0)
+def _cell_heads(heads: int, head_dim: int, pairs: int, unroll: bool,
+                head_bytes: int) -> int:
+    """Heads one grid cell takes, side by side in the lanes of its blocks.
+    As many as fill whole 128-lane tiles (two of 64, one of 128; all of
+    them where the array is narrower than that), and no more — unless a head
+    is so few block pairs (short sequences: one pair at 128 or 512) that a
+    cell of them is mostly waiting: then as many as make ``_CELL_PAIRS``
+    pairs a cell and keep its operands and results (``head_bytes`` a head)
+    within ``_CELL_BYTES``: independent chains of products for the compiler
+    to interleave. A head count the tile's heads do not divide (25 heads of
+    64) leaves the last cell part outside the array: its blocks are read and
+    written only where the array is, and each head is its own lane slice
+    inside the kernels, so what lies outside meets no live head."""
+    tile = math.lcm(head_dim, 128) // head_dim
+    if tile >= heads:
+        return heads
+    if not unroll or heads % tile:
+        return tile
+    most = max(1, min(_CELL_PAIRS // (pairs * tile),
+                      _CELL_BYTES // (head_bytes * tile)))
+    return tile * max(g for g in range(1, most + 1) if heads // tile % g == 0)
+
+
+def _as_row(col):
+    """A ``[rows, 1]`` column of per-query statistics (how the forward's
+    result holds ``lse``) as the ``[1, rows]`` row the transposed score tile
+    takes: spread over 128 lanes, turned on the XLU, one sublane kept."""
+    return jnp.broadcast_to(col, (col.shape[0], 128)).T[:1]
 
 
 def _rows(x, block: int):
-    """Per-query statistics, [BH, S] or [BH, S, 1], as one row of ``block``
-    lanes per Q-block: [BH, S // block, 1, block]."""
-    bh, s = x.shape[:2]
-    return x.reshape(bh, s // block, 1, block)
+    """Per-query statistics [B, H, S, 1] as one row of ``block`` lanes per
+    Q-block: [B, H, S // block, 1, block]."""
+    b, h, s = x.shape[:3]
+    return x.reshape(b, h, s // block, 1, block)
+
+
+def _lse_operand(lse, cell: int, block_q: int, unroll: bool, mine: bool):
+    """``(BlockSpec, operand)`` that hand a backward kernel the forward's
+    ``lse`` [B, H, S, 1]: the column itself, whole, to an unrolled cell; rows
+    by Q-block to a looped one — its own Q-block's (``mine``, dq) or all
+    (dkv). :func:`_lse_rows` reads either inside the kernel."""
+    if unroll:
+        return pl.BlockSpec((None, cell, lse.shape[2], 1),
+                            lambda b, h, i: (b, h, 0, 0)), lse
+    blocks = 1 if mine else lse.shape[2] // block_q
+    return pl.BlockSpec((None, cell, blocks, 1, block_q),
+                        lambda b, h, i: (b, h, i if mine else 0, 0, 0)
+                        ), _rows(lse, block_q)
+
+
+def _lse_rows(lse_ref, qb, block_q: int, unroll: bool):
+    """``lse`` of Q-block ``qb`` for every head of the cell, each a
+    ``[1, block_q]`` row. An unrolled cell holds every row of its heads
+    anyway and takes the forward's column as it is (:func:`_as_row`); a
+    looped one would hold 128 lanes a row for every Q-block (4 MB a pair of
+    heads at 4,096), so the looped side is handed rows by Q-block
+    (:func:`_rows`, made once outside)."""
+    if unroll:
+        return [_as_row(lse_ref[g, qb * block_q:(qb + 1) * block_q, :])
+                for g in range(lse_ref.shape[0])]
+    return [lse_ref[g, qb] for g in range(lse_ref.shape[0])]
+
+
+def _delta_rows(do, o, d: int):
+    """``delta = Σ dO∘O`` over the head size, float32, for every head of
+    ``[rows, heads · d]`` blocks: the product turned once on the XLU for all
+    of them, then each head's ``d`` sublanes summed into a ``[1, rows]``
+    row."""
+    prod_t = (do.astype(jnp.float32) * o.astype(jnp.float32)).T
+    return [jnp.sum(prod_t[g * d:(g + 1) * d], axis=0, keepdims=True)
+            for g in range(prod_t.shape[0] // d)]
+
+
+def _side_by_side(heads, axis: int):
+    """The heads of a cell joined along ``axis`` into one value to store."""
+    return heads[0] if len(heads) == 1 else jnp.concatenate(heads, axis=axis)
+
+
+def _head_cols(lanes: int, d: int):
+    """The lane slice of each head of a ``lanes``-wide block."""
+    return [slice(g * d, (g + 1) * d) for g in range(lanes // d)]
 
 
 # ---------------------------------------------------------------------------
@@ -241,86 +318,98 @@ def _rows(x, block: int):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref,
-    *, block_q: int, block_k: int, causal: bool, scale: float, offset: int, unroll: bool,
+    *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
+    offset: int, unroll: bool,
 ):
-    # q_ref, o_ref: [cell heads, cell rows, d]; lse_ref: [.., cell rows, 1];
-    # k_ref, v_ref: [cell heads, S_k, d]
-    heads, cell_rows, d = q_ref.shape
-    n_k = k_ref.shape[-2] // block_k
-    cell_start = 0 if unroll else pl.program_id(1) * cell_rows
-    for g, j in itertools.product(range(heads), range(cell_rows // block_q)):
+    # q_ref, o_ref: [cell rows, cell heads · d]; k_ref, v_ref: [S_k, cell
+    # heads · d]; lse_ref: [cell heads, cell rows, 1]
+    cell_rows, lanes = q_ref.shape
+    d = head_dim
+    heads = _head_cols(lanes, d)
+    n_k = k_ref.shape[0] // block_k
+    cell_start = 0 if unroll else pl.program_id(2) * cell_rows
+    for j in range(cell_rows // block_q):
         rows = slice(j * block_q, (j + 1) * block_q)
         q_start = cell_start + j * block_q
-        q, s_scale = _fold_scale(q_ref[g, rows, :], scale)
+        qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
 
         def body(kb, carry, *, masked: bool):
-            m, l, acc = carry  # [1, block_q], [1, block_q], [d, block_q]
+            # a K-block for every head of the cell: V is turned once for all
+            # of them, and their chains are independent work to interleave
             k_start = _block_start(kb, block_k)
-            k = k_ref[g, pl.ds(k_start, block_k), :]
-            vt = v_ref[g, pl.ds(k_start, block_k), :].T  # [d, block_k]
-            st = _scores_t(k, q, s_scale,
-                           q_start + offset - k_start if masked else None)
-            m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
-            pt = jnp.exp(st - m_new)
-            correction = jnp.exp(m - m_new)
-            l_new = l * correction + jnp.sum(pt, axis=0, keepdims=True)
-            acc_new = acc * correction + _dot(vt, pt.astype(vt.dtype), _NN)
-            return m_new, l_new, acc_new
+            vt_all = v_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
+            out = []
+            for cols, (q, s_scale), (m, l, acc) in zip(heads, qs, carry):
+                # m, l: [1, block_q]; acc: [d, block_q]
+                k = k_ref[pl.ds(k_start, block_k), cols]
+                vt = vt_all[cols]  # [d, block_k]: the head's sublanes
+                st = _scores_t(k, q, s_scale,
+                               q_start + offset - k_start if masked else None)
+                m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
+                pt = jnp.exp(st - m_new)
+                correction = jnp.exp(m - m_new)
+                l_new = l * correction + jnp.sum(pt, axis=0, keepdims=True)
+                acc_new = acc * correction + _dot(vt, pt.astype(vt.dtype), _NN)
+                out.append((m_new, l_new, acc_new))
+            return tuple(out)
 
-        carry = (
+        carry = tuple((
             jnp.full((1, block_q), NEG_INF, jnp.float32),
             jnp.zeros((1, block_q), jnp.float32),
             jnp.zeros((d, block_q), jnp.float32),
-        )
-        m, l, acc = _over_k_blocks(
+        ) for _ in heads)
+        carry = _over_k_blocks(
             body, carry, q_start, block_q=block_q, block_k=block_k, n_k=n_k,
             offset=offset, causal=causal, unroll=unroll)
-        # Rows that saw no unmasked key (bottom-right-aligned causal with
-        # s_q > s_k leaves the first s_q - s_k rows empty) still have m at
-        # the NEG_INF sentinel: their p would be exp(0)=1, silently averaging
-        # V. Define such rows as zero output, and poison their lse to
-        # +|NEG_INF| so the backward's exp(s - lse) underflows to exactly 0
-        # (no grad leak).
-        dead = m <= NEG_INF * 0.5
-        l = jnp.maximum(l, 1e-30)
-        o_t = jnp.where(dead, 0.0, acc / l)
-        lse = jnp.where(dead, -NEG_INF, m + jnp.log(l))
-        o_ref[g, rows, :] = o_t.T.astype(o_ref.dtype)
-        # lse leaves as a [block_q, 1] column (the result's shape): a row of
-        # 8 equal sublanes transposed, of which one lane is kept.
-        lse_ref[g, rows, :] = jnp.broadcast_to(lse, (8, block_q)).T[:, :1]
+        o_ts = []
+        for g, (m, l, acc) in enumerate(carry):
+            # Rows that saw no unmasked key (bottom-right-aligned causal with
+            # s_q > s_k leaves the first s_q - s_k rows empty) still have m at
+            # the NEG_INF sentinel: their p would be exp(0)=1, silently
+            # averaging V. Define such rows as zero output, and poison their
+            # lse to +|NEG_INF| so the backward's exp(s - lse) underflows to
+            # exactly 0 (no grad leak).
+            dead = m <= NEG_INF * 0.5
+            l = jnp.maximum(l, 1e-30)
+            o_ts.append(jnp.where(dead, 0.0, acc / l))
+            lse = jnp.where(dead, -NEG_INF, m + jnp.log(l))
+            # lse leaves as a [block_q, 1] column (the result's shape): a row
+            # of 8 equal sublanes transposed, of which one lane is kept.
+            lse_ref[g, rows, :] = jnp.broadcast_to(lse, (8, block_q)).T[:, :1]
+        # the cell's heads one under the other as [lanes, block_q], turned
+        # once: whole rows of the model's layout to store
+        o_ref[rows, :] = _side_by_side(o_ts, 0).T.astype(o_ref.dtype)
 
 
-def _fwd(q, k, v, *, causal: bool, scale: float, block_q: int, block_k: int, interpret: bool):
-    # q,k,v: [BH, S, d]
-    bh, s_q, d = q.shape
-    s_k = k.shape[1]
+def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
+         block_k: int, interpret: bool):
+    # q, k, v: [B, S, H·d]
+    b, s_q, width = q.shape
+    s_k, d = k.shape[1], width // heads
     assert s_q % block_q == 0 and s_k % block_k == 0, (s_q, s_k, block_q, block_k)
     n_q, n_k = s_q // block_q, s_k // block_k
     unroll = _unrolled(n_q, n_k)
     cell_rows = s_q if unroll else block_q
-    # q, o and the lse column (float32, one lane in 128: as wide as two
-    # bf16 [s_q, 128] blocks), k, v
-    heads = _cell_heads(bh, n_q * n_k, unroll, 4 * s_q + 2 * s_k, q)
+    # q, o, k, v and the lse column (float32, one lane in 128)
+    cell = _cell_heads(heads, d, n_q * n_k, unroll,
+                       2 * (s_q + s_k) * d * q.dtype.itemsize + s_q * 128 * 4)
     kernel = functools.partial(
-        _fwd_kernel, block_q=block_q, block_k=block_k, causal=causal, scale=scale,
-        offset=s_k - s_q, unroll=unroll,
+        _fwd_kernel, head_dim=d, block_q=block_q, block_k=block_k, causal=causal,
+        scale=scale, offset=s_k - s_q, unroll=unroll,
     )
+    mine = pl.BlockSpec((None, cell_rows, cell * d), lambda b, h, qi: (b, qi, h))
+    whole = pl.BlockSpec((None, s_k, cell * d), lambda b, h, qi: (b, 0, h))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh // heads, s_q // cell_rows),
-        in_specs=[
-            pl.BlockSpec((heads, cell_rows, d), lambda b, qi: (b, qi, 0)),
-            pl.BlockSpec((heads, s_k, d), lambda b, qi: (b, 0, 0)),
-            pl.BlockSpec((heads, s_k, d), lambda b, qi: (b, 0, 0)),
-        ],
+        grid=(b, pl.cdiv(heads, cell), s_q // cell_rows),
+        in_specs=[mine, whole, whole],
         out_specs=[
-            pl.BlockSpec((heads, cell_rows, d), lambda b, qi: (b, qi, 0)),
-            pl.BlockSpec((heads, cell_rows, 1), lambda b, qi: (b, qi, 0)),
+            mine,
+            pl.BlockSpec((None, cell, cell_rows, 1), lambda b, h, qi: (b, h, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, heads, s_q, 1), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -334,68 +423,93 @@ def _fwd(q, k, v, *, causal: bool, scale: float, block_q: int, block_k: int, int
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    *, block_q: int, block_k: int, causal: bool, scale: float, offset: int, unroll: bool,
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
+    *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
+    offset: int, unroll: bool,
 ):
-    # lse_ref, delta_ref: [cell heads, Q-blocks of this cell, 1, block_q]
-    heads, cell_rows, d = q_ref.shape
-    n_k = k_ref.shape[-2] // block_k
-    cell_start = 0 if unroll else pl.program_id(1) * cell_rows
-    for g, j in itertools.product(range(heads), range(cell_rows // block_q)):
+    # lse_ref: [cell heads, cell rows, 1], the forward's column, unrolled;
+    # looped [cell heads, 1, 1, block_q]
+    cell_rows, lanes = q_ref.shape
+    d = head_dim
+    heads = _head_cols(lanes, d)
+    n_k = k_ref.shape[0] // block_k
+    cell_start = 0 if unroll else pl.program_id(2) * cell_rows
+    for j in range(cell_rows // block_q):
         rows = slice(j * block_q, (j + 1) * block_q)
         q_start = cell_start + j * block_q
-        q, s_scale = _fold_scale(q_ref[g, rows, :], scale)
-        do = do_ref[g, rows, :]
-        lse = lse_ref[g, j]
-        delta = delta_ref[g, j]
+        qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
+        dos = [do_ref[rows, cols] for cols in heads]
+        lses = _lse_rows(lse_ref, j, block_q, unroll)
+        deltas = _delta_rows(do_ref[rows, :], o_ref[rows, :], d)
 
-        def body(kb, dq_t, *, masked: bool):
+        def body(kb, dq_ts, *, masked: bool):
             k_start = _block_start(kb, block_k)
-            k = k_ref[g, pl.ds(k_start, block_k), :]
-            v = v_ref[g, pl.ds(k_start, block_k), :]
-            st = _scores_t(k, q, s_scale,
-                           q_start + offset - k_start if masked else None)
-            pt = jnp.exp(st - lse)
-            dst = pt * (_dot(v, do, _NT) - delta)
-            return dq_t + _dot(k.T, dst.astype(k.dtype), _NN)
+            kt_all = k_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
+            out = []
+            for cols, (q, s_scale), do, lse, delta, dq_t in zip(
+                    heads, qs, dos, lses, deltas, dq_ts):
+                k = k_ref[pl.ds(k_start, block_k), cols]
+                v = v_ref[pl.ds(k_start, block_k), cols]
+                st = _scores_t(k, q, s_scale,
+                               q_start + offset - k_start if masked else None)
+                pt = jnp.exp(st - lse)
+                dst = pt * (_dot(v, do, _NT) - delta)
+                out.append(dq_t + _dot(kt_all[cols], dst.astype(k.dtype), _NN))
+            return tuple(out)
 
-        dq_t = _over_k_blocks(
-            body, jnp.zeros((d, block_q), jnp.float32), q_start, block_q=block_q,
-            block_k=block_k, n_k=n_k, offset=offset, causal=causal, unroll=unroll)
-        dq_ref[g, rows, :] = (dq_t * scale).T.astype(dq_ref.dtype)
+        dq_ts = _over_k_blocks(
+            body, tuple(jnp.zeros((d, block_q), jnp.float32) for _ in heads),
+            q_start, block_q=block_q, block_k=block_k, n_k=n_k, offset=offset,
+            causal=causal, unroll=unroll)
+        dq_ref[rows, :] = (_side_by_side(list(dq_ts), 0) * scale
+                           ).T.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, block_q: int, block_k: int, causal: bool, scale: float, offset: int, unroll: bool,
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
+    *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
+    offset: int, unroll: bool,
 ):
-    # lse_ref, delta_ref: [cell heads, n_q, 1, block_q]
-    heads, cell_rows, d = dk_ref.shape
-    n_q = q_ref.shape[-2] // block_q
-    cell_start = 0 if unroll else pl.program_id(1) * cell_rows
-    for g, j in itertools.product(range(heads), range(cell_rows // block_k)):
+    # lse_ref: [cell heads, S_q, 1], the forward's column, unrolled; looped
+    # [cell heads, n_q, 1, block_q]
+    cell_rows, lanes = dk_ref.shape
+    d = head_dim
+    heads = _head_cols(lanes, d)
+    n_q = q_ref.shape[0] // block_q
+    cell_start = 0 if unroll else pl.program_id(2) * cell_rows
+    stats = {}  # unrolled: a Q-block's lse rows and deltas, formed once a cell
+    for j in range(cell_rows // block_k):
         rows = slice(j * block_k, (j + 1) * block_k)
         k_start = cell_start + j * block_k
-        k, s_scale = _fold_scale(k_ref[g, rows, :], scale)
-        v = v_ref[g, rows, :]
+        ks = [_fold_scale(k_ref[rows, cols], scale) for cols in heads]
+        vs = [v_ref[rows, cols] for cols in heads]
 
         def body(qb, carry, *, masked: bool):
-            dk, dv = carry
             q_start = _block_start(qb, block_q)
-            q = q_ref[g, pl.ds(q_start, block_q), :]
-            do = do_ref[g, pl.ds(q_start, block_q), :]
-            st = _scores_t(k, q, s_scale,
-                           q_start + offset - k_start if masked else None)
-            pt = jnp.exp(st - lse_ref[g, qb])
-            dv_new = dv + _dot(pt.astype(do.dtype), do, _NN)
-            dst = pt * (_dot(v, do, _NT) - delta_ref[g, qb])
-            dk_new = dk + _dot(dst.astype(q.dtype), q, _NN)
-            return dk_new, dv_new
+            q_rows = pl.ds(q_start, block_q)
+            found = stats.get(qb) if unroll else None
+            if found is None:
+                found = (_lse_rows(lse_ref, qb, block_q, unroll),
+                         _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :], d))
+                if unroll:
+                    stats[qb] = found
+            out = []
+            for cols, (k, s_scale), v, lse, delta, (dk, dv) in zip(
+                    heads, ks, vs, *found, carry):
+                q = q_ref[q_rows, cols]
+                do = do_ref[q_rows, cols]
+                st = _scores_t(k, q, s_scale,
+                               q_start + offset - k_start if masked else None)
+                pt = jnp.exp(st - lse)
+                dv_new = dv + _dot(pt.astype(do.dtype), do, _NN)
+                dst = pt * (_dot(v, do, _NT) - delta)
+                out.append((dk + _dot(dst.astype(q.dtype), q, _NN), dv_new))
+            return tuple(out)
 
-        carry = (
+        carry = tuple((
             jnp.zeros((block_k, d), jnp.float32),
             jnp.zeros((block_k, d), jnp.float32),
-        )
+        ) for _ in heads)
         first_full = 0
         if causal:
             # Q-blocks before first_live see none of this K-block; from
@@ -403,61 +517,68 @@ def _bwd_dkv_kernel(
             first_live = _clip((k_start - offset) // block_q, 0, n_q)
             first_full = _clip(
                 (k_start + block_k - 1 - offset + block_q - 1) // block_q, 0, n_q)
-            carry = _loop(first_live, first_full, functools.partial(body, masked=True),
-                          carry, unroll=unroll)
-        dk, dv = _loop(first_full, n_q, functools.partial(body, masked=False), carry, unroll=unroll)
+            carry = _loop(first_live, first_full,
+                          functools.partial(body, masked=True), carry,
+                          unroll=unroll)
+        carry = _loop(first_full, n_q, functools.partial(body, masked=False),
+                      carry, unroll=unroll)
         # q entered the products unscaled (the scale sat on k or the scores).
-        dk_ref[g, rows, :] = (dk * scale).astype(dk_ref.dtype)
-        dv_ref[g, rows, :] = dv.astype(dv_ref.dtype)
+        dk_ref[rows, :] = (_side_by_side([dk for dk, _ in carry], 1) * scale
+                           ).astype(dk_ref.dtype)
+        dv_ref[rows, :] = _side_by_side([dv for _, dv in carry], 1
+                                        ).astype(dv_ref.dtype)
 
 
 def _bwd(
-    q, k, v, out, lse, do, *, causal: bool, scale: float,
+    q, k, v, out, lse, do, *, heads: int, causal: bool, scale: float,
     dq_blocks: Tuple[int, int], dkv_blocks: Tuple[int, int], interpret: bool,
 ):
-    bh, s_q, d = q.shape
-    s_k = k.shape[1]
-    delta = jnp.einsum(
-        "bsd,bsd->bs", do.astype(jnp.float32), out.astype(jnp.float32)
-    )
-    static = dict(causal=causal, scale=scale, offset=s_k - s_q)
+    b, s_q, width = q.shape
+    s_k, d = k.shape[1], width // heads
+    item = q.dtype.itemsize
+    static = dict(head_dim=d, causal=causal, scale=scale, offset=s_k - s_q)
 
     block_q, block_k = dq_blocks
     n_q, n_k = s_q // block_q, s_k // block_k
     unroll = _unrolled(n_q, n_k)
-    heads = _cell_heads(bh, n_q * n_k, unroll, 3 * s_q + 2 * s_k, q)  # q do dq k v
+    cell = _cell_heads(heads, d, n_q * n_k, unroll,
+                       (4 * s_q + 2 * s_k) * d * item + s_q * 128 * 4)  # q o do dq k v lse
     cell_q = n_q if unroll else 1  # Q-blocks a grid cell takes
 
-    def whole(s, heads):
-        return pl.BlockSpec((heads, s, d), lambda b, i: (b, 0, 0))
+    def whole(s, cell):
+        return pl.BlockSpec((None, s, cell * d), lambda b, h, i: (b, 0, h))
 
-    mine = pl.BlockSpec((heads, cell_q * block_q, d), lambda b, qi: (b, qi, 0))
-    mine_rows = pl.BlockSpec((heads, cell_q, 1, block_q), lambda b, qi: (b, qi, 0, 0))
+    mine = pl.BlockSpec((None, cell_q * block_q, cell * d),
+                        lambda b, h, qi: (b, qi, h))
+    lse_spec, lse_in = _lse_operand(lse, cell, block_q, unroll, mine=True)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
                           unroll=unroll, **static),
-        grid=(bh // heads, n_q // cell_q),
+        grid=(b, pl.cdiv(heads, cell), n_q // cell_q),
         in_specs=[
-            mine, whole(s_k, heads), whole(s_k, heads), mine, mine_rows, mine_rows,
+            mine, whole(s_k, cell), whole(s_k, cell), mine, mine, lse_spec,
         ],
         out_specs=mine,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, do, _rows(lse, block_q), _rows(delta, block_q))
+    )(q, k, v, out, do, lse_in)
 
     block_q, block_k = dkv_blocks
     n_q, n_k = s_q // block_q, s_k // block_k
     unroll = _unrolled(n_q, n_k)
-    heads = _cell_heads(bh, n_q * n_k, unroll, 2 * s_q + 4 * s_k, q)  # q do k v dk dv
+    cell = _cell_heads(heads, d, n_q * n_k, unroll,
+                       (3 * s_q + 4 * s_k) * d * item + s_q * 128 * 4)  # q o do k v dk dv lse
     cell_k = n_k if unroll else 1  # K-blocks a grid cell takes
-    mine = pl.BlockSpec((heads, cell_k * block_k, d), lambda b, ki: (b, ki, 0))
-    all_rows = pl.BlockSpec((heads, n_q, 1, block_q), lambda b, ki: (b, 0, 0, 0))
+    mine = pl.BlockSpec((None, cell_k * block_k, cell * d),
+                        lambda b, h, ki: (b, ki, h))
+    lse_spec, lse_in = _lse_operand(lse, cell, block_q, unroll, mine=False)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
                           unroll=unroll, **static),
-        grid=(bh // heads, n_k // cell_k),
-        in_specs=[whole(s_q, heads), mine, mine, whole(s_q, heads), all_rows, all_rows],
+        grid=(b, pl.cdiv(heads, cell), n_k // cell_k),
+        in_specs=[whole(s_q, cell), mine, mine, whole(s_q, cell), whole(s_q, cell),
+                  lse_spec],
         out_specs=[mine, mine],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -465,7 +586,7 @@ def _bwd(
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, do, _rows(lse, block_q), _rows(delta, block_q))
+    )(q, k, v, out, do, lse_in)
     return dq, dk, dv
 
 
@@ -474,23 +595,23 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, blocks: Blocks, interpret):
-    return _flash_fwd(q, k, v, causal, scale, blocks, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, heads, causal, scale, blocks: Blocks, interpret):
+    return _flash_fwd(q, k, v, heads, causal, scale, blocks, interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, scale, blocks: Blocks, interpret):
+def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret):
     out, lse = _fwd(
-        q, k, v, causal=causal, scale=scale,
+        q, k, v, heads=heads, causal=causal, scale=scale,
         block_q=blocks[0][0], block_k=blocks[0][1], interpret=interpret,
     )
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, blocks: Blocks, interpret, res, g):
+def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, res, g):
     q, k, v, out, lse = res
     return _bwd(
-        q, k, v, out, lse, g, causal=causal, scale=scale,
+        q, k, v, out, lse, g, heads=heads, causal=causal, scale=scale,
         dq_blocks=blocks[1], dkv_blocks=blocks[2], interpret=interpret,
     )
 
@@ -531,16 +652,16 @@ def flash_attention(
         f"{name} {bq}/{bk} "
         + ("unrolled" if _unrolled(s // bq, s_k // bk) else "looped")
         for name, (bq, bk) in zip(("fwd", "dq", "dkv"), blocks))
+    tile = _cell_heads(h, d, 0, False, 0)  # before short sequences widen it
     log_once(log, f"flash attention: {how} Pallas kernel on "
                   f"{device.platform} ({device.device_kind}), "
                   f"{jnp.dtype(q.dtype).name} operands to the MXU, blocks "
-                  f"q/k {chosen}, over lengths {s}/{s_k}, head_dim {d}")
-    # [B, S, H, d] -> [B*H, S, d]
-    def to_bh(x, sl):
-        return jnp.swapaxes(x, 1, 2).reshape(b * h, sl, d)
-
+                  f"q/k {chosen}, over lengths {s}/{s_k}, head_dim {d}, on "
+                  f"[batch, seq, heads·head_dim] = [{b}, {s}, {h * d}] with "
+                  f"{tile} head(s) to a {tile * d}-lane block")
+    # [B, S, H, d] -> [B, S, H·d] and back: the same bytes in the same order
     out = _flash(
-        to_bh(q, s), to_bh(k, s_k), to_bh(v, s_k),
-        causal, scale, blocks, interpret,
+        q.reshape(b, s, h * d), k.reshape(b, s_k, h * d), v.reshape(b, s_k, h * d),
+        h, causal, scale, blocks, interpret,
     )
-    return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)
+    return out.reshape(b, s, h, d)

@@ -343,20 +343,148 @@ def test_bf16_grads(against, causal):
         assert np.abs(g - w).max() <= 3e-2 * np.abs(w).max(), f"d{name}"
 
 
-@pytest.mark.parametrize("bh,pairs,rows,dtype,heads", [
-    (128, 4, 6 * 1024, "bfloat16", 1),   # gpt2-medium: a head fills its cell
-    (100, 4, 6 * 1024, "bfloat16", 1),   # gpt2-xl's per shard
-    (384, 1, 6 * 128, "bfloat16", 4),    # BERT at 128: one pair a head
-    (384, 2, 6 * 256, "bfloat16", 2),
-    (6, 1, 6 * 512, "bfloat16", 3),      # what divides the batch·heads
-    (64, 1, 6 * 1024, "float32", 2),     # VMEM: [4, 1024, 128] float32 x 6
-], ids=["medium", "xl-shard", "bert-128", "two-pairs", "six-heads", "float32"])
-def test_batch_heads_a_grid_cell(bh, pairs, rows, dtype, heads):
+@pytest.mark.parametrize("heads,d,pairs,head_bytes,cell", [
+    (16, 64, 4, 6 * 1024 * 128, 2),   # gpt2-medium: two heads fill 128 lanes
+    (25, 64, 4, 6 * 1024 * 128, 2),   # gpt2-xl's shard: 13 cells, the last half outside
+    (12, 64, 1, 6 * 128 * 128, 4),    # BERT at 128: one pair a head
+    (12, 64, 2, 6 * 256 * 128, 2),    # two pairs a head
+    (6, 64, 1, 6 * 512 * 128, 2),     # three tiles: what divides them
+    (16, 64, 1, 6 * 2048 * 256, 2),   # VMEM: float32 [2048, 256] x 6 is the limit
+    (8, 128, 4, 6 * 1024 * 256, 1),   # head_dim 128: a head is a tile
+    (8, 32, 4, 6 * 1024 * 64, 4),     # head_dim 32: four heads a tile
+    (2, 16, 1, 6 * 64 * 32, 2),       # narrower than a tile: the whole width
+    (5, 80, 4, 6 * 1024 * 160, 5),    # lcm(80, 128) = 640 lanes > 400: the whole width
+], ids=["medium", "xl-shard", "bert-128", "two-pairs", "six-heads", "float32",
+        "head-dim-128", "head-dim-32", "narrow", "head-dim-80"])
+def test_heads_a_grid_cell(heads, d, pairs, head_bytes, cell):
     from easydl_tpu.ops.flash_attention import _cell_heads
 
-    like = jax.ShapeDtypeStruct((bh, 1024, 64), jnp.dtype(dtype))
-    assert _cell_heads(bh, pairs, True, rows, like) == heads
-    assert _cell_heads(bh, pairs, False, rows, like) == 1
+    assert _cell_heads(heads, d, pairs, True, head_bytes) == cell
+    # looped at run time: the tile's heads, never more
+    import math
+
+    assert _cell_heads(heads, d, pairs, False, head_bytes) \
+        == min(heads, math.lcm(d, 128) // d)
+
+
+def _flash_vs_reference(q, k, v, *, causal, block_q, block_k, atol, rtol,
+                        grad_tol):
+    """Forward and the three gradients of the interpreted kernels against
+    the XLA reference on the same inputs."""
+    scale = q.shape[-1] ** -0.5
+    flash = functools.partial(flash_attention, causal=causal, block_q=block_q,
+                              block_k=block_k, interpret=True)
+    ref = functools.partial(_reference_attention, causal=causal, scale=scale)
+    out, want = flash(q, k, v), ref(q, k, v)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), atol=atol, rtol=rtol)
+    got = jax.grad(_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all(), f"d{name}"
+        assert np.abs(g - w).max() <= grad_tol * np.abs(w).max(), f"d{name}"
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads,d", [
+    (2, 64), (4, 64), (3, 64), (25, 64), (1, 64), (2, 128), (3, 128), (5, 32)],
+    ids=["pair", "two-pairs", "odd-3", "odd-25", "lone-64", "two-of-128",
+         "odd-of-128", "five-of-32"])
+def test_the_models_layout_even_and_odd_head_counts(heads, d, dtype):
+    """``[batch, seq, heads·head_dim]`` blocks of whole 128-lane tiles: two
+    heads of 64 (or four of 32, one of 128) side by side in a grid cell; an
+    odd count leaves the last cell half outside the array, and what lies
+    there reaches no live head's output or gradient."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(11), b=2, s=64, h=heads, d=d,
+                       dtype=jnp.dtype(dtype))
+    tight = dtype == "float32"
+    _flash_vs_reference(q, k, v, causal=True, block_q=32, block_k=32,
+                        atol=2e-5 if tight else 2e-2,
+                        rtol=2e-5 if tight else 2e-2,
+                        grad_tol=5e-4 if tight else 3e-2)
+
+
+@pytest.mark.parametrize("heads", [2, 3], ids=["even", "odd"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("s_q,s_k,block_q,block_k", [
+    (64, 64, 32, 32), (64, 128, 32, 64), (128, 64, 64, 32),
+    (256, 256, 32, 32), (128, 256, 16, 64)],
+    ids=["square", "sq<sk", "sq>sk", "looped-square", "looped-sq<sk"])
+def test_the_models_layout_rectangular_unrolled_and_looped(
+        s_q, s_k, block_q, block_k, causal, heads):
+    """Heads of 64 two to a lane block on both sides of ``_UNROLL_PAIRS``,
+    square and with an offset either way (dead rows where s_q > s_k)."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(12), 3)
+    q = jax.random.normal(kq, (1, s_q, heads, 64))
+    k = jax.random.normal(kk, (1, s_k, heads, 64))
+    v = jax.random.normal(kv, (1, s_k, heads, 64))
+    _flash_vs_reference(q, k, v, causal=causal, block_q=block_q,
+                        block_k=block_k, atol=2e-5, rtol=2e-5, grad_tol=5e-4)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (6, 3), (6, 1), (3, 3)],
+                         ids=["4-over-2", "6-over-3", "6-over-1", "3-over-3"])
+def test_grouped_queries_reach_the_kernels_repeated(monkeypatch, heads, kv_heads):
+    """``multihead_attention`` repeats the shared key/value heads on the
+    heads axis in front of the kernels' view; the repeat's transpose sums
+    their gradients."""
+    monkeypatch.setattr(attention_module, "flash_attention",
+                        functools.partial(flash_attention, interpret=True))
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(13), 3)
+    q = jax.random.normal(kq, (2, 64, heads, 64))
+    k = jax.random.normal(kk, (2, 64, kv_heads, 64))
+    v = jax.random.normal(kv, (2, 64, kv_heads, 64))
+    flash = functools.partial(multihead_attention, causal=True, impl="flash")
+    ref = functools.partial(multihead_attention, causal=True, impl="reference")
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
+    got = jax.grad(_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    _assert_grads_close(got, jax.grad(_loss(ref), argnums=(0, 1, 2))(q, k, v),
+                        atol=5e-4, rtol=5e-4)
+
+
+def _primitives_outside_kernels(fn, *args):
+    """Names of every primitive ``fn(*args)`` traces to, at any depth,
+    except what runs inside a ``pallas_call``."""
+    seen = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            seen.append(eqn.primitive.name)
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for value in eqn.params.values():
+                for v in value if isinstance(value, (tuple, list)) else (value,):
+                    inner = getattr(v, "jaxpr", v)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return seen
+
+
+@pytest.mark.parametrize("heads", [4, 3], ids=["even", "odd"])
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_no_transpose_stands_outside_the_kernels(what, heads):
+    """The kernels take q, k, v, O, dO and give O, dq, dk, dv in the model's
+    own layout: around the three ``pallas_call``s ``flash_attention`` and
+    its gradient hold reshapes only — no ``transpose``, and no product of
+    whole arrays either (``delta`` is formed in the kernels; the one
+    ``reduce_sum`` is this test's loss)."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(14), b=2, s=64, h=heads, d=64,
+                       dtype=jnp.bfloat16)
+    flash = functools.partial(flash_attention, causal=True, block_q=32,
+                              block_k=32, interpret=True)
+    fn = flash if what == "forward" else jax.grad(
+        lambda q, k, v: flash(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    names = _primitives_outside_kernels(fn, q, k, v)
+    assert names.count("pallas_call") == (1 if what == "forward" else 3)
+    assert "transpose" not in names, names
+    assert not {"dot_general", "mul"} & set(names), names
 
 
 def _kernel_dots(fn, *args):

@@ -102,15 +102,19 @@ def test_gpt2_loss_is_the_parents(gpt2_now_and_then):
 
 def test_gpt2_step_program_is_the_parents_op_for_op(gpt2_now_and_then):
     """The lowered bf16 / remat-dots / two-microbatch step at the test size:
-    the same StableHLO text as on the parent commit."""
+    the same StableHLO text as recorded. PR 28 meant to change the program
+    (the projections became matrix products) and wrote this hash anew; the
+    parameter tree, the seeded values, the loss and every gradient above are
+    still held to PR 25's parent."""
     assert step_program_sha256() == gpt2_now_and_then[1]["step_program_sha256"]
 
 
 @pytest.fixture(scope="module")
 def parents_step_programs():
-    """Hashes written on the parent of PR 27 (cb2ffa4: the last commit on
-    which a caller could state the head, its chunk and the accumulation
-    scan's unroll)."""
+    """Hashes of the step programs as PR 28 left them (the projections as
+    matrix products, the flash kernels on ``[batch, seq, heads·head_dim]``
+    under a ``shard_map`` over that view): a later PR that does not mean to
+    change a step program finds them equal."""
     with open(os.path.join(HERE, "goldens", "step_programs.json")) as f:
         return json.load(f)
 
@@ -118,9 +122,8 @@ def parents_step_programs():
 def test_hybrid_step_program_with_the_fused_head_is_the_parents_op_for_op(
         fused_head, parents_step_programs):
     """The hybrid's test description, bf16, remat ``full``, two
-    microbatches of 4 x 64, the fused head in 4 chunks of 64 rows: the
-    parent got it from ``lm_bundle``'s head switch set to True, this tree
-    from the shape rule with its constant lowered — the same StableHLO
+    microbatches of 4 x 64, the fused head in 4 chunks of 64 rows, chosen
+    by the shape rule with its constant lowered: the recorded StableHLO
     text."""
     fused_head(chunk_rows=64)
     assert hybrid_step_program_sha256() \
@@ -131,11 +134,44 @@ def test_gpt2_step_program_under_fsdp4_is_the_parents_op_for_op(
         monkeypatch, eight_devices, parents_step_programs):
     """GPT-2 at the test size under ``MeshSpec(fsdp=4)`` with the kernels
     called per shard (interpreted): what ``_per_shard`` wraps, and the
-    choice in front of it, lower to the parent's text."""
+    choice in front of it, lower to the recorded text."""
     monkeypatch.setattr(attention_module, "flash_attention",
                         functools.partial(flash_attention, interpret=True))
     assert gpt2_fsdp4_step_program_sha256() \
         == parents_step_programs["gpt2_fsdp4_step_program_sha256"]
+
+
+@pytest.mark.parametrize("x_shape,w_shape,n", [
+    ((2, 8, 32), (32, 4, 8), 1),     # q, k, v: [embed, heads, kv]
+    ((2, 8, 4, 8), (4, 8, 32), 2),   # out: [heads, kv, embed]
+    ((2, 8, 32), (32, 64), 1),       # the FFN's: a matrix already
+], ids=["embed-heads-kv", "heads-kv-embed", "matrix"])
+def test_projections_are_matrix_products_with_the_same_numbers(
+        x_shape, w_shape, n):
+    """``_matrix_dot_general`` (the attention projections' ``dot_general``): the
+    result and both gradients of ``lax.dot_general`` on the weight as the
+    parameter tree holds it, from ONE product whose right side is a matrix
+    — so that XLA lays q, k, v out as ``[batch, seq, heads·kv]`` rows."""
+    from easydl_tpu.models.transformer import _matrix_dot_general
+
+    kx, kw = jax.random.split(jax.random.PRNGKey(28))
+    x, w = jax.random.normal(kx, x_shape), jax.random.normal(kw, w_shape)
+    dims = ((tuple(range(x.ndim - n, x.ndim)), tuple(range(n))), ((), ()))
+
+    def loss(dot):
+        return lambda x, w: jnp.sin(dot(x, w, dims)).sum()
+
+    np.testing.assert_allclose(_matrix_dot_general(x, w, dims),
+                               jax.lax.dot_general(x, w, dims), rtol=1e-5,
+                               atol=1e-5)
+    got = jax.grad(loss(_matrix_dot_general), argnums=(0, 1))(x, w)
+    want = jax.grad(loss(jax.lax.dot_general), argnums=(0, 1))(x, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)
+    products = [eqn for eqn in jax.make_jaxpr(
+        lambda x, w: _matrix_dot_general(x, w, dims))(x, w).eqns
+        if eqn.primitive.name == "dot_general"]
+    assert [eqn.invars[1].aval.ndim for eqn in products] == [2]
 
 
 def test_gpt2_hint_is_the_all_attention_formula():

@@ -8,8 +8,10 @@ Mosaic kernel GSPMD is asked to partition. A compile that passes is not a
 run; these guard the build, chip_smoke.py proves the run.
 
 Real widths: [8, 1024, 16, 64] bf16, the per-microbatch attention shape of
-GPT-2 345M at seq 1024. (The whole-step compiles take 10-16 s each and live
-in scripts/rehearse_tpu_compile.py, not in tier-1.)
+GPT-2 345M at seq 1024, which the kernels take as [8, 1024, 1024]: the
+model's own layout, two heads of 64 to a 128-lane block. (The whole-step
+compiles take 10-16 s each and live in scripts/rehearse_tpu_compile.py, not
+in tier-1; the two-layer stack at the bottom of this file stands for them.)
 """
 
 from __future__ import annotations
@@ -78,15 +80,20 @@ def test_flash_compiles_on_one_v5e_device(v5e_2x2, backward, n_kernels):
     compiled = jax.jit(fn).lower(x, x, x).compile()
     calls = _mosaic_calls(compiled)
     assert len(calls) == n_kernels, calls
-    assert all("[128,1024," in c for c in calls), calls  # [B*H, S, ...]
+    assert all("bf16[8,1024,1024]" in c for c in calls), calls  # [B, S, H·d]
 
 
 @pytest.mark.parametrize("shape,blocks", [
-    # [batch·heads, seq, head_dim] = [128, 1024, 64]: gpt2-medium's call;
-    # [100, 1024, 64]: gpt2-xl's per shard under fsdp=4
+    # [batch, seq, heads·head_dim] = [8, 1024, 1024]: gpt2-medium's call;
+    # [4, 1024, 1600]: gpt2-xl's per shard under fsdp=4 — 25 heads, an odd
+    # count: 13 lane blocks, the last half outside the array
     ((8, 1024, 16, 64), ((512, 512), (512, 512), (256, 256))),
     ((4, 1024, 25, 64), ((512, 512), (512, 512), (256, 256))),
-], ids=["128x1024x64", "100x1024x64"])
+    # the hybrid's, key/value heads repeated: the looped side
+    ((2, 4096, 32, 64), ((512, 512), (512, 512), (512, 512))),
+    # head_dim 128: one head a block, nothing to slice
+    ((8, 1024, 8, 128), ((512, 512), (512, 512), (256, 256))),
+], ids=["8x1024x1024", "4x1024x1600", "2x4096x2048", "head-dim-128"])
 def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
     """The (block_q, block_k) the forward, dq and dk/dv kernels choose for
     the benchmark's two calls, bf16 causal — a later change to the choice
@@ -101,12 +108,13 @@ def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
                   argnums=(0, 1, 2))
     calls = _mosaic_calls(jax.jit(fn).lower(x, x, x).compile())
     assert len(calls) == 3, calls
-    assert all(f"[{shape[0] * shape[2]},1024," in c for c in calls), calls
+    assert all(f"bf16[{shape[0]},{seq},{shape[2] * shape[3]}]" in c
+               for c in calls), calls
 
 
 @pytest.mark.parametrize("spec,per_device", [
-    (MeshSpec(dp=4), "[32,1024,"),          # 2 rows x 16 heads
-    (MeshSpec(dp=2, tp=2), "[32,1024,"),    # 4 rows x 8 heads
+    (MeshSpec(dp=4), "bf16[2,1024,1024]"),         # 2 rows x 16 heads
+    (MeshSpec(dp=2, tp=2), "bf16[4,1024,512]"),    # 4 rows x 8 heads
 ], ids=["dp=4", "dp=2,tp=2"])
 def test_sharded_attention_compiles_per_shard(v5e_2x2, spec, per_device):
     """Under a mesh ``multihead_attention`` runs the kernel per shard
@@ -225,3 +233,116 @@ def test_mosaic_payload_and_the_python_call_stack(v5e_2x2, frames):
         jax.config.update("jax_traceback_in_locations_limit", before)
     assert len(a) == len(b) == 1
     assert (a == b) == (frames == 1)
+
+
+@pytest.mark.parametrize("where,batch", [("one_chip", 8), ("shard_map", 2)])
+def test_the_benchmarks_reader_still_tells_the_kernels_by_their_results(
+        named_texts, where, batch):
+    """``benchmark/lib/hlo.flash_calls`` (not this PR's to edit) tells the
+    three calls by result types: the forward's ``(3-D array, float32 array
+    whose last dimension is 1)``, dq's one 3-D array, dkv's two. On the
+    model's layout it reads ``[8, 1024, 1024]`` as 8 batch·heads of
+    head_dim 1024 — the same ``batch_heads x head_dim`` product, which is
+    all ``flops.flash_causal_cost`` takes from them."""
+    import importlib
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    calls = importlib.import_module("lib.hlo").flash_calls(named_texts[where])
+    assert sorted(c["kind"] for c in calls) == ["dkv", "dq", "fwd"]
+    for call in calls:
+        assert (call["batch_heads"], call["seq"], call["head_dim"]) \
+            == (batch, 1024, 1024), call
+        assert {"fwd": "flash_fwd", "dq": "flash_bwd_dq",
+                "dkv": "flash_bwd_dkv"}[call["kind"]] in call["name"]
+
+
+@pytest.fixture(scope="module")
+def two_layer_stack(v5e_2x2):
+    """The instructions under the ``attention`` scope of GPT-2 medium's
+    stack cut to two layers (1024 wide, 16 heads of 64, remat ``dots``,
+    bf16, a 1,024-row head), loss and gradients, compiled for one described
+    chip: ``[(pass, opcode, result type, path inside attention)]`` of every
+    top-level instruction."""
+    import re
+
+    import flax.linen as nn
+
+    from easydl_tpu.models.gpt import lm_bundle
+    from easydl_tpu.models.transformer import TransformerConfig
+
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    try:
+        one = SingleDeviceSharding(v5e_2x2[0])
+        bundle = lm_bundle(TransformerConfig(
+            vocab=1024, d_model=1024, n_heads=16, n_layers=2, d_ff=4096,
+            remat=True, remat_policy="dots", attention_impl="flash",
+            dtype="bfloat16"), "gpt2-medium-two-layers")
+        params = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+            jax.eval_shape(lambda: nn.unbox(
+                bundle.init_fn(jax.random.PRNGKey(0)))))
+        tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one)
+        text = jax.jit(jax.grad(
+            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+        )).lower(params, {"inputs": tokens, "targets": tokens}
+                 ).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    found, fused = [], False
+    for line in text.splitlines():
+        header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if header:  # a fusion's body is not a device operation of its own
+            fused = "fused" in header.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(?[^=]*?\)?) ([\w\-]+)\(", line)
+        if fused or not m or "/attention/" not in line:
+            continue
+        path = line.split('op_name="', 1)[1].split('"', 1)[0]
+        which = ("remat" if "rematted_computation" in path
+                 else "bwd" if "transpose(jvp(" in path else "fwd")
+        found.append((which, m.group(2), m.group(1),
+                      path.split("/attention/", 1)[1]))
+    return found
+
+
+def _big(result: str) -> bool:
+    """Whether a result type holds an array as large as q: 8 x 1024 x 1024."""
+    import math
+    import re
+
+    return any(math.prod(int(x) for x in dims.split(",") if x) >= 8 * 1024 * 1024
+               for _, dims in re.findall(r"(\w+)\[([\d,]*)\]", result))
+
+
+def test_kernels_in_the_stack_take_and_give_the_models_layout(two_layer_stack):
+    calls = [(which, result) for which, opcode, result, path in two_layer_stack
+             if opcode == "custom-call"]
+    assert sorted(which for which, _ in calls) == ["bwd", "bwd", "fwd", "remat"]
+    for _, result in calls:
+        assert "bf16[8,1024,1024]{2,1,0" in result, result
+
+
+@pytest.mark.parametrize("which", ["fwd", "remat", "bwd"])
+def test_no_copy_between_the_projections_and_the_kernels(two_layer_stack, which):
+    """No whole-array ``copy`` or ``transpose`` under the ``attention`` scope
+    in the forward and in the recomputation: q, k, v leave their projections
+    (matrix products, ``models/transformer._matrix_dot_general``) as
+    ``[8, 1024, 1024]`` rows, the layout the kernels take, and O enters
+    ``out`` as the kernel gave it. The backward keeps what XLA puts in
+    front of the q, k, v WEIGHT-gradient products and nothing else: the
+    weights are stored ``{1,3,2,0}`` (``[heads, kv, embed]`` physically), so
+    that product wants dq, dk, dv transposed, whoever made them."""
+    moved = [(opcode, result, path)
+             for w, opcode, result, path in two_layer_stack
+             if w == which and opcode in ("copy", "transpose") and _big(result)]
+    if which != "bwd":
+        assert not moved, moved
+        return
+    assert all(path in ("q/dot_general", "k/dot_general", "v/dot_general")
+               for _, _, path in moved), moved
+    assert len(moved) <= 6, moved
