@@ -2,7 +2,13 @@
 fleet.
 
 MFU = achieved model FLOP/s / the chip's peak dense FLOP/s. The numerator
-uses the PaLM appendix-B accounting (:func:`model_flops_per_token`); the
+uses the PaLM appendix-B accounting and follows the model's DESCRIPTION,
+not its parameter count alone: a bundle's ``flops_per_sample_hint`` is
+``TransformerConfig.train_flops_per_token`` — 6 a matrix parameter, the
+scores of each attention layer, the scan of each Mamba-2 layer, and for a
+looped stack (``loops`` passes over the same parameters) layers, scores and
+head once a PASS — of which :func:`model_flops_per_token` is the
+all-attention, one-pass case. The
 denominator comes from :func:`peak_flops_per_chip`. The elastic worker
 stamps ``mfu`` into its step-metrics records with THESE functions, the
 agent surfaces it live as the ``easydl_worker_mfu`` gauge, and the Brain's

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Sequence
 
-from easydl_tpu.models.gpt import lm_bundle
+from easydl_tpu.models.lm import lm_bundle
 from easydl_tpu.models.registry import ModelBundle, register_model
 from easydl_tpu.models.transformer import SsmConfig, TransformerConfig
 
@@ -88,7 +88,7 @@ def describe(
 def make_granite_hybrid(**description) -> ModelBundle:
     """``description``: the arguments of :func:`describe`. The head is the
     fused chunked one wherever full logits would not fit
-    (``models/gpt.py fused_head_by_shape``)."""
+    (``models/lm.py fused_head_by_shape``)."""
     cfg = describe(**description)
     size = description.get("size", "micro")
     return lm_bundle(cfg, f"granite-4.0-h-{size}-{cfg.n_layers}l")

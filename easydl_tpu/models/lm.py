@@ -1,0 +1,255 @@
+"""The causal-LM bundle of one description of the stack
+(``models/transformer.py``), shared by the families that are descriptions of
+it (``gpt.py``, ``granite_hybrid.py``, ``ouro.py`` hold published numbers and
+a factory each): the plain next-token loss through full logits or the fused
+chunked head, the rule that picks between them, and the looped language
+model's expected-exit objective.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import optax
+from jax import lax
+
+from easydl_tpu.core.data import SyntheticTokens
+from easydl_tpu.models.registry import ModelBundle
+from easydl_tpu.models.transformer import Transformer, TransformerConfig
+from easydl_tpu.ops.fused_xent import fused_softmax_xent, local_batch
+from easydl_tpu.utils.logging import get_logger, log_once
+
+
+def lm_loss(logits, targets, ignore_id: int = -1):
+    """Mean next-token cross-entropy (fp32 accumulation)."""
+    logits = logits.astype(jnp.float32)
+    mask = (targets != ignore_id).astype(jnp.float32)
+    losses = optax.softmax_cross_entropy_with_integer_labels(
+        logits, jnp.maximum(targets, 0)
+    )
+    denom = jnp.maximum(mask.sum(), 1.0)
+    loss = (losses * mask).sum() / denom
+    return loss, denom
+
+
+#: The chunked fused head (ops/fused_xent.py) takes the place of full logits
+#: when ONE device's share of a microbatch's ``[B, S, V]`` float32 logits
+#: would pass this: an eighth of a v5e chip's 16 GB. It is chosen for the
+#: room: GPT-2 at 8 x 1024 x 50304 (1.5 GiB) keeps full logits, the Granite
+#: hybrid at 2 x 4096 x 100352 (3.1 GiB, beside 1.5 GiB of bf16 logits) does
+#: not fit them; a looped model's microbatch forms one set of logits a pass
+#: and is asked about all of them (Ouro at 4 x [2, 4096, 49152]: 6 GiB).
+#: The fused head forms the loss and both gradients in one
+#: pass over each chunk's logits; whether it also beats full logits where
+#: both fit has not been measured since it stopped recomputing them. Which
+#: head a shape gets is decided here and nowhere else; how the fused head
+#: cuts the sequence into chunks is its own matter
+#: (``fused_xent.chunk_positions``).
+FUSED_HEAD_LOGITS_BYTES = 2 * 1024 ** 3
+
+log = get_logger("models", "lm")
+
+
+def fused_head_by_shape(batch: int, seq: int, vocab: int,
+                        heads: int = 1) -> bool:
+    """The rule above, for ``heads`` sets of logits of ``[batch, seq,
+    vocab]`` as the loss function sees them under the context mesh (the one
+    ``Trainer`` enters): the batch is split over the mesh's batch axes where
+    it divides."""
+    return (4 * heads * local_batch(batch) * seq * vocab
+            > FUSED_HEAD_LOGITS_BYTES)
+
+
+def exit_distribution(gate_logits: jax.Array) -> jax.Array:
+    """``p [T, B, S]`` float32 from the passes' gate logits ``[T, B, S]``: a
+    token leaves after pass ``t`` with ``p_t = lambda_t prod_{j<t} (1 -
+    lambda_j)``, ``lambda = sigmoid(gate)``, and after the last pass with
+    what is left (that pass's logit is not read)."""
+    stay = jnp.ones(gate_logits.shape[1:], jnp.float32)
+    p = []
+    for gate in gate_logits[:-1]:
+        leave = jax.nn.sigmoid(gate.astype(jnp.float32))
+        p.append(leave * stay)
+        stay = stay * (1.0 - leave)
+    return jnp.stack(p + [stay])
+
+
+def looplm_objective(states: jax.Array, gate_logits: jax.Array,
+                     head: jax.Array, targets: jax.Array, *, beta: float,
+                     fused: bool, logit_scale: float = 1.0,
+                     ignore_id: int = -1):
+    """The looped language model's training objective (Ouro's stage I,
+    arXiv:2510.25741): the expected next-token loss under the exit gate's
+    distribution over the passes, less ``beta`` times that distribution's
+    entropy. ``(loss, metrics)``.
+
+    ``states``: the passes' normed states ``[T, B, S, D]``;
+    ``gate_logits``: ``[T, B, S]`` float32 (the last pass's is not read:
+    whatever has not left, leaves there); ``head``: ``[V, D]`` in the
+    states' dtype.
+    The distribution is :func:`exit_distribution`'s; gate, distribution and
+    entropy are float32.
+
+    ``fused`` is the head the caller's shape rule picked
+    (:func:`fused_head_by_shape` with ``heads`` the passes): the fused
+    chunked head takes all passes in ONE call on the states joined along
+    the sequence, each row weighted by its ``p_t`` — one pass over each
+    chunk's logits, one carried head gradient, and the weights' gradient is
+    each row's own loss. Else one set of full float32 logits a pass.
+    """
+    passes, batch = states.shape[:2]
+    mask = (targets != ignore_id).astype(jnp.float32)
+    denom = jnp.maximum(mask.sum(), 1.0)
+    with jax.named_scope("exit_gate"):
+        p = exit_distribution(gate_logits)
+        entropy = -(p * jnp.log(jnp.maximum(p, 1e-30))).sum(0)
+        steps = (p * jnp.arange(1, passes + 1, dtype=jnp.float32
+                                )[:, None, None]).sum(0)
+    if fused:
+        with jax.named_scope("lm_head_loss"):
+            # the count the op divides by is of rows, `passes` a token
+            loss, _, rows = fused_softmax_xent(
+                jnp.moveaxis(states, 0, 1).reshape(
+                    batch, -1, states.shape[-1]), head,
+                jnp.tile(targets, (1, passes)),
+                weights=jnp.moveaxis(p, 0, 1).reshape(batch, -1),
+                ignore_id=ignore_id, logit_scale=logit_scale)
+            expected = loss * passes
+            by_pass = rows.reshape(batch, passes, -1).sum((0, 2))
+    else:
+        log_once(log, f"lm head: full logits {passes} x "
+                      f"{[*targets.shape, head.shape[0]]} in float32")
+        by_pass = []
+        for t in range(passes):
+            h = states[t]
+            with jax.named_scope(f"pass_{t}"):
+                with jax.named_scope("lm_head"):
+                    logits = lax.dot_general(
+                        h, head, (((2,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * logit_scale
+                with jax.named_scope("loss"):
+                    by_pass.append(
+                        optax.softmax_cross_entropy_with_integer_labels(
+                            logits, jnp.maximum(targets, 0)) * mask)
+        by_pass = jnp.stack(by_pass)  # [T, B, S]
+        expected = (p * by_pass).sum() / denom
+        by_pass = by_pass.sum((1, 2))
+    mean_entropy = (entropy * mask).sum() / denom
+    metrics = {f"loss_pass_{t}": by_pass[t] / denom for t in range(passes)}
+    metrics.update(perplexity=jnp.exp(expected),
+                   exit_step_mean=(steps * mask).sum() / denom,
+                   exit_entropy=mean_entropy)
+    return expected - beta * mean_entropy, metrics
+
+
+def lm_bundle(cfg: TransformerConfig, name: str, *,
+              moe_aux_weight: float = 0.01,
+              exit_entropy_weight: float = 0.0) -> ModelBundle:
+    """The causal-LM bundle of one description of the stack: init, loss
+    (full logits, or the fused chunked head where
+    :func:`fused_head_by_shape` says so; a gated stack's is
+    :func:`looplm_objective` with ``beta = exit_entropy_weight``), eval,
+    data and the hints."""
+    model = Transformer(cfg)
+    seq_len, vocab, n_layers = cfg.max_seq, cfg.vocab, cfg.n_layers
+
+    def head_of(params, dtype):
+        """The head as ``[V, D]`` in the compute dtype — exactly what
+        tok_emb.attend's dtype promotion does on the logits path. A
+        bf16×f32 dot_general promotes to an f32 matmul, which would take
+        the [B,chunk,V] matmul off the bf16 MXU path."""
+        head = (params["tok_emb"]["embedding"] if cfg.tied_head
+                else params["head"]["kernel"])
+        if hasattr(head, "unbox"):  # boxed (LogicallyPartitioned) params
+            head = head.unbox()
+        head = jnp.asarray(head, dtype=dtype)
+        return head if cfg.tied_head else head.T
+
+    def gated_loss(params, batch):
+        out = model.apply({"params": params}, batch["inputs"],
+                          return_hidden=True)
+        return looplm_objective(
+            out.hidden, out.gate, head_of(params, out.hidden.dtype),
+            batch["targets"], beta=exit_entropy_weight,
+            fused=fused_head_by_shape(*batch["inputs"].shape, vocab,
+                                      heads=cfg.loops),
+            logit_scale=1.0 / cfg.logits_scaling)
+
+    def init_fn(rng):
+        tokens = jnp.zeros((1, seq_len), jnp.int32)
+        return model.init(rng, tokens)["params"]
+
+    def _lm_loss_from(params, batch, mutable=False):
+        """LM loss via the fused chunked head or full logits.
+
+        The fused path asks the stack for hidden states and applies the tied
+        head chunk-by-chunk (ops/fused_xent.py) — the full [B,S,V] f32
+        logits buffer never exists.
+        """
+        mut = None
+        if fused_head_by_shape(*batch["inputs"].shape, vocab):
+            out = model.apply(
+                {"params": params}, batch["inputs"], return_hidden=True,
+                **({"mutable": ["intermediates"]} if mutable else {}),
+            )
+            hidden = out[0] if mutable else out
+            mut = out[1] if mutable else None
+            if cfg.loops > 1:  # ungated: the last pass's state
+                hidden = hidden.hidden[-1]
+            with jax.named_scope("lm_head_loss"):
+                loss, _ = fused_softmax_xent(
+                    hidden, head_of(params, hidden.dtype), batch["targets"],
+                    logit_scale=1.0 / cfg.logits_scaling,
+                )
+        else:
+            log_once(log, f"lm head: full logits "
+                          f"{[*batch['inputs'].shape, vocab]} in float32")
+            out = model.apply(
+                {"params": params}, batch["inputs"],
+                **({"mutable": ["intermediates"]} if mutable else {}),
+            )
+            logits = out[0] if mutable else out
+            mut = out[1] if mutable else None
+            with jax.named_scope("loss"):
+                loss, _ = lm_loss(logits, batch["targets"])
+        return loss, mut
+
+    def loss_fn(params, batch, rng):
+        if cfg.exit_gate:
+            return gated_loss(params, batch)
+        if cfg.moe_experts:
+            loss, mut = _lm_loss_from(params, batch, mutable=True)
+            aux = jnp.sum(
+                jnp.asarray(mut["intermediates"]["moe_aux_loss"][0])
+            )
+            return loss + moe_aux_weight * aux, {
+                "perplexity": jnp.exp(loss),
+                "moe_balance": aux / max(n_layers, 1),
+            }
+        loss, _ = _lm_loss_from(params, batch)
+        return loss, {"perplexity": jnp.exp(loss)}
+
+    def eval_fn(params, batch, rng):
+        # Pure LM loss — no balance regularizer, so eval is comparable
+        # across dense/MoE configs and aux weights.
+        if cfg.exit_gate:
+            return gated_loss(params, batch)
+        loss, _ = _lm_loss_from(params, batch)
+        return loss, {"perplexity": jnp.exp(loss)}
+
+    def make_data(global_batch: int, seed: int = 0):
+        return SyntheticTokens(global_batch, seq_len=seq_len, vocab=vocab, seed=seed)
+
+    return ModelBundle(
+        name=name,
+        init_fn=init_fn,
+        loss_fn=loss_fn,
+        make_data=make_data,
+        eval_fn=eval_fn,
+        param_count_hint=cfg.param_count,
+        # the description's own count: a layer without a score matrix adds
+        # no 12 d s (core/mfu.py's GPT formula is this for all-attention)
+        flops_per_sample_hint=cfg.train_flops_per_token(seq_len) * seq_len,
+    )
+
+

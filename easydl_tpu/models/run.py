@@ -22,7 +22,7 @@ import json
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description="easydl_tpu model zoo runner")
-    ap.add_argument("--model", required=True, help="registry name (mlp, resnet, bert, gpt, granite_hybrid, deepfm, widedeep)")
+    ap.add_argument("--model", required=True, help="registry name (mlp, resnet, bert, gpt, granite_hybrid, ouro, deepfm, widedeep)")
     ap.add_argument("--role", choices=["trainer", "evaluator"], default="trainer")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=32)

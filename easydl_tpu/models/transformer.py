@@ -1,8 +1,11 @@
 """One stack for the LM families, described by data: GPT-2 and BERT
-(LayerNorm, learned positions, multi-head attention, GELU, biases) and the
+(LayerNorm, learned positions, multi-head attention, GELU, biases), the
 Granite 4.0-H hybrid (RMSNorm, no positions, Mamba-2 mixers between
-grouped-query attention layers, SwiGLU, four scalar multipliers) are two
-descriptions of it (``TransformerConfig``). Per layer the description names
+grouped-query attention layers, SwiGLU, four scalar multipliers) and the
+looped Ouro (rotary positions, a norm before AND after every sub-layer,
+the whole stack applied ``loops`` times over the same parameters, an exit
+gate on each pass's normed state) are three descriptions of it
+(``TransformerConfig``). Per layer the description names
 a mixer (``attention`` | ``mamba2``) and an FFN (``gelu`` | ``swiglu``);
 consecutive layers of one kind are one ``nn.scan``.
 
@@ -28,13 +31,14 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from easydl_tpu.ops import multihead_attention
+from easydl_tpu.ops.rope import apply_rope, rope_tables
 from easydl_tpu.ops.ssd import (causal_conv1d, gated_rmsnorm,
                                 ssd_flops_per_token, ssd_scan)
 
@@ -188,7 +192,21 @@ class TransformerConfig:
     layers: Optional[Tuple[Layer, ...]] = None
     norm: str = "layernorm"       # | "rmsnorm"
     norm_eps: float = 1e-6
-    position: str = "learned"     # | "none": no table, no rotary
+    #: "learned": a table added to the embedding | "none" | "rope": q and k
+    #: of every attention layer rotated by position (rotate-half over the
+    #: whole head, angles ``pos * rope_theta ** (-2 i / head_dim)``)
+    position: str = "learned"
+    rope_theta: float = 10000.0
+    #: where a sub-layer is normed: "pre" (its input) | "sandwich" (its input
+    #: AND its output, before the residual add: four norms a layer)
+    norm_placement: str = "pre"
+    #: the runs of layers applied this many times over the SAME parameters,
+    #: the final norm after every pass, the normed state both that pass's
+    #: output and the next pass's input (a looped language model)
+    loops: int = 1
+    #: one linear unit on each pass's normed state (float32): the logit of
+    #: leaving after that pass (``models/lm.py looplm_objective``)
+    exit_gate: bool = False
     #: key/value heads (grouped-query attention); 0 = ``n_heads``
     n_kv_heads: int = 0
     bias: bool = True             # on the projections and the FFN
@@ -211,16 +229,25 @@ class TransformerConfig:
                                  f"{MIXERS}, FFNs {FFNS}")
             if mixer == "mamba2" and self.ssm is None:
                 raise ValueError("a mamba2 layer needs ssm=SsmConfig(...)")
-        if self.position not in ("learned", "none"):
-            raise ValueError(f"position must be 'learned' or 'none', got "
-                             f"{self.position!r}")
+        if self.position not in ("learned", "none", "rope"):
+            raise ValueError(f"position must be 'learned', 'none' or 'rope', "
+                             f"got {self.position!r}")
+        if self.norm_placement not in ("pre", "sandwich"):
+            raise ValueError(f"norm_placement must be 'pre' or 'sandwich', "
+                             f"got {self.norm_placement!r}")
+        if self.loops < 1:
+            raise ValueError(f"loops must be at least 1, got {self.loops}")
+        if self.loops > 1 and self.pipeline_fn is not None:
+            raise NotImplementedError("a looped stack inside the pipeline")
         if self.n_heads % self.kv_heads:
             raise ValueError(f"{self.n_heads} heads do not divide into "
                              f"{self.kv_heads} key/value heads")
 
     @property
     def head_dim(self) -> int:
-        assert self.d_model % self.n_heads == 0
+        if self.d_model % self.n_heads:
+            raise ValueError(f"d_model={self.d_model} does not divide into "
+                             f"n_heads={self.n_heads} heads")
         return self.d_model // self.n_heads
 
     @property
@@ -262,6 +289,8 @@ class TransformerConfig:
             n += self.moe_experts * 2 * d * self.d_ff + d * self.moe_experts
         else:
             n += 2 * d * self.d_ff
+        if self.norm_placement == "sandwich":
+            n += 2 * d                              # the two output norms
         return n + (4 * d if self.bias else 2 * d)  # biases-ish + 2 norms
 
     @property
@@ -273,7 +302,9 @@ class TransformerConfig:
             emb += self.d_model   # final norm (a LayerNorm's sits in the
             #                       layers' "biases-ish" estimate)
         head = 0 if self.tied_head else self.vocab * self.d_model
-        return emb + sum(self.layer_params(l) for l in self.pattern) + head
+        gate = self.d_model + 1 if self.exit_gate else 0
+        return (emb + sum(self.layer_params(l) for l in self.pattern) + head
+                + gate)
 
     def train_flops_per_token(self, seq_len: int) -> float:
         """Training FLOPs a token, forward and backward, recomputation not
@@ -281,14 +312,21 @@ class TransformerConfig:
         appendix B), ``12 * d_model * seq`` for each ATTENTION layer's
         scores and weighted values, and three times the scan's forward
         count for each Mamba-2 layer — not ``12 L d s`` for layers that
-        have no score matrix."""
+        have no score matrix. An untied embedding is a lookup and counts
+        nothing. A looped stack pays its layers, their scores and its head
+        once a pass: ``loops`` does not move ``param_count`` and multiplies
+        this."""
         n_attn = sum(1 for mixer, _ in self.pattern if mixer == "attention")
-        flops = 6.0 * self.param_count + 12.0 * n_attn * self.d_model * seq_len
+        head = self.vocab * self.d_model
+        looped = sum(self.layer_params(l) for l in self.pattern) + head
+        lookup = 0 if self.tied_head else head
+        once = self.param_count - looped - lookup
+        scores = 12.0 * n_attn * self.d_model * seq_len
         if n_attn < len(self.pattern):
             m = self.ssm
-            flops += 3.0 * (len(self.pattern) - n_attn) * ssd_flops_per_token(
+            scores += 3.0 * (len(self.pattern) - n_attn) * ssd_flops_per_token(
                 m.n_heads, m.head_dim, m.d_state, m.n_groups, m.chunk)
-        return flops
+        return 6.0 * once + self.loops * (6.0 * looped + scores)
 
 
 # The mixers and the FFN are functions of the block, not methods of it: flax
@@ -305,7 +343,7 @@ def _projection(block, features, kernel_axes, bias_axes, name,
         axis=axis, dtype=jnp.dtype(cfg.dtype), dot_general=dot_general)
 
 
-def _attention(block, h):
+def _attention(block, h, rope=None):
     cfg = block.cfg
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
     # the four products around the kernels as matrix products: the
@@ -322,11 +360,13 @@ def _attention(block, h):
     k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
     v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
     if cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
+        if rope is not None:  # q and k are whole here: positions from 0
+            q, k = apply_rope(q, *rope), apply_rope(k, *rope)
         attn = cfg.attention_fn(q, k, v, causal=cfg.causal)
     else:
         attn = multihead_attention(
             q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
-            scale=cfg.attention_multiplier,
+            scale=cfg.attention_multiplier, rope=rope,
         )
     return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
                        ("embed",), "out", residual=True, axis=(-2, -1),
@@ -428,8 +468,12 @@ def _ffn(block, h):
 
 
 class Block(nn.Module):
-    """One pre-norm layer of the stack: a mixer and an FFN, each from the
-    norm to the residual add.
+    """One layer of the stack: a mixer and an FFN, each from its norm to
+    the residual add (under ``norm_placement="sandwich"`` each sub-layer's
+    output is normed once more in front of the add).
+
+    ``rope`` is ``None`` or the rotary tables ``(cos, sin)`` of
+    :func:`easydl_tpu.ops.rope.rope_tables`, made once for all layers.
 
     Returns ``(x, aux)`` — the (carry, per-step-output) pair ``nn.scan``
     expects; standalone callers unpack the first element.
@@ -440,13 +484,16 @@ class Block(nn.Module):
     ffn: str = "gelu"
 
     @nn.compact
-    def __call__(self, x, deterministic: bool = True):
-        # NB: ``deterministic`` is positional — nn.scan drops kwargs.
+    def __call__(self, x, deterministic: bool = True, rope=None):
+        # NB: ``deterministic`` and ``rope`` are positional — nn.scan drops
+        # kwargs.
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
-        def residual(x, h):
+        def residual(x, h, ln):
+            if cfg.norm_placement == "sandwich":
+                h = _norm(cfg, f"{ln}_out", dtype=dt)(h)
             if cfg.dropout and not deterministic:
                 h = nn.Dropout(cfg.dropout, deterministic=False)(h)
             if cfg.residual_multiplier != 1.0:
@@ -461,17 +508,20 @@ class Block(nn.Module):
         if self.mixer == "attention":
             with jax.named_scope("attention"):
                 x = residual(x, _attention(
-                    self, _norm(cfg, "ln_attn", dtype=dt)(x)))
+                    self, _norm(cfg, "ln_attn", dtype=dt)(x), rope),
+                    "ln_attn")
         else:
             with jax.named_scope("ssm"):
-                x = residual(x, _mamba2(self, _norm(cfg, "ln_ssm", dtype=dt)(x)))
+                x = residual(x, _mamba2(
+                    self, _norm(cfg, "ln_ssm", dtype=dt)(x)), "ln_ssm")
         with jax.named_scope("ffn"):
             h, aux = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x))
-            x = residual(x, h)
+            x = residual(x, h, "ln_mlp")
         return nn.with_logical_constraint(x, ("batch", "seq", "embed")), aux
 
 
-def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic):
+def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic,
+               rope):
     """The one run of the stack through ``cfg.pipeline_fn``'s GPipe
     schedule, on the stacked params the plain path created."""
     cfg = stack.cfg
@@ -512,7 +562,7 @@ def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic):
     stacked = nn.meta.unbox(stack.variables["params"]["blocks"])
 
     def apply_stage(stage_params, h):
-        y, _ = chunk.apply({"params": stage_params}, h, deterministic)
+        y, _ = chunk.apply({"params": stage_params}, h, deterministic, rope)
         return y
 
     # block_remat tells the pipeline whether the blocks already
@@ -523,14 +573,26 @@ def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic):
     return x, jnp.zeros((cfg.n_layers,), jnp.float32)
 
 
+class LoopStates(NamedTuple):
+    """What a looped or gated stack gives under ``return_hidden``, stacked
+    by pass: the normed states ``[T, B, S, D]``, and (``exit_gate``) the
+    gate logits ``[T, B, S]`` in float32, else None. While the parameters
+    are being made (``init``) only one pass is run."""
+
+    hidden: jax.Array
+    gate: Optional[jax.Array]
+
+
 class Transformer(nn.Module):
     """Token-in, logits-out decoder/encoder stack.
 
     ``return_hidden=True`` skips the head matmul and yields the post-LN
     hidden states ``[B, S, D]`` instead of logits — the input contract of
-    the chunked fused LM loss (ops/fused_xent.py), which applies the (tied)
+    the chunked fused LM loss (ops/fused_xent.py), which applies the
     head chunk-by-chunk so the full ``[B, S, V]`` f32 logits tensor never
-    exists.
+    exists. A looped (``loops > 1``) or gated stack yields
+    :class:`LoopStates`, every pass's; without ``return_hidden`` its logits
+    are the last pass's.
     """
 
     cfg: TransformerConfig
@@ -582,41 +644,95 @@ class Transformer(nn.Module):
         scan_kwargs = dict(
             variable_axes={"params": 0},
             split_rngs={"params": True, "dropout": True},
-            in_axes=(nn.broadcast,),
+            in_axes=(nn.broadcast, nn.broadcast),
             metadata_params={nn.PARTITION_NAME: "layers"},
         )
         runs = cfg.runs
         if cfg.pipeline_fn is not None and len(runs) > 1:
             raise NotImplementedError(
                 "pipeline_fn over a stack of more than one run of layers")
-        aux_losses = []
-        for i, ((mixer, ffn), count) in enumerate(runs):
-            scanned = nn.scan(block_cls, length=count, **scan_kwargs)(
-                cfg, mixer, ffn,
-                name="blocks" if len(runs) == 1 else f"blocks_{i}")
-            if cfg.pipeline_fn is None or self.is_initializing():
-                # plain (or init) path: params are created here with the
-                # stacked [n_layers, ...] layout the pipeline also expects
-                x, layer_aux = scanned(x, deterministic)
-            else:
-                x, layer_aux = _pipelined(
-                    self, block_cls, scan_kwargs, mixer, ffn, x, deterministic)
-            aux_losses.append(jnp.sum(layer_aux))
+        rope = (rope_tables(seq, cfg.head_dim, cfg.rope_theta)
+                if cfg.position == "rope" else None)
+
+        def pass_end(stack, x):
+            """The final norm, and the exit gate's logit on the normed
+            state (or None)."""
+            x = _norm(cfg, "ln_f", dtype=dt)(x)
+            gate = None
+            if cfg.exit_gate:
+                w = stack.param("exit_gate", nn.with_logical_partitioning(
+                    nn.initializers.normal(stddev=0.02), ("embed",)),
+                    (cfg.d_model,))
+                b = stack.param(
+                    "exit_gate_bias", nn.with_logical_partitioning(
+                        nn.initializers.zeros_init(), (None,)), (1,))
+                # float32 whatever the compute dtype: a sum of products on
+                # the VPU, not a matrix product
+                with jax.named_scope("exit_gate"):
+                    gate = jnp.sum(x.astype(jnp.float32)
+                                   * w.astype(jnp.float32), -1) \
+                        + b.astype(jnp.float32)
+            return x, gate
+
+        def one_pass(stack, x):
+            """The runs of layers once and the final norm: ``(x, (x, gate
+            logit, aux))``, a scan body over passes."""
+            aux = jnp.zeros((), jnp.float32)
+            for i, ((mixer, ffn), count) in enumerate(runs):
+                if cfg.pipeline_fn is None or stack.is_initializing():
+                    # plain (or init) path: params are created here with
+                    # the stacked [n_layers, ...] layout the pipeline also
+                    # expects
+                    x, layer_aux = nn.scan(
+                        block_cls, length=count, **scan_kwargs)(
+                            cfg, mixer, ffn,
+                            name="blocks" if len(runs) == 1 else f"blocks_{i}"
+                    )(x, deterministic, rope)
+                else:
+                    x, layer_aux = _pipelined(
+                        stack, block_cls, scan_kwargs, mixer, ffn, x,
+                        deterministic, rope)
+                aux = aux + jnp.sum(layer_aux)
+            # Between passes only the normed state is kept (bf16): the
+            # final norm's and the gate's float32 intermediates, 4 x [B, S,
+            # D] a pass, are recomputed in the backward pass.
+            x, gate = (nn.remat(pass_end, prevent_cse=False)
+                       if cfg.remat and cfg.loops > 1 else pass_end)(stack, x)
+            return x, (x, gate, aux)
+
+        if cfg.loops == 1 or self.is_initializing():
+            x, (_, gate, aux) = one_pass(self, x)
+            states = x[None]
+            gates = None if gate is None else gate[None]
+        else:
+            # A looped stack is ONE traced pass scanned `loops` times over
+            # the same (broadcast) parameters: each parameter's gradient is
+            # summed over its uses in the scan's carry, where a Python loop
+            # over passes kept every pass's stacked gradients alive to the
+            # end (2.7 GiB a pass at Ouro's cell: PERF.md section 6, PR 29).
+            # The normed state is that pass's output and the next one's
+            # input; the outputs are stacked by pass.
+            x, (states, gates, aux) = nn.scan(
+                one_pass, variable_broadcast="params",
+                split_rngs={"params": False, "dropout": True},
+                length=cfg.loops)(self, x)
+            aux = jnp.sum(aux)
         # Per-layer MoE load-balance losses (zeros for dense blocks); read
         # back by MoE loss fns via mutable=["intermediates"] — a no-op sow
         # for plain apply() calls.
-        self.sow("intermediates", "moe_aux_loss", sum(aux_losses[1:], aux_losses[0]))
+        self.sow("intermediates", "moe_aux_loss", aux)
 
-        x = _norm(cfg, "ln_f", dtype=dt)(x)
         if return_hidden:
-            return x
+            if cfg.loops == 1 and not cfg.exit_gate:
+                return x
+            return LoopStates(states, gates)
         with jax.named_scope("lm_head"):
             if cfg.tied_head:
                 logits = tok_emb.attend(x)
             else:
                 logits = _dense(
                     cfg.vocab, ("embed", "vocab"), (), name="head",
-                    use_bias=False,
+                    use_bias=False, dtype=dt,
                 )(x)
             if cfg.logits_scaling != 1.0:
                 logits = logits / jnp.asarray(cfg.logits_scaling,

@@ -47,6 +47,7 @@ from easydl_tpu.ops.flash_attention import (
     choose_blocks,
     flash_attention,
 )
+from easydl_tpu.ops.rope import apply_rope, rope_rows, tiles_lanes
 from easydl_tpu.utils.logging import get_logger, log_once
 
 log = get_logger("ops", "attention")
@@ -102,11 +103,12 @@ def _repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
     return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
 
 
-def _per_shard(fn, q: jax.Array, k: jax.Array):
+def _per_shard(fn, q: jax.Array, k: jax.Array, whole: int = 0):
     """Wrap ``fn``, a function of q, k, v as ``[batch, seq, heads·head_dim]``
-    views, in ``jax.shard_map`` over the context mesh when that mesh spans
-    more than one device, else return it unchanged. ``q`` and ``k`` are the
-    ``[batch, seq, heads, head_dim]`` arrays, for their sizes.
+    views and of ``whole`` further arrays every shard takes whole (the
+    rotary tables), in ``jax.shard_map`` over the context mesh when that
+    mesh spans more than one device, else return it unchanged. ``q`` and
+    ``k`` are the ``[batch, seq, heads, head_dim]`` arrays, for their sizes.
 
     Batch is split over the mesh's batch axes and heads over ``tp`` where
     the sizes divide (the query's heads AND the key/value heads, which may
@@ -138,8 +140,8 @@ def _per_shard(fn, q: jax.Array, k: jax.Array):
             f"heads")
         heads = None
     spec = P(batch or None, None, heads)
-    return jax.shard_map(fn, in_specs=(spec, spec, spec), out_specs=spec,
-                         check_vma=False)
+    return jax.shard_map(fn, in_specs=(spec, spec, spec) + (P(),) * whole,
+                         out_specs=spec, check_vma=False)
 
 
 @functools.partial(
@@ -154,11 +156,16 @@ def multihead_attention(
     scale: Optional[float] = None,
     impl: str = "auto",
     segment_ids: Optional[jax.Array] = None,
+    rope: Optional[tuple] = None,
 ) -> jax.Array:
     """Attention over [batch, seq, heads, head_dim] tensors.
 
     Args:
       impl: "auto" | "flash" (Pallas, TPU) | "reference" (XLA einsum).
+      rope: None, or the tables of ``ops/rope.py rope_tables``: q and k are
+        rotated by position first — beside the flash kernels by the Pallas
+        kernel on their own view where a head is whole lane tiles
+        (``rope_rows``), else in ``jax.numpy``.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -180,23 +187,33 @@ def multihead_attention(
                    f"divisor <= {MAX_BLOCK}/{MAX_BLOCK}")
         if why is None:
             head_dim = q.shape[-1]
+            # rotated beside the kernels, on their own view, where a head
+            # is whole lane tiles; else here, in jax.numpy
+            tables = rope if rope is not None and tiles_lanes(head_dim) else ()
+            if rope is not None and not tables:
+                q, k = apply_rope(q, *rope), apply_rope(k, *rope)
 
             def flat(x):
                 return x.reshape(*x.shape[:2], -1)
 
-            def kernel(q, k, v):
+            def kernel(q, k, v, *tables):
+                if tables:
+                    q = rope_rows(q, *tables, head_dim=head_dim)
+                    k = rope_rows(k, *tables, head_dim=head_dim)
                 q, k, v = (x.reshape(*x.shape[:2], -1, head_dim)
                            for x in (q, k, v))
                 return flat(flash_attention(q, *_repeat_kv(q, k, v),
                                             causal=causal, scale=scale))
 
-            return _per_shard(kernel, q, k)(flat(q), flat(k), flat(v)
-                                            ).reshape(q.shape)
+            return _per_shard(kernel, q, k, len(tables))(
+                flat(q), flat(k), flat(v), *tables).reshape(q.shape)
         # the reference path partitions under GSPMD: no per-shard wrap
         log_once(log, f"flash attention: XLA reference path, not the "
                       f"kernel: {why}")
     elif impl != "reference":
         raise ValueError(f"unknown attention impl {impl!r}")
+    if rope is not None:
+        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
     return _reference_attention(
         q, *_repeat_kv(q, k, v), causal=causal, scale=scale,
         segment_ids=segment_ids

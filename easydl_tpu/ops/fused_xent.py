@@ -99,17 +99,19 @@ def _nll(logits, t):
     return lse, lse - jnp.where(onehot, logits, 0.0).sum(-1), onehot
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _xent(hidden, head, targets, ignore_id, chunk, logit_scale):
-    """``(loss, denom)`` of padded inputs, ``chunk`` positions a scan step.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _xent(hidden, head, targets, weights, ignore_id, chunk, logit_scale):
+    """``(loss, denom)`` of padded inputs — with ``weights`` ``(loss, denom,
+    row losses)`` — ``chunk`` positions a scan step.
     Evaluation is the forward rule's loss: the gradients beside it feed
     nothing there, and a jitted program keeps one product a chunk (read
     from the loss-only program compiled for the CPU and for a v5e). Called
     outside ``jit`` the scan is a program of its own and runs whole."""
-    return _xent_fwd(hidden, head, targets, ignore_id, chunk, logit_scale)[0]
+    return _xent_fwd(hidden, head, targets, weights, ignore_id, chunk,
+                     logit_scale)[0]
 
 
-def _xent_fwd(hidden, head, targets, ignore_id, chunk, logit_scale):
+def _xent_fwd(hidden, head, targets, weights, ignore_id, chunk, logit_scale):
     # unmasked positions, counted before the scan (at least 1)
     denom = jnp.maximum((targets != ignore_id).sum().astype(jnp.float32), 1.0)
     operand = jnp.result_type(hidden, head)
@@ -119,6 +121,10 @@ def _xent_fwd(hidden, head, targets, ignore_id, chunk, logit_scale):
         h = lax.dynamic_slice_in_dim(hidden, i * chunk, chunk, 1)
         t = lax.dynamic_slice_in_dim(targets, i * chunk, chunk, 1)
         mask = (t != ignore_id).astype(jnp.float32)
+        if weights is not None:
+            # a row's weight multiplies its loss and so its gradients: it
+            # rides where the mask does
+            mask = mask * lax.dynamic_slice_in_dim(weights, i * chunk, chunk, 1)
         logits = _logits(h, head, logit_scale)
         lse, nll, onehot = _nll(logits, t)
         # one expression, in the dtype the MXU takes it, for both products
@@ -131,21 +137,34 @@ def _xent_fwd(hidden, head, targets, ignore_id, chunk, logit_scale):
         d_head = (d_head + lax.dot_general(
             dlogits, h, dimension_numbers=(((0, 1), (0, 1)), ((), ())),
             preferred_element_type=jnp.float32)).astype(head.dtype)
-        return (total + (nll * mask).sum(), d_head), d_h.astype(hidden.dtype)
+        total = total + (nll * mask).sum()
+        if weights is None:
+            return (total, d_head), d_h.astype(hidden.dtype)
+        # the row's own loss, unweighted: what the caller reports a head
+        # by, and (over the count) the gradient of its weight
+        return (total, d_head), (d_h.astype(hidden.dtype),
+                                 jnp.where(t != ignore_id, nll, 0.0))
 
-    (total, d_head), d_hidden = lax.scan(
+    (total, d_head), per_chunk = lax.scan(
         body, (jnp.zeros((), jnp.float32), jnp.zeros_like(head)),
         jnp.arange(hidden.shape[1] // chunk))
+    d_hidden, rows = per_chunk if weights is not None else (per_chunk, None)
     # [n, B, C, D] -> [B, n * C, D]
     d_hidden = jnp.moveaxis(d_hidden, 0, 1).reshape(hidden.shape)
-    return (total / denom, denom), (d_hidden, d_head)
+    if weights is None:
+        return (total / denom, denom), (d_hidden, d_head)
+    rows = jnp.moveaxis(rows, 0, 1).reshape(targets.shape)
+    return (total / denom, denom, rows), (d_hidden, d_head, rows / denom)
 
 
 def _xent_bwd(ignore_id, chunk, logit_scale, residuals, cotangents):
-    d_hidden, d_head = residuals
-    g = cotangents[0]  # the count of unmasked positions carries no gradient
+    d_hidden, d_head, *d_weights = residuals
+    # the count of unmasked positions carries no gradient, nor do the row
+    # losses handed back beside the loss: they are a report
+    g = cotangents[0]
     return ((g * d_hidden).astype(d_hidden.dtype),
-            (g * d_head).astype(d_head.dtype), None)
+            (g * d_head).astype(d_head.dtype), None,
+            g * d_weights[0] if d_weights else None)
 
 
 _xent.defvjp(_xent_fwd, _xent_bwd)
@@ -156,6 +175,7 @@ def fused_softmax_xent(
     head: jax.Array,
     targets: jax.Array,
     *,
+    weights: jax.Array | None = None,
     ignore_id: int = -1,
     chunk_size: int | None = None,
     logit_scale: float = 1.0,
@@ -165,10 +185,16 @@ def fused_softmax_xent(
     Args:
       hidden: ``[B, S, D]`` final (post-LN) hidden states, any float dtype.
       head: ``[V, D]`` output head in *embedding layout* (the tied-head
-        ``tok_emb.embedding``; pass ``kernel.T`` for an untied ``[D, V]``
+        ``tok_emb.embedding``; ``kernel.T`` of an untied ``[D, V]``
         head).
       targets: ``[B, S]`` int token ids; positions equal to ``ignore_id``
         contribute nothing to loss or denominator.
+      weights: None, or ``[B, S]`` float32: the loss becomes ``sum(weights *
+        row losses) / count``, still one pass over each chunk's logits, and
+        is differentiable in ``weights`` too (a row's gradient is its loss
+        over the count). Several heads on one set of head weights are ONE
+        call on their states joined along the sequence, each row weighted
+        by its head's share (``models/lm.py looplm_objective``).
       chunk_size: sequence positions per scan step; peak memory is
         ``B · chunk_size · V`` f32. None sizes the chunk in rows from the
         shapes (:func:`chunk_positions`).
@@ -177,8 +203,10 @@ def fused_softmax_xent(
 
     Returns:
       ``(loss, denom)`` — mean f32 loss over unmasked positions and the
-      (f32) count of them, matching ``models.gpt.lm_loss``'s contract;
-      ``denom`` carries no gradient.
+      (f32) count of them, matching ``models.lm.lm_loss``'s contract;
+      ``denom`` carries no gradient. With ``weights``: ``(loss, denom, row
+      losses)``, the last ``[B, S]`` float32, each row's own unweighted
+      loss (0 where masked), a report that carries no gradient either.
     """
     if hidden.ndim != 3:
         raise ValueError(f"hidden must be [B,S,D], got {hidden.shape}")
@@ -195,9 +223,16 @@ def fused_softmax_xent(
         hidden = jnp.pad(hidden, ((0, 0), (0, pad), (0, 0)))
         targets = jnp.pad(targets, ((0, 0), (0, pad)),
                           constant_values=ignore_id)
+        if weights is not None:
+            weights = jnp.pad(weights, ((0, 0), (0, pad)))
     log_once(log, f"lm head: fused one-pass, [{batch}, {seq}, "
                   f"{head.shape[0]}] logits in {(seq + pad) // chunk_size} "
                   f"chunk(s) of {local_batch(batch) * chunk_size} rows a "
                   f"device, head gradient carried in {head.dtype}")
-    return _xent(hidden, head, targets, ignore_id, chunk_size,
-                 float(logit_scale))
+    if weights is None:
+        return _xent(hidden, head, targets, None, ignore_id, chunk_size,
+                     float(logit_scale))
+    loss, denom, rows = _xent(hidden, head, targets,
+                              weights.astype(jnp.float32), ignore_id,
+                              chunk_size, float(logit_scale))
+    return loss, denom, rows[:, :seq]

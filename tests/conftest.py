@@ -55,7 +55,7 @@ def eight_devices():
 @pytest.fixture
 def fused_head(monkeypatch):
     """``fused_head(chunk_rows=None)``: from the call on, every shape gets
-    the fused chunked head (``models/gpt.FUSED_HEAD_LOGITS_BYTES`` -> 0) and,
+    the fused chunked head (``models/lm.FUSED_HEAD_LOGITS_BYTES`` -> 0) and,
     where ``chunk_rows`` is named, chunks of that many rows a device
     (``ops/fused_xent.CHUNK_ROWS``), so that a test-size model runs the head
     the hybrid's cell runs, in several chunks. Both constants are read when
@@ -63,10 +63,10 @@ def fused_head(monkeypatch):
     call. Which head a shape gets is no argument of the program; a test
     steers it here."""
     def steer(chunk_rows=None):
-        from easydl_tpu.models import gpt
+        from easydl_tpu.models import lm
         from easydl_tpu.ops import fused_xent
 
-        monkeypatch.setattr(gpt, "FUSED_HEAD_LOGITS_BYTES", 0)
+        monkeypatch.setattr(lm, "FUSED_HEAD_LOGITS_BYTES", 0)
         if chunk_rows is not None:
             monkeypatch.setattr(fused_xent, "CHUNK_ROWS", chunk_rows)
 

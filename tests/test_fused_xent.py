@@ -15,8 +15,8 @@ import pytest
 
 from easydl_tpu.core.mesh import MeshSpec, build_mesh
 from easydl_tpu.models import get_model
-from easydl_tpu.models import gpt as gpt_module
-from easydl_tpu.models.gpt import lm_loss
+from easydl_tpu.models import lm as gpt_module
+from easydl_tpu.models.lm import lm_loss
 from easydl_tpu.ops import fused_xent
 from easydl_tpu.ops.fused_xent import chunk_positions, fused_softmax_xent
 
@@ -113,7 +113,7 @@ def test_gpt_moe_fused_head_runs(eight_devices, fused_head):
 
 def _reject_cases():
     from easydl_tpu.core.train_loop import TrainConfig
-    from easydl_tpu.models.gpt import lm_bundle
+    from easydl_tpu.models.lm import lm_bundle
     from easydl_tpu.models.granite_hybrid import describe
 
     job = {"model": "gpt", "model_kwargs": {"size": "test", "seq_len": 32}}

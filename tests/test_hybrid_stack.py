@@ -31,7 +31,7 @@ from easydl_tpu.core.checkpoint import CheckpointManager
 from easydl_tpu.core.mesh import MeshSpec, build_mesh
 from easydl_tpu.core.sharding import flatten_dict, unbox
 from easydl_tpu.core.train_loop import TrainConfig, Trainer
-from easydl_tpu.models import gpt as gpt_module
+from easydl_tpu.models import lm as gpt_module
 from easydl_tpu.models.granite_hybrid import describe
 from easydl_tpu.models.registry import get_model, list_models
 from easydl_tpu.ops import attention as attention_module

@@ -93,7 +93,11 @@ def test_flash_compiles_on_one_v5e_device(v5e_2x2, backward, n_kernels):
     ((2, 4096, 32, 64), ((512, 512), (512, 512), (512, 512))),
     # head_dim 128: one head a block, nothing to slice
     ((8, 1024, 8, 128), ((512, 512), (512, 512), (256, 256))),
-], ids=["8x1024x1024", "4x1024x1600", "2x4096x2048", "head-dim-128"])
+    # Ouro's microbatches, 16 heads of 128 at 4,096: the looped side
+    ((1, 4096, 16, 128), ((512, 512), (512, 512), (512, 512))),
+    ((2, 4096, 16, 128), ((512, 512), (512, 512), (512, 512))),
+], ids=["8x1024x1024", "4x1024x1600", "2x4096x2048", "head-dim-128",
+        "ouro-1x4096x16x128", "ouro-2x4096x16x128"])
 def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
     """The (block_q, block_k) the forward, dq and dk/dv kernels choose for
     the benchmark's two calls, bf16 causal — a later change to the choice
@@ -271,7 +275,7 @@ def two_layer_stack(v5e_2x2):
 
     import flax.linen as nn
 
-    from easydl_tpu.models.gpt import lm_bundle
+    from easydl_tpu.models.lm import lm_bundle
     from easydl_tpu.models.transformer import TransformerConfig
 
     frames = jax.config.jax_traceback_in_locations_limit
@@ -346,3 +350,81 @@ def test_no_copy_between_the_projections_and_the_kernels(two_layer_stack, which)
     assert all(path in ("q/dot_general", "k/dot_general", "v/dot_general")
                for _, _, path in moved), moved
     assert len(moved) <= 6, moved
+
+
+@pytest.fixture(scope="module")
+def two_layer_rotary_stack(v5e_2x2):
+    """As ``two_layer_stack``, for Ouro's description cut to two layers and
+    two passes (2048 wide, 16 heads of 128, rotary, sandwich norms, remat
+    ``full``, bf16, one 4,096-token sequence, a 1,024-row head): every
+    top-level instruction under ``attention``."""
+    import re
+
+    import flax.linen as nn
+
+    from easydl_tpu.models.ouro import make_ouro
+
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    try:
+        one = SingleDeviceSharding(v5e_2x2[0])
+        bundle = make_ouro(
+            size="2.6b", seq_len=4096, vocab=1024, dtype="bfloat16",
+            remat=True, remat_policy="full", attention_impl="flash",
+            layer_types=["full_attention"] * 2, total_ut_steps=2)
+        params = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+            jax.eval_shape(lambda: nn.unbox(
+                bundle.init_fn(jax.random.PRNGKey(0)))))
+        tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one)
+        text = jax.jit(jax.grad(
+            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+        )).lower(params, {"inputs": tokens, "targets": tokens}
+                 ).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    found, fused = [], False
+    for line in text.splitlines():
+        header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if header:  # a fusion's body is not a device operation of its own
+            fused = "fused" in header.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(?[^=]*?\)?) ([\w\-]+)\(", line)
+        if fused or not m or "/attention/" not in line:
+            continue
+        path = line.split('op_name="', 1)[1].split('"', 1)[0]
+        which = ("remat" if "rematted_computation" in path
+                 else "bwd" if "transpose(jvp(" in path else "fwd")
+        found.append((which, m.group(2), m.group(1),
+                      path.split("/attention/", 1)[1]))
+    return found
+
+
+@pytest.mark.parametrize("which", ["fwd", "remat"])
+def test_rotary_puts_no_copy_between_the_projections_and_the_kernels(
+        two_layer_rotary_stack, which):
+    """q and k go from their projections through the rotary kernel to the
+    flash kernels as ``[1, 4096, 2048]`` rows: in the forward and in the
+    recomputation no whole-array ``copy`` or ``transpose`` stands under
+    ``attention`` (as XLA operations on half-head slices the rotation
+    brought four float32 copies a layer back: PERF.md section 6, PR 29),
+    and the Mosaic calls there are the flash forward and two rotations."""
+    import math
+    import re
+
+    def big(result):
+        return any(math.prod(int(x) for x in dims.split(",") if x)
+                   >= 4096 * 2048
+                   for _, dims in re.findall(r"(\w+)\[([\d,]*)\]", result))
+
+    mine = [(opcode, result, path)
+            for w, opcode, result, path in two_layer_rotary_stack
+            if w == which]
+    moved = [x for x in mine if x[0] in ("copy", "transpose") and big(x[1])]
+    assert not moved, moved
+    kernels = sorted(path.split("/")[-2] for opcode, _, path in mine
+                     if opcode == "custom-call")
+    assert kernels == ["flash_fwd", "rope_fwd", "rope_fwd"], kernels
+    for opcode, result, path in mine:
+        if opcode == "custom-call":
+            assert "bf16[1,4096,2048]{2,1,0" in result, result
