@@ -37,10 +37,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from easydl_tpu.ops import multihead_attention
+from easydl_tpu.ops import multihead_attention, remat
 from easydl_tpu.ops.rope import apply_rope, rope_tables
 from easydl_tpu.ops.ssd import (causal_conv1d, gated_rmsnorm,
                                 ssd_flops_per_token, ssd_scan)
+from easydl_tpu.utils.logging import get_logger, log_once
+
+log = get_logger("models", "transformer")
 
 Init = nn.initializers.Initializer
 
@@ -50,7 +53,8 @@ def _matrix_dot_general(lhs, rhs, dimension_numbers, precision=None,
     """``lax.dot_general`` for an attention projection, whose weight carries
     heads and head size as two dimensions (``[embed, heads, kv]`` in,
     ``[heads, kv, embed]`` out), as ONE plain matrix product over their
-    merged dimension.
+    merged dimension, its result left as those rows: ``[..., heads·kv]``
+    (:class:`_RowsDense` gives it the features' shape).
     The same numbers; but the compiler lays a ``[batch, seq, heads·kv]``
     product out row by row, the layout the flash kernels take and give,
     where it gives a ``[batch, seq, heads, kv]`` product of 64-wide rows the
@@ -60,13 +64,42 @@ def _matrix_dot_general(lhs, rhs, dimension_numbers, precision=None,
     n = len(lhs_c)
     assert batch == ((), ()) and tuple(rhs_c) == tuple(range(n)) and \
         tuple(lhs_c) == tuple(range(lhs.ndim - n, lhs.ndim)), dimension_numbers
-    free = rhs.shape[n:]
-    out = jax.lax.dot_general(
+    return jax.lax.dot_general(
         lhs.reshape(lhs.shape[:lhs.ndim - n] + (-1,)),
-        rhs.reshape(math.prod(rhs.shape[:n]), math.prod(free)),
+        rhs.reshape(math.prod(rhs.shape[:n]), math.prod(rhs.shape[n:])),
         (((lhs.ndim - n,), (0,)), ((), ())), precision=precision,
         preferred_element_type=preferred_element_type)
-    return out.reshape(out.shape[:-1] + free)
+
+
+class _RowsDense(nn.DenseGeneral):
+    """``nn.DenseGeneral`` for an attention projection — its parameters under
+    their names, with their shapes, axes and initial values — that keeps the
+    result as rows of ONE merged feature dimension until it is finished:
+    the product (:func:`_matrix_dot_general`), the bias added to those rows
+    (``bias_on_rows``; the parent class is built with ``use_bias=False``),
+    the name remat ``dots`` keeps it by, and only then the features' shape.
+    A bias added to ``[batch, seq, heads, 64]`` made the kept sum a
+    four-dimensional array, which the compiler lays out with the sequence as
+    its minor dimension: a transposing copy in front of every kernel, in the
+    forward and in the backward (PERF.md section 6, PR 30)."""
+
+    bias_on_rows: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        # the parent's own body, not its wrapped method: flax would put a
+        # second scope of this module's name into every operation's path
+        # (`q/q/dot_general`), which the trace's readers and tables know
+        # as `q/dot_general`
+        rows = nn.DenseGeneral.__call__.__wrapped__(self, x)
+        features = self.features if isinstance(self.features, tuple) \
+            else (self.features,)
+        if self.bias_on_rows:
+            bias = self.param("bias", self.bias_init, features,
+                              self.param_dtype)
+            rows = rows + jnp.asarray(bias, rows.dtype).reshape(-1)
+        return remat.name(rows, remat.PROJECTION).reshape(
+            rows.shape[:-1] + features)
 
 
 def _dense(
@@ -78,14 +111,19 @@ def _dense(
     init_scale=1.0,
     axis=-1,
     dtype=None,
-    dot_general=None,
+    rows=False,
 ):
-    return nn.DenseGeneral(
+    """``nn.DenseGeneral``; with ``rows`` :class:`_RowsDense`, which is told
+    of the bias apart (its parent class adds none)."""
+    if rows:
+        cls, bias = _RowsDense, dict(use_bias=False, bias_on_rows=use_bias,
+                                     dot_general=_matrix_dot_general)
+    else:
+        cls, bias = nn.DenseGeneral, dict(use_bias=use_bias)
+    return cls(
         features,
         axis=axis,
-        use_bias=use_bias,
         dtype=dtype,  # compute dtype; params stay f32 (param_dtype default)
-        dot_general=dot_general,  # None: lax.dot_general
         kernel_init=nn.with_logical_partitioning(
             nn.initializers.normal(stddev=0.02 * init_scale), kernel_axes
         ),
@@ -93,6 +131,7 @@ def _dense(
             nn.initializers.zeros_init(), bias_axes
         ),
         name=name,
+        **bias,
     )
 
 
@@ -158,8 +197,10 @@ class TransformerConfig:
     dropout: float = 0.0
     remat: bool = False
     #: remat granularity: "full" recomputes the whole block (min memory);
-    #: "dots" keeps matmul outputs and recomputes only elementwise/softmax
-    #: (jax dots_saveable policy — ~8% faster on TPU when HBM allows).
+    #: "dots" keeps what costs a matrix product to make again — every
+    #: product, q, k, v and `out` after their bias — and the flash
+    #: forward's `lse`; it recomputes the elementwise rest and the forward
+    #: kernel (``ops/remat.py`` says why; faster on TPU when HBM allows).
     remat_policy: str = "full"
     attention_impl: str = "auto"
     #: compute/activation dtype ("float32" | "bfloat16"). Params stay f32;
@@ -333,29 +374,28 @@ class TransformerConfig:
 # wraps a Module's methods in a named scope of their own (``blocks._ffn``),
 # which would put a new component into every operation's path.
 def _projection(block, features, kernel_axes, bias_axes, name,
-                residual=False, axis=-1, dot_general=None):
+                residual=False, axis=-1, rows=False):
     cfg = block.cfg
     return _dense(
         features, kernel_axes, bias_axes, name=name, use_bias=cfg.bias,
         # GPT-2 residual scaling on the projections that write the
         # residual stream
         init_scale=(2 * cfg.n_layers) ** -0.5 if residual else 1.0,
-        axis=axis, dtype=jnp.dtype(cfg.dtype), dot_general=dot_general)
+        axis=axis, dtype=jnp.dtype(cfg.dtype), rows=rows)
 
 
 def _attention(block, h, rope=None):
     cfg = block.cfg
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
-    # the four products around the kernels as matrix products: the
+    # the four products around the kernels as matrix products on rows: the
     # kernels' layout (the Mamba-2 mixer's same-shaped projections feed no
     # kernel and measured SLOWER that way: PERF.md section 6, PR 28)
-    rows = _matrix_dot_general
     q = _projection(block, (cfg.n_heads, cfg.head_dim), heads, kv, "q",
-                    dot_general=rows)(h)
+                    rows=True)(h)
     k = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "k",
-                    dot_general=rows)(h)
+                    rows=True)(h)
     v = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "v",
-                    dot_general=rows)(h)
+                    rows=True)(h)
     q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
     k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
     v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
@@ -370,7 +410,7 @@ def _attention(block, h, rope=None):
         )
     return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
                        ("embed",), "out", residual=True, axis=(-2, -1),
-                       dot_general=rows)(attn)
+                       rows=True)(attn)
 
 
 def _mamba2(block, u):
@@ -505,18 +545,34 @@ class Block(nn.Module):
         # or `ssm` and under `ffn` in the compiled program's op_name paths
         # (read by the device trace's reducers); flax's module names sit
         # inside them.
-        if self.mixer == "attention":
-            with jax.named_scope("attention"):
-                x = residual(x, _attention(
-                    self, _norm(cfg, "ln_attn", dtype=dt)(x), rope),
-                    "ln_attn")
-        else:
-            with jax.named_scope("ssm"):
-                x = residual(x, _mamba2(
-                    self, _norm(cfg, "ln_ssm", dtype=dt)(x)), "ln_ssm")
-        with jax.named_scope("ffn"):
-            h, aux = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x))
-            x = residual(x, h, "ln_mlp")
+        with remat.tally() as named:
+            if self.mixer == "attention":
+                with jax.named_scope("attention"):
+                    x = residual(x, _attention(
+                        self, _norm(cfg, "ln_attn", dtype=dt)(x), rope),
+                        "ln_attn")
+            else:
+                with jax.named_scope("ssm"):
+                    x = residual(x, _mamba2(
+                        self, _norm(cfg, "ln_ssm", dtype=dt)(x)), "ln_ssm")
+            with jax.named_scope("ffn"):
+                h, aux = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x))
+                x = residual(x, h, "ln_mlp")
+        if cfg.remat and cfg.remat_policy == "dots" \
+                and not self.is_initializing():
+            kept = [(name, size) for name, size in named
+                    if name in remat.KEPT]
+            names = [name for name, _ in kept]
+            kinds = ", ".join(f"{names.count(kind)} x {kind}"
+                              for kind in dict.fromkeys(names))
+            left = sorted({name for name, _ in named} - set(remat.KEPT))
+            log_once(log, f"remat dots: a ({self.mixer}, {self.ffn}) layer at "
+                          f"{tuple(x.shape)} keeps {len(kept)} values by "
+                          f"name ({kinds}), "
+                          f"{sum(size for _, size in kept) / 1e6:.1f} MB a "
+                          f"microbatch as traced (a kernel's per shard under "
+                          f"a mesh), beside its unnamed products; named and "
+                          f"not kept: {', '.join(left) or 'nothing'}")
         return nn.with_logical_constraint(x, ("batch", "seq", "embed")), aux
 
 
@@ -633,10 +689,9 @@ class Transformer(nn.Module):
                     f"remat_policy must be 'full' or 'dots', got "
                     f"{cfg.remat_policy!r}"
                 )
-            policy = (
-                jax.checkpoint_policies.dots_saveable
-                if cfg.remat_policy == "dots" else None
-            )
+            # "full" keeps nothing: the names in the block are inert there
+            policy = (remat.dots_policy() if cfg.remat_policy == "dots"
+                      else None)
             block_cls = nn.remat(Block, prevent_cse=False, policy=policy)
         # One traced block a run of equal layers, scanned over a stacked
         # 'layers' param axis: `blocks` where the whole stack is one run

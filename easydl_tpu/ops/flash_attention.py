@@ -37,8 +37,18 @@ Pᵀ`` / ``dQᵀ = Kᵀ dSᵀ`` as ``[head_dim, block_q]`` (transposed once, whe
 Q-block is finished; the ``[block_k, head_dim]`` blocks of V and K are
 transposed in the kernel, on the otherwise idle XLU). ``lse`` leaves the
 forward as a ``[.., seq, 1]`` column (the result type the benchmark's reader
-tells the forward by); the backward's unrolled cells turn it into rows
-themselves, the looped ones are handed rows by Q-block (``_lse_row``).
+tells the forward by); the differentiation rule turns it once into dense
+``[batch, heads, seq]`` rows, the residual it keeps (0.5 MB where the column
+is 64 MB of lane padding), and both backward kernels are handed those rows
+by Q-block (``_lse_operand``).
+
+What a rematerialised block may keep. The rule's forward names the two
+results that cost a kernel to make again, ``out`` and the ``lse`` rows
+(``ops/remat.py``), INSIDE the rule: the residuals the backward receives are
+the named values themselves, so a policy that saved both names would run
+the forward kernel once. Remat ``dots`` saves the rows and not ``out``
+(``ops/remat.py`` has the measurements); under any other policy a name is
+inert.
 
 Causal masking is bottom-right aligned (``offset = s_k − s_q``: query row
 r sees key columns ≤ r + offset, as the reference's ``tril(k=s_k−s_q)``).
@@ -81,6 +91,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from easydl_tpu.ops import remat
 from easydl_tpu.utils.logging import get_logger, log_once
 
 log = get_logger("ops", "flash_attention")
@@ -250,45 +261,18 @@ def _cell_heads(heads: int, head_dim: int, pairs: int, unroll: bool,
     return tile * max(g for g in range(1, most + 1) if heads // tile % g == 0)
 
 
-def _as_row(col):
-    """A ``[rows, 1]`` column of per-query statistics (how the forward's
-    result holds ``lse``) as the ``[1, rows]`` row the transposed score tile
-    takes: spread over 128 lanes, turned on the XLU, one sublane kept."""
-    return jnp.broadcast_to(col, (col.shape[0], 128)).T[:1]
-
-
-def _rows(x, block: int):
-    """Per-query statistics [B, H, S, 1] as one row of ``block`` lanes per
-    Q-block: [B, H, S // block, 1, block]."""
-    b, h, s = x.shape[:3]
-    return x.reshape(b, h, s // block, 1, block)
-
-
-def _lse_operand(lse, cell: int, block_q: int, unroll: bool, mine: bool):
-    """``(BlockSpec, operand)`` that hand a backward kernel the forward's
-    ``lse`` [B, H, S, 1]: the column itself, whole, to an unrolled cell; rows
-    by Q-block to a looped one — its own Q-block's (``mine``, dq) or all
-    (dkv). :func:`_lse_rows` reads either inside the kernel."""
-    if unroll:
-        return pl.BlockSpec((None, cell, lse.shape[2], 1),
-                            lambda b, h, i: (b, h, 0, 0)), lse
-    blocks = 1 if mine else lse.shape[2] // block_q
+def _lse_operand(lse, cell: int, block_q: int, whole: bool):
+    """``(BlockSpec, operand)`` that hand a backward kernel ``lse`` (dense
+    rows ``[B, H, S]``) as one ``[1, block_q]`` row a Q-block, ``[B, H,
+    S // block_q, 1, block_q]``: every Q-block of the cell's heads
+    (``whole``: an unrolled cell, and dkv's looped one, which walks them
+    all) or the grid cell's own (dq looped). The kernels read Q-block ``qb``
+    of head ``g`` as ``lse_ref[g, qb]``."""
+    b, h, s = lse.shape
+    blocks = s // block_q if whole else 1
     return pl.BlockSpec((None, cell, blocks, 1, block_q),
-                        lambda b, h, i: (b, h, i if mine else 0, 0, 0)
-                        ), _rows(lse, block_q)
-
-
-def _lse_rows(lse_ref, qb, block_q: int, unroll: bool):
-    """``lse`` of Q-block ``qb`` for every head of the cell, each a
-    ``[1, block_q]`` row. An unrolled cell holds every row of its heads
-    anyway and takes the forward's column as it is (:func:`_as_row`); a
-    looped one would hold 128 lanes a row for every Q-block (4 MB a pair of
-    heads at 4,096), so the looped side is handed rows by Q-block
-    (:func:`_rows`, made once outside)."""
-    if unroll:
-        return [_as_row(lse_ref[g, qb * block_q:(qb + 1) * block_q, :])
-                for g in range(lse_ref.shape[0])]
-    return [lse_ref[g, qb] for g in range(lse_ref.shape[0])]
+                        lambda b, h, i: (b, h, 0 if whole else i, 0, 0)
+                        ), lse.reshape(b, h, s // block_q, 1, block_q)
 
 
 def _delta_rows(do, o, d: int):
@@ -427,8 +411,7 @@ def _bwd_dq_kernel(
     *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
     offset: int, unroll: bool,
 ):
-    # lse_ref: [cell heads, cell rows, 1], the forward's column, unrolled;
-    # looped [cell heads, 1, 1, block_q]
+    # lse_ref: [cell heads, the cell's Q-blocks, 1, block_q]
     cell_rows, lanes = q_ref.shape
     d = head_dim
     heads = _head_cols(lanes, d)
@@ -439,7 +422,7 @@ def _bwd_dq_kernel(
         q_start = cell_start + j * block_q
         qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
         dos = [do_ref[rows, cols] for cols in heads]
-        lses = _lse_rows(lse_ref, j, block_q, unroll)
+        lses = [lse_ref[g, j] for g in range(len(heads))]
         deltas = _delta_rows(do_ref[rows, :], o_ref[rows, :], d)
 
         def body(kb, dq_ts, *, masked: bool):
@@ -470,14 +453,13 @@ def _bwd_dkv_kernel(
     *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
     offset: int, unroll: bool,
 ):
-    # lse_ref: [cell heads, S_q, 1], the forward's column, unrolled; looped
-    # [cell heads, n_q, 1, block_q]
+    # lse_ref: [cell heads, n_q, 1, block_q]
     cell_rows, lanes = dk_ref.shape
     d = head_dim
     heads = _head_cols(lanes, d)
     n_q = q_ref.shape[0] // block_q
     cell_start = 0 if unroll else pl.program_id(2) * cell_rows
-    stats = {}  # unrolled: a Q-block's lse rows and deltas, formed once a cell
+    stats = {}  # unrolled: a Q-block's lse rows and deltas, read once a cell
     for j in range(cell_rows // block_k):
         rows = slice(j * block_k, (j + 1) * block_k)
         k_start = cell_start + j * block_k
@@ -489,7 +471,7 @@ def _bwd_dkv_kernel(
             q_rows = pl.ds(q_start, block_q)
             found = stats.get(qb) if unroll else None
             if found is None:
-                found = (_lse_rows(lse_ref, qb, block_q, unroll),
+                found = ([lse_ref[g, qb] for g in range(len(heads))],
                          _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :], d))
                 if unroll:
                     stats[qb] = found
@@ -542,7 +524,7 @@ def _bwd(
     n_q, n_k = s_q // block_q, s_k // block_k
     unroll = _unrolled(n_q, n_k)
     cell = _cell_heads(heads, d, n_q * n_k, unroll,
-                       (4 * s_q + 2 * s_k) * d * item + s_q * 128 * 4)  # q o do dq k v lse
+                       (4 * s_q + 2 * s_k) * d * item + s_q * 8 * 4)  # q o do dq k v lse
     cell_q = n_q if unroll else 1  # Q-blocks a grid cell takes
 
     def whole(s, cell):
@@ -550,7 +532,7 @@ def _bwd(
 
     mine = pl.BlockSpec((None, cell_q * block_q, cell * d),
                         lambda b, h, qi: (b, qi, h))
-    lse_spec, lse_in = _lse_operand(lse, cell, block_q, unroll, mine=True)
+    lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=unroll)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
                           unroll=unroll, **static),
@@ -568,11 +550,11 @@ def _bwd(
     n_q, n_k = s_q // block_q, s_k // block_k
     unroll = _unrolled(n_q, n_k)
     cell = _cell_heads(heads, d, n_q * n_k, unroll,
-                       (3 * s_q + 4 * s_k) * d * item + s_q * 128 * 4)  # q o do k v dk dv lse
+                       (3 * s_q + 4 * s_k) * d * item + s_q * 8 * 4)  # q o do k v dk dv lse
     cell_k = n_k if unroll else 1  # K-blocks a grid cell takes
     mine = pl.BlockSpec((None, cell_k * block_k, cell * d),
                         lambda b, h, ki: (b, ki, h))
-    lse_spec, lse_in = _lse_operand(lse, cell, block_q, unroll, mine=False)
+    lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
                           unroll=unroll, **static),
@@ -605,6 +587,13 @@ def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret):
         q, k, v, heads=heads, causal=causal, scale=scale,
         block_q=blocks[0][0], block_k=blocks[0][1], interpret=interpret,
     )
+    # The kernel's column [B, H, S, 1] turned once into dense rows
+    # [B, H, S]; both named HERE, so that the residuals below are the named
+    # values (a name on the primal outside the rule would name a copy, and
+    # the kernel's own results would still be made again). Where nothing is
+    # differentiated the turn is dead code.
+    out = remat.name(out, remat.FLASH_OUT)
+    lse = remat.name(lse.reshape(lse.shape[:3]), remat.FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

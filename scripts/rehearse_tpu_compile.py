@@ -1,17 +1,20 @@
 #!/usr/bin/env python3
-"""Compile the real-size train step for a DESCRIBED v5e 2x2 — no chip needed.
+"""Compile the real-size train steps for a DESCRIBED v5e 2x2 — no chip needed.
 
 The third rehearsal of /opt/skills/guides/on-chip-measurement (section 2):
 the TPU compiler is installed here and compiles for a topology that is
 described, not attached. What it refuses here (a Mosaic kernel GSPMD cannot
 partition, a program that does not fit 16 GB) costs no chip time. Compiles
-chip_smoke.py's programs — GPT-2 345M, seq 1024, bf16, remat "dots", flash
-attention — and prints, per program, the bytes on each device, the Mosaic
-calls and their per-device operand shapes, and the collectives in front of
-them. A compile that passes is not a run.
+chip_smoke.py's programs (GPT-2 345M, seq 1024, bf16, remat "dots", flash
+attention) and the benchmark cells' steps, and prints, per program, the
+bytes on each device, the Mosaic calls (how many of them the flash forward)
+and their per-device operand shapes, and the collectives in front of them.
+A cell's step is also a memory gate: it has a limit in GiB a device, and a
+program over its limit makes the exit code 1. A compile that passes is not
+a run.
 
 Usage: JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py [NAME ...]
-       (names: one dp4 fsdp2tp2 one_accum; default: all)
+       (names: the keys of PROGRAMS; default: all)
 """
 
 from __future__ import annotations
@@ -26,55 +29,96 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-#: name -> (mesh shape key, global batch, grad_accum)
+#: get_model's arguments. attention_impl="flash", stated: "auto" asks
+#: jax.devices(), which is the CPU here, and would compile the reference path.
+_CHIP = dict(dtype="bfloat16", remat=True, attention_impl="flash")
+MEDIUM = ("gpt", dict(size="345m", seq_len=1024, remat_policy="dots", **_CHIP))
+XL = ("gpt", dict(size="1558m", seq_len=1024, remat_policy="dots", **_CHIP))
+HYBRID = ("granite_hybrid", dict(
+    size="micro", seq_len=4096, remat_policy="full",
+    layer_types=["mamba"] * 5 + ["attention"], **_CHIP))
+OURO = ("ouro", dict(size="2.6b", seq_len=4096, remat_policy="full",
+                     layer_types=["full_attention"] * 8, **_CHIP))
+
+#: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
+#: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
+#: limit is its own compiled size and a little: medium's steps 15.292
+#: (15.668 with the flash forward's `out` kept as well: PERF.md section 6,
+#: PR 30), XL's shard 14.111, the hybrid's 15.601, Ouro's 15.488 — a change
+#: to the shared block, kernels or policy may not grow them unseen.
 PROGRAMS = {
-    "one": ("dp=1", 8, 1),            # chip_smoke train/resume/elastic
-    "one_accum": ("dp=1", 32, 8),     # chip_smoke --mesh, the comparison
-    "dp4": ("dp=4", 32, 1),
-    "fsdp2tp2": ("fsdp=2,tp=2", 32, 1),
+    "one": (MEDIUM, "dp=1", 8, 1, "adamw", None),    # chip_smoke train/resume/elastic
+    "one_accum": (MEDIUM, "dp=1", 32, 8, "adamw", None),  # chip_smoke --mesh, the comparison
+    "dp4": (MEDIUM, "dp=4", 32, 1, "adamw", None),
+    "fsdp2tp2": (MEDIUM, "fsdp=2,tp=2", 32, 1, "adamw", None),
+    # the benchmark's cells: gpt2-medium.steady, .kill-resume (the elastic
+    # worker's optax.adam), gpt2-xl.fsdp4-steady, the hybrid's, Ouro's
+    "medium_4x8": (MEDIUM, "dp=1", 32, 4, "adamw", 15.35),
+    "worker_4x8": (MEDIUM, "dp=1", 32, 4, "adam", 15.35),
+    "xl_fsdp4": (XL, "fsdp=4", 16, 1, "adamw", 14.2),
+    "hybrid_4x2": (HYBRID, "dp=1", 8, 4, "adamw", 15.61),
+    "ouro_4x1": (OURO, "dp=1", 4, 4, "adamw", 15.50),
 }
 
 
-def main() -> None:
+def compile_program(name: str, devices):
+    """``(compiled step, GiB a device it takes)`` of ``PROGRAMS[name]`` on
+    the first devices of a described topology. The caller has turned the
+    persistent compile cache off: a described-TPU executable cannot be read
+    back without a chip."""
     import jax
     import jax.numpy as jnp
     import optax
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
 
     from easydl_tpu.core.mesh import MeshSpec, build_mesh
     from easydl_tpu.core.train_loop import TrainConfig, Trainer
     from easydl_tpu.models.registry import get_model
 
-    # A described-TPU executable cannot be read back without a chip.
+    (factory, kwargs), key, batch, accum, optimizer, _ = PROGRAMS[name]
+    bundle = get_model(factory, **kwargs)
+    spec = MeshSpec.parse(key)
+    trainer = Trainer(
+        init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+        optimizer=getattr(optax, optimizer)(1e-3),
+        config=TrainConfig(global_batch=batch, grad_accum=accum),
+        mesh=build_mesh(spec, devices=devices[:spec.size]))
+    tokens = jax.ShapeDtypeStruct((batch, kwargs["seq_len"]), jnp.int32)
+    compiled = trainer.step_fn.lower(
+        trainer.abstract_state(),
+        {"inputs": tokens, "targets": tokens}).compile()
+    mem = compiled.memory_analysis()
+    return compiled, (mem.argument_size_in_bytes
+                      + mem.temp_size_in_bytes) / 2**30
+
+
+def mosaic_calls(text: str):
+    """``[(instruction name, result type)]`` of the Mosaic kernels in a
+    compiled program's text."""
+    return [(line.split(" = ", 1)[0].strip(),
+             line.split(" = ", 1)[1].split(" custom-call(")[0])
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def main() -> None:
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    # attention_impl="flash", stated: "auto" asks jax.devices(), which is
-    # the CPU here, and would compile the reference path instead.
-    bundle = get_model("gpt", size="345m", seq_len=1024, dtype="bfloat16",
-                       remat=True, remat_policy="dots",
-                       attention_impl="flash")
+    over = []
     for name in sys.argv[1:] or list(PROGRAMS):
-        key, batch, accum = PROGRAMS[name]
-        spec = MeshSpec.parse(key)
-        trainer = Trainer(
-            init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
-            optimizer=optax.adamw(1e-3),
-            config=TrainConfig(global_batch=batch, grad_accum=accum),
-            mesh=build_mesh(spec, devices=topo.devices[:spec.size]))
-        tokens = jax.ShapeDtypeStruct((batch, 1024), jnp.int32)
+        (factory, _), key, batch, accum, optimizer, limit = PROGRAMS[name]
         t0 = time.perf_counter()
-        compiled = trainer.step_fn.lower(
-            trainer.abstract_state(),
-            {"inputs": tokens, "targets": tokens}).compile()
+        compiled, gib = compile_program(name, topo.devices)
         dt = time.perf_counter() - t0
         mem = compiled.memory_analysis()
         text = compiled.as_text()
-        kernels = [line.split(" = ", 1)[1].split(" custom-call(")[0]
-                   for line in text.splitlines()
-                   if 'custom_call_target="tpu_custom_call"' in line]
-        shapes = sorted({re.sub(r"\{[^}]*\}", "", out) for out in kernels})
+        calls = mosaic_calls(text)
+        shapes = sorted({re.sub(r"\{[^}]*\}", "", out) for _, out in calls})
+        forwards = sum("flash_fwd" in instruction for instruction, _ in calls)
         collectives = {op: len(re.findall(rf" {op}(?:-start)?\(", text))
                        for op in ("all-gather", "all-reduce", "all-to-all",
                                   "reduce-scatter", "collective-permute")}
@@ -83,13 +127,19 @@ def main() -> None:
         # per-shard call. (Rows == 1 is a layer's FSDP weight gather.)
         gathered = sorted({re.sub(r"\{[^}]*\}", "", m) for m in re.findall(
             r"= (\S+) all-gather(?:-start)?\(", text)})
-        print(f"{name}: mesh {key} batch {batch} accum {accum}: compiled in "
-              f"{dt:.1f}s; per device: arguments "
+        print(f"{name}: {factory} mesh {key} batch {batch} accum {accum} "
+              f"{optimizer}: compiled in {dt:.1f}s; per device: arguments "
               f"{mem.argument_size_in_bytes / 2**30:.2f} GiB + temporaries "
-              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB; "
-              f"{len(kernels)} Mosaic calls, outputs {shapes}; "
+              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB = {gib:.3f} GiB"
+              + (f" (limit {limit})" if limit else "") + f"; "
+              f"{len(calls)} Mosaic calls, {forwards} of them flash_fwd, "
+              f"outputs {shapes}; "
               f"collectives {collectives}; all-gathered shapes {gathered}",
               flush=True)
+        if limit and gib > limit:
+            over.append(f"{name}: {gib:.3f} GiB a device, over its {limit}")
+    if over:
+        sys.exit("; ".join(over))
 
 
 if __name__ == "__main__":

@@ -102,19 +102,21 @@ def test_gpt2_loss_is_the_parents(gpt2_now_and_then):
 
 def test_gpt2_step_program_is_the_parents_op_for_op(gpt2_now_and_then):
     """The lowered bf16 / remat-dots / two-microbatch step at the test size:
-    the same StableHLO text as recorded. PR 28 meant to change the program
-    (the projections became matrix products) and wrote this hash anew; the
-    parameter tree, the seeded values, the loss and every gradient above are
-    still held to PR 25's parent."""
+    the same StableHLO text as recorded. PRs 28 and 30 meant to change the
+    program (the projections became matrix products; remat ``dots`` keeps
+    their sums by name, the bias added to merged rows) and wrote this hash
+    anew; the parameter tree, the seeded values, the loss and every gradient
+    above are still held to PR 25's parent."""
     assert step_program_sha256() == gpt2_now_and_then[1]["step_program_sha256"]
 
 
 @pytest.fixture(scope="module")
 def parents_step_programs():
-    """Hashes of the step programs as PR 28 left them (the projections as
-    matrix products, the flash kernels on ``[batch, seq, heads·head_dim]``
-    under a ``shard_map`` over that view): a later PR that does not mean to
-    change a step program finds them equal."""
+    """Hashes of the step programs as PR 30 left them (GPT-2's: what remat
+    ``dots`` keeps; the hybrid's is still PR 28's program, hashed anew
+    without the private functions' running numbers — the parent commit gives
+    the same hash): a later PR that does not mean to change a step program finds
+    them equal."""
     with open(os.path.join(HERE, "goldens", "step_programs.json")) as f:
         return json.load(f)
 
@@ -151,20 +153,25 @@ def test_projections_are_matrix_products_with_the_same_numbers(
     """``_matrix_dot_general`` (the attention projections' ``dot_general``): the
     result and both gradients of ``lax.dot_general`` on the weight as the
     parameter tree holds it, from ONE product whose right side is a matrix
-    — so that XLA lays q, k, v out as ``[batch, seq, heads·kv]`` rows."""
+    and whose result stays ``[batch, seq, heads·kv]`` rows (``_RowsDense``
+    gives them the features' shape) — the layout XLA lays q, k, v out in."""
     from easydl_tpu.models.transformer import _matrix_dot_general
 
     kx, kw = jax.random.split(jax.random.PRNGKey(28))
     x, w = jax.random.normal(kx, x_shape), jax.random.normal(kw, w_shape)
     dims = ((tuple(range(x.ndim - n, x.ndim)), tuple(range(n))), ((), ()))
+    want = jax.lax.dot_general(x, w, dims)
+
+    def rows(x, w, dims):
+        return _matrix_dot_general(x, w, dims).reshape(want.shape)
 
     def loss(dot):
         return lambda x, w: jnp.sin(dot(x, w, dims)).sum()
 
-    np.testing.assert_allclose(_matrix_dot_general(x, w, dims),
-                               jax.lax.dot_general(x, w, dims), rtol=1e-5,
-                               atol=1e-5)
-    got = jax.grad(loss(_matrix_dot_general), argnums=(0, 1))(x, w)
+    assert _matrix_dot_general(x, w, dims).shape \
+        == x.shape[:x.ndim - n] + (int(np.prod(w_shape[n:])),)
+    np.testing.assert_allclose(rows(x, w, dims), want, rtol=1e-5, atol=1e-5)
+    got = jax.grad(loss(rows), argnums=(0, 1))(x, w)
     want = jax.grad(loss(jax.lax.dot_general), argnums=(0, 1))(x, w)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)
@@ -172,6 +179,60 @@ def test_projections_are_matrix_products_with_the_same_numbers(
         lambda x, w: _matrix_dot_general(x, w, dims))(x, w).eqns
         if eqn.primitive.name == "dot_general"]
     assert [eqn.invars[1].aval.ndim for eqn in products] == [2]
+
+
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no-bias"])
+@pytest.mark.parametrize("x_shape,features,axis", [
+    ((2, 8, 32), (4, 8), -1),          # q, k, v
+    ((2, 8, 4, 8), 32, (-2, -1)),      # out
+], ids=["embed-to-heads-kv", "heads-kv-to-embed"])
+def test_rows_projection_is_dense_general_with_the_bias_added_to_rows(
+        x_shape, features, axis, bias):
+    """``_dense(rows=True)`` against ``_dense()``, flax's ``DenseGeneral``:
+    the same parameter tree (names, shapes, logical axes), the same seeded
+    initial values, the same result and gradients — and every addition it
+    makes is on an array of ONE merged feature dimension, never on ``[batch,
+    seq, heads, kv]`` (the compiler lays a kept four-dimensional sum out with
+    the sequence as its minor dimension: PERF.md section 6, PR 30)."""
+    import flax.linen as nn
+
+    from easydl_tpu.models.transformer import _dense
+
+    kernel_axes = ("embed", "heads", "kv") if axis == -1 \
+        else ("heads", "kv", "embed")
+    bias_axes = kernel_axes[1:] if axis == -1 else ("embed",)
+    x = jax.random.normal(jax.random.PRNGKey(30), x_shape)
+    built = {rows: _dense(features, kernel_axes, bias_axes, name="p",
+                          use_bias=bias, axis=axis, rows=rows)
+             for rows in (True, False)}
+    boxed = {rows: m.init(jax.random.PRNGKey(1), x)
+             for rows, m in built.items()}
+    assert nn.get_partition_spec(boxed[True]) \
+        == nn.get_partition_spec(boxed[False])
+    params = {rows: nn.unbox(v) for rows, v in boxed.items()}
+    assert jax.tree.structure(params[True]) == jax.tree.structure(params[False])
+    for a, b in zip(jax.tree.leaves(params[True]),
+                    jax.tree.leaves(params[False])):
+        np.testing.assert_array_equal(a, b)
+    # a bias that is not zero, so that its place shows
+    values = jax.tree.map(lambda p: p + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(2), p.shape), params[True])
+
+    def loss(rows):
+        return lambda v, x: jnp.sin(built[rows].apply(v, x)).sum()
+
+    np.testing.assert_allclose(built[True].apply(values, x),
+                               built[False].apply(values, x), rtol=1e-5,
+                               atol=1e-5)
+    got = jax.grad(loss(True), argnums=(0, 1))(values, x)
+    want = jax.grad(loss(False), argnums=(0, 1))(values, x)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)
+    adds = [eqn for eqn in jax.make_jaxpr(
+        lambda v, x: built[True].apply(v, x))(values, x).eqns
+        if eqn.primitive.name == "add"]
+    assert len(adds) == int(bias)
+    assert all(eqn.outvars[0].aval.ndim == 3 for eqn in adds)
 
 
 def test_gpt2_hint_is_the_all_attention_formula():

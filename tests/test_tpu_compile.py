@@ -264,39 +264,12 @@ def test_the_benchmarks_reader_still_tells_the_kernels_by_their_results(
                 "dkv": "flash_bwd_dkv"}[call["kind"]] in call["name"]
 
 
-@pytest.fixture(scope="module")
-def two_layer_stack(v5e_2x2):
-    """The instructions under the ``attention`` scope of GPT-2 medium's
-    stack cut to two layers (1024 wide, 16 heads of 64, remat ``dots``,
-    bf16, a 1,024-row head), loss and gradients, compiled for one described
-    chip: ``[(pass, opcode, result type, path inside attention)]`` of every
-    top-level instruction."""
+def _attention_instructions(text: str) -> list:
+    """``[(pass, opcode, result type, path inside attention)]`` of every
+    top-level instruction under the ``attention`` scope of a compiled
+    program."""
     import re
 
-    import flax.linen as nn
-
-    from easydl_tpu.models.lm import lm_bundle
-    from easydl_tpu.models.transformer import TransformerConfig
-
-    frames = jax.config.jax_traceback_in_locations_limit
-    jax.config.update("jax_traceback_in_locations_limit", 1)
-    try:
-        one = SingleDeviceSharding(v5e_2x2[0])
-        bundle = lm_bundle(TransformerConfig(
-            vocab=1024, d_model=1024, n_heads=16, n_layers=2, d_ff=4096,
-            remat=True, remat_policy="dots", attention_impl="flash",
-            dtype="bfloat16"), "gpt2-medium-two-layers")
-        params = jax.tree.map(
-            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
-            jax.eval_shape(lambda: nn.unbox(
-                bundle.init_fn(jax.random.PRNGKey(0)))))
-        tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one)
-        text = jax.jit(jax.grad(
-            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
-        )).lower(params, {"inputs": tokens, "targets": tokens}
-                 ).compile().as_text()
-    finally:
-        jax.config.update("jax_traceback_in_locations_limit", frames)
     found, fused = [], False
     for line in text.splitlines():
         header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
@@ -314,6 +287,78 @@ def two_layer_stack(v5e_2x2):
     return found
 
 
+def _two_layer_gpt2(devices, remat_policy: str, spec: MeshSpec = MeshSpec()):
+    """Compiled text of GPT-2 medium's stack cut to two layers (1024 wide,
+    16 heads of 64, bf16, a 1,024-row head) under ``remat_policy``: loss and
+    gradients of 8 sequences of 1,024 on one described chip, or under
+    ``spec`` the whole train step of 16 (the ``Trainer`` places the
+    parameters), one frame per location as the entry points compile."""
+    import flax.linen as nn
+    import optax
+
+    from easydl_tpu.core.train_loop import TrainConfig, Trainer
+    from easydl_tpu.models.lm import lm_bundle
+    from easydl_tpu.models.transformer import TransformerConfig
+
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    try:
+        bundle = lm_bundle(TransformerConfig(
+            vocab=1024, d_model=1024, n_heads=16, n_layers=2, d_ff=4096,
+            remat=True, remat_policy=remat_policy, attention_impl="flash",
+            dtype="bfloat16"), "gpt2-medium-two-layers")
+        if spec.size > 1:
+            trainer = Trainer(
+                init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+                optimizer=optax.sgd(1e-3),
+                config=TrainConfig(global_batch=16, grad_accum=1),
+                mesh=build_mesh(spec, devices=devices[:spec.size]))
+            tokens = jax.ShapeDtypeStruct((16, 1024), jnp.int32)
+            return trainer.step_fn.lower(
+                trainer.abstract_state(),
+                {"inputs": tokens, "targets": tokens}).compile().as_text()
+        one = SingleDeviceSharding(devices[0])
+        params = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+            jax.eval_shape(lambda: nn.unbox(
+                bundle.init_fn(jax.random.PRNGKey(0)))))
+        tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one)
+        return jax.jit(jax.grad(
+            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+        )).lower(params, {"inputs": tokens, "targets": tokens}
+                 ).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+
+
+@pytest.fixture(scope="module")
+def two_layer_texts(v5e_2x2):
+    """``text(stack)``: compiled text of the two-layer GPT-2 stack
+    (``_two_layer_gpt2``) under remat ``dots``, under ``full``, or under
+    ``dots`` with ``fsdp=4`` on the described 2x2 (the kernels per shard,
+    inside a ``shard_map``); each compiled once, when first asked for."""
+    import functools
+
+    stacks = {"dots": ("dots", MeshSpec()), "full": ("full", MeshSpec()),
+              "dots-fsdp4": ("dots", MeshSpec(fsdp=4))}
+
+    @functools.lru_cache(maxsize=None)
+    def text(stack):
+        return _two_layer_gpt2(v5e_2x2, *stacks[stack])
+
+    return text
+
+
+@pytest.fixture(scope="module")
+def two_layer_stack(two_layer_texts):
+    """The instructions under the ``attention`` scope of GPT-2 medium's
+    stack cut to two layers (1024 wide, 16 heads of 64, remat ``dots``,
+    bf16, a 1,024-row head), loss and gradients, compiled for one described
+    chip: ``[(pass, opcode, result type, path inside attention)]`` of every
+    top-level instruction."""
+    return _attention_instructions(two_layer_texts("dots"))
+
+
 def _big(result: str) -> bool:
     """Whether a result type holds an array as large as q: 8 x 1024 x 1024."""
     import math
@@ -323,12 +368,43 @@ def _big(result: str) -> bool:
                for _, dims in re.findall(r"(\w+)\[([\d,]*)\]", result))
 
 
-def test_kernels_in_the_stack_take_and_give_the_models_layout(two_layer_stack):
-    calls = [(which, result) for which, opcode, result, path in two_layer_stack
-             if opcode == "custom-call"]
+@pytest.mark.parametrize("stack,rows", [("dots", 8), ("full", 8),
+                                        ("dots-fsdp4", 4)])
+def test_kernels_in_the_stack_take_and_give_the_models_layout(
+        two_layer_texts, stack, rows):
+    """The Mosaic calls of the whole compiled text (the layers are a scan:
+    one instruction a layer), on ``[batch, seq, heads·head_dim]`` rows: the
+    forward, its second run under ``rematted_computation`` (remat ``dots``
+    names the forward's ``out`` and does not keep it: ``ops/remat.py``), dq
+    and dkv."""
+    calls = [(which, result)
+             for which, opcode, result, path in _attention_instructions(
+                 two_layer_texts(stack)) if opcode == "custom-call"]
     assert sorted(which for which, _ in calls) == ["bwd", "bwd", "fwd", "remat"]
     for _, result in calls:
-        assert "bf16[8,1024,1024]{2,1,0" in result, result
+        assert f"bf16[{rows},1024,1024]{{2,1,0" in result, result
+
+
+@pytest.mark.parametrize("stack,rows,kept", [
+    ("dots", 8, True), ("full", 8, False), ("dots-fsdp4", 4, True)])
+def test_dots_keeps_lse_as_rows_and_adds_no_bias_of_q_k_v_twice(
+        two_layer_texts, stack, rows, kept):
+    """What remat ``dots`` keeps by name, read in the compiled text. The
+    layers' stack of ``lse`` as dense rows, ``f32[2, batch, 16, 1024]`` (named
+    inside the differentiation rule, through the ``shard_map`` too; no
+    ``[.., 1024, 1]`` column is stacked: 64 MB of lane padding a layer). And
+    a projection's result AFTER its bias: nothing named after q, k or v's
+    product or ``add`` stands under ``rematted_computation`` (kept before
+    it, three fusions a layer added the biases again and wrote q, k, v a
+    second time). Under ``full`` nothing is kept: the projections are
+    recomputed, bias and all."""
+    text = two_layer_texts(stack)
+    assert (f"f32[2,{rows},16,1024]" in text) == kept
+    assert f"f32[2,{rows},16,1024,1]" not in text
+    again = [path for which, _, _, path in _attention_instructions(text)
+             if which == "remat" and path.split("/")[0] in ("q", "k", "v")
+             and path.endswith(("/add", "/dot_general"))]
+    assert bool(again) == (not kept), again
 
 
 @pytest.mark.parametrize("which", ["fwd", "remat", "bwd"])
@@ -352,14 +428,38 @@ def test_no_copy_between_the_projections_and_the_kernels(two_layer_stack, which)
     assert len(moved) <= 6, moved
 
 
+@pytest.mark.parametrize("program", ["medium_4x8", "worker_4x8"])
+def test_the_medium_steps_fit_the_chip(v5e_2x2, program):
+    """The memory gate on what remat ``dots`` keeps: the step of
+    ``gpt2-medium.steady`` (4 x 8 x 1,024, AdamW) and the elastic worker's
+    (``optax.adam``), compiled whole for the described chip, take 15.292 GiB
+    of its 15.75 (the parent's 15.296; with the flash forward's ``out`` kept
+    too 15.668) and at most 15.35, and hold the forward kernel twice in each
+    copy of the step's body. The other cells' steps (XL's shard, the
+    hybrid's, Ouro's: 13-35 s each) are programs of the same script with
+    limits of their own: ``scripts/rehearse_tpu_compile.py``."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "rehearse_tpu_compile.py")
+    spec = importlib.util.spec_from_file_location("rehearse_tpu_compile", path)
+    rehearse = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(rehearse)
+    compiled, gib = rehearse.compile_program(program, v5e_2x2)
+    assert gib <= rehearse.PROGRAMS[program][-1] == 15.35, gib
+    calls = rehearse.mosaic_calls(compiled.as_text())
+    # the step's body stands twice (the first microbatch, then the scan)
+    assert sorted(name.strip("%").split(".")[0] for name, _ in calls) \
+        == sorted(["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
+                   "flash_fwd"] * 2), calls
+
+
 @pytest.fixture(scope="module")
 def two_layer_rotary_stack(v5e_2x2):
     """As ``two_layer_stack``, for Ouro's description cut to two layers and
     two passes (2048 wide, 16 heads of 128, rotary, sandwich norms, remat
     ``full``, bf16, one 4,096-token sequence, a 1,024-row head): every
     top-level instruction under ``attention``."""
-    import re
-
     import flax.linen as nn
 
     from easydl_tpu.models.ouro import make_ouro
@@ -383,21 +483,7 @@ def two_layer_rotary_stack(v5e_2x2):
                  ).compile().as_text()
     finally:
         jax.config.update("jax_traceback_in_locations_limit", frames)
-    found, fused = [], False
-    for line in text.splitlines():
-        header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
-        if header:  # a fusion's body is not a device operation of its own
-            fused = "fused" in header.group(1)
-            continue
-        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(?[^=]*?\)?) ([\w\-]+)\(", line)
-        if fused or not m or "/attention/" not in line:
-            continue
-        path = line.split('op_name="', 1)[1].split('"', 1)[0]
-        which = ("remat" if "rematted_computation" in path
-                 else "bwd" if "transpose(jvp(" in path else "fwd")
-        found.append((which, m.group(2), m.group(1),
-                      path.split("/attention/", 1)[1]))
-    return found
+    return _attention_instructions(text)
 
 
 @pytest.mark.parametrize("which", ["fwd", "remat"])
