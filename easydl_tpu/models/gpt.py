@@ -36,10 +36,6 @@ def make_gpt(
     attention_fn=None,
     dropout: float = 0.0,
     dtype: str = "float32",
-    moe_experts: int = 0,
-    moe_k: int = 2,
-    moe_aux_weight: float = 0.01,
-    moe_capacity_factor: float = 1.25,
     pipeline_fn=None,
     pipeline_stages: int = 0,
 ) -> ModelBundle:
@@ -59,19 +55,7 @@ def make_gpt(
         attention_fn=attention_fn,
         dtype=dtype,
         tied_head=True,
-        moe_experts=moe_experts,
-        moe_k=moe_k,
-        moe_capacity_factor=moe_capacity_factor,
         pipeline_fn=pipeline_fn,
         pipeline_stages=pipeline_stages,
     )
-    return lm_bundle(
-        cfg, f"gpt-{size}" + (f"-moe{moe_experts}" if moe_experts else ""),
-        moe_aux_weight=moe_aux_weight)
-
-
-@register_model("gpt_moe")
-def make_gpt_moe(**kwargs) -> ModelBundle:
-    """GPT with mixture-of-experts FFNs (experts shard over ``ep``)."""
-    kwargs.setdefault("moe_experts", 8)
-    return make_gpt(**kwargs)
+    return lm_bundle(cfg, f"gpt-{size}")

@@ -17,6 +17,7 @@ from easydl_tpu.core.data import SyntheticTokens
 from easydl_tpu.models.registry import ModelBundle
 from easydl_tpu.models.transformer import Transformer, TransformerConfig
 from easydl_tpu.ops.fused_xent import fused_softmax_xent, local_batch
+from easydl_tpu.ops.moe import COUNTERS
 from easydl_tpu.utils.logging import get_logger, log_once
 
 
@@ -143,15 +144,17 @@ def looplm_objective(states: jax.Array, gate_logits: jax.Array,
 
 
 def lm_bundle(cfg: TransformerConfig, name: str, *,
-              moe_aux_weight: float = 0.01,
               exit_entropy_weight: float = 0.0) -> ModelBundle:
     """The causal-LM bundle of one description of the stack: init, loss
     (full logits, or the fused chunked head where
     :func:`fused_head_by_shape` says so; a gated stack's is
     :func:`looplm_objective` with ``beta = exit_entropy_weight``), eval,
-    data and the hints."""
+    data and the hints. Where the description has ``moe`` layers the loss's
+    metrics carry their counters (``ops/moe.py COUNTERS``): ``moe_dropped``
+    summed over the layers, the others their mean."""
     model = Transformer(cfg)
-    seq_len, vocab, n_layers = cfg.max_seq, cfg.vocab, cfg.n_layers
+    seq_len, vocab = cfg.max_seq, cfg.vocab
+    n_sparse = sum(1 for _, ffn in cfg.pattern if ffn == "moe")
 
     def head_of(params, dtype):
         """The head as ``[V, D]`` in the compute dtype — exactly what
@@ -190,7 +193,7 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
         if fused_head_by_shape(*batch["inputs"].shape, vocab):
             out = model.apply(
                 {"params": params}, batch["inputs"], return_hidden=True,
-                **({"mutable": ["intermediates"]} if mutable else {}),
+                **({"mutable": ["counters"]} if mutable else {}),
             )
             hidden = out[0] if mutable else out
             mut = out[1] if mutable else None
@@ -206,7 +209,7 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
                           f"{[*batch['inputs'].shape, vocab]} in float32")
             out = model.apply(
                 {"params": params}, batch["inputs"],
-                **({"mutable": ["intermediates"]} if mutable else {}),
+                **({"mutable": ["counters"]} if mutable else {}),
             )
             logits = out[0] if mutable else out
             mut = out[1] if mutable else None
@@ -217,21 +220,17 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     def loss_fn(params, batch, rng):
         if cfg.exit_gate:
             return gated_loss(params, batch)
-        if cfg.moe_experts:
+        if n_sparse:
             loss, mut = _lm_loss_from(params, batch, mutable=True)
-            aux = jnp.sum(
-                jnp.asarray(mut["intermediates"]["moe_aux_loss"][0])
-            )
-            return loss + moe_aux_weight * aux, {
-                "perplexity": jnp.exp(loss),
-                "moe_balance": aux / max(n_layers, 1),
-            }
+            summed = mut["counters"]["moe"][0]
+            counters = {name: summed[i] / (1 if name == "moe_dropped"
+                                           else n_sparse)
+                        for i, name in enumerate(COUNTERS)}
+            return loss, {"perplexity": jnp.exp(loss), **counters}
         loss, _ = _lm_loss_from(params, batch)
         return loss, {"perplexity": jnp.exp(loss)}
 
     def eval_fn(params, batch, rng):
-        # Pure LM loss — no balance regularizer, so eval is comparable
-        # across dense/MoE configs and aux weights.
         if cfg.exit_gate:
             return gated_loss(params, batch)
         loss, _ = _lm_loss_from(params, batch)

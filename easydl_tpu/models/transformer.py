@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from easydl_tpu.ops import multihead_attention, remat
+from easydl_tpu.ops.moe import COUNTERS, MoeMlp
 from easydl_tpu.ops.rope import apply_rope, rope_tables
 from easydl_tpu.ops.ssd import (causal_conv1d, gated_rmsnorm,
                                 ssd_flops_per_token, ssd_scan)
@@ -166,10 +167,52 @@ def _norm(cfg, name, dtype=None):
     )
 
 
-#: one layer of the description: (mixer, ffn)
+#: one layer of the description: (mixer, ffn). A mixer is ``attention``
+#: (the description's own heads, no window, its ``position``), ``mamba2``, or
+#: the name of one of the description's ``attention_kinds``.
 Layer = Tuple[str, str]
 MIXERS = ("attention", "mamba2")
-FFNS = ("gelu", "swiglu")
+FFNS = ("gelu", "swiglu", "moe")
+
+
+@dataclass(frozen=True)
+class RopeScheme:
+    """One rotary scheme (``ops/rope.py rope_tables``): ``rotary_dim``
+    leading dimensions of a head are rotated (0: all), with YaRN's blended
+    frequencies and attention factor where ``yarn`` holds its numbers
+    (pairs, so that the description stays hashable)."""
+
+    theta: float = 10000.0
+    rotary_dim: int = 0
+    yarn: Optional[Tuple[Tuple[str, float], ...]] = None
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """An attention layer's own numbers, where a stack has more than one
+    kind: query heads (0: the description's), a causal window in keys (0:
+    none), a rotary scheme (None: the description's ``position``), and a
+    per-head sigmoid gate on the attention output."""
+
+    n_heads: int = 0
+    window: int = 0
+    rope: Optional[RopeScheme] = None
+    gate: bool = False
+
+
+@dataclass(frozen=True)
+class MoeConfig:
+    """Widths of the ``moe`` FFNs (``ops/moe.py``): the router's width
+    ``experts_total``, the contiguous range of routed experts held here,
+    experts a token, an expert's and the shared expert's inner widths, and
+    the scale on the renormalised router weights."""
+
+    experts_total: int
+    experts_held: Tuple[int, int]
+    k: int
+    d_ff: int
+    shared_d_ff: int = 0
+    scaling: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -221,12 +264,6 @@ class TransformerConfig:
     #: the stage split is purely a ``layers → pp`` sharding rule.
     pipeline_fn: Optional[Callable] = None
     pipeline_stages: int = 0
-    #: mixture-of-experts: replace each block's FFN with ``moe_experts``
-    #: expert FFNs routed top-``moe_k`` (0 = dense). Experts shard over the
-    #: mesh's ``ep`` axis (easydl_tpu/ops/moe.py).
-    moe_experts: int = 0
-    moe_k: int = 2
-    moe_capacity_factor: float = 1.25
     # ---- the description. The defaults below are GPT-2's (and BERT's).
     #: per layer (mixer, ffn); None = ``n_layers`` x ("attention", "gelu").
     #: When given, its length is the depth and ``n_layers`` must agree.
@@ -259,17 +296,26 @@ class TransformerConfig:
     logits_scaling: float = 1.0
     #: widths of the ``mamba2`` mixers, where the description has any
     ssm: Optional[SsmConfig] = None
+    #: a head's size where it is not ``d_model // n_heads`` (0: it is)
+    head_size: int = 0
+    #: named attention kinds a layer's mixer may be, ``(name, kind)`` pairs
+    attention_kinds: Tuple[Tuple[str, AttentionKind], ...] = ()
+    #: widths of the ``moe`` FFNs, where the description has any
+    moe: Optional[MoeConfig] = None
 
     def __post_init__(self):
         if self.layers is not None and len(self.layers) != self.n_layers:
             raise ValueError(f"{len(self.layers)} layers described, "
                              f"n_layers={self.n_layers}")
+        kinds = dict(self.attention_kinds)
         for mixer, ffn in self.pattern:
-            if mixer not in MIXERS or ffn not in FFNS:
+            if mixer not in MIXERS + tuple(kinds) or ffn not in FFNS:
                 raise ValueError(f"unknown layer kind {(mixer, ffn)}; mixers "
-                                 f"{MIXERS}, FFNs {FFNS}")
+                                 f"{MIXERS + tuple(kinds)}, FFNs {FFNS}")
             if mixer == "mamba2" and self.ssm is None:
                 raise ValueError("a mamba2 layer needs ssm=SsmConfig(...)")
+            if ffn == "moe" and self.moe is None:
+                raise ValueError("a moe layer needs moe=MoeConfig(...)")
         if self.position not in ("learned", "none", "rope"):
             raise ValueError(f"position must be 'learned', 'none' or 'rope', "
                              f"got {self.position!r}")
@@ -280,16 +326,32 @@ class TransformerConfig:
             raise ValueError(f"loops must be at least 1, got {self.loops}")
         if self.loops > 1 and self.pipeline_fn is not None:
             raise NotImplementedError("a looped stack inside the pipeline")
-        if self.n_heads % self.kv_heads:
-            raise ValueError(f"{self.n_heads} heads do not divide into "
-                             f"{self.kv_heads} key/value heads")
+        for heads in {self.n_heads} | {
+                kind.n_heads or self.n_heads for kind in kinds.values()}:
+            if heads % self.kv_heads:
+                raise ValueError(f"{heads} heads do not divide into "
+                                 f"{self.kv_heads} key/value heads")
+        if self.has_moe and (self.loops > 1 or self.pipeline_fn is not None):
+            raise NotImplementedError(
+                "moe layers in a looped stack or inside the pipeline")
 
     @property
     def head_dim(self) -> int:
+        if self.head_size:
+            return self.head_size
         if self.d_model % self.n_heads:
             raise ValueError(f"d_model={self.d_model} does not divide into "
                              f"n_heads={self.n_heads} heads")
         return self.d_model // self.n_heads
+
+    def attention_kind(self, mixer: str) -> AttentionKind:
+        """The numbers of an attention mixer; plain ``attention`` is the
+        kind with none of its own."""
+        return dict(self.attention_kinds).get(mixer, AttentionKind())
+
+    @property
+    def has_moe(self) -> bool:
+        return any(ffn == "moe" for _, ffn in self.pattern)
 
     @property
     def kv_heads(self) -> int:
@@ -310,14 +372,21 @@ class TransformerConfig:
                 out.append([layer, 1])
         return tuple((layer, n) for layer, n in out)
 
-    def layer_params(self, layer: Layer) -> int:
+    def layer_params(self, layer: Layer, active: bool = False) -> int:
         """Parameters of one layer. Exact for the bias-free kinds; a layer
         with biases counts ``4 * d_model`` for its biases and norms, as this
-        estimate always has."""
+        estimate always has. ``active``: of a ``moe`` layer's routed
+        experts only what a token meets on average, ``k * held / total`` of
+        them (all ``k`` where every expert is held) — what the matrix
+        products of a step are counted from."""
         mixer, ffn = layer
         d = self.d_model
-        if mixer == "attention":
-            n = 2 * d * d + 2 * d * self.kv_heads * self.head_dim
+        if mixer != "mamba2":
+            kind = self.attention_kind(mixer)
+            inner = (kind.n_heads or self.n_heads) * self.head_dim
+            n = 2 * d * inner + 2 * d * self.kv_heads * self.head_dim
+            if kind.gate:
+                n += d * (kind.n_heads or self.n_heads)
         else:
             m = self.ssm
             inner, bc = m.n_heads * m.head_dim, m.n_groups * m.d_state
@@ -326,8 +395,12 @@ class TransformerConfig:
                  + 3 * m.n_heads + inner + inner * d)      # dt_bias A D norm out
         if ffn == "swiglu":
             n += 3 * d * self.d_ff
-        elif self.moe_experts:
-            n += self.moe_experts * 2 * d * self.d_ff + d * self.moe_experts
+        elif ffn == "moe":
+            m = self.moe
+            held = m.experts_held[1] - m.experts_held[0]
+            routed = m.k * held / m.experts_total if active else held
+            n += (d * m.experts_total + 3 * d * m.shared_d_ff
+                  + round(routed * 3 * d * m.d_ff))
         else:
             n += 2 * d * self.d_ff
         if self.norm_placement == "sandwich":
@@ -356,13 +429,24 @@ class TransformerConfig:
         have no score matrix. An untied embedding is a lookup and counts
         nothing. A looped stack pays its layers, their scores and its head
         once a pass: ``loops`` does not move ``param_count`` and multiplies
-        this."""
-        n_attn = sum(1 for mixer, _ in self.pattern if mixer == "attention")
+        this. Of a ``moe`` layer's routed experts only the ACTIVE ones
+        count (:meth:`layer_params`); an attention layer's scores are
+        ``12 * heads * head_dim`` a key, ``seq`` keys counted in full as the
+        convention has it, or the ``window`` keys a windowed layer's band
+        holds."""
+        n_attn = sum(1 for mixer, _ in self.pattern if mixer != "mamba2")
         head = self.vocab * self.d_model
-        looped = sum(self.layer_params(l) for l in self.pattern) + head
+        held = sum(self.layer_params(l) for l in self.pattern) + head
+        looped = sum(self.layer_params(l, active=True)
+                     for l in self.pattern) + head
         lookup = 0 if self.tied_head else head
-        once = self.param_count - looped - lookup
-        scores = 12.0 * n_attn * self.d_model * seq_len
+        once = self.param_count - held - lookup
+        scores = 0.0
+        for mixer, _ in self.pattern:
+            if mixer != "mamba2":
+                kind = self.attention_kind(mixer)
+                scores += 12.0 * (kind.n_heads or self.n_heads) \
+                    * self.head_dim * min(kind.window or seq_len, seq_len)
         if n_attn < len(self.pattern):
             m = self.ssm
             scores += 3.0 * (len(self.pattern) - n_attn) * ssd_flops_per_token(
@@ -386,11 +470,14 @@ def _projection(block, features, kernel_axes, bias_axes, name,
 
 def _attention(block, h, rope=None):
     cfg = block.cfg
+    kind = cfg.attention_kind(block.mixer)
+    n_heads = kind.n_heads or cfg.n_heads
+    rotary_dim = kind.rope.rotary_dim or None if kind.rope else None
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
     # the four products around the kernels as matrix products on rows: the
     # kernels' layout (the Mamba-2 mixer's same-shaped projections feed no
     # kernel and measured SLOWER that way: PERF.md section 6, PR 28)
-    q = _projection(block, (cfg.n_heads, cfg.head_dim), heads, kv, "q",
+    q = _projection(block, (n_heads, cfg.head_dim), heads, kv, "q",
                     rows=True)(h)
     k = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "k",
                     rows=True)(h)
@@ -400,14 +487,25 @@ def _attention(block, h, rope=None):
     k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
     v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
     if cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
+        if kind.window:
+            raise NotImplementedError(
+                "a windowed attention layer under sequence parallelism")
         if rope is not None:  # q and k are whole here: positions from 0
-            q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+            q, k = (apply_rope(x, *rope, rot=rotary_dim) for x in (q, k))
         attn = cfg.attention_fn(q, k, v, causal=cfg.causal)
     else:
         attn = multihead_attention(
             q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
-            scale=cfg.attention_multiplier, rope=rope,
+            scale=cfg.attention_multiplier, rope=rope, rotary_dim=rotary_dim,
+            window=kind.window or None,
         )
+    if kind.gate:
+        # one sigmoid a head on the layer's normed input, float32
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(_projection(
+                block, n_heads, ("embed", "heads"), ("heads",), "gate_heads"
+            )(h).astype(jnp.float32))
+            attn = (attn * gate[..., None]).astype(attn.dtype)
     return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
                        ("embed",), "out", residual=True, axis=(-2, -1),
                        rows=True)(attn)
@@ -487,16 +585,12 @@ def _ffn(block, h):
                            "gate")(h)
         up = _projection(block, cfg.d_ff, ("embed", "mlp"), ("mlp",), "up")(h)
         h = nn.silu(gate) * up
-    elif cfg.moe_experts:
-        from easydl_tpu.ops.moe import MoeMlp
-
+    elif block.ffn == "moe":
+        m = cfg.moe
         return MoeMlp(
-            num_experts=cfg.moe_experts,
-            d_ff=cfg.d_ff,
-            k=cfg.moe_k,
-            capacity_factor=cfg.moe_capacity_factor,
-            out_init_scale=(2 * cfg.n_layers) ** -0.5,
-            dtype=cfg.dtype,
+            experts_total=m.experts_total, experts_held=m.experts_held,
+            d_ff=m.d_ff, shared_d_ff=m.shared_d_ff, k=m.k, scaling=m.scaling,
+            out_init_scale=(2 * cfg.n_layers) ** -0.5, dtype=cfg.dtype,
             name="moe",
         )(h)
     else:
@@ -546,7 +640,7 @@ class Block(nn.Module):
         # (read by the device trace's reducers); flax's module names sit
         # inside them.
         with remat.tally() as named:
-            if self.mixer == "attention":
+            if self.mixer != "mamba2":
                 with jax.named_scope("attention"):
                     x = residual(x, _attention(
                         self, _norm(cfg, "ln_attn", dtype=dt)(x), rope),
@@ -555,7 +649,9 @@ class Block(nn.Module):
                 with jax.named_scope("ssm"):
                     x = residual(x, _mamba2(
                         self, _norm(cfg, "ln_ssm", dtype=dt)(x)), "ln_ssm")
-            with jax.named_scope("ffn"):
+            # `ffn` is the dense FFN's scope; an expert layer is `moe`, with
+            # the scopes of ops/moe.py inside it
+            with jax.named_scope("moe" if self.ffn == "moe" else "ffn"):
                 h, aux = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x))
                 x = residual(x, h, "ln_mlp")
         if cfg.remat and cfg.remat_policy == "dots" \
@@ -581,8 +677,8 @@ def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic,
     """The one run of the stack through ``cfg.pipeline_fn``'s GPipe
     schedule, on the stacked params the plain path created."""
     cfg = stack.cfg
-    if cfg.moe_experts:
-        raise NotImplementedError("MoE inside the pipeline")
+    if ffn == "moe":
+        raise NotImplementedError("a moe layer inside the pipeline")
     if cfg.dropout and not deterministic:
         # The stage apply below passes no rngs, so a non-
         # deterministic dropout>0 apply would otherwise die with an
@@ -706,8 +802,14 @@ class Transformer(nn.Module):
         if cfg.pipeline_fn is not None and len(runs) > 1:
             raise NotImplementedError(
                 "pipeline_fn over a stack of more than one run of layers")
-        rope = (rope_tables(seq, cfg.head_dim, cfg.rope_theta)
-                if cfg.position == "rope" else None)
+        # one pair of tables a rotary scheme, made once for all its layers
+        ropes = {"attention": rope_tables(seq, cfg.head_dim, cfg.rope_theta)
+                 if cfg.position == "rope" else None}
+        for name, kind in cfg.attention_kinds:
+            ropes[name] = ropes["attention"] if kind.rope is None else \
+                rope_tables(seq, cfg.head_dim, kind.rope.theta,
+                            kind.rope.rotary_dim or None,
+                            dict(kind.rope.yarn) if kind.rope.yarn else None)
 
         def pass_end(stack, x):
             """The final norm, and the exit gate's logit on the normed
@@ -732,8 +834,12 @@ class Transformer(nn.Module):
         def one_pass(stack, x):
             """The runs of layers once and the final norm: ``(x, (x, gate
             logit, aux))``, a scan body over passes."""
-            aux = jnp.zeros((), jnp.float32)
+            # zeros for dense layers; the expert layers' counters summed
+            # (ops/moe.py COUNTERS) where the description has any
+            aux = jnp.zeros((len(COUNTERS),) if cfg.has_moe else (),
+                            jnp.float32)
             for i, ((mixer, ffn), count) in enumerate(runs):
+                rope = ropes.get(mixer)
                 if cfg.pipeline_fn is None or stack.is_initializing():
                     # plain (or init) path: params are created here with
                     # the stacked [n_layers, ...] layout the pipeline also
@@ -747,7 +853,8 @@ class Transformer(nn.Module):
                     x, layer_aux = _pipelined(
                         stack, block_cls, scan_kwargs, mixer, ffn, x,
                         deterministic, rope)
-                aux = aux + jnp.sum(layer_aux)
+                aux = aux + (jnp.sum(layer_aux, 0) if ffn == "moe"
+                             else jnp.sum(layer_aux))
             # Between passes only the normed state is kept (bf16): the
             # final norm's and the gate's float32 intermediates, 4 x [B, S,
             # D] a pass, are recomputed in the backward pass.
@@ -772,10 +879,11 @@ class Transformer(nn.Module):
                 split_rngs={"params": False, "dropout": True},
                 length=cfg.loops)(self, x)
             aux = jnp.sum(aux)
-        # Per-layer MoE load-balance losses (zeros for dense blocks); read
-        # back by MoE loss fns via mutable=["intermediates"] — a no-op sow
-        # for plain apply() calls.
-        self.sow("intermediates", "moe_aux_loss", aux)
+        # The expert layers' counters, summed over the layers; read back by
+        # the loss function via mutable=["counters"] — a no-op sow for plain
+        # apply() calls.
+        if cfg.has_moe:
+            self.sow("counters", "moe", aux)
 
         if return_hidden:
             if cfg.loops == 1 and not cfg.exit_gate:

@@ -64,16 +64,22 @@ def _reference_attention(
     causal: bool,
     scale: float,
     segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """XLA-fused reference path: einsum → mask → softmax → einsum.
 
     fp32 softmax accumulation regardless of input dtype (bf16-safe).
+    ``window`` (causal only) as the kernels have it: query i sees the
+    ``window`` keys up to its own.
     """
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     fully_masked = None
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), jnp.bool_), k=s_k - s_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((s_q, s_k), jnp.bool_),
+                              k=s_k - s_q - window)
         logits = jnp.where(mask[None, None], logits, jnp.finfo(jnp.float32).min)
         # Bottom-right alignment with s_q > s_k leaves the first s_q - s_k
         # rows with no visible keys; the flash kernel outputs zeros for such
@@ -157,6 +163,8 @@ def multihead_attention(
     impl: str = "auto",
     segment_ids: Optional[jax.Array] = None,
     rope: Optional[tuple] = None,
+    rotary_dim: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Attention over [batch, seq, heads, head_dim] tensors.
 
@@ -166,9 +174,17 @@ def multihead_attention(
         rotated by position first — beside the flash kernels by the Pallas
         kernel on their own view where a head is whole lane tiles
         (``rope_rows``), else in ``jax.numpy``.
+      rotary_dim: the leading dimensions of a head the tables rotate (None:
+        all of them).
+      window: with ``causal``, query i sees only the ``window`` keys up to
+        its own; both paths take the same window.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if window is not None and not causal:
+        raise ValueError("attention: a window needs causal=True")
+    banded = "" if window is None else f", window {window}"
+    rotary_dim = rotary_dim or q.shape[-1]
     if impl == "auto":
         platform = jax.devices()[0].platform
         impl = "flash" if platform == "tpu" else "reference"
@@ -176,12 +192,14 @@ def multihead_attention(
             log_once(
                 log,
                 f"attention: XLA reference path (impl=auto on platform "
-                f"{platform!r}, the Pallas flash kernel needs a tpu)")
+                f"{platform!r}, the Pallas flash kernel needs a tpu"
+                f"{banded})")
     if impl == "flash":
         why = None
         if segment_ids is not None:
             why = "segment mask requested"
-        elif choose_blocks(q.shape[1], k.shape[1], causal) is None:
+        elif choose_blocks(q.shape[1], k.shape[1], causal,
+                           window=window) is None:
             # the wrap below never splits the sequence: asked once, here
             why = (f"lengths q={q.shape[1]} k={k.shape[1]} have no block "
                    f"divisor <= {MAX_BLOCK}/{MAX_BLOCK}")
@@ -191,30 +209,31 @@ def multihead_attention(
             # is whole lane tiles; else here, in jax.numpy
             tables = rope if rope is not None and tiles_lanes(head_dim) else ()
             if rope is not None and not tables:
-                q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+                q, k = (apply_rope(x, *rope, rot=rotary_dim) for x in (q, k))
 
             def flat(x):
                 return x.reshape(*x.shape[:2], -1)
 
             def kernel(q, k, v, *tables):
                 if tables:
-                    q = rope_rows(q, *tables, head_dim=head_dim)
-                    k = rope_rows(k, *tables, head_dim=head_dim)
+                    q, k = (rope_rows(x, *tables, head_dim=head_dim,
+                                      rot=rotary_dim) for x in (q, k))
                 q, k, v = (x.reshape(*x.shape[:2], -1, head_dim)
                            for x in (q, k, v))
                 return flat(flash_attention(q, *_repeat_kv(q, k, v),
-                                            causal=causal, scale=scale))
+                                            causal=causal, scale=scale,
+                                            window=window))
 
             return _per_shard(kernel, q, k, len(tables))(
                 flat(q), flat(k), flat(v), *tables).reshape(q.shape)
         # the reference path partitions under GSPMD: no per-shard wrap
         log_once(log, f"flash attention: XLA reference path, not the "
-                      f"kernel: {why}")
+                      f"kernel: {why}{banded}")
     elif impl != "reference":
         raise ValueError(f"unknown attention impl {impl!r}")
     if rope is not None:
-        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+        q, k = (apply_rope(x, *rope, rot=rotary_dim) for x in (q, k))
     return _reference_attention(
         q, *_repeat_kv(q, k, v), causal=causal, scale=scale,
-        segment_ids=segment_ids
+        segment_ids=segment_ids, window=window
     )

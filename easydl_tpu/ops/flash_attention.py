@@ -144,7 +144,8 @@ def _pick_block(s: int, target: int) -> Optional[int]:
 
 def choose_blocks(s_q: int, s_k: int, causal: bool,
                   block_q: Optional[int] = None,
-                  block_k: Optional[int] = None) -> Optional[Blocks]:
+                  block_k: Optional[int] = None,
+                  window: Optional[int] = None) -> Optional[Blocks]:
     """Block sizes per kernel from what the call can see; a caller's
     ``block_q`` / ``block_k`` hold for all three. None when a length has no
     block divisor: the kernels cannot tile it, and whoever chooses the
@@ -155,11 +156,25 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     fastest for the forward and dq; dk/dv, with four products a pair, gains
     more from wasting less of the causal triangle (5/8 of the matrix computed
     at 256², 3/4 at 512²) than it loses to more pairs — as long as the pairs
-    still unroll. Without a triangle the smaller blocks have nothing to win."""
-    def pick(target):
-        return (_pick_block(s_q, block_q or target),
-                _pick_block(s_k, block_k or target))
+    still unroll. Without a triangle the smaller blocks have nothing to win.
 
+    Under a ``window`` of at most a block's keys (a causal band: query i
+    sees the ``window`` keys up to its own) a Q-block meets only the two or
+    three K-blocks the band touches. Swept on a v5e at ``[2, 8192, 64 x
+    128]``, window 512 (PERF.md section 6, PR 31): the forward and dq are
+    fastest at 512 x 512 like the plain kernels (7.6 ms a call against 10.8
+    at 256 x 256: fewer, fuller block pairs win over the band's masked
+    corners); dk/dv takes K-blocks of 256 (512 x 512 does not fit its VMEM
+    beside the whole sequence's q, O and dO, and 512 x 256 beats 256 x 256
+    by 1.5 ms)."""
+    def pick(target, target_k=None):
+        return (_pick_block(s_q, block_q or target),
+                _pick_block(s_k, block_k or target_k or target))
+
+    if window is not None and window <= MAX_BLOCK:
+        big, banded = pick(MAX_BLOCK), pick(MAX_BLOCK, MAX_BLOCK // 2)
+        if None not in big + banded:
+            return big, big, banded
     big, small = pick(MAX_BLOCK), pick(MAX_BLOCK // 2)
     if None in big:
         return None
@@ -189,6 +204,16 @@ def _clip(x, lo: int, hi: int):
     return jnp.clip(x, lo, hi)
 
 
+def _least(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
 def _loop(lo, hi, body, carry, *, unroll: bool):
     """``fori_loop``, or the same iterations as straight-line code when the
     bounds are Python numbers and the caller wants them unrolled."""
@@ -203,32 +228,50 @@ def _block_start(i, block: int):
     return i * block if isinstance(i, int) else pl.multiple_of(i * block, block)
 
 
-def _scores_t(k, q, s_scale: float, bound):
+def _scores_t(k, q, s_scale: float, bound, window: Optional[int] = None):
     """``Sᵀ = K Qᵀ`` of one block pair, float32 ``[block_k, block_q]``;
     causally masked when ``bound`` (= q_start + offset − k_start) is given:
-    key j is seen by query i iff j − i <= bound."""
+    key j is seen by query i iff j − i <= bound, and under a ``window`` iff
+    also j − i > bound − window (the ``window`` keys up to the query's
+    own)."""
     st = _dot(k, q, _NT)
     if s_scale != 1.0:
         st = st * s_scale
     if bound is not None:
         keys = jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
         queries = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        st = jnp.where(keys - queries <= bound, st, NEG_INF)
+        seen = keys - queries <= bound
+        if window is not None:
+            seen = seen & (keys - queries > bound - window)
+        st = jnp.where(seen, st, NEG_INF)
     return st
 
 
 def _over_k_blocks(body, carry, q_start, *, block_q: int, block_k: int, n_k: int,
-                   offset: int, causal: bool, unroll: bool):
+                   offset: int, causal: bool, unroll: bool,
+                   window: Optional[int] = None):
     """``body(kb, carry, masked=)`` over the K-blocks the Q-block at
     ``q_start`` sees: [0, n_full) lie wholly below the diagonal (every row
     sees every column) and take no mask, [n_full, n_live) are crossed by it,
-    the rest are seen by no row."""
+    the rest are seen by no row. Under a ``window`` the blocks before
+    ``first`` lie wholly outside the band and are not visited, those before
+    ``inside`` are crossed by its far edge and masked."""
     if not causal:
         return _loop(0, n_k, functools.partial(body, masked=False), carry, unroll=unroll)
     n_full = _clip((q_start + offset + 1) // block_k, 0, n_k)
     n_live = _clip((q_start + block_q + offset + block_k - 1) // block_k, 0, n_k)
-    carry = _loop(0, n_full, functools.partial(body, masked=False), carry, unroll=unroll)
-    return _loop(n_full, n_live, functools.partial(body, masked=True), carry, unroll=unroll)
+    if window is None:
+        carry = _loop(0, n_full, functools.partial(body, masked=False), carry, unroll=unroll)
+        return _loop(n_full, n_live, functools.partial(body, masked=True), carry, unroll=unroll)
+    # the band's far edge: row r sees keys > r + offset - window
+    first = _clip((q_start + offset - window + 1) // block_k, 0, n_k)
+    inside = _clip((q_start + block_q + offset - window + block_k - 1) // block_k,
+                   0, n_k)
+    masked = functools.partial(body, masked=True)
+    carry = _loop(first, _least(inside, n_live), masked, carry, unroll=unroll)
+    carry = _loop(inside, n_full, functools.partial(body, masked=False), carry,
+                  unroll=unroll)
+    return _loop(_most(n_full, inside), n_live, masked, carry, unroll=unroll)
 
 
 def _unrolled(n_q: int, n_k: int) -> bool:
@@ -303,7 +346,7 @@ def _head_cols(lanes: int, d: int):
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref,
     *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
-    offset: int, unroll: bool,
+    offset: int, unroll: bool, window: Optional[int],
 ):
     # q_ref, o_ref: [cell rows, cell heads · d]; k_ref, v_ref: [S_k, cell
     # heads · d]; lse_ref: [cell heads, cell rows, 1]
@@ -328,7 +371,8 @@ def _fwd_kernel(
                 k = k_ref[pl.ds(k_start, block_k), cols]
                 vt = vt_all[cols]  # [d, block_k]: the head's sublanes
                 st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start if masked else None)
+                               q_start + offset - k_start if masked else None,
+                               window)
                 m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
                 pt = jnp.exp(st - m_new)
                 correction = jnp.exp(m - m_new)
@@ -344,7 +388,7 @@ def _fwd_kernel(
         ) for _ in heads)
         carry = _over_k_blocks(
             body, carry, q_start, block_q=block_q, block_k=block_k, n_k=n_k,
-            offset=offset, causal=causal, unroll=unroll)
+            offset=offset, causal=causal, unroll=unroll, window=window)
         o_ts = []
         for g, (m, l, acc) in enumerate(carry):
             # Rows that saw no unmasked key (bottom-right-aligned causal with
@@ -366,7 +410,7 @@ def _fwd_kernel(
 
 
 def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
-         block_k: int, interpret: bool):
+         block_k: int, interpret: bool, window: Optional[int] = None):
     # q, k, v: [B, S, H·d]
     b, s_q, width = q.shape
     s_k, d = k.shape[1], width // heads
@@ -379,7 +423,7 @@ def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
                        2 * (s_q + s_k) * d * q.dtype.itemsize + s_q * 128 * 4)
     kernel = functools.partial(
         _fwd_kernel, head_dim=d, block_q=block_q, block_k=block_k, causal=causal,
-        scale=scale, offset=s_k - s_q, unroll=unroll,
+        scale=scale, offset=s_k - s_q, unroll=unroll, window=window,
     )
     mine = pl.BlockSpec((None, cell_rows, cell * d), lambda b, h, qi: (b, qi, h))
     whole = pl.BlockSpec((None, s_k, cell * d), lambda b, h, qi: (b, 0, h))
@@ -396,7 +440,7 @@ def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
             jax.ShapeDtypeStruct((b, heads, s_q, 1), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "swa_fwd",
     )(q, k, v)
     return out, lse
 
@@ -409,7 +453,7 @@ def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
     *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
-    offset: int, unroll: bool,
+    offset: int, unroll: bool, window: Optional[int],
 ):
     # lse_ref: [cell heads, the cell's Q-blocks, 1, block_q]
     cell_rows, lanes = q_ref.shape
@@ -434,7 +478,8 @@ def _bwd_dq_kernel(
                 k = k_ref[pl.ds(k_start, block_k), cols]
                 v = v_ref[pl.ds(k_start, block_k), cols]
                 st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start if masked else None)
+                               q_start + offset - k_start if masked else None,
+                               window)
                 pt = jnp.exp(st - lse)
                 dst = pt * (_dot(v, do, _NT) - delta)
                 out.append(dq_t + _dot(kt_all[cols], dst.astype(k.dtype), _NN))
@@ -443,7 +488,7 @@ def _bwd_dq_kernel(
         dq_ts = _over_k_blocks(
             body, tuple(jnp.zeros((d, block_q), jnp.float32) for _ in heads),
             q_start, block_q=block_q, block_k=block_k, n_k=n_k, offset=offset,
-            causal=causal, unroll=unroll)
+            causal=causal, unroll=unroll, window=window)
         dq_ref[rows, :] = (_side_by_side(list(dq_ts), 0) * scale
                            ).T.astype(dq_ref.dtype)
 
@@ -451,7 +496,7 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
     *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
-    offset: int, unroll: bool,
+    offset: int, unroll: bool, window: Optional[int],
 ):
     # lse_ref: [cell heads, n_q, 1, block_q]
     cell_rows, lanes = dk_ref.shape
@@ -481,7 +526,8 @@ def _bwd_dkv_kernel(
                 q = q_ref[q_rows, cols]
                 do = do_ref[q_rows, cols]
                 st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start if masked else None)
+                               q_start + offset - k_start if masked else None,
+                               window)
                 pt = jnp.exp(st - lse)
                 dv_new = dv + _dot(pt.astype(do.dtype), do, _NN)
                 dst = pt * (_dot(v, do, _NT) - delta)
@@ -492,18 +538,31 @@ def _bwd_dkv_kernel(
             jnp.zeros((block_k, d), jnp.float32),
             jnp.zeros((block_k, d), jnp.float32),
         ) for _ in heads)
-        first_full = 0
+        first_full, last_live, last_full = 0, n_q, n_q
+        masked = functools.partial(body, masked=True)
         if causal:
             # Q-blocks before first_live see none of this K-block; from
             # first_full on every row sees all of it.
             first_live = _clip((k_start - offset) // block_q, 0, n_q)
             first_full = _clip(
                 (k_start + block_k - 1 - offset + block_q - 1) // block_q, 0, n_q)
-            carry = _loop(first_live, first_full,
-                          functools.partial(body, masked=True), carry,
-                          unroll=unroll)
-        carry = _loop(first_full, n_q, functools.partial(body, masked=False),
-                      carry, unroll=unroll)
+            near_end = first_full
+            if window is not None:
+                # ... up to last_full, where the band's far edge crosses
+                # the pair; from last_live on no row sees any of it
+                last_live = _clip(
+                    (k_start + block_k - 2 - offset + window) // block_q + 1,
+                    0, n_q)
+                last_full = _least(_clip(
+                    (k_start - offset + window) // block_q, 0, n_q), last_live)
+                near_end = _least(first_full, last_live)
+            carry = _loop(first_live, near_end, masked, carry, unroll=unroll)
+        carry = _loop(first_full, last_full,
+                      functools.partial(body, masked=False), carry,
+                      unroll=unroll)
+        if window is not None:
+            carry = _loop(_most(first_full, last_full), last_live, masked,
+                          carry, unroll=unroll)
         # q entered the products unscaled (the scale sat on k or the scores).
         dk_ref[rows, :] = (_side_by_side([dk for dk, _ in carry], 1) * scale
                            ).astype(dk_ref.dtype)
@@ -514,11 +573,13 @@ def _bwd_dkv_kernel(
 def _bwd(
     q, k, v, out, lse, do, *, heads: int, causal: bool, scale: float,
     dq_blocks: Tuple[int, int], dkv_blocks: Tuple[int, int], interpret: bool,
+    window: Optional[int] = None,
 ):
     b, s_q, width = q.shape
     s_k, d = k.shape[1], width // heads
     item = q.dtype.itemsize
-    static = dict(head_dim=d, causal=causal, scale=scale, offset=s_k - s_q)
+    static = dict(head_dim=d, causal=causal, scale=scale, offset=s_k - s_q,
+                  window=window)
 
     block_q, block_k = dq_blocks
     n_q, n_k = s_q // block_q, s_k // block_k
@@ -543,7 +604,7 @@ def _bwd(
         out_specs=mine,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if window is None else "swa_bwd_dq",
     )(q, k, v, out, do, lse_in)
 
     block_q, block_k = dkv_blocks
@@ -567,7 +628,7 @@ def _bwd(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if window is None else "swa_bwd_dkv",
     )(q, k, v, out, do, lse_in)
     return dq, dk, dv
 
@@ -577,15 +638,18 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, heads, causal, scale, blocks: Blocks, interpret):
-    return _flash_fwd(q, k, v, heads, causal, scale, blocks, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, heads, causal, scale, blocks: Blocks, interpret, window):
+    return _flash_fwd(q, k, v, heads, causal, scale, blocks, interpret,
+                      window)[0]
 
 
-def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret):
+def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
+               window):
     out, lse = _fwd(
         q, k, v, heads=heads, causal=causal, scale=scale,
         block_q=blocks[0][0], block_k=blocks[0][1], interpret=interpret,
+        window=window,
     )
     # The kernel's column [B, H, S, 1] turned once into dense rows
     # [B, H, S]; both named HERE, so that the residuals below are the named
@@ -597,11 +661,13 @@ def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, res, g):
+def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, res,
+               g):
     q, k, v, out, lse = res
     return _bwd(
         q, k, v, out, lse, g, heads=heads, causal=causal, scale=scale,
         dq_blocks=blocks[1], dkv_blocks=blocks[2], interpret=interpret,
+        window=window,
     )
 
 
@@ -618,11 +684,17 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] tensors: the
     kernels' result, or ValueError where they cannot tile the lengths
     (:func:`choose_blocks` is the question; this module holds no other
     path).
+
+    ``window`` (with ``causal``): query i sees only the ``window`` keys up
+    to its own, ``0 <= i + (s_k - s_q) - j < window``. K-blocks (Q-blocks in
+    dk/dv) wholly outside that band are not visited; the calls carry names
+    of their own (``swa_fwd``, ``swa_bwd_dq``, ``swa_bwd_dkv``).
 
     ``block_q`` / ``block_k``, when passed, hold for all three kernels; left
     out, each kernel's are chosen from what the call shows."""
@@ -630,7 +702,10 @@ def flash_attention(
     s_k = k.shape[1]
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    blocks = choose_blocks(s, s_k, causal, block_q, block_k)
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"flash attention: window={window} needs causal "
+                         f"attention and at least one key")
+    blocks = choose_blocks(s, s_k, causal, block_q, block_k, window)
     if blocks is None:
         raise ValueError(
             f"flash attention: lengths q={s} k={s_k} have no block divisor "
@@ -645,12 +720,14 @@ def flash_attention(
     log_once(log, f"flash attention: {how} Pallas kernel on "
                   f"{device.platform} ({device.device_kind}), "
                   f"{jnp.dtype(q.dtype).name} operands to the MXU, blocks "
-                  f"q/k {chosen}, over lengths {s}/{s_k}, head_dim {d}, on "
+                  f"q/k {chosen}, over lengths {s}/{s_k}"
+                  + (f" (window {window})" if window is not None else "")
+                  + f", head_dim {d}, on "
                   f"[batch, seq, heads·head_dim] = [{b}, {s}, {h * d}] with "
                   f"{tile} head(s) to a {tile * d}-lane block")
     # [B, S, H, d] -> [B, S, H·d] and back: the same bytes in the same order
     out = _flash(
         q.reshape(b, s, h * d), k.reshape(b, s_k, h * d), v.reshape(b, s_k, h * d),
-        h, causal, scale, blocks, interpret,
+        h, causal, scale, blocks, interpret, window,
     )
     return out.reshape(b, s, h, d)

@@ -89,8 +89,9 @@ def pipeline_rules(base) -> tuple:
 
 
 #: model families whose factories accept the pipeline (they share the
-#: nn.scan transformer stack). gpt_moe is excluded: MoE inside the
-#: pipeline is a NotImplementedError in the model.
+#: nn.scan transformer stack). A stack with ``moe`` layers (laguna) is
+#: excluded: an expert layer inside the pipeline is a NotImplementedError
+#: in the model.
 PIPELINE_CAPABLE = ("gpt", "bert")
 
 

@@ -25,6 +25,8 @@ Two forms of the same arithmetic:
 from __future__ import annotations
 
 import functools
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,32 +34,96 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 #: rows (positions) a grid cell takes: [256, 2048] bf16 in and out are 1 MB
-#: each, two buffers of each, beside float32 [256, 128] slices
+#: each, two buffers of each, beside float32 [256, 128] slices; fewer where
+#: the array is wider, so that a block stays within _BLOCK_BYTES (64 heads
+#: of 128: [128, 8192])
 _ROWS = 256
+_BLOCK_BYTES = 2 << 20
 
 
-def rope_tables(seq: int, head_dim: int, theta: float):
+def yarn_inv_freq(rot: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's blended inverse frequencies over ``rot`` rotated dimensions
+    (``rot // 2`` of them), as HF's ``_compute_yarn_parameters`` forms them:
+    dimension ``i`` keeps ``f_i = theta ** (-2 i / rot)`` where it turns
+    more than ``beta_fast`` times over the ``original`` length, takes ``f_i /
+    factor`` where it turns less than ``beta_slow`` times, and a linear
+    blend between the two correction dimensions."""
+    def correction_dim(turns):
+        return rot * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rot - 1)
+    if low == high:
+        high += 0.001  # as the source has it: no division by zero
+    f = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return f / factor * (1.0 - keep) + f * keep
+
+
+def rope_tables(seq: int, head_dim: int, theta: float,
+                rot: Optional[int] = None, yarn: Optional[dict] = None):
     """``(cos, sin_signed)``, each float32 ``[seq, head_dim]``, of positions
-    ``0..seq-1``. Made once a forward pass and handed to every layer."""
-    inv = 1.0 / theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                          / head_dim)
+    ``0..seq-1``. Made once a forward pass for each rotary scheme and handed
+    to the layers of that scheme.
+
+    ``rot`` (None: ``head_dim``): only the first ``rot`` dimensions of a head
+    are rotated, dimension ``i`` pairing with ``i + rot / 2``; the rest pass
+    (cosine 1, sine 0). ``yarn``: ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow`` and
+    ``attention_factor`` of a YaRN scheme — :func:`yarn_inv_freq`'s
+    frequencies, cosine and sine times the attention factor."""
+    rot = rot or head_dim
+    if yarn is None:
+        inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    else:
+        inv = yarn_inv_freq(rot, theta, yarn["factor"],
+                            yarn["original_max_position_embeddings"],
+                            yarn["beta_fast"], yarn["beta_slow"])
     angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    return (jnp.concatenate([cos, cos], axis=-1),
-            jnp.concatenate([-sin, sin], axis=-1))
+    if yarn is not None:
+        gain = yarn.get("attention_factor") \
+            or 0.1 * math.log(yarn["factor"]) + 1.0
+        cos, sin = cos * gain, sin * gain
+    passed = head_dim - rot
+    return (jnp.concatenate(
+                [cos, cos] + [jnp.ones((seq, passed), jnp.float32)] * (passed > 0),
+                axis=-1),
+            jnp.concatenate(
+                [-sin, sin] + [jnp.zeros((seq, passed), jnp.float32)] * (passed > 0),
+                axis=-1))
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin_signed: jax.Array):
+def _turn(x, rot: int, roll):
+    """A head's lanes with each rotated dimension's partner in its place
+    (``i`` <-> ``i + rot / 2`` for ``i < rot / 2``); what the lanes past
+    ``rot`` hold meets a zero sine. ``roll(x, shift)`` turns the last
+    axis."""
+    d = x.shape[-1]
+    if rot == d:
+        return roll(x, d // 2)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where(lane < rot // 2, roll(x, d - rot // 2),
+                     roll(x, rot // 2))
+
+
+def apply_rope(x: jax.Array, cos: jax.Array, sin_signed: jax.Array,
+               rot: Optional[int] = None):
     """The rotation on ``[batch, seq, heads, head_dim]`` in ``jax.numpy``."""
     with jax.named_scope("rope"):
         x32 = x.astype(jnp.float32)
-        turned = jnp.roll(x32, x.shape[-1] // 2, axis=-1)
+        turned = _turn(x32, rot or x.shape[-1],
+                       lambda a, shift: jnp.roll(a, shift, axis=-1))
         return (x32 * cos[None, :, None, :]
                 + turned * sin_signed[None, :, None, :]).astype(x.dtype)
 
 
 def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int,
-                 negate: bool):
+                 negate: bool, rot: int):
     # x_ref, o_ref: [rows, heads · d]; cos_ref, sin_ref: [rows, d] float32
     cos, sin = cos_ref[...], sin_ref[...]
     if negate:
@@ -65,17 +131,19 @@ def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int,
     for g in range(x_ref.shape[1] // head_dim):
         cols = slice(g * head_dim, (g + 1) * head_dim)
         x = x_ref[:, cols].astype(jnp.float32)
-        o_ref[:, cols] = (x * cos + pltpu.roll(x, head_dim // 2, 1) * sin
-                          ).astype(o_ref.dtype)
+        turned = _turn(x, rot, lambda a, shift: pltpu.roll(a, shift, 1))
+        o_ref[:, cols] = (x * cos + turned * sin).astype(o_ref.dtype)
 
 
-def _call(x, cos, sin_signed, head_dim, negate, interpret):
+def _call(x, cos, sin_signed, head_dim, negate, interpret, rot):
     b, s, width = x.shape
-    rows = next(r for r in (_ROWS, 128, 64, 32, 16, 8, s) if s % r == 0)
+    rows = next(r for r in (_ROWS, 128, 64, 32, 16, 8, s) if s % r == 0
+                and (r <= 8 or r * width * x.dtype.itemsize <= _BLOCK_BYTES))
     mine = pl.BlockSpec((None, rows, width), lambda i, j: (i, j, 0))
     table = pl.BlockSpec((rows, head_dim), lambda i, j: (j, 0))
     return pl.pallas_call(
-        functools.partial(_rope_kernel, head_dim=head_dim, negate=negate),
+        functools.partial(_rope_kernel, head_dim=head_dim, negate=negate,
+                          rot=rot),
         grid=(b, s // rows),
         in_specs=[mine, table, table],
         out_specs=mine,
@@ -85,35 +153,42 @@ def _call(x, cos, sin_signed, head_dim, negate, interpret):
     )(x, cos, sin_signed)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _rope(x, cos, sin_signed, head_dim, interpret):
-    return _call(x, cos, sin_signed, head_dim, False, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _rope(x, cos, sin_signed, head_dim, interpret, rot):
+    return _call(x, cos, sin_signed, head_dim, False, interpret, rot)
 
 
-def _rope_fwd(x, cos, sin_signed, head_dim, interpret):
-    return _call(x, cos, sin_signed, head_dim, False, interpret), (
+def _rope_fwd(x, cos, sin_signed, head_dim, interpret, rot):
+    return _call(x, cos, sin_signed, head_dim, False, interpret, rot), (
         cos, sin_signed)
 
 
-def _rope_bwd(head_dim, interpret, tables, g):
-    # the tables are positions, not parameters: no gradient
-    return _call(g, *tables, head_dim, True, interpret), None, None
+def _rope_bwd(head_dim, interpret, rot, tables, g):
+    # the tables are positions, not parameters: no gradient. The partner
+    # map is its own inverse and the sine changes sign across a pair, so
+    # the transpose is the same kernel with the sine negated, whatever
+    # gain the tables carry.
+    return _call(g, *tables, head_dim, True, interpret, rot), None, None
 
 
 _rope.defvjp(_rope_fwd, _rope_bwd)
 
 
 def rope_rows(x: jax.Array, cos: jax.Array, sin_signed: jax.Array, *,
-              head_dim: int, interpret: bool = False) -> jax.Array:
+              head_dim: int, interpret: bool = False,
+              rot: Optional[int] = None) -> jax.Array:
     """The rotation on ``[batch, seq, heads·head_dim]``, heads of whole
     128-lane tiles (``head_dim % 128 == 0``; the caller asks
-    :func:`tiles_lanes`), as a Pallas kernel. ``interpret=True`` runs it in
-    the Pallas interpreter — something only a test passes."""
+    :func:`tiles_lanes`), as a Pallas kernel. ``rot`` (None: ``head_dim``):
+    the first ``rot`` lanes of a head are rotated, the rest pass.
+    ``interpret=True`` runs it in the Pallas interpreter — something only a
+    test passes."""
     if not tiles_lanes(head_dim) or x.shape[-1] % head_dim:
         raise ValueError(f"rope_rows: head_dim {head_dim} is not whole "
                          f"128-lane tiles of width {x.shape[-1]}")
     with jax.named_scope("rope"):
-        return _rope(x, cos, sin_signed, head_dim, interpret)
+        return _rope(x, cos, sin_signed, head_dim, interpret,
+                     rot or head_dim)
 
 
 def tiles_lanes(head_dim: int) -> bool:

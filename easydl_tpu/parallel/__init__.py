@@ -30,7 +30,7 @@ from easydl_tpu.core.sharding import (  # noqa: F401
     DEFAULT_RULES,
     state_shardings,
 )
-from easydl_tpu.ops.moe import MoeMlp, top_k_routing  # noqa: F401
+from easydl_tpu.ops.moe import MoeMlp, route, routed_experts  # noqa: F401
 from easydl_tpu.ops.pipeline import (  # noqa: F401
     apply_pipeline_config,
     bubble_fraction,
@@ -58,5 +58,6 @@ __all__ = [
     "bubble_fraction",
     "apply_pipeline_config",
     "MoeMlp",
-    "top_k_routing",
+    "route",
+    "routed_experts",
 ]

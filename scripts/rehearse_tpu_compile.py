@@ -39,6 +39,10 @@ HYBRID = ("granite_hybrid", dict(
     layer_types=["mamba"] * 5 + ["attention"], **_CHIP))
 OURO = ("ouro", dict(size="2.6b", seq_len=4096, remat_policy="full",
                      layer_types=["full_attention"] * 8, **_CHIP))
+LAGUNA = ("laguna", dict(
+    size="xs.2", seq_len=8192, vocab=12544, remat_policy="full",
+    layer_types=["full_attention"] + ["sliding_attention"] * 3
+    + ["full_attention"], experts_held=(0, 32), **_CHIP))
 
 #: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
@@ -58,6 +62,7 @@ PROGRAMS = {
     "xl_fsdp4": (XL, "fsdp=4", 16, 1, "adamw", 14.2),
     "hybrid_4x2": (HYBRID, "dp=1", 8, 4, "adamw", 15.61),
     "ouro_4x1": (OURO, "dp=1", 4, 4, "adamw", 15.50),
+    "laguna_1x2": (LAGUNA, "dp=1", 2, 1, "adamw", 15.75),
 }
 
 

@@ -98,9 +98,11 @@ def test_gpt_bundle_fused_matches_logits_path(eight_devices, fused_head):
                                    rtol=1e-4, atol=1e-6)
 
 
-def test_gpt_moe_fused_head_runs(eight_devices, fused_head):
-    bundle = get_model("gpt", size="test", seq_len=32, vocab=128,
-                       moe_experts=4)
+def test_moe_fused_head_runs(eight_devices, fused_head):
+    """Laguna's test size (expert layers) through the fused chunked head:
+    the counters reach the metrics beside the loss."""
+    bundle = get_model("laguna", size="test", seq_len=32, vocab=128,
+                       experts_held=(0, 4))
     fused_head(chunk_rows=32)  # 4 sequences: 8 positions a chunk
     assert gpt_module.fused_head_by_shape(4, 32, 128)
     rng = jax.random.PRNGKey(1)
@@ -108,7 +110,8 @@ def test_gpt_moe_fused_head_runs(eight_devices, fused_head):
     batch = next(iter(bundle.make_data(4, seed=5)))
     loss, metrics = bundle.loss_fn(params, batch, rng)
     assert np.isfinite(float(loss))
-    assert "moe_balance" in metrics
+    assert float(metrics["moe_dropped"]) == 0.0
+    assert 0.0 < float(metrics["moe_rows_per_token"]) < 2.0
 
 
 def _reject_cases():
@@ -145,15 +148,18 @@ def test_the_retired_switches_are_rejected_by_name(case):
         _reject_cases()[case]()
 
 
-@pytest.mark.parametrize("moe_experts", [0, 4], ids=["dense", "moe4"])
+@pytest.mark.parametrize("family,described", [
+    ("gpt", dict(size="test")),
+    ("laguna", dict(size="test", experts_held=(0, 4))),
+], ids=["dense", "moe4"])
 def test_bf16_bundle_with_the_head_chosen_by_shape_matches_full_logits(
-        monkeypatch, fused_head, moe_experts):
+        monkeypatch, fused_head, family, described):
     """Through ``lm_bundle`` as a training cell reaches it: bf16, the shape
     rule picks the one-pass head and the chunk is sized in rows, the rule's
     own — against the same bundle with full logits, loss and every
     parameter's gradient."""
-    bundle = get_model("gpt", size="test", seq_len=512, vocab=256,
-                       dtype="bfloat16", moe_experts=moe_experts)
+    bundle = get_model(family, seq_len=512, vocab=256, dtype="bfloat16",
+                       **described)
     rng = jax.random.PRNGKey(0)
     params = bundle.init_fn(rng)
     batch = next(iter(bundle.make_data(4, seed=3)))

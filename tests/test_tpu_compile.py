@@ -514,3 +514,61 @@ def test_rotary_puts_no_copy_between_the_projections_and_the_kernels(
     for opcode, result, path in mine:
         if opcode == "custom-call":
             assert "bf16[1,4096,2048]{2,1,0" in result, result
+
+
+# ---------------------------------------------------------------- Laguna
+@pytest.mark.parametrize("heads,window,rot,names", [
+    (64, 512, None, ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")),
+    (48, None, 64, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+], ids=["window-512-64-heads", "full-48-heads-partial-yarn"])
+def test_lagunas_attention_kinds_compile_at_8k(v5e_2x2, heads, window, rot,
+                                               names):
+    """Laguna-XS.2's two attention kinds at the cell's shape, forward and
+    backward: 64 / 48 query heads over 8 key/value heads of 128 at 8,192,
+    the window layers' kernels under names of their own, the rotary kernel
+    on 64 of a head's 128 lanes — within the kernels' VMEM."""
+    from easydl_tpu.ops.rope import rope_tables
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        rope = rope_tables(8192, 128, 10000.0, rot)
+        return multihead_attention(
+            q, k, v, causal=True, impl="flash", rope=rope, rotary_dim=rot,
+            window=window).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in names + ("rope_fwd", "rope_bwd"):
+        assert any(f"/{name}/" in line for line in calls), name
+    other = ("flash_fwd", "swa_fwd")[window is None]
+    assert not any(f"/{other}/" in line for line in calls)
+
+
+def test_the_expert_layers_grouped_products_compile_as_kernels(v5e_2x2):
+    """The routed experts at the cell's size (16,384 tokens, top-8, 32
+    experts of 512 held: a 131,072-row buffer), forward and backward: the
+    grouped products are Mosaic kernels of the compiler's own, nine of
+    them."""
+    from easydl_tpu.ops.moe import routed_experts
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(h, weights, w_gate, w_up, w_down, chosen):
+        y, _ = routed_experts(h, chosen, weights, w_gate, w_up, w_down, 0)
+        return y.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        s((16384, 2048)), s((16384, 8), jnp.float32), s((32, 2048, 512)),
+        s((32, 2048, 512)), s((32, 512, 2048)),
+        s((16384, 8), jnp.int32)).compile()
+    products = [c for c in _mosaic_calls(compiled)
+                if c.startswith("bf16[") or c.startswith("f32[")]
+    assert len(products) == 9, _mosaic_calls(compiled)
