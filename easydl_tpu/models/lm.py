@@ -151,7 +151,11 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     :func:`looplm_objective` with ``beta = exit_entropy_weight``), eval,
     data and the hints. Where the description has ``moe`` layers the loss's
     metrics carry their counters (``ops/moe.py COUNTERS``): ``moe_dropped``
-    summed over the layers, the others their mean."""
+    summed over the layers, the others their mean — ``moe_rows_per_token``,
+    ``moe_load_max_over_mean``, ``moe_buffer_fill`` (landed rows over the
+    bound), ``router_entropy``, and ``moe_overflow``, the share of the
+    step's expert-layer calls whose landed rows needed more than one piece
+    of the sort."""
     model = Transformer(cfg)
     seq_len, vocab = cfg.max_seq, cfg.vocab
     n_sparse = sum(1 for _, ffn in cfg.pattern if ffn == "moe")

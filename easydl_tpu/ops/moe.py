@@ -6,20 +6,39 @@ float32 (sigmoid scores, the ``k`` largest, their scores renormalised and
 scaled), SwiGLU experts, and a shared expert every token passes through.
 The layer holds a contiguous range ``experts_held = [lo, hi)`` of the routed
 experts — all of them, or one chip's share of an expert-parallel group — and
-computes ITS experts' part of the result: for the choices that fall in the
-range, rows sorted by expert into a buffer of static size, three grouped
-matrix products over the experts held (``jax.lax.ragged_dot``: on a TPU one
-Mosaic kernel that walks only the tiles the groups fill), the weighted sum
-back per token; plus the shared expert, once. What absent experts would
-have added is left out; no code stands in for them or for their traffic.
+computes ITS experts' part of the result: the choices that fall in the range
+sorted by expert, their tokens' rows gathered, three grouped matrix products
+over the experts held (``jax.lax.ragged_dot``: on a TPU one Mosaic kernel
+that walks only the tiles the groups fill), the weighted sum back per token;
+plus the shared expert, once. What absent experts would have added is left
+out; no code stands in for them or for their traffic.
 
-No token is ever dropped. A token's ``k`` choices are distinct experts, so at
-most ``min(k, held)`` of them fall here: the buffer holds ``tokens x min(k,
-held)`` rows (:func:`rows_bound`), every choice that falls here has a row, and
-the counter ``dropped`` (choices in the range without a row) says so in every
-step. On average ``k x held / total`` of a token's choices fall here (one in
-Laguna's eight-chip group), so the buffer is mostly unused rows behind the
-last group: the grouped products do not visit them.
+No token is ever dropped, and the layer moves the rows that landed, not the
+rows that could. A token's ``k`` choices are distinct experts, so at most
+``min(k, held)`` of them fall here — ``tokens x min(k, held)`` rows
+(:func:`rows_bound`) — while ``k x held / total`` do on average (one in
+Laguna's eight-chip group). So the sort is worked through in pieces of twice
+the expected load (:func:`piece_rows`; the bound itself where all experts are
+held): one function of a piece's rows, run ``ceil(landed / piece)`` times —
+once for a balanced load, again and again up to the bound where everything
+lands here — inside the layer's own differentiation rule (:func:`_routed`:
+reverse mode cannot differentiate a loop whose length is traced; the experts'
+weight gradients are summed over the pieces). Every choice that falls here
+has a row for any routing; the counter ``dropped`` (choices in the range that
+no piece gave a row) says so in every step, ``overflow`` how often more than
+one piece was needed.
+
+The sums back to the tokens are formed from the rows' side. ``y[t]`` (the
+forward) and ``dh[t]`` (the gather's transpose) are sums of a piece's rows
+into their tokens' rows, accumulated in float32 from the float32-weighted rows
+and rounded once (:func:`_to_tokens`); a choice's weight gradient is its row's
+product with its token's cotangent, a scalar put back by the choice's number.
+Measured on the chip at 32,768 rows of 2048 (PERF.md section 6, PR 32): a
+gather of all ``k`` choices a token — the form this replaces, 7/8 of its rows
+dead — 4.4 ms a call on the bound's 131,072 rows; XLA's own scatter-add of
+the float32 rows 2.7 ms and 0.75 to write them; the rows re-ordered by token
+and each block of 128 tokens summed from the slab of rows it owns by a
+selection product in a Pallas kernel, the form that stayed.
 
 Under a mesh whose ``ep`` axis is larger than one the expert weights are
 sharded over it (rule ``("expert", "ep")``, ``core/sharding.py``): each shard
@@ -28,11 +47,6 @@ experts, computes its experts' part, and the parts are summed over ``ep``
 (``jax.shard_map``: the grouped products are kernels, which GSPMD cannot
 partition). Tokens stay where the batch axes put them: there is no
 all-to-all, each ``ep`` shard sees every token of its batch shard.
-
-Gathers both ways. Sorting rows by expert and putting results back are
-permutations; their transposes are written out as gathers too
-(:func:`_dispatch`, :func:`_combine`), where XLA's own rule for a gather is
-a scatter-add.
 """
 
 from __future__ import annotations
@@ -44,6 +58,8 @@ from typing import Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from easydl_tpu.core.mesh_shapes import BATCH_AXES
@@ -53,13 +69,34 @@ EXPERT_AXIS = "ep"
 
 #: the layer's counters, in the order of the vector it returns
 COUNTERS = ("moe_dropped", "moe_rows_per_token", "moe_load_max_over_mean",
-            "moe_buffer_fill", "router_entropy")
+            "moe_buffer_fill", "router_entropy", "moe_overflow")
+
+
+#: a piece of the sort holds this many times the rows expected to land on
+#: the experts held (a balanced deployment lands the expected count; Laguna's
+#: cell 0.001-1.73 of it over its window: PERF.md section 6, PR 31)
+PIECE_OVER_EXPECTED = 2
+#: a tile of the MXU's: rows a piece is rounded up to and, in the sum back to
+#: the tokens, rows a step reads and tokens a block of the result holds
+TILE = 128
+
+
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
 
 
 def rows_bound(tokens: int, k: int, held: int) -> int:
-    """Rows of the sorted buffer: every choice that can fall on ``held``
-    experts (a token's ``k`` choices are distinct)."""
+    """Rows of the sort that can hold a choice: every choice that can fall
+    on ``held`` experts (a token's ``k`` choices are distinct)."""
     return tokens * min(k, held)
+
+
+def piece_rows(tokens: int, k: int, held: int, total: int) -> int:
+    """Rows the layer moves at a time: twice the ``tokens x k x held /
+    total`` expected to land on ``held`` of ``total`` experts, in whole row
+    tiles; the bound itself where that is no more (all experts held)."""
+    expected = -(-PIECE_OVER_EXPECTED * tokens * k * held // total)
+    return min(rows_bound(tokens, k, held), -(-expected // TILE) * TILE)
 
 
 def route(h: jax.Array, kernel: jax.Array, k: int, scaling: float):
@@ -75,94 +112,250 @@ def route(h: jax.Array, kernel: jax.Array, k: int, scaling: float):
     return logits, chosen, scaling * top / jnp.sum(top, -1, keepdims=True)
 
 
-# ------------------------------------------------------ permutations as gathers
-@jax.custom_vjp
-def _dispatch(x, order, place, live):
-    """``x [T, D]`` as the buffer's rows ``[R, D]``: row ``r`` is the token
-    of choice ``order[r]`` (choices numbered ``token * k + j``)."""
-    return x[order // place.shape[1]]
+# ------------------------------------------------------ the pieces of the sort
+def _piece(p, piece, order, by_token, ends, rowed):
+    """Piece ``p`` of the sort: ``(choice [piece], live [piece], sizes [E],
+    token_order)`` — the choices (numbered ``token * k + j``) of its rows,
+    which of its rows hold a choice that landed, its rows an expert, and
+    ``(rows, choices, live rows)`` of the same rows put in the order of
+    their tokens (dead rows last)."""
+    start = p * piece
+    choice, at, number = (jax.lax.dynamic_slice(a, (start,), (piece,))
+                          for a in (order, *by_token))
+    live = start + jnp.arange(piece) < rowed
+    sizes = jnp.diff(jnp.clip(jnp.minimum(ends, rowed) - start, 0, piece))
+    return (choice, live, sizes.astype(jnp.int32),
+            (at, number, jnp.clip(rowed - start, 0, piece)))
 
 
-def _dispatch_fwd(x, order, place, live):
-    return _dispatch(x, order, place, live), (place, live)
+def _experts(x, w_gate, w_up, w_down, sizes):
+    """The SwiGLU experts over rows ``x [R, D]`` sorted by expert, ``sizes``
+    rows each; rows behind the last group are not visited."""
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                            preferred_element_type=x.dtype)
+    return dot(nn.silu(dot(x, w_gate)) * dot(x, w_up), w_down)
 
 
-def _dispatch_bwd(res, g):
-    place, live = res  # [T, k]: a choice's row, and whether it has one
-    picked = jnp.where(live[..., None], g[place], 0)
-    return picked.astype(jnp.float32).sum(1).astype(g.dtype), None, None, None
+def _sum_kernel(blk_ref, chunk_ref, ends_ref, rows_ref, tok_ref, w_ref,
+                out_ref, *, block):
+    """One step of :func:`_to_tokens`: the chunk of rows ``chunk_ref[s]``
+    into the block of tokens ``blk_ref[s]``, as a product with the chunk's
+    selection matrix (row ``r``'s weight at its token's place). ``ends_ref``
+    holds the schedule's steps and the live rows: a step past the first does
+    nothing, a row past the second counts as zeros whatever it holds."""
+    s = pl.program_id(0)
+    b = blk_ref[s]
+
+    @pl.when((s == 0) | (b != blk_ref[jnp.maximum(s - 1, 0)]))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(s < ends_ref[0])
+    def _():
+        chunk = rows_ref.shape[0]
+        row = chunk_ref[s] * chunk + jax.lax.broadcasted_iota(
+            jnp.int32, (chunk, 1), 0)
+        here = tok_ref[...] - b * block == jax.lax.broadcasted_iota(
+            jnp.int32, (block, chunk), 0)
+        out_ref[...] += jnp.dot(
+            jnp.where(here, w_ref[...], 0.0),
+            jnp.where(row < ends_ref[1], rows_ref[...], 0).astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _to_tokens(rows, scale, token_order, tokens, k, interpret):
+    """``[tokens, D]`` float32: ``scale[j] * rows[by_token[j]]`` summed in
+    float32 into the token ``number[j] // k``, over the first ``n_live``
+    ``j`` — ``token_order = (by_token, number, n_live)``: the rows of
+    ``rows [R, D]`` in the order of their tokens, and their choices.
+
+    The rows are put in that order (one ``[R, D]`` gather), so that a block
+    of tokens owns a contiguous slab of rows, and a Pallas kernel sums each
+    block's slab a chunk at a time: a schedule of (block, chunk) steps, a
+    block's steps one after the other and its result resident between them
+    (``interpret``: in the Pallas interpreter, off the TPU)."""
+    by_token, number, n_live = token_order
+    m, d = rows.shape
+    block = min(TILE, -(-tokens // 8) * 8)
+    n_blocks = -(-tokens // block)
+    nowhere = n_blocks * block
+    pad = -m % TILE
+    rows = jnp.pad(rows[by_token], ((0, pad), (0, 0)))
+    tok = jnp.pad(jnp.where(jnp.arange(m) < n_live, number // k, nowhere),
+                  (0, pad), constant_values=nowhere)
+    scale = jnp.pad(scale, (0, pad))
+    chunks = (m + pad) // TILE
+    # block b owns the rows [starts[b], starts[b + 1]): the chunks that hold
+    # them, at least one (an empty block is written too)
+    starts = jnp.searchsorted(tok, jnp.arange(n_blocks + 1) * block)
+    first = starts[:-1] // TILE
+    n = jnp.maximum(-(-starts[1:] // TILE) - first, 1)
+    done = jnp.cumsum(n)
+    steps = chunks + n_blocks  # no schedule is longer
+    s = jnp.arange(steps)
+    blk = jnp.minimum(jnp.searchsorted(done, s, side="right"), n_blocks - 1)
+    chunk = jnp.minimum(first[blk] + s - (done[blk] - n[blk]), chunks - 1)
+    out = pl.pallas_call(
+        functools.partial(_sum_kernel, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((TILE, d), lambda s, b, c, _: (c[s], 0)),
+                pl.BlockSpec((1, TILE), lambda s, b, c, _: (0, c[s])),
+                pl.BlockSpec((1, TILE), lambda s, b, c, _: (0, c[s]))],
+            out_specs=pl.BlockSpec((block, d), lambda s, b, c, _: (b[s], 0))),
+        out_shape=jax.ShapeDtypeStruct((nowhere, d), jnp.float32),
+        interpret=interpret,
+        name="rows_to_tokens",
+    )(blk, chunk, jnp.stack([done[-1], n_live]), rows, tok[None],
+      scale[None])
+    return out[:tokens]
 
 
-@jax.custom_vjp
-def _combine(rows, weights, order, place, live):
-    """``y [T, D]``: each token's live choices' rows, weighted and summed
-    in float32."""
-    picked = jnp.where(live[..., None], rows[place], 0).astype(jnp.float32)
-    return (picked * weights[..., None]).sum(1).astype(rows.dtype)
+def _over_pieces(one, n):
+    """``one(0) + ... + one(n - 1)``, at least ``one(0)``: ``n`` is traced
+    (the landed count's). The first piece is the program's own; a loop adds
+    the others where there are any."""
+    def more(carry):
+        p, acc = carry
+        return p + 1, jax.tree.map(jnp.add, acc, one(p))
+
+    return jax.lax.while_loop(lambda carry: carry[0] < n, more,
+                              (jnp.int32(1), one(jnp.int32(0))))[1]
 
 
-def _combine_fwd(rows, weights, order, place, live):
-    return _combine(rows, weights, order, place, live), (
-        rows, weights, order, place, live)
+# A piece's two functions are jitted for the tracing's sake, not the
+# compiler's (it inlines them): the first piece and the loop's, and every
+# program of a process with these shapes, share one trace of each.
+@functools.partial(jax.jit, static_argnums=(0,))
+def _piece_forward(how, p, h, weights, w_gate, w_up, w_down, order, by_token,
+                   ends, rowed):
+    """``(y [T, D] float32, live rows)`` of piece ``p``: its tokens' rows,
+    the experts' results, their weighted sum back into the tokens."""
+    piece, interpret = how
+    tokens, k = weights.shape
+    choice, live, sizes, token_order = _piece(p, piece, order, by_token, ends,
+                                              rowed)
+    with jax.named_scope("dispatch"):
+        x = h[choice // k]
+    with jax.named_scope("experts"):
+        out = _experts(x, w_gate, w_up, w_down, sizes)
+    with jax.named_scope("combine"):
+        y = _to_tokens(out, weights.reshape(-1)[token_order[1]], token_order,
+                       tokens, k, interpret)
+    return y, jnp.sum(live, dtype=jnp.int32)
 
 
-def _combine_bwd(res, g):
-    rows, weights, order, place, live = res
-    k = place.shape[1]
-    scale = jnp.where(live, weights, 0.0).reshape(-1)[order]  # [R]
-    d_rows = (g[order // k].astype(jnp.float32) * scale[:, None]
-              ).astype(rows.dtype)
-    picked = jnp.where(live[..., None], rows[place], 0).astype(jnp.float32)
-    d_weights = (picked * g[:, None, :].astype(jnp.float32)).sum(-1)
-    return d_rows, d_weights, None, None, None
+@functools.partial(jax.jit, static_argnums=(0,))
+def _piece_backward(how, p, g, h, weights, w_gate, w_up, w_down, order,
+                    by_token, ends, rowed):
+    """Piece ``p``'s part of the gradients by ``h`` (float32), ``weights``
+    (flat) and the three expert weights under the cotangent ``g [T, D]``;
+    the piece's products are made again."""
+    piece, interpret = how
+    tokens, k = weights.shape
+    choice, live, sizes, token_order = _piece(p, piece, order, by_token, ends,
+                                              rowed)
+    tok = choice // k
+    with jax.named_scope("dispatch"):
+        x = h[tok]
+    with jax.named_scope("experts"):
+        out, experts_bwd = jax.vjp(
+            functools.partial(_experts, sizes=sizes), x, w_gate, w_up, w_down)
+    with jax.named_scope("combine"):
+        scale = jnp.where(live, weights.reshape(-1)[choice], 0.0)
+        g_rows = g[tok].astype(jnp.float32)
+        d_out = (g_rows * scale[:, None]).astype(out.dtype)
+        # a choice's weight gets its row's product with the token's g:
+        # scalars, put back by the choice's number (each has one row; a
+        # dead row's goes nowhere)
+        d_weights = jnp.zeros((tokens * k,), jnp.float32).at[
+            jnp.where(live, choice, tokens * k)].set(
+                jnp.sum(out.astype(jnp.float32) * g_rows, -1), mode="drop",
+                unique_indices=True)
+    with jax.named_scope("experts"):
+        d_x, d_gate, d_up, d_down = experts_bwd(d_out)
+    with jax.named_scope("dispatch"):
+        d_h = _to_tokens(d_x, jnp.ones_like(scale), token_order, tokens, k,
+                         interpret)
+    return d_h, d_weights, d_gate, d_up, d_down
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(how, h, weights, w_gate, w_up, w_down, order, by_token, ends,
+            rowed):
+    """``(y [T, D], rows [] int32)``: the experts' results for the first
+    ``rowed`` rows of the sort, weighted and summed into their tokens in
+    float32, a piece at a time (``how = (piece, interpret)``); and the rows
+    that were visited. The differentiation rule is written out: reverse
+    mode cannot differentiate a loop whose length is traced."""
+    args = (h, weights, w_gate, w_up, w_down, order, by_token, ends, rowed)
+    y, rows = _over_pieces(lambda p: _piece_forward(how, p, *args),
+                           -(-rowed // how[0]))
+    return y.astype(h.dtype), rows
 
 
-def routed_experts(h, chosen, weights, w_gate, w_up, w_down, lo):
-    """The part of the routed result the experts ``[lo, lo + E)`` give,
-    ``E = w_gate.shape[0]``: ``(y [T, D], stats [3])`` with ``stats`` =
-    (choices in the range that got no row, choices in the range, the
-    largest expert's rows), float32. ``lo`` may be traced (a shard's own
-    under ``ep``)."""
+def _routed_fwd(how, *args):
+    return _routed(how, *args), args
+
+
+def _routed_bwd(how, args, cts):
+    g = cts[0]  # [T, D]; the visited rows are an integer
+    d_h, d_weights, *d_experts = _over_pieces(
+        lambda p: _piece_backward(how, p, g, *args), -(-args[-1] // how[0]))
+    h, weights = args[:2]
+    return (d_h.astype(h.dtype), d_weights.reshape(weights.shape), *d_experts,
+            None, None, None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+def routed_experts(h, chosen, weights, w_gate, w_up, w_down, lo, total):
+    """The part of the routed result the experts ``[lo, lo + E)`` of
+    ``total`` give, ``E = w_gate.shape[0]``: ``(y [T, D], stats [4])`` with
+    ``stats`` = (choices in the range that got no row, choices in the
+    range, whether they needed more than one piece, the largest expert's
+    rows), float32. ``lo`` may be traced (a shard's own under ``ep``)."""
     tokens, k = chosen.shape
     held = w_gate.shape[0]
-    rows = rows_bound(tokens, k, held)
+    bound = rows_bound(tokens, k, held)
+    piece = piece_rows(tokens, k, held, total)
     with jax.named_scope("dispatch"):
         local = chosen - lo
         mine = (local >= 0) & (local < held)
         key = jnp.where(mine, local, held).reshape(-1)
-        order = jnp.argsort(key, stable=True)
-        place = jnp.argsort(order).reshape(tokens, k)  # a choice's row
-        order = order[:rows]
-        live = mine & (place < rows)
-        ends = jnp.searchsorted(key[order], jnp.arange(held + 1), side="left")
-        sizes = jnp.diff(ends).astype(jnp.int32)
-        place = jnp.minimum(place, rows - 1)
-        x = _dispatch(h, order, place, live)
-    with jax.named_scope("experts"):
-        dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
-                                preferred_element_type=h.dtype)
-        act = nn.silu(dot(x, w_gate)) * dot(x, w_up)
-        out = dot(act, w_down)
-    with jax.named_scope("combine"):
-        y = _combine(out, weights, order, place, live)
-    n_mine = jnp.sum(mine).astype(jnp.float32)
-    stats = jnp.stack([n_mine - jnp.sum(live).astype(jnp.float32), n_mine,
-                       jnp.max(sizes).astype(jnp.float32)])
+        # row r of the sort holds choice order[r]; the choices that landed
+        # come first, by expert
+        order = jnp.argsort(key, stable=True)[:bound]
+        order = jnp.pad(order, (0, -bound % piece))
+        sizes = jnp.sum(key[:, None] == jnp.arange(held), 0, dtype=jnp.int32)
+        ends = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(sizes)])
+        landed = ends[held]
+        rowed = jnp.minimum(landed, bound)
+        # each piece's rows by token, for the sums back to the tokens: a
+        # landed choice's number sorts its row within its piece (the pieces
+        # before the last are full, dead rows come last of all)
+        row = jnp.arange(order.size)
+        number, at = jax.lax.sort(
+            (jnp.where(row < rowed, row // piece * (tokens * k) + order,
+                       jnp.iinfo(jnp.int32).max), row % piece), num_keys=1)
+        by_token = (at, number % (tokens * k))
+    y, rows = _routed((piece, not _on_tpu()), h, weights, w_gate, w_up,
+                      w_down, order, by_token, ends, rowed)
+    stats = jnp.stack([landed - rows, landed, jnp.int32(landed > piece),
+                       jnp.max(sizes)]).astype(jnp.float32)
     return y, stats
 
 
 def _over_expert_shards(fn, tokens: int, held: int):
     """``fn(h, chosen, weights, w_gate, w_up, w_down, lo)`` per ``ep`` shard
     of the expert weights under the context mesh, the parts summed over
-    ``ep`` (and the stats with them: sums summed, the largest group the
-    largest anywhere); ``fn`` itself where the mesh has no ``ep`` axis
-    larger than one. Tokens are split over the batch axes where they
+    ``ep`` (and the stats with them: sums summed, the shards' calls that
+    needed more than one piece as their share, the largest group the largest
+    anywhere); ``fn`` itself where the mesh has no ``ep`` axis larger than
+    one. Tokens are split over the batch axes where they
     divide, as attention's per-shard wrap has it."""
     mesh = jax.sharding.get_abstract_mesh()
     if EXPERT_AXIS not in mesh.axis_names \
@@ -187,7 +380,8 @@ def _over_expert_shards(fn, tokens: int, held: int):
         # shards, so that it stands against the summed rows' mean
         return jax.lax.psum(y, EXPERT_AXIS), jnp.concatenate([
             jax.lax.psum(stats[:2], every),
-            jax.lax.pmax(stats[2:], every) * batch_shards])
+            jax.lax.pmean(stats[2:3], every),
+            jax.lax.pmax(stats[3:], every) * batch_shards])
 
     rows, experts = P(batch or None), P(EXPERT_AXIS)
     return jax.shard_map(
@@ -197,12 +391,19 @@ def _over_expert_shards(fn, tokens: int, held: int):
 
 class MoeMlp(nn.Module):
     """Router, the routed experts held here, the shared expert: ``x [B, S,
-    D]`` (the normed input) to ``(y [B, S, D], counters [5])``, the counters
-    in :data:`COUNTERS`' order, float32.
+    D]`` (the normed input) to ``(y [B, S, D], counters [6])``, the counters
+    in :data:`COUNTERS`' order, float32: choices on the held experts that
+    got no row (0 by construction), landed rows a token, the largest
+    expert's rows over the mean, landed rows over the bound, the router's
+    entropy, and whether the landed rows needed more than one piece
+    (:func:`piece_rows` of ``experts_total``: the share of the shards'
+    calls under ``ep``).
 
-    Scopes (``jax.named_scope``): ``router``, ``dispatch``, ``experts``,
-    ``combine``, ``shared_expert``; the caller's ``moe`` scope is around
-    them. Where ``intermediates`` is a mutable collection (the benchmark's
+    Scopes (``jax.named_scope``): ``router``, ``dispatch`` (the sort, a
+    piece's gather and the transpose's sum back to the tokens), ``experts``,
+    ``combine`` (the weighted sum back to the tokens and its transpose),
+    ``shared_expert``; the caller's ``moe`` scope is around them, a
+    ``while`` inside where a piece is not the first. Where ``intermediates`` is a mutable collection (the benchmark's
     check, tests) the layer also sows what it routed on: ``router_in``,
     ``router_logits``, ``chosen``."""
 
@@ -252,8 +453,10 @@ class MoeMlp(nn.Module):
         w_up = weight("w_up", (held, d, self.d_ff), inward)
         w_down = weight("w_down", (held, self.d_ff, d), outward,
                         self.out_init_scale)
-        y, stats = _over_expert_shards(routed_experts, tokens, held)(
-            h, chosen, weights, w_gate, w_up, w_down, jnp.int32(lo))
+        y, stats = _over_expert_shards(
+            functools.partial(routed_experts, total=self.experts_total),
+            tokens, held)(h, chosen, weights, w_gate, w_up, w_down,
+                          jnp.int32(lo))
         if self.shared_d_ff:
             with jax.named_scope("shared_expert"):
                 gate = weight("shared_gate", (d, self.shared_d_ff),
@@ -263,11 +466,12 @@ class MoeMlp(nn.Module):
                 down = weight("shared_down", (self.shared_d_ff, d),
                               ("mlp", "embed"), self.out_init_scale)
                 y = y + (nn.silu(h @ gate) * (h @ up)) @ down
-        dropped, n_mine, largest = stats
+        dropped, n_mine, overflow, largest = stats
         counters = jnp.stack([
             dropped,
             n_mine / tokens,
             largest * held / jnp.maximum(n_mine, 1.0),
             n_mine / rows_bound(tokens, self.k, held),
-            entropy])
+            entropy,
+            overflow])
         return y.reshape(batch, seq, d), counters

@@ -6,13 +6,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from easydl_tpu.core import sharding as shd
 from easydl_tpu.core.mesh import MeshSpec
 from easydl_tpu.core.train_loop import TrainConfig, Trainer
 from easydl_tpu.models.registry import get_model
-from easydl_tpu.ops.moe import (COUNTERS, MoeMlp, route, routed_experts,
-                                rows_bound)
+from easydl_tpu.ops.moe import (COUNTERS, MoeMlp, piece_rows, route,
+                                routed_experts, rows_bound)
 
 
 def test_routing_invariants():
@@ -49,15 +50,165 @@ def test_routing_drops_nothing():
     w_gate = jax.random.normal(ks[0], (held, d, f))
     w_up = jax.random.normal(ks[1], (held, d, f))
     w_down = jax.random.normal(ks[2], (held, f, d))
-    y, stats = routed_experts(h, chosen, weights, w_gate, w_up, w_down, 0)
-    dropped, mine, largest = (float(x) for x in stats)
+    y, stats = routed_experts(h, chosen, weights, w_gate, w_up, w_down, 0, 8)
+    dropped, mine, _, largest = (float(x) for x in stats)
     assert (dropped, mine, largest) == (0.0, tokens, tokens)
     want = (jax.nn.silu(h @ w_gate[0]) * (h @ w_up[0])) @ w_down[0]
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5,
                                atol=2e-5)
     # a share that holds none of the chosen experts adds exactly nothing
-    y, stats = routed_experts(h, chosen, weights, w_gate, w_up, w_down, 4)
+    y, stats = routed_experts(h, chosen, weights, w_gate, w_up, w_down, 4, 8)
     assert not np.asarray(y).any() and float(stats[1]) == 0.0
+
+
+@pytest.mark.parametrize("tokens, k, held, total, want", [
+    (16384, 8, 32, 256, 32768),     # Laguna's cell: a quarter of the bound
+    (16384, 8, 256, 256, 131072),   # all experts held: the bound, one piece
+    (32, 2, 8, 8, 64),              # the tier-1 configurations likewise
+    (128, 2, 4, 16, 128),           # an ep shard's own: 4 of 16, half its bound
+    (1024, 4, 4, 32, 1024),         # four pieces to the bound
+    (1000, 4, 4, 32, 1024),         # whole row tiles
+    (32, 1, 4, 8, 32),              # never more than the bound
+])
+def test_piece_is_twice_the_expected_load(tokens, k, held, total, want):
+    assert piece_rows(tokens, k, held, total) == want
+    assert want <= rows_bound(tokens, k, held)
+
+
+def _held_experts(held, d=16, f=8):
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    return (jax.random.normal(ks[0], (held, d, f)),
+            jax.random.normal(ks[1], (held, d, f)),
+            jax.random.normal(ks[2], (held, f, d)))
+
+
+def _loop_over_experts(h, chosen, weights, w_gate, w_up, w_down):
+    """The routed result as a loop over the experts held (numbered from 0),
+    every token through every expert, float32."""
+    y = jnp.zeros_like(h)
+    for e in range(w_gate.shape[0]):
+        weight = jnp.where(chosen == e, weights, 0.0).sum(-1)
+        y = y + weight[:, None] * (
+            (jax.nn.silu(h @ w_gate[e]) * (h @ w_up[e])) @ w_down[e])
+    return y
+
+
+def _weighed(y):
+    """A scalar of ``y`` whose gradient differs from entry to entry."""
+    return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
+
+
+def _value_stats_grads(chosen, total, args):
+    """``(loss, stats, gradients by h, weights and the three expert
+    weights)`` of the layer's routed part under a fixed cotangent."""
+    def loss(h, weights, w_gate, w_up, w_down):
+        y, stats = routed_experts(h, chosen, weights, w_gate, w_up, w_down,
+                                  0, total)
+        return _weighed(y), stats
+
+    (value, stats), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+    return value, np.asarray(stats), grads
+
+
+def _landing(tokens, k, held, total, landed):
+    """``chosen [tokens, k]``: distinct experts a token, exactly ``landed``
+    choices on the ``held`` first experts, spread evenly over the tokens."""
+    chosen = held + (np.arange(tokens)[:, None] + np.arange(k)) % (total - held)
+    for i in range(landed):
+        chosen[i % tokens, i // tokens] = (i + i // tokens) % held
+    assert all(len(set(row)) == k for row in chosen)
+    assert (chosen < held).sum() == landed
+    return jnp.asarray(chosen, jnp.int32)
+
+
+# 1,024 tokens, 4 choices, 4 of 32 experts held: pieces of 1,024 rows, a
+# bound of 4,096
+@pytest.mark.parametrize("landed, pieces", [
+    (0, 0), (1024, 1), (1025, 2), (1700, 2), (4096, 4)])
+def test_pieces_match_one_piece_and_a_loop_over_experts(landed, pieces):
+    """Whatever lands on the experts held — nothing, a piece exactly, one
+    row more, every choice of every token (four pieces) — has a row, and the
+    result and every gradient are those of the one-piece program (all
+    experts held: the piece is the bound) on the same rows, and of a loop
+    over the experts."""
+    tokens, k, held, total = 1024, 4, 4, 32
+    piece = piece_rows(tokens, k, held, total)
+    chosen = _landing(tokens, k, held, total, landed)
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    args = (jax.random.normal(ks[0], (tokens, 16)),
+            jax.random.uniform(ks[1], (tokens, k)), *_held_experts(held))
+    value, stats, grads = _value_stats_grads(chosen, total, args)
+    dropped, mine, overflow, largest = stats
+    assert (dropped, mine) == (0.0, landed)
+    assert overflow == float(pieces > 1) and -(-landed // piece) == pieces
+    assert largest == np.bincount(np.asarray(chosen).ravel(),
+                                  minlength=held)[:held].max()
+
+    # choices elsewhere are no expert of the one-piece program's (-1)
+    whole, stats_whole, grads_whole = _value_stats_grads(
+        jnp.where(chosen < held, chosen, -1), held, args)
+    assert piece_rows(tokens, k, held, held) == rows_bound(tokens, k, held)
+    assert tuple(stats_whole[:3]) == (0.0, landed, 0.0)
+    want, grads_want = jax.value_and_grad(
+        lambda *a: _weighed(_loop_over_experts(a[0], chosen, *a[1:])),
+        argnums=(0, 1, 2, 3, 4))(*args)
+    for got, one_piece, looped in zip((value, *grads), (whole, *grads_whole),
+                                      (want, *grads_want)):
+        scale = max(float(jnp.abs(looped).max()), 1.0)
+        np.testing.assert_allclose(got, one_piece, rtol=0, atol=2e-6 * scale)
+        np.testing.assert_allclose(got, looped, rtol=0, atol=2e-6 * scale)
+
+
+@pytest.mark.parametrize("tokens, k, rows, landed", [
+    (256, 4, 512, 300),    # two blocks of tokens, four chunks of rows
+    (200, 4, 300, 300),    # neither whole blocks nor whole chunks; all live
+    (24, 2, 40, 0),        # nothing landed: zeros are written
+    (640, 8, 128, 128),    # more blocks than chunks: empty blocks
+])
+def test_rows_to_tokens_is_the_float32_scatter_add(tokens, k, rows, landed):
+    """The kernel that sums a piece's rows into their tokens' rows against
+    ``.at[].add`` in float32; what dead rows hold (here NaN) adds nothing."""
+    from easydl_tpu.ops.moe import _to_tokens
+
+    rng = np.random.default_rng(0)
+    choice = rng.permutation(tokens * k)[:rows].astype(np.int32)
+    live = np.arange(rows) < landed
+    data = rng.standard_normal((rows, 16)).astype(np.float32)
+    scale = rng.random(rows).astype(np.float32)
+    # the rows by token: a landed choice's number sorts them, dead rows last
+    by_token = np.argsort(np.where(live, choice, tokens * k), kind="stable")
+    got = jax.jit(_to_tokens, static_argnums=(3, 4, 5))(
+        jnp.where(live[:, None], data, jnp.nan), scale[by_token],
+        (by_token.astype(np.int32), choice[by_token], jnp.int32(landed)),
+        tokens, k, True)
+    want = np.zeros((tokens, 16), np.float32)
+    np.add.at(want, choice[live] // k, scale[live, None] * data[live])
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_sums_back_to_the_tokens_by_landed_choices():
+    """``d_weights`` and ``dh`` come from the rows' side: a token with no
+    landed choice gets exact zeros, a choice elsewhere a zero weight
+    gradient, tokens with one and three landed choices the loop's."""
+    held, total = 4, 32
+    chosen = jnp.asarray([[9, 17, 30, 4],     # none of the four held
+                          [9, 2, 30, 4],      # one
+                          [3, 17, 0, 1]],     # three
+                         jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    args = (jax.random.normal(ks[0], (3, 16)),
+            jax.random.uniform(ks[1], (3, 4)), *_held_experts(held))
+    _, stats, (d_h, d_weights, *_) = _value_stats_grads(chosen, total, args)
+    assert tuple(stats) == (0.0, 4.0, 0.0, 1.0)
+    d_h_want, d_weights_want = jax.grad(
+        lambda *a: _weighed(_loop_over_experts(a[0], chosen, *a[1:])),
+        argnums=(0, 1))(*args)
+    assert not np.asarray(d_h[0]).any()
+    assert not np.asarray(d_weights)[np.asarray(chosen) >= held].any()
+    np.testing.assert_allclose(d_h, d_h_want, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(d_weights, d_weights_want, rtol=1e-5, atol=1e-4)
 
 
 def test_moe_mlp_forward_and_grads():

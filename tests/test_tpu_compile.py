@@ -549,26 +549,40 @@ def test_lagunas_attention_kinds_compile_at_8k(v5e_2x2, heads, window, rot,
     assert not any(f"/{other}/" in line for line in calls)
 
 
-def test_the_expert_layers_grouped_products_compile_as_kernels(v5e_2x2):
-    """The routed experts at the cell's size (16,384 tokens, top-8, 32
-    experts of 512 held: a 131,072-row buffer), forward and backward: the
-    grouped products are Mosaic kernels of the compiler's own, nine of
-    them."""
-    from easydl_tpu.ops.moe import routed_experts
+def test_the_expert_layers_kernels_compile_at_the_cells_size(v5e_2x2,
+                                                            monkeypatch):
+    """The routed experts at the cell's size (16,384 tokens, top-8, 32 of
+    256 experts of 512 held: pieces of 32,768 rows, a quarter of the bound),
+    value and gradients. The grouped products are Mosaic kernels of the
+    compiler's own — three forward, nine backward (the piece's products made
+    again, and six of the gradients'), for the first piece and again in the
+    loop over the others, less what the compiler shares between the first
+    piece's two passes — and the sums back to the tokens are ours,
+    ``rows_to_tokens``, one a piece and pass; nothing has the bound's
+    131,072 rows."""
+    from easydl_tpu.ops import moe
 
+    # jax.devices() is the CPU here: the kernel as the chip compiles it
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
     one = SingleDeviceSharding(v5e_2x2[0])
 
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     def loss(h, weights, w_gate, w_up, w_down, chosen):
-        y, _ = routed_experts(h, chosen, weights, w_gate, w_up, w_down, 0)
+        y, _ = moe.routed_experts(h, chosen, weights, w_gate, w_up, w_down,
+                                  0, 256)
         return y.astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+                       ).lower(
         s((16384, 2048)), s((16384, 8), jnp.float32), s((32, 2048, 512)),
         s((32, 2048, 512)), s((32, 512, 2048)),
         s((16384, 8), jnp.int32)).compile()
-    products = [c for c in _mosaic_calls(compiled)
-                if c.startswith("bf16[") or c.startswith("f32[")]
-    assert len(products) == 9, _mosaic_calls(compiled)
+    calls = _mosaic_calls(compiled)
+    sums = [c for c in calls if c.startswith("f32[16384,2048]")]
+    products = [c for c in calls if c.startswith("bf16[")]
+    assert len(sums) == 4 and 18 <= len(products) <= 24, calls
+    assert all(c.startswith(("bf16[32768,", "bf16[32,")) for c in products)
+    assert compiled.as_text().count("rows_to_tokens/pallas_call") >= 4
+    assert "131072" not in "".join(calls)
