@@ -218,7 +218,17 @@ class CheckpointManager:
         # deferred until :meth:`finalize` (or :meth:`wait`) is called from
         # the training loop at a later step boundary.
         self._pending_commit = None
+        self._in_flight = False
         self.storage.makedirs("")
+
+    @property
+    def in_flight(self) -> bool:
+        """An asynchronous save has returned and is not committed yet: true
+        from ``save()``'s return until its ``ckpt_committed`` (or until its
+        failure is known). The steps a loop runs meanwhile share the host
+        with the chunk writer; the elastic worker stamps each step record
+        with this (``commit_in_flight``)."""
+        return self._in_flight
 
     # ------------------------------------------------------------------ save
     def save(self, step: int, state: Any, metadata: Optional[Dict[str, Any]] = None) -> None:
@@ -382,6 +392,7 @@ class CheckpointManager:
                 storage.write_bytes(f"{step_dir}/{_COMMITTED}", str(step).encode())
             log.info("saved step %d in %.2fs -> %s/%s",
                      step, time.perf_counter() - t0, self.directory, step_dir)
+            self._in_flight = False
             self._event("ckpt_committed", step=step,
                         seconds=time.perf_counter() - t_enter)
             self._gc()
@@ -399,9 +410,11 @@ class CheckpointManager:
                         commit()
                 except BaseException as e:  # surfaced on next wait()/save()
                     self._error = e
+                    self._in_flight = False
 
             if multiproc:
                 self._pending_commit = commit
+            self._in_flight = True
             self._thread = threading.Thread(target=run_io, daemon=True)
             self._thread.start()
         else:
@@ -450,6 +463,7 @@ class CheckpointManager:
             )
             if int(states.max()) == 2:
                 self._pending_commit = None
+                self._in_flight = False
                 if self._error is not None:
                     err, self._error = self._error, None
                     raise RuntimeError(

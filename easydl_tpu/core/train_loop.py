@@ -127,6 +127,10 @@ class Trainer:
         self._step_fn = None
         self._abstract: Any = None
         self._host_step = 0  # train_step calls so far (trace annotation)
+        #: the last ``train_step`` call's host side, seconds: placing the
+        #: batch (``shard_s``) and handing the step to the runtime
+        #: (``dispatch_s``); overwritten by every call
+        self.host_seconds: Dict[str, float] = {}
 
     # ------------------------------------------------------------------ init
     def _abstract_state(self) -> TrainState:
@@ -289,13 +293,18 @@ class Trainer:
         """One step from a host batch. The annotations cost nothing without
         a profiler session; inside one they are host spans on the device
         trace's own clock. The step number is this Trainer's count of calls
-        — never a fetch from the device."""
+        — never a fetch from the device. The two inner spans' lengths stay
+        on ``host_seconds`` for the caller's own record."""
+        t0 = time.perf_counter()
         with jax.profiler.StepTraceAnnotation("train_step",
                                               step_num=self._host_step):
             with jax.profiler.TraceAnnotation("easydl/shard_batch"):
                 batch = self.shard_batch(host_batch)
+            t1 = time.perf_counter()
             with jax.profiler.TraceAnnotation("easydl/dispatch"):
                 out = self.step_fn(state, batch)
+        self.host_seconds["shard_s"] = t1 - t0
+        self.host_seconds["dispatch_s"] = time.perf_counter() - t1
         self._host_step += 1
         return out
 

@@ -265,6 +265,33 @@ class Agent:
             return True
         return False
 
+    def profile_worker(self, steps: int = 4,
+                       logdir: Optional[str] = None) -> bool:
+        """Ask the live worker for a profiler trace of its next ``steps``
+        steps (``utils/profiling.RequestedProfile``): the request file
+        ``<workdir>/profile-<agent>.json``, written whole, then SIGUSR2. The
+        window opens at the worker's next step boundary; the timeline says
+        where it landed (``profile_started``, ``profile_written``).
+        ``logdir`` defaults to ``<workdir>/profile/gen<g>-step<s>``. Returns
+        False, and does nothing, unless the worker has recorded a step of
+        its generation: before that there is no step loop to profile, and a
+        process that has not installed its handlers yet dies of the
+        signal."""
+        if not (self._proc and self._proc.poll() is None):
+            return False
+        recorded = int(self._read_metrics().get("generation", -1))
+        if recorded != self._applied_key[0]:
+            return False
+        request: Dict[str, Any] = {"steps": int(steps)}
+        if logdir:
+            request["dir"] = logdir
+        path = os.path.join(self.workdir, f"profile-{self.agent_id}.json")
+        with open(path + ".tmp", "w") as f:
+            json.dump(request, f)
+        os.replace(path + ".tmp", path)
+        os.kill(self._proc.pid, signal.SIGUSR2)
+        return True
+
     @property
     def worker_pid(self) -> Optional[int]:
         return self._proc.pid if self._proc and self._proc.poll() is None else None

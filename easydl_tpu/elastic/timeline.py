@@ -67,9 +67,11 @@ def remove_listener(fn: Callable[[str, Dict[str, Any]], None]) -> None:
             pass
 
 
-def emit(path: str | None, phase: str, generation: int, **data: Any) -> None:
+def emit(path: str | None, phase: str, generation: int, /,
+         **data: Any) -> None:
     """Append one phase boundary; never raises (timing is best-effort and
-    must not take down a worker)."""
+    must not take down a worker). The three arguments are positional only:
+    a phase's data may use their names (``profile_written`` has a ``path``)."""
     if not path:
         return
     rec = {"t": time.time(), "phase": phase, "gen": int(generation), **data}

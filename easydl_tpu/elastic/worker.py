@@ -5,6 +5,8 @@ Lifecycle: join the jax.distributed group for this generation → build mesh
 over the (new) world → restore the latest committed checkpoint with
 resharding → train, appending step metrics for the agent → on SIGUSR1
 (quiesce) reach a step-boundary consensus with peers, checkpoint, exit 0.
+On SIGUSR2 profile the next few steps (``utils/profiling.RequestedProfile``;
+docs/operations.md "Asking a running job for a profile").
 
 The quiesce consensus matters: SIGUSR1 lands on different hosts at slightly
 different times, but the checkpoint save is a collective — all ranks must
@@ -29,7 +31,7 @@ import os
 import signal
 import sys
 import time
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import numpy as np
 from easydl_tpu.obs.errors import count_swallowed
@@ -41,6 +43,37 @@ _QUIESCE = {"flag": False}
 
 def _on_sigusr1(signum, frame) -> None:
     _QUIESCE["flag"] = True
+
+
+_PROFILE = {"flag": False}
+
+
+def _on_sigusr2(signum, frame) -> None:
+    _PROFILE["flag"] = True
+
+
+def since_exec_s() -> Optional[float]:
+    """Seconds since the kernel made this process (its fork; the exec, the
+    interpreter's start and the imports so far are in it), from
+    ``/proc/self/stat`` against ``/proc/uptime``, to the 10 ms both keep;
+    None where there is no ``/proc``."""
+    try:
+        with open("/proc/self/stat") as f:
+            # after the command's ")": field 3 first, starttime is field 22
+            started_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - started_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def scalars_of(metrics: Dict[str, Any]) -> Dict[str, Any]:
+    """The entries of a step's ``metrics`` that are one number each (the
+    loss, ``grad_norm``, ``perplexity``, whatever counters the family's
+    objective returns); arrays and nested groups stay where they are."""
+    return {k: v for k, v in metrics.items()
+            if isinstance(v, (int, float)) or getattr(v, "shape", None) == ()}
 
 
 def consensus_interval(target_s: float, step_time_s: float,
@@ -92,6 +125,9 @@ def run_worker(env: Dict[str, str]) -> int:
     # jax import / distributed init must set the flag, not kill the process
     # (default SIGUSR1 disposition is terminate).
     signal.signal(signal.SIGUSR1, _on_sigusr1)
+    # The same for a profile request (SIGUSR2): kept until the first step
+    # boundary, where the window opens.
+    signal.signal(signal.SIGUSR2, _on_sigusr2)
     # Orphan-defense baseline, captured BEFORE the slow startup (jax
     # import, dist init, compile): an agent death during that window —
     # the most likely moment for a harness kill — already reparents this
@@ -117,7 +153,11 @@ def run_worker(env: Dict[str, str]) -> int:
     # Phase boundaries for the recovery decomposition (timeline.py): for a
     # warm-promoted standby this "start" is the promote instant, so the
     # imports phase collapses to ~0 — exactly the saving warm start buys.
-    timeline.emit(tl_path, "worker_main_start", generation, rank=rank)
+    # since_exec_s: the process's own age here — fork, exec, the
+    # interpreter and the imports above (a promoted standby's: its wait).
+    age = since_exec_s()
+    timeline.emit(tl_path, "worker_main_start", generation, rank=rank,
+                  **({} if age is None else {"since_exec_s": round(age, 3)}))
 
     # Trace root for this worker's whole life, parented on the master's
     # generation-switch context when the agent passed one
@@ -171,7 +211,12 @@ def run_worker(env: Dict[str, str]) -> int:
                   devices=devices)
     # Made before the Trainer so no compile escapes it; read at `restored`
     # and at the first step's end (first_step_done carries the difference).
-    from easydl_tpu.utils.profiling import CompileWatch
+    from easydl_tpu.utils.profiling import (
+        CompileWatch,
+        RequestedProfile,
+        host_span,
+        largest_programs,
+    )
 
     compile_watch = CompileWatch()
     from jax.experimental import multihost_utils
@@ -462,6 +507,7 @@ def run_worker(env: Dict[str, str]) -> int:
         log.info("gen %d: fresh init, world=%d (%d devices)", generation, world, devices)
     timeline.emit(tl_path, "restored", generation, rank=rank, step=start_step)
     compiled_at_restore = compile_watch.totals()
+    programs_at_restore = compile_watch.table()
     first_step_emitted = False
 
     total_steps = int(cfg.get("total_steps", 100))
@@ -560,7 +606,13 @@ def run_worker(env: Dict[str, str]) -> int:
         mfu_denom = devices * peak_flops_per_chip(device.device_kind)
     mesh_key_out = mesh_spec.key()
 
-    def append_metrics(step: int, loss: float, dt: float) -> None:
+    def append_metrics(step: int, loss: float, dt: float,
+                       inside: Dict[str, Any]) -> None:
+        """``inside``: the step seen from inside, beside what the record
+        always had — where ``dt`` went (``data_s``, ``shard_s``,
+        ``dispatch_s``, ``wait_s``, ``straggle_s`` under a chaos spec: they
+        sum to ``step_time_s``), ``gap_s`` before it, ``commit_in_flight``
+        and the step's ``counters``."""
         rate = (global_batch / dt) if dt > 0 else 0.0
         rec = {
             "step": step,
@@ -576,6 +628,7 @@ def run_worker(env: Dict[str, str]) -> int:
             # 8 decimals: the compile step's MFU is ~1e-5 and a 6-decimal
             # round quantizes it to a flat 0.0
             rec["mfu"] = round(rate * flops_per_sample / mfu_denom, 8)
+        rec.update(inside)
         with open(metrics_path, "a") as f:
             f.write(json.dumps(rec) + "\n")
 
@@ -589,105 +642,157 @@ def run_worker(env: Dict[str, str]) -> int:
     if chaos_armed:
         from easydl_tpu.chaos.injectors import maybe_straggle
 
+    def _profile_event(name: str, **data: Any) -> None:
+        # unrounded: profile_started's t is the trace's clock mark
+        timeline.emit(tl_path, name, generation, rank=rank, **data)
+
+    # SIGUSR2's side: a window opens at the step boundary after the signal
+    # and closes itself; a request file beside the workdir's others may
+    # name its length and directory (Agent.profile_worker writes it).
+    profile = RequestedProfile(
+        os.path.join(workdir, f"profile-{agent_id}.json"),
+        lambda s: os.path.join(workdir, "profile",
+                               f"gen{generation}-step{s}"),
+        _profile_event)
     step = start_step
-    while step < total_steps:
-        if os.getppid() != parent_pid:
-            log.warning("gen %d: agent (parent) died; worker exiting at "
-                        "step %d", generation, step)
-            root_span.end(outcome="orphaned", step=step)
-            return 4
-        # Quiesce consensus at the step boundary. Multi-process workers may
-        # only act on the *agreed* flag (acting on the local flag alone would
-        # leave peers hanging in the next collective).
-        want_quiesce = _QUIESCE["flag"]
-        if world > 1:
-            due = (step % sync_every == 0) if sync_every > 0 \
-                else (step >= next_sync)
-            if due:
-                # Flag and local step-time EMA ride one allgather; in auto
-                # mode every rank derives the next consensus step from the
-                # same reduced (max) step time, keeping the schedule agreed.
-                flags = np.asarray(multihost_utils.process_allgather(
-                    np.asarray([1.0 if want_quiesce else 0.0, ema_dt],
-                               np.float64)
-                )).reshape(world, 2)
-                want_quiesce = bool(flags[:, 0].sum() > 0)
-                agreed_dt = float(flags[:, 1].max())
-                if sync_every <= 0:
-                    next_sync = step + consensus_interval(
-                        sync_target_s, agreed_dt)
-            else:
-                want_quiesce = False
-        if want_quiesce:
-            # From here on a LATE SIGUSR1 must be inert: the consensus can
-            # quiesce this rank off a PEER's flag before its own agent's
-            # signal arrives, and a signal landing during interpreter
-            # teardown kills the process with -SIGUSR1 — which the agent
-            # then reports as a crash and the master escalates into a
-            # spurious KILL drain (observed live; the checkpoint had
-            # landed, so only the reporting was wrong).
-            signal.signal(signal.SIGUSR1, signal.SIG_IGN)
-            log.info("gen %d: quiescing at step %d", generation, step)
-            timeline.emit(tl_path, "quiesce_ckpt_begin", generation, step=step)
-            ps_save(step)
-            ckpt.save(step, state, metadata=_data_meta())  # no-op if already committed
-            ckpt.wait()  # commit must land before this process exits
-            timeline.emit(tl_path, "quiesce_exit", generation, step=step)
-            root_span.end(outcome="quiesced", step=step)
-            return 0
+    fetched_at = None  # perf_counter when the last step's numbers arrived
+    try:
+        while step < total_steps:
+            if os.getppid() != parent_pid:
+                log.warning("gen %d: agent (parent) died; worker exiting at "
+                            "step %d", generation, step)
+                root_span.end(outcome="orphaned", step=step)
+                return 4
+            # Quiesce consensus at the step boundary. Multi-process workers
+            # may only act on the *agreed* flag (acting on the local flag
+            # alone would leave peers hanging in the next collective).
+            want_quiesce = _QUIESCE["flag"]
+            if world > 1:
+                due = (step % sync_every == 0) if sync_every > 0 \
+                    else (step >= next_sync)
+                if due:
+                    # Flag and local step-time EMA ride one allgather; in
+                    # auto mode every rank derives the next consensus step
+                    # from the same reduced (max) step time, keeping the
+                    # schedule agreed.
+                    flags = np.asarray(multihost_utils.process_allgather(
+                        np.asarray([1.0 if want_quiesce else 0.0, ema_dt],
+                                   np.float64)
+                    )).reshape(world, 2)
+                    want_quiesce = bool(flags[:, 0].sum() > 0)
+                    agreed_dt = float(flags[:, 1].max())
+                    if sync_every <= 0:
+                        next_sync = step + consensus_interval(
+                            sync_target_s, agreed_dt)
+                else:
+                    want_quiesce = False
+            if want_quiesce:
+                # From here on a LATE SIGUSR1 must be inert: the consensus
+                # can quiesce this rank off a PEER's flag before its own
+                # agent's signal arrives, and a signal landing during
+                # interpreter teardown kills the process with -SIGUSR1 —
+                # which the agent then reports as a crash and the master
+                # escalates into a spurious KILL drain (observed live; the
+                # checkpoint had landed, so only the reporting was wrong).
+                # A profile request from here on is inert as well.
+                signal.signal(signal.SIGUSR1, signal.SIG_IGN)
+                signal.signal(signal.SIGUSR2, signal.SIG_IGN)
+                log.info("gen %d: quiescing at step %d", generation, step)
+                timeline.emit(tl_path, "quiesce_ckpt_begin", generation,
+                              step=step)
+                ps_save(step)
+                # no-op if already committed
+                ckpt.save(step, state, metadata=_data_meta())
+                ckpt.wait()  # commit must land before this process exits
+                timeline.emit(tl_path, "quiesce_exit", generation, step=step)
+                root_span.end(outcome="quiesced", step=step)
+                return 0
+            if _PROFILE["flag"]:
+                _PROFILE["flag"] = False
+                profile.start(step)
 
-        t0 = time.perf_counter()
-        if maybe_straggle is not None:
-            # Chaos hook point: artificial straggler sleep, INSIDE the
-            # timed window — a simulated slow host must look slow in the
-            # step metrics (the skew detector's signal), exactly as a
-            # thermally-throttled chip would. Placed after the quiesce
-            # check so a draining worker exits promptly regardless.
-            maybe_straggle(rank, agent=agent_id)
-        state, metrics = trainer.train_step(state, next(data))
-        loss = float(metrics["loss"])  # blocks: real step time
-        dt = time.perf_counter() - t0
-        # EMA over recent steps (first step = compile; seed with it anyway —
-        # the schedule self-corrects at the next consensus)
-        ema_dt = dt if ema_dt == 0.0 else 0.8 * ema_dt + 0.2 * dt
-        step += 1
-        append_metrics(step, loss, dt)
-        if step % trace_step_every == 0:
-            # Sampled per-step span, written retroactively from the timing
-            # the loop already took — tracing adds no step-path work.
-            t_end = time.time()
-            tracing.record_span("step", t_end - dt, t_end,
-                                parent=root_span, step=step,
-                                loss=round(loss, 5))
-        if not first_step_emitted:
-            # restored -> here = jit compile (or cache hit) + one step;
-            # the compile counters since `restored` say which: tracing,
-            # lowering, the backend (cache fetch included), hits, misses.
-            timeline.emit(tl_path, "first_step_done", generation,
-                          rank=rank, step=step, step_time_s=round(dt, 3),
-                          **compile_watch.since(compiled_at_restore))
-            first_step_emitted = True
+            t0 = time.perf_counter()
+            # gap_s: what the loop did between the last step's numbers and
+            # this step — the record, a save's call, finalize, the checks
+            # above. commit_in_flight: a save's chunks were still being
+            # written beside this step when it began.
+            inside: Dict[str, Any] = {"commit_in_flight": ckpt.in_flight}
+            if fetched_at is not None:
+                inside["gap_s"] = t0 - fetched_at
+            if maybe_straggle is not None:
+                # Chaos hook point: artificial straggler sleep, INSIDE the
+                # timed window — a simulated slow host must look slow in the
+                # step metrics (the skew detector's signal), exactly as a
+                # thermally-throttled chip would. Placed after the quiesce
+                # check so a draining worker exits promptly regardless.
+                maybe_straggle(rank, agent=agent_id)
+                inside["straggle_s"] = time.perf_counter() - t0
+            with host_span("easydl/next_batch") as input_wait:
+                batch = next(data)
+            state, metrics = trainer.train_step(state, batch)
+            # The loss and every other number of the step in ONE fetch: they
+            # are results of one program, ready at the same instant.
+            with host_span("easydl/fetch_loss") as device_wait:
+                counters = jax.device_get(scalars_of(metrics))
+            loss = float(counters.pop("loss"))  # blocked: real step time
+            fetched_at = time.perf_counter()
+            dt = fetched_at - t0
+            # EMA over recent steps (first step = compile; seed with it
+            # anyway — the schedule self-corrects at the next consensus)
+            ema_dt = dt if ema_dt == 0.0 else 0.8 * ema_dt + 0.2 * dt
+            step += 1
+            with host_span("easydl/record"):
+                inside.update(
+                    data_s=input_wait.seconds, **trainer.host_seconds,
+                    wait_s=device_wait.seconds,
+                    counters={k: float(v) for k, v in counters.items()})
+                append_metrics(step, loss, dt, inside)
+                if step % trace_step_every == 0:
+                    # Sampled per-step span, written retroactively from the
+                    # timing the loop already took — tracing adds no
+                    # step-path work.
+                    t_end = time.time()
+                    tracing.record_span("step", t_end - dt, t_end,
+                                        parent=root_span, step=step,
+                                        loss=round(loss, 5))
+                if not first_step_emitted:
+                    # restored -> here = jit compile (or cache hit) + one
+                    # step; the compile counters since `restored` say which:
+                    # tracing, lowering, the backend (cache fetch included),
+                    # hits, misses — and `programs` by which program.
+                    timeline.emit(
+                        tl_path, "first_step_done", generation,
+                        rank=rank, step=step, step_time_s=round(dt, 3),
+                        **compile_watch.since(compiled_at_restore),
+                        **largest_programs(
+                            compile_watch.table_since(programs_at_restore)))
+                    first_step_emitted = True
 
-        # Auto cadence computes next_ckpt from values every rank shares
-        # (same agreed_dt from the same consensus allgather, same step) —
-        # so save_due is identical across ranks without any extra
-        # collective. Single-process runs substitute the local EMA
-        # (nothing to agree with).
-        if ckpt_interval == 0 and world == 1:
-            agreed_dt = ema_dt
-        save_due, next_ckpt = periodic_ckpt_due(
-            ckpt_interval, step, next_ckpt, ckpt_target_s, agreed_dt)
-        if save_due and step < total_steps:
-            ps_save(step)
-            ckpt.save(step, state, metadata=_data_meta())
-        # Complete any deferred multi-process commit once every rank's chunk
-        # IO is done (collective agreement; barriers on this main thread).
-        ckpt.finalize()
+                # Auto cadence computes next_ckpt from values every rank
+                # shares (same agreed_dt from the same consensus allgather,
+                # same step) — so save_due is identical across ranks without
+                # any extra collective. Single-process runs substitute the
+                # local EMA (nothing to agree with).
+                if ckpt_interval == 0 and world == 1:
+                    agreed_dt = ema_dt
+                save_due, next_ckpt = periodic_ckpt_due(
+                    ckpt_interval, step, next_ckpt, ckpt_target_s, agreed_dt)
+                if save_due and step < total_steps:
+                    ps_save(step)
+                    ckpt.save(step, state, metadata=_data_meta())
+                # Complete any deferred multi-process commit once every
+                # rank's chunk IO is done (collective agreement; barriers on
+                # this main thread).
+                ckpt.finalize()
+            profile.step_done(step)
+    finally:
+        profile.close()  # a worker that leaves inside a window ends it
 
     # Same late-signal shield for the completion path: a quiesce landing
     # between the final save and process exit must not turn a finished
     # worker into a reported crash.
     signal.signal(signal.SIGUSR1, signal.SIG_IGN)
+    signal.signal(signal.SIGUSR2, signal.SIG_IGN)
     ps_save(total_steps)
     ckpt.save(total_steps, state, metadata=_data_meta())
     ckpt.wait()
@@ -753,8 +858,9 @@ def main() -> None:
     warm_file = knob_raw("EASYDL_WARM_FILE", env=env)
     if warm_file:
         # Install the quiesce handler before the long import (same reason
-        # as run_worker's first line).
+        # as run_worker's first lines), and the profile request's.
         signal.signal(signal.SIGUSR1, _on_sigusr1)
+        signal.signal(signal.SIGUSR2, _on_sigusr2)
         env.update(_warm_wait(warm_file))
     sys.exit(run_worker(env))
 

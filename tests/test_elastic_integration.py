@@ -259,15 +259,20 @@ def test_elastic_worker_with_ps_embedding(workdir):
 def test_elastic_worker_with_pipeline_mesh(workdir):
     """A pp axis in the job's mesh config turns on the GPipe schedule
     inside the elastic worker (the pipeline_fn is rebuilt per generation,
-    like the mesh): one agent, 4 devices, pp=2 x dp=2, trains to DONE."""
+    like the mesh): one agent, 4 devices, pp=2 x dp=2, trains to DONE.
+
+    The same job, asked for a profile while it runs
+    (``Agent.profile_worker`` -> request file + SIGUSR2 -> the worker's
+    window): the timeline says where the trace landed, its host plane holds
+    the loop's spans, and every record carries the step's inside."""
     cfg = {
         "model": "gpt",
         "model_kwargs": {"size": "test", "seq_len": 32, "vocab": 256},
         "mesh": {"pp": 2},
         "pp_microbatches": 2,
         "global_batch": 8,
-        "total_steps": 6,
-        "ckpt_interval": 3,
+        "total_steps": 30,
+        "ckpt_interval": 15,
         "lr": 1e-3,
         "seed": 0,
     }
@@ -275,10 +280,38 @@ def test_elastic_worker_with_pipeline_mesh(workdir):
                     min_workers=1, worker_config=cfg).start()
     agent = Agent("a0", master.address, workdir, slots=4).start()
     try:
+        assert agent.profile_worker(2) is False  # no step on record yet
+        wait_for(lambda: read_metrics(workdir, "a0"), interval=0.02,
+                 desc="the first step record")
+        assert agent.profile_worker(2, logdir=os.path.join(workdir, "prof"))
         assert master.wait_done(timeout=240), f"no finish: {master.status()}"
         m0 = read_metrics(workdir, "a0")
-        assert m0 and m0[-1]["step"] == 6
+        assert m0 and m0[-1]["step"] == 30
         assert all(r["loss"] == r["loss"] for r in m0)  # finite
+        for r in m0:
+            inside = sum(r[f] for f in ("data_s", "shard_s", "dispatch_s",
+                                        "wait_s"))
+            assert 0 < inside <= r["step_time_s"]
+            assert {"grad_norm", "perplexity"} <= set(r["counters"])
+            assert isinstance(r["commit_in_flight"], bool)
+        assert all(r["gap_s"] >= 0 for r in m0[1:])
+        from easydl_tpu.elastic import timeline
+
+        events = timeline.read(os.path.join(workdir, "timeline-a0.jsonl"))
+        (started,) = [e for e in events if e["phase"] == "profile_started"]
+        (written,) = [e for e in events if e["phase"] == "profile_written"]
+        assert started["steps"] == 2 and 1 <= started["step"] < 28
+        assert written["first_step"] == started["step"] + 1
+        assert written["last_step"] == started["step"] + 2
+        assert written["path"].startswith(os.path.join(workdir, "prof"))
+        assert os.path.getsize(written["path"]) == written["bytes"] > 0
+        from jax.profiler import ProfileData
+
+        names = {e.name for plane in ProfileData.from_file(
+            written["path"]).planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events}
+        assert {"easydl/clock", "easydl/next_batch", "easydl/fetch_loss",
+                "easydl/record", "easydl/dispatch", "train_step"} <= names
     finally:
         agent.stop()
         master.stop()
