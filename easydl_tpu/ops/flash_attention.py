@@ -60,6 +60,30 @@ Python number, dead pairs are never emitted and both loops unroll into
 straight-line code that the compiler schedules across pairs. Longer
 sequences take one Q-block (K-block) per grid cell and loop at run time.
 
+The band path. Under a ``window`` of at most a block's keys on a square
+problem (``s_q == s_k``: a training step's window layers) a block meets only
+its neighbour, and the three kernels have bodies of their own
+(``_band_fwd_kernel``, ``_band_dq_kernel``, ``_band_dkv_kernel``; the rule
+is :func:`choose_blocks`', from ``window``, ``s_q``, ``s_k`` alone; a wider
+window or a rectangle keeps the loops above). A grid cell takes ``rows``
+rows — Q rows in the forward and dq, K rows in dk/dv — and beside them only
+the ``reach`` rows the band reaches: the block before them of K and V, the
+block after them of q, O, dO and ``lse``. The neighbour is the same array
+handed to the call a second time under a block index of its own, clipped at
+the sequence's ends; no array is padded, shifted or copied in front of a
+kernel, and nothing else of the sequence is in VMEM. Inside a cell,
+sub-blocks of ``sub`` rows each take their band as a few static slices
+(``_band_pieces``): for 256 queries under window 512 the 768 keys ``[start
+− 512, start + 256)``, cut where a mask starts or stops being needed — the
+far edge crosses the first 256, the diagonal the last 256, the 256 between
+are multiplied as they are. No loop and no traced bound: the one cell
+without a neighbour (the first of a sequence, the last in dk/dv) holds the
+clipped block's scores under the sentinel with one ``minimum`` a piece. With
+a sub-block's whole band in hand the forward's softmax is one pass (max,
+``exp``, sum over the band's keys; no running max to correct, and every row
+sees its own key, so no row is dead); its mathematics, its float32 values
+and the two roundings are the looped kernels'.
+
 Layout. Public shapes are the models' ``[batch, seq, heads, head_dim]``; the
 kernels take q, k, v, O, dO and give O, dq, dk, dv as ``[batch, seq,
 heads·head_dim]`` — the same bytes in the same order, and the layout a
@@ -85,11 +109,13 @@ from __future__ import annotations
 import functools
 import itertools
 import math
-from typing import Optional, Tuple
+import operator
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from easydl_tpu.ops import remat
 from easydl_tpu.utils.logging import get_logger, log_once
@@ -121,6 +147,34 @@ MAX_BLOCK = 512
 #: (block_q, block_k) of the forward, the dq and the dk/dv kernel.
 Blocks = Tuple[Tuple[int, int], Tuple[int, int], Tuple[int, int]]
 
+
+class Band(NamedTuple):
+    """How one band kernel is cut (:func:`choose_blocks` gives three).
+    ``rows``: the rows a grid cell takes, Q rows in the forward and dq, K
+    rows in dk/dv. ``sub``: the rows of a sub-block inside it, whose band is
+    one fixed set of pieces. ``reach``: the rows of the one neighbour block
+    resident beside the cell's own (the block before it for K and V, the
+    block after it for q, O, dO and ``lse``); the window is at most that."""
+    rows: int
+    sub: int
+    reach: int
+
+
+#: the band path's cut of the forward, the dq and the dk/dv kernel.
+Bands = Tuple[Band, Band, Band]
+
+#: rows of a band kernel's grid cell and of a sub-block in it, when the
+#: caller names no blocks (the sweep in :func:`choose_blocks`)
+BAND_ROWS = 2048
+BAND_SUB = 256
+
+#: the VMEM a band kernel may take: its cell's blocks twice and the scores
+#: of the sub-blocks in flight — 9 MB for bf16 operands at 2,048 rows,
+#: within the compiler's own 16 MB, but 25 MB for float32 ones (a v5e's
+#: VMEM is 128 MB)
+_BAND_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
+
+
 #: dot_general dimension numbers: ``A Bᵀ`` (both contract their last
 #: dimension) and the plain ``A B``.
 _NT = (((1,), (1,)), ((), ()))
@@ -145,7 +199,8 @@ def _pick_block(s: int, target: int) -> Optional[int]:
 def choose_blocks(s_q: int, s_k: int, causal: bool,
                   block_q: Optional[int] = None,
                   block_k: Optional[int] = None,
-                  window: Optional[int] = None) -> Optional[Blocks]:
+                  window: Optional[int] = None
+                  ) -> Optional[Union[Blocks, Bands]]:
     """Block sizes per kernel from what the call can see; a caller's
     ``block_q`` / ``block_k`` hold for all three. None when a length has no
     block divisor: the kernels cannot tile it, and whoever chooses the
@@ -159,18 +214,44 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     still unroll. Without a triangle the smaller blocks have nothing to win.
 
     Under a ``window`` of at most a block's keys (a causal band: query i
-    sees the ``window`` keys up to its own) a Q-block meets only the two or
-    three K-blocks the band touches. Swept on a v5e at ``[2, 8192, 64 x
+    sees the ``window`` keys up to its own) on a square problem the three
+    kernels take the band path and the result is three :class:`Band` cuts
+    (``_band``): cells of ``BAND_ROWS`` rows (as many whole neighbour blocks
+    as divide the sequence, at most four), sub-blocks of ``BAND_SUB``, a
+    neighbour of ``MAX_BLOCK`` rows or the caller's ``block_k``. Swept on a
+    v5e at ``[2, 8192, 64 x 128]`` bf16, window 512, ms a call forward / dq
+    / dkv (PERF.md section 6, PR 34; the kernels' own events in a trace):
+
+        rows / sub   forward    dq     dkv
+        looped         6.70    7.66    9.49   (512 x 512, dkv 512 x 256)
+        2048 / 256     3.01    3.41    4.37   <- taken
+        2048 / 128     3.01    3.86    4.52
+        1024 / 256     3.53    3.61    4.55
+         512 / 256     4.25    4.29    5.17
+        2048 / 512     3.56    4.41    5.71
+
+    Sub-blocks of 256 compute 768 keys for the 512 a row sees (128: 640),
+    but a product of 128 rows costs the MXU more a FLOP than it saves;
+    larger cells spread a cell's start and end (the first sub-block's
+    scores and the last one's softmax overlap nothing) over more rows and
+    read a neighbour's K and V once for more of them.
+
+    Under a window wider than a block, or where ``s_q != s_k``, the looped
+    kernels walk the band's blocks. Swept on a v5e at ``[2, 8192, 64 x
     128]``, window 512 (PERF.md section 6, PR 31): the forward and dq are
     fastest at 512 x 512 like the plain kernels (7.6 ms a call against 10.8
     at 256 x 256: fewer, fuller block pairs win over the band's masked
     corners); dk/dv takes K-blocks of 256 (512 x 512 does not fit its VMEM
-    beside the whole sequence's q, O and dO, and 512 x 256 beats 256 x 256
-    by 1.5 ms)."""
+    beside the whole sequence's q, O and dO, which the looped kernel holds,
+    and 512 x 256 beats 256 x 256 by 1.5 ms)."""
     def pick(target, target_k=None):
         return (_pick_block(s_q, block_q or target),
                 _pick_block(s_k, block_k or target_k or target))
 
+    if window is not None and s_q == s_k:
+        band = _band(s_q, window, block_q, block_k)
+        if band is not None:
+            return band, band, band
     if window is not None and window <= MAX_BLOCK:
         big, banded = pick(MAX_BLOCK), pick(MAX_BLOCK, MAX_BLOCK // 2)
         if None not in big + banded:
@@ -183,6 +264,23 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
             s_q // small[0], s_k // small[1]):
         dkv = small
     return big, big, dkv
+
+
+def _band(s: int, window: int, block_q: Optional[int],
+          block_k: Optional[int]) -> Optional[Band]:
+    """The band path's cut of a square problem, or None where a block does
+    not hold the window (the looped kernels then walk the band's blocks). A
+    caller's ``block_k`` is the neighbour's rows (``reach``), its
+    ``block_q`` a sub-block's."""
+    reach = _pick_block(s, block_k or MAX_BLOCK)
+    if reach is None or window > reach:
+        return None
+    sub = _pick_block(reach, block_q or BAND_SUB)
+    if sub is None or (sub > 128 and sub % 128):
+        return None
+    cells = max(c for c in range(1, BAND_ROWS // MAX_BLOCK + 1)
+                if s // reach % c == 0)
+    return Band(cells * reach, sub, reach)
 
 
 def _dot(a, b, dims):
@@ -304,17 +402,19 @@ def _cell_heads(heads: int, head_dim: int, pairs: int, unroll: bool,
     return tile * max(g for g in range(1, most + 1) if heads // tile % g == 0)
 
 
-def _lse_operand(lse, cell: int, block_q: int, whole: bool):
+def _lse_operand(lse, cell: int, block_q: int, whole: bool,
+                 at=lambda i: i):
     """``(BlockSpec, operand)`` that hand a backward kernel ``lse`` (dense
     rows ``[B, H, S]``) as one ``[1, block_q]`` row a Q-block, ``[B, H,
     S // block_q, 1, block_q]``: every Q-block of the cell's heads
     (``whole``: an unrolled cell, and dkv's looped one, which walks them
-    all) or the grid cell's own (dq looped). The kernels read Q-block ``qb``
-    of head ``g`` as ``lse_ref[g, qb]``."""
+    all) or the grid cell's own (dq looped; the band kernels, whose Q-block
+    is a cell's rows or, through ``at``, its neighbour's). The kernels read
+    Q-block ``qb`` of head ``g`` as ``lse_ref[g, qb]``."""
     b, h, s = lse.shape
     blocks = s // block_q if whole else 1
     return pl.BlockSpec((None, cell, blocks, 1, block_q),
-                        lambda b, h, i: (b, h, 0 if whole else i, 0, 0)
+                        lambda b, h, i: (b, h, 0 if whole else at(i), 0, 0)
                         ), lse.reshape(b, h, s // block_q, 1, block_q)
 
 
@@ -326,6 +426,11 @@ def _delta_rows(do, o, d: int):
     prod_t = (do.astype(jnp.float32) * o.astype(jnp.float32)).T
     return [jnp.sum(prod_t[g * d:(g + 1) * d], axis=0, keepdims=True)
             for g in range(prod_t.shape[0] // d)]
+
+
+def _total(parts):
+    """The sum of a piece-wise product's parts."""
+    return functools.reduce(operator.add, parts)
 
 
 def _side_by_side(heads, axis: int):
@@ -634,6 +739,351 @@ def _bwd(
 
 
 # ---------------------------------------------------------------------------
+# the band path: a window of at most a block's keys, s_q == s_k
+# ---------------------------------------------------------------------------
+
+
+def _band_pieces(at: int, sub: int, window: int, edge: int, *, keys: bool):
+    """``[(start, stop, far, near)]``: the pieces of the other side's rows
+    that the sub-block ``[at, at + sub)`` meets under the band, counted from
+    the cell's first row — for a Q sub-block its keys (``keys``), ``[at −
+    window + 1, at + sub)``, for a K sub-block the queries ``[at, at + sub +
+    window − 1)``, both widened to whole 128-row tiles (to sub-blocks where
+    those are smaller). Cut at ``edge`` (where the
+    cell's own block ends and its neighbour starts) and where a mask starts
+    or stops being needed: ``far`` says that the band's far edge crosses the
+    piece, ``near`` that the diagonal does; a piece with neither is
+    multiplied as it is."""
+    align = min(sub, 128)
+    if keys:
+        lo, hi = (at - window + 1) // align * align, at + sub
+        cuts = (edge, -(-(at + sub - window) // align) * align, at)
+    else:
+        lo, hi = at, -(-(at + sub + window - 1) // align) * align
+        cuts = (edge, at + sub, (at + window) // align * align)
+    marks = sorted({lo, hi} | {c for c in cuts if lo < c < hi})
+    pieces = []
+    for start, stop in zip(marks, marks[1:]):
+        if keys:  # queries [at, at + sub), keys [start, stop)
+            far = start <= at + sub - 1 - window
+            near = stop - 1 > at
+        else:  # keys [at, at + sub), queries [start, stop)
+            far = stop - 1 - at >= window
+            near = at + sub - 1 > start
+        pieces.append((start, stop, far, near))
+    return pieces
+
+
+class _BandMasks:
+    """The masks of one band kernel's pieces, each made once a grid cell
+    (every sub-block's pieces have the same few shapes and edges)."""
+
+    def __init__(self, window: int):
+        self.window, self.made = window, {}
+
+    def __call__(self, st, bound: int, far: bool, near: bool):
+        """``st`` (``[keys, queries]``, the first key ``bound`` rows before
+        the first query) with what the crossing edges hide set to NEG_INF:
+        key j is seen by query i iff j − i <= bound (``near``: the diagonal)
+        and j − i > bound − window (``far``)."""
+        if not (far or near):
+            return st
+        key = (st.shape, bound, far, near)
+        if key not in self.made:
+            ahead = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+                     - jax.lax.broadcasted_iota(jnp.int32, st.shape, 1))
+            seen = ahead <= bound if near else None
+            if far:
+                inside = ahead > bound - self.window
+                seen = inside if seen is None else seen & inside
+            self.made[key] = seen
+        return jnp.where(self.made[key], st, NEG_INF)
+
+
+def _neighbour_cap(hidden):
+    """A ceiling for the scores of a neighbour block's pieces: the sentinel
+    in the one cell that has no such neighbour (the sequence's first cell in
+    the forward and dq, its last in dk/dv — the block the clipped index map
+    hands it instead holds real rows, so every product is finite), no
+    ceiling elsewhere. One ``minimum`` a piece; no second program."""
+    return jnp.where(hidden, NEG_INF, -NEG_INF)
+
+
+def _one_behind(starts, first, second):
+    """``second(at, first(at))`` for every sub-block, written so that a
+    sub-block's ``first`` half (its products into scores: the MXU's work)
+    stands in the program before the ``second`` half of the sub-block before
+    it (softmax and what follows: the VPU's): the compiler's scheduler then
+    runs them side by side, which it does not find by itself across a whole
+    sub-block (the forward's cell: 8,006 bundles as written in order, 7,165
+    one behind)."""
+    behind = None
+    for at in starts:
+        ahead = first(at)
+        if behind is not None:
+            second(*behind)
+        behind = (at, ahead)
+    second(*behind)
+
+
+def _band_fwd_kernel(
+    q_ref, k_ref, v_ref, k_prev_ref, v_prev_ref, o_ref, lse_ref,
+    *, head_dim: int, band: Band, window: int, scale: float,
+):
+    # q_ref, k_ref, v_ref, o_ref: [cell rows, cell heads · d]; k_prev_ref,
+    # v_prev_ref: [reach, cell heads · d], the block before the cell's own;
+    # lse_ref: [cell heads, cell rows, 1]
+    rows, lanes = q_ref.shape
+    d, sub, reach = head_dim, band.sub, band.reach
+    heads = _head_cols(lanes, d)
+    masks = _BandMasks(window)
+    cap = _neighbour_cap(pl.program_id(2) == 0)
+    # V turned once a cell for all its heads and sub-blocks
+    vt_own, vt_prev = v_ref[...].T, v_prev_ref[...].T
+
+    def scores(at):
+        mine = slice(at, at + sub)
+        pieces = _band_pieces(at, sub, window, 0, keys=True)
+        found = []
+        for g, cols in enumerate(heads):
+            q, s_scale = _fold_scale(q_ref[mine, cols], scale)
+            sts, vts = [], []
+            for start, stop, far, near in pieces:
+                ref_k, vt, base = ((k_prev_ref, vt_prev, reach) if start < 0
+                                   else (k_ref, vt_own, 0))
+                there = slice(base + start, base + stop)
+                vts.append(vt[cols, there])
+                st = masks(_scores_t(ref_k[there, cols], q, s_scale, None),
+                           at - start, far, near)
+                sts.append(jnp.minimum(st, cap) if start < 0 else st)
+            found.append((sts, vts))
+        return found
+
+    def softmax(found):
+        out = []
+        for sts, vts in found:
+            m = functools.reduce(jnp.maximum, [
+                jnp.max(st, axis=0, keepdims=True) for st in sts])
+            pts = [jnp.exp(st - m) for st in sts]
+            l = _total([jnp.sum(pt, axis=0, keepdims=True) for pt in pts])
+            out.append((m, l, pts, vts))
+        return out
+
+    def finish(at, found):
+        mine = slice(at, at + sub)
+        o_ts = []
+        for g, (m, l, pts, vts) in enumerate(found):
+            acc = _total([_dot(vt, pt.astype(vt.dtype), _NN)
+                          for vt, pt in zip(vts, pts)])
+            o_ts.append(acc / l)
+            lse = m + jnp.log(l)
+            lse_ref[g, mine, :] = jnp.broadcast_to(lse, (8, sub)).T[:, :1]
+        o_ref[mine, :] = _side_by_side(o_ts, 0).T.astype(o_ref.dtype)
+
+    _one_behind(range(0, rows, sub), scores,
+                lambda at, found: finish(at, softmax(found)))
+
+
+def _band_dq_kernel(
+    q_ref, k_ref, v_ref, k_prev_ref, v_prev_ref, o_ref, do_ref, lse_ref,
+    dq_ref, *, head_dim: int, band: Band, window: int, scale: float,
+):
+    # lse_ref: [cell heads, 1, 1, cell rows]
+    rows, lanes = q_ref.shape
+    d, sub, reach = head_dim, band.sub, band.reach
+    heads = _head_cols(lanes, d)
+    masks = _BandMasks(window)
+    cap = _neighbour_cap(pl.program_id(2) == 0)
+    # K turned once a cell for all its heads and sub-blocks
+    kt_own, kt_prev = k_ref[...].T, k_prev_ref[...].T
+
+    def products(at):
+        mine = slice(at, at + sub)
+        pieces = _band_pieces(at, sub, window, 0, keys=True)
+        found = []
+        for g, cols in enumerate(heads):
+            q, s_scale = _fold_scale(q_ref[mine, cols], scale)
+            do = do_ref[mine, cols]
+            parts = []
+            for start, stop, far, near in pieces:
+                ref_k, ref_v, kt, base = (
+                    (k_prev_ref, v_prev_ref, kt_prev, reach) if start < 0
+                    else (k_ref, v_ref, kt_own, 0))
+                there = slice(base + start, base + stop)
+                st = masks(_scores_t(ref_k[there, cols], q, s_scale, None),
+                           at - start, far, near)
+                if start < 0:
+                    st = jnp.minimum(st, cap)
+                parts.append((st, _dot(ref_v[there, cols], do, _NT),
+                              kt[cols, there]))
+            found.append(parts)
+        return found
+
+    def finish(at, found):
+        mine = slice(at, at + sub)
+        deltas = _delta_rows(do_ref[mine, :], o_ref[mine, :], d)
+        dq_ts = []
+        for g, parts in enumerate(found):
+            lse = lse_ref[g, 0, :, mine]
+            dq_ts.append(_total([
+                _dot(kt, (jnp.exp(st - lse) * (dpt - deltas[g])
+                          ).astype(kt.dtype), _NN)
+                for st, dpt, kt in parts]))
+        dq_ref[mine, :] = (_side_by_side(dq_ts, 0) * scale
+                           ).T.astype(dq_ref.dtype)
+
+    for at in range(0, rows, sub):
+        finish(at, products(at))
+
+
+def _band_dkv_kernel(
+    k_ref, v_ref, q_ref, o_ref, do_ref, lse_ref, q_next_ref, o_next_ref,
+    do_next_ref, lse_next_ref, dk_ref, dv_ref, delta_ref,
+    *, head_dim: int, band: Band, window: int, scale: float,
+):
+    # k_ref, v_ref, q_ref, o_ref, do_ref, dk_ref, dv_ref: [cell rows, cell
+    # heads · d]; the *_next_ref: [reach, ..], the block after the cell's
+    # own; lse_ref: [cell heads, 1, 1, cell rows], lse_next_ref: [.., reach];
+    # delta_ref (scratch): [cell heads, 1, cell rows + reach]
+    rows, lanes = k_ref.shape
+    d, sub = head_dim, band.sub
+    heads = _head_cols(lanes, d)
+    masks = _BandMasks(window)
+    cap = _neighbour_cap(pl.program_id(2) == pl.num_programs(2) - 1)
+    # delta of every query the cell meets, once: rows [0, rows) its own
+    # block's, the rest its neighbour's (a ref, so that a piece's lanes are
+    # read and not cut out of a value)
+    for g, (own, nxt) in enumerate(zip(
+            _delta_rows(do_ref[...], o_ref[...], d),
+            _delta_rows(do_next_ref[...], o_next_ref[...], d))):
+        delta_ref[g, :, :rows] = own
+        delta_ref[g, :, rows:] = nxt
+    for at in range(0, rows, sub):
+        mine = slice(at, at + sub)
+        pieces = _band_pieces(at, sub, window, rows, keys=False)
+        dks, dvs = [], []
+        for g, cols in enumerate(heads):
+            k, s_scale = _fold_scale(k_ref[mine, cols], scale)
+            v = v_ref[mine, cols]
+            dk_parts, dv_parts = [], []
+            for start, stop, far, near in pieces:
+                nxt = start >= rows
+                base = rows if nxt else 0
+                there = slice(start - base, stop - base)
+                q = (q_next_ref if nxt else q_ref)[there, cols]
+                do = (do_next_ref if nxt else do_ref)[there, cols]
+                lse = (lse_next_ref if nxt else lse_ref)[g, 0, :, there]
+                st = masks(_scores_t(k, q, s_scale, None), start - at, far,
+                           near)
+                if nxt:
+                    st = jnp.minimum(st, cap)
+                pt = jnp.exp(st - lse)
+                dv_parts.append(_dot(pt.astype(do.dtype), do, _NN))
+                dst = pt * (_dot(v, do, _NT) - delta_ref[g, :, start:stop])
+                dk_parts.append(_dot(dst.astype(q.dtype), q, _NN))
+            dks.append(_total(dk_parts))
+            dvs.append(_total(dv_parts))
+        # q entered the products unscaled (the scale sat on k or the scores).
+        dk_ref[mine, :] = (_side_by_side(dks, 1) * scale).astype(dk_ref.dtype)
+        dv_ref[mine, :] = _side_by_side(dvs, 1).astype(dv_ref.dtype)
+
+
+def _band_neighbours(band: Band, s: int):
+    """``(before, after)``: grid cell ``i``'s neighbour blocks, counted in
+    blocks of ``reach`` rows and clipped at the sequence's ends (a cell
+    there hides what it is given instead: ``_neighbour_cap``)."""
+    per, last = band.rows // band.reach, s // band.reach - 1
+    return (lambda i: jnp.maximum(i * per - 1, 0),
+            lambda i: jnp.minimum((i + 1) * per, last))
+
+
+def _band_specs(band: Band, lanes: int, s: int):
+    """``(own, prev, next)`` BlockSpecs over a ``[B, S, H·d]`` array for grid
+    cell ``(batch row, lane block, i)``: the cell's own ``rows``, and the
+    ``reach`` rows before and after them — the same array handed to the
+    kernel again under its neighbour's block index."""
+    before, after = _band_neighbours(band, s)
+    return (
+        pl.BlockSpec((None, band.rows, lanes), lambda b, h, i: (b, i, h)),
+        pl.BlockSpec((None, band.reach, lanes),
+                     lambda b, h, i: (b, before(i), h)),
+        pl.BlockSpec((None, band.reach, lanes),
+                     lambda b, h, i: (b, after(i), h)),
+    )
+
+
+def _band_fwd(q, k, v, *, heads: int, scale: float, band: Band, window: int,
+              interpret: bool):
+    b, s, width = q.shape
+    d = width // heads
+    cell = _cell_heads(heads, d, 0, False, 0)
+    own, prev, _ = _band_specs(band, cell * d, s)
+    return pl.pallas_call(
+        functools.partial(_band_fwd_kernel, head_dim=d, band=band,
+                          window=window, scale=scale),
+        grid=(b, pl.cdiv(heads, cell), s // band.rows),
+        in_specs=[own, own, own, prev, prev],
+        out_specs=[
+            own,
+            pl.BlockSpec((None, cell, band.rows, 1),
+                         lambda b, h, i: (b, h, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, heads, s, 1), jnp.float32),
+        ],
+        compiler_params=_BAND_PARAMS,
+        interpret=interpret,
+        name="swa_fwd",
+    )(q, k, v, k, v)
+
+
+def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
+              dq_band: Band, dkv_band: Band, window: int, interpret: bool):
+    b, s, width = q.shape
+    d = width // heads
+    cell = _cell_heads(heads, d, 0, False, 0)
+    static = dict(head_dim=d, window=window, scale=scale)
+
+    band = dq_band
+    own, prev, _ = _band_specs(band, cell * d, s)
+    lse_spec, lse_in = _lse_operand(lse, cell, band.rows, whole=False)
+    dq = pl.pallas_call(
+        functools.partial(_band_dq_kernel, band=band, **static),
+        grid=(b, pl.cdiv(heads, cell), s // band.rows),
+        in_specs=[own, own, own, prev, prev, own, own, lse_spec],
+        out_specs=own,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_BAND_PARAMS,
+        interpret=interpret,
+        name="swa_bwd_dq",
+    )(q, k, v, k, v, out, do, lse_in)
+
+    band = dkv_band
+    own, _, nxt = _band_specs(band, cell * d, s)
+    lse_spec, lse_in = _lse_operand(lse, cell, band.rows, whole=False)
+    lse_next_spec, lse_next_in = _lse_operand(
+        lse, cell, band.reach, whole=False, at=_band_neighbours(band, s)[1])
+    dk, dv = pl.pallas_call(
+        functools.partial(_band_dkv_kernel, band=band, **static),
+        grid=(b, pl.cdiv(heads, cell), s // band.rows),
+        in_specs=[own, own, own, own, own, lse_spec, nxt, nxt, nxt,
+                  lse_next_spec],
+        out_specs=[own, own],
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((cell, 1, band.rows + band.reach), jnp.float32)],
+        compiler_params=_BAND_PARAMS,
+        interpret=interpret,
+        name="swa_bwd_dkv",
+    )(k, v, q, out, do, lse_in, q, out, do, lse_next_in)
+    return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
 # public op
 # ---------------------------------------------------------------------------
 
@@ -646,11 +1096,16 @@ def _flash(q, k, v, heads, causal, scale, blocks: Blocks, interpret, window):
 
 def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
                window):
-    out, lse = _fwd(
-        q, k, v, heads=heads, causal=causal, scale=scale,
-        block_q=blocks[0][0], block_k=blocks[0][1], interpret=interpret,
-        window=window,
-    )
+    if isinstance(blocks[0], Band):
+        out, lse = _band_fwd(q, k, v, heads=heads, scale=scale,
+                             band=blocks[0], window=window,
+                             interpret=interpret)
+    else:
+        out, lse = _fwd(
+            q, k, v, heads=heads, causal=causal, scale=scale,
+            block_q=blocks[0][0], block_k=blocks[0][1], interpret=interpret,
+            window=window,
+        )
     # The kernel's column [B, H, S, 1] turned once into dense rows
     # [B, H, S]; both named HERE, so that the residuals below are the named
     # values (a name on the primal outside the rule would name a copy, and
@@ -664,6 +1119,10 @@ def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
 def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, res,
                g):
     q, k, v, out, lse = res
+    if isinstance(blocks[1], Band):
+        return _band_bwd(q, k, v, out, lse, g, heads=heads, scale=scale,
+                         dq_band=blocks[1], dkv_band=blocks[2],
+                         window=window, interpret=interpret)
     return _bwd(
         q, k, v, out, lse, g, heads=heads, causal=causal, scale=scale,
         dq_blocks=blocks[1], dkv_blocks=blocks[2], interpret=interpret,
@@ -693,8 +1152,10 @@ def flash_attention(
 
     ``window`` (with ``causal``): query i sees only the ``window`` keys up
     to its own, ``0 <= i + (s_k - s_q) - j < window``. K-blocks (Q-blocks in
-    dk/dv) wholly outside that band are not visited; the calls carry names
-    of their own (``swa_fwd``, ``swa_bwd_dq``, ``swa_bwd_dkv``).
+    dk/dv) wholly outside that band are not visited — on a square problem
+    whose window a block holds not even resident (the band path) — and the
+    calls carry names of their own (``swa_fwd``, ``swa_bwd_dq``,
+    ``swa_bwd_dkv``).
 
     ``block_q`` / ``block_k``, when passed, hold for all three kernels; left
     out, each kernel's are chosen from what the call shows."""
@@ -713,9 +1174,11 @@ def flash_attention(
     device = jax.devices()[0]
     how = "INTERPRETED" if interpret else "compiled"
     chosen = ", ".join(
-        f"{name} {bq}/{bk} "
-        + ("unrolled" if _unrolled(s // bq, s_k // bk) else "looped")
-        for name, (bq, bk) in zip(("fwd", "dq", "dkv"), blocks))
+        f"{name} band {cut.rows}/{cut.sub} beside {cut.reach}"
+        if isinstance(cut, Band) else
+        f"{name} {cut[0]}/{cut[1]} "
+        + ("unrolled" if _unrolled(s // cut[0], s_k // cut[1]) else "looped")
+        for name, cut in zip(("fwd", "dq", "dkv"), blocks))
     tile = _cell_heads(h, d, 0, False, 0)  # before short sequences widen it
     log_once(log, f"flash attention: {how} Pallas kernel on "
                   f"{device.platform} ({device.device_kind}), "

@@ -49,8 +49,8 @@ LAGUNA = ("laguna", dict(
 #: limit is its own compiled size and a little: medium's steps 15.292
 #: (15.668 with the flash forward's `out` kept as well: PERF.md section 6,
 #: PR 30), XL's shard 14.111, the hybrid's 15.601, Ouro's 15.488, Laguna's
-#: share 14.999 (15.227 before the expert layer's sort went in pieces,
-#: PR 32) — a change to the shared block, kernels or policy may not grow
+#: share 15.006 (15.227 before the expert layer's sort went in pieces,
+#: PR 32; 14.999 before the window kernels' band path, PR 34) — a change to the shared block, kernels or policy may not grow
 #: them unseen.
 PROGRAMS = {
     "one": (MEDIUM, "dp=1", 8, 1, "adamw", None),    # chip_smoke train/resume/elastic
