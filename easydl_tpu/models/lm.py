@@ -17,7 +17,6 @@ from easydl_tpu.core.data import SyntheticTokens
 from easydl_tpu.models.registry import ModelBundle
 from easydl_tpu.models.transformer import Transformer, TransformerConfig
 from easydl_tpu.ops.fused_xent import fused_softmax_xent, local_batch
-from easydl_tpu.ops.moe import COUNTERS
 from easydl_tpu.utils.logging import get_logger, log_once
 
 
@@ -150,12 +149,14 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     :func:`fused_head_by_shape` says so; a gated stack's is
     :func:`looplm_objective` with ``beta = exit_entropy_weight``), eval,
     data and the hints. Where the description has ``moe`` layers the loss's
-    metrics carry their counters (``ops/moe.py COUNTERS``): ``moe_dropped``
+    metrics carry their counters (``ops/moe.py counters``): ``moe_dropped``
     summed over the layers, the others their mean — ``moe_rows_per_token``,
     ``moe_load_max_over_mean``, ``moe_buffer_fill`` (landed rows over the
-    bound), ``router_entropy``, and ``moe_overflow``, the share of the
+    bound), ``router_entropy``, ``moe_overflow``, the share of the
     step's expert-layer calls whose landed rows needed more than one piece
-    of the sort."""
+    of the sort, and, where the router has a skip choice, ``moe_skipped``,
+    the share of tokens that took it; where the router's state runs through
+    the depth, ``router_state_rms``, its size after the last layer."""
     model = Transformer(cfg)
     seq_len, vocab = cfg.max_seq, cfg.vocab
     n_sparse = sum(1 for _, ffn in cfg.pattern if ffn == "moe")
@@ -229,7 +230,10 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
             summed = mut["counters"]["moe"][0]
             counters = {name: summed[i] / (1 if name == "moe_dropped"
                                            else n_sparse)
-                        for i, name in enumerate(COUNTERS)}
+                        for i, name in enumerate(cfg.counters)}
+            if cfg.router_state_width:
+                counters["router_state_rms"] = \
+                    mut["counters"]["router_state_rms"][0]
             return loss, {"perplexity": jnp.exp(loss), **counters}
         loss, _ = _lm_loss_from(params, batch)
         return loss, {"perplexity": jnp.exp(loss)}

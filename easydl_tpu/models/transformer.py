@@ -29,6 +29,7 @@ hit the BASELINE configs 3-4 (BERT-base, GPT-2 345M).
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 from typing import Callable, NamedTuple, Optional, Tuple
@@ -38,7 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from easydl_tpu.ops import multihead_attention, remat
-from easydl_tpu.ops.moe import COUNTERS, MoeMlp
+from easydl_tpu.ops import moe as moe_ops
+from easydl_tpu.ops.moe import MoeMlp
 from easydl_tpu.ops.rope import apply_rope, rope_tables
 from easydl_tpu.ops.ssd import (causal_conv1d, gated_rmsnorm,
                                 ssd_flops_per_token, ssd_scan)
@@ -188,24 +190,47 @@ class RopeScheme:
 
 
 @dataclass(frozen=True)
+class LatentMix:
+    """What compressed convolutional attention (CCA, arXiv:2510.04476) does
+    to q, k and v between their projections DOWN to the heads' latent
+    (``heads x head_dim`` and ``kv_heads x head_dim``, narrower than the
+    model) and the attention computed there: two causal convolutions over
+    the sequence on q and k, ``taps[0]`` taps per channel then ``taps[1]``
+    taps mixing the channels of each head, both with a bias; the mean of the
+    un-convolved q and k added back to both; the second half of the
+    key/value heads taken from the PREVIOUS token (``value_shift``); q and k
+    L2-normed to ``sqrt(head_dim)``, k times a learned temperature a
+    key/value head (``qk_norm``)."""
+
+    taps: Tuple[int, int] = (2, 2)
+    value_shift: bool = True
+    qk_norm: bool = True
+
+
+@dataclass(frozen=True)
 class AttentionKind:
     """An attention layer's own numbers, where a stack has more than one
     kind: query heads (0: the description's), a causal window in keys (0:
-    none), a rotary scheme (None: the description's ``position``), and a
-    per-head sigmoid gate on the attention output."""
+    none), a rotary scheme (None: the description's ``position``), a
+    per-head sigmoid gate on the attention output, and (``latent``) the
+    mixing of q, k and v inside the heads' latent."""
 
     n_heads: int = 0
     window: int = 0
     rope: Optional[RopeScheme] = None
     gate: bool = False
+    latent: Optional[LatentMix] = None
 
 
 @dataclass(frozen=True)
 class MoeConfig:
-    """Widths of the ``moe`` FFNs (``ops/moe.py``): the router's width
-    ``experts_total``, the contiguous range of routed experts held here,
-    experts a token, an expert's and the shared expert's inner widths, and
-    the scale on the renormalised router weights."""
+    """Widths of the ``moe`` FFNs (``ops/moe.py``): the published count of
+    routed experts ``experts_total``, the contiguous range of them held
+    here, experts a token, an expert's and the shared expert's inner widths,
+    the scale on the renormalised router weights; the router's form
+    (``ops/moe.py ROUTERS``) and, of the MLP form, its width — also the
+    width of the router state the layers hand on through the scan's carry —
+    and whether it has a skip choice beside the experts."""
 
     experts_total: int
     experts_held: Tuple[int, int]
@@ -213,6 +238,20 @@ class MoeConfig:
     d_ff: int
     shared_d_ff: int = 0
     scaling: float = 1.0
+    router: str = moe_ops.ROUTERS[0]
+    router_hidden: int = 0
+    skip_choice: bool = False
+
+    @property
+    def choices(self) -> int:
+        """The router's outputs: the experts and the skip choice."""
+        return self.experts_total + int(self.skip_choice)
+
+    @property
+    def state_width(self) -> int:
+        """Width of the router state a layer takes from the layer before
+        it (0: the router has none)."""
+        return self.router_hidden if self.router == moe_ops.ROUTERS[1] else 0
 
 
 @dataclass(frozen=True)
@@ -302,6 +341,10 @@ class TransformerConfig:
     attention_kinds: Tuple[Tuple[str, AttentionKind], ...] = ()
     #: widths of the ``moe`` FFNs, where the description has any
     moe: Optional[MoeConfig] = None
+    #: each residual add is ``(s_x * x + t_x) + (s_y * y + t_y)``, four
+    #: learned vectors of the model's width a sub-layer (ones and zeros at
+    #: the start), in float32
+    residual_scale: bool = False
 
     def __post_init__(self):
         if self.layers is not None and len(self.layers) != self.n_layers:
@@ -354,6 +397,18 @@ class TransformerConfig:
         return any(ffn == "moe" for _, ffn in self.pattern)
 
     @property
+    def router_state_width(self) -> int:
+        """Width of the router state that runs through the depth beside the
+        residual stream (the layer scan's carry is then a pair); 0: none."""
+        return self.moe.state_width if self.has_moe else 0
+
+    @property
+    def counters(self) -> Tuple[str, ...]:
+        """Names of the counters the stack sows (``counters`` collection,
+        ``moe``): the expert layers' own, summed over the layers."""
+        return moe_ops.counters(self.moe.skip_choice) if self.has_moe else ()
+
+    @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
@@ -376,9 +431,9 @@ class TransformerConfig:
         """Parameters of one layer. Exact for the bias-free kinds; a layer
         with biases counts ``4 * d_model`` for its biases and norms, as this
         estimate always has. ``active``: of a ``moe`` layer's routed
-        experts only what a token meets on average, ``k * held / total`` of
-        them (all ``k`` where every expert is held) — what the matrix
-        products of a step are counted from."""
+        experts only what a token meets on average, ``k * held / choices`` of
+        them (all ``k`` where every expert is held and none is skipped) —
+        what the matrix products of a step are counted from."""
         mixer, ffn = layer
         d = self.d_model
         if mixer != "mamba2":
@@ -387,6 +442,14 @@ class TransformerConfig:
             n = 2 * d * inner + 2 * d * self.kv_heads * self.head_dim
             if kind.gate:
                 n += d * (kind.n_heads or self.n_heads)
+            if kind.latent:
+                # a tap and a bias a channel, a [head_dim, head_dim] matrix a
+                # tap and head and a bias a channel; a temperature a kv head
+                mix = kind.latent
+                channels = inner + self.kv_heads * self.head_dim
+                n += (mix.taps[0] + 1) * channels \
+                    + (mix.taps[1] * self.head_dim + 1) * channels \
+                    + (self.kv_heads if mix.qk_norm else 0)
         else:
             m = self.ssm
             inner, bc = m.n_heads * m.head_dim, m.n_groups * m.d_state
@@ -398,13 +461,19 @@ class TransformerConfig:
         elif ffn == "moe":
             m = self.moe
             held = m.experts_held[1] - m.experts_held[0]
-            routed = m.k * held / m.experts_total if active else held
-            n += (d * m.experts_total + 3 * d * m.shared_d_ff
+            routed = m.k * held / m.choices if active else held
+            r = m.state_width
+            router = d * m.experts_total if not r else (
+                (d + 1) * r + 2 * r          # down and bias, gain, norm
+                + 2 * (r + 1) * r + r * m.choices)
+            n += (router + 3 * d * m.shared_d_ff
                   + round(routed * 3 * d * m.d_ff))
         else:
             n += 2 * d * self.d_ff
         if self.norm_placement == "sandwich":
             n += 2 * d                              # the two output norms
+        if self.residual_scale:
+            n += 8 * d
         return n + (4 * d if self.bias else 2 * d)  # biases-ish + 2 norms
 
     @property
@@ -468,6 +537,79 @@ def _projection(block, features, kernel_axes, bias_axes, name,
         axis=axis, dtype=jnp.dtype(cfg.dtype), rows=rows)
 
 
+def _shift(x, by: int = 1):
+    """``x [batch, seq, ...]`` moved ``by`` positions later: position ``t``
+    reads ``t - by``, the first ``by`` read zeros."""
+    pad = [(0, 0), (by, 0)] + [(0, 0)] * (x.ndim - 2)
+    return jax.lax.slice_in_dim(jnp.pad(x, pad), 0, x.shape[1], axis=1)
+
+
+def _latent_mix(block, mix, q, k, v):
+    """:class:`LatentMix` on the projections ``q [B, S, H, d]``, ``k, v [B,
+    S, G, d]``: what goes to the attention. The convolutions' taps, the
+    mean, the norm and the temperature in float32; the products that mix a
+    head's channels in the compute dtype, the taps' summed in float32."""
+    f32 = jnp.float32
+    (n_q, d), n_kv = q.shape[2:], k.shape[2]
+    heads = n_q + n_kv
+
+    def param(name, init, shape, axes):
+        return block.param(name, nn.with_logical_partitioning(init, axes),
+                           shape).astype(f32)
+
+    def uniform(fan_in):
+        # torch's Conv1d default for the taps: uniform in +-1/sqrt(fan_in).
+        # The biases start at zero, as every bias of this stack does: a
+        # constant in q is a score every query gives a key alike, and all
+        # positions then attend to the same few tokens of a sequence — at
+        # seeded weights the routers' load on the experts held followed the
+        # seed by 10% either way (PERF.md section 6, PR 35)
+        bound = fan_in ** -0.5
+
+        def init(key, shape, dtype=jnp.float32):
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+        return init
+
+    with jax.named_scope("cca_conv"):
+        # q's and k's heads are groups alike: ten heads of channels
+        u = jnp.concatenate([q, k], 2)
+        t0, t1 = mix.taps
+        zeros = nn.initializers.zeros_init()
+        per_channel = param("conv0", uniform(t0), (t0, heads, d),
+                            (None, "heads", "kv"))
+        bias0 = param("conv0_bias", zeros, (heads, d), ("heads", "kv"))
+        c1 = causal_conv1d(u.astype(f32), per_channel, bias0).astype(u.dtype)
+        per_head = block.param("conv1", nn.with_logical_partitioning(
+            uniform(t1 * d), (None, "heads", "kv", None)), (t1, heads, d, d))
+        bias1 = param("conv1_bias", zeros, (heads, d), ("heads", "kv"))
+        c2 = bias1 + sum(
+            jnp.einsum("bshc,hcd->bshd", _shift(c1, t1 - 1 - i),
+                       per_head[i].astype(u.dtype)).astype(f32)
+            for i in range(t1))
+        # the mean of the un-convolved q and k, under GQA: a query head's
+        # with its key/value head's, a key/value head's the mean of its
+        # query heads' means
+        per = n_q // n_kv
+        q32 = q.astype(f32).reshape(*q.shape[:2], n_kv, per, d)
+        mean_q = 0.5 * (q32 + k.astype(f32)[:, :, :, None])
+        q = c2[:, :, :n_q] + mean_q.reshape(q.shape)
+        k = c2[:, :, n_q:] + jnp.mean(mean_q, 3)
+    if mix.value_shift:
+        with jax.named_scope("value_shift"):
+            # a product with a weight commutes with the shift: the second
+            # half of the heads shifted, not the layer's input
+            half = n_kv // 2
+            v = jnp.concatenate([v[:, :, :half], _shift(v[:, :, half:])], 2)
+    if mix.qk_norm:
+        with jax.named_scope("qk_norm"):
+            tau = param("temperature", nn.initializers.zeros_init(), (n_kv,),
+                        ("heads",))
+            q = q * jax.lax.rsqrt(jnp.mean(q * q, -1, keepdims=True))
+            k = k * jax.lax.rsqrt(jnp.mean(k * k, -1, keepdims=True)) \
+                * jnp.exp(tau)[:, None]
+    return q.astype(v.dtype), k.astype(v.dtype), v
+
+
 def _attention(block, h, rope=None):
     cfg = block.cfg
     kind = cfg.attention_kind(block.mixer)
@@ -476,13 +618,24 @@ def _attention(block, h, rope=None):
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
     # the four products around the kernels as matrix products on rows: the
     # kernels' layout (the Mamba-2 mixer's same-shaped projections feed no
-    # kernel and measured SLOWER that way: PERF.md section 6, PR 28)
-    q = _projection(block, (n_heads, cfg.head_dim), heads, kv, "q",
-                    rows=True)(h)
-    k = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "k",
-                    rows=True)(h)
-    v = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "v",
-                    rows=True)(h)
+    # kernel and measured SLOWER that way: PERF.md section 6, PR 28). With a
+    # latent mix they are the way down into the heads' latent and back up
+    # (`cca_down`, `cca_up`), and the mix stands between them and the kernels
+    scope = jax.named_scope if kind.latent \
+        else lambda name: contextlib.nullcontext()
+    with scope("cca_down"):
+        q = _projection(block, (n_heads, cfg.head_dim), heads, kv, "q",
+                        rows=True)(h)
+        k = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "k",
+                        rows=True)(h)
+        v = _projection(block, (cfg.kv_heads, cfg.head_dim), heads, kv, "v",
+                        rows=True)(h)
+    if kind.latent:
+        q, k, v = _latent_mix(block, kind.latent, q, k, v)
+        # what the mix was given and gave, where `intermediates` is a
+        # mutable collection (the benchmark's check, tests)
+        for name, value in (("in", h), ("q", q), ("k", k), ("v", v)):
+            block.sow("intermediates", f"latent_{name}", value)
     q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
     k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
     v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
@@ -506,9 +659,10 @@ def _attention(block, h, rope=None):
                 block, n_heads, ("embed", "heads"), ("heads",), "gate_heads"
             )(h).astype(jnp.float32))
             attn = (attn * gate[..., None]).astype(attn.dtype)
-    return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
-                       ("embed",), "out", residual=True, axis=(-2, -1),
-                       rows=True)(attn)
+    with scope("cca_up"):
+        return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                           ("embed",), "out", residual=True, axis=(-2, -1),
+                           rows=True)(attn)
 
 
 def _mamba2(block, u):
@@ -577,7 +731,10 @@ def _mamba2(block, u):
                        ("embed",), "out", residual=True, axis=(-2, -1))(y)
 
 
-def _ffn(block, h):
+def _ffn(block, h, state=None):
+    """``(y, aux, state)``: the FFN on the normed input ``h``; an expert
+    layer whose router has a state takes the previous layer's and gives its
+    own, any other layer hands ``state`` on as it came."""
     cfg = block.cfg
     aux = jnp.zeros((), jnp.float32)
     if block.ffn == "swiglu":
@@ -587,18 +744,20 @@ def _ffn(block, h):
         h = nn.silu(gate) * up
     elif block.ffn == "moe":
         m = cfg.moe
-        return MoeMlp(
+        y, aux, routed = MoeMlp(
             experts_total=m.experts_total, experts_held=m.experts_held,
             d_ff=m.d_ff, shared_d_ff=m.shared_d_ff, k=m.k, scaling=m.scaling,
             out_init_scale=(2 * cfg.n_layers) ** -0.5, dtype=cfg.dtype,
-            name="moe",
-        )(h)
+            router=m.router, router_hidden=m.router_hidden,
+            router_eps=cfg.norm_eps, skip_choice=m.skip_choice, name="moe",
+        )(h, state)
+        return y, aux, state if routed is None else routed
     else:
         h = nn.gelu(_projection(block, cfg.d_ff, ("embed", "mlp"), ("mlp",),
                                 "up")(h))
     h = _projection(block, cfg.d_model, ("mlp", "embed"), ("embed",), "down",
                     residual=True)(h)
-    return h, aux
+    return h, aux, state
 
 
 class Block(nn.Module):
@@ -610,7 +769,11 @@ class Block(nn.Module):
     :func:`easydl_tpu.ops.rope.rope_tables`, made once for all layers.
 
     Returns ``(x, aux)`` — the (carry, per-step-output) pair ``nn.scan``
-    expects; standalone callers unpack the first element.
+    expects; standalone callers unpack the first element. Where the
+    description's router has a state that runs through the depth
+    (``cfg.router_state_width``) the carry is the pair ``(x, state)``, coming
+    and going: the residual stream and the router state ``[batch, seq,
+    width]`` in float32, which the layer's FFN reads and writes.
     """
 
     cfg: TransformerConfig
@@ -623,6 +786,9 @@ class Block(nn.Module):
         # kwargs.
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
+        state = None
+        if cfg.router_state_width:
+            x, state = x
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
         def residual(x, h, ln):
@@ -632,6 +798,19 @@ class Block(nn.Module):
                 h = nn.Dropout(cfg.dropout, deterministic=False)(h)
             if cfg.residual_multiplier != 1.0:
                 h = h * jnp.asarray(cfg.residual_multiplier, h.dtype)
+            if cfg.residual_scale:
+                with jax.named_scope("residual_scale"):
+                    s_x, t_x, s_y, t_y = (self.param(
+                        f"{ln}_res_{name}", nn.with_logical_partitioning(
+                            init, ("embed",)), (cfg.d_model,)
+                    ).astype(jnp.float32) for name, init in (
+                        ("scale_x", nn.initializers.ones_init()),
+                        ("bias_x", nn.initializers.zeros_init()),
+                        ("scale_y", nn.initializers.ones_init()),
+                        ("bias_y", nn.initializers.zeros_init())))
+                    return ((s_x * x.astype(jnp.float32) + t_x)
+                            + (s_y * h.astype(jnp.float32) + t_y)
+                            ).astype(x.dtype)
             return x + h
 
         # The scopes put every operation of a layer, residual adds,
@@ -652,7 +831,8 @@ class Block(nn.Module):
             # `ffn` is the dense FFN's scope; an expert layer is `moe`, with
             # the scopes of ops/moe.py inside it
             with jax.named_scope("moe" if self.ffn == "moe" else "ffn"):
-                h, aux = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x))
+                h, aux, state = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x),
+                                     state)
                 x = residual(x, h, "ln_mlp")
         if cfg.remat and cfg.remat_policy == "dots" \
                 and not self.is_initializing():
@@ -669,7 +849,8 @@ class Block(nn.Module):
                           f"microbatch as traced (a kernel's per shard under "
                           f"a mesh), beside its unnamed products; named and "
                           f"not kept: {', '.join(left) or 'nothing'}")
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed")), aux
+        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        return (x, state) if cfg.router_state_width else x, aux
 
 
 def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic,
@@ -835,9 +1016,14 @@ class Transformer(nn.Module):
             """The runs of layers once and the final norm: ``(x, (x, gate
             logit, aux))``, a scan body over passes."""
             # zeros for dense layers; the expert layers' counters summed
-            # (ops/moe.py COUNTERS) where the description has any
-            aux = jnp.zeros((len(COUNTERS),) if cfg.has_moe else (),
+            # (ops/moe.py counters) where the description has any
+            aux = jnp.zeros((len(cfg.counters),) if cfg.has_moe else (),
                             jnp.float32)
+            if cfg.router_state_width:
+                # the router state beside the residual stream: zeros into
+                # the first layer
+                x = (x, jnp.zeros((*x.shape[:2], cfg.router_state_width),
+                                  jnp.float32))
             for i, ((mixer, ffn), count) in enumerate(runs):
                 rope = ropes.get(mixer)
                 if cfg.pipeline_fn is None or stack.is_initializing():
@@ -855,6 +1041,12 @@ class Transformer(nn.Module):
                         deterministic, rope)
                 aux = aux + (jnp.sum(layer_aux, 0) if ffn == "moe"
                              else jnp.sum(layer_aux))
+            if cfg.router_state_width:
+                x, state = x
+                # the carry's size after the last layer, beside the layers'
+                # counters
+                stack.sow("counters", "router_state_rms", jnp.sqrt(jnp.mean(
+                    jnp.square(state))))
             # Between passes only the normed state is kept (bf16): the
             # final norm's and the gate's float32 intermediates, 4 x [B, S,
             # D] a pass, are recomputed in the backward pass.

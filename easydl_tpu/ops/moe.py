@@ -40,6 +40,19 @@ the float32 rows 2.7 ms and 0.75 to write them; the rows re-ordered by token
 and each block of 128 tokens summed from the slab of rows it owns by a
 selection product in a Pallas kernel, the form that stayed.
 
+The router is a described form (:data:`ROUTERS`). ``linear-sigmoid-
+renormalised`` is the one above. ``mlp-softmax-top1`` is ZAYA's
+(:func:`route_mlp`): the normed input projected DOWN to ``router_hidden``
+(with a bias), the previous layer's router state added to it through a learned
+gain (the state runs through the depth: the layer takes it and hands its own
+on), RMSNorm, a three-layer GELU MLP to ``experts_total`` logits and, with
+``skip_choice``, one more — a token whose largest probability is the last
+takes nothing from this layer's experts — softmax, the largest, its
+probability itself the weight (not renormalised: with one choice that would
+be the constant 1 and the router would get no gradient). The sort, the
+pieces, the grouped products and the sums back are the same code with ``k =
+1``; the skip choice is an expert held nowhere.
+
 Under a mesh whose ``ep`` axis is larger than one the expert weights are
 sharded over it (rule ``("expert", "ep")``, ``core/sharding.py``): each shard
 holds ``held / ep`` experts of the range, routes the tokens it has over all
@@ -70,6 +83,25 @@ EXPERT_AXIS = "ep"
 #: the layer's counters, in the order of the vector it returns
 COUNTERS = ("moe_dropped", "moe_rows_per_token", "moe_load_max_over_mean",
             "moe_buffer_fill", "router_entropy", "moe_overflow")
+#: the router's forms
+ROUTERS = ("linear-sigmoid-renormalised", "mlp-softmax-top1")
+#: the MLP router's three maps start orthogonal (the last with orthonormal
+#: columns), the first at this scale and the others at that: every choice's
+#: logit is then the same isotropic map of the normed state, and its hidden
+#: units see inputs of 0.05, where GELU is linear to a part in forty — so at
+#: seeded weights every choice has the same chance whatever the seed. Normal
+#: 0.02 maps do not give that: GELU's positive mean at inputs of 0.3, passed
+#: through a random map, is an offset of a quarter of the logits' spread that
+#: no token moves, and one choice of seventeen then takes 2.3-2.9 times its
+#: share and the rows that land here 0.39-0.50 a token by the seed alone
+#: (PERF.md section 6, PR 35)
+MLP_ROUTER_INIT = (0.05, 1.0)
+
+
+def counters(skip_choice: bool = False) -> Tuple[str, ...]:
+    """The names of the vector a layer returns: :data:`COUNTERS`, and the
+    share of tokens that took the skip choice where the router has one."""
+    return COUNTERS + (("moe_skipped",) if skip_choice else ())
 
 
 #: a piece of the sort holds this many times the rows expected to land on
@@ -110,6 +142,30 @@ def route(h: jax.Array, kernel: jax.Array, k: int, scaling: float):
     scores = jax.nn.sigmoid(logits)
     top, chosen = jax.lax.top_k(scores, k)
     return logits, chosen, scaling * top / jnp.sum(top, -1, keepdims=True)
+
+
+def route_mlp(h: jax.Array, state: jax.Array, w, eps: float):
+    """``(state [T, R], logits [T, C], chosen [T, 1] int32, weights [T, 1])``
+    of tokens ``h [T, D]`` and the previous layer's router state ``[T, R]``,
+    float32 throughout, products at ``highest``: ``r = h W_down + b + gamma *
+    state`` (the state handed on, before its norm), ``logits = W_3
+    gelu(W_2 gelu(W_1 RMSNorm(r) + b_1) + b_2)``, softmax, the largest
+    choice, its probability the weight. ``w``: the router's leaves by name
+    (:class:`MoeMlp`)."""
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+    w = {name: leaf.astype(jnp.float32) for name, leaf in w.items()}
+    with jax.named_scope("router_eda"):
+        r = dot(h.astype(jnp.float32), w["down"]) + w["down_bias"]
+        r = r + w["gamma"] * state.astype(jnp.float32)
+    with jax.named_scope("router_mlp"):
+        u = r * jax.lax.rsqrt(jnp.mean(r * r, -1, keepdims=True) + eps) \
+            * w["norm"]
+        u = jax.nn.gelu(dot(u, w["w1"]) + w["b1"], approximate=False)
+        u = jax.nn.gelu(dot(u, w["w2"]) + w["b2"], approximate=False)
+        logits = dot(u, w["w3"])
+        p = jax.nn.softmax(logits, -1)
+        chosen = jnp.argmax(p, -1).astype(jnp.int32)[:, None]
+        return r, logits, chosen, jnp.take_along_axis(p, chosen, -1)
 
 
 # ------------------------------------------------------ the pieces of the sort
@@ -391,21 +447,26 @@ def _over_expert_shards(fn, tokens: int, held: int):
 
 class MoeMlp(nn.Module):
     """Router, the routed experts held here, the shared expert: ``x [B, S,
-    D]`` (the normed input) to ``(y [B, S, D], counters [6])``, the counters
-    in :data:`COUNTERS`' order, float32: choices on the held experts that
-    got no row (0 by construction), landed rows a token, the largest
+    D]`` (the normed input) to ``(y [B, S, D], counters, state)``, the
+    counters in :func:`counters`' order, float32: choices on the held experts
+    that got no row (0 by construction), landed rows a token, the largest
     expert's rows over the mean, landed rows over the bound, the router's
-    entropy, and whether the landed rows needed more than one piece
-    (:func:`piece_rows` of ``experts_total``: the share of the shards'
-    calls under ``ep``).
+    entropy, whether the landed rows needed more than one piece
+    (:func:`piece_rows` of the router's width: the share of the shards'
+    calls under ``ep``) and, with ``skip_choice``, the share of tokens that
+    took it. ``state`` is the router state handed on ``[B, S,
+    router_hidden]`` float32 (``mlp-softmax-top1``: it takes the previous
+    layer's as its second argument), else None.
 
-    Scopes (``jax.named_scope``): ``router``, ``dispatch`` (the sort, a
-    piece's gather and the transpose's sum back to the tokens), ``experts``,
-    ``combine`` (the weighted sum back to the tokens and its transpose),
-    ``shared_expert``; the caller's ``moe`` scope is around them, a
-    ``while`` inside where a piece is not the first. Where ``intermediates`` is a mutable collection (the benchmark's
-    check, tests) the layer also sows what it routed on: ``router_in``,
-    ``router_logits``, ``chosen``."""
+    Scopes (``jax.named_scope``): ``router`` (``router_eda`` and
+    ``router_mlp`` inside it where the router is the MLP), ``dispatch`` (the
+    sort, a piece's gather and the transpose's sum back to the tokens),
+    ``experts``, ``combine`` (the weighted sum back to the tokens and its
+    transpose), ``shared_expert``; the caller's ``moe`` scope is around them,
+    a ``while`` inside where a piece is not the first. Where
+    ``intermediates`` is a mutable collection (the benchmark's check, tests)
+    the layer also sows what it routed on: ``router_in``, ``router_logits``,
+    ``chosen`` (and ``router_state_in``, ``router_state``)."""
 
     experts_total: int
     experts_held: Tuple[int, int]
@@ -416,15 +477,49 @@ class MoeMlp(nn.Module):
     #: init scale of the down projections (the dense path's residual scale)
     out_init_scale: float = 1.0
     dtype: str = "float32"
+    #: one of :data:`ROUTERS`; the MLP's width and its norm's epsilon, and
+    #: whether it has one more choice than experts, which adds nothing
+    router: str = ROUTERS[0]
+    router_hidden: int = 0
+    router_eps: float = 1e-5
+    skip_choice: bool = False
+
+    def _route_mlp(self, h, state):
+        """The MLP router's parameters and :func:`route_mlp` on them."""
+        d, r = h.shape[-1], self.router_hidden
+        choices = self.experts_total + int(self.skip_choice)
+        normal = nn.initializers.normal(stddev=0.02)
+        ones, zeros = nn.initializers.ones_init(), nn.initializers.zeros_init()
+        first, later = (nn.initializers.orthogonal(scale)
+                        for scale in MLP_ROUTER_INIT)
+        w = {name: self.param(f"router_{name}", nn.with_logical_partitioning(
+            init, axes), shape) for name, init, axes, shape in (
+                ("down", normal, ("embed", None), (d, r)),
+                ("down_bias", zeros, (None,), (r,)),
+                ("gamma", ones, (None,), (r,)),
+                ("norm", ones, (None,), (r,)),
+                ("w1", first, (None, None), (r, r)),
+                ("b1", zeros, (None,), (r,)),
+                ("w2", later, (None, None), (r, r)),
+                ("b2", zeros, (None,), (r,)),
+                ("w3", later, (None, None), (r, choices)))}
+        return route_mlp(h, state, w, self.router_eps)
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, state=None):
         batch, seq, d = x.shape
         lo, hi = self.experts_held
         if not 0 <= lo < hi <= self.experts_total:
             raise ValueError(f"experts_held {self.experts_held} of "
                              f"{self.experts_total}")
+        if self.router not in ROUTERS:
+            raise ValueError(f"router {self.router!r} is none of {ROUTERS}")
+        mlp = self.router == ROUTERS[1]
+        if mlp and self.k != 1 or self.skip_choice and not mlp:
+            raise ValueError(f"router {self.router!r} with k={self.k}, "
+                             f"skip_choice={self.skip_choice}")
         held, tokens = hi - lo, batch * seq
+        choices = self.experts_total + int(self.skip_choice)
         dt = jnp.dtype(self.dtype)
         h = x.astype(dt).reshape(tokens, d)
 
@@ -435,16 +530,25 @@ class MoeMlp(nn.Module):
                 shape), dt)
 
         with jax.named_scope("router"):
-            # the router's width is the published one, whatever is held
-            kernel = self.param("router", nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.02), ("embed", None)),
-                (d, self.experts_total))
-            logits, chosen, weights = route(h, kernel, self.k, self.scaling)
+            if mlp:
+                state_in = state.reshape(tokens, -1)
+                state, logits, chosen, weights = self._route_mlp(h, state_in)
+                self.sow("intermediates", "router_state_in", state_in)
+                self.sow("intermediates", "router_state", state)
+                state = state.reshape(batch, seq, -1)
+                share = jax.nn.softmax(logits, -1)
+            else:
+                # the router's width is the published one, whatever is held
+                kernel = self.param("router", nn.with_logical_partitioning(
+                    nn.initializers.normal(stddev=0.02), ("embed", None)),
+                    (d, self.experts_total))
+                logits, chosen, weights = route(h, kernel, self.k,
+                                                self.scaling)
+                share = jax.nn.sigmoid(logits)
+                share = share / jnp.sum(share, -1, keepdims=True)
             self.sow("intermediates", "router_in", h)
             self.sow("intermediates", "router_logits", logits)
             self.sow("intermediates", "chosen", chosen)
-            share = jax.nn.sigmoid(logits)
-            share = share / jnp.sum(share, -1, keepdims=True)
             entropy = -jnp.mean(jnp.sum(
                 share * jnp.log(jnp.maximum(share, 1e-30)), -1))
 
@@ -454,7 +558,7 @@ class MoeMlp(nn.Module):
         w_down = weight("w_down", (held, self.d_ff, d), outward,
                         self.out_init_scale)
         y, stats = _over_expert_shards(
-            functools.partial(routed_experts, total=self.experts_total),
+            functools.partial(routed_experts, total=choices),
             tokens, held)(h, chosen, weights, w_gate, w_up, w_down,
                           jnp.int32(lo))
         if self.shared_d_ff:
@@ -467,11 +571,15 @@ class MoeMlp(nn.Module):
                               ("mlp", "embed"), self.out_init_scale)
                 y = y + (nn.silu(h @ gate) * (h @ up)) @ down
         dropped, n_mine, overflow, largest = stats
-        counters = jnp.stack([
+        counted = [
             dropped,
             n_mine / tokens,
             largest * held / jnp.maximum(n_mine, 1.0),
             n_mine / rows_bound(tokens, self.k, held),
             entropy,
-            overflow])
-        return y.reshape(batch, seq, d), counters
+            overflow]
+        if self.skip_choice:
+            counted.append(jnp.mean(chosen == self.experts_total,
+                                    dtype=jnp.float32))
+        counters = jnp.stack(counted)
+        return y.reshape(batch, seq, d), counters, state

@@ -43,6 +43,9 @@ LAGUNA = ("laguna", dict(
     size="xs.2", seq_len=8192, vocab=12544, remat_policy="full",
     layer_types=["full_attention"] + ["sliding_attention"] * 3
     + ["full_attention"], experts_held=(0, 32), **_CHIP))
+ZAYA = ("zaya", dict(
+    size="8b", seq_len=8192, vocab=32784, remat_policy="full",
+    layer_types=["hybrid"] * 6, experts_held=(0, 8), **_CHIP))
 
 #: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
@@ -50,7 +53,8 @@ LAGUNA = ("laguna", dict(
 #: (15.668 with the flash forward's `out` kept as well: PERF.md section 6,
 #: PR 30), XL's shard 14.111, the hybrid's 15.601, Ouro's 15.488, Laguna's
 #: share 15.006 (15.227 before the expert layer's sort went in pieces,
-#: PR 32; 14.999 before the window kernels' band path, PR 34) — a change to the shared block, kernels or policy may not grow
+#: PR 32; 14.999 before the window kernels' band path, PR 34), ZAYA1's
+#: share 15.000 (PR 35) — a change to the shared block, kernels or policy may not grow
 #: them unseen.
 PROGRAMS = {
     "one": (MEDIUM, "dp=1", 8, 1, "adamw", None),    # chip_smoke train/resume/elastic
@@ -65,6 +69,7 @@ PROGRAMS = {
     "hybrid_4x2": (HYBRID, "dp=1", 8, 4, "adamw", 15.61),
     "ouro_4x1": (OURO, "dp=1", 4, 4, "adamw", 15.50),
     "laguna_1x2": (LAGUNA, "dp=1", 2, 1, "adamw", 15.05),
+    "zaya_1x2": (ZAYA, "dp=1", 2, 1, "adamw", 15.05),
 }
 
 
@@ -82,9 +87,6 @@ def compile_program(name: str, devices):
     from easydl_tpu.models.registry import get_model
     from easydl_tpu.ops import moe
 
-    # the expert layer asks jax.devices() too (the CPU here) whether its
-    # kernel is compiled or interpreted: compiled, as on the chip
-    moe._on_tpu = lambda: True
     (factory, kwargs), key, batch, accum, optimizer, _ = PROGRAMS[name]
     bundle = get_model(factory, **kwargs)
     spec = MeshSpec.parse(key)
@@ -94,9 +96,17 @@ def compile_program(name: str, devices):
         config=TrainConfig(global_batch=batch, grad_accum=accum),
         mesh=build_mesh(spec, devices=devices[:spec.size]))
     tokens = jax.ShapeDtypeStruct((batch, kwargs["seq_len"]), jnp.int32)
-    compiled = trainer.step_fn.lower(
-        trainer.abstract_state(),
-        {"inputs": tokens, "targets": tokens}).compile()
+    # the expert layer asks jax.devices() too (the CPU here) whether its
+    # kernel is compiled or interpreted: compiled, as on the chip — while
+    # this program is traced and no longer (a test calls this in a process
+    # whose later tests run the layer on the CPU: tests/test_tpu_compile.py)
+    on_tpu, moe._on_tpu = moe._on_tpu, lambda: True
+    try:
+        compiled = trainer.step_fn.lower(
+            trainer.abstract_state(),
+            {"inputs": tokens, "targets": tokens}).compile()
+    finally:
+        moe._on_tpu = on_tpu
     mem = compiled.memory_analysis()
     return compiled, (mem.argument_size_in_bytes
                       + mem.temp_size_in_bytes) / 2**30

@@ -314,7 +314,7 @@ def test_the_shares_add_up_to_the_uncut_reference_layer():
                        d_ff=f, shared_d_ff=f, k=k, scaling=2.5)
         mine = dict(params, **{name: params[name][lo:lo + 4]
                                for name in ("w_gate", "w_up", "w_down")})
-        y, counters = share.apply({"params": mine}, x)
+        y, counters, _ = share.apply({"params": mine}, x)
         parts.append(y)
         dropped += float(counters[0])
         rows += float(counters[1])
@@ -323,7 +323,7 @@ def test_the_shares_add_up_to_the_uncut_reference_layer():
     assert dropped == 0.0
     assert rows == pytest.approx(k)  # every choice fell on exactly one share
     # and the whole layer alone gives the same
-    y, _ = whole.apply({"params": params}, x)
+    y, _, _ = whole.apply({"params": params}, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
 
 

@@ -218,7 +218,7 @@ def test_moe_mlp_forward_and_grads():
     params = layer.init(jax.random.PRNGKey(2), x)
 
     def loss(params, x):
-        y, counters = layer.apply(params, x)
+        y, counters, _ = layer.apply(params, x)
         return (y ** 2).mean(), counters
 
     (val, counters), grads = jax.value_and_grad(loss, has_aux=True)(params, x)
