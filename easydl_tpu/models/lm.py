@@ -154,7 +154,9 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     ``moe_load_max_over_mean``, ``moe_buffer_fill`` (landed rows over the
     bound), ``router_entropy``, ``moe_overflow``, the share of the
     step's expert-layer calls whose landed rows needed more than one piece
-    of the sort, and, where the router has a skip choice, ``moe_skipped``,
+    of the sort, ``moe_tile_fill``, the landed rows over the rows of the
+    row tiles the grouped products visited, and, where the router has a
+    skip choice, ``moe_skipped``,
     the share of tokens that took it; where the router's state runs through
     the depth, ``router_state_rms``, its size after the last layer."""
     model = Transformer(cfg)

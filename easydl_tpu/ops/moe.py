@@ -8,10 +8,29 @@ The layer holds a contiguous range ``experts_held = [lo, hi)`` of the routed
 experts — all of them, or one chip's share of an expert-parallel group — and
 computes ITS experts' part of the result: the choices that fall in the range
 sorted by expert, their tokens' rows gathered, three grouped matrix products
-over the experts held (``jax.lax.ragged_dot``: on a TPU one Mosaic kernel
-that walks only the tiles the groups fill), the weighted sum back per token;
-plus the shared expert, once. What absent experts would have added is left
-out; no code stands in for them or for their traffic.
+over the experts held, the weighted sum back per token; plus the shared
+expert, once. What absent experts would have added is left out; no code
+stands in for them or for their traffic.
+
+The grouped products are Pallas kernels of this module (:func:`grouped_rows`
+``[R, C] x [G, C, N] -> [R, N]``, with the weights as they lie or transposed,
+and :func:`grouped_weights` ``[R, C]^T [R, N] -> [G, C, N]``). A schedule of
+(group, row tile) steps is built on the device from the groups' sizes and
+handed in by scalar prefetch: row tiles on the rows' own grid, a group's tiles
+one after the other with its weight block — the contraction whole, every
+column where 8 MiB hold them — resident meanwhile, a tile two groups share
+visited once for each and stored under a mask, float32 sums and one rounding.
+Rows behind the last group are neither visited nor trusted: the row kernels
+write nothing there that is read, the weight gradient stops at the last
+group's end and masks a tile's foreign rows on both operands. The tiles come
+from the shapes (:func:`choose_tiles`). The experts' differentiation rule is
+written out around them (:func:`_piece_backward`): a Pallas call has no
+derivative, and the rule needs no product a third time. Measured on the chip
+at ZAYA1's 8 experts of 2048 x 2048 over 7,700 of 15,488 rows (PERF.md section
+6, PR 36): the compiler's own grouped product, which this replaced — itself a
+Mosaic call, on row tiles of 128 — 1.14 / 0.76 / 0.93 ms a call by form (29 /
+43 / 35% of the MXU's peak for the rows that landed), these 0.53 / 0.53 / 0.55
+(62%).
 
 No token is ever dropped, and the layer moves the rows that landed, not the
 rows that could. A token's ``k`` choices are distinct experts, so at most
@@ -82,7 +101,8 @@ EXPERT_AXIS = "ep"
 
 #: the layer's counters, in the order of the vector it returns
 COUNTERS = ("moe_dropped", "moe_rows_per_token", "moe_load_max_over_mean",
-            "moe_buffer_fill", "router_entropy", "moe_overflow")
+            "moe_buffer_fill", "router_entropy", "moe_overflow",
+            "moe_tile_fill")
 #: the router's forms
 ROUTERS = ("linear-sigmoid-renormalised", "mlp-softmax-top1")
 #: the MLP router's three maps start orthogonal (the last with orthonormal
@@ -184,12 +204,231 @@ def _piece(p, piece, order, by_token, ends, rowed):
             (at, number, jnp.clip(rowed - start, 0, piece)))
 
 
-def _experts(x, w_gate, w_up, w_down, sizes):
-    """The SwiGLU experts over rows ``x [R, D]`` sorted by expert, ``sizes``
-    rows each; rows behind the last group are not visited."""
-    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
-                            preferred_element_type=x.dtype)
-    return dot(nn.silu(dot(x, w_gate)) * dot(x, w_up), w_down)
+# ------------------------------------------------------ the grouped products
+#: ``dot_general`` dimension numbers: ``A B``, ``A B^T`` and ``A^T B``
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+#: the VMEM a grouped product may take (a v5e's is 128 MiB, the compiler's
+#: own limit 16): a weight block of :data:`WEIGHT_BLOCK_BYTES` twice for each
+#: operand pair — or, for a weight gradient, its float32 sum and the rounded
+#: block twice — and the operands' row tiles twice
+_GROUPED_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
+#: the most an expert's weight block holds, kept resident while the expert's
+#: rows pass: the whole contraction by as many columns as fit (ZAYA1's 2048
+#: x 2048 in bf16 whole; measured on the chip, PERF.md section 6, PR 36:
+#: blocks of 512 and 1,024 of its columns cost 14 and 5% more)
+WEIGHT_BLOCK_BYTES = 8 << 20
+#: the most rows a grouped product's row tile holds
+ROW_TILE = 512
+#: a grid step of a grouped product does at least this many FLOPs (2.7 us at
+#: a v5e's peak, eight times what a step costs to start) where the rows a
+#: group expects allow it
+STEP_FLOPS = 1 << 29
+
+
+def choose_tiles(rows: int, groups: int, contract: int, cols: int,
+                 itemsize: int) -> Tuple[int, int]:
+    """``(row tile, column tile)`` of a grouped product ``[rows, contract]
+    x [groups, contract, cols]`` (or its transpose's, or the weight
+    gradient ``[rows, contract]^T [rows, cols]``) from its shapes alone: the
+    contraction whole, as many columns as keep a weight block within
+    :data:`WEIGHT_BLOCK_BYTES`, and the least row tile — a multiple of the
+    MXU's 128, no more than :data:`ROW_TILE` — that gives a step
+    :data:`STEP_FLOPS`, but no more than half the rows a group expects
+    (``rows`` is a piece: :data:`PIECE_OVER_EXPECTED` times what lands): a
+    group at an arbitrary offset touches ``expected / tile + 1`` tiles, so
+    small tiles waste the fewest rows at its edges and large ones the
+    fewest steps. ZAYA1's 2048 x 2048 over 964 rows a group gets (128,
+    2048), Laguna's 2048 x 512 and 512 x 2048 over 512 rows (256, whole) —
+    the fastest of the nine measured for each form on the chip (PERF.md
+    section 6, PR 36)."""
+    most = max(WEIGHT_BLOCK_BYTES // (contract * itemsize), TILE)
+    tn = cols if cols <= most else most // TILE * TILE
+    expected = rows // (PIECE_OVER_EXPECTED * groups)
+    tm = TILE
+    while 2 * tm <= min(ROW_TILE, expected // 2) \
+            and 2 * tm * contract * tn < STEP_FLOPS:
+        tm *= 2
+    return min(tm, rows), tn
+
+
+def _schedule(sizes, rows: int, tile: int, empty_too: bool):
+    """The (group, row tile) steps of a grouped product over ``rows`` rows
+    sorted by group, ``sizes`` rows each, in row tiles of ``tile`` on the
+    rows' own grid: ``(group [S], tile [S], meta [G + 2])`` — a group's
+    tiles one after the other (so its weight block stays where it is), a
+    tile two groups share once for each; with ``empty_too`` a group without
+    rows has one step (its weight gradient is written too). ``meta`` holds
+    the groups' first rows, the last one's end, and the steps that count:
+    a step past them repeats the last (nothing moves) and does nothing, so
+    tiles behind the last group are not visited."""
+    groups = sizes.shape[0]
+    tiles = -(-rows // tile)
+    steps = tiles + groups - 1  # no schedule is longer
+    ends = jnp.minimum(jnp.cumsum(sizes), rows).astype(jnp.int32)
+    starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
+    first = starts // tile
+    n = jnp.where(ends > starts, -(-ends // tile) - first, int(empty_too))
+    done = jnp.cumsum(n)
+    total = done[-1]
+    s = jnp.minimum(jnp.arange(steps), jnp.maximum(total - 1, 0))
+    # the group of step s: how many groups are done by then (a comparison,
+    # not a search: the compiler merges equal schedules — a recomputed
+    # forward's and the backward's — and with them the equal products, which
+    # it does not do across a search's loop)
+    group = jnp.minimum(jnp.sum(done[None, :] <= s[:, None], 1), groups - 1)
+    at = jnp.minimum(first[group] + s - (done[group] - n[group]), tiles - 1)
+    meta = jnp.concatenate([starts, ends[-1:], total[None]])
+    return group.astype(jnp.int32), at.astype(jnp.int32), meta
+
+
+def _own_rows(s, tile_ref, meta_ref, group, tm):
+    """``(row numbers [tm, 1], first row, end, whole)`` of step ``s``'s tile
+    and its group's rows, and whether the tile lies inside the group."""
+    first = tile_ref[s] * tm
+    lo, hi = meta_ref[group], meta_ref[group + 1]
+    row = first + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return row, lo, hi, (lo <= first) & (first + tm <= hi)
+
+
+def _rows_kernel(group_ref, tile_ref, meta_ref, *refs, dims, groups):
+    """One step of :func:`grouped_rows`: the row tile ``tile_ref[s]`` of
+    each operand times its group's weight block, summed in float32 and
+    rounded once. A tile inside its group is stored as it is; at a group's
+    edge the rows of earlier groups keep what the step before wrote (the
+    tile is still resident), later rows get zeros."""
+    *operands, out_ref = refs
+    s = pl.program_id(1)
+
+    @pl.when(s < meta_ref[groups + 1])
+    def _():
+        tm = out_ref.shape[0]
+        row, lo, hi, whole = _own_rows(s, tile_ref, meta_ref, group_ref[s],
+                                       tm)
+        half = len(operands) // 2
+        acc = sum(jax.lax.dot_general(x[...], w[...], dims,
+                                      preferred_element_type=jnp.float32)
+                  for x, w in zip(operands[:half], operands[half:]))
+
+        @pl.when(whole)
+        def _():
+            out_ref[...] = acc.astype(out_ref.dtype)
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            out_ref[...] = jnp.where(
+                row < lo, out_ref[...].astype(jnp.float32),
+                jnp.where(row < hi, acc, 0.0)).astype(out_ref.dtype)
+
+
+# The two products are jitted for the tracing's sake too: calls of one shape
+# — gate and up; the forward's and the backward's, which makes them again —
+# share one trace and one lowered kernel, so that a program which holds them
+# beside each other on equal operands (the backward of a rematerialised
+# layer) holds two EQUAL calls and the compiler makes the product once. Traced
+# twice they are not equal: a kernel's payload carries its call site.
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def grouped_rows(xs, ws, sizes, transposed: bool, interpret: bool):
+    """``sum_i xs[i][rows of g] @ ws[i][g]`` (``transposed``: ``@
+    ws[i][g]^T``) for every group ``g`` of rows — ``xs[i] [R, C]`` sorted by
+    group, ``sizes [G]`` rows each, ``ws[i] [G, C, N]`` (``[G, N, C]``) — as
+    ``[R, N]`` in the operands' dtype, accumulated in float32 and rounded
+    once. Rows behind the last group hold nothing to be read: their tiles
+    are not visited. Tiles by :func:`choose_tiles`; the visited tiles' rows
+    are the second result."""
+    rows, contract = xs[0].shape
+    groups = ws[0].shape[0]
+    cols = ws[0].shape[1 if transposed else 2]
+    tm, tn = choose_tiles(rows, groups, contract, cols, xs[0].dtype.itemsize)
+    group, at, meta = _schedule(sizes, rows, tm, False)
+    x_spec = pl.BlockSpec((tm, contract), lambda n, s, g, t, _: (t[s], 0))
+    w_spec = pl.BlockSpec(
+        (None, tn, contract) if transposed else (None, contract, tn),
+        (lambda n, s, g, t, _: (g[s], n, 0)) if transposed
+        else (lambda n, s, g, t, _: (g[s], 0, n)))
+    out = pl.pallas_call(
+        functools.partial(_rows_kernel, dims=_NT if transposed else _NN,
+                          groups=groups),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(-(-cols // tn), group.size),
+            in_specs=[x_spec] * len(xs) + [w_spec] * len(ws),
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda n, s, g, t, _: (t[s], n))),
+        out_shape=jax.ShapeDtypeStruct((rows, cols), xs[0].dtype),
+        compiler_params=_GROUPED_PARAMS,
+        interpret=interpret,
+        name="grouped_rows_t" if transposed else "grouped_rows",
+    )(group, at, meta, *xs, *ws)
+    return out, meta[groups + 1] * tm
+
+
+def _weights_kernel(group_ref, tile_ref, meta_ref, x_ref, y_ref, out_ref,
+                    acc_ref, *, groups):
+    """One step of :func:`grouped_weights`: ``x^T y`` of a row tile added to
+    its group's float32 block, the rows of other groups and behind the last
+    taken as zeros whatever they hold; the block is zeroed at the group's
+    first step and rounded into the result at its last."""
+    s = pl.program_id(1)
+    last = meta_ref[groups + 1] - 1
+    g = group_ref[s]
+
+    @pl.when((s <= last)
+             & ((s == 0) | (g != group_ref[jnp.maximum(s - 1, 0)])))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(s <= last)
+    def _():
+        tm = x_ref.shape[0]
+        row, lo, hi, whole = _own_rows(s, tile_ref, meta_ref, g, tm)
+
+        def add(x, y):
+            acc_ref[...] += jax.lax.dot_general(
+                x, y, _TN, preferred_element_type=jnp.float32)
+
+        @pl.when(whole)
+        def _():
+            add(x_ref[...], y_ref[...])
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            mine = (row >= lo) & (row < hi)
+            add(jnp.where(mine, x_ref[...], 0), jnp.where(mine, y_ref[...], 0))
+
+    @pl.when((s == last)
+             | (g != group_ref[jnp.minimum(s + 1, pl.num_programs(1) - 1)]))
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def grouped_weights(x, y, sizes, interpret: bool):
+    """``x[rows of g]^T @ y[rows of g]`` for every group: ``[G, C, N]`` of
+    ``x [R, C]`` and ``y [R, N]`` sorted by group, ``sizes [G]`` rows each,
+    in their dtype, accumulated in float32 over the group's rows and rounded
+    once; a group without rows gets zeros, rows behind the last group are
+    not read."""
+    rows, contract = x.shape
+    cols = y.shape[1]
+    groups = sizes.shape[0]
+    tm, tn = choose_tiles(rows, groups, contract, cols, x.dtype.itemsize)
+    group, at, meta = _schedule(sizes, rows, tm, True)
+    return pl.pallas_call(
+        functools.partial(_weights_kernel, groups=groups),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(-(-cols // tn), group.size),
+            in_specs=[
+                pl.BlockSpec((tm, contract), lambda n, s, g, t, _: (t[s], 0)),
+                pl.BlockSpec((tm, tn), lambda n, s, g, t, _: (t[s], n))],
+            out_specs=pl.BlockSpec((None, contract, tn),
+                                   lambda n, s, g, t, _: (g[s], 0, n)),
+            scratch_shapes=[pltpu.VMEM((contract, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((groups, contract, cols), x.dtype),
+        compiler_params=_GROUPED_PARAMS,
+        interpret=interpret,
+        name="grouped_weights",
+    )(group, at, meta, x, y)
 
 
 def _sum_kernel(blk_ref, chunk_ref, ends_ref, rows_ref, tok_ref, w_ref,
@@ -287,8 +526,9 @@ def _over_pieces(one, n):
 @functools.partial(jax.jit, static_argnums=(0,))
 def _piece_forward(how, p, h, weights, w_gate, w_up, w_down, order, by_token,
                    ends, rowed):
-    """``(y [T, D] float32, live rows)`` of piece ``p``: its tokens' rows,
-    the experts' results, their weighted sum back into the tokens."""
+    """``(y [T, D] float32, live rows, rows of the row tiles visited)`` of
+    piece ``p``: its tokens' rows, the SwiGLU experts' results (three
+    grouped products), their weighted sum back into the tokens."""
     piece, interpret = how
     tokens, k = weights.shape
     choice, live, sizes, token_order = _piece(p, piece, order, by_token, ends,
@@ -296,60 +536,85 @@ def _piece_forward(how, p, h, weights, w_gate, w_up, w_down, order, by_token,
     with jax.named_scope("dispatch"):
         x = h[choice // k]
     with jax.named_scope("experts"):
-        out = _experts(x, w_gate, w_up, w_down, sizes)
+        gate, visited = grouped_rows([x], [w_gate], sizes, False, interpret)
+        up, _ = grouped_rows([x], [w_up], sizes, False, interpret)
+        out, _ = grouped_rows([nn.silu(gate) * up], [w_down], sizes, False,
+                              interpret)
     with jax.named_scope("combine"):
         y = _to_tokens(out, weights.reshape(-1)[token_order[1]], token_order,
                        tokens, k, interpret)
-    return y, jnp.sum(live, dtype=jnp.int32)
+    return y, jnp.sum(live, dtype=jnp.int32), visited
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _piece_backward(how, p, g, h, weights, w_gate, w_up, w_down, order,
                     by_token, ends, rowed):
     """Piece ``p``'s part of the gradients by ``h`` (float32), ``weights``
-    (flat) and the three expert weights under the cotangent ``g [T, D]``;
-    the piece's products are made again."""
+    (flat) and the three expert weights under the cotangent ``g [T, D]``,
+    the experts' rule written out: the gate and up products are made again,
+    the down product is not — with ``u = g_rows W_down^T`` a row's result
+    times its token's cotangent is ``<act, u>`` (the choice's weight
+    gradient), ``scale * u`` the activation's cotangent (the scale applied
+    in float32, after the product), and ``(scale * act)^T g_rows`` the down
+    weights' gradient. Eight grouped products: two again, ``u``, the two
+    that give the rows' gradient summed in one call, three weight
+    gradients."""
     piece, interpret = how
     tokens, k = weights.shape
     choice, live, sizes, token_order = _piece(p, piece, order, by_token, ends,
                                               rowed)
     tok = choice // k
+    f32 = jnp.float32
     with jax.named_scope("dispatch"):
         x = h[tok]
-    with jax.named_scope("experts"):
-        out, experts_bwd = jax.vjp(
-            functools.partial(_experts, sizes=sizes), x, w_gate, w_up, w_down)
     with jax.named_scope("combine"):
-        scale = jnp.where(live, weights.reshape(-1)[choice], 0.0)
-        g_rows = g[tok].astype(jnp.float32)
-        d_out = (g_rows * scale[:, None]).astype(out.dtype)
-        # a choice's weight gets its row's product with the token's g:
-        # scalars, put back by the choice's number (each has one row; a
-        # dead row's goes nowhere)
-        d_weights = jnp.zeros((tokens * k,), jnp.float32).at[
-            jnp.where(live, choice, tokens * k)].set(
-                jnp.sum(out.astype(jnp.float32) * g_rows, -1), mode="drop",
-                unique_indices=True)
+        scale = jnp.where(live, weights.reshape(-1)[choice], 0.0)[:, None]
+        g_rows = g[tok]
     with jax.named_scope("experts"):
-        d_x, d_gate, d_up, d_down = experts_bwd(d_out)
+        gate, _ = grouped_rows([x], [w_gate], sizes, False, interpret)
+        up, _ = grouped_rows([x], [w_up], sizes, False, interpret)
+        u = grouped_rows([g_rows], [w_down], sizes, True,
+                         interpret)[0].astype(f32)
+        act = (nn.silu(gate) * up).astype(f32)  # rounded as the forward's
+        gate, up = gate.astype(f32), up.astype(f32)
+        sig = jax.nn.sigmoid(gate)
+        d_act = scale * u
+        d_gate_rows = (d_act * up * sig * (1 + gate * (1 - sig))).astype(
+            x.dtype)
+        d_up_rows = (d_act * gate * sig).astype(x.dtype)
+        d_x, _ = grouped_rows([d_gate_rows, d_up_rows], [w_gate, w_up], sizes,
+                              True, interpret)
+        d_gate = grouped_weights(x, d_gate_rows, sizes, interpret)
+        d_up = grouped_weights(x, d_up_rows, sizes, interpret)
+        d_down = grouped_weights((scale * act).astype(x.dtype), g_rows, sizes,
+                                 interpret)
+    with jax.named_scope("combine"):
+        # a choice's weight gets its row's result times the token's g:
+        # scalars, put back by the choice's number (each has one row; a
+        # dead row's goes nowhere, whatever it holds)
+        d_weights = jnp.zeros((tokens * k,), f32).at[
+            jnp.where(live, choice, tokens * k)].set(
+                jnp.sum(act * u, -1), mode="drop", unique_indices=True)
     with jax.named_scope("dispatch"):
-        d_h = _to_tokens(d_x, jnp.ones_like(scale), token_order, tokens, k,
-                         interpret)
+        d_h = _to_tokens(d_x, jnp.ones_like(scale[:, 0]), token_order, tokens,
+                         k, interpret)
     return d_h, d_weights, d_gate, d_up, d_down
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _routed(how, h, weights, w_gate, w_up, w_down, order, by_token, ends,
             rowed):
-    """``(y [T, D], rows [] int32)``: the experts' results for the first
-    ``rowed`` rows of the sort, weighted and summed into their tokens in
-    float32, a piece at a time (``how = (piece, interpret)``); and the rows
-    that were visited. The differentiation rule is written out: reverse
-    mode cannot differentiate a loop whose length is traced."""
+    """``(y [T, D], rows [] int32, tile rows [] int32)``: the experts'
+    results for the first ``rowed`` rows of the sort, weighted and summed
+    into their tokens in float32, a piece at a time (``how = (piece,
+    interpret)``); the rows that were visited; and the rows of the row tiles
+    the grouped products' schedules visited for them. The differentiation
+    rule is written out: reverse mode cannot differentiate a loop whose
+    length is traced, nor a Pallas call."""
     args = (h, weights, w_gate, w_up, w_down, order, by_token, ends, rowed)
-    y, rows = _over_pieces(lambda p: _piece_forward(how, p, *args),
-                           -(-rowed // how[0]))
-    return y.astype(h.dtype), rows
+    y, rows, visited = _over_pieces(lambda p: _piece_forward(how, p, *args),
+                                    -(-rowed // how[0]))
+    return y.astype(h.dtype), rows, visited
 
 
 def _routed_fwd(how, *args):
@@ -357,7 +622,7 @@ def _routed_fwd(how, *args):
 
 
 def _routed_bwd(how, args, cts):
-    g = cts[0]  # [T, D]; the visited rows are an integer
+    g = cts[0]  # [T, D]; the visited rows and tiles are integers
     d_h, d_weights, *d_experts = _over_pieces(
         lambda p: _piece_backward(how, p, g, *args), -(-args[-1] // how[0]))
     h, weights = args[:2]
@@ -368,12 +633,10 @@ def _routed_bwd(how, args, cts):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-def routed_experts(h, chosen, weights, w_gate, w_up, w_down, lo, total):
-    """The part of the routed result the experts ``[lo, lo + E)`` of
-    ``total`` give, ``E = w_gate.shape[0]``: ``(y [T, D], stats [4])`` with
-    ``stats`` = (choices in the range that got no row, choices in the
-    range, whether they needed more than one piece, the largest expert's
-    rows), float32. ``lo`` may be traced (a shard's own under ``ep``)."""
+def _routed_part(h, chosen, weights, w_gate, w_up, w_down, lo, total):
+    """:func:`routed_experts` with a fifth number: the rows of the row tiles
+    the grouped products visited (the landed rows over them is the layer's
+    ``moe_tile_fill``)."""
     tokens, k = chosen.shape
     held = w_gate.shape[0]
     bound = rows_bound(tokens, k, held)
@@ -398,11 +661,22 @@ def routed_experts(h, chosen, weights, w_gate, w_up, w_down, lo, total):
             (jnp.where(row < rowed, row // piece * (tokens * k) + order,
                        jnp.iinfo(jnp.int32).max), row % piece), num_keys=1)
         by_token = (at, number % (tokens * k))
-    y, rows = _routed((piece, not _on_tpu()), h, weights, w_gate, w_up,
-                      w_down, order, by_token, ends, rowed)
+    y, rows, visited = _routed((piece, not _on_tpu()), h, weights, w_gate,
+                               w_up, w_down, order, by_token, ends, rowed)
     stats = jnp.stack([landed - rows, landed, jnp.int32(landed > piece),
-                       jnp.max(sizes)]).astype(jnp.float32)
+                       jnp.max(sizes), visited]).astype(jnp.float32)
     return y, stats
+
+
+def routed_experts(h, chosen, weights, w_gate, w_up, w_down, lo, total):
+    """The part of the routed result the experts ``[lo, lo + E)`` of
+    ``total`` give, ``E = w_gate.shape[0]``: ``(y [T, D], stats [4])`` with
+    ``stats`` = (choices in the range that got no row, choices in the
+    range, whether they needed more than one piece, the largest expert's
+    rows), float32. ``lo`` may be traced (a shard's own under ``ep``)."""
+    y, stats = _routed_part(h, chosen, weights, w_gate, w_up, w_down, lo,
+                            total)
+    return y, stats[:4]
 
 
 def _over_expert_shards(fn, tokens: int, held: int):
@@ -410,7 +684,7 @@ def _over_expert_shards(fn, tokens: int, held: int):
     of the expert weights under the context mesh, the parts summed over
     ``ep`` (and the stats with them: sums summed, the shards' calls that
     needed more than one piece as their share, the largest group the largest
-    anywhere); ``fn`` itself where the mesh has no ``ep`` axis larger than
+    anywhere, the visited tiles' rows summed); ``fn`` itself where the mesh has no ``ep`` axis larger than
     one. Tokens are split over the batch axes where they
     divide, as attention's per-shard wrap has it."""
     mesh = jax.sharding.get_abstract_mesh()
@@ -437,7 +711,8 @@ def _over_expert_shards(fn, tokens: int, held: int):
         return jax.lax.psum(y, EXPERT_AXIS), jnp.concatenate([
             jax.lax.psum(stats[:2], every),
             jax.lax.pmean(stats[2:3], every),
-            jax.lax.pmax(stats[3:], every) * batch_shards])
+            jax.lax.pmax(stats[3:4], every) * batch_shards,
+            jax.lax.psum(stats[4:], every)])
 
     rows, experts = P(batch or None), P(EXPERT_AXIS)
     return jax.shard_map(
@@ -453,7 +728,9 @@ class MoeMlp(nn.Module):
     expert's rows over the mean, landed rows over the bound, the router's
     entropy, whether the landed rows needed more than one piece
     (:func:`piece_rows` of the router's width: the share of the shards'
-    calls under ``ep``) and, with ``skip_choice``, the share of tokens that
+    calls under ``ep``), the landed rows over the rows of the row tiles the
+    grouped products visited for them (1 where nothing landed) and, with
+    ``skip_choice``, the share of tokens that
     took it. ``state`` is the router state handed on ``[B, S,
     router_hidden]`` float32 (``mlp-softmax-top1``: it takes the previous
     layer's as its second argument), else None.
@@ -461,8 +738,9 @@ class MoeMlp(nn.Module):
     Scopes (``jax.named_scope``): ``router`` (``router_eda`` and
     ``router_mlp`` inside it where the router is the MLP), ``dispatch`` (the
     sort, a piece's gather and the transpose's sum back to the tokens),
-    ``experts``, ``combine`` (the weighted sum back to the tokens and its
-    transpose), ``shared_expert``; the caller's ``moe`` scope is around them,
+    ``experts`` (the kernels ``grouped_rows``, ``grouped_rows_t`` and
+    ``grouped_weights`` and the activation between them), ``combine`` (the
+    weighted sum back to the tokens and its transpose), ``shared_expert``; the caller's ``moe`` scope is around them,
     a ``while`` inside where a piece is not the first. Where
     ``intermediates`` is a mutable collection (the benchmark's check, tests)
     the layer also sows what it routed on: ``router_in``, ``router_logits``,
@@ -558,7 +836,7 @@ class MoeMlp(nn.Module):
         w_down = weight("w_down", (held, self.d_ff, d), outward,
                         self.out_init_scale)
         y, stats = _over_expert_shards(
-            functools.partial(routed_experts, total=choices),
+            functools.partial(_routed_part, total=choices),
             tokens, held)(h, chosen, weights, w_gate, w_up, w_down,
                           jnp.int32(lo))
         if self.shared_d_ff:
@@ -570,14 +848,15 @@ class MoeMlp(nn.Module):
                 down = weight("shared_down", (self.shared_d_ff, d),
                               ("mlp", "embed"), self.out_init_scale)
                 y = y + (nn.silu(h @ gate) * (h @ up)) @ down
-        dropped, n_mine, overflow, largest = stats
+        dropped, n_mine, overflow, largest, visited = stats
         counted = [
             dropped,
             n_mine / tokens,
             largest * held / jnp.maximum(n_mine, 1.0),
             n_mine / rows_bound(tokens, self.k, held),
             entropy,
-            overflow]
+            overflow,
+            jnp.where(visited > 0, n_mine / jnp.maximum(visited, 1.0), 1.0)]
         if self.skip_choice:
             counted.append(jnp.mean(chosen == self.experts_total,
                                     dtype=jnp.float32))

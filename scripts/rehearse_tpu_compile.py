@@ -52,9 +52,11 @@ ZAYA = ("zaya", dict(
 #: limit is its own compiled size and a little: medium's steps 15.292
 #: (15.668 with the flash forward's `out` kept as well: PERF.md section 6,
 #: PR 30), XL's shard 14.111, the hybrid's 15.601, Ouro's 15.488, Laguna's
-#: share 15.006 (15.227 before the expert layer's sort went in pieces,
-#: PR 32; 14.999 before the window kernels' band path, PR 34), ZAYA1's
-#: share 15.000 (PR 35) — a change to the shared block, kernels or policy may not grow
+#: share 14.851 (15.227 before the expert layer's sort went in pieces,
+#: PR 32; 15.006 before its grouped products were kernels of the repo's own,
+#: PR 36), ZAYA1's share 14.869 (15.000 before PR 36: the backward keeps no
+#: float32 copy of the cotangent's rows and no third result of the experts)
+#: — a change to the shared block, kernels or policy may not grow
 #: them unseen.
 PROGRAMS = {
     "one": (MEDIUM, "dp=1", 8, 1, "adamw", None),    # chip_smoke train/resume/elastic

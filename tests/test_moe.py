@@ -281,3 +281,231 @@ def test_laguna_trains_on_ep_mesh(eight_devices):
     _, first = one.train_step(one.init_state(), batches[0])
     assert float(first["loss"]) == np.float32(losses[0]) or abs(
         float(first["loss"]) - losses[0]) < 1e-4
+
+
+# ------------------------------------------------------ the grouped products
+def _groups_loop(x, w, sizes, transposed=False):
+    """``x[a:b] @ w[g]`` group by group (float64 on the host); rows behind
+    the last group stay NaN: nothing may read them."""
+    x, w = np.asarray(x, np.float64), np.asarray(w, np.float64)
+    out = np.full((x.shape[0], w.shape[1 if transposed else 2]), np.nan)
+    a = 0
+    for g, n in enumerate(sizes):
+        out[a:a + n] = x[a:a + n] @ (w[g].T if transposed else w[g])
+        a += n
+    return out
+
+
+#: rows, the groups' sizes: with row tiles of 128 —
+GROUPINGS = {
+    "an_empty_group": (384, [100, 0, 150, 30]),
+    "empty_first_and_last": (384, [0, 200, 100, 0]),
+    "one_group_holds_every_row": (384, [0, 384, 0]),
+    "a_group_over_several_tiles": (640, [60, 400, 90]),
+    "sizes_off_the_tile_grid": (300, [1, 127, 129, 43]),
+    "rows_off_the_tile_grid_all_live": (200, [50, 150]),
+    "zero_landed_rows": (256, [0, 0, 0]),
+    "groups_on_the_tile_grid": (512, [128, 256, 128]),
+}
+
+
+def _grouped_case(name, dtype, contract=16, cols=24):
+    rows, sizes = GROUPINGS[name]
+    rng = np.random.default_rng(len(name))
+    live = np.arange(rows)[:, None] < sum(sizes)
+    # NaN in every row behind the last group, of both row operands
+    x = jnp.asarray(np.where(live, rng.standard_normal((rows, contract)),
+                             np.nan), dtype)
+    y = jnp.asarray(np.where(live, rng.standard_normal((rows, cols)), np.nan),
+                    dtype)
+    w = jnp.asarray(rng.standard_normal((len(sizes), contract, cols)), dtype)
+    return x, y, w, sizes, jnp.asarray(sizes, jnp.int32)
+
+
+def _weights_loop(x, y, sizes):
+    """``x[a:b]^T @ y[a:b]`` group by group (float64 on the host)."""
+    x, y = np.asarray(x, np.float64), np.asarray(y, np.float64)
+    ends = np.cumsum(sizes)
+    return np.stack([x[a:b].T @ y[a:b] for a, b in zip(ends - sizes, ends)])
+
+
+def _close(got, want, dtype, live=None):
+    got, want = np.asarray(got, np.float64), np.asarray(want)
+    if live is not None:
+        got, want = got[:live], want[:live]
+    assert np.isfinite(got).all()
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(got, want, rtol=tol,
+                               atol=tol * max(np.abs(want).max(initial=0), 1))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name", list(GROUPINGS))
+def test_grouped_rows_is_a_loop_over_the_groups(name, dtype):
+    """``[R, C] x [G, C, N]`` and ``[R, N] x [G, C, N]^T`` against the loop,
+    in the operands' dtype from a float32 sum; a pair of operands is the
+    sum of both products; what rows behind the last group hold (NaN) reaches
+    no live row, and the visited tiles are the ones the groups touch."""
+    from easydl_tpu.ops.moe import grouped_rows
+
+    x, y, w, sizes, s = _grouped_case(name, dtype)
+    live = sum(sizes)
+    out, visited = jax.jit(
+        lambda x, w, s: grouped_rows([x], [w], s, False, True))(x, w, s)
+    assert out.shape == (x.shape[0], w.shape[2]) and out.dtype == dtype
+    _close(out, _groups_loop(x, w, sizes), dtype, live)
+    ends = np.cumsum(sizes)
+    touched = sum(-(-b // 128) - a // 128
+                  for a, b in zip(ends - sizes, ends) if b > a)
+    assert int(visited) == 128 * touched
+    out_t, _ = jax.jit(
+        lambda y, w, s: grouped_rows([y], [w], s, True, True))(y, w, s)
+    assert out_t.shape == x.shape and out_t.dtype == dtype
+    _close(out_t, _groups_loop(y, w, sizes, True), dtype, live)
+    both, _ = jax.jit(lambda y, w, s: grouped_rows(
+        [y, y], [w, -0.5 * w], s, True, True))(y, w, s)
+    _close(both, 0.5 * _groups_loop(y, w, sizes, True), dtype, live)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name", list(GROUPINGS))
+def test_grouped_weights_is_a_loop_over_the_groups(name, dtype):
+    """``x[rows of g]^T y[rows of g]`` for every group against the loop: an
+    empty group gets zeros, NaN rows behind the last group are not summed."""
+    from easydl_tpu.ops.moe import grouped_weights
+
+    x, y, _, sizes, s = _grouped_case(name, dtype)
+    got = jax.jit(lambda x, y, s: grouped_weights(x, y, s, True))(x, y, s)
+    assert got.shape == (len(sizes), x.shape[1], y.shape[1])
+    assert got.dtype == dtype
+    _close(got, _weights_loop(x, y, sizes), dtype)
+    for g, n in enumerate(sizes):
+        if n == 0:
+            assert not np.asarray(got[g], np.float32).any()
+
+
+def test_grouped_products_in_blocks_of_columns(monkeypatch):
+    """A weight block that holds 128 of 320 columns: three blocks, the last
+    a partial one, each expert's rows passing under each."""
+    from easydl_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "WEIGHT_BLOCK_BYTES", 128 * 16 * 4)
+    x, y, w, sizes, s = _grouped_case("an_empty_group", jnp.float32, cols=320)
+    assert moe.choose_tiles(384, 4, 16, 320, 4) == (128, 128)
+    live = sum(sizes)
+    out, _ = jax.jit(
+        lambda x, w, s: moe.grouped_rows([x], [w], s, False, True))(x, w, s)
+    _close(out, _groups_loop(x, w, sizes), jnp.float32, live)
+    back, _ = jax.jit(lambda y, w, s: moe.grouped_rows(
+        [y], [jnp.swapaxes(w, 1, 2)], s, False, True))(y, w, s)
+    _close(back, _groups_loop(y, w, sizes, True), jnp.float32, live)
+    got = jax.jit(lambda x, y, s: moe.grouped_weights(x, y, s, True))(x, y, s)
+    _close(got, _weights_loop(x, y, sizes), jnp.float32)
+
+
+@pytest.mark.parametrize("rows, groups, contract, cols, itemsize, want", [
+    # ZAYA1's cell: a piece of 15,488 rows over 8 experts of 2048 x 2048 —
+    # 964 rows a group: the least row tile, the weight block whole
+    (15488, 8, 2048, 2048, 2, (128, 2048)),
+    (15488, 8, 2048, 2048, 4, (128, 1024)),    # float32: half the columns
+    # Laguna's: 32,768 rows over 32 experts, 2048 x 512 and 512 x 2048 —
+    # a step of 128 rows is too little work, 512 rows a group allow 256
+    (32768, 32, 2048, 512, 2, (256, 512)),
+    (32768, 32, 512, 2048, 2, (256, 2048)),
+    (131072, 256, 2048, 512, 2, (128, 512)),   # all 256 held: 256 rows each
+    (65536, 8, 512, 512, 2, (512, 512)),       # small experts, many rows
+    # the tier-1 sizes: tiles of 128 rows or the rows themselves
+    (1024, 4, 16, 8, 4, (128, 8)),
+    (384, 4, 16, 24, 4, (128, 24)),
+    (64, 8, 16, 8, 4, (64, 8)),
+    (12, 4, 16, 8, 4, (12, 8)),
+])
+def test_tiles_follow_the_shapes(rows, groups, contract, cols, itemsize, want):
+    from easydl_tpu.ops.moe import choose_tiles
+
+    assert choose_tiles(rows, groups, contract, cols, itemsize) == want
+
+
+def _routing(tokens, k, held, total, rows_of):
+    """``chosen [tokens, k]``: ``rows_of[e]`` tokens choose the held expert
+    ``e`` first, every other choice falls on an expert elsewhere."""
+    chosen = held + (np.arange(tokens)[:, None] + np.arange(k)) % (total - held)
+    first = np.repeat(np.arange(held), rows_of)
+    chosen[:first.size, 0] = first
+    return jnp.asarray(chosen, jnp.int32)
+
+
+@pytest.mark.parametrize("name, tokens, k, held, total, d, f, rows_of, dtype", [
+    # ZAYA1's test widths, one choice over 8 of 17: one piece of 256 rows
+    ("zaya_test", 256, 1, 8, 17, 128, 64, [20, 0, 31, 9, 40, 1, 17, 12],
+     jnp.float32),
+    ("zaya_test_bf16", 256, 1, 8, 17, 128, 64, [20, 0, 31, 9, 40, 1, 17, 12],
+     jnp.bfloat16),
+    # Laguna's, two choices over 4 of 16: pieces of 256 of a bound of 512
+    ("laguna_test", 256, 2, 4, 16, 64, 32, [100, 3, 0, 90], jnp.float32),
+    ("laguna_test_second_piece", 256, 2, 4, 16, 64, 32, [0, 200, 56, 0],
+     jnp.float32),
+    ("laguna_test_bf16", 256, 2, 4, 16, 64, 32, [100, 3, 0, 90], jnp.bfloat16),
+    ("every_row_on_one_expert", 256, 2, 4, 16, 64, 32, [0, 0, 256, 0],
+     jnp.float32),
+    ("nothing_lands", 256, 2, 4, 16, 64, 32, [0, 0, 0, 0], jnp.float32),
+])
+def test_the_experts_rule_is_the_loops_gradient(name, tokens, k, held, total,
+                                                d, f, rows_of, dtype):
+    """The value and all five gradients through the hand-written rule — the
+    gate and up products made again, no down product, the rows' gradient as
+    one call over a pair — against reverse mode through a float32 loop over
+    the experts."""
+    chosen = _routing(tokens, k, held, total, rows_of)
+    landed = sum(rows_of)
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    args32 = (jax.random.normal(ks[0], (tokens, d)),
+              jax.random.uniform(ks[1], (tokens, k)),
+              *(0.3 * w for w in _held_experts(held, d, f)))
+    args = tuple(a.astype(dtype) if i != 1 else a
+                 for i, a in enumerate(args32))
+    value, stats, grads = _value_stats_grads(chosen, total, args)
+    assert tuple(stats[:2]) == (0.0, landed)
+    assert stats[2] == float(landed > piece_rows(tokens, k, held, total))
+    want, grads_want = jax.value_and_grad(
+        lambda *a: _weighed(_loop_over_experts(a[0], chosen, *a[1:])),
+        argnums=(0, 1, 2, 3, 4))(*(a.astype(jnp.float32) for a in args))
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
+    # each gradient in its argument's dtype (the choices' weights' float32)
+    assert [g.dtype for g in grads] == [a.dtype for a in args]
+    for got, looped in zip((value, *grads), (want, *grads_want)):
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(
+            got, looped, rtol=0,
+            atol=tol * max(float(jnp.abs(looped).max()), 1.0))
+    if not landed:
+        assert not any(np.asarray(g, np.float32).any() for g in grads)
+
+
+def test_tile_fill_counts_the_tiles_the_groups_touch():
+    """256 tokens on two experts, 100 and 156: the first expert's rows lie
+    in the first tile of 128, the second's in both — three tiles visited
+    for 256 rows; and through the layer at its smallest, where one tile of
+    64 rows holds every row and is visited once for each expert that got
+    any."""
+    from easydl_tpu.ops.moe import _routed_part
+
+    chosen = jnp.asarray(np.repeat([0, 1], [100, 156])[:, None], jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    _, stats = _routed_part(jax.random.normal(ks[0], (256, 16)), chosen,
+                            jax.random.uniform(ks[1], (256, 1)),
+                            *_held_experts(2), 0, 2)
+    assert tuple(np.asarray(stats)) == (0.0, 256.0, 0.0, 156.0, 3 * 128.0)
+
+    layer = MoeMlp(experts_total=8, experts_held=(0, 8), d_ff=32,
+                   shared_d_ff=16, k=2, scaling=2.5)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 8))
+    (_, counters, _), sown = layer.apply(
+        layer.init(jax.random.PRNGKey(2), x), x, mutable=["intermediates"])
+    named = dict(zip(COUNTERS, np.asarray(counters)))
+    experts = np.unique(np.asarray(sown["intermediates"]["chosen"][0])).size
+    assert COUNTERS[-1] == "moe_tile_fill"
+    assert named["moe_tile_fill"] == np.float32(64 / (64 * experts))

@@ -116,6 +116,41 @@ def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
                for c in calls), calls
 
 
+@pytest.mark.parametrize("form", ["rows", "rows_transposed_pair", "weights"])
+@pytest.mark.parametrize("rows, groups, contract, cols", [
+    (15488, 8, 2048, 2048),    # ZAYA1's cell: a piece over 8 experts of 2048
+    (32768, 32, 2048, 512),    # Laguna's: 32 experts, the way in
+    (32768, 32, 512, 2048),    # and out
+], ids=["zaya-8x2048x2048", "laguna-32x2048x512", "laguna-32x512x2048"])
+def test_grouped_products_compile_at_the_cells_shapes(v5e_2x2, rows, groups,
+                                                     contract, cols, form):
+    """The expert layer's three kernel forms at the two cells' shapes, bf16,
+    with the tiles ``choose_tiles`` gives them: Mosaic takes each (the
+    weight block resident, up to 34 MiB of VMEM for ZAYA1's weight
+    gradient) as one kernel under its own name."""
+    from easydl_tpu.ops import moe
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    x, y = arg(rows, contract), arg(rows, cols)
+    w, sizes = arg(groups, contract, cols), arg(groups, dtype=jnp.int32)
+    fn, args, name, result = {
+        "rows": (lambda x, w, s: moe.grouped_rows([x], [w], s, False, False)[0],
+                 (x, w, sizes), "grouped_rows", (rows, cols)),
+        "rows_transposed_pair": (
+            lambda y, w, s: moe.grouped_rows([y, y], [w, w], s, True, False)[0],
+            (y, w, sizes), "grouped_rows_t", (rows, contract)),
+        "weights": (lambda x, y, s: moe.grouped_weights(x, y, s, False),
+                    (x, y, sizes), "grouped_weights", (groups, contract, cols)),
+    }[form]
+    compiled = jax.jit(fn).lower(*args).compile()
+    call, = _mosaic_calls(compiled)
+    assert call.startswith("bf16[" + ",".join(map(str, result)) + "]"), call
+    assert f"/{name}/pallas_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("spec,per_device", [
     (MeshSpec(dp=4), "bf16[2,1024,1024]"),         # 2 rows x 16 heads
     (MeshSpec(dp=2, tp=2), "bf16[4,1024,512]"),    # 4 rows x 8 heads

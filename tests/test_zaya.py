@@ -415,11 +415,12 @@ def test_zaya_trains_on_the_deployments_ep_mesh(eight_devices):
 # ------------------------------------------- what shares the changed code
 def test_lagunas_step_program_is_the_parents_op_for_op():
     """Laguna's test description (bf16, remat ``full``, two microbatches of
-    4 x 64) lowers to the text it lowered to on PR 35's parent (``ca84007``;
-    ``tests/goldens/laguna_step_program.json``, hashed as
-    ``gpt2_fingerprint._step_sha256`` hashes): the expert layer's second
-    router form, the block's carry and the scaled adds are not in a program
-    whose description does not ask for them."""
+    4 x 64) lowers to the text ``tests/goldens/laguna_step_program.json``
+    holds the hash of (``gpt2_fingerprint._step_sha256``; the file says on
+    which tree it was written and why: last by PR 36, whose kernels for the
+    grouped products changed the program): what another model's description
+    asks for — the expert layer's second router form, the block's carry, the
+    scaled adds — is not in a program whose description does not."""
     with open(os.path.join(HERE, "goldens", "laguna_step_program.json")) as f:
         want = json.load(f)["laguna_step_program_sha256"]
     bundle = get_model("laguna", remat_policy="full", **SMALL)
