@@ -142,6 +142,48 @@ def looplm_objective(states: jax.Array, gate_logits: jax.Array,
     return expected - beta * mean_entropy, metrics
 
 
+def mtp_objective(states, head: jax.Array, targets: jax.Array, *,
+                  weight: float, logit_scale: float = 1.0,
+                  ignore_id: int = -1):
+    """A multi-token-prediction model's training objective (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2, depth 1): ``CE(main, t_{i+1}) + weight *
+    CE(module, t_{i+2})``, each a mean over its own valid positions. ``(loss,
+    metrics)``, the metrics ``loss_main``, ``loss_mtp`` and ``perplexity``
+    (the main head's).
+
+    ``states``: ``models/transformer.py MtpStates`` — the main stack's
+    normed state and the module's, ``[B, S, D]`` each; ``targets [B, S]`` are
+    the main head's: the module's are those moved one position on, its last
+    position has none. Both heads are ONE call of the fused chunked head
+    (``ops/fused_xent.py``) on the two states joined along the sequence, as
+    :func:`looplm_objective` joins a looped model's passes: one head leaf,
+    one carried head gradient, each row weighted by its objective's share —
+    and the only form written: there is no full-logits branch to pick by
+    shape (two sets of ``[B, S, V]`` float32 logits beside a chip full of
+    state would not fit where this is trained)."""
+    batch, seq = targets.shape
+    later = jnp.concatenate(
+        [targets[:, 1:], jnp.full((batch, 1), ignore_id, targets.dtype)], 1)
+    joined = jnp.concatenate([targets, later], 1)
+    # valid rows of each head, and of both: what the op divides its
+    # weighted sum by
+    n_main, n_mtp, rows = (
+        jnp.maximum((t != ignore_id).sum().astype(jnp.float32), 1.0)
+        for t in (targets, later, joined))
+    share = jnp.concatenate([
+        jnp.broadcast_to(rows / n_main, targets.shape),
+        jnp.broadcast_to(weight * rows / n_mtp, targets.shape)], 1)
+    with jax.named_scope("lm_head_loss"):
+        loss, _, by_row = fused_softmax_xent(
+            jnp.concatenate([states.hidden, states.mtp], 1), head, joined,
+            weights=lax.stop_gradient(share), ignore_id=ignore_id,
+            logit_scale=logit_scale)
+    loss_main = by_row[:, :seq].sum() / n_main
+    loss_mtp = by_row[:, seq:].sum() / n_mtp
+    return loss, {"perplexity": jnp.exp(loss_main), "loss_main": loss_main,
+                  "loss_mtp": loss_mtp}
+
+
 def lm_bundle(cfg: TransformerConfig, name: str, *,
               exit_entropy_weight: float = 0.0) -> ModelBundle:
     """The causal-LM bundle of one description of the stack: init, loss
@@ -161,7 +203,7 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     the depth, ``router_state_rms``, its size after the last layer."""
     model = Transformer(cfg)
     seq_len, vocab = cfg.max_seq, cfg.vocab
-    n_sparse = sum(1 for _, ffn in cfg.pattern if ffn == "moe")
+    n_sparse = sum(1 for _, ffn in cfg.every_layer if ffn == "moe")
 
     def head_of(params, dtype):
         """The head as ``[V, D]`` in the compute dtype — exactly what
@@ -190,13 +232,27 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
         return model.init(rng, tokens)["params"]
 
     def _lm_loss_from(params, batch, mutable=False):
-        """LM loss via the fused chunked head or full logits.
+        """``(loss, the mutated collections or None, the heads' metrics or
+        None for a plain head)``: LM loss via the fused chunked head or
+        full logits.
 
         The fused path asks the stack for hidden states and applies the tied
         head chunk-by-chunk (ops/fused_xent.py) — the full [B,S,V] f32
         logits buffer never exists.
         """
         mut = None
+        if cfg.mtp is not None:
+            # the module's objective has one form: the fused head on the
+            # joined states (:func:`mtp_objective`)
+            out = model.apply(
+                {"params": params}, batch["inputs"], return_hidden=True,
+                **({"mutable": ["counters"]} if mutable else {}))
+            states, mut = out if mutable else (out, None)
+            loss, heads = mtp_objective(
+                states, head_of(params, states.hidden.dtype),
+                batch["targets"], weight=cfg.mtp.weight,
+                logit_scale=1.0 / cfg.logits_scaling)
+            return loss, mut, heads
         if fused_head_by_shape(*batch["inputs"].shape, vocab):
             out = model.apply(
                 {"params": params}, batch["inputs"], return_hidden=True,
@@ -222,13 +278,18 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
             mut = out[1] if mutable else None
             with jax.named_scope("loss"):
                 loss, _ = lm_loss(logits, batch["targets"])
-        return loss, mut
+        return loss, mut, None
+
+    def metrics_of(loss, heads, counters=None):
+        """The heads' metrics (a plain head's: its perplexity) and the
+        expert layers' counters."""
+        return {**(heads or {"perplexity": jnp.exp(loss)}), **(counters or {})}
 
     def loss_fn(params, batch, rng):
         if cfg.exit_gate:
             return gated_loss(params, batch)
         if n_sparse:
-            loss, mut = _lm_loss_from(params, batch, mutable=True)
+            loss, mut, heads = _lm_loss_from(params, batch, mutable=True)
             summed = mut["counters"]["moe"][0]
             counters = {name: summed[i] / (1 if name == "moe_dropped"
                                            else n_sparse)
@@ -236,15 +297,15 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
             if cfg.router_state_width:
                 counters["router_state_rms"] = \
                     mut["counters"]["router_state_rms"][0]
-            return loss, {"perplexity": jnp.exp(loss), **counters}
-        loss, _ = _lm_loss_from(params, batch)
-        return loss, {"perplexity": jnp.exp(loss)}
+            return loss, metrics_of(loss, heads, counters)
+        loss, _, heads = _lm_loss_from(params, batch)
+        return loss, metrics_of(loss, heads)
 
     def eval_fn(params, batch, rng):
         if cfg.exit_gate:
             return gated_loss(params, batch)
-        loss, _ = _lm_loss_from(params, batch)
-        return loss, {"perplexity": jnp.exp(loss)}
+        loss, _, heads = _lm_loss_from(params, batch)
+        return loss, metrics_of(loss, heads)
 
     def make_data(global_batch: int, seed: int = 0):
         return SyntheticTokens(global_batch, seq_len=seq_len, vocab=vocab, seed=seed)

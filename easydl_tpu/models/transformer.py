@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from easydl_tpu.ops import multihead_attention, remat
+from easydl_tpu.ops.attention import rotate_heads
 from easydl_tpu.ops import moe as moe_ops
 from easydl_tpu.ops.moe import MoeMlp
 from easydl_tpu.ops.rope import apply_rope, rope_tables
@@ -182,11 +183,24 @@ class RopeScheme:
     """One rotary scheme (``ops/rope.py rope_tables``): ``rotary_dim``
     leading dimensions of a head are rotated (0: all), with YaRN's blended
     frequencies and attention factor where ``yarn`` holds its numbers
-    (pairs, so that the description stays hashable)."""
+    (pairs, so that the description stays hashable). The pairing is
+    rotate-half, or ``interleaved`` (dimension ``2i`` with ``2i + 1``); the
+    rotated part is the head's leading dimensions, or its ``last``."""
 
     theta: float = 10000.0
     rotary_dim: int = 0
     yarn: Optional[Tuple[Tuple[str, float], ...]] = None
+    interleaved: bool = False
+    last: bool = False
+
+    def tables(self, seq: int, head_dim: int):
+        """``ops/rope.py rope_tables`` of this scheme (pairing and place by
+        keyword, and only where they are not the default: the benchmark's
+        tests stand a five-argument ``rope_tables`` in)."""
+        place = {name: True for name in ("interleaved", "last")
+                 if getattr(self, name)}
+        return rope_tables(seq, head_dim, self.theta, self.rotary_dim or None,
+                           dict(self.yarn) if self.yarn else None, **place)
 
 
 @dataclass(frozen=True)
@@ -208,18 +222,41 @@ class LatentMix:
 
 
 @dataclass(frozen=True)
+class LowRank:
+    """Multi-head latent attention (MLA; DeepSeek-V2 / V3, arXiv:2412.19437
+    section 2.1.1): q through a bottleneck of ``q_rank`` with an RMSNorm
+    inside it, k and v up from ONE shared latent of ``kv_rank`` with an
+    RMSNorm of its own. A head scores with ``nope_dim`` dimensions without
+    positions beside ``rope_dim`` rotated ones (the kind's rotary scheme:
+    its rotary dimensions, the head's last), and the rotated key part is ONE
+    vector a token, made beside the latent and shared by every head; a
+    head's value is ``value_dim`` wide, so the way back up reads ``heads x
+    value_dim``. The description's ``head_size`` is ``nope_dim +
+    rope_dim``."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    value_dim: int
+
+
+@dataclass(frozen=True)
 class AttentionKind:
     """An attention layer's own numbers, where a stack has more than one
     kind: query heads (0: the description's), a causal window in keys (0:
     none), a rotary scheme (None: the description's ``position``), a
-    per-head sigmoid gate on the attention output, and (``latent``) the
-    mixing of q, k and v inside the heads' latent."""
+    per-head sigmoid gate on the attention output, (``latent``) the
+    mixing of q, k and v inside the heads' latent, and (``lowrank``) q, k
+    and v made through low-rank latents in place of one full-rank map
+    each."""
 
     n_heads: int = 0
     window: int = 0
     rope: Optional[RopeScheme] = None
     gate: bool = False
     latent: Optional[LatentMix] = None
+    lowrank: Optional[LowRank] = None
 
 
 @dataclass(frozen=True)
@@ -241,6 +278,9 @@ class MoeConfig:
     router: str = moe_ops.ROUTERS[0]
     router_hidden: int = 0
     skip_choice: bool = False
+    #: the linear router selects by its scores plus a per-expert bias that
+    #: takes no gradient and weighs by the scores without it (``noaux_tc``)
+    selection_bias: bool = False
 
     @property
     def choices(self) -> int:
@@ -252,6 +292,22 @@ class MoeConfig:
         """Width of the router state a layer takes from the layer before
         it (0: the router has none)."""
         return self.router_hidden if self.router == moe_ops.ROUTERS[1] else 0
+
+
+@dataclass(frozen=True)
+class MtpConfig:
+    """A multi-token-prediction module of depth 1 (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2): the main stack's normed final state at
+    position ``i`` and the embedding of token ``i + 1``, each normed, joined
+    through a ``2 d_model -> d_model`` map, ONE more layer ``(mixer, ffn)``
+    of the description's kinds, a final norm of its own; the main model's
+    embedding and head are used again (one leaf each). ``weight`` is the
+    second objective's, lambda: ``loss = CE(main, t_{i+1}) + weight *
+    CE(module, t_{i+2})`` (``models/lm.py mtp_objective``)."""
+
+    mixer: str
+    ffn: str = "moe"
+    weight: float = 0.3
 
 
 @dataclass(frozen=True)
@@ -345,13 +401,16 @@ class TransformerConfig:
     #: learned vectors of the model's width a sub-layer (ones and zeros at
     #: the start), in float32
     residual_scale: bool = False
+    #: a multi-token-prediction module behind the stack, where the
+    #: description has one
+    mtp: Optional[MtpConfig] = None
 
     def __post_init__(self):
         if self.layers is not None and len(self.layers) != self.n_layers:
             raise ValueError(f"{len(self.layers)} layers described, "
                              f"n_layers={self.n_layers}")
         kinds = dict(self.attention_kinds)
-        for mixer, ffn in self.pattern:
+        for mixer, ffn in self.every_layer:
             if mixer not in MIXERS + tuple(kinds) or ffn not in FFNS:
                 raise ValueError(f"unknown layer kind {(mixer, ffn)}; mixers "
                                  f"{MIXERS + tuple(kinds)}, FFNs {FFNS}")
@@ -377,6 +436,29 @@ class TransformerConfig:
         if self.has_moe and (self.loops > 1 or self.pipeline_fn is not None):
             raise NotImplementedError(
                 "moe layers in a looped stack or inside the pipeline")
+        for name, kind in self.attention_kinds:
+            low = kind.lowrank
+            if low is None:
+                continue
+            if low.nope_dim + low.rope_dim != self.head_dim or kind.rope is \
+                    None or kind.rope.rotary_dim != low.rope_dim or \
+                    kind.latent or kind.gate or kind.window or \
+                    self.kv_heads != (kind.n_heads or self.n_heads):
+                raise ValueError(
+                    f"attention kind {name!r}: low-rank latent attention "
+                    f"scores with head_size = nope_dim + rope_dim, rotates "
+                    f"rope_dim by its own scheme, has as many key/value "
+                    f"heads as query heads and no window, gate or mix")
+        if self.mtp is not None and (
+                self.loops > 1 or self.exit_gate or self.pipeline_fn
+                is not None or self.attention_fn is not None or
+                self.router_state_width or self.mtp.mixer == "mamba2" or
+                self.embedding_multiplier != 1.0):
+            raise NotImplementedError(
+                "a multi-token-prediction module behind a looped, gated, "
+                "pipelined or sequence-parallel stack, one whose router "
+                "state runs through the depth or whose embedding has a "
+                "multiplier, or on a mamba2 layer")
 
     @property
     def head_dim(self) -> int:
@@ -393,8 +475,14 @@ class TransformerConfig:
         return dict(self.attention_kinds).get(mixer, AttentionKind())
 
     @property
+    def every_layer(self) -> Tuple[Layer, ...]:
+        """The pattern, and the multi-token-prediction module's layer."""
+        return self.pattern + (
+            ((self.mtp.mixer, self.mtp.ffn),) if self.mtp else ())
+
+    @property
     def has_moe(self) -> bool:
-        return any(ffn == "moe" for _, ffn in self.pattern)
+        return any(ffn == "moe" for _, ffn in self.every_layer)
 
     @property
     def router_state_width(self) -> int:
@@ -436,7 +524,15 @@ class TransformerConfig:
         what the matrix products of a step are counted from."""
         mixer, ffn = layer
         d = self.d_model
-        if mixer != "mamba2":
+        if mixer != "mamba2" and self.attention_kind(mixer).lowrank:
+            low = self.attention_kind(mixer).lowrank
+            heads = self.attention_kind(mixer).n_heads or self.n_heads
+            # down, the norm's gain and up, for q and for k / v; the way back
+            n = ((d + 1 + heads * self.head_dim) * low.q_rank
+                 + d * (low.kv_rank + low.rope_dim) + low.kv_rank
+                 + low.kv_rank * heads * (low.nope_dim + low.value_dim)
+                 + heads * low.value_dim * d)
+        elif mixer != "mamba2":
             kind = self.attention_kind(mixer)
             inner = (kind.n_heads or self.n_heads) * self.head_dim
             n = 2 * d * inner + 2 * d * self.kv_heads * self.head_dim
@@ -466,8 +562,8 @@ class TransformerConfig:
             router = d * m.experts_total if not r else (
                 (d + 1) * r + 2 * r          # down and bias, gain, norm
                 + 2 * (r + 1) * r + r * m.choices)
-            n += (router + 3 * d * m.shared_d_ff
-                  + round(routed * 3 * d * m.d_ff))
+            n += (router + (m.experts_total if m.selection_bias else 0)
+                  + 3 * d * m.shared_d_ff + round(routed * 3 * d * m.d_ff))
         else:
             n += 2 * d * self.d_ff
         if self.norm_placement == "sandwich":
@@ -487,7 +583,16 @@ class TransformerConfig:
         head = 0 if self.tied_head else self.vocab * self.d_model
         gate = self.d_model + 1 if self.exit_gate else 0
         return (emb + sum(self.layer_params(l) for l in self.pattern) + head
-                + gate)
+                + gate + self._mtp_params())
+
+    def _mtp_params(self, active: bool = False) -> int:
+        """The multi-token-prediction module's own parameters: its layer,
+        the map that joins state and embedding, three norms."""
+        if self.mtp is None:
+            return 0
+        d = self.d_model
+        return (self.layer_params((self.mtp.mixer, self.mtp.ffn), active)
+                + 2 * d * d + 3 * d)
 
     def train_flops_per_token(self, seq_len: int) -> float:
         """Training FLOPs a token, forward and backward, recomputation not
@@ -502,7 +607,9 @@ class TransformerConfig:
         count (:meth:`layer_params`); an attention layer's scores are
         ``12 * heads * head_dim`` a key, ``seq`` keys counted in full as the
         convention has it, or the ``window`` keys a windowed layer's band
-        holds."""
+        holds (latent attention: ``6 * heads * (head_dim + value_dim)``). A
+        multi-token-prediction module pays its layer, its join and the head
+        a second time."""
         n_attn = sum(1 for mixer, _ in self.pattern if mixer != "mamba2")
         head = self.vocab * self.d_model
         held = sum(self.layer_params(l) for l in self.pattern) + head
@@ -511,16 +618,22 @@ class TransformerConfig:
         lookup = 0 if self.tied_head else head
         once = self.param_count - held - lookup
         scores = 0.0
-        for mixer, _ in self.pattern:
+        for mixer, _ in self.every_layer:
             if mixer != "mamba2":
                 kind = self.attention_kind(mixer)
-                scores += 12.0 * (kind.n_heads or self.n_heads) \
-                    * self.head_dim * min(kind.window or seq_len, seq_len)
+                # S = Q K^T at the scores' head size and P V at the values'
+                sizes = 2 * self.head_dim if kind.lowrank is None else \
+                    self.head_dim + kind.lowrank.value_dim
+                scores += 6.0 * (kind.n_heads or self.n_heads) * sizes \
+                    * min(kind.window or seq_len, seq_len)
         if n_attn < len(self.pattern):
             m = self.ssm
             scores += 3.0 * (len(self.pattern) - n_attn) * ssd_flops_per_token(
                 m.n_heads, m.head_dim, m.d_state, m.n_groups, m.chunk)
-        return 6.0 * once + self.loops * (6.0 * looped + scores)
+        # the module's layer at its active count, and the head once more
+        module = self._mtp_params(active=True) + head if self.mtp else 0
+        once -= self._mtp_params()
+        return 6.0 * (once + module) + self.loops * (6.0 * looped + scores)
 
 
 # The mixers and the FFN are functions of the block, not methods of it: flax
@@ -610,9 +723,84 @@ def _latent_mix(block, mix, q, k, v):
     return q.astype(v.dtype), k.astype(v.dtype), v
 
 
+def _rms(block, name, x, eps):
+    """RMSNorm over the last dimension with a gain ``name`` of its own, whole
+    on every shard (a latent's norm: no axis of it is a mesh's)."""
+    gain = block.param(name, nn.with_logical_partitioning(
+        nn.initializers.ones_init(), (None,)), (x.shape[-1],))
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+            * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def _latent_attention(block, kind, h, rope):
+    """:class:`LowRank` attention on the normed input ``h``: the attention's
+    result ``[B, S, heads, value_dim]``, in front of the way back up. Scopes:
+    ``mla_down`` (the two maps into the latents; the key's rotated vector
+    comes out of the second), ``mla_norm``, ``mla_up`` (q's heads; the
+    heads' keys without positions and values, two products on the two
+    halves of ONE leaf ``kv_b [kv_rank, heads, nope_dim + value_dim]``, so
+    that both leave as the rows the kernels take), ``rope`` (q's heads by
+    the kernel on their rows; the one key vector in ``jax.numpy``),
+    ``mla_key`` (the rotated key vector copied beside every head's
+    ``nope_dim``: the published code's form, and the kernels' operand),
+    the kernels ``mla_fwd`` / ``mla_bwd_dq`` / ``mla_bwd_dkv``."""
+    cfg, low = block.cfg, kind.lowrank
+    n_heads = kind.n_heads or cfg.n_heads
+    dt = jnp.dtype(cfg.dtype)
+    with jax.named_scope("mla_down"):
+        c_q = _projection(block, low.q_rank, ("embed", None), (None,),
+                          "q_a")(h)
+        c_kv = _projection(block, low.kv_rank + low.rope_dim,
+                           ("embed", None), (None,), "kv_a")(h)
+        c_kv, k_rot = c_kv[..., :low.kv_rank], c_kv[..., low.kv_rank:]
+    with jax.named_scope("mla_norm"):
+        c_q = _rms(block, "q_norm", c_q, cfg.norm_eps)
+        c_kv = _rms(block, "kv_norm", c_kv, cfg.norm_eps)
+    with jax.named_scope("mla_up"):
+        q = _projection(block, (n_heads, cfg.head_dim), (None, "heads", "kv"),
+                        ("heads", "kv"), "q_b", rows=True)(c_q)
+        kv_b = jnp.asarray(block.param(
+            "kv_b", nn.with_logical_partitioning(
+                nn.initializers.normal(stddev=0.02), (None, "heads", "kv")),
+            (low.kv_rank, n_heads, low.nope_dim + low.value_dim)), dt)
+        k_nope, v = (
+            _matrix_dot_general(c_kv, half, (((2,), (0,)), ((), ())))
+            .reshape(*c_kv.shape[:2], n_heads, -1)
+            for half in (kv_b[..., :low.nope_dim], kv_b[..., low.nope_dim:]))
+    q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
+    # one pair of tables: the key's lone vector is a head's rotated part
+    q = rotate_heads(q, rope, rotary_dim=low.rope_dim,
+                     interleaved=kind.rope.interleaved,
+                     impl=cfg.attention_impl)
+    k_rot = apply_rope(k_rot[:, :, None, :],
+                       *(t[:, -low.rope_dim:] for t in rope),
+                       interleaved=kind.rope.interleaved)
+    with jax.named_scope("mla_key"):
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rot, (*k_nope.shape[:3], low.rope_dim))], -1)
+    k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
+    v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
+    attn = multihead_attention(
+        q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
+        scale=cfg.attention_multiplier)
+    # what the latents held and the kernels were given and gave, where
+    # `intermediates` is a mutable collection (the benchmark's check, tests)
+    for name, value in (("in", h), ("cq", c_q), ("ckv", c_kv), ("q", q),
+                        ("k_rot", k_rot), ("attn", attn)):
+        block.sow("intermediates", f"mla_{name}", value)
+    return attn
+
+
 def _attention(block, h, rope=None):
     cfg = block.cfg
     kind = cfg.attention_kind(block.mixer)
+    if kind.lowrank:
+        attn = _latent_attention(block, kind, h, rope)
+        with jax.named_scope("mla_out"):
+            return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                               ("embed",), "out", residual=True,
+                               axis=(-2, -1), rows=True)(attn)
     n_heads = kind.n_heads or cfg.n_heads
     rotary_dim = kind.rope.rotary_dim or None if kind.rope else None
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
@@ -749,7 +937,8 @@ def _ffn(block, h, state=None):
             d_ff=m.d_ff, shared_d_ff=m.shared_d_ff, k=m.k, scaling=m.scaling,
             out_init_scale=(2 * cfg.n_layers) ** -0.5, dtype=cfg.dtype,
             router=m.router, router_hidden=m.router_hidden,
-            router_eps=cfg.norm_eps, skip_choice=m.skip_choice, name="moe",
+            router_eps=cfg.norm_eps, skip_choice=m.skip_choice,
+            selection_bias=m.selection_bias, name="moe",
         )(h, state)
         return y, aux, state if routed is None else routed
     else:
@@ -916,6 +1105,46 @@ class LoopStates(NamedTuple):
     gate: Optional[jax.Array]
 
 
+def _next_tokens(tokens):
+    """Token ``i + 1`` at position ``i``; the last position, which has none,
+    takes the sequence's first (nothing reads what it then computes: the
+    causal mask hides it from every other position, and it has no
+    target)."""
+    return jnp.roll(tokens, -1, axis=1)
+
+
+class MtpMerge(nn.Module):
+    """What a multi-token-prediction module's layer is given: the next
+    tokens' embeddings and the main stack's normed final state, each normed
+    once more, joined (the embedding first) through a ``2 d_model ->
+    d_model`` map without a bias. flax names every operation of it
+    ``mtp_merge``, the name it is made under."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, emb_next, state):
+        cfg = self.cfg
+        dt = jnp.dtype(cfg.dtype)
+        joined = jnp.concatenate([_norm(cfg, "ln_emb", dtype=dt)(emb_next),
+                                  _norm(cfg, "ln_state", dtype=dt)(state)],
+                                 -1)
+        return _dense(cfg.d_model, ("mlp", "embed"), ("embed",), name="join",
+                      use_bias=False, dtype=dt)(joined)
+
+
+class MtpStates(NamedTuple):
+    """What a stack with a multi-token-prediction module gives under
+    ``return_hidden``: the main stack's normed final state ``[B, S, D]``
+    (position ``i`` predicts token ``i + 1``) and the module's ``[B, S, D]``
+    (position ``i`` predicts token ``i + 2``; its last position was fed the
+    sequence's FIRST token for want of a next one, sees no later position
+    under the causal mask and has no target)."""
+
+    hidden: jax.Array
+    mtp: jax.Array
+
+
 class Transformer(nn.Module):
     """Token-in, logits-out decoder/encoder stack.
 
@@ -925,7 +1154,10 @@ class Transformer(nn.Module):
     head chunk-by-chunk so the full ``[B, S, V]`` f32 logits tensor never
     exists. A looped (``loops > 1``) or gated stack yields
     :class:`LoopStates`, every pass's; without ``return_hidden`` its logits
-    are the last pass's.
+    are the last pass's. A stack with a multi-token-prediction module yields
+    :class:`MtpStates`; without ``return_hidden`` its logits are the main
+    stack's and the module is not computed (its parameters are made at
+    ``init`` all the same).
     """
 
     cfg: TransformerConfig
@@ -988,9 +1220,7 @@ class Transformer(nn.Module):
                  if cfg.position == "rope" else None}
         for name, kind in cfg.attention_kinds:
             ropes[name] = ropes["attention"] if kind.rope is None else \
-                rope_tables(seq, cfg.head_dim, kind.rope.theta,
-                            kind.rope.rotary_dim or None,
-                            dict(kind.rope.yarn) if kind.rope.yarn else None)
+                kind.rope.tables(seq, cfg.head_dim)
 
         def pass_end(stack, x):
             """The final norm, and the exit gate's logit on the normed
@@ -1071,6 +1301,21 @@ class Transformer(nn.Module):
                 split_rngs={"params": False, "dropout": True},
                 length=cfg.loops)(self, x)
             aux = jnp.sum(aux)
+        mtp = None
+        if cfg.mtp is not None and (return_hidden or self.is_initializing()):
+            # The module, outside the scan over the main runs: the normed
+            # final state at position i joined with the embedding of token
+            # i + 1 (the SAME embedding; the last position takes the first
+            # token: `roll`), one more layer, a final norm of its own.
+            mixer, ffn = cfg.mtp.mixer, cfg.mtp.ffn
+            with jax.named_scope("mtp"):
+                mtp = MtpMerge(cfg, name="mtp_merge")(
+                    tok_emb(_next_tokens(tokens)), x)
+                mtp, layer_aux = block_cls(cfg, mixer, ffn, name="mtp_block")(
+                    mtp, deterministic, ropes.get(mixer))
+                mtp = _norm(cfg, "mtp_ln_f", dtype=dt)(mtp)
+            if ffn == "moe":
+                aux = aux + layer_aux
         # The expert layers' counters, summed over the layers; read back by
         # the loss function via mutable=["counters"] — a no-op sow for plain
         # apply() calls.
@@ -1078,6 +1323,8 @@ class Transformer(nn.Module):
             self.sow("counters", "moe", aux)
 
         if return_hidden:
+            if cfg.mtp is not None:
+                return MtpStates(x, mtp)
             if cfg.loops == 1 and not cfg.exit_gate:
                 return x
             return LoopStates(states, gates)

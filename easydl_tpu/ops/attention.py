@@ -23,6 +23,10 @@ Public shapes follow the [batch, seq, heads, head_dim] convention
 throughout; the kernels run on the ``[batch, seq, heads·head_dim]`` view of
 the same bytes (``ops/flash_attention.py``).
 
+``v`` may carry a head size of its own (latent attention: scores 192 deep,
+values 128 wide); the result has ``v``'s. The kernels take both sizes
+(``ops/flash_attention.py``), the reference path's two products likewise.
+
 Grouped-query attention: ``k`` and ``v`` may carry fewer heads than ``q``;
 query head ``h`` then reads key/value head ``h // (heads / kv_heads)``. Both
 paths repeat the shared heads to the query's count in front of the score
@@ -109,9 +113,11 @@ def _repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
     return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
 
 
-def _per_shard(fn, q: jax.Array, k: jax.Array, whole: int = 0):
+def _per_shard(fn, q: jax.Array, k: jax.Array, whole: int = 0,
+               views: int = 3):
     """Wrap ``fn``, a function of q, k, v as ``[batch, seq, heads·head_dim]``
-    views and of ``whole`` further arrays every shard takes whole (the
+    views (``views`` of them: one where a lone array is rotated) and of
+    ``whole`` further arrays every shard takes whole (the
     rotary tables), in ``jax.shard_map`` over the context mesh when that
     mesh spans more than one device, else return it unchanged. ``q`` and
     ``k`` are the ``[batch, seq, heads, head_dim]`` arrays, for their sizes.
@@ -146,8 +152,38 @@ def _per_shard(fn, q: jax.Array, k: jax.Array, whole: int = 0):
             f"heads")
         heads = None
     spec = P(batch or None, None, heads)
-    return jax.shard_map(fn, in_specs=(spec, spec, spec) + (P(),) * whole,
+    return jax.shard_map(fn, in_specs=(spec,) * views + (P(),) * whole,
                          out_specs=spec, check_vma=False)
+
+
+def _on_kernels(impl: str) -> bool:
+    """Whether ``impl`` asks for the Pallas kernels: said so, or ``auto``
+    on a TPU."""
+    return impl == "flash" or (
+        impl == "auto" and jax.devices()[0].platform == "tpu")
+
+
+def rotate_heads(x: jax.Array, rope: tuple, *,
+                 rotary_dim: Optional[int] = None, interleaved: bool = False,
+                 impl: str = "auto") -> jax.Array:
+    """``x [batch, seq, heads, head_dim]`` rotated by the tables of
+    ``ops/rope.py rope_tables``, for a caller that rotates q and k apart
+    (latent attention: every head's q, ONE key vector a token) and hands
+    :func:`multihead_attention` no tables: by the Pallas kernel on the flash
+    kernels' own view, per shard under a mesh, where ``impl`` asks for the
+    kernels and the heads fill whole lane tiles; else in ``jax.numpy``.
+    Both are the same arithmetic."""
+    head_dim = x.shape[-1]
+    if not (_on_kernels(impl)
+            and tiles_lanes(head_dim, x.shape[2], interleaved)):
+        return apply_rope(x, *rope, rot=rotary_dim, interleaved=interleaved)
+
+    def kernel(x, *tables):
+        return rope_rows(x, *tables, head_dim=head_dim, rot=rotary_dim,
+                         interleaved=interleaved)
+
+    return _per_shard(kernel, x, x, len(rope), views=1)(
+        x.reshape(*x.shape[:2], -1), *rope).reshape(x.shape)
 
 
 @functools.partial(
@@ -166,7 +202,8 @@ def multihead_attention(
     rotary_dim: Optional[int] = None,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Attention over [batch, seq, heads, head_dim] tensors.
+    """Attention over [batch, seq, heads, head_dim] tensors (``v``'s head
+    size may be its own, and is the result's).
 
     Args:
       impl: "auto" | "flash" (Pallas, TPU) | "reference" (XLA einsum).
@@ -204,7 +241,7 @@ def multihead_attention(
             why = (f"lengths q={q.shape[1]} k={k.shape[1]} have no block "
                    f"divisor <= {MAX_BLOCK}/{MAX_BLOCK}")
         if why is None:
-            head_dim = q.shape[-1]
+            head_dim, value_dim = q.shape[-1], v.shape[-1]
             # rotated beside the kernels, on their own view, where a head
             # is whole lane tiles; else here, in jax.numpy
             tables = rope if rope is not None and tiles_lanes(head_dim) else ()
@@ -218,14 +255,15 @@ def multihead_attention(
                 if tables:
                     q, k = (rope_rows(x, *tables, head_dim=head_dim,
                                       rot=rotary_dim) for x in (q, k))
-                q, k, v = (x.reshape(*x.shape[:2], -1, head_dim)
-                           for x in (q, k, v))
+                q, k, v = (x.reshape(*x.shape[:2], -1, dim) for x, dim in (
+                    (q, head_dim), (k, head_dim), (v, value_dim)))
                 return flat(flash_attention(q, *_repeat_kv(q, k, v),
                                             causal=causal, scale=scale,
                                             window=window))
 
             return _per_shard(kernel, q, k, len(tables))(
-                flat(q), flat(k), flat(v), *tables).reshape(q.shape)
+                flat(q), flat(k), flat(v), *tables).reshape(
+                    *q.shape[:3], value_dim)
         # the reference path partitions under GSPMD: no per-shard wrap
         log_once(log, f"flash attention: XLA reference path, not the "
                       f"kernel: {why}{banded}")

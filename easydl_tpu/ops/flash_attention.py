@@ -93,7 +93,20 @@ block[, Q- or K-block])`` holds ``(rows, lanes)`` blocks of whole 128-lane
 tiles: two heads of 64 side by side (four of 32, one of 128; the whole
 width where that is narrower than a tile), each head a static lane slice
 inside the kernel, the results of a cell's heads joined and stored as whole
-rows. Short sequences widen the block (``_cell_heads``). A head count the
+rows. Short sequences widen the block (``_cell_heads``).
+
+Two head sizes. The scores' size (q, k, dq, dk: ``head_dim``) and the
+values' (v, O, dO, dv: ``value_dim``) are two numbers through the three
+looped kernels, the blocks' specs and ``_cell_heads``: latent attention
+scores 192 deep (128 without positions beside 64 rotated) and weighs values
+128 wide. A cell then takes the heads that fill whole tiles of BOTH widths
+— two heads: a 384-lane block of q and k beside a 256-lane block of v, O and
+dO — and every product keeps its own depth (``S = K Qᵀ`` 192, ``P V`` and
+``dP = V dOᵀ`` 128): v is not padded to the scores' size, which would cost
+half again those products and the bytes of v, O and dO. Where the two are
+equal the kernels lower to what they lowered to with one; where they differ
+the calls carry names of their own (``mla_fwd``, ``mla_bwd_dq``,
+``mla_bwd_dkv``) and the band path is not offered. A head count the
 tile does not divide (25 heads of 64: 13 lane blocks) leaves the last cell
 half outside the array: Pallas reads and writes only the part inside, and
 since every head is computed from its own slice alone, whatever the other
@@ -380,10 +393,12 @@ def _unrolled(n_q: int, n_k: int) -> bool:
 
 
 def _cell_heads(heads: int, head_dim: int, pairs: int, unroll: bool,
-                head_bytes: int) -> int:
+                head_bytes: int, value_dim: Optional[int] = None) -> int:
     """Heads one grid cell takes, side by side in the lanes of its blocks.
     As many as fill whole 128-lane tiles (two of 64, one of 128; all of
-    them where the array is narrower than that), and no more — unless a head
+    them where the array is narrower than that) of the scores' size
+    ``head_dim`` AND of the values' ``value_dim`` (None: the same; two heads
+    of 192 / 128: 384 lanes beside 256), and no more — unless a head
     is so few block pairs (short sequences: one pair at 128 or 512) that a
     cell of them is mostly waiting: then as many as make ``_CELL_PAIRS``
     pairs a cell and keep its operands and results (``head_bytes`` a head)
@@ -392,7 +407,8 @@ def _cell_heads(heads: int, head_dim: int, pairs: int, unroll: bool,
     64) leaves the last cell part outside the array: its blocks are read and
     written only where the array is, and each head is its own lane slice
     inside the kernels, so what lies outside meets no live head."""
-    tile = math.lcm(head_dim, 128) // head_dim
+    tile = math.lcm(*(math.lcm(d, 128) // d
+                      for d in (head_dim, value_dim or head_dim)))
     if tile >= heads:
         return heads
     if not unroll or heads % tile:
@@ -443,6 +459,22 @@ def _head_cols(lanes: int, d: int):
     return [slice(g * d, (g + 1) * d) for g in range(lanes // d)]
 
 
+#: what the looped kernels may take of VMEM where the two head sizes
+#: differ: a cell holds the whole sequence of two heads' k and v (forward,
+#: dq) or q, O and dO (dk/dv), 15 MB at 8,192 x (384 + 256) bf16 lanes,
+#: twice — over the compiler's own 16 MB (a v5e's VMEM is 128 MB)
+_TWO_SIZE_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
+
+
+def _call_name(kernel: str, d: int, dv: int, window: Optional[int]):
+    """A looped kernel's ``name=`` (and compiler parameters) by what it
+    computes: ``flash_*``, ``swa_*`` under a window, ``mla_*`` where the
+    scores' and the values' head sizes differ."""
+    if d != dv:
+        return dict(name=f"mla_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
+    return dict(name=f"{'flash' if window is None else 'swa'}_{kernel}")
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -450,14 +482,17 @@ def _head_cols(lanes: int, d: int):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref,
-    *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
-    offset: int, unroll: bool, window: Optional[int],
+    *, head_dim: int, value_dim: int, block_q: int, block_k: int,
+    causal: bool, scale: float, offset: int, unroll: bool,
+    window: Optional[int],
 ):
-    # q_ref, o_ref: [cell rows, cell heads · d]; k_ref, v_ref: [S_k, cell
-    # heads · d]; lse_ref: [cell heads, cell rows, 1]
+    # q_ref: [cell rows, cell heads · d], o_ref: [cell rows, cell heads ·
+    # dv]; k_ref: [S_k, cell heads · d], v_ref: [S_k, cell heads · dv];
+    # lse_ref: [cell heads, cell rows, 1]
     cell_rows, lanes = q_ref.shape
-    d = head_dim
+    d, dv = head_dim, value_dim
     heads = _head_cols(lanes, d)
+    v_heads = _head_cols(v_ref.shape[1], dv)
     n_k = k_ref.shape[0] // block_k
     cell_start = 0 if unroll else pl.program_id(2) * cell_rows
     for j in range(cell_rows // block_q):
@@ -471,10 +506,11 @@ def _fwd_kernel(
             k_start = _block_start(kb, block_k)
             vt_all = v_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
             out = []
-            for cols, (q, s_scale), (m, l, acc) in zip(heads, qs, carry):
-                # m, l: [1, block_q]; acc: [d, block_q]
+            for cols, v_cols, (q, s_scale), (m, l, acc) in zip(
+                    heads, v_heads, qs, carry):
+                # m, l: [1, block_q]; acc: [dv, block_q]
                 k = k_ref[pl.ds(k_start, block_k), cols]
-                vt = vt_all[cols]  # [d, block_k]: the head's sublanes
+                vt = vt_all[v_cols]  # [dv, block_k]: the head's sublanes
                 st = _scores_t(k, q, s_scale,
                                q_start + offset - k_start if masked else None,
                                window)
@@ -489,7 +525,7 @@ def _fwd_kernel(
         carry = tuple((
             jnp.full((1, block_q), NEG_INF, jnp.float32),
             jnp.zeros((1, block_q), jnp.float32),
-            jnp.zeros((d, block_q), jnp.float32),
+            jnp.zeros((dv, block_q), jnp.float32),
         ) for _ in heads)
         carry = _over_k_blocks(
             body, carry, q_start, block_q=block_q, block_k=block_k, n_k=n_k,
@@ -516,36 +552,45 @@ def _fwd_kernel(
 
 def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
          block_k: int, interpret: bool, window: Optional[int] = None):
-    # q, k, v: [B, S, H·d]
+    # q, k: [B, S, H·d]; v: [B, S, H·dv]
     b, s_q, width = q.shape
-    s_k, d = k.shape[1], width // heads
+    s_k, d, dv = k.shape[1], width // heads, v.shape[2] // heads
     assert s_q % block_q == 0 and s_k % block_k == 0, (s_q, s_k, block_q, block_k)
     n_q, n_k = s_q // block_q, s_k // block_k
     unroll = _unrolled(n_q, n_k)
     cell_rows = s_q if unroll else block_q
     # q, o, k, v and the lse column (float32, one lane in 128)
     cell = _cell_heads(heads, d, n_q * n_k, unroll,
-                       2 * (s_q + s_k) * d * q.dtype.itemsize + s_q * 128 * 4)
+                       (s_q + s_k) * (d + dv) * q.dtype.itemsize
+                       + s_q * 128 * 4, dv)
     kernel = functools.partial(
-        _fwd_kernel, head_dim=d, block_q=block_q, block_k=block_k, causal=causal,
-        scale=scale, offset=s_k - s_q, unroll=unroll, window=window,
+        _fwd_kernel, head_dim=d, value_dim=dv, block_q=block_q,
+        block_k=block_k, causal=causal, scale=scale, offset=s_k - s_q,
+        unroll=unroll, window=window,
     )
-    mine = pl.BlockSpec((None, cell_rows, cell * d), lambda b, h, qi: (b, qi, h))
-    whole = pl.BlockSpec((None, s_k, cell * d), lambda b, h, qi: (b, 0, h))
+
+    def mine(size):
+        return pl.BlockSpec((None, cell_rows, cell * size),
+                            lambda b, h, qi: (b, qi, h))
+
+    def whole(size):
+        return pl.BlockSpec((None, s_k, cell * size),
+                            lambda b, h, qi: (b, 0, h))
+
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, pl.cdiv(heads, cell), s_q // cell_rows),
-        in_specs=[mine, whole, whole],
+        in_specs=[mine(d), whole(d), whole(dv)],
         out_specs=[
-            mine,
+            mine(dv),
             pl.BlockSpec((None, cell, cell_rows, 1), lambda b, h, qi: (b, h, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, s_q, heads * dv), q.dtype),
             jax.ShapeDtypeStruct((b, heads, s_q, 1), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd" if window is None else "swa_fwd",
+        **_call_name("fwd", d, dv, window),
     )(q, k, v)
     return out, lse
 
@@ -557,31 +602,33 @@ def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
 
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-    *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
-    offset: int, unroll: bool, window: Optional[int],
+    *, head_dim: int, value_dim: int, block_q: int, block_k: int,
+    causal: bool, scale: float, offset: int, unroll: bool,
+    window: Optional[int],
 ):
     # lse_ref: [cell heads, the cell's Q-blocks, 1, block_q]
     cell_rows, lanes = q_ref.shape
-    d = head_dim
+    d, dv = head_dim, value_dim
     heads = _head_cols(lanes, d)
+    v_heads = _head_cols(v_ref.shape[1], dv)
     n_k = k_ref.shape[0] // block_k
     cell_start = 0 if unroll else pl.program_id(2) * cell_rows
     for j in range(cell_rows // block_q):
         rows = slice(j * block_q, (j + 1) * block_q)
         q_start = cell_start + j * block_q
         qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
-        dos = [do_ref[rows, cols] for cols in heads]
+        dos = [do_ref[rows, cols] for cols in v_heads]
         lses = [lse_ref[g, j] for g in range(len(heads))]
-        deltas = _delta_rows(do_ref[rows, :], o_ref[rows, :], d)
+        deltas = _delta_rows(do_ref[rows, :], o_ref[rows, :], dv)
 
         def body(kb, dq_ts, *, masked: bool):
             k_start = _block_start(kb, block_k)
             kt_all = k_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
             out = []
-            for cols, (q, s_scale), do, lse, delta, dq_t in zip(
-                    heads, qs, dos, lses, deltas, dq_ts):
+            for cols, v_cols, (q, s_scale), do, lse, delta, dq_t in zip(
+                    heads, v_heads, qs, dos, lses, deltas, dq_ts):
                 k = k_ref[pl.ds(k_start, block_k), cols]
-                v = v_ref[pl.ds(k_start, block_k), cols]
+                v = v_ref[pl.ds(k_start, block_k), v_cols]
                 st = _scores_t(k, q, s_scale,
                                q_start + offset - k_start if masked else None,
                                window)
@@ -600,13 +647,15 @@ def _bwd_dq_kernel(
 
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
-    *, head_dim: int, block_q: int, block_k: int, causal: bool, scale: float,
-    offset: int, unroll: bool, window: Optional[int],
+    *, head_dim: int, value_dim: int, block_q: int, block_k: int,
+    causal: bool, scale: float, offset: int, unroll: bool,
+    window: Optional[int],
 ):
     # lse_ref: [cell heads, n_q, 1, block_q]
     cell_rows, lanes = dk_ref.shape
     d = head_dim
     heads = _head_cols(lanes, d)
+    v_heads = _head_cols(dv_ref.shape[1], value_dim)
     n_q = q_ref.shape[0] // block_q
     cell_start = 0 if unroll else pl.program_id(2) * cell_rows
     stats = {}  # unrolled: a Q-block's lse rows and deltas, read once a cell
@@ -614,7 +663,7 @@ def _bwd_dkv_kernel(
         rows = slice(j * block_k, (j + 1) * block_k)
         k_start = cell_start + j * block_k
         ks = [_fold_scale(k_ref[rows, cols], scale) for cols in heads]
-        vs = [v_ref[rows, cols] for cols in heads]
+        vs = [v_ref[rows, cols] for cols in v_heads]
 
         def body(qb, carry, *, masked: bool):
             q_start = _block_start(qb, block_q)
@@ -622,14 +671,15 @@ def _bwd_dkv_kernel(
             found = stats.get(qb) if unroll else None
             if found is None:
                 found = ([lse_ref[g, qb] for g in range(len(heads))],
-                         _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :], d))
+                         _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :],
+                                     value_dim))
                 if unroll:
                     stats[qb] = found
             out = []
-            for cols, (k, s_scale), v, lse, delta, (dk, dv) in zip(
-                    heads, ks, vs, *found, carry):
+            for cols, v_cols, (k, s_scale), v, lse, delta, (dk, dv) in zip(
+                    heads, v_heads, ks, vs, *found, carry):
                 q = q_ref[q_rows, cols]
-                do = do_ref[q_rows, cols]
+                do = do_ref[q_rows, v_cols]
                 st = _scores_t(k, q, s_scale,
                                q_start + offset - k_start if masked else None,
                                window)
@@ -641,7 +691,7 @@ def _bwd_dkv_kernel(
 
         carry = tuple((
             jnp.zeros((block_k, d), jnp.float32),
-            jnp.zeros((block_k, d), jnp.float32),
+            jnp.zeros((block_k, value_dim), jnp.float32),
         ) for _ in heads)
         first_full, last_live, last_full = 0, n_q, n_q
         masked = functools.partial(body, masked=True)
@@ -681,59 +731,67 @@ def _bwd(
     window: Optional[int] = None,
 ):
     b, s_q, width = q.shape
-    s_k, d = k.shape[1], width // heads
+    s_k, d, vd = k.shape[1], width // heads, v.shape[2] // heads
     item = q.dtype.itemsize
-    static = dict(head_dim=d, causal=causal, scale=scale, offset=s_k - s_q,
-                  window=window)
+    static = dict(head_dim=d, value_dim=vd, causal=causal, scale=scale,
+                  offset=s_k - s_q, window=window)
 
     block_q, block_k = dq_blocks
     n_q, n_k = s_q // block_q, s_k // block_k
     unroll = _unrolled(n_q, n_k)
     cell = _cell_heads(heads, d, n_q * n_k, unroll,
-                       (4 * s_q + 2 * s_k) * d * item + s_q * 8 * 4)  # q o do dq k v lse
+                       (2 * s_q + s_k) * (d + vd) * item + s_q * 8 * 4,
+                       vd)  # q dq k at d, o do v at vd, lse
     cell_q = n_q if unroll else 1  # Q-blocks a grid cell takes
 
-    def whole(s, cell):
-        return pl.BlockSpec((None, s, cell * d), lambda b, h, i: (b, 0, h))
+    def whole(s, cell, size):
+        return pl.BlockSpec((None, s, cell * size), lambda b, h, i: (b, 0, h))
 
-    mine = pl.BlockSpec((None, cell_q * block_q, cell * d),
-                        lambda b, h, qi: (b, qi, h))
+    def mine(size):
+        return pl.BlockSpec((None, cell_q * block_q, cell * size),
+                            lambda b, h, qi: (b, qi, h))
+
     lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=unroll)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
                           unroll=unroll, **static),
         grid=(b, pl.cdiv(heads, cell), n_q // cell_q),
         in_specs=[
-            mine, whole(s_k, cell), whole(s_k, cell), mine, mine, lse_spec,
+            mine(d), whole(s_k, cell, d), whole(s_k, cell, vd), mine(vd),
+            mine(vd), lse_spec,
         ],
-        out_specs=mine,
+        out_specs=mine(d),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        name="flash_bwd_dq" if window is None else "swa_bwd_dq",
+        **_call_name("bwd_dq", d, vd, window),
     )(q, k, v, out, do, lse_in)
 
     block_q, block_k = dkv_blocks
     n_q, n_k = s_q // block_q, s_k // block_k
     unroll = _unrolled(n_q, n_k)
     cell = _cell_heads(heads, d, n_q * n_k, unroll,
-                       (3 * s_q + 4 * s_k) * d * item + s_q * 8 * 4)  # q o do k v dk dv lse
+                       ((s_q + 2 * s_k) * d + 2 * (s_q + s_k) * vd) * item
+                       + s_q * 8 * 4, vd)  # q k dk at d, o do v dv at vd, lse
     cell_k = n_k if unroll else 1  # K-blocks a grid cell takes
-    mine = pl.BlockSpec((None, cell_k * block_k, cell * d),
-                        lambda b, h, ki: (b, ki, h))
+
+    def mine(size):
+        return pl.BlockSpec((None, cell_k * block_k, cell * size),
+                            lambda b, h, ki: (b, ki, h))
+
     lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
                           unroll=unroll, **static),
         grid=(b, pl.cdiv(heads, cell), n_k // cell_k),
-        in_specs=[whole(s_q, cell), mine, mine, whole(s_q, cell), whole(s_q, cell),
-                  lse_spec],
-        out_specs=[mine, mine],
+        in_specs=[whole(s_q, cell, d), mine(d), mine(vd),
+                  whole(s_q, cell, vd), whole(s_q, cell, vd), lse_spec],
+        out_specs=[mine(d), mine(vd)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        name="flash_bwd_dkv" if window is None else "swa_bwd_dkv",
+        **_call_name("bwd_dkv", d, vd, window),
     )(q, k, v, out, do, lse_in)
     return dq, dk, dv
 
@@ -1148,7 +1206,8 @@ def flash_attention(
     """Flash attention over [batch, seq, heads, head_dim] tensors: the
     kernels' result, or ValueError where they cannot tile the lengths
     (:func:`choose_blocks` is the question; this module holds no other
-    path).
+    path). ``v`` may carry a head size of its own (``[batch, seq, heads,
+    value_dim]``: the result's); the calls are then named ``mla_*``.
 
     ``window`` (with ``causal``): query i sees only the ``window`` keys up
     to its own, ``0 <= i + (s_k - s_q) - j < window``. K-blocks (Q-blocks in
@@ -1160,12 +1219,15 @@ def flash_attention(
     ``block_q`` / ``block_k``, when passed, hold for all three kernels; left
     out, each kernel's are chosen from what the call shows."""
     b, s, h, d = q.shape
-    s_k = k.shape[1]
+    s_k, dv = k.shape[1], v.shape[-1]
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if window is not None and (not causal or window < 1):
         raise ValueError(f"flash attention: window={window} needs causal "
                          f"attention and at least one key")
+    if window is not None and dv != d:
+        raise NotImplementedError(
+            f"flash attention: a window with head sizes {d} / {dv}")
     blocks = choose_blocks(s, s_k, causal, block_q, block_k, window)
     if blocks is None:
         raise ValueError(
@@ -1179,18 +1241,21 @@ def flash_attention(
         f"{name} {cut[0]}/{cut[1]} "
         + ("unrolled" if _unrolled(s // cut[0], s_k // cut[1]) else "looped")
         for name, cut in zip(("fwd", "dq", "dkv"), blocks))
-    tile = _cell_heads(h, d, 0, False, 0)  # before short sequences widen it
+    tile = _cell_heads(h, d, 0, False, 0, dv)  # before short sequences widen it
+    sizes = f"head_dim {d}" if dv == d else f"head_dim {d}/{dv}"
+    lanes = f"{tile * d}-lane block" if dv == d else \
+        f"{tile * d}-lane block of q, k and a {tile * dv}-lane block of v, O"
     log_once(log, f"flash attention: {how} Pallas kernel on "
                   f"{device.platform} ({device.device_kind}), "
                   f"{jnp.dtype(q.dtype).name} operands to the MXU, blocks "
                   f"q/k {chosen}, over lengths {s}/{s_k}"
                   + (f" (window {window})" if window is not None else "")
-                  + f", head_dim {d}, on "
+                  + f", {sizes}, on "
                   f"[batch, seq, heads·head_dim] = [{b}, {s}, {h * d}] with "
-                  f"{tile} head(s) to a {tile * d}-lane block")
+                  f"{tile} head(s) to a {lanes}")
     # [B, S, H, d] -> [B, S, H·d] and back: the same bytes in the same order
     out = _flash(
-        q.reshape(b, s, h * d), k.reshape(b, s_k, h * d), v.reshape(b, s_k, h * d),
-        h, causal, scale, blocks, interpret, window,
+        q.reshape(b, s, h * d), k.reshape(b, s_k, h * d),
+        v.reshape(b, s_k, h * dv), h, causal, scale, blocks, interpret, window,
     )
-    return out.reshape(b, s, h, d)
+    return out.reshape(b, s, h, dv)

@@ -60,7 +60,12 @@ and each block of 128 tokens summed from the slab of rows it owns by a
 selection product in a Pallas kernel, the form that stayed.
 
 The router is a described form (:data:`ROUTERS`). ``linear-sigmoid-
-renormalised`` is the one above. ``mlp-softmax-top1`` is ZAYA's
+renormalised`` is the one above; with ``selection_bias`` (DeepSeek-V3's
+``noaux_tc``) the ``k`` largest of ``scores + b`` are chosen, ``b`` a leaf
+``router_bias [experts_total]`` at zero that takes no gradient, and the
+weights are the chosen experts' scores without it (:func:`route`; the bias's
+load-driven update is a training recipe's and is not here).
+``mlp-softmax-top1`` is ZAYA's
 (:func:`route_mlp`): the normed input projected DOWN to ``router_hidden``
 (with a bias), the previous layer's router state added to it through a learned
 gain (the state runs through the depth: the layer takes it and hands its own
@@ -151,16 +156,26 @@ def piece_rows(tokens: int, k: int, held: int, total: int) -> int:
     return min(rows_bound(tokens, k, held), -(-expected // TILE) * TILE)
 
 
-def route(h: jax.Array, kernel: jax.Array, k: int, scaling: float):
+def route(h: jax.Array, kernel: jax.Array, k: int, scaling: float,
+          bias: jax.Array | None = None):
     """``(logits [T, E] float32, chosen [T, k] int32, weights [T, k]
     float32)`` of tokens ``h [T, D]``: logits in float32 at ``highest``
     precision whatever ``h``'s dtype (on a TPU a float32 product is
     otherwise made of bf16 passes), sigmoid scores, the ``k`` largest, each
-    weight its score over the chosen scores' sum times ``scaling``."""
+    weight its score over the chosen scores' sum times ``scaling``. With a
+    selection ``bias [E]`` (DeepSeek-V3's ``noaux_tc``) the ``k`` largest of
+    ``scores + bias`` are chosen and the weights are the chosen experts'
+    scores WITHOUT it: the bias selects and does not weigh, and takes no
+    gradient."""
     logits = jnp.dot(h.astype(jnp.float32), kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    top, chosen = jax.lax.top_k(scores, k)
+    if bias is None:
+        top, chosen = jax.lax.top_k(scores, k)
+    else:
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        top = jnp.take_along_axis(scores, chosen, -1)
     return logits, chosen, scaling * top / jnp.sum(top, -1, keepdims=True)
 
 
@@ -761,6 +776,10 @@ class MoeMlp(nn.Module):
     router_hidden: int = 0
     router_eps: float = 1e-5
     skip_choice: bool = False
+    #: the linear router chooses by ``scores + b``, ``b`` a leaf
+    #: ``router_bias [experts_total]`` at zero that takes no gradient (its
+    #: load-driven update is a training recipe's and is not here)
+    selection_bias: bool = False
 
     def _route_mlp(self, h, state):
         """The MLP router's parameters and :func:`route_mlp` on them."""
@@ -793,9 +812,11 @@ class MoeMlp(nn.Module):
         if self.router not in ROUTERS:
             raise ValueError(f"router {self.router!r} is none of {ROUTERS}")
         mlp = self.router == ROUTERS[1]
-        if mlp and self.k != 1 or self.skip_choice and not mlp:
+        if mlp and (self.k != 1 or self.selection_bias) \
+                or self.skip_choice and not mlp:
             raise ValueError(f"router {self.router!r} with k={self.k}, "
-                             f"skip_choice={self.skip_choice}")
+                             f"skip_choice={self.skip_choice}, "
+                             f"selection_bias={self.selection_bias}")
         held, tokens = hi - lo, batch * seq
         choices = self.experts_total + int(self.skip_choice)
         dt = jnp.dtype(self.dtype)
@@ -820,8 +841,14 @@ class MoeMlp(nn.Module):
                 kernel = self.param("router", nn.with_logical_partitioning(
                     nn.initializers.normal(stddev=0.02), ("embed", None)),
                     (d, self.experts_total))
-                logits, chosen, weights = route(h, kernel, self.k,
-                                                self.scaling)
+                bias = self.param("router_bias", nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), (None,)),
+                    (self.experts_total,)) if self.selection_bias else None
+                # (the bias by keyword, and only where there is one: the
+                # benchmark's tests stand a four-argument `route` in)
+                logits, chosen, weights = route(
+                    h, kernel, self.k, self.scaling,
+                    **({} if bias is None else {"bias": bias}))
                 share = jax.nn.sigmoid(logits)
                 share = share / jnp.sum(share, -1, keepdims=True)
             self.sow("intermediates", "router_in", h)

@@ -1,4 +1,6 @@
-"""Rotary positions (rotate-half pairing) for q and k.
+"""Rotary positions for q and k: rotate-half pairing over the leading
+lanes of a head, or interleaved pairing (dimension ``2i`` with ``2i + 1``)
+over its leading or its LAST lanes.
 
 ``x cos + rotate_half(x) sin`` with ``rotate_half(x) = [-x2, x1]`` over the
 two halves of a head, the angle of dimensions ``i`` and ``i + head_dim / 2``
@@ -6,6 +8,14 @@ two halves of a head, the angle of dimensions ``i`` and ``i + head_dim / 2``
 operand's dtype. With the sign folded into the sine table
 (``sin_signed = [-sin, sin]``) the rotation is ``x cos + turn(x)
 sin_signed``, ``turn`` a circular shift of a head's lanes by half a head.
+
+Interleaved pairing (``interleaved``; DeepSeek-V3's ``rope_interleave``):
+dimension ``2i`` pairs with ``2i + 1``, the tables repeat each angle twice
+(``cos = [c0, c0, c1, c1, ..]``, ``sin_signed = [-s0, s0, -s1, s1, ..]``) and
+``turn`` swaps neighbours: an even lane takes the lane after it, an odd one
+the lane before. The rotated part may be the LAST ``rot`` lanes of a head
+(``last``: latent attention's 64 rotated dimensions behind 128 without
+positions); the lanes outside it meet cosine 1 and sine 0 wherever they lie.
 
 Two forms of the same arithmetic:
 
@@ -15,6 +25,9 @@ Two forms of the same arithmetic:
   ``[batch, seq, heads·head_dim]`` for heads of whole 128-lane tiles: the
   shift is one rotation of a vreg's lanes on the XLU, the arrays are read
   and written once as the rows the projections leave and the kernels take.
+  Under interleaved pairing a head need not be whole tiles: the kernel
+  works on units of ``lcm(head_dim, 128)`` lanes (two heads of 192), whose
+  neighbours' swap never leaves a head.
   As XLA operations the half-head slices made the compiler lay q and k out
   with the sequence in the lanes and copy them back in front of every
   kernel (four float32 copies a layer application: PERF.md section 6,
@@ -65,7 +78,8 @@ def yarn_inv_freq(rot: int, theta: float, factor: float, original: int,
 
 
 def rope_tables(seq: int, head_dim: int, theta: float,
-                rot: Optional[int] = None, yarn: Optional[dict] = None):
+                rot: Optional[int] = None, yarn: Optional[dict] = None,
+                interleaved: bool = False, last: bool = False):
     """``(cos, sin_signed)``, each float32 ``[seq, head_dim]``, of positions
     ``0..seq-1``. Made once a forward pass for each rotary scheme and handed
     to the layers of that scheme.
@@ -75,8 +89,13 @@ def rope_tables(seq: int, head_dim: int, theta: float,
     (cosine 1, sine 0). ``yarn``: ``factor``,
     ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow`` and
     ``attention_factor`` of a YaRN scheme — :func:`yarn_inv_freq`'s
-    frequencies, cosine and sine times the attention factor."""
+    frequencies, cosine and sine times the attention factor.
+    ``interleaved``: dimension ``2i`` pairs with ``2i + 1``; with ``last``
+    the rotated dimensions are a head's last ``rot``."""
     rot = rot or head_dim
+    if last and not interleaved:
+        raise ValueError("rope_tables: a rotated part behind the passed one "
+                         "is written for interleaved pairing alone")
     if yarn is None:
         inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
     else:
@@ -90,6 +109,13 @@ def rope_tables(seq: int, head_dim: int, theta: float,
             or 0.1 * math.log(yarn["factor"]) + 1.0
         cos, sin = cos * gain, sin * gain
     passed = head_dim - rot
+    if interleaved:
+        parts = ([jnp.repeat(cos, 2, axis=-1)],
+                 [jnp.stack([-sin, sin], -1).reshape(seq, rot)])
+        for part, fill in zip(parts, (jnp.ones, jnp.zeros)):
+            if passed:
+                part.insert(0 if last else 1, fill((seq, passed), jnp.float32))
+        return tuple(jnp.concatenate(part, axis=-1) for part in parts)
     return (jnp.concatenate(
                 [cos, cos] + [jnp.ones((seq, passed), jnp.float32)] * (passed > 0),
                 axis=-1),
@@ -98,12 +124,16 @@ def rope_tables(seq: int, head_dim: int, theta: float,
                 axis=-1))
 
 
-def _turn(x, rot: int, roll):
+def _turn(x, rot: int, roll, interleaved: bool = False):
     """A head's lanes with each rotated dimension's partner in its place
-    (``i`` <-> ``i + rot / 2`` for ``i < rot / 2``); what the lanes past
-    ``rot`` hold meets a zero sine. ``roll(x, shift)`` turns the last
-    axis."""
+    (``i`` <-> ``i + rot / 2`` for ``i < rot / 2``; ``interleaved``: ``2i``
+    <-> ``2i + 1``, wherever the rotated part lies); what the lanes outside
+    the rotated part hold meets a zero sine. ``roll(x, shift)`` turns the
+    last axis."""
     d = x.shape[-1]
+    if interleaved:
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+        return jnp.where(lane % 2 == 0, roll(x, d - 1), roll(x, 1))
     if rot == d:
         return roll(x, d // 2)
     lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
@@ -112,30 +142,33 @@ def _turn(x, rot: int, roll):
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin_signed: jax.Array,
-               rot: Optional[int] = None):
+               rot: Optional[int] = None, interleaved: bool = False):
     """The rotation on ``[batch, seq, heads, head_dim]`` in ``jax.numpy``."""
     with jax.named_scope("rope"):
         x32 = x.astype(jnp.float32)
         turned = _turn(x32, rot or x.shape[-1],
-                       lambda a, shift: jnp.roll(a, shift, axis=-1))
+                       lambda a, shift: jnp.roll(a, shift, axis=-1),
+                       interleaved)
         return (x32 * cos[None, :, None, :]
                 + turned * sin_signed[None, :, None, :]).astype(x.dtype)
 
 
 def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int,
-                 negate: bool, rot: int):
+                 negate: bool, rot: int, interleaved: bool = False):
     # x_ref, o_ref: [rows, heads · d]; cos_ref, sin_ref: [rows, d] float32
+    # (interleaved: d is a unit of whole heads AND whole lane tiles)
     cos, sin = cos_ref[...], sin_ref[...]
     if negate:
         sin = -sin
     for g in range(x_ref.shape[1] // head_dim):
         cols = slice(g * head_dim, (g + 1) * head_dim)
         x = x_ref[:, cols].astype(jnp.float32)
-        turned = _turn(x, rot, lambda a, shift: pltpu.roll(a, shift, 1))
+        turned = _turn(x, rot, lambda a, shift: pltpu.roll(a, shift, 1),
+                       interleaved)
         o_ref[:, cols] = (x * cos + turned * sin).astype(o_ref.dtype)
 
 
-def _call(x, cos, sin_signed, head_dim, negate, interpret, rot):
+def _call(x, cos, sin_signed, head_dim, negate, interpret, rot, interleaved):
     b, s, width = x.shape
     rows = next(r for r in (_ROWS, 128, 64, 32, 16, 8, s) if s % r == 0
                 and (r <= 8 or r * width * x.dtype.itemsize <= _BLOCK_BYTES))
@@ -143,7 +176,7 @@ def _call(x, cos, sin_signed, head_dim, negate, interpret, rot):
     table = pl.BlockSpec((rows, head_dim), lambda i, j: (j, 0))
     return pl.pallas_call(
         functools.partial(_rope_kernel, head_dim=head_dim, negate=negate,
-                          rot=rot),
+                          rot=rot, interleaved=interleaved),
         grid=(b, s // rows),
         in_specs=[mine, table, table],
         out_specs=mine,
@@ -153,22 +186,24 @@ def _call(x, cos, sin_signed, head_dim, negate, interpret, rot):
     )(x, cos, sin_signed)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _rope(x, cos, sin_signed, head_dim, interpret, rot):
-    return _call(x, cos, sin_signed, head_dim, False, interpret, rot)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _rope(x, cos, sin_signed, head_dim, interpret, rot, interleaved):
+    return _call(x, cos, sin_signed, head_dim, False, interpret, rot,
+                 interleaved)
 
 
-def _rope_fwd(x, cos, sin_signed, head_dim, interpret, rot):
-    return _call(x, cos, sin_signed, head_dim, False, interpret, rot), (
-        cos, sin_signed)
+def _rope_fwd(x, cos, sin_signed, head_dim, interpret, rot, interleaved):
+    return _call(x, cos, sin_signed, head_dim, False, interpret, rot,
+                 interleaved), (cos, sin_signed)
 
 
-def _rope_bwd(head_dim, interpret, rot, tables, g):
+def _rope_bwd(head_dim, interpret, rot, interleaved, tables, g):
     # the tables are positions, not parameters: no gradient. The partner
     # map is its own inverse and the sine changes sign across a pair, so
     # the transpose is the same kernel with the sine negated, whatever
     # gain the tables carry.
-    return _call(g, *tables, head_dim, True, interpret, rot), None, None
+    return _call(g, *tables, head_dim, True, interpret, rot,
+                 interleaved), None, None
 
 
 _rope.defvjp(_rope_fwd, _rope_bwd)
@@ -176,20 +211,34 @@ _rope.defvjp(_rope_fwd, _rope_bwd)
 
 def rope_rows(x: jax.Array, cos: jax.Array, sin_signed: jax.Array, *,
               head_dim: int, interpret: bool = False,
-              rot: Optional[int] = None) -> jax.Array:
+              rot: Optional[int] = None,
+              interleaved: bool = False) -> jax.Array:
     """The rotation on ``[batch, seq, heads·head_dim]``, heads of whole
     128-lane tiles (``head_dim % 128 == 0``; the caller asks
     :func:`tiles_lanes`), as a Pallas kernel. ``rot`` (None: ``head_dim``):
     the first ``rot`` lanes of a head are rotated, the rest pass.
+    ``interleaved``: the tables' pairing is ``2i`` with ``2i + 1`` (which
+    lanes rotate is the tables' matter), and the heads need only fill whole
+    units of ``lcm(head_dim, 128)`` lanes: the tables are repeated to a unit.
     ``interpret=True`` runs it in the Pallas interpreter — something only a
     test passes."""
-    if not tiles_lanes(head_dim) or x.shape[-1] % head_dim:
+    heads, ragged = divmod(x.shape[-1], head_dim)
+    if ragged or not tiles_lanes(head_dim, heads, interleaved):
         raise ValueError(f"rope_rows: head_dim {head_dim} is not whole "
                          f"128-lane tiles of width {x.shape[-1]}")
     with jax.named_scope("rope"):
-        return _rope(x, cos, sin_signed, head_dim, interpret,
-                     rot or head_dim)
+        if not interleaved:
+            return _rope(x, cos, sin_signed, head_dim, interpret,
+                         rot or head_dim, False)
+        unit = math.lcm(head_dim, 128)
+        cos, sin_signed = (jnp.tile(t, (1, unit // head_dim))
+                           for t in (cos, sin_signed))
+        return _rope(x, cos, sin_signed, unit, interpret, unit, True)
 
 
-def tiles_lanes(head_dim: int) -> bool:
+def tiles_lanes(head_dim: int, heads: int = 1,
+                interleaved: bool = False) -> bool:
+    """Whether :func:`rope_rows` takes ``heads`` heads of ``head_dim``."""
+    if interleaved:
+        return heads * head_dim % math.lcm(head_dim, 128) == 0
     return head_dim % 128 == 0

@@ -46,6 +46,9 @@ LAGUNA = ("laguna", dict(
 ZAYA = ("zaya", dict(
     size="8b", seq_len=8192, vocab=32784, remat_policy="full",
     layer_types=["hybrid"] * 6, experts_held=(0, 8), **_CHIP))
+JOYAI = ("joyai", dict(
+    size="llm-flash", seq_len=8192, vocab=16160, remat_policy="full",
+    layer_types=["dense"] + ["sparse"] * 4, experts_held=(0, 16), **_CHIP))
 
 #: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
@@ -72,6 +75,7 @@ PROGRAMS = {
     "ouro_4x1": (OURO, "dp=1", 4, 4, "adamw", 15.50),
     "laguna_1x2": (LAGUNA, "dp=1", 2, 1, "adamw", 15.05),
     "zaya_1x2": (ZAYA, "dp=1", 2, 1, "adamw", 15.05),
+    "joyai_1x2": (JOYAI, "dp=1", 2, 1, "adamw", 15.05),
 }
 
 

@@ -292,6 +292,55 @@ def test_partial_rotation_both_forms_against_hfs_rotate_half():
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("last", [False, True], ids=["leading", "last"])
+def test_interleaved_pairing_both_forms_and_either_place(last):
+    """Pairs ``(2i, 2i + 1)`` over the leading or the LAST 64 of 128
+    dimensions, the rest passed (DeepSeek-V3's ``rope_interleave``): the
+    tables, the jax.numpy form, and the kernel (interpreted) with its
+    gradient, against the rotation written out."""
+    seq, d, rot, theta = 64, 128, 64, 32e6
+    cos, sin_signed = rope_tables(seq, d, theta, rot, interleaved=True,
+                                  last=last)
+    lo = d - rot if last else 0
+    angle = np.arange(seq)[:, None] * theta ** (-np.arange(0, rot, 2) / rot)
+    assert cos.shape == sin_signed.shape == (seq, d)
+    np.testing.assert_allclose(np.asarray(cos[:, lo:lo + rot:2]),
+                               np.cos(angle), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(cos[:, lo + 1:lo + rot:2]),
+                               np.cos(angle), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(sin_signed[:, lo:lo + rot:2]),
+                               -np.sin(angle), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(sin_signed[:, lo + 1:lo + rot:2]),
+                               np.sin(angle), atol=1e-5)
+    passed = np.ones(d, bool)
+    passed[lo:lo + rot] = False
+    assert np.all(np.asarray(cos)[:, passed] == 1.0)
+    assert np.all(np.asarray(sin_signed)[:, passed] == 0.0)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, seq, 3, d))
+    want = np.asarray(x, np.float64)
+    a, b = want[..., lo:lo + rot:2].copy(), want[..., lo + 1:lo + rot:2].copy()
+    c, s = np.cos(angle)[None, :, None], np.sin(angle)[None, :, None]
+    want[..., lo:lo + rot:2] = a * c - b * s
+    want[..., lo + 1:lo + rot:2] = b * c + a * s
+    got = apply_rope(x, cos, sin_signed, rot=rot, interleaved=True)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+    def kernel(x):
+        return rope_rows(x.reshape(2, seq, -1), cos, sin_signed, head_dim=d,
+                         rot=rot, interpret=True, interleaved=True
+                         ).reshape(x.shape)
+
+    np.testing.assert_allclose(np.asarray(kernel(x)), want, atol=2e-5)
+    g_kernel = jax.grad(lambda x: jnp.sum(kernel(x) ** 3))(x)
+    g_plain = jax.grad(lambda x: jnp.sum(apply_rope(
+        x, cos, sin_signed, rot=rot, interleaved=True) ** 3))(x)
+    np.testing.assert_allclose(np.asarray(g_kernel), np.asarray(g_plain),
+                               atol=1e-4)
+    # rotate-half tables behind a passed part are not written
+    with pytest.raises(ValueError, match="interleaved"):
+        rope_tables(seq, d, theta, rot, last=True)
+
+
 # -------------------------------------------------------------- the shares
 def test_the_shares_add_up_to_the_uncut_reference_layer():
     """16 experts over 4 shares: the four parts, the shared expert counted

@@ -283,6 +283,50 @@ def test_laguna_trains_on_ep_mesh(eight_devices):
         float(first["loss"]) - losses[0]) < 1e-4
 
 
+def test_joyai_trains_on_ep_mesh(eight_devices):
+    """JoyAI-LLM's test size beside Laguna's, all 32 experts held, sharded
+    over ep=4 with the batch over dp=2: latent attention and the module's
+    layer under the mesh, each expert shard computing its eight experts'
+    part (the module's too) — the same loss as one device gives, a finite,
+    falling loss, nothing dropped, both heads' losses in the metrics."""
+    kwargs = dict(size="test", seq_len=32, vocab=256,
+                  layer_types=["dense", "sparse", "sparse"])
+    bundle = get_model("joyai", **kwargs)
+
+    def trainer(spec):
+        return Trainer(
+            init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+            optimizer=optax.adam(1e-3),
+            config=TrainConfig(global_batch=8, compute_dtype=jnp.float32),
+            mesh_spec=spec)
+
+    sharded = trainer(MeshSpec(dp=2, ep=4))
+    state = sharded.init_state()
+    flat = shd.flatten_dict(shd.unbox(state.params))
+    held = {k: v for k, v in flat.items() if k.endswith("moe/w_gate")}
+    assert {"blocks_1/moe/w_gate", "mtp_block/moe/w_gate"} <= set(held)
+    for key, w in held.items():
+        assert "ep" in str(w.sharding.spec), (key, w.sharding.spec)
+        assert w.shape[-3] == 32  # every expert held, eight a shard
+
+    batches = [next(iter(bundle.make_data(8, seed=0)))] * 6
+    losses, metrics = [], []
+    for batch in batches:
+        state, m = sharded.train_step(state, batch)
+        losses.append(float(m["loss"]))
+        metrics.append({k: float(v) for k, v in m.items()})
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    assert all(m["moe_dropped"] == 0.0 for m in metrics)
+    # all experts held: each of a token's 4 choices has a row somewhere
+    assert all(abs(m["moe_rows_per_token"] - 4.0) < 1e-6 for m in metrics)
+    assert all(abs(m["loss"] - m["loss_main"] - 0.3 * m["loss_mtp"]) < 1e-4
+               for m in metrics)
+
+    one = trainer(MeshSpec(dp=8))
+    _, first = one.train_step(one.init_state(), batches[0])
+    assert abs(float(first["loss"]) - losses[0]) < 1e-4
+
+
 # ------------------------------------------------------ the grouped products
 def _groups_loop(x, w, sizes, transposed=False):
     """``x[a:b] @ w[g]`` group by group (float64 on the host); rows behind

@@ -116,6 +116,41 @@ def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
                for c in calls), calls
 
 
+def test_the_kernels_compile_at_two_head_sizes(v5e_2x2):
+    """JoyAI-LLM-Flash's call: ``[2, 8192, 32 x 192]`` q and k against ``[2,
+    8192, 32 x 128]`` v, bf16 causal, the looped side. Two heads to a cell:
+    384-lane blocks whose heads are lane slices at 0 and 192 (one and a half
+    tiles: interpret mode cannot say whether Mosaic takes them), the whole
+    sequence's k and v of a cell twice in VMEM (21 MB: over the compiler's
+    own limit, so the calls name theirs); and q's rotation on rows of
+    192-lane heads."""
+    from easydl_tpu.ops.flash_attention import choose_blocks
+    from easydl_tpu.ops.rope import rope_rows, rope_tables
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
+    assert choose_blocks(8192, 8192, True) == ((512, 512),) * 3
+    fn = jax.grad(_loss(lambda q, k, v: flash_attention(q, k, v, causal=True)),
+                  argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(q, q, v).compile()
+    calls = _mosaic_calls(compiled)
+    assert len(calls) == 3, calls
+    assert sorted(c.count("bf16[2,8192,6144]") for c in calls) == [0, 1, 1]
+    assert sorted(c.count("bf16[2,8192,4096]") for c in calls) == [0, 1, 1]
+    for name in ("mla_fwd", "mla_bwd_dq", "mla_bwd_dkv"):
+        assert name in compiled.as_text(), name
+
+    def rotate(x):
+        tables = rope_tables(8192, 192, 32e6, 64, interleaved=True, last=True)
+        return rope_rows(x, *tables, head_dim=192, rot=64, interleaved=True)
+
+    rows = jax.ShapeDtypeStruct((2, 8192, 32 * 192), jnp.bfloat16,
+                                sharding=one)
+    rotated, = _mosaic_calls(jax.jit(rotate).lower(rows).compile())
+    assert rotated.startswith("bf16[2,8192,6144]")
+
+
 @pytest.mark.parametrize("form", ["rows", "rows_transposed_pair", "weights"])
 @pytest.mark.parametrize("rows, groups, contract, cols", [
     (15488, 8, 2048, 2048),    # ZAYA1's cell: a piece over 8 experts of 2048
