@@ -337,8 +337,10 @@ class TransformerConfig:
     #: remat granularity: "full" recomputes the whole block (min memory);
     #: "dots" keeps what costs a matrix product to make again — every
     #: product, q, k, v and `out` after their bias — and the flash
-    #: forward's `lse`; it recomputes the elementwise rest and the forward
-    #: kernel (``ops/remat.py`` says why; faster on TPU when HBM allows).
+    #: forward's `lse`; it recomputes the elementwise rest. Both keep the
+    #: flash forward's `out` and `lse` where the call is dear to make again
+    #: and recompute the kernel elsewhere (``ops/remat.py`` has the rule
+    #: and why; "dots" is faster on TPU when HBM allows).
     remat_policy: str = "full"
     attention_impl: str = "auto"
     #: compute/activation dtype ("float32" | "bfloat16"). Params stay f32;
@@ -1023,21 +1025,31 @@ class Block(nn.Module):
                 h, aux, state = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x),
                                      state)
                 x = residual(x, h, "ln_mlp")
-        if cfg.remat and cfg.remat_policy == "dots" \
-                and not self.is_initializing():
-            kept = [(name, size) for name, size in named
-                    if name in remat.KEPT]
-            names = [name for name, _ in kept]
-            kinds = ", ".join(f"{names.count(kind)} x {kind}"
-                              for kind in dict.fromkeys(names))
-            left = sorted({name for name, _ in named} - set(remat.KEPT))
-            log_once(log, f"remat dots: a ({self.mixer}, {self.ffn}) layer at "
-                          f"{tuple(x.shape)} keeps {len(kept)} values by "
-                          f"name ({kinds}), "
-                          f"{sum(size for _, size in kept) / 1e6:.1f} MB a "
-                          f"microbatch as traced (a kernel's per shard under "
-                          f"a mesh), beside its unnamed products; named and "
-                          f"not kept: {', '.join(left) or 'nothing'}")
+        if cfg.remat and not self.is_initializing():
+            saved = remat.KEPT[cfg.remat_policy]
+            kept = [value for value in named if value.label in saved]
+            labels = [value.label for value in kept]
+            kinds = ", ".join(f"{labels.count(kind)} x {kind}"
+                              for kind in dict.fromkeys(labels))
+            left = sorted({value.label for value in named} - set(saved))
+            costs = sorted({round(value.flop_per_byte) for value in named
+                            if value.flop_per_byte is not None})
+            log_once(log, f"remat {cfg.remat_policy}: a ({self.mixer}, "
+                          f"{self.ffn}) layer at {tuple(x.shape)} keeps "
+                          f"{len(kept)} values by name ({kinds or 'none'}), "
+                          f"{sum(value.bytes for value in kept) / 1e6:.1f} MB "
+                          f"a microbatch as traced (a kernel's per shard "
+                          f"under a mesh), "
+                          + ("beside its unnamed products"
+                             if cfg.remat_policy == "dots"
+                             else "and makes everything else again")
+                          + f"; named and not kept: "
+                            f"{', '.join(left) or 'nothing'}"
+                          + "".join(
+                              f"; its flash forward costs {cost:,} FLOP a "
+                              f"byte of out + lse to make again (kept from "
+                              f"{remat.FLASH_KEEP_FLOP_PER_BYTE:,})"
+                              for cost in costs))
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         return (x, state) if cfg.router_state_width else x, aux
 
@@ -1198,10 +1210,10 @@ class Transformer(nn.Module):
                     f"remat_policy must be 'full' or 'dots', got "
                     f"{cfg.remat_policy!r}"
                 )
-            # "full" keeps nothing: the names in the block are inert there
-            policy = (remat.dots_policy() if cfg.remat_policy == "dots"
-                      else None)
-            block_cls = nn.remat(Block, prevent_cse=False, policy=policy)
+            # "full" keeps the flash forward's results where ops/remat.py's
+            # rule picks the call, and nothing else
+            block_cls = nn.remat(Block, prevent_cse=False,
+                                 policy=remat.policy(cfg.remat_policy))
         # One traced block a run of equal layers, scanned over a stacked
         # 'layers' param axis: `blocks` where the whole stack is one run
         # (GPT-2, BERT), `blocks_<i>` where the pattern has several.

@@ -1167,10 +1167,13 @@ def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
     # The kernel's column [B, H, S, 1] turned once into dense rows
     # [B, H, S]; both named HERE, so that the residuals below are the named
     # values (a name on the primal outside the rule would name a copy, and
-    # the kernel's own results would still be made again). Where nothing is
+    # the kernel's own results would still be made again). Which names, and
+    # so whether a rematerialised block keeps them or runs this kernel
+    # again, is ops/remat.py's rule on what the call shows. Where nothing is
     # differentiated the turn is dead code.
-    out = remat.name(out, remat.FLASH_OUT)
-    lse = remat.name(lse.reshape(lse.shape[:3]), remat.FLASH_LSE)
+    out, lse = remat.name_flash(
+        out, lse.reshape(lse.shape[:3]), s_k=k.shape[1],
+        head_dim=q.shape[-1] // heads, causal=causal, window=window)
     return out, (q, k, v, out, lse)
 
 

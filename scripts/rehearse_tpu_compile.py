@@ -57,10 +57,17 @@ JOYAI = ("joyai", dict(
 #: PR 30), XL's shard 14.111, the hybrid's 15.601, Ouro's 15.488, Laguna's
 #: share 14.851 (15.227 before the expert layer's sort went in pieces,
 #: PR 32; 15.006 before its grouped products were kernels of the repo's own,
-#: PR 36), ZAYA1's share 14.869 (15.000 before PR 36: the backward keeps no
-#: float32 copy of the cotangent's rows and no third result of the experts)
-#: — a change to the shared block, kernels or policy may not grow
-#: them unseen.
+#: PR 36), ZAYA1's share 14.767 (15.000 before PR 36: the backward keeps no
+#: float32 copy of the cotangent's rows and no third result of the experts;
+#: 14.869 before PR 38), JoyAI-LLM-Flash's share 14.354 (13.798 before
+#: PR 38). PR 38: remat `full` keeps the flash forward's `out` and `lse` of
+#: the calls ops/remat.py's rule picks — JoyAI's four scanned layers hold
+#: 4 x 136 MB more (+0.556 GiB), ZAYA1's six hold 6 x 34 MB and the
+#: backward's body no longer makes the kernel's results beside them (-0.102
+#: GiB); Laguna's two picked layers are runs of one whose `out` was live
+#: from forward to backward already (14.850); the rule picks nothing in the
+#: other five — a change to the shared block, kernels or policy may not
+#: grow them unseen.
 PROGRAMS = {
     "one": (MEDIUM, "dp=1", 8, 1, "adamw", None),    # chip_smoke train/resume/elastic
     "one_accum": (MEDIUM, "dp=1", 32, 8, "adamw", None),  # chip_smoke --mesh, the comparison
@@ -74,8 +81,8 @@ PROGRAMS = {
     "hybrid_4x2": (HYBRID, "dp=1", 8, 4, "adamw", 15.61),
     "ouro_4x1": (OURO, "dp=1", 4, 4, "adamw", 15.50),
     "laguna_1x2": (LAGUNA, "dp=1", 2, 1, "adamw", 15.05),
-    "zaya_1x2": (ZAYA, "dp=1", 2, 1, "adamw", 15.05),
-    "joyai_1x2": (JOYAI, "dp=1", 2, 1, "adamw", 15.05),
+    "zaya_1x2": (ZAYA, "dp=1", 2, 1, "adamw", 14.85),
+    "joyai_1x2": (JOYAI, "dp=1", 2, 1, "adamw", 14.45),
 }
 
 
@@ -145,7 +152,8 @@ def main() -> None:
         text = compiled.as_text()
         calls = mosaic_calls(text)
         shapes = sorted({re.sub(r"\{[^}]*\}", "", out) for _, out in calls})
-        forwards = sum("flash_fwd" in instruction for instruction, _ in calls)
+        forwards = sum(any(kernel in instruction for kernel in (
+            "flash_fwd", "mla_fwd", "swa_fwd")) for instruction, _ in calls)
         collectives = {op: len(re.findall(rf" {op}(?:-start)?\(", text))
                        for op in ("all-gather", "all-reduce", "all-to-all",
                                   "reduce-scatter", "collective-permute")}
@@ -159,7 +167,8 @@ def main() -> None:
               f"{mem.argument_size_in_bytes / 2**30:.2f} GiB + temporaries "
               f"{mem.temp_size_in_bytes / 2**30:.2f} GiB = {gib:.3f} GiB"
               + (f" (limit {limit})" if limit else "") + f"; "
-              f"{len(calls)} Mosaic calls, {forwards} of them flash_fwd, "
+              f"{len(calls)} Mosaic calls, {forwards} of them a flash forward "
+              f"(flash_fwd, mla_fwd, swa_fwd), "
               f"outputs {shapes}; "
               f"collectives {collectives}; all-gathered shapes {gathered}",
               flush=True)

@@ -71,3 +71,20 @@ def fused_head(monkeypatch):
             monkeypatch.setattr(fused_xent, "CHUNK_ROWS", chunk_rows)
 
     return steer
+
+
+@pytest.fixture
+def flash_kept(monkeypatch):
+    """``flash_kept()``: from the call on, every flash forward is dear
+    enough to keep (``ops/remat.FLASH_KEEP_FLOP_PER_BYTE`` -> 0), so that a
+    test-size stack under remat keeps ``out`` and ``lse`` as JoyAI-LLM-Flash's
+    and ZAYA1's cells do. The constant is read when the kernel's
+    differentiation rule is TRACED: build the other side of a comparison
+    before the call. What a call keeps is no argument of the program; a test
+    steers it here."""
+    def steer():
+        from easydl_tpu.ops import remat
+
+        monkeypatch.setattr(remat, "FLASH_KEEP_FLOP_PER_BYTE", 0)
+
+    return steer

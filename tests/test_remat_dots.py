@@ -1,6 +1,7 @@
 """What remat ``dots`` keeps (PR 30): the attention projections' sums and the
 flash forward's ``lse`` as dense rows, by name (``ops/remat.py``); the
-forward's ``out`` is named and not kept.
+forward's ``out`` is named and, at these sizes, not kept (a call dear enough
+to make again keeps it: ``tests/test_remat_full.py``, PR 38).
 
 On the CPU, kernels interpreted: a kept value and a recomputed one are the
 same number, so gradients under ``dots`` equal those with no remat, and the
@@ -81,11 +82,12 @@ def test_dq_dk_dv_from_rows_of_lse_are_the_parents(case):
 @pytest.mark.parametrize("seq,walk", [(64, "unrolled"), (160, "looped")])
 def test_the_rule_names_out_and_lse_and_dots_keeps_the_rows(seq, walk):
     """The residuals of the differentiation rule are NAMED values: a policy
-    that saves both names keeps ``out`` and ``lse`` as ``[batch, heads,
+    that saves every name keeps ``out`` and ``lse`` as ``[batch, heads,
     seq]`` float32 rows — no ``[.., seq, 1]`` column (64 MB of lane padding
     a layer at the medium cell's shape) — and runs no kernel again. Remat
-    ``dots`` keeps the rows and not ``out`` (``ops/remat.py`` has the
-    measurements); jax's own ``dots_saveable`` keeps neither."""
+    ``dots`` keeps the rows and, of a call this cheap to make again, not
+    ``out`` (``ops/remat.py`` has the rule and the measurements); ``full``
+    and jax's own ``dots_saveable`` keep neither."""
     from easydl_tpu.ops.flash_attention import _unrolled
 
     assert _unrolled(seq // 32, seq // 32) == (walk == "unrolled")
@@ -100,8 +102,9 @@ def test_the_rule_names_out_and_lse_and_dots_keeps_the_rows(seq, walk):
 
     assert kept(policies.save_only_these_names(*remat.NAMES)) \
         == sorted([(2, seq, 4 * 64), (2, 4, seq)])  # out; lse as rows
-    assert remat.FLASH_OUT not in remat.KEPT
-    assert kept(remat.dots_policy()) == [(2, 4, seq)]
+    assert remat.FLASH_OUT_CHEAP not in remat.KEPT["dots"]
+    assert kept(remat.policy("dots")) == [(2, 4, seq)]
+    assert kept(remat.policy("full")) == []
     assert kept(policies.dots_saveable) == []
 
 
@@ -119,7 +122,7 @@ def test_dots_keeps_the_sum_and_drops_the_product_it_was_made_from():
 
     for named in (True, False):
         kept = saved_residuals(jax.checkpoint(
-            functools.partial(block, named=named), policy=remat.dots_policy(),
+            functools.partial(block, named=named), policy=remat.policy("dots"),
             prevent_cse=False), x, w, b)
         results = [why for shape, why in kept if shape == (4, 16)]
         assert len(results) == 1, kept  # the sum OR the product, never both
@@ -128,15 +131,16 @@ def test_dots_keeps_the_sum_and_drops_the_product_it_was_made_from():
 
 def test_a_tally_counts_what_was_named_and_only_while_open():
     x = jnp.ones((2, 3), jnp.bfloat16)
-    assert set(remat.KEPT) < set(remat.NAMES)
+    assert all(set(kept) < set(remat.NAMES) for kept in remat.KEPT.values())
     remat.name(x, remat.PROJECTION)  # no tally open: just the name
     with remat.tally() as named:
         remat.name(x, remat.PROJECTION)
         with remat.tally() as inner:
-            remat.name(x.astype(jnp.float32), remat.FLASH_LSE)
-        remat.name(x, remat.FLASH_OUT)
-    assert inner == [(remat.FLASH_LSE, 24)]
-    assert named == [(remat.PROJECTION, 12), (remat.FLASH_OUT, 12)]
+            remat.name(x.astype(jnp.float32), remat.FLASH_LSE, 8067.0)
+        remat.name(x, remat.FLASH_OUT_CHEAP)
+    assert inner == [(remat.FLASH_LSE, 24, 8067.0)]
+    assert named == [(remat.PROJECTION, 12, None),
+                     (remat.FLASH_OUT_CHEAP, 12, None)]
     with pytest.raises(AssertionError):
         remat.name(x, "a name the policy does not save")
 

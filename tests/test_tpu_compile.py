@@ -586,6 +586,72 @@ def test_rotary_puts_no_copy_between_the_projections_and_the_kernels(
             assert "bf16[1,4096,2048]{2,1,0" in result, result
 
 
+# ------------------------------------------------- what remat full keeps
+def _scanned_run(devices, factory: str, **description) -> list:
+    """``_attention_instructions`` of loss and gradients of a two-layer
+    scanned run of ``factory``'s description at the cell's widths (2 x
+    8,192 tokens, bf16, remat ``full``, a 1,024-row head; the expert layer's
+    kernels compiled as on the chip), for one described chip."""
+    import flax.linen as nn
+
+    from easydl_tpu.models.registry import get_model
+    from easydl_tpu.ops import moe
+
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    on_tpu, moe._on_tpu = moe._on_tpu, lambda: True
+    try:
+        one = SingleDeviceSharding(devices[0])
+        bundle = get_model(
+            factory, seq_len=8192, vocab=1024, dtype="bfloat16", remat=True,
+            remat_policy="full", attention_impl="flash", **description)
+        params = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+            jax.eval_shape(lambda: nn.unbox(
+                bundle.init_fn(jax.random.PRNGKey(0)))))
+        tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one)
+        text = jax.jit(jax.grad(
+            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+        )).lower(params, {"inputs": tokens, "targets": tokens}
+                 ).compile().as_text()
+    finally:
+        moe._on_tpu = on_tpu
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    return _attention_instructions(text)
+
+
+@pytest.mark.parametrize("factory,description,forward,rows,reader", [
+    ("joyai", dict(size="llm-flash", layer_types=["sparse"] * 2, mtp=False,
+                   experts_held=(0, 16)), "mla_fwd", 4096, "mla_out"),
+    ("zaya", dict(size="8b", layer_types=["hybrid"] * 2,
+                  experts_held=(0, 8)), "flash_fwd", 1024, "cca_up"),
+], ids=["joyai-llm-flash", "zaya1-8b"])
+def test_full_keeps_the_flash_forwards_results_of_a_dear_call(
+        v5e_2x2, factory, description, forward, rows, reader):
+    """A scanned run at the cell's shape under remat ``full``
+    (``ops/remat.py``'s rule picks the call: 10,084 and 8,067 FLOP a byte):
+    the forward kernel stands once, in the forward pass, and not again under
+    ``rematted_computation``; dq and dkv read the kept ``out`` and ``lse``.
+    And nothing moves the kept ``out`` between the layers' stack and the
+    projection that reads it (``mla_out``, ``cca_up``): no ``copy`` or
+    ``transpose`` of an ``out``-sized array in the recomputation (PR 30: XLA
+    wrote such a slice twice and transposed it)."""
+    found = _scanned_run(v5e_2x2, factory, **description)
+    back = forward.replace("fwd", "bwd")
+    calls = sorted((which, path.split("/")[-2])
+                   for which, opcode, _, path in found
+                   if opcode == "custom-call"
+                   and path.split("/")[-2] in (forward, f"{back}_dq",
+                                               f"{back}_dkv"))
+    assert calls == [("bwd", f"{back}_dkv"), ("bwd", f"{back}_dq"),
+                     ("fwd", forward)], calls
+    moved = [(opcode, result, path) for which, opcode, result, path in found
+             if which == "remat" and opcode in ("copy", "transpose")
+             and f"[2,8192,{rows}]" in result]
+    assert not [m for m in moved if reader in m[2]
+                or "multihead_attention" in m[2]], moved
+
+
 # ---------------------------------------------------------------- Laguna
 @pytest.mark.parametrize("heads,window,rot,names", [
     (64, 512, None, ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")),
