@@ -8,11 +8,26 @@ TPU it is a Pallas kernel tiled for the MXU (block sizes multiples of 128
 lanes).
 
 Backward follows the standard flash decomposition: save per-row logsumexp
-``lse`` from the forward; recompute P = exp(qkᵀ·scale − lse) blockwise; a
-dq kernel loops K-blocks, a dk/dv kernel loops Q-blocks; the rowwise
-``delta = Σ dO∘O`` term is formed inside both from an ``O`` operand (a
-product and a turn on the XLU a Q-block, under the kernels' compute, where
-an XLA pass over dO and O stood in the open).
+``lse`` from the forward; recompute P = exp(qkᵀ·scale − lse) blockwise; the
+rowwise ``delta = Σ dO∘O`` term is formed inside the kernels from an ``O``
+operand (a product and a turn on the XLU a Q-block, under the kernels'
+compute, where an XLA pass over dO and O stood in the open). Where the
+backward is looped (below) it is ONE kernel on the grid ``(batch row, lane
+block, K-block)`` that walks the K-block's live Q-blocks: a pair's score
+tile, ``exp``, ``dP``, ``delta`` and ``dS`` are made once and feed three
+products, ``dV += Pᵀ dO``, ``dK += dSᵀ Q`` and ``dQᵀ[Q-block] += Kᵀ dSᵀ`` —
+five products a pair. dq's float32 sum lives in VMEM scratch, ``[Q-blocks,
+cell heads · head_dim, block_q]`` (transposed, a Q-block a leading index),
+zeroed at K-block 0, added to in ascending K-block order, and turned, scaled
+and rounded once at the last K-block into a dq block that stays resident
+while the K-block axis runs (``dimension_semantics``: that axis
+``arbitrary``); rows no key sees stay zero. The call says what VMEM it needs
+from what it holds (q, O, dO and the dq block of a cell's heads whole, twice,
+and the sum: 55 MB at 8,192 x two heads of 192 / 128). Where the backward is
+unrolled a grid cell takes a head's whole sequence, no axis carries a sum,
+and two kernels stand: dq walks K-blocks, dk/dv walks Q-blocks, each making
+the pair's tile for itself (seven products a pair; the trade there is code
+size against set-up time).
 
 What follows the input and what is float32, always. Every matmul takes its
 operands in the dtype q, k, v and dO arrive in (bf16 in, bf16 to the MXU;
@@ -24,10 +39,10 @@ Float32 regardless of the input: the scores as they leave the MXU, the
 running max and normaliser, ``exp``, ``lse``, ``delta``, ``dp − delta`` and
 the output / dq / dk / dv accumulators. The softmax scale costs no second
 rounding: it is folded into the block a loop keeps (q, or k in the dk/dv
-kernel) when that is exact — float32, or a power of two as 1/8 is at
+and the one-kernel backward) when that is exact — float32, or a power of two as 1/8 is at
 head_dim 64 — and multiplies the float32 scores otherwise.
 
-All three kernels hold a score tile transposed, ``Sᵀ = K Qᵀ`` as
+All the kernels hold a score tile transposed, ``Sᵀ = K Qᵀ`` as
 ``[block_k, block_q]``: keys along sublanes, queries along lanes. The
 per-query statistics (max, normaliser, ``lse``, ``delta``) are then rows of
 ``block_q`` lanes instead of ``[block_q, 1]`` columns that fill one lane in
@@ -39,7 +54,7 @@ transposed in the kernel, on the otherwise idle XLU). ``lse`` leaves the
 forward as a ``[.., seq, 1]`` column (the result type the benchmark's reader
 tells the forward by); the differentiation rule turns it once into dense
 ``[batch, heads, seq]`` rows, the residual it keeps (0.5 MB where the column
-is 64 MB of lane padding), and both backward kernels are handed those rows
+is 64 MB of lane padding), and the backward kernels are handed those rows
 by Q-block (``_lse_operand``).
 
 What a rematerialised block may keep. The rule's forward names the two
@@ -52,13 +67,15 @@ inert.
 
 Causal masking is bottom-right aligned (``offset = s_k − s_q``: query row
 r sees key columns ≤ r + offset, as the reference's ``tril(k=s_k−s_q)``).
-Each Q-block (K-block in dk/dv) walks its live block pairs in two loops:
+Each Q-block (K-block in the backward that walks Q-blocks) walks its live
+block pairs in two loops:
 pairs wholly below the diagonal take no mask, pairs the diagonal crosses are
 masked. When the whole problem is at most ``_UNROLL_PAIRS`` block pairs, one
 grid cell takes the whole sequence of its heads: every block index is then a
 Python number, dead pairs are never emitted and both loops unroll into
 straight-line code that the compiler schedules across pairs. Longer
-sequences take one Q-block (K-block) per grid cell and loop at run time.
+sequences take one Q-block (in the backward: one K-block) per grid cell and
+loop at run time.
 
 The band path. Under a ``window`` of at most a block's keys on a square
 problem (``s_q == s_k``: a training step's window layers) a block meets only
@@ -96,8 +113,9 @@ inside the kernel, the results of a cell's heads joined and stored as whole
 rows. Short sequences widen the block (``_cell_heads``).
 
 Two head sizes. The scores' size (q, k, dq, dk: ``head_dim``) and the
-values' (v, O, dO, dv: ``value_dim``) are two numbers through the three
-looped kernels, the blocks' specs and ``_cell_heads``: latent attention
+values' (v, O, dO, dv: ``value_dim``) are two numbers through the forward,
+the one-kernel backward, the unrolled dq and dk/dv kernels, the blocks'
+specs and ``_cell_heads``: latent attention
 scores 192 deep (128 without positions beside 64 rotated) and weighs values
 128 wide. A cell then takes the heads that fill whole tiles of BOTH widths
 — two heads: a 384-lane block of q and k beside a 256-lane block of v, O and
@@ -105,8 +123,8 @@ dO — and every product keeps its own depth (``S = K Qᵀ`` 192, ``P V`` and
 ``dP = V dOᵀ`` 128): v is not padded to the scores' size, which would cost
 half again those products and the bytes of v, O and dO. Where the two are
 equal the kernels lower to what they lowered to with one; where they differ
-the calls carry names of their own (``mla_fwd``, ``mla_bwd_dq``,
-``mla_bwd_dkv``) and the band path is not offered. A head count the
+the calls carry names of their own (``mla_fwd``, ``mla_bwd``; unrolled
+``mla_bwd_dq``, ``mla_bwd_dkv``) and the band path is not offered. A head count the
 tile does not divide (25 heads of 64: 13 lane blocks) leaves the last cell
 half outside the array: Pallas reads and writes only the part inside, and
 since every head is computed from its own slice alone, whatever the other
@@ -157,7 +175,8 @@ _CELL_BYTES = 6 << 20
 #: in :func:`choose_blocks`)
 MAX_BLOCK = 512
 
-#: (block_q, block_k) of the forward, the dq and the dk/dv kernel.
+#: (block_q, block_k) of the forward, the dq and the dk/dv kernel; where the
+#: backward is looped its one kernel takes dk/dv's.
 Blocks = Tuple[Tuple[int, int], Tuple[int, int], Tuple[int, int]]
 
 
@@ -423,9 +442,9 @@ def _lse_operand(lse, cell: int, block_q: int, whole: bool,
     """``(BlockSpec, operand)`` that hand a backward kernel ``lse`` (dense
     rows ``[B, H, S]``) as one ``[1, block_q]`` row a Q-block, ``[B, H,
     S // block_q, 1, block_q]``: every Q-block of the cell's heads
-    (``whole``: an unrolled cell, and dkv's looped one, which walks them
-    all) or the grid cell's own (dq looped; the band kernels, whose Q-block
-    is a cell's rows or, through ``at``, its neighbour's). The kernels read
+    (``whole``: an unrolled cell, and the looped backward's, which walks
+    them all) or the grid cell's own (the band kernels, whose Q-block is a
+    cell's rows or, through ``at``, its neighbour's). The kernels read
     Q-block ``qb`` of head ``g`` as ``lse_ref[g, qb]``."""
     b, h, s = lse.shape
     blocks = s // block_q if whole else 1
@@ -459,20 +478,27 @@ def _head_cols(lanes: int, d: int):
     return [slice(g * d, (g + 1) * d) for g in range(lanes // d)]
 
 
-#: what the looped kernels may take of VMEM where the two head sizes
-#: differ: a cell holds the whole sequence of two heads' k and v (forward,
-#: dq) or q, O and dO (dk/dv), 15 MB at 8,192 x (384 + 256) bf16 lanes,
-#: twice — over the compiler's own 16 MB (a v5e's VMEM is 128 MB)
+#: what the forward and the unrolled backward kernels may take of VMEM
+#: where the two head sizes differ: a cell holds the whole sequence of two
+#: heads' k and v (forward, dq) or q, O and dO (dk/dv), 15 MB at 8,192 x
+#: (384 + 256) bf16 lanes, twice — over the compiler's own 16 MB (a v5e's
+#: VMEM is 128 MB); the one-kernel backward states its own
 _TWO_SIZE_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 
 
-def _call_name(kernel: str, d: int, dv: int, window: Optional[int]):
-    """A looped kernel's ``name=`` (and compiler parameters) by what it
-    computes: ``flash_*``, ``swa_*`` under a window, ``mla_*`` where the
-    scores' and the values' head sizes differ."""
+def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
+               params: Optional[pltpu.CompilerParams] = None):
+    """A looped kernel's ``name=`` (and compiler parameters: the call's own
+    ``params``, else the two-size allowance) by what it computes:
+    ``flash_*``, ``swa_*`` under a window, ``mla_*`` where the scores' and
+    the values' head sizes differ."""
     if d != dv:
-        return dict(name=f"mla_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
-    return dict(name=f"{'flash' if window is None else 'swa'}_{kernel}")
+        named = dict(name=f"mla_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
+    else:
+        named = dict(name=f"{'flash' if window is None else 'swa'}_{kernel}")
+    if params is not None:
+        named["compiler_params"] = params
+    return named
 
 
 # ---------------------------------------------------------------------------
@@ -600,29 +626,130 @@ def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
 # ---------------------------------------------------------------------------
 
 
+def _over_q_blocks(body, carry, k_start, *, block_q: int, block_k: int,
+                   n_q: int, offset: int, causal: bool, unroll: bool,
+                   window: Optional[int] = None):
+    """``body(qb, carry, masked=)`` over the Q-blocks that see the K-block
+    at ``k_start``, :func:`_over_k_blocks`' mirror: those before
+    ``first_live`` see none of it, [first_live, first_full) are crossed by
+    the diagonal and masked, from ``first_full`` on every row sees all of it
+    — under a ``window`` up to ``last_full``, where the band's far edge
+    crosses the pair (masked again); from ``last_live`` on no row sees any
+    of it."""
+    first_full, last_live, last_full = 0, n_q, n_q
+    masked = functools.partial(body, masked=True)
+    if causal:
+        first_live = _clip((k_start - offset) // block_q, 0, n_q)
+        first_full = _clip(
+            (k_start + block_k - 1 - offset + block_q - 1) // block_q, 0, n_q)
+        near_end = first_full
+        if window is not None:
+            last_live = _clip(
+                (k_start + block_k - 2 - offset + window) // block_q + 1,
+                0, n_q)
+            last_full = _least(_clip(
+                (k_start - offset + window) // block_q, 0, n_q), last_live)
+            near_end = _least(first_full, last_live)
+        carry = _loop(first_live, near_end, masked, carry, unroll=unroll)
+    carry = _loop(first_full, last_full, functools.partial(body, masked=False),
+                  carry, unroll=unroll)
+    if window is not None:
+        carry = _loop(_most(first_full, last_full), last_live, masked, carry,
+                      unroll=unroll)
+    return carry
+
+
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+    dq_sum_ref, *, head_dim: int, value_dim: int, block_q: int, causal: bool,
+    scale: float, offset: int, window: Optional[int],
+):
+    """The looped backward, one K-block a grid cell: dq, dk and dv from ONE
+    score tile, ``exp``, ``dP`` and ``delta`` a block pair."""
+    # q_ref, dq_ref: [S_q, cell heads · d]; o_ref, do_ref: [S_q, cell heads ·
+    # dv]; k_ref, dk_ref: [block_k, cell heads · d]; v_ref, dv_ref: [block_k,
+    # cell heads · dv]; lse_ref: [cell heads, n_q, 1, block_q]; dq_sum_ref
+    # (scratch, float32): [n_q, cell heads · d, block_q], dQᵀ by Q-block
+    block_k, lanes = dk_ref.shape
+    d = head_dim
+    heads = _head_cols(lanes, d)
+    v_heads = _head_cols(dv_ref.shape[1], value_dim)
+    n_q = q_ref.shape[0] // block_q
+    kb = pl.program_id(2)
+    k_start = kb * block_k
+
+    @pl.when(kb == 0)
+    def _():
+        # rows no key sees (s_q > s_k) are never added to: they stay zero
+        dq_sum_ref[...] = jnp.zeros_like(dq_sum_ref)
+
+    ks = [_fold_scale(k_ref[:, cols], scale) for cols in heads]
+    vs = [v_ref[:, cols] for cols in v_heads]
+    kt_all = k_ref[...].T  # [lanes, block_k]: turned once a grid cell
+
+    def body(qb, carry, *, masked: bool):
+        q_start = _block_start(qb, block_q)
+        q_rows = pl.ds(q_start, block_q)
+        deltas = _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :], value_dim)
+        out = []
+        for g, (cols, v_cols, (k, s_scale), v, delta, (dk, dv)) in enumerate(
+                zip(heads, v_heads, ks, vs, deltas, carry)):
+            q = q_ref[q_rows, cols]
+            do = do_ref[q_rows, v_cols]
+            st = _scores_t(k, q, s_scale,
+                           q_start + offset - k_start if masked else None,
+                           window)
+            pt = jnp.exp(st - lse_ref[g, qb])
+            dv_new = dv + _dot(pt.astype(do.dtype), do, _NN)
+            dst = (pt * (_dot(v, do, _NT) - delta)).astype(q.dtype)
+            dq_sum_ref[qb, cols, :] += _dot(kt_all[cols], dst, _NN)
+            out.append((dk + _dot(dst, q, _NN), dv_new))
+        return tuple(out)
+
+    carry = _over_q_blocks(
+        body, tuple((jnp.zeros((block_k, d), jnp.float32),
+                     jnp.zeros((block_k, value_dim), jnp.float32))
+                    for _ in heads),
+        k_start, block_q=block_q, block_k=block_k, n_q=n_q, offset=offset,
+        causal=causal, unroll=False, window=window)
+    # q and k entered the products unscaled (the scale sat on k or the
+    # scores).
+    dk_ref[...] = (_side_by_side([dk for dk, _ in carry], 1) * scale
+                   ).astype(dk_ref.dtype)
+    dv_ref[...] = _side_by_side([dv for _, dv in carry], 1
+                                ).astype(dv_ref.dtype)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _():
+        def finish(qb, _):
+            rows = pl.ds(_block_start(qb, block_q), block_q)
+            dq_ref[rows, :] = (dq_sum_ref[qb] * scale).T.astype(dq_ref.dtype)
+
+        jax.lax.fori_loop(0, n_q, finish, None)
+
+
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
     *, head_dim: int, value_dim: int, block_q: int, block_k: int,
-    causal: bool, scale: float, offset: int, unroll: bool,
-    window: Optional[int],
+    causal: bool, scale: float, offset: int, window: Optional[int],
 ):
-    # lse_ref: [cell heads, the cell's Q-blocks, 1, block_q]
-    cell_rows, lanes = q_ref.shape
+    # the unrolled side: a grid cell takes the whole sequence of its heads;
+    # lse_ref: [cell heads, n_q, 1, block_q]
+    s_q, lanes = q_ref.shape
     d, dv = head_dim, value_dim
     heads = _head_cols(lanes, d)
     v_heads = _head_cols(v_ref.shape[1], dv)
     n_k = k_ref.shape[0] // block_k
-    cell_start = 0 if unroll else pl.program_id(2) * cell_rows
-    for j in range(cell_rows // block_q):
+    for j in range(s_q // block_q):
         rows = slice(j * block_q, (j + 1) * block_q)
-        q_start = cell_start + j * block_q
+        q_start = j * block_q
         qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
         dos = [do_ref[rows, cols] for cols in v_heads]
         lses = [lse_ref[g, j] for g in range(len(heads))]
         deltas = _delta_rows(do_ref[rows, :], o_ref[rows, :], dv)
 
         def body(kb, dq_ts, *, masked: bool):
-            k_start = _block_start(kb, block_k)
+            k_start = kb * block_k
             kt_all = k_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
             out = []
             for cols, v_cols, (q, s_scale), do, lse, delta, dq_t in zip(
@@ -640,7 +767,7 @@ def _bwd_dq_kernel(
         dq_ts = _over_k_blocks(
             body, tuple(jnp.zeros((d, block_q), jnp.float32) for _ in heads),
             q_start, block_q=block_q, block_k=block_k, n_k=n_k, offset=offset,
-            causal=causal, unroll=unroll, window=window)
+            causal=causal, unroll=True, window=window)
         dq_ref[rows, :] = (_side_by_side(list(dq_ts), 0) * scale
                            ).T.astype(dq_ref.dtype)
 
@@ -648,36 +775,31 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
     *, head_dim: int, value_dim: int, block_q: int, block_k: int,
-    causal: bool, scale: float, offset: int, unroll: bool,
-    window: Optional[int],
+    causal: bool, scale: float, offset: int, window: Optional[int],
 ):
-    # lse_ref: [cell heads, n_q, 1, block_q]
-    cell_rows, lanes = dk_ref.shape
+    # the unrolled side, as the dq kernel
+    s_k, lanes = dk_ref.shape
     d = head_dim
     heads = _head_cols(lanes, d)
     v_heads = _head_cols(dv_ref.shape[1], value_dim)
     n_q = q_ref.shape[0] // block_q
-    cell_start = 0 if unroll else pl.program_id(2) * cell_rows
-    stats = {}  # unrolled: a Q-block's lse rows and deltas, read once a cell
-    for j in range(cell_rows // block_k):
+    stats = {}  # a Q-block's lse rows and deltas, read once a cell
+    for j in range(s_k // block_k):
         rows = slice(j * block_k, (j + 1) * block_k)
-        k_start = cell_start + j * block_k
+        k_start = j * block_k
         ks = [_fold_scale(k_ref[rows, cols], scale) for cols in heads]
         vs = [v_ref[rows, cols] for cols in v_heads]
 
         def body(qb, carry, *, masked: bool):
-            q_start = _block_start(qb, block_q)
+            q_start = qb * block_q
             q_rows = pl.ds(q_start, block_q)
-            found = stats.get(qb) if unroll else None
-            if found is None:
-                found = ([lse_ref[g, qb] for g in range(len(heads))],
-                         _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :],
-                                     value_dim))
-                if unroll:
-                    stats[qb] = found
+            if qb not in stats:
+                stats[qb] = ([lse_ref[g, qb] for g in range(len(heads))],
+                             _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :],
+                                         value_dim))
             out = []
             for cols, v_cols, (k, s_scale), v, lse, delta, (dk, dv) in zip(
-                    heads, v_heads, ks, vs, *found, carry):
+                    heads, v_heads, ks, vs, *stats[qb], carry):
                 q = q_ref[q_rows, cols]
                 do = do_ref[q_rows, v_cols]
                 st = _scores_t(k, q, s_scale,
@@ -689,40 +811,23 @@ def _bwd_dkv_kernel(
                 out.append((dk + _dot(dst.astype(q.dtype), q, _NN), dv_new))
             return tuple(out)
 
-        carry = tuple((
-            jnp.zeros((block_k, d), jnp.float32),
-            jnp.zeros((block_k, value_dim), jnp.float32),
-        ) for _ in heads)
-        first_full, last_live, last_full = 0, n_q, n_q
-        masked = functools.partial(body, masked=True)
-        if causal:
-            # Q-blocks before first_live see none of this K-block; from
-            # first_full on every row sees all of it.
-            first_live = _clip((k_start - offset) // block_q, 0, n_q)
-            first_full = _clip(
-                (k_start + block_k - 1 - offset + block_q - 1) // block_q, 0, n_q)
-            near_end = first_full
-            if window is not None:
-                # ... up to last_full, where the band's far edge crosses
-                # the pair; from last_live on no row sees any of it
-                last_live = _clip(
-                    (k_start + block_k - 2 - offset + window) // block_q + 1,
-                    0, n_q)
-                last_full = _least(_clip(
-                    (k_start - offset + window) // block_q, 0, n_q), last_live)
-                near_end = _least(first_full, last_live)
-            carry = _loop(first_live, near_end, masked, carry, unroll=unroll)
-        carry = _loop(first_full, last_full,
-                      functools.partial(body, masked=False), carry,
-                      unroll=unroll)
-        if window is not None:
-            carry = _loop(_most(first_full, last_full), last_live, masked,
-                          carry, unroll=unroll)
+        carry = _over_q_blocks(
+            body, tuple((jnp.zeros((block_k, d), jnp.float32),
+                         jnp.zeros((block_k, value_dim), jnp.float32))
+                        for _ in heads),
+            k_start, block_q=block_q, block_k=block_k, n_q=n_q, offset=offset,
+            causal=causal, unroll=True, window=window)
         # q entered the products unscaled (the scale sat on k or the scores).
         dk_ref[rows, :] = (_side_by_side([dk for dk, _ in carry], 1) * scale
                            ).astype(dk_ref.dtype)
         dv_ref[rows, :] = _side_by_side([dv for _, dv in carry], 1
                                         ).astype(dv_ref.dtype)
+
+
+#: what the compiler allows a kernel of VMEM where the call names no limit:
+#: the one-kernel backward asks for what its blocks hold and this much for
+#: a pair's tiles in flight (all the split looped kernels had)
+_DEFAULT_VMEM = 16 << 20
 
 
 def _bwd(
@@ -736,56 +841,82 @@ def _bwd(
     static = dict(head_dim=d, value_dim=vd, causal=causal, scale=scale,
                   offset=s_k - s_q, window=window)
 
-    block_q, block_k = dq_blocks
-    n_q, n_k = s_q // block_q, s_k // block_k
-    unroll = _unrolled(n_q, n_k)
-    cell = _cell_heads(heads, d, n_q * n_k, unroll,
-                       (2 * s_q + s_k) * (d + vd) * item + s_q * 8 * 4,
-                       vd)  # q dq k at d, o do v at vd, lse
-    cell_q = n_q if unroll else 1  # Q-blocks a grid cell takes
-
     def whole(s, cell, size):
         return pl.BlockSpec((None, s, cell * size), lambda b, h, i: (b, 0, h))
 
-    def mine(size):
-        return pl.BlockSpec((None, cell_q * block_q, cell * size),
-                            lambda b, h, qi: (b, qi, h))
+    block_q, block_k = dkv_blocks
+    n_q, n_k = s_q // block_q, s_k // block_k
+    if not _unrolled(n_q, n_k):
+        # looped: ONE call on dkv's grid gives all three — dq summed in VMEM
+        # across the K-block axis, which is therefore sequential
+        cell = _cell_heads(heads, d, 0, False, 0, vd)
 
-    lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=unroll)
+        def mine(size):
+            return pl.BlockSpec((None, block_k, cell * size),
+                                lambda b, h, ki: (b, ki, h))
+
+        lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
+        # q, dq, O, dO and lse whole and k, v, dk, dv by block, two buffers
+        # each; dq's float32 sum once
+        held = (2 * cell * (2 * (s_q + block_k) * (d + vd) * item
+                            + s_q * 8 * 4) + cell * d * s_q * 4)
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, block_q=block_q, **static),
+            grid=(b, pl.cdiv(heads, cell), n_k),
+            in_specs=[whole(s_q, cell, d), mine(d), mine(vd),
+                      whole(s_q, cell, vd), whole(s_q, cell, vd), lse_spec],
+            out_specs=[whole(s_q, cell, d), mine(d), mine(vd)],
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((n_q, cell * d, block_q), jnp.float32)],
+            interpret=interpret,
+            **_call_name("bwd", d, vd, window, pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=held + _DEFAULT_VMEM)),
+        )(q, k, v, out, do, lse_in)
+
+    # unrolled (at most _UNROLL_PAIRS block pairs a head): one grid cell a
+    # head cell takes the whole sequence, no axis carries a sum — two kernels
+    def mine(s, cell, size):
+        return pl.BlockSpec((None, s, cell * size), lambda b, h, i: (b, i, h))
+
+    block_q, block_k = dq_blocks
+    n_q, n_k = s_q // block_q, s_k // block_k
+    assert _unrolled(n_q, n_k), (dq_blocks, dkv_blocks)  # dq's are no smaller
+    cell = _cell_heads(heads, d, n_q * n_k, True,
+                       (2 * s_q + s_k) * (d + vd) * item + s_q * 8 * 4,
+                       vd)  # q dq k at d, o do v at vd, lse
+    lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          unroll=unroll, **static),
-        grid=(b, pl.cdiv(heads, cell), n_q // cell_q),
+                          **static),
+        grid=(b, pl.cdiv(heads, cell), 1),
         in_specs=[
-            mine(d), whole(s_k, cell, d), whole(s_k, cell, vd), mine(vd),
-            mine(vd), lse_spec,
+            mine(s_q, cell, d), whole(s_k, cell, d), whole(s_k, cell, vd),
+            mine(s_q, cell, vd), mine(s_q, cell, vd), lse_spec,
         ],
-        out_specs=mine(d),
+        out_specs=mine(s_q, cell, d),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         **_call_name("bwd_dq", d, vd, window),
     )(q, k, v, out, do, lse_in)
 
     block_q, block_k = dkv_blocks
-    n_q, n_k = s_q // block_q, s_k // block_k
-    unroll = _unrolled(n_q, n_k)
-    cell = _cell_heads(heads, d, n_q * n_k, unroll,
+    cell = _cell_heads(heads, d, (s_q // block_q) * (s_k // block_k), True,
                        ((s_q + 2 * s_k) * d + 2 * (s_q + s_k) * vd) * item
                        + s_q * 8 * 4, vd)  # q k dk at d, o do v dv at vd, lse
-    cell_k = n_k if unroll else 1  # K-blocks a grid cell takes
-
-    def mine(size):
-        return pl.BlockSpec((None, cell_k * block_k, cell * size),
-                            lambda b, h, ki: (b, ki, h))
-
     lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                          unroll=unroll, **static),
-        grid=(b, pl.cdiv(heads, cell), n_k // cell_k),
-        in_specs=[whole(s_q, cell, d), mine(d), mine(vd),
+                          **static),
+        grid=(b, pl.cdiv(heads, cell), 1),
+        in_specs=[whole(s_q, cell, d), mine(s_k, cell, d), mine(s_k, cell, vd),
                   whole(s_q, cell, vd), whole(s_q, cell, vd), lse_spec],
-        out_specs=[mine(d), mine(vd)],
+        out_specs=[mine(s_k, cell, d), mine(s_k, cell, vd)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -1214,13 +1345,13 @@ def flash_attention(
 
     ``window`` (with ``causal``): query i sees only the ``window`` keys up
     to its own, ``0 <= i + (s_k - s_q) - j < window``. K-blocks (Q-blocks in
-    dk/dv) wholly outside that band are not visited — on a square problem
-    whose window a block holds not even resident (the band path) — and the
-    calls carry names of their own (``swa_fwd``, ``swa_bwd_dq``,
-    ``swa_bwd_dkv``).
+    the backward) wholly outside that band are not visited — on a square
+    problem whose window a block holds not even resident (the band path) —
+    and the calls carry names of their own (``swa_fwd``, ``swa_bwd_dq``,
+    ``swa_bwd_dkv``; looped, ``swa_bwd``).
 
-    ``block_q`` / ``block_k``, when passed, hold for all three kernels; left
-    out, each kernel's are chosen from what the call shows."""
+    ``block_q`` / ``block_k``, when passed, hold for every kernel; left out,
+    each kernel's are chosen from what the call shows."""
     b, s, h, d = q.shape
     s_k, dv = k.shape[1], v.shape[-1]
     if scale is None:
@@ -1238,12 +1369,17 @@ def flash_attention(
             f"<= {block_q or MAX_BLOCK}/{block_k or MAX_BLOCK}")
     device = jax.devices()[0]
     how = "INTERPRETED" if interpret else "compiled"
+    cuts = dict(zip(("fwd", "dq", "dkv"), blocks))
+    if not isinstance(blocks[2], Band) and not _unrolled(
+            s // blocks[2][0], s_k // blocks[2][1]):
+        cuts = {"fwd": blocks[0], "bwd": blocks[2]}  # what _bwd will call
     chosen = ", ".join(
         f"{name} band {cut.rows}/{cut.sub} beside {cut.reach}"
         if isinstance(cut, Band) else
         f"{name} {cut[0]}/{cut[1]} "
         + ("unrolled" if _unrolled(s // cut[0], s_k // cut[1]) else "looped")
-        for name, cut in zip(("fwd", "dq", "dkv"), blocks))
+        for name, cut in cuts.items()) + (", one kernel" if "bwd" in cuts
+                                          else "")
     tile = _cell_heads(h, d, 0, False, 0, dv)  # before short sequences widen it
     sizes = f"head_dim {d}" if dv == d else f"head_dim {d}/{dv}"
     lanes = f"{tile * d}-lane block" if dv == d else \

@@ -320,6 +320,112 @@ def test_looped_walk_matches_reference(s_q, s_k, block_q, block_k, causal):
                         atol=5e-4, rtol=5e-4)
 
 
+ONE_KERNEL = {
+    # s_q, s_k, heads, d, dv, block_q, block_k, causal, window: every one
+    # more block pairs a head than ``_UNROLL_PAIRS``
+    "causal-square": (256, 256, 2, 32, 32, 32, 32, True, None),
+    "sq<sk": (128, 256, 2, 32, 32, 16, 64, True, None),
+    "sq>sk-dead-rows": (256, 128, 2, 32, 32, 64, 16, True, None),
+    "not-causal": (160, 160, 2, 32, 32, 32, 32, False, None),
+    "window-wider-than-a-block": (256, 256, 2, 32, 32, 32, 32, True, 80),
+    "192-128": (640, 640, 2, 192, 128, 128, 128, True, None),
+    "three-heads-of-a-tile-of-two": (320, 320, 3, 64, 64, 64, 64, True, None),
+}
+
+
+def _eqns(jaxpr, kernel=None):
+    """``(equation, name of the pallas_call it stands inside or None)`` for
+    every equation under ``jaxpr``, kernel bodies and loop bodies included."""
+    for eqn in jaxpr.eqns:
+        yield eqn, kernel
+        inside = (eqn.params["name"] if eqn.primitive.name == "pallas_call"
+                  else kernel)
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, inside)
+
+
+def _backward_calls(fn, *args):
+    """``{name: number of results}`` of the ``*_bwd*`` ``pallas_call``s that
+    ``fn(*args)`` traces to."""
+    return {eqn.params["name"]: len(eqn.outvars)
+            for eqn, _ in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "pallas_call"
+            and "_bwd" in eqn.params["name"]}
+
+
+@pytest.mark.parametrize("case", ONE_KERNEL)
+def test_the_looped_backward_is_one_kernel(case, monkeypatch):
+    """Where the backward is looped ONE call gives dq, dk and dv: against
+    the reference's gradients on float32 operands; rows no key sees get a dq
+    of exactly zero; and on bf16 operands (where both put the softmax scale
+    in the same place) all three to the bit what the split dq and dkv
+    kernels give on the same inputs and blocks — reached by calling this
+    shape unrolled, which only a test can ask for."""
+    s_q, s_k, heads, d, dv, block_q, block_k, causal, window = ONE_KERNEL[case]
+    assert not flash_module._unrolled(s_q // block_q, s_k // block_k)
+    keys = jax.random.split(jax.random.PRNGKey(12), 4)
+    q = jax.random.normal(keys[0], (1, s_q, heads, d))
+    k = jax.random.normal(keys[1], (1, s_k, heads, d))
+    v = jax.random.normal(keys[2], (1, s_k, heads, dv))
+    g = jax.random.normal(keys[3], (1, s_q, heads, dv))
+    flash = functools.partial(flash_attention, causal=causal, block_q=block_q,
+                              block_k=block_k, interpret=True, window=window)
+    ref = functools.partial(_reference_attention, causal=causal,
+                            scale=d ** -0.5, window=window)
+
+    def grads(attend, *x):
+        return jax.grad(lambda *x: jnp.sum(attend(*x) * g.astype(x[0].dtype)),
+                        (0, 1, 2))(*x)
+
+    kind = "mla" if d != dv else "swa" if window else "flash"
+    assert _backward_calls(functools.partial(grads, flash), q, k, v) \
+        == {f"{kind}_bwd": 3}
+    got = grads(flash, q, k, v)
+    _assert_grads_close(got, grads(ref, q, k, v), atol=1e-4, rtol=1e-4)
+    dead = max(s_q - s_k, 0) if causal else 0
+    assert not np.asarray(got[0][:, :dead]).any()
+
+    low = [x.astype(jnp.bfloat16) for x in (q, k, v)]
+    one = grads(flash, *low)
+    monkeypatch.setattr(flash_module, "_UNROLL_PAIRS", 1 << 20)
+    assert _backward_calls(functools.partial(grads, flash), *low) \
+        == {f"{kind}_bwd_dq": 1, f"{kind}_bwd_dkv": 2}
+    for mine, theirs, name in zip(one, grads(flash, *low), "qkv"):
+        np.testing.assert_array_equal(
+            np.asarray(mine, np.float32), np.asarray(theirs, np.float32),
+            err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("s,window,said", [
+    (64, None, "fwd 32/32 unrolled, dq 32/32 unrolled, dkv 32/32 unrolled,"),
+    (256, None, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
+    (256, 80, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
+    (256, 32, "fwd band 128/32 beside 32, dq band 128/32 beside 32, "
+              "dkv band 128/32 beside 32,"),
+], ids=["unrolled", "looped", "looped-window", "band"])
+def test_the_logged_line_says_how_many_kernels_the_backward_is(
+        monkeypatch, s, window, said):
+    """The engagement is static: the line a process logs once for a call's
+    shape says ``one kernel`` where (and only where) the backward is."""
+    monkeypatch.setattr(easydl_logging, "_logged_once", set())
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda record: records.append(record.getMessage())
+    flash_module.log.addHandler(handler)
+    try:
+        q, k, v = rand_qkv(jax.random.PRNGKey(1), b=1, s=s, h=1, d=32)
+        jax.eval_shape(functools.partial(
+            flash_attention, causal=True, block_q=32, block_k=32,
+            interpret=True, window=window), q, k, v)
+    finally:
+        flash_module.log.removeHandler(handler)
+    line, = records
+    assert f"blocks q/k {said} over lengths" in line, line
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("against", ["bf16", "float32"])
 def test_bf16_grads(against, causal):
@@ -491,27 +597,12 @@ def _kernel_dots(fn, *args):
     """{kernel name: [(lhs dtype, rhs dtype) of every dot_general inside]}
     for the ``pallas_call``s that ``fn(*args)`` traces to."""
     found = {}
-
-    def subjaxprs(params):
-        for value in params.values():
-            for v in value if isinstance(value, (tuple, list)) else (value,):
-                inner = getattr(v, "jaxpr", v)
-                if hasattr(inner, "eqns"):
-                    yield inner
-
-    def walk(jaxpr, kernel):
-        for eqn in jaxpr.eqns:
-            inside = kernel
-            if eqn.primitive.name == "pallas_call":
-                inside = eqn.params["name"]
-                found.setdefault(inside, [])
-            elif eqn.primitive.name == "dot_general" and kernel:
-                found[kernel].append(tuple(
-                    jnp.dtype(x.aval.dtype).name for x in eqn.invars))
-            for sub in subjaxprs(eqn.params):
-                walk(sub, inside)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    for eqn, kernel in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            found.setdefault(eqn.params["name"], [])
+        elif eqn.primitive.name == "dot_general" and kernel:
+            found[kernel].append(tuple(
+                jnp.dtype(x.aval.dtype).name for x in eqn.invars))
     return found
 
 
@@ -519,7 +610,7 @@ def _kernel_dots(fn, *args):
 @pytest.mark.parametrize("dtype,other", [("bfloat16", "float32"),
                                          ("float32", "bfloat16")])
 def test_matmul_operands_follow_the_input_dtype(dtype, other, looped):
-    """Walk the kernel jaxprs inside the three ``pallas_call``s: with bf16
+    """Walk the kernel jaxprs inside the ``pallas_call``s: with bf16
     inputs no ``dot_general`` takes a float32 operand, with float32 inputs
     none takes a bf16 one."""
     q, k, v = rand_qkv(jax.random.PRNGKey(10), b=1, s=256 if looped else 64,
@@ -527,8 +618,11 @@ def test_matmul_operands_follow_the_input_dtype(dtype, other, looped):
     flash = functools.partial(flash_attention, causal=True, block_q=32,
                               block_k=32, interpret=True)
     dots = _kernel_dots(jax.grad(_loss(flash), argnums=(0, 1, 2)), q, k, v)
-    assert sorted(dots) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
-    assert [len(dots[n]) > 0 for n in sorted(dots)] == [True] * 3
+    # products a block pair, in the masked and in the unmasked loop's body
+    # (unrolled: 3 live pairs a head): five where dq and dkv make seven
+    assert {n: len(found) for n, found in dots.items()} == (
+        {"flash_fwd": 4, "flash_bwd": 10} if looped else
+        {"flash_fwd": 6, "flash_bwd_dq": 9, "flash_bwd_dkv": 12})
     for name, operands in dots.items():
         assert all(pair == (dtype, dtype) for pair in operands), (name, operands)
         assert not any(other in pair for pair in operands)

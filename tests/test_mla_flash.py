@@ -103,22 +103,27 @@ def test_a_cell_takes_the_heads_that_fill_whole_tiles_of_both_sizes():
 
 
 def test_the_calls_are_named_by_what_they_compute():
-    def names(d, dv, window=None):
-        x = jnp.zeros((1, 128, 2, d))
-        v = jnp.zeros((1, 128, 2, dv))
+    def names(d, dv, window=None, s=128):
+        x = jnp.zeros((1, s, 2, d))
+        v = jnp.zeros((1, s, 2, dv))
         text = str(jax.make_jaxpr(jax.grad(
             lambda q, k, v: jnp.sum(fa.flash_attention(
-                q, k, v, causal=True, interpret=True, window=window)),
+                q, k, v, causal=True, interpret=True, window=window,
+                block_q=min(s, 128), block_k=min(s, 128))),
             (0, 1, 2)))(x, x, v))
-        return {name for name in (
-            "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "swa_fwd",
-            "swa_bwd_dq", "swa_bwd_dkv", "mla_fwd", "mla_bwd_dq",
-            "mla_bwd_dkv") if name in text}
+        return {name for kind in ("flash", "swa", "mla")
+                for name in (f"{kind}_fwd", f"{kind}_bwd_dq",
+                             f"{kind}_bwd_dkv", f"{kind}_bwd")
+                if f"name={name} " in text or f"name={name}\n" in text}
 
     assert names(192, 128) == {"mla_fwd", "mla_bwd_dq", "mla_bwd_dkv"}
     assert names(128, 128) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
     assert names(64, 64, window=32) == {"swa_fwd", "swa_bwd_dq",
                                         "swa_bwd_dkv"}
+    # looped (25 block pairs a head): the backward is one call
+    assert names(192, 128, s=640) == {"mla_fwd", "mla_bwd"}
+    assert names(128, 128, s=640) == {"flash_fwd", "flash_bwd"}
+    assert names(64, 64, window=192, s=640) == {"swa_fwd", "swa_bwd"}
     with pytest.raises(NotImplementedError, match="window"):
         names(192, 128, window=32)
 
