@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from easydl_tpu.core.mesh_shapes import BATCH_AXES
+from easydl_tpu.ops import platform
 from easydl_tpu.ops.flash_attention import (
     MAX_BLOCK,
     choose_blocks,
@@ -159,8 +160,7 @@ def _per_shard(fn, q: jax.Array, k: jax.Array, whole: int = 0,
 def _on_kernels(impl: str) -> bool:
     """Whether ``impl`` asks for the Pallas kernels: said so, or ``auto``
     on a TPU."""
-    return impl == "flash" or (
-        impl == "auto" and jax.devices()[0].platform == "tpu")
+    return impl == "flash" or (impl == "auto" and platform.on_tpu())
 
 
 def rotate_heads(x: jax.Array, rope: tuple, *,
@@ -223,14 +223,13 @@ def multihead_attention(
     banded = "" if window is None else f", window {window}"
     rotary_dim = rotary_dim or q.shape[-1]
     if impl == "auto":
-        platform = jax.devices()[0].platform
-        impl = "flash" if platform == "tpu" else "reference"
+        impl = "flash" if platform.on_tpu() else "reference"
         if impl == "reference":
             log_once(
                 log,
                 f"attention: XLA reference path (impl=auto on platform "
-                f"{platform!r}, the Pallas flash kernel needs a tpu"
-                f"{banded})")
+                f"{jax.devices()[0].platform!r}, the Pallas flash kernel "
+                f"needs a tpu{banded})")
     if impl == "flash":
         why = None
         if segment_ids is not None:

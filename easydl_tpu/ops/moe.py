@@ -100,6 +100,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from easydl_tpu.core.mesh_shapes import BATCH_AXES
+from easydl_tpu.ops import platform
 
 #: mesh axis the held experts are sharded over (core/sharding.py rules)
 EXPERT_AXIS = "ep"
@@ -136,10 +137,6 @@ PIECE_OVER_EXPECTED = 2
 #: a tile of the MXU's: rows a piece is rounded up to and, in the sum back to
 #: the tokens, rows a step reads and tokens a block of the result holds
 TILE = 128
-
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 def rows_bound(tokens: int, k: int, held: int) -> int:
@@ -676,8 +673,9 @@ def _routed_part(h, chosen, weights, w_gate, w_up, w_down, lo, total):
             (jnp.where(row < rowed, row // piece * (tokens * k) + order,
                        jnp.iinfo(jnp.int32).max), row % piece), num_keys=1)
         by_token = (at, number % (tokens * k))
-    y, rows, visited = _routed((piece, not _on_tpu()), h, weights, w_gate,
-                               w_up, w_down, order, by_token, ends, rowed)
+    y, rows, visited = _routed((piece, not platform.on_tpu()), h, weights,
+                               w_gate, w_up, w_down, order, by_token, ends,
+                               rowed)
     stats = jnp.stack([landed - rows, landed, jnp.int32(landed > piece),
                        jnp.max(sizes), visited]).astype(jnp.float32)
     return y, stats

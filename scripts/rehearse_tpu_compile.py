@@ -9,9 +9,10 @@ chip_smoke.py's programs (GPT-2 345M, seq 1024, bf16, remat "dots", flash
 attention) and the benchmark cells' steps, and prints, per program, the
 bytes on each device, the Mosaic calls (how many of them the flash forward)
 and their per-device operand shapes, and the collectives in front of them.
-A cell's step is also a memory gate: it has a limit in GiB a device, and a
-program over its limit makes the exit code 1. A compile that passes is not
-a run.
+A cell's step is also a gate: it has a limit in GiB a device and the
+attention kernels it holds by name (``ATTENTION_KERNELS``: how often the
+forward kernel stands says what remat kept), and a program over its limit or
+with other kernels makes the exit code 1. A compile that passes is not a run.
 
 Usage: JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py [NAME ...]
        (names: the keys of PROGRAMS; default: all)
@@ -89,6 +90,27 @@ PROGRAMS = {
     "joyai_1x2": (JOYAI, "dp=1", 2, 1, "adamw", 14.55),
 }
 
+#: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
+#: compiled step holds, by name: the second gate, exit code 1 like the first.
+#: A forward kernel stands once where its ``out`` and ``lse`` are kept and
+#: twice where remat makes them again: medium's two copies of the step's body
+#: (the first microbatch, then the scan) hold it twice each under ``dots``;
+#: under ``full`` ZAYA1's six scanned layers hold it ONCE and JoyAI-LLM-Flash's
+#: four scanned layers once beside the dense layer's two (PR 38: 2 and 4 if
+#: the scanned run made it again). ``flash_bwd`` / ``mla_bwd`` is the looped
+#: backward as ONE call (PR 39); ``*_bwd_dq`` + ``*_bwd_dkv`` the unrolled one.
+ATTENTION_KERNELS = {
+    "medium_4x8": {"flash_bwd_dkv": 2, "flash_bwd_dq": 2, "flash_fwd": 4},
+    "worker_4x8": {"flash_bwd_dkv": 2, "flash_bwd_dq": 2, "flash_fwd": 4},
+    "xl_fsdp4": {"flash_bwd_dkv": 1, "flash_bwd_dq": 1, "flash_fwd": 2},
+    "hybrid_4x2": {"flash_bwd": 2, "flash_fwd": 2},
+    "ouro_4x1": {"flash_bwd": 2, "flash_fwd": 4},
+    "laguna_1x2": {"flash_bwd": 2, "flash_fwd": 2, "swa_bwd_dkv": 1,
+                   "swa_bwd_dq": 1, "swa_fwd": 2},
+    "zaya_1x2": {"flash_bwd": 1, "flash_fwd": 1},
+    "joyai_1x2": {"mla_bwd": 3, "mla_fwd": 3},
+}
+
 
 def compile_program(name: str, devices):
     """``(compiled step, GiB a device it takes)`` of ``PROGRAMS[name]`` on
@@ -102,7 +124,7 @@ def compile_program(name: str, devices):
     from easydl_tpu.core.mesh import MeshSpec, build_mesh
     from easydl_tpu.core.train_loop import TrainConfig, Trainer
     from easydl_tpu.models.registry import get_model
-    from easydl_tpu.ops import moe
+    from easydl_tpu.ops import platform
 
     (factory, kwargs), key, batch, accum, optimizer, _ = PROGRAMS[name]
     bundle = get_model(factory, **kwargs)
@@ -113,20 +135,29 @@ def compile_program(name: str, devices):
         config=TrainConfig(global_batch=batch, grad_accum=accum),
         mesh=build_mesh(spec, devices=devices[:spec.size]))
     tokens = jax.ShapeDtypeStruct((batch, kwargs["seq_len"]), jnp.int32)
-    # the expert layer asks jax.devices() too (the CPU here) whether its
-    # kernel is compiled or interpreted: compiled, as on the chip — while
-    # this program is traced and no longer (a test calls this in a process
-    # whose later tests run the layer on the CPU: tests/test_tpu_compile.py)
-    on_tpu, moe._on_tpu = moe._on_tpu, lambda: True
+    # the ops ask jax.devices() (the CPU here) whether their kernels are
+    # compiled or interpreted: compiled, as on the chip — while this program
+    # is traced and no longer (a test calls this in a process whose later
+    # tests run the layers on the CPU: tests/test_tpu_compile.py)
+    on_tpu, platform.on_tpu = platform.on_tpu, lambda: True
     try:
         compiled = trainer.step_fn.lower(
             trainer.abstract_state(),
             {"inputs": tokens, "targets": tokens}).compile()
     finally:
-        moe._on_tpu = on_tpu
+        platform.on_tpu = on_tpu
     mem = compiled.memory_analysis()
     return compiled, (mem.argument_size_in_bytes
                       + mem.temp_size_in_bytes) / 2**30
+
+
+def kernel_counts(calls) -> dict:
+    """``{kernel's name: how many calls}`` of ``mosaic_calls``' result."""
+    counts: dict = {}
+    for instruction, _ in calls:
+        kernel = instruction.strip("%").split(".")[0]
+        counts[kernel] = counts.get(kernel, 0) + 1
+    return dict(sorted(counts.items()))
 
 
 def mosaic_calls(text: str):
@@ -166,18 +197,24 @@ def main() -> None:
         # per-shard call. (Rows == 1 is a layer's FSDP weight gather.)
         gathered = sorted({re.sub(r"\{[^}]*\}", "", m) for m in re.findall(
             r"= (\S+) all-gather(?:-start)?\(", text)})
+        kernels = kernel_counts(calls)
         print(f"{name}: {factory} mesh {key} batch {batch} accum {accum} "
               f"{optimizer}: compiled in {dt:.1f}s; per device: arguments "
               f"{mem.argument_size_in_bytes / 2**30:.2f} GiB + temporaries "
               f"{mem.temp_size_in_bytes / 2**30:.2f} GiB = {gib:.3f} GiB"
               + (f" (limit {limit})" if limit else "") + f"; "
               f"{len(calls)} Mosaic calls, {forwards} of them a flash forward "
-              f"(flash_fwd, mla_fwd, swa_fwd), "
+              f"(flash_fwd, mla_fwd, swa_fwd): {kernels}; "
               f"outputs {shapes}; "
               f"collectives {collectives}; all-gathered shapes {gathered}",
               flush=True)
         if limit and gib > limit:
             over.append(f"{name}: {gib:.3f} GiB a device, over its {limit}")
+        attention = {kernel: n for kernel, n in kernels.items()
+                     if kernel.startswith(("flash_", "mla_", "swa_"))}
+        if attention != ATTENTION_KERNELS.get(name, attention):
+            over.append(f"{name}: attention kernels {attention}, not "
+                        f"{ATTENTION_KERNELS[name]}")
     if over:
         sys.exit("; ".join(over))
 

@@ -1,0 +1,129 @@
+"""The window in the flash kernels (``ops/flash_attention.py``, interpreted)
+and in the XLA path against the mask written out entry by entry: the band
+path and the looped kernels under a window, forward and gradients, and the
+rule that chooses between them from the shapes. (Laguna's window layers run
+these; the model's own tests are ``tests/test_laguna.py``.)"""
+
+import functools
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from conftest import normal, out_and_grads, written_out
+
+from easydl_tpu.ops import attention as attention_module
+from easydl_tpu.ops.flash_attention import (
+    Band,
+    choose_blocks,
+    flash_attention,
+)
+
+
+# window against block: below, equal, above; square and s_q != s_k; blocks
+# that unroll (<= 16 pairs) and blocks that loop. A square problem under a
+# window of at most a block's keys takes the band path (block_q: a
+# sub-block's rows, block_k: the neighbour's), every other the looped one.
+LOOP, BAND = "loop", "band"
+
+
+def _case(s_q, s_k, block, window, path, *, sub=None, heads=2, d=16, kv=None):
+    return pytest.param(s_q, s_k, sub or block, block, window, heads, d, kv,
+                        path, id=f"{s_q}x{s_k}-{sub or block}/{block}-w{window}"
+                        f"-{heads}x{d}" + (f"kv{kv}" if kv else ""))
+
+
+WINDOW_CASES = [
+    # s_q, s_k, block, window
+    _case(64, 64, 16, 8, BAND), _case(64, 64, 16, 16, BAND),
+    _case(64, 64, 16, 24, LOOP), _case(64, 64, 16, 40, LOOP),
+    _case(32, 64, 16, 8, LOOP), _case(32, 64, 16, 16, LOOP),
+    _case(32, 64, 16, 24, LOOP),
+    _case(128, 128, 16, 16, BAND), _case(128, 128, 16, 20, LOOP),
+    _case(96, 128, 16, 7, LOOP),
+    _case(64, 64, 32, 1, BAND), _case(64, 64, 16, 64, LOOP),
+    _case(64, 64, 16, 100, LOOP),
+    # the band path over several grid cells: the first has no block before
+    # it and the last none after it, the others read a neighbour's rows.
+    # Window below and equal to the block, sub-blocks smaller than it
+    _case(128, 128, 16, 16, BAND, sub=8), _case(256, 256, 16, 1, BAND),
+    _case(128, 128, 16, 11, BAND, sub=8, heads=3),
+    _case(256, 256, 32, 20, BAND, sub=16),
+    # eight heads of 16 to a 128-lane block, two lane blocks
+    _case(256, 256, 32, 32, BAND, heads=16),
+    # key/value heads repeated to the query's, as the models hand them over
+    _case(192, 192, 16, 9, BAND, heads=4, kv=2),
+    # one head of 128 to a lane block at the sub-blocks the chip runs (256
+    # and 128 rows, pieces cut at whole 128-row tiles)
+    _case(2048, 2048, 256, 256, BAND, heads=1, d=128),
+    _case(2048, 2048, 256, 200, BAND, sub=128, heads=2, d=128, kv=1),
+    # no caller's blocks: the rule's own cut (one cell of 1,024 rows)
+    _case(1024, 1024, None, 512, BAND, heads=1, d=128),
+    # one key past the block, and a rectangle: the looped kernels
+    _case(256, 256, 16, 17, LOOP), _case(128, 256, 16, 16, LOOP),
+]
+
+
+@pytest.mark.parametrize(
+    "s_q,s_k,block_q,block_k,window,heads,d,kv,path", WINDOW_CASES)
+def test_window_kernels_against_the_written_out_mask(
+        s_q, s_k, block_q, block_k, window, heads, d, kv, path):
+    kv_shape = (2, s_k, kv or heads, d)
+    q, k, v = normal(0, (2, s_q, heads, d), kv_shape, kv_shape)
+    if kv:
+        k, v = (jnp.repeat(x, heads // kv, axis=2) for x in (k, v))
+    took = choose_blocks(s_q, s_k, True, block_q, block_k, window)
+    assert {True: BAND, False: LOOP}[isinstance(took[0], Band)] == path
+    want = written_out(*(np.asarray(x, np.float64) for x in (q, k, v)),
+                       window)
+    weights, = normal(9, want.shape)
+
+    def weighed(out):
+        return jnp.sum(out * weights)
+
+    # the three kernels (forward and both backward) as one program, the XLA
+    # path's forward and gradients as one more
+    mine, g_mine = out_and_grads(functools.partial(
+        flash_attention, causal=True, window=window, block_q=block_q,
+        block_k=block_k, interpret=True), weighed)(q, k, v)
+    xla, g_xla = out_and_grads(functools.partial(
+        attention_module._reference_attention, causal=True,
+        scale=q.shape[-1] ** -0.5, window=window), weighed)(q, k, v)
+    np.testing.assert_allclose(np.asarray(mine), want, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(xla), want, atol=2e-5)
+    for a, b in zip(g_mine, g_xla):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def test_window_blocks_are_chosen_from_the_window():
+    banded = choose_blocks(8192, 8192, True, window=512)
+    assert banded == (Band(rows=2048, sub=256, reach=512),) * 3
+    # a caller's blocks hold for all three kernels, window or not
+    assert choose_blocks(64, 64, True, 16, 16, window=8) == (
+        Band(rows=64, sub=16, reach=16),) * 3
+    # without a window, and under one wider than a block: the plain rule
+    assert choose_blocks(8192, 8192, True) == ((512, 512),) * 3
+    assert choose_blocks(8192, 8192, True, window=4096) == ((512, 512),) * 3
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(*normal(0, *[(2, 32, 2, 16)] * 3), causal=False,
+                        window=8, interpret=True)
+
+
+def test_band_or_loop_is_chosen_from_the_shapes_alone():
+    """The band path where a block holds the window and the problem is
+    square; the looped kernels' blocks, as they were, everywhere else."""
+    looped = ((512, 512), (512, 512), (512, 256))
+    # one key more than a block holds; a rectangle (a decode's, a prefix's)
+    assert choose_blocks(8192, 8192, True, window=513) == ((512, 512),) * 3
+    assert choose_blocks(4096, 8192, True, window=512) == looped
+    assert choose_blocks(8192, 8192, True, 512, 256, window=512) == (
+        (512, 256),) * 3
+    for s, window, want in [
+            (8192, 512, Band(2048, 256, 512)), (8192, 128, Band(2048, 256, 512)),
+            (4096, 512, Band(2048, 256, 512)), (1536, 300, Band(1536, 256, 512)),
+            (2560, 512, Band(512, 256, 512)), (256, 64, Band(256, 256, 256))]:
+        assert choose_blocks(s, s, True, window=window) == (want,) * 3
+    # a cell's rows are whole neighbour blocks, a neighbour whole sub-blocks
+    assert choose_blocks(256, 256, True, 8, 16, window=16) == (
+        Band(rows=64, sub=8, reach=16),) * 3
+    # no block divisor: no kernel at all, as without a window
+    assert choose_blocks(520, 520, True, window=64) is None

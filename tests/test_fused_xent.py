@@ -43,9 +43,12 @@ def test_matches_naive_loss_and_grads(dtype, seq, chunk):
     # mask a few positions
     targets = targets.at[:, :3].set(-1)
 
-    loss_f, denom = fused_softmax_xent(hidden, head, targets,
-                                       chunk_size=chunk)
-    loss_n = naive(hidden, head, targets)
+    # loss and both gradients: one program a side
+    (loss_f, denom), g_f = jax.jit(jax.value_and_grad(
+        lambda h, w: fused_softmax_xent(h, w, targets, chunk_size=chunk),
+        argnums=(0, 1), has_aux=True))(hidden, head)
+    loss_n, g_n = jax.jit(jax.value_and_grad(
+        lambda h, w: naive(h, w, targets), argnums=(0, 1)))(hidden, head)
     # bf16: the fused op keeps f32 accumulation (preferred_element_type)
     # where the naive bf16 matmul rounds its output to bf16 — the fused
     # result is the more accurate one, so the comparison needs bf16 slack.
@@ -53,13 +56,6 @@ def test_matches_naive_loss_and_grads(dtype, seq, chunk):
                                rtol=2e-6 if dtype == "float32" else 1e-3)
     assert float(denom) == B * (seq - 3)
 
-    g_f = jax.grad(
-        lambda h, w: fused_softmax_xent(h, w, targets, chunk_size=chunk)[0],
-        argnums=(0, 1),
-    )(hidden, head)
-    g_n = jax.grad(
-        lambda h, w: naive(h, w, targets), argnums=(0, 1)
-    )(hidden, head)
     tol = 1e-5 if dtype == "float32" else 2e-2
     for a, b in zip(g_f, g_n):
         np.testing.assert_allclose(np.asarray(a, np.float32),
@@ -71,7 +67,8 @@ def test_all_masked_is_finite():
     hidden = jnp.ones((2, 8, 16), jnp.float32)
     head = jnp.ones((32, 16), jnp.float32)
     targets = jnp.full((2, 8), -1, jnp.int32)
-    loss, denom = fused_softmax_xent(hidden, head, targets, chunk_size=4)
+    loss, denom = jax.jit(lambda h, w: fused_softmax_xent(
+        h, w, targets, chunk_size=4))(hidden, head)
     assert float(loss) == 0.0 and float(denom) == 1.0
 
 
@@ -81,37 +78,24 @@ def test_gpt_bundle_fused_matches_logits_path(eight_devices, fused_head):
     the same gradients on the same params."""
     bundle = get_model("gpt", size="test", seq_len=64, vocab=256)
     rng = jax.random.PRNGKey(0)
-    params = bundle.init_fn(rng)
+    params = jax.jit(bundle.init_fn)(rng)
     batch = next(iter(bundle.make_data(4, seed=3)))
 
-    lp, mp = bundle.loss_fn(params, batch, rng)
-    gp = jax.grad(lambda p: bundle.loss_fn(p, batch, rng)[0])(params)
+    def loss_metrics_grads():
+        # a program of its own a call: the head is chosen when it is traced
+        return jax.jit(jax.value_and_grad(
+            lambda p: bundle.loss_fn(p, batch, rng), has_aux=True))(params)
+
+    (lp, mp), gp = loss_metrics_grads()
     fused_head(chunk_rows=64)
     assert gpt_module.fused_head_by_shape(4, 64, 256)
-    lf, mf = bundle.loss_fn(params, batch, rng)
-    gf = jax.grad(lambda p: bundle.loss_fn(p, batch, rng)[0])(params)
+    (lf, mf), gf = loss_metrics_grads()
     np.testing.assert_allclose(float(lf), float(lp), rtol=1e-6)
     np.testing.assert_allclose(float(mf["perplexity"]),
                                float(mp["perplexity"]), rtol=1e-6)
     for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gp)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-6)
-
-
-def test_moe_fused_head_runs(eight_devices, fused_head):
-    """Laguna's test size (expert layers) through the fused chunked head:
-    the counters reach the metrics beside the loss."""
-    bundle = get_model("laguna", size="test", seq_len=32, vocab=128,
-                       experts_held=(0, 4))
-    fused_head(chunk_rows=32)  # 4 sequences: 8 positions a chunk
-    assert gpt_module.fused_head_by_shape(4, 32, 128)
-    rng = jax.random.PRNGKey(1)
-    params = bundle.init_fn(rng)
-    batch = next(iter(bundle.make_data(4, seed=5)))
-    loss, metrics = bundle.loss_fn(params, batch, rng)
-    assert np.isfinite(float(loss))
-    assert float(metrics["moe_dropped"]) == 0.0
-    assert 0.0 < float(metrics["moe_rows_per_token"]) < 2.0
 
 
 def _reject_cases():
@@ -161,10 +145,15 @@ def test_bf16_bundle_with_the_head_chosen_by_shape_matches_full_logits(
     bundle = get_model(family, seq_len=512, vocab=256, dtype="bfloat16",
                        **described)
     rng = jax.random.PRNGKey(0)
-    params = bundle.init_fn(rng)
+    params = jax.jit(bundle.init_fn)(rng)
     batch = next(iter(bundle.make_data(4, seed=3)))
-    want, g_want = jax.value_and_grad(
-        lambda p: bundle.loss_fn(p, batch, rng)[0])(params)
+
+    def loss_and_grads():
+        # a program of its own a call: the head is chosen when it is traced
+        return jax.jit(jax.value_and_grad(
+            lambda p: bundle.loss_fn(p, batch, rng)[0]))(params)
+
+    want, g_want = loss_and_grads()
 
     fused_head()
     chunks = []
@@ -172,8 +161,7 @@ def test_bf16_bundle_with_the_head_chosen_by_shape_matches_full_logits(
         gpt_module, "fused_softmax_xent",
         lambda *a, **k: chunks.append(k.get("chunk_size"))
         or fused_softmax_xent(*a, **k))
-    got, g_got = jax.value_and_grad(
-        lambda p: bundle.loss_fn(p, batch, rng)[0])(params)
+    got, g_got = loss_and_grads()
     assert chunks == [None]           # 4 x 512 rows: two chunks of 1,024
     assert chunk_positions(4, 512, 256) == 256
     np.testing.assert_allclose(float(got), float(want), rtol=2e-3)
@@ -230,7 +218,7 @@ def test_one_pass_gradients_match_naive(case, chunk, dtype):
     def plain(h, w):
         return upstream * naive(h, w, targets, logit_scale=scale)
 
-    (lf, gf), (ln, gn) = (jax.value_and_grad(f, (0, 1))(hidden, head)
+    (lf, gf), (ln, gn) = (jax.jit(jax.value_and_grad(f, (0, 1)))(hidden, head)
                           for f in (fused, plain))
     f32 = dtype == "float32"
     np.testing.assert_allclose(float(lf), float(ln),
@@ -252,13 +240,13 @@ def test_denom_carries_no_gradient():
         loss, denom = fused_softmax_xent(h, w, targets, chunk_size=16)
         return loss + weight * denom
 
-    g0 = jax.grad(f, (0, 1))(hidden, head, 0.0)
-    g5 = jax.grad(f, (0, 1))(hidden, head, 5.0)
+    grads = jax.jit(jax.grad(f, (0, 1)))
+    g0, g5 = grads(hidden, head, 0.0), grads(hidden, head, 5.0)
     for a, b in zip(g0, g5):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         assert np.asarray(a).any()
-    only_denom = jax.grad(lambda h: fused_softmax_xent(
-        h, head, targets, chunk_size=16)[1])(hidden)
+    only_denom = jax.jit(jax.grad(lambda h: fused_softmax_xent(
+        h, head, targets, chunk_size=16)[1]))(hidden)
     assert not np.asarray(only_denom).any()
 
 
@@ -266,9 +254,9 @@ def test_denom_carries_no_gradient():
 def test_all_masked_gives_zero_gradients_and_no_nan(chunk):
     hidden, head, _ = _problem("float32", S=8)
     targets = jnp.full(hidden.shape[:2], -1, jnp.int32)
-    loss, grads = jax.value_and_grad(
+    loss, grads = jax.jit(jax.value_and_grad(
         lambda h, w: fused_softmax_xent(h, w, targets, chunk_size=chunk)[0],
-        (0, 1))(hidden, head)
+        (0, 1)))(hidden, head)
     assert float(loss) == 0.0
     for g in grads:
         assert np.isfinite(np.asarray(g)).all() and not np.asarray(g).any()

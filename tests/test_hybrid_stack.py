@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import normal, out_and_grads
 
 from easydl_tpu.core.checkpoint import CheckpointManager
 from easydl_tpu.core.mesh import MeshSpec, build_mesh
@@ -157,8 +158,7 @@ def test_projections_are_matrix_products_with_the_same_numbers(
     gives them the features' shape) — the layout XLA lays q, k, v out in."""
     from easydl_tpu.models.transformer import _matrix_dot_general
 
-    kx, kw = jax.random.split(jax.random.PRNGKey(28))
-    x, w = jax.random.normal(kx, x_shape), jax.random.normal(kw, w_shape)
+    x, w = normal(28, x_shape, w_shape)
     dims = ((tuple(range(x.ndim - n, x.ndim)), tuple(range(n))), ((), ()))
     want = jax.lax.dot_general(x, w, dims)
 
@@ -168,11 +168,13 @@ def test_projections_are_matrix_products_with_the_same_numbers(
     def loss(dot):
         return lambda x, w: jnp.sin(dot(x, w, dims)).sum()
 
-    assert _matrix_dot_general(x, w, dims).shape \
+    assert jax.eval_shape(
+        lambda x, w: _matrix_dot_general(x, w, dims), x, w).shape \
         == x.shape[:x.ndim - n] + (int(np.prod(w_shape[n:])),)
-    np.testing.assert_allclose(rows(x, w, dims), want, rtol=1e-5, atol=1e-5)
-    got = jax.grad(loss(rows), argnums=(0, 1))(x, w)
-    want = jax.grad(loss(jax.lax.dot_general), argnums=(0, 1))(x, w)
+    np.testing.assert_allclose(jax.jit(rows, static_argnums=2)(x, w, dims),
+                               want, rtol=1e-5, atol=1e-5)
+    got = jax.jit(jax.grad(loss(rows), argnums=(0, 1)))(x, w)
+    want = jax.jit(jax.grad(loss(jax.lax.dot_general), argnums=(0, 1)))(x, w)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)
     products = [eqn for eqn in jax.make_jaxpr(
@@ -201,11 +203,11 @@ def test_rows_projection_is_dense_general_with_the_bias_added_to_rows(
     kernel_axes = ("embed", "heads", "kv") if axis == -1 \
         else ("heads", "kv", "embed")
     bias_axes = kernel_axes[1:] if axis == -1 else ("embed",)
-    x = jax.random.normal(jax.random.PRNGKey(30), x_shape)
+    x, = normal(30, x_shape)
     built = {rows: _dense(features, kernel_axes, bias_axes, name="p",
                           use_bias=bias, axis=axis, rows=rows)
              for rows in (True, False)}
-    boxed = {rows: m.init(jax.random.PRNGKey(1), x)
+    boxed = {rows: jax.jit(m.init)(jax.random.PRNGKey(1), x)
              for rows, m in built.items()}
     assert nn.get_partition_spec(boxed[True]) \
         == nn.get_partition_spec(boxed[False])
@@ -215,17 +217,17 @@ def test_rows_projection_is_dense_general_with_the_bias_added_to_rows(
                     jax.tree.leaves(params[False])):
         np.testing.assert_array_equal(a, b)
     # a bias that is not zero, so that its place shows
-    values = jax.tree.map(lambda p: p + 0.1 * jax.random.normal(
-        jax.random.PRNGKey(2), p.shape), params[True])
+    rng = np.random.default_rng(2)
+    values = jax.tree.map(lambda p: np.asarray(p) + 0.1 * rng.standard_normal(
+        p.shape, np.float32), params[True])
 
-    def loss(rows):
-        return lambda v, x: jnp.sin(built[rows].apply(v, x)).sum()
+    def both(rows):
+        """The projection's result and its gradients: one program."""
+        return out_and_grads(built[rows].apply,
+                             lambda y: jnp.sin(y).sum())(values, x)
 
-    np.testing.assert_allclose(built[True].apply(values, x),
-                               built[False].apply(values, x), rtol=1e-5,
-                               atol=1e-5)
-    got = jax.grad(loss(True), argnums=(0, 1))(values, x)
-    want = jax.grad(loss(False), argnums=(0, 1))(values, x)
+    (y_rows, got), (y_dense, want) = both(True), both(False)
+    np.testing.assert_allclose(y_rows, y_dense, rtol=1e-5, atol=1e-5)
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)
     adds = [eqn for eqn in jax.make_jaxpr(
@@ -263,17 +265,23 @@ def test_chunked_scan_equals_the_recurrence(seq, dtype, tol):
     ``lax.scan`` over positions, in float32 on the same (rounded) inputs."""
     args = _scan_inputs(seq, 2, seq, 4, 8, 2, 16, jnp.dtype(dtype))
     as32 = [a.astype(jnp.float32) for a in args]
-    with jax.default_matmul_precision("highest"):
-        y = ssd_scan(*args, chunk=16)
-        y_ref = ref.recurrence(*as32)
-        assert y.dtype == args[0].dtype and y.shape == args[0].shape
-        assert rel(y, y_ref) < tol
-        weights = jnp.asarray(np.random.default_rng(1).normal(size=y.shape),
-                              jnp.float32)
-        grads = jax.grad(lambda *a: (ssd_scan(*a, chunk=16) * weights).sum(),
-                         argnums=range(6))(*args)
-        grads_ref = jax.grad(lambda *a: (ref.recurrence(*a) * weights).sum(),
-                             argnums=range(6))(*as32)
+    weights = np.random.default_rng(1).normal(size=args[0].shape).astype(
+        np.float32)
+
+    def highest(fn):
+        def run(*a):
+            with jax.default_matmul_precision("highest"):
+                return fn(*a)
+        return run
+
+    # the chunked scan with its six gradients: one program; the recurrence
+    # with its: one more
+    y, grads = out_and_grads(highest(functools.partial(ssd_scan, chunk=16)),
+                             lambda y: (y * weights).sum())(*args)
+    y_ref, grads_ref = out_and_grads(highest(ref.recurrence),
+                                     lambda y: (y * weights).sum())(*as32)
+    assert y.dtype == args[0].dtype and y.shape == args[0].shape
+    assert rel(y, y_ref) < tol
     for name, g, g_ref in zip("x dt A B C D".split(), grads, grads_ref):
         assert rel(g, g_ref) < 2 * tol, name
 
@@ -294,8 +302,8 @@ def test_scan_keeps_its_decay_sums_in_float32():
     B, C = (jnp.asarray(r.normal(size=(1, seq, 1, n)) / n ** 0.25,
                         jnp.bfloat16) for _ in range(2))
     args = (x, dt, A, B, C, jnp.ones((h,), jnp.float32))
-    y = ssd_scan(*args, chunk=256)
-    y_ref = ref.recurrence(*[a.astype(jnp.float32) for a in args])
+    y = jax.jit(functools.partial(ssd_scan, chunk=256))(*args)
+    y_ref = jax.jit(ref.recurrence)(*[a.astype(jnp.float32) for a in args])
     assert rel(y, y_ref) < 4e-3
 
 
@@ -360,12 +368,14 @@ def test_grouped_query_attention_reads_head_h_over_r(monkeypatch, impl, heads,
             q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
             causal=True, scale=0.2)
 
-    np.testing.assert_allclose(grouped(q, k, v), repeated(q, k, v), atol=2e-5)
-    weights = jnp.asarray(np.random.default_rng(1).normal(size=q.shape),
-                          jnp.float32)
-    got = jax.grad(lambda *a: (grouped(*a) * weights).sum(), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: (repeated(*a) * weights).sum(),
-                    (0, 1, 2))(q, k, v)
+    weights = np.random.default_rng(1).normal(size=q.shape).astype(np.float32)
+
+    def weighed(out):
+        return (out * weights).sum()
+
+    out, got = out_and_grads(grouped, weighed)(q, k, v)
+    out_want, want = out_and_grads(repeated, weighed)(q, k, v)
+    np.testing.assert_allclose(out, out_want, atol=2e-5)
     for g, w in zip(got, want):
         assert g.shape == w.shape and rel(g, w) < 5e-4
 
@@ -384,9 +394,9 @@ def test_per_shard_wrap_splits_heads_only_where_both_counts_divide(
     monkeypatch.setattr(attention_module, "flash_attention",
                         functools.partial(flash_attention, interpret=True))
     q, k, v = _qkv(8, 2)
-    want = attention_module._reference_attention(
+    want = jax.jit(lambda q, k, v: attention_module._reference_attention(
         q, jnp.repeat(k, 4, axis=2), jnp.repeat(v, 4, axis=2), causal=True,
-        scale=0.2)
+        scale=0.2))(q, k, v)
     for spec in (MeshSpec(dp=2, tp=4), MeshSpec(dp=2, tp=2)):
         mesh = build_mesh(spec, devices=eight_devices[:spec.size])
         with jax.set_mesh(mesh):
@@ -413,11 +423,12 @@ def test_fused_head_with_logit_scale_equals_full_logits(scale):
                                   logit_scale=scale)[0]
 
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(fused(hidden, head), full(hidden, head),
-                                   rtol=1e-6)
-        for g, w in zip(jax.grad(fused, (0, 1))(hidden, head),
-                        jax.grad(full, (0, 1))(hidden, head)):
-            assert rel(g, w) < 1e-5
+        (got, g_got), (want, g_want) = (
+            jax.jit(jax.value_and_grad(loss, (0, 1)))(hidden, head)
+            for loss in (fused, full))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for g, w in zip(g_got, g_want):
+        assert rel(g, w) < 1e-5
 
 
 @pytest.mark.parametrize("shape,mesh,fused", [
@@ -437,15 +448,27 @@ def test_head_is_chosen_by_one_devices_share_of_the_logits(
         assert gpt_module.fused_head_by_shape(*shape) is fused
 
 
-def test_hybrid_loss_is_the_same_through_either_head(monkeypatch):
+@pytest.fixture(scope="module")
+def hybrid_test():
+    """``(bundle, seeded parameters)`` of the hybrid at ``TEST``, float32:
+    built once for the tests that read them."""
     bundle = get_model("granite_hybrid", **TEST)
-    params = unbox(bundle.init_fn(jax.random.PRNGKey(0)))
+    return bundle, unbox(jax.jit(bundle.init_fn)(jax.random.PRNGKey(0)))
+
+
+def test_hybrid_loss_is_the_same_through_either_head(fused_head, hybrid_test):
+    bundle, params = hybrid_test
     tokens = np.random.default_rng(0).integers(0, 256, (2, 33), np.int32)
     batch = {"inputs": jnp.asarray(tokens[:, :-1]),
              "targets": jnp.asarray(tokens[:, 1:])}
-    f = jax.value_and_grad(lambda p: bundle.loss_fn(p, batch, None)[0])
+
+    def f(params):
+        # a program of its own a call: the head is chosen when it is traced
+        return jax.jit(jax.value_and_grad(
+            lambda p: bundle.loss_fn(p, batch, None)[0]))(params)
+
     full, g_full = f(params)
-    monkeypatch.setattr(gpt_module, "FUSED_HEAD_LOGITS_BYTES", 0)
+    fused_head()
     fused, g_fused = f(params)
     assert float(fused) == pytest.approx(float(full), rel=1e-5)
     for key, g in flatten_dict(g_fused).items():
@@ -460,10 +483,9 @@ def _config(dtype):
     return config
 
 
-def _check(dtype, compute_dtype, tolerances=None, seed=0):
+def _program(dtype, compute_dtype, seed=0):
+    """``(config, bundle, trainer)`` as the harness's ``check`` takes them."""
     config = _config(dtype)
-    if tolerances:
-        config["check"]["tolerances"] = tolerances
     bundle = get_model(config["factory"], **config["kwargs"])
     trainer = Trainer(
         init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
@@ -471,6 +493,16 @@ def _check(dtype, compute_dtype, tolerances=None, seed=0):
         config=TrainConfig(global_batch=2, compute_dtype=compute_dtype,
                            seed=seed),
         mesh=build_mesh(MeshSpec.parse("dp=1"), devices=jax.devices()[:1]))
+    return config, bundle, trainer
+
+
+def _check(program, tolerances=None, seed=0):
+    """The harness's own comparison of ``program``; ``tolerances`` stand in
+    the configuration where ``check`` reads them, in a copy."""
+    config, bundle, trainer = program
+    if tolerances:
+        config = copy.deepcopy(config)
+        config["check"]["tolerances"] = tolerances
     return check_module.check(config, bundle, trainer, seed)
 
 
@@ -482,25 +514,31 @@ def test_float32_hybrid_equals_the_reference_to_rounding(seed):
     """Two Mamba-2 layers and one attention layer: loss, final hidden state
     and EVERY gradient leaf (the worst is reported) of the chunked, split
     program against the sequential, fused reference."""
-    result = _check("float32", jnp.float32, TIGHT, seed=seed)
+    result = _check(_program("float32", jnp.float32, seed), TIGHT, seed=seed)
     assert result["ok"], result
     assert result["errors"]["grad_rel_rms_all"] > 0  # it did compare
 
 
-def test_bf16_hybrid_sits_inside_the_files_tolerances():
-    result = _check("bfloat16", jnp.bfloat16)
+@pytest.fixture(scope="module")
+def bf16_program():
+    """The bf16 model and its trainer, built once for the two checks below
+    (each is a run of ``check`` of its own: the harness decides ``ok``)."""
+    return _program("bfloat16", jnp.bfloat16)
+
+
+def test_bf16_hybrid_sits_inside_the_files_tolerances(bf16_program):
+    result = _check(bf16_program)
     assert result["ok"], result
     assert result["errors"]["hidden_rel_rms"] > 1e-3  # bf16 is visible
 
 
-def test_a_lower_precision_than_stated_fails_the_hybrid_check():
-    result = _check("bfloat16", jnp.bfloat16, TIGHT)
+def test_a_lower_precision_than_stated_fails_the_hybrid_check(bf16_program):
+    result = _check(bf16_program, TIGHT)
     assert not result["ok"], result
 
 
-def test_to_reference_rebuilds_the_published_fused_layout():
-    params = unbox(get_model("granite_hybrid", **TEST).init_fn(
-        jax.random.PRNGKey(0)))
+def test_to_reference_rebuilds_the_published_fused_layout(hybrid_test):
+    params = hybrid_test[1]
     plain = check_module.to_reference(
         params, ["mamba", "mamba", "attention"])
     mamba, attn = plain["layers"][1], plain["layers"][2]
@@ -573,10 +611,11 @@ def test_runs_group_equal_neighbours():
                    layer_types=("mamba", "mamba", "attention", "mamba"))
     assert cfg.runs == ((("mamba2", "swiglu"), 2), (("attention", "swiglu"), 1),
                         (("mamba2", "swiglu"), 1))
-    tree = unbox(get_model(
+    # the tree's names and shapes: nothing is initialised
+    tree = unbox(jax.eval_shape(get_model(
         "granite_hybrid", **TEST,
         layer_types=("mamba", "mamba", "attention", "mamba")
-    ).init_fn(jax.random.PRNGKey(0)))
+    ).init_fn, jax.random.PRNGKey(0)))
     assert sorted(k for k in tree if k.startswith("blocks")) \
         == ["blocks_0", "blocks_1", "blocks_2"]
     assert "pos_emb" not in tree and "bias" not in tree["blocks_1"]["q"]
@@ -597,18 +636,21 @@ def test_a_description_that_does_not_hold_together_is_refused(bad):
 
 
 # ------------------------------------------ sharded, saved, restored, named
-def _hybrid_trainer(spec, devices, **kwargs):
-    bundle = get_model("granite_hybrid", **TEST, **kwargs)
+@functools.lru_cache(maxsize=None)
+def _hybrid_trainer(spec):
+    """A trainer of the hybrid at ``TEST`` over ``spec`` and its bundle: one
+    a mesh for the file (its step compiles once, whoever steps it)."""
+    bundle = get_model("granite_hybrid", **TEST)
     return Trainer(
         init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
         optimizer=optax.adamw(1e-3),
         config=TrainConfig(global_batch=4, seed=3),
-        mesh=build_mesh(spec, devices=devices[:spec.size])), bundle
+        mesh=build_mesh(spec, devices=jax.devices()[:spec.size])), bundle
 
 
 @pytest.fixture(scope="module")
 def one_device_step(eight_devices):
-    trainer, bundle = _hybrid_trainer(MeshSpec(), eight_devices)
+    trainer, bundle = _hybrid_trainer(MeshSpec())
     batch = next(iter(bundle.make_data(4, seed=5)))
     state, metrics = trainer.train_step(trainer.init_state(), batch)
     return batch, float(metrics["loss"]), jax.device_get(unbox(state.params))
@@ -624,7 +666,7 @@ def one_device_step(eight_devices):
 def test_sharded_hybrid_steps_like_one_device(eight_devices, one_device_step,
                                               mesh, sharded):
     batch, loss, params = one_device_step
-    trainer, _ = _hybrid_trainer(MeshSpec.parse(mesh), eight_devices)
+    trainer, _ = _hybrid_trainer(MeshSpec.parse(mesh))
     specs = flatten_dict(jax.tree.map(lambda s: str(s.spec),
                                       trainer.state_shardings().params))
     for key, axis in sharded.items():
@@ -642,12 +684,12 @@ def test_hybrid_state_survives_the_checkpoint_manager(tmp_path,
                                                       eight_devices):
     """Saved under fsdp=2, restored under tp=2: every leaf equal, and the
     next step's loss too."""
-    t1, bundle = _hybrid_trainer(MeshSpec(fsdp=2), eight_devices)
+    t1, bundle = _hybrid_trainer(MeshSpec(fsdp=2))
     batch = next(iter(bundle.make_data(4, seed=5)))
     s1, _ = t1.train_step(t1.init_state(), batch)
     mgr = CheckpointManager(str(tmp_path), async_save=False)
     mgr.save(1, s1, metadata={"mesh": "fsdp=2"})
-    t2, _ = _hybrid_trainer(MeshSpec(tp=2), eight_devices)
+    t2, _ = _hybrid_trainer(MeshSpec(tp=2))
     abstract, _, _ = t2._abstract_state()
     s2 = mgr.restore(1, abstract, t2.state_shardings())
     for a, b in zip(jax.tree.leaves(unbox(s1.params)),

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import normal
 
 from easydl_tpu.core import sharding as shd
 from easydl_tpu.core.mesh import MeshSpec, build_mesh
@@ -88,7 +89,7 @@ def test_program_against_reference_joyai(float32_check, what, limit):
 
 def test_every_gradient_leaf_was_compared(float32_check):
     kwargs = _config()["kwargs"]
-    params = shd.unbox(get_model("joyai", **kwargs).init_fn(
+    params = shd.unbox(jax.jit(get_model("joyai", **kwargs).init_fn)(
         jax.random.PRNGKey(0)))
     plain = check_module.to_reference(params)
     # nothing is left out of the map; a layer: 2 norms and 7 of the
@@ -113,27 +114,30 @@ def test_the_reference_imports_nothing_from_the_program():
 
 
 # -------------------------------------------------------------- the shares
-def _uncut():
+@pytest.fixture(scope="module")
+def uncut():
     """The float32 test-size model with every expert held: ``(cfg, params,
-    tokens)``, the selection biases stirred so that they select."""
+    tokens)``, the selection biases stirred so that they select. Built once
+    for the tests that read it (none writes to it)."""
     cfg = describe(**TEST)
-    params = shd.unbox(get_model("joyai", **TEST).init_fn(
+    params = shd.unbox(jax.jit(get_model("joyai", **TEST).init_fn)(
         jax.random.PRNGKey(3)))
-    stir = 0.2 * jax.random.normal(jax.random.PRNGKey(4), (32,))
+    rng = np.random.default_rng(4)
+    stir = 0.2 * rng.standard_normal(32, np.float32)
     params["blocks_1"]["moe"]["router_bias"] += stir
     params["mtp_block"]["moe"]["router_bias"] += stir[::-1]
-    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, SEQ), 0, 256)
+    tokens = jnp.asarray(rng.integers(0, 256, (2, SEQ), np.int32))
     return cfg, params, tokens
 
 
 @pytest.mark.parametrize("where", ["main", "module"])
-def test_the_shares_add_up_to_the_uncut_reference_layer(where):
+def test_the_shares_add_up_to_the_uncut_reference_layer(uncut, where):
     """32 experts over 4 shares (the cell's 256 over 16): the four parts of a
     sparse layer's result, with what every chip computes alike — the
     attention, the shared expert — counted once, equal the reference's
     uncut layer: a main layer on a seeded state, and the module's layer on
     what its own join gives it."""
-    cfg, params, tokens = _uncut()
+    cfg, params, tokens = uncut
     tables = cfg.attention_kind(MLA).rope.tables(SEQ, cfg.head_dim)
     plain = check_module.to_reference(params)
     hp = {"eps": 1e-6, "theta": 32e6, "nope": 16, "rot": 8, "k": 4,
@@ -141,28 +145,35 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(where):
     if where == "main":
         p_layer = jax.tree.map(lambda a: a[0], params["blocks_1"])
         p_ref = plain["layers"][1]
-        x = jax.random.normal(jax.random.PRNGKey(6), (2, SEQ, cfg.d_model))
+        x, = normal(6, (2, SEQ, cfg.d_model))
     else:
         p_layer, p_ref = params["mtp_block"], plain["mtp"]["layer"]
-        state = jax.random.normal(jax.random.PRNGKey(6),
-                                  (2, SEQ, cfg.d_model))
-        x = transformer.MtpMerge(cfg).apply(
-            {"params": params["mtp_merge"]},
-            jnp.take(params["tok_emb"]["embedding"],
-                     jnp.roll(tokens, -1, 1), axis=0), state)
-    want = ref.layer(x, p_ref, hp)[0]
-    after_attention = ref.attention_residual(x, p_ref, hp)
-    alike = after_attention + ref.swiglu(
-        ref.rms_norm(after_attention, p_ref["n2"], 1e-6), p_ref["s_gate"],
-        p_ref["s_up"], p_ref["s_down"])
+        state, = normal(6, (2, SEQ, cfg.d_model))
+        x = jax.jit(lambda p, state: transformer.MtpMerge(cfg).apply(
+            {"params": p["mtp_merge"]},
+            jnp.take(p["tok_emb"]["embedding"],
+                     jnp.roll(tokens, -1, 1), axis=0), state))(params, state)
+
+    # the reference's layer and what every chip computes alike: one program
+    @jax.jit
+    def reference(x, p_ref):
+        after_attention = ref.attention_residual(x, p_ref, hp)
+        return ref.layer(x, p_ref, hp)[0], after_attention + ref.swiglu(
+            ref.rms_norm(after_attention, p_ref["n2"], 1e-6),
+            p_ref["s_gate"], p_ref["s_up"], p_ref["s_down"])
+
+    def block(description):
+        return jax.jit(lambda p, x: transformer.Block(
+            description, MLA, "moe").apply({"params": p}, x, True, tables))
+
+    want, alike = reference(x, p_ref)
     parts, dropped, rows = [], 0.0, 0.0
     for lo in range(0, 32, 8):
         share = describe(**TEST, experts_held=(lo, lo + 8))
         mine = dict(p_layer, moe=dict(p_layer["moe"], **{
             name: p_layer["moe"][name][lo:lo + 8]
             for name in ("w_gate", "w_up", "w_down")}))
-        y, counters = transformer.Block(share, MLA, "moe").apply(
-            {"params": mine}, x, True, tables)
+        y, counters = block(share)(mine, x)
         parts.append(y)
         dropped += float(counters[0])
         rows += float(counters[1])
@@ -170,8 +181,7 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(where):
                                np.asarray(want), atol=3e-5)
     assert dropped == 0.0
     assert rows == pytest.approx(4)  # every choice fell on exactly one share
-    whole, _ = transformer.Block(cfg, MLA, "moe").apply(
-        {"params": p_layer}, x, True, tables)
+    whole, _ = block(cfg)(p_layer, x)
     np.testing.assert_allclose(np.asarray(whole), np.asarray(want),
                                atol=3e-5)
 
@@ -182,11 +192,11 @@ def test_the_bias_selects_and_does_not_weigh():
     the weights are the chosen experts' scores WITHOUT ``b``, renormalised
     and scaled; ``b`` takes no gradient; without a bias the choice is the
     scores' own."""
-    key_h, key_w = jax.random.split(jax.random.PRNGKey(0))
-    h = jax.random.normal(key_h, (64, 32))
-    kernel = jax.random.normal(key_w, (32, 16))
-    bias = jnp.zeros(16).at[3].set(2.0).at[5].set(-2.0)
-    logits, chosen, weights = moe.route(h, kernel, 4, 2.5, bias)
+    h, kernel = normal(0, (64, 32), (32, 16))
+    bias = np.zeros(16, np.float32)
+    bias[[3, 5]] = 2.0, -2.0
+    route = jax.jit(moe.route, static_argnums=(2, 3))
+    logits, chosen, weights = route(h, kernel, 4, 2.5, bias)
     scores = np.asarray(jax.nn.sigmoid(logits))
     want = np.argsort(-(scores + np.asarray(bias)), -1)[:, :4]
     assert (np.sort(np.asarray(chosen), -1) == np.sort(want, -1)).all()
@@ -196,13 +206,13 @@ def test_the_bias_selects_and_does_not_weigh():
     np.testing.assert_allclose(
         np.asarray(weights), 2.5 * picked / picked.sum(-1, keepdims=True),
         rtol=1e-6)
-    plain = moe.route(h, kernel, 4, 2.5)
+    plain = route(h, kernel, 4, 2.5)
     assert (np.sort(np.asarray(plain[1]), -1)
             == np.sort(np.argsort(-scores, -1)[:, :4], -1)).all()
     assert (np.asarray(plain[1]) != np.asarray(chosen)).any()
-    grad_b, grad_w = jax.grad(
+    grad_b, grad_w = jax.jit(jax.grad(
         lambda b, w: jnp.sum(moe.route(h, w, 4, 2.5, b)[2] ** 2),
-        argnums=(0, 1))(bias, kernel)
+        argnums=(0, 1)))(bias, kernel)
     assert np.all(np.asarray(grad_b) == 0.0)
     assert np.abs(np.asarray(grad_w)).sum() > 0.0
 
@@ -213,13 +223,13 @@ def test_the_objective_is_two_means_over_their_own_positions(fused_head):
     call in several chunks: against ``optax`` on full logits, the module's
     last position without a target, ignored positions in neither mean."""
     batch, seq, d, vocab = 2, 16, 8, 32
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    states = transformer.MtpStates(
-        jax.random.normal(keys[0], (batch, seq, d)),
-        jax.random.normal(keys[1], (batch, seq, d)))
-    head = jax.random.normal(keys[2], (vocab, d))
-    targets = jax.random.randint(keys[3], (batch, seq), 0, vocab)
-    targets = targets.at[0, 5].set(-1)
+    hidden, mtp, head = normal(0, (batch, seq, d), (batch, seq, d),
+                               (vocab, d))
+    states = transformer.MtpStates(hidden, mtp)
+    targets = np.random.default_rng(0).integers(0, vocab, (batch, seq),
+                                                np.int32)
+    targets[0, 5] = -1
+    targets = jnp.asarray(targets)
 
     def by_hand(states, head):
         def mean_ce(h, t):
@@ -230,11 +240,11 @@ def test_the_objective_is_two_means_over_their_own_positions(fused_head):
         mtp = mean_ce(states.mtp[:, :-1], targets[:, 1:])
         return main + 0.3 * mtp, (main, mtp)
 
-    (want, (main, mtp)), want_grads = jax.value_and_grad(
-        by_hand, argnums=(0, 1), has_aux=True)(states, head)
-    (loss, metrics), grads = jax.value_and_grad(
+    (want, (main, mtp)), want_grads = jax.jit(jax.value_and_grad(
+        by_hand, argnums=(0, 1), has_aux=True))(states, head)
+    (loss, metrics), grads = jax.jit(jax.value_and_grad(
         lambda s, h: mtp_objective(s, h, targets, weight=0.3),
-        argnums=(0, 1), has_aux=True)(states, head)
+        argnums=(0, 1), has_aux=True))(states, head)
     assert float(loss) == pytest.approx(float(want), rel=1e-6)
     assert float(metrics["loss_main"]) == pytest.approx(float(main), rel=1e-6)
     assert float(metrics["loss_mtp"]) == pytest.approx(float(mtp), rel=1e-6)
@@ -248,16 +258,17 @@ def test_the_objective_is_two_means_over_their_own_positions(fused_head):
     assert np.all(np.asarray(grads[0].mtp[:, -1]) == 0.0)
 
 
-def test_the_module_reads_the_next_token_and_no_later_one():
+def test_the_module_reads_the_next_token_and_no_later_one(uncut):
     """Changing token ``j`` moves the main stack's states from ``j`` on and
     the module's from ``j - 1`` on (position ``i`` is given the embedding of
     token ``i + 1``), and nothing before."""
-    cfg, params, tokens = _uncut()
+    cfg, params, tokens = uncut
     model = transformer.Transformer(cfg)
+    states = jax.jit(lambda tokens: model.apply(
+        {"params": params}, tokens, return_hidden=True))
     j = 20
     other = tokens.at[:, j].set((tokens[:, j] + 1) % 256)
-    a = model.apply({"params": params}, tokens, return_hidden=True)
-    b = model.apply({"params": params}, other, return_hidden=True)
+    a, b = states(tokens), states(other)
 
     def moved(x, y):
         return np.asarray(jnp.max(jnp.abs(x - y), (0, 2)) > 0)
@@ -267,10 +278,11 @@ def test_the_module_reads_the_next_token_and_no_later_one():
     # the last position takes the FIRST token for want of a next one
     assert not module[:j - 1].any() and module[j - 1:SEQ - 1].all()
     first = tokens.at[:, 0].set((tokens[:, 0] + 1) % 256)
-    c = model.apply({"params": params}, first, return_hidden=True)
+    c = states(first)
     assert moved(a.mtp, c.mtp).all()
     # without return_hidden the logits are the main stack's, module unused
-    logits = model.apply({"params": params}, tokens)
+    logits = jax.jit(lambda tokens: model.apply({"params": params}, tokens))(
+        tokens)
     assert logits.shape == (2, SEQ, 256)
 
 
@@ -301,6 +313,57 @@ def test_joyai_trains_through_the_trainer():
     # the selection bias has no gradient: AdamW's update of it is nothing
     after = shd.unbox(state.params)["blocks_1"]["moe"]["router_bias"]
     assert np.all(np.asarray(after) == before)
+
+
+# ------------------------------------------------------- over an ep mesh
+def test_joyai_trains_on_ep_mesh(eight_devices):
+    """JoyAI-LLM's test size beside Laguna's, all 32 experts held, sharded
+    over ep=4 with the batch over dp=2: latent attention and the module's
+    layer under the mesh, each expert shard computing its eight experts'
+    part (the module's too) — the same loss as one device gives, a finite,
+    falling loss, nothing dropped, both heads' losses in the metrics."""
+    kwargs = dict(size="test", seq_len=32, vocab=256,
+                  layer_types=["dense", "sparse", "sparse"])
+    bundle = get_model("joyai", **kwargs)
+
+    def trainer(spec):
+        return Trainer(
+            init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+            optimizer=optax.adam(1e-3),
+            config=TrainConfig(global_batch=8, compute_dtype=jnp.float32),
+            mesh_spec=spec)
+
+    sharded = trainer(MeshSpec(dp=2, ep=4))
+    state = sharded.init_state()
+    flat = shd.flatten_dict(shd.unbox(state.params))
+    held = {k: v for k, v in flat.items() if k.endswith("moe/w_gate")}
+    assert {"blocks_1/moe/w_gate", "mtp_block/moe/w_gate"} <= set(held)
+    for key, w in held.items():
+        assert "ep" in str(w.sharding.spec), (key, w.sharding.spec)
+        assert w.shape[-3] == 32  # every expert held, eight a shard
+
+    # the seeded parameters and the first step's rng (``Trainer.train_step``
+    # folds the step into the state's), on the host, before the first step
+    # donates them
+    seeded, first_rng = jax.device_get(
+        (state.params, jax.random.fold_in(state.rng, state.step)))
+    batches = [next(iter(bundle.make_data(8, seed=0)))] * 6
+    losses, metrics = [], []
+    for batch in batches:
+        state, m = sharded.train_step(state, batch)
+        losses.append(float(m["loss"]))
+        metrics.append({k: float(v) for k, v in m.items()})
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    assert all(m["moe_dropped"] == 0.0 for m in metrics)
+    # all experts held: each of a token's 4 choices has a row somewhere
+    assert all(abs(m["moe_rows_per_token"] - 4.0) < 1e-6 for m in metrics)
+    assert all(abs(m["loss"] - m["loss_main"] - 0.3 * m["loss_mtp"]) < 1e-4
+               for m in metrics)
+
+    # one device: the loss alone, on the same parameters and batch (no second
+    # trainer: its state and step are programs this test does not read)
+    first, _ = jax.jit(bundle.loss_fn)(seeded, batches[0], first_rng)
+    assert abs(float(first) - losses[0]) < 1e-4
 
 
 # ---------------------------------------------------------------- counts

@@ -1,6 +1,6 @@
-"""Laguna's mechanisms at test size on the CPU: the window in the flash
-kernels (interpreted) and in the XLA path against the written-out mask,
-partial rotary and YaRN tables against HF's formulas written out here, GQA 6
+"""Laguna's mechanisms at test size on the CPU (the window in the flash
+kernels against the written-out mask: ``tests/test_flash_window.py``): partial
+rotary and YaRN tables against HF's formulas written out here, GQA 6
 and 8 at head_dim 128, the expert layer's shares adding up to the uncut
 reference layer, the whole model against ``benchmark/lib/reference_laguna``
 and the description's counts against a hand count of ISSUE 31's table."""
@@ -17,19 +17,17 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import normal, written_out
 
 from easydl_tpu.core import sharding as shd
 from easydl_tpu.core.mesh import MeshSpec, build_mesh
 from easydl_tpu.core.train_loop import TrainConfig, Trainer
+from easydl_tpu.models import lm
 from easydl_tpu.models.laguna import describe
 from easydl_tpu.models.registry import get_model
 from easydl_tpu.ops import attention as attention_module
 from easydl_tpu.ops import multihead_attention
-from easydl_tpu.ops.flash_attention import (
-    Band,
-    choose_blocks,
-    flash_attention,
-)
+from easydl_tpu.ops.flash_attention import flash_attention
 from easydl_tpu.ops.moe import MoeMlp
 from easydl_tpu.ops.rope import apply_rope, rope_rows, rope_tables
 
@@ -53,142 +51,6 @@ def _config(name="laguna-test"):
         return json.load(f)
 
 
-def written_out(q, k, v, window):
-    """Softmax attention with the mask written out entry by entry (numpy
-    loops), bottom-right aligned: query i of the last s_q positions."""
-    b, s_q, h, d = q.shape
-    s_k = k.shape[1]
-    mask = np.zeros((s_q, s_k), bool)
-    for i in range(s_q):
-        for j in range(s_k):
-            at = i + s_k - s_q
-            mask[i, j] = j <= at and (window is None or at - j < window)
-    scores = np.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
-    scores = np.where(mask, scores, -np.inf)
-    scores = scores - scores.max(-1, keepdims=True)
-    p = np.exp(scores)
-    p = p / p.sum(-1, keepdims=True)
-    return np.einsum("bhqk,bkhd->bqhd", p, v)
-
-
-def _qkv(s_q, s_k, heads=2, d=16, seed=0, kv_heads=None):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    return (jax.random.normal(ks[0], (2, s_q, heads, d)),
-            jax.random.normal(ks[1], (2, s_k, kv_heads or heads, d)),
-            jax.random.normal(ks[2], (2, s_k, kv_heads or heads, d)))
-
-
-# window against block: below, equal, above; square and s_q != s_k; blocks
-# that unroll (<= 16 pairs) and blocks that loop. A square problem under a
-# window of at most a block's keys takes the band path (block_q: a
-# sub-block's rows, block_k: the neighbour's), every other the looped one.
-LOOP, BAND = "loop", "band"
-
-
-def _case(s_q, s_k, block, window, path, *, sub=None, heads=2, d=16, kv=None):
-    return pytest.param(s_q, s_k, sub or block, block, window, heads, d, kv,
-                        path, id=f"{s_q}x{s_k}-{sub or block}/{block}-w{window}"
-                        f"-{heads}x{d}" + (f"kv{kv}" if kv else ""))
-
-
-WINDOW_CASES = [
-    # s_q, s_k, block, window
-    _case(64, 64, 16, 8, BAND), _case(64, 64, 16, 16, BAND),
-    _case(64, 64, 16, 24, LOOP), _case(64, 64, 16, 40, LOOP),
-    _case(32, 64, 16, 8, LOOP), _case(32, 64, 16, 16, LOOP),
-    _case(32, 64, 16, 24, LOOP),
-    _case(128, 128, 16, 16, BAND), _case(128, 128, 16, 20, LOOP),
-    _case(96, 128, 16, 7, LOOP),
-    _case(64, 64, 32, 1, BAND), _case(64, 64, 16, 64, LOOP),
-    _case(64, 64, 16, 100, LOOP),
-    # the band path over several grid cells: the first has no block before
-    # it and the last none after it, the others read a neighbour's rows.
-    # Window below and equal to the block, sub-blocks smaller than it
-    _case(128, 128, 16, 16, BAND, sub=8), _case(256, 256, 16, 1, BAND),
-    _case(128, 128, 16, 11, BAND, sub=8, heads=3),
-    _case(256, 256, 32, 20, BAND, sub=16),
-    # eight heads of 16 to a 128-lane block, two lane blocks
-    _case(256, 256, 32, 32, BAND, heads=16),
-    # key/value heads repeated to the query's, as the models hand them over
-    _case(192, 192, 16, 9, BAND, heads=4, kv=2),
-    # one head of 128 to a lane block at the sub-blocks the chip runs (256
-    # and 128 rows, pieces cut at whole 128-row tiles)
-    _case(2048, 2048, 256, 256, BAND, heads=1, d=128),
-    _case(2048, 2048, 256, 200, BAND, sub=128, heads=2, d=128, kv=1),
-    # no caller's blocks: the rule's own cut (one cell of 1,024 rows)
-    _case(1024, 1024, None, 512, BAND, heads=1, d=128),
-    # one key past the block, and a rectangle: the looped kernels
-    _case(256, 256, 16, 17, LOOP), _case(128, 256, 16, 16, LOOP),
-]
-
-
-@pytest.mark.parametrize(
-    "s_q,s_k,block_q,block_k,window,heads,d,kv,path", WINDOW_CASES)
-def test_window_kernels_against_the_written_out_mask(
-        s_q, s_k, block_q, block_k, window, heads, d, kv, path):
-    q, k, v = _qkv(s_q, s_k, heads=heads, d=d, kv_heads=kv)
-    if kv:
-        k, v = (jnp.repeat(x, heads // kv, axis=2) for x in (k, v))
-    took = choose_blocks(s_q, s_k, True, block_q, block_k, window)
-    assert {True: BAND, False: LOOP}[isinstance(took[0], Band)] == path
-    want = written_out(*(np.asarray(x, np.float64) for x in (q, k, v)),
-                       window)
-
-    def mine(q, k, v):
-        return flash_attention(q, k, v, causal=True, window=window,
-                               block_q=block_q, block_k=block_k,
-                               interpret=True)
-
-    np.testing.assert_allclose(np.asarray(mine(q, k, v)), want, atol=2e-5)
-    xla = attention_module._reference_attention(
-        q, k, v, causal=True, scale=q.shape[-1] ** -0.5, window=window)
-    np.testing.assert_allclose(np.asarray(xla), want, atol=2e-5)
-    # the three kernels' gradients against the XLA path's
-    weights = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-    g_mine = jax.grad(lambda *a: jnp.sum(mine(*a) * weights), (0, 1, 2))(
-        q, k, v)
-    g_xla = jax.grad(lambda *a: jnp.sum(
-        attention_module._reference_attention(
-            *a, causal=True, scale=q.shape[-1] ** -0.5, window=window)
-        * weights), (0, 1, 2))(q, k, v)
-    for a, b in zip(g_mine, g_xla):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
-
-
-def test_window_blocks_are_chosen_from_the_window():
-    banded = choose_blocks(8192, 8192, True, window=512)
-    assert banded == (Band(rows=2048, sub=256, reach=512),) * 3
-    # a caller's blocks hold for all three kernels, window or not
-    assert choose_blocks(64, 64, True, 16, 16, window=8) == (
-        Band(rows=64, sub=16, reach=16),) * 3
-    # without a window, and under one wider than a block: the plain rule
-    assert choose_blocks(8192, 8192, True) == ((512, 512),) * 3
-    assert choose_blocks(8192, 8192, True, window=4096) == ((512, 512),) * 3
-    with pytest.raises(ValueError, match="window"):
-        flash_attention(*_qkv(32, 32), causal=False, window=8, interpret=True)
-
-
-def test_band_or_loop_is_chosen_from_the_shapes_alone():
-    """The band path where a block holds the window and the problem is
-    square; the looped kernels' blocks, as they were, everywhere else."""
-    looped = ((512, 512), (512, 512), (512, 256))
-    # one key more than a block holds; a rectangle (a decode's, a prefix's)
-    assert choose_blocks(8192, 8192, True, window=513) == ((512, 512),) * 3
-    assert choose_blocks(4096, 8192, True, window=512) == looped
-    assert choose_blocks(8192, 8192, True, 512, 256, window=512) == (
-        (512, 256),) * 3
-    for s, window, want in [
-            (8192, 512, Band(2048, 256, 512)), (8192, 128, Band(2048, 256, 512)),
-            (4096, 512, Band(2048, 256, 512)), (1536, 300, Band(1536, 256, 512)),
-            (2560, 512, Band(512, 256, 512)), (256, 64, Band(256, 256, 256))]:
-        assert choose_blocks(s, s, True, window=window) == (want,) * 3
-    # a cell's rows are whole neighbour blocks, a neighbour whole sub-blocks
-    assert choose_blocks(256, 256, True, 8, 16, window=16) == (
-        Band(rows=64, sub=8, reach=16),) * 3
-    # no block divisor: no kernel at all, as without a window
-    assert choose_blocks(520, 520, True, window=64) is None
-
-
 @pytest.mark.parametrize("heads", [48, 64])
 def test_gqa_six_and_eight_query_heads_a_kv_head_at_head_dim_128(
         monkeypatch, heads):
@@ -197,13 +59,11 @@ def test_gqa_six_and_eight_query_heads_a_kv_head_at_head_dim_128(
     query head reading ``j // (heads / 8)``."""
     monkeypatch.setattr(attention_module, "flash_attention",
                         functools.partial(flash_attention, interpret=True))
-    ks = jax.random.split(jax.random.PRNGKey(heads), 3)
-    q = jax.random.normal(ks[0], (1, 128, heads, 128))
-    k = jax.random.normal(ks[1], (1, 128, 8, 128))
-    v = jax.random.normal(ks[2], (1, 128, 8, 128))
+    q, k, v = normal(heads, (1, 128, heads, 128), *[(1, 128, 8, 128)] * 2)
     window = 64 if heads == 64 else None
-    out = multihead_attention(q, k, v, causal=True, impl="flash",
-                              window=window)
+    out = jax.jit(functools.partial(
+        multihead_attention, causal=True, impl="flash", window=window))(
+            q, k, v)
     rep = heads // 8
     want = written_out(
         np.asarray(q, np.float64), np.repeat(np.asarray(k, np.float64), rep, 2),
@@ -346,24 +206,29 @@ def test_the_shares_add_up_to_the_uncut_reference_layer():
     """16 experts over 4 shares: the four parts, the shared expert counted
     once, equal the reference's uncut layer."""
     d, f, total, k = 32, 16, 16, 4
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, d))
+    x, = normal(0, (2, 24, d))
     whole = MoeMlp(experts_total=total, experts_held=(0, total), d_ff=f,
                    shared_d_ff=f, k=k, scaling=2.5)
-    params = shd.unbox(whole.init(jax.random.PRNGKey(1), x))["params"]
+    params = shd.unbox(jax.jit(whole.init)(jax.random.PRNGKey(1), x))["params"]
     p_ref = {"router": params["router"], "e_gate": params["w_gate"],
              "e_up": params["w_up"], "e_down": params["w_down"],
              "s_gate": params["shared_gate"], "s_up": params["shared_up"],
              "s_down": params["shared_down"]}
     hp = {"experts_held": (0, total), "k": k, "scaling": 2.5}
-    want, _, _ = ref.moe(x, p_ref, hp)
-    shared = ref.swiglu(x, p_ref["s_gate"], p_ref["s_up"], p_ref["s_down"])
+    want, shared = jax.jit(lambda x, p: (
+        ref.moe(x, p, hp)[0],
+        ref.swiglu(x, p["s_gate"], p["s_up"], p["s_down"])))(x, p_ref)
+
+    def layer(module):
+        return jax.jit(lambda p, x: module.apply({"params": p}, x))
+
     parts, dropped, rows = [], 0.0, 0.0
     for lo in range(0, total, 4):
         share = MoeMlp(experts_total=total, experts_held=(lo, lo + 4),
                        d_ff=f, shared_d_ff=f, k=k, scaling=2.5)
         mine = dict(params, **{name: params[name][lo:lo + 4]
                                for name in ("w_gate", "w_up", "w_down")})
-        y, counters, _ = share.apply({"params": mine}, x)
+        y, counters, _ = layer(share)(mine, x)
         parts.append(y)
         dropped += float(counters[0])
         rows += float(counters[1])
@@ -372,8 +237,73 @@ def test_the_shares_add_up_to_the_uncut_reference_layer():
     assert dropped == 0.0
     assert rows == pytest.approx(k)  # every choice fell on exactly one share
     # and the whole layer alone gives the same
-    y, _, _ = whole.apply({"params": params}, x)
+    y, _, _ = layer(whole)(params, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
+
+
+# ------------------------------------------------ through the fused head
+def test_moe_fused_head_runs(eight_devices, fused_head):
+    """Laguna's test size (expert layers) through the fused chunked head:
+    the counters reach the metrics beside the loss."""
+    bundle = get_model("laguna", size="test", seq_len=32, vocab=128,
+                       experts_held=(0, 4))
+    fused_head(chunk_rows=32)  # 4 sequences: 8 positions a chunk
+    assert lm.fused_head_by_shape(4, 32, 128)
+    rng = jax.random.PRNGKey(1)
+    params = jax.jit(bundle.init_fn)(rng)
+    batch = next(iter(bundle.make_data(4, seed=5)))
+    loss, metrics = jax.jit(bundle.loss_fn)(params, batch, rng)
+    assert np.isfinite(float(loss))
+    assert float(metrics["moe_dropped"]) == 0.0
+    assert 0.0 < float(metrics["moe_rows_per_token"]) < 2.0
+
+
+# ------------------------------------------------------- over an ep mesh
+def test_laguna_trains_on_ep_mesh(eight_devices):
+    """Laguna's test size, all 16 experts held, sharded over ep=4 with the
+    batch over dp=2: each shard computes its four experts' part and the
+    parts are summed — the same loss as one device gives, a finite,
+    falling loss, nothing dropped."""
+    kwargs = dict(size="test", seq_len=32, vocab=256)
+    bundle = get_model("laguna", **kwargs)
+
+    def trainer(spec):
+        return Trainer(
+            init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+            optimizer=optax.adam(1e-3),
+            config=TrainConfig(global_batch=8, compute_dtype=jnp.float32),
+            mesh_spec=spec)
+
+    sharded = trainer(MeshSpec(dp=2, ep=4))
+    state = sharded.init_state()
+    flat = shd.flatten_dict(shd.unbox(state.params))
+    held = {k: v for k, v in flat.items() if k.endswith("moe/w_gate")}
+    assert held, list(flat)[:8]
+    for key, w in held.items():
+        assert "ep" in str(w.sharding.spec), (key, w.sharding.spec)
+        assert w.shape[1] == 16  # every expert held, four a shard
+
+    # the seeded parameters and the first step's rng (``Trainer.train_step``
+    # folds the step into the state's), on the host, before the first step
+    # donates them
+    seeded, first_rng = jax.device_get(
+        (state.params, jax.random.fold_in(state.rng, state.step)))
+    # one batch six times over: something to learn
+    batches = [next(iter(bundle.make_data(8, seed=0)))] * 6
+    losses, metrics = [], []
+    for batch in batches:
+        state, m = sharded.train_step(state, batch)
+        losses.append(float(m["loss"]))
+        metrics.append({k: float(v) for k, v in m.items()})
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    assert all(m["moe_dropped"] == 0.0 for m in metrics)
+    # all experts held: each of a token's 2 choices has a row somewhere
+    assert all(abs(m["moe_rows_per_token"] - 2.0) < 1e-6 for m in metrics)
+
+    # one device: the loss alone, on the same parameters and batch (no second
+    # trainer: its state and step are programs this test does not read)
+    first, _ = jax.jit(bundle.loss_fn)(seeded, batches[0], first_rng)
+    assert abs(float(first) - losses[0]) < 1e-4
 
 
 # --------------------------------------------------------- the whole model
@@ -410,7 +340,7 @@ def test_program_against_reference_laguna(float32_check, what, limit):
 
 def test_every_gradient_leaf_was_compared(float32_check):
     cfg = describe(**_config()["kwargs"])
-    params = get_model("laguna", **_config()["kwargs"]).init_fn(
+    params = jax.jit(get_model("laguna", **_config()["kwargs"]).init_fn)(
         jax.random.PRNGKey(0))
     n_leaves = len(jax.tree.leaves(check_module.to_reference(
         shd.unbox(params))))

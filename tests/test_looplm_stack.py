@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import out_and_grads
 
 from easydl_tpu.core.mesh import MeshSpec, build_mesh
 from easydl_tpu.core.sharding import flatten_dict, unbox
@@ -74,7 +75,7 @@ def _batch(seed=0, rows=2, seq=32, vocab=256):
 def _params(bundle, seed=0, gate_scale=20.0):
     """Seeded parameters with the gate's weight enlarged: at 0.02 every exit
     probability sits near a half and a wrong distribution would pass."""
-    params = unbox(bundle.init_fn(jax.random.PRNGKey(seed)))
+    params = unbox(jax.jit(bundle.init_fn)(jax.random.PRNGKey(seed)))
     return dict(params, exit_gate=params["exit_gate"] * gate_scale)
 
 
@@ -89,19 +90,30 @@ def against_reference(request):
     model = Transformer(describe(total_ut_steps=passes, **TEST))
     hp = dict(HP, total_ut_steps=passes)
     plain = check_module.to_reference(params)
-    with jax.default_matmul_precision("highest"):
+
+    # the program's states, logits, loss and gradients: one jitted program;
+    # the reference's: one more
+    @jax.jit
+    def program(params, batch):
         out = model.apply({"params": params}, batch["inputs"],
                           return_hidden=True)
-        (loss, aux), grads = jax.value_and_grad(
-            bundle.loss_fn, has_aux=True)(params, batch, None)
+        return out, jax.value_and_grad(bundle.loss_fn, has_aux=True)(
+            params, batch, None), jnp.einsum(
+                "tbsd,dv->tbsv", out.hidden, params["head"]["kernel"])
+
+    @jax.jit
+    def reference(plain, batch):
         hidden, gates = ref.states(plain, batch["inputs"], hp)
-        loss_r, ce_r, p_r = ref.objective(hidden, gates, plain["head"],
-                                          batch["targets"], hp["beta"])
-        _, grads_r = ref.loss_and_grads(plain, batch["inputs"],
-                                        batch["targets"], hp)
-        logits = jnp.einsum("tbsd,dv->tbsv", out.hidden,
-                            params["head"]["kernel"])
-        logits_r = jnp.stack([h @ plain["head"] for h in hidden])
+        return (hidden, gates, ref.objective(
+            hidden, gates, plain["head"], batch["targets"], hp["beta"]),
+            ref.loss_and_grads(plain, batch["inputs"], batch["targets"],
+                               hp)[1],
+            jnp.stack([h @ plain["head"] for h in hidden]))
+
+    with jax.default_matmul_precision("highest"):
+        out, ((loss, aux), grads), logits = program(params, batch)
+        hidden, gates, (loss_r, ce_r, p_r), grads_r, logits_r = reference(
+            plain, batch)
     return dict(passes=passes, out=out, loss=loss, aux=aux,
                 grads=check_module.to_reference(grads), hidden=hidden,
                 gates=gates, loss_r=loss_r, ce_r=ce_r, p_r=p_r,
@@ -168,12 +180,13 @@ def _plain_config(**over):
 def test_one_loop_without_gate_is_the_plain_stack():
     plain = lm.lm_bundle(_plain_config(), "plain")
     looped = lm.lm_bundle(_plain_config(loops=1, exit_gate=False), "looped")
-    params, batch = unbox(plain.init_fn(jax.random.PRNGKey(0))), _batch()
-    a, _ = plain.loss_fn(params, batch, None)
-    b, _ = looped.loss_fn(params, batch, None)
+    params = unbox(jax.jit(plain.init_fn)(jax.random.PRNGKey(0)))
+    batch = _batch()
+    a, _ = jax.jit(plain.loss_fn)(params, batch, None)
+    b, _ = jax.jit(looped.loss_fn)(params, batch, None)
     assert float(a) == float(b)
-    hidden = Transformer(_plain_config()).apply(
-        {"params": params}, batch["inputs"], return_hidden=True)
+    hidden = jax.jit(lambda p, x: Transformer(_plain_config()).apply(
+        {"params": p}, x, return_hidden=True))(params, batch["inputs"])
     assert hidden.shape == (2, 32, 64)  # an array, as it always was
 
 
@@ -185,8 +198,8 @@ def test_loops_do_not_move_the_parameters_and_multiply_the_flops():
     per_pass = 6.0 * looped + 12.0 * 3 * 64 * 32
     assert one.train_flops_per_token(32) == 6.0 * 64 + per_pass  # + ln_f
     assert four.train_flops_per_token(32) == 6.0 * 64 + 4 * per_pass
-    n = sum(x.size for x in jax.tree.leaves(unbox(
-        lm.lm_bundle(four, "x").init_fn(jax.random.PRNGKey(0)))))
+    n = sum(x.size for x in jax.tree.leaves(unbox(jax.eval_shape(
+        lm.lm_bundle(four, "x").init_fn, jax.random.PRNGKey(0)))))
     assert n == four.param_count
 
 
@@ -196,8 +209,8 @@ def test_a_looped_layers_gradient_is_the_sum_over_an_unrolled_untied_copy():
     hand): each shared leaf's gradient is the sum of its two copies'."""
     cfg = _plain_config(loops=2)
     model = Transformer(cfg)
-    params = unbox(model.init(jax.random.PRNGKey(1),
-                              jnp.zeros((1, 32), jnp.int32))["params"])
+    params = unbox(jax.jit(model.init)(
+        jax.random.PRNGKey(1), jnp.zeros((1, 32), jnp.int32))["params"])
     batch = _batch(3)
     # a normed state's sum of squares is a constant: weigh it instead
     w = jnp.asarray(np.random.default_rng(4).normal(size=(2, 32, 64)),
@@ -225,10 +238,10 @@ def test_a_looped_layers_gradient_is_the_sum_over_an_unrolled_untied_copy():
 
     blocks = params["blocks"]
     with jax.default_matmul_precision("highest"):
-        assert float(looped(blocks)) == pytest.approx(
-            float(unrolled(blocks, blocks)), rel=1e-5)
-        shared = jax.grad(looped)(blocks)
-        g1, g2 = jax.grad(unrolled, (0, 1))(blocks, blocks)
+        value, shared = jax.jit(jax.value_and_grad(looped))(blocks)
+        value_unrolled, (g1, g2) = jax.jit(jax.value_and_grad(
+            unrolled, (0, 1)))(blocks, blocks)
+    assert float(value) == pytest.approx(float(value_unrolled), rel=1e-5)
     for (path, g), a, b in zip(jax.tree_util.tree_leaves_with_path(shared),
                                jax.tree.leaves(g1), jax.tree.leaves(g2)):
         assert rel(g, a + b) < 2e-4, jax.tree_util.keystr(path)
@@ -270,10 +283,13 @@ def test_a_prefix_of_a_sequence_gives_the_prefix_of_the_result():
     bundle = get_model("ouro", **TEST)
     params, batch = _params(bundle), _batch(rows=1)
     model = Transformer(describe(**TEST))
-    whole = model.apply({"params": params}, batch["inputs"],
-                        return_hidden=True)
-    part = Transformer(describe(**dict(TEST, seq_len=12))).apply(
-        {"params": params}, batch["inputs"][:, :12], return_hidden=True)
+    def states(model):
+        return jax.jit(lambda p, x: model.apply({"params": p}, x,
+                                                return_hidden=True))
+
+    whole = states(model)(params, batch["inputs"])
+    part = states(Transformer(describe(**dict(TEST, seq_len=12))))(
+        params, batch["inputs"][:, :12])
     np.testing.assert_allclose(part.hidden, whole.hidden[:, :, :12],
                                atol=2e-5)
     np.testing.assert_allclose(part.gate, whole.gate[:, :, :12], atol=2e-5)
@@ -298,11 +314,14 @@ def test_rope_kernel_is_the_plain_rotation(heads):
 
     # the same float32 arithmetic, fused differently: one bf16 ulp apart
     # in a few entries of a hundred thousand
-    np.testing.assert_allclose(np.asarray(kernel(x), np.float32),
-                               np.asarray(plain(x), np.float32), rtol=8e-3,
-                               atol=1e-6)
-    got = jax.grad(lambda x: (kernel(x).astype(jnp.float32) * w).sum())(x)
-    want = jax.grad(lambda x: (plain(x).astype(jnp.float32) * w).sum())(x)
+    def weighed(y):
+        return (y.astype(jnp.float32) * w).sum()
+
+    rotated, (got,) = out_and_grads(kernel, weighed)(x)
+    rotated_plain, (want,) = out_and_grads(plain, weighed)(x)
+    np.testing.assert_allclose(np.asarray(rotated, np.float32),
+                               np.asarray(rotated_plain, np.float32),
+                               rtol=8e-3, atol=1e-6)
     assert rel(got, want) < 1e-2  # bf16 cotangents, rounded once each way
     with pytest.raises(ValueError, match="128-lane"):
         rope_rows(x[..., :64].reshape(2, 64, -1), cos[:, :64], sin[:, :64],
@@ -345,27 +364,33 @@ def test_fused_head_with_row_weights_is_the_weighted_loss(chunk):
                                   chunk_size=chunk)[0]
 
     with jax.default_matmul_precision("highest"):
-        loss, denom, rows = fused_softmax_xent(
-            hidden, kernel.T, targets, weights=weights, chunk_size=chunk)
-        assert float(denom) == float(mask.sum())
-        assert float(loss) == pytest.approx(
-            float(full(hidden, kernel, weights)), rel=1e-6)
-        np.testing.assert_allclose(rows, rows_of(hidden, kernel), rtol=1e-5,
-                                   atol=1e-6)
-        got = jax.grad(fused, (0, 1, 2))(hidden, kernel, weights)
-        want = jax.grad(full, (0, 1, 2))(hidden, kernel, weights)
+        loss, denom, rows = jax.jit(lambda h, k, w: fused_softmax_xent(
+            h, k.T, targets, weights=w, chunk_size=chunk))(
+                hidden, kernel, weights)
+        _, got = jax.jit(jax.value_and_grad(fused, (0, 1, 2)))(
+            hidden, kernel, weights)
+        loss_full, want = jax.jit(jax.value_and_grad(full, (0, 1, 2)))(
+            hidden, kernel, weights)
+        rows_full = jax.jit(rows_of)(hidden, kernel)
+    assert float(denom) == float(mask.sum())
+    assert float(loss) == pytest.approx(float(loss_full), rel=1e-6)
+    np.testing.assert_allclose(rows, rows_full, rtol=1e-5, atol=1e-6)
     for g, w in zip(got, want):
         assert rel(g, w) < 1e-5
     np.testing.assert_allclose(got[2], rows / denom, rtol=1e-6)
 
 
-def test_looplm_loss_is_the_same_through_either_head(monkeypatch):
+def test_looplm_loss_is_the_same_through_either_head(fused_head):
     bundle = get_model("ouro", **TEST)
     params, batch = _params(bundle), _batch()
-    f = jax.value_and_grad(lambda p: bundle.loss_fn(p, batch, None),
-                           has_aux=True)
+
+    def f(params):
+        # a program of its own a call: the head is chosen when it is traced
+        return jax.jit(jax.value_and_grad(
+            lambda p: bundle.loss_fn(p, batch, None), has_aux=True))(params)
+
     (full, aux_full), g_full = f(params)
-    monkeypatch.setattr(lm, "FUSED_HEAD_LOGITS_BYTES", 0)
+    fused_head()
     (fused, aux_fused), g_fused = f(params)
     assert float(fused) == pytest.approx(float(full), rel=1e-5)
     for key in aux_full:
@@ -397,7 +422,7 @@ def test_flash_kernels_at_head_dim_128(heads):
     r = np.random.default_rng(heads)
     q, k, v = (jnp.asarray(r.normal(size=(2, 256, heads, 128)) * 0.5,
                            jnp.float32) for _ in range(3))
-    w = jnp.asarray(r.normal(size=q.shape), jnp.float32)
+    w = r.normal(size=q.shape).astype(np.float32)
 
     def kernel(q, k, v):
         return flash_attention(q, k, v, causal=True, block_q=128,
@@ -407,9 +432,12 @@ def test_flash_kernels_at_head_dim_128(heads):
         return attention_module._reference_attention(
             q, k, v, causal=True, scale=128 ** -0.5)
 
-    np.testing.assert_allclose(kernel(q, k, v), plain(q, k, v), atol=2e-5)
-    got = jax.grad(lambda *a: (kernel(*a) * w).sum(), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: (plain(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    def weighed(out):
+        return (out * w).sum()
+
+    out, got = out_and_grads(kernel, weighed)(q, k, v)
+    out_plain, want = out_and_grads(plain, weighed)(q, k, v)
+    np.testing.assert_allclose(out, out_plain, atol=2e-5)
     for g, x in zip(got, want):
         assert rel(g, x) < 5e-4
 
@@ -426,13 +454,14 @@ def test_rotary_attention_takes_the_kernels_and_the_rope_kernel(monkeypatch):
     q, k, v = (jnp.asarray(r.normal(size=(1, 128, 2, 128)) * 0.5,
                            jnp.float32) for _ in range(3))
     tables = rope_tables(128, 128, 1e6)
-    got = attention_module.multihead_attention(
-        q, k, v, causal=True, impl="flash", rope=tables)
-    want = attention_module.multihead_attention(
-        q, k, v, causal=True, impl="reference", rope=tables)
+    def attend(impl, rope):
+        return jax.jit(functools.partial(
+            attention_module.multihead_attention, causal=True, impl=impl,
+            rope=rope))(q, k, v)
+
+    got, want = attend("flash", tables), attend("reference", tables)
     np.testing.assert_allclose(got, want, atol=2e-5)
-    plain = attention_module.multihead_attention(
-        q, k, v, causal=True, impl="reference")
+    plain = attend("reference", None)
     assert float(jnp.abs(want - plain).max()) > 1e-3
 
 

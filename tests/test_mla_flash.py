@@ -6,10 +6,13 @@ does not divide; how many heads a cell takes; the calls' names; the public
 path through ``multihead_attention``; and q's rotation by the kernel on rows
 of 192-lane heads beside the one key vector's in ``jax.numpy``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import normal, out_and_grads
 
 from easydl_tpu.ops import attention, flash_attention as fa
 from easydl_tpu.ops.rope import apply_rope, rope_rows, rope_tables
@@ -31,11 +34,9 @@ def _plain(q, k, v, scale):
 
 
 def _operands(s_q, s_k, heads, d, dv, seed=0):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
-    return (jax.random.normal(keys[0], (2, s_q, heads, d)),
-            jax.random.normal(keys[1], (2, s_k, heads, d)),
-            jax.random.normal(keys[2], (2, s_k, heads, dv)),
-            jax.random.normal(keys[3], (2, s_q, heads, dv)))
+    """q, k, v and a cotangent of the result's shape."""
+    return normal(seed, (2, s_q, heads, d), (2, s_k, heads, d),
+                  (2, s_k, heads, dv), (2, s_q, heads, dv))
 
 
 CASES = {
@@ -56,20 +57,21 @@ def test_kernels_at_two_head_sizes_against_the_plain_form(case):
     q, k, v, g = _operands(s_q, s_k, heads, d, dv)
     scale = d ** -0.5
 
-    def kernels(q, k, v):
-        return fa.flash_attention(q, k, v, causal=True, scale=scale,
-                                  block_q=block_q, block_k=block_k,
-                                  interpret=True)
+    def weighed(out):
+        return jnp.sum(out * g)
 
-    out = kernels(q, k, v)
+    # the kernels' forward and gradients: one program; the reference's: one
+    out, got = out_and_grads(functools.partial(
+        fa.flash_attention, causal=True, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=True), weighed)(q, k, v)
     assert out.shape == (2, s_q, heads, dv)
     np.testing.assert_allclose(np.asarray(out), _plain(q, k, v, scale),
                                atol=2e-5)
     looped = not fa._unrolled(s_q // block_q, s_k // block_k)
     assert looped == case.startswith("looped")
-    got = jax.grad(lambda *x: jnp.sum(kernels(*x) * g), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *x: jnp.sum(attention._reference_attention(
-        *x, causal=True, scale=scale) * g), (0, 1, 2))(q, k, v)
+    _, want = out_and_grads(functools.partial(
+        attention._reference_attention, causal=True, scale=scale),
+        weighed)(q, k, v)
     for name, a, b in zip("qkv", got, want):
         assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
@@ -78,10 +80,12 @@ def test_kernels_at_two_head_sizes_against_the_plain_form(case):
 
 def test_bf16_operands_keep_the_two_roundings():
     q, k, v, _ = _operands(256, 256, 2, 192, 128)
-    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
-    out = fa.flash_attention(q, k, v, causal=True, interpret=True)
-    want = attention._reference_attention(q, k, v, causal=True,
-                                          scale=192 ** -0.5)
+    q, k, v = (np.asarray(x).astype(jnp.bfloat16) for x in (q, k, v))
+    out = jax.jit(functools.partial(
+        fa.flash_attention, causal=True, interpret=True))(q, k, v)
+    want = jax.jit(functools.partial(
+        attention._reference_attention, causal=True, scale=192 ** -0.5))(
+            q, k, v)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
@@ -131,15 +135,12 @@ def test_the_calls_are_named_by_what_they_compute():
 def test_the_public_path_takes_a_value_size_of_its_own(monkeypatch):
     """``multihead_attention`` with ``impl="flash"``: the kernels'
     (interpreted) result at v's head size, equal to the reference path's."""
-    import functools
-
     monkeypatch.setattr(attention, "flash_attention", functools.partial(
         fa.flash_attention, interpret=True))
     q, k, v, _ = _operands(256, 256, 4, 24, 16, seed=3)
-    kernels = attention.multihead_attention(q, k, v, causal=True,
-                                            impl="flash")
-    reference = attention.multihead_attention(q, k, v, causal=True,
-                                              impl="reference")
+    kernels, reference = (jax.jit(functools.partial(
+        attention.multihead_attention, causal=True, impl=impl))(q, k, v)
+        for impl in ("flash", "reference"))
     assert kernels.shape == reference.shape == (2, 256, 4, 16)
     np.testing.assert_allclose(np.asarray(kernels), np.asarray(reference),
                                atol=2e-5)
@@ -150,21 +151,19 @@ def test_q_is_rotated_on_its_rows_and_the_key_vector_once(monkeypatch):
     heads x 192]`` rows (units of two heads, 384 lanes), else ``jax.numpy``;
     both the written-out rotation of a head's LAST 64 lanes in pairs ``(2i,
     2i + 1)``, with its gradient."""
-    import functools
-
     from easydl_tpu.ops import rope
 
     seq, heads, d, rot = 64, 4, 192, 64
     tables = rope_tables(seq, d, 32e6, rot, interleaved=True, last=True)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, seq, heads, d))
+    x, key = normal(0, (2, seq, heads, d), (2, seq, 1, rot))
     angle = np.arange(seq)[:, None] * 32e6 ** (-np.arange(0, rot, 2) / rot)
     want = np.asarray(x, np.float64)
     a, b = want[..., d - rot::2].copy(), want[..., d - rot + 1::2].copy()
     cos, sin = np.cos(angle)[None, :, None], np.sin(angle)[None, :, None]
     want[..., d - rot::2] = a * cos - b * sin
     want[..., d - rot + 1::2] = b * cos + a * sin
-    plain = attention.rotate_heads(x, tables, rotary_dim=rot,
-                                   interleaved=True, impl="reference")
+    plain = jax.jit(lambda x: attention.rotate_heads(
+        x, tables, rotary_dim=rot, interleaved=True, impl="reference"))(x)
     np.testing.assert_allclose(np.asarray(plain), want, atol=2e-5)
     monkeypatch.setattr(attention, "rope_rows", functools.partial(
         rope.rope_rows, interpret=True))
@@ -174,19 +173,22 @@ def test_q_is_rotated_on_its_rows_and_the_key_vector_once(monkeypatch):
                                       interleaved=True, impl="flash")
 
     assert "rope_fwd" in str(jax.make_jaxpr(kernel)(x))
-    np.testing.assert_allclose(np.asarray(kernel(x)), want, atol=2e-5)
-    g_kernel = jax.grad(lambda x: jnp.sum(kernel(x) ** 3))(x)
-    g_plain = jax.grad(lambda x: jnp.sum(apply_rope(
-        x, *tables, rot=rot, interleaved=True) ** 3))(x)
+    def cubed(out):
+        return jnp.sum(out ** 3)
+
+    rotated, (g_kernel,) = out_and_grads(kernel, cubed)(x)
+    np.testing.assert_allclose(np.asarray(rotated), want, atol=2e-5)
+    _, (g_plain,) = out_and_grads(lambda x: apply_rope(
+        x, *tables, rot=rot, interleaved=True), cubed)(x)
     np.testing.assert_allclose(np.asarray(g_kernel), np.asarray(g_plain),
                                atol=1e-4)
     # the key's one vector: a head of its own, all of it rotated, the
     # tables the q tables' last lanes
-    key = jax.random.normal(jax.random.PRNGKey(1), (2, seq, 1, rot))
-    lone = apply_rope(key, *(t[:, -rot:] for t in tables), interleaved=True)
-    whole = apply_rope(jnp.concatenate(
+    lone = jax.jit(lambda key: apply_rope(
+        key, *(t[:, -rot:] for t in tables), interleaved=True))(key)
+    whole = jax.jit(lambda key: apply_rope(jnp.concatenate(
         [jnp.zeros((2, seq, 1, d - rot)), key], -1), *tables, rot=rot,
-        interleaved=True)
+        interleaved=True))(key)
     np.testing.assert_allclose(np.asarray(lone),
                                np.asarray(whole[..., d - rot:]), atol=1e-6)
     # three heads of 192 do not fill whole units: jax.numpy, told apart

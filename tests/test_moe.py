@@ -1,26 +1,27 @@
 """The expert layer (``ops/moe.py``): routing invariants, no token dropped,
-forward and gradients, and Laguna's test size training over an ep-sharded
-mesh."""
+forward and gradients, the grouped products and the experts' rule. (The
+models that hold it train over an ep-sharded mesh in their own files:
+``tests/test_laguna.py``, ``tests/test_joyai.py``, ``tests/test_zaya.py``.)"""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
+from conftest import normal
 
 from easydl_tpu.core import sharding as shd
-from easydl_tpu.core.mesh import MeshSpec
-from easydl_tpu.core.train_loop import TrainConfig, Trainer
-from easydl_tpu.models.registry import get_model
-from easydl_tpu.ops.moe import (COUNTERS, MoeMlp, piece_rows, route,
+from easydl_tpu.ops.moe import (COUNTERS, MoeMlp, grouped_rows,
+                                grouped_weights, piece_rows, route,
                                 routed_experts, rows_bound)
 
 
 def test_routing_invariants():
     tokens, d, total, k = 64, 16, 8, 3
-    h = jax.random.normal(jax.random.PRNGKey(0), (tokens, d))
-    kernel = jax.random.normal(jax.random.PRNGKey(1), (d, total))
-    logits, chosen, weights = route(h.astype(jnp.bfloat16), kernel, k, 2.5)
+    h, kernel = normal(0, (tokens, d), (d, total))
+    logits, chosen, weights = jax.jit(route, static_argnums=(2, 3))(
+        np.asarray(h).astype(jnp.bfloat16), kernel, k, 2.5)
     assert logits.dtype == jnp.float32 and logits.shape == (tokens, total)
     chosen, weights = np.asarray(chosen), np.asarray(weights)
     # k distinct experts a token, the k largest sigmoid scores
@@ -43,21 +44,20 @@ def test_routing_drops_nothing():
     """Every token chooses ONE expert, the same one: all of them get a row
     (the old layer kept ``capacity`` of them and dropped the rest)."""
     tokens, d, f, held = 32, 8, 4, 4
-    h = jax.random.normal(jax.random.PRNGKey(0), (tokens, d))
+    h, = normal(0, (tokens, d))
     chosen = jnp.zeros((tokens, 1), jnp.int32)
     weights = jnp.ones((tokens, 1), jnp.float32)
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    w_gate = jax.random.normal(ks[0], (held, d, f))
-    w_up = jax.random.normal(ks[1], (held, d, f))
-    w_down = jax.random.normal(ks[2], (held, f, d))
-    y, stats = routed_experts(h, chosen, weights, w_gate, w_up, w_down, 0, 8)
+    w_gate, w_up, w_down = _held_experts(held, d, f)
+    routed = jax.jit(routed_experts, static_argnums=(6, 7))
+    y, stats = routed(h, chosen, weights, w_gate, w_up, w_down, 0, 8)
     dropped, mine, _, largest = (float(x) for x in stats)
     assert (dropped, mine, largest) == (0.0, tokens, tokens)
-    want = (jax.nn.silu(h @ w_gate[0]) * (h @ w_up[0])) @ w_down[0]
+    want = jax.jit(lambda h, g, u, d: (jax.nn.silu(h @ g) * (h @ u)) @ d)(
+        h, w_gate[0], w_up[0], w_down[0])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5,
                                atol=2e-5)
     # a share that holds none of the chosen experts adds exactly nothing
-    y, stats = routed_experts(h, chosen, weights, w_gate, w_up, w_down, 4, 8)
+    y, stats = routed(h, chosen, weights, w_gate, w_up, w_down, 4, 8)
     assert not np.asarray(y).any() and float(stats[1]) == 0.0
 
 
@@ -76,10 +76,24 @@ def test_piece_is_twice_the_expected_load(tokens, k, held, total, want):
 
 
 def _held_experts(held, d=16, f=8):
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    return (jax.random.normal(ks[0], (held, d, f)),
-            jax.random.normal(ks[1], (held, d, f)),
-            jax.random.normal(ks[2], (held, f, d)))
+    return normal(1, (held, d, f), (held, d, f), (held, f, d))
+
+
+def _tokens_and_weights(seed, tokens, k, d=16):
+    """A state a token (normal) and a weight a choice (uniform)."""
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((tokens, d), np.float32)),
+            jnp.asarray(rng.random((tokens, k), np.float32)))
+
+
+@jax.jit
+def _loop_value_and_grads(chosen, args):
+    """Value and the five gradients of the loop over the experts: one
+    program a set of shapes (``chosen`` is an argument, not a constant, so
+    cases that differ in the choices alone share it)."""
+    return jax.value_and_grad(
+        lambda *a: _weighed(_loop_over_experts(a[0], chosen, *a[1:])),
+        argnums=(0, 1, 2, 3, 4))(*args)
 
 
 def _loop_over_experts(h, chosen, weights, w_gate, w_up, w_down):
@@ -98,16 +112,22 @@ def _weighed(y):
     return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
 
 
-def _value_stats_grads(chosen, total, args):
-    """``(loss, stats, gradients by h, weights and the three expert
-    weights)`` of the layer's routed part under a fixed cotangent."""
+@functools.partial(jax.jit, static_argnums=1)
+def _routed_value_and_grads(chosen, total, args):
     def loss(h, weights, w_gate, w_up, w_down):
         y, stats = routed_experts(h, chosen, weights, w_gate, w_up, w_down,
                                   0, total)
         return _weighed(y), stats
 
-    (value, stats), grads = jax.jit(jax.value_and_grad(
-        loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                              has_aux=True)(*args)
+
+
+def _value_stats_grads(chosen, total, args):
+    """``(loss, stats, gradients by h, weights and the three expert
+    weights)`` of the layer's routed part under a fixed cotangent: one
+    program a set of shapes and ``total`` (the choices are an argument)."""
+    (value, stats), grads = _routed_value_and_grads(chosen, total, args)
     return value, np.asarray(stats), grads
 
 
@@ -135,9 +155,7 @@ def test_pieces_match_one_piece_and_a_loop_over_experts(landed, pieces):
     tokens, k, held, total = 1024, 4, 4, 32
     piece = piece_rows(tokens, k, held, total)
     chosen = _landing(tokens, k, held, total, landed)
-    ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    args = (jax.random.normal(ks[0], (tokens, 16)),
-            jax.random.uniform(ks[1], (tokens, k)), *_held_experts(held))
+    args = (*_tokens_and_weights(0, tokens, k), *_held_experts(held))
     value, stats, grads = _value_stats_grads(chosen, total, args)
     dropped, mine, overflow, largest = stats
     assert (dropped, mine) == (0.0, landed)
@@ -150,9 +168,7 @@ def test_pieces_match_one_piece_and_a_loop_over_experts(landed, pieces):
         jnp.where(chosen < held, chosen, -1), held, args)
     assert piece_rows(tokens, k, held, held) == rows_bound(tokens, k, held)
     assert tuple(stats_whole[:3]) == (0.0, landed, 0.0)
-    want, grads_want = jax.value_and_grad(
-        lambda *a: _weighed(_loop_over_experts(a[0], chosen, *a[1:])),
-        argnums=(0, 1, 2, 3, 4))(*args)
+    want, grads_want = _loop_value_and_grads(chosen, args)
     for got, one_piece, looped in zip((value, *grads), (whole, *grads_whole),
                                       (want, *grads_want)):
         scale = max(float(jnp.abs(looped).max()), 1.0)
@@ -197,14 +213,10 @@ def test_sums_back_to_the_tokens_by_landed_choices():
                           [9, 2, 30, 4],      # one
                           [3, 17, 0, 1]],     # three
                          jnp.int32)
-    ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    args = (jax.random.normal(ks[0], (3, 16)),
-            jax.random.uniform(ks[1], (3, 4)), *_held_experts(held))
+    args = (*_tokens_and_weights(0, 3, 4), *_held_experts(held))
     _, stats, (d_h, d_weights, *_) = _value_stats_grads(chosen, total, args)
     assert tuple(stats) == (0.0, 4.0, 0.0, 1.0)
-    d_h_want, d_weights_want = jax.grad(
-        lambda *a: _weighed(_loop_over_experts(a[0], chosen, *a[1:])),
-        argnums=(0, 1))(*args)
+    _, (d_h_want, d_weights_want, *_) = _loop_value_and_grads(chosen, args)
     assert not np.asarray(d_h[0]).any()
     assert not np.asarray(d_weights)[np.asarray(chosen) >= held].any()
     np.testing.assert_allclose(d_h, d_h_want, rtol=1e-5, atol=1e-4)
@@ -214,14 +226,15 @@ def test_sums_back_to_the_tokens_by_landed_choices():
 def test_moe_mlp_forward_and_grads():
     layer = MoeMlp(experts_total=8, experts_held=(0, 8), d_ff=32,
                    shared_d_ff=16, k=2, scaling=2.5)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 8))
-    params = layer.init(jax.random.PRNGKey(2), x)
+    x, = normal(1, (2, 16, 8))
+    params = jax.jit(layer.init)(jax.random.PRNGKey(2), x)
 
     def loss(params, x):
         y, counters, _ = layer.apply(params, x)
         return (y ** 2).mean(), counters
 
-    (val, counters), grads = jax.value_and_grad(loss, has_aux=True)(params, x)
+    (val, counters), grads = jax.jit(jax.value_and_grad(
+        loss, has_aux=True))(params, x)
     assert np.isfinite(float(val))
     named = dict(zip(COUNTERS, np.asarray(counters)))
     assert named["moe_dropped"] == 0.0 and named["moe_rows_per_token"] == 2.0
@@ -235,96 +248,6 @@ def test_moe_mlp_forward_and_grads():
     assert np.abs(np.asarray(grads["router"])).sum() > 0
     assert np.abs(np.asarray(grads["w_down"])).sum() > 0
     assert np.abs(np.asarray(grads["shared_down"])).sum() > 0
-
-
-def test_laguna_trains_on_ep_mesh(eight_devices):
-    """Laguna's test size, all 16 experts held, sharded over ep=4 with the
-    batch over dp=2: each shard computes its four experts' part and the
-    parts are summed — the same loss as one device gives, a finite,
-    falling loss, nothing dropped."""
-    kwargs = dict(size="test", seq_len=32, vocab=256)
-    bundle = get_model("laguna", **kwargs)
-
-    def trainer(spec):
-        return Trainer(
-            init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
-            optimizer=optax.adam(1e-3),
-            config=TrainConfig(global_batch=8, compute_dtype=jnp.float32),
-            mesh_spec=spec)
-
-    sharded = trainer(MeshSpec(dp=2, ep=4))
-    state = sharded.init_state()
-    flat = shd.flatten_dict(shd.unbox(state.params))
-    held = {k: v for k, v in flat.items() if k.endswith("moe/w_gate")}
-    assert held, list(flat)[:8]
-    for key, w in held.items():
-        assert "ep" in str(w.sharding.spec), (key, w.sharding.spec)
-        assert w.shape[1] == 16  # every expert held, four a shard
-
-    # one batch six times over: something to learn
-    batches = [next(iter(bundle.make_data(8, seed=0)))] * 6
-    losses, metrics = [], []
-    for batch in batches:
-        state, m = sharded.train_step(state, batch)
-        losses.append(float(m["loss"]))
-        metrics.append({k: float(v) for k, v in m.items()})
-    assert np.isfinite(losses).all() and losses[-1] < losses[0]
-    assert all(m["moe_dropped"] == 0.0 for m in metrics)
-    # all experts held: each of a token's 2 choices has a row somewhere
-    assert all(abs(m["moe_rows_per_token"] - 2.0) < 1e-6 for m in metrics)
-
-    one = Trainer(
-        init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
-        optimizer=optax.adam(1e-3),
-        config=TrainConfig(global_batch=8, compute_dtype=jnp.float32),
-        mesh_spec=MeshSpec(dp=8))
-    _, first = one.train_step(one.init_state(), batches[0])
-    assert float(first["loss"]) == np.float32(losses[0]) or abs(
-        float(first["loss"]) - losses[0]) < 1e-4
-
-
-def test_joyai_trains_on_ep_mesh(eight_devices):
-    """JoyAI-LLM's test size beside Laguna's, all 32 experts held, sharded
-    over ep=4 with the batch over dp=2: latent attention and the module's
-    layer under the mesh, each expert shard computing its eight experts'
-    part (the module's too) — the same loss as one device gives, a finite,
-    falling loss, nothing dropped, both heads' losses in the metrics."""
-    kwargs = dict(size="test", seq_len=32, vocab=256,
-                  layer_types=["dense", "sparse", "sparse"])
-    bundle = get_model("joyai", **kwargs)
-
-    def trainer(spec):
-        return Trainer(
-            init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
-            optimizer=optax.adam(1e-3),
-            config=TrainConfig(global_batch=8, compute_dtype=jnp.float32),
-            mesh_spec=spec)
-
-    sharded = trainer(MeshSpec(dp=2, ep=4))
-    state = sharded.init_state()
-    flat = shd.flatten_dict(shd.unbox(state.params))
-    held = {k: v for k, v in flat.items() if k.endswith("moe/w_gate")}
-    assert {"blocks_1/moe/w_gate", "mtp_block/moe/w_gate"} <= set(held)
-    for key, w in held.items():
-        assert "ep" in str(w.sharding.spec), (key, w.sharding.spec)
-        assert w.shape[-3] == 32  # every expert held, eight a shard
-
-    batches = [next(iter(bundle.make_data(8, seed=0)))] * 6
-    losses, metrics = [], []
-    for batch in batches:
-        state, m = sharded.train_step(state, batch)
-        losses.append(float(m["loss"]))
-        metrics.append({k: float(v) for k, v in m.items()})
-    assert np.isfinite(losses).all() and losses[-1] < losses[0]
-    assert all(m["moe_dropped"] == 0.0 for m in metrics)
-    # all experts held: each of a token's 4 choices has a row somewhere
-    assert all(abs(m["moe_rows_per_token"] - 4.0) < 1e-6 for m in metrics)
-    assert all(abs(m["loss"] - m["loss_main"] - 0.3 * m["loss_mtp"]) < 1e-4
-               for m in metrics)
-
-    one = trainer(MeshSpec(dp=8))
-    _, first = one.train_step(one.init_state(), batches[0])
-    assert abs(float(first["loss"]) - losses[0]) < 1e-4
 
 
 # ------------------------------------------------------ the grouped products
@@ -383,6 +306,18 @@ def _close(got, want, dtype, live=None):
                                atol=tol * max(np.abs(want).max(initial=0), 1))
 
 
+# the grouped products under jit, interpreted: module-level, so that cases of
+# equal shapes share a program (the groups' sizes are an argument)
+@functools.partial(jax.jit, static_argnums=3)
+def _rows(xs, ws, sizes, transposed):
+    return grouped_rows(xs, ws, sizes, transposed, True)
+
+
+@jax.jit
+def _weights(x, y, sizes):
+    return grouped_weights(x, y, sizes, True)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("name", list(GROUPINGS))
@@ -391,24 +326,19 @@ def test_grouped_rows_is_a_loop_over_the_groups(name, dtype):
     in the operands' dtype from a float32 sum; a pair of operands is the
     sum of both products; what rows behind the last group hold (NaN) reaches
     no live row, and the visited tiles are the ones the groups touch."""
-    from easydl_tpu.ops.moe import grouped_rows
-
     x, y, w, sizes, s = _grouped_case(name, dtype)
     live = sum(sizes)
-    out, visited = jax.jit(
-        lambda x, w, s: grouped_rows([x], [w], s, False, True))(x, w, s)
+    out, visited = _rows([x], [w], s, False)
     assert out.shape == (x.shape[0], w.shape[2]) and out.dtype == dtype
     _close(out, _groups_loop(x, w, sizes), dtype, live)
     ends = np.cumsum(sizes)
     touched = sum(-(-b // 128) - a // 128
                   for a, b in zip(ends - sizes, ends) if b > a)
     assert int(visited) == 128 * touched
-    out_t, _ = jax.jit(
-        lambda y, w, s: grouped_rows([y], [w], s, True, True))(y, w, s)
+    out_t, _ = _rows([y], [w], s, True)
     assert out_t.shape == x.shape and out_t.dtype == dtype
     _close(out_t, _groups_loop(y, w, sizes, True), dtype, live)
-    both, _ = jax.jit(lambda y, w, s: grouped_rows(
-        [y, y], [w, -0.5 * w], s, True, True))(y, w, s)
+    both, _ = _rows([y, y], [w, -0.5 * w], s, True)
     _close(both, 0.5 * _groups_loop(y, w, sizes, True), dtype, live)
 
 
@@ -418,10 +348,8 @@ def test_grouped_rows_is_a_loop_over_the_groups(name, dtype):
 def test_grouped_weights_is_a_loop_over_the_groups(name, dtype):
     """``x[rows of g]^T y[rows of g]`` for every group against the loop: an
     empty group gets zeros, NaN rows behind the last group are not summed."""
-    from easydl_tpu.ops.moe import grouped_weights
-
     x, y, _, sizes, s = _grouped_case(name, dtype)
-    got = jax.jit(lambda x, y, s: grouped_weights(x, y, s, True))(x, y, s)
+    got = _weights(x, y, s)
     assert got.shape == (len(sizes), x.shape[1], y.shape[1])
     assert got.dtype == dtype
     _close(got, _weights_loop(x, y, sizes), dtype)
@@ -504,18 +432,15 @@ def test_the_experts_rule_is_the_loops_gradient(name, tokens, k, held, total,
     the experts."""
     chosen = _routing(tokens, k, held, total, rows_of)
     landed = sum(rows_of)
-    ks = jax.random.split(jax.random.PRNGKey(3), 2)
-    args32 = (jax.random.normal(ks[0], (tokens, d)),
-              jax.random.uniform(ks[1], (tokens, k)),
-              *(0.3 * w for w in _held_experts(held, d, f)))
-    args = tuple(a.astype(dtype) if i != 1 else a
+    args32 = (*_tokens_and_weights(3, tokens, k, d),
+              *(0.3 * np.asarray(w) for w in _held_experts(held, d, f)))
+    args = tuple(jnp.asarray(np.asarray(a).astype(dtype)) if i != 1 else a
                  for i, a in enumerate(args32))
     value, stats, grads = _value_stats_grads(chosen, total, args)
     assert tuple(stats[:2]) == (0.0, landed)
     assert stats[2] == float(landed > piece_rows(tokens, k, held, total))
-    want, grads_want = jax.value_and_grad(
-        lambda *a: _weighed(_loop_over_experts(a[0], chosen, *a[1:])),
-        argnums=(0, 1, 2, 3, 4))(*(a.astype(jnp.float32) for a in args))
+    want, grads_want = _loop_value_and_grads(
+        chosen, [np.asarray(a, np.float32) for a in args])
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
     # each gradient in its argument's dtype (the choices' weights' float32)
     assert [g.dtype for g in grads] == [a.dtype for a in args]
@@ -538,17 +463,17 @@ def test_tile_fill_counts_the_tiles_the_groups_touch():
     from easydl_tpu.ops.moe import _routed_part
 
     chosen = jnp.asarray(np.repeat([0, 1], [100, 156])[:, None], jnp.int32)
-    ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    _, stats = _routed_part(jax.random.normal(ks[0], (256, 16)), chosen,
-                            jax.random.uniform(ks[1], (256, 1)),
-                            *_held_experts(2), 0, 2)
+    h, weights = _tokens_and_weights(0, 256, 1)
+    _, stats = jax.jit(_routed_part, static_argnums=(6, 7))(
+        h, chosen, weights, *_held_experts(2), 0, 2)
     assert tuple(np.asarray(stats)) == (0.0, 256.0, 0.0, 156.0, 3 * 128.0)
 
     layer = MoeMlp(experts_total=8, experts_held=(0, 8), d_ff=32,
                    shared_d_ff=16, k=2, scaling=2.5)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 8))
-    (_, counters, _), sown = layer.apply(
-        layer.init(jax.random.PRNGKey(2), x), x, mutable=["intermediates"])
+    x, = normal(1, (2, 16, 8))
+    (_, counters, _), sown = jax.jit(lambda params, x: layer.apply(
+        params, x, mutable=["intermediates"]))(
+            jax.jit(layer.init)(jax.random.PRNGKey(2), x), x)
     named = dict(zip(COUNTERS, np.asarray(counters)))
     experts = np.unique(np.asarray(sown["intermediates"]["chosen"][0])).size
     assert COUNTERS[-1] == "moe_tile_fill"

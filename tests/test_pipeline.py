@@ -32,7 +32,7 @@ def bundles(mesh, microbatches=4):
 def test_pipeline_matches_plain_loss_and_grads(eight_devices):
     mesh = build_mesh(MeshSpec(dp=2, pp=2), devices=eight_devices[:4])
     plain, piped = bundles(mesh)
-    params = plain.init_fn(jax.random.PRNGKey(0))
+    params = jax.jit(plain.init_fn)(jax.random.PRNGKey(0))
     batch = next(iter(plain.make_data(8, seed=1)))
     rng = jax.random.PRNGKey(1)
 
@@ -94,7 +94,7 @@ def test_pipeline_config_validation(eight_devices):
         pipeline_fn=make_pipeline(mesh, microbatches=2),
         pipeline_stages=3,  # does not divide n_layers=2
     )
-    params = piped.init_fn(jax.random.PRNGKey(0))
+    params = jax.jit(piped.init_fn)(jax.random.PRNGKey(0))
     batch = next(iter(piped.make_data(4)))
     with mesh, pytest.raises(ValueError, match="not divisible"):
         jax.jit(lambda p: piped.loss_fn(p, batch, jax.random.PRNGKey(0)))(
@@ -110,7 +110,7 @@ def test_pipeline_stage_mismatch_fails_loudly(eight_devices):
         pipeline_fn=make_pipeline(mesh, microbatches=2),
         pipeline_stages=1,  # != mesh pp size 2
     )
-    params = piped.init_fn(jax.random.PRNGKey(0))
+    params = jax.jit(piped.init_fn)(jax.random.PRNGKey(0))
     batch = next(iter(piped.make_data(4)))
     with mesh, pytest.raises(ValueError, match="pp size"):
         jax.jit(lambda p: piped.loss_fn(p, batch, jax.random.PRNGKey(0)))(
@@ -151,7 +151,7 @@ def test_pipeline_rejects_train_mode_dropout_loudly(eight_devices):
     )
     model = Transformer(cfg)
     tokens = jnp.zeros((4, 16), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)["params"]
     with mesh, pytest.raises(NotImplementedError, match="dropout"):
         model.apply({"params": params}, tokens, deterministic=False,
                     rngs={"dropout": jax.random.PRNGKey(1)})
@@ -177,7 +177,7 @@ def test_bubble_model_and_parity_across_microbatches(eight_devices):
 
     mesh = build_mesh(MeshSpec(dp=2, pp=2), devices=eight_devices[:4])
     plain, _ = bundles(mesh)
-    params = plain.init_fn(jax.random.PRNGKey(0))
+    params = jax.jit(plain.init_fn)(jax.random.PRNGKey(0))
     # per-dp-shard batch 8, so microbatches=8 still divides it
     batch = next(iter(plain.make_data(16, seed=3)))
     rng = jax.random.PRNGKey(1)

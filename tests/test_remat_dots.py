@@ -69,9 +69,9 @@ def test_dq_dk_dv_from_rows_of_lse_are_the_parents(case):
     k = jax.random.normal(kk, (2, s_k, heads, 64)).astype(dtype)
     v = jax.random.normal(kv, (2, s_k, heads, 64)).astype(dtype)
     w = jax.random.normal(kw, (2, s_q, heads, 64))
-    grads = jax.grad(lambda q, k, v: (flash_attention(
+    grads = jax.jit(jax.grad(lambda q, k, v: (flash_attention(
         q, k, v, causal=causal, block_q=block, block_k=block,
-        interpret=True).astype(jnp.float32) * w).sum(), argnums=(0, 1, 2))(
+        interpret=True).astype(jnp.float32) * w).sum(), argnums=(0, 1, 2)))(
             q, k, v)
     for g, (total, magnitude), name in zip(grads, want, "qkv"):
         g = np.asarray(g, np.float64)
@@ -174,12 +174,18 @@ def test_gradients_under_dots_equal_those_with_no_remat(
     base = dict(vocab=64, d_model=heads * 32, n_heads=heads,
                 n_kv_heads=kv_heads, n_layers=2, d_ff=64, max_seq=seq,
                 bias=bias, position=position, attention_impl="flash")
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, seq), 0, 64)
-    params = nn.unbox(Transformer(TransformerConfig(**base)).init(
-        jax.random.PRNGKey(0), tokens)["params"])
-    # biases that are not zero, so that where they are added shows
-    params = jax.tree.map(lambda p: p + 0.02 * jax.random.normal(
-        jax.random.PRNGKey(2), p.shape), params)
+    rng = np.random.default_rng(1)
+    tokens = jnp.asarray(rng.integers(0, 64, (2, seq), np.int32))
+    # the tree's shapes from the model, its values drawn on the host (an
+    # initialiser under jit is a third program a case): norm scales about one,
+    # everything else — the biases too, so that where they are added shows —
+    # normal at 0.02, as the model's own initialisers draw the kernels
+    shapes = nn.unbox(jax.eval_shape(
+        Transformer(TransformerConfig(**base)).init, jax.random.PRNGKey(0),
+        tokens)["params"])
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, p: ("scale" in jax.tree_util.keystr(path))
+        + 0.02 * rng.standard_normal(p.shape, np.float32), shapes)
     want = _grads(TransformerConfig(**base), params, tokens)
     got = _grads(TransformerConfig(remat=True, remat_policy="dots", **base),
                  params, tokens)
@@ -205,9 +211,9 @@ def test_a_dots_stack_says_once_what_a_layer_names(monkeypatch):
                             remat_policy="dots")
     tokens = jnp.zeros((8, 64), jnp.int32)
     model = Transformer(cfg)
-    params = model.init(jax.random.PRNGKey(0), tokens)
-    jax.grad(lambda p: model.apply(p, tokens).sum())(params)
-    model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+    jax.jit(jax.grad(lambda p: model.apply(p, tokens).sum()))(params)
+    jax.jit(model.apply)(params, tokens)
     said = [message for message in said if message.startswith("remat dots:")]
     assert len(said) == 1, said
     # q, k, v, out of [8, 64, 128] float32 (the reference attention path:

@@ -144,23 +144,25 @@ def test_a_scanned_run_under_full_runs_the_forward_kernel_once_a_layer(
     body; the backward's reads the kept ``out`` and ``lse``), where it does
     not, twice, as on the parent — and the gradients are those of the stack
     with no remat, to float32 rounding, either way."""
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 64)
+    rng = np.random.default_rng(1)
+    tokens = jnp.asarray(rng.integers(0, 64, (2, 64), np.int32))
     init, _ = model()
-    params = nn.unbox(init(jax.random.PRNGKey(0), tokens))
+    params = nn.unbox(jax.jit(init)(jax.random.PRNGKey(0), tokens))
     if "params" in params and len(params) == 1:
         params = {"params": jax.tree.map(
-            lambda p: p + 0.02 * jax.random.normal(
-                jax.random.PRNGKey(2), p.shape), params["params"])}
-    want = jax.jit(jax.grad(model()[1]))(params, tokens)
-    assert _forward_calls(jax.make_jaxpr(jax.grad(model()[1]))(
-        params, tokens).jaxpr) == 1  # no remat: nothing is made again
+            lambda p: np.asarray(p) + 0.02 * rng.standard_normal(
+                p.shape, np.float32), params["params"])}
+    # traced once a program: the trace is read, then lowered and run
+    plain = jax.jit(jax.grad(model()[1])).trace(params, tokens)
+    assert _forward_calls(plain.jaxpr.jaxpr) == 1  # no remat: made once
+    want = plain.lower().compile()(params, tokens)
     loss = model(remat=True, remat_policy="full")[1]
     left = jax.make_jaxpr(jax.grad(loss))(params, tokens).jaxpr
     assert _forward_calls(left) == 2
     flash_kept()
-    kept = jax.make_jaxpr(jax.grad(loss))(params, tokens).jaxpr
-    assert _forward_calls(kept) == 1
-    got = jax.jit(jax.grad(loss))(params, tokens)
+    kept = jax.jit(jax.grad(loss)).trace(params, tokens)
+    assert _forward_calls(kept.jaxpr.jaxpr) == 1
+    got = kept.lower().compile()(params, tokens)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
                             jax.tree.leaves(want)):
         np.testing.assert_allclose(
@@ -174,7 +176,8 @@ def test_dots_keeps_a_picked_out_beside_what_it_kept(interpreted, flash_kept):
     is kept all the same (``tests/test_remat_dots.py``)."""
     tokens = jnp.zeros((2, 64), jnp.int32)
     init, _ = _gpt()
-    params = init(jax.random.PRNGKey(0), tokens)
+    # traced, never run: the parameters' shapes are enough
+    params = jax.eval_shape(init, jax.random.PRNGKey(0), tokens)
     loss = _gpt(remat=True, remat_policy="dots")[1]
     assert _forward_calls(jax.make_jaxpr(jax.grad(loss))(
         params, tokens).jaxpr) == 2
@@ -239,7 +242,8 @@ def test_a_full_stack_says_once_what_a_layer_keeps(interpreted, flash_kept,
                         transformer.log.handlers + [handler])
     tokens = jnp.zeros((2, 64), jnp.int32)
     init, _ = _gpt()
-    params = init(jax.random.PRNGKey(0), tokens)
+    # traced, never run: the parameters' shapes are enough
+    params = jax.eval_shape(init, jax.random.PRNGKey(0), tokens)
     loss = _gpt(remat=True, remat_policy="full")[1]
     jax.make_jaxpr(jax.grad(loss))(params, tokens)
     jax.make_jaxpr(loss)(params, tokens)

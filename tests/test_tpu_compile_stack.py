@@ -1,0 +1,398 @@
+"""Stacks and whole steps compiled for a DESCRIBED v5e — no chip needed
+(the kernels alone: ``tests/test_tpu_compile.py``): GPT-2 medium's stack cut
+to two layers under remat ``dots`` / ``full`` / ``fsdp=4``, Ouro's cut to two
+layers and two passes, a two-layer scanned run of JoyAI-LLM-Flash's and
+ZAYA1's descriptions at the cells' widths, and the two medium steps' memory
+gate (``scripts/rehearse_tpu_compile.py``'s programs). Each program is
+compiled ONCE, by a module-scoped fixture or by the one test that reads it;
+no two compile the same ``(policy, mesh, model)``. The last two groups are
+whole programs at the cells' sizes that no other test reads: marked ``slow``
+since PR 40 (``pytest -m slow tests/test_tpu_compile_stack.py``, beside the
+script's memory gate, after a change to the block, the kernels' rule, the
+head or what remat keeps); the two-layer stacks stand for them in tier-1.
+"""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from easydl_tpu.core.mesh import MeshSpec, build_mesh
+
+pytestmark = pytest.mark.usefixtures("no_persistent_cache")
+
+
+def _attention_instructions(text: str) -> list:
+    """``[(pass, opcode, result type, path inside attention)]`` of every
+    top-level instruction under the ``attention`` scope of a compiled
+    program."""
+    import re
+
+    found, fused = [], False
+    for line in text.splitlines():
+        header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if header:  # a fusion's body is not a device operation of its own
+            fused = "fused" in header.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(?[^=]*?\)?) ([\w\-]+)\(", line)
+        if fused or not m or "/attention/" not in line:
+            continue
+        path = line.split('op_name="', 1)[1].split('"', 1)[0]
+        which = ("remat" if "rematted_computation" in path
+                 else "bwd" if "transpose(jvp(" in path else "fwd")
+        found.append((which, m.group(2), m.group(1),
+                      path.split("/attention/", 1)[1]))
+    return found
+
+
+def _two_layer_gpt2(devices, remat_policy: str, spec: MeshSpec = MeshSpec()):
+    """Compiled text of GPT-2 medium's stack cut to two layers (1024 wide,
+    16 heads of 64, bf16, a 1,024-row head) under ``remat_policy``: loss and
+    gradients of 8 sequences of 1,024 on one described chip, or under
+    ``spec`` the whole train step of 16 (the ``Trainer`` places the
+    parameters), one frame per location as the entry points compile."""
+    import flax.linen as nn
+    import optax
+
+    from easydl_tpu.core.train_loop import TrainConfig, Trainer
+    from easydl_tpu.models.lm import lm_bundle
+    from easydl_tpu.models.transformer import TransformerConfig
+
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    try:
+        bundle = lm_bundle(TransformerConfig(
+            vocab=1024, d_model=1024, n_heads=16, n_layers=2, d_ff=4096,
+            remat=True, remat_policy=remat_policy, attention_impl="flash",
+            dtype="bfloat16"), "gpt2-medium-two-layers")
+        if spec.size > 1:
+            trainer = Trainer(
+                init_fn=bundle.init_fn, loss_fn=bundle.loss_fn,
+                optimizer=optax.sgd(1e-3),
+                config=TrainConfig(global_batch=16, grad_accum=1),
+                mesh=build_mesh(spec, devices=devices[:spec.size]))
+            tokens = jax.ShapeDtypeStruct((16, 1024), jnp.int32)
+            return trainer.step_fn.lower(
+                trainer.abstract_state(),
+                {"inputs": tokens, "targets": tokens}).compile().as_text()
+        one = SingleDeviceSharding(devices[0])
+        params = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+            jax.eval_shape(lambda: nn.unbox(
+                bundle.init_fn(jax.random.PRNGKey(0)))))
+        tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one)
+        return jax.jit(jax.grad(
+            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+        )).lower(params, {"inputs": tokens, "targets": tokens}
+                 ).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+
+
+@pytest.fixture(scope="module")
+def two_layer_texts(v5e_2x2):
+    """``text(stack)``: compiled text of the two-layer GPT-2 stack
+    (``_two_layer_gpt2``) under remat ``dots``, under ``full``, or under
+    ``dots`` with ``fsdp=4`` on the described 2x2 (the kernels per shard,
+    inside a ``shard_map``); each compiled once, when first asked for."""
+    import functools
+
+    stacks = {"dots": ("dots", MeshSpec()), "full": ("full", MeshSpec()),
+              "dots-fsdp4": ("dots", MeshSpec(fsdp=4))}
+
+    @functools.lru_cache(maxsize=None)
+    def text(stack):
+        return _two_layer_gpt2(v5e_2x2, *stacks[stack])
+
+    return text
+
+
+@pytest.fixture(scope="module")
+def two_layer_stack(two_layer_texts):
+    """The instructions under the ``attention`` scope of GPT-2 medium's
+    stack cut to two layers (1024 wide, 16 heads of 64, remat ``dots``,
+    bf16, a 1,024-row head), loss and gradients, compiled for one described
+    chip: ``[(pass, opcode, result type, path inside attention)]`` of every
+    top-level instruction."""
+    return _attention_instructions(two_layer_texts("dots"))
+
+
+def _big(result: str) -> bool:
+    """Whether a result type holds an array as large as q: 8 x 1024 x 1024."""
+    import math
+    import re
+
+    return any(math.prod(int(x) for x in dims.split(",") if x) >= 8 * 1024 * 1024
+               for _, dims in re.findall(r"(\w+)\[([\d,]*)\]", result))
+
+
+@pytest.mark.parametrize("stack,rows", [("dots", 8), ("full", 8),
+                                        ("dots-fsdp4", 4)])
+def test_kernels_in_the_stack_take_and_give_the_models_layout(
+        two_layer_texts, stack, rows):
+    """The Mosaic calls of the whole compiled text (the layers are a scan:
+    one instruction a layer), on ``[batch, seq, heads·head_dim]`` rows: the
+    forward, its second run under ``rematted_computation`` (remat ``dots``
+    names the forward's ``out`` and does not keep it: ``ops/remat.py``), dq
+    and dkv."""
+    calls = [(which, result)
+             for which, opcode, result, path in _attention_instructions(
+                 two_layer_texts(stack)) if opcode == "custom-call"]
+    assert sorted(which for which, _ in calls) == ["bwd", "bwd", "fwd", "remat"]
+    for _, result in calls:
+        assert f"bf16[{rows},1024,1024]{{2,1,0" in result, result
+
+
+@pytest.mark.parametrize("stack,rows,kept", [
+    ("dots", 8, True), ("full", 8, False), ("dots-fsdp4", 4, True)])
+def test_dots_keeps_lse_as_rows_and_adds_no_bias_of_q_k_v_twice(
+        two_layer_texts, stack, rows, kept):
+    """What remat ``dots`` keeps by name, read in the compiled text. The
+    layers' stack of ``lse`` as dense rows, ``f32[2, batch, 16, 1024]`` (named
+    inside the differentiation rule, through the ``shard_map`` too; no
+    ``[.., 1024, 1]`` column is stacked: 64 MB of lane padding a layer). And
+    a projection's result AFTER its bias: nothing named after q, k or v's
+    product or ``add`` stands under ``rematted_computation`` (kept before
+    it, three fusions a layer added the biases again and wrote q, k, v a
+    second time). Under ``full`` nothing is kept: the projections are
+    recomputed, bias and all."""
+    text = two_layer_texts(stack)
+    assert (f"f32[2,{rows},16,1024]" in text) == kept
+    assert f"f32[2,{rows},16,1024,1]" not in text
+    again = [path for which, _, _, path in _attention_instructions(text)
+             if which == "remat" and path.split("/")[0] in ("q", "k", "v")
+             and path.endswith(("/add", "/dot_general"))]
+    assert bool(again) == (not kept), again
+
+
+@pytest.mark.parametrize("which", ["fwd", "remat", "bwd"])
+def test_no_copy_between_the_projections_and_the_kernels(two_layer_stack, which):
+    """No whole-array ``copy`` or ``transpose`` under the ``attention`` scope
+    in the forward and in the recomputation: q, k, v leave their projections
+    (matrix products, ``models/transformer._matrix_dot_general``) as
+    ``[8, 1024, 1024]`` rows, the layout the kernels take, and O enters
+    ``out`` as the kernel gave it. The backward keeps what XLA puts in
+    front of the q, k, v WEIGHT-gradient products and nothing else: the
+    weights are stored ``{1,3,2,0}`` (``[heads, kv, embed]`` physically), so
+    that product wants dq, dk, dv transposed, whoever made them."""
+    moved = [(opcode, result, path)
+             for w, opcode, result, path in two_layer_stack
+             if w == which and opcode in ("copy", "transpose") and _big(result)]
+    if which != "bwd":
+        assert not moved, moved
+        return
+    assert all(path in ("q/dot_general", "k/dot_general", "v/dot_general")
+               for _, _, path in moved), moved
+    assert len(moved) <= 6, moved
+
+
+@pytest.fixture(scope="module")
+def rehearse():
+    """``scripts/rehearse_tpu_compile.py`` as a module."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "rehearse_tpu_compile.py")
+    spec = importlib.util.spec_from_file_location("rehearse_tpu_compile", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.slow  # a whole step, 45-120 s: ``scripts/rehearse_tpu_compile.py``
+@pytest.mark.parametrize("program", ["medium_4x8", "worker_4x8"])
+def test_the_medium_steps_fit_the_chip(v5e_2x2, rehearse, program):
+    """The memory gate on what remat ``dots`` keeps: the step of
+    ``gpt2-medium.steady`` (4 x 8 x 1,024, AdamW) and the elastic worker's
+    (``optax.adam``), compiled whole for the described chip, take 15.292 GiB
+    of its 15.75 (the parent's 15.296; with the flash forward's ``out`` kept
+    too 15.668) and at most 15.35, and hold the forward kernel twice in each
+    copy of the step's body. The other cells' steps (XL's shard, the
+    hybrid's, Ouro's: 13-35 s each) are programs of the same script with
+    limits of their own: ``scripts/rehearse_tpu_compile.py``."""
+    compiled, gib = rehearse.compile_program(program, v5e_2x2)
+    assert gib <= rehearse.PROGRAMS[program][-1] == 15.35, gib
+    calls = rehearse.mosaic_calls(compiled.as_text())
+    # the step's body stands twice (the first microbatch, then the scan);
+    # the script's own gate holds the same counts
+    assert rehearse.kernel_counts(calls) \
+        == rehearse.ATTENTION_KERNELS[program] \
+        == {"flash_bwd_dkv": 2, "flash_bwd_dq": 2, "flash_fwd": 4}, calls
+
+
+@pytest.mark.parametrize("program,more,said", [
+    ("zaya_1x2", {}, None),
+    ("zaya_1x2", {"flash_fwd": 1},  # the scanned run made ``out`` again
+     "zaya_1x2: attention kernels {'flash_bwd': 1, 'flash_fwd': 2}, not "
+     "{'flash_bwd': 1, 'flash_fwd': 1}"),
+    ("joyai_1x2", {"mla_fwd": 1}, "joyai_1x2: attention kernels"),
+    ("medium_4x8", {"flash_bwd_dq": -2, "flash_bwd_dkv": -2, "flash_bwd": 2},
+     "medium_4x8: attention kernels"),
+], ids=["as-gated", "a-forward-more", "joyai-a-forward-more",
+        "another-backward"])
+def test_the_script_fails_on_other_attention_kernels_than_a_cells(
+        v5e_2x2, rehearse, monkeypatch, capsys, program, more, said):
+    """The script is where the whole steps at the cells' sizes are gated
+    (the ``slow`` tests of this file read the same programs): every cell
+    with a memory limit has its attention kernels by name, and a program
+    that holds others — a forward kernel more is a scanned run that made
+    ``out`` and ``lse`` again — makes the exit code 1 naming the cell. The
+    compile is stood in for: the gate is what is tested, in no time."""
+    import types
+
+    assert set(rehearse.ATTENTION_KERNELS) == {
+        name for name, program in rehearse.PROGRAMS.items() if program[-1]}
+    counts = dict(rehearse.ATTENTION_KERNELS[program], rows_to_tokens=6)
+    for kernel, n in more.items():
+        counts[kernel] = counts.get(kernel, 0) + n
+    text = "\n".join(
+        f'  %{kernel}.{i} = bf16[2,8192,1024]{{2,1,0}} custom-call(%x), '
+        f'custom_call_target="tpu_custom_call"'
+        for kernel, n in counts.items() for i in range(n))
+    fits = types.SimpleNamespace(argument_size_in_bytes=2**30,
+                                 temp_size_in_bytes=2**30)
+    compiled = types.SimpleNamespace(memory_analysis=lambda: fits,
+                                     as_text=lambda: text)
+    monkeypatch.setattr(rehearse, "compile_program",
+                        lambda name, devices: (compiled, 2.0))
+    monkeypatch.setattr(rehearse.sys, "argv", ["rehearse", program])
+    if said is None:
+        rehearse.main()
+    else:
+        with pytest.raises(SystemExit) as exit_:
+            rehearse.main()
+        assert said in str(exit_.value), exit_.value
+    assert f"{program}: " in capsys.readouterr().out
+
+
+@pytest.fixture(scope="module")
+def two_layer_rotary_stack(v5e_2x2):
+    """As ``two_layer_stack``, for Ouro's description cut to two layers and
+    two passes (2048 wide, 16 heads of 128, rotary, sandwich norms, remat
+    ``full``, bf16, one 4,096-token sequence, a 1,024-row head): every
+    top-level instruction under ``attention``."""
+    import flax.linen as nn
+
+    from easydl_tpu.models.ouro import make_ouro
+
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    try:
+        one = SingleDeviceSharding(v5e_2x2[0])
+        bundle = make_ouro(
+            size="2.6b", seq_len=4096, vocab=1024, dtype="bfloat16",
+            remat=True, remat_policy="full", attention_impl="flash",
+            layer_types=["full_attention"] * 2, total_ut_steps=2)
+        params = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+            jax.eval_shape(lambda: nn.unbox(
+                bundle.init_fn(jax.random.PRNGKey(0)))))
+        tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one)
+        text = jax.jit(jax.grad(
+            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+        )).lower(params, {"inputs": tokens, "targets": tokens}
+                 ).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    return _attention_instructions(text)
+
+
+@pytest.mark.parametrize("which", ["fwd", "remat"])
+def test_rotary_puts_no_copy_between_the_projections_and_the_kernels(
+        two_layer_rotary_stack, which):
+    """q and k go from their projections through the rotary kernel to the
+    flash kernels as ``[1, 4096, 2048]`` rows: in the forward and in the
+    recomputation no whole-array ``copy`` or ``transpose`` stands under
+    ``attention`` (as XLA operations on half-head slices the rotation
+    brought four float32 copies a layer back: PERF.md section 6, PR 29),
+    and the Mosaic calls there are the flash forward and two rotations."""
+    import math
+    import re
+
+    def big(result):
+        return any(math.prod(int(x) for x in dims.split(",") if x)
+                   >= 4096 * 2048
+                   for _, dims in re.findall(r"(\w+)\[([\d,]*)\]", result))
+
+    mine = [(opcode, result, path)
+            for w, opcode, result, path in two_layer_rotary_stack
+            if w == which]
+    moved = [x for x in mine if x[0] in ("copy", "transpose") and big(x[1])]
+    assert not moved, moved
+    kernels = sorted(path.split("/")[-2] for opcode, _, path in mine
+                     if opcode == "custom-call")
+    assert kernels == ["flash_fwd", "rope_fwd", "rope_fwd"], kernels
+    for opcode, result, path in mine:
+        if opcode == "custom-call":
+            assert "bf16[1,4096,2048]{2,1,0" in result, result
+
+
+# ------------------------------------------------- what remat full keeps
+def _scanned_run(devices, factory: str, **description) -> list:
+    """``_attention_instructions`` of loss and gradients of a two-layer
+    scanned run of ``factory``'s description at the cell's widths (2 x
+    8,192 tokens, bf16, remat ``full``, a 1,024-row head; the expert layer's
+    kernels compiled as on the chip: the caller asks ``described_tpu``), for
+    one described chip."""
+    import flax.linen as nn
+
+    from easydl_tpu.models.registry import get_model
+
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    try:
+        one = SingleDeviceSharding(devices[0])
+        bundle = get_model(
+            factory, seq_len=8192, vocab=1024, dtype="bfloat16", remat=True,
+            remat_policy="full", attention_impl="flash", **description)
+        params = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+            jax.eval_shape(lambda: nn.unbox(
+                bundle.init_fn(jax.random.PRNGKey(0)))))
+        tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one)
+        text = jax.jit(jax.grad(
+            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+        )).lower(params, {"inputs": tokens, "targets": tokens}
+                 ).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    return _attention_instructions(text)
+
+
+@pytest.mark.parametrize("factory,description,forward,rows,reader", [
+    ("joyai", dict(size="llm-flash", layer_types=["sparse"] * 2, mtp=False,
+                   experts_held=(0, 16)), "mla_fwd", 4096, "mla_out"),
+    ("zaya", dict(size="8b", layer_types=["hybrid"] * 2,
+                  experts_held=(0, 8)), "flash_fwd", 1024, "cca_up"),
+], ids=["joyai-llm-flash", "zaya1-8b"])
+@pytest.mark.slow  # a run at the cell's widths, 60-70 s each: with the script
+def test_full_keeps_the_flash_forwards_results_of_a_dear_call(
+        v5e_2x2, described_tpu, factory, description, forward, rows, reader):
+    """A scanned run at the cell's shape under remat ``full``
+    (``ops/remat.py``'s rule picks the call: 10,084 and 8,067 FLOP a byte):
+    the forward kernel stands once, in the forward pass, and not again under
+    ``rematted_computation``; the ONE backward call (three results: the
+    looped side) reads the kept ``out`` and ``lse``, and no ``*_bwd_dq``
+    stands beside it.
+    And nothing moves the kept ``out`` between the layers' stack and the
+    projection that reads it (``mla_out``, ``cca_up``): no ``copy`` or
+    ``transpose`` of an ``out``-sized array in the recomputation (PR 30: XLA
+    wrote such a slice twice and transposed it)."""
+    found = _scanned_run(v5e_2x2, factory, **description)
+    back = forward.replace("fwd", "bwd")
+    calls = sorted((which, path.split("/")[-2], result.count("bf16[2,8192,"))
+                   for which, opcode, result, path in found
+                   if opcode == "custom-call"
+                   and path.split("/")[-2].startswith((forward, back)))
+    assert calls == [("bwd", back, 3), ("fwd", forward, 1)], calls
+    moved = [(opcode, result, path) for which, opcode, result, path in found
+             if which == "remat" and opcode in ("copy", "transpose")
+             and f"[2,8192,{rows}]" in result]
+    assert not [m for m in moved if reader in m[2]
+                or "multihead_attention" in m[2]], moved

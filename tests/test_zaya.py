@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import normal
 
 from easydl_tpu.core import sharding as shd
 from easydl_tpu.core.mesh import MeshSpec, build_mesh
@@ -86,7 +87,7 @@ def test_program_against_reference_zaya(float32_check, what, limit):
 
 def test_every_gradient_leaf_was_compared(float32_check):
     kwargs = _config()["kwargs"]
-    params = shd.unbox(get_model("zaya", **kwargs).init_fn(
+    params = shd.unbox(jax.jit(get_model("zaya", **kwargs).init_fn)(
         jax.random.PRNGKey(0)))
     plain = check_module.to_reference(params)
     # nothing is left out of the map; per layer: 2 norms, 9 of the attention,
@@ -103,34 +104,49 @@ def test_every_gradient_leaf_was_compared(float32_check):
 
 
 # -------------------------------------------------------------- one layer
+def _held(held):
+    """The float32 test-size description of a share holding ``held``."""
+    return describe(size="test", seq_len=SEQ, vocab=256,
+                    layer_types=["hybrid"] * 3, experts_held=held)
+
+
 def _layer_setup(held=(0, 16), seed=0):
     """A float32 test-size description holding ``held``, one layer's seeded
     parameters (all 16 experts'), a state and a router state."""
-    cfg = describe(size="test", seq_len=SEQ, vocab=256,
-                   layer_types=["hybrid"] * 3, experts_held=held)
+    cfg = _held(held)
     whole = describe(size="test", seq_len=SEQ, vocab=256,
                      layer_types=["hybrid"] * 3)
-    kx, kr, kp = jax.random.split(jax.random.PRNGKey(seed), 3)
-    x = jax.random.normal(kx, (2, SEQ, whole.d_model))
-    r = 0.3 * jax.random.normal(kr, (2, SEQ, whole.router_state_width))
+    x, r = normal(seed, (2, SEQ, whole.d_model),
+                  (2, SEQ, whole.router_state_width))
+    r = 0.3 * r
     scheme = whole.attention_kind("hybrid").rope
     rope = transformer.rope_tables(SEQ, whole.head_dim, scheme.theta,
                                    scheme.rotary_dim)
-    params = shd.unbox(transformer.Block(whole, "hybrid", "moe").init(
-        kp, (x, r), True, rope))["params"]
+    params = shd.unbox(jax.jit(lambda key, x, r: transformer.Block(
+        whole, "hybrid", "moe").init(key, (x, r), True, rope))(
+            jax.random.split(jax.random.PRNGKey(seed), 3)[2], x, r))["params"]
     # seeded weights everywhere a fault could hide behind a one or a zero
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+    rng = np.random.default_rng(seed + 1)
 
     def stir(path, leaf):
         name = jax.tree_util.keystr(path)
         if any(s in name for s in ("_res_", "temperature", "gamma", "bias",
                                    "b1", "b2", "norm")):
-            return leaf + 0.2 * jax.random.normal(next(keys), leaf.shape)
+            return np.asarray(leaf) + 0.2 * rng.standard_normal(
+                leaf.shape, np.float32)
         # logits of a size at which every one of the 17 choices is taken
-        return leaf * 30 if "router_w" in name else leaf
+        return np.asarray(leaf) * 30 if "router_w" in name else leaf
 
     params = jax.tree_util.tree_map_with_path(stir, params)
     return cfg, params, x, r, rope
+
+
+def _layer(cfg, rope):
+    """``Block(cfg).apply`` on a layer's parameters, a state and a router
+    state, its intermediates kept: one jitted program a description."""
+    return jax.jit(lambda params, x, r: transformer.Block(
+        cfg, "hybrid", "moe").apply({"params": params}, (x, r), True, rope,
+                                    mutable=["intermediates"]))
 
 
 def _share_of(params, lo, hi):
@@ -142,8 +158,12 @@ def _share_of(params, lo, hi):
 def _reference_layer(params, x, r, held=(0, 16)):
     hp = {"eps": 1e-5, "experts_held": held,
           "rope": {"rope_theta": 5000000.0, "partial_rotary_factor": 0.5}}
-    with jax.default_matmul_precision("highest"):
-        return ref.layer(x, r, check_module.layer_to_reference(params), hp), hp
+    @jax.jit
+    def layer(x, r, p_r):
+        with jax.default_matmul_precision("highest"):
+            return ref.layer(x, r, p_r, hp)
+
+    return layer(x, r, check_module.layer_to_reference(params)), hp
 
 
 def test_the_shares_add_up_to_the_uncut_reference_layer():
@@ -154,17 +174,18 @@ def test_the_shares_add_up_to_the_uncut_reference_layer():
     every share's alike."""
     _, params, x, r, rope = _layer_setup()
     (want, r_want, _, own), hp = _reference_layer(params, x, r)
-    with jax.default_matmul_precision("highest"):
-        p_r = check_module.layer_to_reference(params)
-        alike = ref.merge(ref.attention_residual(x, p_r, hp),
-                          jnp.zeros_like(x), p_r["res_m"])
+    @jax.jit
+    def alike_of(x, p_r):
+        with jax.default_matmul_precision("highest"):
+            return ref.merge(ref.attention_residual(x, p_r, hp),
+                             jnp.zeros_like(x), p_r["res_m"])
+
+    alike = alike_of(x, check_module.layer_to_reference(params))
     parts, rows, skipped = [], 0.0, []
     for lo in (0, 8):
-        cfg = _layer_setup((lo, lo + 8))[0]
-        ((y, state), counters), _ = transformer.Block(
-            cfg, "hybrid", "moe").apply(
-                {"params": _share_of(params, lo, lo + 8)}, (x, r), True, rope,
-                mutable=["intermediates"])
+        cfg = _held((lo, lo + 8))
+        ((y, state), counters), _ = _layer(cfg, rope)(
+            _share_of(params, lo, lo + 8), x, r)
         named = dict(zip(cfg.counters, np.asarray(counters)))
         assert named["moe_dropped"] == 0.0
         rows += named["moe_rows_per_token"]
@@ -182,9 +203,7 @@ def test_top1_with_the_skip_choice_drops_nothing():
     """One share on its own: ``moe_dropped`` 0, and skipped + landed here +
     landed elsewhere is every token, by the layer's own choices."""
     cfg, params, x, r, rope = _layer_setup((0, 8), seed=3)
-    ((_, _), counters), kept = transformer.Block(cfg, "hybrid", "moe").apply(
-        {"params": _share_of(params, 0, 8)}, (x, r), True, rope,
-        mutable=["intermediates"])
+    ((_, _), counters), kept = _layer(cfg, rope)(_share_of(params, 0, 8), x, r)
     named = dict(zip(cfg.counters, np.asarray(counters)))
     chosen = np.asarray(kept["intermediates"]["moe"]["chosen"][0])[:, 0]
     logits = np.asarray(kept["intermediates"]["moe"]["router_logits"][0])
@@ -205,16 +224,15 @@ def test_the_router_weight_is_the_probability_and_gets_a_gradient():
     before its norm; the router's leaves get a gradient through the
     weight."""
     tokens, d, r, c = 32, 16, 8, 5
-    keys = jax.random.split(jax.random.PRNGKey(0), 8)
-    w = {"down": jax.random.normal(keys[0], (d, r)),
-         "down_bias": jax.random.normal(keys[1], (r,)),
+    down, down_bias, w1, w2, w3, h, state = normal(
+        0, (d, r), (r,), (r, r), (r, r), (r, c), (tokens, d), (tokens, r))
+    w = {"down": down, "down_bias": down_bias,
          "gamma": jnp.full((r,), 0.5), "norm": jnp.ones((r,)),
-         "w1": jax.random.normal(keys[2], (r, r)), "b1": jnp.zeros((r,)),
-         "w2": jax.random.normal(keys[3], (r, r)), "b2": jnp.zeros((r,)),
-         "w3": jax.random.normal(keys[4], (r, c))}
-    h = jax.random.normal(keys[5], (tokens, d)).astype(jnp.bfloat16)
-    state = jax.random.normal(keys[6], (tokens, r))
-    out, logits, chosen, weights = moe.route_mlp(h, state, w, 1e-5)
+         "w1": w1, "b1": jnp.zeros((r,)), "w2": w2, "b2": jnp.zeros((r,)),
+         "w3": w3}
+    h = jnp.asarray(np.asarray(h).astype(jnp.bfloat16))
+    out, logits, chosen, weights = jax.jit(
+        lambda h, state, w: moe.route_mlp(h, state, w, 1e-5))(h, state, w)
     assert logits.dtype == weights.dtype == out.dtype == jnp.float32
     np.testing.assert_allclose(
         out, h.astype(jnp.float32) @ w["down"] + w["down_bias"] + 0.5 * state,
@@ -223,7 +241,8 @@ def test_the_router_weight_is_the_probability_and_gets_a_gradient():
     np.testing.assert_array_equal(chosen[:, 0], jnp.argmax(probs, -1))
     np.testing.assert_allclose(weights[:, 0], jnp.max(probs, -1), rtol=1e-6)
     assert float(weights.min()) < 0.6  # not renormalised to 1
-    grads = jax.grad(lambda w: moe.route_mlp(h, state, w, 1e-5)[3].sum())(w)
+    grads = jax.jit(jax.grad(
+        lambda w: moe.route_mlp(h, state, w, 1e-5)[3].sum()))(w)
     assert all(float(jnp.abs(g).sum()) > 0 for g in grads.values())
 
 
@@ -237,19 +256,23 @@ def test_the_scan_with_the_router_state_equals_a_loop_over_the_layers():
     cfg = describe(**kwargs)
     model = transformer.Transformer(cfg)
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, SEQ)))
-    params = shd.unbox(model.init(jax.random.PRNGKey(1), tokens))["params"]
-    hidden, sown = model.apply({"params": params}, tokens,
-                               return_hidden=True, mutable=["counters"])
+    params = shd.unbox(jax.jit(model.init)(
+        jax.random.PRNGKey(1), tokens))["params"]
+    hidden, sown = jax.jit(lambda params, tokens: model.apply(
+        {"params": params}, tokens, return_hidden=True,
+        mutable=["counters"]))(params, tokens)
     scheme = cfg.attention_kind("hybrid").rope
     rope = transformer.rope_tables(SEQ, cfg.head_dim, scheme.theta,
                                    scheme.rotary_dim)
     carry = (params["tok_emb"]["embedding"][tokens],
              jnp.zeros((2, SEQ, cfg.router_state_width)))
     summed = 0.0
+    # one program for the three layers: the same Block on a layer's slice
+    layer = jax.jit(lambda one, carry: transformer.Block(
+        cfg, "hybrid", "moe").apply({"params": one}, carry, True, rope))
     for j in range(3):
         one = jax.tree.map(lambda a: a[j], params["blocks"])
-        carry, counters = transformer.Block(cfg, "hybrid", "moe").apply(
-            {"params": one}, carry, True, rope)
+        carry, counters = layer(one, carry)
         summed = summed + counters
     x, state = carry
     gain = params["ln_f"]["scale"]
@@ -270,14 +293,14 @@ def test_nothing_reads_a_later_token():
                   layer_types=["hybrid"] * 2, experts_held=(0, 16))
     model = transformer.Transformer(describe(**kwargs))
     tokens = np.random.default_rng(1).integers(0, 256, (1, SEQ))
-    params = model.init(jax.random.PRNGKey(2), jnp.asarray(tokens))
+    params = jax.jit(model.init)(jax.random.PRNGKey(2), jnp.asarray(tokens))
+    hidden = jax.jit(lambda tokens: model.apply(params, tokens,
+                                                return_hidden=True))
     t = 17
     other = tokens.copy()
     other[0, t] = (other[0, t] + 1) % 256
-    a = np.asarray(model.apply(params, jnp.asarray(tokens),
-                               return_hidden=True))
-    b = np.asarray(model.apply(params, jnp.asarray(other),
-                               return_hidden=True))
+    a = np.asarray(hidden(jnp.asarray(tokens)))
+    b = np.asarray(hidden(jnp.asarray(other)))
     np.testing.assert_array_equal(a[:, :t], b[:, :t])
     assert np.abs(a[:, t] - b[:, t]).max() > 0
     assert np.abs(a[:, t + 1] - b[:, t + 1]).max() > 0
@@ -291,13 +314,16 @@ def test_the_latent_mix_against_the_written_out_shifts():
     p_r = check_module.layer_to_reference(params)
     cfg = describe(size="test", seq_len=SEQ, vocab=256,
                    layer_types=["hybrid"])
-    _, kept = transformer.Block(cfg, "hybrid", "moe").apply(
-        {"params": params}, (x, r), True, rope, mutable=["intermediates"])
+    _, kept = _layer(cfg, rope)(params, x, r)
     kept = kept["intermediates"]
     h = kept["latent_in"][0]
-    with jax.default_matmul_precision("highest"):
-        q, k = ref.mixed_qk(h, p_r)
-        v = ref.values(h, p_r["wv"])
+
+    @jax.jit
+    def written_out(h, p_r):
+        with jax.default_matmul_precision("highest"):
+            return (*ref.mixed_qk(h, p_r), ref.values(h, p_r["wv"]))
+
+    q, k, v = written_out(h, p_r)
     for mine, want in ((kept["latent_q"][0], q), (kept["latent_k"][0], k),
                        (kept["latent_v"][0], v)):
         np.testing.assert_allclose(mine, want, rtol=2e-5, atol=2e-5)
