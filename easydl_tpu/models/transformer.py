@@ -6,8 +6,10 @@ looped Ouro (rotary positions, a norm before AND after every sub-layer,
 the whole stack applied ``loops`` times over the same parameters, an exit
 gate on each pass's normed state) are three descriptions of it
 (``TransformerConfig``). Per layer the description names
-a mixer (``attention`` | ``mamba2``) and an FFN (``gelu`` | ``swiglu``);
-consecutive layers of one kind are one ``nn.scan``.
+a mixer (``attention`` | ``mamba2`` | an attention kind) and an FFN (``gelu``
+| ``swiglu`` | ``moe`` | ``none``: NemotronH's layers are a mixer OR an
+expert layer alone, and a mixer that another mixer follows is a layer
+without an FFN); consecutive layers of one kind are one ``nn.scan``.
 
 TPU-first choices:
 - every parameter carries logical axis names (``embed``/``heads``/``kv``/
@@ -172,10 +174,12 @@ def _norm(cfg, name, dtype=None):
 
 #: one layer of the description: (mixer, ffn). A mixer is ``attention``
 #: (the description's own heads, no window, its ``position``), ``mamba2``, or
-#: the name of one of the description's ``attention_kinds``.
+#: the name of one of the description's ``attention_kinds``. The FFN
+#: ``none`` is a layer that is its mixer alone (NemotronH's ``M`` or ``*``
+#: followed by another mixer): no second norm, no second add.
 Layer = Tuple[str, str]
 MIXERS = ("attention", "mamba2")
-FFNS = ("gelu", "swiglu", "moe")
+FFNS = ("gelu", "swiglu", "moe", "none")
 
 
 @dataclass(frozen=True)
@@ -263,7 +267,10 @@ class AttentionKind:
 class MoeConfig:
     """Widths of the ``moe`` FFNs (``ops/moe.py``): the published count of
     routed experts ``experts_total``, the contiguous range of them held
-    here, experts a token, an expert's and the shared expert's inner widths,
+    here, experts a token, an expert's and the shared expert's inner widths
+    and their form (``ops/moe.py EXPERT_FORMS``: a ``swiglu`` expert is
+    three matrices, ``3 * d * d_ff`` parameters; an ungated ``relu2`` expert
+    two, ``2 * d * d_ff``),
     the scale on the renormalised router weights; the router's form
     (``ops/moe.py ROUTERS``) and, of the MLP form, its width — also the
     width of the router state the layers hand on through the scan's carry —
@@ -281,6 +288,16 @@ class MoeConfig:
     #: the linear router selects by its scores plus a per-expert bias that
     #: takes no gradient and weighs by the scores without it (``noaux_tc``)
     selection_bias: bool = False
+    expert_form: str = moe_ops.EXPERT_FORMS[0]
+    #: the experts' and the shared expert's down maps start with zero column
+    #: sums (``ops/moe.py MoeMlp.down_zero_sums``: NemotronH's description,
+    #: whose ``relu2`` hidden units are all positive)
+    down_zero_sums: bool = False
+
+    @property
+    def matrices(self) -> int:
+        """Matrices an expert (routed or shared) is made of."""
+        return 3 if self.expert_form == moe_ops.EXPERT_FORMS[0] else 2
 
     @property
     def choices(self) -> int:
@@ -313,7 +330,19 @@ class MtpConfig:
 @dataclass(frozen=True)
 class SsmConfig:
     """Widths of the Mamba-2 mixer (ops/ssd.py); the inner width is
-    ``n_heads * head_dim``."""
+    ``n_heads * head_dim``. ``n_groups`` are B's and C's (a group's heads
+    share them); ``grouped_norm``: the gated norm behind the scan takes its
+    mean square over each of those groups' channels apart (Mamba-2's and
+    NemotronH's ``group_size = inner / n_groups``), not over all the inner
+    channels at once (GraniteMoeHybrid's, whatever its ``n_groups``). Of the
+    descriptions here only the hybrid's ``test`` preset needs the word, two
+    groups under an ungrouped norm: granite-4.0-h-micro has one group, where
+    both norms are the same program. ``conv_bias_zero``: the convolutions'
+    biases start at zero and not at torch's Conv1d default, uniform in ``+-1
+    / sqrt(d_conv)``, where GraniteMoeHybrid's code leaves them — constants
+    in front of a SiLU are a token-independent part of the mixer's output,
+    which a router behind it turns into a load that follows the seed
+    (``MoeConfig.down_zero_sums``)."""
 
     n_heads: int = 64
     head_dim: int = 64
@@ -321,6 +350,8 @@ class SsmConfig:
     n_groups: int = 1
     d_conv: int = 4
     chunk: int = 256
+    grouped_norm: bool = False
+    conv_bias_zero: bool = False
 
 
 @dataclass(frozen=True)
@@ -523,7 +554,10 @@ class TransformerConfig:
         estimate always has. ``active``: of a ``moe`` layer's routed
         experts only what a token meets on average, ``k * held / choices`` of
         them (all ``k`` where every expert is held and none is skipped) —
-        what the matrix products of a step are counted from."""
+        what the matrix products of a step are counted from. An expert is
+        ``3 * d * d_ff`` (SwiGLU) or ``2 * d * d_ff`` (ungated relu2:
+        ``MoeConfig.matrices``); the FFN ``none`` counts nothing, and one
+        norm less."""
         mixer, ffn = layer
         d = self.d_model
         if mixer != "mamba2" and self.attention_kind(mixer).lowrank:
@@ -565,14 +599,17 @@ class TransformerConfig:
                 (d + 1) * r + 2 * r          # down and bias, gain, norm
                 + 2 * (r + 1) * r + r * m.choices)
             n += (router + (m.experts_total if m.selection_bias else 0)
-                  + 3 * d * m.shared_d_ff + round(routed * 3 * d * m.d_ff))
-        else:
+                  + m.matrices * d * m.shared_d_ff
+                  + round(routed * m.matrices * d * m.d_ff))
+        elif ffn != "none":
             n += 2 * d * self.d_ff
+        subs = 1 if ffn == "none" else 2            # sub-layers: norms, adds
         if self.norm_placement == "sandwich":
-            n += 2 * d                              # the two output norms
+            n += subs * d                           # the output norms
         if self.residual_scale:
-            n += 8 * d
-        return n + (4 * d if self.bias else 2 * d)  # biases-ish + 2 norms
+            n += 4 * subs * d
+        # biases-ish + the sub-layers' norms
+        return n + (2 * subs * d if self.bias else subs * d)
 
     @property
     def param_count(self) -> int:
@@ -885,7 +922,8 @@ def _mamba2(block, u):
         w = block.param(f"conv_{name}", nn.with_logical_partitioning(
             init, (None,) + axes), (m.d_conv,) + a.shape[2:])
         b = block.param(f"conv_{name}_bias", nn.with_logical_partitioning(
-            init, axes), a.shape[2:])
+            nn.initializers.zeros_init() if m.conv_bias_zero else init,
+            axes), a.shape[2:])
         return nn.silu(causal_conv1d(a, w, b))
 
     with jax.named_scope("conv1d"):
@@ -915,9 +953,17 @@ def _mamba2(block, u):
     with jax.named_scope("ssd"):
         y = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), B, C,
                      skip, chunk=m.chunk)
-    gain = block.param("norm_gated", nn.with_logical_partitioning(
-        nn.initializers.ones_init(), kv), (m.n_heads, m.head_dim))
-    y = gated_rmsnorm(y, z, gain, cfg.norm_eps).astype(dt_)
+    with jax.named_scope("gated_norm"):
+        gain = block.param("norm_gated", nn.with_logical_partitioning(
+            nn.initializers.ones_init(), kv), (m.n_heads, m.head_dim))
+        normed = gated_rmsnorm(y, z, gain, cfg.norm_eps,
+                               m.n_groups if m.grouped_norm else 1).astype(dt_)
+    # what the mixer's parts were given and gave, where `intermediates` is a
+    # mutable collection (the benchmark's check, tests)
+    for name, value in (("in", u), ("z", z), ("x", x), ("B", B), ("C", C),
+                        ("dt", dt), ("y", y), ("normed", normed)):
+        block.sow("intermediates", f"ssm_{name}", value)
+    y = normed
     return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
                        ("embed",), "out", residual=True, axis=(-2, -1))(y)
 
@@ -941,7 +987,8 @@ def _ffn(block, h, state=None):
             out_init_scale=(2 * cfg.n_layers) ** -0.5, dtype=cfg.dtype,
             router=m.router, router_hidden=m.router_hidden,
             router_eps=cfg.norm_eps, skip_choice=m.skip_choice,
-            selection_bias=m.selection_bias, name="moe",
+            selection_bias=m.selection_bias, expert_form=m.expert_form,
+            down_zero_sums=m.down_zero_sums, name="moe",
         )(h, state)
         return y, aux, state if routed is None else routed
     else:
@@ -953,9 +1000,10 @@ def _ffn(block, h, state=None):
 
 
 class Block(nn.Module):
-    """One layer of the stack: a mixer and an FFN, each from its norm to
-    the residual add (under ``norm_placement="sandwich"`` each sub-layer's
-    output is normed once more in front of the add).
+    """One layer of the stack: a mixer and an FFN — or, with the FFN
+    ``none``, the mixer alone — each from its norm to the residual add
+    (under ``norm_placement="sandwich"`` each sub-layer's output is normed
+    once more in front of the add).
 
     ``rope`` is ``None`` or the rotary tables ``(cos, sin)`` of
     :func:`easydl_tpu.ops.rope.rope_tables`, made once for all layers.
@@ -1021,11 +1069,15 @@ class Block(nn.Module):
                     x = residual(x, _mamba2(
                         self, _norm(cfg, "ln_ssm", dtype=dt)(x)), "ln_ssm")
             # `ffn` is the dense FFN's scope; an expert layer is `moe`, with
-            # the scopes of ops/moe.py inside it
-            with jax.named_scope("moe" if self.ffn == "moe" else "ffn"):
-                h, aux, state = _ffn(self, _norm(cfg, "ln_mlp", dtype=dt)(x),
-                                     state)
-                x = residual(x, h, "ln_mlp")
+            # the scopes of ops/moe.py inside it; a layer that is its mixer
+            # alone has neither, nor a second norm
+            if self.ffn == "none":
+                aux = jnp.zeros((), jnp.float32)
+            else:
+                with jax.named_scope("moe" if self.ffn == "moe" else "ffn"):
+                    h, aux, state = _ffn(
+                        self, _norm(cfg, "ln_mlp", dtype=dt)(x), state)
+                    x = residual(x, h, "ln_mlp")
         if cfg.remat and not self.is_initializing():
             saved = remat.KEPT[cfg.remat_policy]
             kept = [value for value in named if value.label in saved]

@@ -3,13 +3,15 @@
 A fine-grained mixture-of-experts FFN as DeepSeek-V3-class models have it
 (Laguna's ``sparse`` layers): a router over ALL ``experts_total`` experts in
 float32 (sigmoid scores, the ``k`` largest, their scores renormalised and
-scaled), SwiGLU experts, and a shared expert every token passes through.
+scaled), experts of a described form (:data:`EXPERT_FORMS`: SwiGLU, three
+matrices, or NemotronH's ungated ``relu(h W_up)^2 W_down``, two), and a
+shared expert of the same form that every token passes through.
 The layer holds a contiguous range ``experts_held = [lo, hi)`` of the routed
 experts — all of them, or one chip's share of an expert-parallel group — and
 computes ITS experts' part of the result: the choices that fall in the range
-sorted by expert, their tokens' rows gathered, three grouped matrix products
-over the experts held, the weighted sum back per token; plus the shared
-expert, once. What absent experts would have added is left out; no code
+sorted by expert, their tokens' rows gathered, three (or two) grouped matrix
+products over the experts held, the weighted sum back per token; plus the
+shared expert, once. What absent experts would have added is left out; no code
 stands in for them or for their traffic.
 
 The grouped products are Pallas kernels of this module (:func:`grouped_rows`
@@ -101,6 +103,9 @@ from jax.sharding import PartitionSpec as P
 
 from easydl_tpu.core.mesh_shapes import BATCH_AXES
 from easydl_tpu.ops import platform
+from easydl_tpu.utils.logging import get_logger, log_once
+
+log = get_logger("ops", "moe")
 
 #: mesh axis the held experts are sharded over (core/sharding.py rules)
 EXPERT_AXIS = "ep"
@@ -111,6 +116,12 @@ COUNTERS = ("moe_dropped", "moe_rows_per_token", "moe_load_max_over_mean",
             "moe_tile_fill")
 #: the router's forms
 ROUTERS = ("linear-sigmoid-renormalised", "mlp-softmax-top1")
+#: an expert's forms, the shared expert's too: ``swiglu`` — three matrices,
+#: ``(silu(h W_gate) * (h W_up)) W_down`` — and ``relu2`` — two, ungated,
+#: ``relu(h W_up)^2 W_down`` (NemotronH's ``mlp_hidden_act``). The routed
+#: experts' leaves travel as a tuple in that order, ``(w_gate, w_up,
+#: w_down)`` or ``(w_up, w_down)``, and its length says the form
+EXPERT_FORMS = ("swiglu", "relu2")
 #: the MLP router's three maps start orthogonal (the last with orthonormal
 #: columns), the first at this scale and the others at that: every choice's
 #: logit is then the same isotropic map of the normed state, and its hidden
@@ -122,6 +133,16 @@ ROUTERS = ("linear-sigmoid-renormalised", "mlp-softmax-top1")
 #: share and the rows that land here 0.39-0.50 a token by the seed alone
 #: (PERF.md section 6, PR 35)
 MLP_ROUTER_INIT = (0.05, 1.0)
+
+
+def _zero_column_sums(init):
+    """``init``'s values less their mean over the map's INPUT axis (the
+    second to last): every output's weights then sum to zero
+    (``MoeMlp.down_zero_sums``)."""
+    def centred(key, shape, dtype=jnp.float32):
+        w = init(key, shape, dtype)
+        return w - jnp.mean(w, axis=-2, keepdims=True)
+    return centred
 
 
 def counters(skip_choice: bool = False) -> Tuple[str, ...]:
@@ -251,12 +272,21 @@ def choose_tiles(rows: int, groups: int, contract: int, cols: int,
     (``rows`` is a piece: :data:`PIECE_OVER_EXPECTED` times what lands): a
     group at an arbitrary offset touches ``expected / tile + 1`` tiles, so
     small tiles waste the fewest rows at its edges and large ones the
-    fewest steps. ZAYA1's 2048 x 2048 over 964 rows a group gets (128,
-    2048), Laguna's 2048 x 512 and 512 x 2048 over 512 rows (256, whole) —
-    the fastest of the nine measured for each form on the chip (PERF.md
-    section 6, PR 36)."""
+    fewest steps. Columns that do not all fit are split into as few equal
+    blocks of whole lane tiles as do. ZAYA1's 2048 x 2048 over 964 rows a
+    group gets (128, 2048), Laguna's 2048 x 512 and 512 x 2048 over 512 rows
+    (256, whole) — the fastest of the nine measured for each form on the
+    chip (PERF.md section 6, PR 36); Nemotron 3 Nano's 2688 x 1856 over 768
+    rows (128, 1024) and back (128, 1408)."""
     most = max(WEIGHT_BLOCK_BYTES // (contract * itemsize), TILE)
-    tn = cols if cols <= most else most // TILE * TILE
+    if cols <= most:
+        tn = cols
+    else:
+        # as few column blocks as fit, of one size in whole lane tiles:
+        # NemotronH's 2688 x 1856 (9.98 MB a block, 14.5 lane tiles) goes in
+        # two of 1,024 (the second holds 832), not 1,536 and a ragged 320
+        blocks = -(-cols // (most // TILE * TILE))
+        tn = -(-cols // (blocks * TILE)) * TILE
     expected = rows // (PIECE_OVER_EXPECTED * groups)
     tm = TILE
     while 2 * tm <= min(ROW_TILE, expected // 2) \
@@ -536,11 +566,11 @@ def _over_pieces(one, n):
 # compiler's (it inlines them): the first piece and the loop's, and every
 # program of a process with these shapes, share one trace of each.
 @functools.partial(jax.jit, static_argnums=(0,))
-def _piece_forward(how, p, h, weights, w_gate, w_up, w_down, order, by_token,
-                   ends, rowed):
+def _piece_forward(how, p, h, weights, experts, order, by_token, ends, rowed):
     """``(y [T, D] float32, live rows, rows of the row tiles visited)`` of
-    piece ``p``: its tokens' rows, the SwiGLU experts' results (three
-    grouped products), their weighted sum back into the tokens."""
+    piece ``p``: its tokens' rows, the experts' results (SwiGLU: three
+    grouped products; relu2: two), their weighted sum back into the
+    tokens."""
     piece, interpret = how
     tokens, k = weights.shape
     choice, live, sizes, token_order = _piece(p, piece, order, by_token, ends,
@@ -548,28 +578,42 @@ def _piece_forward(how, p, h, weights, w_gate, w_up, w_down, order, by_token,
     with jax.named_scope("dispatch"):
         x = h[choice // k]
     with jax.named_scope("experts"):
-        gate, visited = grouped_rows([x], [w_gate], sizes, False, interpret)
-        up, _ = grouped_rows([x], [w_up], sizes, False, interpret)
-        out, _ = grouped_rows([nn.silu(gate) * up], [w_down], sizes, False,
-                              interpret)
+        *inward, w_down = experts
+        pre = [grouped_rows([x], [w], sizes, False, interpret)
+               for w in inward]
+        visited = pre[0][1]
+        out, _ = grouped_rows([_activation(*(a for a, _ in pre))], [w_down],
+                              sizes, False, interpret)
     with jax.named_scope("combine"):
         y = _to_tokens(out, weights.reshape(-1)[token_order[1]], token_order,
                        tokens, k, interpret)
     return y, jnp.sum(live, dtype=jnp.int32), visited
 
 
+def _activation(*pre):
+    """What an expert's down product takes, from its inward products in the
+    compute dtype: ``silu(gate) * up`` of a SwiGLU's pair, ``relu(up)^2`` of
+    an ungated one."""
+    if len(pre) == 2:
+        gate, up = pre
+        return nn.silu(gate) * up
+    return jnp.square(nn.relu(pre[0]))
+
+
 @functools.partial(jax.jit, static_argnums=(0,))
-def _piece_backward(how, p, g, h, weights, w_gate, w_up, w_down, order,
-                    by_token, ends, rowed):
+def _piece_backward(how, p, g, h, weights, experts, order, by_token, ends,
+                    rowed):
     """Piece ``p``'s part of the gradients by ``h`` (float32), ``weights``
-    (flat) and the three expert weights under the cotangent ``g [T, D]``,
-    the experts' rule written out: the gate and up products are made again,
-    the down product is not — with ``u = g_rows W_down^T`` a row's result
-    times its token's cotangent is ``<act, u>`` (the choice's weight
-    gradient), ``scale * u`` the activation's cotangent (the scale applied
-    in float32, after the product), and ``(scale * act)^T g_rows`` the down
-    weights' gradient. Eight grouped products: two again, ``u``, the two
-    that give the rows' gradient summed in one call, three weight
+    (flat) and the expert weights (a tuple as ``experts``) under the
+    cotangent ``g [T, D]``, the experts' rule written out: the inward
+    products are made again, the down product is not — with ``u = g_rows
+    W_down^T`` a row's result times its token's cotangent is ``<act, u>``
+    (the choice's weight gradient), ``scale * u`` the activation's cotangent
+    (the scale applied in float32, after the product), and ``(scale * act)^T
+    g_rows`` the down weights' gradient. SwiGLU, eight grouped products: two
+    again, ``u``, the two that give the rows' gradient summed in one call,
+    three weight gradients. relu2, five: ``up`` again, ``u``, ``d_up_rows =
+    scale * u * 2 relu(up)`` and ONE product back to the rows, two weight
     gradients."""
     piece, interpret = how
     tokens, k = weights.shape
@@ -583,21 +627,24 @@ def _piece_backward(how, p, g, h, weights, w_gate, w_up, w_down, order,
         scale = jnp.where(live, weights.reshape(-1)[choice], 0.0)[:, None]
         g_rows = g[tok]
     with jax.named_scope("experts"):
-        gate, _ = grouped_rows([x], [w_gate], sizes, False, interpret)
-        up, _ = grouped_rows([x], [w_up], sizes, False, interpret)
+        *inward, w_down = experts
+        pre = [grouped_rows([x], [w], sizes, False, interpret)[0]
+               for w in inward]
         u = grouped_rows([g_rows], [w_down], sizes, True,
                          interpret)[0].astype(f32)
-        act = (nn.silu(gate) * up).astype(f32)  # rounded as the forward's
-        gate, up = gate.astype(f32), up.astype(f32)
-        sig = jax.nn.sigmoid(gate)
-        d_act = scale * u
-        d_gate_rows = (d_act * up * sig * (1 + gate * (1 - sig))).astype(
-            x.dtype)
-        d_up_rows = (d_act * gate * sig).astype(x.dtype)
-        d_x, _ = grouped_rows([d_gate_rows, d_up_rows], [w_gate, w_up], sizes,
-                              True, interpret)
-        d_gate = grouped_weights(x, d_gate_rows, sizes, interpret)
-        d_up = grouped_weights(x, d_up_rows, sizes, interpret)
+        act = _activation(*pre).astype(f32)  # rounded as the forward's
+        pre = [a.astype(f32) for a in pre]
+        if len(pre) == 2:
+            gate, up = pre
+            sig = jax.nn.sigmoid(gate)
+            d_act = scale * u
+            d_pre = [(d_act * up * sig * (1 + gate * (1 - sig))).astype(
+                         x.dtype),
+                     (d_act * gate * sig).astype(x.dtype)]
+        else:
+            d_pre = [(scale * u * 2 * nn.relu(pre[0])).astype(x.dtype)]
+        d_x, _ = grouped_rows(d_pre, inward, sizes, True, interpret)
+        d_inward = [grouped_weights(x, d, sizes, interpret) for d in d_pre]
         d_down = grouped_weights((scale * act).astype(x.dtype), g_rows, sizes,
                                  interpret)
     with jax.named_scope("combine"):
@@ -610,20 +657,20 @@ def _piece_backward(how, p, g, h, weights, w_gate, w_up, w_down, order,
     with jax.named_scope("dispatch"):
         d_h = _to_tokens(d_x, jnp.ones_like(scale[:, 0]), token_order, tokens,
                          k, interpret)
-    return d_h, d_weights, d_gate, d_up, d_down
+    return d_h, d_weights, (*d_inward, d_down)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed(how, h, weights, w_gate, w_up, w_down, order, by_token, ends,
-            rowed):
+def _routed(how, h, weights, experts, order, by_token, ends, rowed):
     """``(y [T, D], rows [] int32, tile rows [] int32)``: the experts'
     results for the first ``rowed`` rows of the sort, weighted and summed
     into their tokens in float32, a piece at a time (``how = (piece,
-    interpret)``); the rows that were visited; and the rows of the row tiles
-    the grouped products' schedules visited for them. The differentiation
-    rule is written out: reverse mode cannot differentiate a loop whose
-    length is traced, nor a Pallas call."""
-    args = (h, weights, w_gate, w_up, w_down, order, by_token, ends, rowed)
+    interpret)``; ``experts`` the held experts' leaves, a tuple by
+    :data:`EXPERT_FORMS`); the rows that were visited; and the rows of the
+    row tiles the grouped products' schedules visited for them. The
+    differentiation rule is written out: reverse mode cannot differentiate a
+    loop whose length is traced, nor a Pallas call."""
+    args = (h, weights, experts, order, by_token, ends, rowed)
     y, rows, visited = _over_pieces(lambda p: _piece_forward(how, p, *args),
                                     -(-rowed // how[0]))
     return y.astype(h.dtype), rows, visited
@@ -635,22 +682,23 @@ def _routed_fwd(how, *args):
 
 def _routed_bwd(how, args, cts):
     g = cts[0]  # [T, D]; the visited rows and tiles are integers
-    d_h, d_weights, *d_experts = _over_pieces(
+    d_h, d_weights, d_experts = _over_pieces(
         lambda p: _piece_backward(how, p, g, *args), -(-args[-1] // how[0]))
     h, weights = args[:2]
-    return (d_h.astype(h.dtype), d_weights.reshape(weights.shape), *d_experts,
+    return (d_h.astype(h.dtype), d_weights.reshape(weights.shape), d_experts,
             None, None, None, None)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-def _routed_part(h, chosen, weights, w_gate, w_up, w_down, lo, total):
-    """:func:`routed_experts` with a fifth number: the rows of the row tiles
+def _routed_part(h, chosen, weights, experts, lo, total):
+    """:func:`routed_experts` on the experts' leaves as a tuple
+    (:data:`EXPERT_FORMS`), with a fifth number: the rows of the row tiles
     the grouped products visited (the landed rows over them is the layer's
     ``moe_tile_fill``)."""
     tokens, k = chosen.shape
-    held = w_gate.shape[0]
+    held = experts[0].shape[0]
     bound = rows_bound(tokens, k, held)
     piece = piece_rows(tokens, k, held, total)
     with jax.named_scope("dispatch"):
@@ -674,8 +722,7 @@ def _routed_part(h, chosen, weights, w_gate, w_up, w_down, lo, total):
                        jnp.iinfo(jnp.int32).max), row % piece), num_keys=1)
         by_token = (at, number % (tokens * k))
     y, rows, visited = _routed((piece, not platform.on_tpu()), h, weights,
-                               w_gate, w_up, w_down, order, by_token, ends,
-                               rowed)
+                               tuple(experts), order, by_token, ends, rowed)
     stats = jnp.stack([landed - rows, landed, jnp.int32(landed > piece),
                        jnp.max(sizes), visited]).astype(jnp.float32)
     return y, stats
@@ -683,18 +730,20 @@ def _routed_part(h, chosen, weights, w_gate, w_up, w_down, lo, total):
 
 def routed_experts(h, chosen, weights, w_gate, w_up, w_down, lo, total):
     """The part of the routed result the experts ``[lo, lo + E)`` of
-    ``total`` give, ``E = w_gate.shape[0]``: ``(y [T, D], stats [4])`` with
+    ``total`` give, ``E = w_up.shape[0]``: ``(y [T, D], stats [4])`` with
     ``stats`` = (choices in the range that got no row, choices in the
     range, whether they needed more than one piece, the largest expert's
-    rows), float32. ``lo`` may be traced (a shard's own under ``ep``)."""
-    y, stats = _routed_part(h, chosen, weights, w_gate, w_up, w_down, lo,
-                            total)
+    rows), float32. ``w_gate`` None: ungated ``relu2`` experts
+    (:data:`EXPERT_FORMS`). ``lo`` may be traced (a shard's own under
+    ``ep``)."""
+    experts = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    y, stats = _routed_part(h, chosen, weights, experts, lo, total)
     return y, stats[:4]
 
 
 def _over_expert_shards(fn, tokens: int, held: int):
-    """``fn(h, chosen, weights, w_gate, w_up, w_down, lo)`` per ``ep`` shard
-    of the expert weights under the context mesh, the parts summed over
+    """``fn(h, chosen, weights, experts, lo)`` per ``ep`` shard of the expert
+    weights (a tuple of leaves) under the context mesh, the parts summed over
     ``ep`` (and the stats with them: sums summed, the shards' calls that
     needed more than one piece as their share, the largest group the largest
     anywhere, the visited tiles' rows summed); ``fn`` itself where the mesh has no ``ep`` axis larger than
@@ -716,9 +765,9 @@ def _over_expert_shards(fn, tokens: int, held: int):
     batch_shards = math.prod(mesh.shape[a] for a in batch)
     every = (EXPERT_AXIS,) + batch
 
-    def shard(h, chosen, weights, w_gate, w_up, w_down, lo):
+    def shard(h, chosen, weights, experts, lo):
         lo = lo + jax.lax.axis_index(EXPERT_AXIS) * (held // shards)
-        y, stats = fn(h, chosen, weights, w_gate, w_up, w_down, lo)
+        y, stats = fn(h, chosen, weights, experts, lo)
         # the largest group is of ONE batch shard's tokens: times the batch
         # shards, so that it stands against the summed rows' mean
         return jax.lax.psum(y, EXPERT_AXIS), jnp.concatenate([
@@ -729,7 +778,7 @@ def _over_expert_shards(fn, tokens: int, held: int):
 
     rows, experts = P(batch or None), P(EXPERT_AXIS)
     return jax.shard_map(
-        shard, in_specs=(rows, rows, rows, experts, experts, experts, P()),
+        shard, in_specs=(rows, rows, rows, experts, P()),
         out_specs=(rows, P()), check_vma=False)
 
 
@@ -778,6 +827,17 @@ class MoeMlp(nn.Module):
     #: ``router_bias [experts_total]`` at zero that takes no gradient (its
     #: load-driven update is a training recipe's and is not here)
     selection_bias: bool = False
+    #: one of :data:`EXPERT_FORMS`, the routed experts' and the shared
+    #: expert's: ``relu2`` has no ``w_gate`` / ``shared_gate`` leaf
+    expert_form: str = EXPERT_FORMS[0]
+    #: the down maps (routed and shared) start with zero column sums. A
+    #: positive activation's mean (``relu2``'s every hidden unit) is then no
+    #: vector that all tokens add to the stream; through a plain normal map
+    #: it is, the next routers' logits take a per-expert offset from it,
+    #: top-k turns the offsets into loads that follow the seed, and the
+    #: rows on a chip's share of the experts with them (PERF.md section 6,
+    #: PR 42: a step 448-471 ms by the seed without, 450-451 with)
+    down_zero_sums: bool = False
 
     def _route_mlp(self, h, state):
         """The MLP router's parameters and :func:`route_mlp` on them."""
@@ -809,6 +869,9 @@ class MoeMlp(nn.Module):
                              f"{self.experts_total}")
         if self.router not in ROUTERS:
             raise ValueError(f"router {self.router!r} is none of {ROUTERS}")
+        if self.expert_form not in EXPERT_FORMS:
+            raise ValueError(f"expert form {self.expert_form!r} is none of "
+                             f"{EXPERT_FORMS}")
         mlp = self.router == ROUTERS[1]
         if mlp and (self.k != 1 or self.selection_bias) \
                 or self.skip_choice and not mlp:
@@ -819,12 +882,14 @@ class MoeMlp(nn.Module):
         choices = self.experts_total + int(self.skip_choice)
         dt = jnp.dtype(self.dtype)
         h = x.astype(dt).reshape(tokens, d)
+        gated = self.expert_form == EXPERT_FORMS[0]
 
-        def weight(name, shape, axes, scale=1.0):
+        def weight(name, shape, axes, scale=1.0, centred=False):
+            init = nn.initializers.normal(stddev=0.02 * scale)
+            if centred:
+                init = _zero_column_sums(init)
             return jnp.asarray(self.param(
-                name, nn.with_logical_partitioning(
-                    nn.initializers.normal(stddev=0.02 * scale), axes),
-                shape), dt)
+                name, nn.with_logical_partitioning(init, axes), shape), dt)
 
         with jax.named_scope("router"):
             if mlp:
@@ -856,23 +921,37 @@ class MoeMlp(nn.Module):
                 share * jnp.log(jnp.maximum(share, 1e-30)), -1))
 
         inward, outward = ("expert", "embed", "mlp"), ("expert", "mlp", "embed")
-        w_gate = weight("w_gate", (held, d, self.d_ff), inward)
-        w_up = weight("w_up", (held, d, self.d_ff), inward)
-        w_down = weight("w_down", (held, self.d_ff, d), outward,
-                        self.out_init_scale)
+        experts = tuple(
+            weight(name, (held, d, self.d_ff), inward)
+            for name in (("w_gate", "w_up") if gated else ("w_up",))
+        ) + (weight("w_down", (held, self.d_ff, d), outward,
+                    self.out_init_scale, self.down_zero_sums),)
+        piece = piece_rows(tokens, self.k, held, choices)
+        log_once(log, f"moe: {self.expert_form} experts ({len(experts)} "
+                      f"matrices each), {held} of {self.experts_total} held, "
+                      f"pieces of {piece} rows; (row, column) tiles of the "
+                      f"grouped products at {d} x {self.d_ff}: "
+                      f"{choose_tiles(piece, held, d, self.d_ff, dt.itemsize)}"
+                      f" inward, "
+                      f"{choose_tiles(piece, held, self.d_ff, d, dt.itemsize)}"
+                      f" back")
         y, stats = _over_expert_shards(
             functools.partial(_routed_part, total=choices),
-            tokens, held)(h, chosen, weights, w_gate, w_up, w_down,
-                          jnp.int32(lo))
+            tokens, held)(h, chosen, weights, experts, jnp.int32(lo))
         if self.shared_d_ff:
             with jax.named_scope("shared_expert"):
-                gate = weight("shared_gate", (d, self.shared_d_ff),
-                              ("embed", "mlp"))
-                up = weight("shared_up", (d, self.shared_d_ff),
-                            ("embed", "mlp"))
+                *gate, up = (weight(name, (d, self.shared_d_ff),
+                                    ("embed", "mlp"))
+                             for name in (("shared_gate", "shared_up")
+                                          if gated else ("shared_up",)))
                 down = weight("shared_down", (self.shared_d_ff, d),
-                              ("mlp", "embed"), self.out_init_scale)
-                y = y + (nn.silu(h @ gate) * (h @ up)) @ down
+                              ("mlp", "embed"), self.out_init_scale,
+                              self.down_zero_sums)
+                if gated:
+                    hidden = nn.silu(h @ gate[0]) * (h @ up)
+                else:
+                    hidden = _activation(h @ up)
+                y = y + hidden @ down
         dropped, n_mine, overflow, largest, visited = stats
         counted = [
             dropped,

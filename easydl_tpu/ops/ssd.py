@@ -38,10 +38,12 @@ log = get_logger("ops", "ssd")
 def ssd_flops_per_token(n_heads: int, head_dim: int, d_state: int,
                         n_groups: int, chunk: int) -> float:
     """Forward FLOPs of :func:`ssd_scan` a token: per chunk of ``Q`` the
-    ``C B^T`` scores (``2 Q^2 N`` a group), the intra-chunk product
-    (``2 Q^2 P`` a head), the state the chunk leaves and the part it
-    inherits (``2 Q P N`` a head each); the causal half of the two ``Q^2``
-    products is counted in full, as the attention convention has it."""
+    ``C B^T`` scores (``2 Q^2 N`` a GROUP: the heads of a group share them,
+    so eight groups pay eight times one group's and 64 heads still pay them
+    eight times, not 64), the intra-chunk product (``2 Q^2 P`` a head), the
+    state the chunk leaves and the part it inherits (``2 Q P N`` a head
+    each); the causal half of the two ``Q^2`` products is counted in full,
+    as the attention convention has it."""
     return (2.0 * chunk * d_state * n_groups
             + 2.0 * chunk * head_dim * n_heads
             + 4.0 * head_dim * d_state * n_heads)
@@ -67,14 +69,28 @@ def causal_conv1d(x: jax.Array, weight: jax.Array, bias=None) -> jax.Array:
 
 
 def gated_rmsnorm(y: jax.Array, z: jax.Array, weight: jax.Array,
-                  eps: float) -> jax.Array:
-    """``RMSNorm(y * silu(z)) * weight`` over every axis of ``weight`` (the
-    mixer's inner channels, one group), statistics in float32; returns
-    ``y``'s dtype."""
+                  eps: float, groups: int = 1) -> jax.Array:
+    """``RMSNorm(y * silu(z)) * weight``, the gate applied BEFORE the norm,
+    statistics in float32; returns ``y``'s dtype. ``weight``'s axes are the
+    mixer's inner channels (``[heads, head_dim]``); the mean square is taken
+    over all of them (``groups`` 1: GraniteMoeHybrid's gated norm) or, the
+    first of them split into ``groups``, over each group's channels apart
+    (Mamba-2's and NemotronH's ``group_size = inner / n_groups``: 512 of
+    4,096 at eight groups)."""
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    axes = tuple(range(g.ndim - weight.ndim, g.ndim))
-    var = jnp.mean(jnp.square(g), axis=axes, keepdims=True)
-    out = g * lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+    lead = g.ndim - weight.ndim
+    if groups == 1:
+        var = jnp.mean(jnp.square(g), axis=tuple(range(lead, g.ndim)),
+                       keepdims=True)
+        normed = g * lax.rsqrt(var + eps)
+    else:
+        if weight.shape[0] % groups:
+            raise ValueError(f"{weight.shape[0]} heads do not divide into "
+                             f"{groups} norm groups")
+        by_group = g.reshape(g.shape[:lead] + (groups, -1))
+        var = jnp.mean(jnp.square(by_group), axis=-1, keepdims=True)
+        normed = (by_group * lax.rsqrt(var + eps)).reshape(g.shape)
+    out = normed * weight.astype(jnp.float32)
     return out.astype(y.dtype)
 
 
@@ -104,7 +120,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     n_chunks = -(-seq // q)
     pad = n_chunks * q - seq
     cd = x.dtype
-    log_once(log, f"ssd: chunked scan in jax.numpy, chunk {q}, matmul "
+    log_once(log, f"ssd: chunked scan in jax.numpy, {n_chunks} chunks of {q} "
+                  f"a sequence, {heads} heads in {groups} B/C groups, matmul "
                   f"operands {cd.name}, decay and state float32, "
                   f"differentiated by jax (body rematerialised)")
 

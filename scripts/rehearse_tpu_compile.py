@@ -50,6 +50,9 @@ ZAYA = ("zaya", dict(
 JOYAI = ("joyai", dict(
     size="llm-flash", seq_len=8192, vocab=16160, remat_policy="full",
     layer_types=["dense"] + ["sparse"] * 4, experts_held=(0, 16), **_CHIP))
+NEMOTRON = ("nemotron_h", dict(
+    size="nano-30b-a3b", seq_len=8192, vocab=16384, remat_policy="full",
+    hybrid_override_pattern="MEMEM*EME", experts_held=(0, 8), **_CHIP))
 
 #: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
@@ -88,6 +91,13 @@ PROGRAMS = {
     "laguna_1x2": (LAGUNA, "dp=1", 2, 1, "adamw", 15.05),
     "zaya_1x2": (ZAYA, "dp=1", 2, 1, "adamw", 14.85),
     "joyai_1x2": (JOYAI, "dp=1", 2, 1, "adamw", 14.55),
+    # PR 42: 16.155 by this script's sum, MORE than the chip's 15.75, and it
+    # runs: memory_analysis' arguments + temporaries over-count (the
+    # compiler's own buffer assignment allocates 14.91 GiB for this step,
+    # 14.26 live at the peak; for `one` the sum prints 12.64 where 10.44 are
+    # allocated: docs/operations.md). The limit is the sum and a little, as
+    # the others'
+    "nemotron_1x2": (NEMOTRON, "dp=1", 2, 1, "adamw", 16.3),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -109,6 +119,7 @@ ATTENTION_KERNELS = {
                    "swa_bwd_dq": 1, "swa_fwd": 2},
     "zaya_1x2": {"flash_bwd": 1, "flash_fwd": 1},
     "joyai_1x2": {"mla_bwd": 3, "mla_fwd": 3},
+    "nemotron_1x2": {"flash_bwd": 1, "flash_fwd": 1},
 }
 
 

@@ -333,11 +333,77 @@ def test_gated_rmsnorm_normalises_the_gated_product_over_all_channels():
         want, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("groups", [2, 4])
+def test_gated_rmsnorm_by_groups_is_a_reshape_and_a_norm(groups):
+    """Mamba-2's and NemotronH's gated norm: the mean square over each
+    group's channels apart (4 heads of 6 in 2 groups of 12, in 4 of 6), the
+    gate first, one gain over all channels."""
+    r = np.random.default_rng(groups)
+    y, z = r.normal(size=(2, 2, 5, 4, 6)).astype(np.float32)
+    w = r.normal(size=(4, 6)).astype(np.float32)
+    g = (y * (z / (1 + np.exp(-z)))).reshape(2, 5, groups, -1)
+    want = (g / np.sqrt((g ** 2).mean(axis=-1, keepdims=True) + 1e-5)
+            ).reshape(y.shape) * w
+    got = jax.jit(gated_rmsnorm, static_argnums=(3, 4))(y, z, w, 1e-5, groups)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # and no group's statistics reach another's channels
+    y2 = y.copy()
+    y2[..., 0, :] *= 7.0
+    other = jax.jit(gated_rmsnorm, static_argnums=(3, 4))(y2, z, w, 1e-5,
+                                                           groups)
+    np.testing.assert_array_equal(np.asarray(other)[..., 4 // groups:, :],
+                                  np.asarray(got)[..., 4 // groups:, :])
+    with pytest.raises(ValueError, match="norm groups"):
+        gated_rmsnorm(y, z, w, 1e-5, 3)
+
+
+def test_gated_rmsnorm_at_one_group_is_todays_program_bit_for_bit():
+    """``groups=1`` (the hybrid's: GraniteMoeHybrid's gated norm has no
+    groups whatever ``n_groups``) traces to the expression the function was
+    before it took groups — the same jaxpr — and so gives the same bits."""
+    r = np.random.default_rng(1)
+    y, z = r.normal(size=(2, 2, 5, 3, 4)).astype(np.float32)
+    w = r.normal(size=(3, 4)).astype(np.float32)
+
+    def before(y, z, weight, eps):
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        axes = tuple(range(g.ndim - weight.ndim, g.ndim))
+        var = jnp.mean(jnp.square(g), axis=axes, keepdims=True)
+        out = g * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+        return out.astype(y.dtype)
+
+    now = jax.make_jaxpr(lambda y, z, w: gated_rmsnorm(y, z, w, 1e-5))(y, z, w)
+    then = jax.make_jaxpr(lambda y, z, w: before(y, z, w, 1e-5))(y, z, w)
+    assert str(now) == str(then)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda y, z, w: gated_rmsnorm(y, z, w, 1e-5, 1))(
+            y, z, w)),
+        np.asarray(jax.jit(lambda y, z, w: before(y, z, w, 1e-5))(y, z, w)))
+
+
+def test_the_hybrids_gated_norm_has_no_groups_at_two_bc_groups():
+    """The hybrid's test description has two B/C groups and its gated norm
+    still takes ONE mean square over all inner channels (``grouped_norm``
+    off: the published GraniteMoeHybrid code); NemotronH's description turns
+    it on."""
+    from easydl_tpu.models.nemotron_h import describe as nemotron
+
+    hybrid = describe(**TEST)
+    assert (hybrid.ssm.n_groups, hybrid.ssm.grouped_norm,
+            hybrid.ssm.conv_bias_zero) == (2, False, False)
+    mine = nemotron(size="test").ssm
+    assert (mine.grouped_norm, mine.conv_bias_zero) == (True, True)
+
+
 def test_scan_flop_count_by_hand():
     # chunk 256, 64 heads of 64, one group of 128: 2*256*128 + 2*256*64*64
     # + 4*64*128*64
     assert ssd_flops_per_token(64, 64, 128, 1, 256) \
         == 65_536 + 2_097_152 + 2_097_152
+    # eight groups, chunk 128 (Nemotron 3 Nano): the scores once a GROUP —
+    # 2*128*128*8 + 2*128*64*64 + 4*64*128*64
+    assert ssd_flops_per_token(64, 64, 128, 8, 128) \
+        == 262_144 + 1_048_576 + 2_097_152
 
 
 # ------------------------------------------------- grouped-query attention

@@ -388,6 +388,11 @@ def test_grouped_products_in_blocks_of_columns(monkeypatch):
     (32768, 32, 512, 2048, 2, (256, 2048)),
     (131072, 256, 2048, 512, 2, (128, 512)),   # all 256 held: 256 rows each
     (65536, 8, 512, 512, 2, (512, 512)),       # small experts, many rows
+    # Nemotron 3 Nano's: 12,288 rows over 8 experts, 2688 x 1856 and back —
+    # 14.5 lane tiles, a block of 9.98 MB: two equal blocks of whole lane
+    # tiles (the second holds 832 and 1,280), not 1,536 + 320 and 2,176 + 512
+    (12288, 8, 2688, 1856, 2, (128, 1024)),
+    (12288, 8, 1856, 2688, 2, (128, 1408)),
     # the tier-1 sizes: tiles of 128 rows or the rows themselves
     (1024, 4, 16, 8, 4, (128, 8)),
     (384, 4, 16, 24, 4, (128, 24)),
@@ -464,8 +469,8 @@ def test_tile_fill_counts_the_tiles_the_groups_touch():
 
     chosen = jnp.asarray(np.repeat([0, 1], [100, 156])[:, None], jnp.int32)
     h, weights = _tokens_and_weights(0, 256, 1)
-    _, stats = jax.jit(_routed_part, static_argnums=(6, 7))(
-        h, chosen, weights, *_held_experts(2), 0, 2)
+    _, stats = jax.jit(_routed_part, static_argnums=(4, 5))(
+        h, chosen, weights, tuple(_held_experts(2)), 0, 2)
     assert tuple(np.asarray(stats)) == (0.0, 256.0, 0.0, 156.0, 3 * 128.0)
 
     layer = MoeMlp(experts_total=8, experts_held=(0, 8), d_ff=32,
@@ -478,3 +483,121 @@ def test_tile_fill_counts_the_tiles_the_groups_touch():
     experts = np.unique(np.asarray(sown["intermediates"]["chosen"][0])).size
     assert COUNTERS[-1] == "moe_tile_fill"
     assert named["moe_tile_fill"] == np.float32(64 / (64 * experts))
+
+
+# ------------------------------------------------ ungated relu2 experts
+def _relu2_loop(h, chosen, weights, w_up, w_down):
+    """The routed result of ungated experts ``relu(h W_up)^2 W_down`` as a
+    loop over the experts held, every token through every expert."""
+    y = jnp.zeros_like(h)
+    for e in range(w_up.shape[0]):
+        weight = jnp.where(chosen == e, weights, 0.0).sum(-1)
+        y = y + weight[:, None] * (
+            jnp.square(jax.nn.relu(h @ w_up[e])) @ w_down[e])
+    return y
+
+
+@jax.jit
+def _relu2_loop_value_and_grads(chosen, args):
+    return jax.value_and_grad(
+        lambda *a: _weighed(_relu2_loop(a[0], chosen, *a[1:])),
+        argnums=(0, 1, 2, 3))(*args)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _relu2_routed_value_and_grads(chosen, total, args):
+    def loss(h, weights, w_up, w_down):
+        y, stats = routed_experts(h, chosen, weights, None, w_up, w_down, 0,
+                                  total)
+        return _weighed(y), stats
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True)(*args)
+
+
+@pytest.mark.parametrize("name, rows_of, dtype", [
+    # 256 tokens, two choices over 4 of 16 experts of a RAGGED width (24: no
+    # multiple of 16, as Nemotron 3 Nano's 1,856 is none of 128): pieces of
+    # 256 of a bound of 512
+    ("ragged", [100, 3, 0, 90], jnp.float32),
+    ("ragged_second_piece", [0, 200, 56, 0], jnp.float32),
+    ("ragged_bf16", [100, 3, 0, 90], jnp.bfloat16),
+    ("every_row_on_one_expert", [0, 0, 256, 0], jnp.float32),
+    ("nothing_lands", [0, 0, 0, 0], jnp.float32),
+])
+def test_the_relu2_rule_is_the_loops_gradient(name, rows_of, dtype):
+    """The value and all four gradients of ungated experts through the
+    hand-written rule — the up product made again, ``d_u = d_act * 2
+    relu(u)``, ONE product back to the rows — against reverse mode through a
+    float32 loop over the experts; no choice without a row."""
+    tokens, k, held, total, d, f = 256, 2, 4, 16, 64, 24
+    chosen = _routing(tokens, k, held, total, rows_of)
+    landed = sum(rows_of)
+    _, w_up, w_down = _held_experts(held, d, f)
+    args32 = (*_tokens_and_weights(5, tokens, k, d),
+              0.3 * np.asarray(w_up), 0.3 * np.asarray(w_down))
+    args = tuple(jnp.asarray(np.asarray(a).astype(dtype)) if i != 1 else a
+                 for i, a in enumerate(args32))
+    (value, stats), grads = _relu2_routed_value_and_grads(chosen, total, args)
+    stats = np.asarray(stats)
+    assert tuple(stats[:2]) == (0.0, landed)          # dropped, landed
+    assert stats[2] == float(landed > piece_rows(tokens, k, held, total))
+    want, grads_want = _relu2_loop_value_and_grads(
+        chosen, [np.asarray(a, np.float32) for a in args])
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
+    assert [g.dtype for g in grads] == [a.dtype for a in args]
+    for got, looped in zip((value, *grads), (want, *grads_want)):
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(
+            got, looped, rtol=0,
+            atol=tol * max(float(jnp.abs(looped).max()), 1.0))
+
+
+def test_a_relu2_layer_has_two_leaves_an_expert():
+    """``expert_form="relu2"``: ``w_up`` and ``w_down`` of the routed
+    experts, ``shared_up`` and ``shared_down`` of the shared one, no gate
+    leaf; the layer's result is the loop's plus the shared expert's, nothing
+    dropped; an unknown form is refused."""
+    layer = MoeMlp(experts_total=8, experts_held=(0, 8), d_ff=24,
+                   shared_d_ff=40, k=2, scaling=2.5, selection_bias=True,
+                   expert_form="relu2", down_zero_sums=True)
+    x, = normal(1, (2, 16, 8))
+    params = jax.jit(layer.init)(jax.random.PRNGKey(2), x)
+    leaves = params["params"]
+    assert sorted(leaves) == ["router", "router_bias", "shared_down",
+                              "shared_up", "w_down", "w_up"]
+    # ``down_zero_sums``: the down maps start with zero column sums (a
+    # positive activation's mean then adds nothing token-independent), the
+    # up maps do not; without the word the down maps are plain normal
+    plain = shd.unbox(leaves)
+    for name in ("w_down", "shared_down"):
+        sums = np.abs(np.asarray(plain[name]).sum(-2))
+        assert sums.max() < 1e-6, name
+        assert np.asarray(plain[name]).std() == pytest.approx(0.02, rel=0.2)
+    assert np.abs(np.asarray(plain["w_up"]).sum(-2)).max() > 1e-3
+    normal_down = shd.unbox(jax.jit(layer.clone(down_zero_sums=False).init)(
+        jax.random.PRNGKey(2), x)["params"])["w_down"]
+    assert np.abs(np.asarray(normal_down).sum(-2)).max() > 1e-3
+    (y, counters, _), sown = jax.jit(lambda params, x: layer.apply(
+        params, x, mutable=["intermediates"]))(params, x)
+    named = dict(zip(COUNTERS, np.asarray(counters)))
+    assert named["moe_dropped"] == 0.0 and named["moe_rows_per_token"] == 2.0
+
+    @jax.jit
+    def by_hand(p, x, chosen, logits):
+        h = x.reshape(-1, x.shape[-1])
+        scores = jnp.take_along_axis(jax.nn.sigmoid(logits), chosen, -1)
+        weights = 2.5 * scores / scores.sum(-1, keepdims=True)
+        shared = jnp.square(jax.nn.relu(h @ p["shared_up"])) \
+            @ p["shared_down"]
+        return shared + _relu2_loop(h, chosen, weights, p["w_up"],
+                                    p["w_down"])
+
+    kept = sown["intermediates"]
+    want = by_hand(shd.unbox(leaves), x, kept["chosen"][0],
+                   kept["router_logits"][0])
+    np.testing.assert_allclose(np.asarray(y).reshape(want.shape),
+                               np.asarray(want), atol=1e-5)
+    with pytest.raises(ValueError, match="expert form"):
+        MoeMlp(experts_total=8, experts_held=(0, 8), d_ff=24, shared_d_ff=0,
+               k=2, expert_form="geglu").init(jax.random.PRNGKey(0), x)

@@ -394,3 +394,39 @@ def test_the_expert_layers_kernels_compile_at_the_cells_size(v5e_2x2,
     assert all(c.startswith(("bf16[32768,", "bf16[32,")) for c in products)
     assert compiled.as_text().count("rows_to_tokens/pallas_call") >= 4
     assert "131072" not in "".join(calls)
+
+
+def test_ungated_experts_of_a_ragged_width_compile_at_the_cells_size(
+        v5e_2x2, described_tpu):
+    """Nemotron 3 Nano's routed experts at its cell's size (16,384 tokens,
+    top-6, 8 of 128 ungated relu2 experts of 1,856 held: pieces of 12,288
+    rows), value and gradients: 1,856 columns are 14.5 lane tiles and the
+    first expert width that is no multiple of 128 — the grouped kernels take
+    it as two column blocks of 1,024 (the second holds 832) one way and as a
+    contraction of 1,856 whole the other; two products forward and five
+    backward a piece where a SwiGLU expert has three and eight."""
+    from easydl_tpu.ops import moe
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(h, weights, w_up, w_down, chosen):
+        y, _ = moe.routed_experts(h, chosen, weights, None, w_up, w_down,
+                                  0, 128)
+        return y.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        s((16384, 2688)), s((16384, 6), jnp.float32), s((8, 2688, 1856)),
+        s((8, 1856, 2688)), s((16384, 6), jnp.int32)).compile()
+    calls = _mosaic_calls(compiled)
+    products = [c for c in calls if c.startswith("bf16[")]
+    # a piece's two forward and five backward products (the up product made
+    # again is the forward's where the compiler sees them equal), for the
+    # first piece and again in the loop over the others
+    assert 10 <= len(products) <= 14, calls
+    assert all(c.startswith(("bf16[12288,", "bf16[8,")) for c in products)
+    assert any(c.startswith("bf16[12288,1856]") for c in products)
+    assert any(c.startswith("bf16[8,2688,1856]") for c in products)
+    assert moe.choose_tiles(12288, 8, 2688, 1856, 2) == (128, 1024)
