@@ -897,7 +897,10 @@ def _mamba2(block, u):
     """The Mamba-2 mixer on the normed input ``u``. The published fused
     input projection ``[z, xBC, dt]`` is five projections here and the
     depthwise convolution three — the same mathematics, column by
-    column — so that heads shard over ``tp`` and B, C stay whole."""
+    column — so that heads shard over ``tp`` and B, C stay whole. The scan
+    under ``ssd`` is ``ops/ssd.py``'s: its Pallas kernels on a TPU where the
+    mixer's widths tile, else ``jax.numpy``; the convolutions and the gated
+    norm are XLA's."""
     cfg, m = block.cfg, block.cfg.ssm
     dt_ = jnp.dtype(cfg.dtype)
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")

@@ -11,8 +11,9 @@ bytes on each device, the Mosaic calls (how many of them the flash forward)
 and their per-device operand shapes, and the collectives in front of them.
 A cell's step is also a gate: it has a limit in GiB a device and the
 attention kernels it holds by name (``ATTENTION_KERNELS``: how often the
-forward kernel stands says what remat kept), and a program over its limit or
-with other kernels makes the exit code 1. A compile that passes is not a run.
+forward kernel stands says what remat kept) and the Mamba-2 scan's
+(``SCAN_KERNELS``), and a program over its limit or with other kernels makes
+the exit code 1. A compile that passes is not a run.
 
 Usage: JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py [NAME ...]
        (names: the keys of PROGRAMS; default: all)
@@ -58,7 +59,8 @@ NEMOTRON = ("nemotron_h", dict(
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
 #: limit is its own compiled size and a little: medium's steps 15.292
 #: (15.668 with the flash forward's `out` kept as well: PERF.md section 6,
-#: PR 30), XL's shard 14.111, the hybrid's 15.601, Ouro's 15.488, Laguna's
+#: PR 30), XL's shard 14.111, the hybrid's 15.590 (15.601 before the scan's
+#: kernels, PR 43), Ouro's 15.488, Laguna's
 #: share 14.851 (15.227 before the expert layer's sort went in pieces,
 #: PR 32; 15.006 before its grouped products were kernels of the repo's own,
 #: PR 36), ZAYA1's share 14.767 (15.000 before PR 36: the backward keeps no
@@ -92,12 +94,15 @@ PROGRAMS = {
     "zaya_1x2": (ZAYA, "dp=1", 2, 1, "adamw", 14.85),
     "joyai_1x2": (JOYAI, "dp=1", 2, 1, "adamw", 14.55),
     # PR 42: 16.155 by this script's sum, MORE than the chip's 15.75, and it
-    # runs: memory_analysis' arguments + temporaries over-count (the
-    # compiler's own buffer assignment allocates 14.91 GiB for this step,
+    # ran: memory_analysis' arguments + temporaries over-count (the
+    # compiler's own buffer assignment allocated 14.91 GiB for that step,
     # 14.26 live at the peak; for `one` the sum prints 12.64 where 10.44 are
-    # allocated: docs/operations.md). The limit is the sum and a little, as
-    # the others'
-    "nemotron_1x2": (NEMOTRON, "dp=1", 2, 1, "adamw", 16.3),
+    # allocated: docs/operations.md). PR 43: 14.158 — the scan's kernels keep
+    # a layer's operands and its chunks' entry states, where the backward of
+    # the jax.numpy scan held the chunks' float32 intermediates of all 64
+    # steps beside them (temporaries 8.70 -> 6.70 GiB). The limit is the sum
+    # and a little, as the others' (16.3 until PR 43)
+    "nemotron_1x2": (NEMOTRON, "dp=1", 2, 1, "adamw", 14.3),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -120,6 +125,17 @@ ATTENTION_KERNELS = {
     "zaya_1x2": {"flash_bwd": 1, "flash_fwd": 1},
     "joyai_1x2": {"mla_bwd": 3, "mla_fwd": 3},
     "nemotron_1x2": {"flash_bwd": 1, "flash_fwd": 1},
+}
+
+#: name -> the Mamba-2 scan's kernels (``ssd_*``: ops/ssd.py, PR 43) a cell's
+#: compiled step holds, by name, gated like the attention kernels: a scanned
+#: run of Mamba-2 layers holds ``ssd_fwd`` twice (remat ``full`` makes the
+#: layer again) and ``ssd_bwd`` once — the hybrid's five layers are two runs,
+#: Nemotron's four are three. A cell with none of them here may hold none: a
+#: step that fell back to the ``jax.numpy`` scan fails the gate.
+SCAN_KERNELS = {
+    "hybrid_4x2": {"ssd_bwd": 2, "ssd_fwd": 4},
+    "nemotron_1x2": {"ssd_bwd": 3, "ssd_fwd": 6},
 }
 
 
@@ -226,6 +242,11 @@ def main() -> None:
         if attention != ATTENTION_KERNELS.get(name, attention):
             over.append(f"{name}: attention kernels {attention}, not "
                         f"{ATTENTION_KERNELS[name]}")
+        scan = {kernel: n for kernel, n in kernels.items()
+                if kernel.startswith("ssd_")}
+        if limit and scan != SCAN_KERNELS.get(name, {}):
+            over.append(f"{name}: scan kernels {scan}, not "
+                        f"{SCAN_KERNELS.get(name, {})}")
     if over:
         sys.exit("; ".join(over))
 
