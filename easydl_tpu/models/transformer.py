@@ -45,7 +45,8 @@ from easydl_tpu.ops.attention import rotate_heads
 from easydl_tpu.ops import moe as moe_ops
 from easydl_tpu.ops.moe import MoeMlp
 from easydl_tpu.ops.rope import apply_rope, rope_tables
-from easydl_tpu.ops.ssd import (causal_conv1d, gated_rmsnorm,
+from easydl_tpu.ops.ssd import (causal_conv1d, causal_conv1d_silu,
+                                gated_rmsnorm,
                                 ssd_flops_per_token, ssd_scan)
 from easydl_tpu.utils.logging import get_logger, log_once
 
@@ -898,9 +899,9 @@ def _mamba2(block, u):
     input projection ``[z, xBC, dt]`` is five projections here and the
     depthwise convolution three — the same mathematics, column by
     column — so that heads shard over ``tp`` and B, C stay whole. The scan
-    under ``ssd`` is ``ops/ssd.py``'s: its Pallas kernels on a TPU where the
-    mixer's widths tile, else ``jax.numpy``; the convolutions and the gated
-    norm are XLA's."""
+    under ``ssd`` and the convolutions under ``conv1d`` (each with its bias
+    and SiLU) are ``ops/ssd.py``'s: their Pallas kernels on a TPU where the
+    mixer's widths tile, else ``jax.numpy``; the gated norm is XLA's."""
     cfg, m = block.cfg, block.cfg.ssm
     dt_ = jnp.dtype(cfg.dtype)
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
@@ -927,7 +928,7 @@ def _mamba2(block, u):
         b = block.param(f"conv_{name}_bias", nn.with_logical_partitioning(
             nn.initializers.zeros_init() if m.conv_bias_zero else init,
             axes), a.shape[2:])
-        return nn.silu(causal_conv1d(a, w, b))
+        return causal_conv1d_silu(a, w, b)
 
     with jax.named_scope("conv1d"):
         x = conv("x", x, kv)

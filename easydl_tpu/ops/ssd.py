@@ -1,5 +1,5 @@
 """Mamba-2's selective state-space scan in its chunked (state-space dual)
-form, the causal depthwise convolution in front of it and the gated RMSNorm
+form, the causal depthwise convolutions in front of it and the gated RMSNorm
 behind it (Dao and Gu 2024, "Transformers are SSMs"; the layer as the HF
 ``GraniteMoeHybrid`` / ``Mamba2`` modelling files write it).
 
@@ -45,13 +45,38 @@ are; the operands of the four matrix multiplications (``dt * X``, the masked
 decayed scores, the inputs decayed to the chunk's end, the state in front of
 ``C h``) are rounded to the inputs' dtype (bf16 on the chip) where they enter
 a product, with float32 accumulation.
+
+The three convolutions in front of the scan (over x, B and C, each with its
+bias and SiLU) go the same two ways, :func:`causal_conv1d_silu` choosing from
+the platform and the shapes alone (the line ``conv1d: ...`` a process logs
+once a shape says which, and why):
+
+* **on a TPU, where the shapes tile** (:func:`conv_untiled`: taps that reach
+  back no further than one tile, channels that are whole sublane tiles, a
+  sequence of whole tiles — both cells') **two Pallas kernels under one
+  ``jax.custom_vjp``**: ``conv1d_fwd`` reads the array once and writes
+  ``silu(sum_k w[k] x[p - (K - 1) + k] + b)`` once; ``conv1d_bwd`` reads x
+  and dy once, makes the pre-activation again, writes dx once and sums the
+  taps' and the bias's gradients in a float32 VMEM scratch across the
+  sequence, one tile of sums a batch row. The rule keeps x, the taps and the
+  bias and nothing else — what remat ``full`` makes again anyway. Under a
+  mesh per shard, x's channels over ``tp`` with the heads, B and C whole;
+* **anywhere else** ``silu(causal_conv1d(x, w, b))``, differentiated by jax:
+  the reference the kernels are tested against
+  (``tests/test_conv_kernels.py``).
+
+Their precision: taps and bias are rounded to the input's dtype on both
+paths; the kernels make products, sums and the SiLU in float32 and round ONCE
+to the input's dtype (jax.numpy rounds every operation), and their taps' and
+bias's sums are float32 over the whole sequence.
 """
 
 from __future__ import annotations
 
 import functools
+import inspect
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -487,6 +512,21 @@ def _sizes(x, B, q: int):
     return x.shape[3] // q, cell, x.shape[1] // B.shape[1] // cell
 
 
+# The kernels' calls are ``jax.jit``s of their own inside the step: a kernel's
+# body is hundreds to thousands of operations written out head by head or
+# tile by tile, and a step traces a call once a USE (the pass, the one remat
+# makes again, each run of layers, each of the benchmark's programs) —
+# jitted, once a shape: the convolutions' bodies cost a Nemotron run's set-up
+# 34 s before, the scan's nine traces a step program 1.5 s (PERF.md section 6,
+# PR 44).
+def _kernel_jit(call):
+    """``call`` jitted, its keyword-only arguments the static ones."""
+    return jax.jit(call, static_argnames=[
+        name for name, p in inspect.signature(call).parameters.items()
+        if p.kind is p.KEYWORD_ONLY])
+
+
+@_kernel_jit
 def _fwd(skip, x, B, C, dt_rows, cs_rows, *, q: int, keep: bool,
          interpret: bool):
     """``y``, and where ``keep`` every chunk's entry state beside it."""
@@ -520,6 +560,7 @@ def _fwd(skip, x, B, C, dt_rows, cs_rows, *, q: int, keep: bool,
     )(skip, x, B, C, dt_rows, cs_rows)
 
 
+@_kernel_jit
 def _bwd(skip, x, B, C, dt_rows, cs_rows, dy, entries, *, q: int,
          interpret: bool):
     """``dx, dB, dC`` as the operands and three ``[batch, H, seq]`` float32
@@ -650,6 +691,17 @@ def _free_axes():
                   if a not in mesh.manual_axes and mesh.shape[a] > 1]
 
 
+def _batch_split(rows: int):
+    """``(context mesh, its axes that span devices, those of them a batch
+    of rows goes over)``: the mesh's batch axes, or none of them where they
+    do not divide the rows (those devices then compute all of them)."""
+    mesh, free = _free_axes()
+    batch = tuple(a for a in BATCH_AXES if a in free)
+    if batch and rows % math.prod(mesh.shape[a] for a in batch):
+        batch = ()
+    return mesh, free, batch
+
+
 def _head_split(heads: int, groups: int):
     """How a call under the context mesh splits its heads: ``(ways, the
     axis the heads go over or None, the axis B's and C's groups go over or
@@ -677,12 +729,9 @@ def _per_shard(fn, x, B):
     says. An axis that does not divide is left out of the specs: those
     devices compute the whole of it. Axes that are manual already are per
     shard already."""
-    mesh, free = _free_axes()
+    _, free, batch = _batch_split(x.shape[0])
     if not free:
         return fn
-    batch = tuple(a for a in BATCH_AXES if a in free)
-    if batch and x.shape[0] % math.prod(mesh.shape[a] for a in batch):
-        batch = ()
     _, over, by_group = _head_split(x.shape[2], B.shape[2])
     rows, head = P(batch or None, None, over), P(over)
     group = P(batch or None, None, by_group)
@@ -753,3 +802,475 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                   f"{said}, differentiated by jax (body rematerialised)")
     y = _scan_reference(xp, dtp, A, Bp, Cp, D, q=q, n_chunks=n_chunks)
     return y[:, :seq] if pad else y
+
+
+# ---------------------------------------------------------------------------
+# the convolutions' kernels
+# ---------------------------------------------------------------------------
+#
+# ``silu(causal_conv1d(x, w, b))`` as one pass over the array forward and one
+# backward. Like the scan's kernels they take an array in the order XLA keeps
+# it in: x — ``[batch, seq, 64, 64]``, kept with the SEQUENCE along the lanes
+# — turned, ``[batch, 1, heads · P, seq]``, the taps' shift a rotation along
+# the lanes; B and C — a state of 128 fills a lane tile — as ``[batch,
+# groups, seq, N]``, the shift along the sublanes (rank 4 both ways: the
+# benchmark's ``lib/hlo.flash_calls`` takes a rank-3 result for a flash
+# kernel's). One body serves both, the sequence's axis of a block a static
+# argument. A grid cell takes a block and works through it in STRIPS, a strip
+# tile by tile along the sequence: a tile shifted by ``s`` positions is its
+# own rotation below position ``s`` patched with the rotation of the tile in
+# front of it, so a rotation serves two tiles and what lives is a tile's. In
+# front of a strip's first tile stands the block's own tile, or the last tile
+# of the block before it — the same array handed a second time under a
+# clipped block index, as the band kernels hand their neighbour
+# (``ops/flash_attention.py _band_neighbours``) — or zeros in front of the
+# sequence. Taps and bias travel HALVED (:func:`_half_pre_activation`) as one
+# float32 tile a stretch of channels, numbers ``0 .. K`` along the sequence's
+# axis, and the backward's sums come back in the same tile.
+
+#: the most a strip holds ``(tiles of channels, positions of the sequence)``:
+#: sublane tiles of the dtype by lanes where the sequence lies along the
+#: lanes, lane tiles by rows where it lies along the sublanes. A strip is one
+#: pass of a loop the compiler does not overlap with the next, so it pays
+#: each pass's latencies once a strip: on the chip a strip of 128 float32
+#: vregs an array beat one of 32 by a quarter, for all its register spills
+#: (PERF.md section 6, PR 44)
+_STRIP = {1: (2, 4096), 0: (1, 256)}
+#: the most strips a block holds ``(across the channels, along the
+#: sequence)``: a MiB at bfloat16, a DMA that hides a grid step's fixed cost
+_BLOCK_STRIPS = {1: (2, 2), 0: (1, 16)}
+
+
+class _ConvTiles(NamedTuple):
+    """How the convolution's kernels cut a view ``[batch, groups, channels,
+    seq]`` (``axis`` 1: the sequence along the lanes) or ``[batch, groups,
+    seq, channels]`` (``axis`` 0). ``strip`` and ``block`` are ``(channels,
+    seq)``."""
+    axis: int
+    edge: int    # the sequence's extent of the tile in front of a strip
+    tile: int    # and of the tile the taps and the sums travel in
+    strip: tuple
+    block: tuple
+
+    def order(self, channels, seq):
+        """``(channels, seq)`` in the view's own order."""
+        return (channels, seq) if self.axis else (seq, channels)
+
+
+def _fit(n: int, unit: int, most: int) -> int:
+    """The largest of ``unit, 2 unit, .. most unit`` that divides ``n``."""
+    return max(unit * m for m in range(1, most + 1) if n % (unit * m) == 0)
+
+
+def conv_untiled(x, taps: int):
+    """``(why the kernels cannot take x [batch, seq, *channels] on the chip
+    or None, how they cut it)``. The sequence lies along the sublanes where
+    the last channel axis fills whole lane tiles, else along the lanes —
+    XLA's own choice for both; the taps reach back no further than one tile,
+    the channels are whole sublane tiles where the sequence has the lanes,
+    the sequence whole tiles of its own axis."""
+    axis, sub = int(x.shape[-1] % 128 != 0), 32 // x.dtype.itemsize
+    seq = x.shape[1]
+    channels = math.prod(x.shape[2:]) if axis else x.shape[-1]
+    edge, tile, width = (128, 128, sub) if axis else (sub, 8, 128)
+    if taps - 1 > edge or taps + 1 > tile:
+        return f"{taps} taps reach past a tile of {min(edge, tile)}", None
+    if channels % width:
+        return f"{channels} channels are no whole tiles of {sub} sublanes", None
+    if seq % edge:
+        return (f"a sequence of {seq} is no whole tiles of {edge} "
+                f"{'lanes' if axis else 'sublanes'}"), None
+    tiles, positions = _STRIP[axis]
+    strip = (_fit(channels, width, tiles), _fit(seq, edge, positions // edge))
+    across, along = _BLOCK_STRIPS[axis]
+    block = (_fit(channels, strip[0], across), _fit(seq, strip[1], along))
+    return None, _ConvTiles(axis, edge, tile, strip, block)
+
+
+def _at(t: _ConvTiles, channels, seq, *lead):
+    """A block's index of ``channels`` and ``seq``, each ``(start, size)``,
+    in the view's own order behind ``lead``."""
+    return (*lead, *t.order(pl.ds(*channels), pl.ds(*seq)))
+
+
+def _strips(t: _ConvTiles, body, *, reverse: bool = False):
+    """``body(a strip's channels (start, size), its sequence's (start, size),
+    whether a strip of the block lies in front of it)`` for every strip of a
+    block, a stretch of channels' strips in the sequence's order, or against
+    it where ``reverse``."""
+    (width, length), n = t.strip, t.block[1] // t.strip[1]
+
+    def one(i, carry):
+        j = n - 1 - i % n if reverse else i % n
+        body((pl.multiple_of(i // n * width, width), width),
+             (pl.multiple_of(j * length, length), length), j > 0)
+        return carry
+
+    lax.fori_loop(0, t.block[0] // width * n, one, None)
+
+
+def _x_tiles(x_ref, before_ref, t, channels, seq, inside, first):
+    """A strip of x as float32 tiles along the sequence, the tile in front of
+    it first: the block's own where the strip lies ``inside`` the block, else
+    the neighbour's, or zeros at the sequence's ``first`` block."""
+    start = pl.multiple_of(jnp.maximum(seq[0] - t.edge, 0), t.edge)
+    mine = x_ref[_at(t, channels, (start, t.edge))]
+    theirs = before_ref[_at(t, channels, (0, t.edge))]
+    before = jnp.where(inside, mine,
+                       jnp.where(first, jnp.zeros_like(theirs), theirs))
+    return [before.astype(jnp.float32)] + _tiles(
+        x_ref[_at(t, channels, seq)].astype(jnp.float32), t)
+
+
+def _tiles(a, t: _ConvTiles):
+    """A strip cut into tiles along the sequence."""
+    return [lax.slice_in_dim(a, i, i + t.edge, axis=t.axis)
+            for i in range(0, a.shape[t.axis], t.edge)]
+
+
+def _turns(tile, n_taps: int, t: _ConvTiles, *, ahead: bool = False):
+    """``tile`` rotated along the sequence by ``s = 0 .. K - 1`` positions
+    towards its end, or towards its start where ``ahead``."""
+    return [tile] + [pltpu.roll(tile, t.edge - s if ahead else s, t.axis)
+                     for s in range(1, n_taps)]
+
+
+def _from_other(t: _ConvTiles, width: int, n_taps: int, *,
+                ahead: bool = False):
+    """Where a tile shifted by ``s = 1 .. K - 1`` positions reads the tile
+    in front of it (behind it where ``ahead``): its first (last) ``s``
+    positions."""
+    position = lax.broadcasted_iota(jnp.int32, t.order(width, t.edge), t.axis)
+    return [None] + [position >= t.edge - s if ahead else position < s
+                     for s in range(1, n_taps)]
+
+
+def _shifted(own, other, from_other):
+    """A tile shifted along the sequence, ``[p] -> tile[p - s]`` for ``s = 0
+    .. K - 1`` (or ``tile[p + s]``), from its own turns and those of the
+    tile in front of it (behind it): a vreg's rotation serves both
+    neighbours, and nothing lives longer than a tile."""
+    return [own[0]] + [jnp.where(mask, theirs, mine) for mask, theirs, mine
+                       in zip(from_other[1:], other[1:], own[1:])]
+
+
+def _taps(taps_ref, t: _ConvTiles, channels, n_taps: int):
+    """A stretch of channels' ``K + 1`` numbers: columns or rows that spread
+    over the sequence."""
+    tile = taps_ref[_at(t, channels, (0, t.tile))]
+    return [lax.slice_in_dim(tile, k, k + 1, axis=t.axis)
+            for k in range(n_taps + 1)]
+
+
+def _half_pre_activation(taps, back):
+    """``h = (sum_k w[k] x[p - (K - 1) + k] + b) / 2`` with ``back[s] = x[p -
+    s]``, the taps' tile holding HALF the taps and bias (a float's half is
+    exact, so ``2 h`` is the pre-activation to the bit). Both kernels want
+    ``h``: ``sigmoid(pre) = (1 + tanh(h)) / 2`` costs the vector unit two
+    operations where ``1 / (1 + exp(-pre))`` costs it a division (ten, with
+    its special cases), and never overflows."""
+    half = taps[-1]
+    for tap, x in zip(taps[-2::-1], back):
+        half = half + tap * x
+    return half
+
+
+def _conv_fwd_kernel(x_ref, before_ref, taps_ref, y_ref, *, t: _ConvTiles,
+                     n_taps: int):
+    # x_ref, y_ref: a block; before_ref: the tile in front of it (the block's
+    # own first tile at the sequence's start, where it is not read);
+    # taps_ref: the block's channels' tile of taps and bias, float32
+    first = pl.program_id(3) == 0
+    from_before = _from_other(t, t.strip[0], n_taps)
+
+    def strip(channels, seq, inside):
+        tiles = _x_tiles(x_ref, before_ref, t, channels, seq, inside, first)
+        taps = _taps(taps_ref, t, channels, n_taps)
+        before, ys = _turns(tiles[0], n_taps, t), []
+        for x in tiles[1:]:  # a tile at a time: what lives is a tile's
+            own = _turns(x, n_taps, t)
+            half = _half_pre_activation(
+                taps, _shifted(own, before, from_before))
+            # y = pre sigmoid(pre) = h (1 + tanh(h))
+            ys.append((half + half * jnp.tanh(half)).astype(y_ref.dtype))
+            before = own
+        y_ref[_at(t, channels, seq)] = jnp.concatenate(ys, axis=t.axis)
+
+    _strips(t, strip)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, dy_ref, taps_ref, dx_ref, sums_ref,
+                     after_ref, acc_ref, *, t: _ConvTiles, n_taps: int):
+    # The grid walks a sequence's blocks from the last to the first, a block
+    # its strips and a strip its tiles. Results: dx_ref, a block; sums_ref, a
+    # batch row's sums over the sequence of 2 dpre x[p - (K - 1) + k]
+    # (numbers 0 .. K - 1) and of 2 dpre (number K) in the taps' tile, written
+    # with the sequence's first block. Scratch, float32: after_ref [block's
+    # channels, edge], 2 dpre in the tile behind the strip at hand; acc_ref
+    # [K + 1, block's channels, edge], the sums by position in a tile.
+    step = pl.program_id(3)
+    from_before = _from_other(t, t.strip[0], n_taps)
+    from_after = _from_other(t, t.strip[0], n_taps, ahead=True)
+
+    @pl.when(step == 0)
+    def _():  # nothing lies behind a sequence's end
+        after_ref[...] = jnp.zeros_like(after_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def strip(channels, seq, inside):
+        tiles = _x_tiles(x_ref, before_ref, t, channels, seq, inside,
+                         step == pl.num_programs(3) - 1)
+        dys = _tiles(dy_ref[_at(t, channels, seq)], t)
+        taps = _taps(taps_ref, t, channels, n_taps)
+        behind = _at(t, channels, (0, t.edge))
+        before, dpres = _turns(tiles[0], n_taps, t), []
+        for x, dy in zip(tiles[1:], dys):
+            own = _turns(x, n_taps, t)
+            half = _half_pre_activation(
+                taps, _shifted(own, before, from_before))
+            # silu'(pre) = sigmoid (1 + pre (1 - sigmoid))
+            #            = (1 + tanh(h) + h (1 - tanh(h)^2)) / 2
+            # TWICE dpre from here on: the halved taps make dx of it as it
+            # is, and the sums are halved once, outside
+            th = jnp.tanh(half)
+            dpres.append(dy.astype(jnp.float32) * (
+                1.0 + th + half * (1.0 - th * th)))
+            before = own
+        after = _turns(after_ref[behind], n_taps, t, ahead=True)
+        sums, dxs = [0.0] * (n_taps + 1), []
+        for x, dpre in zip(tiles[:0:-1], dpres[::-1]):
+            turned = _turns(dpre, n_taps, t, ahead=True)
+            # dx[p] = sum_s w[K - 1 - s] dpre[p + s], and the tap's own sum
+            # over p of dpre[p] x[p - s] is that of dpre[p + s] x[p] (past
+            # the sequence's end dpre is zero, in front of its start x)
+            ahead = _shifted(turned, after, from_after)
+            dx = sum(tap * a for tap, a in zip(taps[-2::-1], ahead))
+            sums = [total + a * x for total, a in zip(
+                sums, ahead[::-1])] + [sums[n_taps] + dpre]
+            dxs.append(dx.astype(dx_ref.dtype))
+            after = turned
+        after_ref[behind] = after[0]
+        dx_ref[_at(t, channels, seq)] = jnp.concatenate(dxs[::-1],
+                                                        axis=t.axis)
+        for k, partial in enumerate(sums):
+            acc_ref[_at(t, channels, (0, t.edge), k)] += partial
+
+    _strips(t, strip, reverse=True)
+
+    @pl.when(step == pl.num_programs(3) - 1)
+    def _():
+        number = lax.broadcasted_iota(jnp.int32, sums_ref.shape, t.axis)
+        sums = jnp.zeros(sums_ref.shape, jnp.float32)
+        for k in range(n_taps + 1):
+            sums = jnp.where(number == k, jnp.sum(
+                acc_ref[k], axis=t.axis, keepdims=True), sums)
+        sums_ref[...] = sums
+
+
+def _conv_call(kernel, name: str, x, t: _ConvTiles, *, reverse: bool,
+               blocks: int, interpret: bool):
+    """``(the pallas_call of kernel on the grid (batch row, group, block of
+    channels, block of the sequence) — the sequence walked backwards, one
+    block after the other, where reverse —, the specs of: a block, the tile
+    in front of it, a batch row's tile of sums, the taps' tile)``. ``blocks``:
+    how many blocks the call holds at a time."""
+    channels, seq = t.order(*x.shape[2:])
+    n, per = seq // t.block[1], t.block[1] // t.edge
+
+    def at(i):
+        return n - 1 - i if reverse else i
+
+    def spec(extent, where):
+        return pl.BlockSpec(
+            (None, None, *t.order(t.block[0], extent)),
+            lambda b, g, c, i: (b, g, *t.order(c, where(i))))
+
+    specs = (spec(t.block[1], at),
+             spec(t.edge, lambda i: jnp.maximum(at(i) * per - 1, 0)),
+             spec(t.tile, lambda i: 0),
+             pl.BlockSpec((None, *t.order(t.block[0], t.tile)),
+                          lambda b, g, c, i: (g, *t.order(c, 0))))
+    held = 2 * blocks * math.prod(t.block) * x.dtype.itemsize
+    return functools.partial(
+        pl.pallas_call, kernel, name=name, interpret=interpret,
+        grid=(*x.shape[:2], channels // t.block[0], n),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3
+            + ("arbitrary" if reverse else "parallel",),
+            vmem_limit_bytes=held + _DEFAULT_VMEM)), specs
+
+
+@_kernel_jit
+def _conv_fwd(x, taps, *, t: _ConvTiles, n_taps: int, interpret: bool):
+    """``y`` as ``x``, a view ``[batch, groups, *t.order(channels, seq)]``;
+    ``taps``: ``[groups, *t.order(channels, tile)]`` float32."""
+    call, (own, before, _, tile) = _conv_call(
+        functools.partial(_conv_fwd_kernel, t=t, n_taps=n_taps),
+        "conv1d_fwd", x, t, reverse=False, blocks=2, interpret=interpret)
+    return call(in_specs=[own, before, tile], out_specs=own,
+                out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x, x, taps)
+
+
+@_kernel_jit
+def _conv_bwd(x, dy, taps, *, t: _ConvTiles, n_taps: int, interpret: bool):
+    """``dx`` as ``x`` and every batch row's sums ``[batch, groups,
+    *t.order(channels, tile)]`` float32."""
+    channels, _ = t.order(*x.shape[2:])
+    call, (own, before, sums, tile) = _conv_call(
+        functools.partial(_conv_bwd_kernel, t=t, n_taps=n_taps),
+        "conv1d_bwd", x, t, reverse=True, blocks=3, interpret=interpret)
+    f32 = jnp.float32
+    return call(
+        in_specs=[own, before, own, tile], out_specs=[own, sums],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(
+                       (*x.shape[:2], *t.order(channels, t.tile)), f32)],
+        scratch_shapes=[pltpu.VMEM(t.order(t.block[0], t.edge), f32),
+                        pltpu.VMEM((n_taps + 1, *t.order(t.block[0], t.edge)),
+                                   f32)],
+    )(x, x, dy, taps)
+
+
+def _conv_view(a, t: _ConvTiles):
+    """The kernels' view of ``a [batch, seq, *channels]``: ``[batch, 1,
+    channels, seq]``, turned, or ``[batch, groups, seq, N]``."""
+    batch, seq = a.shape[:2]
+    if t.axis:
+        return jnp.moveaxis(a, 1, -1).reshape(batch, 1, -1, seq)
+    return jnp.swapaxes(a.reshape(batch, seq, -1, a.shape[-1]), 1, 2)
+
+
+def _conv_unview(v, shape, t: _ConvTiles):
+    """``[batch, seq, *channels]`` (``shape``) of a view."""
+    if t.axis:
+        return jnp.moveaxis(v.reshape(shape[:1] + shape[2:] + shape[1:2]),
+                            -1, 1)
+    return jnp.swapaxes(v, 1, 2).reshape(shape)
+
+
+def _tap_tiles(x, weight, bias, t: _ConvTiles):
+    """HALF the taps and bias (:func:`_half_pre_activation`), rounded to
+    ``x``'s dtype first as ``causal_conv1d`` rounds them, as the kernels'
+    float32 tiles: ``[1, channels, tile]`` or ``[groups, tile, N]``, numbers
+    ``0 .. K`` first along the tile."""
+    numbers = 0.5 * jnp.concatenate(
+        [weight.astype(x.dtype), bias.astype(x.dtype)[None]]
+    ).astype(jnp.float32)
+    k1 = numbers.shape[0]
+    if t.axis:  # [1, channels, K + 1]
+        tiles = numbers.reshape(k1, -1).T[None]
+    else:  # [groups, K + 1, N]
+        tiles = jnp.swapaxes(numbers.reshape(k1, -1, numbers.shape[-1]), 0, 1)
+    pad = [(0, 0)] * 3
+    pad[1 + t.axis] = (0, t.tile - k1)
+    return jnp.pad(tiles, pad)
+
+
+def _tap_unview(tiles, shape, t: _ConvTiles):
+    """``[K + 1, *channels]`` (``shape``) of tiles as :func:`_tap_tiles`
+    lays them out."""
+    numbers = lax.slice_in_dim(tiles, 0, shape[0], axis=1 + t.axis)
+    return jnp.moveaxis(numbers, 1 + t.axis, 0).reshape(shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_kernels(x, weight, bias, interpret):
+    return _conv_kernels_fwd(x, weight, bias, interpret)[0]
+
+
+def _conv_kernels_fwd(x, weight, bias, interpret):
+    n_taps = weight.shape[0]
+    why, t = conv_untiled(x, n_taps)
+    if why:
+        raise ValueError(f"the convolution's kernels cannot take "
+                         f"{x.shape}: {why}")
+    y = _conv_fwd(_conv_view(*_made(x), t), _tap_tiles(x, weight, bias, t),
+                  t=t, n_taps=n_taps, interpret=interpret)
+    # what the backward takes: x, the taps and the bias, and makes the
+    # pre-activation again from them — what remat `full` makes again anyway
+    return _conv_unview(y, x.shape, t), (x, weight, bias)
+
+
+def _conv_kernels_bwd(interpret, res, dy):
+    x, weight, bias = res
+    n_taps = weight.shape[0]
+    _, t = conv_untiled(x, n_taps)
+    x_made, dy = _made(x, dy)
+    dx, sums = _conv_bwd(
+        _conv_view(x_made, t), _conv_view(dy, t),
+        _tap_tiles(x, weight, bias, t), t=t, n_taps=n_taps,
+        interpret=interpret)
+    sums = 0.5 * _tap_unview(jnp.sum(sums, axis=0),
+                             (n_taps + 1,) + x.shape[2:], t)
+    return (_conv_unview(dx, x.shape, t), sums[:n_taps].astype(weight.dtype),
+            sums[n_taps].astype(bias.dtype))
+
+
+_conv_kernels.defvjp(_conv_kernels_fwd, _conv_kernels_bwd)
+
+
+def _conv_split(x):
+    """``(the mesh axis x's first channel axis goes over under the context
+    mesh or None, how many ways)``: ``tp`` where ``x`` is of the turned kind —
+    the heads' — and divides; B and C (``ssm_group`` is whole on every
+    device: ``core/sharding.py``) stay whole."""
+    mesh, free = _free_axes()
+    if (x.shape[-1] % 128 and HEAD_AXIS in free
+            and x.shape[2] % mesh.shape[HEAD_AXIS] == 0):
+        return HEAD_AXIS, mesh.shape[HEAD_AXIS]
+    return None, 1
+
+
+def _conv_per_shard(fn, x):
+    """Wrap ``fn(x, weight, bias)`` in ``jax.shard_map`` where the context
+    mesh spans devices, as :func:`_per_shard` wraps the scan: batch over the
+    mesh's batch axes, the channels as :func:`_conv_split` says."""
+    _, free, batch = _batch_split(x.shape[0])
+    if not free:
+        return fn
+    over, _ = _conv_split(x)
+    rows = P(batch or None, None, over)
+    return jax.shard_map(fn, in_specs=(rows, P(None, over), P(over)),
+                         out_specs=rows, check_vma=False)
+
+
+def causal_conv1d_silu_kernels(x, weight, bias=None, *,
+                               interpret: bool = False) -> jax.Array:
+    """:func:`causal_conv1d_silu` by the Pallas kernels whatever the
+    platform, on shapes that tile (:func:`conv_untiled`). ``interpret=True``
+    runs them in the Pallas interpreter — something only a test passes."""
+    if bias is None:
+        bias = jnp.zeros(weight.shape[1:], weight.dtype)
+
+    def kernels(x, weight, bias):
+        return _conv_kernels(x, weight, bias, interpret)
+
+    return _conv_per_shard(kernels, x)(x, weight, bias)
+
+
+def causal_conv1d_silu(x: jax.Array, weight: jax.Array,
+                       bias=None) -> jax.Array:
+    """``silu(causal_conv1d(x, weight, bias))``, the Mamba-2 mixer's
+    convolution: by the Pallas kernels on a TPU where the shapes tile, one
+    pass over the array forward and one backward, else as written here,
+    differentiated by jax (the module's docstring has both). Logs once
+    which path a shape took."""
+    taps = weight.shape[0]
+    _, ways = _conv_split(x)
+    mine = jax.ShapeDtypeStruct(
+        x.shape[:2] + (x.shape[2] // ways,) + x.shape[3:], x.dtype)
+    why, t = ("no tpu", None) if not platform.on_tpu() \
+        else conv_untiled(mine, taps)
+    said = (f"{taps} taps, bias and SiLU over {list(mine.shape)} "
+            f"{x.dtype.name}, products and sums float32")
+    if why is None:
+        log_once(log, f"conv1d: Pallas kernels conv1d_fwd / conv1d_bwd, "
+                      f"{said}; the sequence along the "
+                      f"{'lanes' if t.axis else 'sublanes'}, blocks of "
+                      f"{t.block[0]} channels by {t.block[1]} positions in "
+                      f"strips of {t.strip[0]} by {t.strip[1]}; the backward "
+                      f"keeps x and makes the pre-activation again")
+        return causal_conv1d_silu_kernels(x, weight, bias)
+    log_once(log, f"conv1d: jax.numpy, not the kernels ({why}), {said}, "
+                  f"differentiated by jax")
+    return jax.nn.silu(causal_conv1d(x, weight, bias))

@@ -11,8 +11,8 @@ bytes on each device, the Mosaic calls (how many of them the flash forward)
 and their per-device operand shapes, and the collectives in front of them.
 A cell's step is also a gate: it has a limit in GiB a device and the
 attention kernels it holds by name (``ATTENTION_KERNELS``: how often the
-forward kernel stands says what remat kept) and the Mamba-2 scan's
-(``SCAN_KERNELS``), and a program over its limit or with other kernels makes
+forward kernel stands says what remat kept) and the Mamba-2 mixer's
+(``MAMBA_KERNELS``), and a program over its limit or with other kernels makes
 the exit code 1. A compile that passes is not a run.
 
 Usage: JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py [NAME ...]
@@ -59,8 +59,8 @@ NEMOTRON = ("nemotron_h", dict(
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
 #: limit is its own compiled size and a little: medium's steps 15.292
 #: (15.668 with the flash forward's `out` kept as well: PERF.md section 6,
-#: PR 30), XL's shard 14.111, the hybrid's 15.590 (15.601 before the scan's
-#: kernels, PR 43), Ouro's 15.488, Laguna's
+#: PR 30), XL's shard 14.111, the hybrid's 15.482 (15.601 before the scan's
+#: kernels, PR 43; 15.590 before the convolutions', PR 44), Ouro's 15.488, Laguna's
 #: share 14.851 (15.227 before the expert layer's sort went in pieces,
 #: PR 32; 15.006 before its grouped products were kernels of the repo's own,
 #: PR 36), ZAYA1's share 14.767 (15.000 before PR 36: the backward keeps no
@@ -100,9 +100,11 @@ PROGRAMS = {
     # allocated: docs/operations.md). PR 43: 14.158 — the scan's kernels keep
     # a layer's operands and its chunks' entry states, where the backward of
     # the jax.numpy scan held the chunks' float32 intermediates of all 64
-    # steps beside them (temporaries 8.70 -> 6.70 GiB). The limit is the sum
-    # and a little, as the others' (16.3 until PR 43)
-    "nemotron_1x2": (NEMOTRON, "dp=1", 2, 1, "adamw", 14.3),
+    # steps beside them (temporaries 8.70 -> 6.70 GiB). PR 44: 13.926 — the
+    # convolutions' backward kernel makes the pre-activation again in VMEM
+    # where XLA's kept float32 copies. The limit is the sum and a little, as
+    # the others' (16.3 until PR 43, 14.3 until PR 44)
+    "nemotron_1x2": (NEMOTRON, "dp=1", 2, 1, "adamw", 14.05),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -127,15 +129,23 @@ ATTENTION_KERNELS = {
     "nemotron_1x2": {"flash_bwd": 1, "flash_fwd": 1},
 }
 
-#: name -> the Mamba-2 scan's kernels (``ssd_*``: ops/ssd.py, PR 43) a cell's
-#: compiled step holds, by name, gated like the attention kernels: a scanned
-#: run of Mamba-2 layers holds ``ssd_fwd`` twice (remat ``full`` makes the
-#: layer again) and ``ssd_bwd`` once — the hybrid's five layers are two runs,
-#: Nemotron's four are three. A cell with none of them here may hold none: a
-#: step that fell back to the ``jax.numpy`` scan fails the gate.
-SCAN_KERNELS = {
-    "hybrid_4x2": {"ssd_bwd": 2, "ssd_fwd": 4},
-    "nemotron_1x2": {"ssd_bwd": 3, "ssd_fwd": 6},
+#: name -> the Mamba-2 mixer's kernels (ops/ssd.py: the scan's ``ssd_*``,
+#: PR 43; the convolutions' ``conv1d_*``, PR 44) a cell's compiled step holds,
+#: by name, gated like the attention kernels: a scanned run of Mamba-2 layers
+#: holds ``ssd_fwd`` twice (remat ``full`` makes the layer again) and
+#: ``ssd_bwd`` once — the hybrid's five layers are two runs, Nemotron's four
+#: are three — and three convolutions (x, B, C) beside each: ``conv1d_fwd``
+#: six times a scanned run and ``conv1d_bwd`` three. A run of ONE block holds
+#: ``conv1d_fwd`` three times: the compiler merges the pass with the one made
+#: again where the two calls are the same call (the scan's are not: the
+#: differentiated one keeps the chunks' entry states) — two of Nemotron's
+#: three runs. A cell with none of them here may hold none: a step that fell
+#: back to ``jax.numpy`` fails the gate.
+MAMBA_KERNELS = {
+    "hybrid_4x2": {"conv1d_bwd": 6, "conv1d_fwd": 12, "ssd_bwd": 2,
+                   "ssd_fwd": 4},
+    "nemotron_1x2": {"conv1d_bwd": 9, "conv1d_fwd": 12, "ssd_bwd": 3,
+                     "ssd_fwd": 6},
 }
 
 
@@ -242,11 +252,11 @@ def main() -> None:
         if attention != ATTENTION_KERNELS.get(name, attention):
             over.append(f"{name}: attention kernels {attention}, not "
                         f"{ATTENTION_KERNELS[name]}")
-        scan = {kernel: n for kernel, n in kernels.items()
-                if kernel.startswith("ssd_")}
-        if limit and scan != SCAN_KERNELS.get(name, {}):
-            over.append(f"{name}: scan kernels {scan}, not "
-                        f"{SCAN_KERNELS.get(name, {})}")
+        mamba = {kernel: n for kernel, n in kernels.items()
+                 if kernel.startswith(("ssd_", "conv1d_"))}
+        if limit and mamba != MAMBA_KERNELS.get(name, {}):
+            over.append(f"{name}: Mamba-2 kernels {mamba}, not "
+                        f"{MAMBA_KERNELS.get(name, {})}")
     if over:
         sys.exit("; ".join(over))
 

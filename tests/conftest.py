@@ -191,6 +191,25 @@ def described_tpu(monkeypatch):
     monkeypatch.setattr(platform, "on_tpu", lambda: True)
 
 
+@pytest.fixture
+def ssd_log(monkeypatch):
+    """Messages ``ops/ssd.py`` logs during the test (the scan's ``ssd:`` and
+    the convolutions' ``conv1d:`` lines), with ``log_once`` forgetting what
+    earlier tests of this process said."""
+    import logging
+
+    from easydl_tpu.ops import ssd
+    from easydl_tpu.utils import logging as easydl_logging
+
+    monkeypatch.setattr(easydl_logging, "_logged_once", set())
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda record: records.append(record.getMessage())
+    ssd.log.addHandler(handler)
+    yield records
+    ssd.log.removeHandler(handler)
+
+
 @pytest.fixture(scope="session")
 def v5e_2x2():
     """The four devices of a DESCRIBED v5e 2x2 (``tests/test_tpu_compile*.py``

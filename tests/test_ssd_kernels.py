@@ -11,7 +11,6 @@ file, and ``tests/test_hybrid_stack.py``'s scan against the recurrence."""
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
@@ -24,7 +23,6 @@ from jax.sharding import SingleDeviceSharding
 
 from easydl_tpu.core.mesh import MeshSpec, build_mesh
 from easydl_tpu.ops import ssd
-from easydl_tpu.utils import logging as easydl_logging
 
 NAMES = "x dt A B C D".split()
 
@@ -105,19 +103,6 @@ def test_kernels_take_whole_chunks_only():
     args = scan_inputs(0, 1, 40, 8, 8, 1, 16, jnp.float32)
     with pytest.raises(ValueError, match="whole chunks of 16"):
         ssd.ssd_scan_kernels(*args, chunk=16, interpret=True)
-
-
-@pytest.fixture
-def ssd_log(monkeypatch):
-    """Messages ``ops/ssd.py`` logs during the test, with ``log_once``
-    forgetting what earlier tests of this process said."""
-    monkeypatch.setattr(easydl_logging, "_logged_once", set())
-    records = []
-    handler = logging.Handler()
-    handler.emit = lambda record: records.append(record.getMessage())
-    ssd.log.addHandler(handler)
-    yield records
-    ssd.log.removeHandler(handler)
 
 
 def operands_like(shape):
@@ -286,9 +271,12 @@ def test_a_mamba_block_at_a_cells_shape_holds_the_two_kernels(
     gradients under remat ``full``, compiled for the described chip: Mosaic
     takes both kernels at both shapes, ``ssd_fwd`` stands once beside one
     ``ssd_bwd`` (a block that is no scanned run: the compiler merges the
-    forward and the one made again), no other Mosaic call is in the program, and ``benchmark/lib/hlo.py flash_calls`` — which tells the
-    flash kernels by the number and rank of a call's results — lists none
-    of them: a Mamba-2 block held no flash call before the kernels either."""
+    forward and the one made again), the convolutions' ``conv1d_fwd`` and
+    ``conv1d_bwd`` (PR 44) three times each beside them — x, B and C — no
+    other Mosaic call is in the program, and ``benchmark/lib/hlo.py
+    flash_calls`` — which tells the flash kernels by the number and rank of
+    a call's results — lists none of them (the mixer's results are rank 4):
+    a Mamba-2 block held no flash call before the kernels either."""
     import importlib
     import sys
 
@@ -311,5 +299,6 @@ def test_a_mamba_block_at_a_cells_shape_holds_the_two_kernels(
     calls = [line.split(" = ", 1)[0].strip().lstrip("%").split(".")[0]
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert sorted(calls) == ["ssd_bwd", "ssd_fwd"], calls
+    assert sorted(calls) == ["conv1d_bwd"] * 3 + ["conv1d_fwd"] * 3 + [
+        "ssd_bwd", "ssd_fwd"], calls
     assert hlo.flash_calls(text) == []

@@ -237,33 +237,45 @@ def test_the_medium_steps_fit_the_chip(v5e_2x2, rehearse, program):
     ("hybrid_4x2", {}, None),
     ("nemotron_1x2", {}, None),
     ("hybrid_4x2", {"ssd_fwd": -4, "ssd_bwd": -2},  # the jax.numpy scan
-     "hybrid_4x2: scan kernels {}, not {'ssd_bwd': 2, 'ssd_fwd': 4}"),
+     "hybrid_4x2: Mamba-2 kernels {'conv1d_bwd': 6, 'conv1d_fwd': 12}, not "
+     "{'conv1d_bwd': 6, 'conv1d_fwd': 12, 'ssd_bwd': 2, 'ssd_fwd': 4}"),
     ("nemotron_1x2", {"ssd_fwd": -3},  # a run that does not make it again
-     "nemotron_1x2: scan kernels {'ssd_bwd': 3, 'ssd_fwd': 3}, not "
-     "{'ssd_bwd': 3, 'ssd_fwd': 6}"),
-    ("zaya_1x2", {"ssd_fwd": 1}, "zaya_1x2: scan kernels {'ssd_fwd': 1}, "
+     "nemotron_1x2: Mamba-2 kernels {'conv1d_bwd': 9, 'conv1d_fwd': 12, "
+     "'ssd_bwd': 3, 'ssd_fwd': 3}, not {'conv1d_bwd': 9, 'conv1d_fwd': 12, "
+     "'ssd_bwd': 3, 'ssd_fwd': 6}"),
+    ("zaya_1x2", {"ssd_fwd": 1}, "zaya_1x2: Mamba-2 kernels {'ssd_fwd': 1}, "
                                  "not {}"),
+    ("hybrid_4x2", {"conv1d_fwd": -12, "conv1d_bwd": -6},  # jax.numpy's
+     "hybrid_4x2: Mamba-2 kernels {'ssd_bwd': 2, 'ssd_fwd': 4}, not "
+     "{'conv1d_bwd': 6, 'conv1d_fwd': 12, 'ssd_bwd': 2, 'ssd_fwd': 4}"),
+    ("nemotron_1x2", {"conv1d_fwd": 6},  # every run made them again
+     "nemotron_1x2: Mamba-2 kernels {'conv1d_bwd': 9, 'conv1d_fwd': 18, "),
+    ("nemotron_1x2", {"conv1d_bwd": -3},  # a layer's sums by XLA
+     "nemotron_1x2: Mamba-2 kernels {'conv1d_bwd': 6, 'conv1d_fwd': 12, "),
 ], ids=["as-gated", "a-forward-more", "joyai-a-forward-more",
         "another-backward", "hybrid-as-gated", "nemotron-as-gated",
         "hybrid-the-numpy-scan", "nemotron-a-scan-forward-less",
-        "a-scan-kernel-where-none-is"])
+        "a-scan-kernel-where-none-is", "hybrid-the-numpy-convolutions",
+        "nemotron-a-convolution-doubled", "nemotron-a-convolution-missing"])
 def test_the_script_fails_on_other_attention_kernels_than_a_cells(
         v5e_2x2, rehearse, monkeypatch, capsys, program, more, said):
     """The script is where the whole steps at the cells' sizes are gated
     (the ``slow`` tests of this file read the same programs): every cell
     with a memory limit has its attention kernels by name, the two with
-    Mamba-2 layers the scan's (``ssd_fwd``, ``ssd_bwd``: PR 43), and a
+    Mamba-2 layers the mixer's (the scan's ``ssd_fwd``, ``ssd_bwd``: PR 43;
+    the convolutions' ``conv1d_fwd``, ``conv1d_bwd``: PR 44), and a
     program that holds others — a forward kernel more is a scanned run that
-    made ``out`` and ``lse`` again, no scan kernel is the ``jax.numpy`` scan
-    — makes the exit code 1 naming the cell. The
+    made ``out`` and ``lse`` again, no scan or convolution kernel is the
+    ``jax.numpy`` path, a doubled or missing call says which — makes the
+    exit code 1 naming the cell. The
     compile is stood in for: the gate is what is tested, in no time."""
     import types
 
     assert set(rehearse.ATTENTION_KERNELS) == {
         name for name, program in rehearse.PROGRAMS.items() if program[-1]}
-    assert set(rehearse.SCAN_KERNELS) == {"hybrid_4x2", "nemotron_1x2"}
+    assert set(rehearse.MAMBA_KERNELS) == {"hybrid_4x2", "nemotron_1x2"}
     counts = dict(rehearse.ATTENTION_KERNELS[program], rows_to_tokens=6,
-                  **rehearse.SCAN_KERNELS.get(program, {}))
+                  **rehearse.MAMBA_KERNELS.get(program, {}))
     for kernel, n in more.items():
         counts[kernel] = counts.get(kernel, 0) + n
     text = "\n".join(
