@@ -199,7 +199,9 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     of the sort, ``moe_tile_fill``, the landed rows over the rows of the
     row tiles the grouped products visited, and, where the router has a
     skip choice, ``moe_skipped``,
-    the share of tokens that took it; where the router's state runs through
+    the share of tokens that took it, and, where it is the linear softmax,
+    ``router_chosen_mass``, the softmax mass on the chosen experts before
+    the renormalisation; where the router's state runs through
     the depth, ``router_state_rms``, its size after the last layer."""
     model = Transformer(cfg)
     seq_len, vocab = cfg.max_seq, cfg.vocab

@@ -250,7 +250,10 @@ class LowRank:
 class AttentionKind:
     """An attention layer's own numbers, where a stack has more than one
     kind: query heads (0: the description's), a causal window in keys (0:
-    none), a rotary scheme (None: the description's ``position``), a
+    none; on a training step's square problem the flash kernels' band path
+    holds it beside one neighbour block of at least its width, Laguna's 512
+    and Mellum 2's 1,024 alike, up to a grid cell's 2,048 rows:
+    ``ops/flash_attention.py choose_blocks``), a rotary scheme (None: the description's ``position``), a
     per-head sigmoid gate on the attention output, (``latent``) the
     mixing of q, k and v inside the heads' latent, and (``lowrank``) q, k
     and v made through low-rank latents in place of one full-rank map
@@ -273,7 +276,8 @@ class MoeConfig:
     three matrices, ``3 * d * d_ff`` parameters; an ungated ``relu2`` expert
     two, ``2 * d * d_ff``),
     the scale on the renormalised router weights; the router's form
-    (``ops/moe.py ROUTERS``) and, of the MLP form, its width — also the
+    (``ops/moe.py ROUTERS``: the linear router over sigmoid scores or over
+    the softmax, the MLP) and, of the MLP form, its width — also the
     width of the router state the layers hand on through the scan's carry —
     and whether it has a skip choice beside the experts."""
 
@@ -418,6 +422,15 @@ class TransformerConfig:
     n_kv_heads: int = 0
     bias: bool = True             # on the projections and the FFN
     embedding_multiplier: float = 1.0
+    #: the token embedding table starts normal at this scale. 0.02 as every
+    #: other map; 1.0 where the description says that a token's own vector
+    #: has to outweigh what attention adds to it at seeded weights (Mellum
+    #: 2's: a causal average of normed values has a fixed size, 0.03-0.4 an
+    #: entry, and is nearly the same vector for every token, so beside 0.02
+    #: embeddings it is most of the stream, every router's logits take
+    #: per-expert offsets from it and top-k turns them into loads that
+    #: follow the seed: PERF.md section 6, PR 45)
+    embedding_init_std: float = 0.02
     #: the softmax scale; None = ``head_dim ** -0.5``
     attention_multiplier: Optional[float] = None
     residual_multiplier: float = 1.0
@@ -528,7 +541,8 @@ class TransformerConfig:
     def counters(self) -> Tuple[str, ...]:
         """Names of the counters the stack sows (``counters`` collection,
         ``moe``): the expert layers' own, summed over the layers."""
-        return moe_ops.counters(self.moe.skip_choice) if self.has_moe else ()
+        return moe_ops.counters(self.moe.skip_choice, self.moe.router) \
+            if self.has_moe else ()
 
     @property
     def kv_heads(self) -> int:
@@ -1241,7 +1255,8 @@ class Transformer(nn.Module):
             cfg.d_model,
             dtype=dt,
             embedding_init=nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.02), ("vocab", "embed")
+                nn.initializers.normal(stddev=cfg.embedding_init_std),
+                ("vocab", "embed")
             ),
             name="tok_emb",
         )

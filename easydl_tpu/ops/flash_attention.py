@@ -77,23 +77,28 @@ straight-line code that the compiler schedules across pairs. Longer
 sequences take one Q-block (in the backward: one K-block) per grid cell and
 loop at run time.
 
-The band path. Under a ``window`` of at most a block's keys on a square
-problem (``s_q == s_k``: a training step's window layers) a block meets only
-its neighbour, and the three kernels have bodies of their own
+The band path. Under a ``window`` of at most a grid cell's keys on a square
+problem (``s_q == s_k``: a training step's window layers) a cell meets only
+ONE neighbour, and the three kernels have bodies of their own
 (``_band_fwd_kernel``, ``_band_dq_kernel``, ``_band_dkv_kernel``; the rule
-is :func:`choose_blocks`', from ``window``, ``s_q``, ``s_k`` alone; a wider
-window or a rectangle keeps the loops above). A grid cell takes ``rows``
+is :func:`choose_blocks`', from ``window``, ``s_q``, ``s_k`` alone; a window
+wider than a cell's ``BAND_ROWS`` or a rectangle keeps the loops above). A
+grid cell takes ``rows``
 rows — Q rows in the forward and dq, K rows in dk/dv — and beside them only
-the ``reach`` rows the band reaches: the block before them of K and V, the
+the ``reach`` rows the band reaches, the fewest whole blocks that hold the
+window (512 rows for Laguna's window of 512, 1,024 for Mellum 2's of 1,024:
+a window wider than a block is a wider neighbour, not another kernel): the
+block before them of K and V, the
 block after them of q, O, dO and ``lse``. The neighbour is the same array
 handed to the call a second time under a block index of its own, clipped at
 the sequence's ends; no array is padded, shifted or copied in front of a
 kernel, and nothing else of the sequence is in VMEM. Inside a cell,
 sub-blocks of ``sub`` rows each take their band as a few static slices
 (``_band_pieces``): for 256 queries under window 512 the 768 keys ``[start
-− 512, start + 256)``, cut where a mask starts or stops being needed — the
-far edge crosses the first 256, the diagonal the last 256, the 256 between
-are multiplied as they are. No loop and no traced bound: the one cell
+− 512, start + 256)`` (under 1,024: the 1,280 from ``start − 1,024``), cut
+where a mask starts or stops being needed — the
+far edge crosses the first 256, the diagonal the last 256, the 256 (768)
+between are multiplied as they are. No loop and no traced bound: the one cell
 without a neighbour (the first of a sequence, the last in dk/dv) holds the
 clipped block's scores under the sentinel with one ``minimum`` a piece. With
 a sub-block's whole band in hand the forward's softmax is one pass (max,
@@ -186,7 +191,8 @@ class Band(NamedTuple):
     rows in dk/dv. ``sub``: the rows of a sub-block inside it, whose band is
     one fixed set of pieces. ``reach``: the rows of the one neighbour block
     resident beside the cell's own (the block before it for K and V, the
-    block after it for q, O, dO and ``lse``); the window is at most that."""
+    block after it for q, O, dO and ``lse``); the window is at most that,
+    and ``rows`` a whole number of them."""
     rows: int
     sub: int
     reach: int
@@ -202,7 +208,9 @@ BAND_SUB = 256
 
 #: the VMEM a band kernel may take: its cell's blocks twice and the scores
 #: of the sub-blocks in flight — 9 MB for bf16 operands at 2,048 rows,
-#: within the compiler's own 16 MB, but 25 MB for float32 ones (a v5e's
+#: within the compiler's own 16 MB, but 25 MB for float32 ones; beside a
+#: neighbour of 1,024 rows under a window of 1,024 (two sub-blocks' scores
+#: and probabilities of 1,280 x 256 in flight: 5 MB) 13 MB and 33 (a v5e's
 #: VMEM is 128 MB)
 _BAND_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
 
@@ -245,12 +253,13 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     at 256², 3/4 at 512²) than it loses to more pairs — as long as the pairs
     still unroll. Without a triangle the smaller blocks have nothing to win.
 
-    Under a ``window`` of at most a block's keys (a causal band: query i
+    Under a ``window`` of at most ``BAND_ROWS`` keys (a causal band: query i
     sees the ``window`` keys up to its own) on a square problem the three
     kernels take the band path and the result is three :class:`Band` cuts
     (``_band``): cells of ``BAND_ROWS`` rows (as many whole neighbour blocks
     as divide the sequence, at most four), sub-blocks of ``BAND_SUB``, a
-    neighbour of ``MAX_BLOCK`` rows or the caller's ``block_k``. Swept on a
+    neighbour of ``MAX_BLOCK`` rows — of the fewest whole blocks that hold a
+    wider window — or the caller's ``block_k``. Swept on a
     v5e at ``[2, 8192, 64 x 128]`` bf16, window 512, ms a call forward / dq
     / dkv (PERF.md section 6, PR 34; the kernels' own events in a trace):
 
@@ -268,7 +277,31 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     scores and the last one's softmax overlap nothing) over more rows and
     read a neighbour's K and V once for more of them.
 
-    Under a window wider than a block, or where ``s_q != s_k``, the looped
+    The same sweep at ``[2, 8192, 32 x 128]`` bf16 under a window of 1,024
+    beside a neighbour of 1,024 rows (PERF.md section 6, PR 45; the looped
+    backward there is ONE kernel, ``swa_bwd``):
+
+        rows / sub   forward    dq     dkv
+        looped         4.32      7.70 (both)   (512 x 512)
+        1024 / 128     2.67    3.31    3.67
+        1024 / 256     2.51    2.78    3.67
+        1024 / 512     2.89    3.31    4.31
+        2048 / 128     2.44    3.47    3.48
+        2048 / 256     2.42    2.71    3.59   <- taken
+        2048 / 512     2.85    3.24    4.26
+        4096 / 128     2.31    3.56    3.43
+        4096 / 256     2.37    2.67    3.56
+        4096 / 512     2.79    3.20    4.26
+
+    The cut that is fastest at 512 is within 1.4% of the fastest at 1,024
+    (8.72 ms the three against 8.60 at 4,096 / 256), so one pair of
+    constants holds for both; a sub-block of 256 computes 1,280 keys for the
+    1,024 a row sees, a quarter over where 512 computes a half.
+
+    Under a window wider than a cell (over ``BAND_ROWS`` keys: no neighbour
+    within a cell's rows holds it), where a caller's ``block_k`` does not
+    hold the window, or where ``s_q != s_k`` (a decode's or a prefix's
+    rectangle), the looped
     kernels walk the band's blocks. Swept on a v5e at ``[2, 8192, 64 x
     128]``, window 512 (PERF.md section 6, PR 31): the forward and dq are
     fastest at 512 x 512 like the plain kernels (7.6 ms a call against 10.8
@@ -300,17 +333,26 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
 
 def _band(s: int, window: int, block_q: Optional[int],
           block_k: Optional[int]) -> Optional[Band]:
-    """The band path's cut of a square problem, or None where a block does
-    not hold the window (the looped kernels then walk the band's blocks). A
-    caller's ``block_k`` is the neighbour's rows (``reach``), its
-    ``block_q`` a sub-block's."""
+    """The band path's cut of a square problem, or None where no neighbour
+    within a cell's rows holds the window (the looped kernels then walk the
+    band's blocks). A caller's ``block_k`` is the neighbour's rows
+    (``reach``), its ``block_q`` a sub-block's. Where the caller names no
+    neighbour and the window is wider than a block, ``reach`` is the fewest
+    whole blocks that hold it and divide the sequence (1,024 rows for a
+    window of 513 to 1,024), no more than ``BAND_ROWS``."""
     reach = _pick_block(s, block_k or MAX_BLOCK)
-    if reach is None or window > reach:
+    if reach is None:
         return None
+    if window > reach:
+        reach = None if block_k else next(
+            (r for r in range(2 * reach, BAND_ROWS + 1, reach)
+             if r >= window and s % r == 0), None)
+        if reach is None:
+            return None
     sub = _pick_block(reach, block_q or BAND_SUB)
     if sub is None or (sub > 128 and sub % 128):
         return None
-    cells = max(c for c in range(1, BAND_ROWS // MAX_BLOCK + 1)
+    cells = max(c for c in range(1, BAND_ROWS // max(reach, MAX_BLOCK) + 1)
                 if s // reach % c == 0)
     return Band(cells * reach, sub, reach)
 
@@ -928,7 +970,7 @@ def _bwd(
 
 
 # ---------------------------------------------------------------------------
-# the band path: a window of at most a block's keys, s_q == s_k
+# the band path: a window of at most a cell's keys, s_q == s_k
 # ---------------------------------------------------------------------------
 
 
@@ -1346,7 +1388,8 @@ def flash_attention(
     ``window`` (with ``causal``): query i sees only the ``window`` keys up
     to its own, ``0 <= i + (s_k - s_q) - j < window``. K-blocks (Q-blocks in
     the backward) wholly outside that band are not visited — on a square
-    problem whose window a block holds not even resident (the band path) —
+    problem whose window a cell's neighbour holds not even resident (the
+    band path) —
     and the calls carry names of their own (``swa_fwd``, ``swa_bwd_dq``,
     ``swa_bwd_dkv``; looped, ``swa_bwd``).
 

@@ -2,10 +2,12 @@
 
 A fine-grained mixture-of-experts FFN as DeepSeek-V3-class models have it
 (Laguna's ``sparse`` layers): a router over ALL ``experts_total`` experts in
-float32 (sigmoid scores, the ``k`` largest, their scores renormalised and
-scaled), experts of a described form (:data:`EXPERT_FORMS`: SwiGLU, three
+float32 (one of three described forms, below: sigmoid scores, the ``k``
+largest, their scores renormalised and scaled; the same over a softmax; an
+MLP's softmax top-1), experts of a described form (:data:`EXPERT_FORMS`: SwiGLU, three
 matrices, or NemotronH's ungated ``relu(h W_up)^2 W_down``, two), and a
-shared expert of the same form that every token passes through.
+shared expert of the same form that every token passes through (none where
+``shared_d_ff`` is 0: Mellum 2's layers share nothing).
 The layer holds a contiguous range ``experts_held = [lo, hi)`` of the routed
 experts — all of them, or one chip's share of an expert-parallel group — and
 computes ITS experts' part of the result: the choices that fall in the range
@@ -38,7 +40,7 @@ No token is ever dropped, and the layer moves the rows that landed, not the
 rows that could. A token's ``k`` choices are distinct experts, so at most
 ``min(k, held)`` of them fall here — ``tokens x min(k, held)`` rows
 (:func:`rows_bound`) — while ``k x held / total`` do on average (one in
-Laguna's eight-chip group). So the sort is worked through in pieces of twice
+Laguna's eight-chip group, two in Mellum 2's four-chip group). So the sort is worked through in pieces of twice
 the expected load (:func:`piece_rows`; the bound itself where all experts are
 held): one function of a piece's rows, run ``ceil(landed / piece)`` times —
 once for a balanced load, again and again up to the bound where everything
@@ -67,6 +69,14 @@ renormalised`` is the one above; with ``selection_bias`` (DeepSeek-V3's
 ``router_bias [experts_total]`` at zero that takes no gradient, and the
 weights are the chosen experts' scores without it (:func:`route`; the bias's
 load-driven update is a training recipe's and is not here).
+``linear-softmax-renormalised`` (Mellum 2's; the Qwen3-MoE / Mixtral
+convention with ``norm_topk_prob``) is the same linear router with the softmax
+over ALL experts for its scores: the ``k`` largest, each weight its
+probability over the chosen ones' sum — the softmax over the chosen logits
+alone — times ``scaling``; no selection bias (it raises); the entropy is the
+softmax's own, and the layer counts one thing more, ``router_chosen_mass``,
+the mean softmax mass on the chosen ``k`` before the renormalisation (``k /
+experts`` at uniform logits).
 ``mlp-softmax-top1`` is ZAYA's
 (:func:`route_mlp`): the normed input projected DOWN to ``router_hidden``
 (with a bias), the previous layer's router state added to it through a learned
@@ -115,7 +125,8 @@ COUNTERS = ("moe_dropped", "moe_rows_per_token", "moe_load_max_over_mean",
             "moe_buffer_fill", "router_entropy", "moe_overflow",
             "moe_tile_fill")
 #: the router's forms
-ROUTERS = ("linear-sigmoid-renormalised", "mlp-softmax-top1")
+ROUTERS = ("linear-sigmoid-renormalised", "mlp-softmax-top1",
+           "linear-softmax-renormalised")
 #: an expert's forms, the shared expert's too: ``swiglu`` — three matrices,
 #: ``(silu(h W_gate) * (h W_up)) W_down`` — and ``relu2`` — two, ungated,
 #: ``relu(h W_up)^2 W_down`` (NemotronH's ``mlp_hidden_act``). The routed
@@ -145,10 +156,14 @@ def _zero_column_sums(init):
     return centred
 
 
-def counters(skip_choice: bool = False) -> Tuple[str, ...]:
-    """The names of the vector a layer returns: :data:`COUNTERS`, and the
-    share of tokens that took the skip choice where the router has one."""
-    return COUNTERS + (("moe_skipped",) if skip_choice else ())
+def counters(skip_choice: bool = False,
+             router: str = ROUTERS[0]) -> Tuple[str, ...]:
+    """The names of the vector a layer returns: :data:`COUNTERS`, the share
+    of tokens that took the skip choice where the router has one, and the
+    softmax mass on the chosen ``k`` where the router is the linear
+    softmax."""
+    return COUNTERS + (("moe_skipped",) if skip_choice else ()) \
+        + (("router_chosen_mass",) if router == ROUTERS[2] else ())
 
 
 #: a piece of the sort holds this many times the rows expected to land on
@@ -175,19 +190,21 @@ def piece_rows(tokens: int, k: int, held: int, total: int) -> int:
 
 
 def route(h: jax.Array, kernel: jax.Array, k: int, scaling: float,
-          bias: jax.Array | None = None):
+          bias: jax.Array | None = None, softmax: bool = False):
     """``(logits [T, E] float32, chosen [T, k] int32, weights [T, k]
     float32)`` of tokens ``h [T, D]``: logits in float32 at ``highest``
     precision whatever ``h``'s dtype (on a TPU a float32 product is
-    otherwise made of bf16 passes), sigmoid scores, the ``k`` largest, each
-    weight its score over the chosen scores' sum times ``scaling``. With a
+    otherwise made of bf16 passes), sigmoid scores — or, with ``softmax``,
+    the softmax over ALL experts — the ``k`` largest, each weight its score
+    over the chosen scores' sum times ``scaling`` (of a softmax: the softmax
+    over the chosen logits alone). With a
     selection ``bias [E]`` (DeepSeek-V3's ``noaux_tc``) the ``k`` largest of
     ``scores + bias`` are chosen and the weights are the chosen experts'
     scores WITHOUT it: the bias selects and does not weigh, and takes no
     gradient."""
     logits = jnp.dot(h.astype(jnp.float32), kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.softmax(logits, -1) if softmax else jax.nn.sigmoid(logits)
     if bias is None:
         top, chosen = jax.lax.top_k(scores, k)
     else:
@@ -872,9 +889,9 @@ class MoeMlp(nn.Module):
         if self.expert_form not in EXPERT_FORMS:
             raise ValueError(f"expert form {self.expert_form!r} is none of "
                              f"{EXPERT_FORMS}")
-        mlp = self.router == ROUTERS[1]
-        if mlp and (self.k != 1 or self.selection_bias) \
-                or self.skip_choice and not mlp:
+        mlp, softmax = self.router == ROUTERS[1], self.router == ROUTERS[2]
+        if mlp and self.k != 1 or self.skip_choice and not mlp \
+                or self.selection_bias and (mlp or softmax):
             raise ValueError(f"router {self.router!r} with k={self.k}, "
                              f"skip_choice={self.skip_choice}, "
                              f"selection_bias={self.selection_bias}")
@@ -907,13 +924,18 @@ class MoeMlp(nn.Module):
                 bias = self.param("router_bias", nn.with_logical_partitioning(
                     nn.initializers.zeros_init(), (None,)),
                     (self.experts_total,)) if self.selection_bias else None
-                # (the bias by keyword, and only where there is one: the
-                # benchmark's tests stand a four-argument `route` in)
+                # (the bias and the form by keyword, and only where they
+                # are not the default: the benchmark's tests stand a
+                # four-argument `route` in)
                 logits, chosen, weights = route(
                     h, kernel, self.k, self.scaling,
-                    **({} if bias is None else {"bias": bias}))
-                share = jax.nn.sigmoid(logits)
-                share = share / jnp.sum(share, -1, keepdims=True)
+                    **({} if bias is None else {"bias": bias}),
+                    **({"softmax": True} if softmax else {}))
+                if softmax:
+                    share = jax.nn.softmax(logits, -1)
+                else:
+                    share = jax.nn.sigmoid(logits)
+                    share = share / jnp.sum(share, -1, keepdims=True)
             self.sow("intermediates", "router_in", h)
             self.sow("intermediates", "router_logits", logits)
             self.sow("intermediates", "chosen", chosen)
@@ -934,7 +956,7 @@ class MoeMlp(nn.Module):
                       f"{choose_tiles(piece, held, d, self.d_ff, dt.itemsize)}"
                       f" inward, "
                       f"{choose_tiles(piece, held, self.d_ff, d, dt.itemsize)}"
-                      f" back")
+                      f" back; router {self.router}, top-{self.k}")
         y, stats = _over_expert_shards(
             functools.partial(_routed_part, total=choices),
             tokens, held)(h, chosen, weights, experts, jnp.int32(lo))
@@ -964,5 +986,9 @@ class MoeMlp(nn.Module):
         if self.skip_choice:
             counted.append(jnp.mean(chosen == self.experts_total,
                                     dtype=jnp.float32))
+        if softmax:
+            # what the renormalisation restores: k / experts at uniform logits
+            counted.append(jnp.mean(jnp.sum(
+                jnp.take_along_axis(share, chosen, -1), -1)))
         counters = jnp.stack(counted)
         return y.reshape(batch, seq, d), counters, state

@@ -55,6 +55,10 @@ NEMOTRON = ("nemotron_h", dict(
     size="nano-30b-a3b", seq_len=8192, vocab=16384, remat_policy="full",
     hybrid_override_pattern="MEMEM*EME", experts_held=(0, 8), **_CHIP))
 
+MELLUM = ("mellum", dict(
+    size="2-12b-a2.5b", seq_len=8192, vocab=24576, remat_policy="full",
+    layer_types=["sliding_attention"] * 3 + ["full_attention"],
+    experts_held=(0, 16), **_CHIP))
 #: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
 #: limit is its own compiled size and a little: medium's steps 15.292
@@ -105,6 +109,7 @@ PROGRAMS = {
     # where XLA's kept float32 copies. The limit is the sum and a little, as
     # the others' (16.3 until PR 43, 14.3 until PR 44)
     "nemotron_1x2": (NEMOTRON, "dp=1", 2, 1, "adamw", 14.05),
+    "mellum_1x2": (MELLUM, "dp=1", 2, 1, "adamw", 12.1),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -127,6 +132,11 @@ ATTENTION_KERNELS = {
     "zaya_1x2": {"flash_bwd": 1, "flash_fwd": 1},
     "joyai_1x2": {"mla_bwd": 3, "mla_fwd": 3},
     "nemotron_1x2": {"flash_bwd": 1, "flash_fwd": 1},
+    # the three window-1,024 layers are one scanned run on the band path
+    # (``swa_fwd`` twice: remat makes it again), the full layer keeps its
+    # forward's results
+    "mellum_1x2": {"flash_bwd": 1, "flash_fwd": 1, "swa_bwd_dkv": 1,
+                   "swa_bwd_dq": 1, "swa_fwd": 2},
 }
 
 #: name -> the Mamba-2 mixer's kernels (ops/ssd.py: the scan's ``ssd_*``,

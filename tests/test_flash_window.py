@@ -1,8 +1,9 @@
 """The window in the flash kernels (``ops/flash_attention.py``, interpreted)
 and in the XLA path against the mask written out entry by entry: the band
 path and the looped kernels under a window, forward and gradients, and the
-rule that chooses between them from the shapes. (Laguna's window layers run
-these; the model's own tests are ``tests/test_laguna.py``.)"""
+rule that chooses between them from the shapes. (Laguna's window-512 and
+Mellum 2's window-1,024 layers run these; the models' own tests are
+``tests/test_laguna.py`` and ``tests/test_mellum.py``.)"""
 
 import functools
 
@@ -67,6 +68,14 @@ WINDOW_CASES = [
     "s_q,s_k,block_q,block_k,window,heads,d,kv,path", WINDOW_CASES)
 def test_window_kernels_against_the_written_out_mask(
         s_q, s_k, block_q, block_k, window, heads, d, kv, path):
+    _against_the_written_out_mask(s_q, s_k, block_q, block_k, window, heads,
+                                  d, kv, path)
+
+
+def _against_the_written_out_mask(s_q, s_k, block_q, block_k, window, heads,
+                                  d, kv, path):
+    """Forward, dq and dk / dv of the kernels (interpreted) and of the XLA
+    path against the dense band; returns the cut that was taken."""
     kv_shape = (2, s_k, kv or heads, d)
     q, k, v = normal(0, (2, s_q, heads, d), kv_shape, kv_shape)
     if kv:
@@ -92,6 +101,61 @@ def test_window_kernels_against_the_written_out_mask(
     np.testing.assert_allclose(np.asarray(xla), want, atol=2e-5)
     for a, b in zip(g_mine, g_xla):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+    return took
+
+
+@pytest.fixture
+def blocks_of_16(monkeypatch):
+    """The rule's own cut at a test's size: blocks of 16 rows, cells of 64,
+    sub-blocks of 8 (``MAX_BLOCK`` 512, ``BAND_ROWS`` 2,048 and ``BAND_SUB``
+    256 on the chip), so that a window wider than a block is a few rows."""
+    from easydl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "MAX_BLOCK", 16)
+    monkeypatch.setattr(fa, "BAND_ROWS", 64)
+    monkeypatch.setattr(fa, "BAND_SUB", 8)
+
+
+@pytest.mark.parametrize("s,window,heads,kv,want", [
+    # two blocks; one block and one row: a neighbour of two blocks, two to a
+    # cell, so that the second and fourth cell read a neighbour's rows
+    (128, 32, 2, None, Band(64, 8, 32)),
+    (128, 17, 2, None, Band(64, 8, 32)),
+    # two blocks and one row: no three blocks divide the sequence, so four,
+    # the cell itself; key/value heads repeated as the models hand them over
+    (128, 33, 4, 2, Band(64, 8, 64)),
+    # three blocks, which divide this sequence: a cell is its neighbour
+    (144, 40, 2, None, Band(48, 8, 48)),
+], ids=["two-blocks", "a-block-and-a-row", "two-blocks-and-a-row",
+        "three-blocks"])
+def test_the_band_holds_a_window_wider_than_a_block(blocks_of_16, s, window,
+                                                    heads, kv, want):
+    """Forward, dq and dk / dv on the band path under a window wider than a
+    block, no caller's blocks: the rule's own neighbour of whole blocks."""
+    took = _against_the_written_out_mask(s, s, None, None, window, heads, 16,
+                                         kv, BAND)
+    assert took == (want,) * 3
+
+
+def test_the_band_at_the_chips_blocks_under_a_window_of_two(monkeypatch):
+    """One head of 128 at the blocks the chip runs, window 1,024: cells of
+    1,024 rows (``BAND_ROWS`` lowered so that the sequence is two) beside a
+    neighbour of 1,024, sub-blocks of 256 whose band is 1,280 keys."""
+    from easydl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "BAND_ROWS", 1024)
+    took = _against_the_written_out_mask(2048, 2048, None, None, 1024, 1, 128,
+                                         None, BAND)
+    assert took == (Band(1024, 256, 1024),) * 3
+
+
+def test_a_window_wider_than_a_cell_stays_looped(blocks_of_16):
+    """Past the band's cell (``BAND_ROWS``) no neighbour holds the window:
+    the looped kernels walk the band's blocks, as on a rectangle."""
+    took = _against_the_written_out_mask(256, 256, None, None, 65, 2, 16, None,
+                                         LOOP)
+    assert took == ((16, 16),) * 3
+    assert choose_blocks(128, 256, True, window=32) == ((16, 16),) * 3
 
 
 def test_window_blocks_are_chosen_from_the_window():
@@ -100,7 +164,11 @@ def test_window_blocks_are_chosen_from_the_window():
     # a caller's blocks hold for all three kernels, window or not
     assert choose_blocks(64, 64, True, 16, 16, window=8) == (
         Band(rows=64, sub=16, reach=16),) * 3
-    # without a window, and under one wider than a block: the plain rule
+    # under a window wider than a block: the fewest whole blocks that hold
+    # it beside a cell (Mellum 2's window layers)
+    assert choose_blocks(8192, 8192, True, window=1024) == (
+        Band(rows=2048, sub=256, reach=1024),) * 3
+    # without a window, and under one wider than a cell: the plain rule
     assert choose_blocks(8192, 8192, True) == ((512, 512),) * 3
     assert choose_blocks(8192, 8192, True, window=4096) == ((512, 512),) * 3
     with pytest.raises(ValueError, match="window"):
@@ -112,15 +180,23 @@ def test_band_or_loop_is_chosen_from_the_shapes_alone():
     """The band path where a block holds the window and the problem is
     square; the looped kernels' blocks, as they were, everywhere else."""
     looped = ((512, 512), (512, 512), (512, 256))
-    # one key more than a block holds; a rectangle (a decode's, a prefix's)
-    assert choose_blocks(8192, 8192, True, window=513) == ((512, 512),) * 3
+    # one key more than a cell holds; a rectangle (a decode's, a prefix's);
+    # a caller's neighbour that does not hold the window
+    assert choose_blocks(8192, 8192, True, window=2049) == ((512, 512),) * 3
     assert choose_blocks(4096, 8192, True, window=512) == looped
+    assert choose_blocks(4096, 8192, True, window=1024) == ((512, 512),) * 3
     assert choose_blocks(8192, 8192, True, 512, 256, window=512) == (
         (512, 256),) * 3
     for s, window, want in [
             (8192, 512, Band(2048, 256, 512)), (8192, 128, Band(2048, 256, 512)),
             (4096, 512, Band(2048, 256, 512)), (1536, 300, Band(1536, 256, 512)),
-            (2560, 512, Band(512, 256, 512)), (256, 64, Band(256, 256, 256))]:
+            (2560, 512, Band(512, 256, 512)), (256, 64, Band(256, 256, 256)),
+            # a window wider than a block: a neighbour of two blocks (three
+            # where the sequence is not a whole number of twos, the cell
+            # then the neighbour itself), of four past 1,024
+            (8192, 513, Band(2048, 256, 1024)), (4096, 1024, Band(2048, 256, 1024)),
+            (1536, 600, Band(1536, 256, 1536)), (3072, 1024, Band(1024, 256, 1024)),
+            (8192, 1025, Band(2048, 256, 2048)), (1024, 1024, Band(1024, 256, 1024))]:
         assert choose_blocks(s, s, True, window=window) == (want,) * 3
     # a cell's rows are whole neighbour blocks, a neighbour whole sub-blocks
     assert choose_blocks(256, 256, True, 8, 16, window=16) == (

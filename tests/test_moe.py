@@ -393,6 +393,10 @@ def test_grouped_products_in_blocks_of_columns(monkeypatch):
     # tiles (the second holds 832 and 1,280), not 1,536 + 320 and 2,176 + 512
     (12288, 8, 2688, 1856, 2, (128, 1024)),
     (12288, 8, 1856, 2688, 2, (128, 1408)),
+    # Mellum 2's: 65,536 rows over 16 experts, 2304 x 896 (seven lane tiles
+    # of columns, the block whole) and back — 2,048 rows a group allow 256
+    (65536, 16, 2304, 896, 2, (256, 896)),
+    (65536, 16, 896, 2304, 2, (256, 2304)),
     # the tier-1 sizes: tiles of 128 rows or the rows themselves
     (1024, 4, 16, 8, 4, (128, 8)),
     (384, 4, 16, 24, 4, (128, 24)),
@@ -601,3 +605,125 @@ def test_a_relu2_layer_has_two_leaves_an_expert():
     with pytest.raises(ValueError, match="expert form"):
         MoeMlp(experts_total=8, experts_held=(0, 8), d_ff=24, shared_d_ff=0,
                k=2, expert_form="geglu").init(jax.random.PRNGKey(0), x)
+
+
+# ------------------------------------------- the linear softmax router
+SOFTMAX = "linear-softmax-renormalised"
+
+
+@pytest.mark.parametrize("scaling", [1.0, 2.5])
+def test_softmax_routing_invariants(scaling):
+    """``route(..., softmax=True)``: the ``k`` largest of the softmax over
+    ALL experts, each weight its probability over the chosen ones' sum times
+    the scaling — the softmax over the chosen logits alone."""
+    tokens, d, total, k = 64, 16, 16, 4
+    h, kernel = normal(3, (tokens, d), (d, total))
+    logits, chosen, weights = jax.jit(
+        functools.partial(route, softmax=True), static_argnums=(2, 3))(
+            np.asarray(h).astype(jnp.bfloat16), kernel, k, scaling)
+    assert logits.dtype == jnp.float32 and logits.shape == (tokens, total)
+    logits, chosen, weights = (np.asarray(a) for a in (logits, chosen,
+                                                       weights))
+    assert all(len(set(row)) == k for row in chosen)
+    np.testing.assert_array_equal(
+        np.sort(chosen, -1), np.sort(np.argsort(-logits, -1)[:, :k], -1))
+    np.testing.assert_allclose(weights.sum(-1), scaling, rtol=1e-6)
+    picked = np.take_along_axis(logits, chosen, -1).astype(np.float64)
+    over_chosen = np.exp(picked - picked.max(-1, keepdims=True))
+    np.testing.assert_allclose(
+        weights, scaling * over_chosen / over_chosen.sum(-1, keepdims=True),
+        rtol=1e-5)
+
+
+def test_softmax_routing_gradient_passes_the_renormalisation():
+    """The weights' gradient by the router is the gradient of the softmax
+    over the chosen logits (the chosen sets held fixed): what the other
+    experts' logits do to the whole softmax cancels in the quotient."""
+    tokens, d, total, k = 32, 16, 16, 4
+    h, kernel, ct = normal(4, (tokens, d), (d, total), (tokens, k))
+    chosen = jax.jit(functools.partial(route, softmax=True),
+                     static_argnums=(2, 3))(h, kernel, k, 1.0)[1]
+
+    def mine(kernel):
+        return jnp.sum(route(h, kernel, k, 1.0, softmax=True)[2] * ct)
+
+    def by_hand(kernel):
+        picked = jnp.take_along_axis(
+            jnp.dot(h, kernel, precision="highest"), chosen, -1)
+        return jnp.sum(jax.nn.softmax(picked, -1) * ct)
+
+    got, want = jax.jit(jax.grad(mine))(kernel), jax.jit(
+        jax.grad(by_hand))(kernel)
+    assert np.abs(np.asarray(want)).max() > 1e-3
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+
+
+def _softmax_layer(held=(0, 16), **more):
+    return MoeMlp(experts_total=16, experts_held=held, d_ff=8, shared_d_ff=0,
+                  k=4, router=SOFTMAX, **more)
+
+
+def test_softmax_router_takes_no_selection_bias():
+    x, = normal(1, (2, 16, 16))
+    with pytest.raises(ValueError, match="selection_bias=True"):
+        _softmax_layer(selection_bias=True).init(jax.random.PRNGKey(0), x)
+    with pytest.raises(ValueError, match="skip_choice=True"):
+        _softmax_layer(skip_choice=True).init(jax.random.PRNGKey(0), x)
+
+
+def test_softmax_layer_counts_the_chosen_mass():
+    """One counter more where the router is the linear softmax:
+    ``router_chosen_mass``, k / experts at zero logits (and the entropy
+    log(experts)); between that and 1 at seeded weights; the other forms'
+    vectors are as long as they were."""
+    from easydl_tpu.ops.moe import counters
+
+    names = counters(router=SOFTMAX)
+    assert names == COUNTERS + ("router_chosen_mass",)
+    assert counters() == COUNTERS and counters(True) == COUNTERS + (
+        "moe_skipped",)
+    layer = _softmax_layer()
+    x, = normal(1, (2, 16, 16))
+    params = jax.jit(layer.init)(jax.random.PRNGKey(2), x)
+    assert sorted(params["params"]) == ["router", "w_down", "w_gate", "w_up"]
+    apply = jax.jit(layer.apply)
+    named = dict(zip(names, np.asarray(apply(params, x)[1])))
+    assert len(named) == len(names)
+    assert 4 / 16 < named["router_chosen_mass"] < 1.0
+    assert named["moe_dropped"] == 0.0 and named["moe_rows_per_token"] == 4.0
+    flat = jax.tree.map(lambda a: a, shd.unbox(params))
+    flat["params"]["router"] = jnp.zeros_like(flat["params"]["router"])
+    named = dict(zip(names, np.asarray(apply(flat, x)[1])))
+    assert named["router_chosen_mass"] == pytest.approx(4 / 16, rel=1e-6)
+    assert named["router_entropy"] == pytest.approx(np.log(16), rel=1e-6)
+
+
+def test_four_shares_of_a_softmax_layer_sum_to_the_whole():
+    """Nothing shared: the parts the four shares ``[0, 4) .. [12, 16)`` of
+    one layer give (each holding its slice of the same expert weights, the
+    router whole) add up to the layer that holds all sixteen, and so do the
+    rows that landed; the whole is the loop over the experts under the
+    softmax's weights."""
+    whole = _softmax_layer()
+    x, = normal(5, (2, 16, 16))
+    params = shd.unbox(jax.jit(whole.init)(jax.random.PRNGKey(3), x))["params"]
+    (y, counted, _), sown = jax.jit(lambda p, x: whole.apply(
+        {"params": p}, x, mutable=["intermediates"]))(params, x)
+    names = ("moe_rows_per_token",)
+    total, rows = jnp.zeros_like(y), 0.0
+    for lo in range(0, 16, 4):
+        share = {k: (v if k == "router" else v[lo:lo + 4])
+                 for k, v in params.items()}
+        part, c, _ = jax.jit(_softmax_layer((lo, lo + 4)).apply)(
+            {"params": share}, x)
+        total, rows = total + part, rows + float(c[1])
+    np.testing.assert_allclose(np.asarray(total), np.asarray(y), atol=1e-6)
+    assert rows == pytest.approx(float(counted[1])) and rows == 4.0
+    kept = sown["intermediates"]
+    chosen, logits = kept["chosen"][0], kept["router_logits"][0]
+    weights = jax.nn.softmax(jnp.take_along_axis(logits, chosen, -1), -1)
+    want = jax.jit(_loop_over_experts)(
+        x.reshape(-1, 16), chosen, weights, params["w_gate"], params["w_up"],
+        params["w_down"])
+    np.testing.assert_allclose(np.asarray(y).reshape(want.shape),
+                               np.asarray(want), atol=1e-5)

@@ -144,10 +144,13 @@ def test_the_kernels_compile_at_two_head_sizes(v5e_2x2):
     (15488, 8, 2048, 2048),    # ZAYA1's cell: a piece over 8 experts of 2048
     (32768, 32, 2048, 512),    # Laguna's: 32 experts, the way in
     (32768, 32, 512, 2048),    # and out
-], ids=["zaya-8x2048x2048", "laguna-32x2048x512", "laguna-32x512x2048"])
+    (65536, 16, 2304, 896),    # Mellum 2's: 16 experts, seven lane tiles in
+    (65536, 16, 896, 2304),    # and out
+], ids=["zaya-8x2048x2048", "laguna-32x2048x512", "laguna-32x512x2048",
+        "mellum-16x2304x896", "mellum-16x896x2304"])
 def test_grouped_products_compile_at_the_cells_shapes(v5e_2x2, rows, groups,
                                                      contract, cols, form):
-    """The expert layer's three kernel forms at the two cells' shapes, bf16,
+    """The expert layer's three kernel forms at three cells' shapes, bf16,
     with the tiles ``choose_tiles`` gives them: Mosaic takes each (the
     weight block resident, up to 34 MiB of VMEM for ZAYA1's weight
     gradient) as one kernel under its own name."""
@@ -354,6 +357,49 @@ def test_lagunas_attention_kinds_compile_at_8k(v5e_2x2, heads, window, rot,
         assert any(f"/{name}/" in line for line in calls), name
     other = ("flash_fwd", "swa_fwd")[window is None]
     assert not any(f"/{other}/" in line for line in calls)
+    assert not any("/flash_bwd_dq/" in line for line in calls)
+
+
+# ---------------------------------------------------------------- Mellum 2
+@pytest.mark.parametrize("window,yarn,names", [
+    (1024, None, ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")),
+    (None, dict(factor=16.0, original_max_position_embeddings=8192,
+                beta_fast=32.0, beta_slow=1.0,
+                attention_factor=1.2772588722239782),
+     ("flash_fwd", "flash_bwd")),
+], ids=["window-1024", "full-whole-head-yarn"])
+def test_mellums_attention_kinds_compile_at_8k(v5e_2x2, window, yarn, names):
+    """Mellum 2's two attention kinds at the cell's shape, forward and
+    backward: 32 query heads over 4 key/value heads of 128 at 8,192. The
+    window of 1,024 is wider than a block and takes the BAND path all the
+    same — three kernels under the window's names, cells of 2,048 rows
+    beside a neighbour of 1,024, within the kernels' VMEM — where it fell
+    through to the looped ``swa_bwd`` before; the full layer's backward is
+    ONE call, its rotary the kernel over the whole head."""
+    from easydl_tpu.ops.flash_attention import Band, choose_blocks
+    from easydl_tpu.ops.rope import rope_tables
+
+    assert choose_blocks(8192, 8192, True, window=1024) == (
+        Band(rows=2048, sub=256, reach=1024),) * 3
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        rope = rope_tables(8192, 128, 500000.0, None, yarn)
+        return multihead_attention(
+            q, k, v, causal=True, impl="flash", rope=rope,
+            window=window).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in names + ("rope_fwd", "rope_bwd"):
+        assert any(f"/{name}/" in line for line in calls), name
+    other = ("flash_fwd", "swa_fwd")[window is None]
+    assert not any(f"/{other}/" in line for line in calls)
+    assert not any("/swa_bwd/" in line for line in calls)
     assert not any("/flash_bwd_dq/" in line for line in calls)
 
 

@@ -252,11 +252,18 @@ def test_the_medium_steps_fit_the_chip(v5e_2x2, rehearse, program):
      "nemotron_1x2: Mamba-2 kernels {'conv1d_bwd': 9, 'conv1d_fwd': 18, "),
     ("nemotron_1x2", {"conv1d_bwd": -3},  # a layer's sums by XLA
      "nemotron_1x2: Mamba-2 kernels {'conv1d_bwd': 6, 'conv1d_fwd': 12, "),
+    ("mellum_1x2", {}, None),
+    # the window of 1,024 on the looped kernels, as before the band held it
+    ("mellum_1x2", {"swa_bwd_dq": -1, "swa_bwd_dkv": -1, "swa_bwd": 1},
+     "mellum_1x2: attention kernels {'flash_bwd': 1, 'flash_fwd': 1, "
+     "'swa_bwd': 1, 'swa_fwd': 2}, not {'flash_bwd': 1, 'flash_fwd': 1, "
+     "'swa_bwd_dkv': 1, 'swa_bwd_dq': 1, 'swa_fwd': 2}"),
 ], ids=["as-gated", "a-forward-more", "joyai-a-forward-more",
         "another-backward", "hybrid-as-gated", "nemotron-as-gated",
         "hybrid-the-numpy-scan", "nemotron-a-scan-forward-less",
         "a-scan-kernel-where-none-is", "hybrid-the-numpy-convolutions",
-        "nemotron-a-convolution-doubled", "nemotron-a-convolution-missing"])
+        "nemotron-a-convolution-doubled", "nemotron-a-convolution-missing",
+        "mellum-as-gated", "mellum-the-looped-window"])
 def test_the_script_fails_on_other_attention_kernels_than_a_cells(
         v5e_2x2, rehearse, monkeypatch, capsys, program, more, said):
     """The script is where the whole steps at the cells' sizes are gated
