@@ -197,8 +197,9 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     bound), ``router_entropy``, ``moe_overflow``, the share of the
     step's expert-layer calls whose landed rows needed more than one piece
     of the sort, ``moe_tile_fill``, the landed rows over the rows of the
-    row tiles the grouped products visited, and, where the router has a
-    skip choice, ``moe_skipped``,
+    row tiles the grouped products visited, ``moe_row_fill``, the landed
+    rows over the rows the pieces' chunk loops made for them, and, where
+    the router has a skip choice, ``moe_skipped``,
     the share of tokens that took it, and, where it is the linear softmax,
     ``router_chosen_mass``, the softmax mass on the chosen experts before
     the renormalisation; where the router's state runs through

@@ -51,6 +51,36 @@ has a row for any routing; the counter ``dropped`` (choices in the range that
 no piece gave a row) says so in every step, ``overflow`` how often more than
 one piece was needed.
 
+Half of a balanced piece is dead by construction, and only the kernels knew:
+their schedules stop at the last live tile, but XLA's row gathers between
+them ran over the whole static piece, and a row gather from HBM to HBM costs
+by the ROW, dead or live: 37.5 ns each at 2,304 lanes, 2.46 ms a call on a
+piece of 65,536 (PERF.md section 6, PR 46). Three of a layer's five gathers
+are of that kind — the rows re-ordered by token in front of each sum back
+(their source is a piece, which no fast memory holds) and the cotangent's
+rows — and run, as do the two gathers of the choices' weights (a scalar
+costs the chip what a row does), over the piece's live CHUNKS alone
+(:func:`_live_rows`): a chunk is a ninth of a piece in whole row tiles
+(:func:`chunk_rows`), the gather is a function of a chunk's rows, run for
+chunks ``0 .. ceil(live / chunk) - 1`` by a loop whose length is traced (it
+needs no derivative inside the hand-written rule) and written in place into
+a piece-sized array that starts UNWRITTEN (:func:`_unwritten`: zeros would
+be a pass over it). Rows behind the last live chunk are never made; nothing
+reads them (the kernels by their schedules and live counts, ``scale`` by
+``live``). A chunk's row costs 41
+ns — 23 to gather it into VMEM, 18 for XLA's ``dynamic-update-slice`` to put
+it in its place, a pass of its own that no fusion takes — so the loop wins by
+the rows it leaves out and by nothing else, and what is cheaper whole stays
+whole: the layer's own input ``h`` is where XLA keeps it in VMEM, and a
+whole-piece gather of it runs at 7 ns a row; the activation and the
+backward's float32 chain are passes at the memory's rate, which a copy a
+chunk eats up. The chunks are nine because a balanced load must fall INSIDE a
+chunk, not on an edge: a piece is twice the expected load, so any even split
+puts the expected count exactly on a boundary and a balanced layer would flip
+between one chunk more and one less by the step; nine puts the nearest edges
+at 0.89 and 1.11 of it, and five of nine run (``moe_row_fill`` 0.9: the
+landed rows over the rows the chunk loops made).
+
 The sums back to the tokens are formed from the rows' side. ``y[t]`` (the
 forward) and ``dh[t]`` (the gather's transpose) are sums of a piece's rows
 into their tokens' rows, accumulated in float32 from the float32-weighted rows
@@ -123,7 +153,7 @@ EXPERT_AXIS = "ep"
 #: the layer's counters, in the order of the vector it returns
 COUNTERS = ("moe_dropped", "moe_rows_per_token", "moe_load_max_over_mean",
             "moe_buffer_fill", "router_entropy", "moe_overflow",
-            "moe_tile_fill")
+            "moe_tile_fill", "moe_row_fill")
 #: the router's forms
 ROUTERS = ("linear-sigmoid-renormalised", "mlp-softmax-top1",
            "linear-softmax-renormalised")
@@ -189,6 +219,31 @@ def piece_rows(tokens: int, k: int, held: int, total: int) -> int:
     return min(rows_bound(tokens, k, held), -(-expected // TILE) * TILE)
 
 
+#: a piece's HBM-to-HBM gathers are cut into this many chunks and run over
+#: the live ones alone. ODD, so that no edge lies on the expected load —
+#: half a piece: an even split would flip a balanced layer between one chunk
+#: more and one less by the step — nine puts the nearest edges at 0.89 and
+#: 1.11 of it. On the chip at Mellum 2's piece, ms a gather of 32,768 live
+#: rows by 5 / 7 / 9 / 11 / 13 / 17 chunks: 1.65 / 1.57 / 1.53 / 1.52 /
+#: 1.76 / 1.52, and of 36,408: 1.65 / 1.57 / 1.53 / 1.78 / 2.02 / 1.70
+#: (PERF.md section 6, PR 46)
+PIECE_CHUNKS = 9
+
+
+def chunk_rows(piece: int) -> int:
+    """Rows of a chunk of a piece of ``piece`` rows: a :data:`PIECE_CHUNKS`-th
+    of it in whole row tiles (the piece itself where it is no more than a
+    tile: one chunk)."""
+    return min(piece, -(-piece // (PIECE_CHUNKS * TILE)) * TILE)
+
+
+def rows_made(n_live, piece: int):
+    """Rows the chunk loops of a piece with ``n_live`` live rows produce:
+    its live chunks' (the last chunk ends with the piece)."""
+    chunk = chunk_rows(piece)
+    return jnp.minimum(-(-n_live // chunk) * chunk, piece)
+
+
 def route(h: jax.Array, kernel: jax.Array, k: int, scaling: float,
           bias: jax.Array | None = None, softmax: bool = False):
     """``(logits [T, E] float32, chosen [T, k] int32, weights [T, k]
@@ -252,6 +307,51 @@ def _piece(p, piece, order, by_token, ends, rowed):
     sizes = jnp.diff(jnp.clip(jnp.minimum(ends, rowed) - start, 0, piece))
     return (choice, live, sizes.astype(jnp.int32),
             (at, number, jnp.clip(rowed - start, 0, piece)))
+
+
+def _unwritten(shape, dtype, after, interpret):
+    """An array nobody has written: the result of a kernel with an empty
+    body, left where it is allocated. ``jnp.zeros`` (and ``jax.lax.empty``,
+    which lowers to it) costs a pass over the array at the memory's rate;
+    the interpreter fills it with NaN. ``after``: what the loop that writes
+    into it reads, operands the kernel does not touch. They keep the call
+    where it stands — with no operand it is an invariant of every loop
+    around it: XLA hoists it out of the layers' scan and COPIES the array in
+    each layer for the chunk loop to write into (a pass again, and every
+    layer's arrays held at once) — and tell two loops' calls apart, which
+    XLA would else merge (one array and a copy a loop). The call claims no
+    side effects: a forward and the one remat makes again stay equal and
+    are merged whole (PERF.md section 6, PR 46)."""
+    return pl.pallas_call(
+        lambda *refs: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(after),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        interpret=interpret, name="unwritten")(*after)
+
+
+def _live_rows(source, at, n_live, interpret):
+    """``source[at]`` (rows or scalars) for the live chunks of ``at
+    [piece]`` alone: chunk ``c``'s rows (:func:`chunk_rows`) gathered for ``c
+    = 0 .. ceil(n_live / chunk) - 1``, a loop whose length is traced, and
+    written in place into a piece-sized array that starts unwritten. The
+    rows behind the last live chunk are never made and hold anything: what
+    reads the result stops at the live rows (the kernels by their schedules
+    and live counts, ``scale`` by ``live``). The last chunk of a piece that
+    is no whole number of them starts early and makes some of its
+    neighbour's rows again."""
+    piece = at.shape[0]
+    chunk = chunk_rows(piece)
+
+    def one(c, made):
+        start = jnp.minimum(c * chunk, piece - chunk)
+        rows = source[jax.lax.dynamic_slice_in_dim(at, start, chunk)]
+        return jax.lax.dynamic_update_slice_in_dim(made, rows, start, 0)
+
+    with jax.named_scope("live_rows"):
+        return jax.lax.fori_loop(
+            0, -(-n_live // chunk), one,
+            _unwritten((piece,) + source.shape[1:], source.dtype,
+                       (at, source), interpret))
 
 
 # ------------------------------------------------------ the grouped products
@@ -524,7 +624,8 @@ def _to_tokens(rows, scale, token_order, tokens, k, interpret):
     ``j`` — ``token_order = (by_token, number, n_live)``: the rows of
     ``rows [R, D]`` in the order of their tokens, and their choices.
 
-    The rows are put in that order (one ``[R, D]`` gather), so that a block
+    The rows are put in that order (one gather of the live chunks' rows:
+    :func:`_live_rows`), so that a block
     of tokens owns a contiguous slab of rows, and a Pallas kernel sums each
     block's slab a chunk at a time: a schedule of (block, chunk) steps, a
     block's steps one after the other and its result resident between them
@@ -535,7 +636,8 @@ def _to_tokens(rows, scale, token_order, tokens, k, interpret):
     n_blocks = -(-tokens // block)
     nowhere = n_blocks * block
     pad = -m % TILE
-    rows = jnp.pad(rows[by_token], ((0, pad), (0, 0)))
+    rows = jnp.pad(_live_rows(rows, by_token, n_live, interpret),
+                   ((0, pad), (0, 0)))
     tok = jnp.pad(jnp.where(jnp.arange(m) < n_live, number // k, nowhere),
                   (0, pad), constant_values=nowhere)
     scale = jnp.pad(scale, (0, pad))
@@ -584,10 +686,11 @@ def _over_pieces(one, n):
 # program of a process with these shapes, share one trace of each.
 @functools.partial(jax.jit, static_argnums=(0,))
 def _piece_forward(how, p, h, weights, experts, order, by_token, ends, rowed):
-    """``(y [T, D] float32, live rows, rows of the row tiles visited)`` of
-    piece ``p``: its tokens' rows, the experts' results (SwiGLU: three
-    grouped products; relu2: two), their weighted sum back into the
-    tokens."""
+    """``(y [T, D] float32, live rows, rows of the row tiles visited, rows
+    the chunk loops made)`` of piece ``p``: its tokens' rows, the experts'
+    results (SwiGLU: three grouped products; relu2: two), their weighted sum
+    back into the tokens (the rows by token: the live chunks' alone,
+    :func:`_live_rows`)."""
     piece, interpret = how
     tokens, k = weights.shape
     choice, live, sizes, token_order = _piece(p, piece, order, by_token, ends,
@@ -602,9 +705,11 @@ def _piece_forward(how, p, h, weights, experts, order, by_token, ends, rowed):
         out, _ = grouped_rows([_activation(*(a for a, _ in pre))], [w_down],
                               sizes, False, interpret)
     with jax.named_scope("combine"):
-        y = _to_tokens(out, weights.reshape(-1)[token_order[1]], token_order,
-                       tokens, k, interpret)
-    return y, jnp.sum(live, dtype=jnp.int32), visited
+        y = _to_tokens(out, _live_rows(weights.reshape(-1), token_order[1],
+                                       token_order[2], interpret),
+                       token_order, tokens, k, interpret)
+    return (y, jnp.sum(live, dtype=jnp.int32), visited,
+            rows_made(token_order[2], piece))
 
 
 def _activation(*pre):
@@ -641,8 +746,10 @@ def _piece_backward(how, p, g, h, weights, experts, order, by_token, ends,
     with jax.named_scope("dispatch"):
         x = h[tok]
     with jax.named_scope("combine"):
-        scale = jnp.where(live, weights.reshape(-1)[choice], 0.0)[:, None]
-        g_rows = g[tok]
+        scale = jnp.where(live, _live_rows(weights.reshape(-1), choice,
+                                           token_order[2], interpret),
+                          0.0)[:, None]
+        g_rows = _live_rows(g, tok, token_order[2], interpret)
     with jax.named_scope("experts"):
         *inward, w_down = experts
         pre = [grouped_rows([x], [w], sizes, False, interpret)[0]
@@ -679,18 +786,19 @@ def _piece_backward(how, p, g, h, weights, experts, order, by_token, ends,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _routed(how, h, weights, experts, order, by_token, ends, rowed):
-    """``(y [T, D], rows [] int32, tile rows [] int32)``: the experts'
-    results for the first ``rowed`` rows of the sort, weighted and summed
-    into their tokens in float32, a piece at a time (``how = (piece,
-    interpret)``; ``experts`` the held experts' leaves, a tuple by
-    :data:`EXPERT_FORMS`); the rows that were visited; and the rows of the
-    row tiles the grouped products' schedules visited for them. The
+    """``(y [T, D], rows [] int32, tile rows [] int32, chunk rows [] int32)``:
+    the experts' results for the first ``rowed`` rows of the sort, weighted
+    and summed into their tokens in float32, a piece at a time (``how =
+    (piece, interpret)``; ``experts`` the held experts' leaves, a tuple by
+    :data:`EXPERT_FORMS`); the rows that were visited; the rows of the row
+    tiles the grouped products' schedules visited for them; and the rows the
+    pieces' chunk loops made for them (:func:`rows_made`). The
     differentiation rule is written out: reverse mode cannot differentiate a
     loop whose length is traced, nor a Pallas call."""
     args = (h, weights, experts, order, by_token, ends, rowed)
-    y, rows, visited = _over_pieces(lambda p: _piece_forward(how, p, *args),
-                                    -(-rowed // how[0]))
-    return y.astype(h.dtype), rows, visited
+    y, *counts = _over_pieces(lambda p: _piece_forward(how, p, *args),
+                              -(-rowed // how[0]))
+    return (y.astype(h.dtype), *counts)
 
 
 def _routed_fwd(how, *args):
@@ -711,9 +819,10 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 
 def _routed_part(h, chosen, weights, experts, lo, total):
     """:func:`routed_experts` on the experts' leaves as a tuple
-    (:data:`EXPERT_FORMS`), with a fifth number: the rows of the row tiles
-    the grouped products visited (the landed rows over them is the layer's
-    ``moe_tile_fill``)."""
+    (:data:`EXPERT_FORMS`), with a fifth and a sixth number: the rows of the
+    row tiles the grouped products visited (the landed rows over them is the
+    layer's ``moe_tile_fill``) and the rows the pieces' chunk loops made
+    (``moe_row_fill``)."""
     tokens, k = chosen.shape
     held = experts[0].shape[0]
     bound = rows_bound(tokens, k, held)
@@ -738,10 +847,11 @@ def _routed_part(h, chosen, weights, experts, lo, total):
             (jnp.where(row < rowed, row // piece * (tokens * k) + order,
                        jnp.iinfo(jnp.int32).max), row % piece), num_keys=1)
         by_token = (at, number % (tokens * k))
-    y, rows, visited = _routed((piece, not platform.on_tpu()), h, weights,
-                               tuple(experts), order, by_token, ends, rowed)
+    y, rows, visited, made = _routed(
+        (piece, not platform.on_tpu()), h, weights, tuple(experts), order,
+        by_token, ends, rowed)
     stats = jnp.stack([landed - rows, landed, jnp.int32(landed > piece),
-                       jnp.max(sizes), visited]).astype(jnp.float32)
+                       jnp.max(sizes), visited, made]).astype(jnp.float32)
     return y, stats
 
 
@@ -763,7 +873,8 @@ def _over_expert_shards(fn, tokens: int, held: int):
     weights (a tuple of leaves) under the context mesh, the parts summed over
     ``ep`` (and the stats with them: sums summed, the shards' calls that
     needed more than one piece as their share, the largest group the largest
-    anywhere, the visited tiles' rows summed); ``fn`` itself where the mesh has no ``ep`` axis larger than
+    anywhere, the visited tiles' rows and the chunk loops' summed); ``fn``
+    itself where the mesh has no ``ep`` axis larger than
     one. Tokens are split over the batch axes where they
     divide, as attention's per-shard wrap has it."""
     mesh = jax.sharding.get_abstract_mesh()
@@ -808,10 +919,12 @@ class MoeMlp(nn.Module):
     entropy, whether the landed rows needed more than one piece
     (:func:`piece_rows` of the router's width: the share of the shards'
     calls under ``ep``), the landed rows over the rows of the row tiles the
-    grouped products visited for them (1 where nothing landed) and, with
-    ``skip_choice``, the share of tokens that
-    took it. ``state`` is the router state handed on ``[B, S,
-    router_hidden]`` float32 (``mlp-softmax-top1``: it takes the previous
+    grouped products visited for them (1 where nothing landed), the landed
+    rows over the rows the pieces' chunk loops made for them
+    (``moe_row_fill``: 0.9 at a balanced load, five chunks of nine; 1 where
+    nothing landed) and, with ``skip_choice``, the share of tokens that took
+    it. ``state`` is the router state handed on ``[B, S, router_hidden]``
+    float32 (``mlp-softmax-top1``: it takes the previous
     layer's as its second argument), else None.
 
     Scopes (``jax.named_scope``): ``router`` (``router_eda`` and
@@ -819,8 +932,11 @@ class MoeMlp(nn.Module):
     sort, a piece's gather and the transpose's sum back to the tokens),
     ``experts`` (the kernels ``grouped_rows``, ``grouped_rows_t`` and
     ``grouped_weights`` and the activation between them), ``combine`` (the
-    weighted sum back to the tokens and its transpose), ``shared_expert``; the caller's ``moe`` scope is around them,
-    a ``while`` inside where a piece is not the first. Where
+    weighted sum back to the tokens and its transpose), ``shared_expert``;
+    inside the first three ``live_rows`` around each chunk loop (a ``while``
+    and the empty kernel ``unwritten`` whose result it writes into); the
+    caller's ``moe`` scope is around them, a ``while`` inside where a piece
+    is not the first. Where
     ``intermediates`` is a mutable collection (the benchmark's check, tests)
     the layer also sows what it routed on: ``router_in``, ``router_logits``,
     ``chosen`` (and ``router_state_in``, ``router_state``)."""
@@ -974,7 +1090,7 @@ class MoeMlp(nn.Module):
                 else:
                     hidden = _activation(h @ up)
                 y = y + hidden @ down
-        dropped, n_mine, overflow, largest, visited = stats
+        dropped, n_mine, overflow, largest, visited, made = stats
         counted = [
             dropped,
             n_mine / tokens,
@@ -982,13 +1098,18 @@ class MoeMlp(nn.Module):
             n_mine / rows_bound(tokens, self.k, held),
             entropy,
             overflow,
-            jnp.where(visited > 0, n_mine / jnp.maximum(visited, 1.0), 1.0)]
+            jnp.where(visited > 0, n_mine / jnp.maximum(visited, 1.0), 1.0),
+            jnp.where(made > 0, n_mine / jnp.maximum(made, 1.0), 1.0)]
         if self.skip_choice:
             counted.append(jnp.mean(chosen == self.experts_total,
                                     dtype=jnp.float32))
         if softmax:
-            # what the renormalisation restores: k / experts at uniform logits
+            # what the renormalisation restores: k / experts at uniform
+            # logits. The chosen experts' shares by a mask, not a gather: a
+            # gather of k scalars a token costs the chip 1.3 ms a layer at
+            # 16,384 tokens (PERF.md section 6, PR 46), the mask's pass 0.1
+            picked = jnp.any(chosen[:, :, None] == jnp.arange(choices), 1)
             counted.append(jnp.mean(jnp.sum(
-                jnp.take_along_axis(share, chosen, -1), -1)))
+                jnp.where(picked, share, 0.0), -1)))
         counters = jnp.stack(counted)
         return y.reshape(batch, seq, d), counters, state

@@ -15,12 +15,32 @@ forward kernel stands says what remat kept) and the Mamba-2 mixer's
 (``MAMBA_KERNELS``), and a program over its limit or with other kernels makes
 the exit code 1. A compile that passes is not a run.
 
-Usage: JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py [NAME ...]
-       (names: the keys of PROGRAMS; default: all)
+Against another commit (``--census`` / ``--against``; PR 46): each program's
+lowered text hashed without what moves with a checkout (the Mosaic payloads'
+source locations, jax's running numbers on private functions) and its
+compiled text's data movement counted (top-level ``copy`` / ``transpose`` /
+``*bitcast_fusion`` of a megabyte or more, and copies of a carried array
+inside the expert layer's chunk loops, ``ops/moe.py _live_rows``). A program
+with no expert layer has to lower to the other commit's text; one with
+expert layers may hold no copy inside a chunk loop and no large movement
+the other commit's program lacks. Exit code 1 like the other gates.
+
+Usage: JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py
+           [--tree DIR] [--census OUT.json] [--against PARENT.json] [NAME ...]
+       (names: the keys of PROGRAMS; default: all. ``--tree``: import the
+       package from that checkout, e.g. ``git archive`` of the parent, and
+       write its census: ``--tree .chiprun_tree/parent --census
+       /root/scratch/parent.json``, then ``--census /root/scratch/change.json
+       --against /root/scratch/parent.json`` on this tree)
 """
 
 from __future__ import annotations
 
+import argparse
+import base64
+import collections
+import hashlib
+import json
 import os
 import re
 import sys
@@ -159,11 +179,9 @@ MAMBA_KERNELS = {
 }
 
 
-def compile_program(name: str, devices):
-    """``(compiled step, GiB a device it takes)`` of ``PROGRAMS[name]`` on
-    the first devices of a described topology. The caller has turned the
-    persistent compile cache off: a described-TPU executable cannot be read
-    back without a chip."""
+def lower_program(name: str, devices):
+    """The lowered step of ``PROGRAMS[name]`` on the first devices of a
+    described topology."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -188,11 +206,19 @@ def compile_program(name: str, devices):
     # tests run the layers on the CPU: tests/test_tpu_compile.py)
     on_tpu, platform.on_tpu = platform.on_tpu, lambda: True
     try:
-        compiled = trainer.step_fn.lower(
-            trainer.abstract_state(),
-            {"inputs": tokens, "targets": tokens}).compile()
+        return trainer.step_fn.lower(
+            trainer.abstract_state(), {"inputs": tokens, "targets": tokens})
     finally:
         platform.on_tpu = on_tpu
+
+
+def compile_program(name: str, devices, lowered=None):
+    """``(compiled step, GiB a device it takes)`` of ``PROGRAMS[name]`` on
+    the first devices of a described topology (``lowered``: its lowered
+    step, where the caller has it). The caller has turned the persistent
+    compile cache off: a described-TPU executable cannot be read back
+    without a chip."""
+    compiled = (lowered or lower_program(name, devices)).compile()
     mem = compiled.memory_analysis()
     return compiled, (mem.argument_size_in_bytes
                       + mem.temp_size_in_bytes) / 2**30
@@ -216,7 +242,108 @@ def mosaic_calls(text: str):
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
+def program_sha256(text: str) -> str:
+    """SHA-256 of a lowered step's text without what moves with a checkout:
+    each Mosaic payload (MLIR bytecode WITH its source's path and lines)
+    stands as the hash of its operations printed without locations, and
+    private functions lose jax's running numbers (``@tril_217``). Equal
+    hashes: the same program, op for op."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def bare(found):
+        with mlir.make_ir_context() as context:
+            context.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(found.group(2)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        return found.group(1) + hashlib.sha256(asm.encode()).hexdigest()
+
+    text = re.sub(r'(body\\22: \\22)([A-Za-z0-9+/=]+)', bare, text)
+    text = re.sub(r"(@[A-Za-z_][\w.]*?)_\d+\b", r"\1", text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+             "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+             "u64": 8}
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _instructions(text: str):
+    """``(computation, name, type, dims, bytes, opcode, line)`` of every
+    array-valued instruction of a compiled program's text, fused
+    computations' left out (what a fusion holds is one pass)."""
+    computation = None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            computation = line.split()[1 if line.startswith("ENTRY") else 0]
+            continue
+        found = _INSTRUCTION.match(line)
+        if not found or "fused_computation" in (computation or ""):
+            continue
+        name, kind, dims, opcode = found.groups()
+        size = _ITEMSIZE.get(kind, 4)
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        yield computation, name, kind, dims, size, opcode, line
+
+
+def movement(text: str, least: int = 1 << 20):
+    """``({"<what> <type>[<dims>]": how many}, [copies in chunk loops])`` of
+    a compiled step: its top-level ``copy``, ``transpose`` and
+    ``*bitcast_fusion`` instructions of ``least`` bytes or more — data XLA
+    moves for a layout's sake (PR 44) — and, of the expert layer's chunk
+    loops (a ``while`` under the scope ``live_rows``: ``ops/moe.py
+    _live_rows``), every ``copy`` in the body of an array as large as one
+    the loop carries: a carried buffer that is not updated in place."""
+    moves: collections.Counter = collections.Counter()
+    bodies = {}
+    for line in text.splitlines():
+        if " while(" in line and "live_rows/while" in line:
+            body = re.search(r"body=(%[\w.-]+)", line).group(1)
+            bodies[body] = set(re.findall(r"\w+\[[\d,]+\]", line.split(
+                " while(")[0]))
+    in_loops = []
+    for computation, name, kind, dims, size, opcode, line in \
+            _instructions(text):
+        what = name.split(".")[0] if opcode == "fusion" else opcode
+        if size >= least and (opcode in ("copy", "transpose")
+                              or what.endswith("bitcast_fusion")):
+            moves[f"{what} {kind}[{dims}]"] += 1
+        if opcode == "copy" and f"{kind}[{dims}]" in bodies.get(
+                computation, ()) and size >= least:
+            in_loops.append(f"{computation}: {name} {kind}[{dims}]")
+    return dict(sorted(moves.items())), in_loops
+
+
+def against(name: str, mine: dict, parents: dict) -> list:
+    """What ``mine``, a program's census, holds against the parent
+    commit's: nothing where a program without expert layers lowers to the
+    parent's text, and one with them keeps its chunk loops free of copies
+    and moves no large array the parent's did not."""
+    if not mine["expert_layers"]:
+        return [] if mine["lowered_sha256"] == parents["lowered_sha256"] \
+            else [f"{name}: lowers to {mine['lowered_sha256'][:12]}, the "
+                  f"parent to {parents['lowered_sha256'][:12]}"]
+    more = collections.Counter(mine["moves"])
+    more.subtract(parents["moves"])
+    return [f"{name}: a chunk loop copies {copy}"
+            for copy in mine["chunk_loop_copies"]] \
+        + [f"{name}: {n} x {what} more than the parent"
+           for what, n in sorted(more.items()) if n > 0]
+
+
 def main() -> None:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--tree")
+    parser.add_argument("--census")
+    parser.add_argument("--against")
+    parser.add_argument("names", nargs="*", metavar="NAME")
+    options = parser.parse_args()
+    if options.tree:
+        sys.path.remove(REPO)
+        sys.path.insert(0, os.path.abspath(options.tree))
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -224,11 +351,17 @@ def main() -> None:
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    over = []
-    for name in sys.argv[1:] or list(PROGRAMS):
+    over, census = [], {}
+    parents = json.load(open(options.against)) if options.against else {}
+    compare = bool(options.against or options.census)
+    for name in options.names or list(PROGRAMS):
         (factory, _), key, batch, accum, optimizer, limit = PROGRAMS[name]
         t0 = time.perf_counter()
-        compiled, gib = compile_program(name, topo.devices)
+        if compare:
+            lowered = lower_program(name, topo.devices)
+            compiled, gib = compile_program(name, topo.devices, lowered)
+        else:
+            compiled, gib = compile_program(name, topo.devices)
         dt = time.perf_counter() - t0
         mem = compiled.memory_analysis()
         text = compiled.as_text()
@@ -267,6 +400,22 @@ def main() -> None:
         if limit and mamba != MAMBA_KERNELS.get(name, {}):
             over.append(f"{name}: Mamba-2 kernels {mamba}, not "
                         f"{MAMBA_KERNELS.get(name, {})}")
+        if compare:
+            moves, in_loops = movement(text)
+            census[name] = {
+                "lowered_sha256": program_sha256(lowered.as_text()),
+                "expert_layers": "rows_to_tokens" in kernels,
+                "moves": moves, "chunk_loop_copies": in_loops,
+                "gib": round(gib, 3)}
+            print(f"{name}: lowered {census[name]['lowered_sha256'][:12]}; "
+                  f"{sum(moves.values())} copies, transposes and bitcast "
+                  f"fusions of a megabyte or more; {len(in_loops)} copies "
+                  f"inside a chunk loop", flush=True)
+            if name in parents:
+                over.extend(against(name, census[name], parents[name]))
+    if options.census:
+        with open(options.census, "w") as f:
+            json.dump(census, f, indent=1, sort_keys=True)
     if over:
         sys.exit("; ".join(over))
 

@@ -3,6 +3,7 @@ forward and gradients, the grouped products and the experts' rule. (The
 models that hold it train over an ep-sharded mesh in their own files:
 ``tests/test_laguna.py``, ``tests/test_joyai.py``, ``tests/test_zaya.py``.)"""
 
+import contextlib
 import functools
 
 import jax
@@ -12,7 +13,8 @@ import pytest
 from conftest import normal
 
 from easydl_tpu.core import sharding as shd
-from easydl_tpu.ops.moe import (COUNTERS, MoeMlp, grouped_rows,
+from easydl_tpu.ops import moe
+from easydl_tpu.ops.moe import (COUNTERS, MoeMlp, chunk_rows, grouped_rows,
                                 grouped_weights, piece_rows, route,
                                 routed_experts, rows_bound)
 
@@ -174,6 +176,263 @@ def test_pieces_match_one_piece_and_a_loop_over_experts(landed, pieces):
         scale = max(float(jnp.abs(looped).max()), 1.0)
         np.testing.assert_allclose(got, one_piece, rtol=0, atol=2e-6 * scale)
         np.testing.assert_allclose(got, looped, rtol=0, atol=2e-6 * scale)
+
+
+# ------------------------------------------------- a piece's live chunks
+@contextlib.contextmanager
+def _pieces_traced_anew(**stand_ins):
+    """``ops/moe.py`` with ``stand_ins`` in place of its functions, the
+    jitted pieces' traces dropped on the way in and out (they are cached by
+    shape, whatever the module's functions were when they were made)."""
+    kept = {name: getattr(moe, name) for name in stand_ins}
+    try:
+        for name, stand_in in stand_ins.items():
+            setattr(moe, name, stand_in)
+        moe._piece_forward.clear_cache(), moe._piece_backward.clear_cache()
+        yield
+    finally:
+        for name, was in kept.items():
+            setattr(moe, name, was)
+        moe._piece_forward.clear_cache(), moe._piece_backward.clear_cache()
+
+
+def _whole_pieces():
+    """The parent's form (PR 45), kept as the reference: every gather
+    between a piece's kernels runs over the whole static piece."""
+    return _pieces_traced_anew(
+        _live_rows=lambda source, at, n_live, interpret: source[at])
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def _part_value_and_grads(form, chosen, total, shards, args):
+    """``((loss, (y, stats)), gradients by every argument)`` of the routed
+    part on ``args = (h, weights, *experts)`` (two expert leaves: relu2),
+    under the context mesh's ``ep`` shards (``shards`` of them: a key of
+    the trace, as ``form``, which tells the two forms' traces apart and no
+    more): one program a form and set of shapes (the choices are an
+    argument)."""
+    part = functools.partial(moe._routed_part, total=total)
+    tokens, held = chosen.shape[0], args[2].shape[0]
+
+    def loss(h, weights, *experts):
+        y, stats = moe._over_expert_shards(part, tokens, held)(
+            h, chosen, weights, experts, jnp.int32(0))
+        return _weighed(y.astype(jnp.float32)), (y, stats)
+
+    return jax.value_and_grad(loss, argnums=tuple(range(len(args))),
+                              has_aux=True)(*args)
+
+
+def _both_forms(chosen, total, args, shards=1, **stand_ins):
+    """The layer over its live chunks (with ``stand_ins``) and over whole
+    pieces: ``(got, want)``, each ``_part_value_and_grads``' result."""
+    def mesh():
+        return jax.sharding.set_mesh(jax.make_mesh(
+            (shards,), ("ep",), (jax.sharding.AxisType.Auto,),
+            devices=jax.devices()[:shards])) \
+            if shards > 1 else contextlib.nullcontext()
+
+    with mesh(), _whole_pieces():
+        want = _part_value_and_grads("whole", chosen, total, shards, args)
+    with mesh(), _pieces_traced_anew(**stand_ins):
+        got = _part_value_and_grads(("chunks", *stand_ins), chosen, total,
+                                    shards, args)
+    return got, want
+
+
+def _live_chunk_args(dtype, gated, tokens=1024, k=4, held=4):
+    h, weights = _tokens_and_weights(7, tokens, k)
+    experts = _held_experts(held)[0 if gated else 1:]
+    return (h.astype(dtype), weights,
+            *(jnp.asarray(0.3 * np.asarray(w)).astype(dtype)
+              for w in experts))
+
+
+def _assert_same_bits(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+# 1,024 tokens, 4 choices, 4 of 32 experts held: pieces of 1,024 rows in
+# chunks of 128, a bound of 4,096
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("landed, made", [
+    (0, 0),                 # nothing lands: no chunk is made
+    (1, 128),               # one row: one chunk
+    (383, 384), (384, 384), (385, 512),  # a chunk's edge less one, it, one more
+    (512, 512),             # the expected load
+    (1024, 1024),           # a full piece
+    (1025, 1024 + 128),     # two pieces, the second with one live chunk
+])
+def test_live_chunks_are_whole_pieces_bit_for_bit(landed, made, dtype, gated):
+    """The layer whose HBM-to-HBM row gathers (the rows by token in front of
+    each sum back, the cotangent's rows) run over a piece's live chunks
+    alone against the form it replaces (every gather over the whole static
+    piece): the value, the rows' counts and ALL gradients equal bit for bit
+    at any load, in both dtypes and both expert forms — only rows that no
+    kernel reads and no sum counts stop being made."""
+    tokens, k, held, total = 1024, 4, 4, 32
+    assert piece_rows(tokens, k, held, total) == 1024
+    assert chunk_rows(1024) == 128
+    chosen = _landing(tokens, k, held, total, landed)
+    got, want = _both_forms(chosen, total, _live_chunk_args(dtype, gated))
+    _assert_same_bits(got, want)
+    stats = np.asarray(got[0][1][1])
+    assert tuple(stats[:3]) == (0.0, landed, float(landed > 1024))
+    assert stats[5] == made
+
+
+@pytest.mark.parametrize("landed, dtype, gated", [
+    (700, jnp.float32, True), (1025, jnp.bfloat16, False)],
+    ids=["swiglu-float32", "relu2-bfloat16-second-pieces"])
+def test_live_chunks_under_two_expert_shards(eight_devices, landed, dtype,
+                                             gated):
+    """The same under ``ep`` = 2 on CPU devices: each shard's pieces (512
+    rows of its two experts) have their own live counts, and the parts and
+    the counts are summed."""
+    tokens, k, held, total = 1024, 4, 4, 32
+    chosen = _landing(tokens, k, held, total, landed)
+    args = _live_chunk_args(dtype, gated)
+    got, want = _both_forms(chosen, total, args, shards=2)
+    _assert_same_bits(got, want)
+    stats = np.asarray(got[0][1][1])
+    on_shard = [int(((np.asarray(chosen) // 2) == s).sum()) for s in (0, 1)]
+    assert sum(on_shard) == landed == stats[1]
+    assert chunk_rows(512) == 128
+    # a shard's pieces before its last are whole, the last one's live chunks
+    assert stats[5] == sum(n // 512 * 512 + -(-(n % 512) // 128) * 128
+                           for n in on_shard)
+
+
+def test_nothing_reads_the_rows_behind_the_last_live_chunk():
+    """The arrays the chunk loops write into start as NaN throughout (as
+    the interpreter leaves a kernel's unwritten result; on the chip they
+    hold whatever the memory did): the value and every gradient are the
+    whole-piece form's all the same, so nothing downstream reads a dead
+    row."""
+    tokens, k, held, total = 1024, 4, 4, 32
+    chosen = _landing(tokens, k, held, total, 300)
+    seen = []
+
+    def nan(shape, dtype, after, interpret):
+        seen.append(shape)
+        return jnp.full(shape, jnp.nan, dtype)
+
+    got, want = _both_forms(chosen, total,
+                            _live_chunk_args(jnp.bfloat16, True),
+                            _unwritten=nan)
+    _assert_same_bits(got, want)
+    # the forward's weights and rows by token; the backward's weights by
+    # row, the cotangent's rows and the rows' gradient by token
+    assert sorted(seen) == [(1024,)] * 2 + [(1024, 16)] * 3
+
+
+@pytest.mark.parametrize("tokens, k, held, total", [
+    (16384, 8, 32, 256),    # Laguna's cell
+    (16384, 1, 8, 17),      # ZAYA1's
+    (16384, 8, 16, 256),    # JoyAI-LLM-Flash's
+    (16384, 6, 8, 128),     # Nemotron 3 Nano's
+    (16384, 8, 16, 64),     # Mellum 2's
+], ids=["laguna", "zaya1", "joyai", "nemotron", "mellum2"])
+def test_no_chunk_edge_near_the_expected_load(tokens, k, held, total):
+    """A balanced layer must not flip between one chunk more and one less
+    by the step: in the five cells no edge of a chunk lies within 3% of the
+    expected load (half a piece), the chunks are whole row tiles, and nine
+    of them hold the piece."""
+    piece, expected = piece_rows(tokens, k, held, total), \
+        tokens * k * held / total
+    chunk = chunk_rows(piece)
+    assert chunk % 128 == 0 and 8 * chunk < piece <= 9 * chunk
+    nearest = min(abs(edge - expected)
+                  for edge in range(0, piece + chunk, chunk))
+    assert nearest > 0.03 * expected, (chunk, expected, nearest)
+    # and a balanced load makes five chunks of nine
+    assert moe.rows_made(int(expected), piece) == 5 * chunk
+
+
+def _fixed_route(chosen):
+    """A stand-in for ``route``: these choices, uniform weights."""
+    def route(h, kernel, k, scaling, **_):
+        logits = jnp.dot(h.astype(jnp.float32), kernel.astype(jnp.float32))
+        return logits, chosen, jnp.full(chosen.shape, scaling / k)
+    return route
+
+
+@pytest.mark.parametrize("landed, made", [(0, 0), (385, 512),
+                                          (1025, 1024 + 128)])
+def test_row_fill_is_the_landed_rows_over_the_chunks_made(monkeypatch,
+                                                          landed, made):
+    """``moe_row_fill`` through the layer at three loads against the count
+    by hand: 1,024 tokens on 4 of 32 experts, pieces of 1,024 rows in chunks
+    of 128; 1 where nothing landed."""
+    tokens, k, held, total = 1024, 4, 4, 32
+    monkeypatch.setattr(moe, "route", _fixed_route(
+        _landing(tokens, k, held, total, landed)))
+    layer = MoeMlp(experts_total=total, experts_held=(0, held), d_ff=8,
+                   shared_d_ff=0, k=k)
+    x, = normal(1, (2, tokens // 2, 16))
+    _, counted, _ = jax.jit(layer.apply)(
+        jax.jit(layer.init)(jax.random.PRNGKey(2), x), x)
+    named = dict(zip(COUNTERS, np.asarray(counted)))
+    assert named["moe_rows_per_token"] == np.float32(landed / tokens)
+    assert named["moe_row_fill"] == np.float32(landed / made if made else 1.0)
+    assert named["moe_dropped"] == 0.0
+
+
+@pytest.mark.parametrize("router, more", [
+    (moe.ROUTERS[0], {}),
+    (moe.ROUTERS[1], {"router_hidden": 8, "skip_choice": True, "k": 1}),
+    (moe.ROUTERS[2], {})])
+def test_row_fill_has_its_place_in_every_routers_counters(router, more):
+    """The eighth of the counters whatever the router's form, in front of
+    what a form adds; through a small layer it is the landed rows over the
+    whole chunks they need."""
+    names = moe.counters(more.get("skip_choice", False), router)
+    assert names[:8] == COUNTERS and names[7] == "moe_row_fill"
+    assert COUNTERS[6:] == ("moe_tile_fill", "moe_row_fill")
+    layer = MoeMlp(**dict(dict(experts_total=16, experts_held=(0, 4), d_ff=8,
+                               shared_d_ff=0, k=4, router=router), **more))
+    x, = normal(3, (2, 128, 16))
+    state = jnp.zeros((2, 128, 8)) if router == moe.ROUTERS[1] else None
+    _, counted, _ = jax.jit(layer.apply)(
+        jax.jit(layer.init)(jax.random.PRNGKey(4), x, state), x, state)
+    named = dict(zip(names, np.asarray(counted)))
+    assert len(named) == len(names) == len(counted)
+    landed = round(float(named["moe_rows_per_token"]) * 256)
+    piece = piece_rows(256, layer.k, 4, 16 + more.get("skip_choice", False))
+    assert 0 < landed <= piece
+    assert named["moe_row_fill"] == np.float32(
+        landed / moe.rows_made(landed, piece))
+
+
+def test_row_fill_reaches_the_loss_metrics_as_the_layers_mean():
+    """``lm_bundle``'s metrics carry ``moe_row_fill`` beside the other
+    counters: the mean over the expert layers, each the landed rows over
+    its chunks."""
+    from easydl_tpu.models.laguna import describe
+    from easydl_tpu.models.registry import get_model
+
+    kwargs = dict(size="test", seq_len=32, vocab=128, experts_held=(0, 4))
+    bundle = get_model("laguna", **kwargs)
+    rng = jax.random.PRNGKey(1)
+    params = jax.jit(bundle.init_fn)(rng)
+    batch = next(iter(bundle.make_data(4, seed=5)))
+    _, metrics = jax.jit(bundle.loss_fn)(params, batch, rng)
+    assert set(COUNTERS) <= set(metrics)
+    fill = float(metrics["moe_row_fill"])
+    assert 0.0 < fill <= 1.0
+    # a piece at this size is one chunk: every layer's fill is its landed
+    # rows over its piece, the mean of the rows a token over the piece's
+    tokens, cfg = 4 * 32, describe(**kwargs).moe
+    piece = piece_rows(tokens, cfg.k, 4, cfg.experts_total)
+    assert chunk_rows(piece) == piece
+    assert fill == pytest.approx(
+        float(metrics["moe_rows_per_token"]) * tokens / piece, rel=1e-6)
 
 
 @pytest.mark.parametrize("tokens, k, rows, landed", [
@@ -475,7 +734,9 @@ def test_tile_fill_counts_the_tiles_the_groups_touch():
     h, weights = _tokens_and_weights(0, 256, 1)
     _, stats = jax.jit(_routed_part, static_argnums=(4, 5))(
         h, chosen, weights, tuple(_held_experts(2)), 0, 2)
-    assert tuple(np.asarray(stats)) == (0.0, 256.0, 0.0, 156.0, 3 * 128.0)
+    # (the sixth: one piece of 256 rows in two chunks of 128, both live)
+    assert tuple(np.asarray(stats)) == (0.0, 256.0, 0.0, 156.0, 3 * 128.0,
+                                        256.0)
 
     layer = MoeMlp(experts_total=8, experts_held=(0, 8), d_ff=32,
                    shared_d_ff=16, k=2, scaling=2.5)
@@ -485,7 +746,7 @@ def test_tile_fill_counts_the_tiles_the_groups_touch():
             jax.jit(layer.init)(jax.random.PRNGKey(2), x), x)
     named = dict(zip(COUNTERS, np.asarray(counters)))
     experts = np.unique(np.asarray(sown["intermediates"]["chosen"][0])).size
-    assert COUNTERS[-1] == "moe_tile_fill"
+    assert COUNTERS[-2] == "moe_tile_fill"
     assert named["moe_tile_fill"] == np.float32(64 / (64 * experts))
 
 

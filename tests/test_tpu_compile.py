@@ -39,11 +39,17 @@ SHAPE = (8, 1024, 16, 64)
 pytestmark = pytest.mark.usefixtures("no_persistent_cache")
 
 
-def _mosaic_calls(compiled) -> list:
-    """Result shapes of the Mosaic kernels in a compiled program."""
+def _mosaic_calls(compiled, kernel: str = "") -> list:
+    """Result shapes of the Mosaic kernels in a compiled program: those
+    whose name starts with ``kernel``, or all but the expert layer's empty
+    ``unwritten`` (``ops/moe.py``: an array for a chunk loop to write into,
+    no work)."""
     return [line.split(" = ", 1)[1].split(" custom-call(")[0]
             for line in compiled.as_text().splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
+            if 'custom_call_target="tpu_custom_call"' in line
+            and ("%unwritten" in line.split(" = ")[0]) == (
+                kernel == "unwritten")
+            and "%" + kernel in line.split(" = ")[0]]
 
 
 def _loss(attend):
@@ -440,6 +446,16 @@ def test_the_expert_layers_kernels_compile_at_the_cells_size(v5e_2x2,
     assert all(c.startswith(("bf16[32768,", "bf16[32,")) for c in products)
     assert compiled.as_text().count("rows_to_tokens/pallas_call") >= 4
     assert "131072" not in "".join(calls)
+    # the three HBM-to-HBM gathers a piece (the rows by token, forward and
+    # backward, and the cotangent's rows) and the two of the choices'
+    # weights write into unwritten pieces, in chunk loops of nine; the
+    # first piece's and the loop's
+    unwritten = _mosaic_calls(compiled, "unwritten")
+    assert sorted(c.split("{")[0] for c in unwritten) == [
+        "bf16[32768,2048]"] * 6 + ["f32[32768]"] * 4, unwritten
+    assert sum(" while(" in line and "live_rows/while\"" in line
+               for line in compiled.as_text().splitlines()) == 10
+    assert moe.chunk_rows(32768) == 3712
 
 
 def test_ungated_experts_of_a_ragged_width_compile_at_the_cells_size(
@@ -475,4 +491,5 @@ def test_ungated_experts_of_a_ragged_width_compile_at_the_cells_size(
     assert all(c.startswith(("bf16[12288,", "bf16[8,")) for c in products)
     assert any(c.startswith("bf16[12288,1856]") for c in products)
     assert any(c.startswith("bf16[8,2688,1856]") for c in products)
+    assert len(_mosaic_calls(compiled, "unwritten")) == 10
     assert moe.choose_tiles(12288, 8, 2688, 1856, 2) == (128, 1024)

@@ -305,6 +305,165 @@ def test_the_script_fails_on_other_attention_kernels_than_a_cells(
     assert f"{program}: " in capsys.readouterr().out
 
 
+# A compiled step's text in small: a chunk loop of ``ops/moe.py _live_rows``
+# whose body copies the carried buffer, large and small copies, a transpose
+# and a bitcast fusion at the top level, and a copy inside a fusion's body.
+_COMPILED = """\
+%fused_computation.1 (p: bf16[65536,2304]) -> bf16[65536,2304] {
+  %p = bf16[65536,2304]{1,0} parameter(0)
+  ROOT %copy.9 = bf16[65536,2304]{1,0:T(8,128)(2,1)} copy(%p)
+}
+
+%body.7 (arg: (s32[], bf16[65536,2304], bf16[16384,2304])) -> (s32[], bf16[65536,2304], bf16[16384,2304]) {
+  %arg = (s32[]{:T(128)}, bf16[65536,2304]{1,0}, bf16[16384,2304]{1,0}) parameter(0)
+  %gte.1 = bf16[65536,2304]{1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=1
+  %copy.3 = bf16[65536,2304]{1,0:T(8,128)(2,1)} copy(%gte.1), metadata={op_name="jit(train_step)/blocks_1/moe/moe/jit(_piece_forward)/dispatch/live_rows/while/body/dynamic_update_slice"}
+  %copy.4 = bf16[7296,2304]{1,0:T(8,128)(2,1)S(1)} copy(%gte.1)
+  ROOT %tuple.1 = (s32[], bf16[65536,2304], bf16[16384,2304]) tuple(%c, %copy.3, %h)
+}
+
+ENTRY %main.1 (a: bf16[16384,2304]) -> bf16[65536,2304] {
+  %while.5 = (s32[]{:T(128)}, bf16[65536,2304]{1,0:T(8,128)(2,1)}, bf16[16384,2304]{1,0:T(8,128)(2,1)}) while(%tuple.0), condition=%cond.7, body=%body.7, metadata={op_name="jit(train_step)/blocks_1/moe/moe/jit(_piece_forward)/dispatch/live_rows/while"}
+  %copy.1 = bf16[65536,896]{1,0:T(8,128)(2,1)} copy(%x)
+  %copy.2 = bf16[128,896]{1,0:T(8,128)(2,1)} copy(%y)
+  %transpose.1 = f32[2,8192,2304]{2,1,0:T(8,128)} transpose(%z), dimensions={1,0,2}
+  %convert_bitcast_fusion.3 = bf16[2,8192,4096]{2,1,0:T(8,128)(2,1)} fusion(%q), kind=kLoop, calls=%fused_computation.1
+  ROOT %fusion.8 = bf16[65536,2304]{1,0:T(8,128)(2,1)} fusion(%w), kind=kLoop, calls=%fused_computation.1
+}
+"""
+
+
+def test_the_census_counts_what_xla_moves_and_what_a_chunk_loop_copies(
+        rehearse):
+    """``movement``: the top-level copies, transposes and bitcast fusions of
+    a megabyte or more by kind and type (a fusion's body is one pass, a
+    small copy is none), and every copy of a carried array inside the body
+    of a ``while`` under ``live_rows`` — the expert layer's chunk loops have
+    to write their piece-sized buffers in place (PR 46)."""
+    moves, in_loops = rehearse.movement(_COMPILED)
+    assert moves == {"convert_bitcast_fusion bf16[2,8192,4096]": 1,
+                     "copy bf16[65536,2304]": 1, "copy bf16[65536,896]": 1,
+                     "copy bf16[7296,2304]": 1,  # a loop's body counts too
+                     "transpose f32[2,8192,2304]": 1}
+    assert in_loops == ["%body.7: copy.3 bf16[65536,2304]"]
+    # the same body under a `while` of another name is no chunk loop
+    assert rehearse.movement(_COMPILED.replace("live_rows/while", "while"))[
+        1] == []
+
+
+@pytest.mark.parametrize("mine, parents, said", [
+    ({"expert_layers": False, "lowered_sha256": "a" * 64},
+     {"lowered_sha256": "a" * 64}, []),
+    ({"expert_layers": False, "lowered_sha256": "a" * 64},
+     {"lowered_sha256": "b" * 64},
+     ["cell: lowers to aaaaaaaaaaaa, the parent to bbbbbbbbbbbb"]),
+    # an expert program's text differs by design: what it moves may not grow
+    ({"expert_layers": True, "lowered_sha256": "a" * 64,
+      "chunk_loop_copies": [], "moves": {"copy bf16[65536,896]": 2}},
+     {"lowered_sha256": "b" * 64, "moves": {"copy bf16[65536,896]": 3,
+                                            "transpose f32[8,8]": 1}}, []),
+    ({"expert_layers": True, "lowered_sha256": "a" * 64,
+      "chunk_loop_copies": [], "moves": {"copy bf16[65536,896]": 3}},
+     {"lowered_sha256": "b" * 64, "moves": {"copy bf16[65536,896]": 1}},
+     ["cell: 2 x copy bf16[65536,896] more than the parent"]),
+    ({"expert_layers": True, "lowered_sha256": "a" * 64, "moves": {},
+      "chunk_loop_copies": ["%body.7: copy.3 bf16[65536,2304]"]},
+     {"lowered_sha256": "b" * 64, "moves": {}},
+     ["cell: a chunk loop copies %body.7: copy.3 bf16[65536,2304]"]),
+], ids=["the-parents-text", "another-text", "experts-moving-less",
+        "experts-moving-more", "a-chunk-loop-that-copies"])
+def test_a_census_against_the_parents(rehearse, mine, parents, said):
+    """``against``: a program without expert layers has to lower to the
+    parent commit's text; one with them may copy nothing inside a chunk
+    loop and move no large array the parent's program did not."""
+    assert rehearse.against("cell", mine, parents) == said
+
+
+def test_a_programs_hash_forgets_where_its_kernels_were_written():
+    """``program_sha256``: two lowered programs that differ in a Mosaic
+    payload's source locations alone (the same kernel written on another
+    line: every PR that edits ``ops/moe.py`` moves its kernels' lines, and a
+    checkout elsewhere their paths) and in jax's running numbers on private
+    functions hash alike; another kernel does not."""
+    import importlib.util
+
+    from jax.experimental import pallas as pl
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "rehearse_tpu_compile.py")
+    spec = importlib.util.spec_from_file_location("rehearse_hash", path)
+    rehearse = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(rehearse)
+
+    def text(kernel):
+        call = pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+            name="add")
+        return jax.jit(call).trace(
+            jax.ShapeDtypeStruct((8, 128), jnp.float32)).lower(
+                lowering_platforms=("tpu",)).as_text()
+
+    def one(x_ref, o_ref):
+        o_ref[...] = x_ref[...] + 1.0
+
+    def same(x_ref, o_ref):
+        o_ref[...] = x_ref[...] + 1.0
+
+    def other(x_ref, o_ref):
+        o_ref[...] = x_ref[...] + 2.0
+
+    texts = [text(kernel) for kernel in (one, same, other)]
+    assert "tpu_custom_call" in texts[0] and texts[0] != texts[1]
+    hashes = [rehearse.program_sha256(t) for t in texts]
+    assert hashes[0] == hashes[1] != hashes[2]
+    numbered = "func.func private @tril_217() {\n}"
+    assert rehearse.program_sha256(numbered) == rehearse.program_sha256(
+        numbered.replace("217", "9"))
+
+
+@pytest.mark.parametrize("parents_text, said", [
+    ("the step", None), ("another step", "ouro_4x1: lowers to ")],
+    ids=["the-parents-program", "another-program"])
+def test_the_script_holds_a_program_to_the_parents_census(
+        v5e_2x2, rehearse, monkeypatch, tmp_path, capsys, parents_text, said):
+    """``--census`` writes each program's census and ``--against`` gates it
+    on the parent commit's (the compile is stood in for): a program without
+    expert layers that lowers to another text makes the exit code 1."""
+    import json
+    import types
+
+    fits = types.SimpleNamespace(argument_size_in_bytes=2**30,
+                                 temp_size_in_bytes=2**30)
+    compiled = types.SimpleNamespace(
+        memory_analysis=lambda: fits,
+        as_text=lambda: "\n".join(
+            f'  %{kernel}.{i} = bf16[1,4096,2048]{{2,1,0}} custom-call(%x), '
+            f'custom_call_target="tpu_custom_call"'
+            for kernel, n in rehearse.ATTENTION_KERNELS["ouro_4x1"].items()
+            for i in range(n)))
+    monkeypatch.setattr(
+        rehearse, "lower_program", lambda name, devices:
+        types.SimpleNamespace(as_text=lambda: "the step"))
+    monkeypatch.setattr(rehearse, "compile_program",
+                        lambda name, devices, lowered=None: (compiled, 2.0))
+    parents, census = tmp_path / "parent.json", tmp_path / "change.json"
+    parents.write_text(json.dumps({"ouro_4x1": {
+        "lowered_sha256": rehearse.program_sha256(parents_text)}}))
+    monkeypatch.setattr(rehearse.sys, "argv", [
+        "rehearse", "--census", str(census), "--against", str(parents),
+        "ouro_4x1"])
+    if said is None:
+        rehearse.main()
+    else:
+        with pytest.raises(SystemExit) as exit_:
+            rehearse.main()
+        assert said in str(exit_.value), exit_.value
+    written = json.loads(census.read_text())["ouro_4x1"]
+    assert written["lowered_sha256"] == rehearse.program_sha256("the step")
+    assert written["expert_layers"] is False and written["moves"] == {}
+    assert "ouro_4x1: lowered " in capsys.readouterr().out
+
+
 @pytest.fixture(scope="module")
 def two_layer_rotary_stack(v5e_2x2):
     """As ``two_layer_stack``, for Ouro's description cut to two layers and

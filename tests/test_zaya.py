@@ -443,8 +443,8 @@ def test_lagunas_step_program_is_the_parents_op_for_op():
     """Laguna's test description (bf16, remat ``full``, two microbatches of
     4 x 64) lowers to the text ``tests/goldens/laguna_step_program.json``
     holds the hash of (``gpt2_fingerprint._step_sha256``; the file says on
-    which tree it was written and why: last by PR 36, whose kernels for the
-    grouped products changed the program): what another model's description
+    which tree it was written and why: last by PR 46, whose chunk loops
+    around the expert layer's kernels changed the program): what another model's description
     asks for — the expert layer's second router form, the block's carry, the
     scaled adds — is not in a program whose description does not."""
     with open(os.path.join(HERE, "goldens", "laguna_step_program.json")) as f:
