@@ -65,15 +65,12 @@ _RESERVED_LABELS = frozenset(("le", "quantile"))
 #: the sync test. The ``easydl_rpc_{side}_*`` f-string family is listed
 #: expanded (side ∈ client/server).
 REGISTERED_METRICS = frozenset((
-    "easydl_agent_generation",
     "easydl_agent_heartbeat_rate_per_s",
     "easydl_agent_heartbeats_total",
     "easydl_agent_master_outage_seconds",
     "easydl_agent_master_outages_total",
     "easydl_agent_outage_buffered_metrics",
-    "easydl_agent_phase_events_total",
     "easydl_agent_phase_seconds",
-    "easydl_agent_worker_loss",
     "easydl_agent_worker_samples_per_sec",
     "easydl_agent_worker_step",
     "easydl_agent_worker_step_time_seconds",
@@ -104,17 +101,17 @@ REGISTERED_METRICS = frozenset((
     "easydl_feedback_bytes_total",
     "easydl_feedback_dropped_total",
     "easydl_feedback_events_total",
+    "easydl_job_chip_seconds_total",
+    "easydl_job_goodput_ratio",
     "easydl_loop_checkpoints_total",
     "easydl_loop_lag_seconds",
     "easydl_loop_trained_events_total",
     "easydl_master_desired_workers",
-    "easydl_master_directives_total",
     "easydl_master_failovers_total",
     "easydl_master_generation",
     "easydl_master_journal_writes_total",
     "easydl_master_membership_size",
     "easydl_master_phase_seconds",
-    "easydl_master_plan_version",
     "easydl_master_reconciled_agents_total",
     "easydl_master_reshapes_total",
     "easydl_master_straggler_evictions_total",
@@ -189,11 +186,8 @@ REGISTERED_METRICS = frozenset((
     "easydl_serve_router_routed_total",
     "easydl_swallowed_errors_total",
     "easydl_timeline_listener_errors_total",
-    "easydl_train_loss",
     "easydl_train_samples_per_sec",
     "easydl_train_step",
-    "easydl_train_step_time_seconds",
-    "easydl_train_steps_total",
     "easydl_worker_mesh_axis",
     "easydl_worker_mfu",
 ))

@@ -45,6 +45,7 @@ PURE_PATHS = (
     "easydl_tpu/brain/tier_policy.py",
     "easydl_tpu/cell/policy.py",
     "easydl_tpu/core/mesh_shapes.py",
+    "easydl_tpu/elastic/goodput.py",
     "easydl_tpu/elastic/membership.py",
     "easydl_tpu/loop/rollout.py",
     "easydl_tpu/retrieval/policy.py",

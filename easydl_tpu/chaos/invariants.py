@@ -88,6 +88,8 @@ import json
 import os
 from typing import Any, Dict, List, Mapping, Optional
 
+from easydl_tpu.elastic.goodput import wasted_steps
+
 
 def read_metrics(workdir: str) -> List[Dict[str, Any]]:
     """All agents' step records, merged (unsorted)."""
@@ -295,8 +297,10 @@ def check_scenario(
             pre = [int(r["step"]) for r in metrics
                    if int(r.get("generation", -1)) == prev
                    and float(r.get("t", 0.0)) <= t_first_next]
-            last_pre = max(pre) if pre else max(by_gen[prev])
-            lost = max(0, last_pre - (min(by_gen[nxt]) - 1))
+            # the job's own reckoning (elastic/goodput.py): the steps run
+            # above the one the next generation resumed from
+            lost = len(wasted_steps(pre or by_gen[prev],
+                                    min(by_gen[nxt]) - 1))
             losses.append({"from_gen": prev, "to_gen": nxt,
                            "steps_lost": lost})
         worst = max((l["steps_lost"] for l in losses), default=0)

@@ -75,16 +75,9 @@ class MetricsRecorder:
         reg = get_registry()
         self._g_step = reg.gauge(
             "easydl_train_step", "Latest recorded training step.")
-        self._g_loss = reg.gauge(
-            "easydl_train_loss", "Latest recorded training loss.")
-        self._g_step_time = reg.gauge(
-            "easydl_train_step_time_seconds", "Latest recorded step wall "
-            "time.")
         self._g_rate = reg.gauge(
             "easydl_train_samples_per_sec", "Windowed mean global training "
             "throughput.")
-        self._c_steps = reg.counter(
-            "easydl_train_steps_total", "Training steps recorded.")
 
     def add_reporter(self, reporter: Reporter) -> None:
         self._reporters.append(reporter)
@@ -108,10 +101,7 @@ class MetricsRecorder:
         if self._count > self.warmup:
             self._window.append(rec)
         self._g_step.set(step)
-        self._g_loss.set(loss)
-        self._g_step_time.set(rec.step_time_s)
         self._g_rate.set(self.mean_samples_per_sec() or rec.samples_per_sec)
-        self._c_steps.inc()
         for r in self._reporters:
             r(rec)
         return rec

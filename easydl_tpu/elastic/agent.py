@@ -26,7 +26,7 @@ from easydl_tpu.utils.logging import get_logger
 from easydl_tpu.utils.retry import backoff_delay, retry_transient
 from easydl_tpu.utils.rpc import RpcClient
 
-from easydl_tpu.elastic import timeline
+from easydl_tpu.elastic import goodput, timeline
 from easydl_tpu.elastic.master import MASTER_SERVICE
 from easydl_tpu.obs.errors import count_swallowed
 from easydl_tpu.utils.env import default_platform, knob_float, knob_raw
@@ -141,17 +141,11 @@ class Agent:
         self._hb_rate = reg.gauge(
             "easydl_agent_heartbeat_rate_per_s", "Observed heartbeat rate "
             "over the recent window.", ("agent",))
-        self._m_generation = reg.gauge(
-            "easydl_agent_generation", "Generation of the last applied RUN.",
-            ("agent",))
         self._m_worker_rate = reg.gauge(
             "easydl_agent_worker_samples_per_sec", "Worker-reported global "
             "training throughput (from the metrics JSONL).", ("agent",))
         self._m_worker_step = reg.gauge(
             "easydl_agent_worker_step", "Worker-reported training step.",
-            ("agent",))
-        self._m_worker_loss = reg.gauge(
-            "easydl_agent_worker_loss", "Worker-reported training loss.",
             ("agent",))
         self._m_worker_step_time = reg.gauge(
             "easydl_agent_worker_step_time_seconds", "Worker-reported step "
@@ -172,9 +166,28 @@ class Agent:
             "easydl_agent_phase_seconds", "Time from the previous timeline "
             "phase boundary to this one (generation-switch decomposition).",
             ("agent", "phase"))
-        self._m_phase_total = reg.counter(
-            "easydl_agent_phase_events_total", "Timeline phase boundaries "
-            "emitted in-process.", ("agent", "phase"))
+        # The job's own account of its chip-seconds (elastic/goodput.py):
+        # fed each heartbeat from the two files this agent and its worker
+        # write, by offset; another process than the step loop, so always
+        # on. `wasted` is the part of `step` a restore threw away.
+        self._m_chip_seconds = reg.counter(
+            "easydl_job_chip_seconds_total", "Chip-seconds of this agent's "
+            "slots since its first spawn, by what they went to (the causes "
+            "of elastic/goodput.py; reason=\"wasted\" is the part of "
+            "reason=\"step\" that a restore threw away).",
+            ("agent", "reason"))
+        self._m_goodput = reg.gauge(
+            "easydl_job_goodput_ratio", "Share of this agent's wall time "
+            "since its first spawn spent on steps that were kept: "
+            "(step - wasted) / elapsed.", ("agent",))
+        self._account = goodput.Account(chips=slots)
+        self._account_lock = threading.Lock()
+        # the timeline first: an event is then never fed after a record
+        # that was written after it
+        self._tails = [goodput.Tail(self.timeline_path),
+                       goodput.Tail(self.metrics_path)]
+        self._feeds = 0
+        self._feed_s = 0.0
         self._m_outages = reg.counter(
             "easydl_agent_master_outages_total", "Master-unreachable "
             "episodes survived (workers kept training).", ("agent",))
@@ -389,9 +402,11 @@ class Agent:
         updates the phase gauges — durations are measured between
         consecutive in-process boundaries (quiesce_sent → worker_exit →
         spawn), i.e. the agent-side legs of a generation switch."""
-        if path != self.timeline_path:
-            return
         phase = str(rec.get("phase", ""))
+        if path != self.timeline_path or phase == "goodput":
+            # goodput is a reading, not a boundary: it may stand between
+            # two boundaries of a measured leg
+            return
         now = time.monotonic()
         leg = (self._tl_last is not None
                and (self._tl_last[0], phase) in self._PHASE_LEGS)
@@ -418,7 +433,62 @@ class Agent:
         except Exception as e:
             count_swallowed("agent.timeline_emit", e)
         self._tl_last = (phase, now)
-        self._m_phase_total.inc(agent=self.agent_id, phase=phase)
+
+    def goodput(self) -> Optional[Dict[str, Any]]:
+        """The account as of the newest line fed (``goodput.Account.
+        snapshot``); None before the first spawn."""
+        with self._account_lock:
+            return self._account.snapshot()
+
+    def _feed_goodput(self, last: bool = False) -> None:
+        """Feed the account what the two files gained since the last
+        heartbeat, emit the ``goodput`` phase after a line that moved the
+        bottom line (and once at the end, ``last``), and set the series.
+        Best-effort: an account must never take the loop down."""
+        try:
+            t0 = time.perf_counter()
+            lines = [line for tail in self._tails for line in tail.read_new()]
+            lines.sort(key=lambda line: line.get("t", 0.0))
+            with self._account_lock:
+                for line in lines:
+                    self._account.feed(line)
+                    if line.get("phase") in goodput.SNAPSHOT_AFTER:
+                        self._emit_goodput()
+                if last:
+                    self._emit_goodput()
+                snap = self._account.snapshot()
+            if snap is not None and (lines or last):
+                self._export_goodput(snap)
+            self._feeds += 1
+            self._feed_s += time.perf_counter() - t0
+        except Exception as e:
+            count_swallowed("agent.goodput", e)
+
+    def _emit_goodput(self) -> None:
+        snap = self._account.snapshot()
+        if snap is not None:
+            # the record's t is the snapshot's: the account as of that line
+            timeline.emit(self.timeline_path, "goodput", self._applied_key[0],
+                          feeds=self._feeds, feed_s=round(self._feed_s, 6),
+                          **snap)
+
+    def _export_goodput(self, snap: Dict[str, Any]) -> None:
+        reasons = {cause[:-2]: s for cause, s in snap["seconds"].items()
+                   if cause != "unaccounted_s"}
+        reasons["wasted"] = snap["wasted_s"]
+        for reason, seconds in reasons.items():
+            # a counter only grows: a cause that fell (a first step priced
+            # out of first_step) catches up when it next passes its mark
+            behind = seconds * snap["chips"] - self._m_chip_seconds.value(
+                agent=self.agent_id, reason=reason)
+            if behind > 0:
+                self._m_chip_seconds.inc(behind, agent=self.agent_id,
+                                         reason=reason)
+        elapsed = snap["t"] - snap["since"]
+        if elapsed > 0:
+            self._m_goodput.set(
+                (snap["seconds"]["step_s"] - snap["wasted_s"]) / elapsed,
+                agent=self.agent_id)
 
     def run(self) -> None:
         chaos_banner(f"agent-{self.agent_id}")
@@ -445,6 +515,7 @@ class Agent:
             self._terminate_worker(graceful=False)
             self._kill_warm()
             self._kill_preflight()
+            self._feed_goodput(last=True)
             timeline.remove_listener(self._on_timeline_emit)
             if self._exporter is not None:
                 self._exporter.stop()
@@ -497,6 +568,7 @@ class Agent:
             last_kind = directive.kind
             time.sleep(delay)
             metrics = self._read_metrics()
+            self._feed_goodput()
             if self._warm_rearm_ready(metrics):
                 self._warm_due = False
                 self._spawn_warm()
@@ -634,15 +706,12 @@ class Agent:
                 if span > 0:
                     self._hb_rate.set((len(self._hb_times) - 1) / span,
                                       agent=self.agent_id)
-            self._m_generation.set(self._applied_key[0], agent=self.agent_id)
             if metrics:
                 self._m_worker_step.set(float(metrics.get("step", 0)),
                                         agent=self.agent_id)
                 self._m_worker_rate.set(
                     float(metrics.get("samples_per_sec", 0.0)),
                     agent=self.agent_id)
-                self._m_worker_loss.set(float(metrics.get("loss", 0.0)),
-                                        agent=self.agent_id)
                 self._m_worker_step_time.set(
                     float(metrics.get("step_time_s", 0.0)),
                     agent=self.agent_id)

@@ -69,7 +69,7 @@ class _Servicer:
             # counting, so the first directive transition of an RPC-path
             # switch lands on it as an event.
             sw = self._m._trace_switch_span()
-            self._m._count_directive(req.agent_id, d.kind)
+            self._m._note_directive(req.agent_id, d.kind)
             # The journal must carry the new agent (and any cohort change)
             # before the directive leaves the master.
             self._m._persist_if_epoch_advanced()
@@ -122,7 +122,7 @@ class _Servicer:
             # newly in flight) before counting, so the first directive
             # transition lands on the span as an event.
             sw = self._m._trace_switch_span()
-            self._m._count_directive(req.agent_id, d.kind)
+            self._m._note_directive(req.agent_id, d.kind)
             self._m._persist_if_epoch_advanced()
             self._m._drain_reshape_log()
             self._m._drain_mesh_log()
@@ -262,8 +262,8 @@ class Master:
         self._reporter_thread: Optional[threading.Thread] = None
         # Telemetry: the master is the control-plane authority, so its
         # /metrics carries the fleet-level signals the Brain (and any
-        # operator dashboard) needs — generation, membership, directive mix,
-        # time spent per rendezvous phase, and the aggregated train rate.
+        # operator dashboard) needs — generation, membership, time spent
+        # per rendezvous phase, and the aggregated train rate.
         reg = get_registry()
         self._exporter = None
         self._m_generation = reg.gauge(
@@ -275,12 +275,6 @@ class Master:
         self._m_desired = reg.gauge(
             "easydl_master_desired_workers", "Plan-desired worker count.",
             ("job",))
-        self._m_plan_version = reg.gauge(
-            "easydl_master_plan_version", "Version of the applied resource "
-            "plan.", ("job",))
-        self._m_directives = reg.counter(
-            "easydl_master_directives_total", "Directives issued to agents, "
-            "by kind.", ("job", "kind"))
         self._m_phase_seconds = reg.histogram(
             "easydl_master_phase_seconds", "Time spent in each rendezvous "
             "phase before transitioning out of it (drain/re-rendezvous "
@@ -506,7 +500,6 @@ class Master:
                                     job=self.job_name)
                 self._m_desired.set(self.rendezvous.desired_workers,
                                     job=self.job_name)
-                self._m_plan_version.set(self.plan_version, job=self.job_name)
                 # Background journal freshness: structural drift the RPC
                 # path didn't cover (evictions from tick, prepared reports,
                 # host changes) lands on disk within one tick.
@@ -881,19 +874,17 @@ class Master:
         except OSError as e:
             log.warning("event append failed: %s", e)
 
-    def _count_directive(self, agent_id: str, kind: str) -> None:
-        """Count directive TRANSITIONS per agent, not responses: a held
+    def _note_directive(self, agent_id: str, kind: str) -> None:
+        """Note directive TRANSITIONS per agent, not responses: a held
         QUIESCE re-sent on every drain heartbeat (or steady-state NOOP at
-        the full heartbeat rate) is one directive, and the counter's
-        promise is 'directives issued' — the mix must read one long drain
-        as one drain, not fifty. Called with the master lock held."""
+        the full heartbeat rate) is one directive — the switch's span must
+        read one long drain as one drain, not fifty. Called with the master
+        lock held."""
         if self._last_directive_kind.get(agent_id) != kind:
             self._last_directive_kind[agent_id] = kind
-            self._m_directives.inc(job=self.job_name, kind=kind)
             if self._switch_span is not None:
                 # The ladder of the switch (QUIESCE → KILL → RUN per agent)
-                # as events on its span — same transition dedupe as the
-                # counter, so one held QUIESCE is one event.
+                # as events on its span: one held QUIESCE is one event.
                 self._switch_span.add_event(f"directive:{kind}",
                                             agent=agent_id)
 

@@ -44,6 +44,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+from easydl_tpu.elastic.goodput import wasted_steps  # noqa: E402
 from easydl_tpu.utils.env import rerun_on_cpu_mesh  # noqa: E402
 
 
@@ -296,14 +297,14 @@ def preemption_scenario(warm_start: bool) -> dict:
         m0 = read_metrics(wd, "a0")
         pre = [r for r in m0 if r["generation"] <= gen_before and r["t"] < t_kill]
         post = [r for r in m0 if r["generation"] == final_gen]
-        pre_last = max(r["step"] for r in pre)
         first_post = min(post, key=lambda r: r["step"])
         return {
             "scenario": "preemption (SIGKILL worker, no notice)",
             "world": "2 agents x 2 CPU devices",
             "warm_standby": warm_start,
             "recovery_s": round(first_post["t"] - t_kill, 2),
-            "steps_lost": max(0, pre_last - (first_post["step"] - 1)),
+            "steps_lost": len(wasted_steps(
+                (r["step"] for r in pre), first_post["step"] - 1)),
             "ckpt_interval": cfg["ckpt_interval"],
             "detect_mechanism": "heartbeat timeout 1.5s + peer crash report",
             "generations": final_gen,
