@@ -57,6 +57,38 @@ tells the forward by); the differentiation rule turns it once into dense
 is 64 MB of lane padding), and the backward kernels are handed those rows
 by Q-block (``_lse_operand``).
 
+How the looped forward walks a block pair. A ``[512, 512]`` float32 score
+tile is 256 vregs on a register file of 64, and a pair as one tile is one
+chain — ``K Qᵀ``, then max / ``exp`` / sum, then ``Vᵀ Pᵀ`` — with one head of
+128 a cell and so nothing beside it: the TPU's compiler scheduled an
+unmasked pair as 1,336 bundles of which 607 hold a store, every one a spill
+(the tile went to VMEM behind the product, came back for the max, and its
+``exp`` went and came again), where the MXU's own operations need some 790;
+1.21 us a pair on the chip. The looped side (``unroll`` false: more than
+``_UNROLL_PAIRS`` pairs a head) therefore walks a pair in TILES of 128 keys
+x 128 queries (``_FWD_TILE``: one tile of the MXU, 16 vregs of scores), key
+tile after key tile, under each the cell's heads and their Q tiles, as
+straight-line code. A chain — one head's 128 queries — has a running max, a
+normaliser and a ``[value_dim, 128]`` slice of the accumulator of its own,
+kept in VMEM scratch between pairs (read at the chain's first tile of a
+pair, values through it, written back after its last) and corrected a key
+tile at a time: the running max is exact, the float32 sums are taken in
+another order than a block's, as another block size would take them. The
+scores' products are written half a pair's tiles AHEAD of the softmax that
+consumes them (``_behind``: 8 of 16 at one head a cell, 16 of 32 at two), so
+that the scheduler has MXU work that waits for no VPU result. In a masked
+pair whose corner the diagonal passes through (equal blocks, ``s_k − s_q``
+a multiple of them: every training step) a tile wholly above the diagonal
+is not computed and one wholly below it takes no mask — 10 tiles of 16, 4 of
+them masked; anywhere else every tile of a crossed pair is masked under its
+own traced edge. After: 1,181 bundles an unmasked pair, 443 stores of which
+372 spills, 805 a masked pair for 1,381; 0.92 us a pair on the chip (the
+chip gains more than the count says and ranks depth and tile differently,
+so both were swept there: ``_FWD_TILE``). The unrolled side — at most 16
+pairs a head: two heads' straight-line pairs interleave as they are, and
+every pair written out is set-up time — keeps the pair as one tile, its
+program unchanged.
+
 What a rematerialised block may keep. The rule's forward names the two
 results that cost a kernel to make again, ``out`` and the ``lse`` rows
 (``ops/remat.py``), INSIDE the rule: the residuals the backward receives are
@@ -70,7 +102,8 @@ r sees key columns ≤ r + offset, as the reference's ``tril(k=s_k−s_q)``).
 Each Q-block (K-block in the backward that walks Q-blocks) walks its live
 block pairs in two loops:
 pairs wholly below the diagonal take no mask, pairs the diagonal crosses are
-masked. When the whole problem is at most ``_UNROLL_PAIRS`` block pairs, one
+masked (in the looped forward tile by tile, above). When the whole problem
+is at most ``_UNROLL_PAIRS`` block pairs, one
 grid cell takes the whole sequence of its heads: every block index is then a
 Python number, dead pairs are never emitted and both loops unroll into
 straight-line code that the compiler schedules across pairs. Longer
@@ -252,6 +285,10 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     more from wasting less of the causal triangle (5/8 of the matrix computed
     at 256², 3/4 at 512²) than it loses to more pairs — as long as the pairs
     still unroll. Without a triangle the smaller blocks have nothing to win.
+    Where the forward loops it walks its 512 x 512 pair in tiles of 128 x
+    128 with the scores half a pair ahead (``_FWD_TILE``, ``_behind``: the
+    band forward's pattern, PR 49); the block, the grid and what a cell
+    holds are the same.
 
     Under a ``window`` of at most ``BAND_ROWS`` keys (a causal band: query i
     sees the ``window`` keys up to its own) on a square problem the three
@@ -543,38 +580,110 @@ def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
     return named
 
 
+#: keys and queries of a tile of the looped forward's block pair: one tile of
+#: the MXU (a ``[128, 128]`` block of Pᵀ is one set of weights for ``Vᵀ Pᵀ``),
+#: 16 float32 vregs of scores. Swept on a v5e, us a forward call by the
+#: kernel's own events (PERF.md section 6, PR 49), tiles of keys x queries
+#: with their scores ``ahead`` tiles in front of their softmax. One head of
+#: 128 a cell at ``[1, 4096, 16 x 128]`` / ``[2, 8192, 4 x 128]``: the pair as
+#: one tile 730.8 / 1,314.4; 128 x 128 ahead 4 601.9 / 1,113.2, 8 544.7 /
+#: 1,005.4, 12 549.5 / 1,014.1, 16 547.4 / 1,010.8; 128 x 256 ahead 2 600.6 /
+#: 1,101.1, 4 558.5 / 1,019.3; 128 x 512 ahead 1 642.2 / 1,160.8, 2 603.0 /
+#: 1,077.3; 256 x 128 ahead 4 651.3 / 1,201.7. Two heads of 192 / 128 at ``[1,
+#: 8192, 4 x 192 / 128]``: one tile 766.6; 128 x 128 ahead 8 661.4, 12 631.3,
+#: 16 629.7, 24 634.6; 128 x 256 ahead 4 674.3. One rule is within 1% of the
+#: best of each: tiles of 128 x 128, the scores half a pair's tiles ahead (8
+#: at one head a cell, 16 at two). Measured slower and not taken: V turned
+#: once a cell of heads into a scratch (+3 to 5%), the sums read and written a
+#: tile (+0 to 2%), the next pair's first tiles' scores made behind this
+#: pair's last and handed on through a scratch (+5 to 7% at this depth).
+_FWD_TILE = 128
+
+
+def _fwd_tiles(block_q: int, block_k: int,
+               cell_heads: int) -> Tuple[int, int, int]:
+    """``(keys, queries, ahead)``: the tiles the looped forward walks a
+    block pair in, and how many of them the scores run ahead of the softmax:
+    half of what a cell's heads have in a pair. A block the tile does not
+    divide is one tile."""
+    sub_k, sub_q = (_FWD_TILE if block % _FWD_TILE == 0 else block
+                    for block in (block_k, block_q))
+    return sub_k, sub_q, max(
+        1, (block_k // sub_k) * (block_q // sub_q) * cell_heads // 2)
+
+
+def _online_softmax(state, st, vt):
+    """One step of the online softmax: ``(m, l, acc)`` — the running max and
+    normaliser ``[1, queries]`` and ``Oᵀ``'s float32 sum ``[dv, queries]`` —
+    after the keys of the score tile ``st`` (``[keys, queries]``) and their
+    values ``vt`` (``[dv, keys]``)."""
+    m, l, acc = state
+    m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
+    pt = jnp.exp(st - m_new)
+    correction = jnp.exp(m - m_new)
+    l_new = l * correction + jnp.sum(pt, axis=0, keepdims=True)
+    acc_new = acc * correction + _dot(vt, pt.astype(vt.dtype), _NN)
+    return m_new, l_new, acc_new
+
+
+def _behind(steps, first, second, ahead: int = 1):
+    """``second(step, first(step))`` for every step, written so that a
+    step's ``first`` half (its products into scores: the MXU's work) stands
+    in the program ``ahead`` steps before its ``second`` half (softmax and
+    what follows: the VPU's), that is before the ``second`` half of the
+    steps before it: the compiler's scheduler then runs them side by side,
+    which it does not find by itself across a whole step. Two kernels are
+    written so: the band forward's cell of sub-blocks, one behind (8,006
+    bundles as written in order, 7,165 one behind), and the looped forward's
+    block pair of tiles, half a pair behind (``_FWD_TILE``: 0.92 us a pair
+    on the chip eight behind, 1.02 four behind, 1.21 as one tile)."""
+    waiting = []
+    for step in steps:
+        waiting.append((step, first(step)))
+        if len(waiting) > ahead:
+            second(*waiting.pop(0))
+    for behind in waiting:
+        second(*behind)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref,
-    *, head_dim: int, value_dim: int, block_q: int, block_k: int,
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *sums,
+    head_dim: int, value_dim: int, block_q: int, block_k: int,
     causal: bool, scale: float, offset: int, unroll: bool,
     window: Optional[int],
 ):
     # q_ref: [cell rows, cell heads · d], o_ref: [cell rows, cell heads ·
     # dv]; k_ref: [S_k, cell heads · d], v_ref: [S_k, cell heads · dv];
-    # lse_ref: [cell heads, cell rows, 1]
+    # lse_ref: [cell heads, cell rows, 1]; sums (scratch, float32, the looped
+    # side's): m and l [cell heads, 1, block_q], acc [cell heads, dv, block_q]
     cell_rows, lanes = q_ref.shape
     d, dv = head_dim, value_dim
     heads = _head_cols(lanes, d)
     v_heads = _head_cols(v_ref.shape[1], dv)
     n_k = k_ref.shape[0] // block_k
+    # whether the diagonal crosses a masked pair at a place known here: a
+    # square of blocks whose corner it passes through
+    diagonal = (window is None and block_q == block_k
+                and offset % block_k == 0)
     cell_start = 0 if unroll else pl.program_id(2) * cell_rows
     for j in range(cell_rows // block_q):
         rows = slice(j * block_q, (j + 1) * block_q)
         q_start = cell_start + j * block_q
         qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
 
-        def body(kb, carry, *, masked: bool):
-            # a K-block for every head of the cell: V is turned once for all
-            # of them, and their chains are independent work to interleave
+        def whole(kb, carry, *, masked: bool):
+            # the unrolled side: the pair as one tile, a K-block for every
+            # head of the cell — V is turned once for all of them, and their
+            # chains are independent work to interleave
             k_start = _block_start(kb, block_k)
             vt_all = v_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
             out = []
-            for cols, v_cols, (q, s_scale), (m, l, acc) in zip(
+            for cols, v_cols, (q, s_scale), state in zip(
                     heads, v_heads, qs, carry):
                 # m, l: [1, block_q]; acc: [dv, block_q]
                 k = k_ref[pl.ds(k_start, block_k), cols]
@@ -582,22 +691,79 @@ def _fwd_kernel(
                 st = _scores_t(k, q, s_scale,
                                q_start + offset - k_start if masked else None,
                                window)
-                m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
-                pt = jnp.exp(st - m_new)
-                correction = jnp.exp(m - m_new)
-                l_new = l * correction + jnp.sum(pt, axis=0, keepdims=True)
-                acc_new = acc * correction + _dot(vt, pt.astype(vt.dtype), _NN)
-                out.append((m_new, l_new, acc_new))
+                out.append(_online_softmax(state, st, vt))
             return tuple(out)
+
+        def tiled(kb, carry, *, masked: bool):
+            # the looped side: the pair in tiles of [sub_k, sub_q], key tile
+            # after key tile, under each the cell's heads and their Q tiles,
+            # the scores' products ``ahead`` tiles in front of their softmax
+            # (_FWD_TILE has why and the measurements). A chain — one head's
+            # queries of one Q tile — has a running max, a normaliser and a
+            # slice of the accumulator of its own: read from the scratch at
+            # its first tile of the pair, values through the pair, written
+            # back after its last, so that no turn of the loop carries them
+            # in registers it has not got.
+            sub_k, sub_q, ahead = _fwd_tiles(block_q, block_k, len(heads))
+            k_start = _block_start(kb, block_k)
+            steps = [(ki, g, qj) for ki in range(0, block_k, sub_k)
+                     for g in range(len(heads))
+                     for qj in range(0, block_q, sub_q)]
+            # the diagonal's place in a masked pair is static: a tile wholly
+            # above it is not computed, one wholly below it takes no mask
+            placed = masked and diagonal
+            if placed:
+                steps = [(ki, g, qj) for ki, g, qj in steps
+                         if ki <= qj + sub_q - 1]
+            last = {(g, qj): (ki, g, qj) for ki, g, qj in steps}
+            state, vts = {}, {}
+
+            def keys(ki):
+                return pl.ds(_block_start(
+                    kb * (block_k // sub_k) + ki // sub_k, sub_k), sub_k)
+
+            def scores(step):
+                ki, g, qj = step
+                q, s_scale = qs[g]
+                edge = q_start + offset - k_start + qj - ki if masked else None
+                if placed and ki + sub_k - 1 <= qj:
+                    edge = None
+                return _scores_t(k_ref[keys(ki), heads[g]], q[qj:qj + sub_q],
+                                 s_scale, edge, window)
+
+            def softmax(step, st):
+                ki, g, qj = step
+                mine = slice(qj, qj + sub_q)
+                if ki not in vts:  # V's tile turned once for all heads
+                    vts[ki] = v_ref[keys(ki), :].T
+                if (g, qj) not in state:
+                    state[g, qj] = tuple(ref[g, :, mine] for ref in sums)
+                state[g, qj] = _online_softmax(state[g, qj], st,
+                                               vts[ki][v_heads[g]])
+                if step == last[g, qj]:
+                    for ref, value in zip(sums, state.pop((g, qj))):
+                        ref[g, :, mine] = value
+
+            _behind(steps, scores, softmax, ahead)
+            return carry
 
         carry = tuple((
             jnp.full((1, block_q), NEG_INF, jnp.float32),
             jnp.zeros((1, block_q), jnp.float32),
             jnp.zeros((dv, block_q), jnp.float32),
         ) for _ in heads)
+        if not unroll:  # the sums live in the scratch, the loops carry none
+            for g, start in enumerate(carry):
+                for ref, value in zip(sums, start):
+                    ref[g] = value
+            carry = None
         carry = _over_k_blocks(
-            body, carry, q_start, block_q=block_q, block_k=block_k, n_k=n_k,
-            offset=offset, causal=causal, unroll=unroll, window=window)
+            whole if unroll else tiled, carry, q_start, block_q=block_q,
+            block_k=block_k, n_k=n_k, offset=offset, causal=causal,
+            unroll=unroll, window=window)
+        if not unroll:
+            carry = tuple(tuple(ref[g] for ref in sums)
+                          for g in range(len(heads)))
         o_ts = []
         for g, (m, l, acc) in enumerate(carry):
             # Rows that saw no unmasked key (bottom-right-aligned causal with
@@ -620,12 +786,30 @@ def _fwd_kernel(
 
 def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
          block_k: int, interpret: bool, window: Optional[int] = None):
+    """The forward call; where it is looped, a ``jax.jit`` of its own. The
+    body walked in tiles is sixteen times the operations of the pair as one
+    tile, a ``pallas_call``'s body is traced and lowered once a USE (the
+    pass, remat's, each run of layers, each of the benchmark's programs) and
+    tracing is set-up time at every start, cached executable or not: JoyAI's
+    step lowered in 11.1 s for the parent's 8.1 before the jit, Ouro's in 5.8
+    for 3.4 (PERF.md section 6, PR 49; ``ops/ssd.py _kernel_jit`` is the
+    same cure). The unrolled side keeps the plain call, and the three GPT-2
+    steps their text."""
     # q, k: [B, S, H·d]; v: [B, S, H·dv]
+    s_q, s_k = q.shape[1], k.shape[1]
+    assert s_q % block_q == 0 and s_k % block_k == 0, (s_q, s_k, block_q, block_k)
+    unroll = _unrolled(s_q // block_q, s_k // block_k)
+    return (_fwd_call if unroll else _fwd_looped)(
+        q, k, v, heads=heads, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, window=window, unroll=unroll)
+
+
+def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
+              block_q: int, block_k: int, interpret: bool,
+              window: Optional[int], unroll: bool):
     b, s_q, width = q.shape
     s_k, d, dv = k.shape[1], width // heads, v.shape[2] // heads
-    assert s_q % block_q == 0 and s_k % block_k == 0, (s_q, s_k, block_q, block_k)
     n_q, n_k = s_q // block_q, s_k // block_k
-    unroll = _unrolled(n_q, n_k)
     cell_rows = s_q if unroll else block_q
     # q, o, k, v and the lse column (float32, one lane in 128)
     cell = _cell_heads(heads, d, n_q * n_k, unroll,
@@ -657,10 +841,21 @@ def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
             jax.ShapeDtypeStruct((b, s_q, heads * dv), q.dtype),
             jax.ShapeDtypeStruct((b, heads, s_q, 1), jnp.float32),
         ],
+        # the looped side's running max, normaliser and accumulator of a
+        # cell's heads (0.26 MB a head at 512 x 128)
+        scratch_shapes=[] if unroll else [
+            pltpu.VMEM((cell, 1, block_q), jnp.float32),
+            pltpu.VMEM((cell, 1, block_q), jnp.float32),
+            pltpu.VMEM((cell, dv, block_q), jnp.float32)],
         interpret=interpret,
         **_call_name("fwd", d, dv, window),
     )(q, k, v)
     return out, lse
+
+
+_fwd_looped = jax.jit(_fwd_call, static_argnames=(
+    "heads", "causal", "scale", "block_q", "block_k", "interpret", "window",
+    "unroll"))
 
 
 # ---------------------------------------------------------------------------
@@ -1040,23 +1235,6 @@ def _neighbour_cap(hidden):
     return jnp.where(hidden, NEG_INF, -NEG_INF)
 
 
-def _one_behind(starts, first, second):
-    """``second(at, first(at))`` for every sub-block, written so that a
-    sub-block's ``first`` half (its products into scores: the MXU's work)
-    stands in the program before the ``second`` half of the sub-block before
-    it (softmax and what follows: the VPU's): the compiler's scheduler then
-    runs them side by side, which it does not find by itself across a whole
-    sub-block (the forward's cell: 8,006 bundles as written in order, 7,165
-    one behind)."""
-    behind = None
-    for at in starts:
-        ahead = first(at)
-        if behind is not None:
-            second(*behind)
-        behind = (at, ahead)
-    second(*behind)
-
-
 def _band_fwd_kernel(
     q_ref, k_ref, v_ref, k_prev_ref, v_prev_ref, o_ref, lse_ref,
     *, head_dim: int, band: Band, window: int, scale: float,
@@ -1111,7 +1289,7 @@ def _band_fwd_kernel(
             lse_ref[g, mine, :] = jnp.broadcast_to(lse, (8, sub)).T[:, :1]
         o_ref[mine, :] = _side_by_side(o_ts, 0).T.astype(o_ref.dtype)
 
-    _one_behind(range(0, rows, sub), scores,
+    _behind(range(0, rows, sub), scores,
                 lambda at, found: finish(at, softmax(found)))
 
 
@@ -1416,14 +1594,22 @@ def flash_attention(
     if not isinstance(blocks[2], Band) and not _unrolled(
             s // blocks[2][0], s_k // blocks[2][1]):
         cuts = {"fwd": blocks[0], "bwd": blocks[2]}  # what _bwd will call
+    tile = _cell_heads(h, d, 0, False, 0, dv)  # before short sequences widen it
+
+    def walk(name, cut):
+        if _unrolled(s // cut[0], s_k // cut[1]):
+            return "unrolled"
+        sub_k, sub_q, ahead = _fwd_tiles(*cut, tile)
+        if name != "fwd" or (sub_q, sub_k) == cut:
+            return "looped"
+        return f"looped in tiles of {sub_q}/{sub_k}, {ahead} behind"
+
     chosen = ", ".join(
         f"{name} band {cut.rows}/{cut.sub} beside {cut.reach}"
         if isinstance(cut, Band) else
-        f"{name} {cut[0]}/{cut[1]} "
-        + ("unrolled" if _unrolled(s // cut[0], s_k // cut[1]) else "looped")
+        f"{name} {cut[0]}/{cut[1]} {walk(name, cut)}"
         for name, cut in cuts.items()) + (", one kernel" if "bwd" in cuts
                                           else "")
-    tile = _cell_heads(h, d, 0, False, 0, dv)  # before short sequences widen it
     sizes = f"head_dim {d}" if dv == d else f"head_dim {d}/{dv}"
     lanes = f"{tile * d}-lane block" if dv == d else \
         f"{tile * d}-lane block of q, k and a {tile * dv}-lane block of v, O"

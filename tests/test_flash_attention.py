@@ -8,6 +8,7 @@ compiles): every case is ONE jitted program on inputs drawn on the host.
 """
 
 import ast
+import contextlib
 import functools
 import logging
 
@@ -52,6 +53,138 @@ def test_forward_matches_reference(case):
     assert out.dtype == q.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
+
+
+def _float32_out_and_lse(q, k, v, causal):
+    """``out`` and ``lse`` of ``[B, S, H, d]`` operands by whole score
+    matrices in float32 ``jax.numpy``, the mask bottom-right aligned; a row
+    that sees no key: zero output, ``lse`` the kernels' poison."""
+    q, k, v = (jnp.asarray(x, jnp.float32) for x in (q, k, v))
+    s_q, s_k = q.shape[1], k.shape[1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        precision="highest") * q.shape[-1] ** -0.5
+    ahead = jnp.arange(s_k)[None, :] - jnp.arange(s_q)[:, None] - (s_k - s_q)
+    seen = ahead <= 0 if causal else jnp.ones((s_q, s_k), bool)
+    live = seen.any(-1)[None, None, :, None]
+    scores = jnp.where(seen, scores, -jnp.inf)
+    lse = jax.nn.logsumexp(jnp.where(live, scores, 0.0), axis=-1,
+                           keepdims=True)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(scores - lse), v,
+                     precision="highest")
+    return (jnp.where(live.transpose(0, 2, 1, 3), out, 0.0),
+            jnp.where(live, lse, -flash_module.NEG_INF))
+
+
+# the looped forward, more than 16 block pairs a head, its pair walked in
+# tiles of 128 (blocks of 256: four tiles, two behind; 512: sixteen, eight
+# behind): s_q, s_k, heads, d, dv, block_q, block_k (None: the call's own),
+# causal, dtype, tolerance
+LOOPED_FORWARD = {
+    # pairs under the diagonal whole, the pair it crosses placed statically
+    "128-blocks-256": (1280, 1280, 1, 128, 128, 256, 256, True, "float32", 2e-5),
+    "default-512-bf16": (2560, 2560, 1, 128, 128, None, None, True, "bfloat16", 2e-2),
+    "192-128-two-heads": (1280, 1280, 2, 192, 128, 256, 256, True, "float32", 2e-5),
+    "192-128-bf16": (1280, 1280, 2, 192, 128, 256, 256, True, "bfloat16", 2e-2),
+    "no-mask": (1280, 1280, 1, 128, 128, 256, 256, False, "float32", 2e-5),
+    # bottom-right aligned: 512 rows see no key; every row sees 768 keys more
+    "more-queries-than-keys": (1536, 1024, 1, 128, 128, 256, 256, True, "float32", 2e-5),
+    "more-keys-than-queries": (768, 1536, 1, 128, 128, 256, 256, True, "float32", 2e-5),
+    # the diagonal at no static place: every tile of a crossed pair masked
+    # under its own traced edge
+    "uneven-blocks": (1152, 1280, 1, 128, 128, 128, 256, True, "float32", 2e-5),
+    # two heads of 64 a cell, tiles of both
+    "two-heads-of-64": (1280, 1280, 2, 64, 64, 256, 256, True, "bfloat16", 2e-2),
+}
+
+
+@pytest.mark.parametrize("case", LOOPED_FORWARD)
+def test_the_looped_forwards_out_and_lse_against_float32(case):
+    (s_q, s_k, heads, d, dv, block_q, block_k, causal, dtype,
+     tol) = LOOPED_FORWARD[case]
+    q, k, v = normal(7, (1, s_q, heads, d), (1, s_k, heads, d),
+                     (1, s_k, heads, dv), dtype=dtype)
+    blocks = choose_blocks(s_q, s_k, causal, block_q, block_k)[0]
+    assert not flash_module._unrolled(s_q // blocks[0], s_k // blocks[1])
+    assert flash_module._fwd_tiles(*blocks, 1)[:2] == (128, 128)
+
+    def forward(q, k, v):
+        out, lse = flash_module._fwd(
+            q.reshape(1, s_q, heads * d), k.reshape(1, s_k, heads * d),
+            v.reshape(1, s_k, heads * dv), heads=heads, causal=causal,
+            scale=d ** -0.5, block_q=blocks[0], block_k=blocks[1],
+            interpret=True)
+        return out.reshape(1, s_q, heads, dv), lse
+
+    (out, lse), (want, want_lse) = jax.jit(lambda q, k, v: (
+        forward(q, k, v), _float32_out_and_lse(q, k, v, causal)))(q, k, v)
+    assert out.dtype == q.dtype and lse.dtype == jnp.float32
+    assert lse.shape == (1, heads, s_q, 1)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), atol=tol, rtol=tol)
+    # lse: float32 whatever the operands are; bf16 operands round the
+    # reference's scores too
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               atol=tol, rtol=1e-5)
+    if s_q > s_k:  # the rows before the first key: nothing, and the poison
+        dead = s_q - s_k
+        assert not np.asarray(out[:, :dead], np.float32).any()
+        assert (np.asarray(lse[:, :, :dead]) == -flash_module.NEG_INF).all()
+
+
+@contextlib.contextmanager
+def _flash_log(monkeypatch):
+    """What ``ops/flash_attention.py`` logs inside the block, the lines a
+    process logs once logged anew."""
+    monkeypatch.setattr(easydl_logging, "_logged_once", set())
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda record: records.append(record.getMessage())
+    flash_module.log.addHandler(handler)
+    try:
+        yield records
+    finally:
+        flash_module.log.removeHandler(handler)
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, the jitted calls' inside."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("d,dv,window,s_q,s_k,name,walk", [
+    (128, 128, None, 1280, 1280, "flash_fwd", "looped in tiles of 128/128, 2 behind"),
+    (192, 128, None, 1280, 1280, "mla_fwd", "looped in tiles of 128/128, 4 behind"),
+    (128, 128, 384, 1024, 1280, "swa_fwd", "looped in tiles of 128/128, 2 behind"),
+    (128, 128, None, 1024, 1024, "flash_fwd", "unrolled"),
+    (192, 128, None, 1024, 1024, "mla_fwd", "unrolled"),
+], ids=["flash", "mla", "swa-rectangle", "flash-16-pairs", "mla-16-pairs"])
+def test_what_the_benchmarks_readers_tell_the_forward_by(
+        monkeypatch, d, dv, window, s_q, s_k, name, walk):
+    """``benchmark/lib/hlo.flash_calls`` tells the forward by its name and
+    its two results' types: ``[B, S, H·dv]`` of the operands' dtype and a
+    float32 ``[B, H, S, 1]`` — the looped call's as the unrolled one's. A
+    problem of at most 16 block pairs a head keeps the unrolled body, with
+    no scratch; the logged line says which."""
+    heads = 2
+    q, k, v = normal(1, (1, s_q, heads, d), (1, s_k, heads, d),
+                     (1, s_k, heads, dv), dtype="bfloat16")
+    with _flash_log(monkeypatch) as records:
+        jaxpr = jax.make_jaxpr(functools.partial(
+            flash_attention, causal=True, block_q=256, block_k=256,
+            interpret=True, window=window))(q, k, v)
+    call, = _pallas_calls(jaxpr.jaxpr)
+    assert call.params["name"] == name
+    assert [(x.aval.shape, x.aval.dtype) for x in call.outvars] == [
+        ((1, s_q, heads * dv), jnp.bfloat16),
+        ((1, heads, s_q, 1), jnp.float32)]
+    scratch = call.params["grid_mapping"].num_scratch_operands
+    assert scratch == (0 if walk == "unrolled" else 3)
+    line, = records
+    assert f"blocks q/k fwd 256/256 {walk}, " in line, line
 
 
 def test_untileable_length_falls_back_to_reference():
@@ -163,18 +296,11 @@ def test_the_logged_line_says_how_many_kernels_the_backward_is(
         monkeypatch, s, window, said):
     """The engagement is static: the line a process logs once for a call's
     shape says ``one kernel`` where (and only where) the backward is."""
-    monkeypatch.setattr(easydl_logging, "_logged_once", set())
-    records = []
-    handler = logging.Handler()
-    handler.emit = lambda record: records.append(record.getMessage())
-    flash_module.log.addHandler(handler)
-    try:
-        q, k, v = qkv(1, b=1, s=s, h=1, d=32)
+    q, k, v = qkv(1, b=1, s=s, h=1, d=32)
+    with _flash_log(monkeypatch) as records:
         jax.eval_shape(functools.partial(
             flash_attention, causal=True, block_q=32, block_k=32,
             interpret=True, window=window), q, k, v)
-    finally:
-        flash_module.log.removeHandler(handler)
     line, = records
     assert f"blocks q/k {said} over lengths" in line, line
 

@@ -57,12 +57,15 @@ def test_the_models_layout_even_and_odd_head_counts(heads, d, dtype):
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("s_q,s_k,block_q,block_k", [
     (64, 64, 32, 32), (64, 128, 32, 64), (128, 64, 64, 32),
-    (256, 256, 32, 32), (128, 256, 16, 64)],
-    ids=["square", "sq<sk", "sq>sk", "looped-square", "looped-sq<sk"])
+    (256, 256, 32, 32), (128, 256, 16, 64), (1280, 1280, 256, 256),
+    (1536, 1024, 256, 256)],
+    ids=["square", "sq<sk", "sq>sk", "looped-square", "looped-sq<sk",
+         "looped-in-tiles", "looped-in-tiles-sq>sk"])
 def test_the_models_layout_rectangular_unrolled_and_looped(
         s_q, s_k, block_q, block_k, causal, heads):
     """Heads of 64 two to a lane block on both sides of ``_UNROLL_PAIRS``,
-    square and with an offset either way (dead rows where s_q > s_k)."""
+    square and with an offset either way (dead rows where s_q > s_k); at
+    blocks of 256 the looped forward walks a pair in tiles of 128."""
     q, k, v = normal(12, (1, s_q, heads, 64), *[(1, s_k, heads, 64)] * 2)
     _flash_vs_reference(q, k, v, causal=causal, block_q=block_q,
                         block_k=block_k, atol=2e-5, rtol=2e-5, grad_tol=5e-4)

@@ -61,6 +61,11 @@ WINDOW_CASES = [
     _case(1024, 1024, None, 512, BAND, heads=1, d=128),
     # one key past the block, and a rectangle: the looped kernels
     _case(256, 256, 16, 17, LOOP), _case(128, 256, 16, 16, LOOP),
+    # the looped walk with the forward's pair in tiles of 128 (blocks of
+    # 256: four tiles, each masked under its own two edges): a rectangle,
+    # and a square whose window the caller's neighbour block does not hold
+    _case(1024, 1280, 256, 384, LOOP, heads=1, d=128),
+    _case(1280, 1280, 256, 300, LOOP, heads=2, d=64),
 ]
 
 

@@ -45,6 +45,10 @@ CASES = {
     "unrolled-192-128": (256, 256, 2, 192, 128, 128, 128),
     "looped-192-128": (640, 640, 2, 192, 128, 128, 128),   # 25 block pairs
     "looped-uneven-blocks": (768, 768, 2, 24, 16, 128, 64),
+    # the forward's pair in tiles of 128 x 128, two heads a cell: eight
+    # tiles, four behind (and the backward reads that forward's lse)
+    "looped-192-128-in-tiles": (1280, 1280, 2, 192, 128, 256, 256),
+    "looped-rectangle-in-tiles": (768, 1536, 2, 192, 128, 256, 256),
     "rectangle": (128, 384, 2, 192, 128, 64, 128),
     "three-heads-of-a-tile-of-two": (128, 128, 3, 192, 128, 64, 64),
     "value-wider-than-score": (256, 256, 2, 64, 128, 64, 64),
