@@ -50,7 +50,8 @@ class ScheduleFailed(SystemExit):
 
 
 def run(run: Any) -> Dict[str, Any]:
-    from lib import devices as dev, timeline_reduce as tl, traffic
+    from lib import (devices as dev, goodput_events, timeline_reduce as tl,
+                     traffic)
 
     config, mix = run.config, run.traffic
     kwargs = config["kwargs"]
@@ -184,7 +185,7 @@ def run(run: Any) -> Dict[str, Any]:
     # ------------------------------------- the device, now that it is free
     device, memory = _device_and_step_memory(run, worker_config)
     step_busy_s = sum(r["step_time_s"] for r in window)
-    return {
+    artifacts = {
         "device": device,
         "chips": run.cell["chips"],
         "memory_peak_bytes": memory["argument_bytes"] + memory["temp_bytes"]
@@ -210,6 +211,9 @@ def run(run: Any) -> Dict[str, Any]:
         "breakdown": _breakdown(tl, records, timeline, t_kill,
                                 killed_generation, 2 * n_save, step_busy_s),
     }
+    # how far the account's two snapshots stand from the window's edges
+    artifacts["goodput_edges"] = goodput_events.edge_distances(artifacts)
+    return artifacts
 
 
 def _breakdown(tl, records, timeline, t_kill, killed_generation, save_step,

@@ -9,20 +9,64 @@ step as ``models/run.py`` and the elastic worker do; the clock stops when the
 last step's loss is on the host. A traced run then profiles a few more steps
 with ``jax.profiler`` and the benchmark's own host annotations.
 
-Reads from the traffic file: ``global_batch``, ``grad_accum``, ``optimizer``
-(an optax factory and its arguments), ``tokens.support``, ``warmup_steps``,
-``trace_steps``. Reads from the configuration file: ``platform``, ``chips``,
-``mesh``, ``factory``, ``kwargs``, ``check``.
+Two keys of the traffic file, both absent in a mix that does not need them,
+keep a run's work and time the cell's own (PERF.md section 2, "What steadies
+a run"):
+
+- ``dispatch_ahead_steps`` (0): how many steps may be dispatched beyond the
+  one whose loss is awaited. At 0 the loop is closed. Above 0 the chip stays
+  fed while the host stands still for as long as that many steps take; losses
+  are read that many steps late; when the time is up nothing more is sent,
+  every step that was sent is waited for, and the clock is read after that
+  wait: all of that work over all of that time.
+- ``weights_seed`` (``--seed``): the seed of the TRAINED state, where the
+  weights decide how much work a step is (a top-1 router's load). The
+  batches, the check's weights and its sequences stay ``--seed``'s.
+
+Reads from the traffic file besides: ``global_batch``, ``grad_accum``,
+``optimizer`` (an optax factory and its arguments), ``tokens.support``,
+``warmup_steps``, ``trace_steps``. Reads from the configuration file:
+``platform``, ``chips``, ``mesh``, ``factory``, ``kwargs``, ``check``.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import glob
 import importlib
 import math
 import os
 import time
 from typing import Any, Dict
+
+
+def drive(trainer: Any, state: Any, data: Any, ahead: int, more: Any,
+          span: Any = None):
+    """Steps while ``more()``, at most ``ahead`` of them dispatched beyond the
+    one whose loss is awaited; then every step that was sent is waited for.
+    Returns the state, each loss and the host clock as it arrived. ``span``
+    names the host's three parts of a step in a traced run."""
+    span = span or (lambda name: contextlib.nullcontext())
+    sent = collections.deque()
+    losses, arrived = [], []
+
+    def fetch():
+        with span("bench/fetch_loss"):
+            losses.append(float(sent.popleft()["loss"]))  # blocks: it is done
+        arrived.append(time.perf_counter())
+
+    while more():
+        with span("bench/next_data"):
+            host_batch = next(data)
+        with span("bench/dispatch"):
+            state, metrics = trainer.train_step(state, host_batch)
+        sent.append(metrics)
+        while len(sent) > ahead:
+            fetch()
+    while sent:
+        fetch()
+    return state, losses, arrived
 
 
 def run(run: Any) -> Dict[str, Any]:
@@ -49,7 +93,9 @@ def run(run: Any) -> Dict[str, Any]:
     opt = mix["optimizer"]
     bundle, trainer = program.build_trainer(
         config, mix["global_batch"], mix["grad_accum"],
-        getattr(optax, opt["name"])(**opt["args"]), run.seed, devices)
+        getattr(optax, opt["name"])(**opt["args"]),
+        mix.get("weights_seed", run.seed), devices)
+    ahead = mix.get("dispatch_ahead_steps", 0)
 
     checker = importlib.import_module(f"lib.{config['check']['module']}")
     check = checker.check(config, bundle, trainer, run.seed)
@@ -73,15 +119,14 @@ def run(run: Any) -> Dict[str, Any]:
 
     # ------------------------------------------------------------ window
     compiles_before = watch.events
-    step_s, losses = [], []
     t_open = time.perf_counter()
     setup_s = time.time() - run.t_start
-    while time.perf_counter() - t_open < run.seconds:
-        t0 = time.perf_counter()
-        state, metrics = trainer.train_step(state, next(data))
-        losses.append(float(metrics["loss"]))  # blocks: the step is done
-        step_s.append(time.perf_counter() - t0)
-    elapsed_s = time.perf_counter() - t_open
+    state, losses, arrived = drive(
+        trainer, state, data, ahead,
+        lambda: time.perf_counter() - t_open < run.seconds)
+    elapsed_s = arrived[-1] - t_open  # read after the last wait
+    # a step's time: from the loss before it (the window's opening) to its own
+    step_s = [b - a for a, b in zip([t_open] + arrived, arrived)]
     compiles_in_window = watch.events - compiles_before
 
     traced = {}
@@ -91,13 +136,12 @@ def run(run: Any) -> Dict[str, Any]:
         options.python_tracer_level = 0  # host spans are the benchmark's own
         jax.profiler.start_trace(trace_dir, profiler_options=options)
         with jax.profiler.TraceAnnotation("bench/window"):
-            for _ in range(mix["trace_steps"]):
-                with jax.profiler.TraceAnnotation("bench/next_data"):
-                    host_batch = next(data)
-                with jax.profiler.TraceAnnotation("bench/dispatch"):
-                    state, metrics = trainer.train_step(state, host_batch)
-                with jax.profiler.TraceAnnotation("bench/fetch_loss"):
-                    losses.append(float(metrics["loss"]))
+            left = iter(range(mix["trace_steps"]))
+            state, more_losses, _ = drive(
+                trainer, state, data, ahead,
+                lambda: next(left, None) is not None,
+                jax.profiler.TraceAnnotation)
+            losses += more_losses
         jax.profiler.stop_trace()
         path = sorted(glob.glob(os.path.join(
             trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
@@ -137,6 +181,8 @@ def run(run: Any) -> Dict[str, Any]:
         "steps": n_window,
         "tokens_per_step": mix["global_batch"] * seq_len,
         "step_s": step_s,
+        "dispatch_ahead_steps": ahead,
+        "weights_seed": mix.get("weights_seed", run.seed),
         "losses": losses[:n_window],
         "attempted": n_window,
         "failed": sum(1 for x in losses if not math.isfinite(x)),

@@ -1,9 +1,11 @@
-"""model: share of the device's busy time under the block's ``attention``
-scope — ``ln_attn``, the q, k, v and out projections, the residual add and
-the three flash kernels — in every pass (lib/scope_reduce.py)."""
+"""model: share of the device's busy time under the blocks' ``attention`` scope
+— the norms, the projections (or a latent mixer's maps), rotary, the residual
+add and the flash kernels — in every pass; where it stands in the program's
+names is told by the cell's module (lib/told.py). A cell with two attention
+kinds has ``full_attn_`` / ``band_attn_time_pct`` in its place."""
 
-from lib import scope_reduce
+from lib import told
 
 
 def read(artifacts):
-    return scope_reduce.part_pct(artifacts, "attention")
+    return told.share_pct(artifacts, "attn_time_pct")

@@ -1,10 +1,10 @@
-"""ops: roofline share of the flash dkv kernel alone, told by the name the
-program gives it (``flash_bwd_dkv`` on the instruction's path), its shape from the
-same instruction, FLOPs and bytes as ``flash_roofline`` counts them for that
-kind (lib/scope_reduce.py)."""
+"""ops: roofline share of the UNROLLED flash backward's dk/dv kernel alone
+(``flash_bwd_dkv``: four products a pair), told by the name the program gives
+it, its shape from the same instruction, FLOPs and bytes by the cell's
+module's cost of a call (lib/told.py, lib/flops.py)."""
 
-from lib import scope_reduce
+from lib import told
 
 
 def read(artifacts):
-    return scope_reduce.kernel_roofline_of_run(artifacts, "flash_bwd_dkv")
+    return told.kernel_roofline_pct(artifacts, "flash_dkv_roofline")

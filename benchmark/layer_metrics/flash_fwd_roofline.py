@@ -1,10 +1,12 @@
-"""ops: roofline share of the flash forward kernel alone, told by the name the
-program gives it (``flash_fwd`` on the instruction's path), its shape from the
-same instruction, FLOPs and bytes as ``flash_roofline`` counts them for that
-kind (lib/scope_reduce.py)."""
+"""ops: roofline share of the full-attention flash FORWARD kernel alone — the
+calls the program names as the cell's module says (``flash_fwd``; ``mla_fwd``
+at two head sizes), FLOPs of the causal pairs and the operands' bytes by that
+module's cost of a call (lib/told.py), against the chip's published peaks, over
+the time the calls took in the traced window. A cell's band-path kernels are
+``band_flash_fwd_roofline``'s."""
 
-from lib import scope_reduce
+from lib import told
 
 
 def read(artifacts):
-    return scope_reduce.kernel_roofline_of_run(artifacts, "flash_fwd")
+    return told.kernel_roofline_pct(artifacts, "flash_fwd_roofline")

@@ -1,6 +1,10 @@
-"""ops: share of the device's busy time spent in the Mosaic flash-attention
-calls (forward, dq, dkv), found in the trace by the names the compiled step's
-own HLO gives them."""
+"""ops: share of the device's busy time in EVERY flash-attention kernel of the
+cell, forward and backward — the Mosaic calls lib/hlo.flash_calls lists by the
+names the program gives them (``flash_fwd``, ``flash_bwd``, ``flash_bwd_dq``,
+``flash_bwd_dkv``; ``swa_*`` on the band path; ``mla_*``), the recomputed
+forward's second run included — so ``attn_time_pct`` less this is attention
+outside its kernels (in a cell with two attention kinds: their two shares'
+sum less this)."""
 
 
 def read(artifacts):

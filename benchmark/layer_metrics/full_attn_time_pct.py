@@ -1,9 +1,9 @@
 """model: share of the device's busy time under the ``attention`` scope of the
-FULL attention layers' runs (48 query heads over 8 key/value heads of 128,
-partial YaRN rotary), every pass of differentiation (lib/laguna_names.py)."""
+FULL-attention layers' runs in a stack that also has window layers (the runs'
+names by the cell's module: lib/told.py), every pass."""
 
-from lib import laguna_names
+from lib import told
 
 
 def read(artifacts):
-    return laguna_names.attention_pct(artifacts, "full_attention")
+    return told.share_pct(artifacts, "full_attn_time_pct")

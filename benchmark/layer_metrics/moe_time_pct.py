@@ -1,12 +1,11 @@
 """model: share of the device's busy time in the expert layers: the block's
 ``moe`` scope, from its norm to the residual add — router, dispatch, the
-shared expert, combine — plus the grouped products, which are the compiler's
-own kernels and carry no name of the program's (lib/laguna_names.py); every
-pass of differentiation. ``ffn_time_pct``'s sibling: ``ffn`` is the dense
-FFN's."""
+grouped products over the experts held, combine, a shared expert where there
+is one — every pass (lib/scope_names.py). ``ffn_time_pct``'s sibling: ``ffn``
+is the dense FFN's."""
 
-from lib import laguna_names
+from lib import scope_names
 
 
 def read(artifacts):
-    return laguna_names.pct_with_grouped_products(artifacts, "moe")
+    return scope_names.pct_under_any(artifacts, ('moe',))

@@ -27,7 +27,9 @@ from lib.flops_laguna import seen_pairs
 #: products a latent-attention flash call makes, by what they contract
 #: over: ``scores`` are ``S = K Q^T`` and the gradients through it (``dQ =
 #: dS K``, ``dK = dS^T Q``), 192 deep; ``values`` are ``P V``, ``dP = V
-#: dO^T`` and ``dV = P^T dO``, 128 deep. dq and dkv each form S and dP again.
+#: dO^T`` and ``dV = P^T dO``, 128 deep. dq and dkv each form S and dP again;
+#: ``bwd``, the looped backward's one call (PR 39), forms them once: three
+#: products at the scores' depth and two at the values'.
 #: Arrays read and written, at the scores' width (q, k, dq, dk) and at the
 #: values' (v, O, dO, dv); float32 rows a head (``lse``).
 MLA_CALLS: Dict[str, Dict[str, int]] = {
@@ -36,6 +38,8 @@ MLA_CALLS: Dict[str, Dict[str, int]] = {
     "dq": {"scores": 2, "values": 1, "at_scores": 3, "at_values": 3,
            "vecs": 1},
     "dkv": {"scores": 2, "values": 2, "at_scores": 3, "at_values": 4,
+            "vecs": 1},
+    "bwd": {"scores": 3, "values": 2, "at_scores": 4, "at_values": 4,
             "vecs": 1},
 }
 
@@ -124,7 +128,7 @@ def mla_flash_cost(kind: str, batch: int, seq: int, heads: int,
                    score: int, value: int,
                    bytes_per_el: int = 2) -> Dict[str, float]:
     """FLOPs and HBM bytes one causal latent-attention flash call of
-    ``kind`` (``fwd``, ``dq``, ``dkv``) needs on ``[batch, seq, heads x
+    ``kind`` (``fwd``, ``dq``, ``dkv``, ``bwd``) needs on ``[batch, seq, heads x
     score]`` q and k (the shared rotated key copied to every head: what the
     kernels read) and ``[batch, seq, heads x value]`` v, O, dO: 2 FLOPs a
     pair the mask keeps and lane of each product."""

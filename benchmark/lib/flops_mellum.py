@@ -113,18 +113,3 @@ def flash_band_cost(kind: str, batch: int, seq: int, width: int,
     bytes_ = batch * (mats * seq * width * bytes_per_el
                       + call["vecs"] * seq * (width // head_dim) * 4)
     return {"flops": flops, "bytes": float(bytes_)}
-
-
-def flash_fwd_cost(batch: int, seq: int, heads: int, kv_heads: int,
-                   head_dim: int, bytes_per_el: int = 2) -> Dict[str, float]:
-    """FLOPs and HBM bytes one causal flash forward call needs on ``[batch,
-    seq, heads x head_dim]`` q under grouped-query attention: 2 FLOPs a pair
-    the mask keeps and lane of ``S = Q K^T`` and of ``P V``; q and O at the
-    query heads, k and v at the ``kv_heads`` a grouped kernel could not
-    avoid reading (the program repeats them to the query heads in HBM and
-    its kernel reads eight times that: the share reads low for it, never
-    high), float32 ``lse`` a row and head."""
-    flops = batch * heads * 2.0 * seen_pairs(seq) * 2 * head_dim
-    bytes_ = batch * seq * (
-        2 * (heads + kv_heads) * head_dim * bytes_per_el + heads * 4)
-    return {"flops": flops, "bytes": float(bytes_)}

@@ -116,18 +116,3 @@ def ssd_train_cost_per_token(config: Dict[str, Any]) -> Dict[str, float]:
     (``lib/flops_ssd.py``: B and C are read and written at ``n_groups x
     ssm_state_size`` lanes)."""
     return flops_ssd.ssd_train_cost_per_token(**ssd_shape(config))
-
-
-def flash_fwd_cost(batch: int, seq: int, heads: int, kv_heads: int,
-                   head_dim: int, bytes_per_el: int = 2) -> Dict[str, float]:
-    """FLOPs and HBM bytes one causal flash forward call needs on ``[batch,
-    seq, heads x head_dim]`` q under grouped-query attention: 2 FLOPs a pair
-    the mask keeps and lane of ``S = Q K^T`` and of ``P V``; q and O at the
-    query heads, k and v at the ``kv_heads`` a grouped kernel could not avoid
-    reading (the program repeats them to the query heads in HBM and its
-    kernel reads sixteen times that: the share reads low for it, never
-    high), float32 ``lse`` a row and head."""
-    flops = batch * heads * 2.0 * seen_pairs(seq) * 2 * head_dim
-    bytes_ = batch * seq * (
-        2 * (heads + kv_heads) * head_dim * bytes_per_el + heads * 4)
-    return {"flops": flops, "bytes": float(bytes_)}

@@ -3,13 +3,21 @@ the agent keeps of its own chip-seconds (``easydl_tpu/elastic/goodput.py``),
 a snapshot on the timeline after every commit, restore and first step and
 once when the agent stops.
 
-The window of the kill-resume cell opens at C0's commit, which emits one
-(its ``t`` is the commit's, so it may precede the driver's ``t_open`` by its
-20 ms poll), and closes just before the agent's ``stop()``, which emits the
-last. The readers difference the two snapshots nearest those edges and
-divide by THEIR interval, not the window's. Nothing where a run has no such
-phase (a program from before PR 47, a steady cell) or a snapshot lies more
-than ``EDGE_S`` from its edge.
+The window of the kill-resume cell opens at C0's commit, which emits one,
+and closes just before the agent's ``stop()``, which emits the last. A
+snapshot's ``t`` is the newest line the agent had been FED when it took it,
+not the time it was written: the opening one's is the commit's own (so it
+may precede the driver's ``t_open`` by its 20 ms poll) or a step record's
+behind it, the closing one's the last step record's — up to a step before
+``t_close``, and seconds where the resumed generation has not stepped yet. So
+the readers take the snapshots by what they ARE, not by how near an edge
+their ``t`` lies (a frozen host can push the closing one seconds from its
+edge): the OPENING one is the first at or after the ``ckpt_committed`` of the
+run's first save, the CLOSING one the run's last; they are differenced and divided by THEIR interval, not
+the window's, and how far each stands from its edge is kept among the
+artifacts (``goodput_edges``: ``edge_distances``). Nothing where a run has no
+such phase (a program from before PR 47, a steady cell), no commit of its
+first save on the timeline, or no snapshot behind the opening one.
 """
 
 from __future__ import annotations
@@ -18,22 +26,31 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 Snapshot = Dict[str, Any]
 
-EDGE_S = 2.0
-
 
 def edges(artifacts: Dict[str, Any]) -> Optional[Tuple[Snapshot, Snapshot]]:
-    """The snapshots nearest ``t_open`` and ``t_close``."""
-    snaps = [e for e in artifacts.get("timeline", ())
-             if e.get("phase") == "goodput"]
-    if not snaps or artifacts.get("t_open") is None:
+    """The snapshot that opens the window and the one that closes it."""
+    timeline = artifacts.get("timeline") or ()
+    saves = artifacts.get("save_steps") or (None,)
+    commit = next((e for e in timeline if e.get("phase") == "ckpt_committed"
+                   and e.get("step") == saves[0]), None)
+    snaps = [e for e in timeline if e.get("phase") == "goodput"]
+    if commit is None or not snaps:
         return None
-    pair = []
-    for edge in (artifacts["t_open"], artifacts["t_close"]):
-        snap = min(snaps, key=lambda e: abs(e["t"] - edge))
-        if abs(snap["t"] - edge) > EDGE_S:
-            return None
-        pair.append(snap)
-    return (pair[0], pair[1]) if pair[1]["t"] > pair[0]["t"] else None
+    opening = next((s for s in snaps if s["t"] >= commit["t"]), None)
+    closing = snaps[-1]
+    if opening is None or closing["t"] <= opening["t"]:
+        return None
+    return opening, closing
+
+
+def edge_distances(artifacts: Dict[str, Any]) -> Optional[Dict[str, float]]:
+    """Seconds from the window's edges to the two snapshots' ``t`` (negative:
+    before the edge). Kept among the artifacts; decides nothing."""
+    pair = edges(artifacts)
+    if pair is None or artifacts.get("t_open") is None:
+        return None
+    return {"opening_after_t_open_s": pair[0]["t"] - artifacts["t_open"],
+            "closing_after_t_close_s": pair[1]["t"] - artifacts["t_close"]}
 
 
 def over_window(artifacts: Dict[str, Any],

@@ -1,5 +1,5 @@
-"""From a profiler trace to device time by pass, by part of the program and
-by kernel — read from the names the program gives its own operations.
+"""From a profiler trace to device time by pass and by part of the program —
+read from the names the program gives its own operations.
 
 Every operation of a compiled jax program carries its name stack as
 ``op_name`` metadata: ``jit(train_step)/transpose(jvp(Transformer))/while/
@@ -36,20 +36,18 @@ import os
 import re
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
-from lib import flops, peaks, trace_reduce
+from lib import trace_reduce
 
 PASSES = ("fwd", "bwd", "remat", "none")
-PARTS = ("attention", "ffn", "head_loss", "optimizer", "accumulate", "other",
-         "unscoped")
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-#: kernel name -> the kind ``lib/flops.flash_causal_cost`` knows it by
-KERNEL_KIND = {"flash_fwd": "fwd", "flash_bwd_dq": "dq",
-               "flash_bwd_dkv": "dkv"}
+PARTS = ("attention", "ssm", "ffn", "moe", "head_loss", "optimizer",
+         "accumulate", "other", "unscoped")
 
 #: scope or module name on a path -> part. The listed scopes do not nest in
-#: one another, so the first found decides.
+#: one another (a block's mixer is ``attention`` or ``ssm``, its FFN ``ffn``
+#: or ``moe``; a multi-token-prediction module's layer stands with the
+#: blocks), so the first found decides.
 _PART_OF = {
-    "attention": "attention", "ffn": "ffn",
+    "attention": "attention", "ssm": "ssm", "ffn": "ffn", "moe": "moe",
     "tok_emb.attend": "head_loss", "lm_head": "head_loss",
     "loss": "head_loss", "lm_head_loss": "head_loss",
     "optimizer": "optimizer", "grad_norm": "optimizer",
@@ -87,7 +85,7 @@ def names_on(path: str) -> Tuple[List[str], List[str]]:
 
 
 def classify(path: str) -> Dict[str, Optional[str]]:
-    """``{"pass", "part", "kernel"}`` of one operation's path. Pass and part
+    """``{"pass", "part"}`` of one operation's path. Pass and part
     are two partitions: every path has exactly one of each. An operation
     outside the differentiated function (optimizer, accumulation, gradient
     norm) has pass ``none``; one whose path holds a name of the program that
@@ -104,8 +102,7 @@ def classify(path: str) -> Dict[str, Optional[str]]:
         pass_ = "none"
     part = next((_PART_OF[n] for n in names if n in _PART_OF),
                 "other" if names else "unscoped")
-    kernel = next((n for n in names if n in KERNELS), None)
-    return {"pass": pass_, "part": part, "kernel": kernel}
+    return {"pass": pass_, "part": part}
 
 
 # ----------------------------------------------------------------- shares
@@ -177,43 +174,6 @@ def whole_paths(trace: Dict[str, Any]) -> bool:
     helper calls — and shares read from such a program say nothing."""
     named = [p for p in trace.get("paths", {}).values() if p]
     return 2 * sum("/" in p for p in named) > len(named)
-
-
-def kernel_calls(paths: Dict[str, str], flash_calls: List[Dict[str, Any]]
-                 ) -> List[Dict[str, Any]]:
-    """The entries of ``flash_calls`` (``lib/hlo.py``: instruction name and
-    shape of every Mosaic call of the compiled step) whose instruction the
-    program names as one of its kernels, with that ``kernel`` added. Told by
-    name alone: the entry's own ``kind``, guessed from result types, is not
-    looked at."""
-    out = []
-    for call in flash_calls:
-        kernel = classify(paths.get(call["name"], ""))["kernel"]
-        if kernel is not None:
-            out.append(dict(call, kernel=kernel))
-    return out
-
-
-def kernel_roofline_pct(kernel: str, paths: Dict[str, str],
-                        flash_calls: List[Dict[str, Any]],
-                        ops: Dict[str, Dict[str, float]],
-                        peak_flops: float, peak_bytes: float
-                        ) -> Optional[float]:
-    """Least time the chip could take for the calls of one kernel that ran
-    (FLOPs and bytes as ``flash_roofline`` counts them for that kind) over
-    the time they took. ``ops`` is ``trace_reduce.ops_by_name``."""
-    least = took = 0.0
-    for call in kernel_calls(paths, flash_calls):
-        ran = ops.get(call["name"])
-        if call["kernel"] != kernel or not ran:
-            continue
-        cost = flops.flash_causal_cost(
-            KERNEL_KIND[kernel], call["batch_heads"], call["seq"],
-            call["head_dim"])
-        least += ran["calls"] * flops.roofline_seconds(
-            cost["flops"], cost["bytes"], peak_flops, peak_bytes)["seconds"]
-        took += ran["seconds"]
-    return 100.0 * least / took if took else None
 
 
 # ------------------------------------------------------------- the file
@@ -351,16 +311,3 @@ def part_pct(artifacts: Dict[str, Any], part: str) -> Optional[float]:
     if not found or not found["named_parts"]:
         return None
     return found["part_pct"][part]
-
-
-def kernel_roofline_of_run(artifacts: Dict[str, Any], kernel: str
-                           ) -> Optional[float]:
-    found = of_run(artifacts)
-    calls = artifacts.get("flash_calls")
-    if not found or not calls:
-        return None
-    kind = artifacts["device"]["kind"]
-    return kernel_roofline_pct(
-        kernel, found["paths"], calls, artifacts["trace_summary"]["ops"],
-        peaks.peak(kind, "bf16_flops_per_s"),
-        peaks.peak(kind, "hbm_bytes_per_s"))

@@ -72,7 +72,7 @@ def test_train_flops_per_token_by_hand_and_against_the_program(config):
     program = describe(**config["kwargs"]).train_flops_per_token(seq)
     whole_scores = 6 * 6.0 * 32 * (192 + 128) * (seq - pairs / seq)
     assert program == pytest.approx(active + whole_scores, rel=1e-5)
-    # what joyai_mfu leaves out at the seed's load
+    # what ``mfu`` leaves out in the cell at the seed's load
     assert round(want / 1e6) == 3328 and round(active / 1e6) == 3399
 
 

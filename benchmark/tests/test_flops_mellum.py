@@ -127,6 +127,6 @@ def test_band_cost_of_a_windowed_flash_call(config):
 
 
 def test_flash_forward_cost_under_grouped_queries():
-    cost = flops_mellum.flash_fwd_cost(2, 8192, 32, 4, 128)
+    cost = flops.flash_gqa_cost("fwd", 2, 8192, 32, 4, 128)
     assert cost["flops"] == 2 * 32 * 2.0 * (8192 * 8193 // 2) * 256
     assert cost["bytes"] == 2 * 8192 * (2 * 36 * 128 * 2 + 32 * 4)

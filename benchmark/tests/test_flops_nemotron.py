@@ -9,7 +9,7 @@ import os
 import pytest
 
 from conftest import BENCH
-from lib import flops_nemotron, flops_ssd
+from lib import flops, flops_nemotron, flops_ssd
 from lib.flops_laguna import seen_pairs
 
 
@@ -55,7 +55,7 @@ def test_the_counts_are_the_programs(config):
     assert cfg.train_flops_per_token(seq) == pytest.approx(
         mine + 6.0 * not_products + scores_full - scores_seen, rel=1e-6)
     assert mine < cfg.train_flops_per_token(seq)
-    # the routed rows as none: what nemotron_mfu reads with
+    # the routed rows as none: what ``mfu`` reads in the cell with
     none = flops_nemotron.train_flops_per_token(config, seq, 0.0)
     assert mine - none == pytest.approx(6.0 * 4 * 0.375 * 9_977_856)
     assert 0.04 < (mine - none) / mine < 0.045
@@ -78,7 +78,7 @@ def test_the_scan_at_eight_groups_and_chunks_of_128(config):
 
 
 def test_the_flash_forward_under_grouped_queries():
-    cost = flops_nemotron.flash_fwd_cost(2, 8192, 32, 2, 128)
+    cost = flops.flash_gqa_cost("fwd", 2, 8192, 32, 2, 128)
     pairs = 8192 * 8193 // 2
     assert cost["flops"] == 2 * 32 * 2.0 * pairs * 256
     assert cost["bytes"] == 2 * 8192 * (2 * 34 * 128 * 2 + 32 * 4)

@@ -52,13 +52,52 @@ def test_train_flops_per_token(name, expect):
 
 
 @pytest.mark.parametrize("kind,matmuls,mats,vecs", [
-    ("fwd", 2, 4, 1), ("dq", 3, 5, 2), ("dkv", 4, 6, 2)])
+    ("fwd", 2, 4, 1), ("dq", 3, 5, 2), ("dkv", 4, 6, 2), ("bwd", 5, 8, 1)])
 def test_flash_causal_cost_by_hand(kind, matmuls, mats, vecs):
     bh, s, d = 128, 1024, 64   # 8 sequences x 16 heads, the medium cell
     cost = flops.flash_causal_cost(kind, bh, s, d)
     # a full [s,d]x[d,s] product is 2 s^2 d; causal keeps s(s+1)/2 of s^2
     assert cost["flops"] == bh * matmuls * 2 * d * s * (s + 1) / 2
     assert cost["bytes"] == bh * (mats * s * d * 2 + vecs * s * 4)
+
+
+@pytest.mark.parametrize("d,dv,per_score", [(128, 128, 640),
+                                             (192, 128, 832)])
+def test_the_one_call_backward_by_a_hand_count(d, dv, per_score):
+    """The looped backward's ONE kernel (PR 39) makes five products a live
+    pair — ``S^T = K Q^T``, ``dK = dS^T Q`` and ``dQ = dS K`` at the scores'
+    depth ``d``, ``dP^T = V dO^T`` and ``dV = P^T dO`` at the values' ``dv``
+    — so a head costs ``2 x pairs x (3 d + 2 dv)`` FLOP: 640 a score at 128 /
+    128, 832 at 192 / 128; and it reads q, k, v, O, dO and writes dq, dk, dv
+    once each, beside the float32 ``lse`` rows."""
+    from lib import flops_joyai
+
+    batch, seq, heads = 2, 8192, 32
+    pairs = seq * (seq + 1) // 2
+    assert 3 * d + 2 * dv == per_score
+    want = batch * heads * 2.0 * pairs * per_score
+    mla = flops_joyai.mla_flash_cost("bwd", batch, seq, heads, d, dv)
+    assert mla["flops"] == want
+    # q, k, dq, dk at the scores' width, v, O, dO, dv at the values'
+    assert mla["bytes"] == batch * heads * seq * (
+        (4 * d + 4 * dv) * 2 + 4)
+    if d == dv:
+        # on [batch, seq, heads x d], the width taken as one head's
+        plain = flops.flash_causal_cost("bwd", batch, seq, heads * d)
+        assert plain["flops"] == want
+        assert plain["bytes"] == batch * (8 * seq * heads * d * 2 + seq * 4)
+        # under grouped-query attention: the same products, k, v, dk, dv at
+        # the key/value heads
+        gqa = flops.flash_gqa_cost("bwd", batch, seq, heads, 4, d)
+        assert gqa["flops"] == want
+        assert gqa["bytes"] == batch * seq * (
+            (4 * heads + 4 * 4) * d * 2 + heads * 4)
+        # five products where the two-kernel backward makes seven
+        two = sum(flops.flash_causal_cost(k, batch, seq, heads * d)["flops"]
+                  for k in ("dq", "dkv"))
+        assert plain["flops"] * 7 == two * 5
+    # compute sets the roofline at every shape a cell runs
+    assert mla["flops"] / 197e12 > mla["bytes"] / 819e9
 
 
 def test_roofline_names_its_bound():

@@ -63,5 +63,5 @@ def test_train_flops_per_token_by_hand_and_against_the_program(config):
     whole_scores = 12.0 * n_layers * 1024 * (seq - pairs / seq)
     assert program == pytest.approx(active + whole_scores, rel=1e-6)
     if n_layers == 6:
-        # what zaya_model_flops_util leaves out at the seed's load
+        # what ``mfu`` leaves out in the cell at the seed's load
         assert round(want / 1e6) == 930 and round(active / 1e6) == 1143

@@ -8,6 +8,7 @@ import os
 import pytest
 
 from conftest import BENCH, HERE
+from lib import goodput_events
 from test_rehearsal import last_line, run_py
 from test_worker_readers import made_up, read
 
@@ -23,17 +24,20 @@ def snap(t, step_s, wasted_s=0.0, unaccounted_s=0.0, **seconds):
             "steps_wasted": 0, "last_kept_step": 0}
 
 
-def with_snapshots(*snaps):
-    """``made_up``'s run (window 104..150, killed at 116.5, resumed from
-    step 5) with these snapshots on its timeline."""
+def with_snapshots(*snaps, commit_t=103.99):
+    """``made_up``'s run (window 104..150, C0 — step 5 — committed at
+    ``commit_t``, killed at 116.5, resumed from step 5) with these
+    snapshots on its timeline."""
     run = made_up()
-    run["timeline"] = run["timeline"] + list(snaps)
+    commit = [] if commit_t is None else [
+        {"t": commit_t, "phase": "ckpt_committed", "gen": 1, "step": 5}]
+    run["timeline"] = run["timeline"] + commit + list(snaps)
     return run
 
 
 def test_both_snapshots_are_differenced_over_their_own_interval():
     # C0's commit at 103.99, just before the driver saw it; the agent's
-    # stop at 150.2; in between a restore's snapshot that is nearer neither
+    # stop at 150.2; in between a restore's snapshot that is neither
     run = with_snapshots(
         snap(103.99, step_s=4.0, unaccounted_s=0.5),
         snap(136.0, step_s=13.6, wasted_s=6.4, unaccounted_s=0.1),
@@ -44,6 +48,9 @@ def test_both_snapshots_are_differenced_over_their_own_interval():
         100 * (19.2 - 6.4) / interval)
     assert read("goodput_unaccounted_pct", run) == pytest.approx(
         100 * 0.231 / interval)
+    assert goodput_events.edge_distances(run) == {
+        "opening_after_t_open_s": pytest.approx(-0.01),
+        "closing_after_t_close_s": pytest.approx(0.2)}
 
 
 def test_a_job_that_is_never_killed_wastes_nothing():
@@ -52,16 +59,39 @@ def test_a_job_that_is_never_killed_wastes_nothing():
     assert read("goodput_pct", run) == pytest.approx(100 * 40.0 / 46.0)
 
 
+def test_a_closing_snapshot_seconds_before_the_close_still_reads():
+    """What silenced the three readers on one side of PR 49's check: the
+    closing snapshot's ``t`` is the newest line the agent was fed, and where
+    the resumed generation has not stepped yet (a slow resume) that line
+    lies seconds before ``t_close``. The snapshots before C0's commit (the
+    first generation's restore and first step) are never the opening one.
+    The share is over the two snapshots' own interval, and the distance is
+    kept, not judged."""
+    run = with_snapshots(
+        snap(95.0, step_s=0.0), snap(99.0, step_s=1.0),
+        snap(104.3, step_s=4.0),        # the commit's, fed a record later
+        snap(136.0, step_s=13.6, wasted_s=6.4),
+        snap(146.8, step_s=13.6, wasted_s=6.4))  # 3.2 s before the close
+    interval = 146.8 - 104.3
+    assert read("goodput_pct", run) == pytest.approx(
+        100 * (9.6 - 6.4) / interval)
+    assert read("wasted_progress_s", run) == pytest.approx(6.4)
+    assert goodput_events.edge_distances(run)["closing_after_t_close_s"] \
+        == pytest.approx(-3.2)
+
+
 @pytest.mark.parametrize("name", THREE)
-@pytest.mark.parametrize("snaps", [
-    (),                                                   # before PR 47
-    (snap(104.0, step_s=8.0),),                           # one missing
-    (snap(150.0, step_s=48.0),),
-    (snap(107.0, step_s=8.0), snap(150.0, step_s=48.0)),  # too far inside
-    (snap(104.0, step_s=8.0), snap(147.5, step_s=48.0)),
-], ids=["none", "no-closing", "no-opening", "opening-3s-in", "closing-2.5s-in"])
-def test_no_number_without_a_snapshot_at_each_edge(name, snaps):
-    assert read(name, with_snapshots(*snaps)) is None
+@pytest.mark.parametrize("snaps,commit_t", [
+    ((), 103.99),                                         # before PR 47
+    ((snap(104.0, step_s=8.0),), 103.99),                 # one alone
+    ((snap(99.0, step_s=8.0), snap(103.0, step_s=9.0)), 103.99),
+    ((snap(104.0, step_s=8.0), snap(150.0, step_s=48.0)), None),
+], ids=["none", "one-alone", "none-behind-the-commit", "no-commit"])
+def test_no_number_without_the_commit_and_two_snapshots(name, snaps,
+                                                        commit_t):
+    run = with_snapshots(*snaps, commit_t=commit_t)
+    assert read(name, run) is None
+    assert goodput_events.edge_distances(run) is None
 
 
 @pytest.mark.parametrize("name", THREE)
@@ -90,6 +120,9 @@ def test_rehearsal_prints_all_three():
     with open(os.path.join(BENCH, ".work", "gpt2-test.goodput-kill-resume",
                            "artifacts.json")) as f:
         run = json.load(f)
+    # the two snapshots' distance from the window's edges is kept
+    assert abs(run["goodput_edges"]["opening_after_t_open_s"]) < 1.0
+    assert -5.0 < run["goodput_edges"]["closing_after_t_close_s"] < 1.0
     snaps = [e for e in run["timeline"] if e["phase"] == "goodput"]
     for s in snaps:  # every snapshot tiles
         assert sum(s["seconds"].values()) == pytest.approx(

@@ -4,20 +4,19 @@ configuration (two Mamba-2 layers and one attention layer, 64 wide) through
 deciding ``correct`` and the new readers listed; and ``BENCHMARK.json``'s new
 cell refusing to run without a chip."""
 
-import json
 import os
 
 import pytest
 
 from conftest import HERE
+from listed import check_rehearsal_file, device_derived
 from test_rehearsal import last_line, run_py
 
 TEST_JSON = os.path.join(HERE, "BENCHMARK.granite-test.json")
 CELL = "granite-test.steady-4k"
+REAL_CELL = "granite-4.0-h-micro.steady-4k"
 #: what only a device trace or a chip's peak can give
-DEVICE_DERIVED = {"ssm_time_pct", "ssd_time_pct", "conv1d_time_pct",
-                  "ssd_roofline", "hybrid_model_flops_util",
-                  "device_idle_pct"}
+DEVICE_DERIVED = device_derived(REAL_CELL)
 
 
 @pytest.mark.parametrize("trace,expect", [
@@ -40,9 +39,11 @@ def test_hybrid_rehearsal(trace, expect):
 
 
 def test_the_rehearsal_file_lists_the_new_readers():
-    with open(TEST_JSON) as f:
-        bench = json.load(f)
-    assert DEVICE_DERIVED <= {m["name"] for m in bench["per_layer"]}
+    assert {"ssm_time_pct", "ssd_time_pct", "conv1d_time_pct", "ssd_roofline",
+            "mfu", "flash_fwd_roofline", "flash_bwd_roofline",
+            "device_idle_pct"} <= DEVICE_DERIVED
+    cell = check_rehearsal_file(TEST_JSON, CELL, REAL_CELL)
+    assert cell["chips"] == 1 and cell["traffic"] == "steady-4k"
 
 
 def test_no_chip_no_metric_for_the_new_cell():

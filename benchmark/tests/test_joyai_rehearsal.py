@@ -11,18 +11,15 @@ import os
 import pytest
 
 from conftest import BENCH, HERE, ROOT
+from listed import (check_nothing_to_read, check_rehearsal_file,
+                    device_derived, reader as _reader)
 from test_rehearsal import last_line, run_py
 
 TEST_JSON = os.path.join(HERE, "BENCHMARK.joyai-test.json")
 CELL = "joyai-test.mla-mtp-8k-b2"
 REAL_CELL = "joyai-llm-flash.mla-mtp-8k-b2"
 #: what only a device trace or a chip's peak can give
-DEVICE_DERIVED = {
-    "joyai_mfu", "mla_attn_time_pct", "mla_proj_time_pct",
-    "mla_key_rope_time_pct", "mla_flash_time_pct", "mla_flash_fwd_roofline",
-    "mla_flash_dq_roofline", "mla_flash_dkv_roofline", "mtp_time_pct",
-    "joyai_head_time_pct", "joyai_moe_time_pct", "joyai_experts_time_pct",
-    "device_idle_pct"}
+DEVICE_DERIVED = device_derived(REAL_CELL)
 
 
 @pytest.mark.parametrize("trace,expect", [
@@ -52,20 +49,13 @@ def test_joyai_rehearsal(trace, expect):
 
 
 def test_the_rehearsal_file_lists_the_new_readers():
-    with open(TEST_JSON) as f:
-        rehearsal = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert DEVICE_DERIVED <= {m["name"] for m in rehearsal["per_layer"]}
-    mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [REAL_CELL]}
-    assert mine == DEVICE_DERIVED - {"device_idle_pct"}
-    cell, = (w for w in bench["workloads"] if w["name"] == REAL_CELL)
+    assert {"mfu", "attn_time_pct", "mla_proj_time_pct",
+            "mla_key_rope_time_pct", "flash_time_pct", "flash_fwd_roofline",
+            "flash_bwd_roofline", "mtp_time_pct", "head_loss_time_pct",
+            "moe_time_pct", "experts_time_pct",
+            "device_idle_pct"} <= DEVICE_DERIVED
+    cell = check_rehearsal_file(TEST_JSON, CELL, REAL_CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "mla-mtp-8k-b2"
-    # (no word on where the cell stands in the list or how many there are:
-    # the next PR appends its own)
-    # the step's share of the peak carries the word the driver looks for
-    assert sum("mfu" in name for name in mine) == 1
     with open(os.path.join(BENCH, "traffic", "mla-mtp-8k-b2.json")) as f:
         mix = json.load(f)
     assert (mix["global_batch"], mix["grad_accum"], mix["warmup_steps"],
@@ -78,30 +68,17 @@ def test_the_rehearsal_file_lists_the_new_readers():
 def test_the_new_readers_find_nothing_in_a_program_without_the_names():
     """On the parent's side of a traced run the new readers return nothing
     and do not raise: artifacts of another model, no trace, no counters."""
-    import importlib.util
-
-    for config in ({"layer_types": ["full_attention"]},
-                   {"model_type": "zaya", "layer_types": ["hybrid"]},
-                   {"model_type": "joyai_llm_flash",
-                    "layer_types": ["dense"], "kwargs": {"seq_len": 64}}):
-        artifacts = {"config": config,
-                     "traffic": {"global_batch": 2, "trace_steps": 4},
-                     "device": {"platform": "cpu", "kind": "cpu"},
-                     "check": {"ok": True}}
-        for name in sorted(DEVICE_DERIVED):
-            path = os.path.join(ROOT, "benchmark", "layer_metrics",
-                                f"{name}.py")
-            spec = importlib.util.spec_from_file_location(f"reader_{name}",
-                                                          path)
-            module = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(module)
-            assert module.read(artifacts) is None, name
+    check_nothing_to_read(REAL_CELL, (
+        {"layer_types": ["full_attention"]},
+        {"readers": {"module": "cell_zaya"}, "layer_types": ["hybrid"]},
+        {"readers": {"module": "cell_joyai"}, "layer_types": ["dense"],
+         "kwargs": {"seq_len": 64}}))
 
 
 def test_the_roofline_reader_on_a_synthetic_trace(monkeypatch):
     """One ``mla_fwd`` call named by the program, 10 ms a call on a v5e:
     the reader's share is the hand count's least time over it."""
-    from lib import flops_joyai, joyai_names, scope_reduce
+    from lib import flops_joyai, scope_reduce
 
     with open(os.path.join(BENCH, "configs", "joyai-llm-flash.json")) as f:
         config = json.load(f)
@@ -113,19 +90,19 @@ def test_the_roofline_reader_on_a_synthetic_trace(monkeypatch):
     artifacts = {
         "config": config, "device": {"platform": "tpu", "kind": "TPU v5 lite"},
         "flash_calls": [
-            {"name": "mla_fwd.1", "kind": "fwd", "batch_heads": 2,
-             "seq": 8192, "head_dim": 4096},
-            {"name": "rope.1", "kind": "dq", "batch_heads": 2, "seq": 8192,
-             "head_dim": 6144}],
+            {"name": "mla_fwd.1", "kernel": "mla_fwd", "kind": "fwd",
+             "batch_heads": 2, "seq": 8192, "head_dim": 4096}],
         "trace_summary": {"ops": {"mla_fwd.1": {"calls": 4, "seconds": 0.04},
                                   "rope.1": {"calls": 4, "seconds": 0.01}}}}
     cost = flops_joyai.mla_flash_cost("fwd", 2, 8192, 32, 192, 128)
     least = max(cost["flops"] / 197e12, cost["bytes"] / 819e9)
     assert cost["flops"] / 197e12 > cost["bytes"] / 819e9  # compute-bound
-    assert joyai_names.flash_roofline(artifacts, "mla_fwd") \
+    assert _reader("flash_fwd_roofline").read(artifacts) \
         == pytest.approx(100.0 * 4 * least / 0.04)
     assert 60 < 100.0 * least / 0.01 < 80      # 7 ms at the peak
-    assert joyai_names.flash_roofline(artifacts, "mla_bwd_dq") is None
+    # no ``mla_bwd`` call in this program, no unrolled kernel in this cell
+    assert _reader("flash_bwd_roofline").read(artifacts) is None
+    assert _reader("flash_dq_roofline").read(artifacts) is None
 
 
 def test_no_chip_no_metric_for_the_new_cell():

@@ -4,26 +4,20 @@ held) through ``run.py`` with its own ``BENCHMARK.laguna-test.json``,
 ``check_laguna`` deciding ``correct`` and the new readers listed; and
 ``BENCHMARK.json``'s new cell refusing to run without a chip."""
 
-import json
 import os
 
 import pytest
 
-from conftest import HERE, ROOT
+from conftest import HERE
+from listed import (check_nothing_to_read, check_rehearsal_file,
+                    device_derived)
 from test_rehearsal import last_line, run_py
 
 TEST_JSON = os.path.join(HERE, "BENCHMARK.laguna-test.json")
 CELL = "laguna-test.steady-8k-b2"
 REAL_CELL = "laguna-xs.2.steady-8k-b2"
 #: what only a device trace or a chip's peak can give
-DEVICE_DERIVED = {
-    "moe_model_flops_util", "moe_time_pct", "moe_experts_time_pct",
-    "moe_route_time_pct", "swa_attn_time_pct",
-    "full_attn_time_pct", "swa_flash_time_pct", "swa_flash_fwd_roofline",
-    "swa_flash_dq_roofline", "swa_flash_dkv_roofline",
-    "gqa128_flash_fwd_roofline", "gqa128_flash_dq_roofline",
-    "gqa128_flash_dkv_roofline", "swa_full_rope_time_pct",
-    "device_idle_pct"}
+DEVICE_DERIVED = device_derived(REAL_CELL)
 
 
 @pytest.mark.parametrize("trace,expect", [
@@ -52,33 +46,24 @@ def test_laguna_rehearsal(trace, expect):
 
 
 def test_the_rehearsal_file_lists_the_new_readers():
-    with open(TEST_JSON) as f:
-        rehearsal = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert DEVICE_DERIVED <= {m["name"] for m in rehearsal["per_layer"]}
-    mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [REAL_CELL]}
-    assert mine == DEVICE_DERIVED - {"device_idle_pct"}
-    cell, = (w for w in bench["workloads"] if w["name"] == REAL_CELL)
+    assert {"mfu", "moe_time_pct", "experts_time_pct", "route_time_pct",
+            "band_attn_time_pct", "full_attn_time_pct", "band_flash_time_pct",
+            "band_flash_fwd_roofline", "band_flash_dq_roofline",
+            "band_flash_dkv_roofline", "flash_fwd_roofline",
+            "flash_bwd_roofline", "flash_time_pct", "rope_time_pct",
+            "device_idle_pct"} <= DEVICE_DERIVED
+    cell = check_rehearsal_file(TEST_JSON, CELL, REAL_CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "steady-8k-b2"
 
 
 def test_the_new_readers_find_nothing_in_a_program_without_the_names():
     """On the parent's side of a traced run the new readers return nothing
     and do not raise: artifacts of another model, no trace, no counters."""
-    import importlib.util
-
-    artifacts = {"config": {"layer_types": ["full_attention"]},
-                 "traffic": {"global_batch": 2, "trace_steps": 4},
-                 "device": {"platform": "cpu", "kind": "cpu"},
-                 "check": {"ok": True}}
-    for name in sorted(DEVICE_DERIVED):
-        path = os.path.join(ROOT, "benchmark", "layer_metrics", f"{name}.py")
-        spec = importlib.util.spec_from_file_location(f"reader_{name}", path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        assert module.read(artifacts) is None, name
+    check_nothing_to_read(REAL_CELL, (
+        {"layer_types": ["full_attention"]},
+        {"readers": {"module": "cell_laguna"}, "sliding_window": 512,
+         "layer_types": ["sliding_attention"], "mlp_layer_types": ["sparse"],
+         "kwargs": {"seq_len": 64}}))
 
 
 def test_no_chip_no_metric_for_the_new_cell():

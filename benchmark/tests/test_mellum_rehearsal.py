@@ -13,34 +13,15 @@ import os
 import pytest
 
 from conftest import BENCH, HERE, ROOT
+from listed import (HOST_READERS, check_nothing_to_read,
+                    check_rehearsal_file, device_derived, reader as _reader)
 from test_rehearsal import last_line, run_py
 
 TEST_JSON = os.path.join(HERE, "BENCHMARK.mellum-test.json")
 CELL = "mellum-test.top8-swa1k-8k-b2"
 REAL_CELL = "mellum2-12b-a2.5b.top8-swa1k-8k-b2"
 #: what only a device trace or a chip's peak can give
-DEVICE_DERIVED = {
-    "mellum_mfu", "mellum_moe_time_pct", "top8_experts_time_pct",
-    "softmax_router_time_pct", "swa1k_attn_time_pct",
-    "gqa4_full_attn_time_pct", "swa1k_flash_time_pct",
-    "swa1k_flash_fwd_roofline", "swa1k_flash_dq_roofline",
-    "swa1k_flash_dkv_roofline", "gqa4_flash_fwd_roofline"}
-#: the accepted readers of any steady cell's idle share, passes, optimizer
-#: and unnamed time, host-clock step and set-up: the real cell was appended
-#: to their ``workloads`` (the entries are otherwise the parent's)
-ACCEPTED_DEVICE = {"device_idle_pct", "fwd_time_pct", "bwd_time_pct",
-                   "remat_time_pct", "optimizer_time_pct",
-                   "unscoped_time_pct"}
-ACCEPTED_HOST = {"compile_s", "compiles_in_window", "step_ms_p50",
-                 "step_spread_pct", "step_hbm_gib"}
-
-
-def _reader(name):
-    path = os.path.join(ROOT, "benchmark", "layer_metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"reader_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+DEVICE_DERIVED = device_derived(REAL_CELL)
 
 
 def _json(*parts):
@@ -50,7 +31,7 @@ def _json(*parts):
 
 @pytest.mark.parametrize("trace,expect", [
     (0, {"tokens_per_s", "setup_s"}),
-    (1, ACCEPTED_HOST),
+    (1, HOST_READERS),
 ])
 def test_mellum_rehearsal(trace, expect):
     proc = run_py(["--benchmark-json", TEST_JSON, "--workload", CELL,
@@ -60,7 +41,7 @@ def test_mellum_rehearsal(trace, expect):
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert set(line["metrics"]) == expect
-    assert not set(line["metrics"]) & (DEVICE_DERIVED | ACCEPTED_DEVICE)
+    assert not set(line["metrics"]) & DEVICE_DERIVED
     assert "reference check {'ok': True" in proc.stdout
     assert "'state_rel_rms_layer_4'" in proc.stdout
     assert "'window_position_rel_max'" in proc.stdout
@@ -79,28 +60,15 @@ def test_mellum_rehearsal(trace, expect):
 
 
 def test_the_rehearsal_file_lists_the_new_readers():
-    rehearsal = _json(TEST_JSON)
-    bench = _json(ROOT, "BENCHMARK.json")
-    assert DEVICE_DERIVED | ACCEPTED_DEVICE | ACCEPTED_HOST \
-        == {m["name"] for m in rehearsal["per_layer"]}
-    mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [REAL_CELL]}
-    assert mine == DEVICE_DERIVED
-    # (no word on where the cell stands in a list: the next PR appends its
-    # own)
-    assert {m["name"] for m in bench["per_layer"]
-            if REAL_CELL in m.get("workloads", ()) and m["name"] not in mine} \
-        == ACCEPTED_DEVICE | ACCEPTED_HOST
-    assert all(m["moves"] == "tokens_per_s" for m in bench["per_layer"]
-               if m["name"] in mine)
-    assert len(bench["per_layer"]) <= 128
-    assert all("workloads" in m for m in bench["per_layer"])
-    cell, = (w for w in bench["workloads"] if w["name"] == REAL_CELL)
+    assert {"mfu", "moe_time_pct", "experts_time_pct", "router_time_pct",
+            "band_attn_time_pct", "full_attn_time_pct", "band_flash_time_pct",
+            "band_flash_fwd_roofline", "band_flash_dq_roofline",
+            "band_flash_dkv_roofline", "flash_fwd_roofline",
+            "flash_bwd_roofline", "flash_time_pct", "device_idle_pct",
+            "fwd_time_pct", "bwd_time_pct", "remat_time_pct",
+            "optimizer_time_pct", "unscoped_time_pct"} <= DEVICE_DERIVED
+    cell = check_rehearsal_file(TEST_JSON, CELL, REAL_CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "top8-swa1k-8k-b2"
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    # the step's share of the peak carries the word the driver looks for
-    assert sum("mfu" in name for name in mine) == 1
-    assert sum(name.endswith("_roofline") for name in mine) == 4
     mix = _json(BENCH, "traffic", "top8-swa1k-8k-b2.json")
     assert (mix["global_batch"], mix["grad_accum"], mix["warmup_steps"],
             mix["trace_steps"]) == (2, 1, 2, 4)
@@ -112,20 +80,13 @@ def test_the_rehearsal_file_lists_the_new_readers():
 def test_the_new_readers_find_nothing_in_a_program_without_the_names():
     """On the parent's side of a traced run the new readers return nothing
     and do not raise: artifacts of another model, no trace, no counters."""
-    for config in ({"layer_types": ["full_attention"]},
-                   {"model_type": "laguna", "sliding_window": 512,
-                    "layer_types": ["sliding_attention"],
-                    "mlp_layer_types": ["sparse"]},
-                   {"model_type": "mellum",
-                    "layer_types": ["sliding_attention"],
-                    "mlp_layer_types": ["sparse"],
-                    "kwargs": {"seq_len": 64}}):
-        artifacts = {"config": config,
-                     "traffic": {"global_batch": 2, "trace_steps": 4},
-                     "device": {"platform": "cpu", "kind": "cpu"},
-                     "check": {"ok": True}}
-        for name in sorted(DEVICE_DERIVED | ACCEPTED_DEVICE):
-            assert _reader(name).read(artifacts) is None, name
+    check_nothing_to_read(REAL_CELL, (
+        {"layer_types": ["full_attention"]},
+        {"readers": {"module": "cell_laguna"}, "sliding_window": 512,
+         "layer_types": ["sliding_attention"], "mlp_layer_types": ["sparse"]},
+        {"readers": {"module": "cell_mellum"},
+         "layer_types": ["sliding_attention"], "mlp_layer_types": ["sparse"],
+         "kwargs": {"seq_len": 64}}))
 
 
 def _traced(monkeypatch, paths, seconds):
@@ -148,7 +109,7 @@ def test_every_new_reader_returns_a_number_on_a_synthetic_trace(monkeypatch):
     each: every time share reads its operations' part of the busy second,
     and the four rooflines the hand count's least time over the time
     taken."""
-    from lib import flops_mellum
+    from lib import flops, flops_mellum
 
     step = "jit(train_step)/jvp(Transformer)/"
     band = "blocks_0/attention/multihead_attention/"
@@ -172,36 +133,43 @@ def test_every_new_reader_returns_a_number_on_a_synthetic_trace(monkeypatch):
     artifacts.update(
         flash_calls=[dict(call, name=f"op.{i}", kind=kind) for i, kind in
                      enumerate(("fwd", "dq", "dkv"))]
-        + [dict(call, name="op.4", kind="fwd")],
-        trace_summary={"ops": {f"op.{i}": {"calls": 4, "seconds": 0.1}
-                               for i in (0, 1, 2, 4)}},
+        + [dict(call, name="op.4", kind="fwd"),
+           dict(call, name="op.5", kind="bwd")],
+        trace_summary={"busy_s": 0.1 * len(names), "ops": {
+            f"op.{i}": {"calls": 4, "seconds": 0.1}
+            for i in (0, 1, 2, 4, 5)}},
         step_s=[0.3], steps=160, tokens_per_step=16384, window_s=50.0,
         chips=1)
     total = 0.1 * len(names)
-    want = {"mellum_moe_time_pct": 4, "top8_experts_time_pct": 1,
-            "softmax_router_time_pct": 1, "swa1k_attn_time_pct": 4,
-            "gqa4_full_attn_time_pct": 2, "swa1k_flash_time_pct": 3}
+    want = {"moe_time_pct": 4, "experts_time_pct": 1, "router_time_pct": 1,
+            "route_time_pct": 3, "band_attn_time_pct": 4,
+            "full_attn_time_pct": 2, "band_flash_time_pct": 3,
+            "flash_time_pct": 5}
     for name, ops in want.items():
         assert _reader(name).read(artifacts) == pytest.approx(
             100.0 * 0.1 * ops / total), name
     for kind in ("fwd", "dq", "dkv"):
         cost = flops_mellum.flash_band_cost(kind, 2, 8192, 4096, 128, 1024)
         assert cost["flops"] / 197e12 > cost["bytes"] / 819e9
-        assert _reader(f"swa1k_flash_{kind}_roofline").read(artifacts) \
+        assert _reader(f"band_flash_{kind}_roofline").read(artifacts) \
             == pytest.approx(100.0 * 4 * cost["flops"] / 197e12 / 0.1), kind
-    cost = flops_mellum.flash_fwd_cost(2, 8192, 32, 4, 128)
-    assert _reader("gqa4_flash_fwd_roofline").read(artifacts) \
+    cost = flops.flash_gqa_cost("fwd", 2, 8192, 32, 4, 128)
+    assert _reader("flash_fwd_roofline").read(artifacts) \
         == pytest.approx(100.0 * 4 * cost["flops"] / 197e12 / 0.1)
+    back = flops.flash_gqa_cost("bwd", 2, 8192, 32, 4, 128)
+    assert back["flops"] == 2.5 * cost["flops"]  # five products for two
+    assert _reader("flash_bwd_roofline").read(artifacts) \
+        == pytest.approx(100.0 * 4 * back["flops"] / 197e12 / 0.1)
     per_token = flops_mellum.train_flops_per_token(artifacts["config"],
                                                    8192, 0.0)
-    assert _reader("mellum_mfu").read(artifacts) == pytest.approx(
+    assert _reader("mfu").read(artifacts) == pytest.approx(
         100.0 * (160 * 16384 / 50.0) * per_token / 197e12)
-    assert 0 < _reader("mellum_mfu").read(artifacts) < 100
+    assert 0 < _reader("mfu").read(artifacts) < 100
     # a program without the band kernels (the looped path): nothing to read
     for op in ("op.0", "op.1", "op.2"):
         del paths[op]
-    assert _reader("swa1k_flash_time_pct").read(artifacts) is None
-    assert _reader("swa1k_flash_fwd_roofline").read(artifacts) is None
+    assert _reader("band_flash_time_pct").read(artifacts) is None
+    assert _reader("band_flash_fwd_roofline").read(artifacts) is None
 
 
 def test_no_chip_no_metric_for_the_new_cell():

@@ -12,40 +12,20 @@ import os
 import pytest
 
 from conftest import BENCH, HERE, ROOT
+from listed import (HOST_READERS, check_nothing_to_read,
+                    check_rehearsal_file, device_derived, reader as _reader)
 from test_rehearsal import last_line, run_py
 
 TEST_JSON = os.path.join(HERE, "BENCHMARK.nemotron-test.json")
 CELL = "nemotron-test.ssm-moe-8k-b2"
 REAL_CELL = "nemotron-3-nano-30b-a3b.ssm-moe-8k-b2"
 #: what only a device trace or a chip's peak can give
-DEVICE_DERIVED = {
-    "nemotron_mfu", "nemotron_ssm_time_pct", "nemotron_moe_time_pct",
-    "gqa2_attn_time_pct", "nemotron_head_time_pct", "g8_ssd_time_pct",
-    "g8_ssd_roofline", "g8_conv1d_time_pct", "gated_norm_time_pct",
-    "relu2_experts_time_pct", "nemotron_shared_expert_time_pct",
-    "nemotron_router_time_pct", "gqa2_flash_fwd_roofline"}
-#: the accepted readers of any steady cell's idle share, passes, optimizer
-#: and unnamed time, host-clock step and set-up: the real cell stands at the
-#: end of their ``workloads`` (appended: the entries are otherwise the
-#: parent's)
-ACCEPTED_DEVICE = {"device_idle_pct", "fwd_time_pct", "bwd_time_pct",
-                   "remat_time_pct", "optimizer_time_pct",
-                   "unscoped_time_pct"}
-ACCEPTED_HOST = {"compile_s", "compiles_in_window", "step_ms_p50",
-                 "step_spread_pct", "step_hbm_gib"}
-
-
-def _reader(name):
-    path = os.path.join(ROOT, "benchmark", "layer_metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"reader_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+DEVICE_DERIVED = device_derived(REAL_CELL)
 
 
 @pytest.mark.parametrize("trace,expect", [
     (0, {"tokens_per_s", "setup_s"}),
-    (1, ACCEPTED_HOST),
+    (1, HOST_READERS),
 ])
 def test_nemotron_rehearsal(trace, expect):
     proc = run_py(["--benchmark-json", TEST_JSON, "--workload", CELL,
@@ -55,7 +35,7 @@ def test_nemotron_rehearsal(trace, expect):
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert set(line["metrics"]) == expect
-    assert not set(line["metrics"]) & (DEVICE_DERIVED | ACCEPTED_DEVICE)
+    assert not set(line["metrics"]) & DEVICE_DERIVED
     assert "reference check {'ok': True" in proc.stdout
     assert "'state_rel_rms_block_3'" in proc.stdout
     assert "'gated_norm_token_rel_max'" in proc.stdout
@@ -65,35 +45,22 @@ def test_nemotron_rehearsal(trace, expect):
     assert "'moe_dropped': 0.0" in proc.stdout
     assert "'chosen_not_top6_share': 0.0" in proc.stdout
     # the logged-once lines say the groups and chunks, the form and the tiles
-    assert "4 heads in 2 B/C groups" in proc.stderr
+    assert "4 heads of 16 in 2 B/C groups" in proc.stderr
     assert "moe: relu2 experts (2 matrices each), 8 of 16 held" in proc.stderr
     if trace:
         assert line["metrics"]["compiles_in_window"]["value"] == 0
 
 
 def test_the_rehearsal_file_lists_the_new_readers():
-    with open(TEST_JSON) as f:
-        rehearsal = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert DEVICE_DERIVED | ACCEPTED_DEVICE | ACCEPTED_HOST \
-        == {m["name"] for m in rehearsal["per_layer"]}
-    mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [REAL_CELL]}
-    assert mine == DEVICE_DERIVED
-    assert {m["name"] for m in bench["per_layer"]
-            if REAL_CELL in m.get("workloads", ()) and m["name"] not in mine
-            and m["workloads"][-1] == REAL_CELL} \
-        == ACCEPTED_DEVICE | ACCEPTED_HOST
-    assert all(m["moves"] == "tokens_per_s" for m in bench["per_layer"]
-               if m["name"] in mine)
-    cell, = (w for w in bench["workloads"] if w["name"] == REAL_CELL)
+    assert {"mfu", "ssm_time_pct", "moe_time_pct", "attn_time_pct",
+            "head_loss_time_pct", "ssd_time_pct", "ssd_roofline",
+            "conv1d_time_pct", "gated_norm_time_pct", "experts_time_pct",
+            "shared_expert_time_pct", "route_time_pct", "flash_fwd_roofline",
+            "flash_bwd_roofline", "flash_time_pct", "device_idle_pct",
+            "fwd_time_pct", "bwd_time_pct", "remat_time_pct",
+            "optimizer_time_pct", "unscoped_time_pct"} <= DEVICE_DERIVED
+    cell = check_rehearsal_file(TEST_JSON, CELL, REAL_CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "ssm-moe-8k-b2"
-    # (no word on where the cell stands in the list or how many there are:
-    # the next PR appends its own)
-    # the step's share of the peak carries the word the driver looks for
-    assert sum("mfu" in name for name in mine) == 1
-    assert sum(name.endswith("_roofline") for name in mine) == 2
     with open(os.path.join(BENCH, "traffic", "ssm-moe-8k-b2.json")) as f:
         mix = json.load(f)
     assert (mix["global_batch"], mix["grad_accum"], mix["warmup_steps"],
@@ -106,17 +73,11 @@ def test_the_rehearsal_file_lists_the_new_readers():
 def test_the_new_readers_find_nothing_in_a_program_without_the_names():
     """On the parent's side of a traced run the new readers return nothing
     and do not raise: artifacts of another model, no trace, no counters."""
-    for config in ({"layer_types": ["full_attention"]},
-                   {"model_type": "joyai_llm_flash", "layer_types": ["dense"]},
-                   {"model_type": "nemotron_h",
-                    "hybrid_override_pattern": "MEM*EME",
-                    "kwargs": {"seq_len": 64}}):
-        artifacts = {"config": config,
-                     "traffic": {"global_batch": 2, "trace_steps": 4},
-                     "device": {"platform": "cpu", "kind": "cpu"},
-                     "check": {"ok": True}}
-        for name in sorted(DEVICE_DERIVED | ACCEPTED_DEVICE):
-            assert _reader(name).read(artifacts) is None, name
+    check_nothing_to_read(REAL_CELL, (
+        {"layer_types": ["full_attention"]},
+        {"readers": {"module": "cell_joyai"}, "layer_types": ["dense"]},
+        {"readers": {"module": "cell_nemotron_h"},
+         "hybrid_override_pattern": "MEM*EME", "kwargs": {"seq_len": 64}}))
 
 
 def _traced(monkeypatch, paths, seconds):
@@ -142,7 +103,7 @@ def test_every_new_reader_returns_a_number_on_a_synthetic_trace(monkeypatch):
     """One operation under each of the program's names, a tenth of a second
     each: every time share reads its operations' part of the busy second,
     and both rooflines the hand count's least time over the time taken."""
-    from lib import flops_nemotron
+    from lib import flops, flops_nemotron
 
     step = "jit(train_step)/jvp(Transformer)/"
     names = ["blocks_0/ssm/in_x/dot_general", "blocks_0/ssm/conv1d/mul",
@@ -157,36 +118,36 @@ def test_every_new_reader_returns_a_number_on_a_synthetic_trace(monkeypatch):
     paths = {f"op.{i}": step + name for i, name in enumerate(names)}
     seconds = {op: 0.1 for op in paths}
     artifacts = _traced(monkeypatch, paths, seconds)
+    total = 0.1 * len(names)
     artifacts.update(
-        flash_calls=[{"name": "op.9", "kind": "fwd", "batch_heads": 2,
-                      "seq": 8192, "head_dim": 4096}],
-        trace_summary={"ops": {"op.9": {"calls": 4, "seconds": 0.1}}},
+        flash_calls=[{"name": "op.9", "kernel": "flash_fwd", "kind": "fwd",
+                      "batch_heads": 2, "seq": 8192, "head_dim": 4096}],
+        trace_summary={"busy_s": total, "ops": {
+            "op.9": {"calls": 4, "seconds": 0.1}}},
         step_s=[0.5], steps=100, tokens_per_step=16384, window_s=50.0,
         chips=1)
-    total = 0.1 * len(names)
-    want = {"nemotron_ssm_time_pct": 4, "nemotron_moe_time_pct": 5,
-            "gqa2_attn_time_pct": 1, "nemotron_head_time_pct": 1,
-            "g8_ssd_time_pct": 1, "g8_conv1d_time_pct": 1,
-            "gated_norm_time_pct": 1, "relu2_experts_time_pct": 1,
-            "nemotron_shared_expert_time_pct": 1,
-            "nemotron_router_time_pct": 3}
+    want = {"ssm_time_pct": 4, "moe_time_pct": 5, "attn_time_pct": 1,
+            "head_loss_time_pct": 1, "ssd_time_pct": 1, "conv1d_time_pct": 1,
+            "gated_norm_time_pct": 1, "experts_time_pct": 1,
+            "shared_expert_time_pct": 1, "route_time_pct": 3,
+            "router_time_pct": 1}
     for name, ops in want.items():
         assert _reader(name).read(artifacts) == pytest.approx(
             100.0 * 0.1 * ops / total), name
-    cost = flops_nemotron.flash_fwd_cost(2, 8192, 32, 2, 128)
-    assert _reader("gqa2_flash_fwd_roofline").read(artifacts) \
+    cost = flops.flash_gqa_cost("fwd", 2, 8192, 32, 2, 128)
+    assert _reader("flash_fwd_roofline").read(artifacts) \
         == pytest.approx(100.0 * 4 * cost["flops"] / 197e12 / 0.1)
     scan = flops_nemotron.ssd_train_cost_per_token(artifacts["config"])
     tokens = 4 * 2 * 8192 * 4  # steps, sequences, positions, M sub-layers
     least = max(tokens * scan["flops"] / 197e12,
                 tokens * scan["bytes"] / 819e9)
-    assert _reader("g8_ssd_roofline").read(artifacts) \
+    assert _reader("ssd_roofline").read(artifacts) \
         == pytest.approx(100.0 * least / 0.1)
     per_token = flops_nemotron.train_flops_per_token(artifacts["config"],
                                                      8192, 0.0)
-    assert _reader("nemotron_mfu").read(artifacts) == pytest.approx(
+    assert _reader("mfu").read(artifacts) == pytest.approx(
         100.0 * (100 * 16384 / 50.0) * per_token / 197e12)
-    assert 0 < _reader("nemotron_mfu").read(artifacts) < 100
+    assert 0 < _reader("mfu").read(artifacts) < 100
     # the parent's program has no `gated_norm` scope: nothing to read there
     del paths["op.3"]
     assert _reader("gated_norm_time_pct").read(artifacts) is None

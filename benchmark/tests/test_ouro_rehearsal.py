@@ -4,22 +4,19 @@
 ``correct`` and the new readers listed; and ``BENCHMARK.json``'s new cell
 refusing to run without a chip."""
 
-import json
 import os
 
 import pytest
 
-from conftest import HERE, ROOT
+from conftest import HERE
+from listed import check_rehearsal_file, device_derived
 from test_rehearsal import last_line, run_py
 
 TEST_JSON = os.path.join(HERE, "BENCHMARK.ouro-test.json")
 CELL = "ouro-test.steady-4k-b4"
+REAL_CELL = "ouro-2.6b.steady-4k-b4"
 #: what only a device trace or a chip's peak can give
-DEVICE_DERIVED = {"looplm_model_flops_util", "looplm_attn_time_pct",
-                  "hd128_flash_time_pct", "rope_time_pct",
-                  "sandwich_norm_time_pct", "looplm_head_time_pct",
-                  "hd128_flash_fwd_roofline", "hd128_flash_dq_roofline",
-                  "hd128_flash_dkv_roofline", "device_idle_pct"}
+DEVICE_DERIVED = device_derived(REAL_CELL)
 
 
 @pytest.mark.parametrize("trace,expect", [
@@ -43,18 +40,17 @@ def test_looplm_rehearsal(trace, expect):
 
 
 def test_the_rehearsal_file_lists_the_new_readers():
-    with open(TEST_JSON) as f:
-        rehearsal = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert DEVICE_DERIVED <= {m["name"] for m in rehearsal["per_layer"]}
-    mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == ["ouro-2.6b.steady-4k-b4"]}
-    assert mine == DEVICE_DERIVED - {"device_idle_pct"}
+    assert {"mfu", "attn_time_pct", "flash_time_pct", "rope_time_pct",
+            "sandwich_norm_time_pct", "head_loss_time_pct",
+            "flash_fwd_roofline", "flash_bwd_roofline",
+            "device_idle_pct"} <= DEVICE_DERIVED
+    assert not {"flash_dq_roofline", "flash_dkv_roofline"} & DEVICE_DERIVED
+    cell = check_rehearsal_file(TEST_JSON, CELL, REAL_CELL)
+    assert cell["chips"] == 1 and cell["traffic"] == "steady-4k-b4"
 
 
 def test_no_chip_no_metric_for_the_new_cell():
-    proc = run_py(["--workload", "ouro-2.6b.steady-4k-b4", "--seed", "0",
-                   "--seconds", "1", "--trace", "0"])
+    proc = run_py(["--workload", REAL_CELL, "--seed", "0", "--seconds", "1",
+                   "--trace", "0"])
     assert proc.returncode != 0
     assert not proc.stdout.strip().startswith("{")

@@ -86,16 +86,40 @@ def test_empty_trace_reads_nothing():
 
 
 def test_flash_calls_from_hlo_text():
+    """Told by the name the program gives a call — on its ``op_name`` path,
+    or where a line has no metadata the instruction's own — and listed where
+    the results are as many as that kind gives."""
+    meta = ('metadata={op_name="jit(train_step)/jvp(Transformer)/blocks/'
+            'attention/multihead_attention/%s/pallas_call"}')
     text = """
-  %multihead_attention.63 = (bf16[128,1024,64]{2,1,0}, f32[128,1024,1]{2,1,0}) custom-call(%a, %b, %c), custom_call_target="tpu_custom_call", backend_config={...}
-  %multihead_attention.65 = bf16[128,1024,64]{2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call"
-  ROOT %x.66 = (bf16[100,1024,64]{2,1,0}, bf16[100,1024,64]{2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call"
+  %flash_fwd.63 = (bf16[128,1024,64]{2,1,0}, f32[128,1024,1]{2,1,0}) custom-call(%a, %b, %c), custom_call_target="tpu_custom_call", backend_config={...}
+  %flash_bwd_dq.65 = bf16[128,1024,64]{2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call"
+  ROOT %x.66 = (bf16[100,1024,64]{2,1,0}, bf16[100,1024,64]{2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call", """ + meta % "swa_bwd_dkv" + """
+  %y.67 = (bf16[2,8192,6144]{2,1,0}, bf16[2,8192,6144]{2,1,0}, bf16[2,8192,4096]{2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call", """ + meta % "mla_bwd" + """
+  %rope_fwd.70 = bf16[2,8192,6144]{2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call"
+  %rope_bwd.71 = bf16[2,8192,6144]{2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call"
+  %grouped_weights.72 = bf16[16,2048,768]{2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call"
+  %ssd_fwd.73 = (bf16[2,64,64,8192]{3,2,1,0}, f32[2,64,4096,128]{3,2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call"
+  %flash_fwd.74 = bf16[128,1024,64]{2,1,0} custom-call(%a, %b), custom_call_target="tpu_custom_call"
   %other = f32[8]{0} custom-call(%a), custom_call_target="Sharding"
+  %transpose_jvp_flash_bwd_dq__.1 = bf16[8,1024,1024]{2,1,0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(loss)/transpose(jvp(flash_bwd_dq))/pallas_call"}
+  %jvp_flash_fwd_.1 = (bf16[8,1024,1024]{2,1,0}, f32[8,16,1024,1]{3,2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call"
 """
     calls = hlo.flash_calls(text)
-    assert [(c["name"], c["kind"], c["batch_heads"]) for c in calls] == [
-        ("multihead_attention.63", "fwd", 128),
-        ("multihead_attention.65", "dq", 128), ("x.66", "dkv", 100)]
+    # a kernel differentiated on its own (no model around it: tier-1's
+    # tests/test_tpu_compile.py) carries the transforms' names around its own
+    assert [(c["name"], c["kernel"], c["kind"]) for c in calls[4:]] == [
+        ("transpose_jvp_flash_bwd_dq__.1", "flash_bwd_dq", "dq"),
+        ("jvp_flash_fwd_.1", "jvp_flash_fwd", "fwd")]
+    calls = calls[:4]
+    assert [(c["name"], c["kernel"], c["kind"], c["batch_heads"])
+            for c in calls] == [
+        ("flash_fwd.63", "flash_fwd", "fwd", 128),
+        ("flash_bwd_dq.65", "flash_bwd_dq", "dq", 128),
+        ("x.66", "swa_bwd_dkv", "dkv", 100),
+        ("y.67", "mla_bwd", "bwd", 2)]
+    # the one-call backward's sizes are its first result's: dq's
+    assert (calls[3]["seq"], calls[3]["head_dim"]) == (8192, 6144)
 
 
 # ----------------------------------------------------------------- timeline

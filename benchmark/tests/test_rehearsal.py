@@ -15,7 +15,7 @@ from conftest import BENCH, HERE, ROOT
 
 TEST_JSON = os.path.join(HERE, "BENCHMARK.test.json")
 #: names whose value exists only on a device
-DEVICE_DERIVED = {"model_flops_util", "flash_time_pct", "flash_roofline",
+DEVICE_DERIVED = {"mfu", "flash_time_pct", "flash_roofline",
                   "device_idle_pct", "collective_pct",
                   "collective_exposed_pct"}
 

@@ -13,7 +13,7 @@ import os
 import pytest
 
 from conftest import BENCH, HERE
-from lib import hlo, peaks, scope_reduce as sr, trace_reduce as tr
+from lib import hlo, peaks, scope_reduce as sr, told, trace_reduce as tr
 
 DEVICE_READERS = (
     "fwd_time_pct", "bwd_time_pct", "remat_time_pct", "attn_time_pct",
@@ -52,40 +52,40 @@ def scopes():
 @pytest.mark.parametrize("path,expect", [
     ("jit(train_step)/jvp(Transformer)/while/body/closed_call/blocks/"
      "attention/multihead_attention/flash_fwd/pallas_call",
-     ("fwd", "attention", "flash_fwd")),
+     ("fwd", "attention")),
     ("jit(train_step)/transpose(jvp(Transformer))/while/body/closed_call/"
      "checkpoint/rematted_computation/blocks/attention/multihead_attention/"
-     "flash_fwd/pallas_call", ("remat", "attention", "flash_fwd")),
+     "flash_fwd/pallas_call", ("remat", "attention")),
     ("jit(train_step)/transpose(jvp(Transformer))/while/body/closed_call/"
      "checkpoint/blocks/attention/multihead_attention/flash_bwd_dq/"
-     "pallas_call", ("bwd", "attention", "flash_bwd_dq")),
+     "pallas_call", ("bwd", "attention")),
     ("jit(train_step)/transpose(jvp(Transformer))/while/body/closed_call/"
-     "checkpoint/blocks/ffn/down/dot_general", ("bwd", "ffn", None)),
+     "checkpoint/blocks/ffn/down/dot_general", ("bwd", "ffn")),
     ("jit(train_step)/jvp(Transformer)/lm_head/tok_emb.attend/dot_general",
-     ("fwd", "head_loss", None)),
+     ("fwd", "head_loss")),
     ("jit(train_step)/transpose(jvp(loss))/jit(take_along_axis)/scatter-add",
-     ("bwd", "head_loss", None)),
+     ("bwd", "head_loss")),
     ("jit(train_step)/jvp(lm_head_loss)/while/body/dot_general",
-     ("fwd", "head_loss", None)),
-    ("jit(train_step)/optimizer/mul", ("none", "optimizer", None)),
-    ("jit(train_step)/grad_norm/sqrt", ("none", "optimizer", None)),
+     ("fwd", "head_loss")),
+    ("jit(train_step)/optimizer/mul", ("none", "optimizer")),
+    ("jit(train_step)/grad_norm/sqrt", ("none", "optimizer")),
     ("jit(train_step)/while/body/closed_call/accumulate/add",
-     ("none", "accumulate", None)),
+     ("none", "accumulate")),
     ("jit(train_step)/jvp(cast_params)/convert_element_type",
-     ("fwd", "other", None)),
+     ("fwd", "other")),
     ("jit(train_step)/transpose(jvp(Transformer))/tok_emb/jit(_take)/"
-     "scatter-add", ("bwd", "other", None)),
+     "scatter-add", ("bwd", "other")),
     ("jit(train_step)/jvp(Transformer)/while/body/dynamic_slice",
-     ("fwd", "other", None)),
-    ("jit(train_step)/jvp()/mul", ("fwd", "unscoped", None)),
-    ("jit(train_step)/while/body/add", ("none", "unscoped", None)),
-    ("jit(train_step)/jit(_where)/select_n", ("none", "unscoped", None)),
-    ("dot_general", ("none", "unscoped", None)),
-    ("", ("none", "unscoped", None)),
+     ("fwd", "other")),
+    ("jit(train_step)/jvp()/mul", ("fwd", "unscoped")),
+    ("jit(train_step)/while/body/add", ("none", "unscoped")),
+    ("jit(train_step)/jit(_where)/select_n", ("none", "unscoped")),
+    ("dot_general", ("none", "unscoped")),
+    ("", ("none", "unscoped")),
 ])
 def test_classify(path, expect):
     found = sr.classify(path)
-    assert (found["pass"], found["part"], found["kernel"]) == expect
+    assert (found["pass"], found["part"]) == expect
 
 
 @pytest.mark.parametrize("cell", ["medium", "xl"])
@@ -97,7 +97,6 @@ def test_every_recorded_path_has_one_pass_and_one_part(scopes, cell):
     for path in paths:
         found = sr.classify(path)
         assert found["pass"] in sr.PASSES and found["part"] in sr.PARTS
-        assert found["kernel"] in sr.KERNELS + (None,)
         seen.add((found["pass"], found["part"]))
     # the whole table of the step, both partitions crossed
     for cellkey in (("fwd", "attention"), ("bwd", "attention"),
@@ -155,43 +154,56 @@ def test_a_program_without_names_has_passes_and_parts_to_say_nothing_of(
 
 
 # -------------------------------------------------------------- kernels
+KERNELS = {"flash_fwd_roofline": "flash_fwd",
+           "flash_dq_roofline": "flash_bwd_dq",
+           "flash_dkv_roofline": "flash_bwd_dkv"}
+
+
 def test_kernels_told_by_name_on_one_chip_and_under_shard_map(scopes):
+    """The kernel a GPT-2 cell's module names for each roofline is on the
+    recorded calls' paths, on one chip and under ``shard_map``, and agrees
+    with the kind ``lib/hlo.py`` reads from the call's own name."""
+    from lib import cell_gpt2
+
+    stated = cell_gpt2.kernels({})
+    assert {q: k.name for q, k in stated.items()} == KERNELS
+    kind_of = {"flash_fwd": "fwd", "flash_bwd_dq": "dq",
+               "flash_bwd_dkv": "dkv"}
     for calls, paths in (
             (scopes["flash_calls"], scopes["trace"]["paths"]),
             (scopes["xl"]["flash_calls"], scopes["xl"]["kernel_paths"])):
-        named = sr.kernel_calls(paths, calls)
-        have = [c for c in named if c["name"] in paths]
-        assert have and {c["kernel"] for c in have} <= set(sr.KERNELS)
+        have = [c for c in calls if c["name"] in paths]
+        assert have
         for call in have:
-            # the name the program gives agrees with what lib/hlo.py
-            # guesses from the result types, which is no longer needed
-            assert sr.KERNEL_KIND[call["kernel"]] == call["kind"]
-    xl = sr.kernel_calls(scopes["xl"]["kernel_paths"],
-                         scopes["xl"]["flash_calls"])
-    assert {c["kernel"] for c in xl} == set(sr.KERNELS)
-    assert len(xl) == len(scopes["xl"]["flash_calls"])
-    assert all("shard_map" in scopes["xl"]["kernel_paths"][c["name"]]
-               for c in xl)
+            names = sr.names_on(paths[call["name"]])[1]
+            kernel, = (k for k in kind_of if k in names)
+            assert kind_of[kernel] == call["kind"]
+            assert hlo.kernel_name(call["name"], "") == kernel
+    xl = scopes["xl"]
+    assert len(xl["kernel_paths"]) >= len(xl["flash_calls"])
+    assert all("shard_map" in xl["kernel_paths"][c["name"]]
+               for c in xl["flash_calls"])
 
 
-def test_kernel_rooflines_weighted_by_time_give_flash_roofline(scopes):
+def test_kernel_rooflines_weighted_by_time_give_flash_roofline(
+        scopes, monkeypatch):
     trace = scopes["trace"]
     ops = tr.ops_by_name(trace)
     artifacts = {"trace_summary": {"ops": ops},
                  "flash_calls": scopes["flash_calls"],
+                 "config": {"readers": {"module": "cell_gpt2"}},
                  "device": {"kind": "TPU v5 lite"}}
+    monkeypatch.setattr(sr, "of_run", lambda artifacts: {
+        "paths": trace["paths"], "whole_paths": True})
     whole = reader("flash_roofline")(artifacts)
-    peak_f = peaks.peak("TPU v5 lite", "bf16_flops_per_s")
-    peak_b = peaks.peak("TPU v5 lite", "hbm_bytes_per_s")
     weighted = took = 0.0
     each = {}
-    for kernel in sr.KERNELS:
-        pct = sr.kernel_roofline_pct(kernel, trace["paths"],
-                                     scopes["flash_calls"], ops, peak_f,
-                                     peak_b)
-        seconds = sum(ops[c["name"]]["seconds"] for c in sr.kernel_calls(
-            trace["paths"], scopes["flash_calls"])
-            if c["kernel"] == kernel and c["name"] in ops)
+    for quantity, kernel in KERNELS.items():
+        pct = told.kernel_roofline_pct(artifacts, quantity)
+        seconds = sum(
+            ops[c["name"]]["seconds"] for c in scopes["flash_calls"]
+            if c["name"] in ops and kernel in sr.names_on(
+                trace["paths"].get(c["name"], ""))[1])
         each[kernel] = pct
         weighted += pct * seconds
         took += seconds
@@ -200,6 +212,8 @@ def test_kernel_rooflines_weighted_by_time_give_flash_roofline(scopes):
     assert each["flash_fwd"] == pytest.approx(14.8, abs=0.1)
     assert each["flash_bwd_dq"] == pytest.approx(24.9, abs=0.1)
     assert each["flash_bwd_dkv"] == pytest.approx(20.2, abs=0.15)
+    # a cell whose backward is unrolled has no one-call backward to read
+    assert told.kernel_roofline_pct(artifacts, "flash_bwd_roofline") is None
 
 
 # ------------------------------------------------------------- the file
@@ -256,9 +270,9 @@ def test_no_device_trace_no_number():
 
 
 def test_flash_calls_reads_named_kernels():
-    """``lib/hlo.flash_calls`` (PR 22, not edited) keys on the custom call's
-    target, so it still finds the calls now that the instruction is named
-    after the kernel; the text is the chip's (PR 23 chip call 3)."""
+    """``lib/hlo.flash_calls`` keys on the custom call's target and tells
+    the kernel by the name on the call's path; the text is the chip's
+    (PR 23 chip call 3)."""
     line = (
         '  %flash_fwd.25 = (bf16[128,1024,64]{2,1,0:T(8,128)(2,1)}, '
         'f32[128,1024,1]{2,1,0:T(8,128)}) custom-call(%bitcast.1170, '
@@ -267,8 +281,8 @@ def test_flash_calls_reads_named_kernels():
         'closed_call/blocks/attention/multihead_attention/flash_fwd/'
         'pallas_call" stack_frame_id=40}')
     assert hlo.flash_calls(line) == [{
-        "name": "flash_fwd.25", "kind": "fwd", "batch_heads": 128,
-        "seq": 1024, "head_dim": 64}]
+        "name": "flash_fwd.25", "kernel": "flash_fwd", "kind": "fwd",
+        "batch_heads": 128, "seq": 1024, "head_dim": 64}]
 
 
 # ------------------------------------------------------------- elastic

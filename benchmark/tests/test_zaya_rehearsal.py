@@ -9,18 +9,16 @@ import os
 
 import pytest
 
-from conftest import BENCH, HERE, ROOT
+from conftest import BENCH, HERE
+from listed import (check_nothing_to_read, check_rehearsal_file,
+                    device_derived)
 from test_rehearsal import last_line, run_py
 
 TEST_JSON = os.path.join(HERE, "BENCHMARK.zaya-test.json")
 CELL = "zaya1-test.top1-8k-b2"
 REAL_CELL = "zaya1-8b.top1-8k-b2"
 #: what only a device trace or a chip's peak can give
-DEVICE_DERIVED = {
-    "zaya_model_flops_util", "cca_attn_time_pct", "cca_mix_time_pct",
-    "cca_flash_time_pct", "cca_flash_fwd_roofline", "cca_flash_dq_roofline",
-    "cca_flash_dkv_roofline", "top1_moe_time_pct", "top1_experts_time_pct",
-    "zaya_router_time_pct", "zaya_head_time_pct", "device_idle_pct"}
+DEVICE_DERIVED = device_derived(REAL_CELL)
 
 
 @pytest.mark.parametrize("trace,expect", [
@@ -51,51 +49,21 @@ def test_zaya_rehearsal(trace, expect):
 
 
 def test_the_rehearsal_file_lists_the_new_readers():
-    with open(TEST_JSON) as f:
-        rehearsal = json.load(f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert DEVICE_DERIVED <= {m["name"] for m in rehearsal["per_layer"]}
-    mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [REAL_CELL]}
-    assert mine == DEVICE_DERIVED - {"device_idle_pct"}
-    cell, = (w for w in bench["workloads"] if w["name"] == REAL_CELL)
+    assert {"mfu", "attn_time_pct", "cca_mix_time_pct", "flash_time_pct",
+            "flash_fwd_roofline", "flash_bwd_roofline", "moe_time_pct",
+            "experts_time_pct", "router_time_pct", "head_loss_time_pct",
+            "device_idle_pct"} <= DEVICE_DERIVED
+    cell = check_rehearsal_file(TEST_JSON, CELL, REAL_CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "top1-8k-b2"
-    assert [w["name"] for w in bench["workloads"]][-1] == REAL_CELL
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 
 def test_the_new_readers_find_nothing_in_a_program_without_the_names():
     """On the parent's side of a traced run the new readers return nothing
     and do not raise: artifacts of another model, no trace, no counters."""
-    import importlib.util
-
-    for config in ({"layer_types": ["full_attention"]},
-                   {"model_type": "zaya", "layer_types": ["hybrid"],
-                    "kwargs": {"seq_len": 64}}):
-        artifacts = {"config": config,
-                     "traffic": {"global_batch": 2, "trace_steps": 4},
-                     "device": {"platform": "cpu", "kind": "cpu"},
-                     "check": {"ok": True}}
-        for name in sorted(DEVICE_DERIVED):
-            path = os.path.join(ROOT, "benchmark", "layer_metrics",
-                                f"{name}.py")
-            spec = importlib.util.spec_from_file_location(f"reader_{name}",
-                                                          path)
-            module = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(module)
-            assert module.read(artifacts) is None, name
-
-
-def test_grouped_products_are_told_wherever_their_name_stands():
-    from lib import zaya_names
-
-    told = zaya_names._is_grouped_product
-    assert told("ragged-dot.12")
-    assert told("jit(train_step)/transpose(jvp(moe))/experts/"
-                "ragged_dot_general")
-    assert not told("jit(train_step)/moe/experts/mul")
-    assert not told("ragged-dot.3/metadata")
+    check_nothing_to_read(REAL_CELL, (
+        {"layer_types": ["full_attention"]},
+        {"readers": {"module": "cell_zaya"}, "layer_types": ["hybrid"],
+         "kwargs": {"seq_len": 64}}))
 
 
 def test_no_chip_no_metric_for_the_new_cell():
