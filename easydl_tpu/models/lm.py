@@ -16,6 +16,7 @@ from jax import lax
 from easydl_tpu.core.data import SyntheticTokens
 from easydl_tpu.models.registry import ModelBundle
 from easydl_tpu.models.transformer import Transformer, TransformerConfig
+from easydl_tpu.ops.flash_attention import BlockDiffusion, choose_blocks
 from easydl_tpu.ops.fused_xent import fused_softmax_xent, local_batch
 from easydl_tpu.utils.logging import get_logger, log_once
 
@@ -184,6 +185,52 @@ def mtp_objective(states, head: jax.Array, targets: jax.Array, *,
                   "loss_mtp": loss_mtp}
 
 
+#: the least time a block is noised at: ``t ~ U(DIFFUSION_EPS, 1)``
+DIFFUSION_EPS = 1e-3
+
+
+def block_diffusion_noise(rng: jax.Array, tokens: jax.Array, *, block: int,
+                          mask_id: int):
+    """Block diffusion's draw for ``tokens [B, L]`` (the clean ``x0``), a
+    pure function of ``rng`` and the shape: ``(xt, masked, t)`` — for each
+    sequence and block of ``block`` tokens a time ``t ~ U(DIFFUSION_EPS,
+    1)``, each token of the block masked independently with probability
+    ``t`` (the linear schedule, ``alpha_t = 1 - t``), ``xt`` the tokens with
+    ``mask_id`` at the masked places; ``masked [B, L]`` bool and ``t [B, L]``
+    float32, a block's time at each of its tokens. Which positions count is
+    ``masked``, never ``xt == mask_id``: the data may hold that id."""
+    batch, seq = tokens.shape
+    key_t, key_m = jax.random.split(rng)
+    t = jax.random.uniform(key_t, (batch, seq // block), jnp.float32,
+                           DIFFUSION_EPS, 1.0)
+    t = jnp.repeat(t, block, axis=1)
+    masked = jax.random.uniform(key_m, (batch, seq), jnp.float32) < t
+    return jnp.where(masked, mask_id, tokens), masked, t
+
+
+def block_diffusion_objective(hidden: jax.Array, head: jax.Array,
+                              tokens: jax.Array, masked: jax.Array,
+                              t: jax.Array, *, logit_scale: float = 1.0):
+    """Block diffusion's training objective (BD3-LM, arXiv:2503.09573; the
+    linear schedule's weight ``1 / t``): ``(1 / (B L)) sum over the masked
+    positions of (1 / t) * -log softmax(logits_i)[x0_i]`` — no shift: a
+    masked position predicts its own token. ``(loss, metrics)``.
+
+    ``hidden``: the NOISED half's normed final states ``[B, L, D]`` (the
+    clean half's feed nothing and are not read); ``head``: ``[V, D]``;
+    ``tokens``, ``masked``, ``t``: ``x0`` and :func:`block_diffusion_noise`'s
+    draw. One call of the fused chunked head (``ops/fused_xent.py``), each
+    row weighted ``masked / t`` — the only form written, as
+    :func:`mtp_objective`'s."""
+    share = masked.astype(jnp.float32)
+    with jax.named_scope("lm_head_loss"):
+        loss, _, _ = fused_softmax_xent(
+            hidden, head, tokens, weights=lax.stop_gradient(share / t),
+            logit_scale=logit_scale)
+    return loss, {"diffusion_masked_share": jnp.mean(share),
+                  "diffusion_mean_t": jnp.mean(t)}
+
+
 def lm_bundle(cfg: TransformerConfig, name: str, *,
               exit_entropy_weight: float = 0.0) -> ModelBundle:
     """The causal-LM bundle of one description of the stack: init, loss
@@ -203,7 +250,16 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     the share of tokens that took it, and, where it is the linear softmax,
     ``router_chosen_mass``, the softmax mass on the chosen experts before
     the renormalisation; where the router's state runs through
-    the depth, ``router_state_rms``, its size after the last layer."""
+    the depth, ``router_state_rms``, its size after the last layer.
+
+    Under ``cfg.block_diffusion`` the loss is
+    :func:`block_diffusion_objective`'s: the batch's ``inputs`` are ``x0``
+    (its ``targets`` are not read), noised INSIDE the loss from the ``rng``
+    it is handed — ``Trainer``'s ``fold_in(state.rng, state.step)``, so a
+    restored step draws the noise it drew — the stack run on ``[xt || x0]``,
+    the vocabulary's last row standing for the mask token; the metrics
+    carry ``diffusion_masked_share`` and ``diffusion_mean_t``. ``eval_fn`` is
+    the same loss under one fixed key."""
     model = Transformer(cfg)
     seq_len, vocab = cfg.max_seq, cfg.vocab
     n_sparse = sum(1 for _, ffn in cfg.every_layer if ffn == "moe")
@@ -231,8 +287,39 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
             logit_scale=1.0 / cfg.logits_scaling)
 
     def init_fn(rng):
-        tokens = jnp.zeros((1, seq_len), jnp.int32)
+        rows = 2 * seq_len if cfg.block_diffusion else seq_len
+        tokens = jnp.zeros((1, rows), jnp.int32)
         return model.init(rng, tokens)["params"]
+
+    def diffusion_loss(params, batch, rng, mutable=False):
+        """``(loss, mutated collections or None, the objective's
+        metrics)``."""
+        x0 = batch["inputs"]
+        with jax.named_scope("noise"):
+            xt, masked, t = block_diffusion_noise(
+                rng, x0, block=cfg.block_diffusion, mask_id=vocab - 1)
+            rows = jnp.concatenate([xt, x0], axis=1)
+        out = model.apply(
+            {"params": params}, rows, return_hidden=True,
+            **({"mutable": ["counters"]} if mutable else {}))
+        hidden, mut = out if mutable else (out, None)
+        loss, metrics = block_diffusion_objective(
+            hidden[:, :x0.shape[1]], head_of(params, hidden.dtype), x0,
+            masked, t, logit_scale=1.0 / cfg.logits_scaling)
+        # static: the kernel block pairs the block mask's calls visit, of
+        # all (``ops/flash_attention.py choose_blocks``; 0 of 0 where the
+        # lengths have no block and the XLA reference path runs)
+        mask = BlockDiffusion(cfg.block_diffusion, x0.shape[1])
+        blocks = choose_blocks(rows.shape[1], rows.shape[1], False, mask=mask)
+        live, every = mask.block_pairs(blocks[0][0]) if blocks else (0, 0)
+        log_once(log, f"diffusion: blocks of {cfg.block_diffusion} over "
+                      f"{x0.shape[1]} tokens, {rows.shape[1]} rows a "
+                      f"sequence; flash_live_pairs {live} of "
+                      f"flash_block_pairs {every}; metrics "
+                      f"diffusion_masked_share, diffusion_mean_t")
+        metrics.update(flash_live_pairs=jnp.float32(live),
+                       flash_block_pairs=jnp.float32(every))
+        return loss, mut, metrics
 
     def _lm_loss_from(params, batch, mutable=False):
         """``(loss, the mutated collections or None, the heads' metrics or
@@ -292,7 +379,9 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
         if cfg.exit_gate:
             return gated_loss(params, batch)
         if n_sparse:
-            loss, mut, heads = _lm_loss_from(params, batch, mutable=True)
+            loss, mut, heads = diffusion_loss(params, batch, rng, True) \
+                if cfg.block_diffusion \
+                else _lm_loss_from(params, batch, mutable=True)
             summed = mut["counters"]["moe"][0]
             counters = {name: summed[i] / (1 if name == "moe_dropped"
                                            else n_sparse)
@@ -301,13 +390,16 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
                 counters["router_state_rms"] = \
                     mut["counters"]["router_state_rms"][0]
             return loss, metrics_of(loss, heads, counters)
-        loss, _, heads = _lm_loss_from(params, batch)
+        loss, _, heads = diffusion_loss(params, batch, rng) \
+            if cfg.block_diffusion else _lm_loss_from(params, batch)
         return loss, metrics_of(loss, heads)
 
     def eval_fn(params, batch, rng):
         if cfg.exit_gate:
             return gated_loss(params, batch)
-        loss, _, heads = _lm_loss_from(params, batch)
+        # a diffusion model's evaluation draws its noise from one fixed key
+        loss, _, heads = diffusion_loss(params, batch, jax.random.PRNGKey(0)) \
+            if cfg.block_diffusion else _lm_loss_from(params, batch)
         return loss, metrics_of(loss, heads)
 
     def make_data(global_batch: int, seed: int = 0):

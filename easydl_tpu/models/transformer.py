@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from easydl_tpu.ops import multihead_attention, remat
 from easydl_tpu.ops.attention import rotate_heads
+from easydl_tpu.ops.flash_attention import BlockDiffusion
 from easydl_tpu.ops import moe as moe_ops
 from easydl_tpu.ops.moe import MoeMlp
 from easydl_tpu.ops.rope import apply_rope, rope_tables
@@ -255,9 +256,13 @@ class AttentionKind:
     and Mellum 2's 1,024 alike, up to a grid cell's 2,048 rows:
     ``ops/flash_attention.py choose_blocks``), a rotary scheme (None: the description's ``position``), a
     per-head sigmoid gate on the attention output, (``latent``) the
-    mixing of q, k and v inside the heads' latent, and (``lowrank``) q, k
+    mixing of q, k and v inside the heads' latent, (``lowrank``) q, k
     and v made through low-rank latents in place of one full-rank map
-    each."""
+    each, and (``qk_norm``) an RMSNorm over each head's dimensions on q and
+    on k in front of the rotary kernel, one learned gain of ``head_dim``
+    for all of q's heads and one for k's (Qwen3's and SDAR's; a ``latent``
+    or ``lowrank`` kind norms q and k in its own way and refuses this
+    one)."""
 
     n_heads: int = 0
     window: int = 0
@@ -265,6 +270,7 @@ class AttentionKind:
     gate: bool = False
     latent: Optional[LatentMix] = None
     lowrank: Optional[LowRank] = None
+    qk_norm: bool = False
 
 
 @dataclass(frozen=True)
@@ -451,6 +457,14 @@ class TransformerConfig:
     #: a multi-token-prediction module behind the stack, where the
     #: description has one
     mtp: Optional[MtpConfig] = None
+    #: block diffusion's block length (0: none; BD3-LM, arXiv:2503.09573): the
+    #: stack is then given ``[noised || clean]`` rows, TWICE ``max_seq`` of
+    #: them a sequence, every attention layer runs under
+    #: ``ops/flash_attention.py BlockDiffusion(block_diffusion, rows / 2)``
+    #: in place of a causal mask, and both halves carry the positions ``0 ..
+    #: rows / 2 - 1`` (``models/lm.py block_diffusion_objective`` builds the
+    #: rows and reads the noised half)
+    block_diffusion: int = 0
 
     def __post_init__(self):
         if self.layers is not None and len(self.layers) != self.n_layers:
@@ -496,6 +510,38 @@ class TransformerConfig:
                     f"scores with head_size = nope_dim + rope_dim, rotates "
                     f"rope_dim by its own scheme, has as many key/value "
                     f"heads as query heads and no window, gate or mix")
+        for name, kind in self.attention_kinds:
+            if kind.qk_norm and (kind.latent or kind.lowrank):
+                raise ValueError(
+                    f"attention kind {name!r}: qk_norm on a latent or "
+                    f"lowrank kind, which norms q and k in its own way "
+                    f"(TransformerConfig.__post_init__ refuses the pair)")
+        if self.block_diffusion:
+            kinds_used = [self.attention_kind(mixer)
+                          for mixer, _ in self.every_layer
+                          if mixer != "mamba2"]
+            refused = [what for what, found in (
+                ("causal=True", self.causal),
+                ("a mamba2 layer", len(kinds_used) < len(self.every_layer)),
+                ("a window", any(k.window for k in kinds_used)),
+                ("a latent or lowrank attention kind",
+                 any(k.latent or k.lowrank for k in kinds_used)),
+                ("learned positions", self.position == "learned"),
+                ("a looped or gated stack", self.loops > 1 or self.exit_gate),
+                ("a multi-token-prediction module", self.mtp is not None),
+                ("attention_fn (sequence parallelism)",
+                 self.attention_fn is not None),
+                ("pipeline_fn", self.pipeline_fn is not None),
+                ("a router state through the depth",
+                 bool(self.router_state_width)),
+                ("dropout", bool(self.dropout))) if found]
+            if refused or self.max_seq % self.block_diffusion:
+                raise NotImplementedError(
+                    f"block_diffusion={self.block_diffusion} over max_seq="
+                    f"{self.max_seq} with {', '.join(refused) or 'a length'}"
+                    f" it does not divide: the block mask is neither causal "
+                    f"nor a window and stands on plain attention layers "
+                    f"alone (TransformerConfig.__post_init__ refuses it)")
         if self.mtp is not None and (
                 self.loops > 1 or self.exit_gate or self.pipeline_fn
                 is not None or self.attention_fn is not None or
@@ -587,6 +633,8 @@ class TransformerConfig:
             kind = self.attention_kind(mixer)
             inner = (kind.n_heads or self.n_heads) * self.head_dim
             n = 2 * d * inner + 2 * d * self.kv_heads * self.head_dim
+            if kind.qk_norm:
+                n += 2 * self.head_dim  # q's gain and k's
             if kind.gate:
                 n += d * (kind.n_heads or self.n_heads)
             if kind.latent:
@@ -663,7 +711,10 @@ class TransformerConfig:
         convention has it, or the ``window`` keys a windowed layer's band
         holds (latent attention: ``6 * heads * (head_dim + value_dim)``). A
         multi-token-prediction module pays its layer, its join and the head
-        a second time."""
+        a second time. Under ``block_diffusion`` a token is TWO rows through
+        every layer (its noised and its clean one) and one through the head,
+        and sees ``seq + block`` keys a layer (``seq² + seq · block`` live
+        pairs a sequence)."""
         n_attn = sum(1 for mixer, _ in self.pattern if mixer != "mamba2")
         head = self.vocab * self.d_model
         held = sum(self.layer_params(l) for l in self.pattern) + head
@@ -678,8 +729,9 @@ class TransformerConfig:
                 # S = Q K^T at the scores' head size and P V at the values'
                 sizes = 2 * self.head_dim if kind.lowrank is None else \
                     self.head_dim + kind.lowrank.value_dim
-                scores += 6.0 * (kind.n_heads or self.n_heads) * sizes \
-                    * min(kind.window or seq_len, seq_len)
+                scores += 6.0 * (kind.n_heads or self.n_heads) * sizes * (
+                    seq_len + self.block_diffusion if self.block_diffusion
+                    else min(kind.window or seq_len, seq_len))
         if n_attn < len(self.pattern):
             m = self.ssm
             scores += 3.0 * (len(self.pattern) - n_attn) * ssd_flops_per_token(
@@ -687,6 +739,8 @@ class TransformerConfig:
         # the module's layer at its active count, and the head once more
         module = self._mtp_params(active=True) + head if self.mtp else 0
         once -= self._mtp_params()
+        if self.block_diffusion:  # the layers twice a token, the head once
+            looped = 2 * (looped - head) + head
         return 6.0 * (once + module) + self.loops * (6.0 * looped + scores)
 
 
@@ -879,9 +933,18 @@ def _attention(block, h, rope=None):
         # mutable collection (the benchmark's check, tests)
         for name, value in (("in", h), ("q", q), ("k", k), ("v", v)):
             block.sow("intermediates", f"latent_{name}", value)
+    if kind.qk_norm:
+        # over each head's dimensions, float32, one gain for q and one for k
+        with jax.named_scope("qk_rmsnorm"):
+            q = _rms(block, "q_norm", q, cfg.norm_eps)
+            k = _rms(block, "k_norm", k, cfg.norm_eps)
     q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
     k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
     v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
+    # the rows are a sequence's [noised || clean] halves under block
+    # diffusion's mask
+    mask = BlockDiffusion(cfg.block_diffusion, h.shape[1] // 2) \
+        if cfg.block_diffusion else None
     if cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
         if kind.window:
             raise NotImplementedError(
@@ -893,8 +956,13 @@ def _attention(block, h, rope=None):
         attn = multihead_attention(
             q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
             scale=cfg.attention_multiplier, rope=rope, rotary_dim=rotary_dim,
-            window=kind.window or None,
+            window=kind.window or None, mask=mask,
         )
+    # what the kernels were given and gave, where `intermediates` is a
+    # mutable collection (the benchmark's check, tests)
+    if kind.qk_norm:
+        for name, value in (("in", h), ("q", q), ("k", k), ("out", attn)):
+            block.sow("intermediates", f"attn_{name}", value)
     if kind.gate:
         # one sigmoid a head on the layer's normed input, float32
         with jax.named_scope("attn_gate"):
@@ -1006,7 +1074,13 @@ def _ffn(block, h, state=None):
             router=m.router, router_hidden=m.router_hidden,
             router_eps=cfg.norm_eps, skip_choice=m.skip_choice,
             selection_bias=m.selection_bias, expert_form=m.expert_form,
-            down_zero_sums=m.down_zero_sums, name="moe",
+            down_zero_sums=m.down_zero_sums,
+            # under block diffusion every masked row is nearly the mask
+            # token's ONE vector, so the rows' near-ties at the k-th place
+            # are one near-tie: a rematerialised forward that rounds another
+            # way would re-route them all, and the backward weigh experts
+            # the pass did not run (``MoeMlp.keep_routing``)
+            keep_routing=bool(cfg.block_diffusion), name="moe",
         )(h, state)
         return y, aux, state if routed is None else routed
     else:
@@ -1289,8 +1363,10 @@ class Transformer(nn.Module):
         # One traced block a run of equal layers, scanned over a stacked
         # 'layers' param axis: `blocks` where the whole stack is one run
         # (GPT-2, BERT), `blocks_<i>` where the pattern has several.
+        # (`intermediates`, where a caller makes it mutable — a check, a
+        # test — come out of a run stacked by layer, as the params lie)
         scan_kwargs = dict(
-            variable_axes={"params": 0},
+            variable_axes={"params": 0, "intermediates": 0},
             split_rngs={"params": True, "dropout": True},
             in_axes=(nn.broadcast, nn.broadcast),
             metadata_params={nn.PARTITION_NAME: "layers"},
@@ -1300,11 +1376,23 @@ class Transformer(nn.Module):
             raise NotImplementedError(
                 "pipeline_fn over a stack of more than one run of layers")
         # one pair of tables a rotary scheme, made once for all its layers
-        ropes = {"attention": rope_tables(seq, cfg.head_dim, cfg.rope_theta)
+        if cfg.block_diffusion and seq % (2 * cfg.block_diffusion):
+            raise ValueError(
+                f"block_diffusion={cfg.block_diffusion}: the stack takes "
+                f"[noised || clean] rows, twice a whole number of blocks; "
+                f"got {seq}")
+        # under block diffusion a noised token and its clean twin are
+        # rotated alike: positions [0 .. seq/2 - 1, 0 .. seq/2 - 1]
+        held = seq // 2 if cfg.block_diffusion else seq
+        ropes = {"attention": rope_tables(held, cfg.head_dim, cfg.rope_theta)
                  if cfg.position == "rope" else None}
         for name, kind in cfg.attention_kinds:
             ropes[name] = ropes["attention"] if kind.rope is None else \
-                kind.rope.tables(seq, cfg.head_dim)
+                kind.rope.tables(held, cfg.head_dim)
+        if cfg.block_diffusion:
+            ropes = {name: tables and tuple(
+                jnp.concatenate([table, table]) for table in tables)
+                     for name, tables in ropes.items()}
 
         def pass_end(stack, x):
             """The final norm, and the exit gate's logit on the normed

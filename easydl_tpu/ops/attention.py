@@ -49,6 +49,7 @@ from easydl_tpu.core.mesh_shapes import BATCH_AXES
 from easydl_tpu.ops import platform
 from easydl_tpu.ops.flash_attention import (
     MAX_BLOCK,
+    BlockDiffusion,
     choose_blocks,
     flash_attention,
 )
@@ -70,15 +71,20 @@ def _reference_attention(
     scale: float,
     segment_ids: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    mask: Optional[BlockDiffusion] = None,
 ) -> jax.Array:
     """XLA-fused reference path: einsum → mask → softmax → einsum.
 
     fp32 softmax accumulation regardless of input dtype (bf16-safe).
     ``window`` (causal only) as the kernels have it: query i sees the
-    ``window`` keys up to its own.
+    ``window`` keys up to its own. ``mask``: block diffusion's, written out
+    (``BlockDiffusion.dense``), as the kernels have it.
     """
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     fully_masked = None
+    if mask is not None:
+        logits = jnp.where(mask.dense()[None, None], logits,
+                           jnp.finfo(jnp.float32).min)
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), jnp.bool_), k=s_k - s_q)
@@ -201,6 +207,7 @@ def multihead_attention(
     rope: Optional[tuple] = None,
     rotary_dim: Optional[int] = None,
     window: Optional[int] = None,
+    mask: Optional[BlockDiffusion] = None,
 ) -> jax.Array:
     """Attention over [batch, seq, heads, head_dim] tensors (``v``'s head
     size may be its own, and is the result's).
@@ -215,12 +222,27 @@ def multihead_attention(
         all of them).
       window: with ``causal``, query i sees only the ``window`` keys up to
         its own; both paths take the same window.
+      mask: without ``causal``, ``ops/flash_attention.py BlockDiffusion``:
+        the rows are a sequence's ``[noised || clean]`` halves; both paths
+        take the same mask, and ``rope``'s tables carry each half's
+        positions.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if window is not None and not causal:
         raise ValueError("attention: a window needs causal=True")
+    if mask is not None and (causal or window is not None or segment_ids
+                             is not None or q.shape[1] != 2 * mask.seam
+                             or k.shape[1] != 2 * mask.seam):
+        raise ValueError(
+            f"attention: {mask} stands alone over 2 x {mask.seam} rows of q "
+            f"and k; got causal={causal}, window={window}, segment_ids "
+            f"{'given' if segment_ids is not None else 'None'}, "
+            f"{q.shape[1]} / {k.shape[1]} rows (multihead_attention refuses "
+            f"it)")
     banded = "" if window is None else f", window {window}"
+    if mask is not None:
+        banded = f", {mask}"
     rotary_dim = rotary_dim or q.shape[-1]
     if impl == "auto":
         impl = "flash" if platform.on_tpu() else "reference"
@@ -235,7 +257,7 @@ def multihead_attention(
         if segment_ids is not None:
             why = "segment mask requested"
         elif choose_blocks(q.shape[1], k.shape[1], causal,
-                           window=window) is None:
+                           window=window, mask=mask) is None:
             # the wrap below never splits the sequence: asked once, here
             why = (f"lengths q={q.shape[1]} k={k.shape[1]} have no block "
                    f"divisor <= {MAX_BLOCK}/{MAX_BLOCK}")
@@ -258,7 +280,7 @@ def multihead_attention(
                     (q, head_dim), (k, head_dim), (v, value_dim)))
                 return flat(flash_attention(q, *_repeat_kv(q, k, v),
                                             causal=causal, scale=scale,
-                                            window=window))
+                                            window=window, mask=mask))
 
             return _per_shard(kernel, q, k, len(tables))(
                 flat(q), flat(k), flat(v), *tables).reshape(
@@ -272,5 +294,5 @@ def multihead_attention(
         q, k = (apply_rope(x, *rope, rot=rotary_dim) for x in (q, k))
     return _reference_attention(
         q, *_repeat_kv(q, k, v), causal=causal, scale=scale,
-        segment_ids=segment_ids, window=window
+        segment_ids=segment_ids, window=window, mask=mask
     )

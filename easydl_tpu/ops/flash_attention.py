@@ -139,6 +139,41 @@ a sub-block's whole band in hand the forward's softmax is one pass (max,
 sees its own key, so no row is dead); its mathematics, its float32 values
 and the two roundings are the looped kernels'.
 
+The block mask. A step trained by diffusion over blocks runs each sequence
+as ``[noised || clean]``, twice its tokens in rows, under a mask that is
+neither causal nor a window (:class:`BlockDiffusion`: a block length and
+where the halves meet — ONE description, never a dense array): a noised row
+sees its own block of the noised half both ways and the clean half's blocks
+before its own, a clean row the clean half by blocks, nothing sees the noised
+half from outside its block. ``seam² + seam · block`` of the ``4 seam²`` pairs
+are live, half of what a causal mask over as many rows keeps, so a kernel that
+only MASKED the dead ones would make twice the products. It is ONE call over
+the ``2 seam`` rows on the looped kernels above (and the unrolled pair at a
+test's sizes), all three under one square block that divides a half and is
+whole mask blocks, so that a block pair's place under the mask is its two
+block indices' (``_bd_k_blocks``, ``_bd_q_blocks``): with ``n`` blocks a half a
+Q-block walks the clean blocks before its own position's with no mask, the
+clean block AT its position under the rule ``upto`` (strictly, for a noised
+Q-block) and, if it is noised, its own block of the noised half under ``own``
+— ``n² + 2 n`` of ``4 n²`` pairs, 288 of 1,024 at 16,384 rows in blocks of 512
+where causal keeps 528; no other pair is visited, and the backward's K-block
+walks the mirror. A pair on one of the three diagonals is placed tile by tile
+as PR 49's masked pairs are: of the noised half's own pair only the four
+diagonal tiles of 128 hold a live pair (the one-kernel backward walks those
+four alone too), of the other two ten of sixteen, four of them crossed; a
+tile wholly dead is not computed, one wholly live takes no mask, a crossed
+one compares its keys' and queries' block numbers (a shift). Where one loop
+serves both halves the rule's strictness is a traced number. The form not
+taken — the noised rows' two parts, a rectangle over the clean blocks before
+and a ``block x block`` square, joined by their ``lse`` — would write and
+read ``out`` and ``lse`` of the noised half twice and run a merge pass for
+what the walk above gets from two loop bounds. The mathematics, the float32
+values and the two roundings are the looped kernels'; the calls carry names
+of their own (``bd_fwd``, ``bd_bwd``; unrolled ``bd_bwd_dq``, ``bd_bwd_dkv``),
+hold ``2 seam`` rows of k and v (q, O and dO) resident — the two-size
+allowance of VMEM — and refuse ``causal``, a ``window`` and two head sizes
+beside the mask.
+
 Layout. Public shapes are the models' ``[batch, seq, heads, head_dim]``; the
 kernels take q, k, v, O, dO and give O, dq, dk, dv as ``[batch, seq,
 heads·head_dim]`` — the same bytes in the same order, and the layout a
@@ -248,6 +283,46 @@ BAND_SUB = 256
 _BAND_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
 
 
+class BlockDiffusion(NamedTuple):
+    """The mask of a step trained by diffusion over blocks (BD3-LM,
+    arXiv:2503.09573), over ``[noised || clean]`` rows of one sequence:
+    rows ``[0, seam)`` are the noised half, ``[seam, 2 seam)`` the clean
+    one, a row's position is ``r mod seam`` and its block ``position //
+    block``. A noised query sees the noised keys of its own block (both
+    ways) and the clean keys of the blocks before its own; a clean query
+    the clean keys of the blocks up to its own (its own both ways); nothing
+    sees the noised half from outside its block. Every row sees itself, and
+    ``seam² + seam · block`` of the ``4 seam²`` pairs are live."""
+    block: int
+    seam: int
+
+    def pairs(self) -> int:
+        """(query, key) pairs the mask keeps, a head and sequence."""
+        return self.seam * (self.seam + self.block)
+
+    def dense(self):
+        """The mask written out, ``[2 seam, 2 seam]`` bool (query, key):
+        what the XLA reference path and the tests hold the kernels to."""
+        row = jnp.arange(2 * self.seam)
+        clean, blk = row >= self.seam, row % self.seam // self.block
+        q_clean, k_clean = clean[:, None], clean[None, :]
+        q_blk, k_blk = blk[:, None], blk[None, :]
+        return jnp.where(
+            q_clean, k_clean & (k_blk <= q_blk),
+            jnp.where(k_clean, k_blk < q_blk, k_blk == q_blk))
+
+    def block_pairs(self, block: int) -> Tuple[int, int]:
+        """``(visited, all)`` pairs of ``block x block`` kernel blocks a
+        head: with ``n = seam / block`` blocks a half the clean half's
+        triangle with its diagonal, the noised rows' clean blocks before
+        their own and the pair on that offset diagonal, and the noised
+        half's diagonal — ``n² + 2 n`` of ``4 n²`` (288 of 1,024 at 16,384
+        rows in blocks of 512); where a kernel block IS the mask's block
+        the offset diagonal is dead too and ``n² + n`` are visited."""
+        n = self.seam // block
+        return n * n + (n if block == self.block else 2 * n), 4 * n * n
+
+
 #: dot_general dimension numbers: ``A Bᵀ`` (both contract their last
 #: dimension) and the plain ``A B``.
 _NT = (((1,), (1,)), ((), ()))
@@ -272,7 +347,8 @@ def _pick_block(s: int, target: int) -> Optional[int]:
 def choose_blocks(s_q: int, s_k: int, causal: bool,
                   block_q: Optional[int] = None,
                   block_k: Optional[int] = None,
-                  window: Optional[int] = None
+                  window: Optional[int] = None,
+                  mask: Optional[BlockDiffusion] = None,
                   ) -> Optional[Union[Blocks, Bands]]:
     """Block sizes per kernel from what the call can see; a caller's
     ``block_q`` / ``block_k`` hold for all three. None when a length has no
@@ -345,10 +421,24 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     at 256 x 256: fewer, fuller block pairs win over the band's masked
     corners); dk/dv takes K-blocks of 256 (512 x 512 does not fit its VMEM
     beside the whole sequence's q, O and dO, which the looped kernel holds,
-    and 512 x 256 beats 256 x 256 by 1.5 ms)."""
+    and 512 x 256 beats 256 x 256 by 1.5 ms).
+
+    Under a ``mask`` (:class:`BlockDiffusion`) all three kernels take ONE
+    square block that divides a half and is whole mask blocks, so that a
+    block pair's place under the mask is its two block indices': a pair is
+    wholly live, wholly dead (never visited: ``BlockDiffusion.block_pairs``)
+    or one of the three diagonals, which a kernel masks tile by tile."""
     def pick(target, target_k=None):
         return (_pick_block(s_q, block_q or target),
                 _pick_block(s_k, block_k or target_k or target))
+
+    if mask is not None:
+        if not (s_q == s_k == 2 * mask.seam and mask.seam % mask.block == 0):
+            return None
+        side = _pick_block(mask.seam, block_q or block_k or MAX_BLOCK)
+        if side is None or side % mask.block or (block_k or side) != side:
+            return None
+        return ((side, side),) * 3
 
     if window is not None and s_q == s_k:
         band = _band(s_q, window, block_q, block_k)
@@ -454,6 +544,128 @@ def _scores_t(k, q, s_scale: float, bound, window: Optional[int] = None):
             seen = seen & (keys - queries > bound - window)
         st = jnp.where(seen, st, NEG_INF)
     return st
+
+
+def _where(cond, a, b):
+    """``a if cond else b`` for Python numbers, ``jnp.where`` for traced."""
+    if isinstance(cond, (bool, int)):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+#: the rules a block pair on one of the block mask's three diagonals is
+#: masked by, as ``(kind, strict)``: ``("own", 0)`` — a noised block's own
+#: keys: key block == query block — and ``("upto", strict)`` — the clean
+#: keys under a clean (``strict`` 0: key block <= query block) or a noised
+#: query block (``strict`` 1: key block < query block); ``strict`` may be
+#: traced, where one loop's body serves both halves.
+_OWN = ("own", 0)
+
+
+def _bd_tile(rule, block: int, k_at: int, k_n: int, q_at: int,
+             q_n: int) -> Optional[bool]:
+    """What the tile of keys ``[k_at, k_at + k_n)`` x queries ``[q_at, q_at
+    + q_n)`` of a diagonal block pair (rows counted from the pair's first,
+    which the two sides share by position) is under ``rule``: None — no
+    query of it sees a key of it: not computed —, False — every query sees
+    every key: no mask —, True — crossed: masked. Under a traced ``strict``
+    a tile is dead or live only where it is under both values."""
+    k_lo, k_hi = k_at // block, (k_at + k_n - 1) // block
+    q_lo, q_hi = q_at // block, (q_at + q_n - 1) // block
+    kind, strict = rule
+    if kind == "own":
+        if k_hi < q_lo or k_lo > q_hi:
+            return None
+        return not k_lo == k_hi == q_lo == q_hi
+    least, most = (strict, strict) if isinstance(strict, int) else (0, 1)
+    if k_lo + least > q_hi:
+        return None
+    return not k_hi + most <= q_lo
+
+
+def _bd_pair(mask: Optional["BlockDiffusion"], masked, block_k: int,
+             block_q: int) -> Optional[bool]:
+    """:func:`_bd_tile` of a WHOLE block pair (the unrolled side, which
+    takes a pair as one tile); False without a block mask or a rule."""
+    return mask is not None and masked and _bd_tile(
+        masked, mask.block, 0, block_k, 0, block_q)
+
+
+def _bd_tiles(rule, block: int, side: int, tile: int):
+    """``[(k_at, q_at, crossed)]``: the tiles of ``tile x tile`` of a
+    diagonal pair of ``side x side`` that hold a live pair."""
+    found = [(k_at, q_at, _bd_tile(rule, block, k_at, tile, q_at, tile))
+             for k_at in range(0, side, tile) for q_at in range(0, side, tile)]
+    return [(k_at, q_at, crossed) for k_at, q_at, crossed in found
+            if crossed is not None]
+
+
+def _bd_mask(st, rule, block: int, k_at: int, q_at: int):
+    """The score tile ``st`` (``[keys, queries]``, the first key ``k_at``
+    and the first query ``q_at`` rows into their diagonal pair) with what
+    ``rule`` hides set to NEG_INF."""
+    def blocks(axis, at):
+        rows = jax.lax.broadcasted_iota(jnp.int32, st.shape, axis) + at
+        if block & (block - 1) == 0:  # a shift where it is a power of two
+            return rows >> (block.bit_length() - 1)
+        return rows // block
+
+    kind, strict = rule
+    keys, queries = blocks(0, k_at), blocks(1, q_at)
+    seen = keys == queries if kind == "own" else keys + strict <= queries
+    return jnp.where(seen, st, NEG_INF)
+
+
+def _flag(cond):
+    """0 / 1 of a comparison: a Python number's, or a traced one's."""
+    return int(cond) if isinstance(cond, bool) else cond.astype(jnp.int32)
+
+
+def _bd_k_blocks(body, carry, qb, *, mask: BlockDiffusion, side: int,
+                 unroll: bool):
+    """``body(kb, carry, masked=)`` over the K-blocks that Q-block ``qb``
+    (of ``side`` rows) sees under the block mask, ``n`` blocks a half: the
+    clean blocks before its own position's take no mask (``masked`` False);
+    the clean block AT its position is masked ``upto`` — strictly for a
+    noised Q-block, and where a kernel block is one mask block not visited
+    by it at all; a noised Q-block sees its own block of the noised half,
+    masked ``own``. Nothing else is visited."""
+    n = mask.seam // side
+    noised = _flag(qb < n)
+    at = qb - n * (1 - noised)  # the block's place in its half
+    carry = _loop(n, n + at, functools.partial(body, masked=False), carry,
+                  unroll=unroll)
+    if side == mask.block:
+        carry = _loop(n + at, n + at + 1 - noised, functools.partial(
+            body, masked=("upto", 0)), carry, unroll=unroll)
+    else:
+        carry = body(n + at, carry, masked=("upto", noised))
+    return _loop(qb, qb + noised, functools.partial(body, masked=_OWN),
+                 carry, unroll=unroll)
+
+
+def _bd_q_blocks(body, carry, kb, *, mask: BlockDiffusion, side: int,
+                 unroll: bool):
+    """:func:`_bd_k_blocks`' mirror, the Q-blocks that see K-block ``kb``: a
+    noised K-block is seen by its own Q-block alone (``own``); a clean one
+    by the noised Q-block at its position (``upto``, strictly) and the clean
+    one there (``upto``), and whole by the later Q-blocks of both halves —
+    ``t`` counts through both stretches, so that one body serves each."""
+    n = mask.seam // side
+    clean = _flag(kb >= n)
+    at = kb - n * clean
+    carry = _loop(kb, kb + 1 - clean, functools.partial(body, masked=_OWN),
+                  carry, unroll=unroll)
+    if side == mask.block:  # the strict pair is dead, the other whole
+        carry = _loop(kb, kb + clean, functools.partial(
+            body, masked=("upto", 0)), carry, unroll=unroll)
+    else:
+        carry = _loop(0, 2 * clean, lambda t, c: body(
+            at + t * n, c, masked=("upto", 1 - t)), carry, unroll=unroll)
+    later = n - at - 1
+    return _loop(0, 2 * later * clean, lambda t, c: body(
+        at + 1 + t + _where(t >= later, n - later, 0), c, masked=False),
+        carry, unroll=unroll)
 
 
 def _over_k_blocks(body, carry, q_start, *, block_q: int, block_k: int, n_k: int,
@@ -566,12 +778,17 @@ _TWO_SIZE_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 
 
 def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
-               params: Optional[pltpu.CompilerParams] = None):
+               params: Optional[pltpu.CompilerParams] = None,
+               mask: Optional[BlockDiffusion] = None):
     """A looped kernel's ``name=`` (and compiler parameters: the call's own
     ``params``, else the two-size allowance) by what it computes:
     ``flash_*``, ``swa_*`` under a window, ``mla_*`` where the scores' and
-    the values' head sizes differ."""
-    if d != dv:
+    the values' head sizes differ, ``bd_*`` under the block mask (whose
+    forward and unrolled backward hold ``2 seam`` rows of k and v, or of q,
+    O and dO, whole: the two-size allowance of VMEM)."""
+    if mask is not None:
+        named = dict(name=f"bd_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
+    elif d != dv:
         named = dict(name=f"mla_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
     else:
         named = dict(name=f"{'flash' if window is None else 'swa'}_{kernel}")
@@ -655,7 +872,7 @@ def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, *sums,
     head_dim: int, value_dim: int, block_q: int, block_k: int,
     causal: bool, scale: float, offset: int, unroll: bool,
-    window: Optional[int],
+    window: Optional[int], mask: Optional[BlockDiffusion] = None,
 ):
     # q_ref: [cell rows, cell heads · d], o_ref: [cell rows, cell heads ·
     # dv]; k_ref: [S_k, cell heads · d], v_ref: [S_k, cell heads · dv];
@@ -676,10 +893,15 @@ def _fwd_kernel(
         q_start = cell_start + j * block_q
         qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
 
-        def whole(kb, carry, *, masked: bool):
+        def whole(kb, carry, *, masked):
             # the unrolled side: the pair as one tile, a K-block for every
             # head of the cell — V is turned once for all of them, and their
-            # chains are independent work to interleave
+            # chains are independent work to interleave. ``masked``: whether
+            # the diagonal crosses the pair or, under the block mask, the
+            # rule of the pair's diagonal
+            crossed = _bd_pair(mask, masked, block_k, block_q)
+            if crossed is None:  # one mask block a kernel block: dead
+                return carry
             k_start = _block_start(kb, block_k)
             vt_all = v_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
             out = []
@@ -689,12 +911,14 @@ def _fwd_kernel(
                 k = k_ref[pl.ds(k_start, block_k), cols]
                 vt = vt_all[v_cols]  # [dv, block_k]: the head's sublanes
                 st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start if masked else None,
-                               window)
+                               q_start + offset - k_start
+                               if masked and mask is None else None, window)
+                if crossed:
+                    st = _bd_mask(st, masked, mask.block, 0, 0)
                 out.append(_online_softmax(state, st, vt))
             return tuple(out)
 
-        def tiled(kb, carry, *, masked: bool):
+        def tiled(kb, carry, *, masked):
             # the looped side: the pair in tiles of [sub_k, sub_q], key tile
             # after key tile, under each the cell's heads and their Q tiles,
             # the scores' products ``ahead`` tiles in front of their softmax
@@ -711,10 +935,20 @@ def _fwd_kernel(
                      for qj in range(0, block_q, sub_q)]
             # the diagonal's place in a masked pair is static: a tile wholly
             # above it is not computed, one wholly below it takes no mask
-            placed = masked and diagonal
+            placed = masked and mask is None and diagonal
             if placed:
                 steps = [(ki, g, qj) for ki, g, qj in steps
                          if ki <= qj + sub_q - 1]
+            # under the block mask a diagonal pair's tiles are placed too:
+            # one no query of which sees a key is not computed, one wholly
+            # live takes no mask, a crossed one its rule
+            crossed = {}
+            if mask is not None and masked:
+                crossed = {(ki, qj): _bd_tile(masked, mask.block, ki, sub_k,
+                                              qj, sub_q)
+                           for ki, _, qj in steps}
+                steps = [(ki, g, qj) for ki, g, qj in steps
+                         if crossed[ki, qj] is not None]
             last = {(g, qj): (ki, g, qj) for ki, g, qj in steps}
             state, vts = {}, {}
 
@@ -725,11 +959,15 @@ def _fwd_kernel(
             def scores(step):
                 ki, g, qj = step
                 q, s_scale = qs[g]
-                edge = q_start + offset - k_start + qj - ki if masked else None
+                edge = q_start + offset - k_start + qj - ki \
+                    if masked and mask is None else None
                 if placed and ki + sub_k - 1 <= qj:
                     edge = None
-                return _scores_t(k_ref[keys(ki), heads[g]], q[qj:qj + sub_q],
-                                 s_scale, edge, window)
+                st = _scores_t(k_ref[keys(ki), heads[g]], q[qj:qj + sub_q],
+                               s_scale, edge, window)
+                if crossed.get((ki, qj)):
+                    st = _bd_mask(st, masked, mask.block, ki, qj)
+                return st
 
             def softmax(step, st):
                 ki, g, qj = step
@@ -757,10 +995,16 @@ def _fwd_kernel(
                 for ref, value in zip(sums, start):
                     ref[g] = value
             carry = None
-        carry = _over_k_blocks(
-            whole if unroll else tiled, carry, q_start, block_q=block_q,
-            block_k=block_k, n_k=n_k, offset=offset, causal=causal,
-            unroll=unroll, window=window)
+        if mask is not None:
+            carry = _bd_k_blocks(
+                whole if unroll else tiled, carry,
+                j if unroll else pl.program_id(2), mask=mask, side=block_q,
+                unroll=unroll)
+        else:
+            carry = _over_k_blocks(
+                whole if unroll else tiled, carry, q_start, block_q=block_q,
+                block_k=block_k, n_k=n_k, offset=offset, causal=causal,
+                unroll=unroll, window=window)
         if not unroll:
             carry = tuple(tuple(ref[g] for ref in sums)
                           for g in range(len(heads)))
@@ -785,7 +1029,8 @@ def _fwd_kernel(
 
 
 def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
-         block_k: int, interpret: bool, window: Optional[int] = None):
+         block_k: int, interpret: bool, window: Optional[int] = None,
+         mask: Optional[BlockDiffusion] = None):
     """The forward call; where it is looped, a ``jax.jit`` of its own. The
     body walked in tiles is sixteen times the operations of the pair as one
     tile, a ``pallas_call``'s body is traced and lowered once a USE (the
@@ -801,12 +1046,14 @@ def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
     unroll = _unrolled(s_q // block_q, s_k // block_k)
     return (_fwd_call if unroll else _fwd_looped)(
         q, k, v, heads=heads, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret, window=window, unroll=unroll)
+        block_k=block_k, interpret=interpret, window=window, unroll=unroll,
+        mask=mask)
 
 
 def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
               block_q: int, block_k: int, interpret: bool,
-              window: Optional[int], unroll: bool):
+              window: Optional[int], unroll: bool,
+              mask: Optional[BlockDiffusion] = None):
     b, s_q, width = q.shape
     s_k, d, dv = k.shape[1], width // heads, v.shape[2] // heads
     n_q, n_k = s_q // block_q, s_k // block_k
@@ -818,7 +1065,7 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
     kernel = functools.partial(
         _fwd_kernel, head_dim=d, value_dim=dv, block_q=block_q,
         block_k=block_k, causal=causal, scale=scale, offset=s_k - s_q,
-        unroll=unroll, window=window,
+        unroll=unroll, window=window, mask=mask,
     )
 
     def mine(size):
@@ -848,14 +1095,14 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
             pltpu.VMEM((cell, 1, block_q), jnp.float32),
             pltpu.VMEM((cell, dv, block_q), jnp.float32)],
         interpret=interpret,
-        **_call_name("fwd", d, dv, window),
+        **_call_name("fwd", d, dv, window, mask=mask),
     )(q, k, v)
     return out, lse
 
 
 _fwd_looped = jax.jit(_fwd_call, static_argnames=(
     "heads", "causal", "scale", "block_q", "block_k", "interpret", "window",
-    "unroll"))
+    "unroll", "mask"))
 
 
 # ---------------------------------------------------------------------------
@@ -900,9 +1147,14 @@ def _bwd_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
     dq_sum_ref, *, head_dim: int, value_dim: int, block_q: int, causal: bool,
     scale: float, offset: int, window: Optional[int],
+    mask: Optional[BlockDiffusion] = None,
 ):
     """The looped backward, one K-block a grid cell: dq, dk and dv from ONE
-    score tile, ``exp``, ``dP`` and ``delta`` a block pair."""
+    score tile, ``exp``, ``dP`` and ``delta`` a block pair. Under the block
+    mask a K-block walks the Q-blocks that see it (:func:`_bd_q_blocks`), a
+    pair on one of the mask's diagonals in the tiles that hold a live pair
+    (``diagonal``): the noised half's own pair is its four diagonal tiles
+    of 128 alone, the others the pair as one masked tile."""
     # q_ref, dq_ref: [S_q, cell heads · d]; o_ref, do_ref: [S_q, cell heads ·
     # dv]; k_ref, dk_ref: [block_k, cell heads · d]; v_ref, dv_ref: [block_k,
     # cell heads · dv]; lse_ref: [cell heads, n_q, 1, block_q]; dq_sum_ref
@@ -943,12 +1195,61 @@ def _bwd_kernel(
             out.append((dk + _dot(dst, q, _NN), dv_new))
         return tuple(out)
 
-    carry = _over_q_blocks(
-        body, tuple((jnp.zeros((block_k, d), jnp.float32),
-                     jnp.zeros((block_k, value_dim), jnp.float32))
-                    for _ in heads),
-        k_start, block_q=block_q, block_k=block_k, n_q=n_q, offset=offset,
-        causal=causal, unroll=False, window=window)
+    def diagonal(qb, carry, *, masked):
+        q_start = _block_start(qb, block_q)
+        tile = _FWD_TILE if masked == _OWN and block_q % _FWD_TILE == 0 \
+            else block_q
+        tiles = _bd_tiles(masked, mask.block, block_q, tile)
+
+        def rows_at(q_at):
+            return pl.ds(pl.multiple_of(q_start + q_at, tile), tile)
+
+        # a tile's statistics are read and made by tile: a lane slice of a
+        # [1, block_q] VALUE is a layout Mosaic does not broadcast from
+        deltas = {q_at: _delta_rows(do_ref[rows_at(q_at), :],
+                                    o_ref[rows_at(q_at), :], value_dim)
+                  for q_at in sorted({q_at for _, q_at, _ in tiles})}
+        out = []
+        for g, (cols, v_cols, (k, s_scale), v, (dk, dv)) in enumerate(
+                zip(heads, v_heads, ks, vs, carry)):
+            dks, dvs = {}, {}
+            for k_at, q_at, crossed in tiles:
+                there, mine = slice(k_at, k_at + tile), slice(q_at, q_at + tile)
+                rows = rows_at(q_at)
+                q, do = q_ref[rows, cols], do_ref[rows, v_cols]
+                st = _scores_t(k[there], q, s_scale, None)
+                if crossed:
+                    st = _bd_mask(st, masked, mask.block, k_at, q_at)
+                pt = jnp.exp(st - lse_ref[g, qb, :, mine])
+                dvs[k_at] = dvs.get(k_at, 0.0) + _dot(
+                    pt.astype(do.dtype), do, _NN)
+                dst = (pt * (_dot(v[there], do, _NT) - deltas[q_at][g])
+                       ).astype(q.dtype)
+                dq_sum_ref[qb, cols, mine] += _dot(kt_all[cols, there], dst,
+                                                   _NN)
+                dks[k_at] = dks.get(k_at, 0.0) + _dot(dst, q, _NN)
+
+            def by_row(parts, width):  # a part a tile of keys, in order
+                return _side_by_side([
+                    parts[at] if at in parts
+                    else jnp.zeros((tile, width), jnp.float32)
+                    for at in range(0, block_k, tile)], 0)
+
+            out.append((dk + by_row(dks, d), dv + by_row(dvs, value_dim)))
+        return tuple(out)
+
+    carry = tuple((jnp.zeros((block_k, d), jnp.float32),
+                   jnp.zeros((block_k, value_dim), jnp.float32))
+                  for _ in heads)
+    if mask is not None:
+        carry = _bd_q_blocks(
+            lambda qb, carry, *, masked: (diagonal if masked else body)(
+                qb, carry, masked=masked),
+            carry, kb, mask=mask, side=block_q, unroll=False)
+    else:
+        carry = _over_q_blocks(
+            body, carry, k_start, block_q=block_q, block_k=block_k, n_q=n_q,
+            offset=offset, causal=causal, unroll=False, window=window)
     # q and k entered the products unscaled (the scale sat on k or the
     # scores).
     dk_ref[...] = (_side_by_side([dk for dk, _ in carry], 1) * scale
@@ -969,6 +1270,7 @@ def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
     *, head_dim: int, value_dim: int, block_q: int, block_k: int,
     causal: bool, scale: float, offset: int, window: Optional[int],
+    mask: Optional[BlockDiffusion] = None,
 ):
     # the unrolled side: a grid cell takes the whole sequence of its heads;
     # lse_ref: [cell heads, n_q, 1, block_q]
@@ -985,7 +1287,10 @@ def _bwd_dq_kernel(
         lses = [lse_ref[g, j] for g in range(len(heads))]
         deltas = _delta_rows(do_ref[rows, :], o_ref[rows, :], dv)
 
-        def body(kb, dq_ts, *, masked: bool):
+        def body(kb, dq_ts, *, masked):
+            crossed = _bd_pair(mask, masked, block_k, block_q)
+            if crossed is None:  # one mask block a kernel block: dead
+                return dq_ts
             k_start = kb * block_k
             kt_all = k_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
             out = []
@@ -994,17 +1299,24 @@ def _bwd_dq_kernel(
                 k = k_ref[pl.ds(k_start, block_k), cols]
                 v = v_ref[pl.ds(k_start, block_k), v_cols]
                 st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start if masked else None,
-                               window)
+                               q_start + offset - k_start
+                               if masked and mask is None else None, window)
+                if crossed:
+                    st = _bd_mask(st, masked, mask.block, 0, 0)
                 pt = jnp.exp(st - lse)
                 dst = pt * (_dot(v, do, _NT) - delta)
                 out.append(dq_t + _dot(kt_all[cols], dst.astype(k.dtype), _NN))
             return tuple(out)
 
-        dq_ts = _over_k_blocks(
-            body, tuple(jnp.zeros((d, block_q), jnp.float32) for _ in heads),
-            q_start, block_q=block_q, block_k=block_k, n_k=n_k, offset=offset,
-            causal=causal, unroll=True, window=window)
+        dq_ts = tuple(jnp.zeros((d, block_q), jnp.float32) for _ in heads)
+        if mask is not None:
+            dq_ts = _bd_k_blocks(body, dq_ts, j, mask=mask, side=block_q,
+                                 unroll=True)
+        else:
+            dq_ts = _over_k_blocks(
+                body, dq_ts, q_start, block_q=block_q, block_k=block_k,
+                n_k=n_k, offset=offset, causal=causal, unroll=True,
+                window=window)
         dq_ref[rows, :] = (_side_by_side(list(dq_ts), 0) * scale
                            ).T.astype(dq_ref.dtype)
 
@@ -1013,6 +1325,7 @@ def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
     *, head_dim: int, value_dim: int, block_q: int, block_k: int,
     causal: bool, scale: float, offset: int, window: Optional[int],
+    mask: Optional[BlockDiffusion] = None,
 ):
     # the unrolled side, as the dq kernel
     s_k, lanes = dk_ref.shape
@@ -1027,7 +1340,10 @@ def _bwd_dkv_kernel(
         ks = [_fold_scale(k_ref[rows, cols], scale) for cols in heads]
         vs = [v_ref[rows, cols] for cols in v_heads]
 
-        def body(qb, carry, *, masked: bool):
+        def body(qb, carry, *, masked):
+            crossed = _bd_pair(mask, masked, block_k, block_q)
+            if crossed is None:  # one mask block a kernel block: dead
+                return carry
             q_start = qb * block_q
             q_rows = pl.ds(q_start, block_q)
             if qb not in stats:
@@ -1040,20 +1356,27 @@ def _bwd_dkv_kernel(
                 q = q_ref[q_rows, cols]
                 do = do_ref[q_rows, v_cols]
                 st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start if masked else None,
-                               window)
+                               q_start + offset - k_start
+                               if masked and mask is None else None, window)
+                if crossed:
+                    st = _bd_mask(st, masked, mask.block, 0, 0)
                 pt = jnp.exp(st - lse)
                 dv_new = dv + _dot(pt.astype(do.dtype), do, _NN)
                 dst = pt * (_dot(v, do, _NT) - delta)
                 out.append((dk + _dot(dst.astype(q.dtype), q, _NN), dv_new))
             return tuple(out)
 
-        carry = _over_q_blocks(
-            body, tuple((jnp.zeros((block_k, d), jnp.float32),
-                         jnp.zeros((block_k, value_dim), jnp.float32))
-                        for _ in heads),
-            k_start, block_q=block_q, block_k=block_k, n_q=n_q, offset=offset,
-            causal=causal, unroll=True, window=window)
+        carry = tuple((jnp.zeros((block_k, d), jnp.float32),
+                       jnp.zeros((block_k, value_dim), jnp.float32))
+                      for _ in heads)
+        if mask is not None:
+            carry = _bd_q_blocks(body, carry, j, mask=mask, side=block_k,
+                                 unroll=True)
+        else:
+            carry = _over_q_blocks(
+                body, carry, k_start, block_q=block_q, block_k=block_k,
+                n_q=n_q, offset=offset, causal=causal, unroll=True,
+                window=window)
         # q entered the products unscaled (the scale sat on k or the scores).
         dk_ref[rows, :] = (_side_by_side([dk for dk, _ in carry], 1) * scale
                            ).astype(dk_ref.dtype)
@@ -1070,13 +1393,13 @@ _DEFAULT_VMEM = 16 << 20
 def _bwd(
     q, k, v, out, lse, do, *, heads: int, causal: bool, scale: float,
     dq_blocks: Tuple[int, int], dkv_blocks: Tuple[int, int], interpret: bool,
-    window: Optional[int] = None,
+    window: Optional[int] = None, mask: Optional[BlockDiffusion] = None,
 ):
     b, s_q, width = q.shape
     s_k, d, vd = k.shape[1], width // heads, v.shape[2] // heads
     item = q.dtype.itemsize
     static = dict(head_dim=d, value_dim=vd, causal=causal, scale=scale,
-                  offset=s_k - s_q, window=window)
+                  offset=s_k - s_q, window=window, mask=mask)
 
     def whole(s, cell, size):
         return pl.BlockSpec((None, s, cell * size), lambda b, h, i: (b, 0, h))
@@ -1113,7 +1436,7 @@ def _bwd(
             interpret=interpret,
             **_call_name("bwd", d, vd, window, pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
-                vmem_limit_bytes=held + _DEFAULT_VMEM)),
+                vmem_limit_bytes=held + _DEFAULT_VMEM), mask=mask),
         )(q, k, v, out, do, lse_in)
 
     # unrolled (at most _UNROLL_PAIRS block pairs a head): one grid cell a
@@ -1139,7 +1462,7 @@ def _bwd(
         out_specs=mine(s_q, cell, d),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        **_call_name("bwd_dq", d, vd, window),
+        **_call_name("bwd_dq", d, vd, window, mask=mask),
     )(q, k, v, out, do, lse_in)
 
     block_q, block_k = dkv_blocks
@@ -1159,7 +1482,7 @@ def _bwd(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        **_call_name("bwd_dkv", d, vd, window),
+        **_call_name("bwd_dkv", d, vd, window, mask=mask),
     )(q, k, v, out, do, lse_in)
     return dq, dk, dv
 
@@ -1497,14 +1820,15 @@ def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, heads, causal, scale, blocks: Blocks, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, heads, causal, scale, blocks: Blocks, interpret, window,
+           mask=None):
     return _flash_fwd(q, k, v, heads, causal, scale, blocks, interpret,
-                      window)[0]
+                      window, mask)[0]
 
 
 def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
-               window):
+               window, mask=None):
     if isinstance(blocks[0], Band):
         out, lse = _band_fwd(q, k, v, heads=heads, scale=scale,
                              band=blocks[0], window=window,
@@ -1513,7 +1837,7 @@ def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
         out, lse = _fwd(
             q, k, v, heads=heads, causal=causal, scale=scale,
             block_q=blocks[0][0], block_k=blocks[0][1], interpret=interpret,
-            window=window,
+            window=window, mask=mask,
         )
     # The kernel's column [B, H, S, 1] turned once into dense rows
     # [B, H, S]; both named HERE, so that the residuals below are the named
@@ -1524,12 +1848,13 @@ def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
     # differentiated the turn is dead code.
     out, lse = remat.name_flash(
         out, lse.reshape(lse.shape[:3]), s_k=k.shape[1],
-        head_dim=q.shape[-1] // heads, causal=causal, window=window)
+        head_dim=q.shape[-1] // heads, causal=causal, window=window,
+        pairs=mask and mask.pairs())
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, res,
-               g):
+def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, mask,
+               res, g):
     q, k, v, out, lse = res
     if isinstance(blocks[1], Band):
         return _band_bwd(q, k, v, out, lse, g, heads=heads, scale=scale,
@@ -1538,7 +1863,7 @@ def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, res,
     return _bwd(
         q, k, v, out, lse, g, heads=heads, causal=causal, scale=scale,
         dq_blocks=blocks[1], dkv_blocks=blocks[2], interpret=interpret,
-        window=window,
+        window=window, mask=mask,
     )
 
 
@@ -1556,6 +1881,7 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: bool = False,
     window: Optional[int] = None,
+    mask: Optional[BlockDiffusion] = None,
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] tensors: the
     kernels' result, or ValueError where they cannot tile the lengths
@@ -1571,23 +1897,41 @@ def flash_attention(
     and the calls carry names of their own (``swa_fwd``, ``swa_bwd_dq``,
     ``swa_bwd_dkv``; looped, ``swa_bwd``).
 
+    ``mask`` (a :class:`BlockDiffusion`; without ``causal``): the rows are
+    one sequence's ``[noised || clean]`` halves under block diffusion's
+    mask, which is neither causal nor a window. Block pairs no row of which
+    sees a key are not visited, a tile of 128 x 128 wholly dead inside a
+    visited pair is not computed, and the calls carry names of their own
+    (``bd_fwd``, ``bd_bwd``; unrolled ``bd_bwd_dq``, ``bd_bwd_dkv``).
+
     ``block_q`` / ``block_k``, when passed, hold for every kernel; left out,
     each kernel's are chosen from what the call shows."""
     b, s, h, d = q.shape
     s_k, dv = k.shape[1], v.shape[-1]
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if mask is not None and (causal or window is not None):
+        raise ValueError(
+            f"flash attention: the block mask {mask} replaces causal="
+            f"{causal} and window={window}: it is neither (flash_attention "
+            f"refuses the pair)")
+    if mask is not None and dv != d:
+        raise NotImplementedError(
+            f"flash attention: the block mask {mask} with head sizes {d} / "
+            f"{dv} (flash_attention refuses the pair)")
     if window is not None and (not causal or window < 1):
         raise ValueError(f"flash attention: window={window} needs causal "
                          f"attention and at least one key")
     if window is not None and dv != d:
         raise NotImplementedError(
             f"flash attention: a window with head sizes {d} / {dv}")
-    blocks = choose_blocks(s, s_k, causal, block_q, block_k, window)
+    blocks = choose_blocks(s, s_k, causal, block_q, block_k, window, mask)
     if blocks is None:
         raise ValueError(
             f"flash attention: lengths q={s} k={s_k} have no block divisor "
-            f"<= {block_q or MAX_BLOCK}/{block_k or MAX_BLOCK}")
+            f"<= {block_q or MAX_BLOCK}/{block_k or MAX_BLOCK}"
+            + ("" if mask is None else
+               f" that is whole blocks of {mask} over 2 x {mask.seam} rows"))
     device = jax.devices()[0]
     how = "INTERPRETED" if interpret else "compiled"
     cuts = dict(zip(("fwd", "dq", "dkv"), blocks))
@@ -1618,6 +1962,9 @@ def flash_attention(
                   f"{jnp.dtype(q.dtype).name} operands to the MXU, blocks "
                   f"q/k {chosen}, over lengths {s}/{s_k}"
                   + (f" (window {window})" if window is not None else "")
+                  + ("" if mask is None else
+                     " ({0}: {1} of {2} block pairs visited)".format(
+                         mask, *mask.block_pairs(blocks[0][0])))
                   + f", {sizes}, on "
                   f"[batch, seq, heads·head_dim] = [{b}, {s}, {h * d}] with "
                   f"{tile} head(s) to a {lanes}")
@@ -1625,5 +1972,6 @@ def flash_attention(
     out = _flash(
         q.reshape(b, s, h * d), k.reshape(b, s_k, h * d),
         v.reshape(b, s_k, h * dv), h, causal, scale, blocks, interpret, window,
+        mask,
     )
     return out.reshape(b, s, h, dv)

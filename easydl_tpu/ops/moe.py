@@ -142,7 +142,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from easydl_tpu.core.mesh_shapes import BATCH_AXES
-from easydl_tpu.ops import platform
+from easydl_tpu.ops import platform, remat
 from easydl_tpu.utils.logging import get_logger, log_once
 
 log = get_logger("ops", "moe")
@@ -956,6 +956,12 @@ class MoeMlp(nn.Module):
     router_hidden: int = 0
     router_eps: float = 1e-5
     skip_choice: bool = False
+    #: the linear router's chosen experts are NAMED for a rematerialised
+    #: block to keep (``ops/remat.py ROUTED``) and the weights are read from
+    #: the scores at the kept choice: the backward weighs the experts the
+    #: pass ran, whatever a forward made again would choose (the stack
+    #: sets it under block diffusion; the others' programs stay theirs)
+    keep_routing: bool = False
     #: the linear router chooses by ``scores + b``, ``b`` a leaf
     #: ``router_bias [experts_total]`` at zero that takes no gradient (its
     #: load-driven update is a training recipe's and is not here)
@@ -1047,11 +1053,21 @@ class MoeMlp(nn.Module):
                     h, kernel, self.k, self.scaling,
                     **({} if bias is None else {"bias": bias}),
                     **({"softmax": True} if softmax else {}))
-                if softmax:
-                    share = jax.nn.softmax(logits, -1)
-                else:
-                    share = jax.nn.sigmoid(logits)
-                    share = share / jnp.sum(share, -1, keepdims=True)
+                scores = jax.nn.softmax(logits, -1) if softmax \
+                    else jax.nn.sigmoid(logits)
+                if self.keep_routing:
+                    chosen = remat.name(chosen, remat.ROUTED)
+                    # the scores at the kept choice, read by a compare
+                    # against the experts' numbers: a gather's transpose is
+                    # a scatter, which cost the chip 4% of a step here
+                    picked = chosen[..., None] == jnp.arange(
+                        self.experts_total)
+                    top = jnp.sum(jnp.where(picked, scores[:, None, :], 0.0),
+                                  -1)
+                    weights = self.scaling * top / jnp.sum(top, -1,
+                                                           keepdims=True)
+                share = scores if softmax \
+                    else scores / jnp.sum(scores, -1, keepdims=True)
             self.sow("intermediates", "router_in", h)
             self.sow("intermediates", "router_logits", logits)
             self.sow("intermediates", "chosen", chosen)

@@ -16,7 +16,16 @@ gets wrong are named where they are made (:func:`name`, which is
   _flash_fwd``, inside the differentiation rule): 0.5 MB a layer at GPT-2's
   shapes, under ``dots`` whatever the call;
 - the flash forward's ``out`` and ``lse`` where the CALL is dear to make
-  again (:func:`name_flash`), under ``dots`` and ``full`` alike.
+  again (:func:`name_flash`), under ``dots`` and ``full`` alike;
+- the experts a router chose, where the expert layer is told to keep its
+  routing (:data:`ROUTED`; 0.5 MB a layer at 16,384 rows), under both: not
+  for what the top-k costs to make again but because the rematerialised
+  forward is another program than the pass, rounds bf16 elsewhere, and may
+  choose OTHER experts at a near-tie — under block diffusion every masked
+  row is nearly the mask token's one vector, their near-ties are one, and
+  the backward then weighed experts the forward had not run for a quarter
+  of a layer's rows (a routed leaf's gradient off by up to 0.88 on the chip
+  against 0.015 without remat: PERF.md section 6, PR 51).
 
 A Mosaic call is no ``dot_general``: what nothing keeps of it the forward
 kernel makes a second time in every backward of a scanned run of layers. By
@@ -82,12 +91,18 @@ FLASH_LSE = "flash_lse"
 #: ... and of a call cheap to make again, for the room it would take
 FLASH_OUT_CHEAP = "flash_out_cheap"
 FLASH_LSE_CHEAP = "flash_lse_cheap"
-NAMES = (PROJECTION, FLASH_OUT, FLASH_LSE, FLASH_OUT_CHEAP, FLASH_LSE_CHEAP)
+#: the experts a router chose, ``[rows, k]`` int32, where the expert layer
+#: names them (``ops/moe.py MoeMlp.keep_routing``): a selection made again
+#: from a forward that rounds another way may be ANOTHER selection, and the
+#: backward then weighs experts the pass did not run
+ROUTED = "moe_chosen"
+NAMES = (PROJECTION, FLASH_OUT, FLASH_LSE, FLASH_OUT_CHEAP, FLASH_LSE_CHEAP,
+         ROUTED)
 #: the names each ``remat_policy`` saves; :data:`FLASH_OUT_CHEAP` is in
 #: neither (it is named for the block's line in the log)
 KEPT = {
-    "full": (FLASH_OUT, FLASH_LSE),
-    "dots": (PROJECTION, FLASH_OUT, FLASH_LSE, FLASH_LSE_CHEAP),
+    "full": (FLASH_OUT, FLASH_LSE, ROUTED),
+    "dots": (PROJECTION, FLASH_OUT, FLASH_LSE, FLASH_LSE_CHEAP, ROUTED),
 }
 
 #: a flash forward whose ``out`` + ``lse`` cost at least this many FLOP a
@@ -131,26 +146,30 @@ def seen_pairs(s_q: int, s_k: int, causal: bool,
 
 def flash_flop_per_byte(out: jax.ShapeDtypeStruct, lse: jax.ShapeDtypeStruct,
                         *, s_k: int, head_dim: int, causal: bool,
-                        window: Optional[int]) -> float:
+                        window: Optional[int],
+                        pairs: Optional[int] = None) -> float:
     """What a byte of a flash forward's results costs to make again: the
     call's FLOPs over the bytes of ``out`` ``[batch, s_q, heads x value
     size]`` and ``lse`` ``[batch, heads, s_q]``; ``head_dim`` is the score
-    size."""
+    size. ``pairs``: the (query, key) pairs a mask of another kind keeps
+    (block diffusion's: ``BlockDiffusion.pairs``), counted by the caller."""
     batch, heads, s_q = lse.shape
-    flop = 2 * seen_pairs(s_q, s_k, causal, window) * batch * heads \
+    if pairs is None:
+        pairs = seen_pairs(s_q, s_k, causal, window)
+    flop = 2 * pairs * batch * heads \
         * (head_dim + out.shape[-1] // heads)
     return flop / (out.size * out.dtype.itemsize
                    + lse.size * lse.dtype.itemsize)
 
 
 def name_flash(out: jax.Array, lse: jax.Array, *, s_k: int, head_dim: int,
-               causal: bool, window: Optional[int]
-               ) -> Tuple[jax.Array, jax.Array]:
+               causal: bool, window: Optional[int],
+               pairs: Optional[int] = None) -> Tuple[jax.Array, jax.Array]:
     """The flash forward's two results under the names the rule gives them:
     :data:`FLASH_OUT` and :data:`FLASH_LSE` where the call costs
     :data:`FLASH_KEEP_FLOP_PER_BYTE` or more, the ``_CHEAP`` pair below."""
     cost = flash_flop_per_byte(out, lse, s_k=s_k, head_dim=head_dim,
-                               causal=causal, window=window)
+                               causal=causal, window=window, pairs=pairs)
     labels = (FLASH_OUT, FLASH_LSE) if cost >= FLASH_KEEP_FLOP_PER_BYTE \
         else (FLASH_OUT_CHEAP, FLASH_LSE_CHEAP)
     return name(out, labels[0], cost), name(lse, labels[1], cost)

@@ -79,6 +79,10 @@ MELLUM = ("mellum", dict(
     size="2-12b-a2.5b", seq_len=8192, vocab=24576, remat_policy="full",
     layer_types=["sliding_attention"] * 3 + ["full_attention"],
     experts_held=(0, 16), **_CHIP))
+SDAR = ("sdar", dict(
+    size="30b-a3b-chat", seq_len=8192, vocab=18992, block_length=4,
+    remat_policy="full", layer_types=["full_attention"] * 6,
+    experts_held=(0, 16), **_CHIP))
 #: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
 #: limit is its own compiled size and a little: medium's steps 15.292
@@ -130,6 +134,8 @@ PROGRAMS = {
     # the others' (16.3 until PR 43, 14.3 until PR 44)
     "nemotron_1x2": (NEMOTRON, "dp=1", 2, 1, "adamw", 14.05),
     "mellum_1x2": (MELLUM, "dp=1", 2, 1, "adamw", 12.1),
+    # ONE sequence of 8,192 tokens a step: 16,384 [noised || clean] rows
+    "sdar_1x1": (SDAR, "dp=1", 1, 1, "adamw", 15.0),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -157,6 +163,8 @@ ATTENTION_KERNELS = {
     # forward's results
     "mellum_1x2": {"flash_bwd": 1, "flash_fwd": 1, "swa_bwd_dkv": 1,
                    "swa_bwd_dq": 1, "swa_fwd": 2},
+    # six scanned layers under the block mask, the forward's results kept
+    "sdar_1x1": {"bd_bwd": 1, "bd_fwd": 1},
 }
 
 #: name -> the Mamba-2 mixer's kernels (ops/ssd.py: the scan's ``ssd_*``,
@@ -391,7 +399,7 @@ def main() -> None:
         if limit and gib > limit:
             over.append(f"{name}: {gib:.3f} GiB a device, over its {limit}")
         attention = {kernel: n for kernel, n in kernels.items()
-                     if kernel.startswith(("flash_", "mla_", "swa_"))}
+                     if kernel.startswith(("flash_", "mla_", "swa_", "bd_"))}
         if attention != ATTENTION_KERNELS.get(name, attention):
             over.append(f"{name}: attention kernels {attention}, not "
                         f"{ATTENTION_KERNELS[name]}")

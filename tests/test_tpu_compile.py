@@ -368,6 +368,41 @@ def test_lagunas_attention_kinds_compile_at_8k(v5e_2x2, heads, window, rot,
     assert not any("/flash_bwd_dq/" in line for line in calls)
 
 
+# -------------------------------------------------------------------- SDAR
+def test_sdars_attention_compiles_at_16k_rows_under_the_block_mask(v5e_2x2):
+    """SDAR's attention at the cell's shape, forward and backward: one
+    sequence's ``[noised || clean]`` 16,384 rows, 32 query heads over 4
+    key/value heads of 128, under block diffusion's mask at a block length
+    of 4 — the looped kernels under the mask's names, the backward ONE call,
+    the rotary kernel over tables that repeat the positions; within the
+    kernels' VMEM with 16,384 rows of k and v resident."""
+    from easydl_tpu.ops.flash_attention import BlockDiffusion, choose_blocks
+    from easydl_tpu.ops.rope import rope_tables
+
+    mask = BlockDiffusion(4, 8192)
+    assert choose_blocks(16384, 16384, False, mask=mask) == ((512, 512),) * 3
+    assert mask.block_pairs(512) == (288, 1024)
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        rope = tuple(jnp.concatenate([table, table])
+                     for table in rope_tables(8192, 128, 1e6))
+        return multihead_attention(
+            q, k, v, impl="flash", rope=rope, mask=mask
+        ).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("bd_fwd", "bd_bwd", "rope_fwd", "rope_bwd"):
+        assert any(f"/{name}/" in line for line in calls), name
+    for other in ("flash_fwd", "flash_bwd", "bd_bwd_dq"):
+        assert not any(f"/{other}/" in line for line in calls), other
+
+
 # ---------------------------------------------------------------- Mellum 2
 @pytest.mark.parametrize("window,yarn,names", [
     (1024, None, ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")),

@@ -258,12 +258,21 @@ def test_the_medium_steps_fit_the_chip(v5e_2x2, rehearse, program):
      "mellum_1x2: attention kernels {'flash_bwd': 1, 'flash_fwd': 1, "
      "'swa_bwd': 1, 'swa_fwd': 2}, not {'flash_bwd': 1, 'flash_fwd': 1, "
      "'swa_bwd_dkv': 1, 'swa_bwd_dq': 1, 'swa_fwd': 2}"),
+    ("sdar_1x1", {}, None),
+    ("sdar_1x1", {"bd_fwd": 1},  # the scanned run made ``out`` again
+     "sdar_1x1: attention kernels {'bd_bwd': 1, 'bd_fwd': 2}, not "
+     "{'bd_bwd': 1, 'bd_fwd': 1}"),
+    # the mask's calls under the causal kernels' names: another program
+    ("sdar_1x1", {"bd_fwd": -1, "bd_bwd": -1, "flash_fwd": 1, "flash_bwd": 1},
+     "sdar_1x1: attention kernels {'flash_bwd': 1, 'flash_fwd': 1}, not "
+     "{'bd_bwd': 1, 'bd_fwd': 1}"),
 ], ids=["as-gated", "a-forward-more", "joyai-a-forward-more",
         "another-backward", "hybrid-as-gated", "nemotron-as-gated",
         "hybrid-the-numpy-scan", "nemotron-a-scan-forward-less",
         "a-scan-kernel-where-none-is", "hybrid-the-numpy-convolutions",
         "nemotron-a-convolution-doubled", "nemotron-a-convolution-missing",
-        "mellum-as-gated", "mellum-the-looped-window"])
+        "mellum-as-gated", "mellum-the-looped-window", "sdar-as-gated",
+        "sdar-a-forward-more", "sdar-the-causal-kernels"])
 def test_the_script_fails_on_other_attention_kernels_than_a_cells(
         v5e_2x2, rehearse, monkeypatch, capsys, program, more, said):
     """The script is where the whole steps at the cells' sizes are gated
