@@ -16,14 +16,15 @@ backward is looped (below) it is ONE kernel on the grid ``(batch row, lane
 block, K-block)`` that walks the K-block's live Q-blocks: a pair's score
 tile, ``exp``, ``dP``, ``delta`` and ``dS`` are made once and feed three
 products, ``dV += Pᵀ dO``, ``dK += dSᵀ Q`` and ``dQᵀ[Q-block] += Kᵀ dSᵀ`` —
-five products a pair. dq's float32 sum lives in VMEM scratch, ``[Q-blocks,
-cell heads · head_dim, block_q]`` (transposed, a Q-block a leading index),
-zeroed at K-block 0, added to in ascending K-block order, and turned, scaled
-and rounded once at the last K-block into a dq block that stays resident
-while the K-block axis runs (``dimension_semantics``: that axis
-``arbitrary``); rows no key sees stay zero. The call says what VMEM it needs
-from what it holds (q, O, dO and the dq block of a cell's heads whole, twice,
-and the sum: 55 MB at 8,192 x two heads of 192 / 128). Where the backward is
+five products a pair, walked in tiles (below). dq's float32 sum lives in
+VMEM scratch, ``[Q-blocks, cell heads · head_dim, block_q]`` (transposed, a
+Q-block a leading index), zeroed at K-block 0, added to in ascending K-block
+order, and turned, scaled and rounded once at the last K-block into a dq
+block that stays resident while the K-block axis runs
+(``dimension_semantics``: that axis ``arbitrary``); rows no key sees stay
+zero. The call says what VMEM it needs from what it holds (q, O, dO and the
+dq block of a cell's heads whole, twice, and the sums: 56 MB at 8,192 x two
+heads of 192 / 128). Where the backward is
 unrolled a grid cell takes a head's whole sequence, no axis carries a sum,
 and two kernels stand: dq walks K-blocks, dk/dv walks Q-blocks, each making
 the pair's tile for itself (seven products a pair; the trade there is code
@@ -89,6 +90,22 @@ pairs a head: two heads' straight-line pairs interleave as they are, and
 every pair written out is set-up time — keeps the pair as one tile, its
 program unchanged.
 
+How the looped backward walks a block pair: the same way. Its pair as one
+tile held TWO ``[512, 512]`` float32 tiles at once (``Sᵀ`` and ``dPᵀ``) on
+one chain of five products, and carried 128 vregs of dk and dv through
+every turn of its loop; 2.23 us a pair on the chip where the MXU's five
+products need 1.70. It walks tiles of 128 x 128 (``_BWD_TILE``) key tile
+after key tile: a tile's two score products ``Sᵀ = K Qᵀ`` and ``dPᵀ = V
+dOᵀ``, which wait for no VPU result, stand half a pair's tiles ahead of
+``exp``, ``dSᵀ`` and the three products that consume them (``_behind``); dk
+and dv live in a float32 VMEM scratch ``[cell heads, block_k, d | dv]``,
+zeroed a grid cell, a key tile's slices values through its Q tiles; dQᵀ is
+added to in its scratch a tile; the masked pair's tiles are placed as the
+forward's (six of sixteen not computed). 1.91 us a live pair at 128 / 128
+(1.99 a pair computed whole), the call 14 to 20% shorter; what else was
+tried is in ``_BWD_TILE``'s table. The call is a ``jax.jit`` of its own, as
+the forward's (``_bwd_looped``).
+
 What a rematerialised block may keep. The rule's forward names the two
 results that cost a kernel to make again, ``out`` and the ``lse`` rows
 (``ops/remat.py``), INSIDE the rule: the residuals the backward receives are
@@ -102,7 +119,7 @@ r sees key columns ≤ r + offset, as the reference's ``tril(k=s_k−s_q)``).
 Each Q-block (K-block in the backward that walks Q-blocks) walks its live
 block pairs in two loops:
 pairs wholly below the diagonal take no mask, pairs the diagonal crosses are
-masked (in the looped forward tile by tile, above). When the whole problem
+masked (in the looped kernels tile by tile, above). When the whole problem
 is at most ``_UNROLL_PAIRS`` block pairs, one
 grid cell takes the whole sequence of its heads: every block index is then a
 Python number, dead pairs are never emitted and both loops unroll into
@@ -363,8 +380,9 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     still unroll. Without a triangle the smaller blocks have nothing to win.
     Where the forward loops it walks its 512 x 512 pair in tiles of 128 x
     128 with the scores half a pair ahead (``_FWD_TILE``, ``_behind``: the
-    band forward's pattern, PR 49); the block, the grid and what a cell
-    holds are the same.
+    band forward's pattern, PR 49), and so does the looped backward's one
+    kernel (``_BWD_TILE``, PR 52); the block, the grid and what a cell holds
+    are the same.
 
     Under a ``window`` of at most ``BAND_ROWS`` keys (a causal band: query i
     sees the ``window`` keys up to its own) on a square problem the three
@@ -591,15 +609,6 @@ def _bd_pair(mask: Optional["BlockDiffusion"], masked, block_k: int,
         masked, mask.block, 0, block_k, 0, block_q)
 
 
-def _bd_tiles(rule, block: int, side: int, tile: int):
-    """``[(k_at, q_at, crossed)]``: the tiles of ``tile x tile`` of a
-    diagonal pair of ``side x side`` that hold a live pair."""
-    found = [(k_at, q_at, _bd_tile(rule, block, k_at, tile, q_at, tile))
-             for k_at in range(0, side, tile) for q_at in range(0, side, tile)]
-    return [(k_at, q_at, crossed) for k_at, q_at, crossed in found
-            if crossed is not None]
-
-
 def _bd_mask(st, rule, block: int, k_at: int, q_at: int):
     """The score tile ``st`` (``[keys, queries]``, the first key ``k_at``
     and the first query ``q_at`` rows into their diagonal pair) with what
@@ -817,16 +826,39 @@ def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
 _FWD_TILE = 128
 
 
-def _fwd_tiles(block_q: int, block_k: int,
-               cell_heads: int) -> Tuple[int, int, int]:
+def _fwd_tiles(block_q: int, block_k: int, cell_heads: int,
+               tile: int = _FWD_TILE) -> Tuple[int, int, int]:
     """``(keys, queries, ahead)``: the tiles the looped forward walks a
     block pair in, and how many of them the scores run ahead of the softmax:
     half of what a cell's heads have in a pair. A block the tile does not
     divide is one tile."""
-    sub_k, sub_q = (_FWD_TILE if block % _FWD_TILE == 0 else block
+    sub_k, sub_q = (tile if block % tile == 0 else block
                     for block in (block_k, block_q))
     return sub_k, sub_q, max(
         1, (block_k // sub_k) * (block_q // sub_q) * cell_heads // 2)
+
+
+def _live_tiles(steps, sub_k: int, sub_q: int, placed: bool,
+                mask: Optional[BlockDiffusion], masked):
+    """``(steps, crossed)``: the tiles ``(first key, head, first query)`` of
+    a block pair's ``steps`` that hold a live pair, and under the block mask
+    ``{(first key, first query): whether the tile is masked}``. ``placed``:
+    the causal diagonal's place in the pair is static — it passes through
+    the pair's corner — so a tile wholly above it is not computed (one wholly
+    below it takes no mask: the caller's). Under the block mask a pair on
+    one of its diagonals (``masked``: the rule) is placed by
+    :func:`_bd_tile`: a tile no query of which sees a key is not computed,
+    one wholly live takes no mask, a crossed one its rule."""
+    if placed:
+        steps = [(ki, g, qj) for ki, g, qj in steps if ki <= qj + sub_q - 1]
+    crossed = {}
+    if mask is not None and masked:
+        crossed = {(ki, qj): _bd_tile(masked, mask.block, ki, sub_k, qj,
+                                      sub_q)
+                   for ki, _, qj in steps}
+        steps = [(ki, g, qj) for ki, g, qj in steps
+                 if crossed[ki, qj] is not None]
+    return steps, crossed
 
 
 def _online_softmax(state, st, vt):
@@ -933,22 +965,9 @@ def _fwd_kernel(
             steps = [(ki, g, qj) for ki in range(0, block_k, sub_k)
                      for g in range(len(heads))
                      for qj in range(0, block_q, sub_q)]
-            # the diagonal's place in a masked pair is static: a tile wholly
-            # above it is not computed, one wholly below it takes no mask
             placed = masked and mask is None and diagonal
-            if placed:
-                steps = [(ki, g, qj) for ki, g, qj in steps
-                         if ki <= qj + sub_q - 1]
-            # under the block mask a diagonal pair's tiles are placed too:
-            # one no query of which sees a key is not computed, one wholly
-            # live takes no mask, a crossed one its rule
-            crossed = {}
-            if mask is not None and masked:
-                crossed = {(ki, qj): _bd_tile(masked, mask.block, ki, sub_k,
-                                              qj, sub_q)
-                           for ki, _, qj in steps}
-                steps = [(ki, g, qj) for ki, g, qj in steps
-                         if crossed[ki, qj] is not None]
+            steps, crossed = _live_tiles(steps, sub_k, sub_q, placed, mask,
+                                         masked)
             last = {(g, qj): (ki, g, qj) for ki, g, qj in steps}
             state, vts = {}, {}
 
@@ -1143,22 +1162,62 @@ def _over_q_blocks(body, carry, k_start, *, block_q: int, block_k: int,
     return carry
 
 
+#: keys and queries of a tile of the looped backward's block pair, as the
+#: forward's ``_FWD_TILE``: 32 float32 vregs of ``Sᵀ`` and ``dPᵀ`` a tile where
+#: the pair as one tile held 512. Swept on a v5e, us a backward call by the
+#: kernel's own events, five calls a variant, bf16 (PERF.md section 6, PR 52)
+#: at ``[2, 8192, 4 x 128]`` / ``[1, 4096, 16 x 128]`` / ``[1, 8192, 4 x 192 /
+#: 128]`` (two heads a cell) / ``[1, 16384, 4 x 128]`` under ``BlockDiffusion(4,
+#: 8192)`` / ``[2, 4096, 8 x 64]`` (two heads a cell). The pair as one tile (the
+#: parent): 2,426.5 / 1,365.5 / 1,724.0 / 2,513.7 / 1,167.3. Tiles of keys x
+#: queries, a tile's two score products ``ahead`` tiles (a share of what a
+#: cell's heads have in a pair) in front of the chain that consumes them, dk
+#: and dv in a VMEM scratch and a key tile's slices of them values through its
+#: query tiles (TAKEN): 128 x 128 ahead a half 2,073.9 / 1,139.2 / 1,486.2 /
+#: 2,122.7 / 933.4 (-14 to -20%), a whole 2,073.7 / 1,139.1 / 1,485.7 /
+#: 2,122.6 / 933.8 (three eighths and three quarters within 0.1%), a quarter
+#: 2,099.9 / 1,151.1 / 1,484.4 / 2,149.0 / 933.9, TWO tiles 2,189.2 / 1,196.8
+#: / 1,500.3 / 2,240.8 / 957.3, one 2,470.0 / 1,338.9 / 1,564.9 / 2,526.5 /
+#: 1,060.9 (no gain: the depth is the mechanism); 128 x 256 and 256 x 128
+#: ahead a half 2,101 / 1,163 / 1,504 / 2,169 / 952 (+1.3 to 2.1%); 128 x 512
+#: 2,153.8 / 1,209.9 / 1,546.7 / 2,276.7 / 1,004.6 (+4 to 8%). Other homes of
+#: the sums, at 128 x 128 ahead a half: query-major with ``dQᵀ``'s slice the
+#: value and dk, dv added to in the scratch a tile 2,079.0 / 1,141.9 / 1,494.6
+#: / 2,127.8 / 942.0 (+0.2 to 0.9%); dk and dv carried by the loop as values
+#: 2,216.7 / 1,232.8 / 1,652.5 / 2,280.6 / 1,051.2 (+7 to 13%: 128 vregs and
+#: more through every turn). Measured no faster and not taken: the sums read
+#: and written a tile (two heads a cell +2.5 / +7%), the two heads of a cell
+#: alternating tile by tile (+1.4 / +2.5%), a tile's q and dO handed from its
+#: products to its chain instead of read again (0.0%). One rule is within
+#: 0.1% of the best of each shape: the forward's.
+_BWD_TILE = 128
+
+
+def _bwd_tiles(block_q: int, block_k: int,
+               cell_heads: int) -> Tuple[int, int, int]:
+    """``(keys, queries, ahead)``: the tiles the looped backward walks a
+    block pair in, and how many of them a tile's two score products run
+    ahead of the chain that consumes them — the forward's rule
+    (:func:`_fwd_tiles`) on the backward's own measurements."""
+    return _fwd_tiles(block_q, block_k, cell_heads, _BWD_TILE)
+
+
 def _bwd_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
-    dq_sum_ref, *, head_dim: int, value_dim: int, block_q: int, causal: bool,
-    scale: float, offset: int, window: Optional[int],
-    mask: Optional[BlockDiffusion] = None,
+    dq_sum_ref, dk_sum_ref, dv_sum_ref, *, head_dim: int, value_dim: int,
+    block_q: int, causal: bool, scale: float, offset: int,
+    window: Optional[int], mask: Optional[BlockDiffusion] = None,
 ):
     """The looped backward, one K-block a grid cell: dq, dk and dv from ONE
-    score tile, ``exp``, ``dP`` and ``delta`` a block pair. Under the block
-    mask a K-block walks the Q-blocks that see it (:func:`_bd_q_blocks`), a
-    pair on one of the mask's diagonals in the tiles that hold a live pair
-    (``diagonal``): the noised half's own pair is its four diagonal tiles
-    of 128 alone, the others the pair as one masked tile."""
+    score tile, ``exp``, ``dP`` and ``delta``, a block pair walked in tiles
+    (``_BWD_TILE``) as the looped forward's. Under the block mask a K-block
+    walks the Q-blocks that see it (:func:`_bd_q_blocks`), a pair on one of
+    the mask's diagonals in the tiles that hold a live pair."""
     # q_ref, dq_ref: [S_q, cell heads · d]; o_ref, do_ref: [S_q, cell heads ·
     # dv]; k_ref, dk_ref: [block_k, cell heads · d]; v_ref, dv_ref: [block_k,
-    # cell heads · dv]; lse_ref: [cell heads, n_q, 1, block_q]; dq_sum_ref
-    # (scratch, float32): [n_q, cell heads · d, block_q], dQᵀ by Q-block
+    # cell heads · dv]; lse_ref: [cell heads, n_q, 1, block_q]; scratch,
+    # float32: dq_sum_ref [n_q, cell heads · d, block_q], dQᵀ by Q-block;
+    # dk_sum_ref [cell heads, block_k, d], dv_sum_ref [cell heads, block_k, dv]
     block_k, lanes = dk_ref.shape
     d = head_dim
     heads = _head_cols(lanes, d)
@@ -1166,95 +1225,103 @@ def _bwd_kernel(
     n_q = q_ref.shape[0] // block_q
     kb = pl.program_id(2)
     k_start = kb * block_k
+    sub_k, sub_q, ahead = _bwd_tiles(block_q, block_k, len(heads))
+    # whether the diagonal crosses a masked pair at a place known here: a
+    # square of blocks whose corner it passes through
+    diagonal = (window is None and block_q == block_k
+                and offset % block_k == 0)
 
     @pl.when(kb == 0)
     def _():
         # rows no key sees (s_q > s_k) are never added to: they stay zero
         dq_sum_ref[...] = jnp.zeros_like(dq_sum_ref)
 
+    dk_sum_ref[...] = jnp.zeros_like(dk_sum_ref)
+    dv_sum_ref[...] = jnp.zeros_like(dv_sum_ref)
     ks = [_fold_scale(k_ref[:, cols], scale) for cols in heads]
     vs = [v_ref[:, cols] for cols in v_heads]
     kt_all = k_ref[...].T  # [lanes, block_k]: turned once a grid cell
 
-    def body(qb, carry, *, masked: bool):
+    def pair(qb, carry, *, masked):
+        # the pair in tiles of [sub_k, sub_q], key tile after key tile, under
+        # each the cell's heads and their Q tiles. A tile's FIRST half is the
+        # MXU's two products that wait for nothing, ``Sᵀ = K Qᵀ`` and ``dPᵀ
+        # = V dOᵀ``, written ``ahead`` tiles in front of its SECOND: ``exp``,
+        # ``dSᵀ`` and the three products that consume them. A chain — one
+        # head's key tile — has its slices of dk and dv as values through
+        # its Q tiles: read from the scratch at its first tile of the pair,
+        # written back after its last, so that no turn of the loop carries
+        # 128 vregs of sums on a file of 64; dQᵀ's slice is added to in its
+        # scratch a tile (_BWD_TILE has the measurements of each).
+        # ``masked``: whether the diagonal crosses the pair or, under the
+        # block mask, the rule of the pair's diagonal
         q_start = _block_start(qb, block_q)
-        q_rows = pl.ds(q_start, block_q)
-        deltas = _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :], value_dim)
-        out = []
-        for g, (cols, v_cols, (k, s_scale), v, delta, (dk, dv)) in enumerate(
-                zip(heads, v_heads, ks, vs, deltas, carry)):
-            q = q_ref[q_rows, cols]
-            do = do_ref[q_rows, v_cols]
-            st = _scores_t(k, q, s_scale,
-                           q_start + offset - k_start if masked else None,
-                           window)
-            pt = jnp.exp(st - lse_ref[g, qb])
-            dv_new = dv + _dot(pt.astype(do.dtype), do, _NN)
-            dst = (pt * (_dot(v, do, _NT) - delta)).astype(q.dtype)
-            dq_sum_ref[qb, cols, :] += _dot(kt_all[cols], dst, _NN)
-            out.append((dk + _dot(dst, q, _NN), dv_new))
-        return tuple(out)
+        steps = [(ki, g, qj) for ki in range(0, block_k, sub_k)
+                 for g in range(len(heads))
+                 for qj in range(0, block_q, sub_q)]
+        placed = masked and mask is None and diagonal
+        steps, crossed = _live_tiles(steps, sub_k, sub_q, placed, mask,
+                                     masked)
+        last = {(g, ki): (ki, g, qj) for ki, g, qj in steps}
+        deltas, sums = {}, {}
 
-    def diagonal(qb, carry, *, masked):
-        q_start = _block_start(qb, block_q)
-        tile = _FWD_TILE if masked == _OWN and block_q % _FWD_TILE == 0 \
-            else block_q
-        tiles = _bd_tiles(masked, mask.block, block_q, tile)
+        def rows_at(qj):
+            return pl.ds(pl.multiple_of(q_start + qj, sub_q), sub_q)
 
-        def rows_at(q_at):
-            return pl.ds(pl.multiple_of(q_start + q_at, tile), tile)
+        def products(step):
+            ki, g, qj = step
+            there = slice(ki, ki + sub_k)
+            k, s_scale = ks[g]
+            edge = q_start + offset - k_start + qj - ki \
+                if masked and mask is None else None
+            if placed and ki + sub_k - 1 <= qj:
+                edge = None
+            st = _scores_t(k[there], q_ref[rows_at(qj), heads[g]], s_scale,
+                           edge, window)
+            if crossed.get((ki, qj)):
+                st = _bd_mask(st, masked, mask.block, ki, qj)
+            return st, _dot(vs[g][there], do_ref[rows_at(qj), v_heads[g]],
+                            _NT)
 
-        # a tile's statistics are read and made by tile: a lane slice of a
-        # [1, block_q] VALUE is a layout Mosaic does not broadcast from
-        deltas = {q_at: _delta_rows(do_ref[rows_at(q_at), :],
-                                    o_ref[rows_at(q_at), :], value_dim)
-                  for q_at in sorted({q_at for _, q_at, _ in tiles})}
-        out = []
-        for g, (cols, v_cols, (k, s_scale), v, (dk, dv)) in enumerate(
-                zip(heads, v_heads, ks, vs, carry)):
-            dks, dvs = {}, {}
-            for k_at, q_at, crossed in tiles:
-                there, mine = slice(k_at, k_at + tile), slice(q_at, q_at + tile)
-                rows = rows_at(q_at)
-                q, do = q_ref[rows, cols], do_ref[rows, v_cols]
-                st = _scores_t(k[there], q, s_scale, None)
-                if crossed:
-                    st = _bd_mask(st, masked, mask.block, k_at, q_at)
-                pt = jnp.exp(st - lse_ref[g, qb, :, mine])
-                dvs[k_at] = dvs.get(k_at, 0.0) + _dot(
-                    pt.astype(do.dtype), do, _NN)
-                dst = (pt * (_dot(v[there], do, _NT) - deltas[q_at][g])
-                       ).astype(q.dtype)
-                dq_sum_ref[qb, cols, mine] += _dot(kt_all[cols, there], dst,
-                                                   _NN)
-                dks[k_at] = dks.get(k_at, 0.0) + _dot(dst, q, _NN)
+        def chain(step, made):
+            ki, g, qj = step
+            st, dpt = made
+            there, mine = slice(ki, ki + sub_k), slice(qj, qj + sub_q)
+            rows, cols = rows_at(qj), heads[g]
+            # a Q tile's statistics are read and made by tile: a lane slice
+            # of a [1, block_q] VALUE is a layout Mosaic does not broadcast
+            # from
+            if qj not in deltas:
+                deltas[qj] = _delta_rows(do_ref[rows, :], o_ref[rows, :],
+                                         value_dim)
+            q, do = q_ref[rows, cols], do_ref[rows, v_heads[g]]
+            pt = jnp.exp(st - lse_ref[g, qb, :, mine])
+            dst = (pt * (dpt - deltas[qj][g])).astype(q.dtype)
+            if (g, ki) not in sums:
+                sums[g, ki] = (dk_sum_ref[g, there, :],
+                               dv_sum_ref[g, there, :])
+            dk, dv = sums[g, ki]
+            sums[g, ki] = (dk + _dot(dst, q, _NN),
+                           dv + _dot(pt.astype(do.dtype), do, _NN))
+            dq_sum_ref[qb, cols, mine] += _dot(kt_all[cols, there], dst, _NN)
+            if step == last[g, ki]:
+                dk_sum_ref[g, there, :], dv_sum_ref[g, there, :] = sums.pop(
+                    (g, ki))
 
-            def by_row(parts, width):  # a part a tile of keys, in order
-                return _side_by_side([
-                    parts[at] if at in parts
-                    else jnp.zeros((tile, width), jnp.float32)
-                    for at in range(0, block_k, tile)], 0)
+        _behind(steps, products, chain, ahead)
+        return carry
 
-            out.append((dk + by_row(dks, d), dv + by_row(dvs, value_dim)))
-        return tuple(out)
-
-    carry = tuple((jnp.zeros((block_k, d), jnp.float32),
-                   jnp.zeros((block_k, value_dim), jnp.float32))
-                  for _ in heads)
     if mask is not None:
-        carry = _bd_q_blocks(
-            lambda qb, carry, *, masked: (diagonal if masked else body)(
-                qb, carry, masked=masked),
-            carry, kb, mask=mask, side=block_q, unroll=False)
+        _bd_q_blocks(pair, None, kb, mask=mask, side=block_q, unroll=False)
     else:
-        carry = _over_q_blocks(
-            body, carry, k_start, block_q=block_q, block_k=block_k, n_q=n_q,
+        _over_q_blocks(
+            pair, None, k_start, block_q=block_q, block_k=block_k, n_q=n_q,
             offset=offset, causal=causal, unroll=False, window=window)
     # q and k entered the products unscaled (the scale sat on k or the
     # scores).
-    dk_ref[...] = (_side_by_side([dk for dk, _ in carry], 1) * scale
-                   ).astype(dk_ref.dtype)
-    dv_ref[...] = _side_by_side([dv for _, dv in carry], 1
+    dk_ref[...] = (_side_by_side([dk_sum_ref[g] for g in range(len(heads))], 1)
+                   * scale).astype(dk_ref.dtype)
+    dv_ref[...] = _side_by_side([dv_sum_ref[g] for g in range(len(heads))], 1
                                 ).astype(dv_ref.dtype)
 
     @pl.when(kb == pl.num_programs(2) - 1)
@@ -1390,6 +1457,59 @@ def _bwd_dkv_kernel(
 _DEFAULT_VMEM = 16 << 20
 
 
+def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
+              scale: float, block_q: int, block_k: int, interpret: bool,
+              window: Optional[int], mask: Optional[BlockDiffusion]):
+    """The looped backward: ONE call on dkv's grid gives all three — dq
+    summed in VMEM across the K-block axis, which is therefore sequential."""
+    b, s_q, width = q.shape
+    s_k, d, vd = k.shape[1], width // heads, v.shape[2] // heads
+    item = q.dtype.itemsize
+    n_q, n_k = s_q // block_q, s_k // block_k
+    cell = _cell_heads(heads, d, 0, False, 0, vd)
+
+    def whole(size):
+        return pl.BlockSpec((None, s_q, cell * size), lambda b, h, ki: (b, 0, h))
+
+    def mine(size):
+        return pl.BlockSpec((None, block_k, cell * size),
+                            lambda b, h, ki: (b, ki, h))
+
+    lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
+    # q, dq, O, dO and lse whole and k, v, dk, dv by block, two buffers
+    # each; the float32 sums of dq, dk and dv once
+    held = (2 * cell * (2 * (s_q + block_k) * (d + vd) * item + s_q * 8 * 4)
+            + cell * (d * s_q + (d + vd) * block_k) * 4)
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, head_dim=d, value_dim=vd, block_q=block_q,
+            causal=causal, scale=scale, offset=s_k - s_q, window=window,
+            mask=mask),
+        grid=(b, pl.cdiv(heads, cell), n_k),
+        in_specs=[whole(d), mine(d), mine(vd), whole(vd), whole(vd),
+                  lse_spec],
+        out_specs=[whole(d), mine(d), mine(vd)],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((n_q, cell * d, block_q), jnp.float32),
+            pltpu.VMEM((cell, block_k, d), jnp.float32),
+            pltpu.VMEM((cell, block_k, vd), jnp.float32)],
+        interpret=interpret,
+        **_call_name("bwd", d, vd, window, pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=held + _DEFAULT_VMEM), mask=mask),
+    )(q, k, v, out, do, lse_in)
+
+
+_bwd_looped = jax.jit(_bwd_call, static_argnames=(
+    "heads", "causal", "scale", "block_q", "block_k", "interpret", "window",
+    "mask"))
+
+
 def _bwd(
     q, k, v, out, lse, do, *, heads: int, causal: bool, scale: float,
     dq_blocks: Tuple[int, int], dkv_blocks: Tuple[int, int], interpret: bool,
@@ -1398,46 +1518,17 @@ def _bwd(
     b, s_q, width = q.shape
     s_k, d, vd = k.shape[1], width // heads, v.shape[2] // heads
     item = q.dtype.itemsize
+    block_q, block_k = dkv_blocks
+    if not _unrolled(s_q // block_q, s_k // block_k):
+        return _bwd_looped(
+            q, k, v, out, lse, do, heads=heads, causal=causal, scale=scale,
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            window=window, mask=mask)
     static = dict(head_dim=d, value_dim=vd, causal=causal, scale=scale,
                   offset=s_k - s_q, window=window, mask=mask)
 
     def whole(s, cell, size):
         return pl.BlockSpec((None, s, cell * size), lambda b, h, i: (b, 0, h))
-
-    block_q, block_k = dkv_blocks
-    n_q, n_k = s_q // block_q, s_k // block_k
-    if not _unrolled(n_q, n_k):
-        # looped: ONE call on dkv's grid gives all three — dq summed in VMEM
-        # across the K-block axis, which is therefore sequential
-        cell = _cell_heads(heads, d, 0, False, 0, vd)
-
-        def mine(size):
-            return pl.BlockSpec((None, block_k, cell * size),
-                                lambda b, h, ki: (b, ki, h))
-
-        lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
-        # q, dq, O, dO and lse whole and k, v, dk, dv by block, two buffers
-        # each; dq's float32 sum once
-        held = (2 * cell * (2 * (s_q + block_k) * (d + vd) * item
-                            + s_q * 8 * 4) + cell * d * s_q * 4)
-        return pl.pallas_call(
-            functools.partial(_bwd_kernel, block_q=block_q, **static),
-            grid=(b, pl.cdiv(heads, cell), n_k),
-            in_specs=[whole(s_q, cell, d), mine(d), mine(vd),
-                      whole(s_q, cell, vd), whole(s_q, cell, vd), lse_spec],
-            out_specs=[whole(s_q, cell, d), mine(d), mine(vd)],
-            out_shape=[
-                jax.ShapeDtypeStruct(q.shape, q.dtype),
-                jax.ShapeDtypeStruct(k.shape, k.dtype),
-                jax.ShapeDtypeStruct(v.shape, v.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((n_q, cell * d, block_q), jnp.float32)],
-            interpret=interpret,
-            **_call_name("bwd", d, vd, window, pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-                vmem_limit_bytes=held + _DEFAULT_VMEM), mask=mask),
-        )(q, k, v, out, do, lse_in)
 
     # unrolled (at most _UNROLL_PAIRS block pairs a head): one grid cell a
     # head cell takes the whole sequence, no axis carries a sum — two kernels
@@ -1943,8 +2034,9 @@ def flash_attention(
     def walk(name, cut):
         if _unrolled(s // cut[0], s_k // cut[1]):
             return "unrolled"
-        sub_k, sub_q, ahead = _fwd_tiles(*cut, tile)
-        if name != "fwd" or (sub_q, sub_k) == cut:
+        sub_k, sub_q, ahead = (_fwd_tiles if name == "fwd" else _bwd_tiles)(
+            *cut, tile)
+        if (sub_q, sub_k) == cut:
             return "looped"
         return f"looped in tiles of {sub_q}/{sub_k}, {ahead} behind"
 

@@ -285,21 +285,33 @@ def test_each_drop_from_the_kernel_is_logged_once_with_its_reason(
         f"flash attention: XLA reference path, not the kernel: {reason}"]
 
 
-@pytest.mark.parametrize("s,window,said", [
-    (64, None, "fwd 32/32 unrolled, dq 32/32 unrolled, dkv 32/32 unrolled,"),
-    (256, None, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
-    (256, 80, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
-    (256, 32, "fwd band 128/32 beside 32, dq band 128/32 beside 32, "
-              "dkv band 128/32 beside 32,"),
-], ids=["unrolled", "looped", "looped-window", "band"])
+@pytest.mark.parametrize("s,window,block,h,d,said", [
+    (64, None, 32, 1, 32,
+     "fwd 32/32 unrolled, dq 32/32 unrolled, dkv 32/32 unrolled,"),
+    (256, None, 32, 1, 32, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
+    (256, 80, 32, 1, 32, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
+    (256, 32, 32, 1, 32,
+     "fwd band 128/32 beside 32, dq band 128/32 beside 32, "
+     "dkv band 128/32 beside 32,"),
+    # blocks the tile divides: both looped kernels walk a pair in tiles,
+    # half of what a cell's heads have in a pair behind
+    (1280, None, 256, 1, 128,
+     "fwd 256/256 looped in tiles of 128/128, 2 behind, "
+     "bwd 256/256 looped in tiles of 128/128, 2 behind, one kernel,"),
+    (4096, None, None, 2, 64,
+     "fwd 512/512 looped in tiles of 128/128, 16 behind, "
+     "bwd 512/512 looped in tiles of 128/128, 16 behind, one kernel,"),
+], ids=["unrolled", "looped", "looped-window", "band", "looped-in-tiles",
+        "looped-in-tiles-two-heads-of-64"])
 def test_the_logged_line_says_how_many_kernels_the_backward_is(
-        monkeypatch, s, window, said):
+        monkeypatch, s, window, block, h, d, said):
     """The engagement is static: the line a process logs once for a call's
-    shape says ``one kernel`` where (and only where) the backward is."""
-    q, k, v = qkv(1, b=1, s=s, h=1, d=32)
+    shape says ``one kernel`` where (and only where) the backward is, and
+    the tiles and the depth a looped kernel walks its pair in."""
+    q, k, v = qkv(1, b=1, s=s, h=h, d=d)
     with _flash_log(monkeypatch) as records:
         jax.eval_shape(functools.partial(
-            flash_attention, causal=True, block_q=32, block_k=32,
+            flash_attention, causal=True, block_q=block, block_k=block,
             interpret=True, window=window), q, k, v)
     line, = records
     assert f"blocks q/k {said} over lengths" in line, line

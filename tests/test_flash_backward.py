@@ -108,16 +108,28 @@ def test_causal_rectangular_blocks_forward_and_grads(block_q, block_k, s_q, s_k)
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("s_q,s_k,block_q,block_k", [
-    (256, 256, 32, 32), (128, 256, 16, 64), (256, 128, 64, 16),
-    (32, 576, 32, 32)],
-    ids=["square", "sq<sk", "sq>sk", "one-q-block"])
-def test_looped_walk_matches_reference(s_q, s_k, block_q, block_k, causal):
+@pytest.mark.parametrize("s_q,s_k,block_q,block_k,heads,d", [
+    (256, 256, 32, 32, 2, 32), (128, 256, 16, 64, 2, 32),
+    (256, 128, 64, 16, 2, 32), (32, 576, 32, 32, 2, 32),
+    # the backward's pair in tiles of 128 x 128 (blocks of 256: four tiles a
+    # head, the masked pair's three live ones placed statically): one head
+    # of 128 a cell, and two of 64
+    (1280, 1280, 256, 256, 1, 128), (1280, 1280, 256, 256, 2, 64),
+    # an offset of 128 under blocks of 128 x 256: the diagonal at no static
+    # place, every tile of a crossed pair under its own traced edge
+    (1152, 1280, 128, 256, 1, 128),
+    # blocks the tile does not divide: the pair is one tile
+    (1344, 1344, 192, 192, 2, 32)],
+    ids=["square", "sq<sk", "sq>sk", "one-q-block", "tiles-one-head-of-128",
+         "tiles-two-heads-of-64", "tiles-offset-no-static-place",
+         "one-tile-a-block-of-192"])
+def test_looped_walk_matches_reference(s_q, s_k, block_q, block_k, heads, d,
+                                       causal):
     """More block pairs than ``_UNROLL_PAIRS``: one Q-block (K-block) a grid
     cell, block indices known only at run time, both loops ``fori_loop``s."""
     assert (s_q // block_q) * (s_k // block_k) > flash_module._UNROLL_PAIRS
-    b, h, d = 1, 2, 32
-    q, k, v = normal(8, (b, s_q, h, d), (b, s_k, h, d), (b, s_k, h, d))
+    q, k, v = normal(8, (1, s_q, heads, d), (1, s_k, heads, d),
+                     (1, s_k, heads, d))
     (out, g_flash), (ref, g_ref) = flash_and_reference(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -262,3 +274,33 @@ def test_matmul_operands_follow_the_input_dtype(dtype, other, looped):
     for name, operands in dots.items():
         assert all(pair == (dtype, dtype) for pair in operands), (name, operands)
         assert not any(other in pair for pair in operands)
+
+
+@pytest.mark.parametrize("s_q,s_k,causal,mask,dots", [
+    # 25 pairs a head in blocks of 512, sixteen tiles of 128 x 128 a pair
+    # and five products a tile: the unmasked pair's body 80, and the masked
+    # pair's the ten tiles on and under the diagonal — the six above it are
+    # not computed
+    (2560, 2560, True, None, 80 + 50),
+    (2560, 2560, False, None, 80),
+    # the diagonal at no static place (an offset of 128; K-blocks of 384,
+    # twelve tiles a pair): every tile of a crossed pair is computed, under
+    # its own edge
+    (2560, 2688, True, None, 60 + 60),
+    # the block mask at 6,144 rows: the wholly live pair's body, the noised
+    # half's own pair in its four diagonal tiles, the clean diagonals' (one
+    # body under a traced strictness) in ten
+    (6144, 6144, False, flash_module.BlockDiffusion(4, 3072), 80 + 20 + 50),
+], ids=["placed", "no-mask", "offset-no-static-place", "block-mask"])
+def test_a_masked_pairs_dead_tiles_are_not_computed(s_q, s_k, causal, mask,
+                                                    dots):
+    """The looped backward's kernel traced, not run: the products in its
+    bodies, by the tiles a pair is walked in."""
+    q, k, v = normal(11, (1, s_q, 1, 128), (1, s_k, 1, 128),
+                     (1, s_k, 1, 128), dtype="bfloat16")
+    blocks = flash_module.choose_blocks(s_q, s_k, causal, mask=mask)[2]
+    assert flash_module._bwd_tiles(*blocks, 1)[:2] == (128, 128)
+    found = _kernel_dots(jax.grad(
+        lambda *x: cos_weighed(flash_attention(*x, causal=causal, mask=mask)),
+        argnums=(0, 1, 2)), q, k, v)
+    assert len(found["bd_bwd" if mask else "flash_bwd"]) == dots

@@ -166,10 +166,15 @@ def test_the_tiles_of_a_diagonal_pair():
     """A pair of 512 on the noised half's diagonal holds a live pair in its
     four diagonal tiles of 128 alone; on the clean diagonals ten of sixteen,
     four of them crossed — under a traced strictness too."""
-    assert [(k, q) for k, q, _ in fa._bd_tiles(fa._OWN, 4, 512, 128)] == [
+    def live_tiles(rule):  # [(first key, first query, crossed)]
+        found = [(k, q, fa._bd_tile(rule, 4, k, 128, q, 128))
+                 for k in range(0, 512, 128) for q in range(0, 512, 128)]
+        return [tile for tile in found if tile[2] is not None]
+
+    assert [(k, q) for k, q, _ in live_tiles(fa._OWN)] == [
         (at, at) for at in range(0, 512, 128)]
     for strict in (0, 1, jnp.int32(1)):
-        tiles = fa._bd_tiles(("upto", strict), 4, 512, 128)
+        tiles = live_tiles(("upto", strict))
         assert len(tiles) == 10 and sum(c for _, _, c in tiles) == 4
         assert all(k <= q for k, q, _ in tiles)
     # a tile that IS a mask block: wholly live, or dead under the strict rule

@@ -115,8 +115,8 @@ def test_the_kernels_compile_at_two_head_sizes(v5e_2x2):
     tiles: interpret mode cannot say whether Mosaic takes them), the whole
     sequence's k and v of a cell twice in VMEM (21 MB: over the compiler's
     own limit, so the calls name theirs; the one backward call holds q, O,
-    dO and dq's block twice and dq's float32 sum: 55 MB, stated from its
-    shapes); and q's rotation on rows of 192-lane heads."""
+    dO and dq's block twice and the float32 sums of dq, dk and dv: 56 MB,
+    stated from its shapes); and q's rotation on rows of 192-lane heads."""
     from easydl_tpu.ops.flash_attention import choose_blocks
     from easydl_tpu.ops.rope import rope_rows, rope_tables
 
@@ -132,9 +132,10 @@ def test_the_kernels_compile_at_two_head_sizes(v5e_2x2):
     assert sorted(c.count("bf16[2,8192,6144]") for c in calls) == [0, 2]
     assert sorted(c.count("bf16[2,8192,4096]") for c in calls) == [1, 1]
     text = compiled.as_text()
-    # the looped forward is a jit of its own (its body in tiles is traced
-    # once a shape, not once a use): the call's name is a scope inside it
-    assert "jvp(jit(_fwd_call))/mla_fwd/" in text and "jvp(mla_bwd)" in text
+    # the looped calls are jits of their own (a body in tiles is traced
+    # once a shape, not once a use): a call's name is a scope inside its jit
+    assert "jvp(jit(_fwd_call))/mla_fwd/" in text
+    assert "transpose(jvp(jit(_bwd_call)))/mla_bwd/" in text
     assert "mla_bwd_dq" not in text and "mla_bwd_dkv" not in text
 
     def rotate(x):
