@@ -18,6 +18,7 @@ from easydl_tpu.models.registry import ModelBundle
 from easydl_tpu.models.transformer import Transformer, TransformerConfig
 from easydl_tpu.ops.flash_attention import BlockDiffusion, choose_blocks
 from easydl_tpu.ops.fused_xent import fused_softmax_xent, local_batch
+from easydl_tpu.ops import selective_scan as sscan
 from easydl_tpu.utils.logging import get_logger, log_once
 
 
@@ -47,6 +48,14 @@ def lm_loss(logits, targets, ignore_id: int = -1):
 #: cuts the sequence into chunks is its own matter
 #: (``fused_xent.chunk_positions``).
 FUSED_HEAD_LOGITS_BYTES = 2 * 1024 ** 3
+#: ... or when ONE sequence's float32 logits alone would pass half of that:
+#: a microbatch of sequences can be cut to fewer, one sequence cannot, and
+#: it is the long sequence beside a chip full of state that has no room
+#: (16,384 x 25,008: 1.53 GiB, and a copy of it, in a step that then needed
+#: 18.9 GB of 15.75: PERF.md section 6, PR 53). No cell of the benchmark
+#: under the first rule passes the second (the widest, 8,192 x 24,576, is
+#: 0.75 GiB a sequence).
+FUSED_HEAD_SEQUENCE_BYTES = 1024 ** 3
 
 log = get_logger("models", "lm")
 
@@ -58,7 +67,8 @@ def fused_head_by_shape(batch: int, seq: int, vocab: int,
     ``Trainer`` enters): the batch is split over the mesh's batch axes where
     it divides."""
     return (4 * heads * local_batch(batch) * seq * vocab
-            > FUSED_HEAD_LOGITS_BYTES)
+            > FUSED_HEAD_LOGITS_BYTES
+            or 4 * seq * vocab > FUSED_HEAD_SEQUENCE_BYTES)
 
 
 def exit_distribution(gate_logits: jax.Array) -> jax.Array:
@@ -370,6 +380,23 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
                 loss, _ = lm_loss(logits, batch["targets"])
         return loss, mut, None
 
+    def handed_counters(batch):
+        """Static, from shapes, where the stack's layers read earlier
+        layers' values or scan by Mamba-1 (none elsewhere): the layers that
+        read an earlier layer's keys and values or its memory, the chunks a
+        selective scan walks a sequence in and the bytes of entry states a
+        layer's scan keeps for its backward."""
+        rows, seq = batch["inputs"].shape
+        m = cfg.mamba1
+        if m is None:
+            return {}
+        counted = dict(
+            kv_readers=cfg.readers("kv"), memory_readers=cfg.readers("memory"),
+            sscan_chunks=sscan.chunks(seq),
+            sscan_state_bytes_kept=sscan.state_bytes_kept(
+                rows, seq, m.d_inner, m.d_state))
+        return {name: jnp.float32(n) for name, n in counted.items()}
+
     def metrics_of(loss, heads, counters=None):
         """The heads' metrics (a plain head's: its perplexity) and the
         expert layers' counters."""
@@ -392,7 +419,7 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
             return loss, metrics_of(loss, heads, counters)
         loss, _, heads = diffusion_loss(params, batch, rng) \
             if cfg.block_diffusion else _lm_loss_from(params, batch)
-        return loss, metrics_of(loss, heads)
+        return loss, metrics_of(loss, heads, handed_counters(batch))
 
     def eval_fn(params, batch, rng):
         if cfg.exit_gate:

@@ -46,6 +46,8 @@ from easydl_tpu.ops.flash_attention import BlockDiffusion
 from easydl_tpu.ops import moe as moe_ops
 from easydl_tpu.ops.moe import MoeMlp
 from easydl_tpu.ops.rope import apply_rope, rope_tables
+from easydl_tpu.ops.selective_scan import (selective_scan,
+                                           selective_scan_flops_per_token)
 from easydl_tpu.ops.ssd import (causal_conv1d, causal_conv1d_silu,
                                 gated_rmsnorm,
                                 ssd_flops_per_token, ssd_scan)
@@ -180,7 +182,15 @@ def _norm(cfg, name, dtype=None):
 #: ``none`` is a layer that is its mixer alone (NemotronH's ``M`` or ``*``
 #: followed by another mixer): no second norm, no second add.
 Layer = Tuple[str, str]
-MIXERS = ("attention", "mamba2")
+MIXERS = ("attention", "mamba2", "mamba1", "gmu")
+#: the mixers that are no attention kind: Mamba-2's and Mamba-1's scans and
+#: the gated memory unit, which multiplies an earlier Mamba-1 layer's scan
+#: output (``mamba1`` GIVES ``memory`` where a ``gmu`` behind it takes it)
+SCANS = ("mamba2", "mamba1", "gmu")
+#: what a layer may hand to the layers behind it (``TransformerConfig.
+#: handoffs``): a Mamba-1 layer's scan output, an attention layer's keys and
+#: values
+HANDED = ("memory", "kv")
 FFNS = ("gelu", "swiglu", "moe", "none")
 
 
@@ -262,7 +272,20 @@ class AttentionKind:
     on k in front of the rotary kernel, one learned gain of ``head_dim``
     for all of q's heads and one for k's (Qwen3's and SDAR's; a ``latent``
     or ``lowrank`` kind norms q and k in its own way and refuses this
-    one)."""
+    one).
+
+    ``diff`` (None: plain softmax attention) makes the kind DIFFERENTIAL
+    attention (arXiv:2410.05258) and is its ``lambda_init``: score heads pair
+    by neighbours, pair ``j`` of key/value group ``g = j // (heads /
+    kv_heads)`` scores ``q_{2j+c} k_{2g+c}^T`` for ``c`` in 0, 1 against ONE
+    value ``[v_{2g} ; v_{2g+1}]``, twice a head wide; ``o_j = (1 - diff) *
+    RMSNorm(P_0 V - lambda * P_1 V)`` with ``lambda = exp(lq1 . lk1) - exp(lq2
+    . lk2) + diff``, four learned vectors of a head's size and one gain of
+    twice that a layer. ``kv``: ``"gives"`` — the layer hands its keys and
+    values (after their bias) to the layers behind it — or ``"takes"`` — it
+    has a query and an output map alone and reads the nearest giver's
+    (SambaY's cross-decoder, arXiv:2507.06607). ``bias``: this kind's four
+    projections carry a bias though the description's (``bias``) do not."""
 
     n_heads: int = 0
     window: int = 0
@@ -271,6 +294,9 @@ class AttentionKind:
     latent: Optional[LatentMix] = None
     lowrank: Optional[LowRank] = None
     qk_norm: bool = False
+    diff: Optional[float] = None
+    kv: str = ""
+    bias: bool = False
 
 
 @dataclass(frozen=True)
@@ -366,6 +392,26 @@ class SsmConfig:
 
 
 @dataclass(frozen=True)
+class Mamba1Config:
+    """Widths of the ``mamba1`` mixers and the ``gmu`` units behind them
+    (``ops/selective_scan.py``): ``d_inner`` channels, each with ``d_state``
+    states of its own decay rate, a step size a channel made through a
+    bottleneck of ``dt_rank``, ``d_conv`` taps. ``view``: the channels travel
+    as ``[.., d_inner / view, view]``, the shape the convolution's kernels
+    keep turned (``ops/ssd.py``: note C)."""
+
+    d_inner: int
+    d_state: int = 16
+    dt_rank: int = 0
+    d_conv: int = 4
+    view: int = 64
+
+    @property
+    def channels(self) -> Tuple[int, int]:
+        return self.d_inner // self.view, self.view
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 50304            # GPT-2 vocab padded to a multiple of 128 (MXU tiling)
     d_model: int = 1024
@@ -444,6 +490,8 @@ class TransformerConfig:
     logits_scaling: float = 1.0
     #: widths of the ``mamba2`` mixers, where the description has any
     ssm: Optional[SsmConfig] = None
+    #: widths of the ``mamba1`` mixers and ``gmu`` units, where it has any
+    mamba1: Optional[Mamba1Config] = None
     #: a head's size where it is not ``d_model // n_heads`` (0: it is)
     head_size: int = 0
     #: named attention kinds a layer's mixer may be, ``(name, kind)`` pairs
@@ -479,6 +527,9 @@ class TransformerConfig:
                 raise ValueError("a mamba2 layer needs ssm=SsmConfig(...)")
             if ffn == "moe" and self.moe is None:
                 raise ValueError("a moe layer needs moe=MoeConfig(...)")
+            if mixer in ("mamba1", "gmu") and self.mamba1 is None:
+                raise ValueError(
+                    f"a {mixer} layer needs mamba1=Mamba1Config(...)")
         if self.position not in ("learned", "none", "rope"):
             raise ValueError(f"position must be 'learned', 'none' or 'rope', "
                              f"got {self.position!r}")
@@ -516,13 +567,48 @@ class TransformerConfig:
                     f"attention kind {name!r}: qk_norm on a latent or "
                     f"lowrank kind, which norms q and k in its own way "
                     f"(TransformerConfig.__post_init__ refuses the pair)")
+        for name, kind in self.attention_kinds:
+            if kind.diff is None and not (kind.kv or kind.bias):
+                continue
+            if kind.kv not in ("", "gives", "takes") or kind.diff is None \
+                    or kind.latent or kind.lowrank or kind.gate or \
+                    kind.qk_norm or kind.rope or self.position == "rope" or \
+                    (kind.n_heads or self.n_heads) % 2 or self.kv_heads % 2 \
+                    or (kind.kv == "takes" and kind.window):
+                raise ValueError(
+                    f"attention kind {name!r}: differential attention (diff="
+                    f"{kind.diff}, kv={kind.kv!r}) pairs an even number of "
+                    f"score heads over an even number of key/value heads, "
+                    f"carries no rotary scheme, gate, q/k norm or latent, "
+                    f"kv is '', 'gives' or 'takes', a taker has no window, "
+                    f"and kv or bias stand on a diff kind alone "
+                    f"(TransformerConfig.__post_init__ refuses it)")
+        takers = [(i, mixer, name) for i, (mixer, _) in
+                  enumerate(self.every_layer)
+                  for name in HANDED if name == self._takes(mixer)]
+        for i, mixer, name in takers:
+            if not any(self._gives(m) == name
+                       for m, _ in self.every_layer[:i]):
+                raise ValueError(
+                    f"layer {i} ({mixer!r}) takes {name!r} and no layer in "
+                    f"front of it gives it: a 'gmu' reads the 'mamba1' layer "
+                    f"before it, an attention kind with kv='takes' one with "
+                    f"kv='gives' (TransformerConfig.__post_init__ refuses "
+                    f"it)")
+        if takers and (self.loops > 1 or self.pipeline_fn is not None or
+                       self.attention_fn is not None or self.mtp):
+            raise NotImplementedError(
+                "a layer that reads an earlier layer's memory or keys and "
+                "values in a looped, pipelined or sequence-parallel stack or "
+                "in front of a multi-token-prediction module")
         if self.block_diffusion:
             kinds_used = [self.attention_kind(mixer)
                           for mixer, _ in self.every_layer
-                          if mixer != "mamba2"]
+                          if mixer not in SCANS]
             refused = [what for what, found in (
                 ("causal=True", self.causal),
-                ("a mamba2 layer", len(kinds_used) < len(self.every_layer)),
+                ("a mamba2, mamba1 or gmu layer",
+                 len(kinds_used) < len(self.every_layer)),
                 ("a window", any(k.window for k in kinds_used)),
                 ("a latent or lowrank attention kind",
                  any(k.latent or k.lowrank for k in kinds_used)),
@@ -545,13 +631,13 @@ class TransformerConfig:
         if self.mtp is not None and (
                 self.loops > 1 or self.exit_gate or self.pipeline_fn
                 is not None or self.attention_fn is not None or
-                self.router_state_width or self.mtp.mixer == "mamba2" or
+                self.router_state_width or self.mtp.mixer in SCANS or
                 self.embedding_multiplier != 1.0):
             raise NotImplementedError(
                 "a multi-token-prediction module behind a looped, gated, "
                 "pipelined or sequence-parallel stack, one whose router "
                 "state runs through the depth or whose embedding has a "
-                "multiplier, or on a mamba2 layer")
+                "multiplier, or on a mamba2, mamba1 or gmu layer")
 
     @property
     def head_dim(self) -> int:
@@ -566,6 +652,46 @@ class TransformerConfig:
         """The numbers of an attention mixer; plain ``attention`` is the
         kind with none of its own."""
         return dict(self.attention_kinds).get(mixer, AttentionKind())
+
+    def _takes(self, mixer: str) -> str:
+        """What of :data:`HANDED` a layer of ``mixer`` reads ('': nothing)."""
+        if mixer in SCANS:
+            return "memory" if mixer == "gmu" else ""
+        return "kv" if self.attention_kind(mixer).kv == "takes" else ""
+
+    def _gives(self, mixer: str) -> str:
+        """What of :data:`HANDED` a layer of ``mixer`` can hand on."""
+        if mixer in SCANS:
+            return "memory" if mixer == "mamba1" else ""
+        return "kv" if self.attention_kind(mixer).kv == "gives" else ""
+
+    @property
+    def handoffs(self) -> Tuple[Tuple[Tuple[str, ...], Tuple[str, ...]], ...]:
+        """For each run of :attr:`runs`, ``(carried, gives)``: the names of
+        :data:`HANDED` that come to the run beside the residual stream
+        (given in front of it, taken by it or behind it) and those its
+        layers give — a run gives a name where a layer behind it takes it
+        before another run gives it again. Empty pairs everywhere but in a
+        stack whose layers read earlier layers' values."""
+        mixers = [mixer for (mixer, _), _ in self.runs]
+        gives = []
+        for i, mixer in enumerate(mixers):
+            name, behind = self._gives(mixer), mixers[i + 1:]
+            upto = next((j for j, m in enumerate(behind)
+                         if self._gives(m) == name), len(behind))
+            taken = name and any(self._takes(m) == name
+                                 for m in behind[:upto + 1])
+            gives.append((name,) if taken else ())
+        return tuple(
+            (tuple(name for name in HANDED
+                   if any(name in given for given in gives[:i])
+                   and any(self._takes(m) == name for m in mixers[i:])),
+             gives[i]) for i in range(len(mixers)))
+
+    def readers(self, name: str) -> int:
+        """Layers that read an earlier layer's ``name`` of :data:`HANDED`."""
+        return sum(1 for mixer, _ in self.pattern
+                   if self._takes(mixer) == name)
 
     @property
     def every_layer(self) -> Tuple[Layer, ...]:
@@ -621,7 +747,25 @@ class TransformerConfig:
         norm less."""
         mixer, ffn = layer
         d = self.d_model
-        if mixer != "mamba2" and self.attention_kind(mixer).lowrank:
+        if mixer in ("mamba1", "gmu"):
+            m = self.mamba1
+            n = 2 * d * m.d_inner                       # in and out
+            if mixer == "mamba1":
+                n += (d * m.d_inner                                # z
+                      + (m.d_conv + 1) * m.d_inner                 # taps, bias
+                      + m.d_inner * (m.dt_rank + 2 * m.d_state)    # dt, B, C
+                      + (m.dt_rank + 1) * m.d_inner                # dt up, bias
+                      + (m.d_state + 1) * m.d_inner)               # A, D
+        elif mixer != "mamba2" and self.attention_kind(mixer).diff is not None:
+            kind = self.attention_kind(mixer)
+            inner = (kind.n_heads or self.n_heads) * self.head_dim
+            held = 0 if kind.kv == "takes" else 2 * self.kv_heads * self.head_dim
+            # q and out (the pairs' values are as wide as their score heads
+            # together), k and v, the four lambda vectors, the inner gain
+            n = 2 * d * inner + d * held + 6 * self.head_dim
+            if kind.bias and not self.bias:
+                n += inner + held + d
+        elif mixer != "mamba2" and self.attention_kind(mixer).lowrank:
             low = self.attention_kind(mixer).lowrank
             heads = self.attention_kind(mixer).n_heads or self.n_heads
             # down, the norm's gain and up, for q and for k / v; the way back
@@ -715,7 +859,7 @@ class TransformerConfig:
         every layer (its noised and its clean one) and one through the head,
         and sees ``seq + block`` keys a layer (``seq² + seq · block`` live
         pairs a sequence)."""
-        n_attn = sum(1 for mixer, _ in self.pattern if mixer != "mamba2")
+        n_attn = sum(1 for mixer, _ in self.pattern if mixer not in SCANS)
         head = self.vocab * self.d_model
         held = sum(self.layer_params(l) for l in self.pattern) + head
         looped = sum(self.layer_params(l, active=True)
@@ -724,18 +868,25 @@ class TransformerConfig:
         once = self.param_count - held - lookup
         scores = 0.0
         for mixer, _ in self.every_layer:
-            if mixer != "mamba2":
+            if mixer not in SCANS:
                 kind = self.attention_kind(mixer)
                 # S = Q K^T at the scores' head size and P V at the values'
                 sizes = 2 * self.head_dim if kind.lowrank is None else \
                     self.head_dim + kind.lowrank.value_dim
+                if kind.diff is not None:  # a pair's value: two heads wide
+                    sizes = 3 * self.head_dim
                 scores += 6.0 * (kind.n_heads or self.n_heads) * sizes * (
                     seq_len + self.block_diffusion if self.block_diffusion
                     else min(kind.window or seq_len, seq_len))
-        if n_attn < len(self.pattern):
+        n_ssd = sum(1 for mixer, _ in self.pattern if mixer == "mamba2")
+        if n_ssd:
             m = self.ssm
-            scores += 3.0 * (len(self.pattern) - n_attn) * ssd_flops_per_token(
+            scores += 3.0 * n_ssd * ssd_flops_per_token(
                 m.n_heads, m.head_dim, m.d_state, m.n_groups, m.chunk)
+        n_scan = sum(1 for mixer, _ in self.pattern if mixer == "mamba1")
+        if n_scan:
+            scores += 3.0 * n_scan * selective_scan_flops_per_token(
+                self.mamba1.d_inner, self.mamba1.d_state)
         # the module's layer at its active count, and the head once more
         module = self._mtp_params(active=True) + head if self.mtp else 0
         once -= self._mtp_params()
@@ -744,14 +895,36 @@ class TransformerConfig:
         return 6.0 * (once + module) + self.loops * (6.0 * looped + scores)
 
 
+def _uniform_init(fan_in: int):
+    """torch's Conv1d default for a filter's taps: uniform in ``+-1 /
+    sqrt(fan_in)``."""
+    bound = fan_in ** -0.5
+
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    return init
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Mamba's own start of a step's bias: dt from ``exp(U(log 1e-3, log
+    1e-1))``, at least 1e-4, through the inverse of softplus."""
+    dt0 = jnp.exp(jax.random.uniform(
+        key, shape, dtype, jnp.log(1e-3), jnp.log(1e-1)))
+    dt0 = jnp.maximum(dt0, 1e-4)
+    return dt0 + jnp.log(-jnp.expm1(-dt0))
+
+
 # The mixers and the FFN are functions of the block, not methods of it: flax
 # wraps a Module's methods in a named scope of their own (``blocks._ffn``),
 # which would put a new component into every operation's path.
 def _projection(block, features, kernel_axes, bias_axes, name,
-                residual=False, axis=-1, rows=False):
+                residual=False, axis=-1, rows=False, bias=False):
+    """``bias``: this projection carries one though the description's do
+    not (``AttentionKind.bias``)."""
     cfg = block.cfg
     return _dense(
-        features, kernel_axes, bias_axes, name=name, use_bias=cfg.bias,
+        features, kernel_axes, bias_axes, name=name,
+        use_bias=cfg.bias or bias,
         # GPT-2 residual scaling on the projections that write the
         # residual stream
         init_scale=(2 * cfg.n_layers) ** -0.5 if residual else 1.0,
@@ -778,18 +951,13 @@ def _latent_mix(block, mix, q, k, v):
         return block.param(name, nn.with_logical_partitioning(init, axes),
                            shape).astype(f32)
 
-    def uniform(fan_in):
-        # torch's Conv1d default for the taps: uniform in +-1/sqrt(fan_in).
-        # The biases start at zero, as every bias of this stack does: a
-        # constant in q is a score every query gives a key alike, and all
-        # positions then attend to the same few tokens of a sequence — at
-        # seeded weights the routers' load on the experts held followed the
-        # seed by 10% either way (PERF.md section 6, PR 35)
-        bound = fan_in ** -0.5
-
-        def init(key, shape, dtype=jnp.float32):
-            return jax.random.uniform(key, shape, dtype, -bound, bound)
-        return init
+    # torch's Conv1d default for the taps. The biases start at zero, as
+    # every bias of this stack does: a constant in q is a score every query
+    # gives a key alike, and all positions then attend to the same few
+    # tokens of a sequence — at seeded weights the routers' load on the
+    # experts held followed the seed by 10% either way (PERF.md section 6,
+    # PR 35)
+    uniform = _uniform_init
 
     with jax.named_scope("cca_conv"):
         # q's and k's heads are groups alike: ten heads of channels
@@ -998,13 +1166,8 @@ def _mamba2(block, u):
                      "in_dt")(u)
 
     def conv(name, a, axes):
-        # torch's Conv1d default: uniform in +-1/sqrt(fan_in), fan_in
-        # the taps of a depthwise filter
-        bound = m.d_conv ** -0.5
-
-        def init(key, shape, dtype=jnp.float32):
-            return jax.random.uniform(key, shape, dtype, -bound, bound)
-
+        # torch's Conv1d default, fan_in the taps of a depthwise filter
+        init = _uniform_init(m.d_conv)
         w = block.param(f"conv_{name}", nn.with_logical_partitioning(
             init, (None,) + axes), (m.d_conv,) + a.shape[2:])
         b = block.param(f"conv_{name}_bias", nn.with_logical_partitioning(
@@ -1021,18 +1184,11 @@ def _mamba2(block, u):
         return block.param(name, nn.with_logical_partitioning(
             init, ("heads",)), (m.n_heads,))
 
-    # Mamba-2's own: dt from exp(U(log 1e-3, log 1e-1)) through the
-    # inverse of softplus, A from U(1, 16), D ones
-    def dt_bias_init(key, shape, dtype=jnp.float32):
-        dt0 = jnp.exp(jax.random.uniform(
-            key, shape, dtype, jnp.log(1e-3), jnp.log(1e-1)))
-        dt0 = jnp.maximum(dt0, 1e-4)
-        return dt0 + jnp.log(-jnp.expm1(-dt0))
-
+    # Mamba-2's own: dt's bias (`_dt_bias_init`), A from U(1, 16), D ones
     def a_log_init(key, shape, dtype=jnp.float32):
         return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
-    dt_bias = per_head("dt_bias", dt_bias_init)
+    dt_bias = per_head("dt_bias", _dt_bias_init)
     a_log = per_head("A_log", a_log_init)
     skip = per_head("D", nn.initializers.ones_init())
     dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
@@ -1052,6 +1208,153 @@ def _mamba2(block, u):
     y = normed
     return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
                        ("embed",), "out", residual=True, axis=(-2, -1))(y)
+
+
+def _diff_lambda(vector, init: float):
+    """A differential layer's lambda from its four learned vectors
+    (``vector(name)``) and ``lambda_init``: a scalar, float32."""
+    return (jnp.exp(jnp.sum(vector("lambda_q1") * vector("lambda_k1")))
+            - jnp.exp(jnp.sum(vector("lambda_q2") * vector("lambda_k2")))
+            + init)
+
+
+def _diff_attention(block, kind, h, kv=None):
+    """Differential attention (:class:`AttentionKind` ``diff``) on the
+    normed input ``h``: ``(the layer's output, the keys and values it made
+    or None)``. ``kv``: a giver's ``(k, v)`` — the kind takes them and has
+    no key or value map. ONE attention call of score heads ``head_dim`` deep
+    against values twice that wide (``ops/flash_attention.py``: the calls
+    named ``diff_*``, ``swa_*`` under a window), each softmax computed once:
+    score head ``2 j + c`` is handed key head ``2 g + c`` and the value
+    ``[v_2g ; v_2g+1]`` of its group, repeated in front of the call as
+    grouped-query attention's are. Everything between the call's result and
+    the output map — the difference under lambda, the RMSNorm over a pair's
+    value, ``1 - lambda_init`` — stands under ``diff_combine``, float32."""
+    cfg = block.cfg
+    f32 = jnp.float32
+    n_heads, groups, d = kind.n_heads or cfg.n_heads, cfg.kv_heads, \
+        cfg.head_dim
+    heads, axes = ("embed", "heads", "kv"), ("heads", "kv")
+    q = _projection(block, (n_heads, d), heads, axes, "q", rows=True,
+                    bias=kind.bias)(h)
+    given = None
+    if kv is None:
+        k, v = (_projection(block, (groups, d), heads, axes, name, rows=True,
+                            bias=kind.bias)(h) for name in ("k", "v"))
+        given = (k, v)
+    else:
+        k, v = kv
+    # per value group (two key heads): heads / kv_heads pairs of score heads
+    per = n_heads // groups
+    lead = k.shape[:2]
+    k_heads = jnp.broadcast_to(
+        k.reshape(*lead, groups // 2, 1, 2, d),
+        (*lead, groups // 2, per, 2, d)).reshape(*lead, n_heads, d)
+    v_heads = jnp.broadcast_to(
+        v.reshape(*lead, groups // 2, 1, 2 * d),
+        (*lead, groups // 2, 2 * per, 2 * d)).reshape(*lead, n_heads, 2 * d)
+    q, k_heads, v_heads = (nn.with_logical_constraint(
+        a, ("batch", "seq", "heads", "kv")) for a in (q, k_heads, v_heads))
+    attn = multihead_attention(
+        q, k_heads, v_heads, causal=cfg.causal, impl=cfg.attention_impl,
+        scale=cfg.attention_multiplier, window=kind.window or None)
+    with jax.named_scope("diff_combine"):
+        def vector(name):
+            return block.param(name, nn.with_logical_partitioning(
+                nn.initializers.normal(stddev=0.1), (None,)), (d,)).astype(f32)
+
+        lam = _diff_lambda(vector, kind.diff)
+        pair = attn.astype(f32).reshape(*lead, n_heads // 2, 2, 2 * d)
+        before = pair[..., 0, :] - lam * pair[..., 1, :]
+        gain = block.param("sub_norm", nn.with_logical_partitioning(
+            nn.initializers.ones_init(), (None,)), (2 * d,)).astype(f32)
+        normed = before * jax.lax.rsqrt(
+            jnp.mean(before * before, -1, keepdims=True) + cfg.norm_eps)
+        out = (normed * gain * (1.0 - kind.diff)).astype(attn.dtype)
+    # what the call was given and gave, where `intermediates` is a mutable
+    # collection (the benchmark's check, tests)
+    for name, value in (("in", h), ("q", q), ("k", k), ("v", v),
+                        ("attn", attn), ("before_norm", before),
+                        ("out", out)):
+        block.sow("intermediates", f"diff_{name}", value)
+    return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                       ("embed",), "out", residual=True, axis=(-2, -1),
+                       rows=True, bias=kind.bias)(out), given
+
+
+def _mamba1(block, u):
+    """The Mamba-1 mixer (arXiv:2312.00752) on the normed input ``u``:
+    ``(the layer's output, the scan's output y)`` — ``y`` with its ``D x``
+    term and before the ``z`` gate is what a gated memory unit behind the
+    layer multiplies. The published fused input map ``[x ; z]`` is two
+    projections here, the same mathematics column by column; the channels
+    travel as ``[.., d_inner / view, view]`` (:class:`Mamba1Config`). The
+    convolution under ``conv1d`` (its bias and SiLU with it) is
+    ``ops/ssd.py``'s and the scan under ``selective_scan``
+    ``ops/selective_scan.py``'s: their Pallas kernels on a TPU where the
+    widths tile, else ``jax.numpy``. The step sizes, the rates and the scan's
+    state are float32."""
+    cfg, m = block.cfg, block.cfg.mamba1
+    f32 = jnp.float32
+    heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
+    x = _projection(block, m.channels, heads, kv, "in_x")(u)
+    z = _projection(block, m.channels, heads, kv, "in_z")(u)
+
+    conv_init = _uniform_init(m.d_conv)  # fan_in a depthwise filter's taps
+    with jax.named_scope("conv1d"):
+        w = block.param("conv_x", nn.with_logical_partitioning(
+            conv_init, (None,) + kv), (m.d_conv,) + m.channels)
+        b = block.param("conv_x_bias", nn.with_logical_partitioning(
+            conv_init, kv), m.channels)
+        x = causal_conv1d_silu(x, w, b)
+    # the step's bottleneck, B_t and C_t from the convolved x in one map
+    low = _projection(block, m.dt_rank + 2 * m.d_state,
+                      ("heads", "kv", None), (None,), "x_proj",
+                      axis=(-2, -1))(x)
+    B, C = (low[..., m.dt_rank + i * m.d_state:
+                m.dt_rank + (i + 1) * m.d_state] for i in (0, 1))
+    # the steps, the rates and the skip weight by channel, flat: dt's rows
+    # `[batch, seq, d_inner]` are the scan's operand as the product leaves them
+    dt = _projection(block, m.d_inner, (None, "heads"), ("heads",),
+                     "dt_proj")(low[..., :m.dt_rank])
+
+    def channel(name, init, *more):
+        return block.param(name, nn.with_logical_partitioning(
+            init, ("heads",) + (None,) * len(more)), (m.d_inner,) + more)
+
+    # Mamba's own: dt's bias (`_dt_bias_init`), A = -(1 .. d_state) a
+    # channel, D ones
+    def a_log_init(key, shape, dtype=jnp.float32):
+        return jnp.broadcast_to(jnp.log(jnp.arange(
+            1, shape[-1] + 1, dtype=dtype)), shape)
+
+    dt_bias = channel("dt_bias", _dt_bias_init)
+    a_log = channel("A_log", a_log_init, m.d_state)
+    skip = channel("D", nn.initializers.ones_init())
+    dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
+    with jax.named_scope("selective_scan"):
+        y = selective_scan(x, dt, -jnp.exp(a_log.astype(f32)), B, C, skip)
+    for name, value in (("in", u), ("x", x), ("z", z), ("B", B), ("C", C),
+                        ("dt", dt), ("y", y)):
+        block.sow("intermediates", f"scan_{name}", value)
+    gated = y * nn.silu(z)
+    return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                       ("embed",), "out", residual=True, axis=(-2, -1)
+                       )(gated), y
+
+
+def _gmu(block, u, memory):
+    """The gated memory unit (SambaY, arXiv:2507.06607) on the normed input
+    ``u``: ``(memory * silu(u W_1)) W_2``, ``memory`` an earlier Mamba-1
+    layer's scan output as that layer handed it on."""
+    cfg, m = block.cfg, block.cfg.mamba1
+    with jax.named_scope("gmu"):
+        gate = _projection(block, m.channels, ("embed", "heads", "kv"),
+                           ("heads", "kv"), "in_gate")(u)
+        block.sow("intermediates", "gmu_memory", memory)
+        return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                           ("embed",), "out", residual=True, axis=(-2, -1)
+                           )(memory * nn.silu(gate))
 
 
 def _ffn(block, h, state=None):
@@ -1111,6 +1414,13 @@ class Block(nn.Module):
     cfg: TransformerConfig
     mixer: str = "attention"
     ffn: str = "gelu"
+    #: names of :data:`HANDED` that come beside the residual stream, the
+    #: carry then ``(x, {name: value})``, and those this layer gives: the
+    #: second result is then ``(aux, {name: value})``, which a scan stacks by
+    #: layer and the stack hands to the runs behind
+    #: (``TransformerConfig.handoffs``)
+    carried: Tuple[str, ...] = ()
+    gives: Tuple[str, ...] = ()
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, rope=None):
@@ -1118,7 +1428,9 @@ class Block(nn.Module):
         # kwargs.
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
-        state = None
+        state, handed, given = None, {}, {}
+        if self.carried:
+            x, handed = x
         if cfg.router_state_width:
             x, state = x
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
@@ -1151,7 +1463,25 @@ class Block(nn.Module):
         # (read by the device trace's reducers); flax's module names sit
         # inside them.
         with remat.tally() as named:
-            if self.mixer != "mamba2":
+            if self.mixer in ("mamba1", "gmu"):
+                with jax.named_scope("ssm"):
+                    u = _norm(cfg, "ln_ssm", dtype=dt)(x)
+                    if self.mixer == "gmu":
+                        h = _gmu(self, u, handed["memory"])
+                    else:
+                        h, given["memory"] = _mamba1(self, u)
+                    x = residual(x, h, "ln_ssm")
+            elif self.mixer != "mamba2" and \
+                    cfg.attention_kind(self.mixer).diff is not None:
+                kind = cfg.attention_kind(self.mixer)
+                with jax.named_scope("attention"), (
+                        jax.named_scope("cross") if kind.kv == "takes"
+                        else contextlib.nullcontext()):
+                    h, given["kv"] = _diff_attention(
+                        self, kind, _norm(cfg, "ln_attn", dtype=dt)(x),
+                        handed.get("kv") if kind.kv == "takes" else None)
+                    x = residual(x, h, "ln_attn")
+            elif self.mixer != "mamba2":
                 with jax.named_scope("attention"):
                     x = residual(x, _attention(
                         self, _norm(cfg, "ln_attn", dtype=dt)(x), rope),
@@ -1196,7 +1526,10 @@ class Block(nn.Module):
                               f"{remat.FLASH_KEEP_FLOP_PER_BYTE:,})"
                               for cost in costs))
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-        return (x, state) if cfg.router_state_width else x, aux
+        x = (x, state) if cfg.router_state_width else x
+        if self.gives:
+            aux = (aux, {name: given[name] for name in self.gives})
+        return (x, handed) if self.carried else x, aux
 
 
 def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic,
@@ -1358,8 +1691,19 @@ class Transformer(nn.Module):
                 )
             # "full" keeps the flash forward's results where ops/remat.py's
             # rule picks the call, and nothing else
-            block_cls = nn.remat(Block, prevent_cse=False,
-                                 policy=remat.policy(cfg.remat_policy))
+            # `prevent_cse=False` is right inside a loop, which a scanned
+            # run is. A run of ONE layer is no loop once XLA has unrolled
+            # it, and the layer's second forward is then merged with its
+            # first: the run keeps every activation. Where NO run of the
+            # stack is a loop (every layer of another kind than the one in
+            # front of it: Phi-4-mini-flash's six) remat would do nothing at
+            # all, so there the barrier stands (16.9 GB for 12.3 GiB compiled:
+            # PERF.md section 6, PR 53); a stack with a scanned run keeps
+            # the program it had
+            block_cls = nn.remat(
+                Block, policy=remat.policy(cfg.remat_policy),
+                prevent_cse=cfg.n_layers > 1 and all(
+                    count == 1 for _, count in cfg.runs))
         # One traced block a run of equal layers, scanned over a stacked
         # 'layers' param axis: `blocks` where the whole stack is one run
         # (GPT-2, BERT), `blocks_<i>` where the pattern has several.
@@ -1426,17 +1770,30 @@ class Transformer(nn.Module):
                 # the first layer
                 x = (x, jnp.zeros((*x.shape[:2], cfg.router_state_width),
                                   jnp.float32))
-            for i, ((mixer, ffn), count) in enumerate(runs):
+            # what earlier layers handed on (``cfg.handoffs``): a run takes
+            # beside the stream what it or a run behind it reads, and what
+            # its last layer gives goes to the runs behind
+            handed = {}
+            for i, (((mixer, ffn), count), (carried, gives)) in enumerate(
+                    zip(runs, cfg.handoffs)):
                 rope = ropes.get(mixer)
                 if cfg.pipeline_fn is None or stack.is_initializing():
                     # plain (or init) path: params are created here with
                     # the stacked [n_layers, ...] layout the pipeline also
                     # expects
+                    if carried:
+                        x = (x, {name: handed[name] for name in carried})
                     x, layer_aux = nn.scan(
                         block_cls, length=count, **scan_kwargs)(
-                            cfg, mixer, ffn,
+                            cfg, mixer, ffn, *((carried, gives) if carried
+                                               or gives else ()),
                             name="blocks" if len(runs) == 1 else f"blocks_{i}"
                     )(x, deterministic, rope)
+                    if carried:
+                        x, _ = x
+                    if gives:
+                        layer_aux, given = layer_aux
+                        handed.update(jax.tree.map(lambda a: a[-1], given))
                 else:
                     x, layer_aux = _pipelined(
                         stack, block_cls, scan_kwargs, mixer, ffn, x,

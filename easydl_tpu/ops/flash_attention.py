@@ -214,7 +214,11 @@ dO — and every product keeps its own depth (``S = K Qᵀ`` 192, ``P V`` and
 half again those products and the bytes of v, O and dO. Where the two are
 equal the kernels lower to what they lowered to with one; where they differ
 the calls carry names of their own (``mla_fwd``, ``mla_bwd``; unrolled
-``mla_bwd_dq``, ``mla_bwd_dkv``) and the band path is not offered. A head count the
+``mla_bwd_dq``, ``mla_bwd_dkv``; ``diff_*`` where the values are the wider:
+differential attention's pair of score heads of 64 against one value of 128).
+Under a window the band path keeps its names (``swa_*``) and takes values
+the wider, its blocks of v, O and dO as wide as they; scores deeper than the
+values under a window are refused (no model has them). A head count the
 tile does not divide (25 heads of 64: 13 lane blocks) leaves the last cell
 half outside the array: Pallas reads and writes only the part inside, and
 since every head is computed from its own slice alone, whatever the other
@@ -791,14 +795,17 @@ def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
                mask: Optional[BlockDiffusion] = None):
     """A looped kernel's ``name=`` (and compiler parameters: the call's own
     ``params``, else the two-size allowance) by what it computes:
-    ``flash_*``, ``swa_*`` under a window, ``mla_*`` where the scores' and
-    the values' head sizes differ, ``bd_*`` under the block mask (whose
+    ``flash_*``, ``swa_*`` under a window, where the scores' and the values'
+    head sizes differ ``mla_*`` (latent attention: scores deeper than the
+    values are wide) or ``diff_*`` (differential attention: a pair's value
+    wider than its scores are deep), ``bd_*`` under the block mask (whose
     forward and unrolled backward hold ``2 seam`` rows of k and v, or of q,
     O and dO, whole: the two-size allowance of VMEM)."""
     if mask is not None:
         named = dict(name=f"bd_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
     elif d != dv:
-        named = dict(name=f"mla_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
+        named = dict(name=f"{'mla' if d > dv else 'diff'}_{kernel}",
+                     compiler_params=_TWO_SIZE_PARAMS)
     else:
         named = dict(name=f"{'flash' if window is None else 'swa'}_{kernel}")
     if params is not None:
@@ -1095,6 +1102,22 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
         return pl.BlockSpec((None, s_k, cell * size),
                             lambda b, h, qi: (b, 0, h))
 
+    # what a looped cell holds: its heads' q and out blocks and the WHOLE of
+    # their k and v, two buffers each, the lse column (one lane in 128) and
+    # the sums. Stated, as the one-kernel backward states its own, where
+    # that and a pair's tiles in flight pass the compiler's own 16 MB and no
+    # allowance is named (`_call_name`): a causal forward at 16,384 rows of
+    # heads of 128 holds 17.3 MB of k and v alone and did not compile
+    # (PERF.md section 7, SDAR's (h)); every call that fitted keeps its text
+    params = None
+    held = (2 * cell * ((cell_rows + s_k) * (d + dv) * q.dtype.itemsize
+                        + cell_rows * 128 * 4)
+            + cell * (dv + 16) * block_q * 4)
+    if not unroll and d == dv and mask is None and \
+            held + _TILES_VMEM > _DEFAULT_VMEM:
+        params = pltpu.CompilerParams(
+            vmem_limit_bytes=held + _DEFAULT_VMEM)
+
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, pl.cdiv(heads, cell), s_q // cell_rows),
@@ -1114,7 +1137,7 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
             pltpu.VMEM((cell, 1, block_q), jnp.float32),
             pltpu.VMEM((cell, dv, block_q), jnp.float32)],
         interpret=interpret,
-        **_call_name("fwd", d, dv, window, mask=mask),
+        **_call_name("fwd", d, dv, window, params, mask=mask),
     )(q, k, v)
     return out, lse
 
@@ -1453,8 +1476,11 @@ def _bwd_dkv_kernel(
 
 #: what the compiler allows a kernel of VMEM where the call names no limit:
 #: the one-kernel backward asks for what its blocks hold and this much for
-#: a pair's tiles in flight (all the split looped kernels had)
+#: a pair's tiles in flight (all the split looped kernels had); the looped
+#: forward states its limit where its blocks leave less than `_TILES_VMEM`
+#: of it
 _DEFAULT_VMEM = 16 << 20
+_TILES_VMEM = 4 << 20
 
 
 def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
@@ -1651,14 +1677,15 @@ def _neighbour_cap(hidden):
 
 def _band_fwd_kernel(
     q_ref, k_ref, v_ref, k_prev_ref, v_prev_ref, o_ref, lse_ref,
-    *, head_dim: int, band: Band, window: int, scale: float,
+    *, head_dim: int, value_dim: int, band: Band, window: int, scale: float,
 ):
-    # q_ref, k_ref, v_ref, o_ref: [cell rows, cell heads · d]; k_prev_ref,
-    # v_prev_ref: [reach, cell heads · d], the block before the cell's own;
-    # lse_ref: [cell heads, cell rows, 1]
+    # q_ref, k_ref: [cell rows, cell heads · d]; v_ref, o_ref: [cell rows,
+    # cell heads · dv]; k_prev_ref, v_prev_ref: [reach, ..], the block before
+    # the cell's own; lse_ref: [cell heads, cell rows, 1]
     rows, lanes = q_ref.shape
     d, sub, reach = head_dim, band.sub, band.reach
     heads = _head_cols(lanes, d)
+    wide = _head_cols(v_ref.shape[1], value_dim)
     masks = _BandMasks(window)
     cap = _neighbour_cap(pl.program_id(2) == 0)
     # V turned once a cell for all its heads and sub-blocks
@@ -1675,7 +1702,7 @@ def _band_fwd_kernel(
                 ref_k, vt, base = ((k_prev_ref, vt_prev, reach) if start < 0
                                    else (k_ref, vt_own, 0))
                 there = slice(base + start, base + stop)
-                vts.append(vt[cols, there])
+                vts.append(vt[wide[g], there])
                 st = masks(_scores_t(ref_k[there, cols], q, s_scale, None),
                            at - start, far, near)
                 sts.append(jnp.minimum(st, cap) if start < 0 else st)
@@ -1709,12 +1736,14 @@ def _band_fwd_kernel(
 
 def _band_dq_kernel(
     q_ref, k_ref, v_ref, k_prev_ref, v_prev_ref, o_ref, do_ref, lse_ref,
-    dq_ref, *, head_dim: int, band: Band, window: int, scale: float,
+    dq_ref, *, head_dim: int, value_dim: int, band: Band, window: int,
+    scale: float,
 ):
     # lse_ref: [cell heads, 1, 1, cell rows]
     rows, lanes = q_ref.shape
     d, sub, reach = head_dim, band.sub, band.reach
     heads = _head_cols(lanes, d)
+    wide = _head_cols(v_ref.shape[1], value_dim)
     masks = _BandMasks(window)
     cap = _neighbour_cap(pl.program_id(2) == 0)
     # K turned once a cell for all its heads and sub-blocks
@@ -1726,7 +1755,7 @@ def _band_dq_kernel(
         found = []
         for g, cols in enumerate(heads):
             q, s_scale = _fold_scale(q_ref[mine, cols], scale)
-            do = do_ref[mine, cols]
+            do = do_ref[mine, wide[g]]
             parts = []
             for start, stop, far, near in pieces:
                 ref_k, ref_v, kt, base = (
@@ -1737,14 +1766,14 @@ def _band_dq_kernel(
                            at - start, far, near)
                 if start < 0:
                     st = jnp.minimum(st, cap)
-                parts.append((st, _dot(ref_v[there, cols], do, _NT),
+                parts.append((st, _dot(ref_v[there, wide[g]], do, _NT),
                               kt[cols, there]))
             found.append(parts)
         return found
 
     def finish(at, found):
         mine = slice(at, at + sub)
-        deltas = _delta_rows(do_ref[mine, :], o_ref[mine, :], d)
+        deltas = _delta_rows(do_ref[mine, :], o_ref[mine, :], value_dim)
         dq_ts = []
         for g, parts in enumerate(found):
             lse = lse_ref[g, 0, :, mine]
@@ -1762,23 +1791,25 @@ def _band_dq_kernel(
 def _band_dkv_kernel(
     k_ref, v_ref, q_ref, o_ref, do_ref, lse_ref, q_next_ref, o_next_ref,
     do_next_ref, lse_next_ref, dk_ref, dv_ref, delta_ref,
-    *, head_dim: int, band: Band, window: int, scale: float,
+    *, head_dim: int, value_dim: int, band: Band, window: int, scale: float,
 ):
-    # k_ref, v_ref, q_ref, o_ref, do_ref, dk_ref, dv_ref: [cell rows, cell
-    # heads · d]; the *_next_ref: [reach, ..], the block after the cell's
+    # k_ref, q_ref, dk_ref: [cell rows, cell heads · d]; v_ref, o_ref,
+    # do_ref, dv_ref: [cell rows, cell heads · dv]; the *_next_ref: [reach,
+    # ..], the block after the cell's
     # own; lse_ref: [cell heads, 1, 1, cell rows], lse_next_ref: [.., reach];
     # delta_ref (scratch): [cell heads, 1, cell rows + reach]
     rows, lanes = k_ref.shape
     d, sub = head_dim, band.sub
     heads = _head_cols(lanes, d)
+    wide = _head_cols(v_ref.shape[1], value_dim)
     masks = _BandMasks(window)
     cap = _neighbour_cap(pl.program_id(2) == pl.num_programs(2) - 1)
     # delta of every query the cell meets, once: rows [0, rows) its own
     # block's, the rest its neighbour's (a ref, so that a piece's lanes are
     # read and not cut out of a value)
     for g, (own, nxt) in enumerate(zip(
-            _delta_rows(do_ref[...], o_ref[...], d),
-            _delta_rows(do_next_ref[...], o_next_ref[...], d))):
+            _delta_rows(do_ref[...], o_ref[...], value_dim),
+            _delta_rows(do_next_ref[...], o_next_ref[...], value_dim))):
         delta_ref[g, :, :rows] = own
         delta_ref[g, :, rows:] = nxt
     for at in range(0, rows, sub):
@@ -1787,14 +1818,14 @@ def _band_dkv_kernel(
         dks, dvs = [], []
         for g, cols in enumerate(heads):
             k, s_scale = _fold_scale(k_ref[mine, cols], scale)
-            v = v_ref[mine, cols]
+            v = v_ref[mine, wide[g]]
             dk_parts, dv_parts = [], []
             for start, stop, far, near in pieces:
                 nxt = start >= rows
                 base = rows if nxt else 0
                 there = slice(start - base, stop - base)
                 q = (q_next_ref if nxt else q_ref)[there, cols]
-                do = (do_next_ref if nxt else do_ref)[there, cols]
+                do = (do_next_ref if nxt else do_ref)[there, wide[g]]
                 lse = (lse_next_ref if nxt else lse_ref)[g, 0, :, there]
                 st = masks(_scores_t(k, q, s_scale, None), start - at, far,
                            near)
@@ -1838,21 +1869,22 @@ def _band_specs(band: Band, lanes: int, s: int):
 def _band_fwd(q, k, v, *, heads: int, scale: float, band: Band, window: int,
               interpret: bool):
     b, s, width = q.shape
-    d = width // heads
-    cell = _cell_heads(heads, d, 0, False, 0)
+    d, dv = width // heads, v.shape[2] // heads
+    cell = _cell_heads(heads, d, 0, False, 0, dv)
     own, prev, _ = _band_specs(band, cell * d, s)
+    own_v, prev_v, _ = _band_specs(band, cell * dv, s)
     return pl.pallas_call(
-        functools.partial(_band_fwd_kernel, head_dim=d, band=band,
-                          window=window, scale=scale),
+        functools.partial(_band_fwd_kernel, head_dim=d, value_dim=dv,
+                          band=band, window=window, scale=scale),
         grid=(b, pl.cdiv(heads, cell), s // band.rows),
-        in_specs=[own, own, own, prev, prev],
+        in_specs=[own, own, own_v, prev, prev_v],
         out_specs=[
-            own,
+            own_v,
             pl.BlockSpec((None, cell, band.rows, 1),
                          lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(v.shape, q.dtype),
             jax.ShapeDtypeStruct((b, heads, s, 1), jnp.float32),
         ],
         compiler_params=_BAND_PARAMS,
@@ -1864,17 +1896,18 @@ def _band_fwd(q, k, v, *, heads: int, scale: float, band: Band, window: int,
 def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
               dq_band: Band, dkv_band: Band, window: int, interpret: bool):
     b, s, width = q.shape
-    d = width // heads
-    cell = _cell_heads(heads, d, 0, False, 0)
-    static = dict(head_dim=d, window=window, scale=scale)
+    d, dv = width // heads, v.shape[2] // heads
+    cell = _cell_heads(heads, d, 0, False, 0, dv)
+    static = dict(head_dim=d, value_dim=dv, window=window, scale=scale)
 
     band = dq_band
     own, prev, _ = _band_specs(band, cell * d, s)
+    own_v, prev_v, _ = _band_specs(band, cell * dv, s)
     lse_spec, lse_in = _lse_operand(lse, cell, band.rows, whole=False)
     dq = pl.pallas_call(
         functools.partial(_band_dq_kernel, band=band, **static),
         grid=(b, pl.cdiv(heads, cell), s // band.rows),
-        in_specs=[own, own, own, prev, prev, own, own, lse_spec],
+        in_specs=[own, own, own_v, prev, prev_v, own_v, own_v, lse_spec],
         out_specs=own,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_BAND_PARAMS,
@@ -1884,15 +1917,16 @@ def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
 
     band = dkv_band
     own, _, nxt = _band_specs(band, cell * d, s)
+    own_v, _, nxt_v = _band_specs(band, cell * dv, s)
     lse_spec, lse_in = _lse_operand(lse, cell, band.rows, whole=False)
     lse_next_spec, lse_next_in = _lse_operand(
         lse, cell, band.reach, whole=False, at=_band_neighbours(band, s)[1])
     dk, dv = pl.pallas_call(
         functools.partial(_band_dkv_kernel, band=band, **static),
         grid=(b, pl.cdiv(heads, cell), s // band.rows),
-        in_specs=[own, own, own, own, own, lse_spec, nxt, nxt, nxt,
+        in_specs=[own, own_v, own, own_v, own_v, lse_spec, nxt, nxt_v, nxt_v,
                   lse_next_spec],
-        out_specs=[own, own],
+        out_specs=[own, own_v],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -2013,7 +2047,7 @@ def flash_attention(
     if window is not None and (not causal or window < 1):
         raise ValueError(f"flash attention: window={window} needs causal "
                          f"attention and at least one key")
-    if window is not None and dv != d:
+    if window is not None and d > dv:
         raise NotImplementedError(
             f"flash attention: a window with head sizes {d} / {dv}")
     blocks = choose_blocks(s, s_k, causal, block_q, block_k, window, mask)

@@ -83,6 +83,9 @@ SDAR = ("sdar", dict(
     size="30b-a3b-chat", seq_len=8192, vocab=18992, block_length=4,
     remat_policy="full", layer_types=["full_attention"] * 6,
     experts_held=(0, 16), **_CHIP))
+PHI4FLASH = ("phi4flash", dict(
+    size="mini-flash-reasoning", seq_len=16384, vocab=25008,
+    remat_policy="full", layer_ids=[0, 1, 16, 17, 18, 19], **_CHIP))
 #: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
 #: limit is its own compiled size and a little: medium's steps 15.292
@@ -136,6 +139,8 @@ PROGRAMS = {
     "mellum_1x2": (MELLUM, "dp=1", 2, 1, "adamw", 12.1),
     # ONE sequence of 8,192 tokens a step: 16,384 [noised || clean] rows
     "sdar_1x1": (SDAR, "dp=1", 1, 1, "adamw", 15.0),
+    # ONE sequence of 16,384 tokens a step in one microbatch
+    "phi4flash_1x1": (PHI4FLASH, "dp=1", 1, 1, "adamw", 12.5),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -165,10 +170,16 @@ ATTENTION_KERNELS = {
                    "swa_bwd_dq": 1, "swa_fwd": 2},
     # six scanned layers under the block mask, the forward's results kept
     "sdar_1x1": {"bd_bwd": 1, "bd_fwd": 1},
+    # differential attention, scores 64 deep against values 128 wide: the
+    # window layer on the band path (made again), the whole-sequence layer
+    # and the cross layer on the looped side, their results kept
+    "phi4flash_1x1": {"diff_bwd": 2, "diff_fwd": 2, "swa_bwd_dkv": 1,
+                      "swa_bwd_dq": 1, "swa_fwd": 2},
 }
 
-#: name -> the Mamba-2 mixer's kernels (ops/ssd.py: the scan's ``ssd_*``,
-#: PR 43; the convolutions' ``conv1d_*``, PR 44) a cell's compiled step holds,
+#: name -> the Mamba mixers' kernels (ops/ssd.py: Mamba-2's scan ``ssd_*``,
+#: PR 43; the convolutions' ``conv1d_*``, PR 44; ops/selective_scan.py:
+#: Mamba-1's scan ``sscan_*``, PR 53) a cell's compiled step holds,
 #: by name, gated like the attention kernels: a scanned run of Mamba-2 layers
 #: holds ``ssd_fwd`` twice (remat ``full`` makes the layer again) and
 #: ``ssd_bwd`` once — the hybrid's five layers are two runs, Nemotron's four
@@ -184,6 +195,10 @@ MAMBA_KERNELS = {
                    "ssd_fwd": 4},
     "nemotron_1x2": {"conv1d_bwd": 9, "conv1d_fwd": 12, "ssd_bwd": 3,
                      "ssd_fwd": 6},
+    # two Mamba-1 layers, each a run of one made again by remat: the
+    # selective scan's kernels (ops/selective_scan.py) and x's convolution
+    "phi4flash_1x1": {"conv1d_bwd": 2, "conv1d_fwd": 4, "sscan_bwd": 2,
+                      "sscan_fwd": 4},
 }
 
 
@@ -399,12 +414,13 @@ def main() -> None:
         if limit and gib > limit:
             over.append(f"{name}: {gib:.3f} GiB a device, over its {limit}")
         attention = {kernel: n for kernel, n in kernels.items()
-                     if kernel.startswith(("flash_", "mla_", "swa_", "bd_"))}
+                     if kernel.startswith(("flash_", "mla_", "swa_", "bd_",
+                                           "diff_"))}
         if attention != ATTENTION_KERNELS.get(name, attention):
             over.append(f"{name}: attention kernels {attention}, not "
                         f"{ATTENTION_KERNELS[name]}")
         mamba = {kernel: n for kernel, n in kernels.items()
-                 if kernel.startswith(("ssd_", "conv1d_"))}
+                 if kernel.startswith(("ssd_", "conv1d_", "sscan_"))}
         if limit and mamba != MAMBA_KERNELS.get(name, {}):
             over.append(f"{name}: Mamba-2 kernels {mamba}, not "
                         f"{MAMBA_KERNELS.get(name, {})}")

@@ -119,7 +119,7 @@ def test_the_calls_are_named_by_what_they_compute():
                 q, k, v, causal=True, interpret=True, window=window,
                 block_q=min(s, 128), block_k=min(s, 128))),
             (0, 1, 2)))(x, x, v))
-        return {name for kind in ("flash", "swa", "mla")
+        return {name for kind in ("flash", "swa", "mla", "diff")
                 for name in (f"{kind}_fwd", f"{kind}_bwd_dq",
                              f"{kind}_bwd_dkv", f"{kind}_bwd")
                 if f"name={name} " in text or f"name={name}\n" in text}
@@ -134,6 +134,12 @@ def test_the_calls_are_named_by_what_they_compute():
     assert names(64, 64, window=192, s=640) == {"swa_fwd", "swa_bwd"}
     with pytest.raises(NotImplementedError, match="window"):
         names(192, 128, window=32)
+    # values the WIDER (differential attention's pair: PR 53), which a
+    # window takes too: the band path keeps its names
+    assert names(64, 128) == {"diff_fwd", "diff_bwd_dq", "diff_bwd_dkv"}
+    assert names(64, 128, s=640) == {"diff_fwd", "diff_bwd"}
+    assert names(64, 128, window=32) == {"swa_fwd", "swa_bwd_dq",
+                                         "swa_bwd_dkv"}
 
 
 def test_the_public_path_takes_a_value_size_of_its_own(monkeypatch):

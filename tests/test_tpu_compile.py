@@ -21,6 +21,7 @@ The described chip (``v5e_2x2``) and ``no_persistent_cache`` are
 from __future__ import annotations
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -531,3 +532,80 @@ def test_ungated_experts_of_a_ragged_width_compile_at_the_cells_size(
     assert any(c.startswith("bf16[8,2688,1856]") for c in products)
     assert len(_mosaic_calls(compiled, "unwritten")) == 10
     assert moe.choose_tiles(12288, 8, 2688, 1856, 2) == (128, 1024)
+
+
+def test_a_causal_forward_at_16384_rows_states_its_vmem(v5e_2x2, monkeypatch):
+    """PERF.md section 7, SDAR's (h): a ``causal`` forward at 16,384 rows of
+    heads of 128 holds 17.3 MB of k and v, two buffers each, and failed the
+    compiler's own 16 MB; the looped forward now states what it holds where
+    that passes it, and a call that fitted names no parameters, as before."""
+    from easydl_tpu.ops import flash_attention as fa
+
+    stated = []
+    params = fa.pltpu.CompilerParams
+    monkeypatch.setattr(fa.pltpu, "CompilerParams", lambda **kw: (
+        stated.append(kw), params(**kw))[1])
+
+    def forward(batch, seq):
+        x = jax.ShapeDtypeStruct((batch, seq, 4, 128), jnp.bfloat16,
+                                 sharding=SingleDeviceSharding(v5e_2x2[0]))
+        return jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True)).lower(x, x, x)
+
+    calls = _mosaic_calls(forward(1, 16384).compile(), "flash_fwd")
+    assert len(calls) == 1 and "bf16[1,16384,512]" in calls[0], calls
+    (limit,) = stated
+    assert limit["vmem_limit_bytes"] > (17 << 20) + fa._DEFAULT_VMEM // 2
+    forward(2, 8192)
+    assert len(stated) == 1  # 9.7 MB held: the compiler's own limit
+
+
+@pytest.mark.parametrize("window,names", [
+    (None, {"diff_fwd": 1, "diff_bwd": 1}),
+    (512, {"swa_fwd": 1, "swa_bwd_dq": 1, "swa_bwd_dkv": 1}),
+], ids=["whole", "window-512"])
+def test_differential_attention_compiles_at_64_against_128(v5e_2x2, window,
+                                                           names):
+    """Score heads 64 deep against values 128 wide at 16,384 rows: the
+    looped forward and the one-kernel backward (``diff_*``), and under the
+    window the band path's three kernels with v, O and dO twice as wide as q
+    and k."""
+    def of(heads, dim):
+        return jax.ShapeDtypeStruct((1, 16384, heads, dim), jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    fn = jax.grad(_loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window)), argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(of(8, 64), of(8, 64), of(8, 128)).compile()
+    instructions = [line.split(" = ")[0] for line in
+                    compiled.as_text().splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(instructions) == len(names), instructions
+    for name in names:  # `%jvp_swa_fwd_.1`, `%diff_bwd.2`
+        assert sum(bool(re.search(rf"(^|_|%){name}[_.]", i))
+                   for i in instructions) == 1, (name, instructions)
+    assert any("bf16[1,16384,1024]" in c
+               for c in _mosaic_calls(compiled))  # 8 values of 128
+
+
+def test_the_selective_scan_kernels_compile_at_the_cells_shape(v5e_2x2):
+    """``sscan_fwd`` and ``sscan_bwd`` at 16,384 positions of 5,120 channels
+    and 16 states: the turned x and y, dt's rows, the chunks' entry states."""
+    from easydl_tpu.ops import selective_scan as ss
+
+    def of(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    operands = (of(1, 16384, 80, 64, dtype=jnp.bfloat16), of(1, 16384, 5120),
+                of(5120, 16), of(1, 16384, 16, dtype=jnp.bfloat16),
+                of(1, 16384, 16, dtype=jnp.bfloat16), of(5120))
+    fn = jax.grad(lambda *a: ss.selective_scan_kernels(*a).astype(
+        jnp.float32).sum(), argnums=tuple(range(6)))
+    compiled = jax.jit(fn).lower(*operands).compile()
+    forward, = _mosaic_calls(compiled, "sscan_fwd")
+    backward, = _mosaic_calls(compiled, "sscan_bwd")
+    assert "bf16[1,1,5120,16384]" in forward \
+        and "f32[1,128,16,5120]" in forward, forward
+    assert "f32[1,16384,5120]" in backward \
+        and "f32[1,10,16,16384]" in backward, backward

@@ -226,6 +226,23 @@ def test_the_medium_steps_fit_the_chip(v5e_2x2, rehearse, program):
         == {"flash_bwd_dkv": 2, "flash_bwd_dq": 2, "flash_fwd": 4}, calls
 
 
+@pytest.mark.slow  # a whole step, ~45 s: ``scripts/rehearse_tpu_compile.py``
+def test_phi4flash_s_step_holds_its_four_new_kernels_and_fits(v5e_2x2,
+                                                              rehearse):
+    """Phi-4-mini-flash's cell (six published layers, ONE sequence of 16,384
+    tokens, AdamW) compiled whole for the described chip: the selective
+    scan's two kernels and differential attention's looped two by name
+    beside the band path's and the convolution's, under the chip's 15.75
+    GiB and the script's own limit (12.326 compiled; 16.9 GB and no fit
+    without the remat barrier around each run of one layer)."""
+    compiled, gib = rehearse.compile_program("phi4flash_1x1", v5e_2x2)
+    assert gib <= rehearse.PROGRAMS["phi4flash_1x1"][-1] < 15.75, gib
+    counts = rehearse.kernel_counts(rehearse.mosaic_calls(compiled.as_text()))
+    assert counts == {**rehearse.ATTENTION_KERNELS["phi4flash_1x1"],
+                      **rehearse.MAMBA_KERNELS["phi4flash_1x1"]}, counts
+    assert {"sscan_fwd", "sscan_bwd", "diff_fwd", "diff_bwd"} <= set(counts)
+
+
 @pytest.mark.parametrize("program,more,said", [
     ("zaya_1x2", {}, None),
     ("zaya_1x2", {"flash_fwd": 1},  # the scanned run made ``out`` again
@@ -266,13 +283,24 @@ def test_the_medium_steps_fit_the_chip(v5e_2x2, rehearse, program):
     ("sdar_1x1", {"bd_fwd": -1, "bd_bwd": -1, "flash_fwd": 1, "flash_bwd": 1},
      "sdar_1x1: attention kernels {'flash_bwd': 1, 'flash_fwd': 1}, not "
      "{'bd_bwd': 1, 'bd_fwd': 1}"),
+    ("phi4flash_1x1", {}, None),
+    ("phi4flash_1x1", {"sscan_fwd": -4, "sscan_bwd": -2},  # jax.numpy's scan
+     "phi4flash_1x1: Mamba-2 kernels {'conv1d_bwd': 2, 'conv1d_fwd': 4}, "
+     "not {'conv1d_bwd': 2, 'conv1d_fwd': 4, 'sscan_bwd': 2, 'sscan_fwd': "
+     "4}"),
+    # the window layer at two head sizes on the looped kernels, not the band
+    ("phi4flash_1x1", {"swa_fwd": -2, "swa_bwd_dq": -1, "swa_bwd_dkv": -1,
+                       "diff_fwd": 2, "diff_bwd": 1},
+     "phi4flash_1x1: attention kernels {'diff_bwd': 3, 'diff_fwd': 4}, not"),
 ], ids=["as-gated", "a-forward-more", "joyai-a-forward-more",
         "another-backward", "hybrid-as-gated", "nemotron-as-gated",
         "hybrid-the-numpy-scan", "nemotron-a-scan-forward-less",
         "a-scan-kernel-where-none-is", "hybrid-the-numpy-convolutions",
         "nemotron-a-convolution-doubled", "nemotron-a-convolution-missing",
         "mellum-as-gated", "mellum-the-looped-window", "sdar-as-gated",
-        "sdar-a-forward-more", "sdar-the-causal-kernels"])
+        "sdar-a-forward-more", "sdar-the-causal-kernels",
+        "phi4flash-as-gated", "phi4flash-the-numpy-scan",
+        "phi4flash-the-looped-window"])
 def test_the_script_fails_on_other_attention_kernels_than_a_cells(
         v5e_2x2, rehearse, monkeypatch, capsys, program, more, said):
     """The script is where the whole steps at the cells' sizes are gated
@@ -289,7 +317,8 @@ def test_the_script_fails_on_other_attention_kernels_than_a_cells(
 
     assert set(rehearse.ATTENTION_KERNELS) == {
         name for name, program in rehearse.PROGRAMS.items() if program[-1]}
-    assert set(rehearse.MAMBA_KERNELS) == {"hybrid_4x2", "nemotron_1x2"}
+    assert set(rehearse.MAMBA_KERNELS) == {"hybrid_4x2", "nemotron_1x2",
+                                           "phi4flash_1x1"}
     counts = dict(rehearse.ATTENTION_KERNELS[program], rows_to_tokens=6,
                   **rehearse.MAMBA_KERNELS.get(program, {}))
     for kernel, n in more.items():
