@@ -5,7 +5,7 @@ plain language model it is Mellum 2's sibling — every layer sparse, a linear
 softmax router over all experts, the top ``k`` renormalised
 (``norm_topk_prob``), small SwiGLU experts and nothing shared; GQA 32 over 4
 heads of 128, rotary over the whole head, pre-norm RMSNorm, no biases, untied
-head — with an RMSNorm over each head's dimensions on q and k in front of the
+head — with an RMSNorm over each head's dimensions on q and k inside the
 rotary kernel (``AttentionKind.qk_norm``). What it is TRAINED by differs in
 kind: a step runs each sequence as ``[noised || clean]``, twice its tokens in
 rows, under a mask by blocks that is neither causal nor a window

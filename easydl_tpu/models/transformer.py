@@ -41,11 +41,11 @@ import jax
 import jax.numpy as jnp
 
 from easydl_tpu.ops import multihead_attention, remat
-from easydl_tpu.ops.attention import rotate_heads
+from easydl_tpu.ops.attention import norm_heads, rotate_heads
 from easydl_tpu.ops.flash_attention import BlockDiffusion
 from easydl_tpu.ops import moe as moe_ops
 from easydl_tpu.ops.moe import MoeMlp
-from easydl_tpu.ops.rope import apply_rope, rope_tables
+from easydl_tpu.ops.rope import apply_rope, rms_norm, rope_tables
 from easydl_tpu.ops.selective_scan import (selective_scan,
                                            selective_scan_flops_per_token)
 from easydl_tpu.ops.ssd import (causal_conv1d, causal_conv1d_silu,
@@ -269,10 +269,13 @@ class AttentionKind:
     mixing of q, k and v inside the heads' latent, (``lowrank``) q, k
     and v made through low-rank latents in place of one full-rank map
     each, and (``qk_norm``) an RMSNorm over each head's dimensions on q and
-    on k in front of the rotary kernel, one learned gain of ``head_dim``
+    on k in front of their rotation, one learned gain of ``head_dim``
     for all of q's heads and one for k's (Qwen3's and SDAR's; a ``latent``
     or ``lowrank`` kind norms q and k in its own way and refuses this
-    one).
+    one). The gains go to ``multihead_attention`` with q and k: where the
+    rotary kernel rotates them it norms them too, in the same pass over the
+    rows (``ops/rope.py rope_rows``: ``rope_norm_fwd`` / ``rope_norm_bwd``);
+    elsewhere the norm is ``jax.numpy``'s under the scope ``qk_rmsnorm``.
 
     ``diff`` (None: plain softmax attention) makes the kind DIFFERENTIAL
     attention (arXiv:2410.05258) and is its ``lambda_init``: score heads pair
@@ -999,14 +1002,16 @@ def _latent_mix(block, mix, q, k, v):
     return q.astype(v.dtype), k.astype(v.dtype), v
 
 
+def _gain(block, name, width):
+    """A norm's gain ``name [width]``, whole on every shard (a latent's or a
+    head's norm: no axis of it is a mesh's)."""
+    return block.param(name, nn.with_logical_partitioning(
+        nn.initializers.ones_init(), (None,)), (width,))
+
+
 def _rms(block, name, x, eps):
-    """RMSNorm over the last dimension with a gain ``name`` of its own, whole
-    on every shard (a latent's norm: no axis of it is a mesh's)."""
-    gain = block.param(name, nn.with_logical_partitioning(
-        nn.initializers.ones_init(), (None,)), (x.shape[-1],))
-    x32 = x.astype(jnp.float32)
-    return (x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-            * gain.astype(jnp.float32)).astype(x.dtype)
+    """RMSNorm over the last dimension with a gain ``name`` of its own."""
+    return rms_norm(x, _gain(block, name, x.shape[-1]), eps)
 
 
 def _latent_attention(block, kind, h, rope):
@@ -1101,11 +1106,12 @@ def _attention(block, h, rope=None):
         # mutable collection (the benchmark's check, tests)
         for name, value in (("in", h), ("q", q), ("k", k), ("v", v)):
             block.sow("intermediates", f"latent_{name}", value)
+    qk_norm = None
     if kind.qk_norm:
-        # over each head's dimensions, float32, one gain for q and one for k
-        with jax.named_scope("qk_rmsnorm"):
-            q = _rms(block, "q_norm", q, cfg.norm_eps)
-            k = _rms(block, "k_norm", k, cfg.norm_eps)
+        # over each head's dimensions, float32, one gain for q and one for
+        # k: the attention's to apply, in front of the rotation
+        qk_norm = (_gain(block, "q_norm", cfg.head_dim),
+                   _gain(block, "k_norm", cfg.head_dim), cfg.norm_eps)
     q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
     k = nn.with_logical_constraint(k, ("batch", "seq", "heads", "kv"))
     v = nn.with_logical_constraint(v, ("batch", "seq", "heads", "kv"))
@@ -1117,6 +1123,8 @@ def _attention(block, h, rope=None):
         if kind.window:
             raise NotImplementedError(
                 "a windowed attention layer under sequence parallelism")
+        if qk_norm:
+            q, k = norm_heads(q, k, qk_norm)
         if rope is not None:  # q and k are whole here: positions from 0
             q, k = (apply_rope(x, *rope, rot=rotary_dim) for x in (q, k))
         attn = cfg.attention_fn(q, k, v, causal=cfg.causal)
@@ -1124,11 +1132,14 @@ def _attention(block, h, rope=None):
         attn = multihead_attention(
             q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
             scale=cfg.attention_multiplier, rope=rope, rotary_dim=rotary_dim,
-            window=kind.window or None, mask=mask,
+            window=kind.window or None, mask=mask, qk_norm=qk_norm,
         )
-    # what the kernels were given and gave, where `intermediates` is a
-    # mutable collection (the benchmark's check, tests)
-    if kind.qk_norm:
+    # what the kernels were given (the rows behind the norm, in front of the
+    # rotation: made here for the sow alone) and gave, where `intermediates`
+    # is a mutable collection (the benchmark's check, tests)
+    if kind.qk_norm and block.is_mutable_collection("intermediates"):
+        if cfg.attention_fn is None:
+            q, k = norm_heads(q, k, qk_norm)
         for name, value in (("in", h), ("q", q), ("k", k), ("out", attn)):
             block.sow("intermediates", f"attn_{name}", value)
     if kind.gate:

@@ -53,7 +53,7 @@ from easydl_tpu.ops.flash_attention import (
     choose_blocks,
     flash_attention,
 )
-from easydl_tpu.ops.rope import apply_rope, rope_rows, tiles_lanes
+from easydl_tpu.ops.rope import apply_rope, rms_norm, rope_rows, tiles_lanes
 from easydl_tpu.utils.logging import get_logger, log_once
 
 log = get_logger("ops", "attention")
@@ -192,6 +192,16 @@ def rotate_heads(x: jax.Array, rope: tuple, *,
         x.reshape(*x.shape[:2], -1), *rope).reshape(x.shape)
 
 
+def norm_heads(q: jax.Array, k: jax.Array, qk_norm: tuple):
+    """``q`` and ``k`` with every head normed under ``qk_norm = (q's gain,
+    k's gain, eps)``, in ``jax.numpy`` (``ops/rope.py rms_norm``) under the
+    scope ``qk_rmsnorm``: what :func:`multihead_attention` does wherever the
+    rotary kernel does not."""
+    q_gain, k_gain, eps = qk_norm
+    with jax.named_scope("qk_rmsnorm"):
+        return rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
+
+
 @functools.partial(
     jax.named_call, name="multihead_attention"
 )
@@ -208,6 +218,7 @@ def multihead_attention(
     rotary_dim: Optional[int] = None,
     window: Optional[int] = None,
     mask: Optional[BlockDiffusion] = None,
+    qk_norm: Optional[tuple] = None,
 ) -> jax.Array:
     """Attention over [batch, seq, heads, head_dim] tensors (``v``'s head
     size may be its own, and is the result's).
@@ -226,6 +237,11 @@ def multihead_attention(
         the rows are a sequence's ``[noised || clean]`` halves; both paths
         take the same mask, and ``rope``'s tables carry each half's
         positions.
+      qk_norm: None, or ``(q's gain, k's gain, eps)``, each gain
+        ``[head_dim]``: every head of q and of k is normed
+        (``ops/rope.py rms_norm``) in front of its rotation — inside the
+        rotary kernel where that kernel rotates them, else in ``jax.numpy``
+        under the scope ``qk_rmsnorm``; the log says which, once.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -244,6 +260,15 @@ def multihead_attention(
     if mask is not None:
         banded = f", {mask}"
     rotary_dim = rotary_dim or q.shape[-1]
+
+    def normed(q, k, why):
+        # the norm as XLA's operations, wherever the rotary kernel is not
+        # what rotates q and k
+        if qk_norm is None:
+            return q, k
+        log_once(log, f"qk norm: jax.numpy, not the rotary kernel: {why}")
+        return norm_heads(q, k, qk_norm)
+
     if impl == "auto":
         impl = "flash" if platform.on_tpu() else "reference"
         if impl == "reference":
@@ -266,30 +291,44 @@ def multihead_attention(
             # rotated beside the kernels, on their own view, where a head
             # is whole lane tiles; else here, in jax.numpy
             tables = rope if rope is not None and tiles_lanes(head_dim) else ()
+            gains = qk_norm[:2] if tables and qk_norm is not None else ()
+            if gains:
+                log_once(log, f"qk norm: inside the rotary kernel "
+                              f"(rope_norm_fwd / rope_norm_bwd), heads of "
+                              f"{head_dim}, eps {qk_norm[2]}")
+            else:
+                q, k = normed(q, k, "no rotary tables" if rope is None else
+                              f"a head of {head_dim} is not whole lane tiles")
             if rope is not None and not tables:
                 q, k = (apply_rope(x, *rope, rot=rotary_dim) for x in (q, k))
 
             def flat(x):
                 return x.reshape(*x.shape[:2], -1)
 
-            def kernel(q, k, v, *tables):
+            def kernel(q, k, v, *whole):
                 if tables:
-                    q, k = (rope_rows(x, *tables, head_dim=head_dim,
-                                      rot=rotary_dim) for x in (q, k))
+                    # the gains ride behind the two tables, whole on every
+                    # shard
+                    norms = [(gain, qk_norm[2]) for gain in whole[2:]] \
+                        or (None, None)
+                    q, k = (rope_rows(x, *whole[:2], head_dim=head_dim,
+                                      rot=rotary_dim, norm=norm)
+                            for x, norm in zip((q, k), norms))
                 q, k, v = (x.reshape(*x.shape[:2], -1, dim) for x, dim in (
                     (q, head_dim), (k, head_dim), (v, value_dim)))
                 return flat(flash_attention(q, *_repeat_kv(q, k, v),
                                             causal=causal, scale=scale,
                                             window=window, mask=mask))
 
-            return _per_shard(kernel, q, k, len(tables))(
-                flat(q), flat(k), flat(v), *tables).reshape(
+            return _per_shard(kernel, q, k, len(tables) + len(gains))(
+                flat(q), flat(k), flat(v), *tables, *gains).reshape(
                     *q.shape[:3], value_dim)
         # the reference path partitions under GSPMD: no per-shard wrap
         log_once(log, f"flash attention: XLA reference path, not the "
                       f"kernel: {why}{banded}")
     elif impl != "reference":
         raise ValueError(f"unknown attention impl {impl!r}")
+    q, k = normed(q, k, "the XLA reference path")
     if rope is not None:
         q, k = (apply_rope(x, *rope, rot=rotary_dim) for x in (q, k))
     return _reference_attention(

@@ -33,6 +33,18 @@ Two forms of the same arithmetic:
   kernel (four float32 copies a layer application: PERF.md section 6,
   PR 29). A rotation is orthogonal, so the backward pass is the same kernel
   with the sine negated.
+
+The kernel's optional norm (``rope_rows(..., norm=(gain, eps))``, PR 54): a
+layer that norms each head of q and k in front of the rotation
+(``AttentionKind.qk_norm``) hands the kernel the gain, and the kernel
+normalises a head's lanes before it rotates them — :func:`rms_norm`'s
+arithmetic on the float32 slice it already holds, one rounding at the end —
+so q and k are read once and written once where XLA's norm made passes of
+its own at several times their bytes' time. Its calls are ``rope_norm_fwd``
+(ONE result) and ``rope_norm_bwd`` (TWO: ``dx`` and the gain's partial sums
+a grid cell, which XLA adds): by those counts ``benchmark/lib/hlo.py
+flash_calls`` tells them from the flash kernels', whose names end alike.
+Without a gain the calls, their names and their text are what they were.
 """
 
 from __future__ import annotations
@@ -46,12 +58,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from easydl_tpu.utils.logging import get_logger, log_once
+
+log = get_logger("ops", "rope")
+
 #: rows (positions) a grid cell takes: [256, 2048] bf16 in and out are 1 MB
 #: each, two buffers of each, beside float32 [256, 128] slices; fewer where
 #: the array is wider, so that a block stays within _BLOCK_BYTES (64 heads
 #: of 128: [128, 8192])
 _ROWS = 256
 _BLOCK_BYTES = 2 << 20
+
+
+def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last dimension under ``gain``: statistics, quotient
+    and gain in float32, rounded to ``x``'s dtype once. The one ``jax.numpy``
+    form; :func:`rope_rows` has the same arithmetic inside its kernel."""
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+            * gain.astype(jnp.float32)).astype(x.dtype)
 
 
 def yarn_inv_freq(rot: int, theta: float, factor: float, original: int,
@@ -153,6 +178,10 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin_signed: jax.Array,
                 + turned * sin_signed[None, :, None, :]).astype(x.dtype)
 
 
+def _roll(a, shift):
+    return pltpu.roll(a, shift, 1)
+
+
 def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int,
                  negate: bool, rot: int, interleaved: bool = False):
     # x_ref, o_ref: [rows, heads · d]; cos_ref, sin_ref: [rows, d] float32
@@ -163,17 +192,22 @@ def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int,
     for g in range(x_ref.shape[1] // head_dim):
         cols = slice(g * head_dim, (g + 1) * head_dim)
         x = x_ref[:, cols].astype(jnp.float32)
-        turned = _turn(x, rot, lambda a, shift: pltpu.roll(a, shift, 1),
-                       interleaved)
+        turned = _turn(x, rot, _roll, interleaved)
         o_ref[:, cols] = (x * cos + turned * sin).astype(o_ref.dtype)
 
 
-def _call(x, cos, sin_signed, head_dim, negate, interpret, rot, interleaved):
-    b, s, width = x.shape
+def _specs(x, head_dim, block_bytes):
+    """``(rows a grid cell, x's block, a table's block)``."""
+    _, s, width = x.shape
     rows = next(r for r in (_ROWS, 128, 64, 32, 16, 8, s) if s % r == 0
-                and (r <= 8 or r * width * x.dtype.itemsize <= _BLOCK_BYTES))
-    mine = pl.BlockSpec((None, rows, width), lambda i, j: (i, j, 0))
-    table = pl.BlockSpec((rows, head_dim), lambda i, j: (j, 0))
+                and (r <= 8 or r * width * x.dtype.itemsize <= block_bytes))
+    return (rows, pl.BlockSpec((None, rows, width), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((rows, head_dim), lambda i, j: (j, 0)))
+
+
+def _call(x, cos, sin_signed, head_dim, negate, interpret, rot, interleaved):
+    b, s, _ = x.shape
+    rows, mine, table = _specs(x, head_dim, _BLOCK_BYTES)
     return pl.pallas_call(
         functools.partial(_rope_kernel, head_dim=head_dim, negate=negate,
                           rot=rot, interleaved=interleaved),
@@ -209,10 +243,146 @@ def _rope_bwd(head_dim, interpret, rot, interleaved, tables, g):
 _rope.defvjp(_rope_fwd, _rope_bwd)
 
 
+def _inv_rms(x, eps: float):
+    # x: [rows, head_dim] float32 -> each row's 1 / rms, [rows, 1]
+    return jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+
+
+#: heads of the norming kernels' loop body, written out: a body is traced
+#: (on the host's clock, in set-up) at the length it is written, and 32 heads
+#: written out cost a SDAR run 4.5 s of ``setup_s``; a loop over groups of 8
+#: schedules to 7% more bundles than the 32 (PERF.md section 6, PR 54)
+_GROUP = 8
+
+
+def _by_heads(width: int, head_dim: int, one_head, carry):
+    """``one_head(cols, carry) -> carry`` over a block's heads, ``cols`` a
+    head's lanes: a loop over groups of ``_GROUP`` heads written out."""
+    heads = width // head_dim
+    group = math.gcd(heads, _GROUP)
+
+    def some(i, carry):
+        for g in range(group):
+            start = (i * group + g) * head_dim
+            if not isinstance(start, int):
+                start = pl.multiple_of(start, 128)
+            carry = one_head(pl.ds(start, head_dim), carry)
+        return carry
+
+    if heads == group:
+        return some(0, carry)
+    return jax.lax.fori_loop(0, heads // group, some, carry)
+
+
+def _norm_fwd_kernel(x_ref, gain_ref, cos_ref, sin_ref, o_ref, *,
+                     head_dim: int, rot: int, eps: float):
+    # as _rope_kernel, a head's lanes normed under gain_ref [1, d] float32
+    # (rms_norm's arithmetic) before they are rotated
+    def one_head(cols, _):
+        x = x_ref[:, cols].astype(jnp.float32)
+        n = x * _inv_rms(x, eps) * gain_ref[...]
+        o_ref[:, cols] = (n * cos_ref[...] + _turn(n, rot, _roll)
+                          * sin_ref[...]).astype(o_ref.dtype)
+
+    _by_heads(x_ref.shape[1], head_dim, one_head, None)
+
+
+def _norm_bwd_kernel(x_ref, g_ref, gain_ref, cos_ref, sin_ref, dx_ref,
+                     dgain_ref, *, head_dim: int, rot: int, eps: float):
+    # x_ref: the rows in front of the norm; g_ref: the rotated rows'
+    # cotangent; dgain_ref [1, d] float32: this cell's sum over rows and heads
+    rows = x_ref.shape[0]
+    # vreg-high sums: adds on the VPU, one reduction over sublanes at the end
+    fold = 8 if rows % 8 == 0 else rows
+
+    def one_head(cols, acc):
+        x = x_ref[:, cols].astype(jnp.float32)
+        dy = g_ref[:, cols].astype(jnp.float32)
+        # the rotation's transpose, then the norm's: x's unit rows again
+        u = dy * cos_ref[...] - _turn(dy, rot, _roll) * sin_ref[...]
+        r = _inv_rms(x, eps)
+        unit = x * r
+        d = u * gain_ref[...]
+        dx_ref[:, cols] = (r * (d - unit * jnp.mean(
+            d * unit, -1, keepdims=True))).astype(dx_ref.dtype)
+        return acc + (u * unit).reshape(rows // fold, fold, head_dim).sum(0)
+
+    acc = _by_heads(x_ref.shape[1], head_dim, one_head,
+                    jnp.zeros((fold, head_dim), jnp.float32))
+    dgain_ref[...] = acc.sum(0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("head_dim", "rot", "eps",
+                                             "interpret"))
+def _norm_fwd_call(x, gain, cos, sin_signed, *, head_dim, rot, eps, interpret):
+    # a jit of its own: the kernel's body is traced once a shape, not once
+    # a use (the pass, remat's, each program)
+    b, s, _ = x.shape
+    rows, mine, table = _specs(x, head_dim, _BLOCK_BYTES)
+    return pl.pallas_call(
+        functools.partial(_norm_fwd_kernel, head_dim=head_dim, rot=rot,
+                          eps=eps),
+        grid=(b, s // rows),
+        in_specs=[mine, pl.BlockSpec((1, head_dim), lambda i, j: (0, 0)),
+                  table, table],
+        out_specs=mine,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=interpret,
+        name="rope_norm_fwd",
+    )(x, gain, cos, sin_signed)
+
+
+@functools.partial(jax.jit, static_argnames=("head_dim", "rot", "eps",
+                                             "interpret"))
+def _norm_bwd_call(x, g, gain, cos, sin_signed, *, head_dim, rot, eps,
+                   interpret):
+    # x, g and dx by block, two buffers each: half the forward's rows
+    b, s, _ = x.shape
+    rows, mine, table = _specs(x, head_dim, _BLOCK_BYTES // 2)
+    return pl.pallas_call(
+        functools.partial(_norm_bwd_kernel, head_dim=head_dim, rot=rot,
+                          eps=eps),
+        grid=(b, s // rows),
+        in_specs=[mine, mine, pl.BlockSpec((1, head_dim), lambda i, j: (0, 0)),
+                  table, table],
+        out_specs=[mine, pl.BlockSpec((None, None, 1, head_dim),
+                                      lambda i, j: (i, j, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((b, s // rows, 1, head_dim),
+                                        jnp.float32)],
+        interpret=interpret,
+        name="rope_norm_bwd",
+    )(x, g, gain, cos, sin_signed)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _rope_norm(x, gain, cos, sin_signed, head_dim, interpret, rot, eps):
+    return _norm_fwd_call(x, gain, cos, sin_signed, head_dim=head_dim,
+                          rot=rot, eps=eps, interpret=interpret)
+
+
+def _rope_norm_fwd(x, gain, cos, sin_signed, head_dim, interpret, rot, eps):
+    # x, the rows in front of the norm, is all the backward needs: a
+    # rematerialised block makes it again, nothing new is kept
+    return _norm_fwd_call(
+        x, gain, cos, sin_signed, head_dim=head_dim, rot=rot, eps=eps,
+        interpret=interpret), (x, gain, cos, sin_signed)
+
+
+def _rope_norm_bwd(head_dim, interpret, rot, eps, kept, g):
+    x, gain, cos, sin_signed = kept
+    dx, sums = _norm_bwd_call(x, g, gain, cos, sin_signed, head_dim=head_dim,
+                              rot=rot, eps=eps, interpret=interpret)
+    return dx, sums.sum((0, 1)), None, None
+
+
+_rope_norm.defvjp(_rope_norm_fwd, _rope_norm_bwd)
+
+
 def rope_rows(x: jax.Array, cos: jax.Array, sin_signed: jax.Array, *,
               head_dim: int, interpret: bool = False,
-              rot: Optional[int] = None,
-              interleaved: bool = False) -> jax.Array:
+              rot: Optional[int] = None, interleaved: bool = False,
+              norm: Optional[tuple] = None) -> jax.Array:
     """The rotation on ``[batch, seq, heads·head_dim]``, heads of whole
     128-lane tiles (``head_dim % 128 == 0``; the caller asks
     :func:`tiles_lanes`), as a Pallas kernel. ``rot`` (None: ``head_dim``):
@@ -220,14 +390,30 @@ def rope_rows(x: jax.Array, cos: jax.Array, sin_signed: jax.Array, *,
     ``interleaved``: the tables' pairing is ``2i`` with ``2i + 1`` (which
     lanes rotate is the tables' matter), and the heads need only fill whole
     units of ``lcm(head_dim, 128)`` lanes: the tables are repeated to a unit.
+    ``norm``: None, or ``(gain [head_dim], eps)`` — each head is normed
+    (:func:`rms_norm`) in front of its rotation, inside the kernel
+    (``rope_norm_fwd`` / ``rope_norm_bwd``); under interleaved pairing, whose
+    units are not heads, by :func:`rms_norm` in front of the kernel.
     ``interpret=True`` runs it in the Pallas interpreter — something only a
     test passes."""
     heads, ragged = divmod(x.shape[-1], head_dim)
     if ragged or not tiles_lanes(head_dim, heads, interleaved):
         raise ValueError(f"rope_rows: head_dim {head_dim} is not whole "
                          f"128-lane tiles of width {x.shape[-1]}")
+    if norm is not None and interleaved:
+        log_once(log, "rope: interleaved pairing's units are not heads: the "
+                      "norm in jax.numpy, in front of the kernel")
+        with jax.named_scope("qk_rmsnorm"):
+            x = rms_norm(x.reshape(*x.shape[:2], heads, head_dim),
+                         *norm).reshape(x.shape)
     with jax.named_scope("rope"):
         if not interleaved:
+            if norm is not None:
+                gain, eps = norm
+                return _rope_norm(
+                    x, gain.astype(jnp.float32).reshape(1, head_dim), cos,
+                    sin_signed, head_dim, interpret, rot or head_dim,
+                    float(eps))
             return _rope(x, cos, sin_signed, head_dim, interpret,
                          rot or head_dim, False)
         unit = math.lcm(head_dim, 128)

@@ -405,6 +405,42 @@ def test_sdars_attention_compiles_at_16k_rows_under_the_block_mask(v5e_2x2):
         assert not any(f"/{other}/" in line for line in calls), other
 
 
+def test_sdars_attention_norms_q_and_k_inside_the_rotary_kernel(v5e_2x2):
+    """As the test above, with the kind's gains handed in
+    (``multihead_attention(qk_norm=)``): at 16,384 rows of 32 | 4 heads of
+    128 the per-head RMSNorm on q and k runs inside the rotary kernel,
+    forward and backward — ``rope_norm_fwd`` / ``rope_norm_bwd`` within
+    Mosaic's VMEM, the backward's three arrays of a block at half the
+    forward's rows — no bare rotary call and no operation under
+    ``qk_rmsnorm`` is left, and the gains' gradients are there."""
+    from easydl_tpu.ops.flash_attention import BlockDiffusion
+    from easydl_tpu.ops.rope import rope_tables
+
+    mask = BlockDiffusion(4, 8192)
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one)
+    gain = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one)
+
+    def loss(q, k, v, q_gain, k_gain):
+        rope = tuple(jnp.concatenate([table, table])
+                     for table in rope_tables(8192, 128, 1e6))
+        return multihead_attention(
+            q, k, v, impl="flash", rope=rope, mask=mask,
+            qk_norm=(q_gain, k_gain, 1e-6)).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, kv, kv, gain, gain).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name, count in (("bd_fwd", 1), ("bd_bwd", 1), ("rope_norm_fwd", 2),
+                        ("rope_norm_bwd", 2)):
+        assert sum(f"/{name}/" in line for line in calls) == count, name
+    for other in ("rope_fwd", "rope_bwd"):
+        assert not any(f"/{other}/" in line for line in calls), other
+    assert "qk_rmsnorm" not in text
+
+
 # ---------------------------------------------------------------- Mellum 2
 @pytest.mark.parametrize("window,yarn,names", [
     (1024, None, ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")),

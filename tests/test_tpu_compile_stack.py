@@ -565,10 +565,11 @@ def test_rotary_puts_no_copy_between_the_projections_and_the_kernels(
 
 
 # ------------------------------------------------- what remat full keeps
-def _scanned_run(devices, factory: str, **description) -> list:
-    """``_attention_instructions`` of loss and gradients of a two-layer
-    scanned run of ``factory``'s description at the cell's widths (2 x
-    8,192 tokens, bf16, remat ``full``, a 1,024-row head; the expert layer's
+def _scanned_text(devices, factory: str, batch: int = 2,
+                  **description) -> str:
+    """The compiled text of loss and gradients of a two-layer scanned run of
+    ``factory``'s description at the cell's widths (``batch`` x 8,192
+    tokens, bf16, remat ``full``, a 1,024-row head; the expert layer's
     kernels compiled as on the chip: the caller asks ``described_tpu``), for
     one described chip."""
     import flax.linen as nn
@@ -586,14 +587,52 @@ def _scanned_run(devices, factory: str, **description) -> list:
             lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
             jax.eval_shape(lambda: nn.unbox(
                 bundle.init_fn(jax.random.PRNGKey(0)))))
-        tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one)
-        text = jax.jit(jax.grad(
+        tokens = jax.ShapeDtypeStruct((batch, 8192), jnp.int32, sharding=one)
+        return jax.jit(jax.grad(
             lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
         )).lower(params, {"inputs": tokens, "targets": tokens}
                  ).compile().as_text()
     finally:
         jax.config.update("jax_traceback_in_locations_limit", frames)
-    return _attention_instructions(text)
+
+
+def _scanned_run(devices, factory: str, **description) -> list:
+    """``_attention_instructions`` of :func:`_scanned_text`'s program."""
+    return _attention_instructions(_scanned_text(devices, factory,
+                                                 **description))
+
+
+def test_sdars_stack_norms_inside_the_rotary_kernel_and_its_flash_calls_are_bds(
+        v5e_2x2, described_tpu):
+    """A two-layer scanned run of SDAR's description at the cell's widths
+    (one sequence, 16,384 rows, remat ``full``): every layer's q/k norm is
+    the rotary kernel's — ``rope_norm_fwd`` in the forward and in the
+    recomputation, ``rope_norm_bwd`` in the backward, on q and on k; no bare
+    rotary call, nothing under ``qk_rmsnorm`` — and
+    ``benchmark/lib/hlo.flash_calls``, which tells a flash kernel by the end
+    of its name and its count of results, lists ``bd_fwd`` / ``bd_bwd``
+    alone: the fused calls' one and two results are not a flash call's."""
+    import importlib
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    flash_calls = importlib.import_module("lib.hlo").flash_calls
+
+    text = _scanned_text(
+        v5e_2x2, "sdar", batch=1, size="30b-a3b-chat", block_length=4,
+        layer_types=["full_attention"] * 2, experts_held=(0, 16))
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("rope_norm_fwd", "rope_norm_bwd"):
+        assert any(f"/{name}/" in line for line in calls), name
+    for other in ("rope_fwd", "rope_bwd"):
+        assert not any(f"/{other}/" in line for line in calls), other
+    assert "qk_rmsnorm" not in text
+    assert sorted({call["kernel"] for call in flash_calls(text)}) == [
+        "bd_bwd", "bd_fwd"]
 
 
 @pytest.mark.parametrize("factory,description,forward,rows,reader", [
