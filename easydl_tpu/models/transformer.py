@@ -1,15 +1,11 @@
-"""One stack for the LM families, described by data: GPT-2 and BERT
-(LayerNorm, learned positions, multi-head attention, GELU, biases), the
-Granite 4.0-H hybrid (RMSNorm, no positions, Mamba-2 mixers between
-grouped-query attention layers, SwiGLU, four scalar multipliers) and the
-looped Ouro (rotary positions, a norm before AND after every sub-layer,
-the whole stack applied ``loops`` times over the same parameters, an exit
-gate on each pass's normed state) are three descriptions of it
-(``TransformerConfig``). Per layer the description names
-a mixer (``attention`` | ``mamba2`` | an attention kind) and an FFN (``gelu``
-| ``swiglu`` | ``moe`` | ``none``: NemotronH's layers are a mixer OR an
-expert layer alone, and a mixer that another mixer follows is a layer
-without an FFN); consecutive layers of one kind are one ``nn.scan``.
+"""One stack for the LM families, described by data
+(``TransformerConfig``): GPT-2 and BERT (LayerNorm, learned positions,
+multi-head attention, GELU, biases) are its defaults, the hybrids, the looped
+and the expert models of ``models/<name>.py`` other descriptions of it. Per
+layer the description names a mixer (a family of :data:`MIXER_FAMILIES` or
+one of its own attention kinds) and an FFN (``gelu`` | ``swiglu`` | ``moe``
+| ``none``: a layer that is its mixer alone); consecutive layers of one kind
+are one ``nn.scan``.
 
 TPU-first choices:
 - every parameter carries logical axis names (``embed``/``heads``/``kv``/
@@ -24,9 +20,6 @@ TPU-first choices:
   swaps in the Pallas flash kernel on TPU;
 - activations are annotated with ``nn.with_logical_constraint`` so GSPMD
   shards the sequence dim over ``sp`` when sequence parallelism is on.
-
-The reference has no model code at all (SURVEY.md §0); these models exist to
-hit the BASELINE configs 3-4 (BERT-base, GPT-2 345M).
 """
 
 from __future__ import annotations
@@ -49,13 +42,10 @@ from easydl_tpu.ops.rope import apply_rope, rms_norm, rope_tables
 from easydl_tpu.ops.selective_scan import (selective_scan,
                                            selective_scan_flops_per_token)
 from easydl_tpu.ops.ssd import (causal_conv1d, causal_conv1d_silu,
-                                gated_rmsnorm,
-                                ssd_flops_per_token, ssd_scan)
+                                gated_rmsnorm, ssd_flops_per_token, ssd_scan)
 from easydl_tpu.utils.logging import get_logger, log_once
 
 log = get_logger("models", "transformer")
-
-Init = nn.initializers.Initializer
 
 
 def _matrix_dot_general(lhs, rhs, dimension_numbers, precision=None,
@@ -112,17 +102,8 @@ class _RowsDense(nn.DenseGeneral):
             rows.shape[:-1] + features)
 
 
-def _dense(
-    features,
-    kernel_axes,
-    bias_axes,
-    name=None,
-    use_bias=True,
-    init_scale=1.0,
-    axis=-1,
-    dtype=None,
-    rows=False,
-):
+def _dense(features, kernel_axes, bias_axes, name=None, use_bias=True,
+           init_scale=1.0, axis=-1, dtype=None, rows=False):
     """``nn.DenseGeneral``; with ``rows`` :class:`_RowsDense`, which is told
     of the bias apart (its parent class adds none)."""
     if rows:
@@ -131,18 +112,13 @@ def _dense(
     else:
         cls, bias = nn.DenseGeneral, dict(use_bias=use_bias)
     return cls(
-        features,
-        axis=axis,
+        features, axis=axis, name=name,
         dtype=dtype,  # compute dtype; params stay f32 (param_dtype default)
         kernel_init=nn.with_logical_partitioning(
-            nn.initializers.normal(stddev=0.02 * init_scale), kernel_axes
-        ),
+            nn.initializers.normal(stddev=0.02 * init_scale), kernel_axes),
         bias_init=nn.with_logical_partitioning(
-            nn.initializers.zeros_init(), bias_axes
-        ),
-        name=name,
-        **bias,
-    )
+            nn.initializers.zeros_init(), bias_axes),
+        **bias)
 
 
 def _norm(cfg, name, dtype=None):
@@ -150,46 +126,28 @@ def _norm(cfg, name, dtype=None):
     the model width."""
     # Statistics always accumulate in f32 (flax does this when dtype is
     # low-precision); only the output is cast to ``dtype``.
-    if cfg.norm == "rmsnorm":
-        return nn.RMSNorm(
-            epsilon=cfg.norm_eps,
-            dtype=dtype,
-            scale_init=nn.with_logical_partitioning(
-                nn.initializers.ones_init(), ("embed",)
-            ),
-            name=name,
-        )
-    if cfg.norm != "layernorm":
+    if cfg.norm not in ("layernorm", "rmsnorm"):
         raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
                          f"{cfg.norm!r}")
-    return nn.LayerNorm(
-        epsilon=cfg.norm_eps,
-        use_bias=True,
-        dtype=dtype,
-        scale_init=nn.with_logical_partitioning(
-            nn.initializers.ones_init(), ("embed",)
-        ),
-        bias_init=nn.with_logical_partitioning(
-            nn.initializers.zeros_init(), ("embed",)
-        ),
-        name=name,
-    )
+    over = dict(epsilon=cfg.norm_eps, dtype=dtype, name=name,
+                scale_init=nn.with_logical_partitioning(
+                    nn.initializers.ones_init(), ("embed",)))
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(**over)
+    return nn.LayerNorm(use_bias=True, bias_init=nn.with_logical_partitioning(
+        nn.initializers.zeros_init(), ("embed",)), **over)
 
 
-#: one layer of the description: (mixer, ffn). A mixer is ``attention``
-#: (the description's own heads, no window, its ``position``), ``mamba2``, or
-#: the name of one of the description's ``attention_kinds``. The FFN
-#: ``none`` is a layer that is its mixer alone (NemotronH's ``M`` or ``*``
-#: followed by another mixer): no second norm, no second add.
+#: one layer of the description: (mixer, ffn). A mixer is a name a layer may
+#: write of :data:`MIXER_FAMILIES` (the ONE table of the kinds of mixer, which
+#: every question about a mixer is put to: ``TransformerConfig.mixer_family``)
+#: or of one of the description's ``attention_kinds``. The FFN ``none`` is a
+#: layer that is its mixer alone (NemotronH's ``M`` or ``*`` followed by
+#: another mixer): no second norm, no second add.
 Layer = Tuple[str, str]
-MIXERS = ("attention", "mamba2", "mamba1", "gmu")
-#: the mixers that are no attention kind: Mamba-2's and Mamba-1's scans and
-#: the gated memory unit, which multiplies an earlier Mamba-1 layer's scan
-#: output (``mamba1`` GIVES ``memory`` where a ``gmu`` behind it takes it)
-SCANS = ("mamba2", "mamba1", "gmu")
 #: what a layer may hand to the layers behind it (``TransformerConfig.
-#: handoffs``): a Mamba-1 layer's scan output, an attention layer's keys and
-#: values
+#: handoffs``, a family's ``takes`` / ``gives``): a Mamba-1 layer's scan
+#: output, an attention layer's keys and values
 HANDED = ("memory", "kv")
 FFNS = ("gelu", "swiglu", "moe", "none")
 
@@ -261,10 +219,8 @@ class LowRank:
 class AttentionKind:
     """An attention layer's own numbers, where a stack has more than one
     kind: query heads (0: the description's), a causal window in keys (0:
-    none; on a training step's square problem the flash kernels' band path
-    holds it beside one neighbour block of at least its width, Laguna's 512
-    and Mellum 2's 1,024 alike, up to a grid cell's 2,048 rows:
-    ``ops/flash_attention.py choose_blocks``), a rotary scheme (None: the description's ``position``), a
+    none; ``ops/flash_attention.py choose_blocks`` has the kernels' band
+    path for it), a rotary scheme (None: the description's ``position``), a
     per-head sigmoid gate on the attention output, (``latent``) the
     mixing of q, k and v inside the heads' latent, (``lowrank``) q, k
     and v made through low-rank latents in place of one full-rank map
@@ -374,15 +330,13 @@ class SsmConfig:
     share them); ``grouped_norm``: the gated norm behind the scan takes its
     mean square over each of those groups' channels apart (Mamba-2's and
     NemotronH's ``group_size = inner / n_groups``), not over all the inner
-    channels at once (GraniteMoeHybrid's, whatever its ``n_groups``). Of the
-    descriptions here only the hybrid's ``test`` preset needs the word, two
-    groups under an ungrouped norm: granite-4.0-h-micro has one group, where
-    both norms are the same program. ``conv_bias_zero``: the convolutions'
-    biases start at zero and not at torch's Conv1d default, uniform in ``+-1
-    / sqrt(d_conv)``, where GraniteMoeHybrid's code leaves them — constants
-    in front of a SiLU are a token-independent part of the mixer's output,
-    which a router behind it turns into a load that follows the seed
-    (``MoeConfig.down_zero_sums``)."""
+    channels at once (GraniteMoeHybrid's, whatever its ``n_groups``; with
+    one group both are the same program). ``conv_bias_zero``: the
+    convolutions' biases start at zero and not at torch's Conv1d default,
+    uniform in ``+-1 / sqrt(d_conv)``, where GraniteMoeHybrid's code leaves
+    them — constants in front of a SiLU are a token-independent part of the
+    mixer's output, which a router behind it turns into a load that follows
+    the seed (``MoeConfig.down_zero_sums``)."""
 
     n_heads: int = 64
     head_dim: int = 64
@@ -427,11 +381,10 @@ class TransformerConfig:
     remat: bool = False
     #: remat granularity: "full" recomputes the whole block (min memory);
     #: "dots" keeps what costs a matrix product to make again — every
-    #: product, q, k, v and `out` after their bias — and the flash
-    #: forward's `lse`; it recomputes the elementwise rest. Both keep the
-    #: flash forward's `out` and `lse` where the call is dear to make again
-    #: and recompute the kernel elsewhere (``ops/remat.py`` has the rule
-    #: and why; "dots" is faster on TPU when HBM allows).
+    #: product, q, k, v and `out` after their bias — and the flash forward's
+    #: `lse`, and recomputes the elementwise rest. Both keep the flash
+    #: forward's `out` and `lse` where the call is dear to make again
+    #: (``ops/remat.py`` has the rule and why).
     remat_policy: str = "full"
     attention_impl: str = "auto"
     #: compute/activation dtype ("float32" | "bfloat16"). Params stay f32;
@@ -439,17 +392,17 @@ class TransformerConfig:
     #: the usual TPU bottleneck) and the loss upcasts logits to f32.
     dtype: str = "float32"
     #: sequence-parallel attention override: a ``(q, k, v) -> out`` callable
-    #: (e.g. from :func:`easydl_tpu.ops.sequence_parallel.make_sp_attention`)
-    #: replacing the local attention — ring/Ulysses over the mesh's sp axis.
+    #: (``ops/sequence_parallel.py make_sp_attention``) replacing the local
+    #: attention — ring/Ulysses over the mesh's sp axis.
     attention_fn: Optional[Callable] = None
     #: tie the LM head to the token embedding (GPT-2 does)
     tied_head: bool = True
     #: pipeline parallelism over the mesh's ``pp`` axis: ``pipeline_fn``
-    #: (from :func:`easydl_tpu.ops.pipeline.make_pipeline`, closing over the
-    #: mesh like ``attention_fn`` does) runs the block stack as a GPipe
-    #: fill-drain schedule; ``pipeline_stages`` is the pp size (must divide
-    #: ``n_layers``). Params stay the same stacked [n_layers, ...] layout —
-    #: the stage split is purely a ``layers → pp`` sharding rule.
+    #: (``ops/pipeline.py make_pipeline``, closing over the mesh as
+    #: ``attention_fn`` does) runs the block stack as a GPipe fill-drain
+    #: schedule; ``pipeline_stages`` is the pp size (must divide ``n_layers``).
+    #: Params keep the stacked [n_layers, ...] layout — the stage split is
+    #: purely a ``layers → pp`` sharding rule.
     pipeline_fn: Optional[Callable] = None
     pipeline_stages: int = 0
     # ---- the description. The defaults below are GPT-2's (and BERT's).
@@ -478,13 +431,10 @@ class TransformerConfig:
     bias: bool = True             # on the projections and the FFN
     embedding_multiplier: float = 1.0
     #: the token embedding table starts normal at this scale. 0.02 as every
-    #: other map; 1.0 where the description says that a token's own vector
-    #: has to outweigh what attention adds to it at seeded weights (Mellum
-    #: 2's: a causal average of normed values has a fixed size, 0.03-0.4 an
-    #: entry, and is nearly the same vector for every token, so beside 0.02
-    #: embeddings it is most of the stream, every router's logits take
-    #: per-expert offsets from it and top-k turns them into loads that
-    #: follow the seed: PERF.md section 6, PR 45)
+    #: other map; 1.0 where a token's own vector has to outweigh what
+    #: attention adds to it at seeded weights (Mellum 2's: beside 0.02
+    #: embeddings a causal average of normed values is most of the stream,
+    #: and every router's load follows the seed: PERF.md section 6, PR 45)
     embedding_init_std: float = 0.02
     #: the softmax scale; None = ``head_dim ** -0.5``
     attention_multiplier: Optional[float] = None
@@ -522,17 +472,17 @@ class TransformerConfig:
             raise ValueError(f"{len(self.layers)} layers described, "
                              f"n_layers={self.n_layers}")
         kinds = dict(self.attention_kinds)
+        mixers = tuple(name for name, family in MIXER_FAMILIES.items()
+                       if family.written) + tuple(kinds)
         for mixer, ffn in self.every_layer:
-            if mixer not in MIXERS + tuple(kinds) or ffn not in FFNS:
+            if mixer not in mixers or ffn not in FFNS:
                 raise ValueError(f"unknown layer kind {(mixer, ffn)}; mixers "
-                                 f"{MIXERS + tuple(kinds)}, FFNs {FFNS}")
-            if mixer == "mamba2" and self.ssm is None:
-                raise ValueError("a mamba2 layer needs ssm=SsmConfig(...)")
+                                 f"{mixers}, FFNs {FFNS}")
+            needs = self.mixer_family(mixer)[0].needs
+            if needs and getattr(self, needs.split("=")[0]) is None:
+                raise ValueError(f"a {mixer} layer needs {needs}(...)")
             if ffn == "moe" and self.moe is None:
                 raise ValueError("a moe layer needs moe=MoeConfig(...)")
-            if mixer in ("mamba1", "gmu") and self.mamba1 is None:
-                raise ValueError(
-                    f"a {mixer} layer needs mamba1=Mamba1Config(...)")
         if self.position not in ("learned", "none", "rope"):
             raise ValueError(f"position must be 'learned', 'none' or 'rope', "
                              f"got {self.position!r}")
@@ -552,40 +502,7 @@ class TransformerConfig:
             raise NotImplementedError(
                 "moe layers in a looped stack or inside the pipeline")
         for name, kind in self.attention_kinds:
-            low = kind.lowrank
-            if low is None:
-                continue
-            if low.nope_dim + low.rope_dim != self.head_dim or kind.rope is \
-                    None or kind.rope.rotary_dim != low.rope_dim or \
-                    kind.latent or kind.gate or kind.window or \
-                    self.kv_heads != (kind.n_heads or self.n_heads):
-                raise ValueError(
-                    f"attention kind {name!r}: low-rank latent attention "
-                    f"scores with head_size = nope_dim + rope_dim, rotates "
-                    f"rope_dim by its own scheme, has as many key/value "
-                    f"heads as query heads and no window, gate or mix")
-        for name, kind in self.attention_kinds:
-            if kind.qk_norm and (kind.latent or kind.lowrank):
-                raise ValueError(
-                    f"attention kind {name!r}: qk_norm on a latent or "
-                    f"lowrank kind, which norms q and k in its own way "
-                    f"(TransformerConfig.__post_init__ refuses the pair)")
-        for name, kind in self.attention_kinds:
-            if kind.diff is None and not (kind.kv or kind.bias):
-                continue
-            if kind.kv not in ("", "gives", "takes") or kind.diff is None \
-                    or kind.latent or kind.lowrank or kind.gate or \
-                    kind.qk_norm or kind.rope or self.position == "rope" or \
-                    (kind.n_heads or self.n_heads) % 2 or self.kv_heads % 2 \
-                    or (kind.kv == "takes" and kind.window):
-                raise ValueError(
-                    f"attention kind {name!r}: differential attention (diff="
-                    f"{kind.diff}, kv={kind.kv!r}) pairs an even number of "
-                    f"score heads over an even number of key/value heads, "
-                    f"carries no rotary scheme, gate, q/k norm or latent, "
-                    f"kv is '', 'gives' or 'takes', a taker has no window, "
-                    f"and kv or bias stand on a diff kind alone "
-                    f"(TransformerConfig.__post_init__ refuses it)")
+            self.mixer_family(name)[0].check(self, name, kind)
         takers = [(i, mixer, name) for i, (mixer, _) in
                   enumerate(self.every_layer)
                   for name in HANDED if name == self._takes(mixer)]
@@ -605,9 +522,9 @@ class TransformerConfig:
                 "values in a looped, pipelined or sequence-parallel stack or "
                 "in front of a multi-token-prediction module")
         if self.block_diffusion:
-            kinds_used = [self.attention_kind(mixer)
-                          for mixer, _ in self.every_layer
-                          if mixer not in SCANS]
+            kinds_used = [kind for family, kind in (
+                self.mixer_family(mixer) for mixer, _ in self.every_layer)
+                          if family.scope == "attention"]
             refused = [what for what, found in (
                 ("causal=True", self.causal),
                 ("a mamba2, mamba1 or gmu layer",
@@ -634,7 +551,8 @@ class TransformerConfig:
         if self.mtp is not None and (
                 self.loops > 1 or self.exit_gate or self.pipeline_fn
                 is not None or self.attention_fn is not None or
-                self.router_state_width or self.mtp.mixer in SCANS or
+                self.router_state_width or
+                self.mixer_family(self.mtp.mixer)[0].scope != "attention" or
                 self.embedding_multiplier != 1.0):
             raise NotImplementedError(
                 "a multi-token-prediction module behind a looped, gated, "
@@ -656,17 +574,28 @@ class TransformerConfig:
         kind with none of its own."""
         return dict(self.attention_kinds).get(mixer, AttentionKind())
 
+    def mixer_family(self, mixer: str):
+        """A layer's mixer as ``(family, kind)``, the one place that says
+        which entry of :data:`MIXER_FAMILIES` a mixer is: a name a layer may
+        write of the table is that family with no kind; plain ``attention``
+        and every other name are an attention kind, whose own fields say
+        which attention family it is."""
+        family = MIXER_FAMILIES.get(mixer)
+        if family is not None and family.written and mixer != "attention":
+            return family, None
+        kind = self.attention_kind(mixer)
+        return MIXER_FAMILIES["diff" if kind.diff is not None else "lowrank"
+                              if kind.lowrank else "attention"], kind
+
     def _takes(self, mixer: str) -> str:
         """What of :data:`HANDED` a layer of ``mixer`` reads ('': nothing)."""
-        if mixer in SCANS:
-            return "memory" if mixer == "gmu" else ""
-        return "kv" if self.attention_kind(mixer).kv == "takes" else ""
+        family, kind = self.mixer_family(mixer)
+        return family.takes(kind)
 
     def _gives(self, mixer: str) -> str:
         """What of :data:`HANDED` a layer of ``mixer`` can hand on."""
-        if mixer in SCANS:
-            return "memory" if mixer == "mamba1" else ""
-        return "kv" if self.attention_kind(mixer).kv == "gives" else ""
+        family, kind = self.mixer_family(mixer)
+        return family.gives(kind)
 
     @property
     def handoffs(self) -> Tuple[Tuple[Tuple[str, ...], Tuple[str, ...]], ...]:
@@ -750,54 +679,8 @@ class TransformerConfig:
         norm less."""
         mixer, ffn = layer
         d = self.d_model
-        if mixer in ("mamba1", "gmu"):
-            m = self.mamba1
-            n = 2 * d * m.d_inner                       # in and out
-            if mixer == "mamba1":
-                n += (d * m.d_inner                                # z
-                      + (m.d_conv + 1) * m.d_inner                 # taps, bias
-                      + m.d_inner * (m.dt_rank + 2 * m.d_state)    # dt, B, C
-                      + (m.dt_rank + 1) * m.d_inner                # dt up, bias
-                      + (m.d_state + 1) * m.d_inner)               # A, D
-        elif mixer != "mamba2" and self.attention_kind(mixer).diff is not None:
-            kind = self.attention_kind(mixer)
-            inner = (kind.n_heads or self.n_heads) * self.head_dim
-            held = 0 if kind.kv == "takes" else 2 * self.kv_heads * self.head_dim
-            # q and out (the pairs' values are as wide as their score heads
-            # together), k and v, the four lambda vectors, the inner gain
-            n = 2 * d * inner + d * held + 6 * self.head_dim
-            if kind.bias and not self.bias:
-                n += inner + held + d
-        elif mixer != "mamba2" and self.attention_kind(mixer).lowrank:
-            low = self.attention_kind(mixer).lowrank
-            heads = self.attention_kind(mixer).n_heads or self.n_heads
-            # down, the norm's gain and up, for q and for k / v; the way back
-            n = ((d + 1 + heads * self.head_dim) * low.q_rank
-                 + d * (low.kv_rank + low.rope_dim) + low.kv_rank
-                 + low.kv_rank * heads * (low.nope_dim + low.value_dim)
-                 + heads * low.value_dim * d)
-        elif mixer != "mamba2":
-            kind = self.attention_kind(mixer)
-            inner = (kind.n_heads or self.n_heads) * self.head_dim
-            n = 2 * d * inner + 2 * d * self.kv_heads * self.head_dim
-            if kind.qk_norm:
-                n += 2 * self.head_dim  # q's gain and k's
-            if kind.gate:
-                n += d * (kind.n_heads or self.n_heads)
-            if kind.latent:
-                # a tap and a bias a channel, a [head_dim, head_dim] matrix a
-                # tap and head and a bias a channel; a temperature a kv head
-                mix = kind.latent
-                channels = inner + self.kv_heads * self.head_dim
-                n += (mix.taps[0] + 1) * channels \
-                    + (mix.taps[1] * self.head_dim + 1) * channels \
-                    + (self.kv_heads if mix.qk_norm else 0)
-        else:
-            m = self.ssm
-            inner, bc = m.n_heads * m.head_dim, m.n_groups * m.d_state
-            n = (d * (2 * inner + 2 * bc + m.n_heads)      # z, x, B, C, dt
-                 + (m.d_conv + 1) * (inner + 2 * bc)       # conv and bias
-                 + 3 * m.n_heads + inner + inner * d)      # dt_bias A D norm out
+        family, kind = self.mixer_family(mixer)
+        n = family.params(self, kind)
         if ffn == "swiglu":
             n += 3 * d * self.d_ff
         elif ffn == "moe":
@@ -846,23 +729,21 @@ class TransformerConfig:
     def train_flops_per_token(self, seq_len: int) -> float:
         """Training FLOPs a token, forward and backward, recomputation not
         counted: 6 per parameter for the matrix multiplications (PaLM
-        appendix B), ``12 * d_model * seq`` for each ATTENTION layer's
-        scores and weighted values, and three times the scan's forward
-        count for each Mamba-2 layer — not ``12 L d s`` for layers that
-        have no score matrix. An untied embedding is a lookup and counts
-        nothing. A looped stack pays its layers, their scores and its head
-        once a pass: ``loops`` does not move ``param_count`` and multiplies
-        this. Of a ``moe`` layer's routed experts only the ACTIVE ones
-        count (:meth:`layer_params`); an attention layer's scores are
-        ``12 * heads * head_dim`` a key, ``seq`` keys counted in full as the
-        convention has it, or the ``window`` keys a windowed layer's band
-        holds (latent attention: ``6 * heads * (head_dim + value_dim)``). A
+        appendix B) and each layer's ``score_flops`` of its
+        :class:`MixerFamily` — an attention layer's scores and weighted
+        values, ``12 * heads * head_dim`` a key at equal head sizes, ``seq``
+        keys counted in full as the convention has it or the ``window`` keys
+        a windowed layer's band holds; three times a scan's forward count;
+        not ``12 L d s`` for layers that have no score matrix. An untied
+        embedding is a lookup and counts nothing. A looped stack pays its
+        layers, their scores and its head once a pass: ``loops`` does not
+        move ``param_count`` and multiplies this. Of a ``moe`` layer's routed
+        experts only the ACTIVE ones count (:meth:`layer_params`). A
         multi-token-prediction module pays its layer, its join and the head
         a second time. Under ``block_diffusion`` a token is TWO rows through
         every layer (its noised and its clean one) and one through the head,
         and sees ``seq + block`` keys a layer (``seq² + seq · block`` live
         pairs a sequence)."""
-        n_attn = sum(1 for mixer, _ in self.pattern if mixer not in SCANS)
         head = self.vocab * self.d_model
         held = sum(self.layer_params(l) for l in self.pattern) + head
         looped = sum(self.layer_params(l, active=True)
@@ -870,26 +751,10 @@ class TransformerConfig:
         lookup = 0 if self.tied_head else head
         once = self.param_count - held - lookup
         scores = 0.0
+        # (the module's layer is an attention layer: ``__post_init__``)
         for mixer, _ in self.every_layer:
-            if mixer not in SCANS:
-                kind = self.attention_kind(mixer)
-                # S = Q K^T at the scores' head size and P V at the values'
-                sizes = 2 * self.head_dim if kind.lowrank is None else \
-                    self.head_dim + kind.lowrank.value_dim
-                if kind.diff is not None:  # a pair's value: two heads wide
-                    sizes = 3 * self.head_dim
-                scores += 6.0 * (kind.n_heads or self.n_heads) * sizes * (
-                    seq_len + self.block_diffusion if self.block_diffusion
-                    else min(kind.window or seq_len, seq_len))
-        n_ssd = sum(1 for mixer, _ in self.pattern if mixer == "mamba2")
-        if n_ssd:
-            m = self.ssm
-            scores += 3.0 * n_ssd * ssd_flops_per_token(
-                m.n_heads, m.head_dim, m.d_state, m.n_groups, m.chunk)
-        n_scan = sum(1 for mixer, _ in self.pattern if mixer == "mamba1")
-        if n_scan:
-            scores += 3.0 * n_scan * selective_scan_flops_per_token(
-                self.mamba1.d_inner, self.mamba1.d_state)
+            family, kind = self.mixer_family(mixer)
+            scores += family.score_flops(self, kind, seq_len)
         # the module's layer at its active count, and the head once more
         module = self._mtp_params(active=True) + head if self.mtp else 0
         once -= self._mtp_params()
@@ -956,23 +821,20 @@ def _latent_mix(block, mix, q, k, v):
 
     # torch's Conv1d default for the taps. The biases start at zero, as
     # every bias of this stack does: a constant in q is a score every query
-    # gives a key alike, and all positions then attend to the same few
-    # tokens of a sequence — at seeded weights the routers' load on the
-    # experts held followed the seed by 10% either way (PERF.md section 6,
-    # PR 35)
-    uniform = _uniform_init
-
+    # gives a key alike, and at seeded weights the routers' load on the
+    # experts held then follows the seed (PERF.md section 6, PR 35)
     with jax.named_scope("cca_conv"):
         # q's and k's heads are groups alike: ten heads of channels
         u = jnp.concatenate([q, k], 2)
         t0, t1 = mix.taps
         zeros = nn.initializers.zeros_init()
-        per_channel = param("conv0", uniform(t0), (t0, heads, d),
+        per_channel = param("conv0", _uniform_init(t0), (t0, heads, d),
                             (None, "heads", "kv"))
         bias0 = param("conv0_bias", zeros, (heads, d), ("heads", "kv"))
         c1 = causal_conv1d(u.astype(f32), per_channel, bias0).astype(u.dtype)
         per_head = block.param("conv1", nn.with_logical_partitioning(
-            uniform(t1 * d), (None, "heads", "kv", None)), (t1, heads, d, d))
+            _uniform_init(t1 * d), (None, "heads", "kv", None)),
+            (t1, heads, d, d))
         bias1 = param("conv1_bias", zeros, (heads, d), ("heads", "kv"))
         c2 = bias1 + sum(
             jnp.einsum("bshc,hcd->bshd", _shift(c1, t1 - 1 - i),
@@ -1015,8 +877,7 @@ def _rms(block, name, x, eps):
 
 
 def _latent_attention(block, kind, h, rope):
-    """:class:`LowRank` attention on the normed input ``h``: the attention's
-    result ``[B, S, heads, value_dim]``, in front of the way back up. Scopes:
+    """:class:`LowRank` attention on the normed input ``h``. Scopes:
     ``mla_down`` (the two maps into the latents; the key's rotated vector
     comes out of the second), ``mla_norm``, ``mla_up`` (q's heads; the
     heads' keys without positions and values, two products on the two
@@ -1026,7 +887,8 @@ def _latent_attention(block, kind, h, rope):
     ``mla_key`` (the rotated key vector copied beside every head's
     ``nope_dim``: the published code's form, and the kernels' operand),
     the kernels ``mla_fwd`` / ``mla_bwd`` (``mla_bwd_dq`` / ``mla_bwd_dkv``
-    on the kernels' unrolled side)."""
+    on the kernels' unrolled side), ``mla_out`` (the way back up from the
+    attention's result ``[B, S, heads, value_dim]``)."""
     cfg, low = block.cfg, kind.lowrank
     n_heads = kind.n_heads or cfg.n_heads
     dt = jnp.dtype(cfg.dtype)
@@ -1066,23 +928,21 @@ def _latent_attention(block, kind, h, rope):
     attn = multihead_attention(
         q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
         scale=cfg.attention_multiplier)
-    # what the latents held and the kernels were given and gave, where
-    # `intermediates` is a mutable collection (the benchmark's check, tests)
+    # what the latents held and the kernels were given and gave (sown only
+    # where a caller makes `intermediates` mutable: a check, a test)
     for name, value in (("in", h), ("cq", c_q), ("ckv", c_kv), ("q", q),
                         ("k_rot", k_rot), ("attn", attn)):
         block.sow("intermediates", f"mla_{name}", value)
-    return attn
+    with jax.named_scope("mla_out"):
+        return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                           ("embed",), "out", residual=True, axis=(-2, -1),
+                           rows=True)(attn)
 
 
-def _attention(block, h, rope=None):
+def _attention(block, kind, h, rope=None):
+    """Plain attention of ``kind`` (its heads, window, rotary scheme, gate,
+    latent mix and q/k norm) on the normed input ``h``."""
     cfg = block.cfg
-    kind = cfg.attention_kind(block.mixer)
-    if kind.lowrank:
-        attn = _latent_attention(block, kind, h, rope)
-        with jax.named_scope("mla_out"):
-            return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
-                               ("embed",), "out", residual=True,
-                               axis=(-2, -1), rows=True)(attn)
     n_heads = kind.n_heads or cfg.n_heads
     rotary_dim = kind.rope.rotary_dim or None if kind.rope else None
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
@@ -1102,8 +962,7 @@ def _attention(block, h, rope=None):
                         rows=True)(h)
     if kind.latent:
         q, k, v = _latent_mix(block, kind.latent, q, k, v)
-        # what the mix was given and gave, where `intermediates` is a
-        # mutable collection (the benchmark's check, tests)
+        # what the mix was given and gave
         for name, value in (("in", h), ("q", q), ("k", k), ("v", v)):
             block.sow("intermediates", f"latent_{name}", value)
     qk_norm = None
@@ -1135,8 +994,7 @@ def _attention(block, h, rope=None):
             window=kind.window or None, mask=mask, qk_norm=qk_norm,
         )
     # what the kernels were given (the rows behind the norm, in front of the
-    # rotation: made here for the sow alone) and gave, where `intermediates`
-    # is a mutable collection (the benchmark's check, tests)
+    # rotation: made here for the sow alone) and gave
     if kind.qk_norm and block.is_mutable_collection("intermediates"):
         if cfg.attention_fn is None:
             q, k = norm_heads(q, k, qk_norm)
@@ -1211,14 +1069,13 @@ def _mamba2(block, u):
             nn.initializers.ones_init(), kv), (m.n_heads, m.head_dim))
         normed = gated_rmsnorm(y, z, gain, cfg.norm_eps,
                                m.n_groups if m.grouped_norm else 1).astype(dt_)
-    # what the mixer's parts were given and gave, where `intermediates` is a
-    # mutable collection (the benchmark's check, tests)
+    # what the mixer's parts were given and gave
     for name, value in (("in", u), ("z", z), ("x", x), ("B", B), ("C", C),
                         ("dt", dt), ("y", y), ("normed", normed)):
         block.sow("intermediates", f"ssm_{name}", value)
-    y = normed
     return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
-                       ("embed",), "out", residual=True, axis=(-2, -1))(y)
+                       ("embed",), "out", residual=True, axis=(-2, -1)
+                       )(normed)
 
 
 def _diff_lambda(vector, init: float):
@@ -1282,8 +1139,7 @@ def _diff_attention(block, kind, h, kv=None):
         normed = before * jax.lax.rsqrt(
             jnp.mean(before * before, -1, keepdims=True) + cfg.norm_eps)
         out = (normed * gain * (1.0 - kind.diff)).astype(attn.dtype)
-    # what the call was given and gave, where `intermediates` is a mutable
-    # collection (the benchmark's check, tests)
+    # what the call was given and gave
     for name, value in (("in", h), ("q", q), ("k", k), ("v", v),
                         ("attn", attn), ("before_norm", before),
                         ("out", out)):
@@ -1368,6 +1224,198 @@ def _gmu(block, u, memory):
                            )(memory * nn.silu(gate))
 
 
+class MixerFamily(NamedTuple):
+    """One kind of mixer: everything the stack asks about it. ``scope``: the
+    ``jax.named_scope`` its layers run under (``attention`` | ``ssm``, which
+    the trace's readers find in the program's ``op_name`` paths),
+    ``inner(kind)`` one inside it or ``''``; ``norm``: the mixer norm's
+    parameter; ``apply(block, kind, h, rope, handed) -> (y, given)``: the
+    mixer on the normed input ``h``, ``handed`` and ``given`` ``{name of
+    HANDED: value}``; ``params(cfg, kind)``: its parameters
+    (``layer_params``); ``score_flops(cfg, kind, seq_len)``: its training
+    FLOPs a token beside the products with parameters
+    (``train_flops_per_token``); ``takes(kind)`` / ``gives(kind)``: its name
+    of :data:`HANDED` or ``''``; ``needs``: the description's field that
+    holds its widths, as ``field=Class``; ``check(cfg, name, kind)`` refuses
+    the pairings that are its own (``__post_init__``); ``written``: a layer
+    may write the family's name as its mixer (an attention kind alone
+    reaches the others)."""
+
+    scope: str
+    norm: str
+    apply: Callable
+    params: Callable
+    score_flops: Callable
+    inner: Callable = lambda kind: ""
+    takes: Callable = lambda kind: ""
+    gives: Callable = lambda kind: ""
+    needs: str = ""
+    check: Callable = lambda cfg, name, kind: None
+    written: bool = True
+
+
+def _attention_params(cfg, kind):
+    d, heads = cfg.d_model, kind.n_heads or cfg.n_heads
+    held = cfg.kv_heads * cfg.head_dim
+    n = 2 * d * heads * cfg.head_dim + 2 * d * held
+    if kind.qk_norm:
+        n += 2 * cfg.head_dim  # q's gain and k's
+    if kind.gate:
+        n += d * heads
+    if kind.latent:
+        # a tap and a bias a channel, a [head_dim, head_dim] matrix a tap and
+        # head and a bias a channel; a temperature a kv head
+        mix, channels = kind.latent, heads * cfg.head_dim + held
+        n += (mix.taps[0] + 1) * channels \
+            + (mix.taps[1] * cfg.head_dim + 1) * channels \
+            + (cfg.kv_heads if mix.qk_norm else 0)
+    return n
+
+
+def _lowrank_params(cfg, kind):
+    d, low, heads = cfg.d_model, kind.lowrank, kind.n_heads or cfg.n_heads
+    # down, the norm's gain and up, for q and for k / v; the way back
+    return ((d + 1 + heads * cfg.head_dim) * low.q_rank
+            + d * (low.kv_rank + low.rope_dim) + low.kv_rank
+            + low.kv_rank * heads * (low.nope_dim + low.value_dim)
+            + heads * low.value_dim * d)
+
+
+def _diff_params(cfg, kind):
+    d, inner = cfg.d_model, (kind.n_heads or cfg.n_heads) * cfg.head_dim
+    held = 0 if kind.kv == "takes" else 2 * cfg.kv_heads * cfg.head_dim
+    # q and out (the pairs' values are as wide as their score heads
+    # together), k and v, the four lambda vectors, the inner gain
+    n = 2 * d * inner + d * held + 6 * cfg.head_dim
+    return n + (inner + held + d if kind.bias and not cfg.bias else 0)
+
+
+def _mamba2_params(cfg, kind):
+    d, m = cfg.d_model, cfg.ssm
+    inner, bc = m.n_heads * m.head_dim, m.n_groups * m.d_state
+    return (d * (2 * inner + 2 * bc + m.n_heads)      # z, x, B, C, dt
+            + (m.d_conv + 1) * (inner + 2 * bc)       # conv and bias
+            + 3 * m.n_heads + inner + inner * d)      # dt_bias A D norm out
+
+
+def _mamba1_params(cfg, kind):
+    d, m = cfg.d_model, cfg.mamba1
+    return (3 * d * m.d_inner                            # in, z and out
+            + (m.d_conv + 1) * m.d_inner                 # taps, bias
+            + m.d_inner * (m.dt_rank + 2 * m.d_state)    # dt, B, C
+            + (m.dt_rank + 1) * m.d_inner                # dt up, bias
+            + (m.d_state + 1) * m.d_inner)               # A, D
+
+
+def _scores(sizes):
+    """An attention family's ``score_flops``: ``S = Q K^T`` at the scores'
+    head size and ``P V`` at the values', ``sizes(cfg, kind)`` their sum,
+    over the keys a query sees."""
+    return lambda cfg, kind, seq_len: (
+        6.0 * (kind.n_heads or cfg.n_heads) * sizes(cfg, kind) * (
+            seq_len + cfg.block_diffusion if cfg.block_diffusion
+            else min(kind.window or seq_len, seq_len)))
+
+
+def _check_attention(cfg, name, kind):
+    if kind.qk_norm and (kind.latent or kind.lowrank):
+        raise ValueError(
+            f"attention kind {name!r}: qk_norm on a latent or "
+            f"lowrank kind, which norms q and k in its own way "
+            f"(TransformerConfig.__post_init__ refuses the pair)")
+    if kind.kv or kind.bias:  # which stand on a diff kind alone
+        _check_diff(cfg, name, kind)
+
+
+def _check_lowrank(cfg, name, kind):
+    low = kind.lowrank
+    if low.nope_dim + low.rope_dim != cfg.head_dim or kind.rope is None or \
+            kind.rope.rotary_dim != low.rope_dim or kind.latent or \
+            kind.gate or kind.window or \
+            cfg.kv_heads != (kind.n_heads or cfg.n_heads):
+        raise ValueError(
+            f"attention kind {name!r}: low-rank latent attention scores with "
+            f"head_size = nope_dim + rope_dim, rotates rope_dim by its own "
+            f"scheme, has as many key/value heads as query heads and no "
+            f"window, gate or mix")
+    _check_attention(cfg, name, kind)
+
+
+def _check_diff(cfg, name, kind):
+    if kind.kv not in ("", "gives", "takes") or kind.diff is None or \
+            kind.latent or kind.lowrank or kind.gate or kind.qk_norm or \
+            kind.rope or cfg.position == "rope" or \
+            (kind.n_heads or cfg.n_heads) % 2 or cfg.kv_heads % 2 or \
+            (kind.kv == "takes" and kind.window):
+        raise ValueError(
+            f"attention kind {name!r}: differential attention (diff="
+            f"{kind.diff}, kv={kind.kv!r}) pairs an even number of score "
+            f"heads over an even number of key/value heads, carries no "
+            f"rotary scheme, gate, q/k norm or latent, kv is '', 'gives' or "
+            f"'takes', a taker has no window, and kv or bias stand on a diff "
+            f"kind alone (TransformerConfig.__post_init__ refuses it)")
+
+
+def _apply_diff(block, kind, h, rope, handed):
+    out, kv = _diff_attention(
+        block, kind, h, handed.get("kv") if kind.kv == "takes" else None)
+    return out, {"kv": kv}
+
+
+def _apply_mamba1(block, kind, h, rope, handed):
+    out, y = _mamba1(block, h)
+    return out, {"memory": y}
+
+
+#: THE table: the kinds of mixer there are. ``lowrank`` and ``diff`` are the
+#: attention kinds with ``AttentionKind.lowrank`` / ``.diff``. A body is
+#: looked up when it is called (a test stands its own in). A new mixer is one
+#: entry here, its kernel under ``ops/`` and its numbers in
+#: ``models/<name>.py`` — nothing else of this module (docs/operations.md).
+_ATTENTION = dict(scope="attention", norm="ln_attn")
+_SSM = dict(scope="ssm", norm="ln_ssm")
+MIXER_FAMILIES = {
+    "attention": MixerFamily(
+        apply=lambda block, kind, h, rope, handed: (
+            _attention(block, kind, h, rope), {}),
+        params=_attention_params,
+        score_flops=_scores(lambda cfg, kind: 2 * cfg.head_dim),
+        check=_check_attention, **_ATTENTION),
+    "lowrank": MixerFamily(
+        apply=lambda block, kind, h, rope, handed: (
+            _latent_attention(block, kind, h, rope), {}),
+        params=_lowrank_params, score_flops=_scores(
+            lambda cfg, kind: cfg.head_dim + kind.lowrank.value_dim),
+        check=_check_lowrank, written=False, **_ATTENTION),
+    "diff": MixerFamily(  # a pair's value is two heads wide
+        apply=_apply_diff, params=_diff_params,
+        score_flops=_scores(lambda cfg, kind: 3 * cfg.head_dim),
+        inner=lambda kind: "cross" if kind.kv == "takes" else "",
+        takes=lambda kind: "kv" if kind.kv == "takes" else "",
+        gives=lambda kind: "kv" if kind.kv == "gives" else "",
+        check=_check_diff, written=False, **_ATTENTION),
+    "mamba2": MixerFamily(
+        apply=lambda block, kind, h, rope, handed: (_mamba2(block, h), {}),
+        params=_mamba2_params,
+        score_flops=lambda cfg, kind, seq_len: 3.0 * ssd_flops_per_token(
+            cfg.ssm.n_heads, cfg.ssm.head_dim, cfg.ssm.d_state,
+            cfg.ssm.n_groups, cfg.ssm.chunk),
+        needs="ssm=SsmConfig", **_SSM),
+    "mamba1": MixerFamily(
+        apply=_apply_mamba1, params=_mamba1_params,
+        score_flops=lambda cfg, kind, seq_len: 3.0 *
+        selective_scan_flops_per_token(cfg.mamba1.d_inner,
+                                       cfg.mamba1.d_state),
+        gives=lambda kind: "memory", needs="mamba1=Mamba1Config", **_SSM),
+    "gmu": MixerFamily(
+        apply=lambda block, kind, h, rope, handed: (
+            _gmu(block, h, handed["memory"]), {}),
+        params=lambda cfg, kind: 2 * cfg.d_model * cfg.mamba1.d_inner,
+        score_flops=lambda cfg, kind, seq_len: 0.0,
+        takes=lambda kind: "memory", needs="mamba1=Mamba1Config", **_SSM),
+}
+
+
 def _ffn(block, h, state=None):
     """``(y, aux, state)``: the FFN on the normed input ``h``; an expert
     layer whose router has a state takes the previous layer's and gives its
@@ -1409,7 +1457,9 @@ class Block(nn.Module):
     """One layer of the stack: a mixer and an FFN — or, with the FFN
     ``none``, the mixer alone — each from its norm to the residual add
     (under ``norm_placement="sandwich"`` each sub-layer's output is normed
-    once more in front of the add).
+    once more in front of the add). What the mixer is — its scope, norm,
+    body, what it takes and gives — is its entry of :data:`MIXER_FAMILIES`
+    (``cfg.mixer_family``): the block asks nothing about it by name.
 
     ``rope`` is ``None`` or the rotary tables ``(cos, sin)`` of
     :func:`easydl_tpu.ops.rope.rope_tables`, made once for all layers.
@@ -1439,7 +1489,7 @@ class Block(nn.Module):
         # kwargs.
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
-        state, handed, given = None, {}, {}
+        state, handed = None, {}
         if self.carried:
             x, handed = x
         if cfg.router_state_width:
@@ -1469,38 +1519,20 @@ class Block(nn.Module):
             return x + h
 
         # The scopes put every operation of a layer, residual adds,
-        # activations and logical constraints included, under `attention`
-        # or `ssm` and under `ffn` in the compiled program's op_name paths
-        # (read by the device trace's reducers); flax's module names sit
-        # inside them.
+        # activations and logical constraints included, under its family's
+        # (`attention` | `ssm`) and under `ffn` in the compiled program's
+        # op_name paths, which the device trace's reducers read; flax's
+        # module names sit inside them.
         with remat.tally() as named:
-            if self.mixer in ("mamba1", "gmu"):
-                with jax.named_scope("ssm"):
-                    u = _norm(cfg, "ln_ssm", dtype=dt)(x)
-                    if self.mixer == "gmu":
-                        h = _gmu(self, u, handed["memory"])
-                    else:
-                        h, given["memory"] = _mamba1(self, u)
-                    x = residual(x, h, "ln_ssm")
-            elif self.mixer != "mamba2" and \
-                    cfg.attention_kind(self.mixer).diff is not None:
-                kind = cfg.attention_kind(self.mixer)
-                with jax.named_scope("attention"), (
-                        jax.named_scope("cross") if kind.kv == "takes"
-                        else contextlib.nullcontext()):
-                    h, given["kv"] = _diff_attention(
-                        self, kind, _norm(cfg, "ln_attn", dtype=dt)(x),
-                        handed.get("kv") if kind.kv == "takes" else None)
-                    x = residual(x, h, "ln_attn")
-            elif self.mixer != "mamba2":
-                with jax.named_scope("attention"):
-                    x = residual(x, _attention(
-                        self, _norm(cfg, "ln_attn", dtype=dt)(x), rope),
-                        "ln_attn")
-            else:
-                with jax.named_scope("ssm"):
-                    x = residual(x, _mamba2(
-                        self, _norm(cfg, "ln_ssm", dtype=dt)(x)), "ln_ssm")
+            family, kind = cfg.mixer_family(self.mixer)
+            inner = family.inner(kind)
+            with jax.named_scope(family.scope), (
+                    jax.named_scope(inner) if inner
+                    else contextlib.nullcontext()):
+                h, given = family.apply(
+                    self, kind, _norm(cfg, family.norm, dtype=dt)(x), rope,
+                    handed)
+                x = residual(x, h, family.norm)
             # `ffn` is the dense FFN's scope; an expert layer is `moe`, with
             # the scopes of ops/moe.py inside it; a layer that is its mixer
             # alone has neither, nor a second norm
@@ -1547,52 +1579,37 @@ def _pipelined(stack, block_cls, scan_kwargs, mixer, ffn, x, deterministic,
                rope):
     """The one run of the stack through ``cfg.pipeline_fn``'s GPipe
     schedule, on the stacked params the plain path created."""
-    cfg = stack.cfg
-    if ffn == "moe":
-        raise NotImplementedError("a moe layer inside the pipeline")
+    cfg = stack.cfg  # (no moe layer comes here: ``__post_init__``)
     if cfg.dropout and not deterministic:
-        # The stage apply below passes no rngs, so a non-
-        # deterministic dropout>0 apply would otherwise die with an
-        # opaque flax missing-'dropout'-rng error deep inside
-        # shard_map tracing. v1 pipeline scope is dropout-free at
-        # train time — say so. (Deterministic applies — eval,
-        # embedding extraction — need no rng and stay allowed.)
+        # The stage apply below passes no rngs: without this a dropout > 0
+        # apply dies of an opaque missing-'dropout'-rng error deep inside
+        # shard_map tracing. (Deterministic applies need no rng.)
         raise NotImplementedError(
             f"dropout={cfg.dropout} with pipeline_fn: the pipeline "
             "path applies stages without rngs (v1 trains "
-            "dropout-free; deterministic applies are fine)"
-        )
+            "dropout-free; deterministic applies are fine)")
     if cfg.n_layers % cfg.pipeline_stages:
-        raise ValueError(
-            f"n_layers={cfg.n_layers} not divisible by "
-            f"pipeline_stages={cfg.pipeline_stages}"
-        )
+        raise ValueError(f"n_layers={cfg.n_layers} not divisible by "
+                         f"pipeline_stages={cfg.pipeline_stages}")
     fn_stages = getattr(cfg.pipeline_fn, "stages", None)
     if fn_stages is not None and fn_stages != cfg.pipeline_stages:
-        # A mismatch would otherwise surface as an opaque scan
-        # axis-size error deep inside shard_map tracing.
-        raise ValueError(
-            f"pipeline_stages={cfg.pipeline_stages} != the "
-            f"pipeline_fn's mesh pp size {fn_stages}"
-        )
+        # else an opaque scan axis-size error deep inside shard_map tracing
+        raise ValueError(f"pipeline_stages={cfg.pipeline_stages} != the "
+                         f"pipeline_fn's mesh pp size {fn_stages}")
     # Apply the SAME stacked params through the GPipe schedule: a
     # standalone scan of length n_layers/pp has an identical param
     # tree structure, so each stage applies its [L/pp, ...] slice.
-    chunk = nn.scan(
-        block_cls, length=cfg.n_layers // cfg.pipeline_stages,
-        **scan_kwargs,
-    )(cfg, mixer, ffn)
+    chunk = nn.scan(block_cls, length=cfg.n_layers // cfg.pipeline_stages,
+                    **scan_kwargs)(cfg, mixer, ffn)
     stacked = nn.meta.unbox(stack.variables["params"]["blocks"])
 
     def apply_stage(stage_params, h):
         y, _ = chunk.apply({"params": stage_params}, h, deterministic, rope)
         return y
 
-    # block_remat tells the pipeline whether the blocks already
-    # carry nn.remat (then its own stage checkpoint would double
-    # the backward recompute)
-    x = cfg.pipeline_fn(apply_stage, stacked, x,
-                        block_remat=cfg.remat)
+    # block_remat: the blocks already carry nn.remat (the pipeline's own
+    # stage checkpoint would then double the backward recompute)
+    x = cfg.pipeline_fn(apply_stage, stacked, x, block_remat=cfg.remat)
     return x, jnp.zeros((cfg.n_layers,), jnp.float32)
 
 
@@ -1669,48 +1686,33 @@ class Transformer(nn.Module):
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
         tok_emb = nn.Embed(
-            cfg.vocab,
-            cfg.d_model,
-            dtype=dt,
+            cfg.vocab, cfg.d_model, dtype=dt, name="tok_emb",
             embedding_init=nn.with_logical_partitioning(
                 nn.initializers.normal(stddev=cfg.embedding_init_std),
-                ("vocab", "embed")
-            ),
-            name="tok_emb",
-        )
+                ("vocab", "embed")))
         seq = tokens.shape[1]
         x = tok_emb(tokens)
         if cfg.embedding_multiplier != 1.0:
             x = x * jnp.asarray(cfg.embedding_multiplier, dt)
         if cfg.position == "learned":
-            pos_emb = self.param(
-                "pos_emb",
-                nn.with_logical_partitioning(
-                    nn.initializers.normal(stddev=0.01), ("seq", "embed")
-                ),
-                (cfg.max_seq, cfg.d_model),
-            )
+            pos_emb = self.param("pos_emb", nn.with_logical_partitioning(
+                nn.initializers.normal(stddev=0.01), ("seq", "embed")),
+                (cfg.max_seq, cfg.d_model))
             x = x + jnp.asarray(pos_emb, dt)[None, :seq]
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
         block_cls = Block
         if cfg.remat:
             if cfg.remat_policy not in ("full", "dots"):
-                raise ValueError(
-                    f"remat_policy must be 'full' or 'dots', got "
-                    f"{cfg.remat_policy!r}"
-                )
+                raise ValueError(f"remat_policy must be 'full' or 'dots', "
+                                 f"got {cfg.remat_policy!r}")
             # "full" keeps the flash forward's results where ops/remat.py's
-            # rule picks the call, and nothing else
-            # `prevent_cse=False` is right inside a loop, which a scanned
-            # run is. A run of ONE layer is no loop once XLA has unrolled
-            # it, and the layer's second forward is then merged with its
-            # first: the run keeps every activation. Where NO run of the
-            # stack is a loop (every layer of another kind than the one in
-            # front of it: Phi-4-mini-flash's six) remat would do nothing at
-            # all, so there the barrier stands (16.9 GB for 12.3 GiB compiled:
-            # PERF.md section 6, PR 53); a stack with a scanned run keeps
-            # the program it had
+            # rule picks the call, and nothing else. `prevent_cse=False` is
+            # right inside a loop, which a scanned run is. A run of ONE layer
+            # is no loop once XLA has unrolled it, and its second forward is
+            # merged with its first: where NO run of the stack is a loop
+            # (Phi-4-mini-flash's six) remat would do nothing at all, so
+            # there the barrier stands (16.9 GB for 12.3 GiB compiled)
             block_cls = nn.remat(
                 Block, policy=remat.policy(cfg.remat_policy),
                 prevent_cse=cfg.n_layers > 1 and all(
@@ -1789,9 +1791,8 @@ class Transformer(nn.Module):
                     zip(runs, cfg.handoffs)):
                 rope = ropes.get(mixer)
                 if cfg.pipeline_fn is None or stack.is_initializing():
-                    # plain (or init) path: params are created here with
-                    # the stacked [n_layers, ...] layout the pipeline also
-                    # expects
+                    # plain (or init) path: params are created here, in
+                    # the stacked [n_layers, ...] layout the pipeline expects
                     if carried:
                         x = (x, {name: handed[name] for name in carried})
                     x, layer_aux = nn.scan(
@@ -1831,11 +1832,10 @@ class Transformer(nn.Module):
         else:
             # A looped stack is ONE traced pass scanned `loops` times over
             # the same (broadcast) parameters: each parameter's gradient is
-            # summed over its uses in the scan's carry, where a Python loop
-            # over passes kept every pass's stacked gradients alive to the
-            # end (2.7 GiB a pass at Ouro's cell: PERF.md section 6, PR 29).
-            # The normed state is that pass's output and the next one's
-            # input; the outputs are stacked by pass.
+            # summed in the scan's carry, where a Python loop over passes
+            # kept every pass's stacked gradients alive to the end (2.7 GiB
+            # a pass at Ouro's cell). The normed state is that pass's output
+            # and the next one's input; the outputs are stacked by pass.
             x, (states, gates, aux) = nn.scan(
                 one_pass, variable_broadcast="params",
                 split_rngs={"params": False, "dropout": True},
@@ -1872,10 +1872,8 @@ class Transformer(nn.Module):
             if cfg.tied_head:
                 logits = tok_emb.attend(x)
             else:
-                logits = _dense(
-                    cfg.vocab, ("embed", "vocab"), (), name="head",
-                    use_bias=False, dtype=dt,
-                )(x)
+                logits = _dense(cfg.vocab, ("embed", "vocab"), (),
+                                name="head", use_bias=False, dtype=dt)(x)
             if cfg.logits_scaling != 1.0:
                 logits = logits / jnp.asarray(cfg.logits_scaling,
                                               logits.dtype)

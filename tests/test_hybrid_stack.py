@@ -13,7 +13,10 @@ against the float32 recurrence carry bf16's 8 bits through a few products
 
 from __future__ import annotations
 
+import ast
+import collections
 import copy
+import dataclasses
 import functools
 import importlib
 import json
@@ -699,6 +702,69 @@ def test_a_description_that_does_not_hold_together_is_refused(bad):
 
     with pytest.raises(ValueError):
         TransformerConfig(**bad)
+
+
+# ------------------------------------------------- the table of mixer families
+def test_a_mixers_name_stands_in_the_table_and_nowhere_else():
+    """``models/transformer.py`` asks :data:`MIXER_FAMILIES` what a mixer
+    is: a scan's name is a string constant of the module once, as the
+    table's key (``gmu`` a second time, the scope the unit opens, which
+    ``gmu_time_pct`` reads), and no comparison holds one."""
+    from easydl_tpu.models import transformer
+
+    tree = ast.parse(open(transformer.__file__).read())
+    names = ("mamba2", "mamba1", "gmu")
+
+    def named(node):
+        return [n.value for n in ast.walk(node)
+                if isinstance(n, ast.Constant) and n.value in names]
+
+    assert collections.Counter(named(tree)) == {
+        "mamba2": 1, "mamba1": 1, "gmu": 2}
+    assert not [(n.lineno, named(n)) for n in ast.walk(tree)
+                if isinstance(n, ast.Compare) and named(n)]
+    assert [name for name, family in transformer.MIXER_FAMILIES.items()
+            if family.written] == ["attention", "mamba2", "mamba1", "gmu"]
+
+
+def test_a_new_mixer_is_one_entry_of_the_table(monkeypatch):
+    """A family put into the table — the identity, no parameters, under
+    ``ssm`` — and nothing else of the module patched: the description takes
+    its name, counts it, and the stack scans two of its layers as a run."""
+    from easydl_tpu.models import transformer
+
+    monkeypatch.setitem(
+        transformer.MIXER_FAMILIES, "toy", transformer.MixerFamily(
+            scope="ssm", norm="ln_ssm",
+            apply=lambda block, kind, h, rope, handed: (h, {}),
+            params=lambda cfg, kind: 0,
+            score_flops=lambda cfg, kind, seq_len: 7.0 * seq_len))
+    base = describe(**TEST, layer_types=("attention",))
+    cfg = dataclasses.replace(
+        base, n_layers=3, layers=(("toy", "swiglu"),) * 2 + base.layers)
+    assert cfg.runs == ((("toy", "swiglu"), 2), (("attention", "swiglu"), 1))
+    assert cfg.mixer_family("toy") == (transformer.MIXER_FAMILIES["toy"], None)
+    # counted: the FFN and the two norms of a layer, no mixer; its scores
+    d = cfg.d_model
+    assert cfg.layer_params(("toy", "swiglu")) == 3 * d * cfg.d_ff + 2 * d
+    assert cfg.train_flops_per_token(32) - base.train_flops_per_token(32) \
+        == 2 * (6.0 * cfg.layer_params(("toy", "swiglu")) + 7.0 * 32)
+    model = transformer.Transformer(cfg)
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    params = unbox(model.init(jax.random.PRNGKey(0), tokens)["params"])
+    assert sorted(params["blocks_0"]) == ["down", "gate", "ln_mlp", "ln_ssm",
+                                          "up"]
+    assert params["blocks_0"]["ln_ssm"]["scale"].shape == (2, d)
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) \
+        == cfg.param_count
+    logits = model.apply({"params": params}, tokens)
+    assert logits.shape == (2, 32, 256) and bool(jnp.isfinite(logits).all())
+    # the run's ONE traced layer stands under the family's scope
+    text = jax.jit(lambda p: model.apply({"params": p}, tokens)).lower(
+        params).as_text(debug_info=True)
+    assert re.search(r'loc\("[^"]*blocks_0/ssm/ln_ssm', text)
+    with pytest.raises(ValueError, match="unknown layer kind"):
+        dataclasses.replace(cfg, layers=(("diff", "swiglu"),) * 3)
 
 
 # ------------------------------------------ sharded, saved, restored, named

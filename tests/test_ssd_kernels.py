@@ -274,9 +274,11 @@ def test_a_mamba_block_at_a_cells_shape_holds_the_two_kernels(
     forward and the one made again), the convolutions' ``conv1d_fwd`` and
     ``conv1d_bwd`` (PR 44) three times each beside them — x, B and C — no
     other Mosaic call is in the program, and ``benchmark/lib/hlo.py
-    flash_calls`` — which tells the flash kernels by the number and rank of
-    a call's results — lists none of them (the mixer's results are rank 4):
-    a Mamba-2 block held no flash call before the kernels either."""
+    flash_calls`` — which tells a flash kernel's kind by its name's end and
+    lists the call where the number and rank of its results are that kind's
+    — lists none of them (``ssd_fwd`` ends as a forward does, but the
+    mixer's results are rank 4): a Mamba-2 block held no flash call before
+    the kernels either."""
     import importlib
     import sys
 

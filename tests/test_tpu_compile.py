@@ -313,12 +313,13 @@ def test_mosaic_payload_and_the_python_call_stack(v5e_2x2, frames):
 @pytest.mark.parametrize("where,batch", [("one_chip", 8), ("shard_map", 2)])
 def test_the_benchmarks_reader_still_tells_the_kernels_by_their_results(
         named_texts, where, batch):
-    """``benchmark/lib/hlo.flash_calls`` (not this PR's to edit) tells the
-    three calls by result types: the forward's ``(3-D array, float32 array
-    whose last dimension is 1)``, dq's one 3-D array, dkv's two. On the
-    model's layout it reads ``[8, 1024, 1024]`` as 8 batch·heads of
-    head_dim 1024 — the same ``batch_heads x head_dim`` product, which is
-    all ``flops.flash_causal_cost`` takes from them."""
+    """``benchmark/lib/hlo.flash_calls`` tells the three calls by the NAME
+    the program gives each (its end is the kind: ``fwd``, ``dq``, ``dkv``)
+    and lists a call where its results are as many as that kind gives (the
+    test's name is older than that). The sizes are the first result's as
+    they stand: on the model's layout ``[8, 1024, 1024]`` reads as 8
+    batch·heads of head_dim 1024 — the same ``batch_heads x head_dim``
+    product, which is all ``flops.flash_causal_cost`` takes from them."""
     import importlib
     import sys
 
