@@ -28,11 +28,12 @@ values 128 wide); the result has ``v``'s. The kernels take both sizes
 (``ops/flash_attention.py``), the reference path's two products likewise.
 
 Grouped-query attention: ``k`` and ``v`` may carry fewer heads than ``q``;
-query head ``h`` then reads key/value head ``h // (heads / kv_heads)``. Both
-paths repeat the shared heads to the query's count in front of the score
-product (per shard under a mesh, so only the un-repeated heads cross the
-wrap) and the repeat's transpose sums their gradients: the kernels see
-``heads`` equal operands and stay as they are.
+query head ``h`` then reads key/value head ``h // (heads / kv_heads)``. The
+kernels take k and v at the key/value heads and read a shared head by its
+index (``ops/flash_attention.py _shared``; per shard under a mesh, where a
+shard's ratio is the whole's), and their rule sums a group's dk and dv. The
+reference path repeats the shared heads to the query's count in front of
+its score product and the repeat's transpose sums their gradients.
 """
 
 from __future__ import annotations
@@ -109,7 +110,8 @@ def _reference_attention(
 
 
 def _repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
-    """``k, v`` with each head repeated up to ``q``'s head count."""
+    """``k, v`` with each head repeated up to ``q``'s head count: the
+    reference path's alone (the kernels read a shared head by its index)."""
     heads, kv_heads = q.shape[2], k.shape[2]
     if heads == kv_heads:
         return k, v
@@ -316,9 +318,11 @@ def multihead_attention(
                             for x, norm in zip((q, k), norms))
                 q, k, v = (x.reshape(*x.shape[:2], -1, dim) for x, dim in (
                     (q, head_dim), (k, head_dim), (v, value_dim)))
-                return flat(flash_attention(q, *_repeat_kv(q, k, v),
-                                            causal=causal, scale=scale,
-                                            window=window, mask=mask))
+                # k and v at the heads the projections made: the kernels
+                # read a shared head by its index
+                return flat(flash_attention(q, k, v, causal=causal,
+                                            scale=scale, window=window,
+                                            mask=mask))
 
             return _per_shard(kernel, q, k, len(tables) + len(gains))(
                 flat(q), flat(k), flat(v), *tables, *gains).reshape(

@@ -202,6 +202,26 @@ width where that is narrower than a tile), each head a static lane slice
 inside the kernel, the results of a cell's heads joined and stored as whole
 rows. Short sequences widen the block (``_cell_heads``).
 
+Grouped queries. k and v reach every kernel at the KEY/VALUE heads,
+``[batch, seq, kv_heads·head_dim]``, and query head ``h`` reads head ``h //
+(heads // kv_heads)`` by the lane-block index of the BlockSpecs that read k
+or v (:func:`_shared`: the looped forward and one-call backward, the
+unrolled dq / dkv pair, the band path's own and neighbour blocks; under
+``causal``, a window and the block mask alike): no repeat of k and v to the
+query's heads stands in HBM in front of a call. That holds where a grid cell
+is ONE head (heads of 128 and wider). A lane block of two heads of 64 cannot
+name half a tile, so there k and v are repeated in front of the call as far
+as a CELL's heads and no further (:func:`_cell_repeat`: two-fold under a
+ratio of four, and cell ``c`` reads block ``c // 2``; the whole ratio where
+a cell's heads do not divide it, or where a short sequence widened the
+cell). dk and dv LEAVE the kernels a query head, ``[batch, seq,
+heads·head_dim]``, and the differentiation rule sums a group's heads
+(``_flash_bwd``: the reshape and ``reduce_sum`` a repeat's transpose was, in
+the same dtype): the looped backward sums dq in VMEM across its K-block
+axis, the grid's last, so a group's heads cannot be that axis's neighbours
+too, and Pallas gives no output block revisited between other blocks. With
+equal head counts every spec, operand and index map is what it was.
+
 Two head sizes. The scores' size (q, k, dq, dk: ``head_dim``) and the
 values' (v, O, dO, dv: ``value_dim``) are two numbers through the forward,
 the one-kernel backward, the unrolled dq and dk/dv kernels, the blocks'
@@ -782,6 +802,57 @@ def _head_cols(lanes: int, d: int):
     return [slice(g * d, (g + 1) * d) for g in range(lanes // d)]
 
 
+def _head_sizes(q, k, v, heads: int) -> Tuple[int, int, int]:
+    """``(head_dim, value_dim, ratio)`` of the kernels' views: q ``[B, S,
+    heads·head_dim]``, k and v at the key/value heads, which ``ratio`` query
+    heads read each (1: as many as the query's)."""
+    d = q.shape[2] // heads
+    kv_heads = k.shape[2] // d
+    return d, v.shape[2] // kv_heads, heads // kv_heads
+
+
+def _cell_repeat(ratio: int, cell: int) -> int:
+    """How often a call whose grid cells take ``cell`` query heads needs
+    each key/value head side by side: once where a cell is one head, ``cell``
+    times where a lane block is ``cell`` heads of one group (two heads of 64
+    under a ratio of four), the whole ``ratio`` where ``cell`` does not
+    divide it."""
+    return cell if ratio % cell == 0 else ratio
+
+
+def _shared(q, k, v, heads: int, cell: int):
+    """``(k, v, at)`` for a call whose grid cells take ``cell`` query heads,
+    from the views :func:`_head_sizes` reads. ``at(spec)`` is a q-side
+    BlockSpec over ``(batch row, rows, lane block)`` as k or v is read: lane
+    block ``h // group`` for ``h``, ``group`` consecutive cells naming the
+    same block (which the pipeline then does not fetch again). One head a
+    cell reads key/value head ``h // ratio`` by that index and nothing is
+    repeated in HBM; where a lane block is several heads, k and v are
+    repeated in front of the call as far as :func:`_cell_repeat` says and no
+    further. Equal head counts: the arrays and the specs as they came (a
+    division by one would still be an operation of every cell's index
+    maps)."""
+    d, dv, ratio = _head_sizes(q, k, v, heads)
+    rep = _cell_repeat(ratio, cell)
+    group = ratio // rep
+    if rep > 1:
+        k, v = (jnp.repeat(x.reshape(*x.shape[:2], -1, size), rep,
+                           axis=2).reshape(*x.shape[:2], -1)
+                for x, size in ((k, d), (v, dv)))
+
+    def at(spec: pl.BlockSpec) -> pl.BlockSpec:
+        if group == 1:
+            return spec
+
+        def index_map(*cell):
+            b, rows, lanes = spec.index_map(*cell)
+            return b, rows, lanes // group
+
+        return pl.BlockSpec(spec.block_shape, index_map)
+
+    return k, v, at
+
+
 #: what the forward and the unrolled backward kernels may take of VMEM
 #: where the two head sizes differ: a cell holds the whole sequence of two
 #: heads' k and v (forward, dq) or q, O and dO (dk/dv), 15 MB at 8,192 x
@@ -1080,14 +1151,15 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
               block_q: int, block_k: int, interpret: bool,
               window: Optional[int], unroll: bool,
               mask: Optional[BlockDiffusion] = None):
-    b, s_q, width = q.shape
-    s_k, d, dv = k.shape[1], width // heads, v.shape[2] // heads
+    b, s_q, _ = q.shape
+    s_k, (d, dv, _) = k.shape[1], _head_sizes(q, k, v, heads)
     n_q, n_k = s_q // block_q, s_k // block_k
     cell_rows = s_q if unroll else block_q
     # q, o, k, v and the lse column (float32, one lane in 128)
     cell = _cell_heads(heads, d, n_q * n_k, unroll,
                        (s_q + s_k) * (d + dv) * q.dtype.itemsize
                        + s_q * 128 * 4, dv)
+    k, v, shared = _shared(q, k, v, heads, cell)
     kernel = functools.partial(
         _fwd_kernel, head_dim=d, value_dim=dv, block_q=block_q,
         block_k=block_k, causal=causal, scale=scale, offset=s_k - s_q,
@@ -1121,7 +1193,7 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, pl.cdiv(heads, cell), s_q // cell_rows),
-        in_specs=[mine(d), whole(d), whole(dv)],
+        in_specs=[mine(d), shared(whole(d)), shared(whole(dv))],
         out_specs=[
             mine(dv),
             pl.BlockSpec((None, cell, cell_rows, 1), lambda b, h, qi: (b, h, qi, 0)),
@@ -1488,11 +1560,12 @@ def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
               window: Optional[int], mask: Optional[BlockDiffusion]):
     """The looped backward: ONE call on dkv's grid gives all three — dq
     summed in VMEM across the K-block axis, which is therefore sequential."""
-    b, s_q, width = q.shape
-    s_k, d, vd = k.shape[1], width // heads, v.shape[2] // heads
+    b, s_q, _ = q.shape
+    s_k, (d, vd, _) = k.shape[1], _head_sizes(q, k, v, heads)
     item = q.dtype.itemsize
     n_q, n_k = s_q // block_q, s_k // block_k
     cell = _cell_heads(heads, d, 0, False, 0, vd)
+    k, v, shared = _shared(q, k, v, heads, cell)
 
     def whole(size):
         return pl.BlockSpec((None, s_q, cell * size), lambda b, h, ki: (b, 0, h))
@@ -1512,13 +1585,14 @@ def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
             causal=causal, scale=scale, offset=s_k - s_q, window=window,
             mask=mask),
         grid=(b, pl.cdiv(heads, cell), n_k),
-        in_specs=[whole(d), mine(d), mine(vd), whole(vd), whole(vd),
-                  lse_spec],
+        in_specs=[whole(d), shared(mine(d)), shared(mine(vd)), whole(vd),
+                  whole(vd), lse_spec],
         out_specs=[whole(d), mine(d), mine(vd)],
+        # dk and dv a QUERY head: the rule sums a group's (`_flash_bwd`)
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, s_k, heads * d), k.dtype),
+            jax.ShapeDtypeStruct((b, s_k, heads * vd), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((n_q, cell * d, block_q), jnp.float32),
@@ -1541,8 +1615,8 @@ def _bwd(
     dq_blocks: Tuple[int, int], dkv_blocks: Tuple[int, int], interpret: bool,
     window: Optional[int] = None, mask: Optional[BlockDiffusion] = None,
 ):
-    b, s_q, width = q.shape
-    s_k, d, vd = k.shape[1], width // heads, v.shape[2] // heads
+    b, s_q, _ = q.shape
+    s_k, (d, vd, _) = k.shape[1], _head_sizes(q, k, v, heads)
     item = q.dtype.itemsize
     block_q, block_k = dkv_blocks
     if not _unrolled(s_q // block_q, s_k // block_k):
@@ -1568,39 +1642,43 @@ def _bwd(
                        (2 * s_q + s_k) * (d + vd) * item + s_q * 8 * 4,
                        vd)  # q dq k at d, o do v at vd, lse
     lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
+    k_in, v_in, shared = _shared(q, k, v, heads, cell)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
                           **static),
         grid=(b, pl.cdiv(heads, cell), 1),
         in_specs=[
-            mine(s_q, cell, d), whole(s_k, cell, d), whole(s_k, cell, vd),
+            mine(s_q, cell, d), shared(whole(s_k, cell, d)),
+            shared(whole(s_k, cell, vd)),
             mine(s_q, cell, vd), mine(s_q, cell, vd), lse_spec,
         ],
         out_specs=mine(s_q, cell, d),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         **_call_name("bwd_dq", d, vd, window, mask=mask),
-    )(q, k, v, out, do, lse_in)
+    )(q, k_in, v_in, out, do, lse_in)
 
     block_q, block_k = dkv_blocks
     cell = _cell_heads(heads, d, (s_q // block_q) * (s_k // block_k), True,
                        ((s_q + 2 * s_k) * d + 2 * (s_q + s_k) * vd) * item
                        + s_q * 8 * 4, vd)  # q k dk at d, o do v dv at vd, lse
     lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
+    k_in, v_in, shared = _shared(q, k, v, heads, cell)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
                           **static),
         grid=(b, pl.cdiv(heads, cell), 1),
-        in_specs=[whole(s_q, cell, d), mine(s_k, cell, d), mine(s_k, cell, vd),
+        in_specs=[whole(s_q, cell, d), shared(mine(s_k, cell, d)),
+                  shared(mine(s_k, cell, vd)),
                   whole(s_q, cell, vd), whole(s_q, cell, vd), lse_spec],
         out_specs=[mine(s_k, cell, d), mine(s_k, cell, vd)],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, s_k, heads * d), k.dtype),
+            jax.ShapeDtypeStruct((b, s_k, heads * vd), v.dtype),
         ],
         interpret=interpret,
         **_call_name("bwd_dkv", d, vd, window, mask=mask),
-    )(q, k, v, out, do, lse_in)
+    )(q, k_in, v_in, out, do, lse_in)
     return dq, dk, dv
 
 
@@ -1868,23 +1946,25 @@ def _band_specs(band: Band, lanes: int, s: int):
 
 def _band_fwd(q, k, v, *, heads: int, scale: float, band: Band, window: int,
               interpret: bool):
-    b, s, width = q.shape
-    d, dv = width // heads, v.shape[2] // heads
+    b, s, _ = q.shape
+    d, dv, _ = _head_sizes(q, k, v, heads)
     cell = _cell_heads(heads, d, 0, False, 0, dv)
+    k, v, shared = _shared(q, k, v, heads, cell)
     own, prev, _ = _band_specs(band, cell * d, s)
     own_v, prev_v, _ = _band_specs(band, cell * dv, s)
     return pl.pallas_call(
         functools.partial(_band_fwd_kernel, head_dim=d, value_dim=dv,
                           band=band, window=window, scale=scale),
         grid=(b, pl.cdiv(heads, cell), s // band.rows),
-        in_specs=[own, own, own_v, prev, prev_v],
+        in_specs=[own, shared(own), shared(own_v), shared(prev),
+                  shared(prev_v)],
         out_specs=[
             own_v,
             pl.BlockSpec((None, cell, band.rows, 1),
                          lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(v.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, s, heads * dv), q.dtype),
             jax.ShapeDtypeStruct((b, heads, s, 1), jnp.float32),
         ],
         compiler_params=_BAND_PARAMS,
@@ -1895,9 +1975,10 @@ def _band_fwd(q, k, v, *, heads: int, scale: float, band: Band, window: int,
 
 def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
               dq_band: Band, dkv_band: Band, window: int, interpret: bool):
-    b, s, width = q.shape
-    d, dv = width // heads, v.shape[2] // heads
+    b, s, _ = q.shape
+    d, dv, _ = _head_sizes(q, k, v, heads)
     cell = _cell_heads(heads, d, 0, False, 0, dv)
+    k, v, shared = _shared(q, k, v, heads, cell)
     static = dict(head_dim=d, value_dim=dv, window=window, scale=scale)
 
     band = dq_band
@@ -1907,7 +1988,8 @@ def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
     dq = pl.pallas_call(
         functools.partial(_band_dq_kernel, band=band, **static),
         grid=(b, pl.cdiv(heads, cell), s // band.rows),
-        in_specs=[own, own, own_v, prev, prev_v, own_v, own_v, lse_spec],
+        in_specs=[own, shared(own), shared(own_v), shared(prev),
+                  shared(prev_v), own_v, own_v, lse_spec],
         out_specs=own,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_BAND_PARAMS,
@@ -1924,12 +2006,12 @@ def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
     dk, dv = pl.pallas_call(
         functools.partial(_band_dkv_kernel, band=band, **static),
         grid=(b, pl.cdiv(heads, cell), s // band.rows),
-        in_specs=[own, own_v, own, own_v, own_v, lse_spec, nxt, nxt_v, nxt_v,
-                  lse_next_spec],
+        in_specs=[shared(own), shared(own_v), own, own_v, own_v, lse_spec,
+                  nxt, nxt_v, nxt_v, lse_next_spec],
         out_specs=[own, own_v],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, s, heads * d), k.dtype),
+            jax.ShapeDtypeStruct((b, s, heads * dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((cell, 1, band.rows + band.reach), jnp.float32)],
@@ -1982,14 +2064,26 @@ def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, mask,
                res, g):
     q, k, v, out, lse = res
     if isinstance(blocks[1], Band):
-        return _band_bwd(q, k, v, out, lse, g, heads=heads, scale=scale,
-                         dq_band=blocks[1], dkv_band=blocks[2],
-                         window=window, interpret=interpret)
-    return _bwd(
-        q, k, v, out, lse, g, heads=heads, causal=causal, scale=scale,
-        dq_blocks=blocks[1], dkv_blocks=blocks[2], interpret=interpret,
-        window=window, mask=mask,
-    )
+        dq, dk, dv = _band_bwd(q, k, v, out, lse, g, heads=heads, scale=scale,
+                               dq_band=blocks[1], dkv_band=blocks[2],
+                               window=window, interpret=interpret)
+    else:
+        dq, dk, dv = _bwd(
+            q, k, v, out, lse, g, heads=heads, causal=causal, scale=scale,
+            dq_blocks=blocks[1], dkv_blocks=blocks[2], interpret=interpret,
+            window=window, mask=mask,
+        )
+    ratio = _head_sizes(q, k, v, heads)[2]
+    if ratio > 1:
+        # dk and dv leave the kernels a QUERY head (the looped backward's
+        # K-block axis carries dq's sum, so a group's heads cannot be that
+        # axis's neighbours too); a shared head's gradient is its readers'
+        # sum — what the transpose of a repeat in front of the kernels was,
+        # in the same dtype
+        dk, dv = (jax.lax.reduce_sum(
+            dx.reshape(*x.shape[:2], heads // ratio, ratio, -1),
+            axes=(3,)).reshape(x.shape) for dx, x in ((dk, k), (dv, v)))
+    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -2014,6 +2108,11 @@ def flash_attention(
     path). ``v`` may carry a head size of its own (``[batch, seq, heads,
     value_dim]``: the result's); the calls are then named ``mla_*``.
 
+    Grouped queries: k and v may carry fewer heads than q, ``kv_heads``
+    dividing ``heads``; query head ``h`` reads key/value head ``h // (heads
+    // kv_heads)`` BY ITS INDEX in every kernel (:func:`_shared`), and dk,
+    dv come back at ``kv_heads``, each the sum of its readers'.
+
     ``window`` (with ``causal``): query i sees only the ``window`` keys up
     to its own, ``0 <= i + (s_k - s_q) - j < window``. K-blocks (Q-blocks in
     the backward) wholly outside that band are not visited — on a square
@@ -2032,9 +2131,14 @@ def flash_attention(
     ``block_q`` / ``block_k``, when passed, hold for every kernel; left out,
     each kernel's are chosen from what the call shows."""
     b, s, h, d = q.shape
-    s_k, dv = k.shape[1], v.shape[-1]
+    s_k, kv_heads, dv = k.shape[1], k.shape[2], v.shape[-1]
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if h % kv_heads or v.shape[2] != kv_heads:
+        raise ValueError(
+            f"flash attention: {h} query heads over {kv_heads} / "
+            f"{v.shape[2]} key / value heads: every key/value head is read "
+            f"by as many query heads (flash_attention refuses it)")
     if mask is not None and (causal or window is not None):
         raise ValueError(
             f"flash attention: the block mask {mask} replaces causal="
@@ -2083,6 +2187,14 @@ def flash_attention(
     sizes = f"head_dim {d}" if dv == d else f"head_dim {d}/{dv}"
     lanes = f"{tile * d}-lane block" if dv == d else \
         f"{tile * d}-lane block of q, k and a {tile * dv}-lane block of v, O"
+    ratio = h // kv_heads
+    repeat = _cell_repeat(ratio, tile)
+    shared = "" if ratio == 1 else (
+        f"; k, v of {kv_heads} head(s), {ratio} query heads each, "
+        + ("read by index" if repeat == 1 else
+           f"repeated {repeat}-fold to a cell's heads")
+        + (" (as far as a wider cell's where a short sequence widens it)"
+           if "unrolled" in chosen and repeat < ratio else ""))
     log_once(log, f"flash attention: {how} Pallas kernel on "
                   f"{device.platform} ({device.device_kind}), "
                   f"{jnp.dtype(q.dtype).name} operands to the MXU, blocks "
@@ -2093,11 +2205,11 @@ def flash_attention(
                          mask, *mask.block_pairs(blocks[0][0])))
                   + f", {sizes}, on "
                   f"[batch, seq, heads·head_dim] = [{b}, {s}, {h * d}] with "
-                  f"{tile} head(s) to a {lanes}")
+                  f"{tile} head(s) to a {lanes}{shared}")
     # [B, S, H, d] -> [B, S, H·d] and back: the same bytes in the same order
     out = _flash(
-        q.reshape(b, s, h * d), k.reshape(b, s_k, h * d),
-        v.reshape(b, s_k, h * dv), h, causal, scale, blocks, interpret, window,
-        mask,
+        q.reshape(b, s, h * d), k.reshape(b, s_k, kv_heads * d),
+        v.reshape(b, s_k, kv_heads * dv), h, causal, scale, blocks, interpret,
+        window, mask,
     )
     return out.reshape(b, s, h, dv)

@@ -1,9 +1,10 @@
 """The flash kernels on the model's own layout, ``[batch, seq,
 heads·head_dim]`` in blocks of whole 128-lane tiles: even and odd head
 counts, rectangles on both sides of ``_UNROLL_PAIRS``, grouped queries
-repeated in front of the kernels' view, and nothing but reshapes around the
-``pallas_call``s. Interpreted on the CPU, ONE jitted program a side and case
-(``conftest.out_and_grads``) on inputs drawn on the host."""
+whose shared key/value heads the kernels read by index, and nothing but
+reshapes around the ``pallas_call``s. Interpreted on the CPU, ONE jitted
+program a side and case (``conftest.out_and_grads``) on inputs drawn on the
+host."""
 
 import functools
 
@@ -12,12 +13,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from conftest import normal, out_and_grads
-from test_flash_backward import (assert_grads_close, cos_weighed,
-                                 flash_and_reference)
+from test_flash_backward import cos_weighed, flash_and_reference
 
 from easydl_tpu.ops import attention as attention_module
 from easydl_tpu.ops.attention import multihead_attention
-from easydl_tpu.ops.flash_attention import flash_attention
+from easydl_tpu.ops.flash_attention import (
+    Band,
+    BlockDiffusion,
+    choose_blocks,
+    flash_attention,
+)
+
+
+def _grads_within(got, want, tol):
+    """dq, dk, dv finite and within ``tol`` of the largest entry of the
+    reference's."""
+    for g, w, name in zip(got, want, "qkv"):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all(), f"d{name}"
+        assert np.abs(g - w).max() <= tol * np.abs(w).max(), f"d{name}"
 
 
 def _flash_vs_reference(q, k, v, *, causal, block_q, block_k, atol, rtol,
@@ -29,10 +43,7 @@ def _flash_vs_reference(q, k, v, *, causal, block_q, block_k, atol, rtol,
     assert out.shape == q.shape and out.dtype == q.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=atol, rtol=rtol)
-    for g, w, name in zip(got, g_want, "qkv"):
-        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-        assert np.isfinite(g).all(), f"d{name}"
-        assert np.abs(g - w).max() <= grad_tol * np.abs(w).max(), f"d{name}"
+    _grads_within(got, g_want, grad_tol)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -71,33 +82,101 @@ def test_the_models_layout_rectangular_unrolled_and_looped(
                         block_k=block_k, atol=2e-5, rtol=2e-5, grad_tol=5e-4)
 
 
-@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (6, 3), (6, 1), (3, 3)],
-                         ids=["4-over-2", "6-over-3", "6-over-1", "3-over-3"])
-def test_grouped_queries_reach_the_kernels_repeated(monkeypatch, heads, kv_heads):
-    """``multihead_attention`` repeats the shared key/value heads on the
-    heads axis in front of the kernels' view; the repeat's transpose sums
-    their gradients."""
-    monkeypatch.setattr(attention_module, "flash_attention",
-                        functools.partial(flash_attention, interpret=True))
-    q, k, v = normal(13, (2, 64, heads, 64), *[(2, 64, kv_heads, 64)] * 2)
-    flash = functools.partial(multihead_attention, causal=True, impl="flash")
-    ref = functools.partial(multihead_attention, causal=True, impl="reference")
-    out, got = out_and_grads(flash, cos_weighed)(q, k, v)
-    want, g_want = out_and_grads(ref, cos_weighed)(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
+def _grouped(name, heads, kv_heads, d=64, rows=64, block=None, batch=2,
+             dtype="float32", **call):
+    return pytest.param(heads, kv_heads, d, rows, block, batch, dtype, call,
+                        name.endswith("band"), id=name)
+
+
+#: the four of PR 30 at heads of 64 under the rule's own blocks (one pair: a
+#: cell widened to all the heads, so the whole ratio is repeated), then heads
+#: of 128 — one to a cell, read by index — on both sides of `_UNROLL_PAIRS`,
+#: one pair a head (three heads a cell: a shared head repeated three-fold,
+#: two cells to a block), two heads of 64 a cell under a ratio of four (the
+#: hybrid's: repeated two-fold), the band path and the block mask
+GROUPED = [
+    _grouped("4-over-2", 4, 2), _grouped("6-over-3", 6, 3),
+    _grouped("6-over-1", 6, 1), _grouped("3-over-3", 3, 3),
+    _grouped("128-8-over-2-unrolled", 8, 2, 128, 64, 32, 1),
+    _grouped("128-6-over-1-unrolled", 6, 1, 128, 64, 32, 1),
+    _grouped("128-6-over-1-one-pair", 6, 1, 128, 32, 32, 1),
+    _grouped("128-8-over-2-looped", 8, 2, 128, 160, 32, 1),
+    _grouped("128-6-over-1-looped", 6, 1, 128, 160, 32, 1),
+    _grouped("128-8-over-2-looped-bf16", 8, 2, 128, 160, 32, 1, "bfloat16"),
+    _grouped("64-8-over-2-looped", 8, 2, 64, 160, 32, 1),
+    _grouped("128-8-over-2-band", 8, 2, 128, 192, 16, 1, window=9),
+    _grouped("128-6-over-1-band", 6, 1, 128, 192, 16, 1, window=16),
+    _grouped("128-8-over-2-window-looped", 8, 2, 128, 256, 16, 1, window=17),
+    _grouped("128-8-over-2-blockmask-unrolled", 8, 2, 128, 128, 32, 1,
+             mask=BlockDiffusion(4, 64)),
+    _grouped("128-6-over-1-blockmask-looped", 6, 1, 128, 256, 32, 1,
+             mask=BlockDiffusion(4, 128)),
+]
+
+
+@pytest.mark.parametrize(
+    "heads,kv_heads,d,rows,block,batch,dtype,call,band", GROUPED)
+def test_grouped_queries_reach_the_kernels_by_index(
+        monkeypatch, heads, kv_heads, d, rows, block, batch, dtype, call,
+        band):
+    """``multihead_attention`` hands the kernels k and v at the key/value
+    heads and query head ``h`` reads head ``h // ratio`` by its index: the
+    result is, bit for bit, that of the kernels on k and v REPEATED to the
+    query's heads in front of them (the parent's program), dq likewise, and
+    dk, dv — at k's and v's own shapes — the repeat's transpose of theirs;
+    all four stand by the reference path's."""
+    kernels = functools.partial(flash_attention, interpret=True,
+                                block_q=block, block_k=block)
+    monkeypatch.setattr(attention_module, "flash_attention", kernels)
+    q, k, v = normal(13, (batch, rows, heads, d),
+                     *[(batch, rows, kv_heads, d)] * 2, dtype=dtype)
+    call = dict(causal="mask" not in call, **call)
+    took = choose_blocks(rows, rows, call["causal"], block, block,
+                         call.get("window"), call.get("mask"))
+    assert isinstance(took[0], Band) == band
+
+    def repeated(q, k, v):
+        return kernels(q, *(jnp.repeat(x, heads // kv_heads, axis=2)
+                            for x in (k, v)), **call)
+
+    out, got = out_and_grads(functools.partial(
+        multihead_attention, impl="flash", **call), cos_weighed)(q, k, v)
+    same, g_same = out_and_grads(repeated, cos_weighed)(q, k, v)
+    want, g_want = out_and_grads(functools.partial(
+        multihead_attention, impl="reference", **call), cos_weighed)(q, k, v)
     assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
-    assert_grads_close(got, g_want, atol=5e-4, rtol=5e-4)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(same))
+    for g, w, name in zip(got, g_same, "qkv"):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=f"d{name}")
+    tight = dtype == "float32"
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(want, np.float32),
+        atol=2e-5 if tight else 2e-2, rtol=2e-5 if tight else 2e-2)
+    _grads_within(got, g_want, 5e-4 if tight else 3e-2)
 
 
-def _primitives_outside_kernels(fn, *args):
-    """Names of every primitive ``fn(*args)`` traces to, at any depth,
-    except what runs inside a ``pallas_call``."""
+def test_head_counts_that_do_not_divide_are_refused():
+    """Five query heads over two key/value heads, and k's count beside
+    another of v's: the ValueError is the kernels' own."""
+    q, k, v = normal(15, (1, 64, 5, 64), *[(1, 64, 2, 64)] * 2)
+    with pytest.raises(ValueError, match="5 query heads over 2 / 2 key"):
+        flash_attention(q, k, v, causal=True, interpret=True)
+    with pytest.raises(ValueError, match="4 query heads over 2 / 4 key"):
+        flash_attention(q[:, :, :4], k, jnp.repeat(v, 2, axis=2),
+                        causal=True, interpret=True)
+    with pytest.raises(ValueError, match="flash_attention refuses it"):
+        multihead_attention(q, k, v, causal=True, impl="flash")
+
+
+def _equations_outside_kernels(fn, *args):
+    """Every equation ``fn(*args)`` traces to, at any depth, except what
+    runs inside a ``pallas_call``."""
     seen = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            seen.append(eqn.primitive.name)
+            seen.append(eqn)
             if eqn.primitive.name == "pallas_call":
                 continue
             for value in eqn.params.values():
@@ -108,6 +187,12 @@ def _primitives_outside_kernels(fn, *args):
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
     return seen
+
+
+def _primitives_outside_kernels(fn, *args):
+    """Their primitives' names."""
+    return [eqn.primitive.name
+            for eqn in _equations_outside_kernels(fn, *args)]
 
 
 @pytest.mark.parametrize("heads", [4, 3], ids=["even", "odd"])
@@ -128,3 +213,44 @@ def test_no_transpose_stands_outside_the_kernels(what, heads):
     assert names.count("pallas_call") == (1 if what == "forward" else 3)
     assert "transpose" not in names, names
     assert not {"dot_general", "mul"} & set(names), names
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+@pytest.mark.parametrize("rows,call,kernels", [
+    (1024, dict(causal=True), 2), (4096, dict(causal=True), 1),
+    (4096, dict(causal=True, window=512), 2),
+    (4096, dict(mask=BlockDiffusion(4, 2048)), 1)],
+    ids=["unrolled", "looped", "band", "blockmask"])
+def test_no_repeat_stands_in_front_of_the_kernels(what, rows, call, kernels):
+    """``multihead_attention(impl="flash")`` at heads of 128, eight query
+    heads over two key/value heads: every ``pallas_call`` takes k and v
+    ``kv_heads·d`` = 256 lanes wide beside q's 1,024, and nothing outside the
+    kernels repeats, joins or gathers (``broadcast_in_dim``, ``concatenate``,
+    ``gather``). The gradient holds exactly two ``reduce_sum``s, dk's and
+    dv's over a group's four query heads, which leave the kernels a query
+    head: ``[batch, rows, 2, 4, 128]`` over axis 3."""
+    heads, kv_heads, d = 8, 2, 128
+    q, k, v, g = normal(16, (1, rows, heads, d),
+                        *[(1, rows, kv_heads, d)] * 2, (1, rows, heads, d),
+                        dtype="bfloat16")
+    attend = functools.partial(multihead_attention, impl="flash", **call)
+    fn = attend if what == "forward" else (
+        lambda q, k, v: jax.vjp(attend, q, k, v)[1](g))
+    eqns = _equations_outside_kernels(fn, q, k, v)
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == (1 if what == "forward" else 1 + kernels)
+    for e in calls:
+        widths = sorted(x.aval.shape[-1] for x in e.invars
+                        if x.aval.dtype == jnp.bfloat16)
+        # k and v (the band kernels take each a second time: the
+        # neighbour's block), then q, and in the backward O, dO
+        assert set(widths) == {kv_heads * d, heads * d}, (e.params["name"],
+                                                          widths)
+        assert widths.count(kv_heads * d) in (2, 4), (e.params["name"], widths)
+    names = [e.primitive.name for e in eqns]
+    assert not {"broadcast_in_dim", "concatenate", "gather",
+                "transpose"} & set(names), names
+    sums = [e for e in eqns if e.primitive.name == "reduce_sum"]
+    assert [(e.invars[0].aval.shape, tuple(e.params["axes"]))
+            for e in sums] == ([] if what == "forward" else [
+                ((1, rows, kv_heads, heads // kv_heads, d), (3,))] * 2)

@@ -53,6 +53,18 @@ def _mosaic_calls(compiled, kernel: str = "") -> list:
             and "%" + kernel in line.split(" = ")[0]]
 
 
+def _operand_shapes(text: str, kernel: str) -> list:
+    """The operands' shapes (``bf16[1,16384,512]``) of the compiled text's
+    one Mosaic call under the name ``kernel``."""
+    shape = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+\[[\d,]*\])",
+                            text, re.M))
+    line, = (line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and f"/{kernel}/" in line)
+    operands = re.search(r"custom-call\(([^)]*)\)", line).group(1)
+    return [shape[name] for name in re.findall(r"%[\w.-]+", operands)]
+
+
 def _loss(attend):
     return lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum()
 
@@ -404,6 +416,13 @@ def test_sdars_attention_compiles_at_16k_rows_under_the_block_mask(v5e_2x2):
         assert any(f"/{name}/" in line for line in calls), name
     for other in ("flash_fwd", "flash_bwd", "bd_bwd_dq"):
         assert not any(f"/{other}/" in line for line in calls), other
+    # k and v reach both kernels at the FOUR key/value heads (read by index:
+    # no array of either at the query's 32 heads stands in front of them);
+    # the backward's other three are q, O and dO
+    wide, narrow = "bf16[1,16384,4096]", "bf16[1,16384,512]"
+    assert _operand_shapes(text, "bd_fwd") == [wide, narrow, narrow]
+    assert _operand_shapes(text, "bd_bwd")[:5] == [wide, narrow, narrow,
+                                                   wide, wide]
 
 
 def test_sdars_attention_norms_q_and_k_inside_the_rotary_kernel(v5e_2x2):
