@@ -34,7 +34,8 @@ def kernels(config: Dict[str, Any]) -> Dict[str, Kernel]:
         return lambda call: flops_mellum.flash_band_cost(
             kind, call["batch_heads"], call["seq"], call["head_dim"],
             config["head_dim"], config["sliding_window"])
-    # the full layer's k and v are repeated eightfold in HBM
+    # the full layer's k and v at the 4 key/value heads, as they reach the
+    # kernels (8 query heads read each by index since PR 56)
     return {"flash_fwd_roofline": Kernel("flash_fwd", gqa("fwd", config)),
             "flash_bwd_roofline": Kernel("flash_bwd", gqa("bwd", config)),
             "band_flash_fwd_roofline": Kernel("swa_fwd", band("fwd")),

@@ -28,7 +28,8 @@ def scopes(config: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def kernels(config: Dict[str, Any]) -> Dict[str, Kernel]:
-    # k and v are repeated sixteenfold in HBM
+    # k and v at the 2 key/value heads, as they reach the kernels (16 query
+    # heads read each by index since PR 56)
     return {"flash_fwd_roofline": Kernel("flash_fwd", gqa("fwd", config)),
             "flash_bwd_roofline": Kernel("flash_bwd", gqa("bwd", config))}
 

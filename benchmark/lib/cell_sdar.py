@@ -30,7 +30,7 @@ def scopes(config: Dict[str, Any]) -> Dict[str, Any]:
 def kernels(config: Dict[str, Any]) -> Dict[str, Kernel]:
     def masked(kind):
         # FLOPs of the mask's LIVE pairs alone; k and v at the key/value
-        # heads, though the program repeats them eightfold in HBM
+        # heads, as they reach the kernels (read by index since PR 56)
         return lambda call: flops_sdar.flash_block_cost(
             kind, call["batch_heads"], call["seq"],
             config["num_attention_heads"], config["num_key_value_heads"],

@@ -58,6 +58,25 @@ def step_interval_s(records, t_open, t_close, save_steps) -> Optional[float]:
     return statistics.median(gaps) if gaps else None
 
 
+def window_steps_per_s(records: List[Record], t_open: float,
+                       t_close: float) -> Optional[float]:
+    """All the steps over all the time of a window that ONE generation
+    fills: from the record that opened the window (the newest at or before
+    ``t_open``; the window's first where there is none) to the window's last
+    record, the steps between the two over the seconds between them — a
+    save's stall, a compile or any other wait among them. None where the
+    two records are of different generations: a kill lies between them."""
+    inside = in_window(records, t_open, t_close)
+    before = [r for r in records if r["t"] < t_open]
+    if not inside:
+        return None
+    first = max(before, key=lambda r: r["t"]) if before else inside[0]
+    last = inside[-1]
+    if first["generation"] != last["generation"] or last["t"] <= first["t"]:
+        return None
+    return (last["step"] - first["step"]) / (last["t"] - first["t"])
+
+
 def loop_overhead_pct(records, t_open, t_close, save_steps) -> Optional[float]:
     """Share of the worker loop's pace that is not the step itself:
     ``1 - median step_time_s / median interval``, over the same pairs."""

@@ -62,9 +62,11 @@ class Kernel(NamedTuple):
 
 def causal(kind: str) -> Callable[[Dict[str, Any]], Dict[str, float]]:
     """The cost of a causal flash call of ``kind`` on its first result's
-    ``[batch, seq, heads x head_dim]``, key/value heads repeated to the
-    query's as the kernel is handed them (``lib/flops.flash_causal_cost``;
-    the width taken as one head's: the same FLOPs and matrix bytes)."""
+    ``[batch, seq, heads x head_dim]``, k and v counted at the QUERY's heads
+    (``lib/flops.flash_causal_cost``; the width taken as one head's: the same
+    FLOPs and matrix bytes). That is what a call of equal head counts reads;
+    a grouped-query call is handed k and v at the key/value heads since PR
+    56 and reads fewer bytes than this counts (its FLOPs set its roofline)."""
     return lambda call: flops.flash_causal_cost(
         kind, call["batch_heads"], call["seq"], call["head_dim"])
 
@@ -73,8 +75,8 @@ def gqa(kind: str, config: Dict[str, Any]
         ) -> Callable[[Dict[str, Any]], Dict[str, float]]:
     """The cost of a causal flash call of ``kind`` (``fwd``, ``bwd``) under
     grouped-query attention by the configuration's head counts: k and v (dk,
-    dv) at the key/value heads a grouped kernel could not avoid, though the
-    program repeats them to the query heads in HBM
+    dv) at the key/value heads, as they reach the kernels since PR 56 (a
+    query head reads its shared head by index; nothing is repeated in HBM)
     (``lib/flops.flash_gqa_cost``)."""
     return lambda call: flops.flash_gqa_cost(
         kind, call["batch_heads"], call["seq"], config["num_attention_heads"],

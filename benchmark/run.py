@@ -20,7 +20,9 @@ So a later PR adds a configuration, a mix, a driver or a metric by adding
 files and entries; this file has no branch on any of their names. With
 ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, the device's busy time (``busy`` among
-the artifacts) and the ``breakdown``. The last line
+the artifacts) and the ``breakdown``; where a driver keeps the numbers that
+``correct`` compared (``compared``: name, number, limit) they are the line's
+last key and the last lines of standard error. The last line
 of standard output is the result; a run that cannot measure (no accelerator,
 too few chips, no program beside the benchmark) exits non-zero without one.
 """
@@ -157,6 +159,13 @@ def main(argv=None) -> int:
         line["device"].update(artifacts.get("busy", {}))
         if artifacts.get("breakdown"):
             line["breakdown"] = artifacts["breakdown"]
+    # each number `correct` compared, beside its limit: the line's last key
+    # and the last lines of standard error (a driver that keeps none: none)
+    if artifacts.get("compared"):
+        line["compared"] = artifacts["compared"]
+        for name, (number, limit) in line["compared"].items():
+            print(f"benchmark: compared {name} {number!r} limit {limit!r}",
+                  file=sys.stderr)
     with open(os.path.join(workdir, "artifacts.json"), "w") as f:
         json.dump(artifacts, f, default=str)
     sys.stderr.flush()

@@ -53,7 +53,8 @@ def check_rehearsal_file(test_json, cell, real_cell):
         bench = json.load(f)
     assert len(bench["per_layer"]) <= 96
     assert all("workloads" in m for m in bench["per_layer"])
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) <= max(
+        1, len(bench["workloads"]) // 4)
     found, = (w for w in bench["workloads"] if w["name"] == real_cell)
     return found
 

@@ -177,3 +177,24 @@ def test_no_kill_no_numbers():
     assert tl.resume_s(records, 103.5, 1) is None
     assert tl.save_stall_s(records, 10) is None
     assert tl.step_interval_s(records, 200, 300, []) is None
+
+
+@pytest.mark.parametrize("stall, steps, seconds", [
+    (0.0, 9, 9.0),    # nine steps of a second each
+    (3.0, 9, 12.0),   # a save's stall among them: the rate falls with it
+    (45.0, 5, 50.0),  # a compile that eats the window: five steps of it
+])
+def test_a_one_generation_windows_rate_is_all_steps_over_all_time(
+        stall, steps, seconds):
+    """From the record that opened the window (the newest at or before
+    ``t_open``) to the window's last; the median of gaps sees none of it."""
+    records = [rec(s, 1, 50.0 + s) for s in range(1, 6)]    # before the kill
+    records += [rec(6, 2, 100.0 - 0.02)]                    # opens the window
+    records += [rec(s, 2, 100.0 - 0.02 + (s - 6) + (stall if s > 9 else 0.0))
+                for s in range(7, 16)]
+    assert tl.window_steps_per_s(records, 100.0, 150.0) == pytest.approx(
+        steps / seconds)
+    assert tl.step_interval_s(records, 100.0, 150.0, []) == pytest.approx(1.0)
+    # a kill between the two records: no one generation, no such rate
+    assert tl.window_steps_per_s(records, 52.5, 150.0) is None
+    assert tl.window_steps_per_s(records, 200.0, 250.0) is None

@@ -72,6 +72,7 @@ def test_driver_rehearsal(cell, devices, seconds, trace, expect):
 
 @pytest.mark.parametrize("cell", ["gpt2-medium.steady",
                                   "gpt2-medium.kill-resume",
+                                  "gpt2-medium.reshape-resume",
                                   "gpt2-xl.fsdp4-steady"])
 def test_no_chip_no_metric(cell):
     """Off the TPU a real cell exits non-zero and prints no result line."""

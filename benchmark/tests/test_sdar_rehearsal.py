@@ -65,7 +65,7 @@ def test_the_rehearsal_file_lists_the_new_readers():
     assert {"mfu", "attn_time_pct", "flash_time_pct", "flash_fwd_roofline",
             "flash_bwd_roofline", "moe_time_pct", "experts_time_pct",
             "route_time_pct", "router_time_pct", "rope_time_pct",
-            "noise_time_pct", "qk_norm_time_pct", "device_idle_pct",
+            "noise_time_pct", "device_idle_pct",
             "fwd_time_pct", "bwd_time_pct", "remat_time_pct",
             "head_loss_time_pct", "optimizer_time_pct",
             "unscoped_time_pct"} <= DEVICE_DERIVED
@@ -79,12 +79,11 @@ def test_the_rehearsal_file_lists_the_new_readers():
     assert mix["tokens"]["support"] == 18992 and mix["driver"] == "steady"
     bench = _json(os.path.dirname(BENCH), "BENCHMARK.json")
     new = [m for m in bench["per_layer"] if m.get("workloads") == [REAL_CELL]]
-    assert {m["name"] for m in new} == COUNTER_READERS | {
-        "noise_time_pct", "qk_norm_time_pct"}
+    # (the per-head q/k norm's reader went with PR 57: the norm runs inside
+    # the rotary kernel since PR 54, and `rope_time_pct` reads both)
+    assert {m["name"] for m in new} == COUNTER_READERS | {"noise_time_pct"}
     # found by what they are, never by where they stand: the next PR
-    # appends its own (test_goodput_readers.py:152 holds the LAST three
-    # entries to be goodput's and fails since this PR's four stand behind
-    # them; that file is the benchmark's own and a `benchmark` PR's to mend)
+    # appends its own
 
 
 def test_the_new_readers_find_nothing_in_a_program_without_the_names():
@@ -146,7 +145,7 @@ def test_every_new_reader_returns_a_number_on_a_synthetic_trace(monkeypatch):
                                "flash_block_pairs": 1024.0}},
         "step_s": [0.5], "steps": 100, "tokens_per_step": 8192,
         "window_s": 50.0, "chips": 1}
-    want = {"noise_time_pct": 2, "qk_norm_time_pct": 1, "rope_time_pct": 1,
+    want = {"noise_time_pct": 2, "rope_time_pct": 1,
             "flash_time_pct": 2, "moe_time_pct": 2, "router_time_pct": 1,
             "experts_time_pct": 1}
     for name, ops in want.items():

@@ -203,6 +203,6 @@ def test_the_test_files_entries_are_the_real_files_entries():
     with open(os.path.join(HERE, "BENCHMARK.worker-test.json")) as f:
         test = {m["name"]: m for m in json.load(f)["per_layer"]}
     for name in NINE:
-        assert real[name]["workloads"] == ["gpt2-medium.kill-resume"]
+        assert real[name]["workloads"][0] == "gpt2-medium.kill-resume"
         assert dict(real[name], workloads=None) == dict(test[name],
                                                         workloads=None)
