@@ -382,8 +382,9 @@ class TransformerConfig:
     #: remat granularity: "full" recomputes the whole block (min memory);
     #: "dots" keeps what costs a matrix product to make again — every
     #: product, q, k, v and `out` after their bias — and the flash forward's
-    #: `lse`, and recomputes the elementwise rest. Both keep the flash
-    #: forward's `out` and `lse` where the call is dear to make again
+    #: `lse`, and recomputes the elementwise rest. Both keep what is dear to
+    #: make again — the flash forward's `out` and `lse`, under "full" the
+    #: FFN's first products — where the compiled step leaves it room
     #: (``ops/remat.py`` has the rule and why).
     remat_policy: str = "full"
     attention_impl: str = "auto"
@@ -1422,10 +1423,14 @@ def _ffn(block, h, state=None):
     own, any other layer hands ``state`` on as it came."""
     cfg = block.cfg
     aux = jnp.zeros((), jnp.float32)
+    # the first products are candidates for what remat ``full`` keeps
+    # (``ops/remat.py``): the width they contract is what a byte of them costs
+    width = h.shape[-1]
     if block.ffn == "swiglu":
         gate = _projection(block, cfg.d_ff, ("embed", "mlp"), ("mlp",),
                            "gate")(h)
         up = _projection(block, cfg.d_ff, ("embed", "mlp"), ("mlp",), "up")(h)
+        gate, up = remat.name_products((gate, up), width)
         h = nn.silu(gate) * up
     elif block.ffn == "moe":
         m = cfg.moe
@@ -1446,8 +1451,9 @@ def _ffn(block, h, state=None):
         )(h, state)
         return y, aux, state if routed is None else routed
     else:
-        h = nn.gelu(_projection(block, cfg.d_ff, ("embed", "mlp"), ("mlp",),
-                                "up")(h))
+        up, = remat.name_products((_projection(
+            block, cfg.d_ff, ("embed", "mlp"), ("mlp",), "up")(h),), width)
+        h = nn.gelu(up)
     h = _projection(block, cfg.d_model, ("mlp", "embed"), ("embed",), "down",
                     residual=True)(h)
     return h, aux, state
@@ -1523,7 +1529,7 @@ class Block(nn.Module):
         # (`attention` | `ssm`) and under `ffn` in the compiled program's
         # op_name paths, which the device trace's reducers read; flax's
         # module names sit inside them.
-        with remat.tally() as named:
+        with remat.block(cfg.remat_policy if cfg.remat else None) as said:
             family, kind = cfg.mixer_family(self.mixer)
             inner = family.inner(kind)
             with jax.named_scope(family.scope), (
@@ -1544,30 +1550,9 @@ class Block(nn.Module):
                         self, _norm(cfg, "ln_mlp", dtype=dt)(x), state)
                     x = residual(x, h, "ln_mlp")
         if cfg.remat and not self.is_initializing():
-            saved = remat.KEPT[cfg.remat_policy]
-            kept = [value for value in named if value.label in saved]
-            labels = [value.label for value in kept]
-            kinds = ", ".join(f"{labels.count(kind)} x {kind}"
-                              for kind in dict.fromkeys(labels))
-            left = sorted({value.label for value in named} - set(saved))
-            costs = sorted({round(value.flop_per_byte) for value in named
-                            if value.flop_per_byte is not None})
             log_once(log, f"remat {cfg.remat_policy}: a ({self.mixer}, "
-                          f"{self.ffn}) layer at {tuple(x.shape)} keeps "
-                          f"{len(kept)} values by name ({kinds or 'none'}), "
-                          f"{sum(value.bytes for value in kept) / 1e6:.1f} MB "
-                          f"a microbatch as traced (a kernel's per shard "
-                          f"under a mesh), "
-                          + ("beside its unnamed products"
-                             if cfg.remat_policy == "dots"
-                             else "and makes everything else again")
-                          + f"; named and not kept: "
-                            f"{', '.join(left) or 'nothing'}"
-                          + "".join(
-                              f"; its flash forward costs {cost:,} FLOP a "
-                              f"byte of out + lse to make again (kept from "
-                              f"{remat.FLASH_KEEP_FLOP_PER_BYTE:,})"
-                              for cost in costs))
+                          f"{self.ffn}) layer at {tuple(x.shape)}, "
+                          f"{said.line()}")
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         x = (x, state) if cfg.router_state_width else x
         if self.gives:
@@ -1706,8 +1691,8 @@ class Transformer(nn.Module):
             if cfg.remat_policy not in ("full", "dots"):
                 raise ValueError(f"remat_policy must be 'full' or 'dots', "
                                  f"got {cfg.remat_policy!r}")
-            # "full" keeps the flash forward's results where ops/remat.py's
-            # rule picks the call, and nothing else. `prevent_cse=False` is
+            # "full" keeps what ops/remat.py's chooser keeps of the block's
+            # candidates, and nothing else. `prevent_cse=False` is
             # right inside a loop, which a scanned run is. A run of ONE layer
             # is no loop once XLA has unrolled it, and its second forward is
             # merged with its first: where NO run of the stack is a loop
@@ -1795,12 +1780,14 @@ class Transformer(nn.Module):
                     # the stacked [n_layers, ...] layout the pipeline expects
                     if carried:
                         x = (x, {name: handed[name] for name in carried})
-                    x, layer_aux = nn.scan(
-                        block_cls, length=count, **scan_kwargs)(
-                            cfg, mixer, ffn, *((carried, gives) if carried
-                                               or gives else ()),
-                            name="blocks" if len(runs) == 1 else f"blocks_{i}"
-                    )(x, deterministic, rope)
+                    run_name = "blocks" if len(runs) == 1 else f"blocks_{i}"
+                    # what the block keeps is held once a layer and pass
+                    with remat.run(run_name, count * cfg.loops):
+                        x, layer_aux = nn.scan(
+                            block_cls, length=count, **scan_kwargs)(
+                                cfg, mixer, ffn, *((carried, gives) if carried
+                                                   or gives else ()),
+                                name=run_name)(x, deterministic, rope)
                     if carried:
                         x, _ = x
                     if gives:
@@ -1851,8 +1838,10 @@ class Transformer(nn.Module):
             with jax.named_scope("mtp"):
                 mtp = MtpMerge(cfg, name="mtp_merge")(
                     tok_emb(_next_tokens(tokens)), x)
-                mtp, layer_aux = block_cls(cfg, mixer, ffn, name="mtp_block")(
-                    mtp, deterministic, ropes.get(mixer))
+                with remat.run("mtp_block", 1):
+                    mtp, layer_aux = block_cls(
+                        cfg, mixer, ffn, name="mtp_block")(
+                            mtp, deterministic, ropes.get(mixer))
                 mtp = _norm(cfg, "mtp_ln_f", dtype=dt)(mtp)
             if ffn == "moe":
                 aux = aux + layer_aux

@@ -109,10 +109,10 @@ the forward's (``_bwd_looped``).
 What a rematerialised block may keep. The rule's forward names the two
 results that cost a kernel to make again, ``out`` and the ``lse`` rows
 (``ops/remat.py``), INSIDE the rule: the residuals the backward receives are
-the named values themselves, so a policy that saved both names would run
-the forward kernel once. Remat ``dots`` saves the rows and not ``out``
-(``ops/remat.py`` has the measurements); under any other policy a name is
-inert.
+the named values themselves, so a policy that saves both names runs the
+forward kernel once. Whether they are named is the enclosing block's chooser's
+(``ops/remat.py``: the call's FLOP a byte of ``out`` + ``lse`` against the
+room the compiled step leaves); remat ``dots`` keeps the rows of every call.
 
 Causal masking is bottom-right aligned (``offset = s_k − s_q``: query row
 r sees key columns ≤ r + offset, as the reference's ``tril(k=s_k−s_q)``).
@@ -2027,15 +2027,15 @@ def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, heads, causal, scale, blocks: Blocks, interpret, window,
-           mask=None):
+           mask=None, keeps=(False, False)):
     return _flash_fwd(q, k, v, heads, causal, scale, blocks, interpret,
-                      window, mask)[0]
+                      window, mask, keeps)[0]
 
 
 def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
-               window, mask=None):
+               window, mask=None, keeps=(False, False)):
     if isinstance(blocks[0], Band):
         out, lse = _band_fwd(q, k, v, heads=heads, scale=scale,
                              band=blocks[0], window=window,
@@ -2049,19 +2049,17 @@ def _flash_fwd(q, k, v, heads, causal, scale, blocks: Blocks, interpret,
     # The kernel's column [B, H, S, 1] turned once into dense rows
     # [B, H, S]; both named HERE, so that the residuals below are the named
     # values (a name on the primal outside the rule would name a copy, and
-    # the kernel's own results would still be made again). Which names, and
-    # so whether a rematerialised block keeps them or runs this kernel
-    # again, is ops/remat.py's rule on what the call shows. Where nothing is
-    # differentiated the turn is dead code.
-    out, lse = remat.name_flash(
-        out, lse.reshape(lse.shape[:3]), s_k=k.shape[1],
-        head_dim=q.shape[-1] // heads, causal=causal, window=window,
-        pairs=mask and mask.pairs())
+    # the kernel's own results would still be made again). Whether they
+    # are named (`keeps`), and so whether a rematerialised block keeps them
+    # or runs this kernel again, ops/remat.py's rule said where the call was
+    # traced, on what the call shows and the room the step has. Where
+    # nothing is differentiated the turn is dead code.
+    out, lse = remat.name_flash(out, lse.reshape(lse.shape[:3]), keeps)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, mask,
-               res, g):
+               keeps, res, g):
     q, k, v, out, lse = res
     if isinstance(blocks[1], Band):
         dq, dk, dv = _band_bwd(q, k, v, out, lse, g, heads=heads, scale=scale,
@@ -2207,9 +2205,13 @@ def flash_attention(
                   f"[batch, seq, heads·head_dim] = [{b}, {s}, {h * d}] with "
                   f"{tile} head(s) to a {lanes}{shared}")
     # [B, S, H, d] -> [B, S, H·d] and back: the same bytes in the same order
+    keeps = remat.flash_keeps(
+        jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
+        jax.ShapeDtypeStruct((b, h, s), jnp.float32), s_k=s_k, head_dim=d,
+        causal=causal, window=window, pairs=mask and mask.pairs())
     out = _flash(
         q.reshape(b, s, h * d), k.reshape(b, s_k, kv_heads * d),
         v.reshape(b, s_k, kv_heads * dv), h, causal, scale, blocks, interpret,
-        window, mask,
+        window, mask, keeps,
     )
     return out.reshape(b, s, h, dv)

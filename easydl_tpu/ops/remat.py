@@ -1,6 +1,6 @@
 """What a rematerialised block keeps for its backward pass: under
 ``remat_policy="dots"`` every value that costs a matrix product to make
-again, and under both policies of what costs a kernel as much as pays.
+again, and under both policies what is dear to make again AND fits the chip.
 
 jax's ``dots_saveable`` keeps the result of every ``dot_general``. Values it
 gets wrong are named where they are made (:func:`name`, which is
@@ -15,8 +15,6 @@ gets wrong are named where they are made (:func:`name`, which is
 - the flash forward's ``lse`` as dense rows (``ops/flash_attention.
   _flash_fwd``, inside the differentiation rule): 0.5 MB a layer at GPT-2's
   shapes, under ``dots`` whatever the call;
-- the flash forward's ``out`` and ``lse`` where the CALL is dear to make
-  again (:func:`name_flash`), under ``dots`` and ``full`` alike;
 - the experts a router chose, where the expert layer is told to keep its
   routing (:data:`ROUTED`; 0.5 MB a layer at 16,384 rows), under both: not
   for what the top-k costs to make again but because the rematerialised
@@ -25,101 +23,316 @@ gets wrong are named where they are made (:func:`name`, which is
   row is nearly the mask token's one vector, their near-ties are one, and
   the backward then weighed experts the forward had not run for a quarter
   of a layer's rows (a routed leaf's gradient off by up to 0.88 on the chip
-  against 0.015 without remat: PERF.md section 6, PR 51).
+  against 0.015 without remat: PERF.md section 6, PR 51);
+- the CANDIDATES, under both: values that carry what a byte of them costs to
+  make again, kept dearest first while the compiled step leaves them room.
 
-A Mosaic call is no ``dot_general``: what nothing keeps of it the forward
-kernel makes a second time in every backward of a scanned run of layers. By
-TIME, keeping ``out`` pays at every size a benchmark cell runs (a byte kept
-costs a write and a read, 480 FLOP of a v5e's time; gpt2-medium gained 2.2%
-at 1,000 FLOP a byte, PERF.md section 6, PR 30). What limits it is ROOM, and
-a kept byte buys as much as the call costs to make again: the forward's
-FLOPs (``2 x pairs the mask keeps x (score size + value size)`` a head and
-batch row) over the bytes of ``out`` + ``lse``, near enough ``mean keys a
-query sees x (score size + value size) / value size`` — something the call's
-own shapes, ``causal`` and ``window`` say. At the benchmark's cells (PERF.md
-section 6, PR 38):
+**The candidates and their cost.** A Mosaic call is no ``dot_general``: what
+nothing keeps of the flash forward the kernel makes a second time in every
+backward. Under ``full`` an FFN's first products are made a second time too.
+A kept byte costs a write and a read, 480 FLOP of a v5e's time (gpt2-medium
+gained 2.2% at 1,000 FLOP a byte, PERF.md section 6, PR 30; four of
+Phi-4-mini-flash's six FFNs kept at 2,560 gained its cell 5.6%, 19,193 ->
+20,263 tokens/s: my chip run, PR 58, call p58h), so by TIME keeping pays at
+every size a benchmark cell runs; what limits it is ROOM, and a kept byte
+buys as much as it costs to make again:
 
-====================================================  ==========  =======================
-call ``[batch, seq, heads x score / value size]``     FLOP a byte room a chip for bytes
-====================================================  ==========  =======================
-JoyAI-LLM-Flash ``[2, 8192, 32 x 192 / 128]``             10,084  1.95 GiB for 0.51: KEPT
-ZAYA1, Laguna's full layers ``[2, 8192, 8|48 x 128]``      8,067  0.88 GiB for 0.19: KEPT
-Ouro ``[1, 4096, 16 x 128]``, 32 uses x 4 microbatches     4,034  0.26 GiB for 0.50
-the hybrid ``[2, 4096, 32 x 64]``                          3,973  0.15 GiB, one layer
-Laguna's window layers ``[2, 8192, 64 x 128]``, 512          977  0.90 GiB for 0.75
-gpt2-medium, gpt2-xl ``[8, 1024, 16|25 x 64]``               994  85 MB for 0.375 GiB
-====================================================  ==========  =======================
+- the flash forward's ``out`` + ``lse`` (:func:`name_flash`): the call's
+  FLOPs (``2 x pairs the mask keeps x (score size + value size)`` a head and
+  batch row) over their bytes, near enough ``mean keys a query sees x (score
+  size + value size) / value size`` — what the call's own shapes, ``causal``
+  and ``window`` say;
+- an FFN's ``gate`` and ``up`` (SwiGLU) or ``up`` (GELU), under ``full``
+  (:func:`name_products`; ``dots`` keeps them as products already): ``2 x
+  rows x width in x width out`` a product over ``rows x width out`` values,
+  the width of the product's INPUT in a bf16 program (the second forward's
+  ``down`` product is read by nothing either way, and was never made).
 
-:data:`FLASH_KEEP_FLOP_PER_BYTE` stands between the cells that have the gain
-AND the room (8,067 and up) and those that do not fit or gain a tenth as much
-a byte (4,034 and down: it is room, not time, that puts Ouro under it);
-nothing measured lies between. What the rule cannot see is room itself: a
-job of long sequences that filled the chip under a ``full`` that kept
-nothing is now refused by the compiler (the block's logged-once ``remat
-full:`` line says how many bytes a layer the rule holds). No cell is such a
-job; a chooser from ``compiled.memory_analysis()`` is ROADMAP Design 3's
-open item.
+At the benchmark's cells (a microbatch, every layer and pass of a run; the
+room is what the step compiled with nothing kept leaves of a v5e's 15.748
+GiB less :data:`MARGIN_BYTES`; the compiles: the rehearsal's, PR 58 — on
+the chip a process holds 0.06 GiB more beside the step; the gains: PERF.md
+section 6, PR 38 and PR 58):
+
+======================================================  ===========  =========================
+candidate                                               FLOP a byte  bytes, room: kept?
+======================================================  ===========  =========================
+Phi-4-mini-flash's two whole-sequence differential
+calls ``[1, 16384, 20 x 64 / 128]``                          12,100  2 x 170 MB, 3.35 GiB: yes
+JoyAI-LLM-Flash ``[2, 8192, 32 x 192 / 128]``, 6 layers      10,084  818 MB, 1.66 GiB: yes
+SDAR under the block mask ``[1, 16384, 32 x 128]``, 6         8,070  818 MB, 2.03 GiB: yes
+ZAYA1 ``[2, 8192, 8 x 128]``, 6 layers                        8,067  205 MB, 0.67 GiB: yes
+Laguna's two full layers ``[2, 8192, 48 x 128]``              8,067  2 x 205 MB, 1.53 GiB: yes
+Nemotron 3 Nano, Mellum 2 ``[2, 8192, 32 x 128]``, one        8,067  136 MB, 2.01 / 3.91: yes
+Ouro ``[1, 4096, 16 x 128]``, 8 layers x 4 passes             4,034  545 MB, 0.01 GiB: no
+the hybrid ``[2, 4096, 32 x 64]``, one layer                  3,973  35 MB, 0.016 GiB: no
+Phi-4-mini-flash's FFNs ``[1, 16384, 2560 -> 10240]``         2,560  6 x 671 MB: four of six
+Laguna's dense layer ``[2, 8192, 2048 -> 8192]``              2,048  537 MB: yes
+JoyAI-LLM-Flash's dense layer ``[2, 8192, 2048 -> 7168]``     2,048  470 MB: yes
+Ouro's FFN ``[1, 4096, 2048 -> 5632]``, 32 uses               2,048  2.95 GB, 0.01 GiB: no
+the hybrid's FFNs ``[2, 4096, 2048 -> 8192]``, 5 + 1          2,048  1.34 GB + 268 MB: no
+Mellum 2's window layers ``[2, 8192, 32 x 128]``, 1,024       1,891  under the floor
+gpt2-medium, gpt2-xl ``[8, 1024, 16|25 x 64]``                  994  under the floor
+Laguna's window layers ``[2, 8192, 64 x 128]``, 512             977  under the floor
+Phi-4-mini-flash's window layer, 512                            744  under the floor
+======================================================  ===========  =========================
+
+**The rule** (:class:`Chooser`). Kept are the candidates in order of FLOP a
+byte, dearest first, each where it still fits the room; one that does not
+fit is passed over and the cheaper ones behind it are still tried. A scanned
+run of N equal layers is one candidate, N x a layer's bytes x the passes of a
+looped stack, kept or left whole (a microbatch's: one is alive at a time); a
+run of one stands alone — Phi-4-mini-flash's six layers are six runs of one.
+Under :data:`FLOOR_FLOP_PER_BYTE` nothing is kept whatever the room: beside
+a kept ``out`` at GPT-2's shapes (994) XLA's memory-space assignment stopped
+holding the FFN's input in VMEM and gpt2-xl under ``fsdp=4`` lost 5.0% (PR
+30); nothing measured lies between 994 and 2,048.
+
+**Where the room comes from.** ``core/train_loop.py Trainer`` alone knows the
+step: it traces it once with nothing kept (an open :class:`Chooser` with the
+empty plan: :func:`choosing`), compiles, reads ``compiled.memory_analysis()``
+against the device's ``bytes_limit`` less what the process holds beside the
+step's arguments less :data:`MARGIN_BYTES`, and traces again under what the
+rule keeps in that room — where it keeps anything (its docstring has the
+rest: the choice remembered beside the compile cache).
+Anyone else — a ``jax.grad`` of a loss on its own, every program on the CPU,
+where the device states no limit — traces with no chooser open: NO candidate
+is kept and the program is that of ``policy=None`` but for the routing (on a
+TPU that states no limit the ``Trainer`` warns of it). A job that fills the
+chip therefore keeps less, down to nothing, where the constant this rule
+replaced (6,000 FLOP a byte, PR 38) kept the flash results whatever the room
+and the compiler refused the step (Ouro's and the hybrid's cells are such
+jobs: `remat keeps nothing`, their steps and rates the parent's to 0.01%: my
+chip run, PR 58, call p58c). A value kept by name in a run of ONE layer that
+no barrier guards gains nothing: XLA had merged that layer's second forward
+with its first (Laguna's and JoyAI-LLM-Flash's dense layers: steps 441.6 and
+554.2 ms before and after, call p58b). A kept value costs the compiled step
+a little less than its bytes (Phi-4-mini-flash's: 2.82 GiB kept in 2.58, the
+rehearsal's compile, PR 58), and the 0.71 GiB that leaves beside its four
+FFNs would hold the fifth: a third trace and compile there bought 0.09%
+(20,263 -> 20,281 tokens/s: my chip runs, PR 58, calls p58h, p58g) and is
+not made.
 
 Whoever reads a kept ``out`` has to read the kept rows, not a copy of them
 (PR 30: XLA wrote the stack's slice twice and transposed it, 12 ms a step):
 ``tests/test_tpu_compile.py`` reads the compiled text between the kernel and
-its projection. Beside a kept ``out`` at GPT-2's shapes XLA's memory-space
-assignment stopped holding the FFN's input in VMEM (gpt2-xl under ``fsdp=4``
-5.0% slower, PR 30); the rule does not pick those calls.
+its projection.
 
-Every other product stays as ``dots_saveable`` keeps it (the FFN's, the
-MoE's, the Mamba-2 scan's, the reference attention's): its bias is fused
-into whatever reads it, in the forward as in the recomputation. Under
-``full`` everything but the picked names is made again; a block in which
-nothing is picked lowers to the program of ``policy=None``.
+Every other product stays as ``dots_saveable`` keeps it (the MoE's, the
+Mamba-2 scan's, the reference attention's): its bias is fused into whatever
+reads it, in the forward as in the recomputation. Under ``full`` everything
+but the kept names is made again; a block in which nothing is kept lowers to
+the program of ``policy=None``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Iterator, List, NamedTuple, Optional, Tuple
+import dataclasses
+from typing import (Dict, FrozenSet, Iterator, List, NamedTuple, Optional, Set,
+                    Tuple)
 
 import jax
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 PROJECTION = "projection"
-#: the flash forward's results of a call the rule picks ...
+#: the flash forward's results of a call the chooser keeps (and, under
+#: ``dots``, the ``lse`` rows of every call)
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
-#: ... and of a call cheap to make again, for the room it would take
-FLASH_OUT_CHEAP = "flash_out_cheap"
-FLASH_LSE_CHEAP = "flash_lse_cheap"
+#: an FFN's first products where the chooser keeps them: SwiGLU's ``gate``
+#: and ``up``, GELU's ``up``
+FFN_IN = "ffn_in"
 #: the experts a router chose, ``[rows, k]`` int32, where the expert layer
 #: names them (``ops/moe.py MoeMlp.keep_routing``): a selection made again
 #: from a forward that rounds another way may be ANOTHER selection, and the
 #: backward then weighs experts the pass did not run
 ROUTED = "moe_chosen"
-NAMES = (PROJECTION, FLASH_OUT, FLASH_LSE, FLASH_OUT_CHEAP, FLASH_LSE_CHEAP,
-         ROUTED)
-#: the names each ``remat_policy`` saves; :data:`FLASH_OUT_CHEAP` is in
-#: neither (it is named for the block's line in the log)
+NAMES = (PROJECTION, FLASH_OUT, FLASH_LSE, FFN_IN, ROUTED)
+#: the names each ``remat_policy`` saves; a candidate that is not kept is not
+#: named (``dots`` keeps an FFN's products unnamed, as ``dot_general``s)
 KEPT = {
-    "full": (FLASH_OUT, FLASH_LSE, ROUTED),
-    "dots": (PROJECTION, FLASH_OUT, FLASH_LSE, FLASH_LSE_CHEAP, ROUTED),
+    "full": (FLASH_OUT, FLASH_LSE, FFN_IN, ROUTED),
+    "dots": (PROJECTION, FLASH_OUT, FLASH_LSE, ROUTED),
 }
 
-#: a flash forward whose ``out`` + ``lse`` cost at least this many FLOP a
-#: byte to make again is kept: the table in the module's docstring
-FLASH_KEEP_FLOP_PER_BYTE = 6000
+#: under this many FLOP a byte nothing is kept whatever the room: the table
+#: in the module's docstring
+FLOOR_FLOP_PER_BYTE = 2000
+#: what the chooser leaves of the device's limit beside the compiled step:
+#: ``memory_analysis`` is a sum, not the allocator's peak, and a step that
+#: compiles to the limit's last megabytes leaves the runtime nothing for a
+#: batch sent ahead. The hybrid's cell runs at 15.48 of 15.75 GiB with nothing
+#: kept (0.27 spare); a quarter of a GiB keeps its 35 MB candidate out
+MARGIN_BYTES = 256 << 20
 
 
 class Named(NamedTuple):
-    """One value named while a :func:`tally` is open: its label, its bytes
-    and, for a flash forward's result, the rule's FLOP a byte of its call."""
+    """One value named while a :func:`block` is open: its label, its bytes
+    and, for a candidate, what a byte of it costs to make again."""
     label: str
     bytes: int
     flop_per_byte: Optional[float] = None
 
 
-_tally: contextvars.ContextVar[Optional[List[Named]]] = \
-    contextvars.ContextVar("easydl_remat_tally", default=None)
+#: a candidate's place in the program: (the run of layers, ``flash`` |
+#: ``ffn``, which such value of a layer)
+Key = Tuple[str, str, int]
+
+
+class Candidate(NamedTuple):
+    """What a chooser keeps or leaves whole; ``bytes`` a microbatch's over
+    every layer and pass of the run."""
+    key: Key
+    bytes: int
+    flop_per_byte: float
+
+    def __str__(self) -> str:
+        run, what, nth = self.key
+        return (f"{run or 'a block'} {what}{f' {nth}' if nth else ''} "
+                f"{self.bytes / 1e6:,.1f} MB at "
+                f"{round(self.flop_per_byte):,} FLOP a byte")
+
+
+class Chooser:
+    """Which candidates a step keeps in ``room`` bytes.
+
+    While the step is traced each candidate is offered once (:meth:`offer`;
+    a block traced again — the microbatches' scan, flax's lifts — gets the
+    answer it got). With a ``plan`` the answer is whether the key is in it:
+    the ``Trainer`` traces once under the empty plan, sizes the step, and
+    traces again under what :meth:`fill` keeps in the room that leaves.
+    Without one the candidates are kept as they come while they fit — for
+    whoever differentiates a loss of their own and can state a room."""
+
+    def __init__(self, room: int, plan: Optional[FrozenSet[Key]] = None):
+        self.room = max(int(room), 0)
+        self.plan = plan
+        #: every candidate at or over the floor, by key, as traced
+        self.seen: Dict[Key, Candidate] = {}
+        self._kept: Set[Key] = set()
+
+    def offer(self, candidate: Candidate) -> bool:
+        key = candidate.key
+        if key not in self.seen:
+            self.seen[key] = candidate
+            if key in self.plan if self.plan is not None else \
+                    candidate.bytes <= self.room - self.kept_bytes:
+                self._kept.add(key)
+        return key in self._kept
+
+    @property
+    def kept(self) -> FrozenSet[Key]:
+        return frozenset(self._kept)
+
+    @property
+    def kept_bytes(self) -> int:
+        return sum(self.seen[key].bytes for key in self._kept)
+
+    def ranked(self) -> List[Candidate]:
+        """The candidates dearest first; equals in the order of the trace."""
+        return sorted(self.seen.values(), key=lambda c: -c.flop_per_byte)
+
+    def fill(self, room: int) -> FrozenSet[Key]:
+        """The keys the rule keeps in ``room`` bytes: dearest first, each
+        where it still fits."""
+        keys = []
+        for candidate in self.ranked():
+            if candidate.bytes <= room:
+                room -= candidate.bytes
+                keys.append(candidate.key)
+        return frozenset(keys)
+
+    def said(self) -> str:
+        """What was kept, in rank order, and the first candidate left out."""
+        kept = [str(c) for c in self.ranked() if c.key in self._kept]
+        left = [str(c) for c in self.ranked() if c.key not in self._kept]
+        return (f"keeps {'; '.join(kept) or 'nothing'}"
+                + (f"; the first of {len(left)} left out for want of room: "
+                   f"{left[0]}" if left else "; leaves no candidate out"))
+
+
+@dataclasses.dataclass
+class Said:
+    """What a block named and offered while it was traced: for its one line
+    in the log."""
+    policy: Optional[str]
+    #: the room of the chooser the block was traced under (None: none open)
+    room: Optional[int] = None
+    named: List[Named] = dataclasses.field(default_factory=list)
+    #: candidates not kept, ``label`` what they are, -> why
+    left: List[Tuple[Named, str]] = dataclasses.field(default_factory=list)
+    #: how many candidates of a kind the block has offered
+    offered: Dict[str, int] = dataclasses.field(default_factory=dict)
+
+    def line(self) -> str:
+        """The room given, what the block keeps by name — dearest first,
+        with bytes and FLOP a byte — and each candidate left out, with why."""
+        def listed(values):
+            return ", ".join(
+                f"{value.label} {value.bytes / 1e6:.1f} MB"
+                + ("" if value.flop_per_byte is None else
+                   f" at {round(value.flop_per_byte):,} FLOP a byte")
+                for value in values) or "nothing"
+
+        kept = sorted((value for value in self.named
+                       if value.label in KEPT[self.policy]),
+                      key=lambda value: -(value.flop_per_byte or 0))
+        return (("no room stated" if self.room is None else
+                 "given no room (the trace a step is sized by keeps nothing)"
+                 if not self.room else
+                 f"given {self.room / 2**30:.3f} GiB of room")
+                + f", keeps by name {listed(kept)} a microbatch as traced (a "
+                f"kernel's per shard under a mesh), "
+                + ("beside its unnamed products" if self.policy == "dots"
+                   else "and makes everything else again")
+                + "; candidates left out: " + ("; ".join(
+                    f"{listed([value])} ({why})" for value, why in self.left)
+                    or "none"))
+
+
+class Run(NamedTuple):
+    """The run of layers being traced: its name among the stack's parameters
+    and how many times a value kept in its block is held (layers x passes)."""
+    name: str = ""
+    uses: int = 1
+
+
+_chooser: contextvars.ContextVar[Optional[Chooser]] = \
+    contextvars.ContextVar("easydl_remat_chooser", default=None)
+_run: contextvars.ContextVar[Run] = \
+    contextvars.ContextVar("easydl_remat_run", default=Run())
+_said: contextvars.ContextVar[Optional[Said]] = \
+    contextvars.ContextVar("easydl_remat_block", default=None)
+
+
+@contextlib.contextmanager
+def _set(var: contextvars.ContextVar, value) -> Iterator:
+    token = var.set(value)
+    try:
+        yield value
+    finally:
+        var.reset(token)
+
+
+def choosing(chooser: Optional[Chooser]):
+    """``chooser`` decides what the blocks traced while this is open keep."""
+    return _set(_chooser, chooser)
+
+
+def run(name: str, uses: int):
+    """The blocks traced while this is open are the run ``name``, whose kept
+    values are held ``uses`` times."""
+    return _set(_run, Run(name, uses))
+
+
+def block(policy: Optional[str]):
+    """A block under ``remat_policy`` ``policy`` (None: no remat) is traced
+    while this is open; yields its :class:`Said`."""
+    chooser = _chooser.get()
+    return _set(_said, Said(policy, chooser and chooser.room))
 
 
 def name(x: jax.Array, label: str,
@@ -127,10 +340,36 @@ def name(x: jax.Array, label: str,
     """``x`` under ``label`` (one of :data:`NAMES`): :func:`policy` saves it
     if the label is in the policy's :data:`KEPT`."""
     assert label in NAMES, label
-    named = _tally.get()
-    if named is not None:
-        named.append(Named(label, x.size * x.dtype.itemsize, flop_per_byte))
+    said = _said.get()
+    if said is not None:
+        said.named.append(Named(label, _bytes(x), flop_per_byte))
     return checkpoint_name(x, label)
+
+
+def _bytes(*arrays) -> int:
+    return sum(x.size * x.dtype.itemsize for x in arrays)
+
+
+def _offer(what: str, nbytes: int, cost: float) -> bool:
+    """Whether the block being traced keeps a candidate of ``nbytes`` a layer
+    that costs ``cost`` FLOP a byte to make again."""
+    said, chooser, at = _said.get(), _chooser.get(), _run.get()
+    if said is None or said.policy is None:
+        return False
+    nth = said.offered[what] = said.offered.get(what, -1) + 1
+    if cost < FLOOR_FLOP_PER_BYTE:
+        why = f"under the floor of {FLOOR_FLOP_PER_BYTE:,}"
+    elif chooser is None:
+        why = "no room stated"
+    elif chooser.offer(Candidate((at.name, what, nth), nbytes * at.uses,
+                                 cost)):
+        return True
+    else:
+        why = (f"no room: {nbytes * at.uses / 1e6:,.1f} MB in all, "
+               f"{(chooser.room - chooser.kept_bytes) / 1e6:,.1f} MB left"
+               if chooser.plan is None else "left out by the room")
+    said.left.append((Named(what, nbytes, cost), why))
+    return False
 
 
 def seen_pairs(s_q: int, s_k: int, causal: bool,
@@ -158,27 +397,57 @@ def flash_flop_per_byte(out: jax.ShapeDtypeStruct, lse: jax.ShapeDtypeStruct,
         pairs = seen_pairs(s_q, s_k, causal, window)
     flop = 2 * pairs * batch * heads \
         * (head_dim + out.shape[-1] // heads)
-    return flop / (out.size * out.dtype.itemsize
-                   + lse.size * lse.dtype.itemsize)
+    return flop / _bytes(out, lse)
 
 
-def name_flash(out: jax.Array, lse: jax.Array, *, s_k: int, head_dim: int,
-               causal: bool, window: Optional[int],
-               pairs: Optional[int] = None) -> Tuple[jax.Array, jax.Array]:
-    """The flash forward's two results under the names the rule gives them:
-    :data:`FLASH_OUT` and :data:`FLASH_LSE` where the call costs
-    :data:`FLASH_KEEP_FLOP_PER_BYTE` or more, the ``_CHEAP`` pair below."""
+def flash_keeps(out: jax.ShapeDtypeStruct, lse: jax.ShapeDtypeStruct, *,
+                s_k: int, head_dim: int, causal: bool, window: Optional[int],
+                pairs: Optional[int] = None) -> Tuple[bool, bool]:
+    """Whether the block being traced keeps a flash call's ``out`` and its
+    ``lse`` rows, the call a candidate by :func:`flash_flop_per_byte`: both
+    where the chooser keeps it; where it does not, neither, but for the rows
+    under ``dots``. Asked where the CALL is traced, inside its block: the
+    differentiation rule that names the two (:func:`name_flash`) is traced
+    when the block is differentiated, after the block's trace is over."""
     cost = flash_flop_per_byte(out, lse, s_k=s_k, head_dim=head_dim,
                                causal=causal, window=window, pairs=pairs)
-    labels = (FLASH_OUT, FLASH_LSE) if cost >= FLASH_KEEP_FLOP_PER_BYTE \
-        else (FLASH_OUT_CHEAP, FLASH_LSE_CHEAP)
-    return name(out, labels[0], cost), name(lse, labels[1], cost)
+    keep = _offer("flash", _bytes(out, lse), cost)
+    said = _said.get()
+    rows = keep or (said is not None and said.policy == "dots")
+    for kept, value, label in ((keep, out, FLASH_OUT), (rows, lse, FLASH_LSE)):
+        if kept:
+            said.named.append(Named(label, _bytes(value), cost))
+    return keep, rows
+
+
+def name_flash(out: jax.Array, lse: jax.Array,
+               keeps: Tuple[bool, bool]) -> Tuple[jax.Array, jax.Array]:
+    """The flash forward's two results, each under its name
+    (:data:`FLASH_OUT`, :data:`FLASH_LSE`) where :func:`flash_keeps` said so."""
+    return (checkpoint_name(out, FLASH_OUT) if keeps[0] else out,
+            checkpoint_name(lse, FLASH_LSE) if keeps[1] else lse)
+
+
+def name_products(products: Tuple[jax.Array, ...],
+                  contracted: int) -> Tuple[jax.Array, ...]:
+    """An FFN's first products (SwiGLU's ``gate`` and ``up``, GELU's ``up``),
+    each ``rows x contracted`` times ``contracted x width``, a candidate
+    under ``full``: under :data:`FFN_IN` where the block's chooser keeps
+    them. ``dots`` keeps them as the products they are."""
+    said = _said.get()
+    if said is None or said.policy != "full":
+        return products
+    nbytes = _bytes(*products)
+    cost = 2 * contracted * sum(x.size for x in products) / nbytes
+    if _offer("ffn", nbytes, cost):
+        return tuple(name(x, FFN_IN, cost) for x in products)
+    return products
 
 
 #: ``full``'s policy is ONE object, as the ``nothing_saveable`` of
 #: ``policy=None`` is: jax caches a block's inner functions, split for the
 #: backward, by (function, policy), and a policy made anew a call lowers each
-#: of them once a use — a block in which nothing is picked would no longer
+#: of them once a use — a block in which nothing is kept would no longer
 #: lower to the text it had
 _FULL = jax.checkpoint_policies.save_only_these_names(*KEPT["full"])
 
@@ -193,15 +462,3 @@ def policy(remat_policy: str):
     return policies.save_from_both_policies(
         policies.dots_saveable,
         policies.save_only_these_names(*KEPT[remat_policy]))
-
-
-@contextlib.contextmanager
-def tally() -> Iterator[List[Named]]:
-    """Every value named while this is open, in order: a trace-time count,
-    for a block's one line in the log."""
-    named: List[Named] = []
-    token = _tally.set(named)
-    try:
-        yield named
-    finally:
-        _tally.reset(token)

@@ -13,7 +13,13 @@ A cell's step is also a gate: it has a limit in GiB a device and the
 attention kernels it holds by name (``ATTENTION_KERNELS``: how often the
 forward kernel stands says what remat kept) and the Mamba-2 mixer's
 (``MAMBA_KERNELS``), and a program over its limit or with other kernels makes
-the exit code 1. A compile that passes is not a run.
+the exit code 1. A compile that passes is not a run. What a ``full`` step's
+blocks keep is fitted by the ``Trainer`` to the chip's memory
+(``ops/remat.py``): the described chip is SAID to have a v5e's 15.748 GiB
+(``V5E_BYTES``), the step's log line (``train step: compiled to ...``) says
+what was kept and what was first left out, and a ``full`` cell with room is
+compiled twice: with nothing kept, for its size, then with what that leaves
+room for (a cell with room for nothing, Ouro's or the hybrid's, once).
 
 Against another commit (``--census`` / ``--against``; PR 46): each program's
 lowered text hashed without what moves with a checkout (the Mosaic payloads'
@@ -86,6 +92,9 @@ SDAR = ("sdar", dict(
 PHI4FLASH = ("phi4flash", dict(
     size="mini-flash-reasoning", seq_len=16384, vocab=25008,
     remat_policy="full", layer_ids=[0, 1, 16, 17, 18, 19], **_CHIP))
+#: a v5e chip's ``bytes_limit`` as its allocator states it: 15.748 GiB (my
+#: chip run, PR 58, call p58a)
+V5E_BYTES = 16909336064
 #: name -> (model, mesh shape key, global batch, grad_accum, optimizer,
 #: GiB a device the step may take or None). A chip has 15.75 GiB; a step's
 #: limit is its own compiled size and a little: medium's steps 15.292
@@ -144,8 +153,14 @@ PROGRAMS = {
     "mellum_1x2": (MELLUM, "dp=1", 2, 1, "adamw", 11.9),
     # ONE sequence of 8,192 tokens a step: 16,384 [noised || clean] rows
     "sdar_1x1": (SDAR, "dp=1", 1, 1, "adamw", 14.5),
-    # ONE sequence of 16,384 tokens a step in one microbatch
-    "phi4flash_1x1": (PHI4FLASH, "dp=1", 1, 1, "adamw", 12.5),
+    # ONE sequence of 16,384 tokens a step in one microbatch. PR 58: 12.152
+    # with nothing kept (12.326 with the two whole-sequence differential
+    # calls' results, as before) leaves 3.35 GiB here; the two calls and
+    # four of its six FFNs' gate and up (2.82 GiB) take 2.58 of it — every
+    # other cell's choice is what it kept before (Ouro and the hybrid
+    # nothing; Laguna's and JoyAI-LLM-Flash's dense layer keeps its gate and
+    # up as well, in a size that does not move)
+    "phi4flash_1x1": (PHI4FLASH, "dp=1", 1, 1, "adamw", 14.85),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -232,12 +247,19 @@ def lower_program(name: str, devices):
     # compiled or interpreted: compiled, as on the chip — while this program
     # is traced and no longer (a test calls this in a process whose later
     # tests run the layers on the CPU: tests/test_tpu_compile.py)
-    on_tpu, platform.on_tpu = platform.on_tpu, lambda: True
+    # A described chip cannot say how much memory it has: a v5e's limit,
+    # stated, is what the Trainer fits what remat keeps to (ops/remat.py) —
+    # it compiles the step with nothing kept to read its size, once more
+    # where something fits, and hands back the lowering it settled on
+    on_tpu, stats = platform.on_tpu, platform.memory_stats
+    platform.on_tpu = lambda: True
+    platform.memory_stats = lambda device: {"bytes_limit": V5E_BYTES,
+                                            "bytes_in_use": 0}
     try:
         return trainer.step_fn.lower(
             trainer.abstract_state(), {"inputs": tokens, "targets": tokens})
     finally:
-        platform.on_tpu = on_tpu
+        platform.on_tpu, platform.memory_stats = on_tpu, stats
 
 
 def compile_program(name: str, devices, lowered=None):

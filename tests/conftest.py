@@ -271,16 +271,21 @@ def fused_head(monkeypatch):
 
 @pytest.fixture
 def flash_kept(monkeypatch):
-    """``flash_kept()``: from the call on, every flash forward is dear
-    enough to keep (``ops/remat.FLASH_KEEP_FLOP_PER_BYTE`` -> 0), so that a
+    """``flash_kept()``: from the call on, every candidate of what remat
+    keeps is dear enough and has room (``ops/remat.FLOOR_FLOP_PER_BYTE`` -> 0,
+    and where no chooser is open one with room without end is), so that a
     test-size stack under remat keeps ``out`` and ``lse`` as JoyAI-LLM-Flash's
-    and ZAYA1's cells do. The constant is read when the kernel's
-    differentiation rule is TRACED: build the other side of a comparison
-    before the call. What a call keeps is no argument of the program; a test
-    steers it here."""
+    and ZAYA1's cells do (and, under ``full``, its FFN's first products).
+    Both are read when a block is TRACED: build the other side of a
+    comparison before the call. What a call keeps is no argument of the
+    program; a test steers it here."""
     def steer():
+        import contextvars
+
         from easydl_tpu.ops import remat
 
-        monkeypatch.setattr(remat, "FLASH_KEEP_FLOP_PER_BYTE", 0)
+        monkeypatch.setattr(remat, "FLOOR_FLOP_PER_BYTE", 0)
+        monkeypatch.setattr(remat, "_chooser", contextvars.ContextVar(
+            "easydl_remat_chooser_with_room", default=remat.Chooser(1 << 60)))
 
     return steer

@@ -237,4 +237,4 @@ def test_remat_counts_the_masks_pairs():
         pairs=mask.pairs())
     assert cost == pytest.approx(2 * (8192 ** 2 + 8192 * 4) * 256
                                  / (16384 * (256 + 4)))
-    assert round(cost) == 8070 >= remat.FLASH_KEEP_FLOP_PER_BYTE
+    assert round(cost) == 8070 >= remat.FLOOR_FLOP_PER_BYTE

@@ -80,32 +80,43 @@ def test_dq_dk_dv_from_rows_of_lse_are_the_parents(case):
 
 
 @pytest.mark.parametrize("seq,walk", [(64, "unrolled"), (160, "looped")])
-def test_the_rule_names_out_and_lse_and_dots_keeps_the_rows(seq, walk):
-    """The residuals of the differentiation rule are NAMED values: a policy
-    that saves every name keeps ``out`` and ``lse`` as ``[batch, heads,
-    seq]`` float32 rows — no ``[.., seq, 1]`` column (64 MB of lane padding
-    a layer at the medium cell's shape) — and runs no kernel again. Remat
-    ``dots`` keeps the rows and, of a call this cheap to make again, not
-    ``out`` (``ops/remat.py`` has the rule and the measurements); ``full``
-    and jax's own ``dots_saveable`` keep neither."""
+def test_the_rule_names_out_and_lse_and_dots_keeps_the_rows(seq, walk,
+                                                            flash_kept):
+    """The residuals of the differentiation rule are NAMED values where the
+    enclosing block's chooser keeps the call: a policy that saves every name
+    keeps ``out`` and ``lse`` as ``[batch, heads, seq]`` float32 rows — no
+    ``[.., seq, 1]`` column (64 MB of lane padding a layer at the medium
+    cell's shape) — and runs no kernel again. Of a call this cheap to make
+    again (under the floor) a ``dots`` block keeps the rows alone and a
+    ``full`` block nothing (``ops/remat.py`` has the rule and the
+    measurements); outside a block, and under jax's own ``dots_saveable``,
+    nothing is named or kept."""
     from easydl_tpu.ops.flash_attention import _unrolled
 
     assert _unrolled(seq // 32, seq // 32) == (walk == "unrolled")
     q = jnp.ones((2, seq, 4, 64), jnp.float32)
-    attend = functools.partial(flash_attention, causal=True, block_q=32,
-                               block_k=32, interpret=True)
     policies = jax.checkpoint_policies
 
-    def kept(policy):
-        return sorted(shape for shape, _ in saved_residuals(jax.checkpoint(
-            attend, policy=policy, prevent_cse=False), q, q, q))
+    def attend(q, k, v, block=None):
+        with remat.block(block):
+            return flash_attention(q, k, v, causal=True, block_q=32,
+                                   block_k=32, interpret=True)
 
-    assert kept(policies.save_only_these_names(*remat.NAMES)) \
-        == sorted([(2, seq, 4 * 64), (2, 4, seq)])  # out; lse as rows
-    assert remat.FLASH_OUT_CHEAP not in remat.KEPT["dots"]
-    assert kept(remat.policy("dots")) == [(2, 4, seq)]
-    assert kept(remat.policy("full")) == []
-    assert kept(policies.dots_saveable) == []
+    def kept(policy, block=None):
+        return sorted(shape for shape, _ in saved_residuals(jax.checkpoint(
+            functools.partial(attend, block=block), policy=policy,
+            prevent_cse=False), q, q, q))
+
+    every = policies.save_only_these_names(*remat.NAMES)
+    rows, both = [(2, 4, seq)], sorted([(2, seq, 4 * 64), (2, 4, seq)])
+    assert kept(every) == kept(every, "full") == []
+    assert kept(every, "dots") == kept(remat.policy("dots"), "dots") == rows
+    assert kept(remat.policy("full"), "full") == []
+    assert kept(policies.dots_saveable, "dots") == []
+    flash_kept()  # the floor lowered, a chooser with room: both, both named
+    for block in ("full", "dots"):
+        assert kept(every, block) == kept(remat.policy(block), block) == both
+    assert kept(every) == []  # no block, no candidate
 
 
 def test_dots_keeps_the_sum_and_drops_the_product_it_was_made_from():
@@ -132,15 +143,15 @@ def test_dots_keeps_the_sum_and_drops_the_product_it_was_made_from():
 def test_a_tally_counts_what_was_named_and_only_while_open():
     x = jnp.ones((2, 3), jnp.bfloat16)
     assert all(set(kept) < set(remat.NAMES) for kept in remat.KEPT.values())
-    remat.name(x, remat.PROJECTION)  # no tally open: just the name
-    with remat.tally() as named:
+    remat.name(x, remat.PROJECTION)  # no block open: just the name
+    with remat.block(None) as said:
         remat.name(x, remat.PROJECTION)
-        with remat.tally() as inner:
+        with remat.block(None) as inner:
             remat.name(x.astype(jnp.float32), remat.FLASH_LSE, 8067.0)
-        remat.name(x, remat.FLASH_OUT_CHEAP)
-    assert inner == [(remat.FLASH_LSE, 24, 8067.0)]
-    assert named == [(remat.PROJECTION, 12, None),
-                     (remat.FLASH_OUT_CHEAP, 12, None)]
+        remat.name(x, remat.FFN_IN)
+    assert inner.named == [(remat.FLASH_LSE, 24, 8067.0)]
+    assert said.named == [(remat.PROJECTION, 12, None),
+                          (remat.FFN_IN, 12, None)]
     with pytest.raises(AssertionError):
         remat.name(x, "a name the policy does not save")
 
@@ -218,6 +229,8 @@ def test_a_dots_stack_says_once_what_a_layer_names(monkeypatch):
     assert len(said) == 1, said
     # q, k, v, out of [8, 64, 128] float32 (the reference attention path:
     # no kernel, nothing of its own to name)
-    assert "keeps 4 values by name (4 x projection)" in said[0]
-    assert f"{4 * 128 * 8 * 64 * 4 / 1e6:.1f} MB" in said[0]
-    assert said[0].endswith("named and not kept: nothing")
+    each = f"projection {128 * 8 * 64 * 4 / 1e6:.1f} MB"
+    assert f"no room stated, keeps by name {', '.join([each] * 4)} a " \
+           "microbatch as traced" in said[0]
+    assert said[0].endswith("beside its unnamed products; candidates left "
+                            "out: none")

@@ -52,12 +52,18 @@ def _attention_instructions(text: str) -> list:
 
 
 def _two_layer_gpt2(devices, remat_policy: str, spec: MeshSpec = MeshSpec()):
-    """Compiled text of GPT-2 medium's stack cut to two layers (1024 wide,
-    16 heads of 64, bf16, a 1,024-row head) under ``remat_policy``: loss and
+    """Compiled text of :func:`_two_layer_gpt2_lowered`'s program."""
+    return _two_layer_gpt2_lowered(devices, remat_policy,
+                                   spec).compile().as_text()
+
+
+def _two_layer_gpt2_lowered(devices, remat_policy: str,
+                            spec: MeshSpec = MeshSpec()):
+    """GPT-2 medium's stack cut to two layers (1024 wide, 16 heads of 64,
+    bf16, a 1,024-row head) under ``remat_policy``, lowered: loss and
     gradients of 8 sequences of 1,024 on one described chip, or under
     ``spec`` the whole train step of 16 (the ``Trainer`` places the
     parameters), one frame per location as the entry points compile."""
-    import flax.linen as nn
     import optax
 
     from easydl_tpu.core.train_loop import TrainConfig, Trainer
@@ -80,19 +86,26 @@ def _two_layer_gpt2(devices, remat_policy: str, spec: MeshSpec = MeshSpec()):
             tokens = jax.ShapeDtypeStruct((16, 1024), jnp.int32)
             return trainer.step_fn.lower(
                 trainer.abstract_state(),
-                {"inputs": tokens, "targets": tokens}).compile().as_text()
-        one = SingleDeviceSharding(devices[0])
-        params = jax.tree.map(
-            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
-            jax.eval_shape(lambda: nn.unbox(
-                bundle.init_fn(jax.random.PRNGKey(0)))))
-        tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one)
-        return jax.jit(jax.grad(
-            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
-        )).lower(params, {"inputs": tokens, "targets": tokens}
-                 ).compile().as_text()
+                {"inputs": tokens, "targets": tokens})
+        return _loss_and_gradients_lowered(bundle, devices, (8, 1024))
     finally:
         jax.config.update("jax_traceback_in_locations_limit", frames)
+
+
+def _loss_and_gradients_lowered(bundle, devices, tokens_shape):
+    """The gradient of ``bundle``'s loss lowered for one described chip, with
+    no chooser open unless the caller opened one (``ops/remat.py``)."""
+    import flax.linen as nn
+
+    one = SingleDeviceSharding(devices[0])
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+        jax.eval_shape(lambda: nn.unbox(
+            bundle.init_fn(jax.random.PRNGKey(0)))))
+    tokens = jax.ShapeDtypeStruct(tokens_shape, jnp.int32, sharding=one)
+    return jax.jit(jax.grad(
+        lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+    )).lower(params, {"inputs": tokens, "targets": tokens})
 
 
 @pytest.fixture(scope="module")
@@ -233,8 +246,9 @@ def test_phi4flash_s_step_holds_its_four_new_kernels_and_fits(v5e_2x2,
     tokens, AdamW) compiled whole for the described chip: the selective
     scan's two kernels and differential attention's looped two by name
     beside the band path's and the convolution's, under the chip's 15.75
-    GiB and the script's own limit (12.326 compiled; 16.9 GB and no fit
-    without the remat barrier around each run of one layer)."""
+    GiB and the script's own limit (14.730 compiled with four FFNs' gate and
+    up kept, PR 58; 12.326 with none; 16.9 GB and no fit without the remat
+    barrier around each run of one layer)."""
     compiled, gib = rehearse.compile_program("phi4flash_1x1", v5e_2x2)
     assert gib <= rehearse.PROGRAMS["phi4flash_1x1"][-1] < 15.75, gib
     counts = rehearse.kernel_counts(rehearse.mosaic_calls(compiled.as_text()))
@@ -502,36 +516,31 @@ def test_the_script_holds_a_program_to_the_parents_census(
     assert "ouro_4x1: lowered " in capsys.readouterr().out
 
 
-@pytest.fixture(scope="module")
-def two_layer_rotary_stack(v5e_2x2):
-    """As ``two_layer_stack``, for Ouro's description cut to two layers and
-    two passes (2048 wide, 16 heads of 128, rotary, sandwich norms, remat
-    ``full``, bf16, one 4,096-token sequence, a 1,024-row head): every
-    top-level instruction under ``attention``."""
-    import flax.linen as nn
-
+def _two_layer_rotary_lowered(devices):
+    """Ouro's description cut to two layers and two passes (2048 wide, 16
+    heads of 128, rotary, sandwich norms, remat ``full``, bf16, one
+    4,096-token sequence, a 1,024-row head), loss and gradients lowered for
+    one described chip."""
     from easydl_tpu.models.ouro import make_ouro
 
     frames = jax.config.jax_traceback_in_locations_limit
     jax.config.update("jax_traceback_in_locations_limit", 1)
     try:
-        one = SingleDeviceSharding(v5e_2x2[0])
         bundle = make_ouro(
             size="2.6b", seq_len=4096, vocab=1024, dtype="bfloat16",
             remat=True, remat_policy="full", attention_impl="flash",
             layer_types=["full_attention"] * 2, total_ut_steps=2)
-        params = jax.tree.map(
-            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
-            jax.eval_shape(lambda: nn.unbox(
-                bundle.init_fn(jax.random.PRNGKey(0)))))
-        tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one)
-        text = jax.jit(jax.grad(
-            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
-        )).lower(params, {"inputs": tokens, "targets": tokens}
-                 ).compile().as_text()
+        return _loss_and_gradients_lowered(bundle, devices, (1, 4096))
     finally:
         jax.config.update("jax_traceback_in_locations_limit", frames)
-    return _attention_instructions(text)
+
+
+@pytest.fixture(scope="module")
+def two_layer_rotary_stack(v5e_2x2):
+    """As ``two_layer_stack``, for :func:`_two_layer_rotary_lowered`'s
+    program: every top-level instruction under ``attention``."""
+    return _attention_instructions(
+        _two_layer_rotary_lowered(v5e_2x2).compile().as_text())
 
 
 @pytest.mark.parametrize("which", ["fwd", "remat"])
@@ -565,35 +574,38 @@ def test_rotary_puts_no_copy_between_the_projections_and_the_kernels(
 
 
 # ------------------------------------------------- what remat full keeps
-def _scanned_text(devices, factory: str, batch: int = 2,
-                  **description) -> str:
-    """The compiled text of loss and gradients of a two-layer scanned run of
-    ``factory``'s description at the cell's widths (``batch`` x 8,192
-    tokens, bf16, remat ``full``, a 1,024-row head; the expert layer's
-    kernels compiled as on the chip: the caller asks ``described_tpu``), for
-    one described chip."""
-    import flax.linen as nn
+#: the room a scanned run's candidates are given where a test compiles the
+#: run alone: stated, as the ``Trainer`` states what its compiled step leaves
+#: (no chooser open, nothing is kept: ``ops/remat.py``)
+ROOM = 4 << 30
 
+
+def _scanned_lowered(devices, factory: str, batch: int = 2, **description):
+    """Loss and gradients of a two-layer scanned run of ``factory``'s
+    description at the cell's widths (``batch`` x 8,192 tokens, bf16, remat
+    ``full`` with :data:`ROOM` for what it keeps, a 1,024-row head; the
+    expert layer's kernels compiled as on the chip: the caller asks
+    ``described_tpu``), lowered for one described chip."""
     from easydl_tpu.models.registry import get_model
+    from easydl_tpu.ops import remat
 
     frames = jax.config.jax_traceback_in_locations_limit
     jax.config.update("jax_traceback_in_locations_limit", 1)
     try:
-        one = SingleDeviceSharding(devices[0])
         bundle = get_model(
             factory, seq_len=8192, vocab=1024, dtype="bfloat16", remat=True,
             remat_policy="full", attention_impl="flash", **description)
-        params = jax.tree.map(
-            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
-            jax.eval_shape(lambda: nn.unbox(
-                bundle.init_fn(jax.random.PRNGKey(0)))))
-        tokens = jax.ShapeDtypeStruct((batch, 8192), jnp.int32, sharding=one)
-        return jax.jit(jax.grad(
-            lambda p, batch: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
-        )).lower(params, {"inputs": tokens, "targets": tokens}
-                 ).compile().as_text()
+        with remat.choosing(remat.Chooser(ROOM)):
+            return _loss_and_gradients_lowered(bundle, devices, (batch, 8192))
     finally:
         jax.config.update("jax_traceback_in_locations_limit", frames)
+
+
+def _scanned_text(devices, factory: str, batch: int = 2,
+                  **description) -> str:
+    """The compiled text of :func:`_scanned_lowered`'s program."""
+    return _scanned_lowered(devices, factory, batch,
+                            **description).compile().as_text()
 
 
 def _scanned_run(devices, factory: str, **description) -> list:
