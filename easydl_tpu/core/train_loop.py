@@ -135,9 +135,12 @@ class _FittedStep:
     traced and compiled ONCE more under that choice. A kept value costs the
     compiled step a little less than its bytes (XLA packs 8% of it into
     space the step had: Phi-4-mini-flash's cell), so the choice errs to the
-    safe side; one whose compiled size is over the budget after all, or that
-    the compiler refuses, leaves the step that keeps nothing standing, and
-    the log says so. The choice is a function of the program and the limit
+    safe side; by the sum ``memory_analysis()`` gives, a value a scan stacks
+    can cost up to twice its bytes, so a choice whose compiled size is over
+    the budget after all is made ONCE more in the room at the price that
+    compile showed (a third trace and compile, on a cold start); one that is
+    still over, or that the compiler refuses, leaves the step that keeps
+    nothing standing, and the log says so. The choice is a function of the program and the limit
     alone: a resume chooses as the run it resumes did.
 
     A start with room so pays a second trace, lowering and compile. So the
@@ -304,19 +307,31 @@ class _FittedStep:
         want = nothing.chooser.fill(room)
         if not want:
             return nothing
-        step = self._traced(remat.Chooser(room, want), args)
-        try:
-            step = step._replace(size=compiled_bytes(step.lowered))
+        for again in (False, True):
+            step = self._traced(remat.Chooser(room, want), args)
+            kept = step.chooser.kept_bytes
+            try:
+                step = step._replace(size=compiled_bytes(step.lowered))
+            except jax.errors.JaxRuntimeError as refused:
+                # whatever the compiler says of it: the step it took stands
+                log.warning("train step: keeping %.3f GiB the step is "
+                            "refused by the compiler (%s)", kept / 2**30,
+                            str(refused)[:200])
+                break
             if step.size <= budget:
                 return step
-            why = (f"compiles to {step.size / 2**30:.3f} GiB, over the "
-                   f"{budget / 2**30:.3f} the limit leaves it")
-        except jax.errors.JaxRuntimeError as refused:
-            # whatever the compiler says of it: the step it took stands
-            why = f"is refused by the compiler ({str(refused)[:200]})"
-        log.warning("train step: keeping %.3f GiB the step %s: the step "
-                    "that keeps nothing stands",
-                    step.chooser.kept_bytes / 2**30, why)
+            log.warning("train step: keeping %.3f GiB the step compiles to "
+                        "%.3f GiB, over the %.3f the limit leaves it",
+                        kept / 2**30, step.size / 2**30, budget / 2**30)
+            # what was kept cost the compiled step more than its bytes (the
+            # sum counts a value a scan stacks up to twice): the rule ONCE
+            # more, in the room as that price leaves it
+            less = nothing.chooser.fill(
+                room * kept // max(step.size - nothing.size, kept))
+            if again or not less or less == want:
+                break
+            want = less
+        log.warning("train step: the step that keeps nothing stands")
         return nothing
 
 

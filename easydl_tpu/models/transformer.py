@@ -77,7 +77,8 @@ class _RowsDense(nn.DenseGeneral):
     result as rows of ONE merged feature dimension until it is finished:
     the product (:func:`_matrix_dot_general`), the bias added to those rows
     (``bias_on_rows``; the parent class is built with ``use_bias=False``),
-    the name remat ``dots`` keeps it by, and only then the features' shape.
+    the name remat keeps it by (``dots`` always, ``full`` where its chooser
+    does: ``ops/remat.py name_rows``), and only then the features' shape.
     A bias added to ``[batch, seq, heads, 64]`` made the kept sum a
     four-dimensional array, which the compiler lays out with the sequence as
     its minor dimension: a transposing copy in front of every kernel, in the
@@ -98,8 +99,12 @@ class _RowsDense(nn.DenseGeneral):
             bias = self.param("bias", self.bias_init, features,
                               self.param_dtype)
             rows = rows + jnp.asarray(bias, rows.dtype).reshape(-1)
-        return remat.name(rows, remat.PROJECTION).reshape(
-            rows.shape[:-1] + features)
+        # remat's: ``dots`` keeps the finished rows by name; under ``full``
+        # they are a candidate at the width they contract (``ops/remat.py``)
+        axes = self.axis if isinstance(self.axis, tuple) else (self.axis,)
+        return remat.name_rows(
+            rows, math.prod(x.shape[axis] for axis in axes), self.name
+        ).reshape(rows.shape[:-1] + features)
 
 
 def _dense(features, kernel_axes, bias_axes, name=None, use_bias=True,
@@ -1034,6 +1039,9 @@ def _mamba2(block, u):
                     "in_C")(u)
     dt = _projection(block, m.n_heads, ("embed", "heads"), ("heads",),
                      "in_dt")(u)
+    # one candidate for what remat ``full`` keeps, as an FFN's first products
+    z, x, B, C, dt = remat.name_products((z, x, B, C, dt), u.shape[-1],
+                                         "maps")
 
     def conv(name, a, axes):
         # torch's Conv1d default, fan_in the taps of a depthwise filter
@@ -1167,6 +1175,7 @@ def _mamba1(block, u):
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
     x = _projection(block, m.channels, heads, kv, "in_x")(u)
     z = _projection(block, m.channels, heads, kv, "in_z")(u)
+    x, z = remat.name_products((x, z), u.shape[-1], "maps")
 
     conv_init = _uniform_init(m.d_conv)  # fan_in a depthwise filter's taps
     with jax.named_scope("conv1d"):

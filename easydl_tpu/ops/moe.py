@@ -1101,10 +1101,15 @@ class MoeMlp(nn.Module):
                 down = weight("shared_down", (self.shared_d_ff, d),
                               ("mlp", "embed"), self.out_init_scale,
                               self.down_zero_sums)
+                # one candidate for what remat ``full`` keeps, as a dense
+                # FFN's first products (``ops/remat.py``)
+                kept = remat.product_namer((jax.ShapeDtypeStruct(
+                    h.shape[:-1] + (self.shared_d_ff,), h.dtype),)
+                    * (1 + gated), d, "shared")
                 if gated:
-                    hidden = nn.silu(h @ gate[0]) * (h @ up)
+                    hidden = nn.silu(kept(h @ gate[0])) * kept(h @ up)
                 else:
-                    hidden = _activation(h @ up)
+                    hidden = _activation(kept(h @ up))
                 y = y + hidden @ down
         dropped, n_mine, overflow, largest, visited, made = stats
         counted = [

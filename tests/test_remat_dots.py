@@ -149,9 +149,9 @@ def test_a_tally_counts_what_was_named_and_only_while_open():
         with remat.block(None) as inner:
             remat.name(x.astype(jnp.float32), remat.FLASH_LSE, 8067.0)
         remat.name(x, remat.FFN_IN)
-    assert inner.named == [(remat.FLASH_LSE, 24, 8067.0)]
-    assert said.named == [(remat.PROJECTION, 12, None),
-                          (remat.FFN_IN, 12, None)]
+    assert inner.named == [(remat.FLASH_LSE, 24, 8067.0, None)]
+    assert said.named == [(remat.PROJECTION, 12, None, None),
+                          (remat.FFN_IN, 12, None, None)]
     with pytest.raises(AssertionError):
         remat.name(x, "a name the policy does not save")
 
@@ -234,3 +234,34 @@ def test_a_dots_stack_says_once_what_a_layer_names(monkeypatch):
            "microbatch as traced" in said[0]
     assert said[0].endswith("beside its unnamed products; candidates left "
                             "out: none")
+
+
+def test_dots_is_offered_none_of_what_full_keeps_by_name(monkeypatch):
+    """The names a ``full`` block keeps its products by (PR 59: an attention
+    projection's rows, a scan mixer's input maps, a shared expert's first
+    products) are ``full``'s alone: ``dots`` keeps every one as the product it
+    is, or as the ``projection`` it always named. With the floor at zero and
+    a chooser with room without end open, a ``dots`` stack traces to the
+    program it is with none open — not one name more — where ``full``'s
+    holds the new names."""
+    new = {remat.ROWS, remat.MIXER_IN, remat.SHARED_IN}
+    assert new < set(remat.KEPT["full"]) and not new & set(remat.KEPT["dots"])
+    assert set(remat.PRODUCTS.values()) <= set(remat.KEPT["full"])
+    tokens = jnp.zeros((2, 32), jnp.int32)
+
+    def traced(policy, chooser):
+        model = Transformer(TransformerConfig(
+            vocab=64, d_model=64, n_heads=2, n_layers=2, d_ff=128, max_seq=32,
+            remat=True, remat_policy=policy))
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+        with remat.choosing(chooser):
+            program = jax.jit(jax.grad(lambda p: model.apply(
+                p, tokens).astype(jnp.float32).sum())).trace(params)
+        return str(program.jaxpr), program.lower().as_text()
+
+    names, text = traced("dots", None)
+    monkeypatch.setattr(remat, "FLOOR_FLOP_PER_BYTE", 0)
+    chooser = remat.Chooser(1 << 60)
+    assert traced("dots", chooser)[1] == text and not chooser.seen
+    assert not any(f"name={name}" in names for name in new)
+    assert f"name={remat.ROWS}" in traced("full", remat.Chooser(1 << 60))[0]

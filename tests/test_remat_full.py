@@ -40,6 +40,8 @@ CELLS = {
     "laguna-xs.2-full": ((2, 8192, 48, 128, 128, None), 8067, True),
     "ouro-2.6b": ((1, 4096, 16, 128, 128, None), 4034, True),
     "granite-4.0-h-micro": ((2, 4096, 32, 64, 64, None), 3973, True),
+    # PR 59 measured this one: kept, Mellum 2's cell gained 1.45% more
+    "mellum2-window": ((2, 8192, 32, 128, 128, 1024), 1891, True),
     "laguna-xs.2-window": ((2, 8192, 64, 128, 128, 512), 977, False),
     "gpt2-medium": ((8, 1024, 16, 64, 64, None), 994, False),
     "gpt2-xl": ((4, 1024, 25, 64, 64, None), 994, False),
@@ -63,7 +65,7 @@ def test_the_rule_at_the_cells_flash_shapes(cell):
     cost = remat.flash_flop_per_byte(out, lse, **asked)
     assert round(cost) == want
     assert (cost >= remat.FLOOR_FLOP_PER_BYTE) == over
-    assert 994 < remat.FLOOR_FLOP_PER_BYTE <= 2048
+    assert 994 < remat.FLOOR_FLOP_PER_BYTE <= 1891
     held = out.size * 2 + lse.size * 4
 
     def keeps(policy, room):
@@ -200,13 +202,39 @@ def test_dots_keeps_a_picked_out_beside_what_it_kept(interpreted, flash_kept):
         params, tokens).jaxpr) == 1
 
 
-@pytest.mark.parametrize("model", [_gpt, _joyai],
-                         ids=["heads-32-32", "heads-24-16"])
+def _bundle(factory, **kw):
+    """``factory``'s test preset at 32 positions and 64 tokens, unless ``kw``
+    says otherwise."""
+    return get_model(factory, seq_len=32, **{"size": "test", "vocab": 64,
+                                             **kw})
+
+
+def _preset(preset):
+    """``OFFERED[preset]``'s model as :func:`_gpt` gives its own."""
+    def model(**kw):
+        factory, more, _ = OFFERED[preset]
+        bundle = _bundle(factory, **more, **kw)
+        return (lambda key, tokens: bundle.init_fn(key)), \
+            lambda p, tokens: bundle.loss_fn(
+                p, {"inputs": tokens, "targets": tokens},
+                jax.random.PRNGKey(0))[0]
+    return model
+
+
+@pytest.mark.parametrize("model", [
+    _gpt, _joyai, "hybrid-mamba2", "phi4flash", "nemotron", "laguna"],
+    ids=["heads-32-32", "heads-24-16", "mamba2", "mamba1-diff",
+         "mamba2-relu2-shared", "window-shared"])
 def test_a_run_in_which_nothing_is_picked_lowers_to_the_parents_text(
         interpreted, monkeypatch, model):
-    """``full``'s policy saves two names; a block that holds neither lowers
-    to the text of ``policy=None``, the parent's ``full``: same operations,
-    same private functions, as many times."""
+    """``full``'s policy saves its names; a block that holds none of them —
+    no chooser is open: a bare ``jax.grad``, the CPU — lowers to the text of
+    ``policy=None``, the parent's ``full``: same operations, same private
+    functions, as many times. Whatever the block could have offered: flash
+    results, an FFN's products, and (PR 59) its attention's rows, its scan
+    mixer's input maps, its shared expert's products."""
+    if isinstance(model, str):
+        model = _preset(model)
     # jax's tracing caches are bounded (2,048 / 4,096 entries, least recently
     # used out first): in a worker that has run a few hundred tests an entry
     # one lowering reads twice can be gone the second time, and that function
@@ -288,13 +316,19 @@ def test_a_full_stack_says_once_what_a_layer_keeps(interpreted, flash_kept,
     # [2, 64, 64] float32 contracts 128: 2 x 128 FLOP over 4 bytes
     out, lse, up = 2 * 64 * 128 * 4, 2 * 4 * 64 * 4, 2 * 64 * 64 * 4
     cost = round(2 * (64 * 65 // 2) * 2 * 4 * 64 / (out + lse))
+    # ... and, the run being a scan of two, the attention's q, k, v and its
+    # output map's result [2, 64, 128] float32, which contract 128 as well
     assert "a (attention, gelu) layer at (2, 64, 128), no room stated, " \
            "keeps by name nothing a microbatch" in said[0]
-    assert said[0].endswith(
-        f"candidates left out: flash {(out + lse) / 1e6:.1f} MB at {cost:,} "
-        f"FLOP a byte (under the floor of 2,000); ffn {up / 1e6:.1f} MB at "
-        f"64 FLOP a byte (under the floor of 2,000)")
+    under = "(under the floor of 1,800)"
+    rows = [f"{what} {out / 1e6:.1f} MB at 64 FLOP a byte"
+            for what in ("q", "k", "v", "out")]
+    assert said[0].endswith("candidates left out: " + "; ".join(
+        f"{left} {under}" for left in (
+            *rows[:3], f"flash {(out + lse) / 1e6:.1f} MB at {cost:,} FLOP a "
+            f"byte", rows[3], f"ffn {up / 1e6:.1f} MB at 64 FLOP a byte")))
     assert f"given {(1 << 60) / 2**30:.3f} GiB of room, keeps by name " \
+           + "".join(f"rows {row}, " for row in rows) + \
            f"ffn_in {up / 1e6:.1f} MB at 64 FLOP a byte, " \
            f"flash_out {out / 1e6:.1f} MB at {cost:,} FLOP a byte, " \
            f"flash_lse {lse / 1e6:.1f} MB at {cost:,} FLOP a byte " \
@@ -348,9 +382,10 @@ def _phi4flash(**kw):
                      layer_ids=[0, 1, 16, 17, 18, 19], **kw)
 
 
-def _offered(bundle, chooser, batch=2, seq=32):
-    """The candidates ``chooser`` sees while the gradient of ``bundle``'s
-    loss is traced, and what it kept of them."""
+def _offered(bundle, chooser, batch=2, seq=32, what=("ffn",)):
+    """The candidates of the kinds ``what`` that ``chooser`` sees while the
+    gradient of ``bundle``'s loss is traced, and what it kept of them: by run
+    for one kind, by (run, kind) for several."""
     tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
     params = jax.eval_shape(lambda: nn.unbox(
         bundle.init_fn(jax.random.PRNGKey(0))))
@@ -358,27 +393,42 @@ def _offered(bundle, chooser, batch=2, seq=32):
         jax.make_jaxpr(jax.grad(lambda p, tokens: bundle.loss_fn(
             p, {"inputs": tokens, "targets": tokens},
             jax.random.PRNGKey(0))[0]))(params, tokens)
-    return {c.key[0]: (c.bytes, c.key in chooser.kept)
-            for c in chooser.seen.values()}
+    return {c.key[0] if len(what) == 1 else c.key[:2]:
+            (c.bytes, c.key in chooser.kept)
+            for c in chooser.seen.values() if c.key[1] in what}
+
+
+def _filled(bundle, room, **kw):
+    """:func:`_offered` under what the rule keeps in ``room``, as the
+    ``Trainer`` finds it: a trace that keeps nothing shows the candidates,
+    ``fill`` ranks them, a second trace is held to that plan."""
+    first = remat.Chooser(0, frozenset())
+    _offered(bundle, first, **kw)
+    return _offered(bundle, remat.Chooser(room, first.fill(room)), **kw)
 
 
 def test_a_scanned_run_is_all_or_nothing_and_a_run_of_one_stands_alone(
         monkeypatch):
     """GPT-2's test preset is ONE scanned run of two layers: one candidate of
-    both layers' bytes, left whole where only one layer's fit.
+    both layers' bytes, left whole where only one layer's fit — its FFN as
+    each of its attention's rows, which cost as much a byte (they contract
+    the same 128) and are ranked behind it, the smaller.
     Phi-4-mini-flash's six layers are six runs of one: with room for two and
-    a half the first two are kept and the other four made again."""
+    a half FFNs the first two are kept and the other four made again."""
     monkeypatch.setattr(remat, "FLOOR_FLOP_PER_BYTE", 0)
     gpt = get_model("gpt", size="test", seq_len=32, vocab=64, remat=True,
                     remat_policy="full")
     layer = 2 * 32 * 512 * 4  # up [2, 32, 512] float32
-    assert _offered(gpt, remat.Chooser(2 * layer - 1)) \
-        == {"blocks": (2 * layer, False)}
-    assert _offered(gpt, remat.Chooser(2 * layer)) \
-        == {"blocks": (2 * layer, True)}
+    assert _filled(gpt, 2 * layer - 1) == {"blocks": (2 * layer, False)}
+    assert _filled(gpt, 2 * layer) == {"blocks": (2 * layer, True)}
+    rows = 2 * 32 * 128 * 4  # q [2, 32, 128] float32
+    kinds = ("ffn", "q", "k", "v", "out")
+    assert _filled(gpt, 2 * layer + 5 * rows, what=kinds) == {
+        ("blocks", what): (2 * (rows if n else layer), n < 3)
+        for n, what in enumerate(kinds)}
     phi = _phi4flash(remat=True, remat_policy="full")
     layer = 2 * 2 * 32 * 640 * 4  # gate and up [2, 32, 640] float32
-    assert _offered(phi, remat.Chooser(5 * layer // 2)) == {
+    assert _filled(phi, 5 * layer // 2) == {
         f"blocks_{i}": (layer, i < 2) for i in range(6)}
     # what is looped is held once a pass: two layers x two passes
     ouro = get_model("ouro", size="test", seq_len=32, vocab=64, remat=True,
@@ -387,8 +437,7 @@ def test_a_scanned_run_is_all_or_nothing_and_a_run_of_one_stands_alone(
     (nbytes, kept), = _offered(ouro, remat.Chooser(1 << 40)).values()
     layer = nbytes // 4
     assert kept and nbytes == 4 * layer
-    assert _offered(ouro, remat.Chooser(4 * layer - 1)) \
-        == {"blocks": (4 * layer, False)}
+    assert _filled(ouro, 4 * layer - 1) == {"blocks": (4 * layer, False)}
 
 
 @pytest.mark.parametrize("room", [0, 1 << 20, 1 << 60])
@@ -399,9 +448,10 @@ def test_under_the_floor_nothing_is_kept_at_any_room(room):
     assert _offered(_six_runs(), chooser) == {} and not chooser.kept
     with remat.choosing(remat.Chooser(room)), remat.block("full") as said:
         x = jnp.ones((4, 64), jnp.bfloat16)
-        remat.name_products((x, x), 1999)
+        remat.name_products((x, x), remat.FLOOR_FLOP_PER_BYTE - 1)
         assert (len(said.named), len(said.left)) == (0, 1)
-        remat.name_products((x, x), 2000)  # 1,024 bytes at the floor
+        # 1,024 bytes at the floor
+        remat.name_products((x, x), remat.FLOOR_FLOP_PER_BYTE)
     assert (len(said.named), len(said.left)) == ((2, 1) if room >= 1024
                                                  else (0, 2))
 
@@ -439,8 +489,23 @@ def _six_runs():
 
 #: a microbatch's [2, 32, 128] float32
 UNIT = 2 * 32 * 128 * 4
-#: the bytes the six runs keep, in the order of the trace
+#: the bytes the six runs' FFNs keep, in the order of the trace
 RUNS = [UNIT, 2 * UNIT] * 3
+#: every candidate of the six runs in the order of the trace, (key, bytes):
+#: runs of one offer their FFNs alone, all at 32 FLOP a byte (the width 64
+#: they contract, in float32)
+SIX = [((f"blocks_{i}", "ffn", 0), RUNS[i]) for i in range(6)]
+
+
+def _rule(room):
+    """The keys the rule keeps of ``SIX`` (equal a byte) in ``room``, in rank
+    order: the larger first, equals as traced; each where it fits."""
+    kept = []
+    for key, nbytes in sorted(SIX, key=lambda c: -c[1]):
+        if nbytes <= room:
+            room -= nbytes
+            kept.append(key)
+    return kept
 #: what the step is SAID to compile to with nothing kept
 BASE = 1 << 30
 
@@ -450,7 +515,8 @@ def a_chip(monkeypatch):
     """``a_chip(room, ...)``: from the call on every device states a limit
     (``ops/platform.memory_stats``, as a TPU states its own) that leaves
     ``room`` bytes beside a step of ``BASE`` bytes and the margin, and a
-    compiled step is SAID to take ``BASE + share x`` the bytes it keeps (the
+    compiled step is SAID to take ``BASE + share x`` the bytes it keeps
+    (``share`` a function: ``BASE + share(the bytes)``) (the
     CPU's compiler, which packs a test-size step's buffers its own way, is
     not asked: nothing is compiled), or refused for memory where it keeps more than
     ``refused_over``; the floor is lowered so that a test-size FFN is a
@@ -461,6 +527,7 @@ def a_chip(monkeypatch):
 
     def state(room, share=1.0, refused_over=None, in_use=0):
         opened = []
+        cost = share if callable(share) else lambda kept: int(share * kept)
 
         class Chooser(remat.Chooser):
             def __init__(self, *args):
@@ -472,7 +539,7 @@ def a_chip(monkeypatch):
             if refused_over is not None and kept > refused_over:
                 raise jax.errors.JaxRuntimeError(
                     "RESOURCE_EXHAUSTED: Used more than the chip has")
-            return BASE + int(share * kept)
+            return BASE + cost(kept)
 
         monkeypatch.setattr(remat, "FLOOR_FLOP_PER_BYTE", 0)
         monkeypatch.setattr(remat, "Chooser", Chooser)
@@ -486,54 +553,71 @@ def a_chip(monkeypatch):
 
 
 def _kept(trainer):
-    return sorted(key[0] for key in trainer.step_fn._chooser.plan)
+    """The keys the trainer's step keeps, sorted."""
+    return sorted(trainer.step_fn._chooser.plan)
 
 
-@pytest.mark.parametrize("room,share,refused_over,traces,kept,stands", [
+@pytest.mark.parametrize("room,share,refused_over,traces,stands", [
     # room for all six: the trace that sizes the step, then the rule's
-    (9 * UNIT, 1.0, None, 2, 6, None),
-    # room for half
-    (9 * UNIT // 2, 1.0, None, 2, 3, None),
+    (9 * UNIT, 1.0, None, 2, None),
+    # room for half: the two largest
+    (9 * UNIT // 2, 1.0, None, 2, None),
     # no room: the step that keeps nothing stands, traced and compiled once
-    (UNIT - 1, 1.0, None, 1, 0, None), (0, 1.0, None, 1, 0, None),
+    (UNIT - 1, 1.0, None, 1, None), (0, 1.0, None, 1, None),
     # XLA packs half of what is kept into room it had: the room is the
-    # sized step's all the same, and three are kept
-    (4 * UNIT, 0.5, None, 2, 3, None),
-    # what is kept costs half as much again as its bytes, or the compiler
-    # refuses the step that keeps it: the step that keeps nothing stands
-    (4 * UNIT, 1.5, None, 2, 0, "compiles to"),
-    (9 * UNIT // 2, 1.0, 3 * UNIT, 2, 0, "is refused"),
+    # sized step's all the same
+    (4 * UNIT, 0.5, None, 2, None),
+    # what is kept costs half as much again as its bytes: the rule once more
+    # in the room at that price, two thirds of it
+    (4 * UNIT, 1.5, None, 3, None),
+    # three units more than its bytes, whatever is kept: the second choice is
+    # over too; or the compiler refuses the step that keeps the first: the
+    # step that keeps nothing stands
+    (4 * UNIT, lambda kept: kept and kept + 3 * UNIT, None, 3, "compiles to"),
+    (9 * UNIT // 2, 1.0, 3 * UNIT, 2, "is refused"),
 ])
 def test_the_trainer_fits_what_is_kept_to_the_limit_it_is_given(
-        a_chip, said_by_the_block, room, share, refused_over, traces, kept,
-        stands):
-    """With an injected limit the ``Trainer`` keeps the runs of one that fit
-    the room the compiled step leaves, in the order of the trace where they
-    cost alike, and says once what the step compiled to beside the limit
-    (one microbatch a step here; two in the cases below)."""
+        a_chip, said_by_the_block, room, share, refused_over, traces, stands):
+    """With an injected limit the ``Trainer`` keeps the candidates that fit
+    the room the compiled step leaves — where they cost alike the larger
+    first, equals in the order of the trace — and says once what the step
+    compiled to beside the limit (one microbatch a step here; two in the
+    cases below)."""
     bundle = _six_runs()
     opened = a_chip(room, share, refused_over)
     trainer = _trainer(bundle, batch=2, accum=1)
     lowered = _lowered(trainer, batch=2)
     assert len(opened) == traces + 1
-    assert _kept(trainer) == [f"blocks_{i}" for i in range(kept)]
-    size = BASE + share * sum(RUNS[:kept])
+    assert [tuple(c.key) for c in opened[0].seen.values()] \
+        == [key for key, _ in SIX]
+    cost = share if callable(share) else lambda kept: share * kept
+
+    def held(keys):
+        return sum(dict(SIX)[key] for key in keys)
+
+    kept = _rule(room)
+    if traces == 3:  # over: again in the room at the price the compile showed
+        kept = _rule(room * held(kept) // cost(held(kept)))
+    kept = [] if stands else kept
+    assert _kept(trainer) == sorted(kept)
+    size = BASE + cost(held(kept))
     assert size <= BASE + room
     del lowered
     said, = [m for m in said_by_the_block
              if m.startswith("train step: compiled")]
-    warned = [m for m in said_by_the_block if m.endswith(" stands")]
-    assert len(warned) == (stands is not None)
-    for line in warned:
-        assert line.startswith(f"train step: keeping {4 * UNIT / 2**30:.3f} "
-                               f"GiB the step {stands}") and line.endswith(
-            ": the step that keeps nothing stands")
+    warned = [m for m in said_by_the_block if "train step: keeping" in m]
+    assert len(warned) == (traces > 1) * (traces - 2 + (stands is not None))
+    assert all(f"GiB the step {stands or 'compiles to'}" in line
+               for line in warned)
+    assert len([m for m in said_by_the_block if m == "train step: the step "
+                "that keeps nothing stands"]) == (stands is not None)
     assert f"compiled to {size / 2**30:.3f} GiB a device of a limit of " \
         in said and "; remat keeps " in said
+    left = [key for key in _rule(1 << 40) if key not in kept]
     assert said.endswith(
-        "leaves no candidate out" if kept == 6 else
-        f"the first of {6 - kept} left out for want of room: blocks_{kept} "
-        f"ffn {RUNS[kept] / 1e6:.1f} MB at 32 FLOP a byte")
+        "leaves no candidate out" if not left else
+        f"the first of {len(left)} left out for want of room: "
+        f"{remat.Candidate(left[0], dict(SIX)[left[0]], 32)}")
 
 
 def test_the_same_program_and_limit_give_the_same_choice_twice(a_chip):
@@ -544,10 +628,11 @@ def test_the_same_program_and_limit_give_the_same_choice_twice(a_chip):
     first, again = _trainer(bundle), _trainer(bundle)
     text = _lowered(first).as_text()
     assert text == _lowered(again).as_text()
-    assert _kept(first) == _kept(again) == [f"blocks_{i}" for i in range(6)]
+    assert _kept(first) == _kept(again) == sorted(key for key, _ in SIX)
     # a build: the trace that sizes the step, the rule's, the replay; two
     # microbatches: the block is traced twice a step and offered once
-    assert len(opened) == 6 and all(len(c.seen) == 6 for c in opened[:2])
+    assert len(opened) == 6 and all(len(c.seen) == len(SIX)
+                                    for c in opened[:2])
     jax.clear_caches()  # a later TRACE of the step replays the choice
     assert _lowered(first).as_text() == text
     assert len(opened) == 6 and opened[2].kept == opened[2].plan
@@ -584,7 +669,8 @@ def test_a_warm_start_traces_once_from_the_choice_it_remembers(
     assert len(opened) == 2 + 1  # the sizing trace, the rule's, the replay
     memo, = a_compile_cache.iterdir()
     right = json.loads(memo.read_text())
-    assert right["kept"] == [[f"blocks_{i}", "ffn", 0] for i in range(3)]
+    assert right["kept"] == sorted(map(list, _rule(9 * UNIT // 2)))
+    assert right["kept"] == [["blocks_1", "ffn", 0], ["blocks_3", "ffn", 0]]
     assert (right["room"], right["budget"]) == (9 * UNIT // 2,
                                                 BASE + 9 * UNIT // 2)
     del opened[:]
@@ -600,7 +686,8 @@ def test_a_warm_start_traces_once_from_the_choice_it_remembers(
         del opened[:]
         again = _trainer(bundle, batch=2, accum=1)
         assert _lowered(again, batch=2).as_text() == text
-        assert len(opened) == traces + 1 and _kept(again) == _kept(cold)
+        assert len(opened) == traces + 1 \
+            and _kept(again) == _kept(cold)
         assert json.loads(memo.read_text()) == right
     a_chip(9 * UNIT)  # another limit is another step's memory
     _lowered(_trainer(bundle, batch=2, accum=1), batch=2)
@@ -666,7 +753,8 @@ def test_a_job_that_fills_the_chip_keeps_nothing_and_is_still_served(
     assert _lowered(trainer, seq=64).as_text() == plain
     assert _kept(trainer) == []
     # the run's flash results and its FFN were candidates, and had no room
-    assert {key[1] for key in opened[0].seen} == {"flash", "ffn"}
+    assert {key[1] for key in opened[0].seen} == {"flash", "ffn", "q", "k",
+                                                  "v", "out"}
     assert len(opened) == 1 + 1
     del params
 
@@ -689,8 +777,8 @@ from easydl_tpu.models.transformer import TransformerConfig
 from easydl_tpu.ops import platform, remat
 
 # process 0 alone has room for all six beside the 64 MiB it holds, process
-# 1 for three and holds nothing: one program, so one choice — three in the
-# least limit less the most held
+# 1 for the two largest and holds nothing: one program, so one choice — two
+# in the least limit less the most held
 room, in_use = ((9 * t.UNIT, 1 << 26), (9 * t.UNIT // 2, 0))[rank]
 opened, asked = [], []
 
@@ -755,7 +843,7 @@ def test_two_processes_of_one_program_agree_on_one_choice(tmp_path):
         out, err = proc.communicate(timeout=300)
         assert proc.returncode == 0, err[-3000:]
         assert out.splitlines()[-1] == \
-            "KEPT ['blocks_0', 'blocks_1', 'blocks_2'] 1", out
+            "KEPT ['blocks_1', 'blocks_3'] 1", out
 
 
 def test_what_the_process_holds_beside_the_step_is_not_room(a_chip):
@@ -797,6 +885,7 @@ def test_with_gate_and_up_kept_the_gradients_are_no_remats_leaf_for_leaf(
     monkeypatch.setattr(remat, "FLOOR_FLOP_PER_BYTE", 0)
     chooser = remat.Chooser(1 << 40)
     got, products_kept = grads(chooser, remat=True, remat_policy="full")
+    # the two layers are runs of one: their FFNs are all they offer
     assert len(chooser.kept) == 2
     # gate and up, two layers (the second forward's down is read by nothing
     # and is not made either way)
@@ -809,13 +898,182 @@ def test_with_gate_and_up_kept_the_gradients_are_no_remats_leaf_for_leaf(
                                    err_msg=jax.tree_util.keystr(path))
 
 
+# ------------------------------- every product at or over the floor (PR 59)
+def _model(factory, **kw):
+    return _bundle(factory, remat=True, **{"remat_policy": "full", **kw})
+
+
+#: a test preset -> every candidate its ``full`` blocks offer beside the flash
+#: calls and the dense FFNs, ``{(run, what): (bytes of a [2, 32] microbatch
+#: over the run's layers, FLOP a byte)}``: float32, so half the width a
+#: product contracts. Offered in a scanned run alone
+OFFERED = {
+    # one scanned run of two layers, 128 wide
+    "gpt": ("gpt", {}, {
+        ("blocks", what): (2 * 2 * 32 * 128 * 4, 64)
+        for what in ("q", "k", "v", "out")}),
+    # two Mamba-2 layers scanned: z and x 64 wide, B and C 32, dt 4
+    "hybrid-mamba2": ("granite_hybrid", dict(layer_types=["mamba"] * 2), {
+        ("blocks", "maps"): (2 * 2 * 32 * (64 + 64 + 32 + 32 + 4) * 4, 32)}),
+    # a Mamba-1 layer and a differential layer, two runs of ONE under the
+    # barrier: nothing new (offered there the kinds lost Phi-4-mini-flash's
+    # cell 0.19%: ``ops/remat.py``'s docstring)
+    "phi4flash": ("phi4flash", dict(layer_ids=[0, 17]), {}),
+    # two Mamba-1 layers scanned: in_x and in_z, 320 wide each
+    "phi4flash-scanned": ("phi4flash", dict(layer_ids=[0, 2]), {
+        ("blocks", "maps"): (2 * 2 * 32 * 2 * 320 * 4, 80)}),
+    # a dense layer alone (nothing new), two sparse layers scanned: q's way
+    # up (4 heads of 24) contracts the latent's 48, the shared expert is gate
+    # and up of 32
+    "joyai": ("joyai", dict(layer_types=["dense"] + ["sparse"] * 2,
+                            mtp=False), {
+        ("blocks_1", "q_b"): (2 * 2 * 32 * 4 * 24 * 4, 24),
+        ("blocks_1", "out"): (2 * 2 * 32 * 64 * 4, 32),
+        ("blocks_1", "shared"): (2 * 2 * 32 * 2 * 32 * 4, 32)}),
+    # NemotronH's four sub-layer pairs, every one a run of one: nothing new
+    "nemotron": ("nemotron_h", {}, {}),
+    # Laguna's full layers are runs of one beside a scanned run of three
+    # window layers: no barrier, so the three alone offer anything new — and
+    # their output map contracts 8 heads of 16, twice the model's width
+    "laguna": ("laguna", dict(vocab=128), {
+        ("blocks_1", "q"): (3 * 2 * 32 * 128 * 4, 32),
+        ("blocks_1", "k"): (3 * 2 * 32 * 32 * 4, 32),
+        ("blocks_1", "v"): (3 * 2 * 32 * 32 * 4, 32),
+        ("blocks_1", "out"): (3 * 2 * 32 * 64 * 4, 64),
+        ("blocks_1", "shared"): (3 * 2 * 32 * 2 * 32 * 4, 32)}),
+}
+
+
+def _seen(bundle, chooser):
+    _offered(bundle, chooser)
+    return {c.key[:2]: (c.bytes, c.flop_per_byte)
+            for c in chooser.seen.values() if c.key[1] not in ("ffn", "flash")}
+
+
+@pytest.mark.parametrize("preset", list(OFFERED))
+def test_a_full_block_offers_every_product_at_or_over_the_floor(
+        monkeypatch, preset):
+    """Each kind — an attention projection's rows, a scan mixer's input maps,
+    a shared expert's first products — with its bytes over the run and what
+    a byte of it costs to make again; with room all are kept; under the
+    floor as it stands none reaches the chooser; and ``dots``, which keeps
+    them as the products they are, is offered none."""
+    factory, kw, want = OFFERED[preset]
+    chooser = remat.Chooser(1 << 40)
+    assert _seen(_model(factory, **kw), chooser) == {}
+    monkeypatch.setattr(remat, "FLOOR_FLOP_PER_BYTE", 0)
+    chooser = remat.Chooser(1 << 40)
+    assert _seen(_model(factory, **kw), chooser) == want
+    assert {key[:2] for key in chooser.kept} >= set(want)
+    assert _seen(_model(factory, **{**kw, "remat_policy": "dots"}),
+                 remat.Chooser(1 << 40)) == {}
+
+
+@pytest.mark.parametrize("named,policy,uses,kept", [
+    # an FFN's products in any run; the others in a scanned run (several
+    # layers, or a layer a looped pass: uses over one)
+    ("ffn", "full", 1, True), ("ffn", "full", 3, True),
+    ("maps", "full", 1, False), ("maps", "full", 2, True),
+    ("shared", "full", 1, False), ("shared", "full", 6, True),
+    ("q", "full", 1, False), ("q", "full", 4, True),
+    ("out", "full", 1, False), ("out", "full", 3, True),
+    # dots keeps them unnamed, no remat keeps everything
+    ("ffn", "dots", 3, False), ("q", "dots", 3, False),
+    ("maps", None, 3, False),
+])
+def test_a_kind_is_named_in_a_scanned_run_alone(named, policy, uses, kept):
+    x = jnp.ones((4, 64), jnp.bfloat16)
+    chooser = remat.Chooser(1 << 20)
+    with remat.choosing(chooser), remat.run("blocks_7", uses), \
+            remat.block(policy) as said:
+        if named in remat.PRODUCTS:
+            remat.name_products((x, x), 2560, named)
+            label, n = remat.PRODUCTS[named], 2
+        else:
+            remat.name_rows(x, 2560, named)
+            label, n = remat.ROWS if kept else remat.PROJECTION, 1
+    if not kept:
+        assert not chooser.seen and not said.left
+        assert [v.label for v in said.named] == (
+            [] if named in remat.PRODUCTS else [remat.PROJECTION])
+        return
+    # the bytes a layer's x the run's uses, at the width contracted
+    assert [tuple(c) for c in chooser.seen.values()] == [
+        (("blocks_7", named, 0), n * 512 * uses, 2560.0)]
+    assert [(v.label, v.bytes, v.flop_per_byte) for v in said.named] \
+        == [(label, 512, 2560.0)] * n
+    assert label in remat.KEPT["full"] and label not in remat.KEPT["dots"]
+
+
+#: candidates at 2,560 FLOP a byte (Phi-4-mini-flash's widths), in the order
+#: of a trace, MB: a Mamba-1 layer's maps and FFN, a window layer's rows and
+#: FFN
+TIED = [("maps", 335), ("ffn", 671), ("q", 84), ("k", 42), ("v", 42),
+        ("out", 84), ("ffn1", 671)]
+
+
+@pytest.mark.parametrize("room,kept", [
+    # of equals the larger first: both FFNs before anything smaller ...
+    (1342, ["ffn", "ffn1"]), (1341, ["ffn", "maps", "q", "out", "k", "v"]),
+    # ... then what still fits, the larger first, equals as traced
+    (1342 + 335 + 84, ["ffn", "ffn1", "maps", "q"]),
+    (1342 + 100, ["ffn", "ffn1", "q"]),
+    (1342 + 84 + 84 + 42, ["ffn", "ffn1", "q", "out", "k"]),
+    # dearer beats larger: a flash call's 341 at 12,100 goes first
+    (341 + 671, ["flash", "ffn"]), (341 + 670, ["flash", "maps", "q", "out",
+                                                "k", "v"]),
+])
+def test_a_tie_goes_to_the_candidate_that_saves_more_in_all(room, kept):
+    """What a candidate states is what keeping it saves a byte; of those that
+    state the same (at Phi-4-mini-flash's widths an FFN, a mixer's maps and
+    the attention's rows all read 2,560) the one that saves more in all is
+    ranked first: FFNs, then maps, then q and ``out``, then k and v."""
+    chooser = remat.Chooser(0, frozenset())
+    for what, mb in TIED + [("flash", 341)] * ("flash" in kept):
+        chooser.offer(remat.Candidate(
+            ("blocks", what, 0), mb, 12100 if what == "flash" else 2560))
+    ranked = [c.key[1] for c in chooser.ranked()]
+    assert ranked == ["flash"] * ("flash" in kept) + [
+        "ffn", "ffn1", "maps", "q", "out", "k", "v"]
+    assert chooser.fill(room) == frozenset(("blocks", what, 0)
+                                           for what in kept)
+
+
+def test_the_floor_and_what_stands_under_it():
+    """1,800 FLOP a byte: measured on both sides (``ops/remat.py``'s
+    docstring: XL lost 5.0% at 994, Mellum 2 gained 1.45% at 1,891) — under
+    it the GPT-2 cells' flash calls (994), Laguna's and Phi-4-mini-flash's
+    window layers (977, 744) and every product that contracts less than
+    1,800 (ZAYA1's output map, 1,024; JoyAI's ``q_b``, 1,536); at or over it
+    every benchmark cell's model width and Mellum 2's band."""
+    assert remat.FLOOR_FLOP_PER_BYTE == 1800
+    x = jnp.ones((8, 128), jnp.bfloat16)
+    for contracted, over in ((1024, False), (1536, False), (1799, False),
+                             (2048, True), (2304, True), (2560, True),
+                             (2688, True), (4096, True), (8192, True)):
+        chooser = remat.Chooser(1 << 20)
+        with remat.choosing(chooser), remat.run("blocks", 2), \
+                remat.block("full") as said:
+            remat.name_rows(x, contracted, "out")
+        assert bool(chooser.kept) == over
+        assert [why for _, why in said.left] == ([] if over else [
+            f"under the floor of {remat.FLOOR_FLOP_PER_BYTE:,}"])
+    out, lse = _results(2, 8192, 32, 128)
+    band = remat.flash_flop_per_byte(out, lse, s_k=8192, head_dim=128,
+                                     causal=True, window=1024)
+    assert round(band) == 1891  # Mellum 2's window layers
+    assert band >= remat.FLOOR_FLOP_PER_BYTE
+
+
 # ------------------------------------------- the stacks' texts, PR 58's parent
 #: every stack ``tests/test_tpu_compile_stack.py`` compiles for the described
 #: v5e -> how it is lowered. The first four are given no room (GPT-2's under
 #: ``dots`` and ``full``, under ``fsdp=4`` through the ``Trainer``, Ouro's
 #: two layers and passes): no limit is stated, nothing more is kept. The
-#: three scanned runs at a cell's widths keep their flash results today and
-#: are given ``ROOM`` there, as their cells' chips give it.
+#: three scanned runs at a cell's widths kept their flash results there and
+#: are held to that choice (``FLASH``: what else a scanned run keeps since PR
+#: 59 is another text).
+FLASH = frozenset({("blocks", "flash", 0)})
 STACKS = {
     "gpt2-two-layers-dots": lambda stack, devices:
         stack._two_layer_gpt2_lowered(devices, "dots"),
@@ -826,13 +1084,14 @@ STACKS = {
     "ouro-two-layers-two-passes": lambda stack, devices:
         stack._two_layer_rotary_lowered(devices),
     "sdar-scanned": lambda stack, devices: stack._scanned_lowered(
-        devices, "sdar", batch=1, size="30b-a3b-chat", block_length=4,
-        layer_types=["full_attention"] * 2, experts_held=(0, 16)),
+        devices, "sdar", batch=1, plan=FLASH, size="30b-a3b-chat",
+        block_length=4, layer_types=["full_attention"] * 2,
+        experts_held=(0, 16)),
     "joyai-scanned": lambda stack, devices: stack._scanned_lowered(
-        devices, "joyai", size="llm-flash", layer_types=["sparse"] * 2,
-        mtp=False, experts_held=(0, 16)),
+        devices, "joyai", plan=FLASH, size="llm-flash",
+        layer_types=["sparse"] * 2, mtp=False, experts_held=(0, 16)),
     "zaya-scanned": lambda stack, devices: stack._scanned_lowered(
-        devices, "zaya", size="8b", layer_types=["hybrid"] * 2,
+        devices, "zaya", plan=FLASH, size="8b", layer_types=["hybrid"] * 2,
         experts_held=(0, 8)),
 }
 
@@ -860,8 +1119,9 @@ def test_every_stack_lowers_to_the_text_it_had_before_the_rule_saw_room(
         name, v5e_2x2, described_tpu):
     """``tests/goldens/remat_stacks.json`` holds each stack's hash on PR 58's
     parent (cb83808), where a constant picked the flash calls: with no limit
-    stated — and, for the runs that kept their flash results there, with the
-    room their cells have — every one lowers to that text."""
+    stated — and, for the runs that kept their flash results there, held to
+    that choice — every one lowers to that text: what a ``full`` block offers
+    beside them since PR 59 moves no program that does not keep it."""
     import json
     import os
 

@@ -287,8 +287,8 @@ def test_phi4flash_s_step_holds_its_four_new_kernels_and_fits(v5e_2x2,
     # the window of 1,024 on the looped kernels, as before the band held it
     ("mellum_1x2", {"swa_bwd_dq": -1, "swa_bwd_dkv": -1, "swa_bwd": 1},
      "mellum_1x2: attention kernels {'flash_bwd': 1, 'flash_fwd': 1, "
-     "'swa_bwd': 1, 'swa_fwd': 2}, not {'flash_bwd': 1, 'flash_fwd': 1, "
-     "'swa_bwd_dkv': 1, 'swa_bwd_dq': 1, 'swa_fwd': 2}"),
+     "'swa_bwd': 1, 'swa_fwd': 1}, not {'flash_bwd': 1, 'flash_fwd': 1, "
+     "'swa_bwd_dkv': 1, 'swa_bwd_dq': 1, 'swa_fwd': 1}"),
     ("sdar_1x1", {}, None),
     ("sdar_1x1", {"bd_fwd": 1},  # the scanned run made ``out`` again
      "sdar_1x1: attention kernels {'bd_bwd': 1, 'bd_fwd': 2}, not "
@@ -580,12 +580,14 @@ def test_rotary_puts_no_copy_between_the_projections_and_the_kernels(
 ROOM = 4 << 30
 
 
-def _scanned_lowered(devices, factory: str, batch: int = 2, **description):
+def _scanned_lowered(devices, factory: str, batch: int = 2, plan=None,
+                     **description):
     """Loss and gradients of a two-layer scanned run of ``factory``'s
     description at the cell's widths (``batch`` x 8,192 tokens, bf16, remat
-    ``full`` with :data:`ROOM` for what it keeps, a 1,024-row head; the
-    expert layer's kernels compiled as on the chip: the caller asks
-    ``described_tpu``), lowered for one described chip."""
+    ``full`` with :data:`ROOM` for what it keeps — or held to the keys of
+    ``plan``, as the ``Trainer`` holds a trace to a choice — a 1,024-row
+    head; the expert layer's kernels compiled as on the chip: the caller
+    asks ``described_tpu``), lowered for one described chip."""
     from easydl_tpu.models.registry import get_model
     from easydl_tpu.ops import remat
 
@@ -595,17 +597,25 @@ def _scanned_lowered(devices, factory: str, batch: int = 2, **description):
         bundle = get_model(
             factory, seq_len=8192, vocab=1024, dtype="bfloat16", remat=True,
             remat_policy="full", attention_impl="flash", **description)
-        with remat.choosing(remat.Chooser(ROOM)):
+        with remat.choosing(remat.Chooser(ROOM, plan)):
             return _loss_and_gradients_lowered(bundle, devices, (batch, 8192))
     finally:
         jax.config.update("jax_traceback_in_locations_limit", frames)
 
 
+#: compiled texts by (factory, batch, description): two tier-1 tests read
+#: SDAR's, and a compile at a cell's widths is 45 s
+_TEXTS: dict = {}
+
+
 def _scanned_text(devices, factory: str, batch: int = 2,
                   **description) -> str:
     """The compiled text of :func:`_scanned_lowered`'s program."""
-    return _scanned_lowered(devices, factory, batch,
-                            **description).compile().as_text()
+    key = (factory, batch, repr(sorted(description.items())))
+    if key not in _TEXTS:
+        _TEXTS[key] = _scanned_lowered(devices, factory, batch,
+                                       **description).compile().as_text()
+    return _TEXTS[key]
 
 
 def _scanned_run(devices, factory: str, **description) -> list:
@@ -633,9 +643,7 @@ def test_sdars_stack_norms_inside_the_rotary_kernel_and_its_flash_calls_are_bds(
         sys.path.insert(0, bench)
     flash_calls = importlib.import_module("lib.hlo").flash_calls
 
-    text = _scanned_text(
-        v5e_2x2, "sdar", batch=1, size="30b-a3b-chat", block_length=4,
-        layer_types=["full_attention"] * 2, experts_held=(0, 16))
+    text = _scanned_text(v5e_2x2, "sdar", **SDAR_TWO)
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     for name in ("rope_norm_fwd", "rope_norm_bwd"):
@@ -645,6 +653,57 @@ def test_sdars_stack_norms_inside_the_rotary_kernel_and_its_flash_calls_are_bds(
     assert "qk_rmsnorm" not in text
     assert sorted({call["kernel"] for call in flash_calls(text)}) == [
         "bd_bwd", "bd_fwd"]
+
+
+SDAR_TWO = dict(batch=1, size="30b-a3b-chat", block_length=4,
+                layer_types=["full_attention"] * 2, experts_held=(0, 16))
+
+
+@pytest.mark.parametrize("factory,description,lanes,again", [
+    # Mellum 2's window layers: the band's results are kept too (1,891 FLOP
+    # a byte, over the floor since PR 59); the rotation of the kept q and of
+    # the kept k is made again
+    ("mellum", dict(size="2-12b-a2.5b", layer_types=["sliding_attention"] * 2,
+                    experts_held=(0, 16)), (4096, 2304),
+     ["rope_fwd", "rope_fwd"]),
+    # SDAR's under the block mask: the call's results are kept, and the
+    # backward's norming rotation reads the kept rows of q and k
+    ("sdar", SDAR_TWO, (4096, 2048), ["rope_norm_fwd", "rope_norm_fwd"]),
+], ids=["band", "block-diffusion"])
+def test_whoever_reads_a_kept_q_or_output_maps_result_reads_the_kept_rows(
+        v5e_2x2, described_tpu, factory, description, lanes, again):
+    """A two-layer scanned run at the cell's widths under remat ``full``
+    with room for every candidate (PR 59: q, k, v and the output map's
+    result kept as rows): in the recomputation none of the four products
+    stands again, the kernels there are those that read the kept rows, and
+    nothing moves a q-sized or a result-sized array between the layers'
+    stack, the rotary kernel and the flash call — no ``copy`` or
+    ``transpose`` of it in the recomputation (PR 30: XLA wrote such a slice
+    twice and transposed it), and in the backward none but the turned
+    operands of the four weight-gradient products."""
+    text = _scanned_text(v5e_2x2, factory, **description)
+    found = _attention_instructions(text)
+    assert {which for which, *_ in found} == {"fwd", "remat", "bwd"}
+    remade = [path for which, _, _, path in found if which == "remat"
+              and path.split("/")[0] in ("q", "k", "v", "out")
+              and "dot_general" in path]
+    assert not remade, remade
+    assert [path for which, _, _, path in found if which == "fwd"
+            and path.split("/")[:2] == ["q", "dot_general"]]
+    kernels = sorted(path.split("/")[-2] for which, opcode, _, path in found
+                     if which == "remat" and opcode == "custom-call")
+    assert kernels == again, kernels
+    rows = description.get("batch", 2) * 8192
+    big = tuple(f"[{rows // 8192},8192,{n}]" for n in lanes) \
+        + tuple(f"[{n},{rows}]" for n in lanes)
+    moved = [(which, opcode, result, path)
+             for which, opcode, result, path in found
+             if opcode in ("copy", "transpose")
+             and any(shape in result for shape in big)]
+    assert not [m for m in moved if m[0] != "bwd"], moved
+    # a weight gradient takes the layer's input TURNED: XLA's own order
+    assert {m[3] for m in moved} <= {f"{name}/dot_general"
+                                     for name in ("q", "k", "v", "out")}
 
 
 @pytest.mark.parametrize("factory,description,forward,rows,reader", [
