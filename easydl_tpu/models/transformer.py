@@ -892,8 +892,7 @@ def _latent_attention(block, kind, h, rope):
     the kernel on their rows; the one key vector in ``jax.numpy``),
     ``mla_key`` (the rotated key vector copied beside every head's
     ``nope_dim``: the published code's form, and the kernels' operand),
-    the kernels ``mla_fwd`` / ``mla_bwd`` (``mla_bwd_dq`` / ``mla_bwd_dkv``
-    on the kernels' unrolled side), ``mla_out`` (the way back up from the
+    the kernels ``mla_fwd`` / ``mla_bwd``, ``mla_out`` (the way back up from the
     attention's result ``[B, S, heads, value_dim]``)."""
     cfg, low = block.cfg, kind.lowrank
     n_heads = kind.n_heads or cfg.n_heads

@@ -11,9 +11,9 @@ Backward follows the standard flash decomposition: save per-row logsumexp
 ``lse`` from the forward; recompute P = exp(qkᵀ·scale − lse) blockwise; the
 rowwise ``delta = Σ dO∘O`` term is formed inside the kernels from an ``O``
 operand (a product and a turn on the XLU a Q-block, under the kernels'
-compute, where an XLA pass over dO and O stood in the open). Where the
-backward is looped (below) it is ONE kernel on the grid ``(batch row, lane
-block, K-block)`` that walks the K-block's live Q-blocks: a pair's score
+compute, where an XLA pass over dO and O stood in the open). The backward
+is ONE kernel on the grid ``(batch row, lane block, K-block)`` that walks
+the K-block's live Q-blocks: a pair's score
 tile, ``exp``, ``dP``, ``delta`` and ``dS`` are made once and feed three
 products, ``dV += Pᵀ dO``, ``dK += dSᵀ Q`` and ``dQᵀ[Q-block] += Kᵀ dSᵀ`` —
 five products a pair, walked in tiles (below). dq's float32 sum lives in
@@ -24,11 +24,10 @@ block that stays resident while the K-block axis runs
 (``dimension_semantics``: that axis ``arbitrary``); rows no key sees stay
 zero. The call says what VMEM it needs from what it holds (q, O, dO and the
 dq block of a cell's heads whole, twice, and the sums: 56 MB at 8,192 x two
-heads of 192 / 128). Where the backward is
-unrolled a grid cell takes a head's whole sequence, no axis carries a sum,
-and two kernels stand: dq walks K-blocks, dk/dv walks Q-blocks, each making
-the pair's tile for itself (seven products a pair; the trade there is code
-size against set-up time).
+heads of 192 / 128). Every call takes it, the short ones too (below: until
+PR 60 a head of at most 16 block pairs had two kernels of its own, dq
+walking K-blocks and dk/dv Q-blocks, each making the pair's tile for itself:
+seven products a pair); the band path alone keeps a dq and a dk/dv kernel.
 
 What follows the input and what is float32, always. Every matmul takes its
 operands in the dtype q, k, v and dO arrive in (bf16 in, bf16 to the MXU;
@@ -58,16 +57,15 @@ tells the forward by); the differentiation rule turns it once into dense
 is 64 MB of lane padding), and the backward kernels are handed those rows
 by Q-block (``_lse_operand``).
 
-How the looped forward walks a block pair. A ``[512, 512]`` float32 score
+How the forward walks a block pair. A ``[512, 512]`` float32 score
 tile is 256 vregs on a register file of 64, and a pair as one tile is one
 chain — ``K Qᵀ``, then max / ``exp`` / sum, then ``Vᵀ Pᵀ`` — with one head of
 128 a cell and so nothing beside it: the TPU's compiler scheduled an
 unmasked pair as 1,336 bundles of which 607 hold a store, every one a spill
 (the tile went to VMEM behind the product, came back for the max, and its
 ``exp`` went and came again), where the MXU's own operations need some 790;
-1.21 us a pair on the chip. The looped side (``unroll`` false: more than
-``_UNROLL_PAIRS`` pairs a head) therefore walks a pair in TILES of 128 keys
-x 128 queries (``_FWD_TILE``: one tile of the MXU, 16 vregs of scores), key
+1.21 us a pair on the chip. The forward therefore walks a pair in TILES of
+128 keys x 128 queries (``_FWD_TILE``: one tile of the MXU, 16 vregs of scores), key
 tile after key tile, under each the cell's heads and their Q tiles, as
 straight-line code. A chain — one head's 128 queries — has a running max, a
 normaliser and a ``[value_dim, 128]`` slice of the accumulator of its own,
@@ -85,12 +83,9 @@ them masked; anywhere else every tile of a crossed pair is masked under its
 own traced edge. After: 1,181 bundles an unmasked pair, 443 stores of which
 372 spills, 805 a masked pair for 1,381; 0.92 us a pair on the chip (the
 chip gains more than the count says and ranks depth and tile differently,
-so both were swept there: ``_FWD_TILE``). The unrolled side — at most 16
-pairs a head: two heads' straight-line pairs interleave as they are, and
-every pair written out is set-up time — keeps the pair as one tile, its
-program unchanged.
+so both were swept there: ``_FWD_TILE``).
 
-How the looped backward walks a block pair: the same way. Its pair as one
+How the backward walks a block pair: the same way. Its pair as one
 tile held TWO ``[512, 512]`` float32 tiles at once (``Sᵀ`` and ``dPᵀ``) on
 one chain of five products, and carried 128 vregs of dk and dv through
 every turn of its loop; 2.23 us a pair on the chip where the MXU's five
@@ -104,7 +99,7 @@ added to in its scratch a tile; the masked pair's tiles are placed as the
 forward's (six of sixteen not computed). 1.91 us a live pair at 128 / 128
 (1.99 a pair computed whole), the call 14 to 20% shorter; what else was
 tried is in ``_BWD_TILE``'s table. The call is a ``jax.jit`` of its own, as
-the forward's (``_bwd_looped``).
+the forward's (``_bwd``, ``_fwd``).
 
 What a rematerialised block may keep. The rule's forward names the two
 results that cost a kernel to make again, ``out`` and the ``lse`` rows
@@ -119,13 +114,24 @@ r sees key columns ≤ r + offset, as the reference's ``tril(k=s_k−s_q)``).
 Each Q-block (K-block in the backward that walks Q-blocks) walks its live
 block pairs in two loops:
 pairs wholly below the diagonal take no mask, pairs the diagonal crosses are
-masked (in the looped kernels tile by tile, above). When the whole problem
-is at most ``_UNROLL_PAIRS`` block pairs, one
-grid cell takes the whole sequence of its heads: every block index is then a
-Python number, dead pairs are never emitted and both loops unroll into
-straight-line code that the compiler schedules across pairs. Longer
-sequences take one Q-block (in the backward: one K-block) per grid cell and
-loop at run time.
+masked (tile by tile, above). A grid cell is one Q-block (in the backward:
+one K-block) of its heads and loops at run time.
+
+The short problem. A sequence of at most ``SHORT_SEQ`` 2,048 rows takes
+blocks of ``SHORT_BLOCK`` 1,024: a 1,024-long causal head (GPT-2's) is ONE
+block pair a grid cell, walked in the same tiles of 128 x 128 — the 36 on and
+under the diagonal of its 64; no dead tile is computed — by the same two
+kernels as an 8,192-long one. Where a cell is the only block of its side its
+place is a Python number: a loop of no turn is not traced and a loop of one
+is that turn (``_loop``), so the one pair lowers its masked body alone,
+under static slices. Where a head's whole problem is one pair of at most 512
+rows a side (BERT's 128 to 512, not causal) a cell takes four heads of 64
+for two (``_cell_heads``): so little work is mostly a cell's start. Until PR
+60 a head of at most 16 block pairs of 512 ran kernels of its own — one grid
+cell a head's whole sequence, every pair of 512 x 512 (dk/dv: 256 x 256)
+written out as one tile, three kernels — which every short shape measured
+loses to these (``SHORT_BLOCK``'s table: GPT-2's forward -12%, its backward
+-34%); they are gone, with the rule that chose them.
 
 The band path. Under a ``window`` of at most a grid cell's keys on a square
 problem (``s_q == s_k``: a training step's window layers) a cell meets only
@@ -165,8 +171,7 @@ before its own, a clean row the clean half by blocks, nothing sees the noised
 half from outside its block. ``seam² + seam · block`` of the ``4 seam²`` pairs
 are live, half of what a causal mask over as many rows keeps, so a kernel that
 only MASKED the dead ones would make twice the products. It is ONE call over
-the ``2 seam`` rows on the looped kernels above (and the unrolled pair at a
-test's sizes), all three under one square block that divides a half and is
+the ``2 seam`` rows on the kernels above, both under one square block that divides a half and is
 whole mask blocks, so that a block pair's place under the mask is its two
 block indices' (``_bd_k_blocks``, ``_bd_q_blocks``): with ``n`` blocks a half a
 Q-block walks the clean blocks before its own position's with no mask, the
@@ -186,7 +191,7 @@ and a ``block x block`` square, joined by their ``lse`` — would write and
 read ``out`` and ``lse`` of the noised half twice and run a merge pass for
 what the walk above gets from two loop bounds. The mathematics, the float32
 values and the two roundings are the looped kernels'; the calls carry names
-of their own (``bd_fwd``, ``bd_bwd``; unrolled ``bd_bwd_dq``, ``bd_bwd_dkv``),
+of their own (``bd_fwd``, ``bd_bwd``),
 hold ``2 seam`` rows of k and v (q, O and dO) resident — the two-size
 allowance of VMEM — and refuse ``causal``, a ``window`` and two head sizes
 beside the mask.
@@ -205,8 +210,8 @@ rows. Short sequences widen the block (``_cell_heads``).
 Grouped queries. k and v reach every kernel at the KEY/VALUE heads,
 ``[batch, seq, kv_heads·head_dim]``, and query head ``h`` reads head ``h //
 (heads // kv_heads)`` by the lane-block index of the BlockSpecs that read k
-or v (:func:`_shared`: the looped forward and one-call backward, the
-unrolled dq / dkv pair, the band path's own and neighbour blocks; under
+or v (:func:`_shared`: the forward and the one-call backward, the band
+path's own and neighbour blocks; under
 ``causal``, a window and the block mask alike): no repeat of k and v to the
 query's heads stands in HBM in front of a call. That holds where a grid cell
 is ONE head (heads of 128 and wider). A lane block of two heads of 64 cannot
@@ -224,8 +229,7 @@ equal head counts every spec, operand and index map is what it was.
 
 Two head sizes. The scores' size (q, k, dq, dk: ``head_dim``) and the
 values' (v, O, dO, dv: ``value_dim``) are two numbers through the forward,
-the one-kernel backward, the unrolled dq and dk/dv kernels, the blocks'
-specs and ``_cell_heads``: latent attention
+the one-kernel backward, the blocks' specs and ``_cell_heads``: latent attention
 scores 192 deep (128 without positions beside 64 rotated) and weighs values
 128 wide. A cell then takes the heads that fill whole tiles of BOTH widths
 — two heads: a 384-lane block of q and k beside a 256-lane block of v, O and
@@ -233,8 +237,7 @@ dO — and every product keeps its own depth (``S = K Qᵀ`` 192, ``P V`` and
 ``dP = V dOᵀ`` 128): v is not padded to the scores' size, which would cost
 half again those products and the bytes of v, O and dO. Where the two are
 equal the kernels lower to what they lowered to with one; where they differ
-the calls carry names of their own (``mla_fwd``, ``mla_bwd``; unrolled
-``mla_bwd_dq``, ``mla_bwd_dkv``; ``diff_*`` where the values are the wider:
+the calls carry names of their own (``mla_fwd``, ``mla_bwd``; ``diff_*`` where the values are the wider:
 differential attention's pair of score heads of 64 against one value of 128).
 Under a window the band path keeps its names (``swa_*``) and takes values
 the wider, its blocks of v, O and dO as wide as they; scores deeper than the
@@ -252,7 +255,6 @@ to check numerics against the XLA reference path without hardware.
 from __future__ import annotations
 
 import functools
-import itertools
 import math
 import operator
 from typing import NamedTuple, Optional, Tuple, Union
@@ -269,28 +271,63 @@ log = get_logger("ops", "flash_attention")
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
-#: a problem of at most this many block pairs a head is one grid cell per
-#: batch row and lane block, unrolled (1024 x 1024 causal: 3 live pairs in
-#: blocks of 512, 10 in blocks of 256).
-_UNROLL_PAIRS = 16
-
-#: block pairs an unrolled grid cell is filled up to with further lane
-#: tiles of heads. Every unrolled pair costs a cached program about 0.1 s of
-#: set-up each time it is traced and loaded (PERF.md section 6, PR 24: four
-#: heads a cell at 1024 x 1024 gave +1.2% tokens/s for +3.8 s), so only cells
-#: smaller than this are widened.
-_CELL_PAIRS = 4
-
-#: what one grid cell's blocks may take of VMEM, one buffer each (Pallas
-#: keeps two; float32 [4, 1024, 64] blocks of q, k, v, o did not fit).
-_CELL_BYTES = 6 << 20
-
 #: the largest block a kernel takes when the caller names none (the sweep
 #: in :func:`choose_blocks`)
 MAX_BLOCK = 512
 
-#: (block_q, block_k) of the forward, the dq and the dk/dv kernel; where the
-#: backward is looped its one kernel takes dk/dv's.
+#: the largest block where both lengths are at most ``SHORT_SEQ``. Swept on a
+#: v5e, us a call by the kernels' own events, five calls a variant, bf16,
+#: forward / backward (PERF.md section 6, PR 60), at every shape the unrolled
+#: kernels served — the parent's three, one grid cell a head's whole sequence,
+#: every pair of 512 x 512 (dk/dv: 256 x 256 under causal) written out as one
+#: tile, the backward as dq + dk/dv — against these two kernels at a block:
+#:
+#:   shape, causal                 unrolled         block 256        block 512        block 1,024
+#:   [8, 1024, 16 x 64] medium     277.9 / 830.7    506.3 / 740.3    295.2 / 592.8    205.1 / 522.9 <-
+#:   [4, 1024, 25 x 64] XL's shard 249.7 / 723.0    419.3 / 627.4    251.5 / 510.9    190.8 / 457.6 <-
+#:   [16, 1024, 8 x 64] tp=2       277.7 / 830.8    506.4 / 740.5    295.2 / 592.7    204.9 / 522.5 <-
+#:   [2, 2048, 16 x 128]           296.7 / 794.5    884.7 / 917.0    316.2 / 663.7    270.5 / 548.8 <-
+#:   [4, 2048, 16 x 64]            470.2 / 1,460.1  837.6 / 1,229.8  461.9 / 1,005.7  407.9 / 974.0 <-
+#:   [8, 512 x 1024, 16 x 64]      199.8 / 588.1    336.5 / 552.7    157.0 / 444.9 <- 214.5 / 483.8 (512 x 1,024)
+#:   not causal:
+#:   [8, 512 x 2048, 16 x 64]      428.2 / 1,192.4                   374.9 / 966.9    349.1 / 944.8 <- (512 x 1,024)
+#:
+#: and a head that is ONE pair of at most 512 rows, by the heads a grid cell
+#: takes (``_cell_heads``; the unrolled cell took four):
+#:
+#:   shape                         unrolled         two heads        four heads       six, twelve
+#:   [16, 512, 16 x 64] causal     178.1 / 564.4    190.3 / 361.3    135.5 / 317.1 <-
+#:   [16, 512, 12 x 64] BERT       177.8 / 467.7    156.2 / 357.0    134.4 / 350.4 <- 134.2 / 350.5
+#:   [64, 128, 12 x 64] BERT       197.1 / 411.6    244.8 / 313.8    166.2 / 224.4 <- 166.2 / 224.0
+#:
+#: The taken column wins forward AND backward at every shape (-13 to -27% and
+#: -21 to -45%), so the unrolled kernels, the rule that chose them
+#: (``_unrolled``, ``_UNROLL_PAIRS``), the cell's widening by pairs and bytes
+#: (``_CELL_PAIRS``, ``_CELL_BYTES``) and dk/dv's small blocks are gone. What
+#: the numbers say: five products a pair for seven, and no dead tile
+#: computed, is the backward's third; a block of 512 at 1,024 rows — two grid
+#: cells a head, of one and two pairs — pays a cell's start and its
+#: look-ahead's fill and drain once for little work, and its forward LOSES to
+#: the unrolled one (+6%); the whole head as one pair does not. The column at
+#: 1,024 with the cell's place traced (``program_id``, both loop bodies
+#: lowered) read 246.1 / 544.2 at medium's shape and 225.5 / 485.5 at XL's:
+#: the place as a Python number (``_loop``) is more than half of the
+#: forward's gain. Four heads a cell at 1,024 x 1,024 did not fit the
+#: backward's VMEM (28.9 MB over 27.3). NOT taken, because it would move nine
+#: cells' programs and none was run end to end: blocks of 1,024 at 4,096 rows
+#: read 350.9 / 892.9 for 399.4 / 932.7 at ``[2, 4096, 8 x 64]`` and 464.9 /
+#: 1,011.9 for 544.6 / 1,136.6 at ``[1, 4096, 16 x 128]`` (PERF.md section 7).
+SHORT_BLOCK = 1024
+SHORT_SEQ = 2048
+
+#: lanes of the scores' size that a grid cell is widened to where a head is
+#: ONE block pair of at most ``MAX_BLOCK`` rows a side (``SHORT_BLOCK``'s table:
+#: four heads of 64 for two)
+_WIDE_LANES = 256
+
+#: (block_q, block_k) of the forward and, twice, of the backward (the band
+#: path's :data:`Bands` are a dq and a dk/dv kernel's; the ONE backward
+#: kernel takes the last).
 Blocks = Tuple[Tuple[int, int], Tuple[int, int], Tuple[int, int]]
 
 
@@ -396,17 +433,21 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     block divisor: the kernels cannot tile it, and whoever chooses the
     attention path (``ops/attention.py``) asks here before calling them.
 
-    Swept on a v5e over {128, 256, 512, 1024}² at [128, 1024, 64] and
-    [100, 1024, 64] bf16 causal (PERF.md section 6, PR 24): 512 x 512 is
-    fastest for the forward and dq; dk/dv, with four products a pair, gains
-    more from wasting less of the causal triangle (5/8 of the matrix computed
-    at 256², 3/4 at 512²) than it loses to more pairs — as long as the pairs
-    still unroll. Without a triangle the smaller blocks have nothing to win.
-    Where the forward loops it walks its 512 x 512 pair in tiles of 128 x
-    128 with the scores half a pair ahead (``_FWD_TILE``, ``_behind``: the
-    band forward's pattern, PR 49), and so does the looped backward's one
-    kernel (``_BWD_TILE``, PR 52); the block, the grid and what a cell holds
-    are the same.
+    The forward and the ONE backward kernel take the same block: ``MAX_BLOCK``
+    512 x 512 (swept on a v5e over {128, 256, 512, 1024}² while a pair was one
+    tile: PERF.md section 6, PR 24), walked in tiles of 128 x 128 with the
+    scores half a pair ahead (``_FWD_TILE``, ``_BWD_TILE``, ``_behind``: PR
+    49, PR 52), where a dead tile of the causal diagonal's pair is not
+    computed — so a smaller block wastes no less of the triangle and only
+    pays more grid cells (256 x 256 at 1,024: the forward +82%, ``SHORT_BLOCK``'s
+    table). Where both lengths are at most ``SHORT_SEQ`` the block is
+    ``SHORT_BLOCK`` 1,024: a 1,024-long head is ONE pair a grid cell, walked
+    in the same tiles, and a 2,048-long one four (``SHORT_BLOCK``'s table, PR 60; longer
+    sequences were not measured at it and keep 512). Under ``causal`` the
+    block is square, no larger than the shorter side: the diagonal then
+    passes through a pair's corner and its tiles are placed statically (a
+    512 x 1,024 block on a 512 x 1,024 rectangle masks every tile under a
+    traced edge and loses 7% forward to 512 x 512).
 
     Under a ``window`` of at most ``BAND_ROWS`` keys (a causal band: query i
     sees the ``window`` keys up to its own) on a square problem the three
@@ -461,9 +502,9 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
     128]``, window 512 (PERF.md section 6, PR 31): the forward and dq are
     fastest at 512 x 512 like the plain kernels (7.6 ms a call against 10.8
     at 256 x 256: fewer, fuller block pairs win over the band's masked
-    corners); dk/dv takes K-blocks of 256 (512 x 512 does not fit its VMEM
-    beside the whole sequence's q, O and dO, which the looped kernel holds,
-    and 512 x 256 beats 256 x 256 by 1.5 ms).
+    corners); the backward takes K-blocks of 256 (as a dk/dv kernel of its
+    own 512 x 512 did not fit its VMEM beside the whole sequence's q, O and
+    dO, and 512 x 256 beat 256 x 256 by 1.5 ms).
 
     Under a ``mask`` (:class:`BlockDiffusion`) all three kernels take ONE
     square block that divides a half and is whole mask blocks, so that a
@@ -490,14 +531,12 @@ def choose_blocks(s_q: int, s_k: int, causal: bool,
         big, banded = pick(MAX_BLOCK), pick(MAX_BLOCK, MAX_BLOCK // 2)
         if None not in big + banded:
             return big, big, banded
-    big, small = pick(MAX_BLOCK), pick(MAX_BLOCK // 2)
-    if None in big:
-        return None
-    dkv = big
-    if causal and None not in small and _unrolled(
-            s_q // small[0], s_k // small[1]):
-        dkv = small
-    return big, big, dkv
+    target = MAX_BLOCK
+    if window is None and max(s_q, s_k) <= SHORT_SEQ and not (
+            s_q % _FWD_TILE or s_k % _FWD_TILE):  # whole tiles alone
+        target = min(SHORT_BLOCK, s_q, s_k) if causal else SHORT_BLOCK
+    big = pick(target)
+    return None if None in big else (big, big, big)
 
 
 def _band(s: int, window: int, block_q: Optional[int],
@@ -555,18 +594,34 @@ def _most(a, b):
         else jnp.maximum(a, b)
 
 
-def _loop(lo, hi, body, carry, *, unroll: bool):
-    """``fori_loop``, or the same iterations as straight-line code when the
-    bounds are Python numbers and the caller wants them unrolled."""
-    if not unroll:
-        return jax.lax.fori_loop(lo, hi, body, carry)
-    for i in range(lo, hi):
-        carry = body(i, carry)
-    return carry
+def _loop(lo, hi, body, carry):
+    """``fori_loop``. Where the bounds are Python numbers — the grid cell is
+    the only block of its side, so its place is no ``program_id`` — a range
+    of no turn is not traced and a range of one is that turn: the one pair
+    of a 1,024-long causal head lowers its masked body alone, under static
+    slices (the other body would be two thirds of the kernel's text and
+    never run)."""
+    if isinstance(lo, int) and isinstance(hi, int) and hi - lo <= 1:
+        return body(lo, carry) if hi > lo else carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _aligned(x, block: int):
+    """``x``, a multiple of ``block``: told to Mosaic where it is traced."""
+    return x if isinstance(x, int) else pl.multiple_of(x, block)
 
 
 def _block_start(i, block: int):
-    return i * block if isinstance(i, int) else pl.multiple_of(i * block, block)
+    return _aligned(i * block, block)
+
+
+def _when(cond, body):
+    """``pl.when``; a Python ``cond`` (the grid cell is the only block of
+    its side) decides here."""
+    if not isinstance(cond, bool):
+        pl.when(cond)(body)
+    elif cond:
+        body()
 
 
 def _scores_t(k, q, s_scale: float, bound, window: Optional[int] = None):
@@ -625,14 +680,6 @@ def _bd_tile(rule, block: int, k_at: int, k_n: int, q_at: int,
     return not k_hi + most <= q_lo
 
 
-def _bd_pair(mask: Optional["BlockDiffusion"], masked, block_k: int,
-             block_q: int) -> Optional[bool]:
-    """:func:`_bd_tile` of a WHOLE block pair (the unrolled side, which
-    takes a pair as one tile); False without a block mask or a rule."""
-    return mask is not None and masked and _bd_tile(
-        masked, mask.block, 0, block_k, 0, block_q)
-
-
 def _bd_mask(st, rule, block: int, k_at: int, q_at: int):
     """The score tile ``st`` (``[keys, queries]``, the first key ``k_at``
     and the first query ``q_at`` rows into their diagonal pair) with what
@@ -654,8 +701,7 @@ def _flag(cond):
     return int(cond) if isinstance(cond, bool) else cond.astype(jnp.int32)
 
 
-def _bd_k_blocks(body, carry, qb, *, mask: BlockDiffusion, side: int,
-                 unroll: bool):
+def _bd_k_blocks(body, carry, qb, *, mask: BlockDiffusion, side: int):
     """``body(kb, carry, masked=)`` over the K-blocks that Q-block ``qb``
     (of ``side`` rows) sees under the block mask, ``n`` blocks a half: the
     clean blocks before its own position's take no mask (``masked`` False);
@@ -666,19 +712,17 @@ def _bd_k_blocks(body, carry, qb, *, mask: BlockDiffusion, side: int,
     n = mask.seam // side
     noised = _flag(qb < n)
     at = qb - n * (1 - noised)  # the block's place in its half
-    carry = _loop(n, n + at, functools.partial(body, masked=False), carry,
-                  unroll=unroll)
+    carry = _loop(n, n + at, functools.partial(body, masked=False), carry)
     if side == mask.block:
         carry = _loop(n + at, n + at + 1 - noised, functools.partial(
-            body, masked=("upto", 0)), carry, unroll=unroll)
+            body, masked=("upto", 0)), carry)
     else:
         carry = body(n + at, carry, masked=("upto", noised))
     return _loop(qb, qb + noised, functools.partial(body, masked=_OWN),
-                 carry, unroll=unroll)
+                 carry)
 
 
-def _bd_q_blocks(body, carry, kb, *, mask: BlockDiffusion, side: int,
-                 unroll: bool):
+def _bd_q_blocks(body, carry, kb, *, mask: BlockDiffusion, side: int):
     """:func:`_bd_k_blocks`' mirror, the Q-blocks that see K-block ``kb``: a
     noised K-block is seen by its own Q-block alone (``own``); a clean one
     by the noised Q-block at its position (``upto``, strictly) and the clean
@@ -688,21 +732,21 @@ def _bd_q_blocks(body, carry, kb, *, mask: BlockDiffusion, side: int,
     clean = _flag(kb >= n)
     at = kb - n * clean
     carry = _loop(kb, kb + 1 - clean, functools.partial(body, masked=_OWN),
-                  carry, unroll=unroll)
+                  carry)
     if side == mask.block:  # the strict pair is dead, the other whole
         carry = _loop(kb, kb + clean, functools.partial(
-            body, masked=("upto", 0)), carry, unroll=unroll)
+            body, masked=("upto", 0)), carry)
     else:
         carry = _loop(0, 2 * clean, lambda t, c: body(
-            at + t * n, c, masked=("upto", 1 - t)), carry, unroll=unroll)
+            at + t * n, c, masked=("upto", 1 - t)), carry)
     later = n - at - 1
     return _loop(0, 2 * later * clean, lambda t, c: body(
         at + 1 + t + _where(t >= later, n - later, 0), c, masked=False),
-        carry, unroll=unroll)
+        carry)
 
 
 def _over_k_blocks(body, carry, q_start, *, block_q: int, block_k: int, n_k: int,
-                   offset: int, causal: bool, unroll: bool,
+                   offset: int, causal: bool,
                    window: Optional[int] = None):
     """``body(kb, carry, masked=)`` over the K-blocks the Q-block at
     ``q_start`` sees: [0, n_full) lie wholly below the diagonal (every row
@@ -711,54 +755,55 @@ def _over_k_blocks(body, carry, q_start, *, block_q: int, block_k: int, n_k: int
     ``first`` lie wholly outside the band and are not visited, those before
     ``inside`` are crossed by its far edge and masked."""
     if not causal:
-        return _loop(0, n_k, functools.partial(body, masked=False), carry, unroll=unroll)
+        return _loop(0, n_k, functools.partial(body, masked=False), carry)
     n_full = _clip((q_start + offset + 1) // block_k, 0, n_k)
     n_live = _clip((q_start + block_q + offset + block_k - 1) // block_k, 0, n_k)
     if window is None:
-        carry = _loop(0, n_full, functools.partial(body, masked=False), carry, unroll=unroll)
-        return _loop(n_full, n_live, functools.partial(body, masked=True), carry, unroll=unroll)
+        carry = _loop(0, n_full, functools.partial(body, masked=False), carry)
+        return _loop(n_full, n_live, functools.partial(body, masked=True), carry)
     # the band's far edge: row r sees keys > r + offset - window
     first = _clip((q_start + offset - window + 1) // block_k, 0, n_k)
     inside = _clip((q_start + block_q + offset - window + block_k - 1) // block_k,
                    0, n_k)
     masked = functools.partial(body, masked=True)
-    carry = _loop(first, _least(inside, n_live), masked, carry, unroll=unroll)
-    carry = _loop(inside, n_full, functools.partial(body, masked=False), carry,
-                  unroll=unroll)
-    return _loop(_most(n_full, inside), n_live, masked, carry, unroll=unroll)
+    carry = _loop(first, _least(inside, n_live), masked, carry)
+    carry = _loop(inside, n_full, functools.partial(body, masked=False), carry)
+    return _loop(_most(n_full, inside), n_live, masked, carry)
 
 
-def _unrolled(n_q: int, n_k: int) -> bool:
-    """Whether a problem of ``n_q x n_k`` block pairs a head is one grid
-    cell per batch row and lane block, walked in straight-line code (else
-    one block per cell and loops at run time)."""
-    return n_q * n_k <= _UNROLL_PAIRS
-
-
-def _cell_heads(heads: int, head_dim: int, pairs: int, unroll: bool,
-                head_bytes: int, value_dim: Optional[int] = None) -> int:
+def _cell_heads(heads: int, head_dim: int, value_dim: Optional[int] = None,
+                pair: Optional[Tuple[int, int]] = None) -> int:
     """Heads one grid cell takes, side by side in the lanes of its blocks.
     As many as fill whole 128-lane tiles (two of 64, one of 128; all of
     them where the array is narrower than that) of the scores' size
     ``head_dim`` AND of the values' ``value_dim`` (None: the same; two heads
-    of 192 / 128: 384 lanes beside 256), and no more — unless a head
-    is so few block pairs (short sequences: one pair at 128 or 512) that a
-    cell of them is mostly waiting: then as many as make ``_CELL_PAIRS``
-    pairs a cell and keep its operands and results (``head_bytes`` a head)
-    within ``_CELL_BYTES``: independent chains of products for the compiler
-    to interleave. A head count the tile's heads do not divide (25 heads of
-    64) leaves the last cell part outside the array: its blocks are read and
-    written only where the array is, and each head is its own lane slice
-    inside the kernels, so what lies outside meets no live head."""
+    of 192 / 128: 384 lanes beside 256), and no more — unless a head's
+    whole problem is ONE block pair (``pair``: its rows a side) of at most
+    ``MAX_BLOCK`` rows (a short non-causal sequence, 128 to 512 long): a
+    cell of so little work is mostly its own start, and it takes the whole
+    tiles of heads that fill ``_WIDE_LANES`` (four heads of 64) and divide
+    the head count: independent chains for the compiler to interleave
+    (``SHORT_BLOCK``'s table: the forward at 128 rows 245 us a call at two heads
+    a cell, 166 at four, no less at six or twelve). A head count the tile's
+    heads do not divide (25 heads of 64) leaves the last cell part outside
+    the array: its blocks are read and written only where the array is, and
+    each head is its own lane slice inside the kernels, so what lies outside
+    meets no live head."""
     tile = math.lcm(*(math.lcm(d, 128) // d
                       for d in (head_dim, value_dim or head_dim)))
     if tile >= heads:
         return heads
-    if not unroll or heads % tile:
+    if pair is None or max(pair) > MAX_BLOCK or heads % tile:
         return tile
-    most = max(1, min(_CELL_PAIRS // (pairs * tile),
-                      _CELL_BYTES // (head_bytes * tile)))
+    most = max(1, _WIDE_LANES // (tile * head_dim))
     return tile * max(g for g in range(1, most + 1) if heads // tile % g == 0)
+
+
+def _one_pair(s_q: int, s_k: int, block_q: int,
+              block_k: int) -> Optional[Tuple[int, int]]:
+    """The rows a side of a head's whole problem where it is ONE block pair
+    (what :func:`_cell_heads` widens a cell by), else None."""
+    return (block_q, block_k) if (s_q, s_k) == (block_q, block_k) else None
 
 
 def _lse_operand(lse, cell: int, block_q: int, whole: bool,
@@ -766,8 +811,7 @@ def _lse_operand(lse, cell: int, block_q: int, whole: bool,
     """``(BlockSpec, operand)`` that hand a backward kernel ``lse`` (dense
     rows ``[B, H, S]``) as one ``[1, block_q]`` row a Q-block, ``[B, H,
     S // block_q, 1, block_q]``: every Q-block of the cell's heads
-    (``whole``: an unrolled cell, and the looped backward's, which walks
-    them all) or the grid cell's own (the band kernels, whose Q-block is a
+    (``whole``: the one-kernel backward's, which walks them all) or the grid cell's own (the band kernels, whose Q-block is a
     cell's rows or, through ``at``, its neighbour's). The kernels read
     Q-block ``qb`` of head ``g`` as ``lse_ref[g, qb]``."""
     b, h, s = lse.shape
@@ -853,25 +897,24 @@ def _shared(q, k, v, heads: int, cell: int):
     return k, v, at
 
 
-#: what the forward and the unrolled backward kernels may take of VMEM
-#: where the two head sizes differ: a cell holds the whole sequence of two
-#: heads' k and v (forward, dq) or q, O and dO (dk/dv), 15 MB at 8,192 x
-#: (384 + 256) bf16 lanes, twice — over the compiler's own 16 MB (a v5e's
-#: VMEM is 128 MB); the one-kernel backward states its own
+#: what the forward may take of VMEM where the two head sizes differ: a cell
+#: holds the whole sequence of two heads' k and v, 15 MB at 8,192 x (384 +
+#: 256) bf16 lanes, twice — over the compiler's own 16 MB (a v5e's VMEM is
+#: 128 MB); the one-kernel backward states its own
 _TWO_SIZE_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 
 
 def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
                params: Optional[pltpu.CompilerParams] = None,
                mask: Optional[BlockDiffusion] = None):
-    """A looped kernel's ``name=`` (and compiler parameters: the call's own
+    """A kernel's ``name=`` (and compiler parameters: the call's own
     ``params``, else the two-size allowance) by what it computes:
     ``flash_*``, ``swa_*`` under a window, where the scores' and the values'
     head sizes differ ``mla_*`` (latent attention: scores deeper than the
     values are wide) or ``diff_*`` (differential attention: a pair's value
     wider than its scores are deep), ``bd_*`` under the block mask (whose
-    forward and unrolled backward hold ``2 seam`` rows of k and v, or of q,
-    O and dO, whole: the two-size allowance of VMEM)."""
+    forward holds ``2 seam`` rows of k and v whole: the two-size allowance
+    of VMEM)."""
     if mask is not None:
         named = dict(name=f"bd_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
     elif d != dv:
@@ -901,6 +944,10 @@ def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
 #: once a cell of heads into a scratch (+3 to 5%), the sums read and written a
 #: tile (+0 to 2%), the next pair's first tiles' scores made behind this
 #: pair's last and handed on through a scratch (+5 to 7% at this depth).
+#: Since PR 60 the short shapes walk the same tiles (a 1,024-long head's ONE
+#: pair of 64, 64 ahead at two heads a cell: 205.1 us a call at ``[8, 1024,
+#: 16 x 64]`` where the unrolled pair as one tile took 277.9:
+#: ``SHORT_BLOCK``'s table).
 _FWD_TILE = 128
 
 
@@ -980,15 +1027,15 @@ def _behind(steps, first, second, ahead: int = 1):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, *sums,
-    head_dim: int, value_dim: int, block_q: int, block_k: int,
-    causal: bool, scale: float, offset: int, unroll: bool,
+    head_dim: int, value_dim: int, block_k: int, n_q: int,
+    causal: bool, scale: float, offset: int,
     window: Optional[int], mask: Optional[BlockDiffusion] = None,
 ):
-    # q_ref: [cell rows, cell heads · d], o_ref: [cell rows, cell heads ·
-    # dv]; k_ref: [S_k, cell heads · d], v_ref: [S_k, cell heads · dv];
-    # lse_ref: [cell heads, cell rows, 1]; sums (scratch, float32, the looped
-    # side's): m and l [cell heads, 1, block_q], acc [cell heads, dv, block_q]
-    cell_rows, lanes = q_ref.shape
+    # q_ref: [block_q, cell heads · d], o_ref: [block_q, cell heads · dv];
+    # k_ref: [S_k, cell heads · d], v_ref: [S_k, cell heads · dv]; lse_ref:
+    # [cell heads, block_q, 1]; sums (scratch, float32): m and l [cell heads,
+    # 1, block_q], acc [cell heads, dv, block_q]
+    block_q, lanes = q_ref.shape
     d, dv = head_dim, value_dim
     heads = _head_cols(lanes, d)
     v_heads = _head_cols(v_ref.shape[1], dv)
@@ -997,214 +1044,165 @@ def _fwd_kernel(
     # square of blocks whose corner it passes through
     diagonal = (window is None and block_q == block_k
                 and offset % block_k == 0)
-    cell_start = 0 if unroll else pl.program_id(2) * cell_rows
-    for j in range(cell_rows // block_q):
-        rows = slice(j * block_q, (j + 1) * block_q)
-        q_start = cell_start + j * block_q
-        qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
+    # the Q-block's place: a Python number where it is the only one. (Here
+    # and below the statements keep the order, and the one ``+ 0``, that the
+    # nine longer cells' kernels were traced in before PR 60: their step
+    # programs are held to that text by hash.)
+    qb = 0 if n_q == 1 else pl.program_id(2)
+    q_start = qb * block_q + 0
+    qs = [_fold_scale(q_ref[:, cols], scale) for cols in heads]
 
-        def whole(kb, carry, *, masked):
-            # the unrolled side: the pair as one tile, a K-block for every
-            # head of the cell — V is turned once for all of them, and their
-            # chains are independent work to interleave. ``masked``: whether
-            # the diagonal crosses the pair or, under the block mask, the
-            # rule of the pair's diagonal
-            crossed = _bd_pair(mask, masked, block_k, block_q)
-            if crossed is None:  # one mask block a kernel block: dead
-                return carry
-            k_start = _block_start(kb, block_k)
-            vt_all = v_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
-            out = []
-            for cols, v_cols, (q, s_scale), state in zip(
-                    heads, v_heads, qs, carry):
-                # m, l: [1, block_q]; acc: [dv, block_q]
-                k = k_ref[pl.ds(k_start, block_k), cols]
-                vt = vt_all[v_cols]  # [dv, block_k]: the head's sublanes
-                st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start
-                               if masked and mask is None else None, window)
-                if crossed:
-                    st = _bd_mask(st, masked, mask.block, 0, 0)
-                out.append(_online_softmax(state, st, vt))
-            return tuple(out)
+    def pair(kb, carry, *, masked):
+        # the pair in tiles of [sub_k, sub_q], key tile after key tile,
+        # under each the cell's heads and their Q tiles, the scores'
+        # products ``ahead`` tiles in front of their softmax (_FWD_TILE has
+        # why and the measurements). A chain — one head's queries of one Q
+        # tile — has a running max, a normaliser and a slice of the
+        # accumulator of its own: read from the scratch at its first tile of
+        # the pair, values through the pair, written back after its last, so
+        # that no turn of the loop carries them in registers it has not got.
+        # ``masked``: whether the diagonal crosses the pair or, under the
+        # block mask, the rule of the pair's diagonal
+        sub_k, sub_q, ahead = _fwd_tiles(block_q, block_k, len(heads))
+        k_start = _block_start(kb, block_k)
+        steps = [(ki, g, qj) for ki in range(0, block_k, sub_k)
+                 for g in range(len(heads))
+                 for qj in range(0, block_q, sub_q)]
+        placed = masked and mask is None and diagonal
+        steps, crossed = _live_tiles(steps, sub_k, sub_q, placed, mask,
+                                     masked)
+        last = {(g, qj): (ki, g, qj) for ki, g, qj in steps}
+        state, vts = {}, {}
 
-        def tiled(kb, carry, *, masked):
-            # the looped side: the pair in tiles of [sub_k, sub_q], key tile
-            # after key tile, under each the cell's heads and their Q tiles,
-            # the scores' products ``ahead`` tiles in front of their softmax
-            # (_FWD_TILE has why and the measurements). A chain — one head's
-            # queries of one Q tile — has a running max, a normaliser and a
-            # slice of the accumulator of its own: read from the scratch at
-            # its first tile of the pair, values through the pair, written
-            # back after its last, so that no turn of the loop carries them
-            # in registers it has not got.
-            sub_k, sub_q, ahead = _fwd_tiles(block_q, block_k, len(heads))
-            k_start = _block_start(kb, block_k)
-            steps = [(ki, g, qj) for ki in range(0, block_k, sub_k)
-                     for g in range(len(heads))
-                     for qj in range(0, block_q, sub_q)]
-            placed = masked and mask is None and diagonal
-            steps, crossed = _live_tiles(steps, sub_k, sub_q, placed, mask,
-                                         masked)
-            last = {(g, qj): (ki, g, qj) for ki, g, qj in steps}
-            state, vts = {}, {}
+        def keys(ki):
+            return pl.ds(_block_start(
+                kb * (block_k // sub_k) + ki // sub_k, sub_k), sub_k)
 
-            def keys(ki):
-                return pl.ds(_block_start(
-                    kb * (block_k // sub_k) + ki // sub_k, sub_k), sub_k)
+        def scores(step):
+            ki, g, qj = step
+            q, s_scale = qs[g]
+            edge = q_start + offset - k_start + qj - ki \
+                if masked and mask is None else None
+            if placed and ki + sub_k - 1 <= qj:
+                edge = None
+            st = _scores_t(k_ref[keys(ki), heads[g]], q[qj:qj + sub_q],
+                           s_scale, edge, window)
+            if crossed.get((ki, qj)):
+                st = _bd_mask(st, masked, mask.block, ki, qj)
+            return st
 
-            def scores(step):
-                ki, g, qj = step
-                q, s_scale = qs[g]
-                edge = q_start + offset - k_start + qj - ki \
-                    if masked and mask is None else None
-                if placed and ki + sub_k - 1 <= qj:
-                    edge = None
-                st = _scores_t(k_ref[keys(ki), heads[g]], q[qj:qj + sub_q],
-                               s_scale, edge, window)
-                if crossed.get((ki, qj)):
-                    st = _bd_mask(st, masked, mask.block, ki, qj)
-                return st
+        def softmax(step, st):
+            ki, g, qj = step
+            mine = slice(qj, qj + sub_q)
+            if ki not in vts:  # V's tile turned once for all heads
+                vts[ki] = v_ref[keys(ki), :].T
+            if (g, qj) not in state:
+                state[g, qj] = tuple(ref[g, :, mine] for ref in sums)
+            state[g, qj] = _online_softmax(state[g, qj], st,
+                                           vts[ki][v_heads[g]])
+            if step == last[g, qj]:
+                for ref, value in zip(sums, state.pop((g, qj))):
+                    ref[g, :, mine] = value
 
-            def softmax(step, st):
-                ki, g, qj = step
-                mine = slice(qj, qj + sub_q)
-                if ki not in vts:  # V's tile turned once for all heads
-                    vts[ki] = v_ref[keys(ki), :].T
-                if (g, qj) not in state:
-                    state[g, qj] = tuple(ref[g, :, mine] for ref in sums)
-                state[g, qj] = _online_softmax(state[g, qj], st,
-                                               vts[ki][v_heads[g]])
-                if step == last[g, qj]:
-                    for ref, value in zip(sums, state.pop((g, qj))):
-                        ref[g, :, mine] = value
+        _behind(steps, scores, softmax, ahead)
+        return carry
 
-            _behind(steps, scores, softmax, ahead)
-            return carry
-
-        carry = tuple((
-            jnp.full((1, block_q), NEG_INF, jnp.float32),
-            jnp.zeros((1, block_q), jnp.float32),
-            jnp.zeros((dv, block_q), jnp.float32),
-        ) for _ in heads)
-        if not unroll:  # the sums live in the scratch, the loops carry none
-            for g, start in enumerate(carry):
-                for ref, value in zip(sums, start):
-                    ref[g] = value
-            carry = None
-        if mask is not None:
-            carry = _bd_k_blocks(
-                whole if unroll else tiled, carry,
-                j if unroll else pl.program_id(2), mask=mask, side=block_q,
-                unroll=unroll)
-        else:
-            carry = _over_k_blocks(
-                whole if unroll else tiled, carry, q_start, block_q=block_q,
-                block_k=block_k, n_k=n_k, offset=offset, causal=causal,
-                unroll=unroll, window=window)
-        if not unroll:
-            carry = tuple(tuple(ref[g] for ref in sums)
-                          for g in range(len(heads)))
-        o_ts = []
-        for g, (m, l, acc) in enumerate(carry):
-            # Rows that saw no unmasked key (bottom-right-aligned causal with
-            # s_q > s_k leaves the first s_q - s_k rows empty) still have m at
-            # the NEG_INF sentinel: their p would be exp(0)=1, silently
-            # averaging V. Define such rows as zero output, and poison their
-            # lse to +|NEG_INF| so the backward's exp(s - lse) underflows to
-            # exactly 0 (no grad leak).
-            dead = m <= NEG_INF * 0.5
-            l = jnp.maximum(l, 1e-30)
-            o_ts.append(jnp.where(dead, 0.0, acc / l))
-            lse = jnp.where(dead, -NEG_INF, m + jnp.log(l))
-            # lse leaves as a [block_q, 1] column (the result's shape): a row
-            # of 8 equal sublanes transposed, of which one lane is kept.
-            lse_ref[g, rows, :] = jnp.broadcast_to(lse, (8, block_q)).T[:, :1]
-        # the cell's heads one under the other as [lanes, block_q], turned
-        # once: whole rows of the model's layout to store
-        o_ref[rows, :] = _side_by_side(o_ts, 0).T.astype(o_ref.dtype)
-
-
-def _fwd(q, k, v, *, heads: int, causal: bool, scale: float, block_q: int,
-         block_k: int, interpret: bool, window: Optional[int] = None,
-         mask: Optional[BlockDiffusion] = None):
-    """The forward call; where it is looped, a ``jax.jit`` of its own. The
-    body walked in tiles is sixteen times the operations of the pair as one
-    tile, a ``pallas_call``'s body is traced and lowered once a USE (the
-    pass, remat's, each run of layers, each of the benchmark's programs) and
-    tracing is set-up time at every start, cached executable or not: JoyAI's
-    step lowered in 11.1 s for the parent's 8.1 before the jit, Ouro's in 5.8
-    for 3.4 (PERF.md section 6, PR 49; ``ops/ssd.py _kernel_jit`` is the
-    same cure). The unrolled side keeps the plain call, and the three GPT-2
-    steps their text."""
-    # q, k: [B, S, H·d]; v: [B, S, H·dv]
-    s_q, s_k = q.shape[1], k.shape[1]
-    assert s_q % block_q == 0 and s_k % block_k == 0, (s_q, s_k, block_q, block_k)
-    unroll = _unrolled(s_q // block_q, s_k // block_k)
-    return (_fwd_call if unroll else _fwd_looped)(
-        q, k, v, heads=heads, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret, window=window, unroll=unroll,
-        mask=mask)
+    # the sums live in the scratch, the loops carry none
+    starts = [(jnp.full((1, block_q), NEG_INF, jnp.float32),
+               jnp.zeros((1, block_q), jnp.float32),
+               jnp.zeros((dv, block_q), jnp.float32)) for _ in heads]
+    for g, start in enumerate(starts):
+        for ref, value in zip(sums, start):
+            ref[g] = value
+    if mask is not None:  # 2 seam rows: never the only block
+        _bd_k_blocks(pair, None, pl.program_id(2), mask=mask, side=block_q)
+    else:
+        _over_k_blocks(pair, None, q_start, block_q=block_q, block_k=block_k,
+                       n_k=n_k, offset=offset, causal=causal, window=window)
+    ends = [tuple(ref[g] for ref in sums) for g in range(len(heads))]
+    o_ts = []
+    for g, (m, l, acc) in enumerate(ends):
+        # Rows that saw no unmasked key (bottom-right-aligned causal with
+        # s_q > s_k leaves the first s_q - s_k rows empty) still have m at
+        # the NEG_INF sentinel: their p would be exp(0)=1, silently
+        # averaging V. Define such rows as zero output, and poison their
+        # lse to +|NEG_INF| so the backward's exp(s - lse) underflows to
+        # exactly 0 (no grad leak).
+        dead = m <= NEG_INF * 0.5
+        l = jnp.maximum(l, 1e-30)
+        o_ts.append(jnp.where(dead, 0.0, acc / l))
+        lse = jnp.where(dead, -NEG_INF, m + jnp.log(l))
+        # lse leaves as a [block_q, 1] column (the result's shape): a row
+        # of 8 equal sublanes transposed, of which one lane is kept.
+        lse_ref[g] = jnp.broadcast_to(lse, (8, block_q)).T[:, :1]
+    # the cell's heads one under the other as [lanes, block_q], turned
+    # once: whole rows of the model's layout to store
+    o_ref[:, :] = _side_by_side(o_ts, 0).T.astype(o_ref.dtype)
 
 
 def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
               block_q: int, block_k: int, interpret: bool,
-              window: Optional[int], unroll: bool,
+              window: Optional[int] = None,
               mask: Optional[BlockDiffusion] = None):
+    """The forward call (``_fwd``: a ``jax.jit`` of its own). The body
+    walked in tiles is sixteen times the operations of a pair as one tile, a
+    ``pallas_call``'s body is traced and lowered once a USE (the pass,
+    remat's, each run of layers, each of the benchmark's programs) and
+    tracing is set-up time at every start, cached executable or not: JoyAI's
+    step lowered in 11.1 s for the parent's 8.1 before the jit, Ouro's in 5.8
+    for 3.4 (PERF.md section 6, PR 49; ``ops/ssd.py _kernel_jit`` is the
+    same cure)."""
+    # q, k: [B, S, H·d]; v: [B, S, H·dv]
     b, s_q, _ = q.shape
     s_k, (d, dv, _) = k.shape[1], _head_sizes(q, k, v, heads)
+    assert s_q % block_q == 0 and s_k % block_k == 0, (s_q, s_k, block_q, block_k)
     n_q, n_k = s_q // block_q, s_k // block_k
-    cell_rows = s_q if unroll else block_q
-    # q, o, k, v and the lse column (float32, one lane in 128)
-    cell = _cell_heads(heads, d, n_q * n_k, unroll,
-                       (s_q + s_k) * (d + dv) * q.dtype.itemsize
-                       + s_q * 128 * 4, dv)
+    cell = _cell_heads(heads, d, dv, _one_pair(s_q, s_k, block_q, block_k))
     k, v, shared = _shared(q, k, v, heads, cell)
     kernel = functools.partial(
-        _fwd_kernel, head_dim=d, value_dim=dv, block_q=block_q,
-        block_k=block_k, causal=causal, scale=scale, offset=s_k - s_q,
-        unroll=unroll, window=window, mask=mask,
+        _fwd_kernel, head_dim=d, value_dim=dv, block_k=block_k, n_q=n_q,
+        causal=causal, scale=scale, offset=s_k - s_q, window=window,
+        mask=mask,
     )
 
     def mine(size):
-        return pl.BlockSpec((None, cell_rows, cell * size),
+        return pl.BlockSpec((None, block_q, cell * size),
                             lambda b, h, qi: (b, qi, h))
 
     def whole(size):
         return pl.BlockSpec((None, s_k, cell * size),
                             lambda b, h, qi: (b, 0, h))
 
-    # what a looped cell holds: its heads' q and out blocks and the WHOLE of
-    # their k and v, two buffers each, the lse column (one lane in 128) and
-    # the sums. Stated, as the one-kernel backward states its own, where
-    # that and a pair's tiles in flight pass the compiler's own 16 MB and no
-    # allowance is named (`_call_name`): a causal forward at 16,384 rows of
-    # heads of 128 holds 17.3 MB of k and v alone and did not compile
-    # (PERF.md section 7, SDAR's (h)); every call that fitted keeps its text
+    # what a cell holds: its heads' q and out blocks and the WHOLE of their
+    # k and v, two buffers each, the lse column (one lane in 128) and the
+    # sums. Stated, as the backward states its own, where that and a pair's
+    # tiles in flight pass the compiler's own 16 MB and no allowance is
+    # named (`_call_name`): a causal forward at 16,384 rows of heads of 128
+    # holds 17.3 MB of k and v alone and did not compile (PERF.md section 7,
+    # SDAR's (h)); every call that fitted keeps its text
     params = None
-    held = (2 * cell * ((cell_rows + s_k) * (d + dv) * q.dtype.itemsize
-                        + cell_rows * 128 * 4)
+    held = (2 * cell * ((block_q + s_k) * (d + dv) * q.dtype.itemsize
+                        + block_q * 128 * 4)
             + cell * (dv + 16) * block_q * 4)
-    if not unroll and d == dv and mask is None and \
-            held + _TILES_VMEM > _DEFAULT_VMEM:
+    if d == dv and mask is None and held + _TILES_VMEM > _DEFAULT_VMEM:
         params = pltpu.CompilerParams(
             vmem_limit_bytes=held + _DEFAULT_VMEM)
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b, pl.cdiv(heads, cell), s_q // cell_rows),
+        grid=(b, pl.cdiv(heads, cell), n_q),
         in_specs=[mine(d), shared(whole(d)), shared(whole(dv))],
         out_specs=[
             mine(dv),
-            pl.BlockSpec((None, cell, cell_rows, 1), lambda b, h, qi: (b, h, qi, 0)),
+            pl.BlockSpec((None, cell, block_q, 1), lambda b, h, qi: (b, h, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, s_q, heads * dv), q.dtype),
             jax.ShapeDtypeStruct((b, heads, s_q, 1), jnp.float32),
         ],
-        # the looped side's running max, normaliser and accumulator of a
-        # cell's heads (0.26 MB a head at 512 x 128)
-        scratch_shapes=[] if unroll else [
+        # the running max, normaliser and accumulator of a cell's heads
+        # (0.26 MB a head at 512 x 128)
+        scratch_shapes=[
             pltpu.VMEM((cell, 1, block_q), jnp.float32),
             pltpu.VMEM((cell, 1, block_q), jnp.float32),
             pltpu.VMEM((cell, dv, block_q), jnp.float32)],
@@ -1214,9 +1212,9 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
     return out, lse
 
 
-_fwd_looped = jax.jit(_fwd_call, static_argnames=(
+_fwd = jax.jit(_fwd_call, static_argnames=(
     "heads", "causal", "scale", "block_q", "block_k", "interpret", "window",
-    "unroll", "mask"))
+    "mask"))
 
 
 # ---------------------------------------------------------------------------
@@ -1225,7 +1223,7 @@ _fwd_looped = jax.jit(_fwd_call, static_argnames=(
 
 
 def _over_q_blocks(body, carry, k_start, *, block_q: int, block_k: int,
-                   n_q: int, offset: int, causal: bool, unroll: bool,
+                   n_q: int, offset: int, causal: bool,
                    window: Optional[int] = None):
     """``body(qb, carry, masked=)`` over the Q-blocks that see the K-block
     at ``k_start``, :func:`_over_k_blocks`' mirror: those before
@@ -1248,12 +1246,11 @@ def _over_q_blocks(body, carry, k_start, *, block_q: int, block_k: int,
             last_full = _least(_clip(
                 (k_start - offset + window) // block_q, 0, n_q), last_live)
             near_end = _least(first_full, last_live)
-        carry = _loop(first_live, near_end, masked, carry, unroll=unroll)
+        carry = _loop(first_live, near_end, masked, carry)
     carry = _loop(first_full, last_full, functools.partial(body, masked=False),
-                  carry, unroll=unroll)
+                  carry)
     if window is not None:
-        carry = _loop(_most(first_full, last_full), last_live, masked, carry,
-                      unroll=unroll)
+        carry = _loop(_most(first_full, last_full), last_live, masked, carry)
     return carry
 
 
@@ -1284,7 +1281,9 @@ def _over_q_blocks(body, carry, k_start, *, block_q: int, block_k: int,
 #: and written a tile (two heads a cell +2.5 / +7%), the two heads of a cell
 #: alternating tile by tile (+1.4 / +2.5%), a tile's q and dO handed from its
 #: products to its chain instead of read again (0.0%). One rule is within
-#: 0.1% of the best of each shape: the forward's.
+#: 0.1% of the best of each shape: the forward's. Since PR 60 the short
+#: shapes take this kernel too (522.9 us a call at ``[8, 1024, 16 x 64]``
+#: where the unrolled dq + dk/dv took 357.4 + 473.4: ``SHORT_BLOCK``'s table).
 _BWD_TILE = 128
 
 
@@ -1300,10 +1299,10 @@ def _bwd_tiles(block_q: int, block_k: int,
 def _bwd_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
     dq_sum_ref, dk_sum_ref, dv_sum_ref, *, head_dim: int, value_dim: int,
-    block_q: int, causal: bool, scale: float, offset: int,
+    block_q: int, n_k: int, causal: bool, scale: float, offset: int,
     window: Optional[int], mask: Optional[BlockDiffusion] = None,
 ):
-    """The looped backward, one K-block a grid cell: dq, dk and dv from ONE
+    """The backward, one K-block a grid cell: dq, dk and dv from ONE
     score tile, ``exp``, ``dP`` and ``delta``, a block pair walked in tiles
     (``_BWD_TILE``) as the looped forward's. Under the block mask a K-block
     walks the Q-blocks that see it (:func:`_bd_q_blocks`), a pair on one of
@@ -1318,7 +1317,8 @@ def _bwd_kernel(
     heads = _head_cols(lanes, d)
     v_heads = _head_cols(dv_ref.shape[1], value_dim)
     n_q = q_ref.shape[0] // block_q
-    kb = pl.program_id(2)
+    # the K-block's place: a Python number where it is the only one
+    kb = 0 if n_k == 1 else pl.program_id(2)
     k_start = kb * block_k
     sub_k, sub_q, ahead = _bwd_tiles(block_q, block_k, len(heads))
     # whether the diagonal crosses a masked pair at a place known here: a
@@ -1326,7 +1326,7 @@ def _bwd_kernel(
     diagonal = (window is None and block_q == block_k
                 and offset % block_k == 0)
 
-    @pl.when(kb == 0)
+    @functools.partial(_when, kb == 0)
     def _():
         # rows no key sees (s_q > s_k) are never added to: they stay zero
         dq_sum_ref[...] = jnp.zeros_like(dq_sum_ref)
@@ -1361,7 +1361,7 @@ def _bwd_kernel(
         deltas, sums = {}, {}
 
         def rows_at(qj):
-            return pl.ds(pl.multiple_of(q_start + qj, sub_q), sub_q)
+            return pl.ds(_aligned(q_start + qj, sub_q), sub_q)
 
         def products(step):
             ki, g, qj = step
@@ -1407,11 +1407,11 @@ def _bwd_kernel(
         return carry
 
     if mask is not None:
-        _bd_q_blocks(pair, None, kb, mask=mask, side=block_q, unroll=False)
+        _bd_q_blocks(pair, None, kb, mask=mask, side=block_q)
     else:
         _over_q_blocks(
             pair, None, k_start, block_q=block_q, block_k=block_k, n_q=n_q,
-            offset=offset, causal=causal, unroll=False, window=window)
+            offset=offset, causal=causal, window=window)
     # q and k entered the products unscaled (the scale sat on k or the
     # scores).
     dk_ref[...] = (_side_by_side([dk_sum_ref[g] for g in range(len(heads))], 1)
@@ -1419,131 +1419,14 @@ def _bwd_kernel(
     dv_ref[...] = _side_by_side([dv_sum_ref[g] for g in range(len(heads))], 1
                                 ).astype(dv_ref.dtype)
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    @functools.partial(
+        _when, kb == (0 if n_k == 1 else pl.num_programs(2) - 1))
     def _():
         def finish(qb, _):
             rows = pl.ds(_block_start(qb, block_q), block_q)
             dq_ref[rows, :] = (dq_sum_ref[qb] * scale).T.astype(dq_ref.dtype)
 
-        jax.lax.fori_loop(0, n_q, finish, None)
-
-
-def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-    *, head_dim: int, value_dim: int, block_q: int, block_k: int,
-    causal: bool, scale: float, offset: int, window: Optional[int],
-    mask: Optional[BlockDiffusion] = None,
-):
-    # the unrolled side: a grid cell takes the whole sequence of its heads;
-    # lse_ref: [cell heads, n_q, 1, block_q]
-    s_q, lanes = q_ref.shape
-    d, dv = head_dim, value_dim
-    heads = _head_cols(lanes, d)
-    v_heads = _head_cols(v_ref.shape[1], dv)
-    n_k = k_ref.shape[0] // block_k
-    for j in range(s_q // block_q):
-        rows = slice(j * block_q, (j + 1) * block_q)
-        q_start = j * block_q
-        qs = [_fold_scale(q_ref[rows, cols], scale) for cols in heads]
-        dos = [do_ref[rows, cols] for cols in v_heads]
-        lses = [lse_ref[g, j] for g in range(len(heads))]
-        deltas = _delta_rows(do_ref[rows, :], o_ref[rows, :], dv)
-
-        def body(kb, dq_ts, *, masked):
-            crossed = _bd_pair(mask, masked, block_k, block_q)
-            if crossed is None:  # one mask block a kernel block: dead
-                return dq_ts
-            k_start = kb * block_k
-            kt_all = k_ref[pl.ds(k_start, block_k), :].T  # [lanes, block_k]
-            out = []
-            for cols, v_cols, (q, s_scale), do, lse, delta, dq_t in zip(
-                    heads, v_heads, qs, dos, lses, deltas, dq_ts):
-                k = k_ref[pl.ds(k_start, block_k), cols]
-                v = v_ref[pl.ds(k_start, block_k), v_cols]
-                st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start
-                               if masked and mask is None else None, window)
-                if crossed:
-                    st = _bd_mask(st, masked, mask.block, 0, 0)
-                pt = jnp.exp(st - lse)
-                dst = pt * (_dot(v, do, _NT) - delta)
-                out.append(dq_t + _dot(kt_all[cols], dst.astype(k.dtype), _NN))
-            return tuple(out)
-
-        dq_ts = tuple(jnp.zeros((d, block_q), jnp.float32) for _ in heads)
-        if mask is not None:
-            dq_ts = _bd_k_blocks(body, dq_ts, j, mask=mask, side=block_q,
-                                 unroll=True)
-        else:
-            dq_ts = _over_k_blocks(
-                body, dq_ts, q_start, block_q=block_q, block_k=block_k,
-                n_k=n_k, offset=offset, causal=causal, unroll=True,
-                window=window)
-        dq_ref[rows, :] = (_side_by_side(list(dq_ts), 0) * scale
-                           ).T.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
-    *, head_dim: int, value_dim: int, block_q: int, block_k: int,
-    causal: bool, scale: float, offset: int, window: Optional[int],
-    mask: Optional[BlockDiffusion] = None,
-):
-    # the unrolled side, as the dq kernel
-    s_k, lanes = dk_ref.shape
-    d = head_dim
-    heads = _head_cols(lanes, d)
-    v_heads = _head_cols(dv_ref.shape[1], value_dim)
-    n_q = q_ref.shape[0] // block_q
-    stats = {}  # a Q-block's lse rows and deltas, read once a cell
-    for j in range(s_k // block_k):
-        rows = slice(j * block_k, (j + 1) * block_k)
-        k_start = j * block_k
-        ks = [_fold_scale(k_ref[rows, cols], scale) for cols in heads]
-        vs = [v_ref[rows, cols] for cols in v_heads]
-
-        def body(qb, carry, *, masked):
-            crossed = _bd_pair(mask, masked, block_k, block_q)
-            if crossed is None:  # one mask block a kernel block: dead
-                return carry
-            q_start = qb * block_q
-            q_rows = pl.ds(q_start, block_q)
-            if qb not in stats:
-                stats[qb] = ([lse_ref[g, qb] for g in range(len(heads))],
-                             _delta_rows(do_ref[q_rows, :], o_ref[q_rows, :],
-                                         value_dim))
-            out = []
-            for cols, v_cols, (k, s_scale), v, lse, delta, (dk, dv) in zip(
-                    heads, v_heads, ks, vs, *stats[qb], carry):
-                q = q_ref[q_rows, cols]
-                do = do_ref[q_rows, v_cols]
-                st = _scores_t(k, q, s_scale,
-                               q_start + offset - k_start
-                               if masked and mask is None else None, window)
-                if crossed:
-                    st = _bd_mask(st, masked, mask.block, 0, 0)
-                pt = jnp.exp(st - lse)
-                dv_new = dv + _dot(pt.astype(do.dtype), do, _NN)
-                dst = pt * (_dot(v, do, _NT) - delta)
-                out.append((dk + _dot(dst.astype(q.dtype), q, _NN), dv_new))
-            return tuple(out)
-
-        carry = tuple((jnp.zeros((block_k, d), jnp.float32),
-                       jnp.zeros((block_k, value_dim), jnp.float32))
-                      for _ in heads)
-        if mask is not None:
-            carry = _bd_q_blocks(body, carry, j, mask=mask, side=block_k,
-                                 unroll=True)
-        else:
-            carry = _over_q_blocks(
-                body, carry, k_start, block_q=block_q, block_k=block_k,
-                n_q=n_q, offset=offset, causal=causal, unroll=True,
-                window=window)
-        # q entered the products unscaled (the scale sat on k or the scores).
-        dk_ref[rows, :] = (_side_by_side([dk for dk, _ in carry], 1) * scale
-                           ).astype(dk_ref.dtype)
-        dv_ref[rows, :] = _side_by_side([dv for _, dv in carry], 1
-                                        ).astype(dv_ref.dtype)
+        _loop(0, n_q, finish, None)
 
 
 #: what the compiler allows a kernel of VMEM where the call names no limit:
@@ -1557,14 +1440,17 @@ _TILES_VMEM = 4 << 20
 
 def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
               scale: float, block_q: int, block_k: int, interpret: bool,
-              window: Optional[int], mask: Optional[BlockDiffusion]):
-    """The looped backward: ONE call on dkv's grid gives all three — dq
-    summed in VMEM across the K-block axis, which is therefore sequential."""
+              window: Optional[int] = None,
+              mask: Optional[BlockDiffusion] = None):
+    """The backward (``_bwd``: a ``jax.jit`` of its own, as the forward's):
+    ONE call on the grid ``(batch row, lane block, K-block)`` gives all
+    three — dq summed in VMEM across the K-block axis, which is therefore
+    sequential."""
     b, s_q, _ = q.shape
     s_k, (d, vd, _) = k.shape[1], _head_sizes(q, k, v, heads)
     item = q.dtype.itemsize
     n_q, n_k = s_q // block_q, s_k // block_k
-    cell = _cell_heads(heads, d, 0, False, 0, vd)
+    cell = _cell_heads(heads, d, vd, _one_pair(s_q, s_k, block_q, block_k))
     k, v, shared = _shared(q, k, v, heads, cell)
 
     def whole(size):
@@ -1581,7 +1467,7 @@ def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
             + cell * (d * s_q + (d + vd) * block_k) * 4)
     return pl.pallas_call(
         functools.partial(
-            _bwd_kernel, head_dim=d, value_dim=vd, block_q=block_q,
+            _bwd_kernel, head_dim=d, value_dim=vd, block_q=block_q, n_k=n_k,
             causal=causal, scale=scale, offset=s_k - s_q, window=window,
             mask=mask),
         grid=(b, pl.cdiv(heads, cell), n_k),
@@ -1605,81 +1491,9 @@ def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
     )(q, k, v, out, do, lse_in)
 
 
-_bwd_looped = jax.jit(_bwd_call, static_argnames=(
+_bwd = jax.jit(_bwd_call, static_argnames=(
     "heads", "causal", "scale", "block_q", "block_k", "interpret", "window",
     "mask"))
-
-
-def _bwd(
-    q, k, v, out, lse, do, *, heads: int, causal: bool, scale: float,
-    dq_blocks: Tuple[int, int], dkv_blocks: Tuple[int, int], interpret: bool,
-    window: Optional[int] = None, mask: Optional[BlockDiffusion] = None,
-):
-    b, s_q, _ = q.shape
-    s_k, (d, vd, _) = k.shape[1], _head_sizes(q, k, v, heads)
-    item = q.dtype.itemsize
-    block_q, block_k = dkv_blocks
-    if not _unrolled(s_q // block_q, s_k // block_k):
-        return _bwd_looped(
-            q, k, v, out, lse, do, heads=heads, causal=causal, scale=scale,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            window=window, mask=mask)
-    static = dict(head_dim=d, value_dim=vd, causal=causal, scale=scale,
-                  offset=s_k - s_q, window=window, mask=mask)
-
-    def whole(s, cell, size):
-        return pl.BlockSpec((None, s, cell * size), lambda b, h, i: (b, 0, h))
-
-    # unrolled (at most _UNROLL_PAIRS block pairs a head): one grid cell a
-    # head cell takes the whole sequence, no axis carries a sum — two kernels
-    def mine(s, cell, size):
-        return pl.BlockSpec((None, s, cell * size), lambda b, h, i: (b, i, h))
-
-    block_q, block_k = dq_blocks
-    n_q, n_k = s_q // block_q, s_k // block_k
-    assert _unrolled(n_q, n_k), (dq_blocks, dkv_blocks)  # dq's are no smaller
-    cell = _cell_heads(heads, d, n_q * n_k, True,
-                       (2 * s_q + s_k) * (d + vd) * item + s_q * 8 * 4,
-                       vd)  # q dq k at d, o do v at vd, lse
-    lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
-    k_in, v_in, shared = _shared(q, k, v, heads, cell)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          **static),
-        grid=(b, pl.cdiv(heads, cell), 1),
-        in_specs=[
-            mine(s_q, cell, d), shared(whole(s_k, cell, d)),
-            shared(whole(s_k, cell, vd)),
-            mine(s_q, cell, vd), mine(s_q, cell, vd), lse_spec,
-        ],
-        out_specs=mine(s_q, cell, d),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        **_call_name("bwd_dq", d, vd, window, mask=mask),
-    )(q, k_in, v_in, out, do, lse_in)
-
-    block_q, block_k = dkv_blocks
-    cell = _cell_heads(heads, d, (s_q // block_q) * (s_k // block_k), True,
-                       ((s_q + 2 * s_k) * d + 2 * (s_q + s_k) * vd) * item
-                       + s_q * 8 * 4, vd)  # q k dk at d, o do v dv at vd, lse
-    lse_spec, lse_in = _lse_operand(lse, cell, block_q, whole=True)
-    k_in, v_in, shared = _shared(q, k, v, heads, cell)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                          **static),
-        grid=(b, pl.cdiv(heads, cell), 1),
-        in_specs=[whole(s_q, cell, d), shared(mine(s_k, cell, d)),
-                  shared(mine(s_k, cell, vd)),
-                  whole(s_q, cell, vd), whole(s_q, cell, vd), lse_spec],
-        out_specs=[mine(s_k, cell, d), mine(s_k, cell, vd)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s_k, heads * d), k.dtype),
-            jax.ShapeDtypeStruct((b, s_k, heads * vd), v.dtype),
-        ],
-        interpret=interpret,
-        **_call_name("bwd_dkv", d, vd, window, mask=mask),
-    )(q, k_in, v_in, out, do, lse_in)
-    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -1948,7 +1762,7 @@ def _band_fwd(q, k, v, *, heads: int, scale: float, band: Band, window: int,
               interpret: bool):
     b, s, _ = q.shape
     d, dv, _ = _head_sizes(q, k, v, heads)
-    cell = _cell_heads(heads, d, 0, False, 0, dv)
+    cell = _cell_heads(heads, d, dv)
     k, v, shared = _shared(q, k, v, heads, cell)
     own, prev, _ = _band_specs(band, cell * d, s)
     own_v, prev_v, _ = _band_specs(band, cell * dv, s)
@@ -1977,7 +1791,7 @@ def _band_bwd(q, k, v, out, lse, do, *, heads: int, scale: float,
               dq_band: Band, dkv_band: Band, window: int, interpret: bool):
     b, s, _ = q.shape
     d, dv, _ = _head_sizes(q, k, v, heads)
-    cell = _cell_heads(heads, d, 0, False, 0, dv)
+    cell = _cell_heads(heads, d, dv)
     k, v, shared = _shared(q, k, v, heads, cell)
     static = dict(head_dim=d, value_dim=dv, window=window, scale=scale)
 
@@ -2068,7 +1882,7 @@ def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, mask,
     else:
         dq, dk, dv = _bwd(
             q, k, v, out, lse, g, heads=heads, causal=causal, scale=scale,
-            dq_blocks=blocks[1], dkv_blocks=blocks[2], interpret=interpret,
+            block_q=blocks[2][0], block_k=blocks[2][1], interpret=interpret,
             window=window, mask=mask,
         )
     ratio = _head_sizes(q, k, v, heads)[2]
@@ -2124,7 +1938,7 @@ def flash_attention(
     mask, which is neither causal nor a window. Block pairs no row of which
     sees a key are not visited, a tile of 128 x 128 wholly dead inside a
     visited pair is not computed, and the calls carry names of their own
-    (``bd_fwd``, ``bd_bwd``; unrolled ``bd_bwd_dq``, ``bd_bwd_dkv``).
+    (``bd_fwd``, ``bd_bwd``).
 
     ``block_q`` / ``block_k``, when passed, hold for every kernel; left out,
     each kernel's are chosen from what the call shows."""
@@ -2161,15 +1975,15 @@ def flash_attention(
                f" that is whole blocks of {mask} over 2 x {mask.seam} rows"))
     device = jax.devices()[0]
     how = "INTERPRETED" if interpret else "compiled"
-    cuts = dict(zip(("fwd", "dq", "dkv"), blocks))
-    if not isinstance(blocks[2], Band) and not _unrolled(
-            s // blocks[2][0], s_k // blocks[2][1]):
+    cuts, tile = dict(zip(("fwd", "dq", "dkv"), blocks)), _cell_heads(h, d, dv)
+    if not isinstance(blocks[2], Band):
         cuts = {"fwd": blocks[0], "bwd": blocks[2]}  # what _bwd will call
-    tile = _cell_heads(h, d, 0, False, 0, dv)  # before short sequences widen it
+        # what _fwd_call and _bwd_call will take: wider where a head is one
+        # short pair
+        tile = _cell_heads(h, d, dv, _one_pair(s, s_k, *blocks[0])
+                           if blocks[0] == blocks[2] else None)
 
     def walk(name, cut):
-        if _unrolled(s // cut[0], s_k // cut[1]):
-            return "unrolled"
         sub_k, sub_q, ahead = (_fwd_tiles if name == "fwd" else _bwd_tiles)(
             *cut, tile)
         if (sub_q, sub_k) == cut:
@@ -2190,9 +2004,7 @@ def flash_attention(
     shared = "" if ratio == 1 else (
         f"; k, v of {kv_heads} head(s), {ratio} query heads each, "
         + ("read by index" if repeat == 1 else
-           f"repeated {repeat}-fold to a cell's heads")
-        + (" (as far as a wider cell's where a short sequence widens it)"
-           if "unrolled" in chosen and repeat < ratio else ""))
+           f"repeated {repeat}-fold to a cell's heads"))
     log_once(log, f"flash attention: {how} Pallas kernel on "
                   f"{device.platform} ({device.device_kind}), "
                   f"{jnp.dtype(q.dtype).name} operands to the MXU, blocks "

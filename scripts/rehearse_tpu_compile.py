@@ -182,12 +182,14 @@ PROGRAMS = {
 #: (the first microbatch, then the scan) hold it twice each under ``dots``;
 #: under ``full`` ZAYA1's six scanned layers hold it ONCE and JoyAI-LLM-Flash's
 #: four scanned layers once beside the dense layer's two (PR 38: 2 and 4 if
-#: the scanned run made it again). ``flash_bwd`` / ``mla_bwd`` is the looped
-#: backward as ONE call (PR 39); ``*_bwd_dq`` + ``*_bwd_dkv`` the unrolled one.
+#: the scanned run made it again). ``flash_bwd`` / ``mla_bwd`` is the backward
+#: as ONE call (PR 39; the GPT-2 programs' too since PR 60, which took the
+#: unrolled ``flash_bwd_dq`` + ``flash_bwd_dkv`` away); ``swa_bwd_dq`` +
+#: ``swa_bwd_dkv`` are the band path's.
 ATTENTION_KERNELS = {
-    "medium_4x8": {"flash_bwd_dkv": 2, "flash_bwd_dq": 2, "flash_fwd": 4},
-    "worker_4x8": {"flash_bwd_dkv": 2, "flash_bwd_dq": 2, "flash_fwd": 4},
-    "xl_fsdp4": {"flash_bwd_dkv": 1, "flash_bwd_dq": 1, "flash_fwd": 2},
+    "medium_4x8": {"flash_bwd": 2, "flash_fwd": 4},
+    "worker_4x8": {"flash_bwd": 2, "flash_fwd": 4},
+    "xl_fsdp4": {"flash_bwd": 1, "flash_fwd": 2},
     "hybrid_4x2": {"flash_bwd": 2, "flash_fwd": 2},
     "ouro_4x1": {"flash_bwd": 2, "flash_fwd": 4},
     "laguna_1x2": {"flash_bwd": 2, "flash_fwd": 2, "swa_bwd_dkv": 1,

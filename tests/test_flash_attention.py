@@ -104,7 +104,6 @@ def test_the_looped_forwards_out_and_lse_against_float32(case):
     q, k, v = normal(7, (1, s_q, heads, d), (1, s_k, heads, d),
                      (1, s_k, heads, dv), dtype=dtype)
     blocks = choose_blocks(s_q, s_k, causal, block_q, block_k)[0]
-    assert not flash_module._unrolled(s_q // blocks[0], s_k // blocks[1])
     assert flash_module._fwd_tiles(*blocks, 1)[:2] == (128, 128)
 
     def forward(q, k, v):
@@ -159,16 +158,17 @@ def _pallas_calls(jaxpr):
     (128, 128, None, 1280, 1280, "flash_fwd", "looped in tiles of 128/128, 2 behind"),
     (192, 128, None, 1280, 1280, "mla_fwd", "looped in tiles of 128/128, 4 behind"),
     (128, 128, 384, 1024, 1280, "swa_fwd", "looped in tiles of 128/128, 2 behind"),
-    (128, 128, None, 1024, 1024, "flash_fwd", "unrolled"),
-    (192, 128, None, 1024, 1024, "mla_fwd", "unrolled"),
+    (128, 128, None, 1024, 1024, "flash_fwd", "looped in tiles of 128/128, 2 behind"),
+    (192, 128, None, 1024, 1024, "mla_fwd", "looped in tiles of 128/128, 4 behind"),
 ], ids=["flash", "mla", "swa-rectangle", "flash-16-pairs", "mla-16-pairs"])
 def test_what_the_benchmarks_readers_tell_the_forward_by(
         monkeypatch, d, dv, window, s_q, s_k, name, walk):
     """``benchmark/lib/hlo.flash_calls`` tells the forward by its name and
     its two results' types: ``[B, S, H·dv]`` of the operands' dtype and a
-    float32 ``[B, H, S, 1]`` — the looped call's as the unrolled one's. A
-    problem of at most 16 block pairs a head keeps the unrolled body, with
-    no scratch; the logged line says which."""
+    float32 ``[B, H, S, 1]``. A problem of at most 16 block pairs a head is
+    the same call as a longer one (until PR 60 it was an unrolled body with
+    no scratch): three scratch operands, and the logged line says the
+    tiles."""
     heads = 2
     q, k, v = normal(1, (1, s_q, heads, d), (1, s_k, heads, d),
                      (1, s_k, heads, dv), dtype="bfloat16")
@@ -182,7 +182,7 @@ def test_what_the_benchmarks_readers_tell_the_forward_by(
         ((1, s_q, heads * dv), jnp.bfloat16),
         ((1, heads, s_q, 1), jnp.float32)]
     scratch = call.params["grid_mapping"].num_scratch_operands
-    assert scratch == (0 if walk == "unrolled" else 3)
+    assert scratch == 3
     line, = records
     assert f"blocks q/k fwd 256/256 {walk}, " in line, line
 
@@ -286,8 +286,12 @@ def test_each_drop_from_the_kernel_is_logged_once_with_its_reason(
 
 
 @pytest.mark.parametrize("s,window,block,h,d,said", [
-    (64, None, 32, 1, 32,
-     "fwd 32/32 unrolled, dq 32/32 unrolled, dkv 32/32 unrolled,"),
+    (64, None, 32, 1, 32, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
+    # gpt2-medium's call: a 1,024-long head is ONE block pair a grid cell,
+    # walked in the same tiles
+    (1024, None, None, 2, 64,
+     "fwd 1024/1024 looped in tiles of 128/128, 64 behind, "
+     "bwd 1024/1024 looped in tiles of 128/128, 64 behind, one kernel,"),
     (256, None, 32, 1, 32, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
     (256, 80, 32, 1, 32, "fwd 32/32 looped, bwd 32/32 looped, one kernel,"),
     (256, 32, 32, 1, 32,
@@ -301,13 +305,14 @@ def test_each_drop_from_the_kernel_is_logged_once_with_its_reason(
     (4096, None, None, 2, 64,
      "fwd 512/512 looped in tiles of 128/128, 16 behind, "
      "bwd 512/512 looped in tiles of 128/128, 16 behind, one kernel,"),
-], ids=["unrolled", "looped", "looped-window", "band", "looped-in-tiles",
-        "looped-in-tiles-two-heads-of-64"])
+], ids=["four-pairs", "medium-one-pair", "looped", "looped-window", "band",
+        "looped-in-tiles", "looped-in-tiles-two-heads-of-64"])
 def test_the_logged_line_says_how_many_kernels_the_backward_is(
         monkeypatch, s, window, block, h, d, said):
     """The engagement is static: the line a process logs once for a call's
-    shape says ``one kernel`` where (and only where) the backward is, and
-    the tiles and the depth a looped kernel walks its pair in."""
+    shape says ``one kernel`` where (and only where) the backward is — every
+    call but the band path's — and the tiles and the depth a kernel walks
+    its pair in."""
     q, k, v = qkv(1, b=1, s=s, h=h, d=d)
     with _flash_log(monkeypatch) as records:
         jax.eval_shape(functools.partial(
@@ -317,28 +322,33 @@ def test_the_logged_line_says_how_many_kernels_the_backward_is(
     assert f"blocks q/k {said} over lengths" in line, line
 
 
-@pytest.mark.parametrize("heads,d,pairs,head_bytes,cell", [
-    (16, 64, 4, 6 * 1024 * 128, 2),   # gpt2-medium: two heads fill 128 lanes
-    (25, 64, 4, 6 * 1024 * 128, 2),   # gpt2-xl's shard: 13 cells, the last half outside
-    (12, 64, 1, 6 * 128 * 128, 4),    # BERT at 128: one pair a head
-    (12, 64, 2, 6 * 256 * 128, 2),    # two pairs a head
-    (6, 64, 1, 6 * 512 * 128, 2),     # three tiles: what divides them
-    (16, 64, 1, 6 * 2048 * 256, 2),   # VMEM: float32 [2048, 256] x 6 is the limit
-    (8, 128, 4, 6 * 1024 * 256, 1),   # head_dim 128: a head is a tile
-    (8, 32, 4, 6 * 1024 * 64, 4),     # head_dim 32: four heads a tile
-    (2, 16, 1, 6 * 64 * 32, 2),       # narrower than a tile: the whole width
-    (5, 80, 4, 6 * 1024 * 160, 5),    # lcm(80, 128) = 640 lanes > 400: the whole width
-], ids=["medium", "xl-shard", "bert-128", "two-pairs", "six-heads", "float32",
-        "head-dim-128", "head-dim-32", "narrow", "head-dim-80"])
-def test_heads_a_grid_cell(heads, d, pairs, head_bytes, cell):
-    from easydl_tpu.ops.flash_attention import _cell_heads
-
-    assert _cell_heads(heads, d, pairs, True, head_bytes) == cell
-    # looped at run time: the tile's heads, never more
+@pytest.mark.parametrize("heads,d,pair,cell", [
+    (16, 64, (1024, 1024), 2),  # gpt2-medium: two heads fill 128 lanes
+    (25, 64, (1024, 1024), 2),  # gpt2-xl's shard: 13 cells, the last half outside
+    (12, 64, (128, 128), 4),    # BERT at 128: ONE short pair a head, a wider cell
+    (12, 64, (512, 512), 4),    # and at 512
+    (12, 64, None, 2),          # more pairs than one a head
+    (6, 64, (512, 512), 2),     # three tiles: what divides them
+    (25, 64, (512, 512), 2),    # heads the tile does not divide: no wider
+    (8, 128, (512, 512), 2),    # head_dim 128: a head is a tile, 256 lanes two
+    (8, 128, (1024, 512), 1),   # over a block a side: not short
+    (8, 32, (128, 128), 8),     # head_dim 32: four heads a tile, 256 lanes eight
+    (2, 16, (64, 64), 2),       # narrower than a tile: the whole width
+    (5, 80, (512, 512), 5),     # lcm(80, 128) = 640 lanes > 400: the whole width
+], ids=["medium", "xl-shard", "bert-128", "bert-512", "two-pairs", "six-heads",
+        "odd-heads", "head-dim-128", "long-pair", "head-dim-32", "narrow",
+        "head-dim-80"])
+def test_heads_a_grid_cell(heads, d, pair, cell):
+    """``_cell_heads``: the heads that fill whole lane tiles, and twice that
+    where a head's whole problem is one pair of at most 512 rows a side
+    (``_SHORT``'s table has the measurements)."""
     import math
 
-    assert _cell_heads(heads, d, pairs, False, head_bytes) \
-        == min(heads, math.lcm(d, 128) // d)
+    from easydl_tpu.ops.flash_attention import _cell_heads
+
+    assert _cell_heads(heads, d, None, pair) == cell
+    # more pairs than one a head: the tile's heads, never more
+    assert _cell_heads(heads, d) == min(heads, math.lcm(d, 128) // d)
 
 
 def test_inside_jitted_train_step(monkeypatch):

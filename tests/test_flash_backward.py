@@ -1,7 +1,7 @@
 """The flash kernels' backward (custom VJP) against the XLA reference path's
 gradients: causal and bidirectional, s_q != s_k either way and dead rows,
-block_q != block_k, the unrolled kernels and the looped ONE kernel, bf16
-operands. Interpreted on the CPU; a case is the kernels' forward and
+block_q != block_k, few block pairs a head and many on the ONE kernel, bf16
+operands, gpt2-medium's, gpt2-xl's and BERT's shapes. Interpreted on the CPU; a case is the kernels' forward and
 gradients as ONE jitted program and the reference's as one more, on inputs
 drawn on the host (``conftest.out_and_grads``, ``conftest.normal``)."""
 
@@ -97,7 +97,7 @@ def test_causal_cross_length_sq_gt_sk_dead_rows():
 def test_causal_rectangular_blocks_forward_and_grads(block_q, block_k, s_q, s_k):
     """block_q != block_k, both ways, square and with a non-zero offset
     either way: the unmasked loop, the masked loop and the boundary between
-    them all run (8 block pairs: unrolled), and dead rows where s_q > s_k."""
+    them all run (8 block pairs a head), and dead rows where s_q > s_k."""
     b, h, d = 1, 2, 16
     q, k, v = normal(7, (b, s_q, h, d), (b, s_k, h, d), (b, s_k, h, d))
     (out, g_flash), (ref, g_ref) = flash_and_reference(
@@ -125,9 +125,9 @@ def test_causal_rectangular_blocks_forward_and_grads(block_q, block_k, s_q, s_k)
          "one-tile-a-block-of-192"])
 def test_looped_walk_matches_reference(s_q, s_k, block_q, block_k, heads, d,
                                        causal):
-    """More block pairs than ``_UNROLL_PAIRS``: one Q-block (K-block) a grid
-    cell, block indices known only at run time, both loops ``fori_loop``s."""
-    assert (s_q // block_q) * (s_k // block_k) > flash_module._UNROLL_PAIRS
+    """More than 16 block pairs a head: one Q-block (K-block) a grid cell,
+    block indices known only at run time, both loops ``fori_loop``s."""
+    assert (s_q // block_q) * (s_k // block_k) > 16
     q, k, v = normal(8, (1, s_q, heads, d), (1, s_k, heads, d),
                      (1, s_k, heads, d))
     (out, g_flash), (ref, g_ref) = flash_and_reference(
@@ -139,9 +139,8 @@ def test_looped_walk_matches_reference(s_q, s_k, block_q, block_k, heads, d,
 
 ONE_KERNEL = {
     # s_q, s_k, heads, d, dv, block_q, block_k, causal, window: every one
-    # more block pairs a head than ``_UNROLL_PAIRS``. (Tried at 25 pairs a
-    # head, PR 40: the split kernels then leave 4e-8 where the one kernel
-    # gives a dq of exactly zero, in three cases; the shapes stay.)
+    # more than 16 block pairs a head (until PR 60 the side of the rule that
+    # made the backward one kernel).
     "causal-square": (256, 256, 2, 32, 32, 32, 32, True, None),
     "sq<sk": (128, 256, 2, 32, 32, 16, 64, True, None),
     "sq>sk-dead-rows": (256, 128, 2, 32, 32, 64, 16, True, None),
@@ -179,15 +178,12 @@ def _traced_and_run(fn, *args):
 
 
 @pytest.mark.parametrize("case", ONE_KERNEL)
-def test_the_looped_backward_is_one_kernel(case, monkeypatch):
-    """Where the backward is looped ONE call gives dq, dk and dv: against
-    the reference's gradients on float32 operands; rows no key sees get a dq
-    of exactly zero; and on bf16 operands (where both put the softmax scale
-    in the same place) all three to the bit what the split dq and dkv
-    kernels give on the same inputs and blocks — reached by calling this
-    shape unrolled, which only a test can ask for."""
+def test_the_looped_backward_is_one_kernel(case):
+    """ONE call gives dq, dk and dv: against the reference's gradients on
+    float32 operands; rows no key sees get a dq of exactly zero. (The split
+    dq and dkv kernels it was held to bit for bit on bf16 operands went with
+    PR 60: nothing calls them.)"""
     s_q, s_k, heads, d, dv, block_q, block_k, causal, window = ONE_KERNEL[case]
-    assert not flash_module._unrolled(s_q // block_q, s_k // block_k)
     q, k, v, g = normal(12, (1, s_q, heads, d), (1, s_k, heads, d),
                         (1, s_k, heads, dv), (1, s_q, heads, dv))
     flash = functools.partial(flash_attention, causal=causal, block_q=block_q,
@@ -206,17 +202,6 @@ def test_the_looped_backward_is_one_kernel(case, monkeypatch):
                        atol=1e-4, rtol=1e-4)
     dead = max(s_q - s_k, 0) if causal else 0
     assert not np.asarray(got[0][:, :dead]).any()
-
-    low = [x.astype(jnp.bfloat16) for x in (q, k, v)]
-    one = jax.jit(functools.partial(grads, flash))(*low)
-    monkeypatch.setattr(flash_module, "_UNROLL_PAIRS", 1 << 20)
-    # a program traced anew: the constant is read when the kernels are
-    calls, split = _traced_and_run(functools.partial(grads, flash), *low)
-    assert calls == {f"{kind}_bwd_dq": 1, f"{kind}_bwd_dkv": 2}
-    for mine, theirs, name in zip(one, split, "qkv"):
-        np.testing.assert_array_equal(
-            np.asarray(mine, np.float32), np.asarray(theirs, np.float32),
-            err_msg=f"d{name}")
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -254,23 +239,21 @@ def _kernel_dots(fn, *args):
     return found
 
 
-@pytest.mark.parametrize("looped", [False, True], ids=["unrolled", "looped"])
+@pytest.mark.parametrize("rows", [64, 256], ids=["4-pairs", "64-pairs"])
 @pytest.mark.parametrize("dtype,other", [("bfloat16", "float32"),
                                          ("float32", "bfloat16")])
-def test_matmul_operands_follow_the_input_dtype(dtype, other, looped):
+def test_matmul_operands_follow_the_input_dtype(dtype, other, rows):
     """Walk the kernel jaxprs inside the ``pallas_call``s: with bf16
     inputs no ``dot_general`` takes a float32 operand, with float32 inputs
     none takes a bf16 one."""
-    q, k, v = normal(10, *[(1, 256 if looped else 64, 1, 32)] * 3,
-                     dtype=dtype)
+    q, k, v = normal(10, *[(1, rows, 1, 32)] * 3, dtype=dtype)
     flash = functools.partial(flash_attention, causal=True, block_q=32,
                               block_k=32, interpret=True)
     dots = _kernel_dots(jax.grad(lambda *x: cos_weighed(flash(*x)), argnums=(0, 1, 2)), q, k, v)
-    # products a block pair, in the masked and in the unmasked loop's body
-    # (unrolled: 3 live pairs a head): five where dq and dkv make seven
-    assert {n: len(found) for n, found in dots.items()} == (
-        {"flash_fwd": 4, "flash_bwd": 10} if looped else
-        {"flash_fwd": 6, "flash_bwd_dq": 9, "flash_bwd_dkv": 12})
+    # products a block pair, in the masked and in the unmasked loop's body:
+    # five in the backward (the split dq and dkv kernels made seven)
+    assert {n: len(found) for n, found in dots.items()} == {
+        "flash_fwd": 4, "flash_bwd": 10}
     for name, operands in dots.items():
         assert all(pair == (dtype, dtype) for pair in operands), (name, operands)
         assert not any(other in pair for pair in operands)
@@ -291,11 +274,18 @@ def test_matmul_operands_follow_the_input_dtype(dtype, other, looped):
     # half's own pair in its four diagonal tiles, the clean diagonals' (one
     # body under a traced strictness) in ten
     (6144, 6144, False, flash_module.BlockDiffusion(4, 3072), 80 + 20 + 50),
-], ids=["placed", "no-mask", "offset-no-static-place", "block-mask"])
+    # gpt2-medium's call: ONE pair of 1,024 x 1,024 a grid cell, its place a
+    # Python number — the masked body alone is traced, the 36 tiles on and
+    # under the diagonal of its 64
+    (1024, 1024, True, None, 36 * 5),
+    # a 2,048-long head: four pairs of 1,024, both bodies
+    (2048, 2048, True, None, (64 + 36) * 5),
+], ids=["placed", "no-mask", "offset-no-static-place", "block-mask",
+        "one-pair-of-1024", "four-pairs-of-1024"])
 def test_a_masked_pairs_dead_tiles_are_not_computed(s_q, s_k, causal, mask,
                                                     dots):
-    """The looped backward's kernel traced, not run: the products in its
-    bodies, by the tiles a pair is walked in."""
+    """The backward's kernel traced, not run: the products in its bodies, by
+    the tiles a pair is walked in."""
     q, k, v = normal(11, (1, s_q, 1, 128), (1, s_k, 1, 128),
                      (1, s_k, 1, 128), dtype="bfloat16")
     blocks = flash_module.choose_blocks(s_q, s_k, causal, mask=mask)[2]
@@ -304,3 +294,73 @@ def test_a_masked_pairs_dead_tiles_are_not_computed(s_q, s_k, causal, mask,
         lambda *x: cos_weighed(flash_attention(*x, causal=causal, mask=mask)),
         argnums=(0, 1, 2)), q, k, v)
     assert len(found["bd_bwd" if mask else "flash_bwd"]) == dots
+
+
+#: the shapes the unrolled kernels served until PR 60, a batch row of each:
+#: ``(s_q, s_k, heads, causal, blocks)`` at heads of 64
+SHORT = {
+    "gpt2-medium": (1024, 1024, 16, True, (1024, 1024)),
+    # 25 heads of 64 on 13 lane blocks, the last half outside the array
+    "gpt2-xl": (1024, 1024, 25, True, (1024, 1024)),
+    "bert-512": (512, 512, 12, False, (512, 512)),
+    "bert-128": (128, 128, 12, False, (128, 128)),
+    # a rectangle either way: the square block of the shorter side
+    "sq<sk": (512, 1024, 4, True, (512, 512)),
+    "sq>sk-dead-rows": (1024, 512, 4, True, (512, 512)),
+    "2048": (2048, 2048, 2, True, (1024, 1024)),
+}
+
+
+@pytest.mark.parametrize("case", SHORT)
+def test_the_short_shapes_against_the_reference(case):
+    """bf16 operands at the shapes' own blocks (``choose_blocks``: no block
+    named): out, dq, dk and dv against the float32 reference on the same
+    values, within what bf16 keeps; a row no key sees has an out and a dq of
+    exactly zero."""
+    s_q, s_k, heads, causal, blocks = SHORT[case]
+    assert flash_module.choose_blocks(s_q, s_k, causal) == (blocks,) * 3
+    q, k, v = normal(13, (1, s_q, heads, 64), (1, s_k, heads, 64),
+                     (1, s_k, heads, 64), dtype="bfloat16")
+    flash = functools.partial(flash_attention, causal=causal, interpret=True)
+    ref = functools.partial(_reference_attention, causal=causal,
+                            scale=64 ** -0.5)
+    out, got = out_and_grads(flash, cos_weighed)(q, k, v)
+    want_out, want = out_and_grads(ref, cos_weighed)(
+        *(np.asarray(x, np.float32) for x in (q, k, v)))
+    assert out.dtype == jnp.bfloat16
+    for g, w, name in zip((out,) + tuple(got), (want_out,) + tuple(want),
+                          ("out", "dq", "dk", "dv")):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= 3e-2 * np.abs(w).max(), name
+    dead = max(s_q - s_k, 0)
+    assert not np.asarray(out[:, :dead], np.float32).any()
+    assert not np.asarray(got[0][:, :dead], np.float32).any()
+
+
+@pytest.mark.parametrize("s_q,s_k,causal,window,blocks", [
+    (128, 128, False, None, (128, 128)),
+    (512, 512, True, None, (512, 512)),
+    (1024, 1024, True, None, (1024, 1024)),
+    (1024, 1024, False, None, (1024, 1024)),
+    (2048, 2048, True, None, (1024, 1024)),
+    # causal: square, the shorter side's, so that the diagonal passes
+    # through a pair's corner; not causal: each side's own
+    (512, 1024, True, None, (512, 512)),
+    (1024, 512, True, None, (512, 512)),
+    (512, 2048, False, None, (512, 1024)),
+    (1536, 1536, True, None, (768, 768)),
+    # longer than 2,048 either side, under a window, or not whole tiles of
+    # 128: the blocks of 512 every longer cell has
+    (4096, 4096, True, None, (512, 512)),
+    (512, 4096, True, None, (512, 512)),
+    (1024, 1024, True, 2048, (512, 512)),
+    (72, 72, True, None, (72, 72)),
+    (520, 520, True, None, None),  # no block: the XLA path's
+], ids=lambda x: str(x).replace(" ", ""))
+def test_the_blocks_are_a_function_of_the_shape(s_q, s_k, causal, window,
+                                                blocks):
+    """The rule as a table (``choose_blocks``; ``_SHORT``'s measurements):
+    lengths, causal, window -> the one block the forward and the ONE
+    backward take. No form to choose: every call is the same two kernels."""
+    assert flash_module.choose_blocks(
+        s_q, s_k, causal, window=window) == (blocks and (blocks,) * 3)

@@ -1,6 +1,6 @@
 """Block diffusion's mask through the flash kernels (interpreted on the CPU)
 against the mask WRITTEN OUT from its four rules: forward, ``lse``, dq, dk and
-dv on the unrolled and the looped side, at a block length of 4 and of 32,
+dv at few block pairs a head and at many, at a block length of 4 and of 32,
 under kernel blocks that hold many mask blocks, one, and — where a pair is
 walked in tiles of 128 — a tile that is one; that nothing leaks through the
 attention (bit for bit); the walk's block pairs against the formula and a
@@ -55,8 +55,8 @@ def test_dense_is_the_four_rules_and_pairs_the_formula(seq, block):
     assert want.diagonal().all()  # every row sees itself
 
 
-#: (tokens, block length, kernel block): the unrolled side (at most 16 block
-#: pairs) and the looped one; a kernel block of many mask blocks and of one;
+#: (tokens, block length, kernel block): at most 16 block pairs a head and
+#: more; a kernel block of many mask blocks and of one;
 #: 768 x 256 walks a pair in tiles of 128, which at a block length of 128
 #: ARE mask blocks
 CASES = [(64, 4, 32), (64, 32, 32), (128, 4, 32), (128, 32, 32),
@@ -71,8 +71,6 @@ def test_kernels_against_the_written_out_mask(seq, block, side):
     q, k, v, g = normal(seq + block, *[(1, 2 * seq, heads, d)] * 4)
     blocks = fa.choose_blocks(2 * seq, 2 * seq, False, side, side, mask=mask)
     assert blocks == ((side, side),) * 3
-    looped = not fa._unrolled(2 * seq // side, 2 * seq // side)
-    assert looped == (seq > 64)
 
     def kernels(q, k, v):
         flat = [x.reshape(1, 2 * seq, heads * d) for x in (q, k, v)]
@@ -144,16 +142,16 @@ def test_the_walk_visits_the_live_block_pairs_and_no_other(rows, side, block,
     n = seq // side
     assert mask.block_pairs(side) == (visited, 4 * n * n)
     assert visited == n * n + (n if side == block else 2 * n)
-    walked = set()
-    for qb in range(2 * n):
-        fa._bd_k_blocks(
-            lambda kb, carry, masked: walked.add((qb, kb)) or carry, None, qb,
-            mask=mask, side=side, unroll=True)
-    mirrored = set()
-    for kb in range(2 * n):
-        fa._bd_q_blocks(
-            lambda qb, carry, masked: mirrored.add((qb, kb)) or carry, None,
-            kb, mask=mask, side=side, unroll=True)
+    walked, mirrored = set(), set()
+    with jax.disable_jit():  # the loops' turns as Python's, on numbers
+        for qb in range(2 * n):
+            fa._bd_k_blocks(
+                lambda kb, carry, masked: walked.add((qb, int(kb))) or carry,
+                0, qb, mask=mask, side=side)
+        for kb in range(2 * n):
+            fa._bd_q_blocks(
+                lambda qb, carry, masked: mirrored.add((int(qb), kb)) or carry,
+                0, kb, mask=mask, side=side)
     assert walked == mirrored and len(walked) == visited
     if rows <= 256:
         dense = four_rules(seq, block).reshape(2 * n, side, 2 * n, side)

@@ -1,6 +1,6 @@
 """The flash kernels on the model's own layout, ``[batch, seq,
 heads·head_dim]`` in blocks of whole 128-lane tiles: even and odd head
-counts, rectangles on both sides of ``_UNROLL_PAIRS``, grouped queries
+counts, rectangles of few block pairs a head and of many, grouped queries
 whose shared key/value heads the kernels read by index, and nothing but
 reshapes around the ``pallas_call``s. Interpreted on the CPU, ONE jitted
 program a side and case (``conftest.out_and_grads``) on inputs drawn on the
@@ -70,13 +70,14 @@ def test_the_models_layout_even_and_odd_head_counts(heads, d, dtype):
     (64, 64, 32, 32), (64, 128, 32, 64), (128, 64, 64, 32),
     (256, 256, 32, 32), (128, 256, 16, 64), (1280, 1280, 256, 256),
     (1536, 1024, 256, 256)],
-    ids=["square", "sq<sk", "sq>sk", "looped-square", "looped-sq<sk",
-         "looped-in-tiles", "looped-in-tiles-sq>sk"])
-def test_the_models_layout_rectangular_unrolled_and_looped(
+    ids=["square", "sq<sk", "sq>sk", "64-pairs-square", "64-pairs-sq<sk",
+         "in-tiles", "in-tiles-sq>sk"])
+def test_the_models_layout_rectangular_few_pairs_and_many(
         s_q, s_k, block_q, block_k, causal, heads):
-    """Heads of 64 two to a lane block on both sides of ``_UNROLL_PAIRS``,
-    square and with an offset either way (dead rows where s_q > s_k); at
-    blocks of 256 the looped forward walks a pair in tiles of 128."""
+    """Heads of 64 two to a lane block at four block pairs a head and at 64
+    (until PR 60 the two sides of a rule: unrolled, looped), square and with
+    an offset either way (dead rows where s_q > s_k); at blocks of 256 the
+    kernels walk a pair in tiles of 128."""
     q, k, v = normal(12, (1, s_q, heads, 64), *[(1, s_k, heads, 64)] * 2)
     _flash_vs_reference(q, k, v, causal=causal, block_q=block_q,
                         block_k=block_k, atol=2e-5, rtol=2e-5, grad_tol=5e-4)
@@ -88,28 +89,29 @@ def _grouped(name, heads, kv_heads, d=64, rows=64, block=None, batch=2,
                         name.endswith("band"), id=name)
 
 
-#: the four of PR 30 at heads of 64 under the rule's own blocks (one pair: a
-#: cell widened to all the heads, so the whole ratio is repeated), then heads
-#: of 128 — one to a cell, read by index — on both sides of `_UNROLL_PAIRS`,
-#: one pair a head (three heads a cell: a shared head repeated three-fold,
-#: two cells to a block), two heads of 64 a cell under a ratio of four (the
+#: the four of PR 30 at heads of 64 under the rule's own blocks (one short
+#: pair: a cell widened to four heads where they divide the count, so the
+#: whole ratio is repeated), then heads of 128 — one to a cell, read by
+#: index — at four block pairs a head and at 25, one short pair a head (two
+#: heads a cell: a shared head repeated six-fold, the ratio a cell's heads do
+#: not divide), two heads of 64 a cell under a ratio of four (the
 #: hybrid's: repeated two-fold), the band path and the block mask
 GROUPED = [
     _grouped("4-over-2", 4, 2), _grouped("6-over-3", 6, 3),
     _grouped("6-over-1", 6, 1), _grouped("3-over-3", 3, 3),
-    _grouped("128-8-over-2-unrolled", 8, 2, 128, 64, 32, 1),
-    _grouped("128-6-over-1-unrolled", 6, 1, 128, 64, 32, 1),
+    _grouped("128-8-over-2-4-pairs", 8, 2, 128, 64, 32, 1),
+    _grouped("128-6-over-1-4-pairs", 6, 1, 128, 64, 32, 1),
     _grouped("128-6-over-1-one-pair", 6, 1, 128, 32, 32, 1),
-    _grouped("128-8-over-2-looped", 8, 2, 128, 160, 32, 1),
-    _grouped("128-6-over-1-looped", 6, 1, 128, 160, 32, 1),
-    _grouped("128-8-over-2-looped-bf16", 8, 2, 128, 160, 32, 1, "bfloat16"),
-    _grouped("64-8-over-2-looped", 8, 2, 64, 160, 32, 1),
+    _grouped("128-8-over-2-25-pairs", 8, 2, 128, 160, 32, 1),
+    _grouped("128-6-over-1-25-pairs", 6, 1, 128, 160, 32, 1),
+    _grouped("128-8-over-2-25-pairs-bf16", 8, 2, 128, 160, 32, 1, "bfloat16"),
+    _grouped("64-8-over-2-25-pairs", 8, 2, 64, 160, 32, 1),
     _grouped("128-8-over-2-band", 8, 2, 128, 192, 16, 1, window=9),
     _grouped("128-6-over-1-band", 6, 1, 128, 192, 16, 1, window=16),
     _grouped("128-8-over-2-window-looped", 8, 2, 128, 256, 16, 1, window=17),
-    _grouped("128-8-over-2-blockmask-unrolled", 8, 2, 128, 128, 32, 1,
+    _grouped("128-8-over-2-blockmask-16-pairs", 8, 2, 128, 128, 32, 1,
              mask=BlockDiffusion(4, 64)),
-    _grouped("128-6-over-1-blockmask-looped", 6, 1, 128, 256, 32, 1,
+    _grouped("128-6-over-1-blockmask-64-pairs", 6, 1, 128, 256, 32, 1,
              mask=BlockDiffusion(4, 128)),
 ]
 
@@ -199,7 +201,7 @@ def _primitives_outside_kernels(fn, *args):
 @pytest.mark.parametrize("what", ["forward", "gradient"])
 def test_no_transpose_stands_outside_the_kernels(what, heads):
     """The kernels take q, k, v, O, dO and give O, dq, dk, dv in the model's
-    own layout: around the three ``pallas_call``s ``flash_attention`` and
+    own layout: around the two ``pallas_call``s ``flash_attention`` and
     its gradient hold reshapes only — no ``transpose``, and no product of
     whole arrays either (``delta`` is formed in the kernels; the one
     ``reduce_sum`` is this test's loss)."""
@@ -210,17 +212,17 @@ def test_no_transpose_stands_outside_the_kernels(what, heads):
         lambda q, k, v: flash(q, k, v).astype(jnp.float32).sum(),
         argnums=(0, 1, 2))
     names = _primitives_outside_kernels(fn, q, k, v)
-    assert names.count("pallas_call") == (1 if what == "forward" else 3)
+    assert names.count("pallas_call") == (1 if what == "forward" else 2)
     assert "transpose" not in names, names
     assert not {"dot_general", "mul"} & set(names), names
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
 @pytest.mark.parametrize("rows,call,kernels", [
-    (1024, dict(causal=True), 2), (4096, dict(causal=True), 1),
+    (1024, dict(causal=True), 1), (4096, dict(causal=True), 1),
     (4096, dict(causal=True, window=512), 2),
     (4096, dict(mask=BlockDiffusion(4, 2048)), 1)],
-    ids=["unrolled", "looped", "band", "blockmask"])
+    ids=["one-pair", "64-pairs", "band", "blockmask"])
 def test_no_repeat_stands_in_front_of_the_kernels(what, rows, call, kernels):
     """``multihead_attention(impl="flash")`` at heads of 128, eight query
     heads over two key/value heads: every ``pallas_call`` takes k and v

@@ -20,8 +20,8 @@ from easydl_tpu.ops.flash_attention import (
 )
 
 
-# window against block: below, equal, above; square and s_q != s_k; blocks
-# that unroll (<= 16 pairs) and blocks that loop. A square problem under a
+# window against block: below, equal, above; square and s_q != s_k; few
+# block pairs a head (<= 16) and many. A square problem under a
 # window of at most a block's keys takes the band path (block_q: a
 # sub-block's rows, block_k: the neighbour's), every other the looped one.
 LOOP, BAND = "loop", "band"

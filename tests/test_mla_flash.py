@@ -1,6 +1,6 @@
 """The flash kernels at two head sizes (latent attention: scores 192 deep,
 values 128 wide), interpreted on the CPU against the plain product form:
-forward and all three gradients, causal, unrolled and looped, block edges
+forward and all three gradients, causal, few block pairs a head and many, block edges
 that fall inside the sequence, a rectangle, a head count the cell's tile
 does not divide; how many heads a cell takes; the calls' names; the public
 path through ``multihead_attention``; and q's rotation by the kernel on rows
@@ -41,8 +41,8 @@ def _operands(s_q, s_k, heads, d, dv, seed=0):
 
 CASES = {
     # s_q, s_k, heads, d, dv, block_q, block_k
-    "unrolled-24-16": (256, 256, 4, 24, 16, 64, 64),
-    "unrolled-192-128": (256, 256, 2, 192, 128, 128, 128),
+    "16-pairs-24-16": (256, 256, 4, 24, 16, 64, 64),
+    "4-pairs-192-128": (256, 256, 2, 192, 128, 128, 128),
     "looped-192-128": (640, 640, 2, 192, 128, 128, 128),   # 25 block pairs
     "looped-uneven-blocks": (768, 768, 2, 24, 16, 128, 64),
     # the forward's pair in tiles of 128 x 128, two heads a cell: eight
@@ -71,8 +71,6 @@ def test_kernels_at_two_head_sizes_against_the_plain_form(case):
     assert out.shape == (2, s_q, heads, dv)
     np.testing.assert_allclose(np.asarray(out), _plain(q, k, v, scale),
                                atol=2e-5)
-    looped = not fa._unrolled(s_q // block_q, s_k // block_k)
-    assert looped == case.startswith("looped")
     _, want = out_and_grads(functools.partial(
         attention._reference_attention, causal=True, scale=scale),
         weighed)(q, k, v)
@@ -99,15 +97,15 @@ def test_a_cell_takes_the_heads_that_fill_whole_tiles_of_both_sizes():
     cell = fa._cell_heads
     # 192 is one and a half lane tiles: two heads to a 384-lane block of q
     # and k, which are two whole tiles of v, O and dO
-    assert cell(32, 192, 0, False, 0, 128) == 2
-    assert cell(32, 192, 0, False, 0, 192) == 2
-    assert cell(32, 128, 0, False, 0, 192) == 2
+    assert cell(32, 192, 128) == 2
+    assert cell(32, 192, 192) == 2
+    assert cell(32, 128, 192) == 2
     # equal sizes, named or not: what one size gave
     for heads, d in ((16, 64), (25, 64), (8, 128), (4, 32)):
-        assert cell(heads, d, 0, False, 0, d) == cell(heads, d, 0, False, 0)
-    assert cell(16, 64, 0, False, 0) == 2 and cell(8, 128, 0, False, 0) == 1
+        assert cell(heads, d, d) == cell(heads, d)
+    assert cell(16, 64) == 2 and cell(8, 128) == 1
     # fewer heads than the tile: all of them
-    assert cell(4, 24, 0, False, 0, 16) == 4
+    assert cell(4, 24, 16) == 4
 
 
 def test_the_calls_are_named_by_what_they_compute():
@@ -124,11 +122,11 @@ def test_the_calls_are_named_by_what_they_compute():
                              f"{kind}_bwd_dkv", f"{kind}_bwd")
                 if f"name={name} " in text or f"name={name}\n" in text}
 
-    assert names(192, 128) == {"mla_fwd", "mla_bwd_dq", "mla_bwd_dkv"}
-    assert names(128, 128) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert names(192, 128) == {"mla_fwd", "mla_bwd"}
+    assert names(128, 128) == {"flash_fwd", "flash_bwd"}
     assert names(64, 64, window=32) == {"swa_fwd", "swa_bwd_dq",
                                         "swa_bwd_dkv"}
-    # looped (25 block pairs a head): the backward is one call
+    # 25 block pairs a head as one: the backward is one call
     assert names(192, 128, s=640) == {"mla_fwd", "mla_bwd"}
     assert names(128, 128, s=640) == {"flash_fwd", "flash_bwd"}
     assert names(64, 64, window=192, s=640) == {"swa_fwd", "swa_bwd"}
@@ -136,7 +134,7 @@ def test_the_calls_are_named_by_what_they_compute():
         names(192, 128, window=32)
     # values the WIDER (differential attention's pair: PR 53), which a
     # window takes too: the band path keeps its names
-    assert names(64, 128) == {"diff_fwd", "diff_bwd_dq", "diff_bwd_dkv"}
+    assert names(64, 128) == {"diff_fwd", "diff_bwd"}
     assert names(64, 128, s=640) == {"diff_fwd", "diff_bwd"}
     assert names(64, 128, window=32) == {"swa_fwd", "swa_bwd_dq",
                                          "swa_bwd_dkv"}

@@ -398,9 +398,9 @@ def test_one_call_against_four_plain_calls(window):
 
 @pytest.mark.parametrize("seq,window,block", [
     (512, 96, 128),    # the band path beside a neighbour of 128
-    (1024, None, 128),  # 64 block pairs a head: the looped kernels
-    (256, None, 64),    # 16: the unrolled pair
-], ids=["band", "looped", "unrolled"])
+    (1024, None, 128),  # 64 block pairs a head
+    (256, None, 64),    # 16
+], ids=["band", "64-pairs", "16-pairs"])
 def test_the_kernels_at_scores_half_the_values_width(seq, window, block):
     """Score heads ``d`` deep against values ``2 d`` wide through the flash
     kernels, interpreted: the result and the three gradients against the

@@ -1121,7 +1121,11 @@ def test_every_stack_lowers_to_the_text_it_had_before_the_rule_saw_room(
     parent (cb83808), where a constant picked the flash calls: with no limit
     stated — and, for the runs that kept their flash results there, held to
     that choice — every one lowers to that text: what a ``full`` block offers
-    beside them since PR 59 moves no program that does not keep it."""
+    beside them since PR 59 moves no program that does not keep it. The three
+    GPT-2 stacks' hashes were written anew by PR 60, which MEANT to change
+    their kernels (a 1,024-long head on the two looped kernels: one backward
+    call for two); the four at 4,096 and 8,192 rows are still cb83808's, which
+    is how PR 60 showed that it moved no longer cell's kernels."""
     import json
     import os
 

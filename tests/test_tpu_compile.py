@@ -69,7 +69,7 @@ def _loss(attend):
     return lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum()
 
 
-@pytest.mark.parametrize("backward,n_kernels", [(False, 1), (True, 3)],
+@pytest.mark.parametrize("backward,n_kernels", [(False, 1), (True, 2)],
                          ids=["forward", "forward+backward"])
 def test_flash_compiles_on_one_v5e_device(v5e_2x2, backward, n_kernels):
     x = jax.ShapeDtypeStruct(SHAPE, jnp.bfloat16,
@@ -87,26 +87,31 @@ def test_flash_compiles_on_one_v5e_device(v5e_2x2, backward, n_kernels):
     # [batch, seq, heads·head_dim] = [8, 1024, 1024]: gpt2-medium's call;
     # [4, 1024, 1600]: gpt2-xl's per shard under fsdp=4 — 25 heads, an odd
     # count: 13 lane blocks, the last half outside the array
-    ((8, 1024, 16, 64), ((512, 512), (512, 512), (256, 256))),
-    ((4, 1024, 25, 64), ((512, 512), (512, 512), (256, 256))),
-    # the hybrid's, key/value heads repeated: the looped side
+    # — ONE block pair of 1,024 x 1,024 a grid cell since PR 60
+    ((8, 1024, 16, 64), ((1024, 1024),) * 3),
+    ((4, 1024, 25, 64), ((1024, 1024),) * 3),
+    # gpt2-medium.reshape-resume's per shard under fsdp=2,tp=2: 8 heads
+    ((16, 1024, 8, 64), ((1024, 1024),) * 3),
+    # the hybrid's, key/value heads repeated
     ((2, 4096, 32, 64), ((512, 512), (512, 512), (512, 512))),
     # head_dim 128: one head a block, nothing to slice
-    ((8, 1024, 8, 128), ((512, 512), (512, 512), (256, 256))),
-    # Ouro's microbatches, 16 heads of 128 at 4,096: the looped side
+    ((8, 1024, 8, 128), ((1024, 1024),) * 3),
+    # a 2,048-long head: four pairs of 1,024
+    ((2, 2048, 16, 128), ((1024, 1024),) * 3),
+    # Ouro's microbatches, 16 heads of 128 at 4,096
     ((1, 4096, 16, 128), ((512, 512), (512, 512), (512, 512))),
     ((2, 4096, 16, 128), ((512, 512), (512, 512), (512, 512))),
-], ids=["8x1024x1024", "4x1024x1600", "2x4096x2048", "head-dim-128",
-        "ouro-1x4096x16x128", "ouro-2x4096x16x128"])
+], ids=["8x1024x1024", "4x1024x1600", "16x1024x512", "2x4096x2048",
+        "head-dim-128", "2x2048x2048", "ouro-1x4096x16x128",
+        "ouro-2x4096x16x128"])
 def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
-    """The (block_q, block_k) the forward, dq and dk/dv kernels choose for
-    the benchmark's calls, bf16 causal — a later change to the choice shows
-    here — and that Mosaic takes the kernels at those sizes: three on the
-    unrolled side, on the looped side the forward and ONE backward call
-    whose three results are dq, dk and dv (its dq summed in a float32
-    scratch of the whole sequence: interpret mode cannot say whether that
-    fits)."""
-    from easydl_tpu.ops.flash_attention import _unrolled, choose_blocks
+    """The (block_q, block_k) the forward and the backward choose for the
+    benchmark's calls, bf16 causal — a later change to the choice shows
+    here — and that Mosaic takes the kernels at those sizes: the forward and
+    ONE backward call whose three results are dq, dk and dv (its dq summed
+    in a float32 scratch of the whole sequence: interpret mode cannot say
+    whether that fits), at gpt2-medium's and gpt2-xl's 1,024 as at 4,096."""
+    from easydl_tpu.ops.flash_attention import choose_blocks
 
     _, seq, _, _ = shape
     assert choose_blocks(seq, seq, True) == blocks
@@ -116,9 +121,7 @@ def test_chosen_blocks_compile_at_the_benchmark_shapes(v5e_2x2, shape, blocks):
                   argnums=(0, 1, 2))
     calls = _mosaic_calls(jax.jit(fn).lower(x, x, x).compile())
     mine = f"bf16[{shape[0]},{seq},{shape[2] * shape[3]}]"
-    looped = not _unrolled(seq // blocks[2][0], seq // blocks[2][1])
-    assert sorted(c.count(mine) for c in calls) == (
-        [1, 3] if looped else [1, 1, 2]), calls
+    assert sorted(c.count(mine) for c in calls) == [1, 3], calls
 
 
 def test_the_kernels_compile_at_two_head_sizes(v5e_2x2):
@@ -216,7 +219,7 @@ def test_sharded_attention_compiles_per_shard(v5e_2x2, spec, per_device):
     with jax.set_mesh(mesh):
         compiled = jax.jit(fn).lower(x, x, x).compile()
     calls = _mosaic_calls(compiled)
-    assert len(calls) == 3, calls
+    assert len(calls) == 2, calls  # the forward; dq, dk, dv from ONE call
     assert all(per_device in c for c in calls), calls
     assert "all-gather" not in compiled.as_text()
 
@@ -257,15 +260,14 @@ def named_texts(v5e_2x2):
 
 @pytest.mark.parametrize("where", ["one_chip", "shard_map"])
 @pytest.mark.parametrize("kernel,passes", [
-    ("flash_fwd", "jvp("), ("flash_bwd_dq", "transpose(jvp("),
-    ("flash_bwd_dkv", "transpose(jvp(")])
+    ("flash_fwd", "jvp("), ("flash_bwd", "transpose(jvp(")])
 def test_kernels_are_told_by_name_in_the_compiled_text(named_texts, where,
                                                        kernel, passes):
     """Each Mosaic call's own line names its kernel, on its path (op_name)
     and as the instruction's name — no result type has to be looked at."""
     lines = [line for line in named_texts[where].splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(lines) == 3
+    assert len(lines) == 2
     mine = [line for line in lines
             if f"{kernel}/pallas_call" in line or f"({kernel})" in line]
     assert len(mine) == 1, lines
@@ -275,7 +277,7 @@ def test_kernels_are_told_by_name_in_the_compiled_text(named_texts, where,
     assert kernel in line.split(" = ", 1)[0]       # the instruction's name
     if where == "shard_map":
         assert "shard_map" in op_name
-    others = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} - {kernel}
+    others = {"flash_fwd", "flash_bwd"} - {kernel}
     assert not any(o + "/" in op_name or f"({o})" in op_name for o in others)
 
 
@@ -298,7 +300,9 @@ def test_mosaic_payload_and_the_python_call_stack(v5e_2x2, frames):
     default they carry ten frames of the Python call stack, so the same step
     traced from two callers (a fresh run vs a resumed one) never shares a
     cache entry; ``configure_compile_cache`` cuts them to one frame, and
-    then they are equal."""
+    then they are equal. (The kernels' calls are ``jax.jit``s of their own,
+    which remember a shape's trace: each caller here starts with none, as a
+    fresh process does.)"""
     import re
 
     x = jax.ShapeDtypeStruct(SHAPE, jnp.bfloat16,
@@ -306,6 +310,7 @@ def test_mosaic_payload_and_the_python_call_stack(v5e_2x2, frames):
     fn = _loss(lambda q, k, v: flash_attention(q, k, v, causal=True))
 
     def payload(f):
+        jax.clear_caches()
         return re.findall(r'backend_config = "((?:[^"\\]|\\.)*)"',
                           jax.jit(f).lower(x, x, x).as_text())
 
@@ -325,8 +330,8 @@ def test_mosaic_payload_and_the_python_call_stack(v5e_2x2, frames):
 @pytest.mark.parametrize("where,batch", [("one_chip", 8), ("shard_map", 2)])
 def test_the_benchmarks_reader_still_tells_the_kernels_by_their_results(
         named_texts, where, batch):
-    """``benchmark/lib/hlo.flash_calls`` tells the three calls by the NAME
-    the program gives each (its end is the kind: ``fwd``, ``dq``, ``dkv``)
+    """``benchmark/lib/hlo.flash_calls`` tells the two calls by the NAME
+    the program gives each (its end is the kind: ``fwd``, ``bwd``)
     and lists a call where its results are as many as that kind gives (the
     test's name is older than that). The sizes are the first result's as
     they stand: on the model's layout ``[8, 1024, 1024]`` reads as 8
@@ -340,12 +345,12 @@ def test_the_benchmarks_reader_still_tells_the_kernels_by_their_results(
     if bench not in sys.path:
         sys.path.insert(0, bench)
     calls = importlib.import_module("lib.hlo").flash_calls(named_texts[where])
-    assert sorted(c["kind"] for c in calls) == ["dkv", "dq", "fwd"]
+    assert sorted(c["kind"] for c in calls) == ["bwd", "fwd"]
     for call in calls:
         assert (call["batch_heads"], call["seq"], call["head_dim"]) \
             == (batch, 1024, 1024), call
-        assert {"fwd": "flash_fwd", "dq": "flash_bwd_dq",
-                "dkv": "flash_bwd_dkv"}[call["kind"]] in call["name"]
+        assert {"fwd": "flash_fwd",
+                "bwd": "flash_bwd"}[call["kind"]] in call["name"]
 
 
 # ---------------------------------------------------------------- Laguna
