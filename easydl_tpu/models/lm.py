@@ -15,7 +15,9 @@ from jax import lax
 
 from easydl_tpu.core.data import SyntheticTokens
 from easydl_tpu.models.registry import ModelBundle
-from easydl_tpu.models.transformer import Transformer, TransformerConfig
+from easydl_tpu.models.transformer import (INDEX_COUNTERS, Transformer,
+                                           TransformerConfig)
+from easydl_tpu.ops import index as index_ops
 from easydl_tpu.ops.flash_attention import BlockDiffusion, choose_blocks
 from easydl_tpu.ops.fused_xent import fused_softmax_xent, local_batch
 from easydl_tpu.ops import selective_scan as sscan
@@ -269,10 +271,23 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
     restored step draws the noise it drew — the stack run on ``[xt || x0]``,
     the vocabulary's last row standing for the mask token; the metrics
     carry ``diffusion_masked_share`` and ``diffusion_mean_t``. ``eval_fn`` is
-    the same loss under one fixed key."""
+    the same loss under one fixed key.
+
+    Where attention layers stand behind a learned index
+    (``AttentionKind.index``) the objective is the next-token loss PLUS the
+    layers' index losses (``ops/index.py kl``, a layer's mean over its
+    tokens, summed over the layers, weight 1) — the one loss term that leaves
+    a layer: it comes out with the counters and is added here. The metrics
+    carry ``loss_main`` and ``index_loss`` apart, the static
+    ``index_selected_pairs`` / ``index_causal_pairs`` (a head and sequence)
+    and ``index_tiles`` (the causal tiles of the step's index layers),
+    ``index_live_tiles`` of them (those that hold a selected pair) and
+    ``index_score_rms`` over the causal pairs. ``eval_fn`` is the next-token
+    loss alone."""
     model = Transformer(cfg)
     seq_len, vocab = cfg.max_seq, cfg.vocab
     n_sparse = sum(1 for _, ffn in cfg.every_layer if ffn == "moe")
+    summed_index = INDEX_COUNTERS if cfg.index_layers else ()
 
     def head_of(params, dtype):
         """The head as ``[V, D]`` in the compute dtype — exactly what
@@ -402,20 +417,46 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
         expert layers' counters."""
         return {**(heads or {"perplexity": jnp.exp(loss)}), **(counters or {})}
 
+    def index_counters(summed, batch):
+        """The index layers' counters from their sums over the layers, and
+        the static counts beside them."""
+        rows, seq = batch["inputs"].shape
+        (topk,) = {kind.index.topk for _, kind in cfg.attention_kinds
+                   if kind.index is not None}
+        pairs = index_ops.causal_pairs(seq)
+        return {
+            "index_loss": summed["index_loss"],
+            "index_live_tiles": summed["index_live_tiles"],
+            "index_tiles": jnp.float32(
+                cfg.index_layers * rows * index_ops.tiles(seq)),
+            "index_selected_pairs": jnp.float32(
+                index_ops.selected_pairs(seq, topk)),
+            "index_causal_pairs": jnp.float32(pairs),
+            "index_score_rms": jnp.sqrt(
+                summed["index_score_squares"]
+                / (cfg.index_layers * rows * pairs))}
+
     def loss_fn(params, batch, rng):
         if cfg.exit_gate:
             return gated_loss(params, batch)
-        if n_sparse:
+        if cfg.counters:
             loss, mut, heads = diffusion_loss(params, batch, rng, True) \
                 if cfg.block_diffusion \
                 else _lm_loss_from(params, batch, mutable=True)
             summed = mut["counters"]["moe"][0]
             counters = {name: summed[i] / (1 if name == "moe_dropped"
                                            else n_sparse)
-                        for i, name in enumerate(cfg.counters)}
+                        for i, name in enumerate(cfg.counters)
+                        if name not in summed_index}
             if cfg.router_state_width:
                 counters["router_state_rms"] = \
                     mut["counters"]["router_state_rms"][0]
+            if cfg.index_layers:
+                own = {name: summed[cfg.counters.index(name)]
+                       for name in summed_index}
+                counters.update(index_counters(own, batch), loss_main=loss)
+                metrics = metrics_of(loss, heads, counters)
+                return loss + own["index_loss"], metrics
             return loss, metrics_of(loss, heads, counters)
         loss, _, heads = diffusion_loss(params, batch, rng) \
             if cfg.block_diffusion else _lm_loss_from(params, batch)

@@ -13,7 +13,7 @@ from typing import Any, Callable, Dict, Optional
 _REGISTRY: Dict[str, Callable[..., "ModelBundle"]] = {}
 #: modules under easydl_tpu.models that register factories on import
 _MODULES = ("mlp", "resnet", "bert", "gpt", "granite_hybrid", "ouro", "laguna",
-            "zaya", "joyai", "nemotron_h", "mellum", "sdar", "phi4flash",
+            "zaya", "joyai", "nemotron_h", "mellum", "sdar", "phi4flash", "keye",
             "deepfm")
 
 

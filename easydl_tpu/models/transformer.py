@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from easydl_tpu.ops import multihead_attention, remat
-from easydl_tpu.ops.attention import norm_heads, rotate_heads
+from easydl_tpu.ops.attention import (indexed_attention, norm_heads,
+                                      rotate_heads)
 from easydl_tpu.ops.flash_attention import BlockDiffusion
 from easydl_tpu.ops import moe as moe_ops
 from easydl_tpu.ops.moe import MoeMlp
@@ -221,6 +222,37 @@ class LowRank:
 
 
 @dataclass(frozen=True)
+class LearnedIndex:
+    """A learned index in front of an attention (DeepSeek Sparse Attention's
+    lightning indexer; ``ops/index.py``): ``n_heads`` index queries of
+    ``head_dim`` a token against ONE index key a token, a learned weight a
+    head, ``I[t, s] = sum_j w[t, j] relu(a[t, j] . b[s])``; query ``t``
+    attends to the ``min(t + 1, topk)`` causal keys of largest ``I``. Its
+    three maps read the layer's normed input DETACHED, its key passes a
+    LayerNorm (gain and bias), queries and key are rotated (rotate-half
+    over the whole ``head_dim``, the kind's theta), the weights are scaled
+    ``n_heads ** -0.5 * head_dim ** -0.5``, and it is trained by its own
+    loss, a layer's ``mean_t KL(p_t || softmax_{S_t} I_t)`` against the
+    attention's probabilities (``models/lm.py`` adds the layers' sum to the
+    objective). ``q_chunk`` / ``kv_chunk``: the published tiling of the
+    scores; selection is by token, so they change no result (the kernels
+    take ``kv_chunk`` keys at a time and ``ops/index.py QUERIES``
+    queries)."""
+
+    n_heads: int
+    head_dim: int
+    topk: int
+    q_chunk: int = 512
+    kv_chunk: int = 512
+
+
+#: the counters an index layer hands out beside the expert layers' (summed
+#: over the layers): its loss, the tiles that hold a selected pair, the
+#: causal scores' sum of squares
+INDEX_COUNTERS = ("index_loss", "index_live_tiles", "index_score_squares")
+
+
+@dataclass(frozen=True)
 class AttentionKind:
     """An attention layer's own numbers, where a stack has more than one
     kind: query heads (0: the description's), a causal window in keys (0:
@@ -249,7 +281,9 @@ class AttentionKind:
     values (after their bias) to the layers behind it — or ``"takes"`` — it
     has a query and an output map alone and reads the nearest giver's
     (SambaY's cross-decoder, arXiv:2507.06607). ``bias``: this kind's four
-    projections carry a bias though the description's (``bias``) do not."""
+    projections carry a bias though the description's (``bias``) do not.
+    ``index``: a :class:`LearnedIndex` in front of the attention — which keys
+    a query sees is then DATA of the step (plain causal kinds alone)."""
 
     n_heads: int = 0
     window: int = 0
@@ -261,6 +295,7 @@ class AttentionKind:
     diff: Optional[float] = None
     kv: str = ""
     bias: bool = False
+    index: Optional[LearnedIndex] = None
 
 
 @dataclass(frozen=True)
@@ -650,9 +685,17 @@ class TransformerConfig:
     @property
     def counters(self) -> Tuple[str, ...]:
         """Names of the counters the stack sows (``counters`` collection,
-        ``moe``): the expert layers' own, summed over the layers."""
-        return moe_ops.counters(self.moe.skip_choice, self.moe.router) \
+        ``moe``): the expert layers' own, summed over the layers, and
+        behind them an index's (:data:`INDEX_COUNTERS`)."""
+        moe = moe_ops.counters(self.moe.skip_choice, self.moe.router) \
             if self.has_moe else ()
+        return moe + (INDEX_COUNTERS if self.index_layers else ())
+
+    @property
+    def index_layers(self) -> int:
+        """Layers whose attention stands behind a learned index."""
+        return sum(1 for mixer, _ in self.pattern
+                   if self.attention_kind(mixer).index is not None)
 
     @property
     def kv_heads(self) -> int:
@@ -951,6 +994,8 @@ def _attention(block, kind, h, rope=None):
     n_heads = kind.n_heads or cfg.n_heads
     rotary_dim = kind.rope.rotary_dim or None if kind.rope else None
     heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
+    if kind.index is not None:  # its tables ride behind the attention's
+        rope, index_rope = rope[:2], rope[2:]
     # the four products around the kernels as matrix products on rows: the
     # kernels' layout (the Mamba-2 mixer's same-shaped projections feed no
     # kernel and measured SLOWER that way: PERF.md section 6, PR 28). With a
@@ -983,7 +1028,19 @@ def _attention(block, kind, h, rope=None):
     # diffusion's mask
     mask = BlockDiffusion(cfg.block_diffusion, h.shape[1] // 2) \
         if cfg.block_diffusion else None
-    if cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
+    index_stats = None
+    if kind.index is not None:
+        ia, ib, iw = _index_inputs(block, kind.index, h, index_rope)
+        attn, index_loss, found = indexed_attention(
+            q, k, v, ia, ib, iw, topk=kind.index.topk,
+            scale=cfg.attention_multiplier, impl=cfg.attention_impl,
+            rope=rope, qk_norm=qk_norm, chunk=kind.index.kv_chunk)
+        index_stats = jnp.stack([index_loss, found["live_tiles"],
+                                 found["score_squares"]])
+        for name, value in (("a", ia), ("b", ib), ("w", iw),
+                            ("words", found["words"])):
+            block.sow("intermediates", f"ranked_{name}", value)
+    elif cfg.attention_fn is not None:  # sequence-parallel (ring/Ulysses)
         if kind.window:
             raise NotImplementedError(
                 "a windowed attention layer under sequence parallelism")
@@ -1013,9 +1070,41 @@ def _attention(block, kind, h, rope=None):
             )(h).astype(jnp.float32))
             attn = (attn * gate[..., None]).astype(attn.dtype)
     with scope("cca_up"):
-        return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
-                           ("embed",), "out", residual=True, axis=(-2, -1),
-                           rows=True)(attn)
+        out = _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                          ("embed",), "out", residual=True, axis=(-2, -1),
+                          rows=True)(attn)
+    return out if index_stats is None else (out, index_stats)
+
+
+def _index_inputs(block, ix: LearnedIndex, h, rope):
+    """The index's queries ``[batch, seq, heads, dim]``, its one key a token
+    ``[batch, seq, dim]`` (both rotated) and its head weights ``[batch, seq,
+    heads]`` float32, scaled — all from the layer's normed input ``h``
+    DETACHED: nothing of the index moves what is in front of it."""
+    cfg = block.cfg
+    with jax.named_scope("index"):
+        h = jax.lax.stop_gradient(h)
+
+        def mapped(name, width):
+            return _dense(width, ("embed", None), (None,), name=name,
+                          use_bias=False, dtype=jnp.dtype(cfg.dtype))(h)
+
+        a = mapped("index_q", ix.n_heads * ix.head_dim).reshape(
+            *h.shape[:2], ix.n_heads, ix.head_dim)
+        b = nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.dtype(cfg.dtype),
+                         name="index_k_norm")(mapped("index_k", ix.head_dim))
+        w = mapped("index_w", ix.n_heads).astype(jnp.float32) \
+            * (ix.n_heads ** -0.5 * ix.head_dim ** -0.5)
+        a = apply_rope(a, *rope)
+        b = apply_rope(b[:, :, None, :], *rope)[:, :, 0]
+        # ONE value of each for every reader — the ranking, the index's loss,
+        # whoever is handed them (`intermediates`): left to the compiler, a
+        # reader may be given the rotation fused in at another precision
+        # than its neighbour's (a bf16 rounding apart: on the chip the words
+        # were then not the top-k of the inputs handed out for 72% of the
+        # queries: PERF.md section 6, PR 61)
+        a, b, w = jax.lax.optimization_barrier((a, b, w))
+    return a, b, w
 
 
 def _mamba2(block, u):
@@ -1271,6 +1360,12 @@ def _attention_params(cfg, kind):
         n += 2 * cfg.head_dim  # q's gain and k's
     if kind.gate:
         n += d * heads
+    if kind.index:
+        # its queries, its one key with a LayerNorm's gain and bias, its
+        # head weights
+        ix = kind.index
+        n += d * (ix.n_heads * ix.head_dim + ix.head_dim + ix.n_heads) \
+            + 2 * ix.head_dim
     if kind.latent:
         # a tap and a bias a channel, a [head_dim, head_dim] matrix a tap and
         # head and a bias a channel; a temperature a kv head
@@ -1334,6 +1429,27 @@ def _check_attention(cfg, name, kind):
             f"(TransformerConfig.__post_init__ refuses the pair)")
     if kind.kv or kind.bias:  # which stand on a diff kind alone
         _check_diff(cfg, name, kind)
+    if kind.index is not None:
+        refused = [what for what, found in (
+            ("causal=False", not cfg.causal),
+            ("a window", bool(kind.window)),
+            ("a latent mix", kind.latent is not None),
+            ("a gate", kind.gate),
+            ("no rotary scheme of its own", kind.rope is None),
+            ("block diffusion", bool(cfg.block_diffusion)),
+            ("attention_fn (sequence parallelism)",
+             cfg.attention_fn is not None),
+            ("pipeline_fn", cfg.pipeline_fn is not None),
+            ("a looped or gated stack", cfg.loops > 1 or cfg.exit_gate),
+            ("a multi-token-prediction module", cfg.mtp is not None),
+            ("layers of another kind beside it",
+             len(set(cfg.pattern)) > 1)) if found]
+        if refused:
+            raise NotImplementedError(
+                f"attention kind {name!r}: a learned index with "
+                f"{', '.join(refused)}: it stands in front of plain causal "
+                f"attention, in a stack of equal layers "
+                f"(TransformerConfig.__post_init__ refuses it)")
 
 
 def _check_lowrank(cfg, name, kind):
@@ -1365,6 +1481,25 @@ def _check_diff(cfg, name, kind):
             f"kind alone (TransformerConfig.__post_init__ refuses it)")
 
 
+def _apply_attention(block, kind, h, rope, handed):
+    """Plain attention; behind a learned index it hands out the index's
+    counters too (``given["index"]``: the block puts them behind the FFN's)."""
+    out = _attention(block, kind, h, rope)
+    return (out[0], {"index": out[1]}) if kind.index is not None else (out, {})
+
+
+def _attention_scores(cfg, kind, seq_len):
+    """A plain attention's ``score_flops``; behind an index a query sees
+    ``topk`` keys, and the index scores every one of the ``seq_len`` (counted
+    in full, as the convention has the causal scores)."""
+    if kind.index is None:
+        return _scores(lambda cfg, kind: 2 * cfg.head_dim)(cfg, kind, seq_len)
+    ix = kind.index
+    return 6.0 * ((kind.n_heads or cfg.n_heads) * 2 * cfg.head_dim
+                  * min(ix.topk, seq_len)
+                  + ix.n_heads * ix.head_dim * seq_len)
+
+
 def _apply_diff(block, kind, h, rope, handed):
     out, kv = _diff_attention(
         block, kind, h, handed.get("kv") if kind.kv == "takes" else None)
@@ -1385,10 +1520,9 @@ _ATTENTION = dict(scope="attention", norm="ln_attn")
 _SSM = dict(scope="ssm", norm="ln_ssm")
 MIXER_FAMILIES = {
     "attention": MixerFamily(
-        apply=lambda block, kind, h, rope, handed: (
-            _attention(block, kind, h, rope), {}),
+        apply=_apply_attention,
         params=_attention_params,
-        score_flops=_scores(lambda cfg, kind: 2 * cfg.head_dim),
+        score_flops=_attention_scores,
         check=_check_attention, **_ATTENTION),
     "lowrank": MixerFamily(
         apply=lambda block, kind, h, rope, handed: (
@@ -1546,6 +1680,7 @@ class Block(nn.Module):
                 h, given = family.apply(
                     self, kind, _norm(cfg, family.norm, dtype=dt)(x), rope,
                     handed)
+                index_aux = given.pop("index", None)
                 x = residual(x, h, family.norm)
             # `ffn` is the dense FFN's scope; an expert layer is `moe`, with
             # the scopes of ops/moe.py inside it; a layer that is its mixer
@@ -1557,6 +1692,9 @@ class Block(nn.Module):
                     h, aux, state = _ffn(
                         self, _norm(cfg, "ln_mlp", dtype=dt)(x), state)
                     x = residual(x, h, "ln_mlp")
+            if index_aux is not None:  # behind the expert layer's counters
+                aux = jnp.concatenate([aux, index_aux]) \
+                    if self.ffn == "moe" else index_aux
         if cfg.remat and not self.is_initializing():
             log_once(log, f"remat {cfg.remat_policy}: a ({self.mixer}, "
                           f"{self.ffn}) layer at {tuple(x.shape)}, "
@@ -1739,6 +1877,11 @@ class Transformer(nn.Module):
         for name, kind in cfg.attention_kinds:
             ropes[name] = ropes["attention"] if kind.rope is None else \
                 kind.rope.tables(held, cfg.head_dim)
+            if kind.index is not None:
+                # the index's own tables behind the attention's: rotate-half
+                # over its whole head, the kind's theta
+                ropes[name] += rope_tables(held, kind.index.head_dim,
+                                           kind.rope.theta)
         if cfg.block_diffusion:
             ropes = {name: tables and tuple(
                 jnp.concatenate([table, table]) for table in tables)
@@ -1769,7 +1912,7 @@ class Transformer(nn.Module):
             logit, aux))``, a scan body over passes."""
             # zeros for dense layers; the expert layers' counters summed
             # (ops/moe.py counters) where the description has any
-            aux = jnp.zeros((len(cfg.counters),) if cfg.has_moe else (),
+            aux = jnp.zeros((len(cfg.counters),) if cfg.counters else (),
                             jnp.float32)
             if cfg.router_state_width:
                 # the router state beside the residual stream: zeros into
@@ -1805,7 +1948,7 @@ class Transformer(nn.Module):
                     x, layer_aux = _pipelined(
                         stack, block_cls, scan_kwargs, mixer, ffn, x,
                         deterministic, rope)
-                aux = aux + (jnp.sum(layer_aux, 0) if ffn == "moe"
+                aux = aux + (jnp.sum(layer_aux, 0) if layer_aux.ndim > 1
                              else jnp.sum(layer_aux))
             if cfg.router_state_width:
                 x, state = x
@@ -1856,7 +1999,7 @@ class Transformer(nn.Module):
         # The expert layers' counters, summed over the layers; read back by
         # the loss function via mutable=["counters"] — a no-op sow for plain
         # apply() calls.
-        if cfg.has_moe:
+        if cfg.counters:
             self.sow("counters", "moe", aux)
 
         if return_hidden:

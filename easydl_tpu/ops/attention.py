@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from easydl_tpu.core.mesh_shapes import BATCH_AXES
-from easydl_tpu.ops import platform
+from easydl_tpu.ops import index, platform, remat
 from easydl_tpu.ops.flash_attention import (
     MAX_BLOCK,
     BlockDiffusion,
@@ -73,16 +73,22 @@ def _reference_attention(
     segment_ids: Optional[jax.Array] = None,
     window: Optional[int] = None,
     mask: Optional[BlockDiffusion] = None,
+    chosen: Optional[jax.Array] = None,
 ) -> jax.Array:
     """XLA-fused reference path: einsum → mask → softmax → einsum.
 
     fp32 softmax accumulation regardless of input dtype (bf16-safe).
     ``window`` (causal only) as the kernels have it: query i sees the
     ``window`` keys up to its own. ``mask``: block diffusion's, written out
-    (``BlockDiffusion.dense``), as the kernels have it.
+    (``BlockDiffusion.dense``), as the kernels have it. ``chosen``: a
+    learned index's selection written out, ``[batch, queries, keys]`` bool
+    (``ops/index.py unpack``), which is causal already.
     """
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     fully_masked = None
+    if chosen is not None:
+        logits = jnp.where(chosen[:, None], logits,
+                           jnp.finfo(jnp.float32).min)
     if mask is not None:
         logits = jnp.where(mask.dense()[None, None], logits,
                            jnp.finfo(jnp.float32).min)
@@ -339,3 +345,100 @@ def multihead_attention(
         q, *_repeat_kv(q, k, v), causal=causal, scale=scale,
         segment_ids=segment_ids, window=window, mask=mask
     )
+
+
+def indexed_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    a: jax.Array,
+    b: jax.Array,
+    w: jax.Array,
+    *,
+    topk: int,
+    scale: Optional[float] = None,
+    impl: str = "auto",
+    rope: Optional[tuple] = None,
+    qk_norm: Optional[tuple] = None,
+    chunk: int = 512,
+    interpret: bool = False,
+):
+    """Causal attention in which query ``t`` attends to the ``min(t + 1,
+    topk)`` keys a learned index ranks highest (``ops/index.py``), and the
+    index's own loss. ``q [batch, seq, heads, head_dim]``, ``k``, ``v`` (at
+    the key/value heads) as the projections made them; ``rope`` and
+    ``qk_norm`` as :func:`multihead_attention` takes them. ``a [batch, seq,
+    index heads, dim]``, ``b [batch, seq, dim]``, ``w [batch, seq, index
+    heads]``: the index's queries, its one key a token and its head weights,
+    rotated and scaled by the caller, made from a DETACHED input.
+
+    Returns ``(out, loss, stats)``: the attention's result; ``mean_t KL(p_t
+    || softmax_{S_t} I_t)`` with ``p`` the attention's probabilities, the
+    mean of its heads, detached — the loss moves ``a``, ``b`` and ``w`` alone,
+    and nothing of ``out`` moves them; ``stats``: ``live_tiles`` (the tiles
+    of ``index.TILE`` squared that hold a selected pair) and ``score_squares``
+    (the causal scores' sum of squares), float32 scalars over the batch, and
+    ``words``, the packed selection itself (``ops/index.py``).
+
+    The path is chosen as :func:`multihead_attention` chooses it: the Pallas
+    kernels (``index_select``, ``dsa_fwd`` / ``dsa_bwd``, ``index_kl``; no
+    ``[seq, seq]`` array in HBM) where ``impl`` asks for them, else XLA's
+    operations under the selection WRITTEN OUT — the same packed words made,
+    kept and unpacked. The selection is named for remat on both
+    (``ops/remat.py SELECTED``). ``interpret``: the kernels in the Pallas
+    interpreter, which only a test passes."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    seq, head_dim = q.shape[1], q.shape[-1]
+    kernels = _on_kernels(impl)
+    if not kernels and impl not in ("auto", "reference"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    if kernels and choose_blocks(seq, seq, True) is None:
+        log_once(log, f"indexed attention: XLA reference path, not the "
+                      f"kernels: length {seq} has no block divisor <= "
+                      f"{MAX_BLOCK}")
+        kernels = False
+    if kernels:
+        mesh = jax.sharding.get_abstract_mesh()
+        if any(mesh.shape[a] > 1 for a in mesh.axis_names
+               if a not in mesh.manual_axes):
+            raise NotImplementedError(
+                "indexed attention: the kernels under a mesh of more than "
+                "one device (indexed_attention refuses it)")
+    else:
+        log_once(log, f"indexed attention: XLA reference path under the "
+                      f"selection written out (impl={impl!r} on platform "
+                      f"{jax.devices()[0].platform!r}), top-{topk} of {seq}")
+    if kernels and rope is not None and tiles_lanes(head_dim):
+        norms = [(gain, qk_norm[2]) for gain in qk_norm[:2]] \
+            if qk_norm is not None else (None, None)
+        q, k = (rope_rows(x.reshape(*x.shape[:2], -1), *rope,
+                          head_dim=head_dim, norm=norm,
+                          interpret=interpret).reshape(x.shape)
+                for x, norm in zip((q, k), norms))
+    else:
+        if qk_norm is not None:
+            q, k = norm_heads(q, k, qk_norm)
+        if rope is not None:
+            q, k = (apply_rope(x, *rope) for x in (q, k))
+    with jax.named_scope("index"), jax.named_scope("index_topk"):
+        words, lse_i, squares = index.select(
+            a, b, w, topk=topk, kernels=kernels, chunk=chunk,
+            interpret=interpret)
+        words, lse_i = (remat.name(x, remat.SELECTED)
+                        for x in (words, lse_i))
+    if kernels:
+        out, lse = flash_attention(
+            q, k, v, causal=True, scale=scale, select=words,
+            interpret=interpret)
+    else:
+        lse = None
+        out = _reference_attention(q, *_repeat_kv(q, k, v), causal=False,
+                                   scale=scale, chosen=index.unpack(words))
+    with jax.named_scope("index_loss"):
+        loss = index.kl(a, b, w, q, k, lse, words, lse_i, scale=scale,
+                        kernels=kernels, chunk=chunk, interpret=interpret)
+    with jax.named_scope("index"):
+        stats = {"live_tiles": index.live_tiles(words),
+                 "score_squares": jnp.sum(squares), "words": words}
+    return out, loss, stats

@@ -264,7 +264,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from easydl_tpu.ops import remat
+from easydl_tpu.ops import index, remat
 from easydl_tpu.utils.logging import get_logger, log_once
 
 log = get_logger("ops", "flash_attention")
@@ -650,6 +650,14 @@ def _where(cond, a, b):
     return jnp.where(cond, a, b)
 
 
+def _chosen(st, words, half: int):
+    """The score tile ``st`` (``[index.TILE keys, queries]``) with the pairs
+    a selection leaves out set to NEG_INF; ``words``: the ``[8, queries]``
+    rows of the keys' group of the packed selection (``ops/index.py``),
+    ``half`` which 128 keys of the group the tile is."""
+    return jnp.where(index.unpack_tile(words, half) != 0, st, NEG_INF)
+
+
 #: the rules a block pair on one of the block mask's three diagonals is
 #: masked by, as ``(kind, strict)``: ``("own", 0)`` — a noised block's own
 #: keys: key block == query block — and ``("upto", strict)`` — the clean
@@ -906,7 +914,7 @@ _TWO_SIZE_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 
 def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
                params: Optional[pltpu.CompilerParams] = None,
-               mask: Optional[BlockDiffusion] = None):
+               mask: Optional[BlockDiffusion] = None, selected: bool = False):
     """A kernel's ``name=`` (and compiler parameters: the call's own
     ``params``, else the two-size allowance) by what it computes:
     ``flash_*``, ``swa_*`` under a window, where the scores' and the values'
@@ -914,8 +922,10 @@ def _call_name(kernel: str, d: int, dv: int, window: Optional[int],
     values are wide) or ``diff_*`` (differential attention: a pair's value
     wider than its scores are deep), ``bd_*`` under the block mask (whose
     forward holds ``2 seam`` rows of k and v whole: the two-size allowance
-    of VMEM)."""
-    if mask is not None:
+    of VMEM), ``dsa_*`` under a selection (``select``: a learned index's)."""
+    if selected:
+        named = dict(name=f"dsa_{kernel}")
+    elif mask is not None:
         named = dict(name=f"bd_{kernel}", compiler_params=_TWO_SIZE_PARAMS)
     elif d != dv:
         named = dict(name=f"{'mla' if d > dv else 'diff'}_{kernel}",
@@ -1030,7 +1040,10 @@ def _fwd_kernel(
     head_dim: int, value_dim: int, block_k: int, n_q: int,
     causal: bool, scale: float, offset: int,
     window: Optional[int], mask: Optional[BlockDiffusion] = None,
+    select=None,
 ):
+    # select: None, or the packed selection of this Q-block, [S_k / 32,
+    # block_q] int32 (``ops/index.py``): a pair it leaves out is no pair
     # q_ref: [block_q, cell heads · d], o_ref: [block_q, cell heads · dv];
     # k_ref: [S_k, cell heads · d], v_ref: [S_k, cell heads · dv]; lse_ref:
     # [cell heads, block_q, 1]; sums (scratch, float32): m and l [cell heads,
@@ -1083,12 +1096,16 @@ def _fwd_kernel(
             q, s_scale = qs[g]
             edge = q_start + offset - k_start + qj - ki \
                 if masked and mask is None else None
-            if placed and ki + sub_k - 1 <= qj:
-                edge = None
+            if placed and ki + sub_k - 1 <= qj or select is not None:
+                edge = None  # (a selection's bits are causal already)
             st = _scores_t(k_ref[keys(ki), heads[g]], q[qj:qj + sub_q],
                            s_scale, edge, window)
             if crossed.get((ki, qj)):
                 st = _bd_mask(st, masked, mask.block, ki, qj)
+            if select is not None:
+                group = kb * (block_k // index.GROUP) + ki // index.GROUP
+                st = _chosen(st, select[pl.ds(_block_start(group, 8), 8),
+                                        qj:qj + sub_q], ki // sub_k % 2)
             return st
 
         def softmax(step, st):
@@ -1143,7 +1160,7 @@ def _fwd_kernel(
 def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
               block_q: int, block_k: int, interpret: bool,
               window: Optional[int] = None,
-              mask: Optional[BlockDiffusion] = None):
+              mask: Optional[BlockDiffusion] = None, select=None):
     """The forward call (``_fwd``: a ``jax.jit`` of its own). The body
     walked in tiles is sixteen times the operations of a pair as one tile, a
     ``pallas_call``'s body is traced and lowered once a USE (the pass,
@@ -1164,6 +1181,9 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
         causal=causal, scale=scale, offset=s_k - s_q, window=window,
         mask=mask,
     )
+    more, more_specs = _selection(select, b, n_q, block_q, whole_keys=True)
+    if more:
+        kernel = functools.partial(_with_selection, kernel, 3)
 
     def mine(size):
         return pl.BlockSpec((None, block_q, cell * size),
@@ -1184,6 +1204,7 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
     held = (2 * cell * ((block_q + s_k) * (d + dv) * q.dtype.itemsize
                         + block_q * 128 * 4)
             + cell * (dv + 16) * block_q * 4)
+    held += sum(2 * 4 * x.shape[2] * x.shape[3] for x in more)
     if d == dv and mask is None and held + _TILES_VMEM > _DEFAULT_VMEM:
         params = pltpu.CompilerParams(
             vmem_limit_bytes=held + _DEFAULT_VMEM)
@@ -1191,7 +1212,7 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, pl.cdiv(heads, cell), n_q),
-        in_specs=[mine(d), shared(whole(d)), shared(whole(dv))],
+        in_specs=[mine(d), shared(whole(d)), shared(whole(dv)), *more_specs],
         out_specs=[
             mine(dv),
             pl.BlockSpec((None, cell, block_q, 1), lambda b, h, qi: (b, h, qi, 0)),
@@ -1207,9 +1228,36 @@ def _fwd_call(q, k, v, *, heads: int, causal: bool, scale: float,
             pltpu.VMEM((cell, 1, block_q), jnp.float32),
             pltpu.VMEM((cell, dv, block_q), jnp.float32)],
         interpret=interpret,
-        **_call_name("fwd", d, dv, window, params, mask=mask),
-    )(q, k, v)
+        **_call_name("fwd", d, dv, window, params, mask=mask,
+                     selected=bool(more)),
+    )(q, k, v, *more)
     return out, lse
+
+
+def _selection(select, batch: int, n_q: int, block_q: int, *,
+               whole_keys: bool, block_k: int = 0):
+    """``(operands, specs)`` that hand a kernel the packed selection
+    ``select [batch, S_k / 32, S_q]`` (``ops/index.py``; None: nothing), a
+    Q-block a leading index ``[batch, n_q, S_k / 32, block_q]``: the forward
+    takes its own Q-block's rows of every key (``whole_keys``), the backward
+    its K-block's ``block_k / 32`` rows of every Q-block."""
+    if select is None:
+        return (), ()
+    rows = select.shape[1]
+    by_block = select.reshape(batch, rows, n_q, block_q).transpose(0, 2, 1, 3)
+    if whole_keys:
+        spec = pl.BlockSpec((None, None, rows, block_q),
+                            lambda b, h, qi: (b, qi, 0, 0))
+    else:
+        spec = pl.BlockSpec((None, n_q, block_k // 32, block_q),
+                            lambda b, h, ki: (b, 0, ki, 0))
+    return (by_block,), (spec,)
+
+
+def _with_selection(kernel, at: int, *refs):
+    """``kernel`` handed the selection's ref, which stands behind the
+    ``at`` other inputs, by keyword."""
+    return kernel(*refs[:at], *refs[at + 1:], select=refs[at])
 
 
 _fwd = jax.jit(_fwd_call, static_argnames=(
@@ -1301,6 +1349,7 @@ def _bwd_kernel(
     dq_sum_ref, dk_sum_ref, dv_sum_ref, *, head_dim: int, value_dim: int,
     block_q: int, n_k: int, causal: bool, scale: float, offset: int,
     window: Optional[int], mask: Optional[BlockDiffusion] = None,
+    select=None,
 ):
     """The backward, one K-block a grid cell: dq, dk and dv from ONE
     score tile, ``exp``, ``dP`` and ``delta``, a block pair walked in tiles
@@ -1369,12 +1418,17 @@ def _bwd_kernel(
             k, s_scale = ks[g]
             edge = q_start + offset - k_start + qj - ki \
                 if masked and mask is None else None
-            if placed and ki + sub_k - 1 <= qj:
-                edge = None
+            if placed and ki + sub_k - 1 <= qj or select is not None:
+                edge = None  # (a selection's bits are causal already)
             st = _scores_t(k[there], q_ref[rows_at(qj), heads[g]], s_scale,
                            edge, window)
             if crossed.get((ki, qj)):
                 st = _bd_mask(st, masked, mask.block, ki, qj)
+            if select is not None:
+                # [n_q, block_k / 32, block_q]: this K-block's rows
+                group = ki // index.GROUP
+                st = _chosen(st, select[qb, group * 8:(group + 1) * 8,
+                                        qj:qj + sub_q], ki // sub_k % 2)
             return st, _dot(vs[g][there], do_ref[rows_at(qj), v_heads[g]],
                             _NT)
 
@@ -1441,7 +1495,7 @@ _TILES_VMEM = 4 << 20
 def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
               scale: float, block_q: int, block_k: int, interpret: bool,
               window: Optional[int] = None,
-              mask: Optional[BlockDiffusion] = None):
+              mask: Optional[BlockDiffusion] = None, select=None):
     """The backward (``_bwd``: a ``jax.jit`` of its own, as the forward's):
     ONE call on the grid ``(batch row, lane block, K-block)`` gives all
     three — dq summed in VMEM across the K-block axis, which is therefore
@@ -1465,14 +1519,20 @@ def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
     # each; the float32 sums of dq, dk and dv once
     held = (2 * cell * (2 * (s_q + block_k) * (d + vd) * item + s_q * 8 * 4)
             + cell * (d * s_q + (d + vd) * block_k) * 4)
+    kernel = functools.partial(
+        _bwd_kernel, head_dim=d, value_dim=vd, block_q=block_q, n_k=n_k,
+        causal=causal, scale=scale, offset=s_k - s_q, window=window,
+        mask=mask)
+    more, more_specs = _selection(select, b, n_q, block_q, whole_keys=False,
+                                  block_k=block_k)
+    if more:
+        kernel = functools.partial(_with_selection, kernel, 6)
+        held += 2 * 4 * n_q * (block_k // 32) * block_q
     return pl.pallas_call(
-        functools.partial(
-            _bwd_kernel, head_dim=d, value_dim=vd, block_q=block_q, n_k=n_k,
-            causal=causal, scale=scale, offset=s_k - s_q, window=window,
-            mask=mask),
+        kernel,
         grid=(b, pl.cdiv(heads, cell), n_k),
         in_specs=[whole(d), shared(mine(d)), shared(mine(vd)), whole(vd),
-                  whole(vd), lse_spec],
+                  whole(vd), lse_spec, *more_specs],
         out_specs=[whole(d), mine(d), mine(vd)],
         # dk and dv a QUERY head: the rule sums a group's (`_flash_bwd`)
         out_shape=[
@@ -1487,8 +1547,9 @@ def _bwd_call(q, k, v, out, lse, do, *, heads: int, causal: bool,
         interpret=interpret,
         **_call_name("bwd", d, vd, window, pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=held + _DEFAULT_VMEM), mask=mask),
-    )(q, k, v, out, do, lse_in)
+            vmem_limit_bytes=held + _DEFAULT_VMEM), mask=mask,
+            selected=bool(more)),
+    )(q, k, v, out, do, lse_in, *more)
 
 
 _bwd = jax.jit(_bwd_call, static_argnames=(
@@ -1885,6 +1946,11 @@ def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, mask,
             block_q=blocks[2][0], block_k=blocks[2][1], interpret=interpret,
             window=window, mask=mask,
         )
+    return (dq, *_group_sums(dk, dv, q, k, v, heads))
+
+
+def _group_sums(dk, dv, q, k, v, heads: int):
+    """dk and dv at the key/value heads."""
     ratio = _head_sizes(q, k, v, heads)[2]
     if ratio > 1:
         # dk and dv leave the kernels a QUERY head (the looped backward's
@@ -1895,10 +1961,44 @@ def _flash_bwd(heads, causal, scale, blocks: Blocks, interpret, window, mask,
         dk, dv = (jax.lax.reduce_sum(
             dx.reshape(*x.shape[:2], heads // ratio, ratio, -1),
             axes=(3,)).reshape(x.shape) for dx, x in ((dk, k), (dv, v)))
-    return dq, dk, dv
+    return dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_selected(q, k, v, select, heads, scale, blocks: Blocks, interpret,
+                    keeps=(False, False)):
+    """``(out, lse rows)`` of causal attention under the packed selection
+    ``select`` (``ops/index.py``: an int array, no gradient). The rows
+    ``[batch, heads, seq]`` leave for a reader that takes no gradient
+    through them (the index's own loss): their cotangent is not read."""
+    return _flash_selected_fwd(q, k, v, select, heads, scale, blocks,
+                               interpret, keeps)[0]
+
+
+def _flash_selected_fwd(q, k, v, select, heads, scale, blocks: Blocks,
+                        interpret, keeps=(False, False)):
+    out, lse = _fwd(
+        q, k, v, heads=heads, causal=True, scale=scale,
+        block_q=blocks[0][0], block_k=blocks[0][1], interpret=interpret,
+        select=select)
+    out, lse = remat.name_flash(out, lse.reshape(lse.shape[:3]), keeps)
+    return (out, lse), (q, k, v, select, out, lse)
+
+
+def _flash_selected_bwd(heads, scale, blocks: Blocks, interpret, keeps, res,
+                        g):
+    q, k, v, select, out, lse = res
+    dq, dk, dv = _bwd(
+        q, k, v, out, lse, g[0], heads=heads, causal=True, scale=scale,
+        block_q=blocks[2][0], block_k=blocks[2][1], interpret=interpret,
+        select=select)
+    return (dq, *_group_sums(dk, dv, q, k, v, heads), None)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
 
 
 def flash_attention(
@@ -1913,7 +2013,8 @@ def flash_attention(
     interpret: bool = False,
     window: Optional[int] = None,
     mask: Optional[BlockDiffusion] = None,
-) -> jax.Array:
+    select: Optional[jax.Array] = None,
+):
     """Flash attention over [batch, seq, heads, head_dim] tensors: the
     kernels' result, or ValueError where they cannot tile the lengths
     (:func:`choose_blocks` is the question; this module holds no other
@@ -1939,6 +2040,18 @@ def flash_attention(
     sees a key are not visited, a tile of 128 x 128 wholly dead inside a
     visited pair is not computed, and the calls carry names of their own
     (``bd_fwd``, ``bd_bwd``).
+
+    ``select`` (with ``causal``, a square problem): the packed selection of
+    a learned index (``ops/index.py``: ``[batch, seq / 32, seq]`` int32, a
+    bit a pair, causal already) — a mask that is DATA of the step. A pair it
+    leaves out adds nothing to ``out``, to ``lse`` or to a gradient; the
+    blocks and tiles visited are the causal ones (which hold a selected pair
+    is not known where the program is written), each under its bits. The
+    result is then ``(out, lse)``, the forward's statistics ``[batch, heads,
+    seq]`` beside it for a reader that takes no gradient through them, and
+    the calls are named ``dsa_fwd``, ``dsa_bwd``. Remat's rule weighs the
+    call by the CAUSAL pairs: what making it again costs is what the kernel
+    visits, not what the selection keeps.
 
     ``block_q`` / ``block_k``, when passed, hold for every kernel; left out,
     each kernel's are chosen from what the call shows."""
@@ -1966,7 +2079,22 @@ def flash_attention(
     if window is not None and d > dv:
         raise NotImplementedError(
             f"flash attention: a window with head sizes {d} / {dv}")
+    if select is not None and (not causal or window is not None or mask
+                               is not None or s != s_k or d != dv):
+        raise NotImplementedError(
+            f"flash attention: a selection stands on causal attention over "
+            f"a square problem at one head size; got causal={causal}, "
+            f"window={window}, mask={mask}, lengths {s}/{s_k}, head sizes "
+            f"{d}/{dv} (flash_attention refuses it)")
     blocks = choose_blocks(s, s_k, causal, block_q, block_k, window, mask)
+    if select is not None and blocks is not None and (
+            blocks[0] != blocks[2] or blocks[0][1] % index.GROUP):
+        # a tile of the walk is index.TILE keys of one group of the packed
+        # words
+        raise NotImplementedError(
+            f"flash attention: a selection over blocks {blocks[0]} / "
+            f"{blocks[2]}: its words are unpacked {index.TILE} keys of a "
+            f"group of {index.GROUP} at a time (flash_attention refuses it)")
     if blocks is None:
         raise ValueError(
             f"flash attention: lengths q={s} k={s_k} have no block divisor "
@@ -2013,6 +2141,8 @@ def flash_attention(
                   + ("" if mask is None else
                      " ({0}: {1} of {2} block pairs visited)".format(
                          mask, *mask.block_pairs(blocks[0][0])))
+                  + ("" if select is None else
+                     " (under a selection, a bit a pair: dsa_fwd, dsa_bwd)")
                   + f", {sizes}, on "
                   f"[batch, seq, heads·head_dim] = [{b}, {s}, {h * d}] with "
                   f"{tile} head(s) to a {lanes}{shared}")
@@ -2021,6 +2151,12 @@ def flash_attention(
         jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
         jax.ShapeDtypeStruct((b, h, s), jnp.float32), s_k=s_k, head_dim=d,
         causal=causal, window=window, pairs=mask and mask.pairs())
+    if select is not None:
+        out, lse = _flash_selected(
+            q.reshape(b, s, h * d), k.reshape(b, s_k, kv_heads * d),
+            v.reshape(b, s_k, kv_heads * dv), select, h, scale, blocks,
+            interpret, keeps)
+        return out.reshape(b, s, h, dv), lse
     out = _flash(
         q.reshape(b, s, h * d), k.reshape(b, s_k, kv_heads * d),
         v.reshape(b, s_k, kv_heads * dv), h, causal, scale, blocks, interpret,

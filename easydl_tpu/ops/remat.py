@@ -228,13 +228,25 @@ SHARED_IN = "shared_in"
 #: from a forward that rounds another way may be ANOTHER selection, and the
 #: backward then weighs experts the pass did not run
 ROUTED = "moe_chosen"
+#: what a learned index selected (``ops/index.py``: the packed words, a bit a
+#: pair, and the selected scores' ``lse`` rows; 33.6 MB a layer at 16,384
+#: rows), kept under both for :data:`ROUTED`'s reason: a ranking made again
+#: from a forward that rounds another way may be ANOTHER selection, and the
+#: backward would weigh pairs the forward had not scored
+SELECTED = "index_selected"
+#: the three gradients of the index's own loss, which its ONE kernel forms
+#: beside the loss (``ops/index.py kl``: the differentiation rule's
+#: residuals; 35.7 MB a layer at 16,384 rows): kept under both, so that the
+#: kernel — as much work as the attention's forward — runs once a layer
+INDEX_GRADS = "index_grads"
 NAMES = (PROJECTION, FLASH_OUT, FLASH_LSE, FFN_IN, ROWS, MIXER_IN, SHARED_IN,
-         ROUTED)
+         ROUTED, SELECTED, INDEX_GRADS)
 #: the names each ``remat_policy`` saves; a candidate that is not kept is not
 #: named (``dots`` keeps an FFN's products unnamed, as ``dot_general``s)
 KEPT = {
-    "full": (FLASH_OUT, FLASH_LSE, FFN_IN, ROWS, MIXER_IN, SHARED_IN, ROUTED),
-    "dots": (PROJECTION, FLASH_OUT, FLASH_LSE, ROUTED),
+    "full": (FLASH_OUT, FLASH_LSE, FFN_IN, ROWS, MIXER_IN, SHARED_IN, ROUTED,
+             SELECTED, INDEX_GRADS),
+    "dots": (PROJECTION, FLASH_OUT, FLASH_LSE, ROUTED, SELECTED, INDEX_GRADS),
 }
 
 #: under this many FLOP a byte nothing is kept whatever the room: the table

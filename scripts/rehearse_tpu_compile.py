@@ -89,6 +89,9 @@ SDAR = ("sdar", dict(
     size="30b-a3b-chat", seq_len=8192, vocab=18992, block_length=4,
     remat_policy="full", layer_types=["full_attention"] * 6,
     experts_held=(0, 16), **_CHIP))
+KEYE = ("keye", dict(
+    size="vl-2.0-30b-a3b", seq_len=16384, vocab=18992, remat_policy="full",
+    layer_types=["full_attention"] * 6, experts_held=(0, 16), **_CHIP))
 PHI4FLASH = ("phi4flash", dict(
     size="mini-flash-reasoning", seq_len=16384, vocab=25008,
     remat_policy="full", layer_ids=[0, 1, 16, 17, 18, 19], **_CHIP))
@@ -173,6 +176,10 @@ PROGRAMS = {
     # nothing; Laguna's and JoyAI-LLM-Flash's dense layer keeps its gate and
     # up as well, in a size that does not move)
     "phi4flash_1x1": (PHI4FLASH, "dp=1", 1, 1, "adamw", 14.85),
+    # ONE sequence of 16,384 tokens a step in one microbatch, behind a
+    # learned index: the packed selections and the index loss's gradients
+    # are kept whatever the room (PR 61)
+    "keye_1x1": (KEYE, "dp=1", 1, 1, "adamw", 15.3),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -209,6 +216,10 @@ ATTENTION_KERNELS = {
     # and the cross layer on the looped side, their results kept
     "phi4flash_1x1": {"diff_bwd": 2, "diff_fwd": 2, "swa_bwd_dkv": 1,
                       "swa_bwd_dq": 1, "swa_fwd": 2},
+    # six scanned layers under the index's packed selection, the forward's
+    # results kept (weighed by the causal pairs the kernel visits: 16,133
+    # FLOP a byte), k and v beside them
+    "keye_1x1": {"dsa_bwd": 1, "dsa_fwd": 1},
 }
 
 #: name -> the Mamba mixers' kernels (ops/ssd.py: Mamba-2's scan ``ssd_*``,
@@ -456,7 +467,7 @@ def main() -> None:
             over.append(f"{name}: {gib:.3f} GiB a device, over its {limit}")
         attention = {kernel: n for kernel, n in kernels.items()
                      if kernel.startswith(("flash_", "mla_", "swa_", "bd_",
-                                           "diff_"))}
+                                           "diff_", "dsa_"))}
         if attention != ATTENTION_KERNELS.get(name, attention):
             over.append(f"{name}: attention kernels {attention}, not "
                         f"{ATTENTION_KERNELS[name]}")

@@ -430,6 +430,47 @@ def test_sdars_attention_compiles_at_16k_rows_under_the_block_mask(v5e_2x2):
                                                    wide, wide]
 
 
+def test_keyes_indexed_attention_compiles_at_16k_rows(v5e_2x2):
+    """Keye's attention at the cell's shape, forward and backward: 16,384
+    rows, 32 query heads over 4 key/value heads of 128 behind an index of 16
+    heads of 64, top-2,048 — the index's two kernels (the 256-query cell's
+    ``[16384, 256]`` scratch of ordered scores; the loss with its three
+    gradients), the looped kernels under the packed selection's names, the
+    norm inside the rotary kernel; no ``[16384, 16384]`` array in HBM."""
+    from easydl_tpu.ops.attention import indexed_attention
+    from easydl_tpu.ops.rope import rope_tables
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def loss(q, k, v, a, b, w, gain):
+        out, own, _ = indexed_attention(
+            q, k, v, a, b, w, topk=2048, impl="flash",
+            rope=rope_tables(16384, 128, 1e7), qk_norm=(gain, gain, 1e-6))
+        return out.astype(jnp.float32).sum() + own
+
+    text = jax.jit(jax.grad(loss, argnums=range(6))).lower(
+        shape(1, 16384, 32, 128), shape(1, 16384, 4, 128),
+        shape(1, 16384, 4, 128), shape(1, 16384, 16, 64), shape(1, 16384, 64),
+        shape(1, 16384, 16, dtype=jnp.float32),
+        shape(128, dtype=jnp.float32)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("index_select", "index_kl", "dsa_fwd", "dsa_bwd",
+                 "rope_norm_fwd", "rope_norm_bwd"):
+        assert sum(f"/{name}/" in line for line in calls) >= 1, name
+    for other in ("flash_fwd", "flash_bwd"):
+        assert not any(f"/{other}/" in line for line in calls), other
+    # the selection reaches both attention kernels a bit a pair, a Q-block a
+    # leading index; nothing [L, L] and wider than a bit stands anywhere
+    assert "s32[1,32,512,512]" in _operand_shapes(text, "dsa_fwd")
+    assert "s32[1,32,512,512]" in _operand_shapes(text, "dsa_bwd")
+    assert not re.search(r"(f32|bf16|s8|pred)\[(1,)?(\d+,)?16384,16384\]",
+                         text)
+
+
 def test_sdars_attention_norms_q_and_k_inside_the_rotary_kernel(v5e_2x2):
     """As the test above, with the kind's gains handed in
     (``multihead_attention(qk_norm=)``): at 16,384 rows of 32 | 4 heads of
