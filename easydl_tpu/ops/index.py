@@ -46,9 +46,19 @@ Pallas one never does:
   again a tile of 128 keys x 256 queries, ``p`` and ``softmax(I)`` formed
   under the unpacked bits, ``dI = (softmax(I) - p) / n`` pushed back through
   relu into ``da``, ``db`` (summed over the query blocks in a block that
-  stays resident) and ``dw``. The differentiation rule's forward IS that
-  pass; its residuals are the three gradients, named for remat
-  (``ops/remat.py INDEX_GRADS``) so that the kernel runs once a layer and step.
+  stays resident) and ``dw``. A TILE keeps each index head's relu'd scores
+  in VMEM from the loop that sums ``I`` to the loop that pushes ``dI`` back,
+  so every score product is made once; the head weights stay out of that
+  second loop: it multiplies ``v_j = dI * [s_j > 0]`` (no ``w``) into the
+  query gradient's accumulator ``sum_s b[s] v_j[s, t]`` and, for ``db``,
+  with ``w[t, j] * a[t, j]``, which a QUERY BLOCK forms once at its first
+  program. At the block's last program ``da[t, j]`` is ``w[t, j]`` times the
+  accumulator and ``dw[t, j]`` the accumulator's product with ``a[t, j]``
+  along ``dim`` — ``relu(x) = x * [x > 0]``, nothing is divided by ``w``,
+  a weight of exactly zero keeps its gradient. The differentiation rule's
+  forward IS that pass; its residuals are the three gradients, named for
+  remat (``ops/remat.py INDEX_GRADS``) so that the kernel runs once a layer
+  and step.
 """
 
 from __future__ import annotations
@@ -386,24 +396,33 @@ def select(a: jax.Array, b: jax.Array, w: jax.Array, *, topk: int,
 
 
 def _kl_kernel(a_ref, b_ref, w_ref, q_ref, k_ref, lse_ref, words_ref,
-               lsei_ref, loss_ref, da_ref, db_ref, dw_ref, dat_sum, dw_sum,
+               lsei_ref, loss_ref, da_ref, db_ref, dw_ref, dat_sum, aw, kept,
                stats, *, heads: int, dim: int, q_heads: int, kv_heads: int,
                head_dim: int, scale: float, inv_n: float, chunk: int):
     # a_ref, da_ref [queries, heads * dim]; b_ref [chunk, dim]; w_ref, dw_ref
     # [heads, queries] float32; q_ref [queries, q_heads * head_dim]; k_ref
     # [chunk, kv_heads * head_dim]; lse_ref [q_heads, queries]; words_ref
     # [chunk / 32, queries]; lsei_ref, loss_ref [1, queries]; db_ref [L, dim]
-    # float32, resident; scratch float32: dat_sum [heads * dim, queries],
-    # dw_sum [heads, queries], stats [3, queries]
+    # float32, resident. Scratch: kept [heads, TILE, queries] float32, a
+    # TILE's relu(scores) head by head — made ONCE, by the loop that sums the
+    # index, and read again by the loop that pushes dI back through relu;
+    # aw [heads * dim, queries] in a's dtype, a QUERY BLOCK's w[t, j] *
+    # a[t, j] turned, formed at its first program; dat_sum [heads * dim,
+    # queries] float32, sum_s b[s] * dI[s, t] * [s_j > 0] WITHOUT w — at the
+    # block's last program da is w times it and dw its product with a along
+    # dim (relu(x) = x * [x > 0]); stats [3, queries] float32
     n_q = a_ref.shape[0]
     qi, ki = pl.program_id(1), pl.program_id(2)
     last = (qi * n_q + n_q - 1) // chunk
+    heads_rows = [slice(j * dim, (j + 1) * dim) for j in range(heads)]
 
     @pl.when(ki == 0)
     def _():
         dat_sum[...] = jnp.zeros_like(dat_sum)
-        dw_sum[...] = jnp.zeros_like(dw_sum)
         stats[...] = jnp.zeros_like(stats)
+        a_t = a_ref[...].astype(jnp.float32).T
+        for j, mine in enumerate(heads_rows):
+            aw[mine, :] = (a_t[mine, :] * w_ref[j:j + 1, :]).astype(aw.dtype)
 
     @pl.when((qi == 0) & (ki == 0))
     def _():
@@ -419,9 +438,10 @@ def _kl_kernel(a_ref, b_ref, w_ref, q_ref, k_ref, lse_ref, words_ref,
                 words_ref[group * _ROWS:(group + 1) * _ROWS, :],
                 at // TILE % 2) != 0
             index = jnp.zeros((TILE, n_q), jnp.float32)
-            for j in range(heads):
-                s = _dot(bk, a_ref[:, j * dim:(j + 1) * dim], _NT)
-                index = index + w_ref[j:j + 1, :] * jnp.maximum(s, 0.0)
+            for j, mine in enumerate(heads_rows):
+                r = jnp.maximum(_dot(bk, a_ref[:, mine], _NT), 0.0)
+                kept[j] = r
+                index = index + w_ref[j:j + 1, :] * r
             p = jnp.zeros((TILE, n_q), jnp.float32)
             for h in range(q_heads):
                 g = h // ratio
@@ -440,23 +460,22 @@ def _kl_kernel(a_ref, b_ref, w_ref, q_ref, k_ref, lse_ref, words_ref,
             d_index = (soft - p) * inv_n
             bkt = bk.T
             db = jnp.zeros((TILE, dim), jnp.float32)
-            for j in range(heads):
-                mine = slice(j * dim, (j + 1) * dim)
-                aj = a_ref[:, mine]
-                s = _dot(bk, aj, _NT)
-                dw_sum[j:j + 1, :] += jnp.sum(
-                    d_index * jnp.maximum(s, 0.0), axis=0, keepdims=True)
-                u = jnp.where(s > 0.0, d_index * w_ref[j:j + 1, :],
-                              0.0).astype(aj.dtype)
-                dat_sum[mine, :] += _dot(bkt, u, _NN)
-                db = db + _dot(u, aj, _NN)
+            for j, mine in enumerate(heads_rows):
+                v = jnp.where(kept[j] > 0.0, d_index, 0.0).astype(aw.dtype)
+                dat_sum[mine, :] += _dot(bkt, v, _NN)
+                db = db + _dot(v, aw[mine, :], _NT)
             rows = pl.ds(pl.multiple_of(ki * chunk + at, TILE), TILE)
             db_ref[rows, :] += db
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _():
+        a_t = a_ref[...].astype(jnp.float32).T
+        for j, mine in enumerate(heads_rows):
+            unweighted = dat_sum[mine, :]
+            dw_ref[j:j + 1, :] = jnp.sum(a_t[mine, :] * unweighted, axis=0,
+                                         keepdims=True)
+            dat_sum[mine, :] = unweighted * w_ref[j:j + 1, :]
         da_ref[...] = dat_sum[...].T.astype(da_ref.dtype)
-        dw_ref[...] = dw_sum[...]
         loss_ref[...] = (stats[0:1, :] - stats[1:2, :]
                          + lsei_ref[...] * stats[2:3, :])
 
@@ -483,7 +502,8 @@ def _kl_call(a, b, w, q, k, lse, words, lse_i, *, scale: float, chunk: int,
     held = (2 * n_q * (heads * dim * 2 * a.dtype.itemsize
                        + q_heads * head_dim * q.dtype.itemsize)
             + 2 * chunk * (kv_heads * head_dim + 128) * 4
-            + 2 * seq * max(dim, 128) * 4 + heads * dim * n_q * 4)
+            + 2 * seq * max(dim, 128) * 4
+            + heads * n_q * (dim * (4 + a.dtype.itemsize) + TILE * 4))
     loss, da, db, dw = pl.pallas_call(
         functools.partial(
             _kl_kernel, heads=heads, dim=dim, q_heads=q_heads,
@@ -507,7 +527,8 @@ def _kl_call(a, b, w, q, k, lse, words, lse_i, *, scale: float, chunk: int,
             jax.ShapeDtypeStruct((batch, seq, dim), jnp.float32),
             jax.ShapeDtypeStruct((batch, heads, seq), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((heads * dim, n_q), jnp.float32),
-                        pltpu.VMEM((heads, n_q), jnp.float32),
+                        pltpu.VMEM((heads * dim, n_q), a.dtype),
+                        pltpu.VMEM((heads, TILE, n_q), jnp.float32),
                         pltpu.VMEM((3, n_q), jnp.float32)],
         interpret=interpret, name="index_kl",
         compiler_params=pltpu.CompilerParams(

@@ -26,17 +26,17 @@ HEADS, KV, DIM = 2, 1, 128
 IX_HEADS, IX_DIM = 2, 64
 
 
-def _inputs(seed: int, whole: bool):
+def _inputs(seed: int, whole: bool, seq: int = SEQ):
     """q, k, v and the index's a, b, w; ``whole``: the index's are small
     integers (over 4 for w), so that every product is exact on both paths
     and TIES are many."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    q = jax.random.normal(ks[0], (1, SEQ, HEADS, DIM))
-    k = jax.random.normal(ks[1], (1, SEQ, KV, DIM))
-    v = jax.random.normal(ks[2], (1, SEQ, KV, DIM))
-    a = jax.random.normal(ks[3], (1, SEQ, IX_HEADS, IX_DIM))
-    b = jax.random.normal(ks[4], (1, SEQ, IX_DIM))
-    w = jax.random.normal(ks[5], (1, SEQ, IX_HEADS)) * 0.1
+    q = jax.random.normal(ks[0], (1, seq, HEADS, DIM))
+    k = jax.random.normal(ks[1], (1, seq, KV, DIM))
+    v = jax.random.normal(ks[2], (1, seq, KV, DIM))
+    a = jax.random.normal(ks[3], (1, seq, IX_HEADS, IX_DIM))
+    b = jax.random.normal(ks[4], (1, seq, IX_DIM))
+    w = jax.random.normal(ks[5], (1, seq, IX_HEADS)) * 0.1
     if whole:
         a, b, w = jnp.round(a), jnp.round(b), jnp.round(w * 20) / 4
     return q, k, v, a, b, w
@@ -130,6 +130,77 @@ def test_kernels_match_the_xla_path_under_the_written_out_mask():
         lambda *x: _objective("flash")(*x)[1][1], argnums=range(6)))(*args, g)
     for name, grad in zip("qkv", only_loss[:3]):
         assert not np.asarray(grad).any(), name
+
+
+def _kl_case(name, seq=SEQ, dtype=jnp.float32, tol=2e-4, whole=False,
+             zeroed=False):
+    """A case of the test below: 512 rows, float32 operands held to 2e-4 of
+    a gradient's largest entry and inputs as drawn, but for what it names."""
+    return pytest.param(seq, dtype, tol, whole, zeroed, id=name)
+
+
+@pytest.mark.parametrize("seq, dtype, tol, whole, zeroed", [
+    _kl_case("float32"),
+    _kl_case("bfloat16", dtype=jnp.bfloat16, tol=1e-2),
+    _kl_case("a-head-weighs-nothing-on-some-queries", zeroed=True),
+    _kl_case("blocks-finish-after-dead-chunks", seq=768),
+    _kl_case("scores-of-exactly-zero", whole=True)])
+def test_index_kl_is_the_written_out_loss_and_its_gradients(
+        seq, dtype, tol, whole, zeroed, monkeypatch):
+    """``index_kl`` alone (interpreted; query blocks of 256, key chunks of
+    256) against ``_kl_reference`` in float32 on the SAME values and under
+    the same selection: the loss and ``da``, ``db``, ``dw`` entry by entry.
+    A tile's relu(scores) are made once and kept, ``w`` meets the sums a
+    query block at a time and ``dw`` comes from the query gradient's
+    accumulator — so: bf16 operands (the products read ``bf16(dI)`` and
+    ``bf16(w * a)``) beside float32's; weights of both signs and, on every
+    third query, one of exactly zero, whose ``da`` is zero and whose ``dw``
+    is not (nothing is divided by ``w``); three query blocks whose finish
+    runs at the grid's last program, up to two dead chunks after their last
+    live one; and scores of exactly 0.0 (whole-number inputs), through which
+    no gradient goes."""
+    q, k, _, a, b, w = _inputs(5, whole, seq)
+    assert bool((w < 0).any()) and bool((w > 0).any())
+    if zeroed:
+        w = w.at[:, ::3, 0].set(0.0)
+    a, b, q, k = (x.astype(dtype) for x in (a, b, q, k))
+    scale = DIM ** -0.5
+    words, lse_i, _ = index.select(a, b, w, topk=TOPK, kernels=False,
+                                   chunk=256)
+    chosen = index.unpack(words)
+    a32, b32, q32, k32 = (x.astype(jnp.float32) for x in (a, b, q, k))
+    s = jnp.einsum("bthd,bshd->bhts", q32,
+                   jnp.repeat(k32, HEADS // KV, axis=2)) * scale
+    lse = jax.nn.logsumexp(jnp.where(chosen[:, None], s, -jnp.inf), axis=-1)
+    if whole:
+        # the reference's relu gives a zero no gradient either (``maximum``
+        # would halve it), and the case is reached: selected pairs at 0.0
+        monkeypatch.setattr(
+            index, "scores_reference", lambda a, b, w: jnp.einsum(
+                "bhts,bth->bts", jax.nn.relu(jnp.einsum(
+                    "bthd,bsd->bhts", a, b)), w) + 0.0)
+        zeros = (jnp.einsum("bthd,bsd->bhts", a32, b32) == 0.0) \
+            & chosen[:, None]
+        assert int(zeros.sum()) > 100
+
+    (loss, grads), (loss_r, grads_r) = (
+        jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(*x) for f, x in (
+            (lambda a, b, w: index.kl(
+                a, b, w, q, k, lse, words, lse_i, scale=scale, kernels=True,
+                chunk=256, interpret=True), (a, b, w)),
+            (lambda a, b, w: index._kl_reference(
+                a, b, w, q32, k32, words, scale), (a32, b32, w))))
+    np.testing.assert_allclose(loss, loss_r, rtol=1e-5)
+    for name, mine, theirs in zip(("da", "db", "dw"), grads, grads_r):
+        assert mine.dtype == (jnp.float32 if name == "dw" else dtype), name
+        largest = float(jnp.max(jnp.abs(theirs)))
+        assert largest > 0, name
+        np.testing.assert_allclose(mine.astype(jnp.float32), theirs,
+                                   atol=tol * largest, err_msg=name)
+    if zeroed:
+        da, _, dw = (np.asarray(x) for x in grads)
+        assert not da[:, ::3, 0].any()
+        assert (dw[:, ::3, 0] != 0).mean() > 0.9
 
 
 def test_a_pair_outside_the_selection_adds_nothing():
