@@ -444,10 +444,15 @@ def lm_bundle(cfg: TransformerConfig, name: str, *,
                 if cfg.block_diffusion \
                 else _lm_loss_from(params, batch, mutable=True)
             summed = mut["counters"]["moe"][0]
-            counters = {name: summed[i] / (1 if name == "moe_dropped"
-                                           else n_sparse)
+            counters = {name: summed[i] / (
+                1 if name == "moe_dropped" else cfg.counted_layers
+                if name in cfg.mixer_counters else n_sparse)
                         for i, name in enumerate(cfg.counters)
                         if name not in summed_index}
+            if cfg.kda is not None and cfg.mixer_counters:
+                # static: the chunks a layer's recurrence walks
+                counters["kda_chunks"] = jnp.float32(
+                    -(-batch["inputs"].shape[1] // cfg.kda.chunk))
             if cfg.router_state_width:
                 counters["router_state_rms"] = \
                     mut["counters"]["router_state_rms"][0]

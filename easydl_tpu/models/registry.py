@@ -14,6 +14,7 @@ _REGISTRY: Dict[str, Callable[..., "ModelBundle"]] = {}
 #: modules under easydl_tpu.models that register factories on import
 _MODULES = ("mlp", "resnet", "bert", "gpt", "granite_hybrid", "ouro", "laguna",
             "zaya", "joyai", "nemotron_h", "mellum", "sdar", "phi4flash", "keye",
+            "kimi_linear",
             "deepfm")
 
 

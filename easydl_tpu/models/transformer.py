@@ -38,6 +38,7 @@ from easydl_tpu.ops.attention import (indexed_attention, norm_heads,
                                       rotate_heads)
 from easydl_tpu.ops.flash_attention import BlockDiffusion
 from easydl_tpu.ops import moe as moe_ops
+from easydl_tpu.ops.kda import gated_head_norm, kda, kda_flops_per_token
 from easydl_tpu.ops.moe import MoeMlp
 from easydl_tpu.ops.rope import apply_rope, rms_norm, rope_tables
 from easydl_tpu.ops.selective_scan import (selective_scan,
@@ -205,16 +206,21 @@ class LatentMix:
 class LowRank:
     """Multi-head latent attention (MLA; DeepSeek-V2 / V3, arXiv:2412.19437
     section 2.1.1): q through a bottleneck of ``q_rank`` with an RMSNorm
-    inside it, k and v up from ONE shared latent of ``kv_rank`` with an
-    RMSNorm of its own. A head scores with ``nope_dim`` dimensions without
+    inside it (``None``: no bottleneck, q straight from the model's width to
+    ``heads x head_size``: ``q_lora_rank`` null), k and v up from ONE shared
+    latent of ``kv_rank`` with an RMSNorm of its own. A head scores with
+    ``nope_dim`` dimensions without
     positions beside ``rope_dim`` rotated ones (the kind's rotary scheme:
     its rotary dimensions, the head's last), and the rotated key part is ONE
     vector a token, made beside the latent and shared by every head; a
     head's value is ``value_dim`` wide, so the way back up reads ``heads x
     value_dim``. The description's ``head_size`` is ``nope_dim +
-    rope_dim``."""
+    rope_dim``. A kind without a rotary scheme (``AttentionKind.rope`` None,
+    in a description without positions) rotates NOTHING: q's last
+    ``rope_dim`` lanes and the key's one shared vector are used as they come
+    (Kimi Linear's ``mla_use_nope``)."""
 
-    q_rank: int
+    q_rank: Optional[int]
     kv_rank: int
     nope_dim: int
     rope_dim: int
@@ -244,6 +250,14 @@ class LearnedIndex:
     topk: int
     q_chunk: int = 512
     kv_chunk: int = 512
+
+
+#: the counters a delta-rule layer hands out behind the expert layers' (summed
+#: over the layers; ``models/lm.py`` gives their mean over the kda layers and
+#: the static ``kda_chunks`` beside them): the mean ``alpha`` over tokens and
+#: channels, the mean step size, the final state's root mean square — what
+#: says, from a run's log, whether the state forgets, saturates or blows up
+KDA_COUNTERS = ("kda_decay_mean", "kda_beta_mean", "kda_state_rms")
 
 
 #: the counters an index layer hands out beside the expert layers' (summed
@@ -389,6 +403,22 @@ class SsmConfig:
 
 
 @dataclass(frozen=True)
+class KdaConfig:
+    """Widths of the ``kda`` mixers (Kimi Delta Attention, ``ops/kda.py``):
+    ``n_heads`` heads whose keys and values are both ``head_dim`` wide, a
+    state ``[head_dim, head_dim]`` a head; ``d_conv`` taps of the three
+    convolutions; the decay's and the output gate's low-rank maps pass
+    through ``head_dim`` (the published layer's ``head_v_dim``); ``chunk``
+    tokens a chunk of the recurrence (``ops/kda.py``: 128 measured against
+    64, PERF.md section 6, PR 64)."""
+
+    n_heads: int = 32
+    head_dim: int = 128
+    d_conv: int = 4
+    chunk: int = 128
+
+
+@dataclass(frozen=True)
 class Mamba1Config:
     """Widths of the ``mamba1`` mixers and the ``gmu`` units behind them
     (``ops/selective_scan.py``): ``d_inner`` channels, each with ``d_state``
@@ -486,6 +516,8 @@ class TransformerConfig:
     ssm: Optional[SsmConfig] = None
     #: widths of the ``mamba1`` mixers and ``gmu`` units, where it has any
     mamba1: Optional[Mamba1Config] = None
+    #: widths of the ``kda`` mixers, where the description has any
+    kda: Optional[KdaConfig] = None
     #: a head's size where it is not ``d_model // n_heads`` (0: it is)
     head_size: int = 0
     #: named attention kinds a layer's mixer may be, ``(name, kind)`` pairs
@@ -539,6 +571,10 @@ class TransformerConfig:
             if heads % self.kv_heads:
                 raise ValueError(f"{heads} heads do not divide into "
                                  f"{self.kv_heads} key/value heads")
+        for mixer in dict.fromkeys(m for m, _ in self.every_layer):
+            family, kind = self.mixer_family(mixer)
+            if kind is None:  # a family written by name: its own check
+                family.check(self, mixer, None)
         if self.has_moe and (self.loops > 1 or self.pipeline_fn is not None):
             raise NotImplementedError(
                 "moe layers in a looped stack or inside the pipeline")
@@ -689,7 +725,23 @@ class TransformerConfig:
         behind them an index's (:data:`INDEX_COUNTERS`)."""
         moe = moe_ops.counters(self.moe.skip_choice, self.moe.router) \
             if self.has_moe else ()
-        return moe + (INDEX_COUNTERS if self.index_layers else ())
+        return moe + (INDEX_COUNTERS if self.index_layers else ()) \
+            + self.mixer_counters
+
+    @property
+    def mixer_counters(self) -> Tuple[str, ...]:
+        """The counters the stack's mixer families hand out themselves
+        (``MixerFamily.counters``: a delta-rule layer's), behind the expert
+        layers' and an index's."""
+        return tuple(dict.fromkeys(
+            name for mixer, _ in self.pattern
+            for name in self.mixer_family(mixer)[0].counters))
+
+    @property
+    def counted_layers(self) -> int:
+        """Layers whose mixer hands out counters of its own."""
+        return sum(1 for mixer, _ in self.pattern
+                   if self.mixer_family(mixer)[0].counters)
 
     @property
     def index_layers(self) -> int:
@@ -822,6 +874,12 @@ def _uniform_init(fan_in: int):
     return init
 
 
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """Mamba-2's own start of a head's rate: ``log A`` with A from U(1, 16)
+    (the delta rule's decay starts the same way)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
 def _dt_bias_init(key, shape, dtype=jnp.float32):
     """Mamba's own start of a step's bias: dt from ``exp(U(log 1e-3, log
     1e-1))``, at least 1e-4, through the inverse of softplus."""
@@ -941,17 +999,22 @@ def _latent_attention(block, kind, h, rope):
     n_heads = kind.n_heads or cfg.n_heads
     dt = jnp.dtype(cfg.dtype)
     with jax.named_scope("mla_down"):
-        c_q = _projection(block, low.q_rank, ("embed", None), (None,),
-                          "q_a")(h)
+        c_q = None if low.q_rank is None else _projection(
+            block, low.q_rank, ("embed", None), (None,), "q_a")(h)
         c_kv = _projection(block, low.kv_rank + low.rope_dim,
                            ("embed", None), (None,), "kv_a")(h)
         c_kv, k_rot = c_kv[..., :low.kv_rank], c_kv[..., low.kv_rank:]
     with jax.named_scope("mla_norm"):
-        c_q = _rms(block, "q_norm", c_q, cfg.norm_eps)
+        if c_q is not None:
+            c_q = _rms(block, "q_norm", c_q, cfg.norm_eps)
         c_kv = _rms(block, "kv_norm", c_kv, cfg.norm_eps)
     with jax.named_scope("mla_up"):
+        # without a bottleneck q comes straight from the model's width
         q = _projection(block, (n_heads, cfg.head_dim), (None, "heads", "kv"),
-                        ("heads", "kv"), "q_b", rows=True)(c_q)
+                        ("heads", "kv"), "q_b", rows=True)(c_q) \
+            if c_q is not None else _projection(
+                block, (n_heads, cfg.head_dim), ("embed", "heads", "kv"),
+                ("heads", "kv"), "q", rows=True)(h)
         kv_b = jnp.asarray(block.param(
             "kv_b", nn.with_logical_partitioning(
                 nn.initializers.normal(stddev=0.02), (None, "heads", "kv")),
@@ -961,13 +1024,17 @@ def _latent_attention(block, kind, h, rope):
             .reshape(*c_kv.shape[:2], n_heads, -1)
             for half in (kv_b[..., :low.nope_dim], kv_b[..., low.nope_dim:]))
     q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
-    # one pair of tables: the key's lone vector is a head's rotated part
-    q = rotate_heads(q, rope, rotary_dim=low.rope_dim,
-                     interleaved=kind.rope.interleaved,
-                     impl=cfg.attention_impl)
-    k_rot = apply_rope(k_rot[:, :, None, :],
-                       *(t[:, -low.rope_dim:] for t in rope),
-                       interleaved=kind.rope.interleaved)
+    if kind.rope is None:
+        # no positions: q's last lanes and the shared key vector as they come
+        k_rot = k_rot[:, :, None, :]
+    else:
+        # one pair of tables: the key's lone vector is a head's rotated part
+        q = rotate_heads(q, rope, rotary_dim=low.rope_dim,
+                         interleaved=kind.rope.interleaved,
+                         impl=cfg.attention_impl)
+        k_rot = apply_rope(k_rot[:, :, None, :],
+                           *(t[:, -low.rope_dim:] for t in rope),
+                           interleaved=kind.rope.interleaved)
     with jax.named_scope("mla_key"):
         k = jnp.concatenate([k_nope, jnp.broadcast_to(
             k_rot, (*k_nope.shape[:3], low.rope_dim))], -1)
@@ -980,7 +1047,8 @@ def _latent_attention(block, kind, h, rope):
     # where a caller makes `intermediates` mutable: a check, a test)
     for name, value in (("in", h), ("cq", c_q), ("ckv", c_kv), ("q", q),
                         ("k_rot", k_rot), ("attn", attn)):
-        block.sow("intermediates", f"mla_{name}", value)
+        if value is not None:
+            block.sow("intermediates", f"mla_{name}", value)
     with jax.named_scope("mla_out"):
         return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
                            ("embed",), "out", residual=True, axis=(-2, -1),
@@ -1151,11 +1219,8 @@ def _mamba2(block, u):
             init, ("heads",)), (m.n_heads,))
 
     # Mamba-2's own: dt's bias (`_dt_bias_init`), A from U(1, 16), D ones
-    def a_log_init(key, shape, dtype=jnp.float32):
-        return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
     dt_bias = per_head("dt_bias", _dt_bias_init)
-    a_log = per_head("A_log", a_log_init)
+    a_log = per_head("A_log", _a_log_init)
     skip = per_head("D", nn.initializers.ones_init())
     dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
     with jax.named_scope("ssd"):
@@ -1173,6 +1238,76 @@ def _mamba2(block, u):
     return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
                        ("embed",), "out", residual=True, axis=(-2, -1)
                        )(normed)
+
+
+def _kda(block, u):
+    """The Kimi Delta Attention mixer (arXiv:2510.26692; ``fla``'s
+    ``KimiDeltaAttention``) on the normed input ``u``: ``(the layer's output,
+    its three counters of KDA_COUNTERS)``. Scopes: ``conv1d`` (q's, k's and
+    v's OWN causal convolutions, no bias, a SiLU behind each: ``ops/ssd.py``'s
+    kernels), ``kda_gates`` (the decay a channel through its low-rank map and
+    softplus, the step sizes, the L2 norms of q and k a head), ``kda`` (the
+    recurrence, ``ops/kda.py``: its Pallas kernels on a TPU where the widths
+    tile, else ``jax.numpy``) and ``gated_norm`` (the RMSNorm a head with one
+    shared gain, THEN a sigmoid gate through its low-rank map). The decays,
+    the step sizes, the norms' statistics and the state are float32."""
+    cfg, m = block.cfg, block.cfg.kda
+    f32, dt_ = jnp.float32, jnp.dtype(cfg.dtype)
+    heads, kv = ("embed", "heads", "kv"), ("heads", "kv")
+    shape = (m.n_heads, m.head_dim)
+    q, k, v = remat.name_products(tuple(
+        _projection(block, shape, heads, kv, name)(u)
+        for name in ("q", "k", "v")), u.shape[-1], "maps")
+
+    with jax.named_scope("conv1d"):
+        # torch's Conv1d default, fan_in the taps of a depthwise filter
+        q, k, v = (causal_conv1d_silu(a, block.param(
+            f"conv_{name}", nn.with_logical_partitioning(
+                _uniform_init(m.d_conv), (None,) + kv), (m.d_conv,) + shape))
+            for name, a in (("q", q), ("k", k), ("v", v)))
+
+    def low_rank(name):
+        """``u`` through ``head_dim`` and up to a value a channel."""
+        down = _projection(block, m.head_dim, ("embed", None), (None,),
+                           f"{name}_a")(u)
+        return _projection(block, shape, (None, "heads", "kv"), kv,
+                           f"{name}_b")(down)
+
+    with jax.named_scope("kda_gates"):
+        a_log = block.param("A_log", nn.with_logical_partitioning(
+            _a_log_init, ("heads",)), (m.n_heads,))
+        dt_bias = block.param("dt_bias", nn.with_logical_partitioning(
+            _dt_bias_init, kv), shape)
+        g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+            low_rank("f").astype(f32) + dt_bias)
+        beta = jax.nn.sigmoid(_projection(
+            block, m.n_heads, ("embed", "heads"), ("heads",), "b")(u)
+            .astype(f32))
+
+        def l2norm(x, scale=1.0):
+            x = x.astype(f32)
+            return (x * (jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                       + 1e-6) * scale)).astype(dt_)
+
+        q, k = l2norm(q, m.head_dim ** -0.5), l2norm(k)
+    with jax.named_scope("kda"):
+        o, last = kda(q, k, v, g, beta, chunk=m.chunk, impl="xla"
+                      if cfg.attention_impl == "reference" else "auto")
+    with jax.named_scope("gated_norm"):
+        gain = _gain(block, "norm_gated", m.head_dim)
+        gate = low_rank("g")
+        normed = gated_head_norm(o, gate, gain, cfg.norm_eps).astype(dt_)
+    # what the mixer's parts were given and gave
+    for name, value in (("in", u), ("q", q), ("k", k), ("v", v), ("g", g),
+                        ("beta", beta), ("o", o), ("state", last),
+                        ("gate", gate), ("normed", normed)):
+        block.sow("intermediates", f"kda_{name}", value)
+    counted = jax.lax.stop_gradient(jnp.stack([
+        jnp.mean(jnp.exp(g)), jnp.mean(beta),
+        jnp.sqrt(jnp.mean(jnp.square(last)))]))
+    return _projection(block, cfg.d_model, ("heads", "kv", "embed"),
+                       ("embed",), "out", residual=True, axis=(-2, -1)
+                       )(normed), counted
 
 
 def _diff_lambda(vector, init: float):
@@ -1337,7 +1472,8 @@ class MixerFamily(NamedTuple):
     holds its widths, as ``field=Class``; ``check(cfg, name, kind)`` refuses
     the pairings that are its own (``__post_init__``); ``written``: a layer
     may write the family's name as its mixer (an attention kind alone
-    reaches the others)."""
+    reaches the others); ``counters``: names of the numbers its layers hand
+    out beside their result."""
 
     scope: str
     norm: str
@@ -1350,6 +1486,9 @@ class MixerFamily(NamedTuple):
     needs: str = ""
     check: Callable = lambda cfg, name, kind: None
     written: bool = True
+    #: the counters its layers hand out (``given["counters"]``, one number
+    #: each, summed over the layers beside the expert layers')
+    counters: Tuple[str, ...] = ()
 
 
 def _attention_params(cfg, kind):
@@ -1378,8 +1517,10 @@ def _attention_params(cfg, kind):
 
 def _lowrank_params(cfg, kind):
     d, low, heads = cfg.d_model, kind.lowrank, kind.n_heads or cfg.n_heads
-    # down, the norm's gain and up, for q and for k / v; the way back
-    return ((d + 1 + heads * cfg.head_dim) * low.q_rank
+    # down, the norm's gain and up, for q (one map where it has no
+    # bottleneck) and for k / v; the way back
+    return ((d * heads * cfg.head_dim if low.q_rank is None
+             else (d + 1 + heads * cfg.head_dim) * low.q_rank)
             + d * (low.kv_rank + low.rope_dim) + low.kv_rank
             + low.kv_rank * heads * (low.nope_dim + low.value_dim)
             + heads * low.value_dim * d)
@@ -1454,15 +1595,18 @@ def _check_attention(cfg, name, kind):
 
 def _check_lowrank(cfg, name, kind):
     low = kind.lowrank
-    if low.nope_dim + low.rope_dim != cfg.head_dim or kind.rope is None or \
-            kind.rope.rotary_dim != low.rope_dim or kind.latent or \
-            kind.gate or kind.window or \
+    # no scheme of its own is NO rotation, in a description without positions
+    rotation = cfg.position == "none" if kind.rope is None \
+        else kind.rope.rotary_dim == low.rope_dim
+    if low.nope_dim + low.rope_dim != cfg.head_dim or not rotation or \
+            kind.latent or kind.gate or kind.window or \
             cfg.kv_heads != (kind.n_heads or cfg.n_heads):
         raise ValueError(
             f"attention kind {name!r}: low-rank latent attention scores with "
             f"head_size = nope_dim + rope_dim, rotates rope_dim by its own "
-            f"scheme, has as many key/value heads as query heads and no "
-            f"window, gate or mix")
+            f"scheme (or, with none, nothing: then the description's "
+            f"position is 'none'), has as many key/value heads as query "
+            f"heads and no window, gate or mix")
     _check_attention(cfg, name, kind)
 
 
@@ -1504,6 +1648,32 @@ def _apply_diff(block, kind, h, rope, handed):
     out, kv = _diff_attention(
         block, kind, h, handed.get("kv") if kind.kv == "takes" else None)
     return out, {"kv": kv}
+
+
+def _apply_kda(block, kind, h, rope, handed):
+    """The delta-rule mixer; its counters ride out as the index's do
+    (``given["counters"]``: the block puts them behind the FFN's)."""
+    out, counted = _kda(block, h)
+    return out, {"counters": counted}
+
+
+def _check_kda(cfg, name, kind):
+    if cfg.attention_fn is not None or cfg.loops > 1 or \
+            cfg.pipeline_fn is not None:
+        raise NotImplementedError(
+            f"a {name} layer under sequence parallelism (attention_fn), in a "
+            f"looped stack or inside the pipeline: the recurrence's state is "
+            f"not handed across a boundary between the sequence's shards "
+            f"(TransformerConfig.__post_init__ refuses it)")
+
+
+def _kda_params(cfg, kind):
+    d, m = cfg.d_model, cfg.kda
+    inner = m.n_heads * m.head_dim
+    return (4 * d * inner + 3 * m.d_conv * inner       # q, k, v, out; taps
+            + 2 * (d + inner) * m.head_dim             # the two low-rank maps
+            + m.n_heads + inner                        # A_log, dt_bias
+            + d * m.n_heads + m.head_dim)              # beta, the norm's gain
 
 
 def _apply_mamba1(block, kind, h, rope, handed):
@@ -1556,6 +1726,12 @@ MIXER_FAMILIES = {
         params=lambda cfg, kind: 2 * cfg.d_model * cfg.mamba1.d_inner,
         score_flops=lambda cfg, kind, seq_len: 0.0,
         takes=lambda kind: "memory", needs="mamba1=Mamba1Config", **_SSM),
+    "kda": MixerFamily(  # forward and twice that backward, no chunk in it
+        apply=_apply_kda, params=_kda_params,
+        score_flops=lambda cfg, kind, seq_len: 3.0 * kda_flops_per_token(
+            cfg.kda.n_heads, cfg.kda.head_dim, cfg.kda.head_dim),
+        needs="kda=KdaConfig", check=_check_kda, counters=KDA_COUNTERS,
+        **_SSM),
 }
 
 
@@ -1681,6 +1857,7 @@ class Block(nn.Module):
                     self, kind, _norm(cfg, family.norm, dtype=dt)(x), rope,
                     handed)
                 index_aux = given.pop("index", None)
+                own_aux = given.pop("counters", None)
                 x = residual(x, h, family.norm)
             # `ffn` is the dense FFN's scope; an expert layer is `moe`, with
             # the scopes of ops/moe.py inside it; a layer that is its mixer
@@ -1695,6 +1872,15 @@ class Block(nn.Module):
             if index_aux is not None:  # behind the expert layer's counters
                 aux = jnp.concatenate([aux, index_aux]) \
                     if self.ffn == "moe" else index_aux
+            if cfg.mixer_counters:
+                # a stack whose mixers count mixes kinds of layer: every one
+                # hands out the whole vector, zeros where it counts nothing
+                n_own = len(cfg.mixer_counters)
+                aux = jnp.concatenate([
+                    aux if self.ffn == "moe" else jnp.zeros(
+                        (len(cfg.counters) - n_own,), jnp.float32),
+                    jnp.zeros((n_own,), jnp.float32) if own_aux is None
+                    else own_aux])
         if cfg.remat and not self.is_initializing():
             log_once(log, f"remat {cfg.remat_policy}: a ({self.mixer}, "
                           f"{self.ffn}) layer at {tuple(x.shape)}, "

@@ -239,14 +239,20 @@ SELECTED = "index_selected"
 #: residuals; 35.7 MB a layer at 16,384 rows): kept under both, so that the
 #: kernel — as much work as the attention's forward — runs once a layer
 INDEX_GRADS = "index_grads"
+#: a delta-rule kernel's result and the state each of its chunks started from
+#: (``ops/kda.py``: the differentiation rule's residuals), ONE candidate at
+#: what the chunk form's products cost a byte (:func:`kernel_keeps`)
+KDA_OUT = "kda_out"
+KDA_STATES = "kda_states"
 NAMES = (PROJECTION, FLASH_OUT, FLASH_LSE, FFN_IN, ROWS, MIXER_IN, SHARED_IN,
-         ROUTED, SELECTED, INDEX_GRADS)
+         ROUTED, SELECTED, INDEX_GRADS, KDA_OUT, KDA_STATES)
 #: the names each ``remat_policy`` saves; a candidate that is not kept is not
 #: named (``dots`` keeps an FFN's products unnamed, as ``dot_general``s)
 KEPT = {
     "full": (FLASH_OUT, FLASH_LSE, FFN_IN, ROWS, MIXER_IN, SHARED_IN, ROUTED,
-             SELECTED, INDEX_GRADS),
-    "dots": (PROJECTION, FLASH_OUT, FLASH_LSE, ROUTED, SELECTED, INDEX_GRADS),
+             SELECTED, INDEX_GRADS, KDA_OUT, KDA_STATES),
+    "dots": (PROJECTION, FLASH_OUT, FLASH_LSE, ROUTED, SELECTED, INDEX_GRADS,
+             KDA_OUT, KDA_STATES),
 }
 
 #: under this many FLOP a byte nothing is kept whatever the room: the table
@@ -538,6 +544,23 @@ def name_flash(out: jax.Array, lse: jax.Array,
     (:data:`FLASH_OUT`, :data:`FLASH_LSE`) where :func:`flash_keeps` said so."""
     return (checkpoint_name(out, FLASH_OUT) if keeps[0] else out,
             checkpoint_name(lse, FLASH_LSE) if keeps[1] else lse)
+
+
+def kernel_keeps(what: str, kept: Tuple[jax.ShapeDtypeStruct, ...],
+                 labels: Tuple[str, ...], flop: float) -> bool:
+    """Whether the block being traced keeps the results ``kept`` of a Mosaic
+    call that is no flash kernel (``what``: ``kda``), ONE candidate at the
+    call's ``flop`` over their bytes — asked where the call is traced, as
+    :func:`flash_keeps` is; the call's differentiation rule then names them
+    by ``labels``."""
+    nbytes = _bytes(*kept)
+    cost = flop / nbytes
+    keep = _offer(what, nbytes, cost)
+    if keep:
+        _said.get().named.extend(
+            Named(label, _bytes(value), cost)
+            for label, value in zip(labels, kept))
+    return keep
 
 
 def _offer_products(what: str, products: Tuple[jax.Array, ...],

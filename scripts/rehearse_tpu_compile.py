@@ -92,6 +92,10 @@ SDAR = ("sdar", dict(
 KEYE = ("keye", dict(
     size="vl-2.0-30b-a3b", seq_len=16384, vocab=18992, remat_policy="full",
     layer_types=["full_attention"] * 6, experts_held=(0, 16), **_CHIP))
+KIMI = ("kimi_linear", dict(
+    size="48b-a3b", seq_len=16384, vocab=20480, remat_policy="full",
+    layer_types=["kda_dense", "kda_sparse", "kda_sparse", "mla_sparse",
+                 "kda_sparse"], experts_held=(0, 8), **_CHIP))
 PHI4FLASH = ("phi4flash", dict(
     size="mini-flash-reasoning", seq_len=16384, vocab=25008,
     remat_policy="full", layer_ids=[0, 1, 16, 17, 18, 19], **_CHIP))
@@ -180,6 +184,9 @@ PROGRAMS = {
     # learned index: the packed selections and the index loss's gradients
     # are kept whatever the room (PR 61)
     "keye_1x1": (KEYE, "dp=1", 1, 1, "adamw", 15.3),
+    # ONE sequence of 16,384 tokens a step in one microbatch through four
+    # delta-rule layers and one latent layer without positions (PR 64)
+    "kimi_1x1": (KIMI, "dp=1", 1, 1, "adamw", 15.3),
 }
 
 #: name -> the attention kernels (``flash_*``, ``mla_*``, ``swa_*``) a cell's
@@ -220,6 +227,9 @@ ATTENTION_KERNELS = {
     # results kept (weighed by the causal pairs the kernel visits: 16,133
     # FLOP a byte), k and v beside them
     "keye_1x1": {"dsa_bwd": 1, "dsa_fwd": 1},
+    # the ONE latent layer is a run of one between the delta rule's, its
+    # forward's results kept (20,166 FLOP a byte)
+    "kimi_1x1": {"mla_bwd": 1, "mla_fwd": 1},
 }
 
 #: name -> the Mamba mixers' kernels (ops/ssd.py: Mamba-2's scan ``ssd_*``,
@@ -244,6 +254,13 @@ MAMBA_KERNELS = {
     # selective scan's kernels (ops/selective_scan.py) and x's convolution
     "phi4flash_1x1": {"conv1d_bwd": 2, "conv1d_fwd": 4, "sscan_bwd": 2,
                       "sscan_fwd": 4},
+    # four delta-rule layers in three runs (one, two scanned, one): the
+    # recurrence's kernels (ops/kda.py) and three convolutions (q, k, v) a
+    # layer; the scanned run holds its forward twice (remat makes the layer
+    # again), a run of one once (the compiler merges the pass with the one
+    # made again)
+    "kimi_1x1": {"conv1d_bwd": 9, "conv1d_fwd": 12, "kda_bwd": 3,
+                 "kda_fwd": 4},
 }
 
 
@@ -472,7 +489,7 @@ def main() -> None:
             over.append(f"{name}: attention kernels {attention}, not "
                         f"{ATTENTION_KERNELS[name]}")
         mamba = {kernel: n for kernel, n in kernels.items()
-                 if kernel.startswith(("ssd_", "conv1d_", "sscan_"))}
+                 if kernel.startswith(("ssd_", "conv1d_", "sscan_", "kda_"))}
         if limit and mamba != MAMBA_KERNELS.get(name, {}):
             over.append(f"{name}: Mamba-2 kernels {mamba}, not "
                         f"{MAMBA_KERNELS.get(name, {})}")

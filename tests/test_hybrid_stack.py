@@ -708,23 +708,25 @@ def test_a_description_that_does_not_hold_together_is_refused(bad):
 def test_a_mixers_name_stands_in_the_table_and_nowhere_else():
     """``models/transformer.py`` asks :data:`MIXER_FAMILIES` what a mixer
     is: a scan's name is a string constant of the module once, as the
-    table's key (``gmu`` a second time, the scope the unit opens, which
-    ``gmu_time_pct`` reads), and no comparison holds one."""
+    table's key (``gmu`` and ``kda`` a second time, the scope each opens,
+    which ``gmu_time_pct`` / ``kda_time_pct`` read), and no comparison holds
+    one."""
     from easydl_tpu.models import transformer
 
     tree = ast.parse(open(transformer.__file__).read())
-    names = ("mamba2", "mamba1", "gmu")
+    names = ("mamba2", "mamba1", "gmu", "kda")
 
     def named(node):
         return [n.value for n in ast.walk(node)
                 if isinstance(n, ast.Constant) and n.value in names]
 
     assert collections.Counter(named(tree)) == {
-        "mamba2": 1, "mamba1": 1, "gmu": 2}
+        "mamba2": 1, "mamba1": 1, "gmu": 2, "kda": 2}
     assert not [(n.lineno, named(n)) for n in ast.walk(tree)
                 if isinstance(n, ast.Compare) and named(n)]
     assert [name for name, family in transformer.MIXER_FAMILIES.items()
-            if family.written] == ["attention", "mamba2", "mamba1", "gmu"]
+            if family.written] == ["attention", "mamba2", "mamba1", "gmu",
+                                   "kda"]
 
 
 def test_a_new_mixer_is_one_entry_of_the_table(monkeypatch):

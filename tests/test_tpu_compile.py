@@ -471,6 +471,38 @@ def test_keyes_indexed_attention_compiles_at_16k_rows(v5e_2x2):
                          text)
 
 
+def test_the_delta_rules_kernels_compile_at_the_cells_shape(v5e_2x2):
+    """Kimi Linear's recurrence at the cell's shape, forward and backward:
+    16,384 tokens, 32 heads of 128 x 128 state in chunks of 128 — ``kda_fwd``
+    (the differentiated one, which keeps each chunk's entry state) and
+    ``kda_bwd`` on the turned layout the convolutions give, rank 4 (so
+    ``lib/hlo.flash_calls`` never lists them); no ``[L, L]`` array and no
+    state a token in HBM, the states float32."""
+    from easydl_tpu.ops.kda import kda_kernels
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def loss(q, k, v, g, beta):
+        o, last = kda_kernels(q, k, v, g, beta, chunk=128)
+        return o.astype(jnp.float32).sum() + last.sum()
+
+    rows = shape(1, 16384, 32, 128)
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        rows, rows, rows, shape(1, 16384, 32, 128, dtype=jnp.float32),
+        shape(1, 16384, 32, dtype=jnp.float32)).compile().as_text()
+    turned, decays = "bf16[1,32,16384,128]", "f32[1,32,16384,128]"
+    steps = "f32[1,128,16,2,128]"
+    assert _operand_shapes(text, "kda_fwd") == [turned] * 3 + [decays, steps]
+    assert _operand_shapes(text, "kda_bwd") == [
+        turned] * 3 + [decays, steps, "f32[1,32,128,128,128]", turned,
+                       "f32[1,32,128,128]"]
+    assert not re.search(r"\[(1,)?(\d+,)?16384,16384\]", text)
+    assert not re.search(r"\[(1,)?(32,)?16384,128,128\]", text)
+
+
 def test_sdars_attention_norms_q_and_k_inside_the_rotary_kernel(v5e_2x2):
     """As the test above, with the kind's gains handed in
     (``multihead_attention(qk_norm=)``): at 16,384 rows of 32 | 4 heads of

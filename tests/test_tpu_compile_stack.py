@@ -308,6 +308,10 @@ def test_phi4flash_s_step_holds_its_four_new_kernels_and_fits(v5e_2x2,
     ("phi4flash_1x1", {"swa_fwd": -2, "swa_bwd_dq": -1, "swa_bwd_dkv": -1,
                        "diff_fwd": 2, "diff_bwd": 1},
      "phi4flash_1x1: attention kernels {'diff_bwd': 3, 'diff_fwd': 4}, not"),
+    ("kimi_1x1", {}, None),
+    ("kimi_1x1", {"kda_fwd": -4, "kda_bwd": -3},  # jax.numpy's chunks
+     "kimi_1x1: Mamba-2 kernels {'conv1d_bwd': 9, 'conv1d_fwd': 12}, not "
+     "{'conv1d_bwd': 9, 'conv1d_fwd': 12, 'kda_bwd': 3, 'kda_fwd': 4}"),
 ], ids=["as-gated", "a-forward-more", "joyai-a-forward-more",
         "another-backward", "hybrid-as-gated", "nemotron-as-gated",
         "hybrid-the-numpy-scan", "nemotron-a-scan-forward-less",
@@ -316,7 +320,8 @@ def test_phi4flash_s_step_holds_its_four_new_kernels_and_fits(v5e_2x2,
         "mellum-as-gated", "mellum-the-looped-window", "sdar-as-gated",
         "sdar-a-forward-more", "sdar-the-causal-kernels",
         "phi4flash-as-gated", "phi4flash-the-numpy-scan",
-        "phi4flash-the-looped-window"])
+        "phi4flash-the-looped-window", "kimi-as-gated",
+        "kimi-the-numpy-chunks"])
 def test_the_script_fails_on_other_attention_kernels_than_a_cells(
         v5e_2x2, rehearse, monkeypatch, capsys, program, more, said):
     """The script is where the whole steps at the cells' sizes are gated
@@ -334,7 +339,7 @@ def test_the_script_fails_on_other_attention_kernels_than_a_cells(
     assert set(rehearse.ATTENTION_KERNELS) == {
         name for name, program in rehearse.PROGRAMS.items() if program[-1]}
     assert set(rehearse.MAMBA_KERNELS) == {"hybrid_4x2", "nemotron_1x2",
-                                           "phi4flash_1x1"}
+                                           "phi4flash_1x1", "kimi_1x1"}
     counts = dict(rehearse.ATTENTION_KERNELS[program], rows_to_tokens=6,
                   **rehearse.MAMBA_KERNELS.get(program, {}))
     for kernel, n in more.items():
