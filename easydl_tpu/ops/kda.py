@@ -26,15 +26,21 @@ float32 within a few tokens at a rate of 16), so the kernels take a chunk in
 sub-blocks of :data:`SUB` rows: a sub-block's products with the columns in
 front of it are referred to the sub-block's FIRST row (``exp(G_j - G_first)``
 and ``exp(G_first - G_i)``, both at most one, both factors to the MXU), and
-the sub-blocks on the diagonal are ``exp(G_j - G_i)`` element by element.
-``(I + A)^{-1}`` is the diagonal sub-blocks' inverses by substitution (one
-column a step, all sub-blocks at once) and the blocks below them by the
-block Neumann product, which ends after ``log2(C / SUB)`` factors; nothing is
-left out. No ``[L, L]`` array and no state a token exists: HBM sees q, k, v,
-g and beta once a pass (the cumulative sums and the ``beta``-weighted
-operands are made in VMEM), o once, and the state each chunk started from
-(``L / C`` of them a head, float32) written by the differentiated forward and
-read by the backward.
+the sub-blocks on the diagonal are ``exp(G_j - G_i)`` element by element,
+made and inverted SIDE BY SIDE on ``[SUB, C]`` arrays (two vector registers
+at 16 x 128, where a ``[C, C]`` array in its place is sixteen of which
+fourteen hold zeros): a step ``t`` of a sub-block is ONE pass — ``P``'s
+column ``t`` lives on the rows at and behind ``t``, ``A``'s row ``t`` on the
+rows in front, so one operand, one ``exp`` and one lane reduction give both
+— and ``(I + A)^{-1}`` is the diagonal sub-blocks' inverses by substitution
+on one ``[SUB, C]`` array (a row a step from the first, all sub-blocks at
+once, a product and a sum over SUBLANES) put back in their places once, and
+the blocks below them by the block Neumann product, which ends after
+``log2(C / SUB)`` factors; nothing is left out. No ``[L, L]`` array and no
+state a token exists: HBM sees q, k, v, g and beta once a pass (the
+cumulative sums and the ``beta``-weighted operands are made in VMEM), o once,
+and the state each chunk started from (``L / C`` of them a head, float32)
+written by the differentiated forward and read by the backward.
 
 What runs where, chosen from the platform and the shapes alone (the line
 ``kda: ...`` a process logs once says which, and why):
@@ -216,56 +222,75 @@ def _iotas(rows: int, cols: int):
 
 
 def _scores(q, k, kb, G, sub: int, dt):
-    """A chunk's ``(A_front, A_diag^T, P)``, each ``[C, C]`` float32: ``A``'s
-    entries in front of each sub-block's own columns; the TRANSPOSES of its
-    diagonal sub-blocks in their places (what the substitution reads by
-    rows); and ``P`` whole, its diagonal sub-blocks with their diagonals."""
+    """A chunk's ``(A_front, A's diagonal rows, P)``: ``A``'s entries in front
+    of each sub-block's own columns and ``P`` whole, ``[C, C]`` float32 each;
+    and the diagonal sub-blocks of ``A`` COMPACT, side by side, as the
+    substitution reads them — for every local row ``t >= 1`` one ``[SUB, C]``
+    array ``W_t[j, (b, c)] = A_b[t, j]`` (``j < t``, zero below), the same
+    value in all the lanes ``c`` of sub-block ``b``.
+
+    A step ``t`` of a sub-block is ONE pass: ``P``'s column ``t`` lives on
+    the rows at and behind ``t`` and ``A``'s row ``t`` on the rows in front,
+    so one operand, one ``exp`` (its argument at most zero on either side)
+    and one lane reduction give both."""
     n = q.shape[0]
-    row, col = _iotas(sub, n)
+    _, col = _iotas(sub, n)
     local = lax.broadcasted_iota(jnp.int32, (sub, 1), 0)
-    fronts, transposed, ps = [], [], []
+    block = col // sub
+    fronts, p_fronts = [], []
     for r0 in range(0, n, sub):
-        Gr, qr, kr, kbr = (x[r0:r0 + sub] for x in (G, q, k, kb))
+        Gr, qr, kbr = (x[r0:r0 + sub] for x in (G, q, kb))
         down = jnp.exp(Gr - G[r0:r0 + 1])
         k_back = (k * jnp.exp(jnp.minimum(G[r0:r0 + 1] - G, 0.0))).astype(dt)
         front = col < r0
-        a = jnp.where(front, _dot((kbr * down).astype(dt), k_back, _NT), 0.0)
-        p = jnp.where(front, _dot((qr * down).astype(dt), k_back, _NT), 0.0)
-        at = jnp.zeros((sub, n), _F32)
-        for t in range(sub):
-            Gt = G[r0 + t:r0 + t + 1]
-            at_t = col == r0 + t
-            # P's column t: rows at and behind it
-            w = k[r0 + t:r0 + t + 1] * jnp.exp(jnp.minimum(Gr - Gt, 0.0))
-            p = p + jnp.where(at_t & (local >= t), jnp.sum(
-                qr * w, axis=1, keepdims=True), 0.0)
-            # A's row t as a column of the transpose: the rows in front
-            w = kb[r0 + t:r0 + t + 1] * jnp.exp(jnp.minimum(Gt - Gr, 0.0))
-            at = at + jnp.where(at_t & (local < t), jnp.sum(
-                kr * w, axis=1, keepdims=True), 0.0)
-        fronts.append(a)
-        transposed.append(at)
-        ps.append(p)
-    return tuple(jnp.concatenate(x, axis=0) for x in (fronts, transposed, ps))
+        fronts.append(jnp.where(
+            front, _dot((kbr * down).astype(dt), k_back, _NT), 0.0))
+        p_fronts.append(jnp.where(
+            front, _dot((qr * down).astype(dt), k_back, _NT), 0.0))
+    # the diagonal sub-blocks' P side by side: p_diag[j, (b, t)] = P_b[j, t]
+    p_diag = jnp.zeros((sub, n), _F32)
+    a_rows = []
+    for t in range(sub):
+        behind = local >= t
+        w_t = jnp.zeros((sub, n), _F32)
+        for b, r0 in enumerate(range(0, n, sub)):
+            Gr, qr, kr = (x[r0:r0 + sub] for x in (G, q, k))
+            at = slice(r0 + t, r0 + t + 1)
+            d = Gr - G[at]
+            e = jnp.exp(jnp.minimum(jnp.where(behind, d, -d), 0.0))
+            s = jnp.sum(jnp.where(behind, qr, kr)
+                        * (jnp.where(behind, k[at], kb[at]) * e),
+                        axis=1, keepdims=True)
+            p_diag = jnp.where(col == r0 + t, s, p_diag)
+            w_t = jnp.where(block == b, s, w_t)
+        if t:
+            a_rows.append(jnp.where(local < t, w_t, 0.0))
+    own = local >= col % sub
+    P = jnp.concatenate([
+        p + jnp.where((block == b) & own, p_diag, 0.0)
+        for b, p in enumerate(p_fronts)], axis=0)
+    return jnp.concatenate(fronts, axis=0), a_rows, P
 
 
-def _inverse(a_front, a_diag_t, sub: int):
+def _inverse(a_front, a_rows, sub: int):
     """``(I + A)^{-1} [C, C]`` float32 from :func:`_scores`' two parts of
-    the strictly lower ``A``. The diagonal sub-blocks' inverses ``X`` first,
-    all at once, a column a step from the last: ``X (I + A) = I`` gives
-    ``X[:, i] = e_i - sum_{j > i} X[:, j] A[j, i]``. Then ``(D + F)^{-1} = (I
-    + X F)^{-1} X`` with ``N = X F`` strictly lower by BLOCKS, so ``(I +
-    N)^{-1} = (I - N)(I + N^2)(I + N^4) ...`` ends at the number of blocks."""
+    the strictly lower ``A``. The diagonal sub-blocks' inverses first, all at
+    once and side by side, ``X_c[j, (b, c)] = X_b[j, c]`` on ONE ``[SUB, C]``
+    array, a row a step from the first: ``(I + A_b) X_b = I`` gives ``X_b[t,
+    :] = e_t - sum_{j < t} A_b[t, j] X_b[j, :]`` — a product and a sum over
+    SUBLANES (``W_t`` is zero at the rows not yet made). Back in their places
+    ``X``, then ``(D + F)^{-1} = (I + X F)^{-1} X`` with ``N = X F`` strictly
+    lower by BLOCKS, so ``(I + N)^{-1} = (I - N)(I + N^2)(I + N^4) ...`` ends
+    at the number of blocks."""
     n = a_front.shape[0]
-    row, col = _iotas(n, n)
-    own = (row // sub) * sub
-    x = jnp.where(row == col, 1.0, 0.0).astype(_F32)
-    for i in range(sub - 2, -1, -1):
-        a_row = jnp.concatenate([jnp.broadcast_to(
-            a_diag_t[r0 + i:r0 + i + 1], (sub, n))
-            for r0 in range(0, n, sub)], axis=0)
-        x = x - jnp.where(col == own + i, jnp.sum(
-            x * a_row, axis=1, keepdims=True), 0.0)
+    local, col = _iotas(sub, n)
+    x_c = jnp.where(col % sub == local, 1.0, 0.0).astype(_F32)
+    for t, w_t in enumerate(a_rows, 1):
+        x_c = x_c - jnp.where(local == t, jnp.sum(
+            w_t * x_c, axis=0, keepdims=True), 0.0)
+    block = col // sub
+    x = jnp.concatenate([jnp.where(block == b, x_c, 0.0)
+                         for b in range(n // sub)], axis=0)
     if n == sub:
         return x
     step = _dot32(x, a_front, _NN)
@@ -281,8 +306,8 @@ def _inverse(a_front, a_diag_t, sub: int):
 def _chunk_parts(q, k, kb, vb, G, state_t, sub: int, dt):
     """What both kernels make of a chunk and its entry state ``[d_v, d_k]``:
     ``(P, T, U, the decayed operands)``."""
-    a_front, a_diag_t, P = _scores(q, k, kb, G, sub, dt)
-    T = _inverse(a_front, a_diag_t, sub)
+    a_front, a_rows, P = _scores(q, k, kb, G, sub, dt)
+    T = _inverse(a_front, a_rows, sub)
     e_g, last = jnp.exp(G), G[-1:]
     tail = jnp.exp(last - G)
     k_bar, q_bar, k_hat = kb * e_g, q * e_g, k * tail
@@ -614,8 +639,9 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     if why is None:
         log_once(log, f"kda: Pallas kernels kda_fwd / kda_bwd, {said}; a grid "
                       f"cell is one chunk of {CELL_HEADS} heads in sub-blocks "
-                      f"of {SUB}, the state carried in VMEM, every chunk's "
-                      f"entry state kept for the backward")
+                      f"of {SUB}, the diagonal ones made and inverted side by "
+                      f"side on [{SUB}, {n}] arrays, the state carried in "
+                      f"VMEM, every chunk's entry state kept for the backward")
         return kda_kernels(q, k, v, g, beta, chunk=n)
     log_once(log, f"kda: chunks in jax.numpy, not the kernels ({why}), "
                   f"{said}, differentiated by jax (body rematerialised)")

@@ -44,27 +44,28 @@ def token_by_token(q, k, v, g, beta, additive=False):
     return jnp.swapaxes(out, 0, 1), last
 
 
-def operands(case: str, seq: int, seed: int = 3):
+def operands(case: str, seq: int, seed: int = 3, size: int = SIZE):
     """``(q, k, v, g, beta)`` and the weights of a scalar objective over
     ``o`` and the final state. ``strong``: a rate of 16 and steps near 1 (a
     chunk's ``exp(-G)`` would overflow float32); ``repeat``: every key nearly
     the first one and step sizes near 1 (the subtraction is most of the
-    update); ``plain_delta``: no decay, step size one."""
+    update); ``plain_delta``: no decay, step size one; ``none``: no decay."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 7)
-    shape = (1, seq, HEADS, SIZE)
+    shape = (1, seq, HEADS, size)
     q, k, v, w = (jax.random.normal(key, shape) for key in keys[:4])
     if case == "repeat":
         k = k[:, :1] + 0.01 * k
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(SIZE)
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(size)
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     step = jax.nn.softplus(jax.random.normal(keys[4], shape))
-    g = -step * {"strong": 16.0, "plain_delta": 0.0}.get(case, 0.3)
+    g = -step * {"strong": 16.0, "plain_delta": 0.0, "none": 0.0}.get(
+        case, 0.3)
     beta = jax.nn.sigmoid(jax.random.normal(keys[5], shape[:3])
                           + (6.0 if case == "repeat" else 0.0))
     if case == "plain_delta":
         beta = jnp.ones_like(beta)
     return (q, k, v, g, beta), (w, jax.random.normal(
-        keys[6], (1, HEADS, SIZE, SIZE)))
+        keys[6], (1, HEADS, size, size)))
 
 
 def results(fn, args, weights):
@@ -99,6 +100,54 @@ def test_chunks_against_the_recurrence(path, seq, case):
         assert bool(jnp.all(jnp.isfinite(a))), name
         scale = float(jnp.max(jnp.abs(b))) + 1e-30
         assert float(jnp.max(jnp.abs(a - b))) / scale < 5e-5, (name, case)
+
+
+@functools.partial(jax.jit, static_argnames="sub")
+def chunk_parts_and_dense(q, k, kb, G, sub):
+    """``_scores`` and ``_inverse`` in plain ``jax.numpy`` (no interpreter)
+    beside the dense formula: ``A`` and ``P`` from ``exp(G_j - G_i)`` element
+    by element over the lower triangle, as ``_chunk_step`` has them, and
+    ``(I + A)^{-1}`` by a triangular solve."""
+    n = q.shape[0]
+    a_front, a_rows, P = kda_ops._scores(q, k, kb, G, sub, jnp.float32)
+    T = kda_ops._inverse(a_front, a_rows, sub)
+    lower = jnp.tril(jnp.ones((n, n), bool))[..., None]
+    decay = jnp.where(lower, jnp.exp(jnp.where(
+        lower, G[:, None] - G[None, :], 0.0)), 0.0)
+    A = jnp.sum(kb[:, None] * k[None, :] * decay, -1) \
+        * jnp.tril(jnp.ones((n, n)), -1)
+    want_T = jax.scipy.linalg.solve_triangular(
+        jnp.eye(n) + A, jnp.eye(n), lower=True, unit_diagonal=True)
+    return (a_front, jnp.stack(a_rows), P, T), (
+        A, jnp.sum(q[:, None] * k[None, :] * decay, -1), want_T)
+
+
+@pytest.mark.parametrize("n,sub,case", [
+    (128, 16, "plain"), (128, 16, "strong"), (128, 16, "repeat"),
+    (128, 16, "none"), (16, 8, "plain"), (16, 8, "strong"),
+    (16, 8, "repeat"), (16, 16, "repeat")])
+def test_the_chunk_algebras_parts_against_the_dense_formula(n, sub, case):
+    """``A`` in front of the sub-blocks, ``P`` whole and ``T`` to 1e-6 of
+    the largest entry; the compact rows are ``A``'s diagonal sub-blocks side
+    by side, ``W_t[j, (b, c)] = A_b[t, j]`` for ``j < t`` in every lane ``c``
+    of sub-block ``b``; ``chunk == sub`` (the last case) has no front and
+    returns the diagonal inverse alone."""
+    (q, k, _, g, beta), _ = operands(case, n, size=8 if n == 16 else 128)
+    q, k, g, beta = (x[0, :, 0] for x in (q, k, g, beta))
+    (a_front, a_rows, P, T), (A, want_P, want_T) = chunk_parts_and_dense(
+        q, k, k * beta[:, None], jnp.cumsum(g, axis=0), sub=sub)
+    row, col = np.indices((n, n))
+    t, j, lane = np.indices((sub - 1, sub, n))
+    own = lane // sub * sub
+    want = {"A in front": (a_front, np.where(col < row // sub * sub, A, 0.0)),
+            "P": (P, want_P), "T": (T, want_T),
+            "W": (a_rows, np.where(j <= t, np.asarray(A)[
+                own + t + 1, own + j], 0.0))}
+    for what, (mine, wanted) in want.items():
+        scale = float(np.max(np.abs(wanted))) + 1e-30
+        assert float(np.max(np.abs(mine - wanted))) / scale < 1e-6, what
+    assert a_rows.shape == (sub - 1, sub, n)
+    assert (n > sub) == bool(np.any(a_front))
 
 
 def test_an_additive_rule_fails_where_keys_repeat():
